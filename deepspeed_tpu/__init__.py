@@ -14,9 +14,26 @@ Public entry points (reference parity):
 
 __version__ = "0.1.0"
 
-from . import comm  # noqa: F401
-from .accelerator import get_accelerator  # noqa: F401
-from .runtime.config import DeepSpeedTPUConfig, parse_config  # noqa: F401
+# Resolved on first use (PEP 562), not at import: the launcher and the
+# autotuning parent import this package and then start children that need the
+# chip, so importing ``deepspeed_tpu`` alone must not import JAX.
+_LAZY = {"comm": ("comm", None),
+         "get_accelerator": ("accelerator", "get_accelerator"),
+         "DeepSpeedTPUConfig": ("runtime.config", "DeepSpeedTPUConfig"),
+         "parse_config": ("runtime.config", "parse_config")}
+
+
+def __getattr__(name):
+    import importlib
+
+    if name not in _LAZY:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    module, attr = _LAZY[name]
+    value = importlib.import_module(f"{__name__}.{module}")
+    if attr is not None:
+        value = getattr(value, attr)
+    globals()[name] = value
+    return value
 
 
 def initialize(*args, **kwargs):

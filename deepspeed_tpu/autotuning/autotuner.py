@@ -17,14 +17,16 @@ import dataclasses
 import itertools
 import json
 import os
+import subprocess
+import sys
+import tempfile
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax
-import numpy as np
-
 from ..tuning.registry import config_set, default_registry
-from ..utils.logging import log_dist, logger
+# plain logger, never log_dist: that asks JAX for the process index, which
+# starts the backend — and the subprocess-trial parent must stay off the chip
+from ..utils.logging import logger
 from .tuner import GridSearchTuner, ModelBasedTuner, RandomTuner
 
 TUNERS = {"gridsearch": GridSearchTuner, "random": RandomTuner,
@@ -78,6 +80,38 @@ DEFAULT_MICRO_BATCHES = default_registry().choices("train.micro_batch")
 DEFAULT_STAGES = default_registry().choices("train.zero_stage")
 
 
+def describe_devices() -> Dict[str, Any]:
+    """``{"n_chips", "hbm_bytes"}`` of THIS process's devices (touches JAX).
+    ``hbm_bytes`` is what the runtime reports, else the published size from
+    ``utils/peaks.py``, else None — never an assumed default."""
+    import jax
+
+    from ..utils.peaks import UnknownDevice, device_peaks
+
+    dev = jax.devices()[0]
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        hbm = int(stats["bytes_limit"])
+    else:
+        try:
+            hbm = device_peaks(dev).hbm_bytes
+        except UnknownDevice:
+            hbm = None
+    return {"n_chips": len(jax.devices()), "hbm_bytes": hbm}
+
+
+def _describe_devices_in_child(timeout_s: float) -> Dict[str, Any]:
+    r = subprocess.run(
+        [sys.executable, "-m", "deepspeed_tpu.autotuning.trial_worker",
+         "--describe-devices"],
+        capture_output=True, text=True, timeout=timeout_s)
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"device description child failed (rc={r.returncode}): "
+            f"{r.stderr[-500:]}")
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
 class Autotuner:
     """Find the fastest feasible (zero_stage, micro_batch, gas, remat) for a
     model + target global batch on the current devices."""
@@ -97,8 +131,6 @@ class Autotuner:
         self.base_config = dict(base_config)
         self.trial_steps = trial_steps
         self.tuner_type = tuner_type
-        self.n_chips = len(jax.devices())
-        self.hbm = hbm_bytes_per_chip or self._detect_hbm()
         self.model_info = model_info or {}
         self.micro_batches = micro_batches
         self.zero_stages = zero_stages
@@ -115,13 +147,14 @@ class Autotuner:
         if model_spec is None and model_desc is None:
             raise ValueError("need model_spec (in-process trials) or "
                              "model_desc (subprocess trials)")
-
-    def _detect_hbm(self) -> int:
-        d = jax.devices()[0]
-        stats = getattr(d, "memory_stats", lambda: None)()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-        return 16 << 30  # v5e-class default
+        # The chip belongs to one process at a time. With subprocess trials
+        # THIS process must never touch JAX (each trial child needs the
+        # chip), so the device facts come from a short-lived child that has
+        # exited before the first trial starts.
+        facts = (_describe_devices_in_child(trial_timeout_s)
+                 if model_desc is not None else describe_devices())
+        self.n_chips = int(facts["n_chips"])
+        self.hbm = hbm_bytes_per_chip or facts["hbm_bytes"]
 
     # ------------------------------------------------------------------ #
     def build_space(self) -> List[Dict[str, Any]]:
@@ -141,6 +174,11 @@ class Autotuner:
                     info["num_params"], stage, self.n_chips, mb,
                     info.get("seq_len", 2048), info.get("hidden_size", 4096),
                     info.get("num_layers", 32), remat=remat)
+                if self.hbm is None:
+                    raise ValueError(
+                        "memory pruning needs the chip's HBM size, but the "
+                        "device reports none and is not in utils/peaks.py; "
+                        "pass hbm_bytes_per_chip")
                 if est > self.hbm:
                     continue
             space.append({"zero_stage": stage, "micro_batch": mb,
@@ -167,10 +205,6 @@ class Autotuner:
     def run_trial_subprocess(self, point: Dict[str, Any]) -> TrialResult:
         """One trial in an isolated worker process (fresh jit cache; an OOM
         or wedge is contained by the process boundary + timeout)."""
-        import subprocess
-        import sys
-        import tempfile
-
         job = {"model": self.model_desc,
                "trial_config": self._trial_config(point),
                "trial_steps": self.trial_steps}
@@ -206,13 +240,15 @@ class Autotuner:
             except OSError:
                 pass
         self.results.append(res)
-        log_dist(f"autotuning trial {point} [subprocess]: "
-                 f"{res.samples_per_sec:.2f} samples/s"
-                 + (f" ({res.error})" if res.error else ""))
+        logger.info(f"autotuning trial {point} [subprocess]: "
+                    f"{res.samples_per_sec:.2f} samples/s"
+                    + (f" ({res.error})" if res.error else ""))
         return res
 
     def run_trial(self, point: Dict[str, Any],
                   data_fn: Callable[[int], Any]) -> TrialResult:
+        import jax
+
         import deepspeed_tpu as dst
         from ..comm.mesh import set_mesh
 
@@ -232,8 +268,8 @@ class Autotuner:
             logger.warning(f"autotuning trial {point} failed: {e}")
             res = TrialResult(point, 0.0, float("inf"), error=str(e))
         self.results.append(res)
-        log_dist(f"autotuning trial {point}: "
-                 f"{res.samples_per_sec:.2f} samples/s")
+        logger.info(f"autotuning trial {point}: "
+                    f"{res.samples_per_sec:.2f} samples/s")
         return res
 
     def tune(self, data_fn: Optional[Callable[[int], Any]] = None,
@@ -251,9 +287,9 @@ class Autotuner:
         best_cfg, best_metric = tuner.tune(max_trials)
         best = next(r for r in self.results
                     if r.config == best_cfg and r.samples_per_sec == best_metric)
-        log_dist(f"autotuning best: {best.config} "
-                 f"({best.samples_per_sec:.2f} samples/s over "
-                 f"{len(self.results)} trials)")
+        logger.info(f"autotuning best: {best.config} "
+                    f"({best.samples_per_sec:.2f} samples/s over "
+                    f"{len(self.results)} trials)")
         return best
 
     def best_ds_config(self) -> Dict[str, Any]:

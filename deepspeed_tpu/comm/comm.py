@@ -284,29 +284,16 @@ def configure(enabled: bool = False, verbose: bool = False,
 
 
 # --------------------------------------------------------------------------- #
-# shard_map across jax versions
+# shard_map
 # --------------------------------------------------------------------------- #
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """Version-portable ``shard_map``: newer jax exposes ``jax.shard_map``
-    with ``axis_names``/``check_vma``; 0.4-era jax has
-    ``jax.experimental.shard_map.shard_map`` where partial-manual regions are
-    spelled as ``auto=<complement>`` and the replication check is
-    ``check_rep``. Every manual collective region in the framework goes
-    through this one shim so a jax upgrade is a one-line change."""
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(set(mesh.axis_names) - set(axis_names))
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=bool(check_vma), auto=auto)
+    """``jax.shard_map`` with this package's defaults (no replication check;
+    ``axis_names`` as any iterable). Every manual collective region in the
+    framework goes through here."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 # --------------------------------------------------------------------------- #
@@ -485,18 +472,15 @@ def init_distributed(dist_backend: str = "xla",
         _initialized = True  # single-process / TPU-native bootstrap
         log_dist("init_distributed: single-process or TPU-native rendezvous")
         return
-    try:
+    if not jax.distributed.is_initialized():  # else: the launcher did it
         if process_id is None:
             process_id = resolve_process_id()
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes or int(env_procs or 1),
             process_id=process_id)
-        _initialized = True
-        log_dist(f"init_distributed: {jax.process_count()} processes")
-    except Exception as e:  # already initialised by the launcher
-        logger.warning(f"jax.distributed.initialize skipped: {e}")
-        _initialized = True
+    _initialized = True
+    log_dist(f"init_distributed: {jax.process_count()} processes")
 
 
 def is_initialized() -> bool:

@@ -23,13 +23,9 @@ from jax import lax
 
 
 def _axis_size(axis_name: str) -> int:
-    """Static member count of a named axis, portable across jax versions:
-    ``lax.axis_size`` where it exists; on 0.4-era jax, ``psum`` of a Python
-    int short-circuits to ``value * axis_size`` at trace time, resolving the
-    size from the enclosing shard_map's axis env without a global mesh."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return int(lax.psum(1, axis_name))
+    """Static member count of a named axis, resolved from the enclosing
+    shard_map's axis env (no global mesh needed)."""
+    return lax.axis_size(axis_name)
 
 
 def onebit_compress(x: jnp.ndarray, error: jnp.ndarray

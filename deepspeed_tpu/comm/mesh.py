@@ -24,7 +24,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.logging import log_dist, logger
+from ..utils.logging import log_dist
 
 MESH_AXES: Tuple[str, ...] = ("data", "zero_shard", "expert", "pipe", "seq",
                               "tensor")
@@ -79,22 +79,16 @@ def _arrange_devices(devices: Sequence[jax.Device],
                 f"no mesh axis divisible by slice count {n_slices}: "
                 f"{dict(zip(MESH_AXES, sizes))}")
     dcn_name = MESH_AXES[dcn_axis] if dcn_axis is not None else None
-    try:
-        if dcn_axis is not None:
-            dcn = [1] * len(sizes)
-            dcn[dcn_axis] = n_slices
-            per_slice = list(sizes)
-            per_slice[dcn_axis] //= n_slices
-            return mesh_utils.create_hybrid_device_mesh(
-                per_slice, dcn, devices=devices), dcn_name
-        return mesh_utils.create_device_mesh(sizes, devices=devices), None
-    except Exception as e:  # unknown topology (e.g. tunneled sub-slice
-        # quirks) — mesh_utils raises plain ValueError for these too, so no
-        # exception type is exempt from the fallback
-        logger.warning(
-            f"topology-aware mesh assignment failed ({e}); falling back to "
-            "device-order reshape — inner-axis collectives may cross hosts")
-        return np.asarray(devices).reshape(sizes), dcn_name
+    # create_device_mesh either knows the topology or the mesh would be
+    # wrong (inner-axis collectives crossing hosts): a failure raises
+    if dcn_axis is not None:
+        dcn = [1] * len(sizes)
+        dcn[dcn_axis] = n_slices
+        per_slice = list(sizes)
+        per_slice[dcn_axis] //= n_slices
+        return mesh_utils.create_hybrid_device_mesh(
+            per_slice, dcn, devices=devices), dcn_name
+    return mesh_utils.create_device_mesh(sizes, devices=devices), None
 
 
 @dataclass
@@ -190,8 +184,11 @@ class MeshManager:
 
     @contextlib.contextmanager
     def activate(self):
-        """Enter the mesh context so bare ``P`` specs resolve inside jit."""
-        with self.mesh:
+        """Enter the mesh context: bare ``P`` specs resolve inside jit, and
+        whatever is traced here can ask which mesh its program is for
+        (``jax.sharding.get_abstract_mesh()`` — how a per-device kernel
+        learns that it must shard_map itself, ``ops.registry``)."""
+        with jax.set_mesh(self.mesh):
             yield self.mesh
 
 

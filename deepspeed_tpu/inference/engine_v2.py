@@ -131,6 +131,12 @@ class InferenceEngineV2(InferenceEngine):
             self.cache = self._init_paged(self.family.cfg,
                                           rc.memory_config_blocks,
                                           rc.block_size)
+        # commit the fresh pool to the mesh the way every paged program
+        # hands it back: a fresh uncommitted array has another sharding
+        # than a program's output, so the first program to touch it would
+        # compile once for the fresh pool and again for every later call
+        # (the serving twin of the scalar placement in runtime/engine.py)
+        self.cache = jax.device_put(self.cache, self.mesh_mgr.replicated())
         self._paged_fns: Dict[Tuple, Callable] = {}
         # --- host-spill tier for evicted prefix-cache blocks
         # (inference.prefix_cache.host_spill; docs/memory.md). Default OFF →

@@ -146,16 +146,29 @@ class LocalRunner(MultiNodeRunner):
 class LocalMultiRunner(MultiNodeRunner):
     """N processes on ONE host, coordinator on localhost — the reference's
     per-device fork (``launcher/launch.py:145`` spawns ``num_local_procs``
-    workers with RANK/LOCAL_RANK env). On TPU pods one process drives all
-    local chips so this is mainly the CPU/simulation path — but it is the
+    workers with RANK/LOCAL_RANK env). This is the CPU simulation path: the
     same bootstrap contract (``jax.distributed.initialize``) as a real
-    multi-host launch, which is exactly what makes it the right
-    end-to-end launcher test double."""
+    multi-host launch, which makes it the end-to-end launcher test double.
+
+    It is NOT how a TPU host is driven. A chip belongs to one process at a
+    time and ONE process drives all the chips of a host (four on a v5e 2x2
+    host), so N processes that each open the default backend would fight
+    over the same chips: the first holds them and the rest fail or hang.
+    Nothing here hands a process a chip of its own, so the runner refuses
+    to start unless the launch environment keeps every child off the chip
+    (``JAX_PLATFORMS=cpu``)."""
 
     name = "local_multi"
 
     def __init__(self, args, world_info: Dict[str, int], nproc: int):
         super().__init__(args, world_info)
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            raise RuntimeError(
+                f"--num_local_procs {nproc} would start {nproc} processes "
+                f"that all open the host's chips, and a chip belongs to one "
+                f"process at a time. One process drives every local chip: "
+                f"drop --num_local_procs, or set JAX_PLATFORMS=cpu for the "
+                f"CPU simulation this mode exists for")
         self.nproc = nproc
 
     def node_env(self, process_id: int) -> Dict[str, str]:
@@ -331,7 +344,11 @@ def parse_args(argv=None):
 def build_commands(args) -> Tuple[MultiNodeRunner, List[List[str]]]:
     hosts = fetch_hostfile(args.hostfile)
     if hosts is None:
-        hosts = {"localhost": max(1, len_local_devices())}
+        # one process drives every local chip, so localhost is ONE slot —
+        # the same default a hostfile line without ``slots=`` gets. (The
+        # launcher never asks JAX: a parent that touched the chip would
+        # keep it from the children it starts.)
+        hosts = {"localhost": 1}
     hosts = parse_inclusion_exclusion(hosts, args.include, args.exclude)
     if args.num_nodes > 0:
         hosts = dict(list(hosts.items())[:args.num_nodes])
@@ -360,15 +377,6 @@ def build_commands(args) -> Tuple[MultiNodeRunner, List[List[str]]]:
     if not runner.backend_exists():
         raise RuntimeError(f"launcher backend '{runner.name}' unavailable")
     return runner, runner.get_cmd()
-
-
-def len_local_devices() -> int:
-    try:
-        import jax
-
-        return len(jax.devices())
-    except Exception:
-        return 1
 
 
 def main(argv=None) -> int:

@@ -2,25 +2,21 @@
 
 One capability story for every backend:
 
-- **TPU** exposes separate ``device`` (HBM) and ``pinned_host`` memory
-  spaces; ``jax.device_put`` with a memory kind moves an array between them
-  (async DMA over PCIe), and inside jit a ``TransferToMemoryKind``
-  annotation lowers to an XLA host-memory (``S(5)``) placement the
-  latency-hiding scheduler can stream around.
-- the **CPU test mesh** has exactly one memory space (``unpinned_host``),
-  so real memory-kind moves are impossible. Eager moves fall back to
-  :class:`HostBuffer` — a numpy-resident leaf that carries its logical tier
-  and original sharding so restore is exact — and in-jit annotations are
-  identity. Callers write one code path; the semantics ("this leaf is on
-  the host tier / bring it back") hold everywhere, and on CPU the
-  host-tier leaves really do leave the device allocator (``HostBuffer`` is
-  not a ``jax.Array``, so ``jax.live_arrays`` no longer counts it).
+- A device that exposes a host memory space beside its own (``device`` and
+  ``pinned_host`` / ``unpinned_host`` — the TPU, and under the installed
+  JAX the CPU backend too) moves arrays between them with
+  ``jax.device_put`` and a memory kind (async DMA over PCIe on the TPU).
+  Inside jit, ``jax.device_put(x, jax.memory.Space.Host)`` lowers to an XLA
+  host-memory (``S(5)``) placement the latency-hiding scheduler can stream
+  around.
+- A backend that reports NO separate host space falls back to
+  :class:`HostBuffer` for eager moves — a numpy-resident leaf that carries
+  its logical tier and original sharding so restore is exact (``HostBuffer``
+  is not a ``jax.Array``, so ``jax.live_arrays`` no longer counts it).
 
 ``offloaded_memory_kinds`` reports LOGICAL tier kinds: a leaf in its
-device's default memory reports ``device`` (on CPU the default memory is
-literally named ``unpinned_host`` — normalizing it keeps test and caller
-logic backend-independent), a host-kind ``jax.Array`` or ``HostBuffer``
-reports its host kind.
+device's default memory reports ``device`` whatever the backend names it, a
+host-kind ``jax.Array`` or ``HostBuffer`` reports its host kind.
 """
 
 from __future__ import annotations
@@ -29,12 +25,7 @@ from typing import Any, Dict, Optional, Set, Tuple
 
 import jax
 import numpy as np
-
-try:  # the in-jit memory-kind annotation (jax >= 0.4.35 public behavior;
-    # the old ``jax.memory.Space`` aliases were removed)
-    from jax._src.sharding_impls import TransferToMemoryKind
-except ImportError:  # pragma: no cover - depends on jax version
-    TransferToMemoryKind = None
+from jax.memory import Space
 
 PINNED = "pinned_host"
 UNPINNED = "unpinned_host"
@@ -49,11 +40,8 @@ def _device_kinds(device=None) -> Tuple[str, frozenset]:
     cached = _KIND_CACHE.get(device)
     if cached is not None:
         return cached
-    try:
-        default = device.default_memory().kind
-        kinds = frozenset(m.kind for m in device.addressable_memories())
-    except Exception:  # pragma: no cover - exotic backends
-        default, kinds = "device", frozenset(["device"])
+    default = device.default_memory().kind
+    kinds = frozenset(m.kind for m in device.addressable_memories())
     _KIND_CACHE[device] = (default, kinds)
     return default, kinds
 
@@ -68,8 +56,8 @@ def supports_memory_kind(kind: str, device=None) -> bool:
 
 def host_memory_kind(device=None, pin: bool = True) -> Optional[str]:
     """The host-tier memory kind this backend can actually address, or None
-    when the backend has no separate host space (single-memory backends —
-    the CPU mesh — use the :class:`HostBuffer` fallback instead)."""
+    when the backend has no separate host space (such backends use the
+    :class:`HostBuffer` fallback instead)."""
     default, kinds = _device_kinds(device)
     want = PINNED if pin else UNPINNED
     if want in kinds and want != default:
@@ -85,32 +73,20 @@ def host_memory_kind(device=None, pin: bool = True) -> Optional[str]:
 # --------------------------------------------------------------------------- #
 # in-jit annotations (traced values)
 # --------------------------------------------------------------------------- #
-def _tracing() -> bool:
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - depends on jax version
-        return True
-
-
 def to_host(x, pin: bool = True):
-    """Place a value in host memory: a ``TransferToMemoryKind`` annotation
-    under a trace (XLA host placement), a concrete sharding move eagerly.
-    Identity when the backend has a single memory space."""
-    kind = host_memory_kind(pin=pin)
-    if kind is None or TransferToMemoryKind is None:
-        return x
-    if _tracing():
-        return jax.device_put(x, TransferToMemoryKind(kind))
+    """Place a value in host memory: under a trace the ``Space.Host``
+    annotation (XLA host placement; ``pin`` has no in-jit spelling), on a
+    concrete array a real move to the host memory kind."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.device_put(x, Space.Host)
     return _leaf_to_host(x, pin)
 
 
 def to_device(x):
     """Place a value back into device (HBM) memory — the inverse of
-    :func:`to_host`, identity on single-memory backends."""
-    if TransferToMemoryKind is None or host_memory_kind() is None:
-        return x
-    if _tracing():
-        return jax.device_put(x, TransferToMemoryKind(default_memory_kind()))
+    :func:`to_host`."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.device_put(x, Space.Device)
     return _leaf_to_device(x)
 
 
@@ -171,7 +147,7 @@ def _leaf_to_host(leaf, pin: bool):
         if getattr(sh, "memory_kind", None) == kind:
             return leaf
         return jax.device_put(leaf, sh.with_memory_kind(kind))
-    # single-memory backend: numpy residency, exact-restore metadata
+    # no host memory space: numpy residency, exact-restore metadata
     return HostBuffer(np.asarray(leaf), logical, sharding=leaf.sharding)
 
 

@@ -4,12 +4,7 @@ from .quantization import dequantize_int8, quantize_int8
 from .registry import available_backends, get_op, register, set_backend
 from .rotary import apply_rotary, rope_frequencies
 
-try:  # register the Pallas kernel tier (optional: needs pallas TPU support)
-    from . import pallas  # noqa: F401
-except Exception as _e:  # pragma: no cover
-    from ..utils.logging import logger as _logger
-
-    _logger.warning(f"pallas kernels unavailable: {_e}")
+from . import pallas  # noqa: F401  (registers the Pallas kernel tier)
 
 __all__ = ["attention", "layer_norm", "rms_norm", "available_backends", "get_op",
            "register", "set_backend", "apply_rotary", "rope_frequencies"]

@@ -179,4 +179,13 @@ def attention_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return out.astype(q.dtype)
 
 
-attention = op("attention")
+_attention = op("attention")
+
+
+def attention(q, k, v, *, mask: Optional[jnp.ndarray] = None, **kwargs):
+    """The attention op. A ``mask`` is the XLA implementation's alone (the
+    kernels mask causally and by length natively), so such a call never
+    reaches a kernel — or the layout a kernel needs over a mesh."""
+    if mask is not None:
+        return attention_xla(q, k, v, mask=mask, **kwargs)
+    return _attention(q, k, v, **kwargs)

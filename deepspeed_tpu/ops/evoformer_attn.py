@@ -20,6 +20,8 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from .registry import on_tpu
+
 NEG_INF = -1e30
 
 
@@ -42,7 +44,7 @@ def evoformer_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     *lead, s, r, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = on_tpu()
     if use_kernel:
         from .pallas.flash_attention import flash_attention
 

@@ -4,17 +4,30 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from ..registry import on_tpu
 
 
 def interpret() -> bool:
     """Run kernels through the Pallas interpreter off-TPU (tests select the
-    pallas backend explicitly on the CPU mesh)."""
-    return jax.default_backend() != "tpu"
+    pallas backend explicitly on the CPU mesh). On the chip this is False,
+    so a kernel Mosaic cannot lower fails the compile."""
+    return not on_tpu()
+
+
+def mxu_dot(a, b, dimension_numbers, preferred_element_type=jnp.float32):
+    """``lax.dot_general`` for kernel bodies. The precision is pinned to
+    DEFAULT unless both operands are float32: Mosaic has no bf16 matmul at
+    fp32 contract precision ("Bad lhs type"), and that is what an enclosing
+    ``jax.default_matmul_precision("highest")`` would ask of every dot in
+    its scope. bf16 products are exact on the MXU either way; accumulation
+    stays ``preferred_element_type``."""
+    both_f32 = a.dtype == jnp.float32 and b.dtype == jnp.float32
+    return jax.lax.dot_general(
+        a, b, dimension_numbers,
+        precision=None if both_f32 else jax.lax.Precision.DEFAULT,
+        preferred_element_type=preferred_element_type)
 
 
 def dim_semantics(*sem: str):
@@ -23,14 +36,7 @@ def dim_semantics(*sem: str):
     independent dims marked 'parallel' let Mosaic partition them across
     TensorCores (a no-op on single-core v5e, significant on multi-core
     generations) and relax ordering constraints."""
-    if pltpu is None:
-        return None
-    # renamed TPUCompilerParams -> CompilerParams across jax versions
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    if params_cls is None:  # pragma: no cover
-        return None
-    return params_cls(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def row_block(n_rows: int) -> int:

@@ -44,14 +44,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU builds too, but guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ._common import dim_semantics as _dim_semantics
 from ._common import interpret as _interpret
+from ._common import mxu_dot as _mxu_dot
 
 NEG_INF = -1e30
 
@@ -310,8 +307,8 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv, kv_len, q_offset, nkv,
             q = q_ref[0]                          # [bq, d]
         k = k_ref[0]                              # [bkv, d]
         v = v_ref[0]                              # [bkv, d]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
 
@@ -329,7 +326,7 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv, kv_len, q_offset, nkv,
         p = jnp.exp(s - m_new[:, :1])                          # [g*bq, bkv]
         l_new = l_prev * alpha + jnp.broadcast_to(
             jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -476,8 +473,8 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bkv, kv_len, q_offset, nkv,
         k = k_ref[0]
         v = v_ref[0]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
         if masked:
@@ -487,14 +484,14 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bkv, kv_len, q_offset, nkv,
                           jnp.exp(s - lse), 0.0)              # [g*bq, bkv]
         else:
             p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v, (((1,), (1,)), ((), ())),
+                      preferred_element_type=jnp.float32)
         ds_raw = p * (dp - delta)   # dL/d(logits) — the bias gradient
         if dbias_ref is not None:
             dbias_ref[0] = ds_raw.astype(dbias_ref.dtype)
         ds = (ds_raw * scale).astype(k.dtype)
-        dq_scr[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        dq_scr[...] += _mxu_dot(ds, k, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
 
     split = _mask_split(qi, ki, causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
                         q_offset=q_offset, nkv=nkv, window=window)
@@ -552,8 +549,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bkv, kv_len, q_offset, nq,
         k = k_ref[0]
         v = v_ref[0]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
         if masked:
@@ -563,16 +560,16 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bkv, kv_len, q_offset, nq,
                           jnp.exp(s - lse), 0.0)
         else:
             p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v, (((1,), (1,)), ((), ())),
+                      preferred_element_type=jnp.float32)
         ds = (p * (dp - delta) * scale).astype(q.dtype)
         # contraction over the ROW axis (g*bq): the query-head group's
         # contributions accumulate onto the NARROW dk/dv tile for free
-        dv_scr[...] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                           (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        dv_scr[...] += _mxu_dot(p.astype(do.dtype), do,
+                                (((0,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        dk_scr[...] += _mxu_dot(ds, q, (((0,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
 
     split = _mask_split(qi, ki, causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
                         q_offset=q_offset, nkv=nkv, window=window)
@@ -870,4 +867,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 from ..registry import register  # noqa: E402
 
-register("attention", backend="pallas")(flash_attention)
+# q, k and v carry the batch on their leading dim
+register("attention", backend="pallas", rows=3)(flash_attention)

@@ -73,7 +73,7 @@ def _rms_vjp_bwd(eps, res, dy):
 _rms.defvjp(_rms_vjp_fwd, _rms_vjp_bwd)
 
 
-@register("rms_norm", backend="pallas")
+@register("rms_norm", backend="pallas", rows=1)
 def rms_norm_pallas(x: jnp.ndarray, weight: jnp.ndarray,
                     eps: float = 1e-6) -> jnp.ndarray:
     d = x.shape[-1]
@@ -145,7 +145,7 @@ def _ln_vjp_bwd(eps, res, dy):
 _ln.defvjp(_ln_vjp_fwd, _ln_vjp_bwd)
 
 
-@register("layer_norm", backend="pallas")
+@register("layer_norm", backend="pallas", rows=1)
 def layer_norm_pallas(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
                       eps: float = 1e-5) -> jnp.ndarray:
     if bias is None:

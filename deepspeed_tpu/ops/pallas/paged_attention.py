@@ -43,14 +43,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ._common import dim_semantics as _dim_semantics
 from ._common import interpret as _interpret
+from ._common import mxu_dot as _mxu_dot
 
 NEG_INF = -1e30
 
@@ -116,8 +113,8 @@ def _decode_kernel(*refs, bs, scale, nblk, gpad, has_window, quant=False):
         else:
             k = k_ref[...]                 # [bs, hd]
             v = v_ref[...]                 # [bs, hd]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = pos < ctx
         if has_window:
@@ -131,7 +128,7 @@ def _decode_kernel(*refs, bs, scale, nblk, gpad, has_window, quant=False):
         p = jnp.exp(s - m_new[:, :1])
         l_scr[...] = l_prev * alpha + jnp.broadcast_to(
             jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -358,8 +355,8 @@ def _spec_verify_kernel(*refs, bs, scale, nblk, t, rpad, has_window,
         else:
             k = k_ref[...]                 # [bs, hd]
             v = v_ref[...]                 # [bs, hd]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         ti = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, s.shape, 0),
                          t)
@@ -375,7 +372,7 @@ def _spec_verify_kernel(*refs, bs, scale, nblk, t, rpad, has_window,
         p = jnp.exp(s - m_new[:, :1])
         l_scr[...] = l_prev * alpha + jnp.broadcast_to(
             jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new

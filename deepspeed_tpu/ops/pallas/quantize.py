@@ -41,7 +41,7 @@ def _dequant_kernel(q_ref, s_ref, o_ref):
                   * s_ref[...][:, :1]).astype(o_ref.dtype)
 
 
-@register("quantize_int8", backend="pallas")
+@register("quantize_int8", backend="pallas", rows=1)
 def quantize_int8_pallas(x: jnp.ndarray, group_size: int = 2048):
     """x: any shape with size % group_size == 0 →
     (int8 values same shape, fp32 scales [n_groups])."""
@@ -63,7 +63,7 @@ def quantize_int8_pallas(x: jnp.ndarray, group_size: int = 2048):
     return q[:n].reshape(shape), s[:n, 0]
 
 
-@register("dequantize_int8", backend="pallas")
+@register("dequantize_int8", backend="pallas", rows=2)
 def dequantize_int8_pallas(q: jnp.ndarray, scales: jnp.ndarray,
                            group_size: int = 2048, dtype=jnp.float32):
     shape = q.shape

@@ -24,14 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ._common import dim_semantics as _dim_semantics
 from ._common import interpret as _interpret
+from ._common import mxu_dot as _mxu_dot
 
 NEG_INF = -1e30
 
@@ -56,8 +53,8 @@ def _sparse_fwd_kernel(idx_ref, cnt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         if causal:
             # intra-block causal masking on the diagonal block
             q_idx = qi * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
@@ -71,7 +68,7 @@ def _sparse_fwd_kernel(idx_ref, cnt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         p = jnp.exp(s - m_new[:, :1])
         l_scr[...] = l_prev * alpha + jnp.broadcast_to(
             jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -102,8 +99,8 @@ def _sparse_dq_kernel(idx_ref, cnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         do = do_ref[0]
         lse = lse_ref[0][:, :1]
         delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse)
         if causal:
             # only the diagonal block needs intra-block masking (off-diagonal
@@ -112,11 +109,11 @@ def _sparse_dq_kernel(idx_ref, cnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             q_idx = qi * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
             kv_idx = ki * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
             p = jnp.where(kv_idx <= q_idx, p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v, (((1,), (1,)), ((), ())),
+                      preferred_element_type=jnp.float32)
         ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dq_scr[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        dq_scr[...] += _mxu_dot(ds, k, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
 
     @pl.when(j == max_a - 1)
     def _finish():
@@ -145,21 +142,21 @@ def _sparse_dkv_kernel(idx_ref, cnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         do = do_ref[0]
         lse = lse_ref[0][:, :1]
         delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+                     preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse)
         if causal:
             q_idx = qi * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
             kv_idx = ki * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
             p = jnp.where(kv_idx <= q_idx, p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v, (((1,), (1,)), ((), ())),
+                      preferred_element_type=jnp.float32)
         ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dv_scr[...] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                           (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        dv_scr[...] += _mxu_dot(p.astype(do.dtype), do,
+                                (((0,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        dk_scr[...] += _mxu_dot(ds, q, (((0,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
 
     @pl.when(i == max_a - 1)
     def _finish():

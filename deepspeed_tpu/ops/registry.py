@@ -6,7 +6,10 @@ the same role is: each logical op (attention, rms_norm, rotary, quantize,
 optimizer updates, ...) has one or more *implementations* — a pure-XLA
 reference implementation (always available, differentiable, any backend) and
 optionally a Pallas kernel (TPU) or a C++ XLA custom call. Selection order:
-explicit override > pallas-on-TPU > xla.
+explicit override > pallas-on-TPU > xla. The platform decides and nothing
+else does: on the chip a kernel that cannot lower raises at compile time, and
+one that has no layout for the mesh its program spans raises at trace time —
+no implementation is ever swapped in behind either.
 
 Usage::
 
@@ -19,12 +22,15 @@ Usage::
 from __future__ import annotations
 
 import functools
+import math
 import os
-from typing import Any, Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
+import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from ..utils.logging import logger
+from ..comm.mesh import BATCH_AXES  # axis names only; no mesh state is read
 
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 _OVERRIDES: Dict[str, str] = {}
@@ -32,12 +38,70 @@ _OVERRIDES: Dict[str, str] = {}
 _PREFERENCE = ("native", "pallas", "xla")
 
 
-def register(name: str, backend: str = "xla") -> Callable[[Callable], Callable]:
+def register(name: str, backend: str = "xla",
+             rows: Optional[int] = None) -> Callable[[Callable], Callable]:
+    """``rows`` is a Pallas kernel's one layout over a mesh: its first
+    ``rows`` positional arguments and every result are arrays whose leading
+    dimension is independent rows (the batch); the rest is whole on every
+    device. See :func:`_per_device`."""
     def deco(fn: Callable) -> Callable:
-        _REGISTRY.setdefault(name, {})[backend] = fn
+        _REGISTRY.setdefault(name, {})[backend] = \
+            _per_device(name, fn, rows) if backend == "pallas" else fn
         return fn
 
     return deco
+
+
+def _per_device(name: str, kernel: Callable, rows: Optional[int]) -> Callable:
+    """A Pallas kernel is one device's program: Mosaic refuses to lower one
+    the SPMD partitioner would have to split ("cannot be automatically
+    partitioned"). So where the program being traced spans a mesh
+    (``MeshManager.activate`` is what tells the trace), the kernel runs under
+    a ``shard_map`` with its rows over the data-parallel axes — the layout
+    every model family gives the batch — and with no ``rows``, with mesh axes
+    this layout does not cover, or inside a region that is manual over only
+    some axes (Mosaic refuses a kernel there whatever wraps it), it RAISES.
+    A trace with no mesh context (the inference engines) calls the kernel as
+    is, and over several devices Mosaic's own refusal is the error."""
+
+    @functools.wraps(kernel)
+    def call(*args, **kwargs):
+        mesh = jax.sharding.get_abstract_mesh()  # of the trace in progress
+        auto = {a: n for a, n in mesh.shape.items()
+                if n > 1 and a not in mesh.manual_axes}
+        if not auto:  # one device, or the caller's own manual region
+            return kernel(*args, **kwargs)
+        batch = tuple(a for a in BATCH_AXES if a in auto)
+        is_array = [isinstance(a, (jax.Array, np.ndarray)) for a in args]
+        if (rows is None or mesh.manual_axes or len(batch) < len(auto)
+                or any(isinstance(v, (jax.Array, np.ndarray))
+                       for v in kwargs.values())
+                or any(a.shape[0] % math.prod(auto.values())
+                       for a in args[:rows])):
+            layout = "none yet" if rows is None else (
+                f"the leading dim of its first {rows} arguments over "
+                f"{BATCH_AXES}, keyword arguments static, not from inside "
+                f"a partly manual region")
+            raise NotImplementedError(
+                f"op '{name}': the Pallas kernel is a per-device program "
+                f"and cannot run on these arguments in a program over mesh "
+                f"axes {auto} (its layout: {layout}). The XLA reference is "
+                f"an explicit choice: set_backend('{name}', 'xla') or "
+                f"DSTPU_OP_{name.upper()}=xla")
+
+        def body(*arrays):
+            it = iter(arrays)
+            return kernel(*(next(it) if arr else a
+                            for a, arr in zip(args, is_array)), **kwargs)
+
+        by_rows, whole = P(batch), P()
+        return jax.shard_map(
+            body, out_specs=by_rows, check_vma=False,
+            in_specs=tuple(by_rows if i < rows else whole
+                           for i, arr in enumerate(is_array) if arr),
+        )(*(a for a, arr in zip(args, is_array) if arr))
+
+    return call
 
 
 def set_backend(name: str, backend: Optional[str]) -> None:
@@ -48,18 +112,18 @@ def set_backend(name: str, backend: Optional[str]) -> None:
         _OVERRIDES[name] = backend
 
 
-def _platform() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+def on_tpu() -> bool:
+    """THE platform test of the op tier (kernel selection, interpret mode).
+    A backend that fails to initialize raises here; it is never read as
+    'cpu'."""
+    return jax.default_backend() == "tpu"
 
 
 def available_backends(name: str) -> Dict[str, Callable]:
     return dict(_REGISTRY.get(name, {}))
 
 
-def get_op(name: str) -> Callable:
+def _resolve(name: str) -> Tuple[str, Callable]:
     impls = _REGISTRY.get(name)
     if not impls:
         raise KeyError(f"no implementations registered for op '{name}'")
@@ -68,15 +132,21 @@ def get_op(name: str) -> Callable:
         if override not in impls:
             raise KeyError(f"op '{name}' has no '{override}' implementation "
                            f"(available: {list(impls)})")
-        return impls[override]
-    on_tpu = _platform() == "tpu"
-    for backend in _PREFERENCE:
+        return override, impls[override]
+    for backend in (_PREFERENCE if on_tpu() else ("xla",)):
         if backend in impls:
-            if backend in ("pallas", "native") and not on_tpu:
-                continue
-            return impls[backend]
-    # fall back to anything (e.g. pallas-in-interpret-mode registered as such)
-    return next(iter(impls.values()))
+            return backend, impls[backend]
+    raise KeyError(f"op '{name}' has no implementation for platform "
+                   f"{jax.default_backend()!r} (registered: {list(impls)})")
+
+
+def get_op(name: str) -> Callable:
+    return _resolve(name)[1]
+
+
+def resolved() -> Dict[str, str]:
+    """op -> the backend a call resolves to right now, for every op."""
+    return {name: _resolve(name)[0] for name in sorted(_REGISTRY)}
 
 
 def op(name: str) -> Callable:

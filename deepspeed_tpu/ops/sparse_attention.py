@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .attention import attention
+from .registry import on_tpu
 
 
 def sliding_window_layout(num_blocks: int, window_blocks: int = 3,
@@ -99,7 +100,7 @@ def blocksparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # undefined — and the kernel fwd / dense bwd would disagree about it)
     compact_layout(layout, causal)
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = on_tpu()
     if not use_kernel:
         return _dense_masked(q, k, v, layout, block_size, causal, scale)
     lay = np.asarray(layout, bool)

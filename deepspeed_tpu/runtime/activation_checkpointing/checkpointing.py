@@ -199,7 +199,7 @@ class CheckpointFunction:
 
 
 def saved_bytes(function: Callable, *args,
-                policy: Optional[str] = None) -> Optional[int]:
+                policy: Optional[str] = None) -> int:
     """Total bytes of NON-ARGUMENT residuals the backward of ``function``
     keeps alive under the named ``policy`` — the trace-time, exact
     measurement behind the HBM-vs-step-time sweep (``bench.py`` remat sweep,
@@ -207,16 +207,10 @@ def saved_bytes(function: Callable, *args,
     tests: ``none`` (no remat) saves every needed intermediate,
     ``save_big_matmuls`` ⊇ ``save_attn_out``, ``full`` saves nothing.
 
-    ``policy=None``/``"none"`` measures the un-rematerialized function.
-    Returns None when jax's saved-residuals introspection is unavailable
-    (the sweep then falls back to allocator stats)."""
-    try:
-        from jax.ad_checkpoint import saved_residuals  # newer jax
-    except ImportError:
-        try:
-            from jax._src.ad_checkpoint import saved_residuals
-        except ImportError:  # pragma: no cover - depends on jax version
-            return None
+    ``policy=None``/``"none"`` measures the un-rematerialized function."""
+    # the installed jax (0.9.0) has no public spelling of this introspection
+    from jax._src.ad_checkpoint import saved_residuals
+
     wrapped = function
     if policy not in (None, "none"):
         wrapped = jax.checkpoint(function, policy=get_policy(policy))

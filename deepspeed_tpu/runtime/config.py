@@ -640,10 +640,6 @@ class DeepSpeedTPUConfig:
     memory_breakdown: bool = False
     sequence_parallel_size: int = 1
     seed: int = 42
-    # persistent XLA compilation cache dir: re-runs skip the multi-minute
-    # TPU compiles. None -> fall back to $DSTPU_COMPILE_CACHE; "" -> cache
-    # explicitly OFF even if the env var is set
-    compile_cache_dir: Optional[str] = None
     communication_data_type: Optional[str] = None
     gradient_accumulation_dtype: Optional[str] = None
     data_efficiency: Dict[str, Any] = field(default_factory=dict)
@@ -720,7 +716,7 @@ _SCALAR_KEYS = [
     "gradient_clipping", "prescale_gradients", "gradient_predivide_factor",
     "steps_per_print", "wall_clock_breakdown", "memory_breakdown",
     "sequence_parallel_size", "seed", "communication_data_type",
-    "gradient_accumulation_dtype", "compile_cache_dir",
+    "gradient_accumulation_dtype",
 ]
 
 _DICT_KEYS = ["data_efficiency", "compression_training", "elasticity", "autotuning"]

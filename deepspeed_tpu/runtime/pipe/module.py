@@ -170,8 +170,8 @@ def pipeline_apply(block_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         return psum_f32(outputs, pipe_axis)
 
     # FULLY manual region (axis_names=None): partial-manual (manual over
-    # 'pipe' only, auto= on 0.4-era jax) fatally CHECK-fails XLA's SPMD
-    # partitioner on every ppermute in this jax/XLA version
+    # 'pipe' only) fatally CHECK-fails XLA's SPMD partitioner on every
+    # ppermute
     # ("target.IsManualSubgroup() == sharding().IsManualSubgroup()"), and
     # lax.axis_index lowers to an unpartitionable PartitionId there — the
     # pipeline schedule never compiled. Fully manual, P() inputs replicate

@@ -16,7 +16,7 @@ from .fleet import (FleetMetricsAggregator, FleetObsConfig,  # noqa: F401
                     FleetObservability, TenantSLOAccountant, TraceContext,
                     tenant_slug)
 from .compile import (CompileMonitor, CompileMonitorConfig,  # noqa: F401
-                      RecompileBudgetExceeded, peak_flops_per_chip)
+                      RecompileBudgetExceeded, peak_flops_total)
 from .hub import TelemetryHub  # noqa: F401
 from .memory import MemoryTelemetry  # noqa: F401
 from .metrics_server import MetricsServer  # noqa: F401

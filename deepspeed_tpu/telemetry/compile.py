@@ -40,10 +40,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 from ..utils.logging import logger
+from ..utils.peaks import UnknownDevice, device_peaks
 from .trace import NULL_TRACER
 
 __all__ = ["CompileMonitorConfig", "CompileMonitor", "MonitoredFunction",
-           "ProgramStats", "RecompileBudgetExceeded", "peak_flops_per_chip"]
+           "ProgramStats", "RecompileBudgetExceeded", "peak_flops_total"]
 
 Event = Tuple[str, float, int]
 
@@ -95,23 +96,15 @@ class ProgramStats:
     signatures: List[Any] = field(default_factory=list)
 
 
-def peak_flops_per_chip() -> float:
-    """bf16 peak flops of the local accelerator (mirrors ``bench.py``; CPU
-    gets the same 2e12 smoke-run placeholder so CPU-run MFU gauges stay
-    finite and comparable across runs)."""
+def peak_flops_total() -> Optional[float]:
+    """bf16 peak FLOP/s of every local chip together, from the one published
+    table (``utils/peaks.py``) — or None on a device the table does not
+    list, where the MFU gauges are then ABSENT (a utilization against an
+    assumed peak is not a measurement)."""
     try:
-        kind = getattr(jax.devices()[0], "device_kind", "").lower()
-    except Exception:
-        return 2e12
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v6" in kind or "trillium" in kind:
-        return 918e12
-    return 2e12
+        return device_peaks().bf16_flops * max(1, jax.device_count())
+    except UnknownDevice:
+        return None
 
 
 def _sharding_signature(x: jax.Array) -> str:
@@ -383,7 +376,7 @@ class CompileMonitor:
             return []
         now = time.monotonic()
         events: List[Event] = []
-        peak_total = peak_flops_per_chip() * max(1, jax.device_count())
+        peak_total = peak_flops_total()
         gkey = group if group is not None else ""
         with self._lock:
             last = self._last_drain.get(gkey)
@@ -422,7 +415,8 @@ class CompileMonitor:
                 if st.cost_bytes > 0:
                     events.append((f"Compile/{name}/cost_bytes",
                                    st.cost_bytes, step))
-                if st.cost_flops > 0 and st.calls_since_drain > 0:
+                if peak_total and st.cost_flops > 0 \
+                        and st.calls_since_drain > 0:
                     mfu = (st.cost_flops * st.calls_since_drain
                            / (window * peak_total))
                     events.append((f"{st.group}/mfu/{name}", mfu, step))

@@ -33,7 +33,7 @@ from ..utils.timer import (BACKWARD_GLOBAL_TIMER, BACKWARD_MICRO_TIMER,
                            STEP_GLOBAL_TIMER, STEP_MICRO_TIMER,
                            TRAIN_BATCH_TIMER, SynchronizedWallClockTimer)
 from .anomaly import AnomalyDetector
-from .compile import CompileMonitor, peak_flops_per_chip
+from .compile import CompileMonitor, peak_flops_total
 from .memory import MemoryTelemetry
 from .profiler import ProfilerSession
 from .trace import Tracer
@@ -258,9 +258,8 @@ class TelemetryHub:
         if self.tput_timer is not None and \
                 getattr(self.tput_timer, "flops_per_step", None):
             tf = self.tput_timer.avg_tflops_per_sec()
-            if tf > 0:
-                peak_total = peak_flops_per_chip() * \
-                    max(1, jax.device_count())
+            peak_total = peak_flops_total()
+            if tf > 0 and peak_total:
                 events.append(("Train/mfu/headline",
                                tf * 1e12 / peak_total, step))
         for n, v, _ in events:

@@ -1,8 +1,8 @@
 """Pull-based metrics endpoint: Prometheus text format over stdlib HTTP.
 
-The monitor backends PUSH events to files/SDKs; external watchers (a
-``tpu_watch.sh``-style prober, a fleet dashboard, ``curl`` during an
-incident) want to PULL live state instead. :class:`MetricsServer` serves the
+The monitor backends PUSH events to files/SDKs; external observers (a
+prober, a fleet dashboard, ``curl`` during an incident) want to PULL live
+state instead. :class:`MetricsServer` serves the
 TelemetryHub's counters and gauges — ``Reliability/*`` and ``Anomaly/*``
 counts, ``Serving/*`` gauges (prefix-cache counters, latency SLO
 percentiles), per-program ``Compile/*`` counters and MFU-attribution gauges

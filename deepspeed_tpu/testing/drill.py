@@ -12,7 +12,7 @@ loss trajectory equals the uninterrupted one to ``tol`` at every step. Each
 phase is one (topology, stage, tier) combination, so a 3-phase drill covers
 3 matrix cells.
 
-Also runnable standalone (the ``tpu_watch.sh`` non-fatal ELASTIC row)::
+Also runnable standalone::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python -m deepspeed_tpu.testing.drill
@@ -504,8 +504,8 @@ def sdc_drill(workdir: str, sites: Sequence[str] = ("grad", "param",
 
 
 def main(argv=None) -> int:
-    """Standalone entry (the ``tpu_watch.sh`` ELASTIC and SDC rows): run a
-    drill on a temp dir and print a one-line verdict."""
+    """Standalone entry: run a drill on a temp dir and print a one-line
+    verdict."""
     import argparse
     import json
     import tempfile

@@ -29,9 +29,6 @@ def main():
     args = ap.parse_args()
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
 

@@ -21,9 +21,6 @@ def main():
     args = ap.parse_args()
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from deepspeed_tpu.inference.engine_v2 import (build_engine_v2,

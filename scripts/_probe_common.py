@@ -1,14 +1,9 @@
-"""Shared finalizer for the TPU probe scripts (ONE failure-detection rule).
-
-Round-4 lesson (VERDICT item 4): failed subprobes shipped inside ok-looking
-captures because each consumer scanned for failure strings its own way. Now
-every probe computes ``detail.ok`` itself via this one rule, and the
-watcher's promote() trusts ONLY that flag.
+"""Shared finalizer for the probe scripts (ONE failure-detection rule): every
+probe computes ``detail.ok`` itself via this rule, prints its one JSON line,
+and exits non-zero when the flag is false.
 """
 
 import json
-import signal
-import sys
 
 
 # Structured failure markers (ADVICE r5): a failure row must START with one
@@ -35,8 +30,9 @@ def _bad(v, key=None) -> bool:
     return False
 
 
-def finalize(result: dict, ok=None) -> None:
-    """Set ``detail.ok`` and print the one stdout JSON line.
+def finalize(result: dict, ok=None) -> int:
+    """Set ``detail.ok``, print the one stdout JSON line, and return the
+    process exit code (0 only when ok).
 
     ``ok=None`` (the default rule): False if any nested detail value carries
     a STRUCTURED failure marker — a string starting with ``error:`` /
@@ -49,16 +45,4 @@ def finalize(result: dict, ok=None) -> None:
     result["detail"]["ok"] = (not _bad(result["detail"])) if ok is None \
         else bool(ok)
     print(json.dumps(result), flush=True)
-
-
-def install_term_handler(result: dict) -> None:
-    """Emit the partial RESULT (ok=false) on SIGTERM so a watcher-timeout
-    kill still leaves a valid, promotion-rejected artifact instead of an
-    empty file (round 4: 'the gate produced nothing')."""
-
-    def on_term(signum, frame):
-        result["detail"]["interrupted"] = "SIGTERM (watcher timeout)"
-        finalize(result, ok=False)
-        sys.exit(0)
-
-    signal.signal(signal.SIGTERM, on_term)
+    return 0 if result["detail"]["ok"] else 1

@@ -7,8 +7,7 @@ durable-save manifest (per-file SHA-256 + byte size, written at seal time by
 ``runtime/checkpoint/manifest.py``) — the at-rest half of the SDC story: the
 in-flight fingerprint plane catches corruption between replicas, this tool
 catches bit rot / torn copies / tampering AFTER the bytes hit disk, e.g. on
-a cron next to ``tpu_watch.sh`` (its non-fatal SCRUB row) or before
-promoting a checkpoint across clusters.
+a cron or before promoting a checkpoint across clusters.
 
 Per tag it prints one verdict row::
 

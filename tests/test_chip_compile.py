@@ -1,0 +1,350 @@
+"""Compile — not just lower — for a described TPU v5e, without the chip.
+
+The TPU compiler is installed here and compiles for a ``v5e:2x2`` that is
+described, not attached (``jax.experimental.topologies``): Mosaic refuses a
+misaligned slice or too much VMEM, XLA refuses a program that does not fit
+16 GB, and ``compiled.as_text()`` shows whether the kernel is really in the
+program (``tpu_custom_call``). Nothing runs, so this says nothing about
+results or times. The cases are the kernels of the two main paths at the
+widths ``chip_smoke.py`` uses (Mistral-7B: 32 query / 8 KV heads of 128,
+hidden 4096) and its serving pool geometry.
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so the
+tests steer the one platform test of the op tier (``registry.on_tpu``).
+"""
+
+import dataclasses
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+import chip_smoke
+
+MOSAIC = chip_smoke.MOSAIC
+_CFG, _SERVE = chip_smoke.mistral_7b(2), chip_smoke.ServeSize()
+NQ, NKV, HD, HIDDEN = (_CFG.num_heads, _CFG.num_kv_heads, _CFG.head_size,
+                       _CFG.hidden_size)            # Mistral-7B widths
+SLOTS, POOL, BS = _SERVE.slots, _SERVE.pool_blocks, _SERVE.block_size
+MAX_BLOCKS = _CFG.max_seq_len // BS
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _as_on_the_chip(monkeypatch):
+    """Kernels lower through Mosaic and the registry prefers Pallas, as on
+    the chip; the persistent compile cache is off, because an executable
+    compiled for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import _common
+
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    monkeypatch.setattr(_common, "on_tpu", lambda: True)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes, device):
+    """``shapes``: (shape, dtype) pairs, placed on one described chip."""
+    sh = SingleDeviceSharding(device)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _flash(**kw):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    return lambda q, k, v: flash_attention(q, k, v, causal=True, **kw)
+
+
+def _flash_under_highest(q, k, v):
+    # bf16 operands at fp32 contract precision are what Mosaic refuses; the
+    # kernels pin their own precision so a caller's scope cannot ask for it
+    with jax.default_matmul_precision("highest"):
+        return _flash()(q, k, v)
+
+
+def _flash_grad(**kw):
+    f = _flash(**kw)
+
+    def loss(q, k, v):
+        return jnp.sum(f(q, k, v).astype(jnp.float32) ** 2)
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _qkv(b, sq, skv=None):
+    bf = jnp.bfloat16
+    skv = skv or sq
+    return (((b, sq, NQ, HD), bf), ((b, skv, NKV, HD), bf),
+            ((b, skv, NKV, HD), bf))
+
+
+def _paged(**kw):
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    return lambda q, kp, vp, bt, cl, *scales: pa.paged_decode_attention(
+        q, kp, vp, bt, cl, **kw,
+        **(dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}))
+
+
+def _pool_args(q_shape, pool_dtype=jnp.bfloat16):
+    pool = ((POOL, NKV, BS, HD), pool_dtype)
+    return ((q_shape, jnp.bfloat16), pool, pool,
+            ((SLOTS, MAX_BLOCKS), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+def _spec_verify(q, kp, vp, bt, cl):
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    return pa.paged_spec_verify_attention(q, kp, vp, bt, cl)
+
+
+def _rms_norm(x, w):
+    from deepspeed_tpu.ops.pallas.norms import rms_norm_pallas
+
+    return rms_norm_pallas(x, w)
+
+
+def _quantize(x):
+    from deepspeed_tpu.ops.pallas.quantize import quantize_int8_pallas
+
+    return quantize_int8_pallas(x, group_size=HD)
+
+
+_SCALES = (((POOL, NKV, BS, 1), jnp.float32),) * 2
+KERNEL_CASES = {
+    "flash_fwd_s2048": (_flash(), _qkv(2, 2048)),
+    "flash_fwd_under_highest_precision": (_flash_under_highest,
+                                          _qkv(2, 2048)),
+    "flash_fwd_bwd_s2048": (_flash_grad(), _qkv(2, 2048)),
+    "flash_fwd_bwd_s8192": (_flash_grad(), _qkv(1, 8192)),
+    "flash_windowed_fwd_bwd": (_flash_grad(window=4096), _qkv(1, 8192)),
+    "flash_q512_on_kv4096_offset": (_flash(q_offset=3584),
+                                    _qkv(1, 512, 4096)),
+    "paged_decode": (_paged(), _pool_args((SLOTS, NQ, HD))),
+    "paged_decode_windowed": (_paged(window=4096),
+                              _pool_args((SLOTS, NQ, HD))),
+    "paged_decode_int8_kv": (_paged(),
+                             _pool_args((SLOTS, NQ, HD), jnp.int8) + _SCALES),
+    "spec_verify_t4": (_spec_verify, _pool_args((SLOTS, 4, NQ, HD))),
+    "rms_norm_fwd_h4096": (_rms_norm, (((2 * 2048, HIDDEN), jnp.bfloat16),
+                                       ((HIDDEN,), jnp.bfloat16))),
+    "int8_group_quantize": (_quantize, (((2048 * HIDDEN,), jnp.bfloat16),)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_v5e(v5e, case):
+    fn, shapes = KERNEL_CASES[case]
+    compiled = _compile(fn, *shapes, device=v5e.devices[0])
+    assert MOSAIC in compiled.as_text(), \
+        f"{case}: compiled without a Mosaic kernel"
+
+
+def _sq_sum_grad(fn, argnums):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+                    argnums=argnums)
+
+
+def _attention_op(q, k, v):
+    from deepspeed_tpu.ops import attention
+
+    return attention(q, k, v, causal=True)
+
+
+def _rms_norm_op(x, w):
+    from deepspeed_tpu.ops import rms_norm
+
+    return rms_norm(x, w, 1e-5)
+
+
+def _quantize_op(x):
+    from deepspeed_tpu.ops import quantize_int8
+
+    return quantize_int8(x, HD)[0]
+
+
+# op as the models call it -> (function, shapes, how many lead with the batch)
+FOUR_CHIP_CASES = {
+    "attention_fwd_bwd": (_sq_sum_grad(_attention_op, (0, 1, 2)),
+                          _qkv(4, 2048), 3),
+    "rms_norm_fwd_bwd": (_sq_sum_grad(_rms_norm_op, (0, 1)),
+                         (((4, 2048, HIDDEN), jnp.bfloat16),
+                          ((HIDDEN,), jnp.bfloat16)), 1),
+    "int8_group_quantize": (_quantize_op,
+                            (((4 * 2048, HIDDEN), jnp.bfloat16),), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOUR_CHIP_CASES))
+def test_pallas_op_compiles_in_a_program_over_four_chips(v5e, case):
+    """A Mosaic kernel under a multi-device jit does not lower ("cannot be
+    automatically partitioned") — which is what ZeRO-3 over ``data=4`` hit.
+    The registry runs the kernel per device (``registry._per_device``): the
+    op is still the kernel, and batch-sharded in, batch-sharded out, it
+    moves nothing between the chips."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+
+    fn, shapes, rows = FOUR_CHIP_CASES[case]
+    mm = mesh_lib.MeshManager.create({"data": 4}, devices=v5e.devices)
+    args = [jax.ShapeDtypeStruct(
+        s, d, sharding=mm.sharding(mesh_lib.BATCH_AXES) if i < rows
+        else mm.replicated()) for i, (s, d) in enumerate(shapes)]
+    with mm.activate():
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert MOSAIC in hlo
+    assert " all-gather(" not in hlo
+    # only a replicated weight's gradient is summed over the chips
+    assert hlo.count(" all-reduce(") == (case == "rms_norm_fwd_bwd")
+
+
+def test_kernel_with_no_mesh_in_its_trace_does_not_lower_over_four_chips(v5e):
+    """The inference engines trace with no mesh context, so the registry
+    calls the paged kernel as is; over several chips Mosaic itself refuses —
+    an error, never the gather reference under the kernel's name."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import registry
+
+    mm = mesh_lib.MeshManager.create({"data": 4}, devices=v5e.devices)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=mm.replicated())
+            for s, d in _pool_args((SLOTS, NQ, HD))]
+    assert registry.resolved()["paged_decode_attention"] == "pallas"
+    with pytest.raises(NotImplementedError, match="automatically partition"):
+        jax.jit(registry.get_op("paged_decode_attention")).lower(*args)
+
+
+def test_in_jit_host_offload_is_not_an_identity(v5e):
+    """``memory.placement.to_host`` under a trace must put the value in host
+    memory: every in-jit offload path (ZeRO-Offload state, FPDT host KV, the
+    KV spill) routes through it, and an annotation that silently does nothing
+    leaves them all running in HBM."""
+    from deepspeed_tpu.memory import placement
+
+    def f(x):
+        parked = placement.to_host(x * 2)
+        return placement.to_device(parked) + 1, parked
+
+    compiled = _compile(f, ((1024, 1024), jnp.float32),
+                        device=v5e.devices[0])
+    mem = compiled.memory_analysis()
+    assert mem.host_output_size_in_bytes == 1024 * 1024 * 4, mem
+    assert "S(5)" in compiled.as_text()  # XLA's host memory space
+
+
+# --------------------------------------------------------------------------- #
+# whole programs at full width: the engines' own step functions, built here on
+# CPU devices and handed the described chips (15 s and more each: slow lane)
+# --------------------------------------------------------------------------- #
+def _on_described_chips(tree, mesh_mgr, devices):
+    """Point ``mesh_mgr`` at ``devices`` and return ``tree`` as shapes with
+    the same partition specs on the new mesh."""
+    old = mesh_mgr.mesh
+    mesh_mgr.mesh = Mesh(np.asarray(devices).reshape(old.devices.shape),
+                         old.axis_names)
+
+    def abstract(x):
+        spec = getattr(x.sharding, "spec", jax.sharding.PartitionSpec())
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh_mgr.mesh, spec))
+
+    return jax.tree.map(abstract, tree)
+
+
+def compile_train_step_for(devices, cfg, batch, seq, cpu_devices):
+    """The trainer's real step function (``chip_smoke`` config), compiled for
+    ``devices``. The engine is built on ``cpu_devices`` (same count), then its
+    mesh and cached sharding trees are re-pointed at the described chips."""
+    from deepspeed_tpu.comm import overlap
+
+    engine = chip_smoke.build_trainer(cfg, batch, seed=0,
+                                      devices=cpu_devices)
+    tokens = engine._shard_batch(
+        {"tokens": np.zeros((batch, seq + 1), np.int32)}, with_gas_dim=True)
+    state, tokens = _on_described_chips((engine.state, tokens),
+                                        engine.mesh_mgr, devices)
+    p = engine.partitioner
+    engine._param_shardings = p.shardings(engine.param_specs)
+    engine._grad_shardings = p.shardings(engine.grad_specs)
+    engine._master_shardings = p.shardings(engine.opt_param_specs)
+    if overlap._SCAN_SLICE["shardings"] is not None:  # ZeRO-3 over >1 chip
+        overlap.configure_scan_slice_layout(
+            engine._layer_prefetch_shardings())
+    # the engine's OWN program, lowered as a first train_batch lowers it: no
+    # mesh context from here (one was once added here, and the rehearsal
+    # then passed where the chip failed)
+    return engine._build_train_step().lower(
+        state, tokens, engine._lr_override).compile()
+
+
+@pytest.mark.slow
+def test_train_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
+    size = chip_smoke.TrainSize()
+    cfg = chip_smoke.mistral_7b(size.layers, remat=True)
+    compiled = compile_train_step_for(
+        v5e.devices[:1], cfg, size.batch, size.seq, jax.devices()[:1])
+    assert compiled.as_text().count(MOSAIC) > 0
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live < 16 * 10 ** 9, mem
+
+
+@pytest.mark.slow
+def test_zero3_step_over_four_chips_keeps_every_kernel(v5e):
+    """ZeRO-3 over ``data=4`` as ``chip_smoke.py --chips 4`` runs it: the step
+    compiles, and holds as many Mosaic kernels as the one-chip step (an op
+    that ran its XLA reference over the mesh would be missing)."""
+    size = chip_smoke.TrainSize()
+    cfg = chip_smoke.mistral_7b(size.layers, remat=True)
+    mosaic = [compile_train_step_for(
+        v5e.devices[:n], cfg, size.batch, size.seq,
+        jax.devices()[:n]).as_text().count(MOSAIC) for n in (4, 1)]
+    assert mosaic[0] == mosaic[1] > 0, mosaic
+
+
+@pytest.mark.slow
+def test_decode_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
+    """The server's decode program over the real pool geometry (depth 2: the
+    layer scan makes the program the same at any depth)."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.inference.sampling import SamplingParams
+
+    # one device, as on the chip: the paged kernels have no layout over a
+    # mesh yet, and over several devices they do not lower
+    mesh_lib.init_mesh({"data": 1}, devices=jax.devices()[:1])
+    size = dataclasses.replace(chip_smoke.ServeSize(), layers=2)
+    eng = chip_smoke.build_server(chip_smoke.mistral_7b(size.layers), size,
+                                  seed=0)
+    args = (eng.params, eng.cache, jnp.asarray(eng._slot_tokens),
+            jnp.asarray(eng._slot_lens), jnp.asarray(eng._slot_tables),
+            jnp.asarray(eng._slot_active), jax.random.PRNGKey(0))
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), args)
+    decode = eng._decode_fn(SamplingParams(greedy=True))
+    compiled = decode._jitted.lower(*args).compile()
+    assert MOSAIC in compiled.as_text()

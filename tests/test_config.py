@@ -2,6 +2,7 @@
 ``tests/unit/runtime/test_ds_config_dict.py``)."""
 
 import json
+import os
 
 import pytest
 
@@ -100,42 +101,39 @@ def test_mesh_axis_sizes():
     assert sizes == {"data": 2, "expert": 1, "pipe": 1, "seq": 2, "tensor": 2}
 
 
-def test_compile_cache_dir_config(tmp_path, devices8, monkeypatch):
-    """config.compile_cache_dir / DSTPU_COMPILE_CACHE turn on the persistent
-    XLA compilation cache at engine construction (TPU cold-start cutter)."""
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["placed_from_outside", "not_placed"])
+def test_compile_cache_rule(placed, tmp_path, devices8, monkeypatch):
+    """Where ``JAX_COMPILATION_CACHE_DIR`` is set the engine sets no other
+    directory in code; where it is not, the cache is ``<checkout>/.xla_cache``
+    (``utils/compile_cache.py`` — the engine, ``chip_smoke.py``, ``bench.py``
+    and the scripts all go through it)."""
+    import jax
     import jax.numpy as jnp
 
     import deepspeed_tpu as dst
     from deepspeed_tpu.comm import mesh as mesh_lib
     from deepspeed_tpu.runtime.engine import ModelSpec
 
-    import jax as _jax
-
-    cache = tmp_path / "xla_cache"
-    cache.mkdir()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = str(tmp_path / "whatever_was_configured")
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "out"))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     mesh_lib.set_mesh(None)
     spec = ModelSpec(loss_fn=lambda p, b: (jnp.sum((p["w"] * b["x"]) ** 2), {}),
                      init_fn=lambda k: {"w": jnp.ones((4,))},
                      pipeline_capable=False)
-    prev = _jax.config.jax_compilation_cache_dir
+    prev = jax.config.jax_compilation_cache_dir
     try:
-        engine, *_ = dst.initialize(model=spec, config={
-            "train_batch_size": 8,
-            "optimizer": {"type": "sgd", "params": {"lr": 0.1}},
-            "compile_cache_dir": str(cache),
-            "steps_per_print": 0})
-        assert engine.config.compile_cache_dir == str(cache)
-        assert _jax.config.jax_compilation_cache_dir == str(cache)
-        # "" disables explicitly, even when the env var is set
-        mesh_lib.set_mesh(None)
-        monkeypatch.setenv("DSTPU_COMPILE_CACHE", str(tmp_path / "envcache"))
-        _jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", before)
         dst.initialize(model=spec, config={
             "train_batch_size": 8,
             "optimizer": {"type": "sgd", "params": {"lr": 0.1}},
-            "compile_cache_dir": "",
             "steps_per_print": 0})
-        assert _jax.config.jax_compilation_cache_dir is None
+        assert jax.config.jax_compilation_cache_dir == (
+            before if placed else os.path.join(repo, ".xla_cache"))
     finally:
         # process-global jax config must not leak into later tests
-        _jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_compilation_cache_dir", prev)

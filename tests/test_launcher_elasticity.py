@@ -54,6 +54,78 @@ def test_local_runner_cmds(tmp_path):
     assert cmds == [[sys.executable, "train.py", "--lr", "0.1"]]
 
 
+_POISON_JAX = """
+import sys
+class Poison:
+    def find_spec(self, name, path=None, target=None):
+        if name.split(".")[0] in ("jax", "jaxlib"):
+            raise ImportError("the launcher must not import " + name)
+sys.meta_path.insert(0, Poison())
+"""
+
+
+def test_launcher_builds_commands_without_importing_jax():
+    """A parent that touched JAX holds the chip, and the processes it starts
+    then fail or hang — so the launcher counts nothing through JAX: its
+    command builder works in an interpreter where importing jax raises."""
+    code = _POISON_JAX + """
+from deepspeed_tpu.launcher.runner import build_commands, parse_args
+runner, cmds = build_commands(parse_args(["-H", "/nonexistent", "train.py"]))
+assert runner.name == "local" and runner.world_info == {"localhost": 1}
+assert cmds == [[sys.executable, "train.py"]], cmds
+assert "jax" not in sys.modules
+print("ok")
+"""
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=60)
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]
+
+
+def test_local_multi_runner_refuses_to_share_the_chips(tmp_path, monkeypatch):
+    """N processes on one host would all open the same chips, and a chip
+    belongs to one process at a time: outside the CPU simulation
+    (``JAX_PLATFORMS=cpu``) the runner refuses to start."""
+    hf = tmp_path / "hostfile"
+    hf.write_text("localhost slots=1\n")
+    args = parse_args(["-H", str(hf), "--num_local_procs", "4", "train.py"])
+    assert len(build_commands(args)[1]) == 4  # conftest pins the CPU
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        build_commands(args)
+
+
+def test_autotuning_parent_never_initializes_a_backend(tmp_path):
+    """Subprocess trials each need the chip, so the tuning parent asks a
+    short-lived child for the device facts and never touches JAX itself."""
+    code = """
+import json, subprocess, sys
+real_run = subprocess.run
+def fake_run(cmd, **kw):
+    assert "deepspeed_tpu.autotuning.trial_worker" in cmd, cmd
+    out = {"n_chips": 4, "hbm_bytes": 1 << 34} if "--describe-devices" in cmd \\
+        else {"samples_per_sec": 10.0 / json.load(open(cmd[-1]))[
+            "trial_config"]["train_micro_batch_size_per_gpu"],
+              "step_time_s": 0.1}
+    return subprocess.CompletedProcess(cmd, 0, json.dumps(out) + "\\n", "")
+subprocess.run = fake_run
+from deepspeed_tpu.autotuning.cli import autotune_main
+assert autotune_main(sys.argv[1]) == 0
+from jax._src import xla_bridge
+assert not xla_bridge.backends_are_initialized(), "the parent touched JAX"
+"""
+    job = {"model": {"family": "llama", "config": {}},
+           "config": {"train_batch_size": 8}, "tuner": "gridsearch",
+           "micro_batches": [1, 2], "zero_stages": [1],
+           "output": str(tmp_path / "best.json")}
+    job_path = tmp_path / "job.json"
+    job_path.write_text(__import__("json").dumps(job))
+    r = subprocess.run([sys.executable, "-c", code, str(job_path)],
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    best = __import__("json").loads(r.stdout.strip().splitlines()[-1])
+    assert best["best"]["micro_batch"] == 1  # dp=4 came from the child
+
+
 def test_pdsh_runner_cmds(tmp_path):
     hf = tmp_path / "hostfile"
     hf.write_text("w0 slots=4\nw1 slots=4\n")

@@ -34,7 +34,8 @@ class TestRematPolicies:
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16))
         g_plain = jax.grad(lambda x: f(x))(x)
         g_remat = jax.grad(lambda x: checkpoint(f, x, policy="full"))(x)
-        np.testing.assert_allclose(g_plain, g_remat, rtol=1e-6)
+        # remat re-associates the fp32 recompute: a few ulps, not equality
+        np.testing.assert_allclose(g_plain, g_remat, rtol=1e-5)
 
     def test_configure_cpu_checkpointing_selects_offload(self):
         cfg = configure(checkpoint_in_cpu=True)
@@ -489,8 +490,8 @@ def test_fpdt_offload_kv_parks_kv_in_host_space():
     (investigated 2026-08: not a rot casualty). The CPU backend compiles
     the same program but XLA:CPU has a single flat memory space — no
     ``S(5)`` annotation ever appears in its HLO, so the assertion is only
-    meaningful on real TPU hardware, where ``tpu_watch.sh``'s full-suite
-    run exercises it. The CPU-checkable halves of fpdt offload (numerics,
+    meaningful for the TPU (``tests/test_chip_compile.py`` checks the
+    annotation itself against a described chip). The CPU-checkable halves of fpdt offload (numerics,
     saved-residual bytes) are covered by the tests above."""
     from deepspeed_tpu.sequence.fpdt import fpdt_attention
 

@@ -130,9 +130,10 @@ def test_cpu_mesh_order_unchanged():
     assert mm.tp_world_size == 2 and mm.dp_world_size == 4
 
 
-def test_unknown_topology_falls_back(caplog):
-    # holes in the cuboid make mesh_utils raise; we must fall back, not die
+def test_unknown_topology_raises():
+    # holes in the cuboid make mesh_utils raise; a device-order reshape in
+    # its place would be a wrong mesh that still runs, so the error stands
     devs = v5p_cuboid(4, 2, 2)[:8] + v5p_cuboid(4, 2, 2)[8:]
     devs[3].coords = (17, 9, 5)  # break the cuboid
-    arr, _ = _arrange_devices(devs, sizes_for(data=4, tensor=4))
-    assert {d.id for d in arr.flat} == set(range(16))
+    with pytest.raises(AssertionError):
+        _arrange_devices(devs, sizes_for(data=4, tensor=4))

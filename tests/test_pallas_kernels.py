@@ -6,10 +6,13 @@ reference's kernel unit tests (``tests/unit/ops/``) which compare CUDA kernels
 against torch reference implementations.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.ops.attention import attention_xla
 from deepspeed_tpu.ops.norms import layer_norm_xla, rms_norm_xla
@@ -439,3 +442,146 @@ def test_flash_causal_kv_longer_than_q():
                                rtol=2e-4, atol=2e-4)
     # trailing (fully-masked) keys must receive exactly zero gradient
     assert (np.asarray(gk)[:, sq:] == 0).all()
+
+
+# --------------------------------------------------------------------------- #
+# the registry's one per-device wrapper (ops/registry._per_device): a Pallas
+# kernel in a program that spans a mesh runs under a shard_map with its rows
+# over the data-parallel axes, or raises — it is never swapped for XLA
+# --------------------------------------------------------------------------- #
+def _attention_case():
+    b, s, h, kvh, d = 4, 64, 4, 2, 32
+    q, k, v = (rand(i, (b, s, n, d)) for i, n in enumerate((h, kvh, kvh)))
+    return (q, k, v), lambda f, q, k, v: jnp.sum(f(q, k, v, causal=True) ** 2)
+
+
+def _rms_case():
+    args = (rand(0, (4, 16, 256)), rand(1, (256,)))
+    return args, lambda f, x, w: jnp.sum(f(x, w, 1e-6) ** 2)
+
+
+def _layer_norm_case():
+    args = (rand(0, (8, 256)), rand(1, (256,)), rand(2, (256,)))
+    return args, lambda f, x, w, b: jnp.sum(f(x, w, b, 1e-5) ** 2)
+
+
+def _quantize_roundtrip_case():
+    def loss(f, x):  # f is quantize; dequantize resolves the same way
+        from deepspeed_tpu.ops import registry
+
+        q, scales = f(x, 128)
+        assert q.dtype == jnp.int8 and scales.shape == (x.size // 128,)
+        return jnp.sum(registry.get_op("dequantize_int8")(q, scales, 128))
+
+    return (rand(0, (8, 512)),), loss
+
+
+PER_DEVICE_CASES = {
+    "attention": _attention_case,
+    "rms_norm": _rms_case,
+    "layer_norm": _layer_norm_case,
+    "quantize_int8": _quantize_roundtrip_case,
+}
+
+
+@pytest.fixture
+def pallas_everywhere(monkeypatch):
+    """The registry resolves as on the chip (kernels stay in interpret mode)."""
+    from deepspeed_tpu.ops import registry
+
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    return registry
+
+
+@pytest.mark.parametrize("mesh_axes", [{"data": 4}, {"data": 2, "expert": 2}],
+                         ids=["data4", "data2_expert2"])
+@pytest.mark.parametrize("op", sorted(PER_DEVICE_CASES))
+def test_pallas_op_over_a_data_parallel_mesh(devices8, pallas_everywhere,
+                                             op, mesh_axes):
+    """Value and every gradient (a replicated weight's is summed over the
+    mesh) equal the XLA reference, and the program has the shard_map."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+
+    registry = pallas_everywhere
+    mm = mesh_lib.MeshManager.create(mesh_axes, devices=devices8[:4])
+    args, loss = PER_DEVICE_CASES[op]()
+    differentiable = op != "quantize_int8"
+
+    def run(f):
+        fn = functools.partial(loss, f)
+        if differentiable:
+            fn = jax.value_and_grad(fn, argnums=tuple(range(len(args))))
+        return jax.jit(fn), fn
+
+    assert registry.resolved()[op] == "pallas"
+    with mm.activate():
+        jitted, fn = run(registry.get_op(op))
+        assert "shard_map" in str(jax.make_jaxpr(fn)(*args))
+        got = jitted(*args)
+    want = run(registry.available_backends(op)["xla"])[0](*args)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+def _paged_call(registry):
+    pool = jnp.zeros((8, 2, 16, 32))
+    return lambda: registry.get_op("paged_decode_attention")(
+        jnp.zeros((4, 4, 32)), pool, pool, jnp.zeros((4, 4), jnp.int32),
+        jnp.ones((4,), jnp.int32))
+
+
+def _attention_call(registry, b=4, **kw):
+    q = jnp.zeros((b, 64, 4, 32))
+    return lambda: registry.get_op("attention")(q, q, q, **kw)
+
+
+NO_LAYOUT_CASES = {
+    # (mesh axes, call): why the kernel cannot run there
+    "paged_decode_has_no_layout": ({"data": 4}, _paged_call),
+    "tensor_axis_is_not_covered": ({"data": 2, "tensor": 2}, _attention_call),
+    "bias_is_a_keyword_array": (
+        {"data": 4}, lambda r: _attention_call(
+            r, bias=jnp.zeros((4, 4, 64, 64)), causal=False)),
+    "rows_do_not_divide": ({"data": 4},
+                           lambda r: _attention_call(r, b=2)),
+    # Mosaic refuses a kernel under a shard_map over only some of the axes
+    "inside_a_partly_manual_region": (
+        {"data": 2, "pipe": 2}, lambda r: jax.shard_map(
+            _attention_call(r), in_specs=(), out_specs=P(),
+            axis_names={"pipe"}, check_vma=False)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NO_LAYOUT_CASES))
+def test_pallas_op_without_a_layout_raises(devices8, pallas_everywhere, case):
+    from deepspeed_tpu.comm import mesh as mesh_lib
+
+    mesh_axes, make = NO_LAYOUT_CASES[case]
+    mm = mesh_lib.MeshManager.create(mesh_axes, devices=devices8[:4])
+    call = make(pallas_everywhere)
+    with mm.activate(), pytest.raises(NotImplementedError,
+                                      match="per-device program"):
+        jax.jit(call)()
+
+
+def test_mesh_of_the_trace_decides_not_the_global_mesh(devices8,
+                                                       pallas_everywhere):
+    """A one-device program under a four-device trainer's global mesh calls
+    the kernel as is; the trainer's own trace wraps it whatever the global
+    mesh says; inside the caller's own manual region it is called as is."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+
+    registry = pallas_everywhere
+    x, w = rand(0, (8, 256)), rand(1, (256,))
+    norm = lambda x, w: registry.get_op("rms_norm")(x, w, 1e-6)  # noqa: E731
+
+    four = mesh_lib.init_mesh({"data": 4}, devices=devices8[:4])
+    assert "shard_map" not in str(jax.make_jaxpr(norm)(x, w))
+    one = mesh_lib.init_mesh({"data": 1}, devices=devices8[:1])
+    with one.activate():
+        assert "shard_map" not in str(jax.make_jaxpr(norm)(x, w))
+    with four.activate():
+        assert "shard_map" in str(jax.make_jaxpr(norm)(x, w))
+        manual = jax.shard_map(norm, in_specs=(P("data"), P()),
+                               out_specs=P("data"), check_vma=False)
+        assert str(jax.make_jaxpr(manual)(x, w)).count("shard_map") == 1
