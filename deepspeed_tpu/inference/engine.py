@@ -27,7 +27,7 @@ import numpy as np
 
 from ..comm.mesh import MeshManager, get_mesh, init_mesh, set_mesh
 from ..runtime.partitioning import Partitioner
-from ..telemetry.profiler import annotate as _annotate
+from ..telemetry.trace import Tracer
 from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .sampling import SamplingParams, sample
@@ -70,10 +70,17 @@ class InferenceEngine:
 
     def __init__(self, family: ModelFamily, params: Any,
                  config: Optional[InferenceConfig] = None,
-                 mesh_mgr: Optional[MeshManager] = None):
+                 mesh_mgr: Optional[MeshManager] = None,
+                 tracer: Optional[Tracer] = None):
         self.family = family
         self.config = config or InferenceConfig()
         self.dtype = jnp.dtype(self.config.dtype)
+        # spans (telemetry/trace.py): the ring is governed by the config's
+        # ``trace`` block (default OFF); the same spans reach the profiler's
+        # timeline whenever a profiler session is running
+        self.tracer = tracer if tracer is not None else Tracer(
+            getattr(self.config, "trace", None), name="serving",
+            annotate=jax.profiler.TraceAnnotation)
         self._generate_cache: Dict[Tuple, Callable] = {}
 
         # --- mesh / TP group (reference _create_model_parallel_group :247) ---
@@ -309,7 +316,7 @@ class InferenceEngine:
 
         rng = jax.random.PRNGKey(seed)
         rng, k = jax.random.split(rng)
-        with _annotate("prefill"):
+        with self.tracer.span("generate/prefill", cat="serving"):
             tok, cache = prefill(self.params, jnp.asarray(padded), lengths, k)
         first_tok = tok
         if max_new_tokens <= 1:
@@ -328,7 +335,7 @@ class InferenceEngine:
         while remaining > 0:
             n = min(CHUNK, remaining)
             rng, k = jax.random.split(rng)
-            with _annotate("decode_chunk"):
+            with self.tracer.span("generate/decode_chunk", cat="serving"):
                 steps, tok, cache, cache_len, finished = decode_chunk(
                     self.params, tok, cache, cache_len, k, finished, eos_dev, n)
             outs.append(np.asarray(steps))

@@ -31,7 +31,7 @@ import numpy as np
 from ..comm.mesh import MeshManager
 from ..ops.quantization import kv_dequantize_int8, kv_quantize_int8
 from ..telemetry.compile import CompileMonitor
-from ..telemetry.trace import Tracer, percentiles
+from ..telemetry.trace import percentiles
 from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .engine import InferenceEngine, ModelFamily, _round_up
@@ -67,6 +67,10 @@ def prompt_lookup_draft(history, max_tokens: int, ngram_max: int = 3,
     return []
 
 
+# what ``InferenceEngineV2.last_step`` holds before a step has done anything
+_NO_WORK = {"prefill_tokens": 0, "decode_seqs": 0, "kv_tokens": 0}
+
+
 class InferenceEngineV2(InferenceEngine):
     """put()/step() continuous batching; also exposes a high-level
     ``generate`` that drains a prompt list through the scheduler."""
@@ -77,7 +81,13 @@ class InferenceEngineV2(InferenceEngine):
                  init_paged_cache: Optional[Callable] = None,
                  apply_paged: Optional[Callable] = None,
                  telemetry_hub=None):
-        super().__init__(family, params, config, mesh_mgr)
+        # a hub with an ENABLED tracer shares its flight recorder (serving
+        # spans land next to training/checkpoint spans); otherwise the
+        # engine's own config.trace block governs the ring (base class)
+        hub_tracer = getattr(telemetry_hub, "tracer", None)
+        if hub_tracer is not None and not hub_tracer.enabled:
+            hub_tracer = None
+        super().__init__(family, params, config, mesh_mgr, tracer=hub_tracer)
         rc = self.config.ragged
         pc = self.config.prefix_cache
         self._apply_paged = apply_paged
@@ -190,17 +200,19 @@ class InferenceEngineV2(InferenceEngine):
             "rolled_back_tokens": 0, "verify_positions": 0,
             "verify_capacity": 0, "fused_verify_steps": 0}
         # --- request-lifecycle tracing + latency SLO stats (trace.py;
-        # docs/serving.md). A hub with an ENABLED tracer shares its flight
-        # recorder (serving spans land next to training/checkpoint spans);
-        # otherwise the engine's own config.trace block governs. Default
-        # OFF: every hook below is a no-op and no timer ever starts.
-        hub_tracer = getattr(telemetry_hub, "tracer", None)
-        if hub_tracer is not None and hub_tracer.enabled:
-            self.tracer = hub_tracer
-        else:
-            self.tracer = Tracer(getattr(self.config, "trace", None),
-                                 name="serving")
+        # docs/serving.md), on the ring of ``self.tracer``. Default OFF:
+        # every ``_req_*`` hook below is a no-op and no timer ever starts.
+        # (The dispatch spans are not gated: they cost a microsecond each
+        # and a profiler session records them, ring or no ring.)
         self._trace_on = self.tracer.enabled
+        # --- counters at the dispatch boundaries, public and read-only:
+        # what the last step()/step_many() did (prompt tokens whose KV it
+        # wrote, sequences in its decode batch, the KV tokens that batch
+        # attends over after the step), and the prompt tokens whose KV any
+        # call wrote since construction (put/put_many prefill included,
+        # prefix-cache hits not: nothing is written for them)
+        self.last_step: Dict[str, int] = dict(_NO_WORK)
+        self.prefill_tokens_written = 0
         # --- recompilation sentinel + per-program MFU attribution
         # (telemetry/compile.py; docs/observability.md). A hub with an
         # ENABLED monitor is shared — serving programs land in the same
@@ -312,8 +324,10 @@ class InferenceEngineV2(InferenceEngine):
 
     def _req_tokens(self, uid: int, k: int, t_ns: int) -> None:
         """``k`` decode tokens for ``uid`` landed at ``t_ns`` (one fused
-        quantum): ITL per token = elapsed / k; per-token instants are
-        interpolated across the quantum."""
+        quantum): ITL per token = elapsed / k. (No per-token instant: 32 a
+        tick would turn the flight recorder over in two minutes and push
+        out the spans a crash dump is kept for; the gaps are in
+        ``latency_summary``.)"""
         rec = self._req.get(uid)
         if rec is None or k <= 0:
             return
@@ -324,12 +338,7 @@ class InferenceEngineV2(InferenceEngine):
         if not rec["first_done"]:
             self._req_first_token(uid, int(start + per))
             i0 = 1
-        for i in range(i0, k):
-            self._lat["itl_ms"].append(per / 1e6)
-            self.tracer.instant("decode_token", cat="serving",
-                                trace=rec["trace"],
-                                parent=rec["span"].span_id,
-                                ts_ns=int(start + per * (i + 1)), uid=uid)
+        self._lat["itl_ms"].extend([per / 1e6] * (k - i0))
         rec["last_ns"] = t_ns
 
     def _req_finish(self, uid: int, **args) -> None:
@@ -605,53 +614,53 @@ class InferenceEngineV2(InferenceEngine):
         done = desc.seen_tokens
         chunk = prompt[done:done + chunk_tokens]
         final = done + len(chunk) >= len(prompt)
-        padded = np.zeros((1, chunk_tokens), np.int32)
-        padded[0, :len(chunk)] = chunk
-        table = self.state.block_table(desc)
-        fn = self._chunk_prefill_fn(chunk_tokens, sp, final)
-        if self._trace_on:
-            self._req_compute_begin(uid)   # first chunk ends queue-wait
-            t0 = time.monotonic_ns()
-        args = (self.params, self.cache, jnp.asarray(padded),
-                jnp.asarray(len(chunk), jnp.int32),
-                jnp.asarray(done, jnp.int32), jnp.asarray(table),
-                jax.random.PRNGKey(seed), jnp.asarray(uid, jnp.int32))
-        if not final:
-            self.cache = fn(*args)
+        rec = self._req.get(uid)        # the request's ring lifecycle
+        with self.tracer.span(
+                "prefill_chunk", cat="serving",
+                trace=rec["trace"] if rec else None,
+                parent=rec["span"].span_id if rec else None,
+                uid=uid, tokens=len(chunk), ctx=done, final=final):
+            with self.tracer.span("engine_prep", cat="serving"):
+                padded = np.zeros((1, chunk_tokens), np.int32)
+                padded[0, :len(chunk)] = chunk
+                table = self.state.block_table(desc)
+                fn = self._chunk_prefill_fn(chunk_tokens, sp, final)
             if self._trace_on:
-                self._trace_chunk(uid, t0, len(chunk), done, final=False)
-            desc.seen_tokens = done + len(chunk)
-            self.state.mark_filled(desc)  # completed chunks become matchable
-            return {}
-        tok, self.cache = fn(*args)
-        tok = int(tok)
-        if self._trace_on:
-            self._trace_chunk(uid, t0, len(chunk), done, final=True)
-        del self._pending_prefill[uid]
-        desc.seen_tokens = len(prompt)
-        self.state.mark_filled(desc)
-        desc.prefilling = False
-        desc.last_token = tok
-        desc.generated.append(tok)
-        s = desc.slot
-        self._slot_tokens[s] = tok
-        self._slot_lens[s] = desc.seen_tokens
-        self._slot_tables[s] = table
-        self._slot_active[s] = True
-        self._slot_sp[s] = self._canon_sp(sp)
+                self._req_compute_begin(uid)   # first chunk ends queue-wait
+            with self.tracer.span("engine_dispatch", cat="serving"):
+                res = fn(self.params, self.cache, jnp.asarray(padded),
+                         jnp.asarray(len(chunk), jnp.int32),
+                         jnp.asarray(done, jnp.int32), jnp.asarray(table),
+                         jax.random.PRNGKey(seed),
+                         jnp.asarray(uid, jnp.int32))
+            self.last_step["prefill_tokens"] += len(chunk)
+            self.prefill_tokens_written += len(chunk)
+            if not final:
+                # no engine_wait: the call is asynchronous and nothing here
+                # blocks on it, so this span says dispatch, not device
+                self.cache = res
+                desc.seen_tokens = done + len(chunk)
+                self.state.mark_filled(desc)  # completed chunks are matchable
+                return {}
+            tok, self.cache = res
+            with self.tracer.span("engine_wait", cat="serving"):
+                tok = int(tok)
+            if self._trace_on:
+                self._req_first_token(uid, time.monotonic_ns())
+            with self.tracer.span("engine_emit", cat="serving"):
+                del self._pending_prefill[uid]
+                desc.seen_tokens = len(prompt)
+                self.state.mark_filled(desc)
+                desc.prefilling = False
+                desc.last_token = tok
+                desc.generated.append(tok)
+                s = desc.slot
+                self._slot_tokens[s] = tok
+                self._slot_lens[s] = desc.seen_tokens
+                self._slot_tables[s] = table
+                self._slot_active[s] = True
+                self._slot_sp[s] = self._canon_sp(sp)
         return {uid: tok}
-
-    def _trace_chunk(self, uid: int, t0_ns: int, tokens: int, ctx: int,
-                     final: bool) -> None:
-        t1 = time.monotonic_ns()
-        rec = self._req.get(uid)
-        self.tracer.complete(
-            "prefill_chunk", t0_ns, t1, cat="serving",
-            trace=rec["trace"] if rec else None,
-            parent=rec["span"].span_id if rec else None,
-            uid=uid, tokens=tokens, ctx=ctx, final=final)
-        if final:
-            self._req_first_token(uid, t1)
 
     def put_split(self, uid: int, prompt_tokens,
                   sp: SamplingParams = SamplingParams(greedy=True)) -> None:
@@ -906,72 +915,79 @@ class InferenceEngineV2(InferenceEngine):
             # prefill-shaped dense-gather dispatch
             self.spec_stats["fused_verify_steps"] += 1
         self.spec_stats["step_seqs"] += len(live)
-        cow = []
-        for d in live:
-            dl = len(drafts[d.uid])
-            cow += self.state.ensure_writable(d, d.seen_tokens + dl + 1)
-            self.state.extend(d, n=dl + 1)
-            self._slot_tables[d.slot] = self.state.block_table(d)
-        self._copy_blocks(cow)
-        B = self._slot_tokens.shape[0]
-        tok_w = np.zeros((B, kmax + 1), np.int32)
-        tok_w[:, 0] = self._slot_tokens
-        dr_arr = np.zeros((B, kmax), np.int32)
-        nvalid = np.ones((B,), np.int32)
-        uids_arr = np.zeros((B,), np.int32)
-        for d in live:
-            dr = drafts[d.uid]
-            dr_arr[d.slot, :len(dr)] = dr
-            tok_w[d.slot, 1:len(dr) + 1] = dr
-            nvalid[d.slot] = 1 + len(dr)
-            uids_arr[d.slot] = d.uid
-        if self._trace_on:
-            t0 = time.monotonic_ns()
-        m, nxt, self.cache = self._verify_fn(kmax + 1)(
-            self.params, self.cache, jnp.asarray(tok_w),
-            jnp.asarray(self._slot_lens), jnp.asarray(self._slot_tables),
-            jnp.asarray(self._slot_active), jnp.asarray(nvalid),
-            jnp.asarray(dr_arr), jax.random.PRNGKey(seed),
-            jnp.asarray(uids_arr), *map(jnp.asarray,
-                                        sp_arrays(self._slot_sp)))
-        m, nxt = np.asarray(m), np.asarray(nxt)
-        if self._trace_on:
-            t1 = time.monotonic_ns()
-            self.tracer.complete(
-                "spec_verify", t0, t1, cat="serving", batch=len(live),
-                drafted=int(sum(len(v) for v in drafts.values())),
-                accepted=int(sum(min(int(m[d.slot]), len(drafts[d.uid]))
-                                 for d in live)))
         out: Dict[int, List[int]] = {}
         st = self.spec_stats
-        for d in live:
-            dr = drafts[d.uid]
-            dl = len(dr)
-            mi = min(int(m[d.slot]), dl)
-            tok = int(nxt[d.slot])
-            # KV positions seen..seen+dl now hold [last_token] + drafts;
-            # record them, then un-fill the rejected suffix
-            d.tokens.extend([d.last_token] + dr)
-            d.seen_tokens += dl + 1
-            if mi < dl:
-                pairs = self.state.truncate(d, d.seen_tokens - (dl - mi))
-                self._copy_blocks(pairs)
-                self._slot_tables[d.slot] = self.state.block_table(d)
-            emitted = dr[:mi] + [tok]
-            d.last_token = tok
-            d.generated.extend(emitted)
-            self._slot_tokens[d.slot] = tok
-            self._slot_lens[d.slot] = d.seen_tokens
-            self.state.mark_filled(d)
-            out[d.uid] = emitted
-            st["drafted_tokens"] += dl
-            st["accepted_tokens"] += mi
-            st["emitted_tokens"] += mi + 1
-            st["rolled_back_tokens"] += dl - mi
-            st["verify_positions"] += dl + 1
-            st["verify_capacity"] += kmax + 1
-            if self._trace_on:
-                self._req_tokens(d.uid, mi + 1, t1)
+        with self.tracer.span(
+                "spec_verify", cat="serving", batch=len(live),
+                drafted=sum(len(v) for v in drafts.values())) as span:
+            with self.tracer.span("engine_prep", cat="serving"):
+                cow = []
+                for d in live:
+                    dl = len(drafts[d.uid])
+                    cow += self.state.ensure_writable(
+                        d, d.seen_tokens + dl + 1)
+                    self.state.extend(d, n=dl + 1)
+                    self._slot_tables[d.slot] = self.state.block_table(d)
+                self._copy_blocks(cow)
+                B = self._slot_tokens.shape[0]
+                tok_w = np.zeros((B, kmax + 1), np.int32)
+                tok_w[:, 0] = self._slot_tokens
+                dr_arr = np.zeros((B, kmax), np.int32)
+                nvalid = np.ones((B,), np.int32)
+                uids_arr = np.zeros((B,), np.int32)
+                for d in live:
+                    dr = drafts[d.uid]
+                    dr_arr[d.slot, :len(dr)] = dr
+                    tok_w[d.slot, 1:len(dr) + 1] = dr
+                    nvalid[d.slot] = 1 + len(dr)
+                    uids_arr[d.slot] = d.uid
+            with self.tracer.span("engine_dispatch", cat="serving"):
+                m, nxt, self.cache = self._verify_fn(kmax + 1)(
+                    self.params, self.cache, jnp.asarray(tok_w),
+                    jnp.asarray(self._slot_lens),
+                    jnp.asarray(self._slot_tables),
+                    jnp.asarray(self._slot_active), jnp.asarray(nvalid),
+                    jnp.asarray(dr_arr), jax.random.PRNGKey(seed),
+                    jnp.asarray(uids_arr),
+                    *map(jnp.asarray, sp_arrays(self._slot_sp)))
+            with self.tracer.span("engine_wait", cat="serving"):
+                m, nxt = np.asarray(m), np.asarray(nxt)
+            t1 = time.monotonic_ns() if self._trace_on else 0
+            with self.tracer.span("engine_emit", cat="serving"):
+                kv = 0
+                for d in live:
+                    dr = drafts[d.uid]
+                    dl = len(dr)
+                    mi = min(int(m[d.slot]), dl)
+                    tok = int(nxt[d.slot])
+                    # KV positions seen..seen+dl now hold [last_token] +
+                    # drafts; record them, then un-fill the rejected suffix
+                    d.tokens.extend([d.last_token] + dr)
+                    d.seen_tokens += dl + 1
+                    kv += d.seen_tokens
+                    if mi < dl:
+                        pairs = self.state.truncate(
+                            d, d.seen_tokens - (dl - mi))
+                        self._copy_blocks(pairs)
+                        self._slot_tables[d.slot] = self.state.block_table(d)
+                    emitted = dr[:mi] + [tok]
+                    d.last_token = tok
+                    d.generated.extend(emitted)
+                    self._slot_tokens[d.slot] = tok
+                    self._slot_lens[d.slot] = d.seen_tokens
+                    self.state.mark_filled(d)
+                    out[d.uid] = emitted
+                    st["drafted_tokens"] += dl
+                    st["accepted_tokens"] += mi
+                    st["emitted_tokens"] += mi + 1
+                    st["rolled_back_tokens"] += dl - mi
+                    st["verify_positions"] += dl + 1
+                    st["verify_capacity"] += kmax + 1
+                    if self._trace_on:
+                        self._req_tokens(d.uid, mi + 1, t1)
+            self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
+            span.set(kv_tokens=kv,
+                     accepted=sum(len(v) - 1 for v in out.values()))
         return out
 
     # ------------------------------------------------------------------ #
@@ -1032,67 +1048,75 @@ class InferenceEngineV2(InferenceEngine):
         pad_t = _round_up(max(max(len(p) - c for (_, p, _), c
                                   in zip(entries, cached)), 1),
                           self.config.prefill_bucket)
-        padded = np.zeros((n_pad, pad_t), np.int32)
-        lengths = np.zeros((n_pad,), np.int32)  # dummy rows: length 0
-        ctx = np.zeros((n_pad,), np.int32)
-        uids_arr = np.zeros((n_pad,), np.int32)
-        tables = np.zeros((n_pad, self._slot_tables.shape[1]), np.int32)
-        for i, (uid, prompt, desc) in enumerate(entries):
-            suffix = prompt[cached[i]:]
-            padded[i, :len(suffix)] = suffix
-            lengths[i] = len(suffix)
-            ctx[i] = cached[i]
-            uids_arr[i] = uid
-            tables[i] = self.state.block_table(desc)
-        with_ctx = any(cached)
-        if self._trace_on:
-            for uid, prompt, _ in entries:
-                self._req_admit(uid, len(prompt))  # generate() admits direct
-                self._req_compute_begin(uid)
-            t0 = time.monotonic_ns()
-        base = (self.params, self.cache, jnp.asarray(padded),
-                jnp.asarray(lengths), jnp.asarray(tables))
-        if with_ctx:
-            base += (jnp.asarray(ctx),)
-        base += (jax.random.PRNGKey(seed), jnp.asarray(uids_arr))
-        greedy_sp = SamplingParams(greedy=True)
-        if all(s_ == greedy_sp for s_ in sps):
-            fn = (self._prefill_ctx_fn if with_ctx else self._prefill_fn)(
-                pad_t, greedy_sp, n_pad)
-            toks, self.cache = fn(*base)
-        else:
-            pad_sps = sps + [greedy_sp] * (n_pad - n)  # dummy rows: greedy
-            fn = (self._prefill_ctx_dyn_fn(pad_t, n_pad) if with_ctx
-                  else self._prefill_dyn_fn(pad_t, n_pad))
-            toks, self.cache = fn(*base, *map(jnp.asarray,
-                                              sp_arrays(pad_sps)))
-        toks = np.asarray(toks)
-        if self._trace_on:
-            t1 = time.monotonic_ns()
-            self.tracer.complete("prefill_batch", t0, t1, cat="serving",
-                                 n=n, pad_t=pad_t)
         out: Dict[int, int] = {}
-        for i, (uid, prompt, desc) in enumerate(entries):
-            tok = int(toks[i])
-            desc.seen_tokens = len(prompt)
-            self.state.mark_filled(desc)  # full prompt blocks → matchable
-            desc.last_token = tok
-            desc.generated.append(tok)
-            s = desc.slot
-            self._slot_tokens[s] = tok
-            self._slot_lens[s] = desc.seen_tokens
-            self._slot_tables[s] = tables[i]
-            self._slot_active[s] = True
-            self._slot_sp[s] = sps[i]
-            out[uid] = tok
+        with self.tracer.span("prefill_batch", cat="serving", n=n,
+                              pad_t=pad_t):
+            with self.tracer.span("engine_prep", cat="serving"):
+                padded = np.zeros((n_pad, pad_t), np.int32)
+                lengths = np.zeros((n_pad,), np.int32)  # dummy rows: length 0
+                ctx = np.zeros((n_pad,), np.int32)
+                uids_arr = np.zeros((n_pad,), np.int32)
+                tables = np.zeros((n_pad, self._slot_tables.shape[1]),
+                                  np.int32)
+                for i, (uid, prompt, desc) in enumerate(entries):
+                    suffix = prompt[cached[i]:]
+                    padded[i, :len(suffix)] = suffix
+                    lengths[i] = len(suffix)
+                    ctx[i] = cached[i]
+                    uids_arr[i] = uid
+                    tables[i] = self.state.block_table(desc)
+                with_ctx = any(cached)
             if self._trace_on:
-                rec = self._req.get(uid)
-                if rec is not None:
-                    self.tracer.complete(
-                        "prefill", t0, t1, cat="serving", trace=rec["trace"],
-                        parent=rec["span"].span_id, uid=uid,
-                        tokens=int(lengths[i]), cached=int(ctx[i]))
-                self._req_first_token(uid, t1)
+                for uid, prompt, _ in entries:
+                    self._req_admit(uid, len(prompt))  # generate() admits direct
+                    self._req_compute_begin(uid)
+                t0 = time.monotonic_ns()
+            with self.tracer.span("engine_dispatch", cat="serving"):
+                base = (self.params, self.cache, jnp.asarray(padded),
+                        jnp.asarray(lengths), jnp.asarray(tables))
+                if with_ctx:
+                    base += (jnp.asarray(ctx),)
+                base += (jax.random.PRNGKey(seed), jnp.asarray(uids_arr))
+                greedy_sp = SamplingParams(greedy=True)
+                if all(s_ == greedy_sp for s_ in sps):
+                    fn = (self._prefill_ctx_fn if with_ctx
+                          else self._prefill_fn)(pad_t, greedy_sp, n_pad)
+                    toks, self.cache = fn(*base)
+                else:
+                    pad_sps = sps + [greedy_sp] * (n_pad - n)  # dummies: greedy
+                    fn = (self._prefill_ctx_dyn_fn(pad_t, n_pad) if with_ctx
+                          else self._prefill_dyn_fn(pad_t, n_pad))
+                    toks, self.cache = fn(*base, *map(jnp.asarray,
+                                                      sp_arrays(pad_sps)))
+            with self.tracer.span("engine_wait", cat="serving"):
+                toks = np.asarray(toks)
+            t1 = time.monotonic_ns() if self._trace_on else 0
+            self.prefill_tokens_written += int(lengths.sum())
+            with self.tracer.span("engine_emit", cat="serving"):
+                for i, (uid, prompt, desc) in enumerate(entries):
+                    tok = int(toks[i])
+                    desc.seen_tokens = len(prompt)
+                    self.state.mark_filled(desc)  # full blocks → matchable
+                    desc.last_token = tok
+                    desc.generated.append(tok)
+                    s = desc.slot
+                    self._slot_tokens[s] = tok
+                    self._slot_lens[s] = desc.seen_tokens
+                    self._slot_tables[s] = tables[i]
+                    self._slot_active[s] = True
+                    self._slot_sp[s] = sps[i]
+                    out[uid] = tok
+                    if self._trace_on:
+                        rec = self._req.get(uid)
+                        if rec is not None:
+                            # one interval credited to every request of the
+                            # batch: explicit endpoints, ring only
+                            self.tracer.complete(
+                                "prefill", t0, t1, cat="serving",
+                                trace=rec["trace"],
+                                parent=rec["span"].span_id, uid=uid,
+                                tokens=int(lengths[i]), cached=int(ctx[i]))
+                        self._req_first_token(uid, t1)
         return out
 
     def step(self, sp: SamplingParams = SamplingParams(greedy=True),
@@ -1110,6 +1134,7 @@ class InferenceEngineV2(InferenceEngine):
         the return type widens to {uid: [tokens]} — every value is a list,
         including prefill first-tokens and draft-less fallback steps."""
         self._warn_ignored_sp(sp)
+        self.last_step = dict(_NO_WORK)
         out = self._advance_prefill(seed)
         live = [d for d in self.state.seqs.values()
                 if not d.finished and not d.prefilling
@@ -1136,42 +1161,51 @@ class InferenceEngineV2(InferenceEngine):
             self.spec_stats["decode_steps"] += 1
             self.spec_stats["step_seqs"] += len(live)
             self.spec_stats["emitted_tokens"] += len(live)
-        cow = []
-        for d in live:
-            # copy-on-write BEFORE extend: only pre-existing blocks can be
-            # shared; the blocks extend allocates are fresh (refcount 1)
-            cow += self.state.ensure_writable(d, d.seen_tokens + 1)
-            self.state.extend(d)
-            self._slot_tables[d.slot] = self.state.block_table(d)
-        self._copy_blocks(cow)
-        if self._trace_on:
-            t0 = time.monotonic_ns()
-        base = (self.params, self.cache, jnp.asarray(self._slot_tokens),
-                jnp.asarray(self._slot_lens), jnp.asarray(self._slot_tables),
-                jnp.asarray(self._slot_active), jax.random.PRNGKey(seed))
-        if self._needs_dynamic_sp(live):
-            nxt, self.cache = self._decode_dyn_fn()(
-                *base, *map(jnp.asarray, sp_arrays(self._slot_sp)))
-        else:
-            nxt, self.cache = self._decode_fn(
-                SamplingParams(greedy=True))(*base)
-        nxt = np.asarray(nxt)
-        if self._trace_on:
-            t1 = time.monotonic_ns()
-            self.tracer.complete("decode_step", t0, t1, cat="serving",
-                                 batch=len(live))
-        for d in live:
-            tok = int(nxt[d.slot])
-            d.tokens.append(d.last_token)  # the id whose KV this step wrote
-            d.seen_tokens += 1
-            d.last_token = tok
-            d.generated.append(tok)
-            self._slot_tokens[d.slot] = tok
-            self._slot_lens[d.slot] = d.seen_tokens
-            self.state.mark_filled(d)
-            out[d.uid] = tok
-            if self._trace_on:
-                self._req_tokens(d.uid, 1, t1)
+        with self.tracer.span("decode_step", cat="serving",
+                              batch=len(live)) as span:
+            with self.tracer.span("engine_prep", cat="serving"):
+                cow = []
+                for d in live:
+                    # copy-on-write BEFORE extend: only pre-existing blocks
+                    # can be shared; the blocks extend allocates are fresh
+                    # (refcount 1)
+                    cow += self.state.ensure_writable(d, d.seen_tokens + 1)
+                    self.state.extend(d)
+                    self._slot_tables[d.slot] = self.state.block_table(d)
+                self._copy_blocks(cow)
+            with self.tracer.span("engine_dispatch", cat="serving"):
+                base = (self.params, self.cache,
+                        jnp.asarray(self._slot_tokens),
+                        jnp.asarray(self._slot_lens),
+                        jnp.asarray(self._slot_tables),
+                        jnp.asarray(self._slot_active),
+                        jax.random.PRNGKey(seed))
+                if self._needs_dynamic_sp(live):
+                    nxt, self.cache = self._decode_dyn_fn()(
+                        *base, *map(jnp.asarray, sp_arrays(self._slot_sp)))
+                else:
+                    nxt, self.cache = self._decode_fn(
+                        SamplingParams(greedy=True))(*base)
+            with self.tracer.span("engine_wait", cat="serving"):
+                nxt = np.asarray(nxt)
+            t1 = time.monotonic_ns() if self._trace_on else 0
+            with self.tracer.span("engine_emit", cat="serving"):
+                kv = 0
+                for d in live:
+                    tok = int(nxt[d.slot])
+                    d.tokens.append(d.last_token)  # the id whose KV was written
+                    d.seen_tokens += 1
+                    kv += d.seen_tokens
+                    d.last_token = tok
+                    d.generated.append(tok)
+                    self._slot_tokens[d.slot] = tok
+                    self._slot_lens[d.slot] = d.seen_tokens
+                    self.state.mark_filled(d)
+                    out[d.uid] = tok
+                    if self._trace_on:
+                        self._req_tokens(d.uid, 1, t1)
+            self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
+            span.set(kv_tokens=kv)
         return {u: [t] for u, t in out.items()} if self._spec_on else out
 
     def step_many(self, k: int, sp: SamplingParams = SamplingParams(greedy=True),
@@ -1189,6 +1223,7 @@ class InferenceEngineV2(InferenceEngine):
         number of tokens per call. ``generate`` picks ``step()`` when
         ``inference.speculative.enabled`` is set."""
         self._warn_ignored_sp(sp)
+        self.last_step = dict(_NO_WORK)
         first = self._advance_prefill(seed)
         live = [d for d in self.state.seqs.values()
                 if not d.finished and not d.prefilling
@@ -1208,42 +1243,51 @@ class InferenceEngineV2(InferenceEngine):
         k = min(k, self.family.cfg.max_seq_len - max_seen)
         if k <= 0:
             return out
-        cow = []
-        for d in live:
-            cow += self.state.ensure_writable(d, d.seen_tokens + k)
-            self.state.extend(d, n=k)  # reserve ALL k tokens up front
-            self._slot_tables[d.slot] = self.state.block_table(d)
-        self._copy_blocks(cow)
-        if self._trace_on:
-            t0 = time.monotonic_ns()
-        base = (self.params, self.cache, jnp.asarray(self._slot_tokens),
-                jnp.asarray(self._slot_lens), jnp.asarray(self._slot_tables),
-                jnp.asarray(self._slot_active), jax.random.PRNGKey(seed))
-        if self._needs_dynamic_sp(live):
-            toks, lens, self.cache = self._decode_many_dyn_fn(k)(
-                *base, *map(jnp.asarray, sp_arrays(self._slot_sp)))
-        else:
-            toks, lens, self.cache = self._decode_many_fn(
-                k, SamplingParams(greedy=True))(*base)
-        toks = np.asarray(toks)          # [k, B] — the ONLY host sync
-        if self._trace_on:
-            t1 = time.monotonic_ns()
-            self.tracer.complete("decode_quantum", t0, t1, cat="serving",
-                                 k=k, batch=len(live))
-        for d in live:
-            seq = [int(t) for t in toks[:, d.slot]]
-            # KV writes this quantum: the previous last_token, then each
-            # sampled token except the newest (still pending its write)
-            d.tokens.extend([d.last_token] + seq[:-1])
-            d.seen_tokens += k
-            d.last_token = seq[-1]
-            d.generated.extend(seq)
-            self._slot_tokens[d.slot] = seq[-1]
-            self._slot_lens[d.slot] = d.seen_tokens
-            self.state.mark_filled(d)
-            out[d.uid] = seq
-            if self._trace_on:
-                self._req_tokens(d.uid, k, t1)
+        with self.tracer.span("decode_quantum", cat="serving", k=k,
+                              batch=len(live)) as span:
+            with self.tracer.span("engine_prep", cat="serving"):
+                cow = []
+                for d in live:
+                    cow += self.state.ensure_writable(d, d.seen_tokens + k)
+                    self.state.extend(d, n=k)  # reserve ALL k tokens up front
+                    self._slot_tables[d.slot] = self.state.block_table(d)
+                self._copy_blocks(cow)
+            with self.tracer.span("engine_dispatch", cat="serving"):
+                base = (self.params, self.cache,
+                        jnp.asarray(self._slot_tokens),
+                        jnp.asarray(self._slot_lens),
+                        jnp.asarray(self._slot_tables),
+                        jnp.asarray(self._slot_active),
+                        jax.random.PRNGKey(seed))
+                if self._needs_dynamic_sp(live):
+                    toks, lens, self.cache = self._decode_many_dyn_fn(k)(
+                        *base, *map(jnp.asarray, sp_arrays(self._slot_sp)))
+                else:
+                    toks, lens, self.cache = self._decode_many_fn(
+                        k, SamplingParams(greedy=True))(*base)
+            with self.tracer.span("engine_wait", cat="serving"):
+                toks = np.asarray(toks)      # [k, B] — the ONLY host sync
+            t1 = time.monotonic_ns() if self._trace_on else 0
+            with self.tracer.span("engine_emit", cat="serving"):
+                kv = 0
+                for d in live:
+                    seq = [int(t) for t in toks[:, d.slot]]
+                    # KV writes this quantum: the previous last_token, then
+                    # each sampled token except the newest (still pending
+                    # its write)
+                    d.tokens.extend([d.last_token] + seq[:-1])
+                    d.seen_tokens += k
+                    kv += d.seen_tokens
+                    d.last_token = seq[-1]
+                    d.generated.extend(seq)
+                    self._slot_tokens[d.slot] = seq[-1]
+                    self._slot_lens[d.slot] = d.seen_tokens
+                    self.state.mark_filled(d)
+                    out[d.uid] = seq
+                    if self._trace_on:
+                        self._req_tokens(d.uid, k, t1)
+            self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
+            span.set(kv_tokens=kv)
         return out
 
     def finish(self, uid: int) -> List[int]:

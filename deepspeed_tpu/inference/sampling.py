@@ -56,6 +56,7 @@ def filter_logits(logits: jnp.ndarray,
     return logits
 
 
+@jax.named_scope("sample")
 def sample(rng: jax.Array, logits: jnp.ndarray,
            params: SamplingParams = SamplingParams()) -> jnp.ndarray:
     """logits [..., vocab] → token ids [...]. Static sampling params."""
@@ -95,6 +96,7 @@ def filter_logits_batch(logits: jnp.ndarray, temperature: jnp.ndarray,
     return jnp.where(scaled < cutoff, -jnp.inf, filt)
 
 
+@jax.named_scope("sample")
 def sample_batch(rng: jax.Array, logits: jnp.ndarray,
                  temperature: jnp.ndarray, top_k: jnp.ndarray,
                  top_p: jnp.ndarray, greedy: jnp.ndarray) -> jnp.ndarray:

@@ -170,7 +170,15 @@ class ServingScheduler:
             "submitted": 0, "admitted": 0, "resumed": 0, "preempted": 0,
             "rejected": 0, "expired": 0, "completed": 0, "slo_met": 0,
             "slo_missed": 0, "ticks": 0, "chunked_admissions": 0,
-            "tokens_emitted": 0}
+            "tokens_emitted": 0,
+            # what the ticks did (the sched_tick span's arguments, summed):
+            # prompt tokens whose KV a tick wrote, sequences in its decode
+            # batch, ticks that carried prefill work
+            "prefill_tokens": 0, "decode_seq_steps": 0, "chunk_ticks": 0}
+        # the last tick's sched_tick arguments (read-only; the same numbers
+        # a profiler session records on the span)
+        self.last_tick: Dict[str, int] = {}
+        self._admit_tokens = 0   # tokens the current tick's admissions streamed
         self._queue_wait_ms: List[float] = []
         self._e2e_ms: List[float] = []
         self._t0 = self._clock()
@@ -404,21 +412,40 @@ class ServingScheduler:
         self.stats["ticks"] += 1
         if seed is None:
             seed = self.stats["ticks"]
-        t0 = time.monotonic_ns() if self._trace_on else 0
-        now = self._clock()
-        if self.cfg.drop_expired:
-            self._expire(now)
-        n_adm = self._admit(now, seed)
-        n_pre = self._preempt_guard()
-        out = self._step_engine(seed)
-        emitted = self._harvest(out)
-        self._retire()
-        if self._trace_on:
-            self.tracer.complete(
-                "sched_tick", t0, time.monotonic_ns(), cat="serving",
-                admitted=n_adm, preempted=n_pre, live=len(self._live),
-                queued=self.queue_depth,
-                tokens=sum(len(v) for v in emitted.values()))
+        span, eng = self.tracer.span, self.engine
+        with span("sched_tick", cat="serving",
+                  tick=self.stats["ticks"]) as tick:
+            now = self._clock()
+            wrote = eng.prefill_tokens_written
+            self._admit_tokens = 0
+            with span("sched_expire", cat="serving"):
+                if self.cfg.drop_expired:
+                    self._expire(now)
+            with span("sched_admit", cat="serving"):
+                n_adm = self._admit(now, seed)
+            with span("sched_preempt_guard", cat="serving"):
+                n_pre = self._preempt_guard()
+            with span("sched_step_engine", cat="serving"):
+                out, did = self._step_engine(seed)
+            with span("sched_harvest", cat="serving"):
+                emitted = self._harvest(out)
+            with span("sched_retire", cat="serving"):
+                self._retire()
+            self.last_tick = {
+                "tick": self.stats["ticks"], "admitted": n_adm,
+                "preempted": n_pre, "live": len(self._live),
+                "queued": self.queue_depth,
+                "prefill_tokens": eng.prefill_tokens_written - wrote,
+                "decode_seqs": did["decode_seqs"],
+                "kv_tokens": did["kv_tokens"],
+                # every token streamed to a client this tick: an admission's
+                # first token (one-shot prefill, resume) and the harvest's
+                "tokens_out": self._admit_tokens
+                + sum(len(v) for v in emitted.values())}
+            tick.set(**self.last_tick)
+        self.stats["prefill_tokens"] += self.last_tick["prefill_tokens"]
+        self.stats["decode_seq_steps"] += self.last_tick["decode_seqs"]
+        self.stats["chunk_ticks"] += self.last_tick["prefill_tokens"] > 0
         if self.tuning is not None:
             # sched-tick seam: the only point a serving knob may flip —
             # between ticks no request is mid-admission or mid-harvest
@@ -512,7 +539,7 @@ class ServingScheduler:
             if parked is not None:
                 toks = eng.resume(parked, seed=seed,
                                   split=split > 0 and len(tokens) > eff_chunk)
-                h._emit(toks)
+                self._admit_tokens += h._emit(toks)
                 self.stats["resumed"] += 1
             elif split > 0 and len(tokens) > eff_chunk:
                 eng.put_split(uid, tokens, h.request.sp)
@@ -526,7 +553,7 @@ class ServingScheduler:
         for sp, pairs in batches.items():
             first = eng.put_many(pairs, sp, seed=seed)
             for uid, tok in first.items():
-                self.handles[uid]._emit([tok])
+                self._admit_tokens += self.handles[uid]._emit([tok])
         return admitted
 
     def _preempt_guard(self) -> int:
@@ -585,11 +612,16 @@ class ServingScheduler:
                                 kv_tokens=len(parked["history"]))
 
     def _step_engine(self, seed: int):
-        if not self.engine.state.seqs:
-            return {}
-        if self.cfg.decode_quantum > 1 and not self.engine._spec_on:
-            return self.engine.step_many(self.cfg.decode_quantum, seed=seed)
-        return self.engine.step(seed=seed)
+        """One engine step (or fused quantum) → its tokens and what it did
+        (``engine.last_step``; zeros when there was nothing to step)."""
+        eng = self.engine
+        if not eng.state.seqs:
+            return {}, {"decode_seqs": 0, "kv_tokens": 0}
+        if self.cfg.decode_quantum > 1 and not eng._spec_on:
+            out = eng.step_many(self.cfg.decode_quantum, seed=seed)
+        else:
+            out = eng.step(seed=seed)
+        return out, eng.last_step
 
     def _harvest(self, out) -> Dict[int, List[int]]:
         emitted: Dict[int, List[int]] = {}

@@ -26,6 +26,7 @@ from __future__ import annotations
 from contextlib import contextmanager
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..ops.attention import attention
@@ -142,23 +143,24 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
         bs = k_cache.shape[2]
     max_blocks = block_tables.shape[1]
 
-    blk_idx = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-    blk_idx = jnp.where(valid, blk_idx, 0)
-    off = positions % bs
-    # advanced indices (blk_idx, off) straddle the kv-head slice, so the
-    # result dims land in front: [b, t, nkv, hd] — exactly k's layout
-    if quant:
-        # fill-time quantization fused into the cache-update: codes and the
-        # per-(token, head, group) scales scatter in the same program
-        qk, sk = kv_quantize_int8(k, group_size)
-        qv, sv = kv_quantize_int8(v, group_size)
-        k_codes = k_codes.at[blk_idx, :, off].set(qk)
-        v_codes = v_codes.at[blk_idx, :, off].set(qv)
-        k_scales = k_scales.at[blk_idx, :, off].set(sk)
-        v_scales = v_scales.at[blk_idx, :, off].set(sv)
-    else:
-        k_cache = k_cache.at[blk_idx, :, off].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[blk_idx, :, off].set(v.astype(v_cache.dtype))
+    with jax.named_scope("kv_write"):   # the pool update, by its own name
+        blk_idx = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+        blk_idx = jnp.where(valid, blk_idx, 0)
+        off = positions % bs
+        # advanced indices (blk_idx, off) straddle the kv-head slice, so the
+        # result dims land in front: [b, t, nkv, hd] — exactly k's layout
+        if quant:
+            # fill-time quantization fused into the cache-update: codes and
+            # the per-(token, head, group) scales scatter in the same program
+            qk, sk = kv_quantize_int8(k, group_size)
+            qv, sv = kv_quantize_int8(v, group_size)
+            k_codes = k_codes.at[blk_idx, :, off].set(qk)
+            v_codes = v_codes.at[blk_idx, :, off].set(qv)
+            k_scales = k_scales.at[blk_idx, :, off].set(sk)
+            v_scales = v_scales.at[blk_idx, :, off].set(sv)
+        else:
+            k_cache = k_cache.at[blk_idx, :, off].set(k.astype(k_cache.dtype))
+            v_cache = v_cache.at[blk_idx, :, off].set(v.astype(v_cache.dtype))
 
     if t == 1:
         from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)

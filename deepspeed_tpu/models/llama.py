@@ -340,23 +340,30 @@ def _block(cfg: LlamaConfig, x: jnp.ndarray, layer: Params,
             return t
         return lax.with_sharding_constraint(t, res_sharding)
 
-    y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-    q, k, v = _qkv_proj(cfg, y, layer)
-    q = apply_rotary(q, cos, sin, positions)
-    k = apply_rotary(k, cos, sin, positions)
-    # checkpoint names mark the selective-remat saveables (identity outside
-    # a jax.checkpoint policy that targets them — see POLICY_SAVED_NAMES in
-    # runtime/activation_checkpointing/checkpointing.py): "attn_mix" = the
-    # pre-projection attention output (what the wo backward consumes),
-    # "attn_out"/"mlp_out" = the residual-branch projections
-    attn_out = checkpoint_name(attn_fn(q, k, v, causal=True), "attn_mix")
-    x = x + pin(checkpoint_name(
-        attn_out.reshape(b, s, nh * hd) @ layer["wo"], "attn_out"))
+    # the named scopes (norm / attn / ffn) are metadata on the lowered ops:
+    # a trace reduction sums device time by them (docs/observability.md)
+    with jax.named_scope("norm"):
+        y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn"):
+        q, k, v = _qkv_proj(cfg, y, layer)
+        q = apply_rotary(q, cos, sin, positions)
+        k = apply_rotary(k, cos, sin, positions)
+        # checkpoint names mark the selective-remat saveables (identity
+        # outside a jax.checkpoint policy that targets them — see
+        # POLICY_SAVED_NAMES in
+        # runtime/activation_checkpointing/checkpointing.py): "attn_mix" =
+        # the pre-projection attention output (what the wo backward
+        # consumes), "attn_out"/"mlp_out" = the residual-branch projections
+        attn_out = checkpoint_name(attn_fn(q, k, v, causal=True), "attn_mix")
+        x = x + pin(checkpoint_name(
+            attn_out.reshape(b, s, nh * hd) @ layer["wo"], "attn_out"))
 
-    y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-    gate = jax.nn.silu(checkpoint_name(y @ layer["w_gate"], "mlp_gate"))
-    up = checkpoint_name(y @ layer["w_up"], "mlp_up")
-    x = x + pin(checkpoint_name((gate * up) @ layer["w_down"], "mlp_out"))
+    with jax.named_scope("norm"):
+        y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("ffn"):
+        gate = jax.nn.silu(checkpoint_name(y @ layer["w_gate"], "mlp_gate"))
+        up = checkpoint_name(y @ layer["w_up"], "mlp_up")
+        x = x + pin(checkpoint_name((gate * up) @ layer["w_down"], "mlp_out"))
     return x
 
 
@@ -365,8 +372,9 @@ def _head_split(cfg: LlamaConfig, params: Params, x: jnp.ndarray,
     """Final norm + unembed matrix WITHOUT the logits matmul — the
     factorization the tiled fused logits+loss head consumes so [B, S, V]
     is never materialized. ``_head`` composes it back for the dense path."""
-    x = rms_norm(x, params["final_norm"].astype(compute_dtype),
-                 cfg.rms_norm_eps)
+    with jax.named_scope("norm"):
+        x = rms_norm(x, params["final_norm"].astype(compute_dtype),
+                     cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
         head = params["embed"].T
@@ -375,7 +383,8 @@ def _head_split(cfg: LlamaConfig, params: Params, x: jnp.ndarray,
 
 def _head(cfg: LlamaConfig, params: Params, x: jnp.ndarray, compute_dtype):
     x, head = _head_split(cfg, params, x, compute_dtype)
-    return (x @ head).astype(jnp.float32)
+    with jax.named_scope("logits"):
+        return (x @ head).astype(jnp.float32)
 
 
 def apply(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray, *,
@@ -389,7 +398,8 @@ def apply(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray, *,
     ``cfg.remat`` each block is wrapped in ``jax.checkpoint`` so the backward
     pass rematerializes activations (the reference's
     ``runtime/activation_checkpointing``)."""
-    x = embedding_lookup(params["embed"], tokens, compute_dtype)
+    with jax.named_scope("embed"):
+        x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
 
     layers = jax.tree.map(lambda p: p.astype(compute_dtype)
@@ -571,19 +581,23 @@ def _block_paged(cfg: LlamaConfig, x: jnp.ndarray, layer: Params,
     b, t, h = x.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
 
-    y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-    q, k, v = _qkv_proj(cfg, y, layer)
-    q = apply_rotary(q, cos, sin, positions)
-    k = apply_rotary(k, cos, sin, positions)
-    attn_out, k_cache, v_cache = paged_attention_step(
-        q, k, v, k_cache, v_cache, block_tables, context_lens, positions,
-        valid)
-    x = x + attn_out.reshape(b, t, nh * hd) @ layer["wo"]
+    with jax.named_scope("norm"):
+        y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn"):   # the pool update inside is "kv_write"
+        q, k, v = _qkv_proj(cfg, y, layer)
+        q = apply_rotary(q, cos, sin, positions)
+        k = apply_rotary(k, cos, sin, positions)
+        attn_out, k_cache, v_cache = paged_attention_step(
+            q, k, v, k_cache, v_cache, block_tables, context_lens, positions,
+            valid)
+        x = x + attn_out.reshape(b, t, nh * hd) @ layer["wo"]
 
-    y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-    gate = jax.nn.silu(y @ layer["w_gate"])
-    up = y @ layer["w_up"]
-    x = x + (gate * up) @ layer["w_down"]
+    with jax.named_scope("norm"):
+        y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("ffn"):
+        gate = jax.nn.silu(y @ layer["w_gate"])
+        up = y @ layer["w_up"]
+        x = x + (gate * up) @ layer["w_down"]
     return x, k_cache, v_cache
 
 
@@ -600,7 +614,8 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     b, t = tokens.shape
     if valid is None:
         valid = jnp.ones((b, t), bool)
-    x = embedding_lookup(params["embed"], tokens, compute_dtype)
+    with jax.named_scope("embed"):
+        x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
     positions = context_lens[:, None] + jnp.arange(t)[None, :]
 
@@ -614,14 +629,21 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                                    context_lens, valid, cos, sin, positions)
         return x, (k_c, v_c)
 
-    # quantized-KV mode threads (codes, scales) tuples per pool (split_kv)
-    x, (new_k, new_v) = lax.scan(scan_body, x, (layers,) + split_kv(cache))
-    x = rms_norm(x, params["final_norm"].astype(compute_dtype), cfg.rms_norm_eps)
+    # what the scan itself adds around the blocks is pool traffic - each
+    # layer's slice of the pools in, the updated slices stacked back - so it
+    # carries the pool update's name; the blocks' own scopes lie inside it
+    with jax.named_scope("kv_write"):
+        # quantized-KV mode threads (codes, scales) tuples per pool (split_kv)
+        x, (new_k, new_v) = lax.scan(scan_body, x, (layers,) + split_kv(cache))
+    with jax.named_scope("norm"):
+        x = rms_norm(x, params["final_norm"].astype(compute_dtype),
+                     cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
         head = params["embed"].T
-    logits = x @ head.astype(compute_dtype)
-    return logits.astype(jnp.float32), join_kv(new_k, new_v)
+    with jax.named_scope("logits"):
+        logits = (x @ head.astype(compute_dtype)).astype(jnp.float32)
+    return logits, join_kv(new_k, new_v)
 
 
 def model_spec(cfg: LlamaConfig, compute_dtype=jnp.bfloat16):
@@ -734,12 +756,14 @@ def loss_fn(cfg: LlamaConfig, params: Params, batch: Dict[str, jnp.ndarray], *,
     else:
         inputs, labels = tokens[:, :-1], tokens[:, 1:]
     logits = apply(cfg, params, inputs, compute_dtype=compute_dtype)
-    valid = labels != -100
-    safe_labels = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    token_loss = -jnp.take_along_axis(logp, safe_labels[..., None], axis=-1)[..., 0]
-    denom = jnp.maximum(valid.sum(), 1)
-    loss = jnp.where(valid, token_loss, 0.0).sum() / denom
+    with jax.named_scope("loss"):
+        valid = labels != -100
+        safe_labels = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        token_loss = -jnp.take_along_axis(
+            logp, safe_labels[..., None], axis=-1)[..., 0]
+        denom = jnp.maximum(valid.sum(), 1)
+        loss = jnp.where(valid, token_loss, 0.0).sum() / denom
     return loss, {"loss": loss, "ntokens": valid.sum()}
 
 
@@ -760,5 +784,6 @@ def tiled_loss_fn(cfg: LlamaConfig, params: Params,
         inputs, labels = tokens[:, :-1], tokens[:, 1:]
     hidden, head = apply(cfg, params, inputs, compute_dtype=compute_dtype,
                          return_hidden=True)
-    loss = tiled_fused_logits_loss(hidden, head, labels, shards=shards)
+    with jax.named_scope("logits"):    # unembed matmul and CE, fused per tile
+        loss = tiled_fused_logits_loss(hidden, head, labels, shards=shards)
     return loss, {"loss": loss, "ntokens": (labels != -100).sum()}

@@ -151,21 +151,24 @@ def param_logical_axes(cfg: MixtralConfig) -> Params:
 def _head_split(cfg, params, x, compute_dtype):
     """Final norm + unembed matrix minus the logits matmul — consumed by
     the tiled fused logits+loss head (``tiled_loss_fn``)."""
-    x = rms_norm(x, params["final_norm"].astype(compute_dtype),
-                 cfg.rms_norm_eps)
+    with jax.named_scope("norm"):
+        x = rms_norm(x, params["final_norm"].astype(compute_dtype),
+                     cfg.rms_norm_eps)
     return x, params["lm_head"].astype(compute_dtype)
 
 
 def _head(cfg, params, x, compute_dtype):
     x, head = _head_split(cfg, params, x, compute_dtype)
-    return (x @ head).astype(jnp.float32)
+    with jax.named_scope("logits"):
+        return (x @ head).astype(jnp.float32)
 
 
 def apply(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray, *,
           compute_dtype=jnp.bfloat16, return_hidden: bool = False):
     """Forward → (logits [b, s, vocab] fp32, total_aux_loss); with
     ``return_hidden`` → (normed hidden, unembed matrix, total_aux_loss)."""
-    x = embedding_lookup(params["embed"], tokens, compute_dtype)
+    with jax.named_scope("embed"):
+        x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
     moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
                          cfg.min_capacity, cfg.drop_tokens,
@@ -179,25 +182,31 @@ def apply(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray, *,
     def block(x, layer):
         b, s, h = x.shape
         nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
-        y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
-        if "bq" in layer:
-            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-        # selective-remat saveables (identity outside a targeting policy);
-        # see POLICY_SAVED_NAMES in activation_checkpointing/checkpointing
-        q = checkpoint_name(q, "qkv_proj")
-        k = checkpoint_name(k, "qkv_proj")
-        v = checkpoint_name(v, "qkv_proj")
-        q = apply_rotary(q.reshape(b, s, nh, hd), cos, sin)
-        k = apply_rotary(k.reshape(b, s, nkv, hd), cos, sin)
-        v = v.reshape(b, s, nkv, hd)
-        # K/V pass NARROW (nkv heads) into the attention op: widening —
-        # when the gqa_native kernels are off — happens inside the op,
-        # never here (the gqa-native lint traces this apply)
-        x = x + checkpoint_name(
-            checkpoint_name(attention(q, k, v, causal=True), "attn_mix")
-            .reshape(b, s, nh * hd) @ layer["wo"], "attn_out")
-        y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+        # named scopes (norm / attn, and moe_router / moe_experts inside
+        # the MoE layer): metadata a trace reduction sums device time by
+        with jax.named_scope("norm"):
+            y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+        with jax.named_scope("attn"):
+            q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
+            if "bq" in layer:
+                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+            # selective-remat saveables (identity outside a targeting
+            # policy); see POLICY_SAVED_NAMES in
+            # activation_checkpointing/checkpointing
+            q = checkpoint_name(q, "qkv_proj")
+            k = checkpoint_name(k, "qkv_proj")
+            v = checkpoint_name(v, "qkv_proj")
+            q = apply_rotary(q.reshape(b, s, nh, hd), cos, sin)
+            k = apply_rotary(k.reshape(b, s, nkv, hd), cos, sin)
+            v = v.reshape(b, s, nkv, hd)
+            # K/V pass NARROW (nkv heads) into the attention op: widening —
+            # when the gqa_native kernels are off — happens inside the op,
+            # never here (the gqa-native lint traces this apply)
+            x = x + checkpoint_name(
+                checkpoint_name(attention(q, k, v, causal=True), "attn_mix")
+                .reshape(b, s, nh * hd) @ layer["wo"], "attn_out")
+        with jax.named_scope("norm"):
+            y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
         ffn_out, aux = moe_layer(layer["moe"], y)
         return x + checkpoint_name(ffn_out, "mlp_out"), aux
 
@@ -293,9 +302,11 @@ def loss_fn(cfg: MixtralConfig, params: Params, batch: Dict[str, jnp.ndarray], *
     tokens = batch["tokens"]
     inputs, labels = tokens[:, :-1], tokens[:, 1:]
     logits, aux = apply(cfg, params, inputs, compute_dtype=compute_dtype)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    lm_loss = -jnp.mean(jnp.take_along_axis(logp, labels[..., None], axis=-1))
-    loss = lm_loss + cfg.aux_loss_coef * aux
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        lm_loss = -jnp.mean(
+            jnp.take_along_axis(logp, labels[..., None], axis=-1))
+        loss = lm_loss + cfg.aux_loss_coef * aux
     return loss, {"loss": loss, "lm_loss": lm_loss, "aux_loss": aux}
 
 
@@ -312,7 +323,8 @@ def tiled_loss_fn(cfg: MixtralConfig, params: Params,
     hidden, head, aux = apply(cfg, params, inputs,
                               compute_dtype=compute_dtype,
                               return_hidden=True)
-    lm_loss = tiled_fused_logits_loss(hidden, head, labels, shards=shards)
+    with jax.named_scope("logits"):    # unembed matmul and CE, fused per tile
+        lm_loss = tiled_fused_logits_loss(hidden, head, labels, shards=shards)
     loss = lm_loss + cfg.aux_loss_coef * aux
     return loss, {"loss": loss, "lm_loss": lm_loss, "aux_loss": aux}
 
@@ -358,7 +370,8 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
     if valid is None:
         valid = jnp.ones((b, t), bool)
-    x = embedding_lookup(params["embed"], tokens, compute_dtype)
+    with jax.named_scope("embed"):
+        x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
     positions = context_lens[:, None] + jnp.arange(t)[None, :]
     moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
@@ -371,22 +384,33 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
 
     def scan_body(x, scanned):
         layer, k_c, v_c = scanned
-        y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
-        if "bq" in layer:
-            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-        q = apply_rotary(q.reshape(b, t, nh, hd), cos, sin, positions)
-        k = apply_rotary(k.reshape(b, t, nkv, hd), cos, sin, positions)
-        v = v.reshape(b, t, nkv, hd)
-        attn, k_c, v_c = paged_attention_step(
-            q, k, v, k_c, v_c, block_tables, context_lens, positions, valid)
-        x = x + attn.reshape(b, t, nh * hd) @ layer["wo"]
-        y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+        with jax.named_scope("norm"):
+            y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+        with jax.named_scope("attn"):   # the pool update inside is "kv_write"
+            q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
+            if "bq" in layer:
+                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+            q = apply_rotary(q.reshape(b, t, nh, hd), cos, sin, positions)
+            k = apply_rotary(k.reshape(b, t, nkv, hd), cos, sin, positions)
+            v = v.reshape(b, t, nkv, hd)
+            attn, k_c, v_c = paged_attention_step(
+                q, k, v, k_c, v_c, block_tables, context_lens, positions,
+                valid)
+            x = x + attn.reshape(b, t, nh * hd) @ layer["wo"]
+        with jax.named_scope("norm"):
+            y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
         ffn_out, _aux = moe_layer(layer["moe"], y)
         return x + ffn_out, (k_c, v_c)
 
-    x, (nk, nv) = lax.scan(scan_body, x, (layers,) + split_kv(cache))
-    x = rms_norm(x, params["final_norm"].astype(compute_dtype),
-                 cfg.rms_norm_eps)
-    logits = x @ params["lm_head"].astype(compute_dtype)
-    return logits.astype(jnp.float32), join_kv(nk, nv)
+    # what the scan itself adds around the blocks is pool traffic - each
+    # layer's slice of the pools in, the updated slices stacked back - so it
+    # carries the pool update's name; the blocks' own scopes lie inside it
+    with jax.named_scope("kv_write"):
+        x, (nk, nv) = lax.scan(scan_body, x, (layers,) + split_kv(cache))
+    with jax.named_scope("norm"):
+        x = rms_norm(x, params["final_norm"].astype(compute_dtype),
+                     cfg.rms_norm_eps)
+    with jax.named_scope("logits"):
+        logits = (x @ params["lm_head"].astype(compute_dtype)).astype(
+            jnp.float32)
+    return logits, join_kv(nk, nv)

@@ -90,71 +90,75 @@ class MoELayer:
         b, s, h = x.shape
         tokens = x.reshape(b * s, h)
         T = tokens.shape[0]
-        logits = tokens @ params["router"].astype(tokens.dtype)
-        gate_kw = dict(capacity_factor=self.capacity_factor,
-                       min_capacity=self.min_capacity,
-                       drop_tokens=self.drop_tokens,
-                       norm_topk=self.norm_topk)
+        # moe_router: router logits, gating and the dispatch of tokens to
+        # expert slots; moe_experts: the expert bank and the combine
+        with jax.named_scope("moe_router"):
+            logits = tokens @ params["router"].astype(tokens.dtype)
+            gate_kw = dict(capacity_factor=self.capacity_factor,
+                           min_capacity=self.min_capacity,
+                           drop_tokens=self.drop_tokens,
+                           norm_topk=self.norm_topk)
 
-        # dispatch to [E, C, H], then expert-shard (a2a)
-        if self.dispatch == "compact":
-            # O(k·T) end to end: the gating stays compact (no [T, E, C]
-            # tensor ever exists) and the (expert, slot) → token table +
-            # per-slot gate come from two scatters — the computation the
-            # reference's moe_scatter/top_k_gating kernels perform
-            # (inference/v2/kernels/ragged_ops)
-            cg = top_k_gating_compact(logits, self.top_k, **gate_kw)
-            aux_loss = cg.aux_loss
-            E, C = self.n_experts, cg.capacity
-            t_ids = jnp.broadcast_to(
-                jnp.arange(T, dtype=jnp.int32)[:, None], cg.pos.shape)
-            e_flat = jnp.where(cg.keep, cg.topk_idx, E).reshape(-1)
-            p_flat = cg.pos.reshape(-1)
-            # distinct (expert, slot) pairs are unique by construction, so
-            # .set scatters can't collide; dropped entries go out of bounds
-            token_for = jnp.full((E, C), T, jnp.int32).at[
-                e_flat, p_flat].set(t_ids.reshape(-1), mode="drop")
-            w_for = jnp.zeros((E, C), jnp.float32).at[
-                e_flat, p_flat].set(cg.gates.reshape(-1), mode="drop")
-            toks_z = jnp.concatenate(
-                [tokens, jnp.zeros((1, h), tokens.dtype)])
-            expert_in = toks_z[token_for]                         # gather
-        else:
-            gating: GatingOutput = top_k_gating(logits, self.top_k, **gate_kw)
-            aux_loss = gating.aux_loss
-            expert_in = jnp.einsum(
-                "tec,th->ech", gating.dispatch_mask.astype(tokens.dtype),
-                tokens)
-        expert_in = _expert_constraint(expert_in)
+            # dispatch to [E, C, H], then expert-shard (a2a)
+            if self.dispatch == "compact":
+                # O(k·T) end to end: the gating stays compact (no [T, E, C]
+                # tensor ever exists) and the (expert, slot) → token table +
+                # per-slot gate come from two scatters — the computation the
+                # reference's moe_scatter/top_k_gating kernels perform
+                # (inference/v2/kernels/ragged_ops)
+                cg = top_k_gating_compact(logits, self.top_k, **gate_kw)
+                aux_loss = cg.aux_loss
+                E, C = self.n_experts, cg.capacity
+                t_ids = jnp.broadcast_to(
+                    jnp.arange(T, dtype=jnp.int32)[:, None], cg.pos.shape)
+                e_flat = jnp.where(cg.keep, cg.topk_idx, E).reshape(-1)
+                p_flat = cg.pos.reshape(-1)
+                # distinct (expert, slot) pairs are unique by construction, so
+                # .set scatters can't collide; dropped entries go out of bounds
+                token_for = jnp.full((E, C), T, jnp.int32).at[
+                    e_flat, p_flat].set(t_ids.reshape(-1), mode="drop")
+                w_for = jnp.zeros((E, C), jnp.float32).at[
+                    e_flat, p_flat].set(cg.gates.reshape(-1), mode="drop")
+                toks_z = jnp.concatenate(
+                    [tokens, jnp.zeros((1, h), tokens.dtype)])
+                expert_in = toks_z[token_for]                         # gather
+            else:
+                gating: GatingOutput = top_k_gating(logits, self.top_k, **gate_kw)
+                aux_loss = gating.aux_loss
+                expert_in = jnp.einsum(
+                    "tec,th->ech", gating.dispatch_mask.astype(tokens.dtype),
+                    tokens)
+        with jax.named_scope("moe_experts"):
+            expert_in = _expert_constraint(expert_in)
 
-        # expert FFN bank, vmapped over E (each expert's compute lands on its
-        # own 'expert' shard)
-        def ffn(w_gate, w_up, w_down, xe):
-            g = jax.nn.silu(xe @ w_gate)
-            u = xe @ w_up
-            return (g * u) @ w_down
+            # expert FFN bank, vmapped over E (each expert's compute lands on its
+            # own 'expert' shard)
+            def ffn(w_gate, w_up, w_down, xe):
+                g = jax.nn.silu(xe @ w_gate)
+                u = xe @ w_up
+                return (g * u) @ w_down
 
-        expert_out = jax.vmap(ffn)(params["w_gate"].astype(tokens.dtype),
-                                   params["w_up"].astype(tokens.dtype),
-                                   params["w_down"].astype(tokens.dtype),
-                                   expert_in)
-        expert_out = _expert_constraint(expert_out)
+            expert_out = jax.vmap(ffn)(params["w_gate"].astype(tokens.dtype),
+                                       params["w_up"].astype(tokens.dtype),
+                                       params["w_down"].astype(tokens.dtype),
+                                       expert_in)
+            expert_out = _expert_constraint(expert_out)
 
-        # combine: back to [T, H]  (a2a back)
-        if self.dispatch == "compact":
-            out = jnp.zeros_like(tokens).at[token_for.reshape(-1)].add(
-                (expert_out * w_for[..., None].astype(tokens.dtype))
-                .reshape(-1, h), mode="drop")
-        else:
-            out = jnp.einsum(
-                "tec,ech->th", gating.combine_weights.astype(tokens.dtype),
-                expert_out)
-        # Qwen2-MoE shared expert: a dense SwiGLU added to every token,
-        # scaled by a learned sigmoid gate (params present only when used)
-        if "shared_w_gate" in params:
-            sg = jax.nn.silu(tokens @ params["shared_w_gate"].astype(tokens.dtype))
-            su = tokens @ params["shared_w_up"].astype(tokens.dtype)
-            shared = (sg * su) @ params["shared_w_down"].astype(tokens.dtype)
-            gate = jax.nn.sigmoid(tokens @ params["shared_gate"].astype(tokens.dtype))
-            out = out + gate * shared
+            # combine: back to [T, H]  (a2a back)
+            if self.dispatch == "compact":
+                out = jnp.zeros_like(tokens).at[token_for.reshape(-1)].add(
+                    (expert_out * w_for[..., None].astype(tokens.dtype))
+                    .reshape(-1, h), mode="drop")
+            else:
+                out = jnp.einsum(
+                    "tec,ech->th", gating.combine_weights.astype(tokens.dtype),
+                    expert_out)
+            # Qwen2-MoE shared expert: a dense SwiGLU added to every token,
+            # scaled by a learned sigmoid gate (params present only when used)
+            if "shared_w_gate" in params:
+                sg = jax.nn.silu(tokens @ params["shared_w_gate"].astype(tokens.dtype))
+                su = tokens @ params["shared_w_up"].astype(tokens.dtype)
+                shared = (sg * su) @ params["shared_w_down"].astype(tokens.dtype)
+                gate = jax.nn.sigmoid(tokens @ params["shared_gate"].astype(tokens.dtype))
+                out = out + gate * shared
         return out.reshape(b, s, h), aux_loss

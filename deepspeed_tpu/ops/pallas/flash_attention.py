@@ -433,6 +433,7 @@ def _flash_fwd(q, k, v, bias=None, *, causal, scale, q_offset, g=1,
         ],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="flash_fwd",
     )(*args)
     if g > 1:
         return o[:, :, :sq], lse[:, :, :sq]
@@ -662,6 +663,7 @@ def _flash_bwd(q, k, v, o, lse, do, bias=None, *, causal, scale, q_offset,
         scratch_shapes=[pltpu.VMEM((g * bq, d), jnp.float32)],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(*dq_args)
     if has_bias:
         dq, dbias = dq_out
@@ -720,6 +722,7 @@ def _flash_bwd(q, k, v, o, lse, do, bias=None, *, causal, scale, q_offset,
         ],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(*dkv_args)
     if g > 1:
         return dq[:, :, :sq], dk[:, :kv_len], dv[:, :kv_len], dbias

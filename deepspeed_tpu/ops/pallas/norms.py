@@ -44,6 +44,7 @@ def _rms_fwd_pallas(x2, w, eps):
         out_shape=jax.ShapeDtypeStruct((np_, d), x2.dtype),
         compiler_params=_dim_semantics("parallel"),
         interpret=_interpret(),
+        name="rms_norm_fwd",
     )(x2, w)
     return out[:n]
 
@@ -108,6 +109,7 @@ def _ln_fwd_pallas(x2, w, b, eps):
         out_shape=jax.ShapeDtypeStruct((np_, d), x2.dtype),
         compiler_params=_dim_semantics("parallel"),
         interpret=_interpret(),
+        name="layer_norm_fwd",
     )(x2, w, b)
     return out[:n]
 

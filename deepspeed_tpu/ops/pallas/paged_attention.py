@@ -229,6 +229,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, nkv, gpad, hd), q.dtype),
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="paged_decode",
     )(*prefetch, *operands)
     return out[:, :, :g].reshape(B, nh, hd)
 
@@ -468,6 +469,7 @@ def paged_spec_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, nkv, rpad, hd), q.dtype),
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="paged_spec_verify",
     )(*prefetch, *operands)
     return out[:, :, :g * t].reshape(B, nkv, g, t, hd) \
         .transpose(0, 3, 1, 2, 4).reshape(B, t, nh, hd)

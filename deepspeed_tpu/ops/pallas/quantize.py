@@ -59,6 +59,7 @@ def quantize_int8_pallas(x: jnp.ndarray, group_size: int = 2048):
                    jax.ShapeDtypeStruct((np_, 128), jnp.float32)],
         compiler_params=_dim_semantics("parallel"),
         interpret=_interpret(),
+        name="quantize_int8",
     )(x2)
     return q[:n].reshape(shape), s[:n, 0]
 
@@ -80,5 +81,6 @@ def dequantize_int8_pallas(q: jnp.ndarray, scales: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((np_, group_size), dtype),
         compiler_params=_dim_semantics("parallel"),
         interpret=_interpret(),
+        name="dequantize_int8",
     )(q2, s2)
     return out[:n].reshape(shape)

@@ -252,6 +252,7 @@ def _sparse_fwd_lse(q, k, v, layout, block_size, *, causal, scale):
                    jax.ShapeDtypeStruct((b * h, s, 128), jnp.float32)],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="sparse_flash_fwd",
     )(jnp.asarray(idx), jnp.asarray(counts), _to_bh(q, b, h, s, d),
       _to_bh(k, b, h, s, d), _to_bh(v, b, h, s, d))
     return _from_bh(o, b, h, s, d), lse
@@ -314,6 +315,7 @@ def sparse_flash_attention_bwd(q, k, v, o, lse, do, layout, block_size, *,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="sparse_flash_bwd_dq",
     )(jnp.asarray(idx), jnp.asarray(counts), q_bh, k_bh, v_bh, do_bh, lse,
       delta)
 
@@ -342,6 +344,7 @@ def sparse_flash_attention_bwd(q, k, v, o, lse, do, layout, block_size, *,
                    jax.ShapeDtypeStruct((b * h, s, d), v.dtype)],
         compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
         interpret=_interpret(),
+        name="sparse_flash_bwd_dkv",
     )(jnp.asarray(idx_t), jnp.asarray(counts_t), q_bh, k_bh, v_bh, do_bh,
       lse, delta)
     return (_from_bh(dq, b, h, s, d), _from_bh(dk, b, h, s, d),

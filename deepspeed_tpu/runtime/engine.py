@@ -35,7 +35,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..comm import comm as dist
 from ..comm.mesh import BATCH_AXES, MeshManager, init_mesh
 from ..ops.optimizers import Optimizer, get_optimizer
-from ..telemetry.profiler import annotate as _annotate
 from ..utils.compile_cache import enable_compile_cache
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, BACKWARD_MICRO_TIMER,
@@ -1577,6 +1576,7 @@ class DeepSpeedTPUEngine:
         grads = jax.lax.with_sharding_constraint(grads, self._grad_shardings)
         return grads, loss, aux, new_residuals
 
+    @jax.named_scope("optimizer")   # unscale, clip, the optimizer's update
     def _apply_update(self, state: TrainState, grads, loss, aux=None,
                       lr_override=None,
                       loco_residual=None) -> Tuple[TrainState, StepOutput]:
@@ -1730,16 +1730,16 @@ class DeepSpeedTPUEngine:
             self._build_breakdown_steps()
         t = self.timers
         tracer = self.telemetry.tracer
-        with _annotate("fwd"), tracer.span("train/fwd", cat="train"):
+        with tracer.span("train/fwd", cat="train"):
             t(FORWARD_GLOBAL_TIMER).start(sync=True)
             self._fwd_step(self.state.params, batch)
             t(FORWARD_GLOBAL_TIMER).stop(sync=True)
-        with _annotate("bwd"), tracer.span("train/bwd", cat="train"):
+        with tracer.span("train/bwd", cat="train"):
             t(BACKWARD_GLOBAL_TIMER).start()
             grads, loss, aux = self._bwd_step(self.state.params, batch,
                                               self.state.loss_scale)
             t(BACKWARD_GLOBAL_TIMER).stop(sync=True)
-        with _annotate("step"), tracer.span("train/step", cat="train"):
+        with tracer.span("train/step", cat="train"):
             t(STEP_GLOBAL_TIMER).start()
             self.state, out = self._apply_step(self.state, grads, loss,
                                                self._lr_override)
@@ -1820,7 +1820,15 @@ class DeepSpeedTPUEngine:
 
     def train_batch(self, batch) -> StepOutput:
         """One full optimizer step from one global batch (all GAS micro-batches
-        stacked in the leading dim)."""
+        stacked in the leading dim). One ``train_step`` span: a step event
+        on the profiler's timeline, with the host phases of the step as its
+        children (docs/observability.md)."""
+        with self.telemetry.tracer.step_span(
+                "train_step", self.global_steps + 1, cat="train"):
+            return self._train_batch(batch)
+
+    def _train_batch(self, batch) -> StepOutput:
+        tracer = self.telemetry.tracer
         self.global_tokens += self._count_batch_tokens(batch)
         if self._nvme_opt is not None:
             return self._train_batch_nvme(batch)
@@ -1836,13 +1844,14 @@ class DeepSpeedTPUEngine:
         if self.curriculum_scheduler is not None:
             # difficulty = seq length; each bucket is its own cached jit
             batch = self.curriculum_scheduler.truncate(batch, self.global_steps)
-        batch = self._shard_batch(batch, with_gas_dim=True)
+        with tracer.span("train_shard_batch", cat="train"):
+            batch = self._shard_batch(batch, with_gas_dim=True)
         if not self._flops_estimated and self.config.flops_profiler.enabled:
             self._estimate_step_flops(batch)
         if breakdown:
             self.timers(TRAIN_BATCH_TIMER).start()
-            with self.telemetry.tracer.span("train/train_batch", cat="train",
-                                            step=self.global_steps + 1):
+            with tracer.span("train/train_batch", cat="train",
+                             step=self.global_steps + 1):
                 out = self._train_batch_breakdown(batch)
             self.timers(TRAIN_BATCH_TIMER).stop(sync=False)
         else:
@@ -1850,20 +1859,25 @@ class DeepSpeedTPUEngine:
             # the live step donates the state buffers it reads
             if self.integrity is not None:
                 self.integrity.pre_step(self, batch)
-            # the fused step is ONE XLA program — a single span (the phase
-            # split only exists under wall_clock_breakdown)
-            with self.telemetry.tracer.span("train/train_batch", cat="train",
-                                            step=self.global_steps + 1):
+            # the fused step is ONE XLA program — a single span around its
+            # dispatch (the phase split only exists under
+            # wall_clock_breakdown)
+            with tracer.span("train/train_batch", cat="train",
+                             step=self.global_steps + 1):
                 self.state, out = self._train_step(self.state, batch,
                                                    self._lr_override)
         self.global_steps += 1
         self._last_grad_norm = out.grad_norm
         self.lr_scheduler.last_step = self.global_steps
-        self.tput_timer.stop()
-        self._write_monitor_events(out)
-        self.telemetry.step_end(self.global_steps,
-                                step_time_s=self.tput_timer.avg_step_time()
-                                or None)
+        with tracer.span("train_sync", cat="train"):
+            # asks every device to drain (utils/timer.py); on the v5e the
+            # call returns at once (PERF.md section 5)
+            self.tput_timer.stop()
+        with tracer.span("train_step_end", cat="train"):
+            self._write_monitor_events(out)
+            self.telemetry.step_end(
+                self.global_steps,
+                step_time_s=self.tput_timer.avg_step_time() or None)
         if self.tuning is not None:
             # optimizer-step seam: the only point a training knob may flip
             # (an apply invalidates the cached step — next batch rebuilds).
@@ -1998,7 +2012,7 @@ class DeepSpeedTPUEngine:
                 "eval_step", lambda p, b: self._loss(p, b)[0])
         batch = self._shard_batch(batch, with_gas_dim=False)
         breakdown = self.wall_clock_breakdown()
-        with _annotate("eval_batch"):
+        with self.telemetry.tracer.span("train/eval_batch", cat="train"):
             if breakdown:
                 self.timers("eval_batch").start(sync=True)
             loss = self._eval_step(self.state.params, batch)

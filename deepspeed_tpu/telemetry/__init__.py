@@ -20,7 +20,7 @@ from .compile import (CompileMonitor, CompileMonitorConfig,  # noqa: F401
 from .hub import TelemetryHub  # noqa: F401
 from .memory import MemoryTelemetry  # noqa: F401
 from .metrics_server import MetricsServer  # noqa: F401
-from .profiler import ProfilerSession, annotate  # noqa: F401
+from .profiler import ProfilerSession  # noqa: F401
 from .schema import (ANOMALY_SERIES, COMPILE_METRICS,  # noqa: F401
                      SERVING_SERIES, validate_events,
                      validate_jsonl_records)

@@ -17,10 +17,13 @@ the flops went). This module answers both:
   wall time, the abstract-shape signature that triggered it, cache hits vs
   misses, and **recompile detection** (same program name, new signature)
   with a config-gated budget that warns or raises after N unexpected
-  recompiles in steady state.
+  recompiles in steady state. Each lower + compile runs inside a
+  ``compile`` span of the tracer (ring and profiler timeline).
 - Each compile pulls ``lower(...).compile().cost_analysis()`` flops/bytes
   (guarded — backends may return ``None``), giving the TelemetryHub an
-  analytic per-program cost model: the headline MFU decomposes into
+  analytic per-program cost model - and ``memory_analysis()`` the bytes the
+  program holds at its fullest point
+  (``Compile/<program>/peak_memory_bytes``): the headline MFU decomposes into
   ``Train/mfu/<program>`` and ``Serving/mfu/<program>`` gauges (prefill vs
   decode vs train-step) instead of one ThroughputTimer number.
 
@@ -92,6 +95,7 @@ class ProgramStats:
     compile_ms: float = 0.0         # cumulative backend-compile wall time
     cost_flops: float = 0.0         # per-call flops (last compile's analysis)
     cost_bytes: float = 0.0         # per-call bytes accessed (last compile)
+    peak_memory_bytes: int = 0      # largest compiled signature's device peak
     calls_since_drain: int = 0      # executions since the last events() drain
     signatures: List[Any] = field(default_factory=list)
 
@@ -169,6 +173,17 @@ def _cost_analysis(compiled) -> Tuple[float, float]:
         return 0.0, 0.0
 
 
+def _peak_memory_bytes(compiled) -> int:
+    """Bytes the compiled program holds on a device at the fullest point of
+    its run (arguments, results and temporaries live together) from
+    ``memory_analysis()``; 0 where the backend gives none. The allocator's
+    ``peak_bytes_in_use`` does not see a program's temporaries."""
+    try:
+        return max(0, int(compiled.memory_analysis().peak_memory_in_bytes))
+    except Exception:
+        return 0
+
+
 class MonitoredFunction:
     """A jitted entry point dispatching through the monitor's own
     signature → compiled-program cache. A signature miss runs the explicit
@@ -212,21 +227,26 @@ class MonitoredFunction:
                 # die with a confusing secondary error instead.
                 self._degrade(f"AOT dispatch: {e}")
                 return self._jitted(*args, **kwargs)
-        try:
-            t0 = time.perf_counter()
-            lowered = self._jitted.lower(*args, **kwargs)
-            t1 = time.perf_counter()
-            compiled = lowered.compile()
-            t2 = time.perf_counter()
-        except Exception as e:
-            self._degrade(f"lower/compile: {e}")
-            return self._jitted(*args, **kwargs)
-        self._compiled[sig] = compiled
-        # budget enforcement may raise — record AFTER caching the program so
-        # a caller that catches RecompileBudgetExceeded can still proceed
-        self._monitor._record_compile(
-            self._name, self._group, sig, lower_ms=(t1 - t0) * 1e3,
-            compile_ms=(t2 - t1) * 1e3, compiled=compiled)
+        # one span around lower + compile: on the profiler's timeline a
+        # recompilation covers the device idle gap it causes
+        with self._monitor.tracer.span("compile", cat="compile",
+                                       program=self._name) as span:
+            try:
+                t0 = time.perf_counter()
+                lowered = self._jitted.lower(*args, **kwargs)
+                t1 = time.perf_counter()
+                compiled = lowered.compile()
+                t2 = time.perf_counter()
+            except Exception as e:
+                self._degrade(f"lower/compile: {e}")
+                return self._jitted(*args, **kwargs)
+            self._compiled[sig] = compiled
+            # budget enforcement may raise — record AFTER caching the program
+            # so a caller that catches RecompileBudgetExceeded can still
+            # proceed
+            self._monitor._record_compile(
+                self._name, self._group, sig, lower_ms=(t1 - t0) * 1e3,
+                compile_ms=(t2 - t1) * 1e3, compiled=compiled, span=span)
         return compiled(*args, **kwargs)
 
     def _degrade(self, why: str) -> None:
@@ -294,10 +314,11 @@ class CompileMonitor:
             self._dispatch_t0.setdefault(st.group, time.monotonic())
 
     def _record_compile(self, name: str, group: str, sig, lower_ms: float,
-                        compile_ms: float, compiled) -> None:
+                        compile_ms: float, compiled, span) -> None:
         flops = bytes_ = 0.0
         if self.cost_analysis:
             flops, bytes_ = _cost_analysis(compiled)
+        peak = _peak_memory_bytes(compiled)
         with self._lock:
             st = self.stats[name]
             recompile = len(st.signatures) >= 1
@@ -312,6 +333,7 @@ class CompileMonitor:
                 st.cost_flops = flops
             if bytes_ > 0:
                 st.cost_bytes = bytes_
+            st.peak_memory_bytes = max(st.peak_memory_bytes, peak)
             if unexpected:
                 self.unexpected_recompiles += 1
             over = (self.recompile_budget > 0 and not self._budget_tripped
@@ -321,10 +343,8 @@ class CompileMonitor:
             # _record_compile runs after lower+compile finished, so this
             # marks the start of the group's executed window
             self._dispatch_t0.setdefault(group, time.monotonic())
-        self.tracer.instant("compile", cat="compile", program=name,
-                            lower_ms=round(lower_ms, 3),
-                            compile_ms=round(compile_ms, 3),
-                            recompile=recompile)
+        span.set(lower_ms=round(lower_ms, 3), compile_ms=round(compile_ms, 3),
+                 recompile=recompile)
         if recompile:
             logger.warning(
                 f"recompilation detected: program '{name}' compiled a new "
@@ -353,6 +373,7 @@ class CompileMonitor:
                         "lower_ms": st.lower_ms, "compile_ms": st.compile_ms,
                         "cost_flops": st.cost_flops,
                         "cost_bytes": st.cost_bytes,
+                        "peak_memory_bytes": st.peak_memory_bytes,
                         "signatures": len(st.signatures)}
                     for n, st in self.stats.items()}
 
@@ -415,6 +436,9 @@ class CompileMonitor:
                 if st.cost_bytes > 0:
                     events.append((f"Compile/{name}/cost_bytes",
                                    st.cost_bytes, step))
+                if st.peak_memory_bytes > 0:
+                    events.append((f"Compile/{name}/peak_memory_bytes",
+                                   float(st.peak_memory_bytes), step))
                 if peak_total and st.cost_flops > 0 \
                         and st.calls_since_drain > 0:
                     mfu = (st.cost_flops * st.calls_since_drain

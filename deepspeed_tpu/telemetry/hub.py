@@ -72,11 +72,13 @@ class TelemetryHub:
                            debug=cl.debug)
         self.comms = dist.get_telemetry()
         # span tracer + crash flight recorder (telemetry/trace.py), gated by
-        # the telemetry.trace config block; default OFF → a shared null span
-        # and zero ring allocation beyond the deque itself
+        # the telemetry.trace config block; default OFF → zero ring
+        # allocation beyond the deque itself. The spans also go to the
+        # profiler's timeline, which records them while a profiler session
+        # runs, whatever that block says
         self.tracer = Tracer(
             getattr(getattr(config, "telemetry", None), "trace", None),
-            name="train")
+            name="train", annotate=jax.profiler.TraceAnnotation)
         # Reliability/* counters (checkpoint commits/rollbacks, watchdog
         # trips, preemptions) — counted on every rank for tests/reports,
         # written through the monitor on rank 0

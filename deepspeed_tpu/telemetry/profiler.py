@@ -3,14 +3,13 @@
 ``ProfilerSession`` brackets a window of global steps with
 ``jax.profiler.start_trace`` / ``stop_trace`` (the xprof/tensorboard trace the
 T3-style overlap analysis needs), driven by the ``profiler`` config block:
-``{"enabled", "start_step", "end_step", "output_dir"}``. ``annotate(name)``
-wraps host-side phases in ``TraceAnnotation`` spans so fwd/bwd/step show up
-named on the trace timeline.
+``{"enabled", "start_step", "end_step", "output_dir"}``. While a session
+runs, every ``Tracer.span`` of the program is on its timeline as
+``dstpu:<name>`` (telemetry/trace.py).
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
 import tempfile
 from typing import Optional
@@ -18,15 +17,6 @@ from typing import Optional
 import jax
 
 from ..utils.logging import log_dist, logger
-
-
-def annotate(name: str):
-    """A named host-span context for the profiler timeline (no-op when the
-    profiler machinery is unavailable)."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
 
 
 class ProfilerSession:

@@ -59,7 +59,7 @@ __all__ = ["EVENT_NAME_RE", "SERVING_SERIES", "TRAIN_SERIES",
            "MEMORY_TIER_SERIES", "RELIABILITY_ELASTIC_SERIES",
            "RELIABILITY_INTEGRITY_SERIES",
            "TENANT_METRICS", "FLEET_REPLICA_METRICS", "FLEET_AGG_SERIES",
-           "FLEET_OUTLIER_SERIES", "TRACER_INSTANTS",
+           "FLEET_OUTLIER_SERIES", "TRACER_INSTANTS", "TRACER_SPANS",
            "TUNE_TOTAL_SERIES", "TUNE_KNOB_METRICS",
            "MFU_SEGMENT_RE", "ANOMALY_PHASES",
            "REMAT_POLICIES", "validate_events", "validate_jsonl_records"]
@@ -96,7 +96,11 @@ SERVING_SERIES = frozenset(
     + ["Serving/sched/" + m for m in (
         "submitted", "admitted", "resumed", "preempted", "rejected",
         "expired", "completed", "slo_met", "slo_missed", "ticks",
-        "chunked_admissions", "tokens_emitted", "queue_depth",
+        "chunked_admissions", "tokens_emitted",
+        # what the ticks did, counted where the work happens: prompt tokens
+        # whose KV a tick wrote, sequences in its decode batch, and ticks
+        # that carried prefill work (the sched_tick span's arguments, summed)
+        "prefill_tokens", "decode_seq_steps", "chunk_ticks", "queue_depth",
         "queue_wait_ms_p50", "queue_wait_ms_p90", "queue_wait_ms_p99",
         "queue_wait_ms_count", "goodput_frac", "goodput_rps")]
     # multi-replica router (serving/router.py router_events)
@@ -187,7 +191,7 @@ COMM_RING_SERIES = frozenset(
 # CLOSED metric set; the Compile/total/* rollup family is fully enumerated.
 COMPILE_METRICS = frozenset((
     "compiles", "cache_hits", "recompiles", "lower_ms", "compile_ms",
-    "cost_flops", "cost_bytes"))
+    "cost_flops", "cost_bytes", "peak_memory_bytes"))
 COMPILE_TOTAL_SERIES = frozenset(
     "Compile/total/" + m for m in (
         "programs", "compiles", "cache_hits", "recompiles", "lower_ms",
@@ -274,10 +278,12 @@ _FLEET_REPLICA_RE = re.compile(r"^Fleet/replica\d+/([A-Za-z0-9_]+)$")
 # telemetry_report --trace key off). CLOSED: a new instant name must be
 # registered here (a tier-1 test pins exported traces against this set).
 TRACER_INSTANTS = frozenset((
-    # tracer/hub/compile internals
-    "trace_begin", "anomaly", "compile",
+    # tracer/hub internals
+    "trace_begin", "anomaly",
+    # trace-time marker of the gradient-bucket flush (runtime/engine.py)
+    "overlap/bucket_flush",
     # serving request lifecycle (engine_v2)
-    "first_token", "decode_token", "parked", "resumed",
+    "first_token", "parked", "resumed",
     # scheduler + fleet resilience (serving/scheduler.py, fleet.py, router)
     "sched_preempt", "degrade", "rehome", "failover",
     "circuit_open", "circuit_closed",
@@ -287,6 +293,34 @@ TRACER_INSTANTS = frozenset((
     "trace_handoff", "slo_burn_alert",
     # online tuner arm transitions (tuning/tuner.py — docs/tuning.md)
     "tune_step", "tune_revert"))
+
+# Registered tracer SPAN names (Tracer.span / step_span / begin / complete
+# call sites). CLOSED, like the instants. A context-managed span is also on
+# the profiler's timeline as ``dstpu:<name>`` (trace.TIMELINE_PREFIX), where
+# the benchmark's per-layer metrics find it by this name — renaming one
+# changes a yardstick (docs/observability.md "Spans on the profiler
+# timeline" has the tree and the arguments).
+TRACER_SPANS = frozenset((
+    # training (runtime/engine.py): the step and its host phases ...
+    "train_step", "train_shard_batch", "train_sync", "train_step_end",
+    # ... the dispatch of the step's program(s), and the per-program phases
+    # of the breakdown / API-parity paths
+    "train/train_batch", "train/fwd", "train/bwd", "train/step",
+    "train/fwd_micro", "train/eval_batch",
+    "checkpoint/save", "checkpoint/publish",
+    # one lower + compile of a monitored program (telemetry/compile.py)
+    "compile",
+    # request lifecycle, ring only (engine_v2; telemetry/fleet.py)
+    "request", "replica_leg", "queue_wait", "prefill",
+    # scheduler tick and its phases (serving/scheduler.py)
+    "sched_tick", "sched_expire", "sched_admit", "sched_preempt_guard",
+    "sched_step_engine", "sched_harvest", "sched_retire",
+    # one engine dispatch and its host phases (engine_v2)
+    "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
+    "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
+    "engine_emit",
+    # v1 generate loop (inference/engine.py)
+    "generate/prefill", "generate/decode_chunk"))
 
 # Registered Tune/* series (the self-tuning runtime — tuning/tuner.py;
 # docs/tuning.md): the Tune/total/* rollup family is fully enumerated, and

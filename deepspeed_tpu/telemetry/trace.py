@@ -24,9 +24,23 @@ training config tree and ``InferenceConfig``). Default **OFF**: a disabled
 tracer allocates nothing, records nothing, and returns a shared null span,
 so the default step/serving paths are event-free (pinned by parity tests).
 
+**The profiler's timeline.** The config block governs the RING only. A
+tracer built with ``annotate=jax.profiler.TraceAnnotation`` (the engines
+and the hub do) also opens every :meth:`Tracer.span` as a profiler
+annotation named ``dstpu:<name>`` with the span's args as the event's
+stats, whether or not the ring is enabled: one call site feeds both. The
+profiler records an annotation only while a profiler session is running
+(``jax.profiler.start_trace`` / the ``profiler`` config block) and it then
+sits on the clock of the device trace; with no session it costs about a
+microsecond. Only context-managed spans reach the timeline — an annotation
+must close on the thread that opened it, innermost first, which neither a
+:meth:`Tracer.begin` handle (a request open across calls) nor a
+:meth:`Tracer.complete` interval promises; those stay in the ring alone.
+
 Deliberately stdlib-only (no jax/numpy): the serving engine, the fault
 harness, and offline tooling all import it, and a trace must be dumpable
-from any thread at any point of a dying process.
+from any thread at any point of a dying process. That is why the annotation
+factory comes from the caller.
 """
 
 from __future__ import annotations
@@ -44,7 +58,10 @@ from dataclasses import dataclass
 from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 __all__ = ["TraceConfig", "Tracer", "Span", "NULL_SPAN", "NULL_TRACER",
-           "dump_all", "percentiles"]
+           "TIMELINE_PREFIX", "dump_all", "percentiles"]
+
+#: how a trace reduction finds the program's spans on the profiler's timeline
+TIMELINE_PREFIX = "dstpu:"
 
 
 @dataclass
@@ -113,12 +130,12 @@ class Span:
     traced lifecycle completes (cross-call spans, e.g. a serving request)."""
 
     __slots__ = ("_tracer", "name", "cat", "trace_id", "span_id", "parent_id",
-                 "t0_ns", "args", "_tid", "_stacked", "_ended")
+                 "t0_ns", "args", "_tid", "_stacked", "_ended", "_ann")
     enabled = True
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, trace_id: int,
                  span_id: int, parent_id: int, args: Dict[str, Any],
-                 stacked: bool):
+                 stacked: bool, ann=None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -130,10 +147,15 @@ class Span:
         self._tid = threading.get_ident()
         self._stacked = stacked
         self._ended = False
+        self._ann = ann
+        if ann is not None:
+            ann.__enter__()
 
     def set(self, **args) -> None:
         """Attach/overwrite args on an open span."""
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self) -> "Span":
         return self
@@ -147,19 +169,48 @@ class Span:
             return
         self._ended = True
         if args:
-            self.args.update(args)
+            self.set(**args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._tracer._finish(self)
+
+
+class _TimelineSpan:
+    """A span of a tracer whose ring is off: the profiler annotation alone,
+    under the span interface."""
+
+    __slots__ = ("_ann",)
+    enabled = False
+    trace_id = 0
+    span_id = 0
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **args):
+        self._ann.set_metadata(**args)
 
 
 class Tracer:
     """See module docstring. ``cfg`` is any object carrying the
     :class:`TraceConfig` attributes (the runtime and inference config trees
     both qualify); ``None`` or ``enabled: false`` yields a disabled tracer
-    whose every operation is a cheap no-op."""
+    whose every operation is a cheap no-op. ``annotate`` is the profiler's
+    annotation factory (``jax.profiler.TraceAnnotation``); with it, spans
+    also go to the profiler's timeline (module docstring)."""
 
-    def __init__(self, cfg=None, name: str = "trace"):
+    def __init__(self, cfg=None, name: str = "trace", annotate=None):
         self.cfg = cfg if cfg is not None else TraceConfig()
         self.name = name
+        self._annotate = annotate
         self.enabled = bool(getattr(self.cfg, "enabled", False))
         self.ring_size = max(16, int(getattr(self.cfg, "ring_size", 4096)
                                      or 4096))
@@ -206,16 +257,30 @@ class Tracer:
              parent: Optional[int] = None, **args):
         """Open a span. Used as a context manager it nests under the
         enclosing span of the same thread; ``trace``/``parent`` override
-        for explicit lifecycles."""
+        for explicit lifecycles. Feeds the profiler's timeline too (as
+        ``dstpu:<name>``) when the tracer has an annotation factory."""
+        return self._open(name, cat, trace, parent, args, {})
+
+    def step_span(self, name: str, step_num: int, cat: str = "app", **args):
+        """:meth:`span` for one step of a loop: on the timeline it is a step
+        event (``jax.profiler.StepTraceAnnotation`` is a ``TraceAnnotation``
+        with ``_r=1``), which the profiler's per-step analysis keys on."""
+        return self._open(name, cat, None, None,
+                          dict(args, step_num=step_num), {"_r": 1})
+
+    def _open(self, name, cat, trace, parent, args, how):
+        ann = None
+        if self._annotate is not None:
+            ann = self._annotate(TIMELINE_PREFIX + name, **how, **args)
         if not self.enabled:
-            return NULL_SPAN
+            return NULL_SPAN if ann is None else _TimelineSpan(ann)
         st = self._stack()
         if parent is None and st:
             parent = st[-1].span_id
             if trace is None:
                 trace = st[-1].trace_id
         sp = Span(self, name, cat, trace or self._default_trace,
-                  self._new_id(), parent or 0, args, stacked=True)
+                  self._new_id(), parent or 0, args, stacked=True, ann=ann)
         st.append(sp)
         return sp
 

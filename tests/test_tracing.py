@@ -100,6 +100,7 @@ def test_tracer_ring_is_bounded_and_disabled_is_free(tmp_path):
     off = Tracer(TraceConfig(enabled=False))
     sp = off.span("x")
     assert sp is off.span("y")  # shared null span, no allocation
+    assert sp is off.step_span("z", 3)
     with sp:
         off.instant("z")
     off.complete("c", 0, 10)
@@ -275,8 +276,14 @@ def test_serving_trace_lifecycle_and_latency(devices8, tmp_path):
     doc = _chrome(out)
     names = [e["name"] for e in doc["traceEvents"]]
     for want in ("request", "queue_wait", "prefill", "decode_quantum",
-                 "decode_token", "first_token"):
+                 "first_token"):
         assert want in names, f"missing {want}"
+    # no instant per decoded token (32 a tick would turn the ring over and
+    # push out the spans a crash dump is kept for): every inter-token gap is
+    # in the latency summary instead: 3 sequences x 3 quanta of 2 tokens
+    # (the 5 tokens after the first, rounded up to whole quanta)
+    assert "decode_token" not in names
+    assert eng.latency_summary()["itl_ms"]["count"] == 3 * 3 * 2
     _check_nesting(doc)
     # one trace id per request, and its spans share it
     reqs = [e for e in doc["traceEvents"] if e["name"] == "request"]
@@ -486,3 +493,335 @@ def test_report_trace_mode(tmp_path):
     bad = subprocess.run([sys.executable, REPORT],
                          capture_output=True, text=True, timeout=60)
     assert bad.returncode != 0
+
+
+# --------------------------------------------------------------------------- #
+# the same spans on the profiler's timeline (dstpu:<name>), and the
+# device-side names (kernel names, named scopes)
+# --------------------------------------------------------------------------- #
+from deepspeed_tpu.telemetry import schema  # noqa: E402
+from deepspeed_tpu.telemetry.trace import TIMELINE_PREFIX  # noqa: E402
+
+
+class _Profiled:
+    """A profiler session around a block; afterwards ``.spans`` holds the
+    program's spans it recorded: per thread line, ``(name, start, end,
+    stats)`` sorted by start, names without the ``dstpu:`` prefix."""
+
+    def __init__(self, out_dir):
+        self.out_dir = str(out_dir)
+        self.spans = []
+
+    def __enter__(self):
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.out_dir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.stop_trace()
+        path = sorted(glob.glob(os.path.join(
+            self.out_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                mine = [(e.name[len(TIMELINE_PREFIX):], e.start_ns,
+                         e.start_ns + e.duration_ns, dict(e.stats))
+                        for e in line.events
+                        if e.name.startswith(TIMELINE_PREFIX)]
+                if mine:
+                    self.spans.append(sorted(mine, key=lambda s: (s[1],
+                                                                   -s[2])))
+        return False
+
+    def named(self, name):
+        return [s for line in self.spans for s in line if s[0] == name]
+
+    def children(self, parent):
+        """Spans directly inside ``parent`` on its thread's line."""
+        line = next(ln for ln in self.spans if parent in ln)
+        inside = [s for s in line if s is not parent
+                  and parent[1] <= s[1] and s[2] <= parent[2]]
+        return [s for s in inside
+                if not any(o is not s and o[1] <= s[1] and s[2] <= o[2]
+                           for o in inside)]
+
+
+SCHED_CHILDREN = ["sched_expire", "sched_admit", "sched_preempt_guard",
+                  "sched_step_engine", "sched_harvest", "sched_retire"]
+TICK_STATS = {"tick", "admitted", "preempted", "live", "queued",
+              "prefill_tokens", "decode_seqs", "kv_tokens", "tokens_out"}
+
+
+def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
+    """Ring OFF, profiler session ON: one ``dstpu:sched_tick`` per tick with
+    its phases inside it in order and the tick's counts as stats; the counts
+    add up to the work that was submitted."""
+    from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
+                                                 ServingScheduler)
+
+    cfg, eng = _serving_engine(trace=False, split=16)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    rng = np.random.default_rng(3)
+    sizes = [(40, 5), (9, 4), (70, 3), (16, 6), (33, 2), (5, 7)]
+    handles = [sched.submit(Request(
+        prompt=rng.integers(0, cfg.vocab_size, (n,)).tolist(),
+        max_new_tokens=m)) for n, m in sizes]
+    ticks = []   # last_tick and the decode tokens seen from outside, per tick
+    with _Profiled(tmp_path) as prof:
+        while sched.pending:
+            before = [len(h.tokens) for h in handles]
+            sched.tick()
+            after = [len(h.tokens) for h in handles]
+            # a request's first token comes from its prefill, every later
+            # one from one sequence-step of a decode batch
+            decoded = sum(a - b - (b == 0 and a > 0)
+                          for a, b in zip(after, before))
+            ticks.append((dict(sched.last_tick), decoded))
+    assert len(eng.tracer) == 0                 # the ring stayed off
+    assert all(h.done and len(h.tokens) == m
+               for h, (_, m) in zip(handles, sizes))
+    spans = prof.named("sched_tick")
+    assert len(spans) == len(ticks) == sched.stats["ticks"]
+    for span, (last, decoded) in zip(spans, ticks):
+        assert [c[0] for c in prof.children(span)] == SCHED_CHILDREN
+        stats = {k: int(v) for k, v in span[3].items() if k in TICK_STATS}
+        assert stats == last and set(stats) == TICK_STATS
+        assert last["decode_seqs"] == decoded
+    prompt_tokens = sum(n for n, _ in sizes)
+    assert sum(t["prefill_tokens"] for t, _ in ticks) == prompt_tokens \
+        == sched.stats["prefill_tokens"] == eng.prefill_tokens_written
+    assert sum(t["decode_seqs"] for t, _ in ticks) \
+        == sum(m - 1 for _, m in sizes) == sched.stats["decode_seq_steps"]
+    assert sched.stats["chunk_ticks"] \
+        == sum(t["prefill_tokens"] > 0 for t, _ in ticks)
+    assert sum(t["tokens_out"] for t, _ in ticks) == sum(m for _, m in sizes)
+    # under the engine step: each dispatch with its host phases, in order;
+    # a chunk that does not end its prompt has no engine_wait
+    chunks = prof.named("prefill_chunk")
+    assert sum(int(c[3]["tokens"]) for c in chunks) \
+        == sum(n for n, _ in sizes if n > 16)
+    for c in chunks:
+        want = ["engine_prep", "engine_dispatch"]
+        if c[3]["final"] in ("True", "1", 1, True):
+            want += ["engine_wait", "engine_emit"]
+        assert [k[0] for k in prof.children(c)] == want
+    decodes = prof.named("decode_step")
+    assert decodes and all(
+        [k[0] for k in prof.children(d)] == ["engine_prep", "engine_dispatch",
+                                             "engine_wait", "engine_emit"]
+        for d in decodes)
+    assert sum(int(d[3]["batch"]) for d in decodes) \
+        == sched.stats["decode_seq_steps"]
+    batches = prof.named("prefill_batch")     # the one-shot prompts, in admit
+    assert sum(int(b[3]["n"]) for b in batches) \
+        == sum(1 for n, _ in sizes if n <= 16)
+    names = {s[0] for line in prof.spans for s in line}
+    assert names <= schema.TRACER_SPANS, names - schema.TRACER_SPANS
+    ev = dict((n, v) for n, v, _ in sched.sched_events())
+    assert ev["Serving/sched/prefill_tokens"] == prompt_tokens
+    assert schema.validate_events(sched.sched_events()) == []
+
+
+def test_train_step_on_the_profiler_timeline(devices8, tmp_path):
+    engine, batch = _train_engine(tmp_path, {
+        "telemetry": {"compile": {"enabled": True}}})
+    assert not engine.telemetry.tracer.enabled
+    with _Profiled(tmp_path / "prof") as prof:
+        for _ in range(2):
+            engine.train_batch(batch)
+    assert len(engine.telemetry.tracer) == 0
+    steps = prof.named("train_step")
+    assert [int(s[3]["step_num"]) for s in steps] == [1, 2]
+    for s in steps:
+        assert [c[0] for c in prof.children(s)] == [
+            "train_shard_batch", "train/train_batch", "train_sync",
+            "train_step_end"]
+    # the first step compiled: one compile span, under the dispatch, that
+    # covers lower + compile and says which program it was
+    comp = prof.named("compile")
+    assert len(comp) == 1 and comp[0][3]["program"] == "train_step"
+    assert comp[0] in prof.children(prof.named("train/train_batch")[0])
+    assert (comp[0][2] - comp[0][1]) / 1e6 >= float(
+        comp[0][3]["lower_ms"]) + float(comp[0][3]["compile_ms"])
+    summary = engine.telemetry.compile.summary()["train_step"]
+    assert summary["peak_memory_bytes"] > 0
+    names = {s[0] for line in prof.spans for s in line}
+    assert names <= schema.TRACER_SPANS, names - schema.TRACER_SPANS
+    engine.destroy()
+
+
+def test_ring_and_timeline_share_one_call_site(devices8, tmp_path):
+    """Ring ON and a profiler session: the same spans land in both, and the
+    ring's spans are all registered names."""
+    cfg, eng = _serving_engine(trace=True, split=16)
+    rng = np.random.default_rng(1)
+    with _Profiled(tmp_path) as prof:
+        eng.put_split(0, rng.integers(0, cfg.vocab_size, (40,)).tolist())
+        while 0 in eng._pending_prefill:
+            eng.step()
+        eng.step()
+        eng.finish(0)
+    ring = [e for e in eng.tracer.events() if e["ph"] == "X"]
+    assert {e["name"] for e in ring} <= schema.TRACER_SPANS
+    assert {e["name"] for e in eng.tracer.events() if e["ph"] == "i"} \
+        <= schema.TRACER_INSTANTS
+    for name in ("prefill_chunk", "decode_step", "engine_prep",
+                 "engine_dispatch", "engine_wait", "engine_emit"):
+        assert len(prof.named(name)) \
+            == sum(e["name"] == name for e in ring) > 0, name
+    chunk = next(e for e in ring if e["name"] == "prefill_chunk")
+    assert chunk["args"]["tokens"] == 16 and chunk["args"]["ctx"] == 0
+    assert eng.last_step == {"prefill_tokens": 0, "decode_seqs": 1,
+                             "kv_tokens": 41}
+
+
+def _kernel_cases():
+    """One tiny call into each ``pallas_call`` site -> the kernel's name."""
+    import jax
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.ops.pallas.norms import (layer_norm_pallas,
+                                                rms_norm_pallas)
+    from deepspeed_tpu.ops.pallas.quantize import (dequantize_int8_pallas,
+                                                   quantize_int8_pallas)
+    from deepspeed_tpu.ops.sparse_attention import blocksparse_attention
+
+    f32 = jnp.float32
+    qkv = [jnp.ones((1, 128, 2, 32), f32)] * 3
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True)
+    flash_grad = jax.grad(lambda *a: jnp.sum(flash(*a)), argnums=(0, 1, 2))
+    layout = np.tril(np.ones((8, 8), bool))
+    sparse = lambda q, k, v: blocksparse_attention(
+        q, k, v, layout, 16, causal=True, use_kernel=True)
+    sparse_bwd = jax.grad(lambda *a: jnp.sum(sparse(*a)), argnums=(0, 1, 2))
+
+    pool = jnp.ones((8, 2, 16, 32), f32)
+    tables, lens = jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32)
+    x, w = jnp.ones((16, 128), f32), jnp.ones((128,), f32)
+    q8, scales = jnp.ones((4, 256), jnp.int8), jnp.ones((4,), f32)
+    return {
+        "flash_fwd": (flash, qkv),
+        "flash_bwd_dq": (flash_grad, qkv),
+        "flash_bwd_dkv": (flash_grad, qkv),
+        "sparse_flash_fwd": (sparse, qkv),
+        "sparse_flash_bwd_dq": (sparse_bwd, qkv),
+        "sparse_flash_bwd_dkv": (sparse_bwd, qkv),
+        "paged_decode": (pa.paged_decode_attention,
+                         [jnp.ones((2, 4, 32), f32), pool, pool, tables,
+                          lens]),
+        "paged_spec_verify": (pa.paged_spec_verify_attention,
+                              [jnp.ones((2, 3, 4, 32), f32), pool, pool,
+                               tables, lens]),
+        "rms_norm_fwd": (rms_norm_pallas, [x, w]),
+        "layer_norm_fwd": (layer_norm_pallas, [x, w, w]),
+        "quantize_int8": (lambda a: quantize_int8_pallas(a, group_size=256),
+                          [jnp.ones((1024,), f32)]),
+        "dequantize_int8": (lambda q, s: dequantize_int8_pallas(
+            q, s, group_size=256), [q8, scales]),
+    }
+
+
+KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                "sparse_flash_fwd", "sparse_flash_bwd_dq",
+                "sparse_flash_bwd_dkv", "paged_decode", "paged_spec_verify",
+                "rms_norm_fwd", "layer_norm_fwd", "quantize_int8",
+                "dequantize_int8"]
+
+
+@pytest.mark.parametrize("kernel", KERNEL_NAMES)
+def test_every_pallas_call_site_names_its_kernel(kernel):
+    """The name is what the device trace shows for the kernel's events (the
+    HLO instruction is named after it), whatever the program around it;
+    under autodiff it arrives wrapped, ``transpose(jvp(<name>))``."""
+    import re
+
+    import jax
+
+    fn, args = _kernel_cases()[kernel]
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    assert re.search(rf"[/(]{kernel}\)*/pallas_call", text)
+
+
+def test_every_pallas_call_site_is_in_the_list():
+    import re
+
+    folder = os.path.join(REPO, "deepspeed_tpu", "ops", "pallas")
+    found = []
+    for f in sorted(os.listdir(folder)):
+        if f.endswith(".py"):
+            text = open(os.path.join(folder, f)).read()
+            calls = len(re.findall(r"pl\.pallas_call\(", text))
+            names = re.findall(r"^\s+name=\"([a-z0-9_]+)\",$", text, re.M)
+            assert calls == len(names), f
+            found += names
+    assert sorted(found) == sorted(KERNEL_NAMES)
+
+
+def _scopes_of(fn, *args):
+    import re
+
+    import jax
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found |= set(re.findall(r"[A-Za-z_][A-Za-z0-9_]*", op_name))
+    return found
+
+
+@pytest.mark.parametrize("family,program,want", [
+    ("llama", "train", {"embed", "norm", "attn", "ffn", "logits", "loss",
+                        "optimizer"}),
+    ("mixtral", "train", {"embed", "norm", "attn", "moe_router",
+                          "moe_experts", "logits", "loss", "optimizer"}),
+    ("llama", "decode", {"embed", "norm", "attn", "kv_write", "ffn",
+                         "logits", "sample"}),
+    ("mixtral", "decode", {"embed", "norm", "attn", "kv_write", "moe_router",
+                           "moe_experts", "logits", "sample"}),
+])
+def test_model_step_blocks_are_named_scopes(devices8, family, program, want):
+    """The block boundaries of the model step are in the ``op_name`` of the
+    compiled operations, where a trace reduction sums device time by them."""
+    import importlib
+
+    import jax
+
+    mod = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    cfg = (mod.LlamaConfig if family == "llama" else mod.MixtralConfig).tiny()
+    if program == "train":
+        engine, *_ = dst.initialize(
+            model=mod.model_spec(cfg, compute_dtype=jnp.float32),
+            config={"train_batch_size": 8, "steps_per_print": 0,
+                    "gradient_clipping": 1.0,
+                    "optimizer": {"type": "adamw", "params": {"lr": 1e-2}}})
+        engine._build_train_step()
+        batch = engine._shard_batch({"tokens": np.zeros((8, 33), np.int32)},
+                                    with_gas_dim=True)
+        found = _scopes_of(engine._train_step, engine.state, batch,
+                           engine._lr_override)
+        engine.destroy()
+    else:
+        from deepspeed_tpu.inference.engine_v2 import build_engine_v2
+        from deepspeed_tpu.inference.sampling import SamplingParams
+
+        params = mod.init(cfg, jax.random.PRNGKey(0))
+        eng = build_engine_v2(mod, cfg, params, config={
+            "dtype": "float32", "prefill_bucket": 16,
+            "ragged": {"max_tracked_sequences": 4, "max_ragged_batch_size": 4,
+                       "memory_config_blocks": 64, "block_size": 16}})
+        found = _scopes_of(
+            eng._decode_fn(SamplingParams(greedy=True)), eng.params,
+            eng.cache, jnp.asarray(eng._slot_tokens),
+            jnp.asarray(eng._slot_lens), jnp.asarray(eng._slot_tables),
+            jnp.asarray(eng._slot_active), jax.random.PRNGKey(0))
+    assert want <= found, want - found
