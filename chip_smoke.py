@@ -39,8 +39,8 @@ MOSAIC = "tpu_custom_call"  # how a Pallas kernel Mosaic compiled shows in HLO
 # bf16 tolerances, each with its reason -------------------------------------
 # Logits of a random-weight model have about unit variance (unit-RMS final
 # norm times a 1/sqrt(h) head). The served path and the dense forward do the
-# same bf16 arithmetic in a different order (paged kernel / gathered prefill
-# against flash attention), so they differ by accumulated bf16 rounding:
+# same bf16 arithmetic in a different order (paged decode / paged prefill
+# kernels against flash attention), so they differ by accumulated bf16 rounding:
 # 2^-9 relative per op over a few ops in each of 16 layers is about 1-2% of
 # a unit-variance logit in the mean, a few times that in the worst of 32000
 # entries. A wrong block, offset or mask moves logits by order 1.
@@ -84,9 +84,9 @@ class ServeSize:
     layers: int = 16              # of 32: 7.5 GB of bf16 weights
     block_size: int = 32
     # 28.7k tokens of KV, 1.9 GB in bf16. Not "the rest of the chip": every
-    # paged program holds a SECOND copy of the pool as scan temporaries, and
-    # a 4 x 256-token prefill needs 4 GB more for attention over the whole
-    # 8192-token table width (rehearsed: 15.4 GB compiled, 15.75 GiB usable)
+    # paged program holds a SECOND copy of the pool as scan temporaries
+    # (and the size dates from when a 4 x 256-token prefill held 4 GB of f32
+    # scores over the table's width besides: 15.4 GB compiled then)
     pool_blocks: int = 896
     slots: int = 32               # decode batch width
     prefill_chunk: int = 256      # Dynamic-SplitFuse chunk for long prompts

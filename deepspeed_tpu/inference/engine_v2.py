@@ -68,7 +68,8 @@ def prompt_lookup_draft(history, max_tokens: int, ngram_max: int = 3,
 
 
 # what ``InferenceEngineV2.last_step`` holds before a step has done anything
-_NO_WORK = {"prefill_tokens": 0, "decode_seqs": 0, "kv_tokens": 0}
+_NO_WORK = {"prefill_tokens": 0, "prefill_kv_tokens": 0, "decode_seqs": 0,
+            "kv_tokens": 0}
 
 
 class InferenceEngineV2(InferenceEngine):
@@ -184,10 +185,9 @@ class InferenceEngineV2(InferenceEngine):
         self._spec_ngram_max = max(1, int(sc.ngram_max))
         self._spec_min_match = max(1, int(sc.min_match))
         # fused verification (inference.speculative.fused_verify;
-        # docs/serving.md "Fused verification"): the verify program's
-        # multi-token attention dispatches the paged spec-verify kernel
-        # instead of the prefill-shaped gathered-view path. OFF → the
-        # exact pre-fuse verify programs (pinned).
+        # docs/serving.md "Fused verification"): the verify program traces
+        # under fused_verify_scope as its own program family. It selects
+        # no kernel: every multi-token attention is paged_prefill.
         self._spec_fused = bool(self._spec_on
                                 and getattr(sc, "fused_verify", False))
         # cumulative Serving/spec/* counters (spec_events): model steps run
@@ -207,11 +207,15 @@ class InferenceEngineV2(InferenceEngine):
         self._trace_on = self.tracer.enabled
         # --- counters at the dispatch boundaries, public and read-only:
         # what the last step()/step_many() did (prompt tokens whose KV it
-        # wrote, sequences in its decode batch, the KV tokens that batch
+        # wrote, the KV positions its prefill calls and the one-shot
+        # prefills admitted since the previous step attended over - what
+        # the flash kernel walked, where the table's width is what it did
+        # not -, sequences in its decode batch, the KV tokens that batch
         # attends over after the step), and the prompt tokens whose KV any
         # call wrote since construction (put/put_many prefill included,
         # prefix-cache hits not: nothing is written for them)
         self.last_step: Dict[str, int] = dict(_NO_WORK)
+        self._admitted_kv_tokens = 0   # one-shot prefills since the last step
         self.prefill_tokens_written = 0
         # --- recompilation sentinel + per-program MFU attribution
         # (telemetry/compile.py; docs/observability.md). A hub with an
@@ -599,6 +603,20 @@ class InferenceEngineV2(InferenceEngine):
                                            donate_argnums=donate)
         return self._paged_fns[key]
 
+    def _begin_step(self) -> None:
+        """``last_step`` starts over; the one-shot prefills that ran since
+        the previous step (``put``/``put_many``, a scheduler tick's
+        admissions) count with this step's ``prefill_kv_tokens``."""
+        self.last_step = dict(_NO_WORK,
+                              prefill_kv_tokens=self._admitted_kv_tokens)
+        self._admitted_kv_tokens = 0
+
+    def _kv_blocks(self, kv_tokens: int) -> int:
+        """Blocks a prefill call's longest row attends over (cached context
+        + this call's tokens): what the flash kernel walks of the table's
+        ``max_blocks_per_seq``."""
+        return -(-kv_tokens // self.state.block_size)
+
     def _advance_prefill(self, seed: int = 0) -> Dict[int, int]:
         """Advance the OLDEST pending split prefill by one chunk (FIFO, the
         reference scheduler's arrival order), sampling with the
@@ -619,7 +637,9 @@ class InferenceEngineV2(InferenceEngine):
                 "prefill_chunk", cat="serving",
                 trace=rec["trace"] if rec else None,
                 parent=rec["span"].span_id if rec else None,
-                uid=uid, tokens=len(chunk), ctx=done, final=final):
+                uid=uid, tokens=len(chunk), ctx=done, final=final,
+                kv_blocks=self._kv_blocks(done + len(chunk)),
+                table_blocks=self.state.max_blocks_per_seq):
             with self.tracer.span("engine_prep", cat="serving"):
                 padded = np.zeros((1, chunk_tokens), np.int32)
                 padded[0, :len(chunk)] = chunk
@@ -634,6 +654,7 @@ class InferenceEngineV2(InferenceEngine):
                          jax.random.PRNGKey(seed),
                          jnp.asarray(uid, jnp.int32))
             self.last_step["prefill_tokens"] += len(chunk)
+            self.last_step["prefill_kv_tokens"] += done + len(chunk)
             self.prefill_tokens_written += len(chunk)
             if not final:
                 # no engine_wait: the call is asynchronous and nothing here
@@ -803,13 +824,12 @@ class InferenceEngineV2(InferenceEngine):
         cache).
 
         With ``inference.speculative.fused_verify`` the forward pass traces
-        under ``models/_paged.fused_verify_scope``: every layer's
-        multi-token attention dispatches the paged spec-verify kernel
-        (block-table reads, dequant-in-register in kv_quant mode) instead
-        of the prefill-shaped dense-gather path — a distinct program
-        family (``spec_verify_fused``) so the compile monitor and the
-        serving bench can count prefill-shaped dispatches per accepted
-        token."""
+        under ``models/_paged.fused_verify_scope`` and registers as a
+        distinct program family (``spec_verify_fused``) for the compile
+        monitor and the serving bench. Either way every layer's multi-token
+        attention is the ``paged_prefill`` kernel over the block table
+        (dequant-in-register in kv_quant mode): the key selects no kernel
+        any more (ROADMAP simplicity queue)."""
         fused = self._spec_fused
         key = ("spec_verify_fused" if fused else "spec_verify", kp1)
         if key not in self._paged_fns:
@@ -1049,8 +1069,11 @@ class InferenceEngineV2(InferenceEngine):
                                   in zip(entries, cached)), 1),
                           self.config.prefill_bucket)
         out: Dict[int, int] = {}
+        kv_rows = [len(p) for _, p, _ in entries]  # cached prefix + suffix
         with self.tracer.span("prefill_batch", cat="serving", n=n,
-                              pad_t=pad_t):
+                              pad_t=pad_t,
+                              kv_blocks=self._kv_blocks(max(kv_rows)),
+                              table_blocks=self.state.max_blocks_per_seq):
             with self.tracer.span("engine_prep", cat="serving"):
                 padded = np.zeros((n_pad, pad_t), np.int32)
                 lengths = np.zeros((n_pad,), np.int32)  # dummy rows: length 0
@@ -1092,6 +1115,7 @@ class InferenceEngineV2(InferenceEngine):
                 toks = np.asarray(toks)
             t1 = time.monotonic_ns() if self._trace_on else 0
             self.prefill_tokens_written += int(lengths.sum())
+            self._admitted_kv_tokens += sum(kv_rows)
             with self.tracer.span("engine_emit", cat="serving"):
                 for i, (uid, prompt, desc) in enumerate(entries):
                     tok = int(toks[i])
@@ -1134,7 +1158,7 @@ class InferenceEngineV2(InferenceEngine):
         the return type widens to {uid: [tokens]} — every value is a list,
         including prefill first-tokens and draft-less fallback steps."""
         self._warn_ignored_sp(sp)
-        self.last_step = dict(_NO_WORK)
+        self._begin_step()
         out = self._advance_prefill(seed)
         live = [d for d in self.state.seqs.values()
                 if not d.finished and not d.prefilling
@@ -1223,7 +1247,7 @@ class InferenceEngineV2(InferenceEngine):
         number of tokens per call. ``generate`` picks ``step()`` when
         ``inference.speculative.enabled`` is set."""
         self._warn_ignored_sp(sp)
-        self.last_step = dict(_NO_WORK)
+        self._begin_step()
         first = self._advance_prefill(seed)
         live = [d for d in self.state.seqs.values()
                 if not d.finished and not d.prefilling

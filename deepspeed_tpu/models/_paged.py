@@ -15,10 +15,15 @@ codes, and :func:`paged_attention_step` receives each pool as a
 ``(codes, scales)`` tuple (:func:`split_kv`). Fill-time quantization is
 fused into the cache-update scatter (per-token groupwise scales — a token's
 write never touches another position's scale), and dequant is fused into
-the attention reads: in-register inside the Pallas paged-decode kernel, and
-into the gather consumer on the multi-token prefill path. There is NO
-standalone int8→bf16 convert pass over the pool — QUANT_TPU_LIVE.json shows
-that path losing to bf16 outright.
+the attention reads: in-register inside both Pallas kernels (``paged_decode``
+for one query token, ``paged_prefill`` for more). There is NO standalone
+int8→bf16 convert pass over the pool — QUANT_TPU_LIVE.json shows that path
+losing to bf16 outright.
+
+Reads: both kernels walk the block table over the live context
+(``ops/pallas/paged_attention.py``). Nothing here gathers a dense view of
+the pool; only the ops' XLA references do, and the registry picks those off
+a TPU alone.
 """
 
 from __future__ import annotations
@@ -29,16 +34,16 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention
-from ..ops.quantization import kv_dequantize_int8, kv_quantize_int8
+from ..ops.quantization import kv_quantize_int8
 
 # --------------------------------------------------------------------------- #
 # fused speculative verification (inference.speculative.fused_verify;
-# docs/serving.md "Fused verification"). Trace-time gate: the engine's
-# verify program wraps its apply_paged call in :func:`fused_verify_scope`,
-# so ONLY that program's multi-token attention dispatches the
-# block-table-walking spec-verify kernel — prefill keeps the gathered-view
-# path, and with the gate off every program is byte-identical to before.
+# docs/serving.md "Fused verification"). The engine's fused verify program
+# wraps its apply_paged call in :func:`fused_verify_scope`. Since every
+# multi-token attention walks the block table in the ``paged_prefill`` kernel
+# the scope selects nothing in :func:`paged_attention_step` any more: it
+# remains what names that program family (ROADMAP simplicity queue: remove
+# it with the config key).
 # --------------------------------------------------------------------------- #
 _FUSED_VERIFY = {"on": False}
 
@@ -49,9 +54,7 @@ def fused_verify_active() -> bool:
 
 @contextmanager
 def fused_verify_scope():
-    """Arm the fused-verify dispatch for the duration of one trace (the
-    flag is consulted at trace time only — compiled programs keep whatever
-    path they were traced with)."""
+    """Mark one trace as the fused verify program's (trace time only)."""
     prev = _FUSED_VERIFY["on"]
     _FUSED_VERIFY["on"] = True
     try:
@@ -108,15 +111,6 @@ def join_kv(k_entry, v_entry):
     return {"k": k_entry, "v": v_entry}
 
 
-def _gathered_view(pool, block_tables):
-    """Dense [b, S, nkv, *] view of the pool rows the tables reference —
-    the multi-token (prefill) read path's gather."""
-    b, max_blocks = block_tables.shape
-    g = pool[block_tables]                     # [b, mb, nkv, bs, *]
-    g = g.swapaxes(2, 3)                       # [b, mb, bs, nkv, *]
-    return g.reshape((b, max_blocks * g.shape[2]) + g.shape[3:])
-
-
 def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
                          context_lens, positions, valid, *,
                          window=None) -> Tuple:
@@ -126,9 +120,10 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
     either plain pools or ``(codes, scales)`` tuples (:func:`split_kv` —
     quantized KV mode). ``window``: optional per-layer sliding-window length
     (int or traced scalar — exaone4 scans per-layer windows). Single-token
-    decode dispatches the paged flash-decode kernel (windowed, plain-causal,
-    or the fused-dequant quantized variant); multi-token prefill takes the
-    gathered-view mask path (dequant fusing into the gather consumer).
+    decode dispatches the paged flash-decode kernel, every multi-token call
+    the paged flash-prefill kernel (each windowed or plain-causal, with the
+    dequant fused in quantized mode); ``valid`` [b, t] is a prefix mask of
+    each sequence's real rows, and a padded row's output is unspecified.
     Returns (attn_out [b, t, nh, hd], k_cache, v_cache) with the cache
     entries in the same representation they arrived in."""
     b, t = q.shape[0], q.shape[1]
@@ -141,7 +136,6 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
         group_size = hd // k_scales.shape[-1]
     else:
         bs = k_cache.shape[2]
-    max_blocks = block_tables.shape[1]
 
     with jax.named_scope("kv_write"):   # the pool update, by its own name
         blk_idx = jnp.take_along_axis(block_tables, positions // bs, axis=1)
@@ -174,42 +168,25 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
             out = get_op("paged_decode_attention")(
                 q[:, 0], k_cache, v_cache, block_tables, context_lens,
                 window=window)[:, None]
-    elif fused_verify_active():
-        # speculative verification rides the paged-decode kernel family:
-        # t = 1 + max_draft_tokens rows per sequence score against the
-        # block-table-indexed pools (dequant-in-register in quant mode) —
-        # never the dense [B, max_blocks*bs, ...] gather below
+    else:
+        # every multi-token call - a prefill chunk at a context offset, a
+        # batched prefill, a prefix-cache suffix, a speculative verify
+        # window - walks the block table over the live context in one flash
+        # kernel (dequant in-register in quant mode). Off a TPU the op is
+        # the gathered XLA reference, as for every op. ``valid`` is a prefix
+        # mask, so its sum is each sequence's count of real rows.
         from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
         from ..ops.registry import get_op
 
+        n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
         if quant:
-            out = get_op("paged_spec_verify_attention")(
-                q, k_codes, v_codes, block_tables, context_lens,
+            out = get_op("paged_prefill_attention")(
+                q, k_codes, v_codes, block_tables, context_lens, n_valid,
                 window=window, k_scale=k_scales, v_scale=v_scales)
         else:
-            out = get_op("paged_spec_verify_attention")(
-                q, k_cache, v_cache, block_tables, context_lens,
+            out = get_op("paged_prefill_attention")(
+                q, k_cache, v_cache, block_tables, context_lens, n_valid,
                 window=window)
-    else:
-        if quant:
-            # dequant fuses into the gather consumer — the gathered view is
-            # materialized either way, so the convert rides the same pass
-            kg = kv_dequantize_int8(_gathered_view(k_codes, block_tables),
-                                    _gathered_view(k_scales, block_tables),
-                                    q.dtype)
-            vg = kv_dequantize_int8(_gathered_view(v_codes, block_tables),
-                                    _gathered_view(v_scales, block_tables),
-                                    q.dtype)
-        else:
-            kg = _gathered_view(k_cache, block_tables)
-            vg = _gathered_view(v_cache, block_tables)
-        S = max_blocks * bs
-        kv_pos = jnp.arange(S)[None, None, None, :]
-        q_abs = positions[:, None, :, None]
-        mask = kv_pos <= q_abs
-        if window is not None:
-            mask = mask & (q_abs - kv_pos < window)
-        out = attention(q, kg, vg, causal=False, mask=mask)
     if quant:
         return out, (k_codes, k_scales), (v_codes, v_scales)
     return out, k_cache, v_cache

@@ -337,9 +337,9 @@ def model_spec(cfg: Exaone4Config, compute_dtype=jnp.bfloat16):
 # --------------------------------------------------------------------------- #
 # Paged (blocked) KV-cache path — the v2 continuous-batching protocol
 # (reference lists exaone4 among the v2 model implementations). The hybrid
-# sliding/global masks rule out the plain-causal paged decode kernel, so
-# both prefill and decode run the gathered-view attention with the windowed
-# mask; block-table layout as in models/llama.py (block 0 = trash).
+# sliding/global layers pass their scanned per-layer window to the paged
+# kernels (decode and prefill both take it by scalar prefetch); block-table
+# layout as in models/llama.py (block 0 = trash).
 # --------------------------------------------------------------------------- #
 def init_paged_cache(cfg: Exaone4Config, num_blocks: int, block_size: int,
                      dtype=jnp.bfloat16,

@@ -1,13 +1,16 @@
-"""Paged (blocked-KV) flash-decode attention Pallas kernel.
+"""Paged (blocked-KV) flash attention Pallas kernels: ``paged_decode`` for
+one query token a sequence, ``paged_prefill`` for more (prefill chunks,
+batched prefill, speculative verification).
 
-Reference parity: the inference v2 ragged decode kernels
+Reference parity: the inference v2 ragged kernels
 (``inference/v2/kernels/ragged_ops/`` — blocked flash attention over the
 ``BlockedKVCache``, ``inference/v2/ragged/kv_cache.py``). Round-1 shipped a
 gather-based XLA path (``models/llama.py apply_paged``) that materializes a
-dense [B, max_blocks*bs, ...] KV view per layer; this kernel reads KV blocks
-straight out of the shared pool via a block-table-indexed ``BlockSpec``
-(scalar-prefetch), online-softmax accumulating — no dense copy, HBM traffic =
-exactly the live context.
+dense [B, max_blocks*bs, ...] KV view per layer; the kernels read KV blocks
+straight out of the shared pool via block-table-indexed ``BlockSpec``s
+(scalar-prefetch), online-softmax accumulating — no dense copy, HBM traffic
+= exactly the live context. The gathered expressions survive as the ops' XLA
+references (``*_xla``), which the registry resolves to off a TPU.
 
 Decode layout: one query token per sequence.
   q            [B, nh, hd]
@@ -68,6 +71,56 @@ def _dequant_tile(codes_ref, scale_ref, dtype):
     return x.astype(dtype)
 
 
+def _flash_init(j, m_scr, l_scr, acc_scr):
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _flash_update(s, v, m_scr, l_scr, acc_scr):
+    """One online-softmax step: masked f32 scores ``s`` [rows, kv] and the
+    KV tile's values ``v`` [kv, hd] into the running max / sum / output."""
+    m_prev, l_prev = m_scr[...], l_scr[...]
+    m_curr = jnp.max(s, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_curr, m_prev.shape))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[:, :1])
+    l_scr[...] = l_prev * alpha + jnp.broadcast_to(
+        jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+
+def _flash_finish(last, o_ref, l_scr, acc_scr):
+    @pl.when(last)
+    def _finish():
+        l = l_scr[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[...] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
+
+
+def _checked_window(window):
+    """The kernels' window contract. window <= 0 is nonsensical: every score
+    masks to NEG_INF and the all-masked softmax degenerates to a uniform
+    average over a garbage block (ADVICE r5). Reject static values outright;
+    clamp traced ones."""
+    if isinstance(window, (int, np.integer)):
+        assert window >= 1, f"sliding window must be >= 1, got {window}"
+    return jnp.maximum(jnp.asarray(window, jnp.int32), 1)
+
+
+def _gathered_view(pool, block_tables):
+    """Dense [B, S, nkv, *] view of the pool rows the tables reference: the
+    XLA references' read of the pool (the kernels never build it)."""
+    b, max_blocks = block_tables.shape
+    g = pool[block_tables].swapaxes(2, 3)      # [b, mb, bs, nkv, *]
+    return g.reshape((b, max_blocks * g.shape[2]) + g.shape[3:])
+
+
 def _decode_kernel(*refs, bs, scale, nblk, gpad, has_window, quant=False):
     if quant:
         if has_window:
@@ -87,11 +140,7 @@ def _decode_kernel(*refs, bs, scale, nblk, gpad, has_window, quant=False):
     b = pl.program_id(0)
     j = pl.program_id(2)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    _flash_init(j, m_scr, l_scr, acc_scr)
 
     ctx = ctx_ref[b] + 1  # current token attends to itself too
     # sliding window: only positions in (ctx-1-w, ctx-1] are visible; blocks
@@ -120,24 +169,9 @@ def _decode_kernel(*refs, bs, scale, nblk, gpad, has_window, quant=False):
         if has_window:
             valid = jnp.logical_and(valid, pos > lo)
         s = jnp.where(valid, s, NEG_INF)
+        _flash_update(s, v, m_scr, l_scr, acc_scr)
 
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_curr = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_curr, m_prev.shape))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_scr[...] = l_prev * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(j == nblk - 1)
-    def _finish():
-        l = l_scr[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
+    _flash_finish(j == nblk - 1, o_ref, l_scr, acc_scr)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -164,12 +198,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     assert quant == (v_scale is not None), \
         "k_scale and v_scale must be given together"
     if has_window:
-        # window <= 0 is nonsensical: every score masks to NEG_INF and the
-        # all-masked softmax degenerates to a uniform average over a garbage
-        # block (ADVICE r5). Reject static values outright; clamp traced ones.
-        if isinstance(window, (int, np.integer)):
-            assert window >= 1, f"sliding window must be >= 1, got {window}"
-        window = jnp.maximum(jnp.asarray(window, jnp.int32), 1)
+        window = _checked_window(window)
 
     # [B, nkv, gpad, hd] query groups
     qg = q.reshape(B, nkv, g, hd)
@@ -251,13 +280,10 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     _, nkv, bs, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
     S = max_blocks * bs
-    kg = k_pool[block_tables].swapaxes(2, 3).reshape(B, S, nkv, hd)
-    vg = v_pool[block_tables].swapaxes(2, 3).reshape(B, S, nkv, hd)
+    kg = _gathered_view(k_pool, block_tables)
+    vg = _gathered_view(v_pool, block_tables)
     if window is not None:
-        # same window >= 1 contract as the Pallas kernel
-        if isinstance(window, (int, np.integer)):
-            assert window >= 1, f"sliding window must be >= 1, got {window}"
-        window = jnp.maximum(jnp.asarray(window, jnp.int32), 1)
+        window = _checked_window(window)
     if k_scale is not None and k_scale.shape[-1] == 1:
         # one scale per (block, head, token) — the default group_size >= hd
         # config. Fold the scales into SCORE space instead of dequantizing
@@ -267,8 +293,8 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
         sc = hd ** -0.5 if scale is None else scale
         g = nh // nkv
         qg = q.reshape(B, nkv, g, hd).astype(jnp.float32)
-        ksg = k_scale[block_tables].swapaxes(2, 3).reshape(B, S, nkv)
-        vsg = v_scale[block_tables].swapaxes(2, 3).reshape(B, S, nkv)
+        ksg = _gathered_view(k_scale, block_tables)[..., 0]
+        vsg = _gathered_view(v_scale, block_tables)[..., 0]
         s = jnp.einsum("bngh,bsnh->bngs", qg, kg.astype(jnp.float32)) * sc
         s = s * ksg.transpose(0, 2, 1)[:, :, None, :]       # [B, nkv, g, S]
         kv_pos = jnp.arange(S)[None, None, None, :]
@@ -284,11 +310,10 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     if k_scale is not None:
         from ..quantization import kv_dequantize_int8
 
-        ng = k_scale.shape[-1]
-        ksg = k_scale[block_tables].swapaxes(2, 3).reshape(B, S, nkv, ng)
-        vsg = v_scale[block_tables].swapaxes(2, 3).reshape(B, S, nkv, ng)
-        kg = kv_dequantize_int8(kg, ksg, q.dtype)
-        vg = kv_dequantize_int8(vg, vsg, q.dtype)
+        kg = kv_dequantize_int8(kg, _gathered_view(k_scale, block_tables),
+                                q.dtype)
+        vg = kv_dequantize_int8(vg, _gathered_view(v_scale, block_tables),
+                                q.dtype)
     kv_pos = jnp.arange(S)[None, None, None, :]
     cl = context_lens[:, None, None, None]
     mask = kv_pos <= cl
@@ -300,225 +325,236 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 
 # --------------------------------------------------------------------------- #
-# fused speculative verification (inference.speculative.fused_verify;
-# docs/serving.md "Fused verification"): score the [last_token, draft_1..k]
-# rows of every sequence against the SAME block-table-indexed KV pools the
-# decode kernel walks — t query rows per (sequence, kv-head) grid cell
-# instead of one, row ti attending positions <= ctx + ti. Replaces the
-# prefill-shaped ctx-offset dispatch (`engine_v2._verify_fn`), which
-# re-materialized a dense [B, max_blocks*bs, ...] KV view of the WHOLE
-# context at prefill width for every verify step. Composes with the int8
-# dequant-in-register path exactly like the decode kernel.
+# multi-token paged attention: every ``t > 1`` call of
+# ``models/_paged.paged_attention_step`` — a SplitFuse prefill chunk at a
+# context offset, a batched prefill, a prefix-cache suffix, the speculative
+# verify window ``[last_token, draft_1..k]``. One flash kernel walks the
+# block table over the LIVE context: no dense [B, max_blocks*bs, ...] view of
+# the pool, no f32 scores over the table's whole width. Tile sizes come from
+# the shapes alone (:func:`_prefill_tiles`).
 # --------------------------------------------------------------------------- #
-def _spec_verify_kernel(*refs, bs, scale, nblk, t, rpad, has_window,
-                        quant=False):
-    if quant:
-        if has_window:
-            (tables_ref, ctx_ref, wnd_ref, q_ref, k_ref, v_ref, ks_ref,
-             vs_ref, o_ref, m_scr, l_scr, acc_scr) = refs
-        else:
-            (tables_ref, ctx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-             o_ref, m_scr, l_scr, acc_scr) = refs
-            wnd_ref = None
-    elif has_window:
-        (tables_ref, ctx_ref, wnd_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    else:
-        (tables_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-        wnd_ref = None
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+_Q_ROWS = 1024      # query rows (GQA group x tokens) of one tile at hd <= 128
+_KV_TOKENS = 256    # KV tokens of one grid step: the matmul N, the softmax lanes
+_MAX_PAGES = 8      # pool pages gathered into one KV tile (operands per pool)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    ctx = ctx_ref[b]
-    # block j is live if ANY of the t rows can see it: the newest row
-    # attends up to ctx + t - 1, the oldest row's window reaches back to
-    # ctx - window + 1 (rows are g-major/t-minor: row r verifies draft
-    # position r % t)
+def _prefill_tiles(t: int, g: int, hd: int, bs: int,
+                   max_blocks: int) -> Tuple[int, int, int]:
+    """(query tokens a tile, query tiles, pages a KV tile). A query tile is
+    ``g * tq`` rows so that its q, f32 accumulator, m/l scratch and one
+    ``[rows, KV]`` f32 score tile stay a few MB of VMEM whatever ``t`` is; a
+    KV tile is as many pages as make ~256 tokens (a 32-token page alone is a
+    32-wide matmul N and a quarter of the softmax's lanes)."""
+    rows = _Q_ROWS * 128 // max(hd, 128)
+    tq_max = max(16, rows // g // 16 * 16)
+    n_qt = -(-t // tq_max)
+    tq = -(-(-(-t // n_qt)) // 16) * 16     # balanced, sublane-aligned
+    pages = max(1, min(_MAX_PAGES, _KV_TOKENS // bs, max_blocks))
+    return tq, n_qt, pages
+
+
+def _kv_tile(page_refs, scale_refs, dtype):
+    """One KV tile from its pages' refs (int8 pages dequantize in-register
+    with their scale tiles), joined along the token axis in VMEM."""
+    tiles = [r[...] if s is None else _dequant_tile(r, s, dtype)
+             for r, s in zip(page_refs, scale_refs)]
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
+
+
+def _prefill_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
+    tables_ref, ctx_ref, len_ref = refs[:3]
+    wnd_ref = refs[3] if has_window else None
+    refs = refs[3 + int(has_window):]
+    q_ref, refs = refs[0], refs[1:]
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    ks_refs, vs_refs = ((refs[2 * pages:3 * pages], refs[3 * pages:4 * pages])
+                        if quant else ((None,) * pages,) * 2)
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
+    b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    kv = pages * bs
+
+    _flash_init(j, m_scr, l_scr, acc_scr)
+
+    # rows are g-major/t-minor inside the tile: row r is query token
+    # q_lo + r % tq at absolute position ctx + q_lo + r % tq. The tile's
+    # live range ends at its last REAL row (padded rows and zero-length
+    # dummy sequences extend nothing) and starts at its first row's window.
+    ctx, n = ctx_ref[b], len_ref[b]
+    q_lo = qi * tq
+    live = jnp.logical_and(q_lo < n,
+                           j * kv < ctx + jnp.minimum(q_lo + tq, n))
     if has_window:
-        lo = ctx - wnd_ref[0]
-        live = jnp.logical_and(j * bs < ctx + t, j * bs + bs - 1 > lo)
-    else:
-        live = j * bs < ctx + t
+        live = jnp.logical_and(live,
+                               j * kv + kv - 1 > ctx + q_lo - wnd_ref[0])
 
     @pl.when(live)
     def _compute():
-        q = q_ref[...]                     # [rpad, hd]
-        if quant:                          # int8 tile → q.dtype, in-register
-            k = _dequant_tile(k_ref, ks_ref, q_ref.dtype)
-            v = _dequant_tile(v_ref, vs_ref, q_ref.dtype)
-        else:
-            k = k_ref[...]                 # [bs, hd]
-            v = v_ref[...]                 # [bs, hd]
+        q = q_ref[...]                     # [rows, hd]
+        k = _kv_tile(k_refs, ks_refs, q.dtype)   # [kv, hd]
+        v = _kv_tile(v_refs, vs_refs, q.dtype)
         s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
                      preferred_element_type=jnp.float32) * scale
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ti = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, s.shape, 0),
-                         t)
-        valid = pos <= ctx + ti            # row ti attends itself too
+        rows = s.shape[0]
+        pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
+        q_abs = ctx + q_lo + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tq)
+        valid = jnp.logical_and(pos <= q_abs,   # row attends itself too
+                                pos < ctx + n)
         if has_window:
-            valid = jnp.logical_and(valid, pos > ctx + ti - wnd_ref[0])
+            valid = jnp.logical_and(valid, pos > q_abs - wnd_ref[0])
         s = jnp.where(valid, s, NEG_INF)
+        _flash_update(s, v, m_scr, l_scr, acc_scr)
 
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_curr = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_curr, m_prev.shape))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_scr[...] = l_prev * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(j == nblk - 1)
-    def _finish():
-        l = l_scr[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
+    _flash_finish(j == n_kv - 1, o_ref, l_scr, acc_scr)
 
 
-def paged_spec_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
-                                v_pool: jnp.ndarray,
-                                block_tables: jnp.ndarray,
-                                context_lens: jnp.ndarray, *,
-                                scale: float = None,
-                                window=None, k_scale=None,
-                                v_scale=None) -> jnp.ndarray:
-    """Fused speculative-verification attention over the paged pools.
+def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
+                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
+                            context_lens: jnp.ndarray, lengths=None, *,
+                            scale: float = None, window=None, k_scale=None,
+                            v_scale=None) -> jnp.ndarray:
+    """Multi-token attention over the paged pools, flash over the block table.
 
     q ``[B, t, nh, hd]`` — row ti of sequence b sits at absolute position
-    ``context_lens[b] + ti`` (the verify window ``[last_token,
-    draft_1..t-1]``; its K/V must already be scattered into the pool, like
-    the decode kernel's current token). Returns ``[B, t, nh, hd]``.
+    ``context_lens[b] + ti``; this step's K/V must already be scattered into
+    the pool (like the decode kernel's current token), and row ti attends
+    positions ``<= context_lens[b] + ti``. ``lengths`` ``[B]`` (default: all
+    ``t``) counts each sequence's REAL rows: rows past it are padding whose
+    output is unspecified (callers discard it), and they neither extend the
+    live range nor reach table entries past the sequence's blocks — a
+    zero-length dummy row of a batched prefill computes nothing.
     ``window``/``k_scale``/``v_scale`` as in :func:`paged_decode_attention`.
-    HBM traffic is exactly the live context per kv head — never a dense
-    [B, max_blocks*bs, ...] gather."""
+    Returns ``[B, t, nh, hd]``.
+
+    Grid ``(B, nkv, query tiles, KV tiles)``, KV innermost. A query tile skips
+    the KV tiles wholly above its last real row (causal) and wholly below its
+    first row's window; skipped steps fold onto a live page index, so their
+    DMA is elided and HBM traffic is the live context per (KV head, query
+    tile). A KV tile is ``pages`` pool pages, each its own table-indexed
+    ``BlockSpec`` over the same pool, joined in VMEM."""
     B, t, nh, hd = q.shape
     nblocks, nkv, bs, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
     g = nh // nkv
-    # rows are g-major/t-minor, sublane-padded: row r = gi*t + ti
-    rpad = max(8, -(-(g * t) // 8) * 8)
+    tq, n_qt, pages = _prefill_tiles(t, g, hd, bs, max_blocks)
+    n_kv = -(-max_blocks // pages)
+    rows = g * tq
     scale = hd ** -0.5 if scale is None else scale
     has_window = window is not None
     quant = k_scale is not None
     assert quant == (v_scale is not None), \
         "k_scale and v_scale must be given together"
     if has_window:
-        # same window >= 1 contract as the decode kernel
-        if isinstance(window, (int, np.integer)):
-            assert window >= 1, f"sliding window must be >= 1, got {window}"
-        window = jnp.maximum(jnp.asarray(window, jnp.int32), 1)
+        window = _checked_window(window)
+    if lengths is None:
+        lengths = jnp.full((B,), t, jnp.int32)
 
-    # [B, nkv, rpad, hd] row-folded query groups (head h = kv*g + gi)
-    qg = q.reshape(B, t, nkv, g, hd).transpose(0, 2, 3, 1, 4) \
-        .reshape(B, nkv, g * t, hd)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rpad - g * t), (0, 0)))
+    # [B, nkv, n_qt * rows, hd]: tile-major, then g-major/t-minor rows
+    # (head h = kv * g + gi)
+    qg = jnp.pad(q, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
+    qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
+        .reshape(B, nkv, n_qt * rows, hd)
 
-    kernel = functools.partial(_spec_verify_kernel, bs=bs,
-                               scale=float(scale), nblk=max_blocks, t=t,
-                               rpad=rpad, has_window=has_window, quant=quant)
+    kernel = functools.partial(_prefill_kernel, bs=bs, pages=pages,
+                               scale=float(scale), n_kv=n_kv, tq=tq,
+                               has_window=has_window, quant=quant)
 
-    def qmap(b, h, j, *_):
-        return (b, h, 0, 0)
+    def qmap(b, h, qi, j, *_):
+        return (b, h, qi, 0)
 
-    def kvmap(b, h, j, tables, ctx, *rest):
-        # the newest verify row writes/reads position ctx + t - 1
-        hi_blk = (ctx[b] + t - 1) // bs
-        lo_blk = (jnp.maximum(ctx[b] - rest[0][0] + 1, 0) // bs
-                  if rest else 0)
-        j_eff = jnp.clip(j, lo_blk, hi_blk)
-        return (jnp.clip(tables[b, j_eff], 0, nblocks - 1), h, 0, 0)
+    def page_map(p):
+        def kvmap(b, h, qi, j, tables, ctx, lens, *rest):
+            # the tile's live pages [lo_pg, hi_pg]; every other (tile, page)
+            # folds onto the nearest of them
+            last = ctx[b] + jnp.minimum(qi * tq + tq, lens[b]) - 1
+            hi_pg = jnp.clip(last // bs, 0, max_blocks - 1)
+            lo_pg = (jnp.minimum(jnp.maximum(
+                ctx[b] + qi * tq - rest[0][0] + 1, 0) // bs, hi_pg)
+                if rest else 0)
+            j_eff = jnp.clip(j, lo_pg // pages, hi_pg // pages)
+            pg = jnp.clip(j_eff * pages + p, lo_pg, hi_pg)
+            return (jnp.clip(tables[b, pg], 0, nblocks - 1), h, 0, 0)
+        return kvmap
 
-    in_specs = [
-        pl.BlockSpec((None, None, rpad, hd), qmap),
-        pl.BlockSpec((None, None, bs, hd), kvmap),
-        pl.BlockSpec((None, None, bs, hd), kvmap),
-    ]
-    operands = [qg, k_pool, v_pool]
+    def pool_specs(width):
+        return [pl.BlockSpec((None, None, bs, width), page_map(p))
+                for p in range(pages)]
+
+    in_specs = [pl.BlockSpec((None, None, rows, hd), qmap)] \
+        + pool_specs(hd) + pool_specs(hd)
+    operands = [qg] + [k_pool] * pages + [v_pool] * pages
     if quant:
         ng = k_scale.shape[-1]
-        in_specs += [pl.BlockSpec((None, None, bs, ng), kvmap),
-                     pl.BlockSpec((None, None, bs, ng), kvmap)]
-        operands += [k_scale, v_scale]
+        in_specs += pool_specs(ng) + pool_specs(ng)
+        operands += [k_scale] * pages + [v_scale] * pages
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 + int(has_window),
-        grid=(B, nkv, max_blocks),
+        num_scalar_prefetch=3 + int(has_window),
+        grid=(B, nkv, n_qt, n_kv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, rpad, hd), qmap),
+        out_specs=pl.BlockSpec((None, None, rows, hd), qmap),
         scratch_shapes=[
-            pltpu.VMEM((rpad, 128), jnp.float32),
-            pltpu.VMEM((rpad, 128), jnp.float32),
-            pltpu.VMEM((rpad, hd), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32),
         ],
     )
     prefetch = [block_tables.astype(jnp.int32),
-                context_lens.astype(jnp.int32)]
+                context_lens.astype(jnp.int32), lengths.astype(jnp.int32)]
     if has_window:
         prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nkv, rpad, hd), q.dtype),
-        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((B, nkv, n_qt * rows, hd), q.dtype),
+        compiler_params=_dim_semantics("parallel", "parallel", "parallel",
+                                       "arbitrary"),
         interpret=_interpret(),
-        name="paged_spec_verify",
+        name="paged_prefill",
     )(*prefetch, *operands)
-    return out[:, :, :g * t].reshape(B, nkv, g, t, hd) \
-        .transpose(0, 3, 1, 2, 4).reshape(B, t, nh, hd)
+    return out.reshape(B, nkv, n_qt, g, tq, hd).transpose(0, 2, 4, 1, 3, 5) \
+        .reshape(B, n_qt * tq, nh, hd)[:, :t]
 
 
-def paged_spec_verify_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
-                                    v_pool: jnp.ndarray,
-                                    block_tables: jnp.ndarray,
-                                    context_lens: jnp.ndarray, *,
-                                    scale: float = None,
-                                    window=None, k_scale=None,
-                                    v_scale=None) -> jnp.ndarray:
-    """Dense-gather fallback with identical semantics — deliberately the
-    SAME expressions as the multi-token prefill read path
-    (``models/_paged.paged_attention_step``), so on CPU the fused-verify
-    programs match the unfused ones and greedy streams stay
-    token-identical."""
+def paged_prefill_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
+                                v_pool: jnp.ndarray,
+                                block_tables: jnp.ndarray,
+                                context_lens: jnp.ndarray, lengths=None, *,
+                                scale: float = None, window=None,
+                                k_scale=None, v_scale=None) -> jnp.ndarray:
+    """The reference with identical semantics on every real row: gather the
+    table's whole width, mask, soft-max in f32 (the right choice off-TPU,
+    where the Pallas path runs interpreted; what every multi-token paged
+    program computed before the kernel). ``lengths`` is not needed here:
+    a padded row attends whatever lies under its mask and is discarded."""
     from ..attention import attention_xla
     from ..quantization import kv_dequantize_int8
 
-    B, t, nh, hd = q.shape
-    _, nkv, bs, _ = k_pool.shape
-    max_blocks = block_tables.shape[1]
-    S = max_blocks * bs
-    kg = k_pool[block_tables].swapaxes(2, 3).reshape(B, S, nkv, hd)
-    vg = v_pool[block_tables].swapaxes(2, 3).reshape(B, S, nkv, hd)
+    del lengths
+    t = q.shape[1]
+    kg = _gathered_view(k_pool, block_tables)
+    vg = _gathered_view(v_pool, block_tables)
     if k_scale is not None:
-        ng = k_scale.shape[-1]
-        ksg = k_scale[block_tables].swapaxes(2, 3).reshape(B, S, nkv, ng)
-        vsg = v_scale[block_tables].swapaxes(2, 3).reshape(B, S, nkv, ng)
-        kg = kv_dequantize_int8(kg, ksg, q.dtype)
-        vg = kv_dequantize_int8(vg, vsg, q.dtype)
-    positions = context_lens[:, None] + jnp.arange(t)[None, :]
-    kv_pos = jnp.arange(S)[None, None, None, :]
-    q_abs = positions[:, None, :, None]
+        kg = kv_dequantize_int8(kg, _gathered_view(k_scale, block_tables),
+                                q.dtype)
+        vg = kv_dequantize_int8(vg, _gathered_view(v_scale, block_tables),
+                                q.dtype)
+    kv_pos = jnp.arange(kg.shape[1])[None, None, None, :]
+    q_abs = (context_lens[:, None] + jnp.arange(t)[None, :])[:, None, :, None]
     mask = kv_pos <= q_abs
     if window is not None:
-        if isinstance(window, (int, np.integer)):
-            assert window >= 1, f"sliding window must be >= 1, got {window}"
-        window = jnp.maximum(jnp.asarray(window, jnp.int32), 1)
-        mask = mask & (q_abs - kv_pos < window)
+        mask = mask & (q_abs - kv_pos < _checked_window(window))
     return attention_xla(q, kg, vg, causal=False, mask=mask, scale=scale)
+
+
+# speculative verification is the same computation at t = 1 + draft tokens:
+# the op name the verify programs were written against resolves to the kernel
+paged_spec_verify_attention = paged_prefill_attention
+paged_spec_verify_attention_xla = paged_prefill_attention_xla
 
 
 from ..registry import register  # noqa: E402
 
 register("paged_decode_attention", backend="pallas")(paged_decode_attention)
 register("paged_decode_attention", backend="xla")(paged_decode_attention_xla)
-register("paged_spec_verify_attention",
-         backend="pallas")(paged_spec_verify_attention)
-register("paged_spec_verify_attention",
-         backend="xla")(paged_spec_verify_attention_xla)
+for _name in ("paged_prefill_attention", "paged_spec_verify_attention"):
+    register(_name, backend="pallas")(paged_prefill_attention)
+    register(_name, backend="xla")(paged_prefill_attention_xla)

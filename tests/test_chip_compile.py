@@ -165,6 +165,57 @@ def test_kernel_compiles_for_v5e(v5e, case):
         f"{case}: compiled without a Mosaic kernel"
 
 
+# the serve cells' geometry (benchmark/configs: max context 8192 in blocks of
+# 32, so a table 256 wide; serve-chat's pool of 896 blocks)
+CELL_BS, CELL_TABLE, CELL_POOL = 32, 256, 896
+
+
+def _prefill_step(quant):
+    """The multi-token branch of the model step as ``chunk_prefill`` traces
+    it: one sequence, its K/V scattered into the pool, then the op."""
+    from deepspeed_tpu.models._paged import paged_attention_step
+
+    def step(q, k, v, kp, vp, table, ctx, n_valid, *scales):
+        t = q.shape[1]
+        positions = ctx[:, None] + jnp.arange(t)[None, :]
+        valid = jnp.arange(t)[None, :] < n_valid[:, None]
+        if quant:
+            kp, vp = (kp, scales[0]), (vp, scales[1])
+        return paged_attention_step(q, k, v, kp, vp, table, ctx, positions,
+                                    valid)[0]
+
+    return step
+
+
+def _prefill_args(t, quant):
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = ((CELL_POOL, NKV, CELL_BS, HD), jnp.int8 if quant else bf)
+    scales = (((CELL_POOL, NKV, CELL_BS, 1), jnp.float32),) * 2
+    return (((1, t, NQ, HD), bf), ((1, t, NKV, HD), bf),
+            ((1, t, NKV, HD), bf), pool, pool, ((1, CELL_TABLE), i32),
+            ((1,), i32), ((1,), i32)) + (scales if quant else ())
+
+
+@pytest.mark.parametrize("t", [256, 2816])   # a chunk; a whole prompt, unsplit
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_prefill_attention_walks_the_table_at_the_cells_geometry(v5e, t,
+                                                                 pool):
+    """Prefill at a context offset is the flash kernel over the block table:
+    the Mosaic call is in the program, and no f32 score buffer over the
+    table's 8192 positions is (the gathered path's ``[1, 32, t, 8192]``)."""
+    import re
+
+    from deepspeed_tpu.ops import registry
+
+    assert registry.resolved()["paged_prefill_attention"] == "pallas"
+    quant = pool == "int8"
+    text = _compile(_prefill_step(quant), *_prefill_args(t, quant),
+                    device=v5e.devices[0]).as_text()
+    assert MOSAIC in text and "paged_prefill" in text
+    wide = CELL_TABLE * CELL_BS
+    assert not re.search(rf"f32\[[0-9,]*\b{wide}\]", text)
+
+
 def _sq_sum_grad(fn, argnums):
     return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
                     argnums=argnums)

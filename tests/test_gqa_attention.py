@@ -30,7 +30,9 @@ from deepspeed_tpu.ops.attention import (attention_xla, configure_gqa_native,
                                          kv_alignment_heads, repeat_kv,
                                          widen_kv)
 from deepspeed_tpu.ops.pallas import flash_attention as fa
+from deepspeed_tpu.ops.pallas import paged_attention as paged_mod
 from deepspeed_tpu.ops.pallas.paged_attention import (
+    paged_prefill_attention, paged_prefill_attention_xla,
     paged_spec_verify_attention, paged_spec_verify_attention_xla)
 from deepspeed_tpu.models import exaone4, falcon, gpt, llama, mixtral
 
@@ -553,6 +555,138 @@ def test_spec_verify_mqa_and_wide_group():
         out_k = paged_spec_verify_attention(q, kp, vp, tables, ctx)
         out_x = paged_spec_verify_attention_xla(q, kp, vp, tables, ctx)
         np.testing.assert_allclose(out_k, out_x, atol=2e-5, rtol=2e-5)
+
+
+# one query tile is 16 tokens at nh/nkv 4 and 64 at nh/nkv 1 once
+# ``_Q_ROWS`` is 64 (below); blocks are 8 tokens, a KV tile 8 pages
+_PREFILL_CASES = {
+    "t_below_tile_ctx0": dict(t=9, ctx=[0], lengths=[9]),
+    "t_equals_tile_ctx_mid_block": dict(t=16, ctx=[13], lengths=[16]),
+    "t_above_tile_ctx_block_edge": dict(t=40, ctx=[16], lengths=[40]),
+    "t_above_tile_padded_rows": dict(t=48, ctx=[70], lengths=[21]),
+    "rows_differ_and_one_dummy": dict(t=40, ctx=[0, 13, 0, 64],
+                                      lengths=[40, 25, 0, 7]),
+    "window_static": dict(t=40, ctx=[0, 29], lengths=[40, 33], window=9),
+    "window_traced": dict(t=40, ctx=[0, 29], lengths=[40, 33], window=9,
+                          traced=True),
+    "window_wider_than_context": dict(t=24, ctx=[11], lengths=[24],
+                                      window=1 << 30, traced=True),
+    "int8_one_group": dict(t=24, ctx=[5, 24], lengths=[24, 7], ngroups=1),
+    "int8_two_groups_windowed": dict(t=24, ctx=[5, 24], lengths=[24, 7],
+                                     ngroups=2, window=20),
+    "mha_group_of_one": dict(t=70, ctx=[3, 40], lengths=[70, 9], nh=2),
+    "mqa_group_of_four_one_kv_head": dict(t=20, ctx=[8], lengths=[20],
+                                          nkv=1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PREFILL_CASES))
+def test_paged_prefill_kernel_matches_reference(case, monkeypatch):
+    """The flash-over-the-table kernel (interpreted) against the gathered
+    XLA op on every REAL row: query tiling, context offsets, per-row
+    lengths with a zero-length dummy row, windows, int8 pools, head ratios.
+    Table entries past a sequence's blocks point at a poisoned block, and
+    so do those of the dummy row: a kernel that read them, even under its
+    mask, would return NaN."""
+    c = dict(dict(nh=4, nkv=2, window=None, traced=False, ngroups=0),
+             **_PREFILL_CASES[case])
+    monkeypatch.setattr(paged_mod, "_Q_ROWS", 64)
+    rng = np.random.default_rng(3)
+    nh, nkv, hd, bs, nb, mb = c["nh"], c["nkv"], 32, 8, 48, 20
+    t, B = c["t"], len(c["ctx"])
+    q = jnp.asarray(rng.standard_normal((B, t, nh, hd)), jnp.float32)
+    kf = rng.standard_normal((nb, nkv, bs, hd)).astype(np.float32)
+    vf = rng.standard_normal((nb, nkv, bs, hd)).astype(np.float32)
+    poison = nb - 1
+    tables = np.zeros((B, mb), np.int32)
+    poisoned = np.full((B, mb), poison, np.int32)
+    for b in range(B):
+        need = -(-(c["ctx"][b] + c["lengths"][b]) // bs) \
+            if c["lengths"][b] else 0
+        tables[b, :need] = rng.integers(1, poison, need)
+        poisoned[b, :need] = tables[b, :need]
+    kw = {}
+    if c["window"] is not None:
+        kw["window"] = jnp.asarray(c["window"]) if c["traced"] \
+            else c["window"]
+    if c["ngroups"]:
+        from deepspeed_tpu.ops.quantization import kv_quantize_int8
+
+        kp, ks = kv_quantize_int8(jnp.asarray(kf), hd // c["ngroups"])
+        vp, vs = kv_quantize_int8(jnp.asarray(vf), hd // c["ngroups"])
+        bad = dict(k_scale=ks.at[poison].set(jnp.nan),
+                   v_scale=vs.at[poison].set(jnp.nan))
+        kw_ref = dict(kw, k_scale=ks, v_scale=vs)
+        kw = dict(kw, **bad)
+        kp_bad, vp_bad = kp, vp
+    else:
+        kp, vp = jnp.asarray(kf), jnp.asarray(vf)
+        kp_bad, vp_bad = kp.at[poison].set(jnp.nan), vp.at[poison].set(jnp.nan)
+        kw_ref = kw
+    ctx = jnp.asarray(c["ctx"], jnp.int32)
+    lengths = jnp.asarray(c["lengths"], jnp.int32)
+    out_k = paged_prefill_attention(q, kp_bad, vp_bad, jnp.asarray(poisoned),
+                                    ctx, lengths, **kw)
+    out_x = paged_prefill_attention_xla(q, kp, vp, jnp.asarray(tables), ctx,
+                                        lengths, **kw_ref)
+    assert out_k.shape == (B, t, nh, hd)
+    assert np.isfinite(np.asarray(out_k)).all()
+    for b, n in enumerate(c["lengths"]):
+        np.testing.assert_allclose(out_k[b, :n], out_x[b, :n],
+                                   atol=2e-5, rtol=2e-5)
+    g = nh // nkv
+    tq, n_qt, pages = paged_mod._prefill_tiles(t, g, hd, bs, mb)
+    assert g * tq <= 64 and n_qt * tq >= t and pages == 8
+
+
+def test_prefill_tiles_come_from_the_shapes():
+    """Mistral-7B widths at the serve cells' geometry: a 256-token chunk is
+    one 1024-row tile, a whole 2816-token prompt eleven, a KV tile eight
+    32-token pages; nothing grows with ``t``."""
+    tiles = paged_mod._prefill_tiles
+    assert tiles(256, 4, 128, 32, 256) == (256, 1, 8)
+    assert tiles(2816, 4, 128, 32, 256) == (256, 11, 8)
+    assert tiles(5, 4, 128, 32, 256) == (16, 1, 8)       # a verify window
+    assert tiles(300, 4, 128, 32, 256) == (160, 2, 8)    # balanced tiles
+    assert tiles(256, 1, 256, 128, 64) == (256, 1, 2)
+    assert tiles(64, 8, 128, 512, 4) == (64, 1, 1)
+
+
+def test_served_stream_is_the_same_through_the_kernel(tiny):
+    """A tiny model served with the multi-token op forced to the kernel
+    (interpreted) and to the XLA reference: the same greedy stream, through
+    ``put_split`` chunks at context offsets and a batched one-shot
+    prefill. (A short stream: the two agree to f32 rounding, and a random
+    tiny model's near-ties flip on less after enough steps.)"""
+    from deepspeed_tpu.ops import registry
+
+    cfg, _ = tiny
+    rng = np.random.default_rng(11)
+    long_p = rng.integers(0, cfg.vocab_size, (41,), dtype=np.int32).tolist()
+    shorts = [rng.integers(0, cfg.vocab_size, (n,), dtype=np.int32).tolist()
+              for n in (7, 19, 12)]           # 3 rows: one dummy row pads to 4
+
+    def serve(backend):
+        registry.set_backend("paged_prefill_attention", backend)
+        try:
+            eng = build(tiny, False, spec_on=False, split_prefill_chunk=16)
+            streams = {}
+            for uid, tok in eng.put_many(list(enumerate(shorts))).items():
+                streams[uid] = [tok]
+            eng.put_split(9, long_p)
+            for _ in range(5):   # 3 chunks, then the long prompt decodes
+                for uid, tok in eng.step().items():
+                    streams.setdefault(uid, []).append(tok)
+            assert any(k[0] == "chunk_prefill" for k in eng._paged_fns)
+            assert any(k[0] == "prefill" and k[-1] == 4
+                       for k in eng._paged_fns)
+            return streams
+        finally:
+            registry.set_backend("paged_prefill_attention", None)
+
+    got, want = serve("pallas"), serve("xla")
+    assert got == want
+    assert len(got[9]) == 3 and all(len(got[u]) == 6 for u in range(3))
 
 
 # --------------------------------------------------------------------------- #

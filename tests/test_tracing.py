@@ -680,8 +680,42 @@ def test_ring_and_timeline_share_one_call_site(devices8, tmp_path):
             == sum(e["name"] == name for e in ring) > 0, name
     chunk = next(e for e in ring if e["name"] == "prefill_chunk")
     assert chunk["args"]["tokens"] == 16 and chunk["args"]["ctx"] == 0
-    assert eng.last_step == {"prefill_tokens": 0, "decode_seqs": 1,
-                             "kv_tokens": 41}
+    assert eng.last_step == {"prefill_tokens": 0, "prefill_kv_tokens": 0,
+                             "decode_seqs": 1, "kv_tokens": 41}
+
+
+def test_prefill_spans_say_what_the_kernel_walked(devices8):
+    """``prefill_chunk`` / ``prefill_batch`` carry the blocks their longest
+    row attends over beside the table's width, and ``last_step`` the KV
+    positions of the tick's prefill calls: its admissions' one-shot
+    prefills and the step's chunk."""
+    cfg, eng = _serving_engine(trace=True, split=16)
+    bs, width = eng.state.block_size, eng.state.max_blocks_per_seq
+    rng = np.random.default_rng(5)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, (n,)).tolist()
+    eng.put_split(0, prompt(45))
+    admissions = [[(1, 9), (2, 14)], [], [(3, 16)], []]   # per tick
+    seen = 0
+    for tick, admitted in enumerate(admissions):
+        if admitted:
+            eng.put_many([(uid, prompt(n)) for uid, n in admitted])
+        eng.step()
+        spans = [e for e in eng.tracer.events()[seen:] if e["ph"] == "X"]
+        seen = len(eng.tracer.events())
+        chunks = [e["args"] for e in spans if e["name"] == "prefill_chunk"]
+        batches = [e["args"] for e in spans if e["name"] == "prefill_batch"]
+        assert len(batches) == bool(admitted)
+        assert len(chunks) == (tick < 3)       # 45 tokens: 16 + 16 + 13
+        for a in chunks:
+            assert a["kv_blocks"] == -(-(a["ctx"] + a["tokens"]) // bs)
+        for a in batches:
+            assert a["kv_blocks"] == -(-max(n for _, n in admitted) // bs)
+        for a in chunks + batches:
+            assert 1 <= a["kv_blocks"] <= a["table_blocks"] == width
+        assert eng.last_step["prefill_kv_tokens"] \
+            == sum(a["ctx"] + a["tokens"] for a in chunks) \
+            + sum(n for _, n in admitted)
+    assert eng.last_step["prefill_kv_tokens"] == 0
 
 
 def _kernel_cases():
@@ -719,9 +753,9 @@ def _kernel_cases():
         "paged_decode": (pa.paged_decode_attention,
                          [jnp.ones((2, 4, 32), f32), pool, pool, tables,
                           lens]),
-        "paged_spec_verify": (pa.paged_spec_verify_attention,
-                              [jnp.ones((2, 3, 4, 32), f32), pool, pool,
-                               tables, lens]),
+        "paged_prefill": (pa.paged_prefill_attention,
+                          [jnp.ones((2, 3, 4, 32), f32), pool, pool,
+                           tables, lens]),
         "rms_norm_fwd": (rms_norm_pallas, [x, w]),
         "layer_norm_fwd": (layer_norm_pallas, [x, w, w]),
         "quantize_int8": (lambda a: quantize_int8_pallas(a, group_size=256),
@@ -733,7 +767,7 @@ def _kernel_cases():
 
 KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "sparse_flash_fwd", "sparse_flash_bwd_dq",
-                "sparse_flash_bwd_dkv", "paged_decode", "paged_spec_verify",
+                "sparse_flash_bwd_dkv", "paged_decode", "paged_prefill",
                 "rms_norm_fwd", "layer_norm_fwd", "quantize_int8",
                 "dequantize_int8"]
 
