@@ -45,6 +45,9 @@ class ModelFamily:
     param_logical_axes: Callable
     cache_logical_axes: Optional[Callable] = None
     name: str = "model"
+    # (cfg, rows) -> the MoE layer's static row counts for a serving call of
+    # ``rows`` tokens; None for a dense family (models/mixtral.py moe_rows)
+    moe_rows: Optional[Callable] = None
 
     @classmethod
     def from_module(cls, module, cfg) -> "ModelFamily":
@@ -58,7 +61,8 @@ class ModelFamily:
                    init_cache=module.init_cache,
                    param_logical_axes=module.param_logical_axes,
                    cache_logical_axes=getattr(module, "cache_logical_axes", None),
-                   name=getattr(module, "__name__", "model").rsplit(".", 1)[-1])
+                   name=getattr(module, "__name__", "model").rsplit(".", 1)[-1],
+                   moe_rows=getattr(module, "moe_rows", None))
 
 
 def _round_up(n: int, m: int) -> int:

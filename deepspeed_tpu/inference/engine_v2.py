@@ -617,6 +617,14 @@ class InferenceEngineV2(InferenceEngine):
         ``max_blocks_per_seq``."""
         return -(-kv_tokens // self.state.block_size)
 
+    def _moe_args(self, rows: int) -> Dict[str, int]:
+        """Span arguments of a call over ``rows`` token rows (padding
+        included) in a MoE family: ``moe_rows_routed`` and
+        ``moe_rows_computed`` of ONE MoE layer (the call runs ``num_layers``
+        of them). Shape facts, known at dispatch; none for a dense family."""
+        fn = self.family.moe_rows
+        return fn(self.family.cfg, rows) if fn else {}
+
     def _advance_prefill(self, seed: int = 0) -> Dict[int, int]:
         """Advance the OLDEST pending split prefill by one chunk (FIFO, the
         reference scheduler's arrival order), sampling with the
@@ -639,7 +647,8 @@ class InferenceEngineV2(InferenceEngine):
                 parent=rec["span"].span_id if rec else None,
                 uid=uid, tokens=len(chunk), ctx=done, final=final,
                 kv_blocks=self._kv_blocks(done + len(chunk)),
-                table_blocks=self.state.max_blocks_per_seq):
+                table_blocks=self.state.max_blocks_per_seq,
+                **self._moe_args(chunk_tokens)):
             with self.tracer.span("engine_prep", cat="serving"):
                 padded = np.zeros((1, chunk_tokens), np.int32)
                 padded[0, :len(chunk)] = chunk
@@ -1073,7 +1082,8 @@ class InferenceEngineV2(InferenceEngine):
         with self.tracer.span("prefill_batch", cat="serving", n=n,
                               pad_t=pad_t,
                               kv_blocks=self._kv_blocks(max(kv_rows)),
-                              table_blocks=self.state.max_blocks_per_seq):
+                              table_blocks=self.state.max_blocks_per_seq,
+                              **self._moe_args(n_pad * pad_t)):
             with self.tracer.span("engine_prep", cat="serving"):
                 padded = np.zeros((n_pad, pad_t), np.int32)
                 lengths = np.zeros((n_pad,), np.int32)  # dummy rows: length 0
@@ -1185,8 +1195,9 @@ class InferenceEngineV2(InferenceEngine):
             self.spec_stats["decode_steps"] += 1
             self.spec_stats["step_seqs"] += len(live)
             self.spec_stats["emitted_tokens"] += len(live)
-        with self.tracer.span("decode_step", cat="serving",
-                              batch=len(live)) as span:
+        with self.tracer.span("decode_step", cat="serving", batch=len(live),
+                              **self._moe_args(len(self._slot_tokens))
+                              ) as span:
             with self.tracer.span("engine_prep", cat="serving"):
                 cow = []
                 for d in live:

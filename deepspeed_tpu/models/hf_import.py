@@ -10,9 +10,9 @@ then the checkpoint layer's job (orbax/universal).
 
 Supported families: Llama/Mistral/Qwen2/Phi-3 (→ ``models/llama``; fused
 QKV/gate-up checkpoints are split), GPT-2 (→ ``models/gpt``),
-Mixtral/Qwen2-MoE (→ ``models/mixtral``), Falcon (→ ``models/falcon``), OPT (→ ``models/gpt``,
-ReLU/pre-LN), GPT-NeoX/GPT-J (→ ``models/gptneox``), BLOOM (→ ``models/bloom``,
-ALiBi), BERT/DistilBERT (→ ``models/bert``), CLIP (→ ``models/clip``,
+Mixtral/Qwen2-MoE/OLMoE (→ ``models/mixtral``), Falcon (→ ``models/falcon``),
+OPT (→ ``models/gpt``, ReLU/pre-LN), GPT-NeoX/GPT-J (→ ``models/gptneox``),
+BLOOM (→ ``models/bloom``, ALiBi), BERT/DistilBERT (→ ``models/bert``), CLIP (→ ``models/clip``,
 both towers + contrastive head), Megatron-GPT state dicts
 (``megatron_gpt_params_from_sd``, composing with the TP-degree-changing
 ``SDLoaderFactory``). Accepts a live
@@ -424,9 +424,12 @@ def qwen2_moe_config_from_hf(hf_config) -> "Any":
     )
 
 
-def qwen2_moe_params_from_hf(src, cfg=None) -> Params:
-    """HF Qwen2MoeForCausalLM → ``models/mixtral`` pytree (+ shared expert
-    and QKV biases)."""
+def _mlp_experts_params_from_hf(src, cfg=None):
+    """What Qwen2-MoE and OLMoE checkpoints share -> ``models/mixtral``
+    pytree: Llama-named attention and norms, the router at ``mlp.gate`` and
+    experts at ``mlp.experts.N.{gate,up,down}_proj``, stacked to [L, E, ...].
+    Returns (params, state dict, per-layer key prefix, L, E); the caller
+    adds what is its family's own."""
     sd = _normalize_state_dict(src)
     pfx = "model." if any(k.startswith("model.") for k in sd) else ""
     L = cfg.num_layers if cfg is not None else \
@@ -441,20 +444,6 @@ def qwen2_moe_params_from_hf(src, cfg=None) -> Params:
             np.stack([sd[lay.format(i=i) + f"mlp.experts.{e}.{w}.weight"].T
                       for e in range(E)]) for i in range(L)])
 
-    moe: Params = {
-        "router": _stack(sd, lay + "mlp.gate.weight", L, transpose=True),
-        "w_gate": stack_expert("gate_proj"),
-        "w_up": stack_expert("up_proj"),
-        "w_down": stack_expert("down_proj"),
-        "shared_w_gate": _stack(sd, lay + "mlp.shared_expert.gate_proj.weight",
-                                L, transpose=True),
-        "shared_w_up": _stack(sd, lay + "mlp.shared_expert.up_proj.weight",
-                              L, transpose=True),
-        "shared_w_down": _stack(sd, lay + "mlp.shared_expert.down_proj.weight",
-                                L, transpose=True),
-        "shared_gate": _stack(sd, lay + "mlp.shared_expert_gate.weight", L,
-                              transpose=True),
-    }
     params: Params = {
         "embed": sd[pfx + "embed_tokens.weight"],
         "layers": {
@@ -463,18 +452,76 @@ def qwen2_moe_params_from_hf(src, cfg=None) -> Params:
             "wk": _stack(sd, lay + "self_attn.k_proj.weight", L, transpose=True),
             "wv": _stack(sd, lay + "self_attn.v_proj.weight", L, transpose=True),
             "wo": _stack(sd, lay + "self_attn.o_proj.weight", L, transpose=True),
-            "bq": _stack(sd, lay + "self_attn.q_proj.bias", L),
-            "bk": _stack(sd, lay + "self_attn.k_proj.bias", L),
-            "bv": _stack(sd, lay + "self_attn.v_proj.bias", L),
             "mlp_norm": _stack(sd, lay + "post_attention_layernorm.weight", L),
-            "moe": moe,
+            "moe": {
+                "router": _stack(sd, lay + "mlp.gate.weight", L,
+                                 transpose=True),
+                "w_gate": stack_expert("gate_proj"),
+                "w_up": stack_expert("up_proj"),
+                "w_down": stack_expert("down_proj"),
+            },
         },
         "final_norm": sd[pfx + "norm.weight"],
         "lm_head": (sd["lm_head.weight"].T if "lm_head.weight" in sd
                     else sd[pfx + "embed_tokens.weight"].T.copy()),
     }
+    return params, sd, lay, L, E
+
+
+def qwen2_moe_params_from_hf(src, cfg=None) -> Params:
+    """HF Qwen2MoeForCausalLM → ``models/mixtral`` pytree (+ shared expert
+    and QKV biases)."""
+    params, sd, lay, L, E = _mlp_experts_params_from_hf(src, cfg)
+    layers = params["layers"]
+    for ours, theirs in (("bq", "q_proj"), ("bk", "k_proj"), ("bv", "v_proj")):
+        layers[ours] = _stack(sd, lay + f"self_attn.{theirs}.bias", L)
+    for ours, theirs in (("shared_w_gate", "shared_expert.gate_proj"),
+                         ("shared_w_up", "shared_expert.up_proj"),
+                         ("shared_w_down", "shared_expert.down_proj"),
+                         ("shared_gate", "shared_expert_gate")):
+        layers["moe"][ours] = _stack(sd, lay + f"mlp.{theirs}.weight", L,
+                                     transpose=True)
     log_dist(f"imported HF qwen2_moe weights: {L} layers x {E} experts "
              f"+ shared expert")
+    return params
+
+
+def olmoe_config_from_hf(hf_config) -> "Any":
+    """Map a transformers OlmoeConfig: ``intermediate_size`` is the width of
+    ONE expert, the gates stay unnormalised (``norm_topk_prob`` false as
+    published) and q / k pass an RMSNorm over the whole projection."""
+    from .mixtral import MixtralConfig
+
+    for key in ("clip_qkv", "attention_bias", "rope_scaling"):
+        if getattr(hf_config, key, None):
+            raise ValueError(f"OLMoE with {key} is not supported - "
+                             f"models/mixtral.py has no such path")
+    return MixtralConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.intermediate_size,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        num_experts=hf_config.num_experts,
+        top_k=hf_config.num_experts_per_tok,
+        drop_tokens=False,
+        norm_topk_prob=bool(getattr(hf_config, "norm_topk_prob", False)),
+        qk_proj_norm=True,
+        max_seq_len=getattr(hf_config, "max_position_embeddings", 4096),
+        rope_theta=float(getattr(hf_config, "rope_theta", 1e4)),
+        rms_norm_eps=float(getattr(hf_config, "rms_norm_eps", 1e-5)),
+        aux_loss_coef=float(getattr(hf_config, "router_aux_loss_coef", 0.01)),
+    )
+
+
+def olmoe_params_from_hf(src, cfg=None) -> Params:
+    """HF OlmoeForCausalLM -> ``models/mixtral`` pytree (+ the two norms over
+    the whole q and k projections, ``self_attn.{q,k}_norm.weight``)."""
+    params, sd, lay, L, E = _mlp_experts_params_from_hf(src, cfg)
+    for name in ("q_norm", "k_norm"):
+        params["layers"][name] = _stack(sd, lay + f"self_attn.{name}.weight", L)
+    log_dist(f"imported HF olmoe weights: {L} layers x {E} experts")
     return params
 
 
@@ -1165,7 +1212,7 @@ def resolve_module(family: str):
         "llama": llama, "mistral": llama, "qwen2": llama, "qwen3": llama,
         "phi3": llama,
         "gpt2": gpt, "opt": gpt,
-        "mixtral": mixtral, "qwen2_moe": mixtral,
+        "mixtral": mixtral, "qwen2_moe": mixtral, "olmoe": mixtral,
         "falcon": falcon,
         "gpt_neox": gptneox, "gptj": gptneox,
         "bloom": bloom,
@@ -1215,6 +1262,7 @@ _FAMILIES = {
     "opt": (opt_config_from_hf, opt_params_from_hf),
     "mixtral": (mixtral_config_from_hf, mixtral_params_from_hf),
     "qwen2_moe": (qwen2_moe_config_from_hf, qwen2_moe_params_from_hf),
+    "olmoe": (olmoe_config_from_hf, olmoe_params_from_hf),
     "falcon": (falcon_config_from_hf, falcon_params_from_hf),
     "gpt_neox": (gptneox_config_from_hf, gptneox_params_from_hf),
     "gptj": (gptj_config_from_hf, gptj_params_from_hf),

@@ -20,6 +20,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..moe.layer import MoELayer, init_moe_ffn, moe_ffn_logical_axes
+from ..moe.sharded_moe import compute_capacity
 from ..ops.attention import attention
 from ._paged import join_kv, paged_attention_step, split_kv
 from ._paged import init_paged_pools as _init_paged_pools
@@ -60,6 +61,10 @@ class MixtralConfig:
     attention_bias: bool = False
     norm_topk_prob: bool = True
     shared_expert_intermediate_size: int = 0
+    # OLMoE: an RMSNorm with a learned weight over the WHOLE q and k
+    # projections (nh*hd and nkv*hd wide), before the split into heads and
+    # before rope (models/llama.py's ``qk_norm`` norms each head by itself)
+    qk_proj_norm: bool = False
     # MoE dispatch implementation: 'einsum' (dense one-hot, MXU) or
     # 'compact' (index-table gather/scatter) — see moe/layer.py
     moe_dispatch: str = "einsum"
@@ -117,6 +122,9 @@ def init(cfg: MixtralConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         out["layers"]["bq"] = jnp.zeros((L, nh * hd), dtype)
         out["layers"]["bk"] = jnp.zeros((L, nkv * hd), dtype)
         out["layers"]["bv"] = jnp.zeros((L, nkv * hd), dtype)
+    if cfg.qk_proj_norm:
+        out["layers"]["q_norm"] = jnp.ones((L, nh * hd), dtype)
+        out["layers"]["k_norm"] = jnp.ones((L, nkv * hd), dtype)
     return out
 
 
@@ -145,7 +153,33 @@ def param_logical_axes(cfg: MixtralConfig) -> Params:
         axes["layers"]["bq"] = ("layers", "heads")
         axes["layers"]["bk"] = ("layers", "kv_heads")
         axes["layers"]["bv"] = ("layers", "kv_heads")
+    if cfg.qk_proj_norm:
+        axes["layers"]["q_norm"] = ("layers", "heads")
+        axes["layers"]["k_norm"] = ("layers", "kv_heads")
     return axes
+
+
+def _qkv(cfg, layer, y, cos, sin, positions=None, save=False):
+    """q ``[b, t, nh, hd]``, k and v ``[b, t, nkv, hd]`` of one block from
+    its normed input ``y [b, t, h]``: projection, bias (Qwen2-MoE), the norm
+    over the whole projection (OLMoE), the split into heads, rope at
+    ``positions`` (the first ``t`` if None). ``save`` tags the projections as
+    the training block's ``qkv_proj`` saveables (identity outside a
+    selective-remat policy; POLICY_SAVED_NAMES in
+    activation_checkpointing/checkpointing)."""
+    b, t, _ = y.shape
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
+    q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
+    if "bq" in layer:
+        q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+    if "q_norm" in layer:
+        q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
+    if save:
+        q, k, v = (checkpoint_name(a, "qkv_proj") for a in (q, k, v))
+    q = apply_rotary(q.reshape(b, t, nh, hd), cos, sin, positions)
+    k = apply_rotary(k.reshape(b, t, nkv, hd), cos, sin, positions)
+    return q, k, v.reshape(b, t, nkv, hd)
 
 
 def _head_split(cfg, params, x, compute_dtype):
@@ -181,24 +215,13 @@ def apply(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray, *,
 
     def block(x, layer):
         b, s, h = x.shape
-        nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
+        nh, hd = cfg.num_heads, cfg.head_size
         # named scopes (norm / attn, and moe_router / moe_experts inside
         # the MoE layer): metadata a trace reduction sums device time by
         with jax.named_scope("norm"):
             y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
         with jax.named_scope("attn"):
-            q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
-            if "bq" in layer:
-                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-            # selective-remat saveables (identity outside a targeting
-            # policy); see POLICY_SAVED_NAMES in
-            # activation_checkpointing/checkpointing
-            q = checkpoint_name(q, "qkv_proj")
-            k = checkpoint_name(k, "qkv_proj")
-            v = checkpoint_name(v, "qkv_proj")
-            q = apply_rotary(q.reshape(b, s, nh, hd), cos, sin)
-            k = apply_rotary(k.reshape(b, s, nkv, hd), cos, sin)
-            v = v.reshape(b, s, nkv, hd)
+            q, k, v = _qkv(cfg, layer, y, cos, sin, save=True)
             # K/V pass NARROW (nkv heads) into the attention op: widening —
             # when the gqa_native kernels are off — happens inside the op,
             # never here (the gqa-native lint traces this apply)
@@ -257,7 +280,7 @@ def apply_cached(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     if cache_len.ndim == 0:
         cache_len = jnp.broadcast_to(cache_len, (tokens.shape[0],))
     b, t = tokens.shape
-    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
+    nh, hd = cfg.num_heads, cfg.head_size
     x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
     positions = cache_len[:, None] + jnp.arange(t)[None, :]
@@ -274,12 +297,7 @@ def apply_cached(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     def scan_body(x, scanned):
         layer, k_c, v_c = scanned
         y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
-        if "bq" in layer:
-            q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-        q = apply_rotary(q.reshape(b, t, nh, hd), cos, sin, positions)
-        k = apply_rotary(k.reshape(b, t, nkv, hd), cos, sin, positions)
-        v = v.reshape(b, t, nkv, hd)
+        q, k, v = _qkv(cfg, layer, y, cos, sin, positions)
         k_c = llama_mod._write_cache(k_c, k, cache_len)
         v_c = llama_mod._write_cache(v_c, v, cache_len)
         S = k_c.shape[1]
@@ -359,6 +377,20 @@ def init_paged_cache(cfg: MixtralConfig, num_blocks: int, block_size: int,
                              kv_quant_group)
 
 
+def moe_rows(cfg: MixtralConfig, rows: int) -> Dict[str, int]:
+    """What ONE MoE layer of a serving call over ``rows`` token rows does,
+    from shapes alone (the engine puts it on the call's span): the rows its
+    router sends to experts, ``rows * top_k``, and the rows its expert bank
+    computes, ``num_experts * capacity`` - serving never drops a token, so
+    the capacity is at least ``rows`` (``sharded_moe.top_k_gating_compact``)
+    and every expert runs over a slab that long."""
+    capacity = max(compute_capacity(rows, cfg.num_experts, cfg.top_k,
+                                    cfg.capacity_factor, cfg.min_capacity),
+                   rows)
+    return {"moe_rows_routed": rows * cfg.top_k,
+            "moe_rows_computed": cfg.num_experts * capacity}
+
+
 def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
@@ -367,7 +399,7 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     """Ragged forward over the paged cache (see llama.apply_paged for the
     contract); the FFN is the no-drop MoE routing of apply_cached."""
     b, t = tokens.shape
-    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
+    nh, hd = cfg.num_heads, cfg.head_size
     if valid is None:
         valid = jnp.ones((b, t), bool)
     with jax.named_scope("embed"):
@@ -387,12 +419,7 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
         with jax.named_scope("norm"):
             y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
         with jax.named_scope("attn"):   # the pool update inside is "kv_write"
-            q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
-            if "bq" in layer:
-                q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-            q = apply_rotary(q.reshape(b, t, nh, hd), cos, sin, positions)
-            k = apply_rotary(k.reshape(b, t, nkv, hd), cos, sin, positions)
-            v = v.reshape(b, t, nkv, hd)
+            q, k, v = _qkv(cfg, layer, y, cos, sin, positions)
             attn, k_c, v_c = paged_attention_step(
                 q, k, v, k_c, v_c, block_tables, context_lens, positions,
                 valid)

@@ -324,6 +324,44 @@ def test_qwen2_moe_logit_parity():
     np.testing.assert_allclose(np.asarray(logits), ref, rtol=2e-3, atol=2e-3)
 
 
+def test_olmoe_logit_parity():
+    """OLMoE -> mixtral family: an RMSNorm over the WHOLE q and k projections
+    before rope, raw (unnormalised) top-k gates, no shared expert; against
+    transformers' own ``OlmoeForCausalLM`` and against the benchmark's plain
+    reference on the same imported weights."""
+    from benchmark.families import olmoe as family
+    from benchmark.reference import olmoe as reference
+    from deepspeed_tpu.models import mixtral
+
+    hf_cfg = transformers.OlmoeConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=48,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        num_experts=8, num_experts_per_tok=4, norm_topk_prob=False,
+        max_position_embeddings=64, rope_theta=10000.0, rms_norm_eps=1e-5,
+        tie_word_embeddings=False)
+    torch.manual_seed(13)
+    hf_model = transformers.OlmoeForCausalLM(hf_cfg).eval()
+    with torch.no_grad():      # norms start at one: a weight unused would pass
+        for name, p in hf_model.named_parameters():
+            if "norm" in name:
+                p.copy_(1.0 + 0.2 * torch.randn_like(p))
+    cfg, params = from_hf(hf_model)
+    assert cfg.qk_proj_norm and not cfg.norm_topk_prob and not cfg.drop_tokens
+    assert params["layers"]["q_norm"].shape == (2, 64)
+    assert params["layers"]["k_norm"].shape == (2, 32)
+    assert params["layers"]["moe"]["w_gate"].shape == (2, 8, 64, 48)
+    tokens = np.random.RandomState(13).randint(0, 128, (2, 10))
+    with torch.no_grad():
+        ref = hf_model(torch.tensor(tokens)).logits.numpy()
+    logits, _aux = mixtral.apply(cfg, params, jnp.asarray(tokens),
+                                 compute_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(logits), ref, rtol=2e-3, atol=2e-3)
+    plain = reference.logits(hf_cfg.to_dict(), family.Weights(params),
+                             tokens[0])
+    np.testing.assert_allclose(np.asarray(plain), ref[0], rtol=2e-3,
+                               atol=2e-3)
+
+
 def test_gptneox_logit_parity():
     """GPT-NeoX: fused per-head QKV de-interleave, partial rotary
     (rotary_pct), parallel residual with separate norms."""
