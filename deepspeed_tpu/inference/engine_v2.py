@@ -625,6 +625,24 @@ class InferenceEngineV2(InferenceEngine):
         fn = self.family.moe_rows
         return fn(self.family.cfg, rows) if fn else {}
 
+    def _attn_tile_args(self) -> Dict[str, float]:
+        """Span arguments of a decode dispatch over the slots as they stand:
+        of ONE layer's ``paged_decode`` call, the grid's KV tiles that hold
+        live context, the tiles the grid visits, and their ratio (the
+        kernel's own tile sizes: ``ops/pallas/paged_attention.py``). None
+        for a family whose paged cache is not ``init_paged_pools``'."""
+        from ..ops.pallas.paged_attention import decode_tile_counts
+
+        pool = self.cache.get("k")
+        if pool is None:
+            return {}
+        live, grid = decode_tile_counts(
+            self._slot_lens, self.family.cfg.num_heads, pool.shape,
+            pool.dtype.itemsize, self._slot_tables.shape[1],
+            "k_scale" in self.cache)
+        return {"attn_tiles_live": live, "attn_tiles_grid": grid,
+                "attn_live_tile_share": live / grid}
+
     def _advance_prefill(self, seed: int = 0) -> Dict[int, int]:
         """Advance the OLDEST pending split prefill by one chunk (FIFO, the
         reference scheduler's arrival order), sampling with the
@@ -1208,6 +1226,7 @@ class InferenceEngineV2(InferenceEngine):
                     self.state.extend(d)
                     self._slot_tables[d.slot] = self.state.block_table(d)
                 self._copy_blocks(cow)
+                tiles = self._attn_tile_args()
             with self.tracer.span("engine_dispatch", cat="serving"):
                 base = (self.params, self.cache,
                         jnp.asarray(self._slot_tokens),
@@ -1239,8 +1258,9 @@ class InferenceEngineV2(InferenceEngine):
                     out[d.uid] = tok
                     if self._trace_on:
                         self._req_tokens(d.uid, 1, t1)
-            self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
-            span.set(kv_tokens=kv)
+            self.last_step.update(decode_seqs=len(live), kv_tokens=kv,
+                                  **tiles)
+            span.set(kv_tokens=kv, **tiles)
         return {u: [t] for u, t in out.items()} if self._spec_on else out
 
     def step_many(self, k: int, sp: SamplingParams = SamplingParams(greedy=True),

@@ -15,15 +15,24 @@ references (``*_xla``), which the registry resolves to off a TPU.
 Decode layout: one query token per sequence.
   q            [B, nh, hd]
   k/v pool     [num_blocks, nkv, bs, hd]   (block 0 = trash block; kv-head
-               axis ahead of the token axis so the per-block tile is
-               (bs, hd) — a squeezed dim in the last two positions is
-               rejected by the Mosaic TPU lowering's tiling check)
+               axis ahead of the token axis so one page of EVERY KV head is
+               one contiguous ``(nkv, bs, hd)`` block with a ``(bs, hd)``
+               tail — a squeezed dim in the last two positions is rejected
+               by the Mosaic TPU lowering's tiling check)
   block_tables [B, max_blocks] int32
   context_lens [B] int32 — tokens ALREADY cached; the current token's K/V
                must be written to the pool before calling (so the effective
                length is context_lens + 1).
-Grid: (B, nkv, max_blocks), KV-block loop innermost/sequential; the GQA query
-group (g = nh/nkv rows) rides the MXU sublanes.
+Grid: (B, KV-head blocks, 1, KV tiles), KV tiles innermost/sequential. A grid
+step takes every KV head of a sequence (a divisor of ``nkv`` where they do not
+fit: :func:`_decode_tiles`) and a KV tile of several pages (~256 tokens); the
+scores are one head-batched ``[nkv, gpad, hd] x [nkv, kv, hd]`` contraction,
+the GQA query group (g = nh/nkv rows, sublane-padded) on the MXU sublanes.
+The last grid dimension is DYNAMIC: the tiles of the longest context in the
+batch, not the table's width, so a step costs what its context costs. Within
+it a shorter sequence's dead steps fold onto its last live page (no DMA, no
+compute). ``paged_prefill`` is the same kernel body (:func:`_paged_kernel`)
+at ``tq`` query tokens a tile and one KV head a step.
 
 Quantized KV mode (``inference.kv_quant``, docs/serving.md "Quantized KV
 cache"): ``k_pool``/``v_pool`` hold int8 codes and ``k_scale``/``v_scale``
@@ -56,19 +65,28 @@ NEG_INF = -1e30
 
 
 def _dequant_tile(codes_ref, scale_ref, dtype):
-    """In-register dequant of one [bs, hd] int8 KV tile with its [bs, ng]
-    fp32 scale tile, emitted right before the MXU dot. ng == 1 (the default
-    ``group_size >= hd`` config) is a pure lane broadcast; ng > 1 groups the
-    lanes (blocked layout, matching ``ops.quantization.kv_quantize_int8``)."""
+    """In-register dequant of one ``[.., bs, hd]`` int8 KV tile with its
+    ``[.., bs, ng]`` fp32 scale tile, emitted right before the MXU dot.
+    ng == 1 (the default ``group_size >= hd`` config) is a pure lane
+    broadcast; ng > 1 groups the lanes (blocked layout, matching
+    ``ops.quantization.kv_quantize_int8``)."""
     x = codes_ref[...].astype(jnp.float32)
     s = scale_ref[...]
-    ng = s.shape[1]
+    ng = s.shape[-1]
     if ng == 1:
         x = x * s
     else:
-        bs_, hd_ = x.shape
-        x = (x.reshape(bs_, ng, hd_ // ng) * s[:, :, None]).reshape(bs_, hd_)
+        x = (x.reshape(x.shape[:-1] + (ng, x.shape[-1] // ng))
+             * s[..., None]).reshape(x.shape)
     return x.astype(dtype)
+
+
+def _contract(ndim, rhs_axis):
+    """``dot_general`` numbers of ``[.., rows, n] x [.., *, *]``: the left's
+    last axis contracts with the right's ``rhs_axis`` (-1 for keys ``[.., kv,
+    hd]``, -2 for values); any leading (KV-head) axis is a batch."""
+    batch = tuple(range(ndim - 2))
+    return (((ndim - 1,), (ndim + rhs_axis,)), (batch, batch))
 
 
 def _flash_init(j, m_scr, l_scr, acc_scr):
@@ -80,17 +98,18 @@ def _flash_init(j, m_scr, l_scr, acc_scr):
 
 
 def _flash_update(s, v, m_scr, l_scr, acc_scr):
-    """One online-softmax step: masked f32 scores ``s`` [rows, kv] and the
-    KV tile's values ``v`` [kv, hd] into the running max / sum / output."""
+    """One online-softmax step: masked f32 scores ``s`` [.., rows, kv] and
+    the KV tile's values ``v`` [.., kv, hd] into the running max / sum /
+    output (a leading axis is the KV heads of one grid step)."""
     m_prev, l_prev = m_scr[...], l_scr[...]
-    m_curr = jnp.max(s, axis=1, keepdims=True)
+    m_curr = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_curr, m_prev.shape))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, :1])
+    p = jnp.exp(s - m_new[..., :1])
     l_scr[...] = l_prev * alpha + jnp.broadcast_to(
-        jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
-    acc_scr[...] = acc_scr[...] * alpha[:, :1] + _mxu_dot(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        jnp.sum(p, axis=-1, keepdims=True), l_prev.shape)
+    acc_scr[...] = acc_scr[...] * alpha[..., :1] + _mxu_dot(
+        p.astype(v.dtype), v, _contract(s.ndim, -2),
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
@@ -100,7 +119,7 @@ def _flash_finish(last, o_ref, l_scr, acc_scr):
     def _finish():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l_safe[..., :1]).astype(o_ref.dtype)
 
 
 def _checked_window(window):
@@ -121,57 +140,208 @@ def _gathered_view(pool, block_tables):
     return g.reshape((b, max_blocks * g.shape[2]) + g.shape[3:])
 
 
-def _decode_kernel(*refs, bs, scale, nblk, gpad, has_window, quant=False):
-    if quant:
-        if has_window:
-            (tables_ref, ctx_ref, wnd_ref, q_ref, k_ref, v_ref, ks_ref,
-             vs_ref, o_ref, m_scr, l_scr, acc_scr) = refs
-        else:
-            (tables_ref, ctx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-             o_ref, m_scr, l_scr, acc_scr) = refs
-            wnd_ref = None
-    elif has_window:
-        (tables_ref, ctx_ref, wnd_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    else:
-        (tables_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-        wnd_ref = None
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+# --------------------------------------------------------------------------- #
+# the one flash walk over the block table. Both ops are this kernel:
+# ``paged_prefill`` at ``tq`` query tokens a tile and one KV head a grid
+# step, ``paged_decode`` at one query token and every KV head of a sequence
+# a grid step. Tile sizes come from the shapes alone (:func:`_prefill_tiles`,
+# :func:`_decode_tiles`).
+# --------------------------------------------------------------------------- #
+_Q_ROWS = 1024      # query rows (GQA group x tokens) of one tile at hd <= 128
+_KV_TOKENS = 256    # KV tokens of one grid step: the matmul N, the softmax lanes
+_MAX_PAGES = 8      # pool pages gathered into one KV tile (operands per pool)
+_TILE_VMEM = 8 << 20    # a decode step's double-buffered KV tiles and scores
+
+
+def _prefill_tiles(t: int, g: int, hd: int, bs: int,
+                   max_blocks: int) -> Tuple[int, int, int]:
+    """(query tokens a tile, query tiles, pages a KV tile). A query tile is
+    ``g * tq`` rows so that its q, f32 accumulator, m/l scratch and one
+    ``[rows, KV]`` f32 score tile stay a few MB of VMEM whatever ``t`` is; a
+    KV tile is as many pages as make ~256 tokens (a 32-token page alone is a
+    32-wide matmul N and a quarter of the softmax's lanes)."""
+    rows = _Q_ROWS * 128 // max(hd, 128)
+    tq_max = max(16, rows // g // 16 * 16)
+    n_qt = -(-t // tq_max)
+    tq = -(-(-(-t // n_qt)) // 16) * 16     # balanced, sublane-aligned
+    pages = max(1, min(_MAX_PAGES, _KV_TOKENS // bs, max_blocks))
+    return tq, n_qt, pages
+
+
+def _group_rows(g: int) -> int:
+    """Rows of one KV head's query group at one token: sublane-padded."""
+    return max(8, 1 << (g - 1).bit_length())
+
+
+def _decode_tiles(nkv: int, g: int, hd: int, bs: int, max_blocks: int,
+                  itemsize: int, quant: bool) -> Tuple[int, int, int]:
+    """(pages a KV tile, KV heads a grid step, KV tiles the table holds).
+    One page of every KV head is one contiguous block of the pool, so a
+    grid step takes them all, and as many pages as make ~256 tokens - as
+    long as what the step keeps in VMEM for each (head, page) fits
+    ``_TILE_VMEM``: the K and V tiles, double-buffered (an int8 page's f32
+    scale tile pads its group lanes to 128), and the group's f32 scores and
+    probabilities. Many or wide KV heads get a head block that divides
+    ``nkv``; only a single head over the budget gets fewer pages."""
+    pages = max(1, min(_MAX_PAGES, _KV_TOKENS // bs, max_blocks))
+    page = 4 * bs * (hd * itemsize + (128 * 4 if quant else 0)) \
+        + 2 * _group_rows(g) * bs * 4
+    room = _TILE_VMEM // page               # (head, page) pairs a step
+    heads = max([h for h in range(1, nkv + 1)
+                 if nkv % h == 0 and h * pages <= room], default=1)
+    pages = max(1, min(pages, room // heads))
+    return pages, heads, -(-max_blocks // pages)
+
+
+def decode_tile_counts(context_lens, nh: int, pool_shape, itemsize: int,
+                       max_blocks: int, quant: bool) -> Tuple[int, int]:
+    """(live, visited) KV tiles of ONE ``paged_decode`` call over slots at
+    ``context_lens`` (host integers): the grid steps that hold context and
+    the steps the grid takes - every slot walks as far as the longest. What
+    the serving engine puts on its ``decode_step`` span."""
+    nkv, bs, hd = pool_shape[-3:]
+    pages, heads, n_kv = _decode_tiles(nkv, nh // nkv, hd, bs, max_blocks,
+                                       itemsize, quant)
+    tiles = np.minimum(np.asarray(context_lens) // (pages * bs) + 1, n_kv)
+    return (int(tiles.sum()) * (nkv // heads),
+            int(tiles.max()) * tiles.size * (nkv // heads))
+
+
+def _kv_tile(page_refs, scale_refs, dtype):
+    """One KV tile from its pages' refs (int8 pages dequantize in-register
+    with their scale tiles), joined along the token axis in VMEM."""
+    tiles = [r[...] if s is None else _dequant_tile(r, s, dtype)
+             for r, s in zip(page_refs, scale_refs)]
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=-2)
+
+
+def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
+    """q ``[.., rows, hd]`` against the KV tile ``[.., pages * bs, hd]`` of
+    grid step ``j``; a leading axis is the KV heads of the step. ``n_kv``
+    None: the grid's last dimension is dynamic."""
+    tables_ref, ctx_ref, len_ref = refs[:3]
+    wnd_ref = refs[3] if has_window else None
+    refs = refs[3 + int(has_window):]
+    q_ref, refs = refs[0], refs[1:]
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    ks_refs, vs_refs = ((refs[2 * pages:3 * pages], refs[3 * pages:4 * pages])
+                        if quant else ((None,) * pages,) * 2)
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
+    b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    kv = pages * bs
 
     _flash_init(j, m_scr, l_scr, acc_scr)
 
-    ctx = ctx_ref[b] + 1  # current token attends to itself too
-    # sliding window: only positions in (ctx-1-w, ctx-1] are visible; blocks
-    # entirely older than the window skip their compute AND their DMA —
-    # kvmap folds dead grid steps onto the nearest live block index, and
-    # Pallas elides the copy when consecutive steps map to the same block
+    # rows are g-major/t-minor inside the tile: row r is query token
+    # q_lo + r % tq at absolute position ctx + q_lo + r % tq. The tile's
+    # live range ends at its last REAL row (padded rows and zero-length
+    # dummy sequences extend nothing) and starts at its first row's window.
+    ctx, n = ctx_ref[b], len_ref[b]
+    q_lo = qi * tq
+    live = jnp.logical_and(q_lo < n,
+                           j * kv < ctx + jnp.minimum(q_lo + tq, n))
     if has_window:
-        lo = ctx_ref[b] - wnd_ref[0]
-        live = jnp.logical_and(j * bs < ctx, j * bs + bs - 1 > lo)
-    else:
-        live = j * bs < ctx
+        live = jnp.logical_and(live,
+                               j * kv + kv - 1 > ctx + q_lo - wnd_ref[0])
 
     @pl.when(live)
     def _compute():
-        q = q_ref[...]                     # [gpad, hd]
-        if quant:                          # int8 tile → q.dtype, in-register
-            k = _dequant_tile(k_ref, ks_ref, q_ref.dtype)
-            v = _dequant_tile(v_ref, vs_ref, q_ref.dtype)
-        else:
-            k = k_ref[...]                 # [bs, hd]
-            v = v_ref[...]                 # [bs, hd]
-        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
+        q = q_ref[...]                     # [.., rows, hd]
+        k = _kv_tile(k_refs, ks_refs, q.dtype)   # [.., kv, hd]
+        v = _kv_tile(v_refs, vs_refs, q.dtype)
+        s = _mxu_dot(q, k, _contract(q.ndim, -1),
                      preferred_element_type=jnp.float32) * scale
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = pos < ctx
+        rows = s.shape[-2]
+        pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
+        q_abs = ctx + q_lo + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tq)
+        valid = jnp.logical_and(pos <= q_abs,   # row attends itself too
+                                pos < ctx + n)
         if has_window:
-            valid = jnp.logical_and(valid, pos > lo)
+            valid = jnp.logical_and(valid, pos > q_abs - wnd_ref[0])
         s = jnp.where(valid, s, NEG_INF)
         _flash_update(s, v, m_scr, l_scr, acc_scr)
 
-    _flash_finish(j == nblk - 1, o_ref, l_scr, acc_scr)
+    _flash_finish(j == (pl.num_programs(3) if n_kv is None else n_kv) - 1,
+                  o_ref, l_scr, acc_scr)
+
+
+def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
+                window, k_scale, v_scale, *, scale, rows, tq, pages, heads,
+                n_kv):
+    """The kernel, grid and arguments of one walk. ``qg`` ``[B, nkv, query
+    tiles * rows, hd]``; row r of tile qi is query token ``qi * tq + r % tq``
+    of its sequence. Grid ``(B, KV-head blocks, query tiles, KV tiles)``, KV
+    innermost; ``heads`` None is one KV head a step with the head axis
+    squeezed, ``n_kv`` may be traced. A KV tile is ``pages`` pool pages, each
+    its own table-indexed ``BlockSpec`` over the same pool, joined in VMEM.
+    A step wholly above its query tile's last real row (causal) or wholly
+    below its first row's window skips its compute and FOLDS onto a live
+    page index: Pallas elides the DMA when consecutive steps map to the same
+    block, so HBM traffic is the live context per (KV-head block, query
+    tile), with or without a window."""
+    B, nkv, _, hd = qg.shape
+    nblocks, bs = k_pool.shape[0], k_pool.shape[2]
+    max_blocks = block_tables.shape[1]
+    has_window, quant = window is not None, k_scale is not None
+    static = isinstance(n_kv, int)
+    kernel = functools.partial(_paged_kernel, bs=bs, pages=pages,
+                               scale=float(scale),
+                               n_kv=n_kv if static else None, tq=tq,
+                               has_window=has_window, quant=quant)
+
+    # index maps are called positionally with one trailing arg per
+    # prefetched scalar array
+    def qmap(b, h, qi, j, *_):
+        return (b, h, qi, 0)
+
+    def page_map(p):
+        def kvmap(b, h, qi, j, tables, ctx, lens, *rest):
+            # the tile's live pages [lo_pg, hi_pg]; every other (tile, page)
+            # folds onto the nearest of them
+            last = ctx[b] + jnp.minimum(qi * tq + tq, lens[b]) - 1
+            hi_pg = jnp.clip(last // bs, 0, max_blocks - 1)
+            lo_pg = (jnp.minimum(jnp.maximum(
+                ctx[b] + qi * tq - rest[0][0] + 1, 0) // bs, hi_pg)
+                if rest else 0)
+            j_eff = jnp.clip(j, lo_pg // pages, hi_pg // pages)
+            pg = jnp.clip(j_eff * pages + p, lo_pg, hi_pg)
+            return (jnp.clip(tables[b, pg], 0, nblocks - 1), h, 0, 0)
+        return kvmap
+
+    def pool_specs(width):
+        # scale tiles ride the same maps as their code tiles, so a dead
+        # step elides both DMAs together
+        return [pl.BlockSpec((None, heads, bs, width), page_map(p))
+                for p in range(pages)]
+
+    in_specs = [pl.BlockSpec((None, heads, rows, hd), qmap)] \
+        + pool_specs(hd) + pool_specs(hd)
+    operands = [qg] + [k_pool] * pages + [v_pool] * pages
+    if quant:
+        ng = k_scale.shape[-1]
+        in_specs += pool_specs(ng) + pool_specs(ng)
+        operands += [k_scale] * pages + [v_scale] * pages
+    lead = () if heads is None else (heads,)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3 + int(has_window),
+        grid=(B, nkv // (heads or 1), qg.shape[2] // rows, n_kv),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, heads, rows, hd), qmap),
+        scratch_shapes=[
+            pltpu.VMEM(lead + (rows, 128), jnp.float32),
+            pltpu.VMEM(lead + (rows, 128), jnp.float32),
+            pltpu.VMEM(lead + (rows, hd), jnp.float32),
+        ],
+    )
+    prefetch = [block_tables.astype(jnp.int32),
+                context_lens.astype(jnp.int32), lengths.astype(jnp.int32)]
+    if has_window:
+        prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
+    return kernel, grid_spec, prefetch + operands
+
+
+_WALK_GRID = _dim_semantics("parallel", "parallel", "parallel", "arbitrary")
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -182,84 +352,39 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_scale=None) -> jnp.ndarray:
     """See module docstring. Returns [B, nh, hd]. ``window``: optional
     sliding-window length (int or traced scalar — exaone4 scans per-layer
-    windows): only the last ``window`` positions are attended; blocks
+    windows): only the last ``window`` positions are attended; tiles
     entirely outside the window skip their compute. ``k_scale``/``v_scale``:
     per-block-per-group fp32 scale pools ``[num_blocks, nkv, bs, ngroups]``
     for int8 code pools — the quantized-KV mode with dequant fused into the
     flash loop (both or neither must be given)."""
     B, nh, hd = q.shape
-    nblocks, nkv, bs, _ = k_pool.shape
-    max_blocks = block_tables.shape[1]
+    nkv, bs = k_pool.shape[1:3]
     g = nh // nkv
-    gpad = max(8, 1 << (g - 1).bit_length())  # sublane-pad the query group
-    scale = hd ** -0.5 if scale is None else scale
-    has_window = window is not None
-    quant = k_scale is not None
-    assert quant == (v_scale is not None), \
+    gpad = _group_rows(g)
+    assert (k_scale is None) == (v_scale is None), \
         "k_scale and v_scale must be given together"
-    if has_window:
+    if window is not None:
         window = _checked_window(window)
-
-    # [B, nkv, gpad, hd] query groups
-    qg = q.reshape(B, nkv, g, hd)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gpad - g), (0, 0)))
-
-    kernel = functools.partial(_decode_kernel, bs=bs, scale=float(scale),
-                               nblk=max_blocks, gpad=gpad,
-                               has_window=has_window, quant=quant)
-
-    # index maps are called positionally with one trailing arg per
-    # prefetched scalar array — varargs serves both arities. Dead grid
-    # steps (past the context, or older than the window) FOLD onto the
-    # nearest live block index: Pallas elides the DMA when consecutive
-    # steps map to the same block, so HBM traffic stays "exactly the live
-    # context" with or without a window.
-    def qmap(b, h, j, *_):
-        return (b, h, 0, 0)
-
-    def kvmap(b, h, j, tables, ctx, *rest):
-        hi_blk = ctx[b] // bs              # block holding the current token
-        lo_blk = (jnp.maximum(ctx[b] - rest[0][0] + 1, 0) // bs
-                  if rest else 0)
-        j_eff = jnp.clip(j, lo_blk, hi_blk)
-        return (jnp.clip(tables[b, j_eff], 0, nblocks - 1), h, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((None, None, gpad, hd), qmap),
-        # the paged read: pool block chosen by the table
-        pl.BlockSpec((None, None, bs, hd), kvmap),
-        pl.BlockSpec((None, None, bs, hd), kvmap),
-    ]
-    operands = [qg, k_pool, v_pool]
-    if quant:
-        # scale tiles ride the SAME block-table-indexed map as their code
-        # tiles, so a dead grid step elides both DMAs together
-        ng = k_scale.shape[-1]
-        in_specs += [pl.BlockSpec((None, None, bs, ng), kvmap),
-                     pl.BlockSpec((None, None, bs, ng), kvmap)]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 + int(has_window),
-        grid=(B, nkv, max_blocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, gpad, hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((gpad, 128), jnp.float32),
-            pltpu.VMEM((gpad, 128), jnp.float32),
-            pltpu.VMEM((gpad, hd), jnp.float32),
-        ],
-    )
-    prefetch = [block_tables.astype(jnp.int32),
-                context_lens.astype(jnp.int32)]
-    if has_window:
-        prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
+    pages, heads, n_kv = _decode_tiles(
+        nkv, g, hd, bs, block_tables.shape[1], k_pool.dtype.itemsize,
+        k_scale is not None)
+    # [B, nkv, gpad, hd] query groups; the walk ends with the longest
+    # context's last tile (the current token included)
+    qg = jnp.pad(q.reshape(B, nkv, g, hd),
+                 ((0, 0), (0, 0), (0, gpad - g), (0, 0)))
+    n_live = jnp.clip(jnp.max(context_lens) // (pages * bs) + 1, 1, n_kv)
+    kernel, grid_spec, args = _table_walk(
+        qg, k_pool, v_pool, block_tables, context_lens,
+        jnp.ones((B,), jnp.int32), window, k_scale, v_scale,
+        scale=hd ** -0.5 if scale is None else scale, rows=gpad, tq=1,
+        pages=pages, heads=heads, n_kv=n_live.astype(jnp.int32))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nkv, gpad, hd), q.dtype),
-        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=_WALK_GRID,
         interpret=_interpret(),
         name="paged_decode",
-    )(*prefetch, *operands)
+    )(*args)
     return out[:, :, :g].reshape(B, nh, hd)
 
 
@@ -324,96 +449,16 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     return out[:, 0]
 
 
-# --------------------------------------------------------------------------- #
-# multi-token paged attention: every ``t > 1`` call of
-# ``models/_paged.paged_attention_step`` — a SplitFuse prefill chunk at a
-# context offset, a batched prefill, a prefix-cache suffix, the speculative
-# verify window ``[last_token, draft_1..k]``. One flash kernel walks the
-# block table over the LIVE context: no dense [B, max_blocks*bs, ...] view of
-# the pool, no f32 scores over the table's whole width. Tile sizes come from
-# the shapes alone (:func:`_prefill_tiles`).
-# --------------------------------------------------------------------------- #
-_Q_ROWS = 1024      # query rows (GQA group x tokens) of one tile at hd <= 128
-_KV_TOKENS = 256    # KV tokens of one grid step: the matmul N, the softmax lanes
-_MAX_PAGES = 8      # pool pages gathered into one KV tile (operands per pool)
-
-
-def _prefill_tiles(t: int, g: int, hd: int, bs: int,
-                   max_blocks: int) -> Tuple[int, int, int]:
-    """(query tokens a tile, query tiles, pages a KV tile). A query tile is
-    ``g * tq`` rows so that its q, f32 accumulator, m/l scratch and one
-    ``[rows, KV]`` f32 score tile stay a few MB of VMEM whatever ``t`` is; a
-    KV tile is as many pages as make ~256 tokens (a 32-token page alone is a
-    32-wide matmul N and a quarter of the softmax's lanes)."""
-    rows = _Q_ROWS * 128 // max(hd, 128)
-    tq_max = max(16, rows // g // 16 * 16)
-    n_qt = -(-t // tq_max)
-    tq = -(-(-(-t // n_qt)) // 16) * 16     # balanced, sublane-aligned
-    pages = max(1, min(_MAX_PAGES, _KV_TOKENS // bs, max_blocks))
-    return tq, n_qt, pages
-
-
-def _kv_tile(page_refs, scale_refs, dtype):
-    """One KV tile from its pages' refs (int8 pages dequantize in-register
-    with their scale tiles), joined along the token axis in VMEM."""
-    tiles = [r[...] if s is None else _dequant_tile(r, s, dtype)
-             for r, s in zip(page_refs, scale_refs)]
-    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
-
-
-def _prefill_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
-    tables_ref, ctx_ref, len_ref = refs[:3]
-    wnd_ref = refs[3] if has_window else None
-    refs = refs[3 + int(has_window):]
-    q_ref, refs = refs[0], refs[1:]
-    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
-    ks_refs, vs_refs = ((refs[2 * pages:3 * pages], refs[3 * pages:4 * pages])
-                        if quant else ((None,) * pages,) * 2)
-    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
-    b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    kv = pages * bs
-
-    _flash_init(j, m_scr, l_scr, acc_scr)
-
-    # rows are g-major/t-minor inside the tile: row r is query token
-    # q_lo + r % tq at absolute position ctx + q_lo + r % tq. The tile's
-    # live range ends at its last REAL row (padded rows and zero-length
-    # dummy sequences extend nothing) and starts at its first row's window.
-    ctx, n = ctx_ref[b], len_ref[b]
-    q_lo = qi * tq
-    live = jnp.logical_and(q_lo < n,
-                           j * kv < ctx + jnp.minimum(q_lo + tq, n))
-    if has_window:
-        live = jnp.logical_and(live,
-                               j * kv + kv - 1 > ctx + q_lo - wnd_ref[0])
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[...]                     # [rows, hd]
-        k = _kv_tile(k_refs, ks_refs, q.dtype)   # [kv, hd]
-        v = _kv_tile(v_refs, vs_refs, q.dtype)
-        s = _mxu_dot(q, k, (((1,), (1,)), ((), ())),
-                     preferred_element_type=jnp.float32) * scale
-        rows = s.shape[0]
-        pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
-        q_abs = ctx + q_lo + jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tq)
-        valid = jnp.logical_and(pos <= q_abs,   # row attends itself too
-                                pos < ctx + n)
-        if has_window:
-            valid = jnp.logical_and(valid, pos > q_abs - wnd_ref[0])
-        s = jnp.where(valid, s, NEG_INF)
-        _flash_update(s, v, m_scr, l_scr, acc_scr)
-
-    _flash_finish(j == n_kv - 1, o_ref, l_scr, acc_scr)
-
-
 def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                             v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                             context_lens: jnp.ndarray, lengths=None, *,
                             scale: float = None, window=None, k_scale=None,
                             v_scale=None) -> jnp.ndarray:
-    """Multi-token attention over the paged pools, flash over the block table.
+    """Multi-token attention over the paged pools, flash over the block
+    table: every ``t > 1`` call of ``models/_paged.paged_attention_step`` — a
+    SplitFuse prefill chunk at a context offset, a batched prefill, a
+    prefix-cache suffix, the speculative verify window ``[last_token,
+    draft_1..k]``.
 
     q ``[B, t, nh, hd]`` — row ti of sequence b sits at absolute position
     ``context_lens[b] + ti``; this step's K/V must already be scattered into
@@ -424,27 +469,17 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     live range nor reach table entries past the sequence's blocks — a
     zero-length dummy row of a batched prefill computes nothing.
     ``window``/``k_scale``/``v_scale`` as in :func:`paged_decode_attention`.
-    Returns ``[B, t, nh, hd]``.
-
-    Grid ``(B, nkv, query tiles, KV tiles)``, KV innermost. A query tile skips
-    the KV tiles wholly above its last real row (causal) and wholly below its
-    first row's window; skipped steps fold onto a live page index, so their
-    DMA is elided and HBM traffic is the live context per (KV head, query
-    tile). A KV tile is ``pages`` pool pages, each its own table-indexed
-    ``BlockSpec`` over the same pool, joined in VMEM."""
+    Returns ``[B, t, nh, hd]``. The walk: :func:`_table_walk`, one KV head a
+    grid step, query tiles of ``g * tq`` rows."""
     B, t, nh, hd = q.shape
-    nblocks, nkv, bs, _ = k_pool.shape
+    nkv, bs = k_pool.shape[1:3]
     max_blocks = block_tables.shape[1]
     g = nh // nkv
     tq, n_qt, pages = _prefill_tiles(t, g, hd, bs, max_blocks)
-    n_kv = -(-max_blocks // pages)
     rows = g * tq
-    scale = hd ** -0.5 if scale is None else scale
-    has_window = window is not None
-    quant = k_scale is not None
-    assert quant == (v_scale is not None), \
+    assert (k_scale is None) == (v_scale is None), \
         "k_scale and v_scale must be given together"
-    if has_window:
+    if window is not None:
         window = _checked_window(window)
     if lengths is None:
         lengths = jnp.full((B,), t, jnp.int32)
@@ -454,62 +489,18 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     qg = jnp.pad(q, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
     qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
         .reshape(B, nkv, n_qt * rows, hd)
-
-    kernel = functools.partial(_prefill_kernel, bs=bs, pages=pages,
-                               scale=float(scale), n_kv=n_kv, tq=tq,
-                               has_window=has_window, quant=quant)
-
-    def qmap(b, h, qi, j, *_):
-        return (b, h, qi, 0)
-
-    def page_map(p):
-        def kvmap(b, h, qi, j, tables, ctx, lens, *rest):
-            # the tile's live pages [lo_pg, hi_pg]; every other (tile, page)
-            # folds onto the nearest of them
-            last = ctx[b] + jnp.minimum(qi * tq + tq, lens[b]) - 1
-            hi_pg = jnp.clip(last // bs, 0, max_blocks - 1)
-            lo_pg = (jnp.minimum(jnp.maximum(
-                ctx[b] + qi * tq - rest[0][0] + 1, 0) // bs, hi_pg)
-                if rest else 0)
-            j_eff = jnp.clip(j, lo_pg // pages, hi_pg // pages)
-            pg = jnp.clip(j_eff * pages + p, lo_pg, hi_pg)
-            return (jnp.clip(tables[b, pg], 0, nblocks - 1), h, 0, 0)
-        return kvmap
-
-    def pool_specs(width):
-        return [pl.BlockSpec((None, None, bs, width), page_map(p))
-                for p in range(pages)]
-
-    in_specs = [pl.BlockSpec((None, None, rows, hd), qmap)] \
-        + pool_specs(hd) + pool_specs(hd)
-    operands = [qg] + [k_pool] * pages + [v_pool] * pages
-    if quant:
-        ng = k_scale.shape[-1]
-        in_specs += pool_specs(ng) + pool_specs(ng)
-        operands += [k_scale] * pages + [v_scale] * pages
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3 + int(has_window),
-        grid=(B, nkv, n_qt, n_kv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, rows, hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
-        ],
-    )
-    prefetch = [block_tables.astype(jnp.int32),
-                context_lens.astype(jnp.int32), lengths.astype(jnp.int32)]
-    if has_window:
-        prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
+    kernel, grid_spec, args = _table_walk(
+        qg, k_pool, v_pool, block_tables, context_lens, lengths, window,
+        k_scale, v_scale, scale=hd ** -0.5 if scale is None else scale,
+        rows=rows, tq=tq, pages=pages, heads=None,
+        n_kv=-(-max_blocks // pages))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nkv, n_qt * rows, hd), q.dtype),
-        compiler_params=_dim_semantics("parallel", "parallel", "parallel",
-                                       "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=_WALK_GRID,
         interpret=_interpret(),
         name="paged_prefill",
-    )(*prefetch, *operands)
+    )(*args)
     return out.reshape(B, nkv, n_qt, g, tq, hd).transpose(0, 2, 4, 1, 3, 5) \
         .reshape(B, n_qt * tq, nh, hd)[:, :t]
 

@@ -170,19 +170,21 @@ def test_kernel_compiles_for_v5e(v5e, case):
 CELL_BS, CELL_TABLE, CELL_POOL = 32, 256, 896
 
 
-def _prefill_step(quant):
+def _prefill_step(quant, window=None):
     """The multi-token branch of the model step as ``chunk_prefill`` traces
-    it: one sequence, its K/V scattered into the pool, then the op."""
+    it: each sequence's K/V scattered into the pool, then the op. A
+    ``"traced"`` window is the step's last argument."""
     from deepspeed_tpu.models._paged import paged_attention_step
 
-    def step(q, k, v, kp, vp, table, ctx, n_valid, *scales):
+    def step(q, k, v, kp, vp, table, ctx, n_valid, *rest):
         t = q.shape[1]
         positions = ctx[:, None] + jnp.arange(t)[None, :]
         valid = jnp.arange(t)[None, :] < n_valid[:, None]
         if quant:
-            kp, vp = (kp, scales[0]), (vp, scales[1])
-        return paged_attention_step(q, k, v, kp, vp, table, ctx, positions,
-                                    valid)[0]
+            kp, vp = (kp, rest[0]), (vp, rest[1])
+        return paged_attention_step(
+            q, k, v, kp, vp, table, ctx, positions, valid,
+            window=rest[-1] if window == "traced" else window)[0]
 
     return step
 
@@ -214,6 +216,94 @@ def test_prefill_attention_walks_the_table_at_the_cells_geometry(v5e, t,
     assert MOSAIC in text and "paged_prefill" in text
     wide = CELL_TABLE * CELL_BS
     assert not re.search(rf"f32\[[0-9,]*\b{wide}\]", text)
+
+
+# the three serve cells' decode geometry: slots, query heads, KV heads, table
+# width, pool blocks (head size 128, blocks of 32) -> the layer's static grid
+CELL_DECODES = {
+    "mistral-7b.serve-chat": (32, 32, 8, 256, 896, 1024),
+    "mixtral-8x7b.serve-longprompt": (16, 32, 8, 256, 1536, 512),
+    "olmoe-1b-7b.serve-longprompt": (16, 16, 16, 128, 1536, 256),
+}
+
+
+@pytest.mark.parametrize("pool", ["bf16", "windowed", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELL_DECODES))
+def test_decode_kernel_lowers_at_the_cells_geometry(v5e, cell, pool):
+    """Every KV head and eight pages a grid step compile for the chip at
+    each serve cell's shapes, and the Mosaic call is still the instruction
+    ``paged_decode.N`` that ``paged_decode_roofline`` looks for."""
+    import re
+
+    slots, nq, nkv, table, blocks, _ = CELL_DECODES[cell]
+    quant = pool == "int8"
+    kv = ((blocks, nkv, CELL_BS, HD), jnp.int8 if quant else jnp.bfloat16)
+    shapes = (((slots, nq, HD), jnp.bfloat16), kv, kv,
+              ((slots, table), jnp.int32), ((slots,), jnp.int32))
+    if quant:
+        shapes += (((blocks, nkv, CELL_BS, 1), jnp.float32),) * 2
+    fn = _paged(**({"window": 4096} if pool == "windowed" else {}))
+    text = _compile(fn, *shapes, device=v5e.devices[0]).as_text()
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(.*" + MOSAIC, text)
+    assert len(calls) == 1 and re.fullmatch(r"paged_decode(\.\d+)?", calls[0])
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DECODES))
+def test_decode_grid_is_sized_by_the_shapes(cell):
+    from deepspeed_tpu.ops.pallas.paged_attention import _decode_tiles
+
+    slots, nq, nkv, table, _, steps = CELL_DECODES[cell]
+    pages, heads, n_kv = _decode_tiles(nkv, nq // nkv, HD, CELL_BS, table, 2,
+                                       False)
+    assert (pages, heads) == (8, nkv)
+    assert slots * (nkv // heads) * n_kv == steps
+
+
+# --- the ``t > 1`` programs are the parent's ------------------------------- #
+# b, t, query heads, KV heads, head size, block, pool blocks, table width,
+# int8 pools (scale groups), window
+MULTI_TOKEN_PROGRAMS = {
+    "mistral_chunk256_bf16": (1, 256, 32, 8, 128, 32, 896, 256, 0, None),
+    "mistral_prompt2816_int8": (1, 2816, 32, 8, 128, 32, 896, 256, 1, None),
+    "olmoe_chunk256_window": (1, 256, 16, 16, 128, 32, 1536, 128, 0, 4096),
+    "verify_t5_traced_window_int8_ng2": (16, 5, 32, 8, 128, 32, 896, 256, 2,
+                                         "traced"),
+    "batched_prefill_mqa": (4, 40, 8, 1, 64, 16, 64, 20, 0, None),
+}
+# sha256 of each program's jaxpr (kernel body included) at the commit before
+# ISSUE 27 (345a122). A PR that means to change the multi-token walk
+# replaces these; one that does not has changed it by accident.
+PARENT_HASHES = {
+    "mistral_chunk256_bf16": "38679a03dd93c2bc",
+    "mistral_prompt2816_int8": "48082b0c424d871e",
+    "olmoe_chunk256_window": "e84cd41a6fc05051",
+    "verify_t5_traced_window_int8_ng2": "9045530ab6353880",
+    "batched_prefill_mqa": "ad0bfe99b61b2ca8",
+}
+
+
+@pytest.mark.parametrize("program", sorted(MULTI_TOKEN_PROGRAMS))
+def test_multi_token_paged_program_is_the_parents(program):
+    """Decode and prefill share one flash body; what that sharing traces to
+    for ``t > 1`` (a chunk, an unsplit prompt, a verify window, a batched
+    prefill) is the parent's jaxpr to the letter."""
+    import hashlib
+    import re
+
+    b, t, nq, nkv, hd, bs, blocks, table, ng, window = \
+        MULTI_TOKEN_PROGRAMS[program]
+    step = _prefill_step(bool(ng), window)
+    shape, bf, i32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.int32
+    pool = shape((blocks, nkv, bs, hd), jnp.int8 if ng else bf)
+    args = [shape((b, t, nq, hd), bf), shape((b, t, nkv, hd), bf),
+            shape((b, t, nkv, hd), bf), pool, pool, shape((b, table), i32),
+            shape((b,), i32), shape((b,), i32)]
+    args += [shape((blocks, nkv, bs, ng), jnp.float32)] * 2 if ng else []
+    args += [shape((), i32)] if window == "traced" else []
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(step)(*args)))
+    assert "paged_prefill" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_HASHES[program]
 
 
 def _sq_sum_grad(fn, argnums):

@@ -32,6 +32,7 @@ from deepspeed_tpu.ops.attention import (attention_xla, configure_gqa_native,
 from deepspeed_tpu.ops.pallas import flash_attention as fa
 from deepspeed_tpu.ops.pallas import paged_attention as paged_mod
 from deepspeed_tpu.ops.pallas.paged_attention import (
+    paged_decode_attention, paged_decode_attention_xla,
     paged_prefill_attention, paged_prefill_attention_xla,
     paged_spec_verify_attention, paged_spec_verify_attention_xla)
 from deepspeed_tpu.models import exaone4, falcon, gpt, llama, mixtral
@@ -580,29 +581,19 @@ _PREFILL_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_PREFILL_CASES))
-def test_paged_prefill_kernel_matches_reference(case, monkeypatch):
-    """The flash-over-the-table kernel (interpreted) against the gathered
-    XLA op on every REAL row: query tiling, context offsets, per-row
-    lengths with a zero-length dummy row, windows, int8 pools, head ratios.
-    Table entries past a sequence's blocks point at a poisoned block, and
-    so do those of the dummy row: a kernel that read them, even under its
-    mask, would return NaN."""
-    c = dict(dict(nh=4, nkv=2, window=None, traced=False, ngroups=0),
-             **_PREFILL_CASES[case])
-    monkeypatch.setattr(paged_mod, "_Q_ROWS", 64)
-    rng = np.random.default_rng(3)
-    nh, nkv, hd, bs, nb, mb = c["nh"], c["nkv"], 32, 8, 48, 20
-    t, B = c["t"], len(c["ctx"])
-    q = jnp.asarray(rng.standard_normal((B, t, nh, hd)), jnp.float32)
+def _poisoned_pools(rng, c, need_blocks, nb=48, mb=20, bs=8, hd=32):
+    """Random pools and tables for the kernel-against-reference tests.
+    Table entries past a sequence's ``need_blocks`` point at a poisoned
+    block in the kernel's copy (NaN pools, or NaN scales over int8 codes)
+    and at the trash block in the reference's: a kernel that read them,
+    even under its mask, would return NaN. -> (kernel's pools, tables and
+    keywords), (the reference's)."""
+    nkv, poison = c["nkv"], nb - 1
     kf = rng.standard_normal((nb, nkv, bs, hd)).astype(np.float32)
     vf = rng.standard_normal((nb, nkv, bs, hd)).astype(np.float32)
-    poison = nb - 1
-    tables = np.zeros((B, mb), np.int32)
-    poisoned = np.full((B, mb), poison, np.int32)
-    for b in range(B):
-        need = -(-(c["ctx"][b] + c["lengths"][b]) // bs) \
-            if c["lengths"][b] else 0
+    tables = np.zeros((len(need_blocks), mb), np.int32)
+    poisoned = np.full((len(need_blocks), mb), poison, np.int32)
+    for b, need in enumerate(need_blocks):
         tables[b, :need] = rng.integers(1, poison, need)
         poisoned[b, :need] = tables[b, :need]
     kw = {}
@@ -614,21 +605,41 @@ def test_paged_prefill_kernel_matches_reference(case, monkeypatch):
 
         kp, ks = kv_quantize_int8(jnp.asarray(kf), hd // c["ngroups"])
         vp, vs = kv_quantize_int8(jnp.asarray(vf), hd // c["ngroups"])
-        bad = dict(k_scale=ks.at[poison].set(jnp.nan),
-                   v_scale=vs.at[poison].set(jnp.nan))
         kw_ref = dict(kw, k_scale=ks, v_scale=vs)
-        kw = dict(kw, **bad)
+        kw = dict(kw, k_scale=ks.at[poison].set(jnp.nan),
+                  v_scale=vs.at[poison].set(jnp.nan))
         kp_bad, vp_bad = kp, vp
     else:
         kp, vp = jnp.asarray(kf), jnp.asarray(vf)
         kp_bad, vp_bad = kp.at[poison].set(jnp.nan), vp.at[poison].set(jnp.nan)
         kw_ref = kw
+    return ((kp_bad, vp_bad, jnp.asarray(poisoned), kw),
+            (kp, vp, jnp.asarray(tables), kw_ref))
+
+
+@pytest.mark.parametrize("case", sorted(_PREFILL_CASES))
+def test_paged_prefill_kernel_matches_reference(case, monkeypatch):
+    """The flash-over-the-table kernel (interpreted) against the gathered
+    XLA op on every REAL row: query tiling, context offsets, per-row
+    lengths with a zero-length dummy row, windows, int8 pools, head ratios.
+    Table entries past a sequence's blocks point at a poisoned block, and
+    so do those of the dummy row."""
+    c = dict(dict(nh=4, nkv=2, window=None, traced=False, ngroups=0),
+             **_PREFILL_CASES[case])
+    monkeypatch.setattr(paged_mod, "_Q_ROWS", 64)
+    rng = np.random.default_rng(3)
+    nh, nkv, hd, bs, mb = c["nh"], c["nkv"], 32, 8, 20
+    t, B = c["t"], len(c["ctx"])
+    q = jnp.asarray(rng.standard_normal((B, t, nh, hd)), jnp.float32)
+    (kp_bad, vp_bad, poisoned, kw), (kp, vp, tables, kw_ref) = \
+        _poisoned_pools(rng, c, [-(-(x + n) // bs) if n else 0
+                                 for x, n in zip(c["ctx"], c["lengths"])])
     ctx = jnp.asarray(c["ctx"], jnp.int32)
     lengths = jnp.asarray(c["lengths"], jnp.int32)
-    out_k = paged_prefill_attention(q, kp_bad, vp_bad, jnp.asarray(poisoned),
-                                    ctx, lengths, **kw)
-    out_x = paged_prefill_attention_xla(q, kp, vp, jnp.asarray(tables), ctx,
-                                        lengths, **kw_ref)
+    out_k = paged_prefill_attention(q, kp_bad, vp_bad, poisoned, ctx,
+                                    lengths, **kw)
+    out_x = paged_prefill_attention_xla(q, kp, vp, tables, ctx, lengths,
+                                        **kw_ref)
     assert out_k.shape == (B, t, nh, hd)
     assert np.isfinite(np.asarray(out_k)).all()
     for b, n in enumerate(c["lengths"]):
@@ -637,6 +648,102 @@ def test_paged_prefill_kernel_matches_reference(case, monkeypatch):
     g = nh // nkv
     tq, n_qt, pages = paged_mod._prefill_tiles(t, g, hd, bs, mb)
     assert g * tq <= 64 and n_qt * tq >= t and pages == 8
+
+
+# blocks are 8 tokens and a KV tile 8 pages (64 tokens) of every KV head;
+# the table is 20 blocks wide: two whole tiles and half a third. A context
+# of n is n tokens cached plus the current one.
+_DECODE_CASES = {
+    "gqa_8x4_contexts_round_a_block": dict(nkv=8, g=4, ctx=[0, 7, 8, 9]),
+    "mha_16x1_round_a_tile_boundary": dict(nkv=16, g=1, ctx=[62, 63, 64, 65]),
+    "mqa_1x8_second_boundary_and_full_table": dict(
+        nkv=1, g=8, ctx=[126, 127, 128, 159]),
+    "odd_group_of_3": dict(nkv=2, g=3, ctx=[0, 63, 64, 159]),
+    "empty_slots_on_the_trash_block_beside_full_ones": dict(
+        nkv=2, g=2, ctx=[159, 0, 159, 0], trash=[1, 3]),
+    "all_slots_short_walk_is_one_tile": dict(nkv=2, g=2, ctx=[3, 0, 40]),
+    "window_static": dict(nkv=2, g=2, ctx=[5, 77, 159], window=9),
+    "window_static_wider_than_a_tile": dict(nkv=2, g=2, ctx=[5, 77, 159],
+                                            window=70),
+    "window_traced_one_compile": dict(nkv=2, g=2, ctx=[5, 77, 159], window=9,
+                                      traced=True),
+    "int8_one_group": dict(nkv=2, g=2, ctx=[0, 64, 159], ngroups=1),
+    "int8_two_groups_windowed": dict(nkv=2, g=4, ctx=[13, 64, 130],
+                                     ngroups=2, window=20),
+    "head_block_smaller_than_nkv": dict(nkv=4, g=2, ctx=[0, 64, 159],
+                                        vmem=72 << 10),
+    "one_head_over_the_budget_fewer_pages": dict(nkv=2, g=2,
+                                                 ctx=[0, 64, 159],
+                                                 vmem=24 << 10),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE_CASES))
+def test_paged_decode_kernel_matches_reference(case, monkeypatch):
+    """The decode walk (interpreted) against the gathered XLA op: every KV
+    head and eight pages a grid step at GQA, MHA, MQA and an odd group;
+    contexts either side of a block and of a KV-tile boundary, the table's
+    full width (not a multiple of a tile), empty slots whose table is all
+    trash block; static and traced windows (one compile for two values);
+    int8 pools; the head block and page count a small VMEM budget forces.
+    Out-of-range table entries are poisoned as in the prefill test."""
+    c = dict(dict(window=None, traced=False, ngroups=0, trash=(), vmem=None),
+             **_DECODE_CASES[case])
+    if c["vmem"]:
+        monkeypatch.setattr(paged_mod, "_TILE_VMEM", c["vmem"])
+    rng = np.random.default_rng(5)
+    nkv, g, hd, bs, mb = c["nkv"], c["g"], 32, 8, 20
+    B = len(c["ctx"])
+    q = jnp.asarray(rng.standard_normal((B, nkv * g, hd)), jnp.float32)
+    (kp_bad, vp_bad, poisoned, kw), (kp, vp, tables, kw_ref) = \
+        _poisoned_pools(rng, c, [0 if b in c["trash"] else x // bs + 1
+                                 for b, x in enumerate(c["ctx"])])
+    poisoned = poisoned.at[np.asarray(c["trash"], int)].set(0)
+    ctx = jnp.asarray(c["ctx"], jnp.int32)
+    pages, heads, n_kv = paged_mod._decode_tiles(
+        nkv, g, hd, bs, mb, 1 if c["ngroups"] else 4, bool(c["ngroups"]))
+    assert (pages, heads, n_kv) == {72 << 10: (8, 2, 3), 24 << 10: (5, 1, 4),
+                                    None: (8, nkv, 3)}[c["vmem"]]
+    if c["traced"]:
+        kw, kw_ref = ({k: v for k, v in d.items() if k != "window"}
+                      for d in (kw, kw_ref))
+        f = jax.jit(lambda w: paged_decode_attention(
+            q, kp_bad, vp_bad, poisoned, ctx, window=w, **kw))
+        for w in (c["window"], 70):
+            np.testing.assert_allclose(
+                f(jnp.asarray(w, jnp.int32)), paged_decode_attention_xla(
+                    q, kp, vp, tables, ctx, window=w, **kw_ref),
+                atol=2e-5, rtol=2e-5)
+        assert f._cache_size() == 1
+        return
+    out_k = paged_decode_attention(q, kp_bad, vp_bad, poisoned, ctx, **kw)
+    out_x = paged_decode_attention_xla(q, kp, vp, tables, ctx, **kw_ref)
+    assert out_k.shape == (B, nkv * g, hd)
+    np.testing.assert_allclose(out_k, out_x, atol=2e-5, rtol=2e-5)
+    live, grid = paged_mod.decode_tile_counts(
+        c["ctx"], nkv * g, kp.shape, kp.dtype.itemsize, mb,
+        bool(c["ngroups"]))
+    assert live == sum(x // (pages * bs) + 1 for x in c["ctx"]) \
+        * (nkv // heads) <= grid \
+        == B * (max(c["ctx"]) // (pages * bs) + 1) * (nkv // heads)
+
+
+def test_decode_tiles_come_from_the_shapes():
+    """The serve cells' geometries: every KV head and eight 32-token pages a
+    grid step, so a layer's static grid is 1024 / 512 / 256 tiles (it was
+    65 536 / 32 768 / 32 768 steps); int8 pools count their lane-padded
+    scale tiles; wide or many heads get a head block, one head over the
+    budget fewer pages; the table is never overshot."""
+    tiles = paged_mod._decode_tiles
+    assert tiles(8, 4, 128, 32, 256, 2, False) == (8, 8, 32)     # x 32 slots
+    assert tiles(16, 1, 128, 32, 128, 2, False) == (8, 16, 16)   # x 16 slots
+    assert tiles(8, 4, 128, 32, 256, 1, True) == (8, 8, 32)
+    assert tiles(16, 1, 128, 32, 128, 1, True) == (8, 8, 16)     # two blocks
+    assert tiles(1, 71, 64, 32, 64, 2, False) == (8, 1, 8)       # falcon
+    assert tiles(64, 1, 256, 32, 256, 2, False) == (8, 8, 32)
+    assert tiles(8, 4, 128, 512, 16, 2, False) == (1, 8, 16)     # a wide page
+    assert tiles(1, 8, 128, 4096, 4, 4, False) == (1, 1, 4)
+    assert tiles(8, 4, 128, 32, 3, 2, False) == (3, 8, 1)        # short table
 
 
 def test_prefill_tiles_come_from_the_shapes():

@@ -113,7 +113,7 @@ def torn_group(hd):
 # --------------------------------------------------------------------------- #
 # kernel ↔ reference fallback agreement
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("ng", [1, 4])
+@pytest.mark.parametrize("ng", [1, 2, 4])
 @pytest.mark.parametrize("window", [None, 20])
 def test_quant_kernel_matches_xla_fallback(ng, window):
     """The Pallas fused-dequant decode kernel (interpret mode on CPU) and

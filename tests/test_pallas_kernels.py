@@ -163,13 +163,15 @@ class TestQuantize:
         assert np.all(np.asarray(back) == 0)
 
 
-def test_paged_decode_attention_matches_dense():
+@pytest.mark.parametrize("nh,nkv", [(8, 4), (8, 8), (8, 1), (6, 2)])
+def test_paged_decode_attention_matches_dense(nh, nkv):
     """Block-table-indexed flash-decode kernel vs dense gather reference
-    (reference inference/v2/kernels/ragged_ops)."""
+    (reference inference/v2/kernels/ragged_ops), at GQA, MHA, MQA and an
+    odd query group: every KV head of a sequence rides one grid step."""
     from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
 
     rs = np.random.RandomState(0)
-    B, nh, nkv, hd, bs, nblocks, max_blocks = 3, 8, 4, 64, 16, 32, 4
+    B, hd, bs, nblocks, max_blocks = 3, 64, 16, 32, 4
     q = jnp.asarray(rs.randn(B, nh, hd).astype(np.float32))
     kp = jnp.asarray(rs.randn(nblocks, nkv, bs, hd).astype(np.float32))
     vp = jnp.asarray(rs.randn(nblocks, nkv, bs, hd).astype(np.float32))

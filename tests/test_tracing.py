@@ -681,7 +681,9 @@ def test_ring_and_timeline_share_one_call_site(devices8, tmp_path):
     chunk = next(e for e in ring if e["name"] == "prefill_chunk")
     assert chunk["args"]["tokens"] == 16 and chunk["args"]["ctx"] == 0
     assert eng.last_step == {"prefill_tokens": 0, "prefill_kv_tokens": 0,
-                             "decode_seqs": 1, "kv_tokens": 41}
+                             "decode_seqs": 1, "kv_tokens": 41,
+                             "attn_tiles_live": 4, "attn_tiles_grid": 4,
+                             "attn_live_tile_share": 1.0}
 
 
 def test_prefill_spans_say_what_the_kernel_walked(devices8):
@@ -716,6 +718,40 @@ def test_prefill_spans_say_what_the_kernel_walked(devices8):
             == sum(a["ctx"] + a["tokens"] for a in chunks) \
             + sum(n for _, n in admitted)
     assert eng.last_step["prefill_kv_tokens"] == 0
+
+
+def test_decode_span_says_how_much_of_the_grid_is_live(devices8,
+                                                       monkeypatch):
+    """``decode_step`` (and ``last_step``) carry, for one layer's
+    ``paged_decode`` call, the KV tiles that hold live context, the tiles
+    the grid visits - every slot walks as far as the longest - and their
+    ratio, from the kernel's own tile sizes and the slots' lengths."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_TOKENS", 32)   # two 16-token pages a tile
+    cfg, eng = _serving_engine(trace=True)
+    nkv, hd = cfg.num_kv_heads, cfg.head_size
+    pages, heads, n_kv = pa._decode_tiles(
+        nkv, cfg.num_heads // nkv, hd, 16, eng.state.max_blocks_per_seq, 4,
+        False)
+    assert (pages, heads) == (2, nkv) and n_kv > 2
+    rng = np.random.default_rng(2)
+    eng.put_many([(uid, rng.integers(0, cfg.vocab_size, (n,)).tolist())
+                  for uid, n in ((0, 70), (1, 9))])
+    for _ in range(3):
+        lens = eng._slot_lens.copy()        # as the dispatch sees them
+        eng.step()
+        args = [e["args"] for e in eng.tracer.events()
+                if e["ph"] == "X" and e["name"] == "decode_step"][-1]
+        tiles = lens // 32 + 1              # the current token included
+        assert sorted(tiles) == [1, 1, 1, 3]   # two free slots: one dead tile
+        assert args["attn_tiles_live"] == tiles.sum() == 6
+        assert args["attn_tiles_grid"] == 4 * tiles.max() == 12
+        assert args["attn_live_tile_share"] == 0.5
+        assert {k: eng.last_step[k] for k in args if k.startswith("attn_")} \
+            == {k: v for k, v in args.items() if k.startswith("attn_")}
+    assert pa.decode_tile_counts(lens, cfg.num_heads, eng.cache["k"].shape, 4,
+                                 eng.state.max_blocks_per_seq, False) == (6, 12)
 
 
 def _kernel_cases():
