@@ -348,7 +348,7 @@ def probe_logits(eng, cfg, prompt, steps: int, uid: int):
 
     paged, eng.cache = jax.jit(last_position, donate_argnums=(1,))(
         eng.params, eng.cache, jnp.asarray(tokens[None, -1:]),
-        jnp.asarray(eng._slot_tables[d.slot][None]),
+        jnp.asarray(eng.state.block_table(d)[None]),
         jnp.asarray([d.seen_tokens - 1], jnp.int32))
     # the reference is the plain XLA softmax attention, not the flash kernel
     registry.set_backend("attention", "xla")
@@ -375,7 +375,6 @@ def phase_serve(size: ServeSize, seed: int, mosaic: bool = True,
     import numpy as np
 
     from deepspeed_tpu.comm import mesh as mesh_lib
-    from deepspeed_tpu.inference.sampling import SamplingParams
 
     cfg = cfg or mistral_7b(size.layers)
     mesh_lib.set_mesh(None)  # the server builds its own over every device
@@ -406,7 +405,7 @@ def phase_serve(size: ServeSize, seed: int, mosaic: bool = True,
                            rng.integers(0, cfg.vocab_size, n).tolist(),
                            steps, uid=10 ** 6 + i)
               for i, (n, steps) in enumerate(size.probes)]
-    decode = eng._paged_fns[("decode", SamplingParams(greedy=True))]
+    decode = eng._decode_fn(1, False)    # the program the passes ran
     decode_hlo = "\n".join(c.as_text() for c in compiled_programs(decode))
     pool_bytes = sum(x.nbytes for x in jax.tree.leaves(eng.cache))
     weight_bytes = sum(x.nbytes for x in jax.tree.leaves(eng.params))

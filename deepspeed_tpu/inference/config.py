@@ -76,14 +76,6 @@ class SpeculativeConfig:
     max_draft_tokens: int = 4    # draft positions verified per step (k)
     ngram_max: int = 3           # longest trailing n-gram tried first
     min_match: int = 1           # shortest n-gram that may draft
-    # fuse verification into the paged-decode kernel family (docs/serving.md
-    # "Fused verification"): the [last_token, draft_1..k] rows score against
-    # the block-table-indexed KV pools (dequant-in-register in kv_quant
-    # mode) instead of re-running the ctx-offset PREFILL programs, which
-    # re-gather the whole context into a dense [B, max_blocks*bs, ...] view
-    # at prefill width every verify step. OFF → the exact pre-fuse verify
-    # programs, byte-identical (pinned by parity tests).
-    fused_verify: bool = False
 
 
 @dataclass

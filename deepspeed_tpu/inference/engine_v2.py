@@ -22,6 +22,7 @@ serving then emits >1 token per model step without a second model.
 from __future__ import annotations
 
 import time
+from itertools import repeat
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -36,8 +37,8 @@ from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .engine import InferenceEngine, ModelFamily, _round_up
 from .ragged import StateManager, UnknownSequenceError  # noqa: F401 (re-export)
-from .sampling import (SamplingParams, filter_logits_batch, sample,
-                       sample_batch, sp_arrays)
+from .sampling import (SamplingParams, accept_drafts, sample, sample_batch,
+                       sp_arrays)
 
 
 def prompt_lookup_draft(history, max_tokens: int, ngram_max: int = 3,
@@ -70,6 +71,41 @@ def prompt_lookup_draft(history, max_tokens: int, ngram_max: int = 3,
 # what ``InferenceEngineV2.last_step`` holds before a step has done anything
 _NO_WORK = {"prefill_tokens": 0, "prefill_kv_tokens": 0, "decode_seqs": 0,
             "kv_tokens": 0}
+
+# the one static sampling config the programs are built with (a final prefill
+# chunk apart): every greedy-equivalent request canonicalizes to it
+_GREEDY = SamplingParams(greedy=True)
+
+
+def _last_row(logits, lengths):
+    """Logits of each sequence's last REAL row, traced: ``logits`` [n, t, V]
+    with ``lengths`` [n] give [n, V]; one chunk's scalar length gives [V]."""
+    idx = jnp.maximum(lengths - 1, 0)
+    if idx.ndim == 0:
+        return jnp.take_along_axis(logits, idx[None, None, None],
+                                   axis=1)[0, 0]
+    return jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
+
+
+def _sampler(rows: bool):
+    """A program samples one of two ways, chosen from what its call can see
+    (its sequences' params): an all-greedy call takes the argmax - no per-row
+    sort machinery in the program - and any stochastic request switches the
+    call to per-row (temperature, top_k, top_p, greedy) arrays as traced
+    arguments, which compile ONCE for every mix of client configs (keying a
+    program on a non-greedy ``sp`` would compile per distinct config).
+    Returns ``pick(key, logits, *arrays)`` over logits [B, V], or over one
+    row's [V] under ``vmap`` with the row's own key."""
+    if not rows:
+        return lambda key, logits: sample(key, logits, _GREEDY)
+
+    def pick(key, logits, *arrays):
+        if logits.ndim == 1:        # sample_batch works on a batch of rows
+            return sample_batch(key, logits[None],
+                                *(a[None] for a in arrays))[0]
+        return sample_batch(key, logits, *arrays)
+
+    return pick
 
 
 class InferenceEngineV2(InferenceEngine):
@@ -174,7 +210,7 @@ class InferenceEngineV2(InferenceEngine):
         self._slot_active = np.zeros((B,), bool)
         # per-slot sampling params, recorded at admission — decode honors
         # these (the reference's v2 engine carries per-request sampling)
-        self._slot_sp: List[SamplingParams] = [SamplingParams(greedy=True)] * B
+        self._slot_sp: List[SamplingParams] = [_GREEDY] * B
         # uid → (full prompt, SamplingParams from put_split)
         self._pending_prefill: Dict[int, Tuple] = {}
         # --- speculative decoding (docs/serving.md). Default OFF: step()
@@ -184,12 +220,6 @@ class InferenceEngineV2(InferenceEngine):
         self._spec_k = max(1, int(sc.max_draft_tokens))
         self._spec_ngram_max = max(1, int(sc.ngram_max))
         self._spec_min_match = max(1, int(sc.min_match))
-        # fused verification (inference.speculative.fused_verify;
-        # docs/serving.md "Fused verification"): the verify program traces
-        # under fused_verify_scope as its own program family. It selects
-        # no kernel: every multi-token attention is paged_prefill.
-        self._spec_fused = bool(self._spec_on
-                                and getattr(sc, "fused_verify", False))
         # cumulative Serving/spec/* counters (spec_events): model steps run
         # in spec mode split into verify (>=1 draft scored) vs plain decode
         # fallbacks, plus drafted/accepted/emitted/rolled-back token counts
@@ -198,7 +228,7 @@ class InferenceEngineV2(InferenceEngine):
             "verify_steps": 0, "decode_steps": 0, "step_seqs": 0,
             "drafted_tokens": 0, "accepted_tokens": 0, "emitted_tokens": 0,
             "rolled_back_tokens": 0, "verify_positions": 0,
-            "verify_capacity": 0, "fused_verify_steps": 0}
+            "verify_capacity": 0}
         # --- request-lifecycle tracing + latency SLO stats (trace.py;
         # docs/serving.md), on the ring of ``self.tracer``. Default OFF:
         # every ``_req_*`` hook below is a no-op and no timer ever starts.
@@ -239,10 +269,7 @@ class InferenceEngineV2(InferenceEngine):
         self._adopted: Dict[int, Any] = {}
         self._lat: Dict[str, List[float]] = {
             "ttft_ms": [], "itl_ms": [], "queue_ms": [], "e2e_ms": []}
-        spec_lbl = "off"
-        if self._spec_on:
-            spec_lbl = "on(k=%d%s)" % (self._spec_k,
-                                       ",fused" if self._spec_fused else "")
+        spec_lbl = "on(k=%d)" % self._spec_k if self._spec_on else "off"
         log_dist(f"InferenceEngineV2: {rc.memory_config_blocks} blocks × "
                  f"{rc.block_size} tokens, {B} sequence slots, "
                  f"kv_quant={'int8(g=%d)' % self._kvq_group if self._kvq_on else 'off'}, "
@@ -271,13 +298,18 @@ class InferenceEngineV2(InferenceEngine):
         they would never end and never reach the flight-recorder ring — but
         record NO latency samples (the destination leg owns the stream's SLO
         story). Tolerant of an absent record, like ``_req_drop``."""
+        rec = self._req_close(uid)
+        if rec is not None:
+            rec["span"].end(handoff=reason)
+
+    def _req_close(self, uid: int) -> Optional[dict]:
+        """Take ``uid``'s lifecycle record out (None when there is none)
+        with its queue-wait ended; the caller ends the request's span."""
         self._adopted.pop(uid, None)
         rec = self._req.pop(uid, None)
-        if rec is None:
-            return
-        if rec["queue"] is not None:
+        if rec is not None and rec["queue"] is not None:
             rec["queue"].end()
-        rec["span"].end(handoff=reason)
+        return rec
 
     def _req_admit(self, uid: int, prompt_len: int,
                    split: bool = False) -> None:
@@ -346,12 +378,9 @@ class InferenceEngineV2(InferenceEngine):
         rec["last_ns"] = t_ns
 
     def _req_finish(self, uid: int, **args) -> None:
-        self._adopted.pop(uid, None)
-        rec = self._req.pop(uid, None)
+        rec = self._req_close(uid)
         if rec is None:
             return
-        if rec["queue"] is not None:
-            rec["queue"].end()
         self._lat["e2e_ms"].append(
             (time.monotonic_ns() - rec["t_admit"]) / 1e6)
         rec["span"].end(**args)
@@ -364,13 +393,9 @@ class InferenceEngineV2(InferenceEngine):
         error-bearing surface for unknown/already-finished uids is
         ``finish()``/``park()``/``fork()`` via ``StateManager.lookup``
         (one consistent :class:`UnknownSequenceError`)."""
-        self._adopted.pop(uid, None)
-        rec = self._req.pop(uid, None)
-        if rec is None:
-            return
-        if rec["queue"] is not None:
-            rec["queue"].end()
-        rec["span"].end(cancelled=True)
+        rec = self._req_close(uid)
+        if rec is not None:
+            rec["span"].end(cancelled=True)
 
     # ------------------------------------------------------------------ #
     def _jit(self, key, fn, **jit_kwargs):
@@ -383,7 +408,21 @@ class InferenceEngineV2(InferenceEngine):
                                         **jit_kwargs)
 
     # ------------------------------------------------------------------ #
-    def _prefill_fn(self, pad_t: int, sp: SamplingParams, n: int = 1):
+    # the programs: ONE forward (``_paged_forward``), the last real row's
+    # logits (``_last_row``), one of two samplers (``_sampler``), in four
+    # builders - prefill, chunk_prefill, decode, spec_verify. ``_dispatch``
+    # launches all of them.
+    # ------------------------------------------------------------------ #
+    def _paged_forward(self, params, tokens, cache, tables, ctx, valid):
+        """The engine's ONE call of the family's paged forward, traced inside
+        every program: ``tokens`` [b, t] at context offsets ``ctx`` [b]
+        through block tables [b, blocks], ``params`` as ``_dq`` hands them
+        over; rows where ``valid`` [b, t] is False write to the trash block.
+        Returns (logits [b, t, V] fp32, cache)."""
+        return self._apply_paged(self.family.cfg, params, tokens, cache,
+                                 tables, ctx, valid=valid)
+
+    def _prefill_fn(self, pad_t: int, n: int, with_ctx: bool, rows: bool):
         """One compiled prefill over ``n`` admitted sequences at once —
         admission bursts (serving start, high churn) run one program call
         instead of n (the reference schedules multi-sequence ragged prefill
@@ -391,22 +430,38 @@ class InferenceEngineV2(InferenceEngine):
         zero-length dummy rows (masked by ``valid``, writing to the trash
         block) so compile count stays O(log max_sequences) per pad_t, not
         O(max_sequences). Per-row rng keys fold in each uid, keeping
-        first-token sampling independent of burst composition."""
-        key = ("prefill", pad_t, sp, n)
-        if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
+        first-token sampling independent of burst composition.
 
-            def prefill(params, cache, tokens, lengths, tables, rng, uids):
-                # tokens [n, pad_t]; lengths [n]; tables [n, blocks]
+        ``with_ctx``: the prefix-cache admission path — row i's tokens are
+        the UNCACHED suffix of its prompt and ``ctx[i]`` counts the tokens
+        already resolved to shared blocks, so positions/attention pick up
+        mid-prompt exactly like a split-prefill chunk does. Compiled only
+        when the cache is enabled AND a batch actually hit; without it the
+        offset is a zeros constant of the program, so cache-off admissions
+        keep the zero-offset programs byte for byte.
+
+        ``rows``: see ``_sampler`` (the static variant would also break
+        admission bursts into per-config groups)."""
+        name = "prefill" + ("_ctx" if with_ctx else "") \
+            + ("_dyn" if rows else "")
+        key = (name, pad_t, n)
+        if key not in self._paged_fns:
+            pick = _sampler(rows)
+
+            def prefill(params, cache, tokens, lengths, tables, *rest):
+                # tokens [n, pad_t]; lengths [n]; tables [n, blocks]; then
+                # ctx [n] (with_ctx), rng, uids [n], sampling arrays (rows)
+                ctx, rng, uids, *sp_rows = rest if with_ctx \
+                    else (None,) + rest
                 valid = jnp.arange(pad_t)[None, :] < lengths[:, None]
-                logits, cache = ap(fam.cfg, self._dq(params), tokens, cache,
-                                   tables, jnp.zeros((n,), jnp.int32),
-                                   valid=valid)
-                last = jnp.take_along_axis(
-                    logits, jnp.maximum(lengths - 1, 0)[:, None, None],
-                    axis=1)[:, 0]
+                dq = self._dq(params)
+                if not with_ctx:
+                    ctx = jnp.zeros((n,), jnp.int32)
+                logits, cache = self._paged_forward(dq, tokens, cache, tables,
+                                                    ctx, valid)
+                last = _last_row(logits, lengths)
                 keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
-                toks = jax.vmap(lambda k, l: sample(k, l, sp))(keys, last)
+                toks = jax.vmap(pick)(keys, last, *sp_rows)
                 return toks.astype(jnp.int32), cache
 
             self._paged_fns[key] = self._jit(key, prefill, donate_argnums=(1,))
@@ -418,8 +473,7 @@ class InferenceEngineV2(InferenceEngine):
         """step()/step_many() sample with ADMISSION-time params; a caller
         passing a non-default sp here (the pre-r4 API contract) would
         otherwise silently get each slot's put()-time config instead."""
-        if not self._sp_warned and \
-                self._canon_sp(sp) != SamplingParams(greedy=True):
+        if not self._sp_warned and self._canon_sp(sp) != _GREEDY:
             import warnings
 
             warnings.warn(
@@ -433,86 +487,8 @@ class InferenceEngineV2(InferenceEngine):
         """Greedy-equivalent configs (greedy=True, or temperature 0) all
         canonicalize to ONE params value so they share compiled programs."""
         if sp.greedy or sp.temperature == 0.0:
-            return SamplingParams(greedy=True)
+            return _GREEDY
         return sp
-
-    def _prefill_dyn_fn(self, pad_t: int, n: int):
-        """Batched prefill with per-ROW sampling params as traced arrays —
-        one compile per (pad_t, n) serves any mix of client configs (the
-        static variant would compile per distinct SamplingParams and break
-        admission bursts into per-config groups)."""
-        key = ("prefill_dyn", pad_t, n)
-        if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
-
-            def prefill(params, cache, tokens, lengths, tables, rng, uids,
-                        temp, topk, topp, greedy):
-                valid = jnp.arange(pad_t)[None, :] < lengths[:, None]
-                logits, cache = ap(fam.cfg, self._dq(params), tokens, cache,
-                                   tables, jnp.zeros((n,), jnp.int32),
-                                   valid=valid)
-                last = jnp.take_along_axis(
-                    logits, jnp.maximum(lengths - 1, 0)[:, None, None],
-                    axis=1)[:, 0]
-                keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
-                toks = jax.vmap(lambda k, l, t, tk, tp, g: sample_batch(
-                    k, l[None], t[None], tk[None], tp[None], g[None])[0])(
-                        keys, last, temp, topk, topp, greedy)
-                return toks.astype(jnp.int32), cache
-
-            self._paged_fns[key] = self._jit(key, prefill, donate_argnums=(1,))
-        return self._paged_fns[key]
-
-    def _prefill_ctx_fn(self, pad_t: int, sp: SamplingParams, n: int):
-        """Batched prefill starting at a per-ROW context offset — the
-        prefix-cache admission path: row i's tokens are the UNCACHED suffix
-        of its prompt and ``ctx[i]`` counts the tokens already resolved to
-        shared blocks, so positions/attention pick up mid-prompt exactly
-        like a split-prefill chunk does. Compiled only when the cache is
-        enabled AND a batch actually hit — cache-off admissions keep the
-        original zero-offset programs byte for byte."""
-        key = ("prefill_ctx", pad_t, sp, n)
-        if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
-
-            def prefill(params, cache, tokens, lengths, tables, ctx, rng,
-                        uids):
-                valid = jnp.arange(pad_t)[None, :] < lengths[:, None]
-                logits, cache = ap(fam.cfg, self._dq(params), tokens, cache,
-                                   tables, ctx, valid=valid)
-                last = jnp.take_along_axis(
-                    logits, jnp.maximum(lengths - 1, 0)[:, None, None],
-                    axis=1)[:, 0]
-                keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
-                toks = jax.vmap(lambda k, l: sample(k, l, sp))(keys, last)
-                return toks.astype(jnp.int32), cache
-
-            self._paged_fns[key] = self._jit(key, prefill, donate_argnums=(1,))
-        return self._paged_fns[key]
-
-    def _prefill_ctx_dyn_fn(self, pad_t: int, n: int):
-        """Context-offset prefill with per-row sampling params as traced
-        arrays (the ``_prefill_dyn_fn`` analog of ``_prefill_ctx_fn``)."""
-        key = ("prefill_ctx_dyn", pad_t, n)
-        if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
-
-            def prefill(params, cache, tokens, lengths, tables, ctx, rng,
-                        uids, temp, topk, topp, greedy):
-                valid = jnp.arange(pad_t)[None, :] < lengths[:, None]
-                logits, cache = ap(fam.cfg, self._dq(params), tokens, cache,
-                                   tables, ctx, valid=valid)
-                last = jnp.take_along_axis(
-                    logits, jnp.maximum(lengths - 1, 0)[:, None, None],
-                    axis=1)[:, 0]
-                keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
-                toks = jax.vmap(lambda k, l, t, tk, tp, g: sample_batch(
-                    k, l[None], t[None], tk[None], tp[None], g[None])[0])(
-                        keys, last, temp, topk, topp, greedy)
-                return toks.astype(jnp.int32), cache
-
-            self._paged_fns[key] = self._jit(key, prefill, donate_argnums=(1,))
-        return self._paged_fns[key]
 
     def _copy_block_fn(self):
         """One compiled (src, dst are traced scalars) whole-block copy in the
@@ -571,45 +547,45 @@ class InferenceEngineV2(InferenceEngine):
             self.cache = fn(self.cache, jnp.asarray(src, jnp.int32),
                             jnp.asarray(dst, jnp.int32))
 
-    def _chunk_prefill_fn(self, chunk_t: int, sp: SamplingParams,
-                          final: bool):
+    def _chunk_prefill_fn(self, chunk_t: int, final: bool,
+                          sp: SamplingParams):
         """One compiled prefill CHUNK for one sequence at an arbitrary
         context offset — the Dynamic-SplitFuse unit (reference
         blogs/deepspeed-fastgen: 'decompose long prompts into chunks').
         Mid chunks only write KV; the final chunk also samples the first
         token. One compile per (chunk_t, final) for mid chunks — sp is
         unused there, so keying on it would recompile identical programs
-        per client config — plus one per sp for final chunks."""
+        per client config — plus one per sp for final chunks (the one
+        program still compiled per client sampling config)."""
         key = ("chunk_prefill", chunk_t, sp if final else None, final)
         if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
 
             def chunk_prefill(params, cache, tokens, n_valid, ctx, table,
                               rng, uid):
                 # tokens [1, chunk_t]; ctx = tokens already cached
                 valid = (jnp.arange(chunk_t) < n_valid)[None, :]
-                logits, cache = ap(fam.cfg, self._dq(params), tokens, cache,
-                                   table[None], ctx[None], valid=valid)
+                logits, cache = self._paged_forward(
+                    self._dq(params), tokens, cache, table[None], ctx[None],
+                    valid)
                 if not final:
                     return cache
-                last = jnp.take_along_axis(
-                    logits, jnp.maximum(n_valid - 1, 0)[None, None, None],
-                    axis=1)[0, 0]
+                last = _last_row(logits, n_valid)
                 tok = sample(jax.random.fold_in(rng, uid), last, sp)
                 return tok.astype(jnp.int32), cache
 
-            donate = (1,)
             self._paged_fns[key] = self._jit(key, chunk_prefill,
-                                           donate_argnums=donate)
+                                             donate_argnums=(1,))
         return self._paged_fns[key]
 
-    def _begin_step(self) -> None:
-        """``last_step`` starts over; the one-shot prefills that ran since
-        the previous step (``put``/``put_many``, a scheduler tick's
-        admissions) count with this step's ``prefill_kv_tokens``."""
-        self.last_step = dict(_NO_WORK,
-                              prefill_kv_tokens=self._admitted_kv_tokens)
-        self._admitted_kv_tokens = 0
+    def _dispatch(self, fn, pre, seed: int, post=()):
+        """The one place a forward program is launched: ``pre`` and ``post``
+        are the call's host arrays in the program's argument order, on
+        either side of its rng key, each uploaded here and nowhere else.
+        Returns what the program returns - the donated cache last, for the
+        caller to take back."""
+        with self.tracer.span("engine_dispatch", cat="serving"):
+            return fn(self.params, self.cache, *map(jnp.asarray, pre),
+                      jax.random.PRNGKey(seed), *map(jnp.asarray, post))
 
     def _kv_blocks(self, kv_tokens: int) -> int:
         """Blocks a prefill call's longest row attends over (cached context
@@ -671,15 +647,12 @@ class InferenceEngineV2(InferenceEngine):
                 padded = np.zeros((1, chunk_tokens), np.int32)
                 padded[0, :len(chunk)] = chunk
                 table = self.state.block_table(desc)
-                fn = self._chunk_prefill_fn(chunk_tokens, sp, final)
+                fn = self._chunk_prefill_fn(chunk_tokens, final, sp)
             if self._trace_on:
                 self._req_compute_begin(uid)   # first chunk ends queue-wait
-            with self.tracer.span("engine_dispatch", cat="serving"):
-                res = fn(self.params, self.cache, jnp.asarray(padded),
-                         jnp.asarray(len(chunk), jnp.int32),
-                         jnp.asarray(done, jnp.int32), jnp.asarray(table),
-                         jax.random.PRNGKey(seed),
-                         jnp.asarray(uid, jnp.int32))
+            res = self._dispatch(
+                fn, (padded, np.int32(len(chunk)), np.int32(done), table),
+                seed, (np.int32(uid),))
             self.last_step["prefill_tokens"] += len(chunk)
             self.last_step["prefill_kv_tokens"] += done + len(chunk)
             self.prefill_tokens_written += len(chunk)
@@ -702,13 +675,17 @@ class InferenceEngineV2(InferenceEngine):
                 desc.prefilling = False
                 desc.last_token = tok
                 desc.generated.append(tok)
-                s = desc.slot
-                self._slot_tokens[s] = tok
-                self._slot_lens[s] = desc.seen_tokens
-                self._slot_tables[s] = table
-                self._slot_active[s] = True
-                self._slot_sp[s] = self._canon_sp(sp)
+                self._seat(desc, table, self._canon_sp(sp))
         return {uid: tok}
+
+    def _seat(self, desc, table, sp: SamplingParams) -> None:
+        """The sequence's slot as the next decode-shaped call reads it."""
+        s = desc.slot
+        self._slot_tokens[s] = desc.last_token
+        self._slot_lens[s] = desc.seen_tokens
+        self._slot_tables[s] = table
+        self._slot_active[s] = True
+        self._slot_sp[s] = sp
 
     def put_split(self, uid: int, prompt_tokens,
                   sp: SamplingParams = SamplingParams(greedy=True)) -> None:
@@ -729,102 +706,50 @@ class InferenceEngineV2(InferenceEngine):
         desc.prefilling = True
         self._pending_prefill[uid] = (prompt, sp)
 
-    def _decode_fn(self, sp: SamplingParams):
-        key = ("decode", sp)
+    def _decode_fn(self, k: int, rows: bool):
+        """Decode over every sequence slot, ``k`` ticks in ONE compiled
+        program: ``k == 1`` is the single step, ``k > 1`` a ``lax.scan`` of
+        the same tick with a single host sync at the end. The reference's
+        persistent-kernel decode loop achieves the same thing on GPU; over a
+        network-attached TPU the per-step host round-trip dominates
+        single-step decode, so the scan is the serving fast path (block
+        capacity is reserved for all k tokens before launch — see
+        ``_reserve``). ``rows``: see ``_sampler``."""
+        name = ("decode" if k == 1 else "decode_many") \
+            + ("_dyn" if rows else "")
+        key = (name, k)
         if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
-
-            def decode(params, cache, tokens, lens, tables, active, rng):
-                # inactive slots write to the trash block (valid=False)
-                logits, cache = ap(fam.cfg, self._dq(params), tokens[:, None], cache,
-                                   tables, lens, valid=active[:, None])
-                nxt = sample(rng, logits[:, 0], sp)
-                return nxt.astype(jnp.int32), cache
-
-            self._paged_fns[key] = self._jit(key, decode, donate_argnums=(1,))
-        return self._paged_fns[key]
-
-    def _decode_dyn_fn(self):
-        """Decode with per-SLOT sampling params as traced arrays — ONE
-        compile serves any mix of client sampling configs."""
-        key = ("decode_dyn",)
-        if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
+            pick = _sampler(rows)
 
             def decode(params, cache, tokens, lens, tables, active, rng,
-                       temp, topk, topp, greedy):
-                logits, cache = ap(fam.cfg, self._dq(params), tokens[:, None], cache,
-                                   tables, lens, valid=active[:, None])
-                nxt = sample_batch(rng, logits[:, 0], temp, topk, topp, greedy)
-                return nxt.astype(jnp.int32), cache
+                       *sp_rows):
+                dq = self._dq(params)
 
+                def tick(tokens, lens, cache, key_t):
+                    # inactive slots write to the trash block (valid=False)
+                    logits, cache = self._paged_forward(
+                        dq, tokens[:, None], cache, tables, lens,
+                        active[:, None])
+                    nxt = pick(key_t, logits[:, 0], *sp_rows)
+                    return nxt.astype(jnp.int32), cache
+
+                if k == 1:
+                    return tick(tokens, lens, cache, rng)
+
+                def body(carry, key_t):
+                    tokens, lens, cache = carry
+                    nxt, cache = tick(tokens, lens, cache, key_t)
+                    return (nxt, lens + active.astype(jnp.int32), cache), nxt
+
+                keys = jax.random.split(rng, k)
+                (tokens, lens, cache), toks = jax.lax.scan(
+                    body, (tokens, lens, cache), keys)
+                return toks, lens, cache  # toks: [k, B]
+
+            # the programs' names are read off the device trace
+            # (benchmark/readers: jit_decode)
+            decode.__name__ = "decode" if k == 1 else "decode_many"
             self._paged_fns[key] = self._jit(key, decode, donate_argnums=(1,))
-        return self._paged_fns[key]
-
-    def _decode_many_dyn_fn(self, k: int):
-        key = ("decode_many_dyn", k)
-        if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
-
-            def decode_many(params, cache, tokens, lens, tables, active, rng,
-                            temp, topk, topp, greedy):
-                dq = self._dq(params)
-
-                def tick(carry, key_t):
-                    tokens, lens, cache = carry
-                    logits, cache = ap(fam.cfg, dq, tokens[:, None], cache,
-                                       tables, lens, valid=active[:, None])
-                    nxt = sample_batch(key_t, logits[:, 0], temp, topk, topp,
-                                       greedy).astype(jnp.int32)
-                    lens = lens + active.astype(jnp.int32)
-                    return (nxt, lens, cache), nxt
-
-                keys = jax.random.split(rng, k)
-                (tokens, lens, cache), toks = jax.lax.scan(
-                    tick, (tokens, lens, cache), keys)
-                return toks, lens, cache  # toks: [k, B]
-
-            self._paged_fns[key] = self._jit(key, decode_many, donate_argnums=(1,))
-        return self._paged_fns[key]
-
-    def _needs_dynamic_sp(self, live) -> bool:
-        """True unless every live sequence is greedy. Greedy batches take
-        the static variant (argmax only — no per-row sort machinery); any
-        stochastic request takes the per-slot-array variant, which compiles
-        ONCE for every sampling-config mix (keying the static variant on a
-        non-greedy sp would compile per distinct client config)."""
-        return not all(self._slot_sp[d.slot].greedy
-                       or self._slot_sp[d.slot].temperature == 0.0
-                       for d in live)
-
-    def _decode_many_fn(self, k: int, sp: SamplingParams):
-        """k fused decode ticks in ONE compiled program (lax.scan) with a
-        single host sync at the end. The reference's persistent-kernel decode
-        loop achieves the same thing on GPU; over a network-attached TPU the
-        per-step host round-trip dominates single-step decode, so this is
-        the serving fast path (block capacity is reserved for all k tokens
-        before launch — see ``StateManager.extend(n=k)``)."""
-        key = ("decode_many", k, sp)
-        if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
-
-            def decode_many(params, cache, tokens, lens, tables, active, rng):
-                dq = self._dq(params)
-
-                def tick(carry, key_t):
-                    tokens, lens, cache = carry
-                    logits, cache = ap(fam.cfg, dq, tokens[:, None], cache,
-                                       tables, lens, valid=active[:, None])
-                    nxt = sample(key_t, logits[:, 0], sp).astype(jnp.int32)
-                    lens = lens + active.astype(jnp.int32)
-                    return (nxt, lens, cache), nxt
-
-                keys = jax.random.split(rng, k)
-                (tokens, lens, cache), toks = jax.lax.scan(
-                    tick, (tokens, lens, cache), keys)
-                return toks, lens, cache  # toks: [k, B]
-
-            self._paged_fns[key] = self._jit(key, decode_many, donate_argnums=(1,))
         return self._paged_fns[key]
 
     # ------------------------------------------------------------------ #
@@ -836,82 +761,27 @@ class InferenceEngineV2(InferenceEngine):
         of every sequence slot against the paged cache — the ctx-offset
         prefill machinery applied at decode time: row i feeds
         ``[last_token, draft_1..draft_k]`` at context offset ``lens[i]`` with
-        positions past ``1 + draft_len[i]`` masked to the trash block.
+        positions past ``1 + draft_len[i]`` masked to the trash block. Every
+        layer's attention is the ``paged_prefill`` kernel over the block
+        table, as for any multi-token call (dequant-in-register in kv_quant
+        mode).
 
-        Acceptance runs on-device so the step has exactly one host sync:
-        greedy rows accept draft j while it equals the argmax of the logits
-        that precede it; stochastic rows accept with probability
-        ``p(draft_j)`` under their own temperature/top-k/top-p-filtered
-        distribution — exact rejection sampling for the DETERMINISTIC
-        prompt-lookup drafter (q = δ), so on rejection the correction is
-        drawn from p with the rejected token removed and renormalized, and
-        the emitted stream is distributed exactly as plain decode. When every
-        draft is accepted the bonus position (scored in the same pass)
-        supplies one extra token. Returns (accepted_len [B], next_token [B],
-        cache).
-
-        With ``inference.speculative.fused_verify`` the forward pass traces
-        under ``models/_paged.fused_verify_scope`` and registers as a
-        distinct program family (``spec_verify_fused``) for the compile
-        monitor and the serving bench. Either way every layer's multi-token
-        attention is the ``paged_prefill`` kernel over the block table
-        (dequant-in-register in kv_quant mode): the key selects no kernel
-        any more (ROADMAP simplicity queue)."""
-        fused = self._spec_fused
-        key = ("spec_verify_fused" if fused else "spec_verify", kp1)
+        Acceptance (``sampling.accept_drafts``, this program's sampler) runs
+        on-device so the step has exactly one host sync. Returns
+        (accepted_len [B], next_token [B], cache)."""
+        key = ("spec_verify", kp1)
         if key not in self._paged_fns:
-            fam, ap = self.family, self._apply_paged
-            from ..models import _paged as _paged_mod
 
             def verify(params, cache, tokens, lens, tables, active, nvalid,
                        drafts, rng, uids, temp, topk, topp, greedy):
                 # tokens [B, kp1]; nvalid [B] = 1 + draft_len;
                 # drafts [B, kp1-1] (zero-padded past draft_len)
-                B = tokens.shape[0]
-                k = kp1 - 1
                 valid = (jnp.arange(kp1)[None, :] < nvalid[:, None]) \
                     & active[:, None]
-                if fused:
-                    with _paged_mod.fused_verify_scope():
-                        logits, cache = ap(fam.cfg, self._dq(params), tokens,
-                                           cache, tables, lens, valid=valid)
-                else:
-                    logits, cache = ap(fam.cfg, self._dq(params), tokens,
-                                       cache, tables, lens, valid=valid)
-                amax = jnp.argmax(logits, axis=-1)                 # [B, kp1]
-                filt = filter_logits_batch(
-                    logits.reshape(B * kp1, -1),
-                    jnp.repeat(temp, kp1), jnp.repeat(topk, kp1),
-                    jnp.repeat(topp, kp1)).reshape(B, kp1, -1)
-                probs = jax.nn.softmax(filt, axis=-1)
-                draft_len = nvalid - 1
-                keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
-                accept_u = jax.vmap(
-                    lambda kk: jax.random.uniform(kk, (k,)))(keys)  # [B, k]
-                p_draft = jnp.take_along_axis(
-                    probs[:, :k, :], drafts[..., None], axis=-1)[..., 0]
-                is_greedy = jnp.logical_or(greedy, temp <= 0.0)
-                ok = jnp.where(is_greedy[:, None], drafts == amax[:, :k],
-                               accept_u < p_draft)
-                ok = ok & (jnp.arange(k)[None, :] < draft_len[:, None])
-                # longest agreeing prefix: cumprod zeroes everything after
-                # the first rejection
-                m = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1),
-                            axis=1)                                 # [B]
-                lm = jnp.take_along_axis(filt, m[:, None, None],
-                                         axis=1)[:, 0]              # [B, V]
-                la = jnp.take_along_axis(amax, m[:, None], axis=1)[:, 0]
-                rejected = m < draft_len
-                d_m = jnp.take_along_axis(
-                    drafts, jnp.minimum(m, k - 1)[:, None], axis=1)[:, 0]
-                vocab = jax.lax.broadcasted_iota(jnp.int32, lm.shape, 1)
-                residual = jnp.where(
-                    rejected[:, None] & (vocab == d_m[:, None]),
-                    -jnp.inf, lm)
-                keys2 = jax.vmap(
-                    lambda kk: jax.random.fold_in(kk, kp1))(keys)
-                sampled = jax.vmap(jax.random.categorical)(keys2, residual)
-                nxt = jnp.where(is_greedy, la, sampled)
+                logits, cache = self._paged_forward(
+                    self._dq(params), tokens, cache, tables, lens, valid)
+                m, nxt = accept_drafts(rng, logits, drafts, nvalid, uids, temp,
+                                       topk, topp, greedy)
                 return m, nxt.astype(jnp.int32), cache
 
             self._paged_fns[key] = self._jit(key, verify, donate_argnums=(1,))
@@ -957,10 +827,6 @@ class InferenceEngineV2(InferenceEngine):
             return None
         kmax = self._spec_k
         self.spec_stats["verify_steps"] += 1
-        if self._spec_fused:
-            # verification rode the paged-decode kernel family, not a
-            # prefill-shaped dense-gather dispatch
-            self.spec_stats["fused_verify_steps"] += 1
         self.spec_stats["step_seqs"] += len(live)
         out: Dict[int, List[int]] = {}
         st = self.spec_stats
@@ -968,14 +834,7 @@ class InferenceEngineV2(InferenceEngine):
                 "spec_verify", cat="serving", batch=len(live),
                 drafted=sum(len(v) for v in drafts.values())) as span:
             with self.tracer.span("engine_prep", cat="serving"):
-                cow = []
-                for d in live:
-                    dl = len(drafts[d.uid])
-                    cow += self.state.ensure_writable(
-                        d, d.seen_tokens + dl + 1)
-                    self.state.extend(d, n=dl + 1)
-                    self._slot_tables[d.slot] = self.state.block_table(d)
-                self._copy_blocks(cow)
+                self._reserve(live, (len(drafts[d.uid]) + 1 for d in live))
                 B = self._slot_tokens.shape[0]
                 tok_w = np.zeros((B, kmax + 1), np.int32)
                 tok_w[:, 0] = self._slot_tokens
@@ -988,15 +847,11 @@ class InferenceEngineV2(InferenceEngine):
                     tok_w[d.slot, 1:len(dr) + 1] = dr
                     nvalid[d.slot] = 1 + len(dr)
                     uids_arr[d.slot] = d.uid
-            with self.tracer.span("engine_dispatch", cat="serving"):
-                m, nxt, self.cache = self._verify_fn(kmax + 1)(
-                    self.params, self.cache, jnp.asarray(tok_w),
-                    jnp.asarray(self._slot_lens),
-                    jnp.asarray(self._slot_tables),
-                    jnp.asarray(self._slot_active), jnp.asarray(nvalid),
-                    jnp.asarray(dr_arr), jax.random.PRNGKey(seed),
-                    jnp.asarray(uids_arr),
-                    *map(jnp.asarray, sp_arrays(self._slot_sp)))
+                fn = self._verify_fn(kmax + 1)
+                sp_rows = sp_arrays(self._slot_sp)
+            m, nxt, self.cache = self._dispatch(
+                fn, self._slots(tok_w) + (nvalid, dr_arr), seed,
+                (uids_arr,) + sp_rows)
             with self.tracer.span("engine_wait", cat="serving"):
                 m, nxt = np.asarray(m), np.asarray(nxt)
             t1 = time.monotonic_ns() if self._trace_on else 0
@@ -1017,21 +872,16 @@ class InferenceEngineV2(InferenceEngine):
                             d, d.seen_tokens - (dl - mi))
                         self._copy_blocks(pairs)
                         self._slot_tables[d.slot] = self.state.block_table(d)
-                    emitted = dr[:mi] + [tok]
-                    d.last_token = tok
-                    d.generated.extend(emitted)
-                    self._slot_tokens[d.slot] = tok
-                    self._slot_lens[d.slot] = d.seen_tokens
-                    self.state.mark_filled(d)
-                    out[d.uid] = emitted
+                    # what was written is recorded above, ahead of the
+                    # rollback: the commit indexes only blocks that stand
+                    out[d.uid] = emitted = dr[:mi] + [tok]
+                    self._commit(d, (), emitted, t1)
                     st["drafted_tokens"] += dl
                     st["accepted_tokens"] += mi
                     st["emitted_tokens"] += mi + 1
                     st["rolled_back_tokens"] += dl - mi
                     st["verify_positions"] += dl + 1
                     st["verify_capacity"] += kmax + 1
-                    if self._trace_on:
-                        self._req_tokens(d.uid, mi + 1, t1)
             self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
             span.set(kv_tokens=kv,
                      accepted=sum(len(v) - 1 for v in out.values()))
@@ -1071,8 +921,8 @@ class InferenceEngineV2(InferenceEngine):
         return self._prefill_admitted(entries, [sp] * len(entries), seed,
                                       cached=cached)
 
-    def _prefill_admitted(self, entries, sps, seed: int = 0,
-                          cached=None) -> Dict[int, int]:
+    def _prefill_admitted(self, entries, sps, seed: int,
+                          cached) -> Dict[int, int]:
         """Batched prefill over already-admitted ``(uid, prompt, desc)``
         entries (callers admit first so capacity accounting stays exact),
         with per-ENTRY sampling params ``sps``. The batch pads to a
@@ -1087,8 +937,6 @@ class InferenceEngineV2(InferenceEngine):
         the cache off) takes the original zero-offset programs unchanged."""
         if not entries:
             return {}
-        if cached is None:
-            cached = [0] * len(entries)
         sps = [self._canon_sp(s_) for s_ in sps]
         n = len(entries)
         n_pad = 1 << (n - 1).bit_length()
@@ -1117,28 +965,19 @@ class InferenceEngineV2(InferenceEngine):
                     uids_arr[i] = uid
                     tables[i] = self.state.block_table(desc)
                 with_ctx = any(cached)
+                rows = not all(s_ == _GREEDY for s_ in sps)
+                fn = self._prefill_fn(pad_t, n_pad, with_ctx, rows)
+                # dummy rows sample greedily
+                sp_rows = sp_arrays(sps + [_GREEDY] * (n_pad - n)) \
+                    if rows else ()
             if self._trace_on:
                 for uid, prompt, _ in entries:
                     self._req_admit(uid, len(prompt))  # generate() admits direct
                     self._req_compute_begin(uid)
                 t0 = time.monotonic_ns()
-            with self.tracer.span("engine_dispatch", cat="serving"):
-                base = (self.params, self.cache, jnp.asarray(padded),
-                        jnp.asarray(lengths), jnp.asarray(tables))
-                if with_ctx:
-                    base += (jnp.asarray(ctx),)
-                base += (jax.random.PRNGKey(seed), jnp.asarray(uids_arr))
-                greedy_sp = SamplingParams(greedy=True)
-                if all(s_ == greedy_sp for s_ in sps):
-                    fn = (self._prefill_ctx_fn if with_ctx
-                          else self._prefill_fn)(pad_t, greedy_sp, n_pad)
-                    toks, self.cache = fn(*base)
-                else:
-                    pad_sps = sps + [greedy_sp] * (n_pad - n)  # dummies: greedy
-                    fn = (self._prefill_ctx_dyn_fn(pad_t, n_pad) if with_ctx
-                          else self._prefill_dyn_fn(pad_t, n_pad))
-                    toks, self.cache = fn(*base, *map(jnp.asarray,
-                                                      sp_arrays(pad_sps)))
+            toks, self.cache = self._dispatch(
+                fn, (padded, lengths, tables) + ((ctx,) if with_ctx else ()),
+                seed, (uids_arr,) + sp_rows)
             with self.tracer.span("engine_wait", cat="serving"):
                 toks = np.asarray(toks)
             t1 = time.monotonic_ns() if self._trace_on else 0
@@ -1146,18 +985,12 @@ class InferenceEngineV2(InferenceEngine):
             self._admitted_kv_tokens += sum(kv_rows)
             with self.tracer.span("engine_emit", cat="serving"):
                 for i, (uid, prompt, desc) in enumerate(entries):
-                    tok = int(toks[i])
+                    out[uid] = tok = int(toks[i])
                     desc.seen_tokens = len(prompt)
                     self.state.mark_filled(desc)  # full blocks → matchable
                     desc.last_token = tok
                     desc.generated.append(tok)
-                    s = desc.slot
-                    self._slot_tokens[s] = tok
-                    self._slot_lens[s] = desc.seen_tokens
-                    self._slot_tables[s] = tables[i]
-                    self._slot_active[s] = True
-                    self._slot_sp[s] = sps[i]
-                    out[uid] = tok
+                    self._seat(desc, tables[i], sps[i])
                     if self._trace_on:
                         rec = self._req.get(uid)
                         if rec is not None:
@@ -1170,6 +1003,101 @@ class InferenceEngineV2(InferenceEngine):
                                 tokens=int(lengths[i]), cached=int(ctx[i]))
                         self._req_first_token(uid, t1)
         return out
+
+    # ------------------------------------------------------------------ #
+    # what step(), step_many() and _spec_step() share around their program
+    # ------------------------------------------------------------------ #
+    def _prefill_then_live(self, seed: int):
+        """How a step begins: ``last_step`` starts over - the one-shot
+        prefills that ran since the previous step (``put``/``put_many``, a
+        scheduler tick's admissions) count with this step's
+        ``prefill_kv_tokens`` -, the oldest split prefill advances one chunk,
+        and the sequences to decode are listed. Returns ({uid: first token}
+        of a prompt this completed, live)."""
+        self.last_step = dict(_NO_WORK,
+                              prefill_kv_tokens=self._admitted_kv_tokens)
+        self._admitted_kv_tokens = 0
+        first = self._advance_prefill(seed)
+        live = [d for d in self.state.seqs.values()
+                if not d.finished and not d.prefilling
+                and d.uid not in first]  # completed-this-step: first token only
+        if not live:
+            # no decodes in flight: the one-chunk-per-step bound exists to
+            # protect live decodes from prefill stalls — with none to
+            # protect, advance the oldest split prefill chunk after chunk
+            # until it completes (it holds KV blocks the whole time), then
+            # stop: the completed sequence is a live decode to protect again
+            while self._pending_prefill and not first:
+                first.update(self._advance_prefill(seed))
+        return first, live
+
+    def _reserve(self, live, counts) -> None:
+        """Room for the tokens a decode-shaped call is about to write:
+        ``counts`` gives, for each sequence of ``live``, how many (1 a step,
+        k a quantum - all k up front, so the scan never needs the host
+        mid-flight -, draft_len + 1 a verify window). Copy-on-write BEFORE
+        extend: only pre-existing blocks can be shared; the blocks extend
+        allocates are fresh (refcount 1). The copies are stamped here, before
+        the program that writes into them launches."""
+        cow = []
+        for d, n in zip(live, counts):
+            cow += self.state.ensure_writable(d, d.seen_tokens + n)
+            self.state.extend(d, n)
+            self._slot_tables[d.slot] = self.state.block_table(d)
+        self._copy_blocks(cow)
+
+    def _slots(self, tokens=None) -> Tuple:
+        """(tokens, lens, tables, active) over every slot: what a
+        decode-shaped program takes after the cache. ``tokens`` stands in
+        for the slots' last tokens (a verify window)."""
+        return (self._slot_tokens if tokens is None else tokens,
+                self._slot_lens, self._slot_tables, self._slot_active)
+
+    def _commit(self, d, written, emitted, t_ns: int) -> int:
+        """A decode-shaped call's tokens land on sequence ``d``: ``written``
+        are the ids whose KV the call wrote after the context (recorded so
+        the blocks they fill can be chain-hashed), ``emitted`` the tokens it
+        produced, the last of them pending its write by the next call.
+        Returns the sequence's KV length."""
+        d.tokens.extend(written)
+        d.seen_tokens += len(written)
+        d.last_token = emitted[-1]
+        d.generated.extend(emitted)
+        self._slot_tokens[d.slot] = d.last_token
+        self._slot_lens[d.slot] = d.seen_tokens
+        self.state.mark_filled(d)
+        if self._trace_on:
+            self._req_tokens(d.uid, len(emitted), t_ns)
+        return d.seen_tokens
+
+    def _decode_ticks(self, k: int, live, seed: int, span, out,
+                      tiles: bool = False) -> None:
+        """``k`` decode ticks over ``live`` in one program, inside the
+        caller's ``span``: reserve, dispatch, the ONE host sync, and each
+        sequence's k tokens into ``out[uid]``. ``tiles``: the span and
+        ``last_step`` also say what the first tick's attention grid walks."""
+        with self.tracer.span("engine_prep", cat="serving"):
+            self._reserve(live, repeat(k))
+            extra = self._attn_tile_args() if tiles else {}
+            # slots hold canonical params (``_canon_sp``): see ``_sampler``
+            rows = any(self._slot_sp[d.slot] != _GREEDY for d in live)
+            fn = self._decode_fn(k, rows)
+            sp_rows = sp_arrays(self._slot_sp) if rows else ()
+        # (toks [k, B], lens, cache); the single step's (toks [B], cache)
+        toks, *_, self.cache = self._dispatch(fn, self._slots(), seed,
+                                              sp_rows)
+        with self.tracer.span("engine_wait", cat="serving"):
+            toks = np.asarray(toks).reshape(k, -1)
+        t1 = time.monotonic_ns() if self._trace_on else 0
+        with self.tracer.span("engine_emit", cat="serving"):
+            kv = 0
+            for d in live:
+                out[d.uid] = seq = toks[:, d.slot].tolist()
+                # KV writes of the call: the previous last_token, then each
+                # sampled token except the newest (still pending its write)
+                kv += self._commit(d, [d.last_token] + seq[:-1], seq, t1)
+        self.last_step.update(decode_seqs=len(live), kv_tokens=kv, **extra)
+        span.set(kv_tokens=kv, **extra)
 
     def step(self, sp: SamplingParams = SamplingParams(greedy=True),
              seed: int = 0) -> Dict[int, int]:
@@ -1186,82 +1114,26 @@ class InferenceEngineV2(InferenceEngine):
         the return type widens to {uid: [tokens]} — every value is a list,
         including prefill first-tokens and draft-less fallback steps."""
         self._warn_ignored_sp(sp)
-        self._begin_step()
-        out = self._advance_prefill(seed)
-        live = [d for d in self.state.seqs.values()
-                if not d.finished and not d.prefilling
-                and d.uid not in out]  # completed-this-step: first token only
-        if not live:
-            # no decodes in flight: the one-chunk-per-step bound exists to
-            # protect live decodes from prefill stalls — with none to
-            # protect, advance the oldest split prefill chunk after chunk
-            # until it completes (it holds KV blocks the whole time), then
-            # stop: the completed sequence is a live decode to protect again
-            while self._pending_prefill and not out:
-                out.update(self._advance_prefill(seed))
-            return ({u: [t] for u, t in out.items()} if self._spec_on
-                    else out)
-        if self._spec_on:
-            spec_out = self._spec_step(live, seed)
-            if spec_out is not None:
-                for u, t in out.items():
-                    spec_out[u] = [t]
-                return spec_out
-            # no sequence drafted this step: run the plain decode program
-            # below — bit-identical to a non-spec step, and cheaper than a
-            # k+1-wide verify batch with one valid column
-            self.spec_stats["decode_steps"] += 1
-            self.spec_stats["step_seqs"] += len(live)
-            self.spec_stats["emitted_tokens"] += len(live)
-        with self.tracer.span("decode_step", cat="serving", batch=len(live),
-                              **self._moe_args(len(self._slot_tokens))
-                              ) as span:
-            with self.tracer.span("engine_prep", cat="serving"):
-                cow = []
-                for d in live:
-                    # copy-on-write BEFORE extend: only pre-existing blocks
-                    # can be shared; the blocks extend allocates are fresh
-                    # (refcount 1)
-                    cow += self.state.ensure_writable(d, d.seen_tokens + 1)
-                    self.state.extend(d)
-                    self._slot_tables[d.slot] = self.state.block_table(d)
-                self._copy_blocks(cow)
-                tiles = self._attn_tile_args()
-            with self.tracer.span("engine_dispatch", cat="serving"):
-                base = (self.params, self.cache,
-                        jnp.asarray(self._slot_tokens),
-                        jnp.asarray(self._slot_lens),
-                        jnp.asarray(self._slot_tables),
-                        jnp.asarray(self._slot_active),
-                        jax.random.PRNGKey(seed))
-                if self._needs_dynamic_sp(live):
-                    nxt, self.cache = self._decode_dyn_fn()(
-                        *base, *map(jnp.asarray, sp_arrays(self._slot_sp)))
-                else:
-                    nxt, self.cache = self._decode_fn(
-                        SamplingParams(greedy=True))(*base)
-            with self.tracer.span("engine_wait", cat="serving"):
-                nxt = np.asarray(nxt)
-            t1 = time.monotonic_ns() if self._trace_on else 0
-            with self.tracer.span("engine_emit", cat="serving"):
-                kv = 0
-                for d in live:
-                    tok = int(nxt[d.slot])
-                    d.tokens.append(d.last_token)  # the id whose KV was written
-                    d.seen_tokens += 1
-                    kv += d.seen_tokens
-                    d.last_token = tok
-                    d.generated.append(tok)
-                    self._slot_tokens[d.slot] = tok
-                    self._slot_lens[d.slot] = d.seen_tokens
-                    self.state.mark_filled(d)
-                    out[d.uid] = tok
-                    if self._trace_on:
-                        self._req_tokens(d.uid, 1, t1)
-            self.last_step.update(decode_seqs=len(live), kv_tokens=kv,
-                                  **tiles)
-            span.set(kv_tokens=kv, **tiles)
-        return {u: [t] for u, t in out.items()} if self._spec_on else out
+        first, live = self._prefill_then_live(seed)
+        out: Dict[int, List[int]] = {u: [t] for u, t in first.items()}
+        spec_out = self._spec_step(live, seed) if live and self._spec_on \
+            else None
+        if spec_out is not None:
+            out.update(spec_out)
+        elif live:
+            if self._spec_on:
+                # no sequence drafted this step: run the plain decode
+                # program — bit-identical to a non-spec step, and cheaper
+                # than a k+1-wide verify batch with one valid column
+                self.spec_stats["decode_steps"] += 1
+                self.spec_stats["step_seqs"] += len(live)
+                self.spec_stats["emitted_tokens"] += len(live)
+            with self.tracer.span("decode_step", cat="serving",
+                                  batch=len(live),
+                                  **self._moe_args(len(self._slot_tokens))
+                                  ) as span:
+                self._decode_ticks(1, live, seed, span, out, tiles=True)
+        return out if self._spec_on else {u: s[0] for u, s in out.items()}
 
     def step_many(self, k: int, sp: SamplingParams = SamplingParams(greedy=True),
                   seed: int = 0) -> Dict[int, List[int]]:
@@ -1278,71 +1150,18 @@ class InferenceEngineV2(InferenceEngine):
         number of tokens per call. ``generate`` picks ``step()`` when
         ``inference.speculative.enabled`` is set."""
         self._warn_ignored_sp(sp)
-        self._begin_step()
-        first = self._advance_prefill(seed)
-        live = [d for d in self.state.seqs.values()
-                if not d.finished and not d.prefilling
-                and d.uid not in first]
-        if not live:
-            # same no-decodes fast path as step(): drain the oldest split
-            # prefill to completion instead of one chunk per quantum call
-            while self._pending_prefill and not first:
-                first.update(self._advance_prefill(seed))
+        first, live = self._prefill_then_live(seed)
         out: Dict[int, List[int]] = {u: [t] for u, t in first.items()}
-        if not live or k <= 0:
-            return out
-        max_seen = max(d.seen_tokens for d in live)
-        # a tick at seen writes KV position seen, so seen may reach exactly
-        # max_seq_len after the last tick — same boundary as the per-step
-        # path (which decodes while seen == max_seq_len - 1)
-        k = min(k, self.family.cfg.max_seq_len - max_seen)
-        if k <= 0:
-            return out
-        with self.tracer.span("decode_quantum", cat="serving", k=k,
-                              batch=len(live)) as span:
-            with self.tracer.span("engine_prep", cat="serving"):
-                cow = []
-                for d in live:
-                    cow += self.state.ensure_writable(d, d.seen_tokens + k)
-                    self.state.extend(d, n=k)  # reserve ALL k tokens up front
-                    self._slot_tables[d.slot] = self.state.block_table(d)
-                self._copy_blocks(cow)
-            with self.tracer.span("engine_dispatch", cat="serving"):
-                base = (self.params, self.cache,
-                        jnp.asarray(self._slot_tokens),
-                        jnp.asarray(self._slot_lens),
-                        jnp.asarray(self._slot_tables),
-                        jnp.asarray(self._slot_active),
-                        jax.random.PRNGKey(seed))
-                if self._needs_dynamic_sp(live):
-                    toks, lens, self.cache = self._decode_many_dyn_fn(k)(
-                        *base, *map(jnp.asarray, sp_arrays(self._slot_sp)))
-                else:
-                    toks, lens, self.cache = self._decode_many_fn(
-                        k, SamplingParams(greedy=True))(*base)
-            with self.tracer.span("engine_wait", cat="serving"):
-                toks = np.asarray(toks)      # [k, B] — the ONLY host sync
-            t1 = time.monotonic_ns() if self._trace_on else 0
-            with self.tracer.span("engine_emit", cat="serving"):
-                kv = 0
-                for d in live:
-                    seq = [int(t) for t in toks[:, d.slot]]
-                    # KV writes this quantum: the previous last_token, then
-                    # each sampled token except the newest (still pending
-                    # its write)
-                    d.tokens.extend([d.last_token] + seq[:-1])
-                    d.seen_tokens += k
-                    kv += d.seen_tokens
-                    d.last_token = seq[-1]
-                    d.generated.extend(seq)
-                    self._slot_tokens[d.slot] = seq[-1]
-                    self._slot_lens[d.slot] = d.seen_tokens
-                    self.state.mark_filled(d)
-                    out[d.uid] = seq
-                    if self._trace_on:
-                        self._req_tokens(d.uid, k, t1)
-            self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
-            span.set(kv_tokens=kv)
+        if live:
+            # a tick at seen writes KV position seen, so seen may reach
+            # exactly max_seq_len after the last tick — same boundary as the
+            # per-step path (which decodes while seen == max_seq_len - 1)
+            k = min(k, self.family.cfg.max_seq_len
+                    - max(d.seen_tokens for d in live))
+        if live and k > 0:
+            with self.tracer.span("decode_quantum", cat="serving", k=k,
+                                  batch=len(live)) as span:
+                self._decode_ticks(k, live, seed, span, out)
         return out
 
     def finish(self, uid: int) -> List[int]:
@@ -1362,7 +1181,7 @@ class InferenceEngineV2(InferenceEngine):
         self._slot_active[s] = False
         self._slot_lens[s] = 0
         self._slot_tables[s] = 0
-        self._slot_sp[s] = SamplingParams(greedy=True)
+        self._slot_sp[s] = _GREEDY
 
     # ------------------------------------------------------------------ #
     # scheduler seams: KV headroom + decode preemption (park/resume) —
@@ -1463,13 +1282,9 @@ class InferenceEngineV2(InferenceEngine):
         given, the parent's sampling params."""
         desc = self.state.fork(uid, new_uid)
         self._req_admit(new_uid, desc.seen_tokens)
-        s, parent_slot = desc.slot, self.state.seqs[uid].slot
-        self._slot_tokens[s] = desc.last_token
-        self._slot_lens[s] = desc.seen_tokens
-        self._slot_tables[s] = self.state.block_table(desc)
-        self._slot_active[s] = True
-        self._slot_sp[s] = (self._canon_sp(sp) if sp is not None
-                            else self._slot_sp[parent_slot])
+        self._seat(desc, self.state.block_table(desc),
+                   self._canon_sp(sp) if sp is not None
+                   else self._slot_sp[self.state.seqs[uid].slot])
         return desc
 
     # ------------------------------------------------------------------ #
@@ -1631,21 +1446,26 @@ class InferenceEngineV2(InferenceEngine):
         return [(f"Serving/prefix_cache/{k}", float(v), step)
                 for k, v in sorted(stats.items())]
 
-    def publish_prefix_telemetry(self, step: int = 0):
-        events = self.prefix_cache_events(step)
+    def _publish(self, events, sink: str = "serving_event"):
+        """Hand ``events`` to the attached hub's ``sink``, if there is one."""
         if self._hub is not None:
+            send = getattr(self._hub, sink)
             for name, value, s in events:
-                self._hub.serving_event(name, value, s)
-            if self._kv_spill is not None:
-                # the host pool is a memory TIER — its occupancy also lands
-                # in the closed Memory/tier/* family beside the training
-                # store's gauges (telemetry_report.py --memory)
-                pool = self._kv_spill
-                for k, v in (("kv_spilled_blocks", pool.spilled_blocks),
-                             ("kv_spilled_bytes", pool.spilled_bytes),
-                             ("kv_spills", pool.stats["spills"]),
-                             ("kv_restores", pool.stats["restores"])):
-                    self._hub.memory_tier_event(k, float(v), step)
+                send(name, value, s)
+        return events
+
+    def publish_prefix_telemetry(self, step: int = 0):
+        events = self._publish(self.prefix_cache_events(step))
+        if self._hub is not None and self._kv_spill is not None:
+            # the host pool is a memory TIER — its occupancy also lands in
+            # the closed Memory/tier/* family beside the training store's
+            # gauges (telemetry_report.py --memory)
+            pool = self._kv_spill
+            for k, v in (("kv_spilled_blocks", pool.spilled_blocks),
+                         ("kv_spilled_bytes", pool.spilled_bytes),
+                         ("kv_spills", pool.stats["spills"]),
+                         ("kv_restores", pool.stats["restores"])):
+                self._hub.memory_tier_event(k, float(v), step)
         return events
 
     # ------------------------------------------------------------------ #
@@ -1667,8 +1487,6 @@ class InferenceEngineV2(InferenceEngine):
           (the QUANT_TPU_LIVE-losing path)."""
         if not self._kvq_on:
             return []
-        import jax.numpy as jnp_
-
         resident = (self.state.allocator.num_blocks - 1
                     - self.state.allocator.free_blocks)
         code_elems = scale_elems = 0
@@ -1678,7 +1496,7 @@ class InferenceEngineV2(InferenceEngine):
             code_elems += c.size // c.shape[1]          # per-block elements
             s = self.cache[name + "_scale"]
             scale_elems += s.size // s.shape[1]
-            max_scale = max(max_scale, float(jnp_.max(s)))
+            max_scale = max(max_scale, float(jnp.max(s)))
         saved_per_block = 2 * code_elems - (code_elems + 4 * scale_elems)
         vals = {"blocks_quantized": float(resident),
                 "bytes_saved": float(saved_per_block * resident),
@@ -1688,11 +1506,7 @@ class InferenceEngineV2(InferenceEngine):
                 for k, v in sorted(vals.items())]
 
     def publish_kv_quant_telemetry(self, step: int = 0):
-        events = self.kv_quant_events(step)
-        if self._hub is not None:
-            for name, value, s in events:
-                self._hub.serving_event(name, value, s)
-        return events
+        return self._publish(self.kv_quant_events(step))
 
     def debug_check_cache(self) -> None:
         """Cache-pytree invariants beside ``StateManager.debug_check`` —
@@ -1706,20 +1520,18 @@ class InferenceEngineV2(InferenceEngine):
             assert keys == {"k", "v"}, \
                 f"unquantized cache has unexpected leaves {keys}"
             return
-        import jax.numpy as jnp_
-
         assert keys == {"k", "v", "k_scale", "v_scale"}, \
             f"quantized cache has unexpected leaves {keys}"
         hd = self.family.cfg.head_size
         ng = hd // self._kvq_group
         for name in ("k", "v"):
             c, s = self.cache[name], self.cache[name + "_scale"]
-            assert c.dtype == jnp_.int8, f"{name} codes are {c.dtype}"
-            assert s.dtype == jnp_.float32, f"{name} scales are {s.dtype}"
+            assert c.dtype == jnp.int8, f"{name} codes are {c.dtype}"
+            assert s.dtype == jnp.float32, f"{name} scales are {s.dtype}"
             assert s.shape == c.shape[:-1] + (ng,), \
                 f"{name}_scale shape {s.shape} inconsistent with codes " \
                 f"{c.shape} at group_size {self._kvq_group}"
-            smin, smax = float(jnp_.min(s)), float(jnp_.max(s))
+            smin, smax = float(jnp.min(s)), float(jnp.max(s))
             assert np.isfinite(smax) and smin >= 0.0, \
                 f"{name}_scale range [{smin}, {smax}] invalid"
 
@@ -1748,11 +1560,7 @@ class InferenceEngineV2(InferenceEngine):
                 for k, v in sorted(vals.items())]
 
     def publish_spec_telemetry(self, step: int = 0):
-        events = self.spec_events(step)
-        if self._hub is not None:
-            for name, value, s in events:
-                self._hub.serving_event(name, value, s)
-        return events
+        return self._publish(self.spec_events(step))
 
     # ------------------------------------------------------------------ #
     # latency SLOs: TTFT / inter-token latency / queue time / e2e, with
@@ -1779,11 +1587,7 @@ class InferenceEngineV2(InferenceEngine):
         return events
 
     def publish_latency_telemetry(self, step: int = 0):
-        events = self.latency_events(step)
-        if self._hub is not None:
-            for name, value, s in events:
-                self._hub.serving_event(name, value, s)
-        return events
+        return self._publish(self.latency_events(step))
 
     def compile_events(self, step: int = 0):
         """Drain the compile monitor: cumulative ``Compile/*`` counters per
@@ -1797,11 +1601,7 @@ class InferenceEngineV2(InferenceEngine):
         return self.compile_monitor.events(step, group="Serving")
 
     def publish_compile_telemetry(self, step: int = 0):
-        events = self.compile_events(step)
-        if self._hub is not None:
-            for name, value, s in events:
-                self._hub.compile_event(name, value, s)
-        return events
+        return self._publish(self.compile_events(step), "compile_event")
 
     def export_trace(self, path: str):
         """Dump the flight recorder as Chrome-trace/Perfetto JSON."""

@@ -6,8 +6,9 @@ so it fuses into the decode step.
 
 ``filter_logits`` / ``filter_logits_batch`` expose the temperature/top-k/top-p
 filtering WITHOUT the final draw — the speculative-decoding verifier
-(``engine_v2``) needs the filtered distribution itself to accept/reject draft
-tokens by exact rejection sampling.
+(:func:`accept_drafts`, the sampler of ``engine_v2``'s verify program) needs
+the filtered distribution itself to accept/reject draft tokens by exact
+rejection sampling.
 """
 
 from __future__ import annotations
@@ -111,6 +112,58 @@ def sample_batch(rng: jax.Array, logits: jnp.ndarray,
     sampled = jax.random.categorical(rng, filt, axis=-1)
     pick_greedy = jnp.logical_or(greedy, temperature <= 0.0)
     return jnp.where(pick_greedy, argmax, sampled)
+
+
+def accept_drafts(rng: jax.Array, logits: jnp.ndarray, drafts: jnp.ndarray,
+                  nvalid: jnp.ndarray, uids: jnp.ndarray, temp: jnp.ndarray,
+                  topk: jnp.ndarray, topp: jnp.ndarray, greedy: jnp.ndarray):
+    """Speculative verification's sampler, all traced: ``logits``
+    [B, k+1, V] score ``drafts`` [B, k] (zero-padded past each row's
+    ``nvalid - 1`` real drafts) plus the bonus position; per-row keys fold
+    ``uids`` into ``rng``; sampling params per row as in
+    :func:`sample_batch`.
+
+    Greedy rows accept draft j while it equals the argmax of the logits
+    that precede it; stochastic rows accept with probability
+    ``p(draft_j)`` under their own temperature/top-k/top-p-filtered
+    distribution — exact rejection sampling for a DETERMINISTIC drafter
+    (q = δ), so on rejection the correction is drawn from p with the
+    rejected token removed and renormalized, and the emitted stream is
+    distributed exactly as plain decode. When every draft is accepted the
+    bonus position (scored in the same pass) supplies one extra token.
+    Returns (accepted_len [B], next_token [B])."""
+    B, kp1 = logits.shape[:2]
+    k = kp1 - 1
+    amax = jnp.argmax(logits, axis=-1)                         # [B, kp1]
+    filt = filter_logits_batch(
+        logits.reshape(B * kp1, -1),
+        jnp.repeat(temp, kp1), jnp.repeat(topk, kp1),
+        jnp.repeat(topp, kp1)).reshape(B, kp1, -1)
+    probs = jax.nn.softmax(filt, axis=-1)
+    draft_len = nvalid - 1
+    keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
+    accept_u = jax.vmap(lambda kk: jax.random.uniform(kk, (k,)))(keys)
+    p_draft = jnp.take_along_axis(
+        probs[:, :k, :], drafts[..., None], axis=-1)[..., 0]
+    is_greedy = jnp.logical_or(greedy, temp <= 0.0)
+    ok = jnp.where(is_greedy[:, None], drafts == amax[:, :k],
+                   accept_u < p_draft)
+    ok = ok & (jnp.arange(k)[None, :] < draft_len[:, None])
+    # longest agreeing prefix: cumprod zeroes everything after the first
+    # rejection
+    m = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)   # [B]
+    lm = jnp.take_along_axis(filt, m[:, None, None], axis=1)[:, 0]   # [B, V]
+    la = jnp.take_along_axis(amax, m[:, None], axis=1)[:, 0]
+    rejected = m < draft_len
+    d_m = jnp.take_along_axis(
+        drafts, jnp.minimum(m, k - 1)[:, None], axis=1)[:, 0]
+    vocab = jax.lax.broadcasted_iota(jnp.int32, lm.shape, 1)
+    residual = jnp.where(rejected[:, None] & (vocab == d_m[:, None]),
+                         -jnp.inf, lm)
+    keys2 = jax.vmap(lambda kk: jax.random.fold_in(kk, kp1))(keys)
+    sampled = jax.vmap(jax.random.categorical)(keys2, residual)
+    nxt = jnp.where(is_greedy, la, sampled)
+    return m, nxt
 
 
 def sp_arrays(sps) -> tuple:

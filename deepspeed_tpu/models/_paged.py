@@ -12,7 +12,7 @@ Quantized KV mode (``inference.kv_quant``, docs/serving.md "Quantized KV
 cache"): the cache dict additionally carries ``k_scale``/``v_scale`` pools
 ``[num_blocks, kv_heads, block_size, ngroups]`` fp32, K/V pools hold int8
 codes, and :func:`paged_attention_step` receives each pool as a
-``(codes, scales)`` tuple (:func:`split_kv`). Fill-time quantization is
+``(codes, scales)`` tuple (:func:`scan_layers`). Fill-time quantization is
 fused into the cache-update scatter (per-token groupwise scales — a token's
 write never touches another position's scale), and dequant is fused into
 the attention reads: in-register inside both Pallas kernels (``paged_decode``
@@ -28,39 +28,13 @@ a TPU alone.
 
 from __future__ import annotations
 
-from contextlib import contextmanager
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..ops.quantization import kv_quantize_int8
-
-# --------------------------------------------------------------------------- #
-# fused speculative verification (inference.speculative.fused_verify;
-# docs/serving.md "Fused verification"). The engine's fused verify program
-# wraps its apply_paged call in :func:`fused_verify_scope`. Since every
-# multi-token attention walks the block table in the ``paged_prefill`` kernel
-# the scope selects nothing in :func:`paged_attention_step` any more: it
-# remains what names that program family (ROADMAP simplicity queue: remove
-# it with the config key).
-# --------------------------------------------------------------------------- #
-_FUSED_VERIFY = {"on": False}
-
-
-def fused_verify_active() -> bool:
-    return _FUSED_VERIFY["on"]
-
-
-@contextmanager
-def fused_verify_scope():
-    """Mark one trace as the fused verify program's (trace time only)."""
-    prev = _FUSED_VERIFY["on"]
-    _FUSED_VERIFY["on"] = True
-    try:
-        yield
-    finally:
-        _FUSED_VERIFY["on"] = prev
 
 
 def init_paged_pools(num_layers: int, num_blocks: int, num_kv_heads: int,
@@ -90,25 +64,44 @@ def init_paged_pools(num_layers: int, num_blocks: int, num_kv_heads: int,
             "v_scale": jnp.zeros(sshape, jnp.float32)}
 
 
-def split_kv(cache):
-    """The per-family adapter from the cache dict to
-    :func:`paged_attention_step`'s K/V entries: plain pools stay arrays;
-    quantized pools (``k_scale`` present) become ``(codes, scales)`` tuples
-    so ``lax.scan`` threads codes AND scales per layer with no per-family
-    plumbing. Returns ``(k_entry, v_entry)``."""
+def _split_kv(cache):
+    """From the cache dict to :func:`paged_attention_step`'s K/V entries:
+    plain pools stay arrays; quantized pools (``k_scale`` present) become
+    ``(codes, scales)`` tuples so ``lax.scan`` threads codes AND scales per
+    layer with no per-family plumbing. Returns ``(k_entry, v_entry)``."""
     if "k_scale" in cache:
         return ((cache["k"], cache["k_scale"]),
                 (cache["v"], cache["v_scale"]))
     return cache["k"], cache["v"]
 
 
-def join_kv(k_entry, v_entry):
-    """Inverse of :func:`split_kv`: rebuild the cache dict from the scan's
+def _join_kv(k_entry, v_entry):
+    """Inverse of :func:`_split_kv`: rebuild the cache dict from the scan's
     stacked per-layer outputs."""
     if isinstance(k_entry, tuple):
         return {"k": k_entry[0], "k_scale": k_entry[1],
                 "v": v_entry[0], "v_scale": v_entry[1]}
     return {"k": k_entry, "v": v_entry}
+
+
+def scan_layers(body, x, layers, cache, *extras):
+    """The pools' way through a program's layers, for every family: the
+    stacked ``layers`` are scanned with each layer's slice of the K/V pools
+    (and any per-layer ``extras`` - exaone4's windows and rope flags) as
+    scanned INPUTS, and the updated slices are stacked back on the way out.
+    ``body(x, (layer, k_entry, v_entry, *extras))`` returns
+    ``(x, (k_entry, v_entry))`` with the entries as
+    :func:`paged_attention_step` hands them back. Returns ``(x, cache)``.
+
+    What the scan itself adds around the blocks is pool traffic - each
+    layer's slice of the pools in, the updated slices stacked back - so it
+    carries the pool update's name; the blocks' own scopes lie inside it.
+    Whoever changes how the pools travel (a donated carry, ``[L, ...]``
+    pools the kernels index: ROADMAP Queue A2) changes it here."""
+    with jax.named_scope("kv_write"):
+        x, (new_k, new_v) = lax.scan(
+            body, x, (layers,) + _split_kv(cache) + extras)
+    return x, _join_kv(new_k, new_v)
 
 
 def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
@@ -117,7 +110,7 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
     """Scatter this step's K/V into the block pool, then attend over it.
 
     q [b, t, nh, hd]; k/v [b, t, nkv, hd]. ``k_cache``/``v_cache`` are
-    either plain pools or ``(codes, scales)`` tuples (:func:`split_kv` —
+    either plain pools or ``(codes, scales)`` tuples (:func:`scan_layers` —
     quantized KV mode). ``window``: optional per-layer sliding-window length
     (int or traced scalar — exaone4 scans per-layer windows). Single-token
     decode dispatches the paged flash-decode kernel, every multi-token call
@@ -156,37 +149,31 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
             k_cache = k_cache.at[blk_idx, :, off].set(k.astype(k_cache.dtype))
             v_cache = v_cache.at[blk_idx, :, off].set(v.astype(v_cache.dtype))
 
-    if t == 1:
-        from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
-        from ..ops.registry import get_op
+    from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
+    from ..ops.registry import get_op
 
-        if quant:
-            out = get_op("paged_decode_attention")(
-                q[:, 0], k_codes, v_codes, block_tables, context_lens,
-                window=window, k_scale=k_scales, v_scale=v_scales)[:, None]
-        else:
-            out = get_op("paged_decode_attention")(
-                q[:, 0], k_cache, v_cache, block_tables, context_lens,
-                window=window)[:, None]
+    # int8 pools reach the kernels as codes with their scale pools beside
+    # them, dequantized in-register
+    if quant:
+        pools = (k_codes, v_codes)
+        scales = {"k_scale": k_scales, "v_scale": v_scales}
+    else:
+        pools, scales = (k_cache, v_cache), {}
+    if t == 1:
+        out = get_op("paged_decode_attention")(
+            q[:, 0], *pools, block_tables, context_lens, window=window,
+            **scales)[:, None]
     else:
         # every multi-token call - a prefill chunk at a context offset, a
         # batched prefill, a prefix-cache suffix, a speculative verify
         # window - walks the block table over the live context in one flash
-        # kernel (dequant in-register in quant mode). Off a TPU the op is
-        # the gathered XLA reference, as for every op. ``valid`` is a prefix
-        # mask, so its sum is each sequence's count of real rows.
-        from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
-        from ..ops.registry import get_op
-
+        # kernel. Off a TPU the op is the gathered XLA reference, as for
+        # every op. ``valid`` is a prefix mask, so its sum is each
+        # sequence's count of real rows.
         n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
-        if quant:
-            out = get_op("paged_prefill_attention")(
-                q, k_codes, v_codes, block_tables, context_lens, n_valid,
-                window=window, k_scale=k_scales, v_scale=v_scales)
-        else:
-            out = get_op("paged_prefill_attention")(
-                q, k_cache, v_cache, block_tables, context_lens, n_valid,
-                window=window)
+        out = get_op("paged_prefill_attention")(
+            q, *pools, block_tables, context_lens, n_valid, window=window,
+            **scales)
     if quant:
         return out, (k_codes, k_scales), (v_codes, v_scales)
     return out, k_cache, v_cache

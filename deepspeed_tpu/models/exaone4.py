@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.attention import attention
-from ._paged import join_kv, paged_attention_step, split_kv
+from ._paged import paged_attention_step, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -382,6 +382,5 @@ def apply_paged(cfg: Exaone4Config, params: Params, tokens: jnp.ndarray,
         x = x + rms_norm(mlp, layer["post_mlp_norm"], cfg.rms_norm_eps)
         return x, (k_c, v_c)
 
-    x, (nk, nv) = lax.scan(
-        scan_body, x, (layers,) + split_kv(cache) + (windows, use_rope))
-    return _head(cfg, params, x, compute_dtype), join_kv(nk, nv)
+    x, cache = scan_layers(scan_body, x, layers, cache, windows, use_rope)
+    return _head(cfg, params, x, compute_dtype), cache

@@ -22,7 +22,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ._paged import join_kv, paged_attention_step, split_kv
+from ._paged import paged_attention_step, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import layer_norm
@@ -353,8 +353,8 @@ def apply_paged(cfg: GPTConfig, params: Params, tokens: jnp.ndarray,
         x, kv = _block(cfg, x, layer, attn_call=attn_call)
         return x, kv
 
-    x, (nk, nv) = lax.scan(scan_body, x, (layers,) + split_kv(cache))
-    return _head(cfg, params, x, compute_dtype), join_kv(nk, nv)
+    x, cache = scan_layers(scan_body, x, layers, cache)
+    return _head(cfg, params, x, compute_dtype), cache
 
 
 def loss_fn(cfg: GPTConfig, params: Params, batch: Dict[str, jnp.ndarray], *,

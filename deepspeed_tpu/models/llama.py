@@ -31,7 +31,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ._paged import join_kv, paged_attention_step, split_kv
+from ._paged import paged_attention_step, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -629,12 +629,7 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                                    context_lens, valid, cos, sin, positions)
         return x, (k_c, v_c)
 
-    # what the scan itself adds around the blocks is pool traffic - each
-    # layer's slice of the pools in, the updated slices stacked back - so it
-    # carries the pool update's name; the blocks' own scopes lie inside it
-    with jax.named_scope("kv_write"):
-        # quantized-KV mode threads (codes, scales) tuples per pool (split_kv)
-        x, (new_k, new_v) = lax.scan(scan_body, x, (layers,) + split_kv(cache))
+    x, cache = scan_layers(scan_body, x, layers, cache)
     with jax.named_scope("norm"):
         x = rms_norm(x, params["final_norm"].astype(compute_dtype),
                      cfg.rms_norm_eps)
@@ -643,7 +638,7 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         head = params["embed"].T
     with jax.named_scope("logits"):
         logits = (x @ head.astype(compute_dtype)).astype(jnp.float32)
-    return logits, join_kv(new_k, new_v)
+    return logits, cache
 
 
 def model_spec(cfg: LlamaConfig, compute_dtype=jnp.bfloat16):

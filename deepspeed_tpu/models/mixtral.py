@@ -22,7 +22,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..moe.layer import MoELayer, init_moe_ffn, moe_ffn_logical_axes
 from ..moe.sharded_moe import compute_capacity
 from ..ops.attention import attention
-from ._paged import join_kv, paged_attention_step, split_kv
+from ._paged import paged_attention_step, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -429,15 +429,11 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
         ffn_out, _aux = moe_layer(layer["moe"], y)
         return x + ffn_out, (k_c, v_c)
 
-    # what the scan itself adds around the blocks is pool traffic - each
-    # layer's slice of the pools in, the updated slices stacked back - so it
-    # carries the pool update's name; the blocks' own scopes lie inside it
-    with jax.named_scope("kv_write"):
-        x, (nk, nv) = lax.scan(scan_body, x, (layers,) + split_kv(cache))
+    x, cache = scan_layers(scan_body, x, layers, cache)
     with jax.named_scope("norm"):
         x = rms_norm(x, params["final_norm"].astype(compute_dtype),
                      cfg.rms_norm_eps)
     with jax.named_scope("logits"):
         logits = (x @ params["lm_head"].astype(compute_dtype)).astype(
             jnp.float32)
-    return logits, join_kv(nk, nv)
+    return logits, cache

@@ -87,11 +87,7 @@ SERVING_SERIES = frozenset(
         "verify_steps", "decode_steps", "step_seqs", "drafted_tokens",
         "accepted_tokens", "emitted_tokens", "rolled_back_tokens",
         "verify_positions", "verify_capacity", "accept_rate",
-        "mean_accepted_len", "tokens_per_step", "verify_batch_occupancy",
-        # verify steps that rode the paged-decode kernel family instead of
-        # a prefill-shaped dispatch (inference.speculative.fused_verify;
-        # docs/serving.md "Fused verification")
-        "fused_verify_steps")]
+        "mean_accepted_len", "tokens_per_step", "verify_batch_occupancy")]
     # continuous-batching scheduler (serving/scheduler.py sched_events)
     + ["Serving/sched/" + m for m in (
         "submitted", "admitted", "resumed", "preempted", "rejected",

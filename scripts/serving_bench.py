@@ -14,9 +14,8 @@ client counts, plus a shared-system-prompt workload (N clients sharing a
 long common prefix) that measures the paged engine's prefix cache ON vs
 OFF: tok/s, hit-rate, and prefill_tokens_saved (docs/serving.md), a
 decode-heavy workload (short repetitive prompts, long generations) that
-measures speculative decoding OFF vs ON vs ON+fused verification: tok/s,
-accept rate, ITL p50/p99, model forward passes per generated token, and
-prefill-shaped verify dispatches per accepted token, and an OPEN-LOOP Poisson
+measures speculative decoding OFF vs ON: tok/s, accept rate, ITL p50/p99
+and model forward passes per generated token, and an OPEN-LOOP Poisson
 workload replayed against the continuous-batching scheduler vs the
 hand-rolled FCFS admit loop — goodput-under-SLO, queue-wait percentiles,
 and preemption counts — plus a fleet
@@ -233,18 +232,12 @@ def run_decode_heavy(build, sp, vocab, batch, prompt_len, gen_len,
     (a ``pattern_len``-token pattern tiled to ``prompt_len`` — the
     prompt-lookup drafter's best case, standing in for quoted-context /
     multi-turn-echo traffic) and long generations, run with speculative
-    decoding OFF, ON, and ON+FUSED verification
-    (``inference.speculative.fused_verify`` — docs/serving.md "Fused
-    verification"). Reports generated tok/s, per-token latency p50/p99,
-    the accept-rate / tokens-per-step counters, model forward passes per
-    generated token — the number speculative decoding exists to shrink —
-    and ``prefill_shaped_per_accepted``: prefill-shaped verify dispatches
-    per accepted draft token, the number fused verification exists to
-    shrink (every unfused verify step re-gathers the whole context at
-    prefill width; fused steps ride the paged-decode kernel family)."""
+    decoding OFF and ON. Reports generated tok/s, per-token latency
+    p50/p99, the accept-rate / tokens-per-step counters, and model forward
+    passes per generated token — the number speculative decoding exists to
+    shrink."""
     out = {"prompt_len": prompt_len, "gen_len": gen_len, "batch": batch}
-    for label, mode in (("spec_off", False), ("spec_on", True),
-                        ("spec_fused", "fused")):
+    for label, mode in (("spec_off", False), ("spec_on", True)):
         traffic = _traffic(seed=13, vocab_size=vocab,
                            prompt_kind="repetitive", prompt_len=prompt_len,
                            pattern_len=pattern_len)
@@ -267,14 +260,8 @@ def run_decode_heavy(build, sp, vocab, batch, prompt_len, gen_len,
                     stats["emitted_tokens"] / stats["step_seqs"], 3) \
                     if stats["step_seqs"] else 0.0
                 row["verify_steps"] = stats["verify_steps"]
-                row["fused_verify_steps"] = stats.get(
-                    "fused_verify_steps", 0)
                 row["drafted_tokens"] = stats["drafted_tokens"]
                 row["accepted_tokens"] = stats["accepted_tokens"]
-                row["prefill_shaped_per_accepted"] = round(
-                    (stats["verify_steps"]
-                     - stats.get("fused_verify_steps", 0))
-                    / max(1, stats["accepted_tokens"]), 3)
             out[label] = row
             sys.stderr.write(f"[serving] decode_heavy {label}: {row}\n")
         finally:
@@ -1028,16 +1015,13 @@ def main():
             bs_sd = 16
 
         def build_sd(spec_mode):
-            # spec_mode: False | True | "fused" (fused_verify arm)
             nb = (batch_sd + 1) * ((plen_sd + glen_sd) // bs_sd + 3) + 8
             return build_engine_v2(
                 llama, mcfg, llama.init(mcfg, jax.random.PRNGKey(0)),
                 config={"dtype": "bfloat16",
                         "prefill_bucket": min(64, plen_sd),
                         "speculative": {"enabled": bool(spec_mode),
-                                        "max_draft_tokens": k_sd,
-                                        "fused_verify":
-                                            spec_mode == "fused"},
+                                        "max_draft_tokens": k_sd},
                         "ragged": {"max_tracked_sequences": batch_sd,
                                    "max_ragged_batch_size": batch_sd,
                                    "memory_config_blocks": nb,

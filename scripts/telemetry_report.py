@@ -674,12 +674,6 @@ def serving(events: List[dict]) -> str:
                      f"{sp.get('tokens_per_step', 0):.2f} per sequence")
         lines.append(f"  verify batch occupancy: "
                      f"{sp.get('verify_batch_occupancy', 0) * 100:.1f}%")
-        if sp.get("fused_verify_steps"):
-            lines.append(f"  fused verify steps:     "
-                         f"{sp.get('fused_verify_steps', 0):,.0f} of "
-                         f"{sp.get('verify_steps', 0):,.0f} rode the "
-                         f"paged-decode kernel (zero prefill-shaped "
-                         f"dispatches)")
     if sched:
         if lines:
             lines.append("")

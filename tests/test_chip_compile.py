@@ -472,7 +472,6 @@ def test_decode_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
     """The server's decode program over the real pool geometry (depth 2: the
     layer scan makes the program the same at any depth)."""
     from deepspeed_tpu.comm import mesh as mesh_lib
-    from deepspeed_tpu.inference.sampling import SamplingParams
 
     # one device, as on the chip: the paged kernels have no layout over a
     # mesh yet, and over several devices they do not lower
@@ -480,12 +479,11 @@ def test_decode_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
     size = dataclasses.replace(chip_smoke.ServeSize(), layers=2)
     eng = chip_smoke.build_server(chip_smoke.mistral_7b(size.layers), size,
                                   seed=0)
-    args = (eng.params, eng.cache, jnp.asarray(eng._slot_tokens),
-            jnp.asarray(eng._slot_lens), jnp.asarray(eng._slot_tables),
-            jnp.asarray(eng._slot_active), jax.random.PRNGKey(0))
+    args = (eng.params, eng.cache, *map(jnp.asarray, eng._slots()),
+            jax.random.PRNGKey(0))
     sh = SingleDeviceSharding(v5e.devices[0])
     args = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), args)
-    decode = eng._decode_fn(SamplingParams(greedy=True))
+    decode = eng._decode_fn(1, False)
     compiled = decode._jitted.lower(*args).compile()
     assert MOSAIC in compiled.as_text()

@@ -1,12 +1,12 @@
-"""Native-GQA attention + fused speculative verification (ISSUE 14;
-docs/performance.md "Native GQA attention", docs/serving.md "Fused
+"""Native-GQA attention + the paged multi-token kernel (ISSUE 14, 25;
+docs/performance.md "Native GQA attention", docs/serving.md "Batched
 verification"): flash-kernel fwd/bwd parity vs the repeat_kv XLA reference
 across head ratios × causal/windowed × remat policies, the default-OFF
 byte-identity pins, the jaxpr lint (no model family's training apply
 widens K/V to query width when ``attention.gqa_native`` is on), the
-Ulysses alignment widener, fused-verify greedy token-identity vs the
-prefill-shaped ``_verify_fn`` path (incl. prefix-cache/fork/kv_quant
-compose), and the telemetry/schema/report surface."""
+Ulysses alignment widener, the paged prefill / verify kernel against its
+XLA reference, and the telemetry/schema/report surface. (Speculative
+serving held to plain decode, token for token: tests/test_spec_decode.py.)"""
 
 import os
 import subprocess
@@ -23,8 +23,7 @@ import importlib
 # name, shadowing the submodule on attribute access — resolve the module
 attn_mod = importlib.import_module("deepspeed_tpu.ops.attention")
 from deepspeed_tpu.comm import mesh as mesh_lib
-from deepspeed_tpu.inference import (InferenceConfig, SamplingParams,
-                                     build_engine_v2)
+from deepspeed_tpu.inference import build_engine_v2
 from deepspeed_tpu.ops.attention import (attention_xla, configure_gqa_native,
                                          gqa_native_active,
                                          kv_alignment_heads, repeat_kv,
@@ -37,7 +36,6 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
     paged_spec_verify_attention, paged_spec_verify_attention_xla)
 from deepspeed_tpu.models import exaone4, falcon, gpt, llama, mixtral
 
-SP = SamplingParams(greedy=True)
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -62,11 +60,6 @@ def test_gqa_gate_defaults_off_and_config_block():
     assert parse_config({}).attention.gqa_native is False
     cfg = parse_config({"attention": {"gqa_native": True}})
     assert cfg.attention.gqa_native is True
-    # serving knob: fused verification defaults off too
-    assert InferenceConfig().speculative.fused_verify is False
-    assert InferenceConfig.from_dict(
-        {"speculative": {"enabled": True,
-                         "fused_verify": True}}).speculative.fused_verify
 
 
 def test_widen_kv_is_the_one_helper():
@@ -379,7 +372,7 @@ def test_runtime_engine_publishes_gate(tmp_path):
 
 
 # --------------------------------------------------------------------------- #
-# fused speculative verification
+# the paged multi-token kernel (prefill chunks, verify windows)
 # --------------------------------------------------------------------------- #
 @pytest.fixture(scope="module")
 def tiny():
@@ -388,130 +381,16 @@ def tiny():
     return cfg, params
 
 
-def build(tiny, fused, spec_on=True, k=4, **kw):
+def build(tiny, **kw):
     cfg, params = tiny
     mesh_lib.set_mesh(None)
     return build_engine_v2(
         llama, cfg, params,
         config=dict({"dtype": "float32", "prefill_bucket": 16,
-                     "speculative": {"enabled": spec_on,
-                                     "max_draft_tokens": k,
-                                     "fused_verify": fused},
                      "ragged": {"max_tracked_sequences": 4,
                                 "max_ragged_batch_size": 4,
                                 "memory_config_blocks": 64,
                                 "block_size": 16}}, **kw))
-
-
-def _spec_prompts(cfg, n_extra=1, seed=1):
-    rng = np.random.default_rng(seed)
-    pat = rng.integers(0, cfg.vocab_size, (6,), dtype=np.int32).tolist()
-    out = [(pat * 6)[:32]]
-    for _ in range(n_extra):
-        out.append(rng.integers(0, cfg.vocab_size, (23,),
-                                dtype=np.int32).tolist())
-    return out
-
-
-def test_fused_verify_default_off_runs_pre_fuse_programs(tiny):
-    from deepspeed_tpu.models import _paged
-
-    eng = build(tiny, fused=False)
-    assert not _paged.fused_verify_active()
-    prompts = _spec_prompts(tiny[0])
-    eng.generate(prompts, max_new_tokens=8)
-    assert eng.spec_stats["verify_steps"] > 0
-    assert eng.spec_stats["fused_verify_steps"] == 0
-    assert any(k[0] == "spec_verify" for k in eng._paged_fns)
-    assert not any(k[0] == "spec_verify_fused" for k in eng._paged_fns)
-    assert not _paged.fused_verify_active()   # scope never leaked
-
-
-def test_fused_verify_greedy_token_identity(tiny):
-    """Acceptance: fused verification streams greedy-token-identical to
-    the `_verify_fn` path, with every verify step riding the paged-decode
-    kernel family instead of a prefill-shaped dispatch."""
-    prompts = _spec_prompts(tiny[0])
-    e_ref = build(tiny, fused=False)
-    want = e_ref.generate(prompts, max_new_tokens=12)
-    eng = build(tiny, fused=True)
-    got = eng.generate(prompts, max_new_tokens=12)
-    assert got == want
-    st = eng.spec_stats
-    assert st["verify_steps"] > 0
-    assert st["fused_verify_steps"] == st["verify_steps"]
-    assert st["drafted_tokens"] > 0
-    assert any(k[0] == "spec_verify_fused" for k in eng._paged_fns)
-    assert not any(k[0] == "spec_verify" for k in eng._paged_fns)
-    eng.state.debug_check()
-
-
-def test_fused_verify_composes_prefix_cache_and_kv_quant(tiny):
-    """Fused verification over SHARED (prefix-cache) and QUANTIZED (int8
-    codes + scales through the same block-table specs) blocks still
-    streams identically to the unfused engine with the same features."""
-    cfg, _ = tiny
-    extras = {"prefix_cache": {"enabled": True},
-              "kv_quant": {"enabled": True, "group_size": 8}}
-    rng = np.random.default_rng(1)
-    pat = rng.integers(0, cfg.vocab_size, (6,), dtype=np.int32).tolist()
-    pa = (pat * 6)[:32]   # repetitive: the drafter's best case
-    pb = pa[:16] + rng.integers(0, cfg.vocab_size, (7,),
-                                dtype=np.int32).tolist()
-    e_ref = build(tiny, fused=False, **extras)
-    want = [e_ref.generate([p], max_new_tokens=12)[0] for p in (pa, pb)]
-    eng = build(tiny, fused=True, **extras)
-    got = [eng.generate([p], max_new_tokens=12)[0] for p in (pa, pb)]
-    assert got == want
-    assert eng.spec_stats["fused_verify_steps"] > 0
-    assert eng.state.prefix_stats["hit_tokens"] > 0
-    eng.state.debug_check()
-    eng.debug_check_cache()
-
-
-def test_fused_verify_composes_with_fork(tiny):
-    def run(fused):
-        eng = build(tiny, fused=fused)
-        prompt = _spec_prompts(tiny[0], n_extra=0)[0]
-        eng.put(1, prompt, SP)
-        eng.step(SP)
-        eng.fork(1, 2)
-        for i in range(4):
-            eng.step(SP, seed=i)
-        streams = {u: list(eng.state.seqs[u].generated) for u in (1, 2)}
-        eng.state.debug_check()
-        return streams
-
-    assert run(True) == run(False)
-
-
-def test_fused_verify_windowed_family_exaone4():
-    """exaone4's scanned per-layer sliding windows thread into the fused
-    verify path as the same traced window scalar the decode kernel takes:
-    fused streams stay token-identical on a hybrid-attention family."""
-    cfg = exaone4.Exaone4Config.tiny(max_seq_len=128)
-    params = exaone4.init(cfg, jax.random.PRNGKey(0))
-    mesh_lib.set_mesh(None)
-
-    def mk(fused):
-        return build_engine_v2(
-            exaone4, cfg, params,
-            config={"dtype": "float32", "prefill_bucket": 16,
-                    "speculative": {"enabled": True, "max_draft_tokens": 3,
-                                    "fused_verify": fused},
-                    "ragged": {"max_tracked_sequences": 2,
-                               "max_ragged_batch_size": 2,
-                               "memory_config_blocks": 32,
-                               "block_size": 16}})
-
-    rng = np.random.default_rng(5)
-    pat = rng.integers(0, cfg.vocab_size, (5,), dtype=np.int32).tolist()
-    prompts = [(pat * 6)[:24]]
-    want = mk(False).generate(prompts, max_new_tokens=10)
-    eng = mk(True)
-    got = eng.generate(prompts, max_new_tokens=10)
-    assert got == want
-    assert eng.spec_stats["fused_verify_steps"] > 0
 
 
 @pytest.mark.parametrize("window,quant", [(None, False), (9, False),
@@ -776,7 +655,7 @@ def test_served_stream_is_the_same_through_the_kernel(tiny):
     def serve(backend):
         registry.set_backend("paged_prefill_attention", backend)
         try:
-            eng = build(tiny, False, spec_on=False, split_prefill_chunk=16)
+            eng = build(tiny, split_prefill_chunk=16)
             streams = {}
             for uid, tok in eng.put_many(list(enumerate(shorts))).items():
                 streams[uid] = [tok]
@@ -803,10 +682,10 @@ def test_schema_registration():
     from deepspeed_tpu.telemetry.schema import (SERVING_SERIES, TRAIN_SERIES,
                                                 validate_events)
 
-    assert "Serving/spec/fused_verify_steps" in SERVING_SERIES
+    assert "Serving/spec/verify_steps" in SERVING_SERIES
     assert "Train/attn/kv_bytes_saved" in TRAIN_SERIES
     assert "Train/attn/gqa_ratio" in TRAIN_SERIES
-    ok = [("Serving/spec/fused_verify_steps", 3.0, 1),
+    ok = [("Serving/spec/verify_steps", 3.0, 1),
           ("Train/attn/kv_bytes_saved", 1024.0, 1),
           ("Train/attn/gqa_ratio", 4.0, 1)]
     assert validate_events(ok) == []
@@ -814,19 +693,7 @@ def test_schema_registration():
     assert validate_events([("Train/attn/bogus", 1.0, 1)])
 
 
-def test_spec_events_carry_fused_counter(tiny):
-    from deepspeed_tpu.telemetry import validate_events
-
-    eng = build(tiny, fused=True)
-    eng.generate(_spec_prompts(tiny[0], n_extra=0), max_new_tokens=8)
-    events = eng.spec_events(step=1)
-    assert validate_events(events) == []
-    vals = {n: v for n, v, _ in events}
-    assert vals["Serving/spec/fused_verify_steps"] == \
-        vals["Serving/spec/verify_steps"] > 0
-
-
-def test_report_renders_gqa_and_fused_sections(tmp_path):
+def test_report_renders_gqa_and_spec_sections(tmp_path):
     import json
 
     path = tmp_path / "events.jsonl"
@@ -836,7 +703,6 @@ def test_report_renders_gqa_and_fused_sections(tmp_path):
          "step": 1},
         {"name": "Train/overlap/prefetch_depth", "value": 1.0, "step": 1},
         {"name": "Serving/spec/verify_steps", "value": 5.0, "step": 1},
-        {"name": "Serving/spec/fused_verify_steps", "value": 5.0, "step": 1},
         {"name": "Serving/spec/drafted_tokens", "value": 20.0, "step": 1},
         {"name": "Serving/spec/accepted_tokens", "value": 18.0, "step": 1},
         {"name": "Serving/spec/accept_rate", "value": 0.9, "step": 1},
@@ -849,8 +715,7 @@ def test_report_renders_gqa_and_fused_sections(tmp_path):
         [sys.executable, script, str(path), "--serving"],
         capture_output=True, text=True, timeout=60)
     assert out.returncode == 0, out.stderr
-    assert "fused verify steps" in out.stdout
-    assert "paged-decode kernel" in out.stdout
+    assert "accept rate:" in out.stdout
     out2 = subprocess.run(
         [sys.executable, script, str(path), "--comm-efficiency"],
         capture_output=True, text=True, timeout=60)

@@ -754,15 +754,13 @@ def test_v2_midchunk_prefill_compiles_shared_across_sampling_params(tiny):
                 "ragged": {"max_tracked_sequences": 4,
                            "max_ragged_batch_size": 4,
                            "memory_config_blocks": 64, "block_size": 16}})
-    f1 = eng._chunk_prefill_fn(32, SamplingParams(temperature=0.7),
-                               final=False)
-    f2 = eng._chunk_prefill_fn(32, SamplingParams(temperature=1.3, top_k=5),
-                               final=False)
+    f1 = eng._chunk_prefill_fn(32, False, SamplingParams(temperature=0.7))
+    f2 = eng._chunk_prefill_fn(32, False,
+                               SamplingParams(temperature=1.3, top_k=5))
     assert f1 is f2
-    g1 = eng._chunk_prefill_fn(32, SamplingParams(temperature=0.7),
-                               final=True)
-    g2 = eng._chunk_prefill_fn(32, SamplingParams(temperature=1.3, top_k=5),
-                               final=True)
+    g1 = eng._chunk_prefill_fn(32, True, SamplingParams(temperature=0.7))
+    g2 = eng._chunk_prefill_fn(32, True,
+                               SamplingParams(temperature=1.3, top_k=5))
     assert g1 is not g2  # final chunks DO sample with their own sp
 
 

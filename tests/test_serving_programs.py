@@ -1,0 +1,243 @@
+"""The serving engine's programs, to the letter.
+
+``inference/engine_v2.py`` builds four forward programs (prefill, chunk
+prefill, decode, speculative verify) in the variants the dispatch sites
+choose between, and five families route their paged pools through
+``models/_paged.scan_layers``. A PR that reshapes that code without meaning
+to change a program must leave every jaxpr here as it was: the hashes below
+are of the commit before ISSUE 28 (f552895), where ten builders and five
+hand-written scans produced them. ``str(jaxpr)`` carries no scope names, so
+the ``kv_write`` scope that ``scan_layers`` gives gpt, falcon and exaone4
+does not show here; an operation added or moved does.
+
+The last test counts what ONE ``step()`` does on the host before its
+program runs (uploads and keys made inside ``engine_v2``): the dispatch
+path's cost is the serve cells' ``serve_idle_dispatch_share``.
+"""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.comm import mesh as mesh_lib
+from deepspeed_tpu.inference import SamplingParams, build_engine_v2
+from deepspeed_tpu.inference import engine_v2 as engine_mod
+from deepspeed_tpu.models import exaone4, falcon, gpt, llama
+
+SLOTS, BLOCK, PAD_T, K, KP1 = 4, 4, 8, 4, 4
+STOCHASTIC = SamplingParams(temperature=0.7, top_k=5, top_p=0.9)
+
+
+def _text(fn, *args) -> str:
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+
+
+def _hash(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _engine(**extra):
+    cfg = llama.LlamaConfig.tiny(max_seq_len=64)
+    mesh_lib.set_mesh(None)
+    return build_engine_v2(
+        llama, cfg, llama.init(cfg, jax.random.PRNGKey(0)),
+        config=dict({"prefill_bucket": PAD_T,
+                     "ragged": {"max_tracked_sequences": SLOTS,
+                                "max_ragged_batch_size": SLOTS,
+                                "memory_config_blocks": 32,
+                                "block_size": BLOCK}}, **extra))
+
+
+@pytest.fixture(scope="module")
+def engines():
+    return {"bf16": _engine(),
+            "int8": _engine(kv_quant={"enabled": True, "group_size": 8})}
+
+
+def _shapes(tree):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        tree)
+
+
+def _args(eng):
+    """Abstract arguments of the programs, by the names the engine's calls
+    give them: ``n`` rows of a prefill, one chunk, all ``SLOTS`` of a decode
+    or a verify."""
+    s = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    width = eng._slot_tables.shape[1]
+
+    def rows(n):
+        return (s((n,), f32), s((n,), i32), s((n,), f32), s((n,), bool))
+
+    head = (_shapes(eng.params), _shapes(eng.cache))
+    key = s((2,), jnp.uint32)
+    prefill = head + (s((2, PAD_T), i32), s((2,), i32), s((2, width), i32))
+    slots = (s((SLOTS,), i32), s((SLOTS, width), i32), s((SLOTS,), bool))
+    decode = head + (s((SLOTS,), i32),) + slots + (key,)
+    return {
+        "prefill": prefill + (key, s((2,), i32)),
+        "prefill_ctx": prefill + (s((2,), i32), key, s((2,), i32)),
+        "chunk": head + (s((1, PAD_T), i32), s((), i32), s((), i32),
+                         s((width,), i32), key, s((), i32)),
+        "decode": decode,
+        "verify": head + (s((SLOTS, KP1), i32),) + slots + (
+            s((SLOTS,), i32), s((SLOTS, KP1 - 1), i32), key,
+            s((SLOTS,), i32)) + rows(SLOTS),
+        "rows2": rows(2), "rows": rows(SLOTS)}
+
+
+# name -> (engine, its builder's call, the arguments' names in ``_args``)
+PROGRAMS = {
+    "prefill.greedy": ("bf16", lambda e: e._prefill_fn(PAD_T, 2, False, False),
+                       ("prefill",)),
+    "prefill.rows": ("bf16", lambda e: e._prefill_fn(PAD_T, 2, False, True),
+                     ("prefill", "rows2")),
+    "prefill_ctx.greedy": ("bf16",
+                           lambda e: e._prefill_fn(PAD_T, 2, True, False),
+                           ("prefill_ctx",)),
+    "prefill_ctx.rows": ("bf16",
+                         lambda e: e._prefill_fn(PAD_T, 2, True, True),
+                         ("prefill_ctx", "rows2")),
+    "chunk_prefill.mid": ("bf16", lambda e: e._chunk_prefill_fn(
+        PAD_T, False, SamplingParams(greedy=True)), ("chunk",)),
+    "chunk_prefill.final_greedy": ("bf16", lambda e: e._chunk_prefill_fn(
+        PAD_T, True, SamplingParams(greedy=True)), ("chunk",)),
+    "chunk_prefill.final_stochastic": ("bf16", lambda e: e._chunk_prefill_fn(
+        PAD_T, True, STOCHASTIC), ("chunk",)),
+    "decode.greedy": ("bf16", lambda e: e._decode_fn(1, False), ("decode",)),
+    "decode.rows": ("bf16", lambda e: e._decode_fn(1, True),
+                    ("decode", "rows")),
+    "decode_many.greedy": ("bf16", lambda e: e._decode_fn(K, False),
+                           ("decode",)),
+    "decode_many.rows": ("bf16", lambda e: e._decode_fn(K, True),
+                         ("decode", "rows")),
+    "spec_verify": ("bf16", lambda e: e._verify_fn(KP1), ("verify",)),
+    "decode.greedy.int8": ("int8", lambda e: e._decode_fn(1, False),
+                           ("decode",)),
+    "chunk_prefill.mid.int8": ("int8", lambda e: e._chunk_prefill_fn(
+        PAD_T, False, SamplingParams(greedy=True)), ("chunk",)),
+}
+
+
+def program_text(engines, name: str) -> str:
+    which, build, arg_names = PROGRAMS[name]
+    eng = engines[which]
+    args = _args(eng)
+    return _text(build(eng), *sum((args[a] for a in arg_names), ()))
+
+
+PAGED_FAMILIES = {
+    "gpt": lambda: (gpt, gpt.GPTConfig.tiny()),
+    "falcon": lambda: (falcon, falcon.FalconConfig.tiny()),
+    "exaone4": lambda: (exaone4, exaone4.Exaone4Config.tiny()),
+}
+
+
+def paged_text(family: str, t: int) -> str:
+    """``apply_paged`` of a family over ``t`` tokens a row, on shapes."""
+    module, cfg = PAGED_FAMILIES[family]()
+    s = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda k: module.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    paged = jax.eval_shape(lambda: module.init_paged_cache(cfg, 8, BLOCK))
+    return _text(lambda p, x, c, b, n: module.apply_paged(cfg, p, x, c, b, n),
+                 params, s((2, t), jnp.int32), paged, s((2, 4), jnp.int32),
+                 s((2,), jnp.int32))
+
+
+# taken on f552895 under this directory's conftest, before any of the code
+# moved. A PR that means to change one of these programs replaces its line.
+PARENT_HASHES = {
+    "chunk_prefill.final_greedy": "1d5ddd5c63d6b820",
+    "chunk_prefill.final_stochastic": "7601a8abf1c593a8",
+    "chunk_prefill.mid": "8249e849ca3231c8",
+    "chunk_prefill.mid.int8": "64a0539822d23ba0",
+    "decode.greedy": "29cb773389bccaa2",
+    "decode.greedy.int8": "f827142dee713212",
+    "decode.rows": "cf79d4f1a6ddc149",
+    "decode_many.greedy": "6692f081ad5be613",
+    "decode_many.rows": "30fc0a93ac321aa4",
+    "exaone4.apply_paged.t1": "6192bc36a8e2d031",
+    "exaone4.apply_paged.t8": "b3613330675111b6",
+    "falcon.apply_paged.t1": "1d04fe2e2c5988fd",
+    "falcon.apply_paged.t8": "83f6aaf021bdc995",
+    "gpt.apply_paged.t1": "b530ef24c972e315",
+    "gpt.apply_paged.t8": "307319c7b77b22c4",
+    "prefill.greedy": "a084d36e7501994d",
+    "prefill.rows": "6390078d318cb885",
+    "prefill_ctx.greedy": "42ea0db6e00ee03f",
+    "prefill_ctx.rows": "779d41141192ea91",
+    "spec_verify": "575cd95a2db166b6",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_the_engines_program_is_the_parents(engines, name):
+    assert _hash(program_text(engines, name)) == PARENT_HASHES[name]
+
+
+@pytest.mark.parametrize("t", [8, 1])
+@pytest.mark.parametrize("family", sorted(PAGED_FAMILIES))
+def test_the_familys_paged_forward_is_the_parents(family, t):
+    assert _hash(paged_text(family, t)) == \
+        PARENT_HASHES[f"{family}.apply_paged.t{t}"]
+
+
+def test_the_programs_keep_the_names_the_benchmark_reads(engines):
+    """``benchmark/readers`` find ``jit_decode`` and ``jit_chunk_prefill``
+    by the inner functions' names, which the pinned text carries."""
+    for name, want in (("decode.greedy", "name=decode"),
+                       ("decode_many.rows", "name=decode_many"),
+                       ("chunk_prefill.mid", "name=chunk_prefill"),
+                       ("prefill_ctx.rows", "name=prefill"),
+                       ("spec_verify", "name=verify")):
+        assert re.search(want + r"\b", program_text(engines, name)), name
+
+
+# --- what one step() does on the host before its program runs -------------- #
+class _Counted:
+    """Stands in for a module inside ``engine_v2``: the attribute at the end
+    of ``path`` counts its calls, everything else is the module's own."""
+
+    def __init__(self, target, path, counts):
+        self._target, self._path, self._counts = target, path, counts
+
+    def __getattr__(self, name):
+        value = getattr(self._target, name)
+        if name != self._path[0]:
+            return value
+        if len(self._path) > 1:
+            return _Counted(value, self._path[1:], self._counts)
+
+        def counted(*args, **kwargs):
+            self._counts[name] += 1
+            return value(*args, **kwargs)
+
+        return counted
+
+
+# of the parent (f552895): the slot arrays, a key, and in rows mode the four
+# sampling arrays. Queue A4 replaces this line on purpose.
+PARENT_HOST_OPS = {"greedy": {"asarray": 4, "PRNGKey": 1},
+                   "rows": {"asarray": 8, "PRNGKey": 1}}
+
+
+@pytest.mark.parametrize("mode", sorted(PARENT_HOST_OPS))
+def test_a_step_uploads_and_makes_keys_as_the_parent_did(monkeypatch, mode):
+    eng = _engine()
+    sp = SamplingParams(greedy=True) if mode == "greedy" else STOCHASTIC
+    eng.put(1, list(range(5)), sp)
+    eng.put(2, list(range(7)))
+    eng.step()                                   # warm: the program exists
+    counts = {"asarray": 0, "PRNGKey": 0}
+    monkeypatch.setattr(engine_mod, "jnp",
+                        _Counted(jnp, ("asarray",), counts))
+    monkeypatch.setattr(engine_mod, "jax",
+                        _Counted(jax, ("random", "PRNGKey"), counts))
+    out = eng.step(seed=1)
+    assert sorted(out) == [1, 2]
+    assert counts == PARENT_HOST_OPS[mode]

@@ -229,6 +229,67 @@ def test_greedy_spec_parity_composes_with_prefix_cache(tiny, eng_off):
     eng.state.debug_check()
 
 
+def test_greedy_spec_after_a_fork_matches_plain_decode(tiny):
+    """A fork shares every block with its parent, the partial tail too:
+    each side's verify window must copy-on-write before it writes drafts,
+    and a rollback into the shared tail must not reach the other side.
+    Both streams stay the plain engine's, token for token."""
+    cfg, _ = tiny
+    rng = np.random.default_rng(1)
+    pat = rng.integers(0, cfg.vocab_size, (6,), dtype=np.int32).tolist()
+    prompt = (pat * 6)[:32]
+
+    def run(spec_on, enough):
+        eng = build(tiny, spec_on=spec_on)
+        eng.put(1, prompt, SP)
+        eng.step(SP)
+        eng.fork(1, 2)
+        i = 0
+        while not enough(eng):
+            eng.step(SP, seed=i)
+            i += 1
+        eng.state.debug_check()
+        return eng, {u: list(eng.state.seqs[u].generated) for u in (1, 2)}
+
+    spec, got = run(True, lambda e: e.spec_stats["verify_steps"] >= 4)
+    assert spec.spec_stats["drafted_tokens"] > 0
+    _, want = run(False, lambda e: all(
+        len(e.state.seqs[u].generated) >= len(got[u]) for u in (1, 2)))
+    for u in (1, 2):
+        assert got[u] == want[u][:len(got[u])] and len(got[u]) > 4
+
+
+def test_greedy_spec_on_a_windowed_family_matches_plain_decode():
+    """exaone4's scanned per-layer sliding windows reach the verify pass
+    as the same traced window scalar the decode kernel takes: verification
+    over a hybrid-attention family emits the plain engine's stream."""
+    from deepspeed_tpu.models import exaone4
+
+    cfg = exaone4.Exaone4Config.tiny(max_seq_len=128)
+    params = exaone4.init(cfg, jax.random.PRNGKey(0))
+    mesh_lib.set_mesh(None)
+
+    def mk(spec_on):
+        return build_engine_v2(
+            exaone4, cfg, params,
+            config={"dtype": "float32", "prefill_bucket": 16,
+                    "speculative": {"enabled": spec_on,
+                                    "max_draft_tokens": 3},
+                    "ragged": {"max_tracked_sequences": 2,
+                               "max_ragged_batch_size": 2,
+                               "memory_config_blocks": 32,
+                               "block_size": 16}})
+
+    rng = np.random.default_rng(5)
+    pat = rng.integers(0, cfg.vocab_size, (5,), dtype=np.int32).tolist()
+    prompts = [(pat * 6)[:24]]
+    want = mk(False).generate(prompts, max_new_tokens=10)
+    eng = mk(True)
+    assert eng.generate(prompts, max_new_tokens=10) == want
+    assert eng.spec_stats["verify_steps"] > 0
+    assert eng.spec_stats["drafted_tokens"] > 0
+
+
 # --------------------------------------------------------------------------- #
 # deterministic acceptance / rejection via the stub family
 # --------------------------------------------------------------------------- #

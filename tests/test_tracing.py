@@ -882,7 +882,6 @@ def test_model_step_blocks_are_named_scopes(devices8, family, program, want):
         engine.destroy()
     else:
         from deepspeed_tpu.inference.engine_v2 import build_engine_v2
-        from deepspeed_tpu.inference.sampling import SamplingParams
 
         params = mod.init(cfg, jax.random.PRNGKey(0))
         eng = build_engine_v2(mod, cfg, params, config={
@@ -890,8 +889,6 @@ def test_model_step_blocks_are_named_scopes(devices8, family, program, want):
             "ragged": {"max_tracked_sequences": 4, "max_ragged_batch_size": 4,
                        "memory_config_blocks": 64, "block_size": 16}})
         found = _scopes_of(
-            eng._decode_fn(SamplingParams(greedy=True)), eng.params,
-            eng.cache, jnp.asarray(eng._slot_tokens),
-            jnp.asarray(eng._slot_lens), jnp.asarray(eng._slot_tables),
-            jnp.asarray(eng._slot_active), jax.random.PRNGKey(0))
+            eng._decode_fn(1, False), eng.params, eng.cache,
+            *map(jnp.asarray, eng._slots()), jax.random.PRNGKey(0))
     assert want <= found, want - found
