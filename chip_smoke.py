@@ -83,10 +83,11 @@ class TrainSize:
 class ServeSize:
     layers: int = 16              # of 32: 7.5 GB of bf16 weights
     block_size: int = 32
-    # 28.7k tokens of KV, 1.9 GB in bf16. Not "the rest of the chip": every
-    # paged program holds a SECOND copy of the pool as scan temporaries
-    # (and the size dates from when a 4 x 256-token prefill held 4 GB of f32
-    # scores over the table's width besides: 15.4 GB compiled then)
+    # 28.7k tokens of KV, 1.9 GB in bf16. Not "the rest of the chip": the
+    # size dates from when every paged program held a SECOND copy of the pool
+    # as scan temporaries (until ISSUE 29 made the pools the layer scan's
+    # carry) and a 4 x 256-token prefill held 4 GB of f32 scores over the
+    # table's width besides: 15.4 GB compiled then
     pool_blocks: int = 896
     slots: int = 32               # decode batch width
     prefill_chunk: int = 256      # Dynamic-SplitFuse chunk for long prompts

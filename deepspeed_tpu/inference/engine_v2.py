@@ -4,7 +4,7 @@ Reference parity: ``InferenceEngineV2`` (``inference/v2/engine_v2.py:30``) and
 ``build_hf_engine`` (``engine_factory.py:70``). The reference schedules ragged
 batches through persistent CUDA kernels with host/device shadow buffers; here
 every decode step is one fixed-shape jit program over all sequence slots —
-inactive slots compute into the trash block and are ignored — so continuous
+inactive slots read the trash block, write nothing and are ignored — so continuous
 batching costs zero recompiles and XLA keeps the MXU busy with the batched
 GEMMs. Prefill runs per-sequence at bucketed lengths (one compile per bucket).
 
@@ -403,9 +403,14 @@ class InferenceEngineV2(InferenceEngine):
         registration helper. ``key[0]`` is the program FAMILY name, so a new
         bucket/shape of an existing family registers as a recompile — which
         is exactly what an unbucketed-prompt recompilation storm looks like.
-        Default OFF → the exact ``jax.jit`` object back."""
-        return self.compile_monitor.jit(str(key[0]), fn, group="Serving",
-                                        **jit_kwargs)
+        Default OFF → the exact ``jax.jit`` object back. The monitor is told
+        the KV pools, so each compile says what it copies of them
+        (``pool_copy_bytes``: 0 for the forward programs, whose layers write
+        the pools where they lie) and what it aliases (``aliased_bytes``)."""
+        return self.compile_monitor.jit(
+            str(key[0]), fn, group="Serving",
+            pools=[c.shape for c in jax.tree.leaves(self.cache)],
+            **jit_kwargs)
 
     # ------------------------------------------------------------------ #
     # the programs: ONE forward (``_paged_forward``), the last real row's
@@ -417,7 +422,7 @@ class InferenceEngineV2(InferenceEngine):
         """The engine's ONE call of the family's paged forward, traced inside
         every program: ``tokens`` [b, t] at context offsets ``ctx`` [b]
         through block tables [b, blocks], ``params`` as ``_dq`` hands them
-        over; rows where ``valid`` [b, t] is False write to the trash block.
+        over; rows where ``valid`` [b, t] is False write nothing.
         Returns (logits [b, t, V] fp32, cache)."""
         return self._apply_paged(self.family.cfg, params, tokens, cache,
                                  tables, ctx, valid=valid)
@@ -427,8 +432,8 @@ class InferenceEngineV2(InferenceEngine):
         admission bursts (serving start, high churn) run one program call
         instead of n (the reference schedules multi-sequence ragged prefill
         batches the same way). Callers pad n to a power-of-two bucket with
-        zero-length dummy rows (masked by ``valid``, writing to the trash
-        block) so compile count stays O(log max_sequences) per pad_t, not
+        zero-length dummy rows (masked by ``valid``, writing nothing) so
+        compile count stays O(log max_sequences) per pad_t, not
         O(max_sequences). Per-row rng keys fold in each uid, keeping
         first-token sampling independent of burst composition.
 
@@ -726,7 +731,7 @@ class InferenceEngineV2(InferenceEngine):
                 dq = self._dq(params)
 
                 def tick(tokens, lens, cache, key_t):
-                    # inactive slots write to the trash block (valid=False)
+                    # inactive slots write nothing (valid=False)
                     logits, cache = self._paged_forward(
                         dq, tokens[:, None], cache, tables, lens,
                         active[:, None])
@@ -761,7 +766,7 @@ class InferenceEngineV2(InferenceEngine):
         of every sequence slot against the paged cache — the ctx-offset
         prefill machinery applied at decode time: row i feeds
         ``[last_token, draft_1..draft_k]`` at context offset ``lens[i]`` with
-        positions past ``1 + draft_len[i]`` masked to the trash block. Every
+        positions past ``1 + draft_len[i]`` masked (they write nothing). Every
         layer's attention is the ``paged_prefill`` kernel over the block
         table, as for any multi-token call (dequant-in-register in kv_quant
         mode).

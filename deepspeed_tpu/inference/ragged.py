@@ -9,7 +9,8 @@ of fixed-shape block pool arrays plus fixed-width block tables — every decode
 step is the SAME compiled program regardless of which sequences are live, so
 XLA graph caching plays the role of the reference's persistent kernel launch.
 
-Block 0 is reserved as the trash block: padded/invalid writes land there.
+Block 0 is reserved as the trash block: padded table entries point at it
+(a padded or invalid row writes nothing: ``paged_kv_write``).
 
 Prefix-aware KV reuse (vLLM/SGLang-style, docs/serving.md): blocks are
 ref-counted so multiple sequences may point their tables at the same block;

@@ -1,24 +1,30 @@
 """Shared paged-KV attention step — the one copy of the v2 block-table
 protocol every family's ``apply_paged`` builds on.
 
-Contract (see ``models/llama.py`` for the layout): the KV pool is
-``[num_blocks, kv_heads, block_size, hd]`` per layer (last two dims are the
-decode kernel's per-block tile — TPU tiling legal), block tables are
-fixed-width ``[b, max_blocks]`` indices into the pool, block 0 is the trash
-block that absorbs writes for padded tokens, and ``positions`` are absolute
-token positions (``context_lens + arange(t)``).
+Contract (see ``models/llama.py`` for the layout): the KV pool is ONE
+``[num_layers, num_blocks, kv_heads, block_size, hd]`` buffer (last two dims
+are the decode kernel's per-block tile — TPU tiling legal), block tables are
+fixed-width ``[b, max_blocks]`` indices into a layer's blocks, block 0 is the
+trash block that padded table entries point at (nothing writes it: a padded
+row writes nothing), and ``positions`` are absolute token positions
+(``context_lens + arange(t)``).
+
+The pools stay where they are (:func:`scan_layers`): they are the layer
+scan's carry, every layer writes its rows in place (``paged_kv_write``) and
+both attention kernels take the layer as an index into the ``[L, ...]``
+buffer. No layer's pool is sliced out and none is stacked back.
 
 Quantized KV mode (``inference.kv_quant``, docs/serving.md "Quantized KV
 cache"): the cache dict additionally carries ``k_scale``/``v_scale`` pools
-``[num_blocks, kv_heads, block_size, ngroups]`` fp32, K/V pools hold int8
-codes, and :func:`paged_attention_step` receives each pool as a
-``(codes, scales)`` tuple (:func:`scan_layers`). Fill-time quantization is
-fused into the cache-update scatter (per-token groupwise scales — a token's
-write never touches another position's scale), and dequant is fused into
-the attention reads: in-register inside both Pallas kernels (``paged_decode``
-for one query token, ``paged_prefill`` for more). There is NO standalone
-int8→bf16 convert pass over the pool — QUANT_TPU_LIVE.json shows that path
-losing to bf16 outright.
+``[num_layers, num_blocks, kv_heads, block_size, ngroups]`` fp32, K/V pools
+hold int8 codes, and each :class:`LayerPool` carries its scale pool beside
+its codes. Fill-time quantization is fused into the cache update (per-token
+groupwise scales — a token's write never touches another position's scale;
+codes and scales are written by the one ``paged_kv_write`` call), and dequant
+is fused into the attention reads: in-register inside both Pallas kernels
+(``paged_decode`` for one query token, ``paged_prefill`` for more). There is
+NO standalone int8→bf16 convert pass over the pool — QUANT_TPU_LIVE.json
+shows that path losing to bf16 outright.
 
 Reads: both kernels walk the block table over the live context
 (``ops/pallas/paged_attention.py``). Nothing here gathers a dense view of
@@ -28,13 +34,11 @@ a TPU alone.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ..ops.quantization import kv_quantize_int8
 
 
 def init_paged_pools(num_layers: int, num_blocks: int, num_kv_heads: int,
@@ -64,116 +68,108 @@ def init_paged_pools(num_layers: int, num_blocks: int, num_kv_heads: int,
             "v_scale": jnp.zeros(sshape, jnp.float32)}
 
 
-def _split_kv(cache):
-    """From the cache dict to :func:`paged_attention_step`'s K/V entries:
-    plain pools stay arrays; quantized pools (``k_scale`` present) become
-    ``(codes, scales)`` tuples so ``lax.scan`` threads codes AND scales per
-    layer with no per-family plumbing. Returns ``(k_entry, v_entry)``."""
-    if "k_scale" in cache:
-        return ((cache["k"], cache["k_scale"]),
-                (cache["v"], cache["v_scale"]))
-    return cache["k"], cache["v"]
-
-
-def _join_kv(k_entry, v_entry):
-    """Inverse of :func:`_split_kv`: rebuild the cache dict from the scan's
-    stacked per-layer outputs."""
-    if isinstance(k_entry, tuple):
-        return {"k": k_entry[0], "k_scale": k_entry[1],
-                "v": v_entry[0], "v_scale": v_entry[1]}
-    return {"k": k_entry, "v": v_entry}
+class LayerPool(NamedTuple):
+    """One of a layer's two cache entries as :func:`scan_layers` hands it to
+    a family's scan body and the body hands it on to
+    :func:`paged_attention_step`: the WHOLE ``[L, num_blocks, nkv, bs, hd]``
+    pool (int8 codes in quantized mode), its scale pool (None in plain
+    mode) and the layer's index. The pool is never sliced: the write and
+    both kernels index the layer where the pool lies."""
+    pool: jnp.ndarray
+    scale: Optional[jnp.ndarray]
+    layer: jnp.ndarray
 
 
 def scan_layers(body, x, layers, cache, *extras):
     """The pools' way through a program's layers, for every family: the
-    stacked ``layers`` are scanned with each layer's slice of the K/V pools
-    (and any per-layer ``extras`` - exaone4's windows and rope flags) as
-    scanned INPUTS, and the updated slices are stacked back on the way out.
-    ``body(x, (layer, k_entry, v_entry, *extras))`` returns
-    ``(x, (k_entry, v_entry))`` with the entries as
-    :func:`paged_attention_step` hands them back. Returns ``(x, cache)``.
+    pools are the scan's CARRY, beside ``x`` - one ``[L, ...]`` buffer a
+    pool from the program's (donated) argument to its result, written in
+    place by each layer and read where it lies. The stacked ``layers``, the
+    layer's index and any per-layer ``extras`` (exaone4's windows and rope
+    flags) are the scanned inputs. ``body(x, (layer, k_entry, v_entry,
+    *extras))`` returns ``(x, (k_entry, v_entry))``, the entries
+    :class:`LayerPool` s that it passes through
+    :func:`paged_attention_step`. Returns ``(x, cache)``.
 
-    What the scan itself adds around the blocks is pool traffic - each
-    layer's slice of the pools in, the updated slices stacked back - so it
-    carries the pool update's name; the blocks' own scopes lie inside it.
-    Whoever changes how the pools travel (a donated carry, ``[L, ...]``
-    pools the kernels index: ROADMAP Queue A2) changes it here."""
+    Nothing with a layout preference of its own may touch the pools on the
+    way (an XLA scatter on the carry copies the whole ``[L, ...]`` pool a
+    layer: PERF.md Findings, PR 29). What the scan itself adds around the
+    blocks carries the pool update's name; the blocks' own scopes lie
+    inside it."""
+    def step(carry, scanned):
+        x, pools = carry
+        layer, index, *rest = scanned
+        entries = [LayerPool(pools[n], pools.get(n + "_scale"), index)
+                   for n in ("k", "v")]
+        x, (k_entry, v_entry) = body(x, (layer, *entries, *rest))
+        pools = {"k": k_entry.pool, "v": v_entry.pool}
+        if k_entry.scale is not None:
+            pools.update(k_scale=k_entry.scale, v_scale=v_entry.scale)
+        return (x, pools), None
+
+    num_layers = cache["k"].shape[0]
     with jax.named_scope("kv_write"):
-        x, (new_k, new_v) = lax.scan(
-            body, x, (layers,) + _split_kv(cache) + extras)
-    return x, _join_kv(new_k, new_v)
+        (x, cache), _ = lax.scan(
+            step, (x, cache),
+            (layers, jnp.arange(num_layers, dtype=jnp.int32)) + extras)
+    return x, cache
 
 
 def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
                          context_lens, positions, valid, *,
                          window=None) -> Tuple:
-    """Scatter this step's K/V into the block pool, then attend over it.
+    """Write this step's K/V into the block pool, then attend over it.
 
-    q [b, t, nh, hd]; k/v [b, t, nkv, hd]. ``k_cache``/``v_cache`` are
-    either plain pools or ``(codes, scales)`` tuples (:func:`scan_layers` —
-    quantized KV mode). ``window``: optional per-layer sliding-window length
-    (int or traced scalar — exaone4 scans per-layer windows). Single-token
-    decode dispatches the paged flash-decode kernel, every multi-token call
+    q [b, t, nh, hd]; k/v [b, t, nkv, hd]. ``k_cache``/``v_cache`` are the
+    layer's :class:`LayerPool` entries (:func:`scan_layers`). ``positions``
+    are ``context_lens + arange(t)`` (the module's contract) and ``valid``
+    [b, t] is a prefix mask of each sequence's real rows: a padded row
+    writes nothing and its output is unspecified. ``window``: optional
+    per-layer sliding-window length (int or traced scalar — exaone4 scans
+    per-layer windows). The write is ``paged_kv_write`` (fill-time
+    quantization in the same call in quantized mode); single-token decode
+    then dispatches the paged flash-decode kernel, every multi-token call
     the paged flash-prefill kernel (each windowed or plain-causal, with the
-    dequant fused in quantized mode); ``valid`` [b, t] is a prefix mask of
-    each sequence's real rows, and a padded row's output is unspecified.
-    Returns (attn_out [b, t, nh, hd], k_cache, v_cache) with the cache
-    entries in the same representation they arrived in."""
-    b, t = q.shape[0], q.shape[1]
-    nkv, hd = k.shape[-2], k.shape[-1]
-    quant = isinstance(k_cache, tuple)
-    if quant:
-        k_codes, k_scales = k_cache
-        v_codes, v_scales = v_cache
-        bs = k_codes.shape[2]
-        group_size = hd // k_scales.shape[-1]
-    else:
-        bs = k_cache.shape[2]
-
-    with jax.named_scope("kv_write"):   # the pool update, by its own name
-        blk_idx = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-        blk_idx = jnp.where(valid, blk_idx, 0)
-        off = positions % bs
-        # advanced indices (blk_idx, off) straddle the kv-head slice, so the
-        # result dims land in front: [b, t, nkv, hd] — exactly k's layout
-        if quant:
-            # fill-time quantization fused into the cache-update: codes and
-            # the per-(token, head, group) scales scatter in the same program
-            qk, sk = kv_quantize_int8(k, group_size)
-            qv, sv = kv_quantize_int8(v, group_size)
-            k_codes = k_codes.at[blk_idx, :, off].set(qk)
-            v_codes = v_codes.at[blk_idx, :, off].set(qv)
-            k_scales = k_scales.at[blk_idx, :, off].set(sk)
-            v_scales = v_scales.at[blk_idx, :, off].set(sv)
-        else:
-            k_cache = k_cache.at[blk_idx, :, off].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[blk_idx, :, off].set(v.astype(v_cache.dtype))
-
+    dequant fused in quantized mode), all three on the layer's index into
+    the ``[L, ...]`` pools. Returns (attn_out [b, t, nh, hd], k_cache,
+    v_cache) with the written pools in the entries."""
+    del positions
     from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
     from ..ops.registry import get_op
 
-    # int8 pools reach the kernels as codes with their scale pools beside
-    # them, dequantized in-register
-    if quant:
-        pools = (k_codes, v_codes)
-        scales = {"k_scale": k_scales, "v_scale": v_scales}
-    else:
-        pools, scales = (k_cache, v_cache), {}
+    t = q.shape[1]
+    layer = k_cache.layer
+    # ``valid`` is a prefix mask, so its sum is each sequence's count of
+    # real rows
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    # int8 pools reach the ops as codes with their scales beside them:
+    # quantized on the way in, dequantized in-register on the way out. The
+    # ops get the LAYER's scale pools: a ``[.., bs, 1]`` f32 pool has no
+    # dense tiled layout (a Mosaic operand pads its last dim to 128 lanes),
+    # so the whole ``[L, ...]`` scale pool is never one - the layer's slice,
+    # 1/128 of its codes' bytes, is cut out and put back here
+    scales = {} if k_cache.scale is None else {
+        "k_scale": k_cache.scale[layer], "v_scale": v_cache.scale[layer]}
+    with jax.named_scope("kv_write"):   # the pool update, by its own name
+        k_pool, v_pool, *written = get_op("paged_kv_write")(
+            k, v, k_cache.pool, v_cache.pool, block_tables, context_lens,
+            n_valid, layer=layer, **scales)
+        scales = dict(zip(scales, written))
+        k_scale, v_scale = (
+            None if s is None else c.scale.at[layer].set(s)
+            for c, s in zip((k_cache, v_cache), written))
     if t == 1:
         out = get_op("paged_decode_attention")(
-            q[:, 0], *pools, block_tables, context_lens, window=window,
-            **scales)[:, None]
+            q[:, 0], k_pool, v_pool, block_tables, context_lens,
+            window=window, layer=layer, **scales)[:, None]
     else:
         # every multi-token call - a prefill chunk at a context offset, a
         # batched prefill, a prefix-cache suffix, a speculative verify
         # window - walks the block table over the live context in one flash
         # kernel. Off a TPU the op is the gathered XLA reference, as for
-        # every op. ``valid`` is a prefix mask, so its sum is each
-        # sequence's count of real rows.
-        n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+        # every op.
         out = get_op("paged_prefill_attention")(
-            q, *pools, block_tables, context_lens, n_valid, window=window,
-            **scales)
-    if quant:
-        return out, (k_codes, k_scales), (v_codes, v_scales)
-    return out, k_cache, v_cache
+            q, k_pool, v_pool, block_tables, context_lens, n_valid,
+            window=window, layer=layer, **scales)
+    return (out, LayerPool(k_pool, k_scale, layer),
+            LayerPool(v_pool, v_scale, layer))

@@ -1,6 +1,14 @@
 """Paged (blocked-KV) flash attention Pallas kernels: ``paged_decode`` for
 one query token a sequence, ``paged_prefill`` for more (prefill chunks,
-batched prefill, speculative verification).
+batched prefill, speculative verification) - and ``paged_kv_write``, which
+puts a step's K/V rows into the pool pages they belong to, in place.
+
+The pools are ``[num_layers, num_blocks, nkv, bs, hd]`` and stay where they
+are: all three kernels take the layer as a prefetched scalar and address
+pages ``(layer, block, ...)``, so no program slices a layer's pool out of
+the buffer or stacks one back (``models/_paged.scan_layers`` carries the
+buffer through the layer scan). A 4-D pool is one layer's and needs no
+layer; each operand says which it is by its rank.
 
 Reference parity: the inference v2 ragged kernels
 (``inference/v2/kernels/ragged_ops/`` — blocked flash attention over the
@@ -14,7 +22,7 @@ references (``*_xla``), which the registry resolves to off a TPU.
 
 Decode layout: one query token per sequence.
   q            [B, nh, hd]
-  k/v pool     [num_blocks, nkv, bs, hd]   (block 0 = trash block; kv-head
+  k/v pool     [(L,) num_blocks, nkv, bs, hd]   (block 0 = trash block; kv-head
                axis ahead of the token axis so one page of EVERY KV head is
                one contiguous ``(nkv, bs, hd)`` block with a ``(bs, hd)``
                tail — a squeezed dim in the last two positions is rejected
@@ -132,11 +140,33 @@ def _checked_window(window):
     return jnp.maximum(jnp.asarray(window, jnp.int32), 1)
 
 
-def _gathered_view(pool, block_tables):
-    """Dense [B, S, nkv, *] view of the pool rows the tables reference: the
-    XLA references' read of the pool (the kernels never build it)."""
+def _layer_scalar(layer, *pools):
+    """The ops' pool contract: a pool is ``[L, num_blocks, nkv, bs, *]`` and
+    read at ``layer`` (int or traced scalar), or ``[num_blocks, nkv, bs, *]``
+    and one layer's; each operand says which by its own rank. Returns the
+    layer as the ``[1]`` int32 the kernels prefetch."""
+    assert layer is not None or all(p is None or p.ndim == 4 for p in pools), \
+        "an [L, ...] pool needs the layer to read"
+    return jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
+
+
+def _page_spec(pool, heads, index_map):
+    """One page of ``heads`` KV heads (None: one, squeezed) of ``pool`` as a
+    block: ``index_map`` gives ``(layer, block, head block, 0, 0)``, and a
+    one-layer pool drops the layer."""
+    page = (None, heads) + pool.shape[-2:]
+    if pool.ndim == 5:
+        return pl.BlockSpec((None,) + page, index_map)
+    return pl.BlockSpec(page, lambda *a: index_map(*a)[1:])
+
+
+def _gathered_view(pool, block_tables, layer):
+    """Dense [B, S, nkv, *] view of the rows of layer ``layer`` the tables
+    reference: the XLA references' read of the pool (the kernels never build
+    it)."""
     b, max_blocks = block_tables.shape
-    g = pool[block_tables].swapaxes(2, 3)      # [b, mb, bs, nkv, *]
+    g = pool[block_tables] if pool.ndim == 4 else pool[layer[0], block_tables]
+    g = g.swapaxes(2, 3)                              # [b, mb, bs, nkv, *]
     return g.reshape((b, max_blocks * g.shape[2]) + g.shape[3:])
 
 
@@ -219,9 +249,9 @@ def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
     """q ``[.., rows, hd]`` against the KV tile ``[.., pages * bs, hd]`` of
     grid step ``j``; a leading axis is the KV heads of the step. ``n_kv``
     None: the grid's last dimension is dynamic."""
-    tables_ref, ctx_ref, len_ref = refs[:3]
-    wnd_ref = refs[3] if has_window else None
-    refs = refs[3 + int(has_window):]
+    ctx_ref, len_ref = refs[1], refs[2]    # after the tables, before the layer
+    wnd_ref = refs[4] if has_window else None
+    refs = refs[4 + int(has_window):]
     q_ref, refs = refs[0], refs[1:]
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     ks_refs, vs_refs = ((refs[2 * pages:3 * pages], refs[3 * pages:4 * pages])
@@ -267,11 +297,14 @@ def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
 
 
 def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
-                window, k_scale, v_scale, *, scale, rows, tq, pages, heads,
-                n_kv):
-    """The kernel, grid and arguments of one walk. ``qg`` ``[B, nkv, query
-    tiles * rows, hd]``; row r of tile qi is query token ``qi * tq + r % tq``
-    of its sequence. Grid ``(B, KV-head blocks, query tiles, KV tiles)``, KV
+                layer, window, k_scale, v_scale, *, scale, rows, tq, pages,
+                heads, n_kv):
+    """The kernel, grid and arguments of one walk over layer ``layer`` of the
+    ``[L, num_blocks, nkv, bs, hd]`` pools: the layer is one more prefetched
+    scalar and the first coordinate of every pool page, so the pools are read
+    where they lie and no layer's pool is ever sliced out. ``qg`` ``[B, nkv,
+    query tiles * rows, hd]``; row r of tile qi is query token ``qi * tq + r
+    % tq`` of its sequence. Grid ``(B, KV-head blocks, query tiles, KV tiles)``, KV
     innermost; ``heads`` None is one KV head a step with the head axis
     squeezed, ``n_kv`` may be traced. A KV tile is ``pages`` pool pages, each
     its own table-indexed ``BlockSpec`` over the same pool, joined in VMEM.
@@ -281,7 +314,7 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
     block, so HBM traffic is the live context per (KV-head block, query
     tile), with or without a window."""
     B, nkv, _, hd = qg.shape
-    nblocks, bs = k_pool.shape[0], k_pool.shape[2]
+    nblocks, bs = k_pool.shape[-4], k_pool.shape[-2]
     max_blocks = block_tables.shape[1]
     has_window, quant = window is not None, k_scale is not None
     static = isinstance(n_kv, int)
@@ -296,7 +329,7 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
         return (b, h, qi, 0)
 
     def page_map(p):
-        def kvmap(b, h, qi, j, tables, ctx, lens, *rest):
+        def kvmap(b, h, qi, j, tables, ctx, lens, layer, *rest):
             # the tile's live pages [lo_pg, hi_pg]; every other (tile, page)
             # folds onto the nearest of them
             last = ctx[b] + jnp.minimum(qi * tq + tq, lens[b]) - 1
@@ -306,25 +339,20 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
                 if rest else 0)
             j_eff = jnp.clip(j, lo_pg // pages, hi_pg // pages)
             pg = jnp.clip(j_eff * pages + p, lo_pg, hi_pg)
-            return (jnp.clip(tables[b, pg], 0, nblocks - 1), h, 0, 0)
+            return (layer[0], jnp.clip(tables[b, pg], 0, nblocks - 1), h,
+                    0, 0)
         return kvmap
 
-    def pool_specs(width):
-        # scale tiles ride the same maps as their code tiles, so a dead
-        # step elides both DMAs together
-        return [pl.BlockSpec((None, heads, bs, width), page_map(p))
-                for p in range(pages)]
-
-    in_specs = [pl.BlockSpec((None, heads, rows, hd), qmap)] \
-        + pool_specs(hd) + pool_specs(hd)
-    operands = [qg] + [k_pool] * pages + [v_pool] * pages
-    if quant:
-        ng = k_scale.shape[-1]
-        in_specs += pool_specs(ng) + pool_specs(ng)
-        operands += [k_scale] * pages + [v_scale] * pages
+    # scale tiles ride the same maps as their code tiles, so a dead step
+    # elides both DMAs together
+    pools = (k_pool, v_pool) + ((k_scale, v_scale) if quant else ())
+    in_specs = [pl.BlockSpec((None, heads, rows, hd), qmap)] + [
+        _page_spec(pool, heads, page_map(p))
+        for pool in pools for p in range(pages)]
+    operands = [qg] + [pool for pool in pools for _ in range(pages)]
     lead = () if heads is None else (heads,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3 + int(has_window),
+        num_scalar_prefetch=4 + int(has_window),
         grid=(B, nkv // (heads or 1), qg.shape[2] // rows, n_kv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, heads, rows, hd), qmap),
@@ -335,7 +363,8 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
         ],
     )
     prefetch = [block_tables.astype(jnp.int32),
-                context_lens.astype(jnp.int32), lengths.astype(jnp.int32)]
+                context_lens.astype(jnp.int32), lengths.astype(jnp.int32),
+                layer]
     if has_window:
         prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
     return kernel, grid_spec, prefetch + operands
@@ -349,8 +378,10 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            context_lens: jnp.ndarray, *,
                            scale: float = None,
                            window=None, k_scale=None,
-                           v_scale=None) -> jnp.ndarray:
-    """See module docstring. Returns [B, nh, hd]. ``window``: optional
+                           v_scale=None, layer=None) -> jnp.ndarray:
+    """See module docstring. Returns [B, nh, hd]. ``layer``: the layer of
+    ``[L, num_blocks, nkv, bs, hd]`` pools to attend over (int or traced
+    scalar - the layer scan's index); a 4-D pool is one layer's. ``window``: optional
     sliding-window length (int or traced scalar — exaone4 scans per-layer
     windows): only the last ``window`` positions are attended; tiles
     entirely outside the window skip their compute. ``k_scale``/``v_scale``:
@@ -358,11 +389,12 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     for int8 code pools — the quantized-KV mode with dequant fused into the
     flash loop (both or neither must be given)."""
     B, nh, hd = q.shape
-    nkv, bs = k_pool.shape[1:3]
-    g = nh // nkv
-    gpad = _group_rows(g)
     assert (k_scale is None) == (v_scale is None), \
         "k_scale and v_scale must be given together"
+    layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
+    nkv, bs = k_pool.shape[-3:-1]
+    g = nh // nkv
+    gpad = _group_rows(g)
     if window is not None:
         window = _checked_window(window)
     pages, heads, n_kv = _decode_tiles(
@@ -375,7 +407,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     n_live = jnp.clip(jnp.max(context_lens) // (pages * bs) + 1, 1, n_kv)
     kernel, grid_spec, args = _table_walk(
         qg, k_pool, v_pool, block_tables, context_lens,
-        jnp.ones((B,), jnp.int32), window, k_scale, v_scale,
+        jnp.ones((B,), jnp.int32), layer, window, k_scale, v_scale,
         scale=hd ** -0.5 if scale is None else scale, rows=gpad, tq=1,
         pages=pages, heads=heads, n_kv=n_live.astype(jnp.int32))
     out = pl.pallas_call(
@@ -393,7 +425,7 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
                                context_lens: jnp.ndarray, *,
                                scale: float = None,
                                window=None, k_scale=None,
-                               v_scale=None) -> jnp.ndarray:
+                               v_scale=None, layer=None) -> jnp.ndarray:
     """Dense-gather fallback with identical semantics (compiled XLA — the
     right choice off-TPU, where the Pallas path runs interpreted).
     ``k_scale``/``v_scale``: the quantized-KV reference path — int8 code
@@ -402,11 +434,12 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     from ..attention import attention_xla
 
     B, nh, hd = q.shape
-    _, nkv, bs, _ = k_pool.shape
+    layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
+    nkv, bs = k_pool.shape[-3:-1]
     max_blocks = block_tables.shape[1]
     S = max_blocks * bs
-    kg = _gathered_view(k_pool, block_tables)
-    vg = _gathered_view(v_pool, block_tables)
+    kg = _gathered_view(k_pool, block_tables, layer)
+    vg = _gathered_view(v_pool, block_tables, layer)
     if window is not None:
         window = _checked_window(window)
     if k_scale is not None and k_scale.shape[-1] == 1:
@@ -418,8 +451,8 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
         sc = hd ** -0.5 if scale is None else scale
         g = nh // nkv
         qg = q.reshape(B, nkv, g, hd).astype(jnp.float32)
-        ksg = _gathered_view(k_scale, block_tables)[..., 0]
-        vsg = _gathered_view(v_scale, block_tables)[..., 0]
+        ksg = _gathered_view(k_scale, block_tables, layer)[..., 0]
+        vsg = _gathered_view(v_scale, block_tables, layer)[..., 0]
         s = jnp.einsum("bngh,bsnh->bngs", qg, kg.astype(jnp.float32)) * sc
         s = s * ksg.transpose(0, 2, 1)[:, :, None, :]       # [B, nkv, g, S]
         kv_pos = jnp.arange(S)[None, None, None, :]
@@ -435,10 +468,10 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     if k_scale is not None:
         from ..quantization import kv_dequantize_int8
 
-        kg = kv_dequantize_int8(kg, _gathered_view(k_scale, block_tables),
-                                q.dtype)
-        vg = kv_dequantize_int8(vg, _gathered_view(v_scale, block_tables),
-                                q.dtype)
+        kg = kv_dequantize_int8(
+            kg, _gathered_view(k_scale, block_tables, layer), q.dtype)
+        vg = kv_dequantize_int8(
+            vg, _gathered_view(v_scale, block_tables, layer), q.dtype)
     kv_pos = jnp.arange(S)[None, None, None, :]
     cl = context_lens[:, None, None, None]
     mask = kv_pos <= cl
@@ -453,7 +486,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                             v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                             context_lens: jnp.ndarray, lengths=None, *,
                             scale: float = None, window=None, k_scale=None,
-                            v_scale=None) -> jnp.ndarray:
+                            v_scale=None, layer=None) -> jnp.ndarray:
     """Multi-token attention over the paged pools, flash over the block
     table: every ``t > 1`` call of ``models/_paged.paged_attention_step`` — a
     SplitFuse prefill chunk at a context offset, a batched prefill, a
@@ -468,17 +501,19 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     output is unspecified (callers discard it), and they neither extend the
     live range nor reach table entries past the sequence's blocks — a
     zero-length dummy row of a batched prefill computes nothing.
-    ``window``/``k_scale``/``v_scale`` as in :func:`paged_decode_attention`.
+    ``window``/``k_scale``/``v_scale``/``layer`` as in
+    :func:`paged_decode_attention`.
     Returns ``[B, t, nh, hd]``. The walk: :func:`_table_walk`, one KV head a
     grid step, query tiles of ``g * tq`` rows."""
     B, t, nh, hd = q.shape
-    nkv, bs = k_pool.shape[1:3]
+    assert (k_scale is None) == (v_scale is None), \
+        "k_scale and v_scale must be given together"
+    layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
+    nkv, bs = k_pool.shape[-3:-1]
     max_blocks = block_tables.shape[1]
     g = nh // nkv
     tq, n_qt, pages = _prefill_tiles(t, g, hd, bs, max_blocks)
     rows = g * tq
-    assert (k_scale is None) == (v_scale is None), \
-        "k_scale and v_scale must be given together"
     if window is not None:
         window = _checked_window(window)
     if lengths is None:
@@ -490,8 +525,9 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
         .reshape(B, nkv, n_qt * rows, hd)
     kernel, grid_spec, args = _table_walk(
-        qg, k_pool, v_pool, block_tables, context_lens, lengths, window,
-        k_scale, v_scale, scale=hd ** -0.5 if scale is None else scale,
+        qg, k_pool, v_pool, block_tables, context_lens, lengths, layer,
+        window, k_scale, v_scale,
+        scale=hd ** -0.5 if scale is None else scale,
         rows=rows, tq=tq, pages=pages, heads=None,
         n_kv=-(-max_blocks // pages))
     out = pl.pallas_call(
@@ -510,7 +546,8 @@ def paged_prefill_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
                                 block_tables: jnp.ndarray,
                                 context_lens: jnp.ndarray, lengths=None, *,
                                 scale: float = None, window=None,
-                                k_scale=None, v_scale=None) -> jnp.ndarray:
+                                k_scale=None, v_scale=None,
+                                layer=None) -> jnp.ndarray:
     """The reference with identical semantics on every real row: gather the
     table's whole width, mask, soft-max in f32 (the right choice off-TPU,
     where the Pallas path runs interpreted; what every multi-token paged
@@ -521,19 +558,159 @@ def paged_prefill_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
 
     del lengths
     t = q.shape[1]
-    kg = _gathered_view(k_pool, block_tables)
-    vg = _gathered_view(v_pool, block_tables)
+    layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
+    kg = _gathered_view(k_pool, block_tables, layer)
+    vg = _gathered_view(v_pool, block_tables, layer)
     if k_scale is not None:
-        kg = kv_dequantize_int8(kg, _gathered_view(k_scale, block_tables),
-                                q.dtype)
-        vg = kv_dequantize_int8(vg, _gathered_view(v_scale, block_tables),
-                                q.dtype)
+        kg = kv_dequantize_int8(
+            kg, _gathered_view(k_scale, block_tables, layer), q.dtype)
+        vg = kv_dequantize_int8(
+            vg, _gathered_view(v_scale, block_tables, layer), q.dtype)
     kv_pos = jnp.arange(kg.shape[1])[None, None, None, :]
     q_abs = (context_lens[:, None] + jnp.arange(t)[None, :])[:, None, :, None]
     mask = kv_pos <= q_abs
     if window is not None:
         mask = mask & (q_abs - kv_pos < _checked_window(window))
     return attention_xla(q, kg, vg, causal=False, mask=mask, scale=scale)
+
+
+# --------------------------------------------------------------------------- #
+# the write: a step's K/V rows into the pages they belong to, in place
+# --------------------------------------------------------------------------- #
+def _stored_rows(k, v, k_pool, k_scale):
+    """This step's rows as the pools store them, pool by pool: ``(k, v)`` in
+    the pool's dtype, or int8 codes and their fp32 per-(token, head, group)
+    scales ``(qk, qv, sk, sv)`` - fill-time quantisation, in the same step
+    as the write."""
+    if k_scale is None:
+        return k.astype(k_pool.dtype), v.astype(k_pool.dtype)
+    from ..quantization import kv_quantize_int8
+
+    group = k.shape[-1] // k_scale.shape[-1]
+    (qk, sk), (qv, sv) = kv_quantize_int8(k, group), kv_quantize_int8(v, group)
+    return qk, qv, sk, sv
+
+
+def _write_pages(t: int, bs: int) -> int:
+    """Pages a sequence's ``t`` rows can touch from any offset in a page."""
+    return 1 if t == 1 else -(-t // bs) + 1
+
+
+def _kv_write_kernel(*refs, bs, n):
+    """Overlay the step's rows on their page: slot ``o`` of the sequence's
+    ``j``-th touched page holds its row ``j * bs + o - ctx % bs``, where that
+    is one of its ``lengths`` real rows; every other slot keeps the pool's."""
+    ctx_ref, len_ref = refs[1], refs[2]    # after the tables, before the layer
+    rows, pages, outs = refs[4:4 + n], refs[4 + n:4 + 2 * n], refs[4 + 2 * n:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    r = j * bs - ctx_ref[b] % bs \
+        + jax.lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1)
+    mine = jnp.logical_and(r >= 0, r < len_ref[b])
+    for row, page, out in zip(rows, pages, outs):
+        out[...] = jnp.where(mine, row[...], page[...])
+
+
+def paged_kv_write(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
+                   v_pool: jnp.ndarray, block_tables: jnp.ndarray,
+                   context_lens: jnp.ndarray, lengths: jnp.ndarray, *,
+                   layer=None, k_scale=None, v_scale=None) -> Tuple:
+    """Write a step's K/V ``[B, t, nkv, hd]`` into layer ``layer`` of the
+    ``[L, num_blocks, nkv, bs, hd]`` pools IN PLACE: row ti of sequence b
+    goes to position ``context_lens[b] + ti`` of its block table, for its
+    first ``lengths[b]`` rows; a padded row and a zero-length dummy sequence
+    write nothing. With ``k_scale``/``v_scale`` the pools hold int8 codes and
+    the same call writes codes and scales. Returns ``(k_pool, v_pool,
+    k_scale, v_scale)``.
+
+    One Mosaic call whose pools are aliased to its results, grid (sequence,
+    page the step can touch): a grid step reads one page of every KV head
+    through the block table, overlays the rows that fall on it and writes
+    it back, so the call moves the pages a step touches (9 for a 256-token
+    chunk, one a sequence for a decode) and nothing else of the pool. The
+    step's rows reach it page-aligned - a gather over the step's own rows
+    in XLA - so no slice in the kernel is unaligned. A grid step past its
+    sequence's last touched page aims at block 0 with nothing to overlay,
+    and rewrites what it read. No two grid steps of a call write one live
+    page (copy-on-write keeps two sequences off one page; a sequence's
+    pages are distinct table entries), so a page fetched ahead of an
+    earlier step's write-back is never one that step writes; the grid runs
+    in order."""
+    B, t = k.shape[:2]
+    layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
+    nblocks, nkv, bs = k_pool.shape[-4:-1]
+    max_blocks = block_tables.shape[1]
+    n_pages = _write_pages(t, bs)
+    pools = [k_pool, v_pool] + ([] if k_scale is None else [k_scale, v_scale])
+
+    # slot o of page j <- row j * bs + o - ctx % bs (clipped: the kernel's
+    # mask drops what is not the sequence's own)
+    src = jnp.clip(jnp.arange(n_pages * bs)[None, :]
+                   - (context_lens % bs)[:, None], 0, t - 1)
+
+    def page_aligned(x):                # [B, t, nkv, w] -> [B, j, nkv, bs, w]
+        x = jnp.take_along_axis(x, src[:, :, None, None], axis=1)
+        return x.reshape(B, n_pages, bs, nkv, -1).swapaxes(2, 3)
+
+    def row_map(b, j, *_):              # the rows' page j of sequence b
+        return (b, j, 0, 0, 0)
+
+    rows = [page_aligned(r) for r in _stored_rows(k, v, k_pool, k_scale)]
+
+    def page_map(b, j, tables, ctx, lens, layer):
+        pg = ctx[b] // bs + j
+        live = jnp.logical_and(
+            lens[b] > 0, pg <= jnp.minimum((ctx[b] + lens[b] - 1) // bs,
+                                           max_blocks - 1))
+        blk = jnp.where(live, tables[b, jnp.minimum(pg, max_blocks - 1)], 0)
+        return (layer[0], jnp.clip(blk, 0, nblocks - 1), 0, 0, 0)
+
+    def specs(index_map):
+        return [_page_spec(p, nkv, index_map) for p in pools]
+
+    outs = pl.pallas_call(
+        functools.partial(_kv_write_kernel, bs=bs, n=len(pools)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B, n_pages),
+            in_specs=[_page_spec(r, nkv, row_map) for r in rows]
+            + specs(page_map),
+            out_specs=specs(page_map)),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # operands: 4 prefetched scalars, the rows, then the pools
+        input_output_aliases={4 + len(pools) + i: i
+                              for i in range(len(pools))},
+        compiler_params=_dim_semantics("arbitrary", "arbitrary"),
+        interpret=_interpret(),
+        name="paged_kv_write",
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      lengths.astype(jnp.int32), layer, *rows, *pools)
+    return tuple(outs) + (None,) * (4 - len(outs))
+
+
+def paged_kv_write_xla(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
+                       v_pool: jnp.ndarray, block_tables: jnp.ndarray,
+                       context_lens: jnp.ndarray, lengths: jnp.ndarray, *,
+                       layer=None, k_scale=None, v_scale=None) -> Tuple:
+    """The reference with identical semantics: one scatter a pool, on the
+    layer's index (off a TPU nothing has a layout to disagree with; on one
+    the scatter's layout preference copies the whole pool - PERF.md
+    Findings, PR 29). Rows past ``lengths`` are dropped."""
+    t = k.shape[1]
+    layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
+    nblocks, bs = k_pool.shape[-4], k_pool.shape[-2]
+    positions = context_lens[:, None] + jnp.arange(t)[None, :]
+    blk = jnp.take_along_axis(
+        block_tables, jnp.minimum(positions // bs, block_tables.shape[1] - 1),
+        axis=1)
+    blk = jnp.where(jnp.arange(t)[None, :] < lengths[:, None], blk, nblocks)
+    # advanced indices (layer, blk, off) straddle the kv-head slice, so the
+    # result dims land in front: [b, t, nkv, *] - exactly the rows' layout
+    off = positions % bs
+    outs = tuple(
+        (p.at[blk, :, off] if p.ndim == 4 else p.at[layer[0], blk, :, off])
+        .set(r, mode="drop")
+        for p, r in zip((k_pool, v_pool, k_scale, v_scale),
+                        _stored_rows(k, v, k_pool, k_scale)))
+    return outs + (None,) * (4 - len(outs))
 
 
 # speculative verification is the same computation at t = 1 + draft tokens:
@@ -549,3 +726,5 @@ register("paged_decode_attention", backend="xla")(paged_decode_attention_xla)
 for _name in ("paged_prefill_attention", "paged_spec_verify_attention"):
     register(_name, backend="pallas")(paged_prefill_attention)
     register(_name, backend="xla")(paged_prefill_attention_xla)
+register("paged_kv_write", backend="pallas")(paged_kv_write)
+register("paged_kv_write", backend="xla")(paged_kv_write_xla)

@@ -25,7 +25,10 @@ the flops went). This module answers both:
   program holds at its fullest point
   (``Compile/<program>/peak_memory_bytes``): the headline MFU decomposes into
   ``Train/mfu/<program>`` and ``Serving/mfu/<program>`` gauges (prefill vs
-  decode vs train-step) instead of one ThroughputTimer number.
+  decode vs train-step) instead of one ThroughputTimer number. A program
+  registered with its KV ``pools`` also says what it moves to re-house them
+  (``pool_copy_bytes``: 0 for one that writes them where they lie) and what
+  it aliases argument-to-result (``aliased_bytes``).
 
 Event names (``Compile/<program>/<metric>``, ``Compile/total/*``,
 ``<group>/mfu/<program>``) are registered in ``telemetry/schema.py``;
@@ -34,6 +37,7 @@ Event names (``Compile/<program>/<metric>``, ``Compile/total/*``,
 
 from __future__ import annotations
 
+import math
 import re
 import threading
 import time
@@ -47,7 +51,8 @@ from ..utils.peaks import UnknownDevice, device_peaks
 from .trace import NULL_TRACER
 
 __all__ = ["CompileMonitorConfig", "CompileMonitor", "MonitoredFunction",
-           "ProgramStats", "RecompileBudgetExceeded", "peak_flops_total"]
+           "ProgramStats", "RecompileBudgetExceeded", "peak_flops_total",
+           "pool_copy_bytes"]
 
 Event = Tuple[str, float, int]
 
@@ -96,6 +101,8 @@ class ProgramStats:
     cost_flops: float = 0.0         # per-call flops (last compile's analysis)
     cost_bytes: float = 0.0         # per-call bytes accessed (last compile)
     peak_memory_bytes: int = 0      # largest compiled signature's device peak
+    pool_copy_bytes: int = 0        # last compile: pool-shaped copies (``pools``)
+    aliased_bytes: int = 0          # last compile: arguments aliased to results
     calls_since_drain: int = 0      # executions since the last events() drain
     signatures: List[Any] = field(default_factory=list)
 
@@ -184,6 +191,51 @@ def _peak_memory_bytes(compiled) -> int:
         return 0
 
 
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+              "u64": 8}
+# ``%name = dtype[dims]{layout} opcode(``: an instruction with one array result
+_HLO_ARRAY_OP = re.compile(
+    r"^\s*(?:ROOT\s+)?%\S+ = (\w+)\[([\d,]+)\]\S* ([\w-]+)\(")
+
+
+def pool_copy_bytes(hlo_text: str, pools) -> int:
+    """Bytes of a compiled program's results that re-house a stacked pool:
+    every ``copy``, ``dynamic-slice``, ``dynamic-update-slice``, loop fusion
+    or ``AllocateBuffer`` whose result has the shape of one of ``pools``
+    (``[L, ...]`` arrays or shapes) or of one layer of it (``[...]``,
+    ``[1, ...]``). A program that writes its pools where they lie has none:
+    0 is the serving programs' target (models/_paged.scan_layers)."""
+    dims = set()
+    for pool in pools:
+        shape = tuple(getattr(pool, "shape", pool))
+        dims |= {shape, shape[1:], (1,) + shape[1:]}
+    total = 0
+    for line in hlo_text.splitlines():
+        m = _HLO_ARRAY_OP.match(line)
+        if m is None:
+            continue
+        dtype, shape, opcode = m.groups()
+        shape = tuple(map(int, shape.split(",")))
+        if shape not in dims or not (
+                opcode in ("copy", "dynamic-slice", "dynamic-update-slice")
+                or opcode == "fusion" and "kind=kLoop" in line
+                or opcode == "custom-call" and '"AllocateBuffer"' in line):
+            continue
+        total += _HLO_BYTES.get(dtype, 4) * math.prod(shape)
+    return total
+
+
+def _aliased_bytes(compiled) -> int:
+    """Bytes of arguments the compiled program's results live in (donated
+    and taken up: ``memory_analysis().alias_size_in_bytes``); 0 where the
+    backend gives none."""
+    try:
+        return max(0, int(compiled.memory_analysis().alias_size_in_bytes))
+    except Exception:
+        return 0
+
+
 class MonitoredFunction:
     """A jitted entry point dispatching through the monitor's own
     signature → compiled-program cache. A signature miss runs the explicit
@@ -193,11 +245,12 @@ class MonitoredFunction:
     underlying ``jax.jit`` object so AOT consumers keep working."""
 
     def __init__(self, monitor: "CompileMonitor", name: str, jitted,
-                 group: str):
+                 group: str, pools=()):
         self._monitor = monitor
         self._name = name
         self._jitted = jitted
         self._group = group
+        self._pools = tuple(pools)
         self._compiled: Dict[Tuple, Any] = {}
         self._fallback = False
 
@@ -246,7 +299,8 @@ class MonitoredFunction:
             # proceed
             self._monitor._record_compile(
                 self._name, self._group, sig, lower_ms=(t1 - t0) * 1e3,
-                compile_ms=(t2 - t1) * 1e3, compiled=compiled, span=span)
+                compile_ms=(t2 - t1) * 1e3, compiled=compiled, span=span,
+                pools=self._pools)
         return compiled(*args, **kwargs)
 
     def _degrade(self, why: str) -> None:
@@ -285,17 +339,20 @@ class CompileMonitor:
         self._dispatch_t0: Dict[str, float] = {}
 
     # ------------------------------------------------------------------ #
-    def jit(self, name: str, fn: Callable, group: str = "Train",
+    def jit(self, name: str, fn: Callable, group: str = "Train", pools=(),
             **jit_kwargs):
         """The shared registration helper: ``jax.jit(fn, **jit_kwargs)``,
         wrapped for monitoring when enabled. Disabled → the exact jit object
-        (default program byte-identical)."""
+        (default program byte-identical). ``pools``: the stacked ``[L, ...]``
+        KV pools (arrays or shapes) the program is meant to write in place;
+        each compile then records :func:`pool_copy_bytes` over them and the
+        bytes aliased argument-to-result."""
         jitted = jax.jit(fn, **jit_kwargs)
         if not self.enabled:
             return jitted
-        return self.wrap(name, jitted, group=group)
+        return self.wrap(name, jitted, group=group, pools=pools)
 
-    def wrap(self, name: str, jitted, group: str = "Train"):
+    def wrap(self, name: str, jitted, group: str = "Train", pools=()):
         """Wrap an already-jitted callable (for call sites that need jit
         options the helper doesn't forward)."""
         if not self.enabled:
@@ -303,7 +360,7 @@ class CompileMonitor:
         name = _NAME_SANITIZE.sub("_", name).lower() or "program"
         with self._lock:
             self.stats.setdefault(name, ProgramStats(name=name, group=group))
-        return MonitoredFunction(self, name, jitted, group)
+        return MonitoredFunction(self, name, jitted, group, pools)
 
     # ------------------------------------------------------------------ #
     def _record_hit(self, name: str) -> None:
@@ -314,11 +371,16 @@ class CompileMonitor:
             self._dispatch_t0.setdefault(st.group, time.monotonic())
 
     def _record_compile(self, name: str, group: str, sig, lower_ms: float,
-                        compile_ms: float, compiled, span) -> None:
+                        compile_ms: float, compiled, span, pools=()) -> None:
         flops = bytes_ = 0.0
         if self.cost_analysis:
             flops, bytes_ = _cost_analysis(compiled)
         peak = _peak_memory_bytes(compiled)
+        pool_attrs = {}
+        if pools:
+            pool_attrs = {
+                "pool_copy_bytes": pool_copy_bytes(compiled.as_text(), pools),
+                "aliased_bytes": _aliased_bytes(compiled)}
         with self._lock:
             st = self.stats[name]
             recompile = len(st.signatures) >= 1
@@ -334,6 +396,8 @@ class CompileMonitor:
             if bytes_ > 0:
                 st.cost_bytes = bytes_
             st.peak_memory_bytes = max(st.peak_memory_bytes, peak)
+            for key, value in pool_attrs.items():
+                setattr(st, key, value)
             if unexpected:
                 self.unexpected_recompiles += 1
             over = (self.recompile_budget > 0 and not self._budget_tripped
@@ -344,7 +408,7 @@ class CompileMonitor:
             # marks the start of the group's executed window
             self._dispatch_t0.setdefault(group, time.monotonic())
         span.set(lower_ms=round(lower_ms, 3), compile_ms=round(compile_ms, 3),
-                 recompile=recompile)
+                 recompile=recompile, **pool_attrs)
         if recompile:
             logger.warning(
                 f"recompilation detected: program '{name}' compiled a new "
@@ -374,6 +438,8 @@ class CompileMonitor:
                         "cost_flops": st.cost_flops,
                         "cost_bytes": st.cost_bytes,
                         "peak_memory_bytes": st.peak_memory_bytes,
+                        "pool_copy_bytes": st.pool_copy_bytes,
+                        "aliased_bytes": st.aliased_bytes,
                         "signatures": len(st.signatures)}
                     for n, st in self.stats.items()}
 
@@ -439,6 +505,12 @@ class CompileMonitor:
                 if st.peak_memory_bytes > 0:
                     events.append((f"Compile/{name}/peak_memory_bytes",
                                    float(st.peak_memory_bytes), step))
+                if st.aliased_bytes > 0:    # a program that was given pools
+                    events += [
+                        (f"Compile/{name}/pool_copy_bytes",
+                         float(st.pool_copy_bytes), step),
+                        (f"Compile/{name}/aliased_bytes",
+                         float(st.aliased_bytes), step)]
                 if peak_total and st.cost_flops > 0 \
                         and st.calls_since_drain > 0:
                     mfu = (st.cost_flops * st.calls_since_drain

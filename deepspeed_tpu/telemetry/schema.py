@@ -187,7 +187,8 @@ COMM_RING_SERIES = frozenset(
 # CLOSED metric set; the Compile/total/* rollup family is fully enumerated.
 COMPILE_METRICS = frozenset((
     "compiles", "cache_hits", "recompiles", "lower_ms", "compile_ms",
-    "cost_flops", "cost_bytes", "peak_memory_bytes"))
+    "cost_flops", "cost_bytes", "peak_memory_bytes", "pool_copy_bytes",
+    "aliased_bytes"))
 COMPILE_TOTAL_SERIES = frozenset(
     "Compile/total/" + m for m in (
         "programs", "compiles", "cache_hits", "recompiles", "lower_ms",

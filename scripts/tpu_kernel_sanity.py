@@ -121,6 +121,34 @@ def main():
 
     check("paged_decode_serving_pool", paged_serving)
 
+    # the in-place pool write and the layer-indexed reads over [L, ...] pools
+    # at a serving geometry: the write is a copy, so it must match its
+    # scatter reference bit for bit, a chunk from mid page and a decode batch
+    def paged_kv_write_layered():
+        from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+        L, nblocks, nkv, bs, hd, mb = 3, 896, 8, 32, 128, 64
+        kp = randn(L, nblocks, nkv, bs, hd).astype(jnp.bfloat16)
+        vp = randn(L, nblocks, nkv, bs, hd).astype(jnp.bfloat16)
+        for b, t, ctx in ((1, 256, [1013]), (8, 1, list(range(40, 840, 100)))):
+            k = randn(b, t, nkv, hd).astype(jnp.bfloat16)
+            v = randn(b, t, nkv, hd).astype(jnp.bfloat16)
+            bt = jnp.asarray(rs.permutation(np.arange(1, nblocks))[:b * mb]
+                             .reshape(b, mb).astype(np.int32))
+            ctx = jnp.asarray(ctx, jnp.int32)
+            lens = jnp.full((b,), t, jnp.int32)
+            got = pa.paged_kv_write(k, v, kp, vp, bt, ctx, lens, layer=1)
+            want = pa.paged_kv_write_xla(k, v, kp, vp, bt, ctx, lens, layer=1)
+            assert bool(jnp.array_equal(got[0], want[0])) \
+                and bool(jnp.array_equal(got[1], want[1])), f"write b={b}"
+            q = randn(b, 32, hd).astype(jnp.bfloat16)
+            diff_ok(pa.paged_decode_attention(q, got[0], got[1], bt, ctx,
+                                              layer=1),
+                    pa.paged_decode_attention_xla(q, got[0], got[1], bt, ctx,
+                                                  layer=1), 0.05)
+
+    check("paged_kv_write_layered_pools", paged_kv_write_layered)
+
     # compact MoE dispatch parity ON CHIP at true-f32 matmul precision —
     # round-4's 1.1e-2 divergence (bench_runs/MOE_20260731T034754Z.json)
     # was captured before the 06:54Z compact-gating rewrite; this pins the

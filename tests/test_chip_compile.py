@@ -172,16 +172,17 @@ CELL_BS, CELL_TABLE, CELL_POOL = 32, 256, 896
 
 def _prefill_step(quant, window=None):
     """The multi-token branch of the model step as ``chunk_prefill`` traces
-    it: each sequence's K/V scattered into the pool, then the op. A
+    it: each sequence's K/V written into the pool, then the op. A
     ``"traced"`` window is the step's last argument."""
-    from deepspeed_tpu.models._paged import paged_attention_step
+    from deepspeed_tpu.models._paged import LayerPool, paged_attention_step
 
     def step(q, k, v, kp, vp, table, ctx, n_valid, *rest):
         t = q.shape[1]
         positions = ctx[:, None] + jnp.arange(t)[None, :]
         valid = jnp.arange(t)[None, :] < n_valid[:, None]
-        if quant:
-            kp, vp = (kp, rest[0]), (vp, rest[1])
+        # the pools of a one-layer model, at its layer
+        kp, vp = (LayerPool(p[None], rest[i][None] if quant else None,
+                            jnp.int32(0)) for i, p in enumerate((kp, vp)))
         return paged_attention_step(
             q, k, v, kp, vp, table, ctx, positions, valid,
             window=rest[-1] if window == "traced" else window)[0]
@@ -259,6 +260,153 @@ def test_decode_grid_is_sized_by_the_shapes(cell):
     assert slots * (nkv // heads) * n_kv == steps
 
 
+# --- the pools stay where they are (ISSUE 29) ------------------------------ #
+SERVE_CELLS = ("mistral-7b.serve-chat", "mixtral-8x7b.serve-longprompt",
+               "olmoe-1b-7b.serve-longprompt")
+POOL_DEPTH = 2      # the layer scan makes the program the same at any depth
+
+
+def _cell_forward(cell_name, quant):
+    """A serve cell's paged forward on shapes, at its published widths and
+    its pool geometry (``benchmark/configs``), ``POOL_DEPTH`` layers deep:
+    ``(forward(params, cache, tokens, tables, ctx, valid) -> (logits,
+    cache), params, cache, slots, chunk, table width)``."""
+    from benchmark.harness.manifest import Cell
+
+    cell = Cell(cell_name)
+    engine = cell.role["engine"]
+    ragged = engine["ragged"]
+    cfg = cell.family.build_cfg(
+        {**cell.model, "num_hidden_layers": POOL_DEPTH},
+        **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.eval_shape(lambda k: module.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16), params)
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, ragged["memory_config_blocks"], ragged["block_size"],
+        **({"kv_quant_group": cfg.head_size} if quant else {})))
+
+    def forward(params, cache, tokens, tables, ctx, valid):
+        return module.apply_paged(cfg, params, tokens, cache, tables, ctx,
+                                  valid=valid)
+
+    return (forward, params, cache, ragged["max_tracked_sequences"],
+            engine["split_prefill_chunk"],
+            cfg.max_seq_len // ragged["block_size"])
+
+
+def _pool_program(cell_name, program, quant):
+    """``(function, arguments)`` of ``program`` over the cell's pools, the
+    cache its second argument: one chunk of one sequence, one decode tick of
+    every slot, or ``decode_many``'s scan of ticks around the layer scan
+    (greedy, as ``engine_v2._decode_fn`` nests it)."""
+    forward, params, cache, slots, chunk, table = _cell_forward(cell_name,
+                                                                quant)
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    b, t = (1, chunk) if program == "chunk_prefill" else (slots, 1)
+    args = (params, cache, s((b, t), i32), s((b, table), i32), s((b,), i32),
+            s((b, t), bool))
+    if program != "decode_many":
+        return forward, args
+
+    def decode_many(params, cache, tokens, tables, ctx, valid):
+        def tick(carry, _):
+            tokens, ctx, cache = carry
+            logits, cache = forward(params, cache, tokens, tables, ctx, valid)
+            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(i32)
+            return (nxt[:, None], ctx + 1, cache), nxt
+
+        (_, _, cache), toks = jax.lax.scan(tick, (tokens, ctx, cache), None,
+                                           length=4)
+        return toks, cache
+
+    return decode_many, args
+
+
+POOL_PROGRAMS = [(cell, program, pool) for cell in SERVE_CELLS
+                 for program in ("chunk_prefill", "decode")
+                 for pool in ("bf16", "int8")] \
+    + [(cell, "decode_many", "bf16") for cell in SERVE_CELLS]
+
+
+@pytest.mark.parametrize("cell,program,pool", POOL_PROGRAMS)
+def test_the_pools_stay_where_they_are(v5e, cell, program, pool):
+    """The KV pools are ONE ``[L, ...]`` buffer from a program's donated
+    argument to its result: the compiled program holds no copy, slice,
+    update, buffer or loop fusion the size of a pool or of a layer's pool
+    (``pool_copy_bytes``, the compile span's counter), aliases the pools
+    argument-to-result, keeps less than a layer's pool of temporaries, and
+    its layer body is one ``paged_kv_write`` and one attention kernel. In
+    int8 mode that holds for the code pools; a layer's ``[.., bs, 1]`` f32
+    scale pool still goes to the kernels lane-padded (PERF.md section 7)."""
+    import math
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = _pool_program(cell, program, pool == "int8")
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cache = args[1]
+
+    def nbytes(a):
+        return math.prod(a.shape) * a.dtype.itemsize
+
+    assert pool_copy_bytes(text, [cache["k"], cache["v"]]) == 0
+    assert mem.alias_size_in_bytes >= sum(map(nbytes, jax.tree.leaves(cache)))
+    layer_pool = nbytes(cache["k"]) // POOL_DEPTH
+    if pool == "bf16":
+        assert pool_copy_bytes(text, jax.tree.leaves(cache)) == 0
+        # (the scan of ticks keeps the model's own temporaries twice)
+        assert mem.temp_size_in_bytes < layer_pool * (
+            2 if program == "decode_many" else 1)
+    else:   # two layer scale pools, their last dim of 1 padded to 128 lanes
+        padded = 2 * 128 * nbytes(cache["k_scale"]) // POOL_DEPTH
+        assert mem.temp_size_in_bytes < padded + layer_pool
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    attn = "paged_prefill" if program == "chunk_prefill" else "paged_decode"
+    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
+
+
+def test_pool_copy_bytes_counts_what_the_scanned_pools_cost(v5e):
+    """The counter sees the traffic this PR removed: a layer scan that takes
+    the stacked pool as a scanned input and stacks the written slices back
+    (the parent's ``scan_layers``) holds pool-shaped copies; a scatter on a
+    carried pool holds whole-pool copies in the loop."""
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    L, blocks, nkv, bs, hd = 2, 896, 8, 32, 128
+
+    def scanned(pool, rows, blk, off):
+        def body(_, pool_l):
+            return None, pool_l.at[blk, :, off].set(rows)
+
+        return jax.lax.scan(body, None, pool)[1]
+
+    def carried(pool, rows, blk, off):
+        def body(pool, layer):
+            return pool.at[layer, blk, :, off].set(rows), None
+
+        return jax.lax.scan(body, pool, jnp.arange(L))[0]
+
+    shapes = (((L, blocks, nkv, bs, hd), jnp.bfloat16),
+              ((16, nkv, hd), jnp.bfloat16), ((16,), jnp.int32),
+              ((16,), jnp.int32))
+    for fn in (scanned, carried):
+        sh = SingleDeviceSharding(v5e.devices[0])
+        args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
+        text = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile() \
+            .as_text()
+        assert pool_copy_bytes(text, [shapes[0][0]]) \
+            >= blocks * nkv * bs * hd * 2, fn.__name__
+
+
 # --- the ``t > 1`` programs are the parent's ------------------------------- #
 # b, t, query heads, KV heads, head size, block, pool blocks, table width,
 # int8 pools (scale groups), window
@@ -270,15 +418,17 @@ MULTI_TOKEN_PROGRAMS = {
                                          "traced"),
     "batched_prefill_mqa": (4, 40, 8, 1, 64, 16, 64, 20, 0, None),
 }
-# sha256 of each program's jaxpr (kernel body included) at the commit before
-# ISSUE 27 (345a122). A PR that means to change the multi-token walk
-# replaces these; one that does not has changed it by accident.
+# sha256 of each program's jaxpr (kernel body included): ISSUE 29's, re-taken
+# on its finished tree (the step writes through ``paged_kv_write`` and the
+# walk takes the layer as a prefetched scalar; before it they were 345a122's).
+# A PR that means to change the multi-token walk replaces these; one that does
+# not has changed it by accident.
 PARENT_HASHES = {
-    "mistral_chunk256_bf16": "38679a03dd93c2bc",
-    "mistral_prompt2816_int8": "48082b0c424d871e",
-    "olmoe_chunk256_window": "e84cd41a6fc05051",
-    "verify_t5_traced_window_int8_ng2": "9045530ab6353880",
-    "batched_prefill_mqa": "ad0bfe99b61b2ca8",
+    "mistral_chunk256_bf16": "cd29e389fa1aee2e",
+    "mistral_prompt2816_int8": "d6fdfb6459bdf7d6",
+    "olmoe_chunk256_window": "796ce8ac594e33be",
+    "verify_t5_traced_window_int8_ng2": "bee098fcb8a6c2a6",
+    "batched_prefill_mqa": "825ccdf392f3a9e8",
 }
 
 

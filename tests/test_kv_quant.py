@@ -140,6 +140,77 @@ def test_quant_kernel_matches_xla_fallback(ng, window):
                                rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("op", ["decode", "prefill"])
+def test_quant_kernels_read_the_layer_they_are_given(op):
+    """int8 ``[L, ...]`` code pools with the LAYER's scale pools beside them
+    (what ``paged_attention_step`` hands the ops): kernel and reference
+    agree at every layer, and the layers differ."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    rng = np.random.default_rng(2)
+    L, nb, nkv, bs, hd, B, nh, mb, t = 3, 12, 2, 16, 64, 2, 4, 5, 4
+    kp = jnp.asarray(rng.integers(-127, 128, (L, nb, nkv, bs, hd)), jnp.int8)
+    vp = jnp.asarray(rng.integers(-127, 128, (L, nb, nkv, bs, hd)), jnp.int8)
+    ks = jnp.asarray(rng.random((L, nb, nkv, bs, 2)) * 0.02, jnp.float32)
+    vs = jnp.asarray(rng.random((L, nb, nkv, bs, 2)) * 0.02, jnp.float32)
+    bt = jnp.asarray(rng.integers(1, nb, (B, mb)), jnp.int32)
+    cl = jnp.asarray([13, 37], jnp.int32)
+    if op == "decode":
+        q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+        kernel, ref, args = (pa.paged_decode_attention,
+                             pa.paged_decode_attention_xla, (bt, cl))
+    else:
+        q = jnp.asarray(rng.standard_normal((B, t, nh, hd)), jnp.float32)
+        kernel, ref, args = (pa.paged_prefill_attention,
+                             pa.paged_prefill_attention_xla, (bt, cl))
+    outs = []
+    for layer in range(L):
+        kw = dict(k_scale=ks[layer], v_scale=vs[layer], layer=layer)
+        got = np.asarray(kernel(q, kp, vp, *args, **kw))
+        np.testing.assert_allclose(
+            got, np.asarray(ref(q, kp, vp, *args, **kw)),
+            rtol=2e-5, atol=2e-5)
+        outs.append(got)
+    assert np.abs(outs[0] - outs[1]).max() > 1e-3
+    assert np.abs(outs[1] - outs[2]).max() > 1e-3
+
+
+@pytest.mark.parametrize("ng", [1, 2])
+def test_quant_kv_write_writes_codes_and_scales_in_one_call(ng):
+    """Fill-time quantisation stays in the write: ``paged_kv_write`` on int8
+    ``[L, ...]`` code pools and the layer's scale pools equals the scatter
+    reference bit for bit, for a chunk that starts and ends mid-page beside
+    a dummy sequence; the written rows dequantize to the step's K/V within
+    the quantiser's bound and no other slot moves."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.quantization import kv_dequantize_int8
+
+    rng = np.random.default_rng(4)
+    L, nb, nkv, bs, hd, t = 3, 12, 2, 16, 64, 21
+    kp = jnp.asarray(rng.integers(-127, 128, (L, nb, nkv, bs, hd)), jnp.int8)
+    vp = jnp.asarray(rng.integers(-127, 128, (L, nb, nkv, bs, hd)), jnp.int8)
+    ks = jnp.asarray(rng.random((nb, nkv, bs, ng)) * 0.02, jnp.float32)
+    vs = jnp.asarray(rng.random((nb, nkv, bs, ng)) * 0.02, jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, t, nkv, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, t, nkv, hd)), jnp.float32)
+    bt = jnp.asarray(rng.permutation(np.arange(1, nb))[:8].reshape(2, 4),
+                     jnp.int32)
+    ctx, lens = jnp.asarray([11, 0], jnp.int32), jnp.asarray([t, 0], jnp.int32)
+    kw = dict(layer=2, k_scale=ks, v_scale=vs)
+    got = pa.paged_kv_write(k, v, kp, vp, bt, ctx, lens, **kw)
+    want = pa.paged_kv_write_xla(k, v, kp, vp, bt, ctx, lens, **kw)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    pos = 11 + np.arange(t)
+    blocks = np.asarray(bt)[0, pos // bs]
+    back = kv_dequantize_int8(got[0][2, blocks, :, pos % bs],
+                              got[2][blocks, :, pos % bs], jnp.float32)
+    assert np.abs(np.asarray(back) - np.asarray(k[0])).max() < 0.05
+    changed = np.any(np.asarray(got[0]) != np.asarray(kp), axis=(2, 4))
+    assert changed.sum() <= t and not changed[:2].any()
+    assert not np.any(np.asarray(got[2])[0] != np.asarray(ks)[0])
+
+
 def test_quant_scales_required_together():
     from deepspeed_tpu.ops.pallas.paged_attention import \
         paged_decode_attention

@@ -244,24 +244,26 @@ def program_hashes():
             for program, text in program_texts(*make()).items()}
 
 
-# ``program_hashes()`` of the commit before ISSUE 26 (45ecee2), taken under
-# this directory's conftest. A PR that means to change one of these programs
-# replaces its line; one that does not has changed it by accident.
+# ``program_hashes()`` under this directory's conftest: the dense and the
+# training programs are those of the commit before ISSUE 26 (45ecee2); the six
+# ``apply_paged`` lines are ISSUE 29's, re-taken on its finished tree (the
+# pools became the layer scan's carry). A PR that means to change one of these
+# programs replaces its line; one that does not has changed it by accident.
 PARENT_HASHES = {
     "mistral.apply": "18726a070296e819",
     "mistral.apply_cached": "26908a8864698d2d",
-    "mistral.apply_paged": "00ae6224f9bec2ca",
-    "mistral.apply_paged_decode": "02444e35bd31bd31",
+    "mistral.apply_paged": "75f76ae051c1e560",
+    "mistral.apply_paged_decode": "ffe8c14146072ee0",
     "mistral.loss_grad": "6d328cc674130e50",
     "mixtral.apply": "9dda8853ea9faa33",
     "mixtral.apply_cached": "8a284dcc95d1bdc8",
-    "mixtral.apply_paged": "4d5c472d048e8e8e",
-    "mixtral.apply_paged_decode": "d3bd908c5047070a",
+    "mixtral.apply_paged": "c7cd0c20ba4f3634",
+    "mixtral.apply_paged_decode": "752dc3cfbdd603e9",
     "mixtral.loss_grad": "0bf2200b1c25dbf1",
     "qwen2_moe.apply": "85641834200bb56f",
     "qwen2_moe.apply_cached": "d160ec6149557341",
-    "qwen2_moe.apply_paged": "d634e9f21dccd90b",
-    "qwen2_moe.apply_paged_decode": "5f0324c0737918ce",
+    "qwen2_moe.apply_paged": "8550fe9e4ef9b557",
+    "qwen2_moe.apply_paged_decode": "d8bce010d024ebe0",
     "qwen2_moe.loss_grad": "e4872d8dfc0a6a34",
 }
 

@@ -418,6 +418,127 @@ def test_paged_decode_sliding_window():
     assert np.abs(np.asarray(full[2]) - np.asarray(win[2])).max() > 1e-3
 
 
+# --- the pools are one [L, ...] buffer: the kernels index the layer, the
+# --- write overlays the pages a step touches (ISSUE 29)
+def _layered_pools(rs, L=3, nblocks=24, nkv=2, bs=8, hd=32):
+    """K and V pools whose every layer holds different rows."""
+    shape = (L, nblocks, nkv, bs, hd)
+    return (jnp.asarray(rs.randn(*shape).astype(np.float32)),
+            jnp.asarray(rs.randn(*shape).astype(np.float32)))
+
+
+def _tables(rs, b, max_blocks, nblocks):
+    """Distinct non-trash blocks for every sequence of the call."""
+    return jnp.asarray(rs.permutation(np.arange(1, nblocks))[:b * max_blocks]
+                       .reshape(b, max_blocks).astype(np.int32))
+
+
+@pytest.mark.parametrize("window", [None, 12])
+@pytest.mark.parametrize("op", ["decode", "prefill"])
+def test_paged_kernels_read_the_layer_they_are_given(op, window):
+    """Both kernels take the ``[L, ...]`` pools and a (traced) layer: at
+    every layer they agree with their XLA reference and with the kernel on
+    that layer's pool alone, and the layers' results differ - a kernel that
+    read layer 0 for every layer fails."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    rs = np.random.RandomState(3)
+    kp, vp = _layered_pools(rs)
+    B, nh, hd, t = 2, 4, 32, 5
+    bt = _tables(rs, B, 6, 24)
+    ctx = jnp.asarray([9, 30], jnp.int32)
+    kw = {} if window is None else {"window": window}
+    if op == "decode":
+        q = jnp.asarray(rs.randn(B, nh, hd).astype(np.float32))
+        kernel, ref, args = (pa.paged_decode_attention,
+                             pa.paged_decode_attention_xla, (bt, ctx))
+    else:
+        q = jnp.asarray(rs.randn(B, t, nh, hd).astype(np.float32))
+        kernel, ref, args = (pa.paged_prefill_attention,
+                             pa.paged_prefill_attention_xla,
+                             (bt, ctx, jnp.asarray([t, 3], jnp.int32)))
+    at = jax.jit(lambda layer: kernel(q, kp, vp, *args, layer=layer, **kw))
+    outs = []
+    for layer in range(3):
+        got = np.asarray(at(jnp.asarray(layer, jnp.int32)))
+        want = np.asarray(ref(q, kp, vp, *args, layer=layer, **kw))
+        alone = np.asarray(kernel(q, kp[layer], vp[layer], *args, **kw))
+        if op == "prefill":      # a padded row's output is unspecified
+            got, want, alone = got[1, :3], want[1, :3], alone[1, :3]
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        np.testing.assert_array_equal(got, alone)
+        outs.append(got)
+    assert np.abs(outs[0] - outs[1]).max() > 1e-2
+    assert np.abs(outs[1] - outs[2]).max() > 1e-2
+
+
+# name -> (t, context_lens, lengths): where a step's rows fall on the pages
+KV_WRITES = {
+    "page_aligned_chunk": (16, [16], [16]),
+    "chunk_from_mid_page_to_mid_page": (16, [13], [14]),
+    "decode_batch": (1, [0, 7, 8, 31], [1, 1, 1, 1]),
+    "rows_that_are_not_valid": (6, [5, 20], [4, 0]),
+    "zero_length_dummy_sequence": (8, [0, 11], [0, 8]),
+    "two_sequences_in_one_call": (11, [3, 22], [11, 9]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KV_WRITES))
+def test_paged_kv_write_matches_the_scatter(case):
+    """``paged_kv_write`` (the Mosaic call, interpreted) against the
+    ``.at[].set`` reference on layer 1 of three: the same rows at the same
+    positions, padded rows and dummy sequences write nothing, and every
+    block, layer and slot the step does not touch is bit-identical."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    t, ctx, lens = KV_WRITES[case]
+    rs = np.random.RandomState(5)
+    kp, vp = _layered_pools(rs)
+    b, nkv, bs, hd = len(ctx), 2, 8, 32
+    k = jnp.asarray(rs.randn(b, t, nkv, hd).astype(np.float32))
+    v = jnp.asarray(rs.randn(b, t, nkv, hd).astype(np.float32))
+    bt = _tables(rs, b, 5, 24)
+    ctx, lens = jnp.asarray(ctx, jnp.int32), jnp.asarray(lens, jnp.int32)
+    write = jax.jit(lambda layer: pa.paged_kv_write(
+        k, v, kp, vp, bt, ctx, lens, layer=layer))
+    got = write(jnp.asarray(1, jnp.int32))
+    want = pa.paged_kv_write_xla(k, v, kp, vp, bt, ctx, lens, layer=1)
+    assert got[2] is None and got[3] is None
+    # the rows, read back from where the tables put them
+    for pool, rows in ((got[0], k), (got[1], v)):
+        np.testing.assert_array_equal(np.asarray(pool), np.asarray(
+            want[0] if pool is got[0] else want[1]))
+        for i in range(b):
+            for ti in range(int(lens[i])):
+                pos = int(ctx[i]) + ti
+                np.testing.assert_array_equal(
+                    np.asarray(pool[1, bt[i, pos // bs], :, pos % bs]),
+                    np.asarray(rows[i, ti]))
+    # nothing else moved: as many slots differ as rows were written
+    changed = np.any(np.asarray(got[0]) != np.asarray(kp), axis=(2, 4))
+    assert changed.sum() == int(lens.sum())
+    assert not changed[0].any() and not changed[2].any()
+    assert not changed[:, 0].any()          # the trash block stays as it was
+
+
+def test_paged_kv_write_on_one_layers_pool():
+    """A 4-D pool is one layer's: no layer to give, the same overlay."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    rs = np.random.RandomState(6)
+    kp, vp = (p[0] for p in _layered_pools(rs, L=1))
+    k = jnp.asarray(rs.randn(2, 3, 2, 32).astype(np.float32))
+    bt = _tables(rs, 2, 4, 24)
+    ctx, lens = jnp.asarray([6, 17], jnp.int32), jnp.asarray([3, 2], jnp.int32)
+    got = pa.paged_kv_write(k, -k, kp, vp, bt, ctx, lens)
+    want = pa.paged_kv_write_xla(k, -k, kp, vp, bt, ctx, lens)
+    for g, w in zip(got[:2], want[:2]):
+        assert g.shape == kp.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    with pytest.raises(AssertionError, match="needs the layer"):
+        pa.paged_kv_write(k, k, kp[None], vp[None], bt, ctx, lens)
+
+
 def test_flash_causal_kv_longer_than_q():
     """kv_len > sq with causal=True is API-legal (trailing keys fully
     masked); the dead-step DMA fold must clamp the dkv kernel's q-side

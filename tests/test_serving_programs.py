@@ -4,11 +4,14 @@
 prefill, decode, speculative verify) in the variants the dispatch sites
 choose between, and five families route their paged pools through
 ``models/_paged.scan_layers``. A PR that reshapes that code without meaning
-to change a program must leave every jaxpr here as it was: the hashes below
-are of the commit before ISSUE 28 (f552895), where ten builders and five
-hand-written scans produced them. ``str(jaxpr)`` carries no scope names, so
-the ``kv_write`` scope that ``scan_layers`` gives gpt, falcon and exaone4
-does not show here; an operation added or moved does.
+to change a program must leave every jaxpr here as it was. The hashes below
+are ISSUE 29's, re-taken one for one on its finished tree: that PR changed
+every paged program on purpose (the pools became the layer scan's carry,
+written in place and indexed by layer inside the kernels), so what it was
+held to instead is the served tokens - ``PARENT_TOKENS``, what the engines
+of the commit before it (72e45a1) served greedily. ``str(jaxpr)`` carries no
+scope names, so the ``kv_write`` scope that ``scan_layers`` gives gpt, falcon
+and exaone4 does not show here; an operation added or moved does.
 
 The last test counts what ONE ``step()`` does on the host before its
 program runs (uploads and keys made inside ``engine_v2``): the dispatch
@@ -149,29 +152,30 @@ def paged_text(family: str, t: int) -> str:
                  s((2,), jnp.int32))
 
 
-# taken on f552895 under this directory's conftest, before any of the code
-# moved. A PR that means to change one of these programs replaces its line.
+# ISSUE 29's: taken on its finished tree under this directory's conftest (the
+# lines before it were f552895's). A PR that means to change one of these
+# programs replaces its line.
 PARENT_HASHES = {
-    "chunk_prefill.final_greedy": "1d5ddd5c63d6b820",
-    "chunk_prefill.final_stochastic": "7601a8abf1c593a8",
-    "chunk_prefill.mid": "8249e849ca3231c8",
-    "chunk_prefill.mid.int8": "64a0539822d23ba0",
-    "decode.greedy": "29cb773389bccaa2",
-    "decode.greedy.int8": "f827142dee713212",
-    "decode.rows": "cf79d4f1a6ddc149",
-    "decode_many.greedy": "6692f081ad5be613",
-    "decode_many.rows": "30fc0a93ac321aa4",
-    "exaone4.apply_paged.t1": "6192bc36a8e2d031",
-    "exaone4.apply_paged.t8": "b3613330675111b6",
-    "falcon.apply_paged.t1": "1d04fe2e2c5988fd",
-    "falcon.apply_paged.t8": "83f6aaf021bdc995",
-    "gpt.apply_paged.t1": "b530ef24c972e315",
-    "gpt.apply_paged.t8": "307319c7b77b22c4",
-    "prefill.greedy": "a084d36e7501994d",
-    "prefill.rows": "6390078d318cb885",
-    "prefill_ctx.greedy": "42ea0db6e00ee03f",
-    "prefill_ctx.rows": "779d41141192ea91",
-    "spec_verify": "575cd95a2db166b6",
+    "chunk_prefill.final_greedy": "2fba01067a00967c",
+    "chunk_prefill.final_stochastic": "ac75ddded01ea6b7",
+    "chunk_prefill.mid": "a4afc6de0baf7628",
+    "chunk_prefill.mid.int8": "acc809c779ddf322",
+    "decode.greedy": "0a6f5a9965fc0637",
+    "decode.greedy.int8": "d097770f52fa7f7a",
+    "decode.rows": "df2f47272cb02bc6",
+    "decode_many.greedy": "f0fd8799d66418a7",
+    "decode_many.rows": "9fda1d7c518a3300",
+    "exaone4.apply_paged.t1": "9052b4becd0e33b1",
+    "exaone4.apply_paged.t8": "b6ec6f09c8e127b0",
+    "falcon.apply_paged.t1": "48f438229bd392fb",
+    "falcon.apply_paged.t8": "34a9462d4be1d3b6",
+    "gpt.apply_paged.t1": "ee3a1123c85ea42a",
+    "gpt.apply_paged.t8": "8a8f509981f5c0ca",
+    "prefill.greedy": "711417c7aba9837d",
+    "prefill.rows": "2d4c4e712c3a4a38",
+    "prefill_ctx.greedy": "41e82bf7d475a0dd",
+    "prefill_ctx.rows": "60f3edc57419334f",
+    "spec_verify": "5a2b0e419537fdc2",
 }
 
 
@@ -185,6 +189,56 @@ def test_the_engines_program_is_the_parents(engines, name):
 def test_the_familys_paged_forward_is_the_parents(family, t):
     assert _hash(paged_text(family, t)) == \
         PARENT_HASHES[f"{family}.apply_paged.t{t}"]
+
+
+# what the parent of ISSUE 29 (72e45a1) served in ``_serve``, greedily, through
+# every program: the check that stood in for "same jaxpr" in that PR
+PARENT_TOKENS = {
+    "bf16": {
+        "1": [34, 197, 163, 113, 101, 85, 28, 181, 91],
+        "2": [156, 147, 156, 147, 135, 186, 156, 147, 164],
+        "3": [13, 227, 116, 71, 105, 152, 152],
+    },
+    "int8": {
+        "1": [34, 197, 163, 113, 101, 85, 28, 181, 91],
+        "2": [156, 147, 156, 147, 135, 186, 156, 147, 164],
+        "3": [13, 227, 116, 71, 105, 152, 152],
+    },
+    "spec": {
+        "1": [34, 197, 163, 113, 101],
+        "2": [156, 147, 156, 147, 135, 186],
+        "3": [13, 227, 116],
+    },
+}
+
+
+def _serve(eng):
+    """A burst prefill, a split prompt's chunks beside live decodes, single
+    ticks (drafted and verified where the engine speculates), a fused
+    quantum: ``{uid: tokens}``."""
+    import numpy as np
+
+    rng = np.random.RandomState(7)
+    out = {}
+    eng.put(1, rng.randint(1, 200, 5).tolist())
+    eng.put(2, rng.randint(1, 200, 11).tolist())
+    eng.put_split(3, rng.randint(1, 200, 21).tolist())
+    for _ in range(5):
+        for uid, t in eng.step().items():
+            out.setdefault(uid, []).extend(t if isinstance(t, list) else [t])
+    if not eng._spec_on:
+        for uid, ts in eng.step_many(4).items():
+            out.setdefault(uid, []).extend(ts)
+    return {str(uid): [int(t) for t in ts] for uid, ts in out.items()}
+
+
+@pytest.mark.parametrize("mode", sorted(PARENT_TOKENS))
+def test_the_engine_serves_the_parents_tokens(mode):
+    extra = {"bf16": {}, "int8": {"kv_quant": {"enabled": True,
+                                               "group_size": 8}},
+             "spec": {"speculative": {"enabled": True,
+                                      "max_draft_tokens": 3}}}[mode]
+    assert _serve(_engine(**extra)) == PARENT_TOKENS[mode]
 
 
 def test_the_programs_keep_the_names_the_benchmark_reads(engines):

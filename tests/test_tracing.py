@@ -659,6 +659,51 @@ def test_train_step_on_the_profiler_timeline(devices8, tmp_path):
     engine.destroy()
 
 
+def test_serving_compile_span_says_what_it_copies_of_the_pools(devices8):
+    """A serving program is registered with its KV pools: its ``compile``
+    span and ``Compile/<program>/`` series carry ``pool_copy_bytes`` and
+    ``aliased_bytes`` (values are the chip compiler's to give:
+    tests/test_chip_compile.py holds them to 0 and the pools' bytes)."""
+    from deepspeed_tpu.inference.engine_v2 import build_engine_v2
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    cfg = llama.LlamaConfig.tiny()
+    eng = build_engine_v2(
+        llama, cfg, llama.init(cfg, __import__("jax").random.PRNGKey(0)),
+        config={"dtype": "float32", "prefill_bucket": 16,
+                "compile_monitor": {"enabled": True},
+                "trace": {"enabled": True, "ring_size": 4096,
+                          "dump_on_crash": False},
+                "ragged": {"max_tracked_sequences": 4,
+                           "max_ragged_batch_size": 4,
+                           "memory_config_blocks": 64, "block_size": 16}})
+    eng.put(0, list(range(1, 12)))
+    eng.step()
+    spans = {e["args"]["program"]: e["args"] for e in eng.tracer.events()
+             if e["ph"] == "X" and e["name"] == "compile"}
+    assert {"prefill", "decode"} <= set(spans)
+    for program in ("prefill", "decode"):
+        assert spans[program]["pool_copy_bytes"] >= 0
+        assert spans[program]["aliased_bytes"] >= 0
+        assert {"pool_copy_bytes", "aliased_bytes"} \
+            <= set(eng.compile_monitor.summary()[program])
+    events = eng.compile_monitor.events(group="Serving")
+    assert schema.validate_events(events) == []
+    # the counter itself, on a program's text: pool-shaped results of the
+    # opcodes that re-house a pool count, anything else does not
+    pool = (2, 64, 2, 16, 16)
+    text = "\n".join((
+        "  %copy.1 = f32[2,64,2,16,16]{4,3,2,1,0} copy(%p)",
+        "  %ds.2 = f32[1,64,2,16,16]{4,3,2,1,0} dynamic-slice(%p, %i)",
+        "  ROOT %f.3 = f32[64,2,16,16]{3,2,1,0} fusion(%p), kind=kLoop",
+        "  %f.4 = f32[64,2,16,16]{3,2,1,0} fusion(%p), kind=kOutput",
+        "  %w.5 = f32[2,64,2,16,16]{4,3,2,1,0} custom-call(%p), "
+        'custom_call_target="tpu_custom_call"',
+        "  %c.6 = f32[4,16]{1,0} copy(%q)"))
+    one_layer = 64 * 2 * 16 * 16 * 4
+    assert pool_copy_bytes(text, [pool]) == 4 * one_layer
+
+
 def test_ring_and_timeline_share_one_call_site(devices8, tmp_path):
     """Ring ON and a profiler session: the same spans land in both, and the
     ring's spans are all registered names."""
@@ -792,6 +837,10 @@ def _kernel_cases():
         "paged_prefill": (pa.paged_prefill_attention,
                           [jnp.ones((2, 3, 4, 32), f32), pool, pool,
                            tables, lens]),
+        "paged_kv_write": (
+            lambda k, kp, vp, bt, n: pa.paged_kv_write(k, k, kp, vp, bt, n,
+                                                       n)[:2],
+            [jnp.ones((2, 3, 2, 32), f32), pool, pool, tables, lens]),
         "rms_norm_fwd": (rms_norm_pallas, [x, w]),
         "layer_norm_fwd": (layer_norm_pallas, [x, w, w]),
         "quantize_int8": (lambda a: quantize_int8_pallas(a, group_size=256),
@@ -804,7 +853,7 @@ def _kernel_cases():
 KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "sparse_flash_fwd", "sparse_flash_bwd_dq",
                 "sparse_flash_bwd_dkv", "paged_decode", "paged_prefill",
-                "rms_norm_fwd", "layer_norm_fwd", "quantize_int8",
+                "paged_kv_write", "rms_norm_fwd", "layer_norm_fwd", "quantize_int8",
                 "dequantize_int8"]
 
 
