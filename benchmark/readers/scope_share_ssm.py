@@ -1,0 +1,27 @@
+"""``scope_share`` with the state-space mixer's own scopes known: device
+time in operations whose innermost named scope is one of ``scopes``, over
+device busy time, in percent. The mixer's scopes (``ssm_proj``: the in and
+out projections and the gated norm; ``ssm_conv``; ``ssm_state``: the chunked
+scan and the single-token update) lie INSIDE ``attn`` in the program, and
+``program_spans.SCOPES`` - fixed, what ``scope_share`` reads by - does not
+name them, so that reader books the whole mixer to ``attn``. A program that
+names none of them reports nothing."""
+
+from benchmark.harness import program_spans as ps
+
+SSM_SCOPES = ("ssm_proj", "ssm_conv", "ssm_state")
+
+
+def read(ctx, scopes):
+    program = ps.load(ctx)
+    if program is None or not program.ops:
+        return None
+    window = ctx["trace"].window()
+    mine = busy = 0.0
+    named = False
+    for ops in program.ops.values():
+        by_scope = ps.scope_seconds(ops, window, ps.SCOPES + SSM_SCOPES)
+        named = named or any(s in by_scope for s in SSM_SCOPES)
+        mine += sum(by_scope.get(s, 0.0) for s in scopes)
+        busy += sum(by_scope.values())
+    return 100.0 * mine / busy if busy and named else None

@@ -48,6 +48,12 @@ class ModelFamily:
     # (cfg, rows) -> the MoE layer's static row counts for a serving call of
     # ``rows`` tokens; None for a dense family (models/mixtral.py moe_rows)
     moe_rows: Optional[Callable] = None
+    # (cfg) -> bytes of recurrent state ONE sequence slot holds;
+    # a family that has it declares recurrent state: its paged cache carries
+    # per-slot leaves (named in ``state_leaves``) beside the block pools and
+    # its ``apply_paged`` takes each row's ``slots`` (models/granite_hybrid.py)
+    state_slot_bytes: Optional[Callable] = None
+    state_leaves: Tuple[str, ...] = ()
 
     @classmethod
     def from_module(cls, module, cfg) -> "ModelFamily":
@@ -62,7 +68,9 @@ class ModelFamily:
                    param_logical_axes=module.param_logical_axes,
                    cache_logical_axes=getattr(module, "cache_logical_axes", None),
                    name=getattr(module, "__name__", "model").rsplit(".", 1)[-1],
-                   moe_rows=getattr(module, "moe_rows", None))
+                   moe_rows=getattr(module, "moe_rows", None),
+                   state_slot_bytes=getattr(module, "state_slot_bytes", None),
+                   state_leaves=tuple(getattr(module, "STATE_LEAVES", ())))
 
 
 def _round_up(n: int, m: int) -> int:

@@ -36,7 +36,8 @@ from ..telemetry.trace import percentiles
 from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .engine import InferenceEngine, ModelFamily, _round_up
-from .ragged import StateManager, UnknownSequenceError  # noqa: F401 (re-export)
+from .ragged import (RecurrentStateError, StateManager,  # noqa: F401
+                     UnknownSequenceError)                # (re-exports)
 from .sampling import (SamplingParams, accept_drafts, sample, sample_batch,
                        sp_arrays)
 
@@ -75,6 +76,10 @@ _NO_WORK = {"prefill_tokens": 0, "prefill_kv_tokens": 0, "decode_seqs": 0,
 # the one static sampling config the programs are built with (a final prefill
 # chunk apart): every greedy-equivalent request canonicalizes to it
 _GREEDY = SamplingParams(greedy=True)
+
+# why a family with recurrent state refuses the disagg block export / import
+_HANDOFF = ("a sequence's blocks are not its whole state, and the "
+            "destination resumes through the prefix cache")
 
 
 def _last_row(logits, lengths):
@@ -136,11 +141,21 @@ class InferenceEngineV2(InferenceEngine):
             self._init_paged = _llama.init_paged_cache
         max_blocks_per_seq = max(
             2, (self.family.cfg.max_seq_len + rc.block_size - 1) // rc.block_size)
-        self.state = StateManager(rc.max_tracked_sequences,
-                                  rc.memory_config_blocks, rc.block_size,
-                                  max_blocks_per_seq,
-                                  prefix_cache=pc.enabled,
-                                  max_retained_blocks=pc.max_retained_blocks)
+        # --- recurrent state (docs/serving.md "Recurrent state"): a family
+        # that declares it (``state_slot_bytes``) keeps one fixed-size row a
+        # sequence slot a state-space layer beside its KV blocks. What treats
+        # a sequence's state as its blocks is refused here or at its call.
+        self._recurrent = self.family.state_slot_bytes is not None
+        slot_kw = {}
+        if self._recurrent:
+            self._refuse_for_recurrent_state()
+            slot_kw = {"slots": rc.max_tracked_sequences}
+        self.state = StateManager(
+            rc.max_tracked_sequences, rc.memory_config_blocks, rc.block_size,
+            max_blocks_per_seq, prefix_cache=pc.enabled,
+            max_retained_blocks=pc.max_retained_blocks,
+            state_slot_bytes=self.family.state_slot_bytes(
+                self.family.cfg) if self._recurrent else 0)
         # --- quantized KV cache (inference.kv_quant; docs/serving.md
         # "Quantized KV cache"). Default OFF → the cache pytree, every
         # compiled paged program, and the token streams are byte-identical
@@ -177,7 +192,7 @@ class InferenceEngineV2(InferenceEngine):
         else:
             self.cache = self._init_paged(self.family.cfg,
                                           rc.memory_config_blocks,
-                                          rc.block_size)
+                                          rc.block_size, **slot_kw)
         # commit the fresh pool to the mesh the way every paged program
         # hands it back: a fresh uncommitted array has another sharding
         # than a program's output, so the first program to touch it would
@@ -207,7 +222,6 @@ class InferenceEngineV2(InferenceEngine):
         self._slot_tokens = np.zeros((B,), np.int32)
         self._slot_lens = np.zeros((B,), np.int32)
         self._slot_tables = np.zeros((B, max_blocks_per_seq), np.int32)
-        self._slot_active = np.zeros((B,), bool)
         # per-slot sampling params, recorded at admission — decode honors
         # these (the reference's v2 engine carries per-request sampling)
         self._slot_sp: List[SamplingParams] = [_GREEDY] * B
@@ -246,6 +260,8 @@ class InferenceEngineV2(InferenceEngine):
         # prefix-cache hits not: nothing is written for them)
         self.last_step: Dict[str, int] = dict(_NO_WORK)
         self._admitted_kv_tokens = 0   # one-shot prefills since the last step
+        # their ssm_rows / ssm_tokens (a family with recurrent state)
+        self._admitted_ssm: Dict[str, int] = {}
         self.prefill_tokens_written = 0
         # --- recompilation sentinel + per-program MFU attribution
         # (telemetry/compile.py; docs/observability.md). A hub with an
@@ -274,6 +290,7 @@ class InferenceEngineV2(InferenceEngine):
                  f"{rc.block_size} tokens, {B} sequence slots, "
                  f"kv_quant={'int8(g=%d)' % self._kvq_group if self._kvq_on else 'off'}, "
                  f"prefix_cache={'on' if pc.enabled else 'off'}, "
+                 f"recurrent_state={'%d B/slot' % self.state.state_slot_bytes if self._recurrent else 'none'}, "
                  f"speculative={spec_lbl}, "
                  f"trace={'on' if self._trace_on else 'off'}")
 
@@ -398,6 +415,33 @@ class InferenceEngineV2(InferenceEngine):
             rec["span"].end(cancelled=True)
 
     # ------------------------------------------------------------------ #
+    def _refuse_for_recurrent_state(self) -> None:
+        """Configuration-time refusals for a family with recurrent state
+        (``fork`` and the disagg block export / import refuse at their
+        call)."""
+        cfg = self.config
+        kq = getattr(cfg, "kv_quant", None)
+        for on, feature, why in (
+                (cfg.prefix_cache.enabled, "inference.prefix_cache",
+                 "a cached prefix's blocks hold its keys and values but not "
+                 "the recurrent state at its end, and no snapshot of that "
+                 "state is kept"),
+                (getattr(cfg.prefix_cache, "host_spill", False),
+                 "inference.prefix_cache.host_spill",
+                 "it spills and restores prefix-cache blocks"),
+                (cfg.speculative.enabled, "inference.speculative",
+                 "a rejected draft is rolled back by truncating blocks, and "
+                 "a recurrent state cannot be rolled back"),
+                (kq is not None and kq.enabled, "inference.kv_quant",
+                 "the family's cache has no quantized mode")):
+            if on:
+                raise RecurrentStateError(feature, why)
+
+    def _refuse_call(self, call: str, why: str) -> None:
+        """Call-time refusal for a family with recurrent state."""
+        if self._recurrent:
+            raise RecurrentStateError(call, why)
+
     def _jit(self, key, fn, **jit_kwargs):
         """Every paged program routes through the compile monitor's shared
         registration helper. ``key[0]`` is the program FAMILY name, so a new
@@ -418,14 +462,19 @@ class InferenceEngineV2(InferenceEngine):
     # builders - prefill, chunk_prefill, decode, spec_verify. ``_dispatch``
     # launches all of them.
     # ------------------------------------------------------------------ #
-    def _paged_forward(self, params, tokens, cache, tables, ctx, valid):
+    def _paged_forward(self, params, tokens, cache, tables, ctx, valid,
+                       slots=None):
         """The engine's ONE call of the family's paged forward, traced inside
         every program: ``tokens`` [b, t] at context offsets ``ctx`` [b]
         through block tables [b, blocks], ``params`` as ``_dq`` hands them
-        over; rows where ``valid`` [b, t] is False write nothing.
+        over; rows where ``valid`` [b, t] is False write nothing. ``slots``
+        [b]: each row's sequence slot, for a family with recurrent state and
+        a call whose rows are not the slots in order (the prefills; a
+        decode-shaped call's row i IS slot i, the family's default).
         Returns (logits [b, t, V] fp32, cache)."""
+        kw = {} if slots is None else {"slots": slots}
         return self._apply_paged(self.family.cfg, params, tokens, cache,
-                                 tables, ctx, valid=valid)
+                                 tables, ctx, valid=valid, **kw)
 
     def _prefill_fn(self, pad_t: int, n: int, with_ctx: bool, rows: bool):
         """One compiled prefill over ``n`` admitted sequences at once —
@@ -455,15 +504,18 @@ class InferenceEngineV2(InferenceEngine):
 
             def prefill(params, cache, tokens, lengths, tables, *rest):
                 # tokens [n, pad_t]; lengths [n]; tables [n, blocks]; then
-                # ctx [n] (with_ctx), rng, uids [n], sampling arrays (rows)
-                ctx, rng, uids, *sp_rows = rest if with_ctx \
-                    else (None,) + rest
+                # ctx [n] (with_ctx), slots [n] (recurrent state), rng,
+                # uids [n], sampling arrays (rows)
+                rest = list(rest)
+                ctx = rest.pop(0) if with_ctx else None
+                slots = rest.pop(0) if self._recurrent else None
+                rng, uids, *sp_rows = rest
                 valid = jnp.arange(pad_t)[None, :] < lengths[:, None]
                 dq = self._dq(params)
                 if not with_ctx:
                     ctx = jnp.zeros((n,), jnp.int32)
                 logits, cache = self._paged_forward(dq, tokens, cache, tables,
-                                                    ctx, valid)
+                                                    ctx, valid, slots)
                 last = _last_row(logits, lengths)
                 keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
                 toks = jax.vmap(pick)(keys, last, *sp_rows)
@@ -504,11 +556,21 @@ class InferenceEngineV2(InferenceEngine):
         if key not in self._paged_fns:
 
             def cp(cache, src, dst):
-                return jax.tree.map(
+                return self._map_block_leaves(
                     lambda c: c.at[:, dst].set(c[:, src]), cache)
 
             self._paged_fns[key] = self._jit(key, cp, donate_argnums=(0,))
         return self._paged_fns[key]
+
+    def _map_block_leaves(self, fn, cache):
+        """``fn`` over the cache leaves that have the block axis (all of
+        them but a recurrent family's per-slot state leaves, which pass
+        through as they are)."""
+        state = self.family.state_leaves
+        if not state:
+            return jax.tree.map(fn, cache)
+        return {name: leaf if name in state else jax.tree.map(fn, leaf)
+                for name, leaf in cache.items()}
 
     def _spill_read_block(self, b: int):
         """One block's per-cache-leaf contents as PRIVATE device slices —
@@ -566,12 +628,14 @@ class InferenceEngineV2(InferenceEngine):
         if key not in self._paged_fns:
 
             def chunk_prefill(params, cache, tokens, n_valid, ctx, table,
-                              rng, uid):
-                # tokens [1, chunk_t]; ctx = tokens already cached
+                              *rest):
+                # tokens [1, chunk_t]; ctx = tokens already cached; then the
+                # sequence's slot (recurrent state), rng, uid
+                *slot, rng, uid = rest
                 valid = (jnp.arange(chunk_t) < n_valid)[None, :]
                 logits, cache = self._paged_forward(
                     self._dq(params), tokens, cache, table[None], ctx[None],
-                    valid)
+                    valid, *(s_[None] for s_ in slot))
                 if not final:
                     return cache
                 last = _last_row(logits, n_valid)
@@ -605,6 +669,22 @@ class InferenceEngineV2(InferenceEngine):
         of them). Shape facts, known at dispatch; none for a dense family."""
         fn = self.family.moe_rows
         return fn(self.family.cfg, rows) if fn else {}
+
+    def _ssm_args(self, rows: int, tokens: int,
+                  admitted: bool = False) -> Dict[str, int]:
+        """Span arguments of a call in a family with recurrent state:
+        ``ssm_rows``, the rows whose state ONE state-space layer of the call
+        advances (a decode's active slots, a prefill's admitted sequences),
+        and ``ssm_tokens``, the tokens it advances them by. ``last_step``
+        counts them too (a one-shot prefill ``admitted`` between steps with
+        the next step, as its ``prefill_kv_tokens``). None for any other
+        family."""
+        if not self._recurrent:
+            return {}
+        into = self._admitted_ssm if admitted else self.last_step
+        for key, n in (("ssm_rows", rows), ("ssm_tokens", tokens)):
+            into[key] = into.get(key, 0) + n
+        return {"ssm_rows": rows, "ssm_tokens": tokens}
 
     def _attn_tile_args(self) -> Dict[str, float]:
         """Span arguments of a decode dispatch over the slots as they stand:
@@ -647,7 +727,8 @@ class InferenceEngineV2(InferenceEngine):
                 uid=uid, tokens=len(chunk), ctx=done, final=final,
                 kv_blocks=self._kv_blocks(done + len(chunk)),
                 table_blocks=self.state.max_blocks_per_seq,
-                **self._moe_args(chunk_tokens)):
+                **self._moe_args(chunk_tokens),
+                **self._ssm_args(1, len(chunk))):
             with self.tracer.span("engine_prep", cat="serving"):
                 padded = np.zeros((1, chunk_tokens), np.int32)
                 padded[0, :len(chunk)] = chunk
@@ -656,7 +737,8 @@ class InferenceEngineV2(InferenceEngine):
             if self._trace_on:
                 self._req_compute_begin(uid)   # first chunk ends queue-wait
             res = self._dispatch(
-                fn, (padded, np.int32(len(chunk)), np.int32(done), table),
+                fn, (padded, np.int32(len(chunk)), np.int32(done), table)
+                + ((np.int32(desc.slot),) if self._recurrent else ()),
                 seed, (np.int32(uid),))
             self.last_step["prefill_tokens"] += len(chunk)
             self.last_step["prefill_kv_tokens"] += done + len(chunk)
@@ -689,7 +771,6 @@ class InferenceEngineV2(InferenceEngine):
         self._slot_tokens[s] = desc.last_token
         self._slot_lens[s] = desc.seen_tokens
         self._slot_tables[s] = table
-        self._slot_active[s] = True
         self._slot_sp[s] = sp
 
     def put_split(self, uid: int, prompt_tokens,
@@ -855,7 +936,7 @@ class InferenceEngineV2(InferenceEngine):
                 fn = self._verify_fn(kmax + 1)
                 sp_rows = sp_arrays(self._slot_sp)
             m, nxt, self.cache = self._dispatch(
-                fn, self._slots(tok_w) + (nvalid, dr_arr), seed,
+                fn, self._slots(live, tok_w) + (nvalid, dr_arr), seed,
                 (uids_arr,) + sp_rows)
             with self.tracer.span("engine_wait", cat="serving"):
                 m, nxt = np.asarray(m), np.asarray(nxt)
@@ -954,7 +1035,9 @@ class InferenceEngineV2(InferenceEngine):
                               pad_t=pad_t,
                               kv_blocks=self._kv_blocks(max(kv_rows)),
                               table_blocks=self.state.max_blocks_per_seq,
-                              **self._moe_args(n_pad * pad_t)):
+                              **self._moe_args(n_pad * pad_t),
+                              **self._ssm_args(
+                                  n, sum(kv_rows) - sum(cached), True)):
             with self.tracer.span("engine_prep", cat="serving"):
                 padded = np.zeros((n_pad, pad_t), np.int32)
                 lengths = np.zeros((n_pad,), np.int32)  # dummy rows: length 0
@@ -962,7 +1045,9 @@ class InferenceEngineV2(InferenceEngine):
                 uids_arr = np.zeros((n_pad,), np.int32)
                 tables = np.zeros((n_pad, self._slot_tables.shape[1]),
                                   np.int32)
+                slots = np.zeros((n_pad,), np.int32)  # dummy rows write none
                 for i, (uid, prompt, desc) in enumerate(entries):
+                    slots[i] = desc.slot
                     suffix = prompt[cached[i]:]
                     padded[i, :len(suffix)] = suffix
                     lengths[i] = len(suffix)
@@ -981,7 +1066,8 @@ class InferenceEngineV2(InferenceEngine):
                     self._req_compute_begin(uid)
                 t0 = time.monotonic_ns()
             toks, self.cache = self._dispatch(
-                fn, (padded, lengths, tables) + ((ctx,) if with_ctx else ()),
+                fn, (padded, lengths, tables) + ((ctx,) if with_ctx else ())
+                + ((slots,) if self._recurrent else ()),
                 seed, (uids_arr,) + sp_rows)
             with self.tracer.span("engine_wait", cat="serving"):
                 toks = np.asarray(toks)
@@ -1020,8 +1106,10 @@ class InferenceEngineV2(InferenceEngine):
         and the sequences to decode are listed. Returns ({uid: first token}
         of a prompt this completed, live)."""
         self.last_step = dict(_NO_WORK,
-                              prefill_kv_tokens=self._admitted_kv_tokens)
+                              prefill_kv_tokens=self._admitted_kv_tokens,
+                              **self._admitted_ssm)
         self._admitted_kv_tokens = 0
+        self._admitted_ssm = {}
         first = self._advance_prefill(seed)
         live = [d for d in self.state.seqs.values()
                 if not d.finished and not d.prefilling
@@ -1051,12 +1139,19 @@ class InferenceEngineV2(InferenceEngine):
             self._slot_tables[d.slot] = self.state.block_table(d)
         self._copy_blocks(cow)
 
-    def _slots(self, tokens=None) -> Tuple:
+    def _slots(self, live=(), tokens=None) -> Tuple:
         """(tokens, lens, tables, active) over every slot: what a
-        decode-shaped program takes after the cache. ``tokens`` stands in
-        for the slots' last tokens (a verify window)."""
+        decode-shaped program takes after the cache. Active are the slots
+        of ``live``, the sequences the call decodes, and no other: a slot
+        seated by this step's final chunk, or one whose sequence has
+        finished and is not yet retired, holds a sequence but is not live,
+        and a row computed for it would write its KV (and advance a
+        recurrent state) a second time. ``tokens`` stands in for the slots'
+        last tokens (a verify window)."""
+        active = np.zeros(self._slot_lens.shape, bool)
+        active[[d.slot for d in live]] = True
         return (self._slot_tokens if tokens is None else tokens,
-                self._slot_lens, self._slot_tables, self._slot_active)
+                self._slot_lens, self._slot_tables, active)
 
     def _commit(self, d, written, emitted, t_ns: int) -> int:
         """A decode-shaped call's tokens land on sequence ``d``: ``written``
@@ -1089,7 +1184,7 @@ class InferenceEngineV2(InferenceEngine):
             fn = self._decode_fn(k, rows)
             sp_rows = sp_arrays(self._slot_sp) if rows else ()
         # (toks [k, B], lens, cache); the single step's (toks [B], cache)
-        toks, *_, self.cache = self._dispatch(fn, self._slots(), seed,
+        toks, *_, self.cache = self._dispatch(fn, self._slots(live), seed,
                                               sp_rows)
         with self.tracer.span("engine_wait", cat="serving"):
             toks = np.asarray(toks).reshape(k, -1)
@@ -1135,7 +1230,8 @@ class InferenceEngineV2(InferenceEngine):
                 self.spec_stats["emitted_tokens"] += len(live)
             with self.tracer.span("decode_step", cat="serving",
                                   batch=len(live),
-                                  **self._moe_args(len(self._slot_tokens))
+                                  **self._moe_args(len(self._slot_tokens)),
+                                  **self._ssm_args(len(live), len(live))
                                   ) as span:
                 self._decode_ticks(1, live, seed, span, out, tiles=True)
         return out if self._spec_on else {u: s[0] for u, s in out.items()}
@@ -1183,7 +1279,6 @@ class InferenceEngineV2(InferenceEngine):
         return desc.generated
 
     def _clear_slot(self, s: int) -> None:
-        self._slot_active[s] = False
         self._slot_lens[s] = 0
         self._slot_tables[s] = 0
         self._slot_sp[s] = _GREEDY
@@ -1198,11 +1293,18 @@ class InferenceEngineV2(InferenceEngine):
         an admission could actually obtain (retained prefix blocks are
         evicted on demand)."""
         st = self.state
-        return {"free_blocks": st.allocator.free_blocks,
-                "retained_blocks": st.retained_blocks,
-                "headroom_blocks": st.headroom_blocks,
-                "free_slots": st.free_slots,
-                "total_blocks": st.allocator.num_blocks - 1}
+        out = {"free_blocks": st.allocator.free_blocks,
+               "retained_blocks": st.retained_blocks,
+               "headroom_blocks": st.headroom_blocks,
+               "free_slots": st.free_slots,
+               "total_blocks": st.allocator.num_blocks - 1}
+        if self._recurrent:
+            # a slot is its state row: what a free slot stands for, in bytes
+            out.update(state_bytes_per_slot=st.state_slot_bytes,
+                       state_bytes_free=st.state_bytes_free,
+                       state_bytes_total=st.max_sequences
+                       * st.state_slot_bytes)
+        return out
 
     def set_speculative(self, enabled: bool) -> bool:
         """Runtime toggle for speculative decoding — the overload
@@ -1285,6 +1387,10 @@ class InferenceEngineV2(InferenceEngine):
         whichever appends first gets a private copy via copy-on-write. The
         child starts with an empty ``generated`` list and, unless ``sp`` is
         given, the parent's sampling params."""
+        self._refuse_call("fork", "the child shares the parent's KV blocks, "
+                          "and the parent's recurrent state would have to "
+                          "be copied into a slot of its own, which is not "
+                          "written")
         desc = self.state.fork(uid, new_uid)
         self._req_admit(new_uid, desc.seen_tokens)
         self._seat(desc, self.state.block_table(desc),
@@ -1347,6 +1453,7 @@ class InferenceEngineV2(InferenceEngine):
         did NOT cost."""
         if wire not in ("native", "int8"):
             raise ValueError(f"unknown KV wire format {wire!r}")
+        self._refuse_call("export_kv_blocks", _HANDOFF)
         desc = self.state.lookup(uid)
         self.state.mark_filled(desc)
         hashes = list(desc.block_hashes)
@@ -1398,6 +1505,7 @@ class InferenceEngineV2(InferenceEngine):
         stamp the converted payload into the device pool. A dropped block
         (pool exhausted / retention off) is harmless — resume re-prefills
         that suffix. Returns ``{"imported", "dedup", "dropped"}``."""
+        self._refuse_call("import_kv_blocks", _HANDOFF)
         res = {"imported": 0, "dedup": 0, "dropped": 0}
         for h, payload in zip(chain_hashes, blocks):
             if self.state.prefix_cache and h in self.state.index._by_hash:
@@ -1513,6 +1621,25 @@ class InferenceEngineV2(InferenceEngine):
     def publish_kv_quant_telemetry(self, step: int = 0):
         return self._publish(self.kv_quant_events(step))
 
+    def state_events(self, step: int = 0):
+        """``Serving/state/*`` telemetry events of a family with recurrent
+        state (none for any other): ``bytes``, the size of the per-slot state
+        pools as they lie on the device (every slot's row and the trash row,
+        every state-space layer); ``bytes_per_slot``, what one sequence
+        holds; ``slots_held``, the slots sequences hold now."""
+        if not self._recurrent:
+            return []
+        st = self.state
+        vals = {"bytes": sum(self.cache[n].nbytes
+                             for n in self.family.state_leaves),
+                "bytes_per_slot": st.state_slot_bytes,
+                "slots_held": st.max_sequences - st.free_slots}
+        return [(f"Serving/state/{k}", float(v), step)
+                for k, v in sorted(vals.items())]
+
+    def publish_state_telemetry(self, step: int = 0):
+        return self._publish(self.state_events(step))
+
     def debug_check_cache(self) -> None:
         """Cache-pytree invariants beside ``StateManager.debug_check`` —
         in quantized-KV mode the scale tables must stay consistent with the
@@ -1520,7 +1647,7 @@ class InferenceEngineV2(InferenceEngine):
         spill/restore): int8 codes, fp32 scales, one scale vector per
         (block, head, token) with ``head_size // group_size`` groups, all
         finite and non-negative. Raises AssertionError on violation."""
-        keys = set(self.cache.keys())
+        keys = set(self.cache.keys()) - set(self.family.state_leaves)
         if not self._kvq_on:
             assert keys == {"k", "v"}, \
                 f"unquantized cache has unexpected leaves {keys}"
@@ -1713,6 +1840,8 @@ class InferenceEngineV2(InferenceEngine):
             self.publish_spec_telemetry(step_i)
         if self._kvq_on and self._hub is not None:
             self.publish_kv_quant_telemetry(step_i)
+        if self._recurrent and self._hub is not None:
+            self.publish_state_telemetry(step_i)
         if self.compile_monitor.enabled and self._hub is not None:
             self.publish_compile_telemetry(step_i)
         return [results[i] for i in range(len(prompts))]

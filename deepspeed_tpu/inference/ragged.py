@@ -54,6 +54,20 @@ class UnknownSequenceError(KeyError):
         return self.args[0]
 
 
+class RecurrentStateError(NotImplementedError):
+    """A serving feature that treats a sequence's state as its KV blocks was
+    asked of a family with RECURRENT state (a fixed-size row a sequence slot
+    a state-space layer, rewritten every token): sharing, copying, rolling
+    back or shipping blocks says nothing about that row, and without state
+    snapshots the feature would serve wrong tokens. Raised at configuration
+    or call time instead (docs/serving.md "Recurrent state")."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(
+            f"{feature} is not available for a family with recurrent "
+            f"state: {why} (docs/serving.md 'Recurrent state')")
+
+
 class BlockedAllocator:
     """Ref-counted free-list allocator over a fixed pool of KV blocks
     (reference ``inference/v2/ragged/blocked_allocator.py``). Block 0 is never
@@ -270,8 +284,12 @@ class StateManager:
 
     def __init__(self, max_sequences: int, num_blocks: int, block_size: int,
                  max_blocks_per_seq: int, prefix_cache: bool = False,
-                 max_retained_blocks: int = -1):
+                 max_retained_blocks: int = -1, state_slot_bytes: int = 0):
         self.block_size = block_size
+        # recurrent state (0: none): one fixed-size row a slot, allocated
+        # with the pool - a slot IS its state row, so a free slot is the
+        # admission check for it and these bytes are what it stands for
+        self.state_slot_bytes = state_slot_bytes
         self.max_sequences = max_sequences
         self.max_blocks_per_seq = max_blocks_per_seq
         self.allocator = BlockedAllocator(num_blocks)
@@ -295,6 +313,11 @@ class StateManager:
     @property
     def free_slots(self) -> int:
         return len(self._free_slots)
+
+    @property
+    def state_bytes_free(self) -> int:
+        """Recurrent-state bytes of the slots no sequence holds."""
+        return self.free_slots * self.state_slot_bytes
 
     @property
     def retained_blocks(self) -> int:
@@ -357,7 +380,10 @@ class StateManager:
         ``admit_prompt``/``extend`` before an allocation can fail, so
         admission pressure drains the prefix pool before this reports
         False (with the cache off, the retained pool is always empty and
-        this is exactly the free-list check)."""
+        this is exactly the free-list check). A family's recurrent state
+        is one row a slot (``state_slot_bytes``, 80.2 MB a sequence for
+        Granite-4.0-H-Micro where its KV is 8 KB a token): the free slot
+        checked here is that row, so no sequence is admitted without one."""
         avail = self.allocator.free_blocks + self.index.retained_blocks
         return bool(self._free_slots) and avail >= self._admit_need(prompt_len)
 

@@ -14,6 +14,13 @@ scan's carry, every layer writes its rows in place (``paged_kv_write``) and
 both attention kernels take the layer as an index into the ``[L, ...]``
 buffer. No layer's pool is sliced out and none is stacked back.
 
+Heads narrower than a lane tile (``init_paged_pools(lane_pack=True)``): as
+many KV heads as fill 128 lanes lie side by side in one pool row,
+``[L, blocks, nkv / pack, bs, pack * hd]`` - the same bytes in whole tiles.
+:func:`paged_attention_step` reads the packing off the pool's shape and
+packs and unpacks its operands around the same three ops, so a family hands
+it q, k and v at their own head size.
+
 Quantized KV mode (``inference.kv_quant``, docs/serving.md "Quantized KV
 cache"): the cache dict additionally carries ``k_scale``/``v_scale`` pools
 ``[num_layers, num_blocks, kv_heads, block_size, ngroups]`` fp32, K/V pools
@@ -41,9 +48,23 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def lane_pack_of(num_kv_heads: int, head_size: int) -> int:
+    """KV heads that share one row of a lane-packed pool's last axis: as
+    many as fill a 128-lane tile and divide the heads. A head of 64 is half
+    a tile: the device's default layout of a ``[.., bs, 64]`` pool is then
+    NOT row-major (it makes the blocks axis minor rather than pad 64 lanes
+    to 128), a Mosaic operand must be, and every program re-laid both pools
+    out on its way in and on its way out (738 MB of copies a call at two
+    attention layers of Granite-4.0-H-Micro's pool, compiled for a
+    described v5e; PERF.md Findings, PR 31)."""
+    lanes = max(1, 128 // head_size)
+    return max(p for p in range(1, lanes + 1) if num_kv_heads % p == 0)
+
+
 def init_paged_pools(num_layers: int, num_blocks: int, num_kv_heads: int,
                      block_size: int, head_size: int, dtype=jnp.bfloat16,
-                     kv_quant_group: Optional[int] = None):
+                     kv_quant_group: Optional[int] = None,
+                     lane_pack: bool = False):
     """The one cache-pool constructor every family's ``init_paged_cache``
     delegates to. Plain mode returns the historical ``{"k", "v"}`` dict;
     with ``kv_quant_group`` set (``inference.kv_quant.group_size``, clamped
@@ -52,8 +73,16 @@ def init_paged_pools(num_layers: int, num_blocks: int, num_kv_heads: int,
     per-block scale table that every block-lifecycle op (COW copy, fork,
     spill, truncate) carries automatically because it is part of the cache
     pytree. Scales init to ZERO so unwritten positions and the trash block
-    dequantize to exactly the bf16 pool's zeros."""
-    shape = (num_layers, num_blocks, num_kv_heads, block_size, head_size)
+    dequantize to exactly the bf16 pool's zeros. ``lane_pack``: heads
+    narrower than a lane tile share pool rows (:func:`lane_pack_of`). A
+    family opts in, and the choice is not made from the head size alone:
+    packed rows have no quantized mode (the scale pools are a head's), and
+    a tensor-parallel cache shards the KV-heads axis that packing folds."""
+    pack = lane_pack_of(num_kv_heads, head_size) if lane_pack else 1
+    if pack > 1 and kv_quant_group is not None:
+        raise ValueError("lane-packed pools have no quantized mode")
+    shape = (num_layers, num_blocks, num_kv_heads // pack, block_size,
+             pack * head_size)
     if kv_quant_group is None:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     gs = min(int(kv_quant_group), head_size)
@@ -117,7 +146,7 @@ def scan_layers(body, x, layers, cache, *extras):
 
 def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
                          context_lens, positions, valid, *,
-                         window=None) -> Tuple:
+                         window=None, scale=None) -> Tuple:
     """Write this step's K/V into the block pool, then attend over it.
 
     q [b, t, nh, hd]; k/v [b, t, nkv, hd]. ``k_cache``/``v_cache`` are the
@@ -126,18 +155,27 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
     [b, t] is a prefix mask of each sequence's real rows: a padded row
     writes nothing and its output is unspecified. ``window``: optional
     per-layer sliding-window length (int or traced scalar — exaone4 scans
-    per-layer windows). The write is ``paged_kv_write`` (fill-time
+    per-layer windows). ``scale``: the softmax scale of ``q k^T``, handed to
+    both kernels (None: ``hd ** -0.5``; Granite's ``attention_multiplier``
+    is another). The write is ``paged_kv_write`` (fill-time
     quantization in the same call in quantized mode); single-token decode
     then dispatches the paged flash-decode kernel, every multi-token call
     the paged flash-prefill kernel (each windowed or plain-causal, with the
     dequant fused in quantized mode), all three on the layer's index into
-    the ``[L, ...]`` pools. Returns (attn_out [b, t, nh, hd], k_cache,
-    v_cache) with the written pools in the entries."""
+    the ``[L, ...]`` pools. Lane-packed pools (:func:`lane_pack_of`) are
+    known by their rows, ``pack`` times as wide as ``k``'s heads. Returns
+    (attn_out [b, t, nh, hd], k_cache, v_cache) with the written pools in
+    the entries."""
     del positions
     from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
     from ..ops.registry import get_op
 
     t = q.shape[1]
+    pack = k_cache.pool.shape[-1] // k.shape[-1]
+    if pack > 1:
+        if scale is None:
+            scale = q.shape[-1] ** -0.5     # of the head, not of the row
+        q, k, v, unpack = _lane_packed(q, k, v, pack)
     layer = k_cache.layer
     # ``valid`` is a prefix mask, so its sum is each sequence's count of
     # real rows
@@ -161,7 +199,7 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
     if t == 1:
         out = get_op("paged_decode_attention")(
             q[:, 0], k_pool, v_pool, block_tables, context_lens,
-            window=window, layer=layer, **scales)[:, None]
+            scale=scale, window=window, layer=layer, **scales)[:, None]
     else:
         # every multi-token call - a prefill chunk at a context offset, a
         # batched prefill, a prefix-cache suffix, a speculative verify
@@ -170,6 +208,30 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
         # every op.
         out = get_op("paged_prefill_attention")(
             q, k_pool, v_pool, block_tables, context_lens, n_valid,
-            window=window, layer=layer, **scales)
+            scale=scale, window=window, layer=layer, **scales)
+    if pack > 1:
+        out = unpack(out)
     return (out, LayerPool(k_pool, k_scale, layer),
             LayerPool(v_pool, v_scale, layer))
+
+
+def _lane_packed(q, k, v, pack: int):
+    """A step's operands as lane-packed pools take them, and the way back
+    for the output. A packed row is ``pack`` heads' keys (values) side by
+    side, which is the projection's own memory order, so K and V are only
+    reshaped. A query head meets its own KV head's lanes and zeros
+    elsewhere - the other heads' lanes add nothing to its scores -, and of
+    the output row (every packed head's weighted values) it keeps its own
+    head's lanes. Query heads stay in order: head ``kv * g + i`` is row
+    ``(kv % pack) * g + i`` of packed head ``kv // pack``'s group."""
+    b, t, nh, hd = q.shape
+    nkv = k.shape[2]
+    mine = jax.nn.one_hot((jnp.arange(nh) // (nh // nkv)) % pack, pack,
+                          dtype=q.dtype)[:, :, None]        # [nh, pack, 1]
+    q = (q[:, :, :, None, :] * mine).reshape(b, t, nh, pack * hd)
+    k, v = (a.reshape(b, t, nkv // pack, pack * hd) for a in (k, v))
+
+    def unpack(out):
+        return jnp.sum(out.reshape(b, t, nh, pack, hd) * mine, axis=3)
+
+    return q, k, v, unpack

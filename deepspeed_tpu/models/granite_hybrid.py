@@ -1,0 +1,569 @@
+"""Granite-4.0-H family (HF ``granitemoehybrid``, dense variant): a stack of
+TWO kinds of layer - Mamba-2 state-space mixers with a full-attention layer
+among every few - each followed by a fused gate-and-up SwiGLU
+(``shared_mlp``; the sparse branch of the family is not here:
+``num_local_experts`` must be 0).
+
+    x = embedding_multiplier * E[token]
+    x = x + residual_multiplier * mixer(RMSNorm(x))
+    x = x + residual_multiplier * mlp(RMSNorm(x))            (every layer)
+    logits = RMSNorm(x) E^T / logits_scaling                 (tied table)
+
+Attention layers: GQA without bias and WITHOUT positional embedding
+(``position_embedding_type`` "nope"), softmax of ``attention_multiplier * q
+k^T``. Mamba-2 layers: ``in_proj -> [z | xBC | dt]``; ``xBC <- silu(conv1d(xBC)
++ b)`` (depthwise, causal, over the sequence's own previous ``K - 1`` rows);
+``xBC -> x | B | C``; ``dt <- softplus(dt + dt_bias)``; per head ``H_t =
+exp(dt_t A) H_(t-1) + dt_t x_t B_t^T``, ``y_t = H_t C_t + D x_t``; ``y <-
+RMSNorm(y * silu(z))`` (gate first, then norm) and ``out_proj``. (The
+published ``in_proj`` is kept as two matrices: its ``[z | xBC]`` columns and
+its ``dt`` columns, ``dt_proj``.)
+
+Layout: weights are stacked BY KIND (``params["mamba"]`` ``[L_mamba, ...]``,
+``params["attn"]`` ``[L_attn, ...]``) and the stack runs as the scan nest
+``layer_types`` spells: an outer scan over the pattern's periods whose body
+scans each run of one kind (Granite-4.0-H-Micro: 4 x [5 Mamba, 1 attention,
+4 Mamba]), so one body a kind of run is compiled whatever the depth.
+
+Serving. The cache has two kinds of leaf, all carried through the scan nest
+and written where they lie: the attention layers' paged ``k``/``v`` pools
+``[L_attn, blocks, nkv / pack, bs, pack * hd]`` (``_paged.
+paged_attention_step``, as every family; lane-packed there: two heads of 64
+share a 128-lane row), and one fixed-size row a SEQUENCE SLOT a Mamba layer
+with no block axis - ``ssm [L_mamba, slots + 1, N + 8, heads * P]`` (float32: a KV entry
+is rounded once, this state is rewritten every token and its rounding
+compounds), the state on a row's first ``N`` sublanes and the convolution's
+tail ``[K - 1, conv_dim]`` under it (``tail_part``; ``ops/ssm.py`` has the
+layout, the trash row and why one pool). A
+call row is addressed by its sequence's slot; a row whose context offset is
+0 starts from zeros inside the program; a row that is not ``valid`` leaves
+both states exactly as they were. Only ``ops/pallas/ssm.py``'s kernels touch
+the state pools.
+"""
+
+from __future__ import annotations
+
+import itertools
+import math
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..ops import ssm
+from ..ops.attention import attention
+from ..ops.embedding import embedding_lookup
+from ..ops.norms import rms_norm
+from ..ops.pallas import ssm as _ssm_kernels  # noqa: F401 (registers)
+from ..ops.registry import get_op
+from ._paged import LayerPool, init_paged_pools, paged_attention_step
+
+Params = Dict[str, Any]
+F32 = jnp.float32
+KINDS = {"mamba": "mamba", "attention": "attn"}   # layer type -> params key
+STATE_LEAVES = ("ssm",)           # the cache leaves with no block axis
+
+
+@dataclass(frozen=True)
+class GraniteHybridConfig:
+    vocab_size: int = 100352
+    hidden_size: int = 2048
+    intermediate_size: int = 8192        # shared_intermediate_size
+    layer_types: Tuple[str, ...] = (("mamba",) * 5 + ("attention",)
+                                    + ("mamba",) * 4) * 4
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: Optional[int] = None
+    max_seq_len: int = 131072
+    rms_norm_eps: float = 1e-5
+    embedding_multiplier: float = 12.0
+    attention_multiplier: float = 0.015625
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 8.0
+    mamba_heads: int = 64
+    mamba_head_dim: int = 64
+    mamba_state: int = 128
+    mamba_conv: int = 4
+    mamba_chunk: int = 256      # how the scan is blocked, not part of the result
+    state_dtype: str = "float32"
+    compute_dtype: str = "bfloat16"
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_state      # one group of B, C
+
+    @property
+    def state_part(self) -> Tuple[int, int, int]:
+        """The recurrent state's part of a slot's row: ``(first sublane,
+        sublanes, lanes)`` = the first ``N`` sublanes, every lane."""
+        return 0, self.mamba_state, self.d_inner
+
+    @property
+    def tail_part(self) -> Tuple[int, int, int]:
+        """The convolution tail's part of a slot's row, under the state:
+        ``[K - 1, conv_dim]`` flattened into whole sublanes of as few whole
+        128-lane tiles as hold it (8 x 1664 for the published 3 x 4352)."""
+        flat = (self.mamba_conv - 1) * self.conv_dim
+        width = self.d_inner
+        sublanes = 8 * -(-flat // (8 * width))
+        lanes = width if width % 128 else min(
+            width, -(-flat // (sublanes * 128)) * 128)
+        assert self.mamba_state % sublanes == 0, \
+            "the tail starts on a block of its own size"
+        return self.mamba_state, sublanes, lanes
+
+    def count(self, kind: str) -> int:
+        return sum(t == kind for t in self.layer_types)
+
+    @classmethod
+    def tiny(cls, periods: int = 1, **kw) -> "GraniteHybridConfig":
+        """A whole 10-layer period a ``periods`` at the published RATIOS of
+        widths (inner = 2 x hidden = heads x head size, group 4, N = 2 x
+        the Mamba head size), for CPU tests."""
+        base = dict(vocab_size=256, hidden_size=32, intermediate_size=128,
+                    layer_types=(("mamba",) * 5 + ("attention",)
+                                 + ("mamba",) * 4) * periods,
+                    num_heads=8, num_kv_heads=2, max_seq_len=256,
+                    mamba_heads=8, mamba_head_dim=8, mamba_state=16,
+                    mamba_chunk=16)
+        base.update(kw)
+        return cls(**base)
+
+
+def _check(cfg: GraniteHybridConfig) -> None:
+    unknown = set(cfg.layer_types) - set(KINDS)
+    if unknown:
+        raise ValueError(f"layer_types names {sorted(unknown)}; this family "
+                         f"has {sorted(KINDS)}")
+
+
+def layer_plan(layer_types) -> Tuple[int, list, Dict[str, int]]:
+    """``(periods, runs, layers of each kind a period)``: the smallest
+    period the pattern repeats with, and that period's runs of one kind as
+    ``(kind, first layer of the kind inside the period, count)``."""
+    n = len(layer_types)
+    period = next(p for p in range(1, n + 1) if n % p == 0 and all(
+        layer_types[i] == layer_types[i % p] for i in range(n)))
+    runs, seen = [], {kind: 0 for kind in KINDS}
+    for kind, group in itertools.groupby(layer_types[:period]):
+        count = len(list(group))
+        runs.append((kind, seen[kind], count))
+        seen[kind] += count
+    return n // period, runs, seen
+
+
+# --------------------------------------------------------------------------- #
+# parameters
+# --------------------------------------------------------------------------- #
+def init(cfg: GraniteHybridConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
+    """Random weights: fan-in scaled normals; Mamba-2's published
+    initialisation for ``A_log`` (log of uniform 1-16), ``dt_bias`` (inverse
+    softplus of log-uniform 0.001-0.1) and ``D`` (ones); the convolution as
+    ``torch.nn.Conv1d`` draws it (uniform within ``K ** -0.5``); the tied
+    table at standard deviation ``logits_scaling / sqrt(hidden)``, so that
+    logits (a unit-RMS vector times the table, over ``logits_scaling``) have
+    about unit variance, as an untied fan-in head gives."""
+    _check(cfg)
+    h, i, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
+    H, K, C, d_in = cfg.mamba_heads, cfg.mamba_conv, cfg.conv_dim, cfg.d_inner
+    keys = iter(jax.random.split(rng, 16))
+
+    def normal(shape, fan_in):
+        # a layer at a time: the float32 draw of a whole stack (4.8 GB for
+        # the 36 fused gate-and-up matrices) must not stand beside the model
+        one = lambda key: (jax.random.normal(key, shape[1:], F32)
+                           * fan_in ** -0.5).astype(dtype)
+        return lax.map(one, jax.random.split(next(keys), shape[0]))
+
+    def uniform(shape, lo, hi):
+        return jax.random.uniform(next(keys), shape, F32, lo, hi)
+
+    def mlp(n):
+        return {"mlp_norm": jnp.ones((n, h), dtype),
+                "w_in": normal((n, h, 2 * i), h),
+                "w_out": normal((n, i, h), i)}
+
+    m, a = cfg.count("mamba"), cfg.count("attention")
+    dt = jnp.exp(uniform((m, H), math.log(1e-3), math.log(1e-1)))
+    params: Params = {
+        "embed": (normal((v, h), h) * cfg.logits_scaling).astype(dtype),
+        "final_norm": jnp.ones((h,), dtype),
+        "mamba": {
+            "norm": jnp.ones((m, h), dtype),
+            # in_proj's columns [z | xBC] and, by themselves, [dt]: the whole
+            # 8512 is no multiple of 128 lanes, the device's default layout
+            # of the stack is then not row-major, and every program copied
+            # all 1.25 GB of it (compiled for a described v5e)
+            "in_proj": normal((m, h, d_in + C), h),
+            "dt_proj": normal((m, h, H), h),
+            "conv_w": uniform((m, K, C), -K ** -0.5, K ** -0.5).astype(dtype),
+            "conv_b": uniform((m, C), -K ** -0.5, K ** -0.5).astype(dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "A_log": jnp.log(uniform((m, H), 1.0, 16.0)).astype(dtype),
+            "D": jnp.ones((m, H), dtype),
+            "gate_norm": jnp.ones((m, d_in), dtype),
+            "out_proj": normal((m, d_in, h), d_in),
+            **mlp(m)},
+        "attn": {
+            "norm": jnp.ones((a, h), dtype),
+            "wq": normal((a, h, nh * hd), h),
+            "wk": normal((a, h, nkv * hd), h),
+            "wv": normal((a, h, nkv * hd), h),
+            "wo": normal((a, nh * hd, h), nh * hd),
+            **mlp(a)},
+    }
+    return params
+
+
+def param_logical_axes(cfg: GraniteHybridConfig) -> Params:
+    """Attention and feed-forward as ``llama``; the Mamba mixer's own
+    weights unsharded (one chip serves the model whole; a tensor-parallel
+    mixer splits its heads and is not written)."""
+    mlp = {"mlp_norm": ("layers", "embed"), "w_in": ("layers", "embed", None),
+           "w_out": ("layers", "mlp", "embed")}
+    flat = ("layers", None)
+    return {
+        "embed": ("vocab", "embed"), "final_norm": ("embed",),
+        "mamba": {"norm": ("layers", "embed"),
+                  "in_proj": ("layers", "embed", None),
+                  "dt_proj": ("layers", "embed", None),
+                  "conv_w": ("layers", None, None), "conv_b": flat,
+                  "dt_bias": flat, "A_log": flat, "D": flat,
+                  "gate_norm": flat, "out_proj": ("layers", None, "embed"),
+                  **mlp},
+        "attn": {"norm": ("layers", "embed"),
+                 "wq": ("layers", "embed", "heads"),
+                 "wk": ("layers", "embed", "kv_heads"),
+                 "wv": ("layers", "embed", "kv_heads"),
+                 "wo": ("layers", "heads", "embed"), **mlp},
+    }
+
+
+# --------------------------------------------------------------------------- #
+# the blocks
+# --------------------------------------------------------------------------- #
+def _mlp(cfg, x, w):
+    with jax.named_scope("norm"):
+        y = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("ffn"):
+        gate, up = jnp.split(y @ w["w_in"], 2, axis=-1)
+        return x + cfg.residual_multiplier * (
+            (jax.nn.silu(gate) * up) @ w["w_out"])
+
+
+def _qkv(cfg, y, w):
+    b, t, _ = y.shape
+    nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
+    return ((y @ w["wq"]).reshape(b, t, nh, hd),
+            (y @ w["wk"]).reshape(b, t, nkv, hd),
+            (y @ w["wv"]).reshape(b, t, nkv, hd))
+
+
+def _mixer_in(cfg, y, w, token_valid):
+    """``in_proj`` and what the recurrence takes of it: ``(z, xBC, dt, A)``
+    with ``dt`` float32 after its softplus and 0 on padding."""
+    with jax.named_scope("ssm_proj"):
+        z, xbc = jnp.split(y @ w["in_proj"], [cfg.d_inner], axis=-1)
+        dt = jax.nn.softplus((y @ w["dt_proj"]).astype(F32)
+                             + w["dt_bias"].astype(F32))
+        dt = jnp.where(token_valid[..., None], dt, 0.0)
+        return z, xbc, dt, -jnp.exp(w["A_log"].astype(F32))
+
+
+def _conv(cfg, xbc, tail, w):
+    """The causal depthwise convolution of ``xbc [b, t, C]`` after the
+    sequence's previous ``K - 1`` rows ``tail``: ``(silu(conv + b) -> x, B,
+    C``, the rows it ran over ``[b, K - 1 + t, C])``."""
+    t = xbc.shape[1]
+    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    taps = w["conv_w"].astype(F32)
+    out = sum(ext[:, k:k + t].astype(F32) * taps[k]
+              for k in range(cfg.mamba_conv)) + w["conv_b"].astype(F32)
+    x, B, C = jnp.split(jax.nn.silu(out).astype(xbc.dtype),
+                        [cfg.d_inner, cfg.d_inner + cfg.mamba_state], axis=-1)
+    return x, B, C, ext
+
+
+def _pack_tail(cfg, tail):
+    """``[b, K - 1, C]`` as its part of the pool's rows (``cfg.tail_part``),
+    in the pool's type: every value of the compute type is one of it."""
+    b = tail.shape[0]
+    _, sublanes, lanes = cfg.tail_part
+    flat = tail.reshape(b, -1)
+    return jnp.pad(flat, ((0, 0), (0, sublanes * lanes - flat.shape[1]))) \
+        .reshape(b, sublanes, lanes)
+
+
+def _unpack_tail(cfg, part, dtype):
+    b = part.shape[0]
+    k, c = cfg.mamba_conv - 1, cfg.conv_dim
+    return part.reshape(b, -1)[:, :k * c].reshape(b, k, c).astype(dtype)
+
+
+def _mixer_out(cfg, y, x, z, w):
+    """The skip ``D x``, the gate, the norm over the whole inner width (gate
+    first, then norm) and ``out_proj``. ``y [b, t, heads * P]`` float32."""
+    with jax.named_scope("ssm_proj"):
+        y = y + jnp.repeat(w["D"].astype(F32), cfg.mamba_head_dim) \
+            * x.astype(F32)
+        y = (y * jax.nn.silu(z.astype(F32))).astype(z.dtype)
+        return rms_norm(y, w["gate_norm"], cfg.rms_norm_eps) @ w["out_proj"]
+
+
+def _mamba_mixer(cfg, y, w, tail, h0, token_valid):
+    """The mixer over whole rows of tokens from the given state:
+    ``(out [b, t, h], the convolution's rows, state after the last real
+    token [b, H, P, N])``."""
+    b, t, _ = y.shape
+    z, xbc, dt, A = _mixer_in(cfg, y, w, token_valid)
+    with jax.named_scope("ssm_conv"):
+        x, B, C, ext = _conv(cfg, xbc, tail, w)
+    with jax.named_scope("ssm_state"):
+        mixed, h_t = ssm.ssd_chunked_scan(
+            x.reshape(b, t, cfg.mamba_heads, cfg.mamba_head_dim), dt, A, B,
+            C, h0, cfg.mamba_chunk)
+    return _mixer_out(cfg, mixed.reshape(b, t, -1), x, z, w), ext, h_t
+
+
+def _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid):
+    """One Mamba layer over the state pools: row i's state is read at
+    ``[index, rows[i]]`` (zeros where ``fresh[i]``), advanced over the row's
+    real tokens and written back there. ``rows`` already aims rows that must
+    write nothing at the trash row."""
+    b, t, _ = x.shape
+    read, write = get_op("state_rows_read"), get_op("state_rows_write")
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    state = pools["ssm"]
+    with jax.named_scope("norm"):
+        y = rms_norm(x, w["norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn"):       # this layer's token mixer
+        with jax.named_scope("ssm_conv"):
+            tail = jnp.where(fresh[:, None, None], 0, _unpack_tail(
+                cfg, read(state, index, rows, cfg.tail_part), x.dtype))
+        if t == 1:
+            z, xbc, dt, A = _mixer_in(cfg, y, w, valid)
+            with jax.named_scope("ssm_conv"):
+                xs, B, C, ext = _conv(cfg, xbc, tail, w)
+                state = write(state, index, rows,
+                              _pack_tail(cfg, ext[:, 1:]), cfg.tail_part)
+            with jax.named_scope("ssm_state"):
+                per_lane = lambda a: jnp.repeat(a, cfg.mamba_head_dim, axis=-1)
+                state, mixed = get_op("ssm_decode_update")(
+                    state, index, rows, fresh,
+                    per_lane(jnp.exp(dt[:, 0] * A)),
+                    per_lane(dt[:, 0]) * xs[:, 0].astype(F32),
+                    B[:, 0], C[:, 0])
+            out = _mixer_out(cfg, mixed[:, None], xs, z, w)
+        else:
+            with jax.named_scope("ssm_state"):
+                h0 = jnp.where(fresh[:, None, None, None], 0.0,
+                               ssm.state_to_heads(
+                                   read(state, index, rows, cfg.state_part),
+                                   cfg.mamba_heads))
+            out, ext, h_t = _mamba_mixer(cfg, y, w, tail, h0, valid)
+            with jax.named_scope("ssm_conv"):
+                # the last K - 1 rows of [tail | the row's real tokens]
+                new_tail = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
+                    e, n, cfg.mamba_conv - 1, axis=0))(ext, n_valid)
+                state = write(state, index, rows,
+                              _pack_tail(cfg, new_tail), cfg.tail_part)
+            with jax.named_scope("ssm_state"):
+                state = write(state, index, rows,
+                              ssm.state_from_heads(h_t), cfg.state_part)
+        x = x + cfg.residual_multiplier * out
+    return _mlp(cfg, x, w), {**pools, "ssm": state}
+
+
+def _attention_paged(cfg, x, w, pools, index, tables, ctx, positions, valid):
+    b, t, _ = x.shape
+    with jax.named_scope("norm"):
+        y = rms_norm(x, w["norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn"):   # the pool update inside is "kv_write"
+        q, k, v = _qkv(cfg, y, w)
+        out, k_c, v_c = paged_attention_step(
+            q, k, v, LayerPool(pools["k"], None, index),
+            LayerPool(pools["v"], None, index), tables, ctx, positions,
+            valid, scale=cfg.attention_multiplier)
+        x = x + cfg.residual_multiplier * (out.reshape(b, t, -1) @ w["wo"])
+    return _mlp(cfg, x, w), {**pools, "k": k_c.pool, "v": v_c.pool}
+
+
+def _scan_nest(cfg, x, layers, pools, blocks):
+    """The stack as ``layer_types`` spells it: an outer scan over the
+    pattern's periods whose body scans each run of one kind. ``layers`` is
+    the weights stacked by kind; a layer takes its own by its index into
+    them (what a scan's per-step slice of its inputs is), so no period's
+    slab is cut out on the way. ``blocks[kind](x, weights, pools, index) ->
+    (x, pools)``; ``pools`` (None without a cache) is the carry of every
+    scan, beside ``x``."""
+    periods, runs, per_period = layer_plan(cfg.layer_types)
+
+    def run(kind, carry, first, count):
+        def step(carry, index):
+            w = jax.tree.map(lambda p: lax.dynamic_index_in_dim(
+                p, index, 0, keepdims=False), layers[KINDS[kind]])
+            x, pools = carry
+            return blocks[kind](x, w, pools, index), None
+
+        return lax.scan(step, carry,
+                        first + jnp.arange(count, dtype=jnp.int32))[0]
+
+    def period(carry, p):
+        for kind, first, count in runs:
+            carry = run(kind, carry, p * per_period[kind] + first, count)
+        return carry, None
+
+    with jax.named_scope("kv_write"):   # as _paged.scan_layers names its scan
+        return lax.scan(period, (x, pools),
+                        jnp.arange(periods, dtype=jnp.int32))[0]
+
+
+def _compute_layers(cfg, params, compute_dtype):
+    compute_dtype = jnp.dtype(compute_dtype or cfg.compute_dtype)
+    cast = lambda p: p.astype(compute_dtype) \
+        if jnp.issubdtype(p.dtype, jnp.floating) else p
+    return compute_dtype, {k: jax.tree.map(cast, params[k])
+                           for k in KINDS.values()}
+
+
+def _embed(cfg, params, tokens, compute_dtype):
+    with jax.named_scope("embed"):
+        return embedding_lookup(params["embed"], tokens, compute_dtype) \
+            * jnp.asarray(cfg.embedding_multiplier, compute_dtype)
+
+
+def _logits(cfg, params, x, compute_dtype):
+    with jax.named_scope("norm"):
+        x = rms_norm(x, params["final_norm"].astype(compute_dtype),
+                     cfg.rms_norm_eps)
+    with jax.named_scope("logits"):      # the tied table
+        return (x @ params["embed"].astype(compute_dtype).T) \
+            .astype(F32) / cfg.logits_scaling
+
+
+# --------------------------------------------------------------------------- #
+# entry points
+# --------------------------------------------------------------------------- #
+def apply(cfg: GraniteHybridConfig, params: Params, tokens: jnp.ndarray, *,
+          compute_dtype=None) -> jnp.ndarray:
+    """Whole sequences with no cache: ``tokens [b, s]`` -> logits ``[b, s,
+    vocab]`` float32. Every Mamba layer starts from a zero state."""
+    _check(cfg)
+    b, s = tokens.shape
+    compute_dtype, layers = _compute_layers(cfg, params, compute_dtype)
+    everywhere = jnp.ones((b, s), bool)
+
+    def mamba(x, w, _pools, _index):
+        with jax.named_scope("norm"):
+            y = rms_norm(x, w["norm"], cfg.rms_norm_eps)
+        with jax.named_scope("attn"):
+            out, _, _ = _mamba_mixer(
+                cfg, y, w,
+                jnp.zeros((b, cfg.mamba_conv - 1, cfg.conv_dim), x.dtype),
+                jnp.zeros((b, cfg.mamba_heads, cfg.mamba_head_dim,
+                           cfg.mamba_state), F32), everywhere)
+            x = x + cfg.residual_multiplier * out
+        return _mlp(cfg, x, w), None
+
+    def attn(x, w, _pools, _index):
+        with jax.named_scope("norm"):
+            y = rms_norm(x, w["norm"], cfg.rms_norm_eps)
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(cfg, y, w)
+            out = attention(q, k, v, causal=True,
+                            scale=cfg.attention_multiplier)
+            x = x + cfg.residual_multiplier * (out.reshape(b, s, -1) @ w["wo"])
+        return _mlp(cfg, x, w), None
+
+    x, _ = _scan_nest(cfg, _embed(cfg, params, tokens, compute_dtype), layers,
+                      None, {"mamba": mamba, "attention": attn})
+    return _logits(cfg, params, x, compute_dtype)
+
+
+def state_slot_bytes(cfg: GraniteHybridConfig) -> int:
+    """Bytes of recurrent state ONE sequence slot holds over every Mamba
+    layer (the state and, under it, the convolution's tail, in
+    ``state_dtype``): what an admission occupies beside its KV blocks. Its
+    presence is how a family declares recurrent state to the engine."""
+    return cfg.count("mamba") * _state_sublanes(cfg) * cfg.d_inner \
+        * jnp.dtype(cfg.state_dtype).itemsize
+
+
+def _state_sublanes(cfg) -> int:
+    return cfg.mamba_state + cfg.tail_part[1]
+
+
+def init_paged_cache(cfg: GraniteHybridConfig, num_blocks: int,
+                     block_size: int, dtype=jnp.bfloat16,
+                     slots: int = 1) -> Params:
+    """The attention layers' block pools (``_paged.init_paged_pools``) and
+    the Mamba layers' per-slot pool, ``slots`` rows and the trash row
+    (the engine passes its ``max_tracked_sequences``). No quantized-KV mode."""
+    _check(cfg)
+    m = cfg.count("mamba")
+    return {
+        **init_paged_pools(cfg.count("attention"), num_blocks,
+                           cfg.num_kv_heads, block_size, cfg.head_size,
+                           dtype, lane_pack=True),
+        "ssm": jnp.zeros((m, slots + 1, _state_sublanes(cfg), cfg.d_inner),
+                         jnp.dtype(cfg.state_dtype))}
+
+
+def apply_paged(cfg: GraniteHybridConfig, params: Params,
+                tokens: jnp.ndarray, cache: Params,
+                block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,
+                valid: Optional[jnp.ndarray] = None,
+                slots: Optional[jnp.ndarray] = None,
+                compute_dtype=None) -> Tuple[jnp.ndarray, Params]:
+    """Ragged forward over the two-kind cache (prefill rows, chunks or
+    decode steps): ``llama.apply_paged``'s contract, and ``slots [b]``, each
+    row's sequence slot (``arange(b)`` when not given: row i is slot i). A
+    row at context offset 0 starts its recurrent state from zeros; a row
+    with no valid token leaves its slot's state as it was."""
+    _check(cfg)
+    b, t = tokens.shape
+    if valid is None:
+        valid = jnp.ones((b, t), bool)
+    if slots is None:
+        slots = jnp.arange(b, dtype=jnp.int32)
+    compute_dtype, layers = _compute_layers(cfg, params, compute_dtype)
+    positions = context_lens[:, None] + jnp.arange(t)[None, :]
+    rows = ssm.pool_rows(slots, valid[:, 0], cache["ssm"])
+    fresh = context_lens == 0
+
+    def mamba(x, w, pools, index):
+        return _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid)
+
+    def attn(x, w, pools, index):
+        return _attention_paged(cfg, x, w, pools, index, block_tables,
+                                context_lens, positions, valid)
+
+    x, cache = _scan_nest(cfg, _embed(cfg, params, tokens, compute_dtype),
+                          layers, dict(cache),
+                          {"mamba": mamba, "attention": attn})
+    return _logits(cfg, params, x, compute_dtype), cache
+
+
+def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):
+    raise NotImplementedError(
+        "granite_hybrid has no dense-cache path (engine v1); serve it "
+        "through build_engine_v2 (the paged cache with per-slot state)")
+
+
+def apply_cached(cfg, params, tokens, cache, cache_len, **kw):
+    init_cache(cfg, 0, 0)

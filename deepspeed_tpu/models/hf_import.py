@@ -1200,6 +1200,95 @@ def exaone4_params_from_hf(src, cfg=None) -> Params:
     return params
 
 
+def granitemoehybrid_config_from_hf(hf_config) -> "Any":
+    """HF ``GraniteMoeHybridConfig`` (the dense variant: no sparse branch) →
+    ``models/granite_hybrid.GraniteHybridConfig``. What the module does not
+    have is refused, never dropped."""
+    from .granite_hybrid import GraniteHybridConfig
+
+    get = lambda key, default=None: getattr(hf_config, key, default)
+    if get("num_local_experts", 0) or get("mamba_n_groups", 1) != 1 \
+            or get("attention_bias") or get("mamba_proj_bias") \
+            or get("position_embedding_type", "nope") != "nope" \
+            or not get("tie_word_embeddings", True):
+        raise ValueError(
+            "models/granite_hybrid.py runs the dense hybrid as Granite-4.0-H-"
+            "Micro publishes it: no sparse branch, one group of B and C, no "
+            "biases on the projections, no positional embedding, a tied head")
+    return GraniteHybridConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.shared_intermediate_size,
+        layer_types=tuple(hf_config.layer_types),
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        max_seq_len=hf_config.max_position_embeddings,
+        rms_norm_eps=float(hf_config.rms_norm_eps),
+        embedding_multiplier=float(hf_config.embedding_multiplier),
+        attention_multiplier=float(hf_config.attention_multiplier),
+        residual_multiplier=float(hf_config.residual_multiplier),
+        logits_scaling=float(hf_config.logits_scaling),
+        mamba_heads=hf_config.mamba_n_heads,
+        mamba_head_dim=hf_config.mamba_d_head,
+        mamba_state=hf_config.mamba_d_state,
+        mamba_conv=hf_config.mamba_d_conv,
+        mamba_chunk=hf_config.mamba_chunk_size)
+
+
+def granitemoehybrid_params_from_hf(src, cfg) -> Params:
+    """HF ``GraniteMoeHybridForCausalLM`` → ``models/granite_hybrid`` pytree:
+    the layers stacked BY KIND in stack order, ``mamba.in_proj`` split into
+    its ``[z | xBC]`` and ``dt`` columns, the depthwise ``conv1d`` as ``[K,
+    channels]`` taps, ``shared_mlp``'s fused gate-and-up kept fused, the
+    tied table once."""
+    sd = _normalize_state_dict(src)
+    lay = "model.layers.{i}."
+    by_kind = {kind: [i for i, t in enumerate(cfg.layer_types) if t == kind]
+               for kind in ("mamba", "attention")}
+
+    def stack(kind, suffix, transpose=False):
+        mats = []
+        for i in by_kind[kind]:
+            key = lay.format(i=i) + suffix
+            if key not in sd:
+                raise KeyError(f"missing weight {key}")
+            mats.append(sd[key].T if transpose else sd[key])
+        return np.stack(mats)
+
+    def block(kind):
+        return {"norm": stack(kind, "input_layernorm.weight"),
+                "mlp_norm": stack(kind, "post_attention_layernorm.weight"),
+                "w_in": stack(kind, "shared_mlp.input_linear.weight", True),
+                "w_out": stack(kind, "shared_mlp.output_linear.weight", True)}
+
+    in_proj = stack("mamba", "mamba.in_proj.weight", transpose=True)
+    split = cfg.d_inner + cfg.conv_dim
+    params: Params = {
+        "embed": sd["model.embed_tokens.weight"],
+        "final_norm": sd["model.norm.weight"],
+        "mamba": {
+            **block("mamba"),
+            "in_proj": in_proj[:, :, :split], "dt_proj": in_proj[:, :, split:],
+            # conv1d.weight [channels, 1, K] -> taps [K, channels]
+            "conv_w": stack("mamba", "mamba.conv1d.weight")[:, :, 0, :]
+            .transpose(0, 2, 1),
+            "conv_b": stack("mamba", "mamba.conv1d.bias"),
+            "dt_bias": stack("mamba", "mamba.dt_bias"),
+            "A_log": stack("mamba", "mamba.A_log"),
+            "D": stack("mamba", "mamba.D"),
+            "gate_norm": stack("mamba", "mamba.norm.weight"),
+            "out_proj": stack("mamba", "mamba.out_proj.weight", True)},
+        "attn": {
+            **block("attention"),
+            **{ours: stack("attention", f"self_attn.{theirs}.weight", True)
+               for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                    ("wv", "v_proj"), ("wo", "o_proj"))}},
+    }
+    log_dist(f"imported HF granitemoehybrid weights: "
+             f"{len(by_kind['mamba'])} mamba + {len(by_kind['attention'])} "
+             f"attention layers")
+    return params
+
+
 def resolve_module(family: str):
     """Family name → the ``deepspeed_tpu.models`` module that executes it."""
     from . import bloom, falcon, gpt, gptneox, llama, mixtral
@@ -1208,6 +1297,10 @@ def resolve_module(family: str):
     from . import clip as clip_mod
     from . import exaone4 as exaone4_mod
 
+    if family == "granitemoehybrid":     # loaded when asked for, not before
+        from . import granite_hybrid
+
+        return granite_hybrid
     modules = {
         "llama": llama, "mistral": llama, "qwen2": llama, "qwen3": llama,
         "phi3": llama,
@@ -1271,6 +1364,8 @@ _FAMILIES = {
     "distilbert": (distilbert_config_from_hf, distilbert_params_from_hf),
     "clip": (clip_config_from_hf, clip_params_from_hf),
     "exaone4": (exaone4_config_from_hf, exaone4_params_from_hf),
+    "granitemoehybrid": (granitemoehybrid_config_from_hf,
+                         granitemoehybrid_params_from_hf),
 }
 
 

@@ -83,6 +83,9 @@ SERVING_SERIES = frozenset(
     # KV cache" — engine_v2.kv_quant_events)
     + ["Serving/kv_quant/" + m for m in (
         "blocks_quantized", "bytes_saved", "max_abs_err", "dequant_fused")]
+    # recurrent state (a family with state-space layers; docs/serving.md
+    # "Recurrent state" - engine_v2.state_events)
+    + ["Serving/state/" + m for m in ("bytes", "bytes_per_slot", "slots_held")]
     + ["Serving/spec/" + m for m in (
         "verify_steps", "decode_steps", "step_seqs", "drafted_tokens",
         "accepted_tokens", "emitted_tokens", "rolled_back_tokens",

@@ -407,6 +407,121 @@ def test_pool_copy_bytes_counts_what_the_scanned_pools_cost(v5e):
             >= blocks * nkv * bs * hd * 2, fn.__name__
 
 
+# --- a family with recurrent state (ISSUE 31) ------------------------------ #
+GRANITE_CELL = "granite-4.0-h-micro.serve-chat-64"
+
+
+def _granite_program(program, periods=1):
+    """The Granite cell's paged forward on shapes at its published widths
+    and its pool geometry, ``periods`` of its 10-layer pattern deep, as the
+    engine calls it: ``(forward, arguments)`` with the cache second."""
+    from benchmark.harness.manifest import Cell
+
+    cell = Cell(GRANITE_CELL)
+    ragged = cell.role["engine"]["ragged"]
+    kinds = cell.model["layer_types"][:10] * periods
+    cfg = cell.family.build_cfg(
+        {**cell.model, "layer_types": kinds, "num_hidden_layers": len(kinds)},
+        **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: module.init(cfg, k), jax.random.PRNGKey(0)))
+    slots = ragged["max_tracked_sequences"]
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, ragged["memory_config_blocks"], ragged["block_size"],
+        slots=slots))
+
+    def forward(params, cache, tokens, tables, ctx, valid, rows):
+        return module.apply_paged(cfg, params, tokens, cache, tables, ctx,
+                                  valid=valid, slots=rows)
+
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    b, t = (slots, 1) if program == "decode" else (
+        1, cell.role["engine"]["split_prefill_chunk"])
+    table = cfg.max_seq_len // ragged["block_size"]
+    return forward, (params, cache, s((b, t), i32), s((b, table), i32),
+                     s((b,), i32), s((b, t), bool), s((b,), i32))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_the_state_pool_stays_where_it_is(v5e, program):
+    """Granite-4.0-H-Micro's ``decode`` (64 rows) and ``chunk_prefill`` (256
+    tokens) at the cell's geometry, one period deep, compiled for the chip:
+    no copy - plain or ``copy-start`` -, slice, update, buffer or loop
+    fusion of the state pool's, the KV pools' or one layer's shape; every
+    pool aliased argument-to-result; ONE ``ssm_decode_update`` a Mamba layer
+    body (the nest has two: the run of five and the run of four) and none in
+    a multi-token program; the attention layer still one ``paged_kv_write``
+    and one attention kernel."""
+    import math
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = _granite_program(program)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pools = jax.tree.leaves(args[1])
+    assert {tuple(p.shape) for p in pools} == {
+        (1, 2816, 4, 32, 128), (9, 65, 136, 4096)}
+    assert pool_copy_bytes(text, pools) == 0
+    # nor moved to the chip's fast memory and back around a kernel (what a
+    # pool of the convolution tails alone, 62 MB, was: a ``copy-start`` of
+    # the whole pool a layer, which ``pool_copy_bytes`` does not count)
+    dims = {",".join(map(str, shape)) for p in pools
+            for shape in (p.shape, p.shape[1:])}
+    assert not [line for line in text.splitlines() if "copy-start(" in line
+                and any(f"[{d}]" in line for d in dims)]
+    assert mem.alias_size_in_bytes >= sum(
+        math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    # less than one layer's state of temporaries: nothing pool-sized hides
+    assert mem.temp_size_in_bytes < math.prod(pools[-1].shape[1:]) * 4
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    assert calls.count("ssm_decode_update") == (2 if program == "decode"
+                                                else 0)
+    attn = "paged_decode" if program == "decode" else "paged_prefill"
+    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
+
+
+# head size 64 (half a lane tile) in plain pools, and the lane-packed geometry
+# Granite runs (``_paged.init_paged_pools(lane_pack=True)``: two heads a row)
+HEAD_64 = {"unpacked_hd64_group4": (8, 64), "packed_hd128_group8": (4, 128)}
+
+
+@pytest.mark.parametrize("geometry", sorted(HEAD_64))
+@pytest.mark.parametrize("op", ["decode", "prefill"])
+def test_paged_kernels_compile_at_head_size_64(v5e, op, geometry):
+    """32 query heads over 8 KV heads of 64 (Granite-4.0-H-Micro's attention):
+    both paged kernels and the write compile for the chip as they are, and
+    with two KV heads side by side in a 128-lane row."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nkv, hd = HEAD_64[geometry]
+    b, t = (64, 1) if op == "decode" else (1, 256)
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def step(q, k, v, kp, vp, table, ctx, n):
+        kp, vp, *_ = pa.paged_kv_write(k, v, kp, vp, table, ctx, n,
+                                       layer=jnp.int32(1))
+        if op == "decode":
+            return pa.paged_decode_attention(q[:, 0], kp, vp, table, ctx,
+                                             scale=1 / 64, layer=jnp.int32(1))
+        return pa.paged_prefill_attention(q, kp, vp, table, ctx, n,
+                                          scale=1 / 64, layer=jnp.int32(1))
+
+    pool = ((4, 2816, nkv, 32, hd), bf)
+    text = _compile(step, ((b, t, 32, hd), bf), ((b, t, nkv, hd), bf),
+                    ((b, t, nkv, hd), bf), pool, pool, ((b, 256), i32),
+                    ((b,), i32), ((b,), i32),
+                    device=v5e.devices[0]).as_text()
+    assert text.count(MOSAIC) >= 2
+
+
 # --- the ``t > 1`` programs are the parent's ------------------------------- #
 # b, t, query heads, KV heads, head size, block, pool blocks, table width,
 # int8 pools (scale groups), window

@@ -242,6 +242,40 @@ def test_v2_split_prefill_matches_and_never_starves(tiny):
     assert got == want
 
 
+def test_a_slot_seated_by_a_steps_final_chunk_is_not_decoded_in_that_step(
+        tiny):
+    """A decode-shaped call is active on the slots of the sequences it
+    DECODES and no other (``engine_v2._slots``): the step whose final chunk
+    seats a sequence runs its decode beside it, and that decode writes
+    nothing for the new sequence - the row at its ``seen_tokens`` stays
+    unwritten until its first real decode (it used to be written twice; a
+    recurrent state would have been advanced twice)."""
+    cfg, params = tiny
+    mesh_lib.set_mesh(None)
+    eng = build_engine_v2(llama, cfg, params, config={
+        "dtype": "float32", "prefill_bucket": 16, "split_prefill_chunk": 32,
+        "ragged": {"max_tracked_sequences": 4, "max_ragged_batch_size": 4,
+                   "memory_config_blocks": 64, "block_size": 16}})
+    rng = np.random.default_rng(3)
+    a, b = (rng.integers(0, cfg.vocab_size, (n,)).tolist() for n in (8, 11))
+    eng.put(1, a)
+    eng.put_split(2, b)
+    out = eng.step()                 # b's only chunk, and a decode of 1
+    assert set(out) == {1, 2}
+    desc = eng.state.seqs[2]
+    assert desc.seen_tokens == 11 and not desc.prefilling
+
+    def row(pos):                    # layer 0's keys of sequence 2 at ``pos``
+        return np.asarray(eng.cache["k"][0, desc.blocks[0], :, pos])
+
+    assert np.abs(row(10)).max() > 0 and np.abs(row(11)).max() == 0
+    live = [d for d in eng.state.seqs.values()]
+    assert eng._slots(live[:1])[3].tolist() == [
+        s == live[0].slot for s in range(4)]
+    eng.step()                       # its first real decode writes the row
+    assert np.abs(row(11)).max() > 0
+
+
 def test_v1_tensor_parallel_sharding(tiny):
     cfg, params = tiny
     mesh_lib.set_mesh(None)

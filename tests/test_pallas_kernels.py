@@ -472,6 +472,120 @@ def test_paged_kernels_read_the_layer_they_are_given(op, window):
     assert np.abs(outs[1] - outs[2]).max() > 1e-2
 
 
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+@pytest.mark.parametrize("t", [1, 5])
+def test_paged_attention_step_hands_its_scale_to_both_kernels(t, backend):
+    """``paged_attention_step(scale=)`` reaches the decode kernel (t = 1),
+    the prefill kernel (t > 1) and their XLA references: at a scale that is
+    not ``hd ** -0.5`` (Granite's ``attention_multiplier`` is 1 / hd) the
+    step agrees with plain softmax attention at THAT scale over the written
+    context, and not at the default."""
+    from deepspeed_tpu.models._paged import LayerPool, paged_attention_step
+    from deepspeed_tpu.ops import registry
+
+    rs = np.random.RandomState(5)
+    kp, vp = _layered_pools(rs)
+    L, nblocks, nkv, bs, hd = kp.shape
+    B, nh, layer, scale = 2, 4, 1, 1.0 / hd
+    bt = _tables(rs, B, 6, nblocks)
+    ctx = jnp.asarray([9, 20], jnp.int32)
+    q = jnp.asarray(rs.randn(B, t, nh, hd).astype(np.float32))
+    k, v = (jnp.asarray(rs.randn(B, t, nkv, hd).astype(np.float32))
+            for _ in range(2))
+    for op in ("paged_kv_write", "paged_decode_attention",
+               "paged_prefill_attention"):
+        registry.set_backend(op, backend)
+    try:
+        out, k_c, v_c = paged_attention_step(
+            q, k, v, LayerPool(kp, None, jnp.int32(layer)),
+            LayerPool(vp, None, jnp.int32(layer)), bt, ctx,
+            ctx[:, None] + jnp.arange(t)[None], jnp.ones((B, t), bool),
+            scale=scale)
+    finally:
+        for op in ("paged_kv_write", "paged_decode_attention",
+                   "paged_prefill_attention"):
+            registry.set_backend(op, None)
+
+    def dense(pool, b):       # the sequence's context, in order
+        return np.asarray(pool[layer, bt[b]]).swapaxes(1, 2).reshape(
+            -1, nkv, hd)
+
+    for b in range(B):
+        n = int(ctx[b]) + t
+        keys, values = dense(k_c.pool, b)[:n], dense(v_c.pool, b)[:n]
+        for ti in range(t):
+            for h in range(nh):
+                upto = int(ctx[b]) + ti + 1
+                kh = keys[:upto, h // (nh // nkv)]
+                logits = kh @ np.asarray(q[b, ti, h])
+                for s_, agrees in ((scale, True), (hd ** -0.5, False)):
+                    p = np.exp(logits * s_ - (logits * s_).max())
+                    want = (p / p.sum()) @ values[:upto, h // (nh // nkv)]
+                    close = np.allclose(np.asarray(out[b, ti, h]), want,
+                                        rtol=2e-4, atol=2e-4)
+                    assert close == agrees
+
+
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+@pytest.mark.parametrize("t", [1, 5])
+def test_lane_packed_pools_give_what_plain_pools_give(t, backend):
+    """Heads of 64 lanes, two a 128-lane pool row
+    (``init_paged_pools(lane_pack=True)``): ``paged_attention_step`` reads
+    the packing off the pool's shape, and the step's output and the written
+    context are those of plain ``[.., nkv, bs, 64]`` pools - at the default
+    scale, the head's and not the row's, and at another."""
+    from deepspeed_tpu.models._paged import (LayerPool, init_paged_pools,
+                                             lane_pack_of,
+                                             paged_attention_step)
+    from deepspeed_tpu.ops import registry
+
+    rs = np.random.RandomState(7)
+    L, nblocks, nkv, bs, hd, B, nh, layer = 2, 24, 4, 8, 64, 2, 16, 1
+    assert (lane_pack_of(nkv, hd), lane_pack_of(3, hd),
+            lane_pack_of(nkv, 128)) == (2, 1, 1)
+    bt = _tables(rs, B, 6, nblocks)
+    ctx = jnp.asarray([0, 11], jnp.int32)
+    ops = ("paged_kv_write", "paged_decode_attention",
+           "paged_prefill_attention")
+
+    def steps(packed, scale):
+        """A 9-token fill of every sequence, then the call under test."""
+        pools = init_paged_pools(L, nblocks, nkv, bs, hd, jnp.float32,
+                                 lane_pack=packed)
+        assert pools["k"].shape == ((L, nblocks, 2, bs, 128) if packed
+                                    else (L, nblocks, 4, bs, 64))
+        rs_ = np.random.RandomState(8)
+        entries = [LayerPool(pools[n], None, jnp.int32(layer))
+                   for n in ("k", "v")]
+        for n, at in ((9, ctx), (t, ctx + 9)):
+            q = jnp.asarray(rs_.randn(B, n, nh, hd).astype(np.float32))
+            k, v = (jnp.asarray(rs_.randn(B, n, nkv, hd).astype(np.float32))
+                    for _ in range(2))
+            out, *entries = paged_attention_step(
+                q, k, v, *entries, bt, at, at[:, None] + jnp.arange(n)[None],
+                jnp.ones((B, n), bool), scale=scale)
+        return np.asarray(out), [np.asarray(e.pool) for e in entries]
+
+    for op in ops:
+        registry.set_backend(op, backend)
+    try:
+        for scale in (None, 1.0 / hd):
+            plain, plain_pools = steps(False, scale)
+            got, got_pools = steps(True, scale)
+            np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-5)
+            for a, b in zip(got_pools, plain_pools):
+                # a packed row is two heads' rows side by side
+                np.testing.assert_array_equal(
+                    a, b.reshape(L, nblocks, 2, 2, bs, hd)
+                    .transpose(0, 1, 2, 4, 3, 5).reshape(a.shape))
+    finally:
+        for op in ops:
+            registry.set_backend(op, None)
+    with pytest.raises(ValueError, match="no quantized mode"):
+        init_paged_pools(L, nblocks, nkv, bs, hd, kv_quant_group=64,
+                         lane_pack=True)
+
+
 # name -> (t, context_lens, lengths): where a step's rows fall on the pages
 KV_WRITES = {
     "page_aligned_chunk": (16, [16], [16]),

@@ -804,6 +804,7 @@ def _kernel_cases():
     import jax
 
     from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import ssm
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.norms import (layer_norm_pallas,
                                                 rms_norm_pallas)
@@ -824,6 +825,7 @@ def _kernel_cases():
     tables, lens = jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32)
     x, w = jnp.ones((16, 128), f32), jnp.ones((128,), f32)
     q8, scales = jnp.ones((4, 256), jnp.int8), jnp.ones((4,), f32)
+    state, rows = jnp.ones((2, 4, 24, 128), f32), jnp.asarray([0, 2])
     return {
         "flash_fwd": (flash, qkv),
         "flash_bwd_dq": (flash_grad, qkv),
@@ -847,6 +849,16 @@ def _kernel_cases():
                           [jnp.ones((1024,), f32)]),
         "dequantize_int8": (lambda q, s: dequantize_int8_pallas(
             q, s, group_size=256), [q8, scales]),
+        # the per-slot state pool's three (ops/pallas/ssm.py, ISSUE 31)
+        "state_rows_read": (lambda p, r: ssm.state_rows_read(
+            p, 1, r, (16, 8, 128)), [state, rows]),
+        "state_rows_write": (lambda p, r, new: ssm.state_rows_write(
+            p, 1, r, new, (16, 8, 128)), [state, rows,
+                                          jnp.ones((2, 8, 128), f32)]),
+        "ssm_decode_update": (
+            lambda p, r, v, bc: ssm.ssm_decode_update(p, 1, r, r == 0, v, v,
+                                                      bc, bc)[1],
+            [state, rows, jnp.ones((2, 128), f32), jnp.ones((2, 16), f32)]),
     }
 
 
@@ -854,7 +866,8 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "sparse_flash_fwd", "sparse_flash_bwd_dq",
                 "sparse_flash_bwd_dkv", "paged_decode", "paged_prefill",
                 "paged_kv_write", "rms_norm_fwd", "layer_norm_fwd", "quantize_int8",
-                "dequantize_int8"]
+                "dequantize_int8", "state_rows_read", "state_rows_write",
+                "ssm_decode_update"]
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
