@@ -54,6 +54,11 @@ class ModelFamily:
     # its ``apply_paged`` takes each row's ``slots`` (models/granite_hybrid.py)
     state_slot_bytes: Optional[Callable] = None
     state_leaves: Tuple[str, ...] = ()
+    # the family's ``apply_paged`` takes a mixed call (``models/_paged.py``
+    # ``MixedCall``: a prefill chunk's rows beside every slot's decode row),
+    # so a serving step with both runs ONE program; a family without it
+    # keeps the two calls
+    mixed_paged: bool = False
 
     @classmethod
     def from_module(cls, module, cfg) -> "ModelFamily":
@@ -70,7 +75,8 @@ class ModelFamily:
                    name=getattr(module, "__name__", "model").rsplit(".", 1)[-1],
                    moe_rows=getattr(module, "moe_rows", None),
                    state_slot_bytes=getattr(module, "state_slot_bytes", None),
-                   state_leaves=tuple(getattr(module, "STATE_LEAVES", ())))
+                   state_leaves=tuple(getattr(module, "STATE_LEAVES", ())),
+                   mixed_paged=bool(getattr(module, "MIXED_PAGED", False)))
 
 
 def _round_up(n: int, m: int) -> int:

@@ -23,13 +23,14 @@ from __future__ import annotations
 
 import time
 from itertools import repeat
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..comm.mesh import MeshManager
+from ..models._paged import MixedCall
 from ..ops.quantization import kv_dequantize_int8, kv_quantize_int8
 from ..telemetry.compile import CompileMonitor
 from ..telemetry.trace import percentiles
@@ -80,6 +81,26 @@ _GREEDY = SamplingParams(greedy=True)
 # why a family with recurrent state refuses the disagg block export / import
 _HANDOFF = ("a sequence's blocks are not its whole state, and the "
             "destination resumes through the prefix cache")
+
+
+class _Chunk(NamedTuple):
+    """The oldest pending split prefill's next chunk (``_next_chunk``)."""
+    uid: int
+    desc: Any
+    sp: SamplingParams          # as given at ``put_split``
+    tokens: np.ndarray          # the chunk's real tokens
+    ctx: int                    # the sequence's tokens already cached
+    final: bool                 # it ends the prompt
+    width: int                  # the program's chunk rows (padding included)
+
+    def arrays(self, table, recurrent: bool) -> Tuple:
+        """(tokens ``[1, width]``, real tokens, context offset, block
+        table, then the sequence's slot for a family with recurrent state):
+        what a program takes of one chunk."""
+        padded = np.zeros((1, self.width), np.int32)
+        padded[0, :len(self.tokens)] = self.tokens
+        return (padded, np.int32(len(self.tokens)), np.int32(self.ctx),
+                table) + ((np.int32(self.desc.slot),) if recurrent else ())
 
 
 def _last_row(logits, lengths):
@@ -263,6 +284,10 @@ class InferenceEngineV2(InferenceEngine):
         # their ssm_rows / ssm_tokens (a family with recurrent state)
         self._admitted_ssm: Dict[str, int] = {}
         self.prefill_tokens_written = 0
+        # steps that ran their chunk and their decodes as one program
+        # (``_decode_chunk``; ``Serving/engine/mixed_steps``)
+        self.steps = 0
+        self.mixed_steps = 0
         # --- recompilation sentinel + per-program MFU attribution
         # (telemetry/compile.py; docs/observability.md). A hub with an
         # ENABLED monitor is shared — serving programs land in the same
@@ -458,8 +483,9 @@ class InferenceEngineV2(InferenceEngine):
 
     # ------------------------------------------------------------------ #
     # the programs: ONE forward (``_paged_forward``), the last real row's
-    # logits (``_last_row``), one of two samplers (``_sampler``), in four
-    # builders - prefill, chunk_prefill, decode, spec_verify. ``_dispatch``
+    # logits (``_last_row``), one of two samplers (``_sampler``), in five
+    # builders - prefill, chunk_prefill, decode, decode_chunk (a step's
+    # chunk and its decodes in one forward), spec_verify. ``_dispatch``
     # launches all of them.
     # ------------------------------------------------------------------ #
     def _paged_forward(self, params, tokens, cache, tables, ctx, valid,
@@ -467,7 +493,9 @@ class InferenceEngineV2(InferenceEngine):
         """The engine's ONE call of the family's paged forward, traced inside
         every program: ``tokens`` [b, t] at context offsets ``ctx`` [b]
         through block tables [b, blocks], ``params`` as ``_dq`` hands them
-        over; rows where ``valid`` [b, t] is False write nothing. ``slots``
+        over; rows where ``valid`` [b, t] is False write nothing. A mixed
+        call: ``tables`` is a ``MixedCall``, ``ctx`` None and ``tokens``
+        [1, slots + chunk] (``_decode_chunk_fn``). ``slots``
         [b]: each row's sequence slot, for a family with recurrent state and
         a call whose rows are not the slots in order (the prefills; a
         decode-shaped call's row i IS slot i, the family's default).
@@ -650,11 +678,12 @@ class InferenceEngineV2(InferenceEngine):
         """The one place a forward program is launched: ``pre`` and ``post``
         are the call's host arrays in the program's argument order, on
         either side of its rng key, each uploaded here and nowhere else.
-        Returns what the program returns - the donated cache last, for the
-        caller to take back."""
+        Returns what the program returns as a tuple - the donated cache
+        last, for the caller to take back."""
         with self.tracer.span("engine_dispatch", cat="serving"):
-            return fn(self.params, self.cache, *map(jnp.asarray, pre),
-                      jax.random.PRNGKey(seed), *map(jnp.asarray, post))
+            out = fn(self.params, self.cache, *map(jnp.asarray, pre),
+                     jax.random.PRNGKey(seed), *map(jnp.asarray, post))
+        return out if isinstance(out, tuple) else (out,)
 
     def _kv_blocks(self, kv_tokens: int) -> int:
         """Blocks a prefill call's longest row attends over (cached context
@@ -704,66 +733,102 @@ class InferenceEngineV2(InferenceEngine):
         return {"attn_tiles_live": live, "attn_tiles_grid": grid,
                 "attn_live_tile_share": live / grid}
 
-    def _advance_prefill(self, seed: int = 0) -> Dict[int, int]:
-        """Advance the OLDEST pending split prefill by one chunk (FIFO, the
-        reference scheduler's arrival order), sampling with the
-        SamplingParams given at put_split time. Returns {uid: first_token}
-        when that chunk completes the prompt, else {}."""
-        if not self._pending_prefill:
-            return {}
+    def _next_chunk(self) -> _Chunk:
+        """The OLDEST pending split prefill's next chunk (FIFO, the
+        reference scheduler's arrival order)."""
         uid = next(iter(self._pending_prefill))
         prompt, sp = self._pending_prefill[uid]
         desc = self.state.seqs[uid]
-        chunk_tokens = _round_up(
-            max(self.config.split_prefill_chunk, 1), self.config.prefill_bucket)
+        width = _round_up(max(self.config.split_prefill_chunk, 1),
+                          self.config.prefill_bucket)
         done = desc.seen_tokens
-        chunk = prompt[done:done + chunk_tokens]
-        final = done + len(chunk) >= len(prompt)
-        rec = self._req.get(uid)        # the request's ring lifecycle
+        tokens = prompt[done:done + width]
+        return _Chunk(uid, desc, sp, tokens, done,
+                      done + len(tokens) >= len(prompt), width)
+
+    def _chunk_args(self, ch: _Chunk) -> Dict[str, Any]:
+        """What a span says of the chunk its call runs."""
+        return {"uid": ch.uid, "tokens": len(ch.tokens), "ctx": ch.ctx,
+                "final": ch.final,
+                "kv_blocks": self._kv_blocks(ch.ctx + len(ch.tokens))}
+
+    def _chunk_landed(self, ch: _Chunk, table, tok=None) -> Dict[int, int]:
+        """The bookkeeping of a chunk whose program has been dispatched;
+        ``tok`` is the first token a final chunk sampled, and the sequence
+        is then seated for the NEXT decode-shaped call. Returns {uid: tok}
+        for a final chunk, else {}."""
+        n, desc = len(ch.tokens), ch.desc
+        self.last_step["prefill_tokens"] += n
+        self.last_step["prefill_kv_tokens"] += ch.ctx + n
+        self.prefill_tokens_written += n
+        desc.seen_tokens = ch.ctx + n
+        self.state.mark_filled(desc)      # completed chunks are matchable
+        if not ch.final:
+            return {}
+        del self._pending_prefill[ch.uid]
+        desc.prefilling = False
+        desc.last_token = tok
+        desc.generated.append(tok)
+        self._seat(desc, table, self._canon_sp(ch.sp))
+        return {ch.uid: tok}
+
+    def _chunk_program(self, ch: _Chunk, live, table, mixed: bool = True):
+        """(program, its arrays before the key, its arrays after) for one
+        chunk. ``mixed``: the family's ONE chunk program, ``decode_chunk``,
+        whose decode rows are the slots of ``live`` - none, where nothing
+        decodes beside the chunk -, so that every chunk of a ``step()``
+        runs (and a server's start-up loads) one program; else
+        ``chunk_prefill`` (a family that takes no mixed call, ``step_many``,
+        a speculative step)."""
+        chunk = ch.arrays(table, self._recurrent)
+        uid = (np.int32(ch.uid),)
+        if not mixed:
+            return self._chunk_prefill_fn(ch.width, ch.final, ch.sp), \
+                chunk, uid
+        # a chunk that does not end its prompt samples for nothing; slots
+        # hold canonical params (``_canon_sp``): see ``_sampler``
+        sp = self._canon_sp(ch.sp) if ch.final else _GREEDY
+        rows = sp != _GREEDY or any(
+            self._slot_sp[d.slot] != _GREEDY for d in live)
+        return (self._decode_chunk_fn(ch.width, rows),
+                self._slots(live) + chunk,
+                uid + (sp_arrays(self._slot_sp + [sp]) if rows else ()))
+
+    def _advance_prefill(self, seed: int = 0,
+                         mixed: bool = False) -> Dict[int, int]:
+        """Advance the oldest pending split prefill by one chunk with
+        nothing decoding beside it, sampling with the SamplingParams given
+        at put_split time (``mixed``: see ``_chunk_program``). Returns
+        {uid: first_token} when that chunk completes the prompt, else {}."""
+        if not self._pending_prefill:
+            return {}
+        ch = self._next_chunk()
+        rec = self._req.get(ch.uid)     # the request's ring lifecycle
+        rows = ch.width + (len(self._slot_tokens) if mixed else 0)
         with self.tracer.span(
                 "prefill_chunk", cat="serving",
                 trace=rec["trace"] if rec else None,
                 parent=rec["span"].span_id if rec else None,
-                uid=uid, tokens=len(chunk), ctx=done, final=final,
-                kv_blocks=self._kv_blocks(done + len(chunk)),
                 table_blocks=self.state.max_blocks_per_seq,
-                **self._moe_args(chunk_tokens),
-                **self._ssm_args(1, len(chunk))):
+                **self._chunk_args(ch), **self._moe_args(rows),
+                **self._ssm_args(1, len(ch.tokens))):
             with self.tracer.span("engine_prep", cat="serving"):
-                padded = np.zeros((1, chunk_tokens), np.int32)
-                padded[0, :len(chunk)] = chunk
-                table = self.state.block_table(desc)
-                fn = self._chunk_prefill_fn(chunk_tokens, final, sp)
+                table = self.state.block_table(ch.desc)
+                fn, pre, post = self._chunk_program(ch, (), table, mixed)
             if self._trace_on:
-                self._req_compute_begin(uid)   # first chunk ends queue-wait
-            res = self._dispatch(
-                fn, (padded, np.int32(len(chunk)), np.int32(done), table)
-                + ((np.int32(desc.slot),) if self._recurrent else ()),
-                seed, (np.int32(uid),))
-            self.last_step["prefill_tokens"] += len(chunk)
-            self.last_step["prefill_kv_tokens"] += done + len(chunk)
-            self.prefill_tokens_written += len(chunk)
-            if not final:
+                self._req_compute_begin(ch.uid)  # first chunk ends queue-wait
+            *tok, self.cache = self._dispatch(fn, pre, seed, post)
+            if not ch.final:
                 # no engine_wait: the call is asynchronous and nothing here
-                # blocks on it, so this span says dispatch, not device
-                self.cache = res
-                desc.seen_tokens = done + len(chunk)
-                self.state.mark_filled(desc)  # completed chunks are matchable
-                return {}
-            tok, self.cache = res
+                # blocks on it (nor reads a token the program sampled for
+                # nothing), so this span says dispatch, not device
+                return self._chunk_landed(ch, table)
             with self.tracer.span("engine_wait", cat="serving"):
-                tok = int(tok)
+                tok = int(np.asarray(tok[0]).reshape(-1)[-1])
             if self._trace_on:
-                self._req_first_token(uid, time.monotonic_ns())
+                self._req_first_token(ch.uid, time.monotonic_ns())
             with self.tracer.span("engine_emit", cat="serving"):
-                del self._pending_prefill[uid]
-                desc.seen_tokens = len(prompt)
-                self.state.mark_filled(desc)
-                desc.prefilling = False
-                desc.last_token = tok
-                desc.generated.append(tok)
-                self._seat(desc, table, self._canon_sp(sp))
-        return {uid: tok}
+                return self._chunk_landed(ch, table, tok)
 
     def _seat(self, desc, table, sp: SamplingParams) -> None:
         """The sequence's slot as the next decode-shaped call reads it."""
@@ -836,6 +901,54 @@ class InferenceEngineV2(InferenceEngine):
             # (benchmark/readers: jit_decode)
             decode.__name__ = "decode" if k == 1 else "decode_many"
             self._paged_fns[key] = self._jit(key, decode, donate_argnums=(1,))
+        return self._paged_fns[key]
+
+    def _decode_chunk_fn(self, chunk_t: int, rows: bool):
+        """The step's prefill chunk AND its decodes in ONE forward (a family
+        whose ``apply_paged`` takes a mixed call: ``ModelFamily.
+        mixed_paged``): every slot's token and the chunk's ``chunk_t``
+        tokens are one row dimension of ``slots + chunk_t`` rows, so the
+        tick reads every weight once where ``chunk_prefill`` then ``decode``
+        read them twice; only attention (and a recurrent state) runs a
+        segment at a time (``models/_paged.py``). ONE variant for mid and
+        final chunks: the chunk's last real row is always sampled (its key
+        folds in the uid, as ``chunk_prefill``'s) and the host drops the
+        token of a chunk that does not end its prompt - so the program
+        compiles once a ``(slots, chunk_t)``, greedy or ``rows`` (see
+        ``_sampler``; the per-row arrays are the slots' and then the
+        chunk's). Returns (tokens [slots + 1], cache)."""
+        name = "decode_chunk" + ("_dyn" if rows else "")
+        key = (name, chunk_t)
+        if key not in self._paged_fns:
+            pick = _sampler(rows)
+
+            def decode_chunk(params, cache, tokens, lens, tables, active,
+                             chunk, n_valid, ctx, table, *rest):
+                # the slots as ``decode`` takes them; the chunk as
+                # ``chunk_prefill`` does: chunk [1, chunk_t], then the
+                # sequence's slot (recurrent state), rng, uid; then the
+                # sampling arrays [slots + 1] (rows)
+                rest = list(rest)
+                slot = rest.pop(0) if self._recurrent else None
+                rng, uid, *sp_rows = rest
+                b = tokens.shape[0]
+                call = MixedCall(tables, lens, active, table, ctx, n_valid,
+                                 slot)
+                logits, cache = self._paged_forward(
+                    self._dq(params),
+                    jnp.concatenate([tokens, chunk[0]])[None], cache, call,
+                    None, call.valid(b + chunk_t))
+                nxt = pick(rng, logits[0, :b], *(a[:b] for a in sp_rows))
+                first = pick(jax.random.fold_in(rng, uid),
+                             _last_row(logits[:, b:], n_valid),
+                             *(a[b] for a in sp_rows))
+                return jnp.concatenate([nxt, first[None]]).astype(
+                    jnp.int32), cache
+
+            # the name is read off the device trace, as ``decode``'s
+            # (benchmark/readers: ^jit_decode finds the tick's program)
+            self._paged_fns[key] = self._jit(key, decode_chunk,
+                                             donate_argnums=(1,))
         return self._paged_fns[key]
 
     # ------------------------------------------------------------------ #
@@ -1098,22 +1211,28 @@ class InferenceEngineV2(InferenceEngine):
     # ------------------------------------------------------------------ #
     # what step(), step_many() and _spec_step() share around their program
     # ------------------------------------------------------------------ #
-    def _prefill_then_live(self, seed: int):
+    def _prefill_then_live(self, seed: int, mixed: bool = False):
         """How a step begins: ``last_step`` starts over - the one-shot
         prefills that ran since the previous step (``put``/``put_many``, a
         scheduler tick's admissions) count with this step's
-        ``prefill_kv_tokens`` -, the oldest split prefill advances one chunk,
-        and the sequences to decode are listed. Returns ({uid: first token}
-        of a prompt this completed, live)."""
+        ``prefill_kv_tokens`` -, the sequences to decode are listed (a
+        prefilling one is not among them, the one this step's chunk
+        completes included: it has its first token only), and the oldest
+        split prefill advances one chunk. ``mixed``: the caller can run a
+        chunk and its decodes as ONE program (``_decode_chunk``); where
+        there are both, the chunk is left to it. Returns ({uid: first
+        token} of a prompt this completed, live, the chunk left over or
+        None)."""
         self.last_step = dict(_NO_WORK,
                               prefill_kv_tokens=self._admitted_kv_tokens,
                               **self._admitted_ssm)
         self._admitted_kv_tokens = 0
         self._admitted_ssm = {}
-        first = self._advance_prefill(seed)
         live = [d for d in self.state.seqs.values()
-                if not d.finished and not d.prefilling
-                and d.uid not in first]  # completed-this-step: first token only
+                if not d.finished and not d.prefilling]
+        if mixed and live and self._pending_prefill:
+            return {}, live, self._next_chunk()
+        first = self._advance_prefill(seed, mixed)
         if not live:
             # no decodes in flight: the one-chunk-per-step bound exists to
             # protect live decodes from prefill stalls — with none to
@@ -1121,8 +1240,8 @@ class InferenceEngineV2(InferenceEngine):
             # until it completes (it holds KV blocks the whole time), then
             # stop: the completed sequence is a live decode to protect again
             while self._pending_prefill and not first:
-                first.update(self._advance_prefill(seed))
-        return first, live
+                first.update(self._advance_prefill(seed, mixed))
+        return first, live, None
 
     def _reserve(self, live, counts) -> None:
         """Room for the tokens a decode-shaped call is about to write:
@@ -1190,14 +1309,69 @@ class InferenceEngineV2(InferenceEngine):
             toks = np.asarray(toks).reshape(k, -1)
         t1 = time.monotonic_ns() if self._trace_on else 0
         with self.tracer.span("engine_emit", cat="serving"):
-            kv = 0
-            for d in live:
-                out[d.uid] = seq = toks[:, d.slot].tolist()
-                # KV writes of the call: the previous last_token, then each
-                # sampled token except the newest (still pending its write)
-                kv += self._commit(d, [d.last_token] + seq[:-1], seq, t1)
+            self._commit_ticks(live, toks, out, t1, span, extra)
+
+    def _commit_ticks(self, live, toks, out, t_ns: int, span, extra) -> None:
+        """``toks`` [k, slots] of a decode-shaped call land on ``live``:
+        each sequence's k tokens into ``out[uid]``, and what the call did
+        onto ``last_step`` and its ``span``."""
+        kv = 0
+        for d in live:
+            out[d.uid] = seq = toks[:, d.slot].tolist()
+            # KV writes of the call: the previous last_token, then each
+            # sampled token except the newest (still pending its write)
+            kv += self._commit(d, [d.last_token] + seq[:-1], seq, t_ns)
         self.last_step.update(decode_seqs=len(live), kv_tokens=kv, **extra)
         span.set(kv_tokens=kv, **extra)
+
+    def _decode_chunk(self, ch: _Chunk, live, seed: int, out) -> None:
+        """One step's chunk AND its decodes in one program
+        (``_decode_chunk_fn``), under ONE ``decode_step`` span: ``batch``,
+        ``kv_tokens``, the tile counts and ``ssm_*`` are the decode rows' as
+        in a plain step, the MoE rows are the whole call's (``slots +
+        chunk`` rows: one pass through the expert bank, so no other span
+        may carry them), and the chunk's facts ride as ``chunk_*``. One
+        dispatch, one host sync - a final chunk's first token arrives with
+        the decodes' -, then the chunk's bookkeeping and the decodes'
+        commits in the order the two programs had. ``last_step`` counts
+        what the two calls counted."""
+        rec = self._req.get(ch.uid)
+        self._ssm_args(1, len(ch.tokens))        # ``last_step``'s count
+        with self.tracer.span(
+                "decode_step", cat="serving", batch=len(live),
+                **self._moe_args(len(self._slot_tokens) + ch.width),
+                **self._ssm_args(len(live), len(live)),
+                **{"chunk_" + k: v
+                   for k, v in self._chunk_args(ch).items()}) as span:
+            with self.tracer.span("engine_prep", cat="serving"):
+                self._reserve(live, repeat(1))
+                extra = self._attn_tile_args()
+                table = self.state.block_table(ch.desc)
+                fn, pre, post = self._chunk_program(ch, live, table)
+            t0 = 0
+            if self._trace_on:
+                self._req_compute_begin(ch.uid)  # first chunk ends queue-wait
+                t0 = time.monotonic_ns()
+            toks, self.cache = self._dispatch(fn, pre, seed, post)
+            with self.tracer.span("engine_wait", cat="serving"):
+                toks = np.asarray(toks)
+            t1 = time.monotonic_ns() if self._trace_on else 0
+            with self.tracer.span("engine_emit", cat="serving"):
+                first = self._chunk_landed(ch, table, int(toks[-1]))
+                out.update((u, [t]) for u, t in first.items())
+                if rec is not None:
+                    # the request's lifecycle keeps its chunk: ring only
+                    # (the timeline has this call's one span), no rows on it
+                    self.tracer.complete(
+                        "prefill_chunk", t0, t1, cat="serving",
+                        trace=rec["trace"], parent=rec["span"].span_id,
+                        table_blocks=self.state.max_blocks_per_seq,
+                        **self._chunk_args(ch))
+                    if first:
+                        self._req_first_token(ch.uid, t1)
+                self._commit_ticks(live, toks[None, :-1], out, t1, span,
+                                   extra)
+        self.mixed_steps += 1
 
     def step(self, sp: SamplingParams = SamplingParams(greedy=True),
              seed: int = 0) -> Dict[int, int]:
@@ -1214,12 +1388,16 @@ class InferenceEngineV2(InferenceEngine):
         the return type widens to {uid: [tokens]} — every value is a list,
         including prefill first-tokens and draft-less fallback steps."""
         self._warn_ignored_sp(sp)
-        first, live = self._prefill_then_live(seed)
+        self.steps += 1
+        first, live, chunk = self._prefill_then_live(
+            seed, mixed=self.family.mixed_paged and not self._spec_on)
         out: Dict[int, List[int]] = {u: [t] for u, t in first.items()}
         spec_out = self._spec_step(live, seed) if live and self._spec_on \
             else None
         if spec_out is not None:
             out.update(spec_out)
+        elif chunk is not None:
+            self._decode_chunk(chunk, live, seed, out)
         elif live:
             if self._spec_on:
                 # no sequence drafted this step: run the plain decode
@@ -1229,7 +1407,7 @@ class InferenceEngineV2(InferenceEngine):
                 self.spec_stats["step_seqs"] += len(live)
                 self.spec_stats["emitted_tokens"] += len(live)
             with self.tracer.span("decode_step", cat="serving",
-                                  batch=len(live),
+                                  batch=len(live), chunk_tokens=0,
                                   **self._moe_args(len(self._slot_tokens)),
                                   **self._ssm_args(len(live), len(live))
                                   ) as span:
@@ -1251,7 +1429,7 @@ class InferenceEngineV2(InferenceEngine):
         number of tokens per call. ``generate`` picks ``step()`` when
         ``inference.speculative.enabled`` is set."""
         self._warn_ignored_sp(sp)
-        first, live = self._prefill_then_live(seed)
+        first, live, _ = self._prefill_then_live(seed)
         out: Dict[int, List[int]] = {u: [t] for u, t in first.items()}
         if live:
             # a tick at seen writes KV position seen, so seen may reach
@@ -1640,6 +1818,19 @@ class InferenceEngineV2(InferenceEngine):
     def publish_state_telemetry(self, step: int = 0):
         return self._publish(self.state_events(step))
 
+    def engine_events(self, step: int = 0):
+        """``Serving/engine/*`` telemetry events (cumulative): ``steps``,
+        the ``step()`` calls, and ``mixed_steps``, those that ran their
+        prefill chunk and their decodes as one program (``decode_chunk``: a
+        pending chunk met live decodes in a family that takes a mixed
+        call)."""
+        return [("Serving/engine/steps", float(self.steps), step),
+                ("Serving/engine/mixed_steps", float(self.mixed_steps),
+                 step)]
+
+    def publish_engine_telemetry(self, step: int = 0):
+        return self._publish(self.engine_events(step))
+
     def debug_check_cache(self) -> None:
         """Cache-pytree invariants beside ``StateManager.debug_check`` —
         in quantized-KV mode the scale tables must stay consistent with the
@@ -1842,6 +2033,8 @@ class InferenceEngineV2(InferenceEngine):
             self.publish_kv_quant_telemetry(step_i)
         if self._recurrent and self._hub is not None:
             self.publish_state_telemetry(step_i)
+        if self._hub is not None:
+            self.publish_engine_telemetry(step_i)
         if self.compile_monitor.enabled and self._hub is not None:
             self.publish_compile_telemetry(step_i)
         return [results[i] for i in range(len(prompts))]

@@ -33,6 +33,13 @@ is fused into the attention reads: in-register inside both Pallas kernels
 NO standalone int8→bf16 convert pass over the pool — QUANT_TPU_LIVE.json
 shows that path losing to bf16 outright.
 
+A mixed call (:class:`MixedCall` in place of the block tables): one
+sequence's prefill chunk rides in a decode step as more rows of the same row
+dimension, so everything that works a row at a time - projections, the FFN
+or the expert bank, the head - reads its weights once for both. Only
+:func:`paged_attention_step` splits the rows back into the two segments it
+has kernels for.
+
 Reads: both kernels walk the block table over the live context
 (``ops/pallas/paged_attention.py``). Nothing here gathers a dense view of
 the pool; only the ops' XLA references do, and the registry picks those off
@@ -109,6 +116,60 @@ class LayerPool(NamedTuple):
     layer: jnp.ndarray
 
 
+class MixedCall(NamedTuple):
+    """A call of TWO segments in one row dimension, handed to a family's
+    ``apply_paged`` where a ``[b, t]`` call hands ``block_tables`` (its
+    ``context_lens`` is then None): tokens ``[1, slots + t]``, the first
+    ``slots`` rows ONE decode token of each sequence slot - ``tables
+    [slots, blocks]``, ``lens [slots]``, ``active [slots]`` as a decode
+    step's - and the last ``t`` rows one sequence's prefill chunk at context
+    offset ``chunk_ctx`` through ``chunk_table [blocks]``, ``chunk_valid`` of
+    them real (both scalars). ``chunk_slot``: the chunk's sequence slot, for
+    a family with recurrent state. The chunk's sequence is not an active
+    slot. A family passes the structure on to :func:`paged_attention_step`
+    untouched; whether its ``apply_paged`` takes one is the family's
+    ``MIXED_PAGED`` (``inference.engine.ModelFamily.mixed_paged``)."""
+    tables: jnp.ndarray
+    lens: jnp.ndarray
+    active: jnp.ndarray
+    chunk_table: jnp.ndarray
+    chunk_ctx: jnp.ndarray
+    chunk_valid: jnp.ndarray
+    chunk_slot: Optional[jnp.ndarray] = None
+
+    @property
+    def slots(self) -> int:
+        """The static boundary between the two segments' rows."""
+        return self.lens.shape[0]
+
+    def valid(self, rows: int) -> jnp.ndarray:
+        """``[1, rows]``: the active slots' rows and the chunk's real ones."""
+        chunk = jnp.arange(rows - self.slots) < self.chunk_valid
+        return jnp.concatenate([self.active, chunk])[None]
+
+    def split(self, x) -> Tuple:
+        """``x [1, slots + t, ...]`` as the decode segment ``[slots, 1,
+        ...]`` and the chunk segment ``[1, t, ...]``."""
+        return x[0, :self.slots, None], x[:, self.slots:]
+
+    @staticmethod
+    def join(decode, chunk):
+        """The inverse of :meth:`split`."""
+        return jnp.concatenate([decode[:, 0][None], chunk], axis=1)
+
+
+def row_positions(block_tables, context_lens, t: int) -> jnp.ndarray:
+    """Every row's absolute token positions ``[b, t]``: ``context_lens +
+    arange(t)`` of a ``[b, t]`` call; of a :class:`MixedCall` (``t`` its
+    ``slots + chunk`` rows) ``lens[i]`` for slot i's decode row and
+    ``chunk_ctx + j`` for the chunk's row j."""
+    if isinstance(block_tables, MixedCall):
+        call = block_tables
+        return jnp.concatenate(
+            [call.lens, call.chunk_ctx + jnp.arange(t - call.slots)])[None]
+    return context_lens[:, None] + jnp.arange(t)[None, :]
+
+
 def scan_layers(body, x, layers, cache, *extras):
     """The pools' way through a program's layers, for every family: the
     pools are the scan's CARRY, beside ``x`` - one ``[L, ...]`` buffer a
@@ -163,10 +224,15 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
     the paged flash-prefill kernel (each windowed or plain-causal, with the
     dequant fused in quantized mode), all three on the layer's index into
     the ``[L, ...]`` pools. Lane-packed pools (:func:`lane_pack_of`) are
-    known by their rows, ``pack`` times as wide as ``k``'s heads. Returns
-    (attn_out [b, t, nh, hd], k_cache, v_cache) with the written pools in
-    the entries."""
+    known by their rows, ``pack`` times as wide as ``k``'s heads. A
+    :class:`MixedCall` as ``block_tables`` (q/k/v ``[1, slots + t, ..]``) is
+    honoured here and nowhere else: its two segments are this function's two
+    calls. Returns (attn_out [b, t, nh, hd], k_cache, v_cache) with the
+    written pools in the entries."""
     del positions
+    if isinstance(block_tables, MixedCall):
+        return _mixed_step(q, k, v, k_cache, v_cache, block_tables,
+                           window=window, scale=scale)
     from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
     from ..ops.registry import get_op
 
@@ -213,6 +279,24 @@ def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
         out = unpack(out)
     return (out, LayerPool(k_pool, k_scale, layer),
             LayerPool(v_pool, v_scale, layer))
+
+
+def _mixed_step(q, k, v, k_cache, v_cache, call: MixedCall, **kw) -> Tuple:
+    """:func:`paged_attention_step` of a mixed call: the rows are cut at the
+    static segment boundary and each segment is written and attended as the
+    ``[1, t]`` chunk call and the ``[slots, 1]`` decode call it would have
+    been alone, in that order - the same Mosaic calls on the same operands -
+    and the outputs joined. Nothing is padded to a ``[slots, t]`` rectangle
+    and nothing but those calls touches the pools."""
+    (q_d, q_c), (k_d, k_c), (v_d, v_c) = map(call.split, (q, k, v))
+    chunk_rows = (jnp.arange(q_c.shape[1]) < call.chunk_valid)[None]
+    out_c, k_cache, v_cache = paged_attention_step(
+        q_c, k_c, v_c, k_cache, v_cache, call.chunk_table[None],
+        call.chunk_ctx[None], None, chunk_rows, **kw)
+    out_d, k_cache, v_cache = paged_attention_step(
+        q_d, k_d, v_d, k_cache, v_cache, call.tables, call.lens, None,
+        call.active[:, None], **kw)
+    return call.join(out_d, out_c), k_cache, v_cache
 
 
 def _lane_packed(q, k, v, pack: int):

@@ -58,7 +58,8 @@ from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
 from ..ops.pallas import ssm as _ssm_kernels  # noqa: F401 (registers)
 from ..ops.registry import get_op
-from ._paged import LayerPool, init_paged_pools, paged_attention_step
+from ._paged import (LayerPool, MixedCall, init_paged_pools,
+                     paged_attention_step, row_positions)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -340,52 +341,84 @@ def _mamba_mixer(cfg, y, w, tail, h0, token_valid):
     return _mixer_out(cfg, mixed.reshape(b, t, -1), x, z, w), ext, h_t
 
 
-def _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid):
-    """One Mamba layer over the state pools: row i's state is read at
-    ``[index, rows[i]]`` (zeros where ``fresh[i]``), advanced over the row's
-    real tokens and written back there. ``rows`` already aims rows that must
-    write nothing at the trash row."""
-    b, t, _ = x.shape
+def _ssm_rows(cfg, w, state, index, rows, fresh, xbc, dt, A, n_valid):
+    """The convolution and the recurrence of ONE segment's rows over the
+    state pool, between ``in_proj`` and the gate: ``xbc [b, t, C]`` and ``dt
+    [b, t, H]`` (``_mixer_in``'s) from each row's state at ``[index,
+    rows[i]]`` (zeros where ``fresh[i]``), which is advanced over the row's
+    ``n_valid[i]`` real tokens and written back there. One token a row is
+    the in-place ``ssm_decode_update``; more are the blocked scan between a
+    read and a write of the rows. Returns ``(state pool, y [b, t, heads *
+    P] float32, x [b, t, heads * P])``."""
+    b, t = xbc.shape[:2]
     read, write = get_op("state_rows_read"), get_op("state_rows_write")
-    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    with jax.named_scope("ssm_conv"):
+        tail = jnp.where(fresh[:, None, None], 0, _unpack_tail(
+            cfg, read(state, index, rows, cfg.tail_part), xbc.dtype))
+    if t == 1:
+        with jax.named_scope("ssm_conv"):
+            xs, B, C, ext = _conv(cfg, xbc, tail, w)
+            state = write(state, index, rows,
+                          _pack_tail(cfg, ext[:, 1:]), cfg.tail_part)
+        with jax.named_scope("ssm_state"):
+            per_lane = lambda a: jnp.repeat(a, cfg.mamba_head_dim, axis=-1)
+            state, mixed = get_op("ssm_decode_update")(
+                state, index, rows, fresh,
+                per_lane(jnp.exp(dt[:, 0] * A)),
+                per_lane(dt[:, 0]) * xs[:, 0].astype(F32),
+                B[:, 0], C[:, 0])
+        return state, mixed[:, None], xs
+    with jax.named_scope("ssm_state"):
+        h0 = jnp.where(fresh[:, None, None, None], 0.0,
+                       ssm.state_to_heads(
+                           read(state, index, rows, cfg.state_part),
+                           cfg.mamba_heads))
+    with jax.named_scope("ssm_conv"):
+        xs, B, C, ext = _conv(cfg, xbc, tail, w)
+    with jax.named_scope("ssm_state"):
+        mixed, h_t = ssm.ssd_chunked_scan(
+            xs.reshape(b, t, cfg.mamba_heads, cfg.mamba_head_dim), dt, A, B,
+            C, h0, cfg.mamba_chunk)
+    with jax.named_scope("ssm_conv"):
+        # the last K - 1 rows of [tail | the row's real tokens]
+        new_tail = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
+            e, n, cfg.mamba_conv - 1, axis=0))(ext, n_valid)
+        state = write(state, index, rows,
+                      _pack_tail(cfg, new_tail), cfg.tail_part)
+    with jax.named_scope("ssm_state"):
+        state = write(state, index, rows,
+                      ssm.state_from_heads(h_t), cfg.state_part)
+    return state, mixed.reshape(b, t, -1), xs
+
+
+def _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid, call=None):
+    """One Mamba layer over the state pools (``_ssm_rows``). ``rows``
+    already aims rows that must write nothing at the trash row. In a mixed
+    call (``call``, a ``_paged.MixedCall``; ``x [1, slots + t, h]``)
+    ``in_proj``, the gate, ``out_proj`` and the MLP see every row at once;
+    only the state's part splits into the two segments, the chunk's first as
+    the two programs ran, and ``rows`` / ``fresh`` are (the decode rows',
+    the chunk's) pairs."""
     state = pools["ssm"]
     with jax.named_scope("norm"):
         y = rms_norm(x, w["norm"], cfg.rms_norm_eps)
     with jax.named_scope("attn"):       # this layer's token mixer
-        with jax.named_scope("ssm_conv"):
-            tail = jnp.where(fresh[:, None, None], 0, _unpack_tail(
-                cfg, read(state, index, rows, cfg.tail_part), x.dtype))
-        if t == 1:
-            z, xbc, dt, A = _mixer_in(cfg, y, w, valid)
-            with jax.named_scope("ssm_conv"):
-                xs, B, C, ext = _conv(cfg, xbc, tail, w)
-                state = write(state, index, rows,
-                              _pack_tail(cfg, ext[:, 1:]), cfg.tail_part)
-            with jax.named_scope("ssm_state"):
-                per_lane = lambda a: jnp.repeat(a, cfg.mamba_head_dim, axis=-1)
-                state, mixed = get_op("ssm_decode_update")(
-                    state, index, rows, fresh,
-                    per_lane(jnp.exp(dt[:, 0] * A)),
-                    per_lane(dt[:, 0]) * xs[:, 0].astype(F32),
-                    B[:, 0], C[:, 0])
-            out = _mixer_out(cfg, mixed[:, None], xs, z, w)
+        z, xbc, dt, A = _mixer_in(cfg, y, w, valid)
+        if call is None:
+            state, mixed, xs = _ssm_rows(
+                cfg, w, state, index, rows, fresh, xbc, dt, A,
+                jnp.sum(valid, axis=1, dtype=jnp.int32))
         else:
-            with jax.named_scope("ssm_state"):
-                h0 = jnp.where(fresh[:, None, None, None], 0.0,
-                               ssm.state_to_heads(
-                                   read(state, index, rows, cfg.state_part),
-                                   cfg.mamba_heads))
-            out, ext, h_t = _mamba_mixer(cfg, y, w, tail, h0, valid)
-            with jax.named_scope("ssm_conv"):
-                # the last K - 1 rows of [tail | the row's real tokens]
-                new_tail = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
-                    e, n, cfg.mamba_conv - 1, axis=0))(ext, n_valid)
-                state = write(state, index, rows,
-                              _pack_tail(cfg, new_tail), cfg.tail_part)
-            with jax.named_scope("ssm_state"):
-                state = write(state, index, rows,
-                              ssm.state_from_heads(h_t), cfg.state_part)
-        x = x + cfg.residual_multiplier * out
+            (xbc_d, xbc_c), (dt_d, dt_c) = call.split(xbc), call.split(dt)
+            state, mixed_c, xs_c = _ssm_rows(
+                cfg, w, state, index, rows[1], fresh[1], xbc_c, dt_c, A,
+                call.chunk_valid[None])
+            state, mixed_d, xs_d = _ssm_rows(
+                cfg, w, state, index, rows[0], fresh[0], xbc_d, dt_d, A,
+                None)
+            mixed = call.join(mixed_d, mixed_c)
+            xs = call.join(xs_d, xs_c)
+        x = x + cfg.residual_multiplier * _mixer_out(cfg, mixed, xs, z, w)
     return _mlp(cfg, x, w), {**pools, "ssm": state}
 
 
@@ -524,6 +557,9 @@ def init_paged_cache(cfg: GraniteHybridConfig, num_blocks: int,
                          jnp.dtype(cfg.state_dtype))}
 
 
+MIXED_PAGED = True      # as llama's: ``apply_paged`` takes a mixed call
+
+
 def apply_paged(cfg: GraniteHybridConfig, params: Params,
                 tokens: jnp.ndarray, cache: Params,
                 block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,
@@ -534,20 +570,31 @@ def apply_paged(cfg: GraniteHybridConfig, params: Params,
     decode steps): ``llama.apply_paged``'s contract, and ``slots [b]``, each
     row's sequence slot (``arange(b)`` when not given: row i is slot i). A
     row at context offset 0 starts its recurrent state from zeros; a row
-    with no valid token leaves its slot's state as it was."""
+    with no valid token leaves its slot's state as it was. A mixed call
+    (``block_tables`` a ``_paged.MixedCall``): decode row i is slot i and
+    the chunk's rows are ``chunk_slot``'s."""
     _check(cfg)
     b, t = tokens.shape
     if valid is None:
         valid = jnp.ones((b, t), bool)
-    if slots is None:
-        slots = jnp.arange(b, dtype=jnp.int32)
     compute_dtype, layers = _compute_layers(cfg, params, compute_dtype)
-    positions = context_lens[:, None] + jnp.arange(t)[None, :]
-    rows = ssm.pool_rows(slots, valid[:, 0], cache["ssm"])
-    fresh = context_lens == 0
+    positions = row_positions(block_tables, context_lens, t)
+    pool = cache["ssm"]
+    call = block_tables if isinstance(block_tables, MixedCall) else None
+    if call is None:
+        if slots is None:
+            slots = jnp.arange(b, dtype=jnp.int32)
+        rows = ssm.pool_rows(slots, valid[:, 0], pool)
+        fresh = context_lens == 0
+    else:
+        rows = (ssm.pool_rows(jnp.arange(call.slots), call.active, pool),
+                ssm.pool_rows(call.chunk_slot[None],
+                              (call.chunk_valid > 0)[None], pool))
+        fresh = (call.lens == 0, (call.chunk_ctx == 0)[None])
 
     def mamba(x, w, pools, index):
-        return _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid)
+        return _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid,
+                            call)
 
     def attn(x, w, pools, index):
         return _attention_paged(cfg, x, w, pools, index, block_tables,

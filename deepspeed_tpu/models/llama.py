@@ -31,7 +31,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ._paged import paged_attention_step, scan_layers
+from ._paged import paged_attention_step, row_positions, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -601,6 +601,11 @@ def _block_paged(cfg: LlamaConfig, x: jnp.ndarray, layer: Params,
     return x, k_cache, v_cache
 
 
+# ``apply_paged`` takes a mixed call (``_paged.MixedCall``): the engine reads
+# this through ``ModelFamily.mixed_paged``
+MIXED_PAGED = True
+
+
 def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
@@ -610,14 +615,17 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
 
     tokens [B, t]; context_lens [B] tokens already cached per sequence;
     block_tables [B, max_blocks] into the shared pool; valid [B, t] marks
-    real (non-pad) tokens. Returns (logits [B, t, vocab] fp32, cache)."""
+    real (non-pad) tokens. A mixed call (``MIXED_PAGED``): ``block_tables``
+    is a ``_paged.MixedCall``, ``context_lens`` None and tokens
+    [1, slots + t] - every slot's decode token, then one prefill chunk.
+    Returns (logits [B, t, vocab] fp32, cache)."""
     b, t = tokens.shape
     if valid is None:
         valid = jnp.ones((b, t), bool)
     with jax.named_scope("embed"):
         x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
-    positions = context_lens[:, None] + jnp.arange(t)[None, :]
+    positions = row_positions(block_tables, context_lens, t)
 
     layers = jax.tree.map(lambda p: p.astype(compute_dtype)
                           if jnp.issubdtype(p.dtype, jnp.floating) else p,

@@ -22,7 +22,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..moe.layer import MoELayer, init_moe_ffn, moe_ffn_logical_axes
 from ..moe.sharded_moe import compute_capacity
 from ..ops.attention import attention
-from ._paged import paged_attention_step, scan_layers
+from ._paged import paged_attention_step, row_positions, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -391,13 +391,18 @@ def moe_rows(cfg: MixtralConfig, rows: int) -> Dict[str, int]:
             "moe_rows_computed": cfg.num_experts * capacity}
 
 
+MIXED_PAGED = True      # as llama's: ``apply_paged`` takes a mixed call
+
+
 def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
                 valid: Optional[jnp.ndarray] = None,
                 compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """Ragged forward over the paged cache (see llama.apply_paged for the
-    contract); the FFN is the no-drop MoE routing of apply_cached."""
+    contract, a mixed call included: its ``slots + t`` rows go through the
+    expert bank as one call's rows); the FFN is the no-drop MoE routing of
+    apply_cached."""
     b, t = tokens.shape
     nh, hd = cfg.num_heads, cfg.head_size
     if valid is None:
@@ -405,7 +410,7 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     with jax.named_scope("embed"):
         x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
-    positions = context_lens[:, None] + jnp.arange(t)[None, :]
+    positions = row_positions(block_tables, context_lens, t)
     moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
                          cfg.min_capacity, drop_tokens=False,
                          norm_topk=cfg.norm_topk_prob,

@@ -266,9 +266,10 @@ SERVE_CELLS = ("mistral-7b.serve-chat", "mixtral-8x7b.serve-longprompt",
 POOL_DEPTH = 2      # the layer scan makes the program the same at any depth
 
 
-def _cell_forward(cell_name, quant):
+def _cell_forward(cell_name, quant, depth=POOL_DEPTH):
     """A serve cell's paged forward on shapes, at its published widths and
-    its pool geometry (``benchmark/configs``), ``POOL_DEPTH`` layers deep:
+    its pool geometry (``benchmark/configs``), ``depth`` layers deep (None:
+    as the cell runs it):
     ``(forward(params, cache, tokens, tables, ctx, valid) -> (logits,
     cache), params, cache, slots, chunk, table width)``."""
     from benchmark.harness.manifest import Cell
@@ -277,7 +278,8 @@ def _cell_forward(cell_name, quant):
     engine = cell.role["engine"]
     ragged = engine["ragged"]
     cfg = cell.family.build_cfg(
-        {**cell.model, "num_hidden_layers": POOL_DEPTH},
+        {**cell.model,
+         "num_hidden_layers": depth or cell.model["num_hidden_layers"]},
         **cell.role["program_options"])
     module = cell.family.module()
     params = jax.eval_shape(lambda k: module.init(cfg, k),
@@ -486,6 +488,75 @@ def test_the_state_pool_stays_where_it_is(v5e, program):
                                                 else 0)
     attn = "paged_decode" if program == "decode" else "paged_prefill"
     assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
+
+
+# --- a step's chunk rides in its decode program (ISSUE 32) ----------------- #
+V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)     # what the chip's allocator holds
+
+
+def _mixed_program(cell_name):
+    """A serve cell's forward of a MIXED call (``_paged.MixedCall``) on
+    shapes, at the cell's own depth: every slot's decode token and one
+    SplitFuse chunk as ``slots + chunk`` rows. ``(forward, arguments)``
+    with the cache second."""
+    from deepspeed_tpu.models._paged import MixedCall
+
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    if cell_name == GRANITE_CELL:
+        forward, (params, cache, _, tables, *_) = _granite_program(
+            "decode", periods=4)
+        slots, table, chunk, slot = *tables.shape, 256, [s((), i32)]
+    else:
+        forward, params, cache, slots, chunk, table = _cell_forward(
+            cell_name, False, depth=None)
+        slot = []
+    call = MixedCall(s((slots, table), i32), s((slots,), i32),
+                     s((slots,), bool), s((table,), i32), s((), i32),
+                     s((), i32), *slot)
+    rows = slots + chunk
+    args = (params, cache, s((1, rows), i32), call, None, s((1, rows), bool))
+    return forward, args + ((None,) if slot else ())
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS + (GRANITE_CELL,))
+def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
+    """The four serve cells' mixed call (``slots + 256`` rows) at their real
+    configurations, compiled for the chip: the pools stay where they are
+    (no pool-shaped copy, every pool - Granite's state pool too - aliased
+    argument-to-result), a layer body that attends is one ``paged_decode``,
+    one ``paged_prefill`` and a ``paged_kv_write`` a segment, a Mamba layer
+    body one ``ssm_decode_update`` beside the chunk's state rows, the whole
+    program fits the chip, and each FFN / expert-bank weight meets ONE
+    matmul a layer body: ``slots + 256`` rows wide, where the two programs
+    had one of 256 rows and one of ``slots``."""
+    import math
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = _mixed_program(cell)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pools = jax.tree.leaves(args[1])
+    assert pool_copy_bytes(text, pools) == 0
+    assert mem.alias_size_in_bytes >= sum(
+        math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert 0 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    assert (calls.count("paged_decode"), calls.count("paged_prefill"),
+            calls.count("paged_kv_write")) == (1, 1, 2)
+    assert calls.count("ssm_decode_update") == (
+        2 if cell == GRANITE_CELL else 0)
+    # every matmul against a weight (bf16; the blocked scan's own are f32)
+    # runs over the call's rows: none over one segment's alone
+    rows = args[2].shape[1]
+    matmuls = [tuple(map(int, dims.split(","))) for dims in re.findall(
+        r"= bf16\[([\d,]+)\]\S* convolution\(", text)]
+    assert len(matmuls) >= 7 and all(rows in dims for dims in matmuls)
 
 
 # head size 64 (half a lane tile) in plain pools, and the lane-packed geometry

@@ -1,7 +1,8 @@
 """The serving engine's programs, to the letter.
 
-``inference/engine_v2.py`` builds four forward programs (prefill, chunk
-prefill, decode, speculative verify) in the variants the dispatch sites
+``inference/engine_v2.py`` builds five forward programs (prefill, chunk
+prefill, decode, a step's chunk inside its decode - ISSUE 32's
+``decode_chunk`` -, speculative verify) in the variants the dispatch sites
 choose between, and five families route their paged pools through
 ``models/_paged.scan_layers``. A PR that reshapes that code without meaning
 to change a program must leave every jaxpr here as it was. The hashes below
@@ -87,10 +88,14 @@ def _args(eng):
         "chunk": head + (s((1, PAD_T), i32), s((), i32), s((), i32),
                          s((width,), i32), key, s((), i32)),
         "decode": decode,
+        # the slots as decode takes them, the chunk as chunk_prefill does
+        "decode_chunk": decode[:-1] + (
+            s((1, PAD_T), i32), s((), i32), s((), i32), s((width,), i32),
+            key, s((), i32)),
         "verify": head + (s((SLOTS, KP1), i32),) + slots + (
             s((SLOTS,), i32), s((SLOTS, KP1 - 1), i32), key,
             s((SLOTS,), i32)) + rows(SLOTS),
-        "rows2": rows(2), "rows": rows(SLOTS)}
+        "rows2": rows(2), "rows": rows(SLOTS), "rows+1": rows(SLOTS + 1)}
 
 
 # name -> (engine, its builder's call, the arguments' names in ``_args``)
@@ -123,6 +128,12 @@ PROGRAMS = {
                            ("decode",)),
     "chunk_prefill.mid.int8": ("int8", lambda e: e._chunk_prefill_fn(
         PAD_T, False, SamplingParams(greedy=True)), ("chunk",)),
+    "decode_chunk.greedy": ("bf16", lambda e: e._decode_chunk_fn(
+        PAD_T, False), ("decode_chunk",)),
+    "decode_chunk.rows": ("bf16", lambda e: e._decode_chunk_fn(PAD_T, True),
+                          ("decode_chunk", "rows+1")),
+    "decode_chunk.greedy.int8": ("int8", lambda e: e._decode_chunk_fn(
+        PAD_T, False), ("decode_chunk",)),
 }
 
 
@@ -153,7 +164,8 @@ def paged_text(family: str, t: int) -> str:
 
 
 # ISSUE 29's: taken on its finished tree under this directory's conftest (the
-# lines before it were f552895's). A PR that means to change one of these
+# lines before it were f552895's); the three ``decode_chunk`` lines are ISSUE
+# 32's, which added the program. A PR that means to change one of these
 # programs replaces its line.
 PARENT_HASHES = {
     "chunk_prefill.final_greedy": "2fba01067a00967c",
@@ -161,6 +173,9 @@ PARENT_HASHES = {
     "chunk_prefill.mid": "a4afc6de0baf7628",
     "chunk_prefill.mid.int8": "acc809c779ddf322",
     "decode.greedy": "0a6f5a9965fc0637",
+    "decode_chunk.greedy": "c8d8932dbd014a34",
+    "decode_chunk.greedy.int8": "abf75ec465c9de69",
+    "decode_chunk.rows": "23136133943497b0",
     "decode.greedy.int8": "d097770f52fa7f7a",
     "decode.rows": "df2f47272cb02bc6",
     "decode_many.greedy": "f0fd8799d66418a7",
@@ -243,10 +258,13 @@ def test_the_engine_serves_the_parents_tokens(mode):
 
 def test_the_programs_keep_the_names_the_benchmark_reads(engines):
     """``benchmark/readers`` find ``jit_decode`` and ``jit_chunk_prefill``
-    by the inner functions' names, which the pinned text carries."""
+    by the inner functions' names, which the pinned text carries; the
+    ``^jit_decode`` pattern also finds a mixed step's ``jit_decode_chunk``."""
     for name, want in (("decode.greedy", "name=decode"),
                        ("decode_many.rows", "name=decode_many"),
                        ("chunk_prefill.mid", "name=chunk_prefill"),
+                       ("decode_chunk.greedy", "name=decode_chunk"),
+                       ("decode_chunk.rows", "name=decode_chunk"),
                        ("prefill_ctx.rows", "name=prefill"),
                        ("spec_verify", "name=verify")):
         assert re.search(want + r"\b", program_text(engines, name)), name
@@ -275,17 +293,23 @@ class _Counted:
 
 
 # of the parent (f552895): the slot arrays, a key, and in rows mode the four
-# sampling arrays. Queue A4 replaces this line on purpose.
+# sampling arrays. ``mixed`` (ISSUE 32): a step whose chunk rides in its
+# decode program uploads the slot arrays, the chunk's four and the uid with
+# ONE key, where the two programs uploaded 5 + 4 with two. Queue A2 replaces
+# these lines on purpose.
 PARENT_HOST_OPS = {"greedy": {"asarray": 4, "PRNGKey": 1},
-                   "rows": {"asarray": 8, "PRNGKey": 1}}
+                   "rows": {"asarray": 8, "PRNGKey": 1},
+                   "mixed": {"asarray": 9, "PRNGKey": 1}}
 
 
 @pytest.mark.parametrize("mode", sorted(PARENT_HOST_OPS))
 def test_a_step_uploads_and_makes_keys_as_the_parent_did(monkeypatch, mode):
     eng = _engine()
-    sp = SamplingParams(greedy=True) if mode == "greedy" else STOCHASTIC
+    sp = STOCHASTIC if mode == "rows" else SamplingParams(greedy=True)
     eng.put(1, list(range(5)), sp)
     eng.put(2, list(range(7)))
+    if mode == "mixed":
+        eng.put_split(3, list(range(21)))        # three chunks of 8
     eng.step()                                   # warm: the program exists
     counts = {"asarray": 0, "PRNGKey": 0}
     monkeypatch.setattr(engine_mod, "jnp",
@@ -295,3 +319,4 @@ def test_a_step_uploads_and_makes_keys_as_the_parent_did(monkeypatch, mode):
     out = eng.step(seed=1)
     assert sorted(out) == [1, 2]
     assert counts == PARENT_HOST_OPS[mode]
+    assert eng.mixed_steps == (2 if mode == "mixed" else 0)

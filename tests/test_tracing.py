@@ -606,15 +606,20 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     assert sum(t["tokens_out"] for t, _ in ticks) == sum(m for _, m in sizes)
     # under the engine step: each dispatch with its host phases, in order;
     # a chunk that does not end its prompt has no engine_wait
+    # (a chunk beside live decodes rides in their ``decode_step``: one
+    # program, one span, the chunk's facts as ``chunk_*``)
     chunks = prof.named("prefill_chunk")
+    decodes = prof.named("decode_step")
+    mixed = [d for d in decodes if int(d[3]["chunk_tokens"])]
+    assert mixed and len(mixed) == eng.mixed_steps
     assert sum(int(c[3]["tokens"]) for c in chunks) \
+        + sum(int(d[3]["chunk_tokens"]) for d in mixed) \
         == sum(n for n, _ in sizes if n > 16)
     for c in chunks:
         want = ["engine_prep", "engine_dispatch"]
         if c[3]["final"] in ("True", "1", 1, True):
             want += ["engine_wait", "engine_emit"]
         assert [k[0] for k in prof.children(c)] == want
-    decodes = prof.named("decode_step")
     assert decodes and all(
         [k[0] for k in prof.children(d)] == ["engine_prep", "engine_dispatch",
                                              "engine_wait", "engine_emit"]
