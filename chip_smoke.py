@@ -313,6 +313,109 @@ def serve_pass(eng, prompts, new_tokens: int):
     return handles, time.perf_counter() - t0
 
 
+def synchronous(sched):
+    """The tick as it was before a program stayed in flight: the engine's
+    ``step()`` - launch, then collect at once, what ``generate()`` and the
+    logit probes below call - and the tick returns its own program's
+    tokens."""
+    eng = sched.engine
+
+    def step_engine(seed):
+        if not eng.state.seqs:
+            return {}, {"decode_seqs": 0, "kv_tokens": 0}
+        return eng.step(seed=seed), eng.last_step
+
+    sched._step_engine = step_engine
+    return sched
+
+
+def check_overlap(eng, prompts, new_tokens: int, greedy) -> dict:
+    """The streams of the ticks as shipped (program n+1 launched before
+    program n's tokens are read, the tokens resolved on the device) against
+    the same requests through synchronous ``step()`` ticks with the same
+    seeds: every stream token for token. Every other request is sampled (its
+    own row of the ``_dyn`` programs); request 0 ends on an ``eos_token_id``
+    taken from the middle of the stream it has without one, so a row
+    launched past its end is dropped; request 1 is preempted with a token in
+    flight and resumed; the long prompts' first tokens are seated from the
+    final chunk's result. Then fused quanta (``decode_quantum=4``: each
+    drains what is in flight) against ``greedy``, the single steps' streams
+    of the same prompts, and a quantum of ONE tick."""
+    from deepspeed_tpu.inference.sampling import SamplingParams
+    from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
+                                                 ServingScheduler)
+
+    hot = SamplingParams(temperature=0.8, top_k=40)
+
+    def streams(sched, eos=None):
+        # a sampled row's noise is its SLOT's (one categorical draw over
+        # [slots, vocab]): every pass seats its requests in the same slots
+        eng.state._free_slots.sort(reverse=True)
+        handles = [sched.submit(Request(
+            prompt=p, max_new_tokens=new_tokens,
+            eos_token_id=eos if i == 0 else None,
+            **({"sp": hot} if i % 2 else {})))
+            for i, p in enumerate(prompts)]
+        before = eng.overlapped_steps
+        ticks = 0
+        while sched.pending:
+            sched.tick()
+            ticks += 1
+            if ticks == 2:
+                sched.preempt(handles[1].uid)
+        require(all(h.state == "done" for h in handles),
+                f"requests ended {[h.state for h in handles]}")
+        return ([h.tokens for h in handles], ticks,
+                eng.overlapped_steps - before, sched.stats["preempted"])
+
+    new = lambda: ServingScheduler(eng, SchedulerConfig())  # noqa: E731
+    whole = streams(synchronous(new()))[0][0]
+    eos = whole[new_tokens // 2]
+    want, sync_ticks, sync_over, _ = streams(synchronous(new()), eos)
+    got, ticks, overlapped, preempted = streams(new(), eos)
+    differ = [i for i, (a, b) in enumerate(zip(got, want)) if a != b]
+    require(not differ, f"overlapped ticks stream other tokens than "
+            f"synchronous steps for requests {differ}: "
+            f"{[(got[i], want[i]) for i in differ[:2]]}")
+    require(sync_over == 0 and overlapped > 0 and preempted == 1,
+            f"overlapped_steps {sync_over} through step(), {overlapped} "
+            f"through the ticks; {preempted} preemptions")
+    require(got[0] == whole[:whole.index(eos) + 1],
+            f"the eos stream is {got[0]}, without its eos {whole}")
+    require(all(len(t) == new_tokens for t in got[1:]),
+            f"stream lengths {[len(t) for t in got]}")
+
+    # fused quanta: another compiled program (a scan of the tick), so equal
+    # streams are reported and not required at bf16 with random weights
+    sched = ServingScheduler(eng, SchedulerConfig(decode_quantum=4))
+    handles = [sched.submit(Request(prompt=p, max_new_tokens=new_tokens))
+               for p in prompts]
+    sched.run()
+    require(all(h.state == "done" and len(h.tokens) == new_tokens
+                for h in handles), "a fused-quantum stream ended short")
+    one = {}
+    for name, step in (("quantum", lambda: eng.step_many(1, seed=7)[9][0]),
+                       ("step", lambda: eng.step(seed=7)[9])):
+        eng.put(9, prompts[0])
+        one[name] = [step() for _ in range(3)]
+        eng.finish(9)
+    require(one["quantum"] == one["step"],
+            f"a quantum of one tick gives {one}")
+    require(eng.in_flight == 0 and not eng.state.seqs,
+            "the engine did not end empty")
+    return {"requests": len(prompts), "sampled": len(prompts) // 2,
+            "streams_equal": len(prompts) - len(differ),
+            "eos_stream_tokens": len(got[0]), "preempted": preempted,
+            "ticks": ticks, "synchronous_ticks": sync_ticks,
+            "overlapped_steps": overlapped,
+            # tokens before a fused-quantum stream first leaves its
+            # single-step stream (``new_tokens``: never)
+            "quantum_tokens_as_greedy": [
+                next((j for j, (a, b) in enumerate(zip(h.tokens, g))
+                      if a != b), new_tokens)
+                for h, g in zip(handles, greedy)]}
+
+
 def total_compiles(eng) -> int:
     return sum(int(s["compiles"])
                for s in eng.compile_monitor.summary().values())
@@ -431,6 +534,9 @@ def phase_serve(size: ServeSize, seed: int, mosaic: bool = True,
         require(decode_hlo.count(MOSAIC) > 0,
                 "no Mosaic kernel in the decode step: paged attention ran "
                 "the XLA reference")
+    say(phase="serve_overlap",
+        **check_overlap(eng, prompts, size.new_tokens,
+                        [h.tokens for h in handles]))
 
 
 # --------------------------------------------------------------------------- #

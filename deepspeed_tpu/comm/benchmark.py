@@ -73,8 +73,10 @@ def bench_collective(op: str, nbytes: int, *, axis: str = "data",
     busbw = payload * _FACTORS[op](n) / dt
     return {"op": op, "bytes": int(payload), "world": int(n),
             "latency_us": round(dt * 1e6, 1),
-            "algbw_GBps": round(payload / dt / 1e9, 3),
-            "busbw_GBps": round(busbw / 1e9, 3)}
+            # six places: a 4 KB collective on a loaded host is under
+            # 0.0005 GB/s, and a rate never reads 0
+            "algbw_GBps": round(payload / dt / 1e9, 6),
+            "busbw_GBps": round(busbw / 1e9, 6)}
 
 
 def sweep(ops: List[str] = ("all_reduce", "all_gather", "reduce_scatter",

@@ -22,8 +22,10 @@ serving then emits >1 token per model step without a second model.
 from __future__ import annotations
 
 import time
+from collections import Counter, deque
 from itertools import repeat
-from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterator, List, NamedTuple, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +85,24 @@ _HANDOFF = ("a sequence's blocks are not its whole state, and the "
             "destination resumes through the prefix cache")
 
 
+# how a slot's row of a decode-shaped program finds its token (``_slot_src``;
+# resolved on the device by ``_own_tokens``): the host's copy, the slot's entry
+# of the newest launched program's result, or that result's LAST entry - the
+# first token of the prompt whose final chunk that program ran
+_FROM_HOST, _FROM_SLOT, _FROM_CHUNK = 0, -1, -2
+
+
+def _own_tokens(prev, seat):
+    """The slots' token row, built inside the program: ``seat`` [slots] is
+    the host's row - a token where the host seated the slot, else one of the
+    two negative codes above - and ``prev`` [slots + 1] the token result of
+    the program launched before this one, which the host may not have read
+    yet (``InferenceEngineV2.launch``)."""
+    b = seat.shape[0]
+    return jnp.where(seat >= 0, seat,
+                     jnp.where(seat == _FROM_CHUNK, prev[b], prev[:b]))
+
+
 class _Chunk(NamedTuple):
     """The oldest pending split prefill's next chunk (``_next_chunk``)."""
     uid: int
@@ -101,6 +121,17 @@ class _Chunk(NamedTuple):
         padded[0, :len(self.tokens)] = self.tokens
         return (padded, np.int32(len(self.tokens)), np.int32(self.ctx),
                 table) + ((np.int32(self.desc.slot),) if recurrent else ())
+
+
+class _Flight(NamedTuple):
+    """A launched decode-shaped program whose tokens the host has not read
+    (``launch`` appends, ``_read`` lands them)."""
+    toks: Any                   # its result on the device, [slots + 1]
+    live: Tuple                 # the sequences that had a decode row
+    chunk: Optional[_Chunk]     # the chunk it ran (final: toks[-1] is the
+                                # prompt's first token)
+    t0: Optional[int]           # a mixed step's dispatch time, for the
+                                # request's ring record; else None
 
 
 def _last_row(logits, lengths):
@@ -240,8 +271,25 @@ class InferenceEngineV2(InferenceEngine):
                                          self._spill_write_block)
         # persistent device-side slot state
         B = rc.max_tracked_sequences
-        self._slot_tokens = np.zeros((B,), np.int32)
+        self._slot_tokens = np.zeros((B,), np.int32)   # the host's copy
         self._slot_lens = np.zeros((B,), np.int32)
+        # --- one program in flight (docs/serving.md "One program in
+        # flight"): ``launch`` dispatches a step's program and ``collect``
+        # reads its tokens, and a scheduler tick launches program n+1 BEFORE
+        # it collects program n, so the host's part of a tick runs under the
+        # device. The one thing program n+1 needs of n's result - each
+        # slot's last token - stays on the device: ``_prev`` is the newest
+        # launched program's token result ([slots + 1], zeros before the
+        # first: ONE shape and ONE placement whichever program produced it,
+        # so no program compiles twice), ``_slot_src`` says which slots take
+        # their token from it, ``_flight`` holds the launched programs the
+        # host has not read, and ``_out`` the tokens read and not yet handed
+        # to a caller.
+        self._prev = jax.device_put(jnp.zeros((B + 1,), jnp.int32),
+                                    self.mesh_mgr.replicated())
+        self._slot_src = np.zeros((B,), np.int32)
+        self._flight: deque = deque()
+        self._out: Dict[int, List[int]] = {}
         self._slot_tables = np.zeros((B, max_blocks_per_seq), np.int32)
         # per-slot sampling params, recorded at admission — decode honors
         # these (the reference's v2 engine carries per-request sampling)
@@ -284,10 +332,13 @@ class InferenceEngineV2(InferenceEngine):
         # their ssm_rows / ssm_tokens (a family with recurrent state)
         self._admitted_ssm: Dict[str, int] = {}
         self.prefill_tokens_written = 0
-        # steps that ran their chunk and their decodes as one program
-        # (``_decode_chunk``; ``Serving/engine/mixed_steps``)
+        # steps, those that ran their chunk and their decodes as one program
+        # and those launched while the program before was still unread
+        # (``_launch_decode``; ``Serving/engine/{steps, mixed_steps,
+        # overlapped_steps}``)
         self.steps = 0
         self.mixed_steps = 0
+        self.overlapped_steps = 0
         # --- recompilation sentinel + per-program MFU attribution
         # (telemetry/compile.py; docs/observability.md). A hub with an
         # ENABLED monitor is shared — serving programs land in the same
@@ -674,14 +725,19 @@ class InferenceEngineV2(InferenceEngine):
                                              donate_argnums=(1,))
         return self._paged_fns[key]
 
-    def _dispatch(self, fn, pre, seed: int, post=()):
+    def _dispatch(self, fn, pre, seed: int, post=(), prev: bool = False):
         """The one place a forward program is launched: ``pre`` and ``post``
         are the call's host arrays in the program's argument order, on
         either side of its rng key, each uploaded here and nowhere else.
-        Returns what the program returns as a tuple - the donated cache
-        last, for the caller to take back."""
+        ``prev``: the program resolves its slots' tokens from the newest
+        launched result (``_own_tokens``), which goes in after the cache as
+        the device array it is - never read, never uploaded. Returns what
+        the program returns as a tuple - the donated cache last, for the
+        caller to take back."""
         with self.tracer.span("engine_dispatch", cat="serving"):
-            out = fn(self.params, self.cache, *map(jnp.asarray, pre),
+            out = fn(self.params, self.cache,
+                     *((self._prev,) if prev else ()),
+                     *map(jnp.asarray, pre),
                      jax.random.PRNGKey(seed), *map(jnp.asarray, post))
         return out if isinstance(out, tuple) else (out,)
 
@@ -752,25 +808,29 @@ class InferenceEngineV2(InferenceEngine):
                 "final": ch.final,
                 "kv_blocks": self._kv_blocks(ch.ctx + len(ch.tokens))}
 
-    def _chunk_landed(self, ch: _Chunk, table, tok=None) -> Dict[int, int]:
-        """The bookkeeping of a chunk whose program has been dispatched;
-        ``tok`` is the first token a final chunk sampled, and the sequence
-        is then seated for the NEXT decode-shaped call. Returns {uid: tok}
-        for a final chunk, else {}."""
+    def _chunk_landed(self, ch: _Chunk, table) -> None:
+        """The bookkeeping of a chunk whose program has been dispatched, all
+        of it lengths: a final chunk's sequence is seated for the NEXT
+        decode-shaped call, and its first token lands when the host reads
+        it (``_first_token``)."""
         n, desc = len(ch.tokens), ch.desc
         self.last_step["prefill_tokens"] += n
         self.last_step["prefill_kv_tokens"] += ch.ctx + n
         self.prefill_tokens_written += n
         desc.seen_tokens = ch.ctx + n
         self.state.mark_filled(desc)      # completed chunks are matchable
-        if not ch.final:
-            return {}
-        del self._pending_prefill[ch.uid]
-        desc.prefilling = False
+        if ch.final:
+            del self._pending_prefill[ch.uid]
+            desc.prefilling = False
+            self._seat(desc, table, self._canon_sp(ch.sp))
+
+    def _first_token(self, ch: _Chunk, tok: int) -> None:
+        """The first token a final chunk sampled, read from the device."""
+        desc = ch.desc
         desc.last_token = tok
         desc.generated.append(tok)
-        self._seat(desc, table, self._canon_sp(ch.sp))
-        return {ch.uid: tok}
+        self._slot_tokens[desc.slot] = tok
+        self._out.setdefault(ch.uid, []).append(tok)
 
     def _chunk_program(self, ch: _Chunk, live, table, mixed: bool = True):
         """(program, its arrays before the key, its arrays after) for one
@@ -794,14 +854,16 @@ class InferenceEngineV2(InferenceEngine):
                 self._slots(live) + chunk,
                 uid + (sp_arrays(self._slot_sp + [sp]) if rows else ()))
 
-    def _advance_prefill(self, seed: int = 0,
-                         mixed: bool = False) -> Dict[int, int]:
+    def _advance_prefill(self, seed: int = 0, mixed: bool = False) -> bool:
         """Advance the oldest pending split prefill by one chunk with
         nothing decoding beside it, sampling with the SamplingParams given
         at put_split time (``mixed``: see ``_chunk_program``). Returns
-        {uid: first_token} when that chunk completes the prompt, else {}."""
+        whether that chunk completed its prompt. Its first token is then in
+        flight (``mixed``: the program is decode-shaped, and the next one
+        takes the token from its result) or, from ``chunk_prefill``, read
+        here and now."""
         if not self._pending_prefill:
-            return {}
+            return False
         ch = self._next_chunk()
         rec = self._req.get(ch.uid)     # the request's ring lifecycle
         rows = ch.width + (len(self._slot_tokens) if mixed else 0)
@@ -817,23 +879,31 @@ class InferenceEngineV2(InferenceEngine):
                 fn, pre, post = self._chunk_program(ch, (), table, mixed)
             if self._trace_on:
                 self._req_compute_begin(ch.uid)  # first chunk ends queue-wait
-            *tok, self.cache = self._dispatch(fn, pre, seed, post)
+            *tok, self.cache = self._dispatch(fn, pre, seed, post,
+                                              prev=mixed)
+            self._chunk_landed(ch, table)
             if not ch.final:
                 # no engine_wait: the call is asynchronous and nothing here
                 # blocks on it (nor reads a token the program sampled for
                 # nothing), so this span says dispatch, not device
-                return self._chunk_landed(ch, table)
+                return False
+            if mixed:
+                self._launched(tok[0], (), ch, None)
+                return True
+            self._drain()       # what was launched before it lands first
             with self.tracer.span("engine_wait", cat="serving"):
-                tok = int(np.asarray(tok[0]).reshape(-1)[-1])
+                tok = int(np.asarray(tok[0]))
             if self._trace_on:
                 self._req_first_token(ch.uid, time.monotonic_ns())
             with self.tracer.span("engine_emit", cat="serving"):
-                return self._chunk_landed(ch, table, tok)
+                self._first_token(ch, tok)
+            return True
 
     def _seat(self, desc, table, sp: SamplingParams) -> None:
         """The sequence's slot as the next decode-shaped call reads it."""
         s = desc.slot
         self._slot_tokens[s] = desc.last_token
+        self._slot_src[s] = _FROM_HOST
         self._slot_lens[s] = desc.seen_tokens
         self._slot_tables[s] = table
         self._slot_sp[s] = sp
@@ -865,16 +935,22 @@ class InferenceEngineV2(InferenceEngine):
         network-attached TPU the per-step host round-trip dominates
         single-step decode, so the scan is the serving fast path (block
         capacity is reserved for all k tokens before launch — see
-        ``_reserve``). ``rows``: see ``_sampler``."""
+        ``_reserve``). ``rows``: see ``_sampler``. The slots' tokens are
+        resolved on the device from the result of the program launched
+        before (``_own_tokens``), and the single step returns its tokens in
+        that result's one shape, ``[slots + 1]`` (the last entry is a final
+        chunk's first token in ``decode_chunk`` and nothing here), so the
+        next program takes either's result under one signature."""
         name = ("decode" if k == 1 else "decode_many") \
             + ("_dyn" if rows else "")
         key = (name, k)
         if key not in self._paged_fns:
             pick = _sampler(rows)
 
-            def decode(params, cache, tokens, lens, tables, active, rng,
+            def decode(params, cache, prev, seat, lens, tables, active, rng,
                        *sp_rows):
                 dq = self._dq(params)
+                tokens = _own_tokens(prev, seat)
 
                 def tick(tokens, lens, cache, key_t):
                     # inactive slots write nothing (valid=False)
@@ -885,7 +961,8 @@ class InferenceEngineV2(InferenceEngine):
                     return nxt.astype(jnp.int32), cache
 
                 if k == 1:
-                    return tick(tokens, lens, cache, rng)
+                    nxt, cache = tick(tokens, lens, cache, rng)
+                    return jnp.pad(nxt, (0, 1)), cache
 
                 def body(carry, key_t):
                     tokens, lens, cache = carry
@@ -916,13 +993,14 @@ class InferenceEngineV2(InferenceEngine):
         token of a chunk that does not end its prompt - so the program
         compiles once a ``(slots, chunk_t)``, greedy or ``rows`` (see
         ``_sampler``; the per-row arrays are the slots' and then the
-        chunk's). Returns (tokens [slots + 1], cache)."""
+        chunk's). The slots' tokens come as ``decode``'s do. Returns (tokens
+        [slots + 1], cache)."""
         name = "decode_chunk" + ("_dyn" if rows else "")
         key = (name, chunk_t)
         if key not in self._paged_fns:
             pick = _sampler(rows)
 
-            def decode_chunk(params, cache, tokens, lens, tables, active,
+            def decode_chunk(params, cache, prev, seat, lens, tables, active,
                              chunk, n_valid, ctx, table, *rest):
                 # the slots as ``decode`` takes them; the chunk as
                 # ``chunk_prefill`` does: chunk [1, chunk_t], then the
@@ -931,6 +1009,7 @@ class InferenceEngineV2(InferenceEngine):
                 rest = list(rest)
                 slot = rest.pop(0) if self._recurrent else None
                 rng, uid, *sp_rows = rest
+                tokens = _own_tokens(prev, seat)
                 b = tokens.shape[0]
                 call = MixedCall(tables, lens, active, table, ctx, n_valid,
                                  slot)
@@ -1000,15 +1079,15 @@ class InferenceEngineV2(InferenceEngine):
                                    self._spec_ngram_max,
                                    self._spec_min_match)
 
-    def _spec_step(self, live, seed: int = 0) -> Optional[Dict[int, List[int]]]:
+    def _spec_step(self, live, seed: int = 0) -> bool:
         """One speculative decode step over ``live``: draft, verify every
         draft position in one batched forward pass, accept the longest
-        agreeing prefix per sequence, roll back rejected KV. Returns
-        {uid: [emitted tokens]} — at least one token per sequence (the
-        correction/bonus sample), up to ``max_draft_tokens + 1`` — or None
+        agreeing prefix per sequence, roll back rejected KV. Each sequence
+        emits at least one token (the correction/bonus sample), up to
+        ``max_draft_tokens + 1``, for the next ``collect``. Returns False
         when no sequence produced a draft (the caller runs the plain decode
         program, keeping draft-less steps bit-identical to non-spec
-        serving)."""
+        serving). Drafts read history, so nothing may be in flight."""
         drafts = {d.uid: self._draft_tokens(d) for d in live}
         bs = self.state.block_size
         # capacity guard: verification may need blocks for up to k+1 new
@@ -1023,7 +1102,7 @@ class InferenceEngineV2(InferenceEngine):
                 self.state.retained_blocks:
             drafts = {u: [] for u in drafts}
         if not any(drafts.values()):
-            return None
+            return False
         kmax = self._spec_k
         self.spec_stats["verify_steps"] += 1
         self.spec_stats["step_seqs"] += len(live)
@@ -1084,7 +1163,7 @@ class InferenceEngineV2(InferenceEngine):
             self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
             span.set(kv_tokens=kv,
                      accepted=sum(len(v) - 1 for v in out.values()))
-        return out
+        return True
 
     # ------------------------------------------------------------------ #
     def put(self, uid: int, prompt_tokens, sp: SamplingParams = SamplingParams(greedy=True),
@@ -1136,6 +1215,7 @@ class InferenceEngineV2(InferenceEngine):
         the cache off) takes the original zero-offset programs unchanged."""
         if not entries:
             return {}
+        self._drain()       # the slots move: no program in flight across it
         sps = [self._canon_sp(s_) for s_ in sps]
         n = len(entries)
         n_pad = 1 << (n - 1).bit_length()
@@ -1209,39 +1289,39 @@ class InferenceEngineV2(InferenceEngine):
         return out
 
     # ------------------------------------------------------------------ #
-    # what step(), step_many() and _spec_step() share around their program
+    # what launch(), step_many() and _spec_step() share around their program
     # ------------------------------------------------------------------ #
-    def _prefill_then_live(self, seed: int, mixed: bool = False):
+    def _prefill_then_live(self, seed: int, mixed: bool = False, hold=()):
         """How a step begins: ``last_step`` starts over - the one-shot
         prefills that ran since the previous step (``put``/``put_many``, a
         scheduler tick's admissions) count with this step's
         ``prefill_kv_tokens`` -, the sequences to decode are listed (a
         prefilling one is not among them, the one this step's chunk
-        completes included: it has its first token only), and the oldest
-        split prefill advances one chunk. ``mixed``: the caller can run a
-        chunk and its decodes as ONE program (``_decode_chunk``); where
-        there are both, the chunk is left to it. Returns ({uid: first
-        token} of a prompt this completed, live, the chunk left over or
-        None)."""
+        completes included: it has its first token only; nor one of
+        ``hold``, the uids the caller knows to be at their end), and the
+        oldest split prefill advances one chunk. ``mixed``: the caller can
+        run a chunk and its decodes as ONE program (``_launch_decode``);
+        where there are both, the chunk is left to it. Returns (live, the
+        chunk left over or None)."""
         self.last_step = dict(_NO_WORK,
                               prefill_kv_tokens=self._admitted_kv_tokens,
                               **self._admitted_ssm)
         self._admitted_kv_tokens = 0
         self._admitted_ssm = {}
         live = [d for d in self.state.seqs.values()
-                if not d.finished and not d.prefilling]
+                if not d.finished and not d.prefilling and d.uid not in hold]
         if mixed and live and self._pending_prefill:
-            return {}, live, self._next_chunk()
-        first = self._advance_prefill(seed, mixed)
+            return live, self._next_chunk()
+        done = self._advance_prefill(seed, mixed)
         if not live:
             # no decodes in flight: the one-chunk-per-step bound exists to
             # protect live decodes from prefill stalls — with none to
             # protect, advance the oldest split prefill chunk after chunk
             # until it completes (it holds KV blocks the whole time), then
             # stop: the completed sequence is a live decode to protect again
-            while self._pending_prefill and not first:
-                first.update(self._advance_prefill(seed, mixed))
-        return first, live, None
+            while self._pending_prefill and not done:
+                done = self._advance_prefill(seed, mixed)
+        return live, None
 
     def _reserve(self, live, counts) -> None:
         """Room for the tokens a decode-shaped call is about to write:
@@ -1260,124 +1340,279 @@ class InferenceEngineV2(InferenceEngine):
 
     def _slots(self, live=(), tokens=None) -> Tuple:
         """(tokens, lens, tables, active) over every slot: what a
-        decode-shaped program takes after the cache. Active are the slots
-        of ``live``, the sequences the call decodes, and no other: a slot
-        seated by this step's final chunk, or one whose sequence has
-        finished and is not yet retired, holds a sequence but is not live,
-        and a row computed for it would write its KV (and advance a
-        recurrent state) a second time. ``tokens`` stands in for the slots'
-        last tokens (a verify window)."""
+        decode-shaped program takes after the cache (and after the newest
+        launched result, where it resolves its tokens from that:
+        ``_dispatch``). Active are the slots of ``live``, the sequences the
+        call decodes, and no other: a slot seated by this step's final
+        chunk, or one whose sequence has finished and is not yet retired,
+        holds a sequence but is not live, and a row computed for it would
+        write its KV (and advance a recurrent state) a second time. The
+        tokens are the seat row - the host's copy of a slot's last token, or
+        the code that says where on the device it lies (``_own_tokens``) -
+        unless ``tokens`` stands in (a verify window). Copies: a later
+        launch moves the slot arrays while this program may still wait."""
         active = np.zeros(self._slot_lens.shape, bool)
         active[[d.slot for d in live]] = True
-        return (self._slot_tokens if tokens is None else tokens,
-                self._slot_lens, self._slot_tables, active)
+        if tokens is None:
+            tokens = np.where(self._slot_src == _FROM_HOST,
+                              self._slot_tokens, self._slot_src)
+        return (tokens, self._slot_lens.copy(), self._slot_tables.copy(),
+                active)
 
     def _commit(self, d, written, emitted, t_ns: int) -> int:
-        """A decode-shaped call's tokens land on sequence ``d``: ``written``
-        are the ids whose KV the call wrote after the context (recorded so
-        the blocks they fill can be chain-hashed), ``emitted`` the tokens it
-        produced, the last of them pending its write by the next call.
-        Returns the sequence's KV length."""
+        """A synchronous decode-shaped call's tokens (a quantum's, a verify
+        window's) land on sequence ``d``: ``written`` are the ids whose KV
+        the call wrote after the context (recorded so the blocks they fill
+        can be chain-hashed), ``emitted`` the tokens it produced, the last
+        of them pending its write by the next call. Returns the sequence's
+        KV length."""
         d.tokens.extend(written)
         d.seen_tokens += len(written)
         d.last_token = emitted[-1]
         d.generated.extend(emitted)
         self._slot_tokens[d.slot] = d.last_token
+        self._slot_src[d.slot] = _FROM_HOST
         self._slot_lens[d.slot] = d.seen_tokens
         self.state.mark_filled(d)
+        self._out.setdefault(d.uid, []).extend(emitted)
         if self._trace_on:
             self._req_tokens(d.uid, len(emitted), t_ns)
         return d.seen_tokens
 
-    def _decode_ticks(self, k: int, live, seed: int, span, out,
-                      tiles: bool = False) -> None:
+    def _decode_quantum(self, k: int, live, seed: int, span) -> None:
         """``k`` decode ticks over ``live`` in one program, inside the
         caller's ``span``: reserve, dispatch, the ONE host sync, and each
-        sequence's k tokens into ``out[uid]``. ``tiles``: the span and
-        ``last_step`` also say what the first tick's attention grid walks."""
+        sequence's k tokens."""
         with self.tracer.span("engine_prep", cat="serving"):
             self._reserve(live, repeat(k))
-            extra = self._attn_tile_args() if tiles else {}
             # slots hold canonical params (``_canon_sp``): see ``_sampler``
             rows = any(self._slot_sp[d.slot] != _GREEDY for d in live)
             fn = self._decode_fn(k, rows)
             sp_rows = sp_arrays(self._slot_sp) if rows else ()
-        # (toks [k, B], lens, cache); the single step's (toks [B], cache)
+        # (toks [k, slots], lens, cache); a quantum clamped to ONE tick (a
+        # stream one token short of its context's end, ``step_many(1)``) is
+        # the single step's program: (toks [slots + 1], cache)
         toks, *_, self.cache = self._dispatch(fn, self._slots(live), seed,
-                                              sp_rows)
+                                              sp_rows, prev=True)
         with self.tracer.span("engine_wait", cat="serving"):
             toks = np.asarray(toks).reshape(k, -1)
         t1 = time.monotonic_ns() if self._trace_on else 0
         with self.tracer.span("engine_emit", cat="serving"):
-            self._commit_ticks(live, toks, out, t1, span, extra)
+            kv = 0
+            for d in live:
+                seq = toks[:, d.slot].tolist()
+                # KV writes of the call: the previous last_token, then each
+                # sampled token except the newest (still pending its write)
+                kv += self._commit(d, [d.last_token] + seq[:-1], seq, t1)
+            self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
+            span.set(kv_tokens=kv)
 
-    def _commit_ticks(self, live, toks, out, t_ns: int, span, extra) -> None:
-        """``toks`` [k, slots] of a decode-shaped call land on ``live``:
-        each sequence's k tokens into ``out[uid]``, and what the call did
-        onto ``last_step`` and its ``span``."""
-        kv = 0
-        for d in live:
-            out[d.uid] = seq = toks[:, d.slot].tolist()
-            # KV writes of the call: the previous last_token, then each
-            # sampled token except the newest (still pending its write)
-            kv += self._commit(d, [d.last_token] + seq[:-1], seq, t_ns)
-        self.last_step.update(decode_seqs=len(live), kv_tokens=kv, **extra)
-        span.set(kv_tokens=kv, **extra)
-
-    def _decode_chunk(self, ch: _Chunk, live, seed: int, out) -> None:
-        """One step's chunk AND its decodes in one program
-        (``_decode_chunk_fn``), under ONE ``decode_step`` span: ``batch``,
-        ``kv_tokens``, the tile counts and ``ssm_*`` are the decode rows' as
-        in a plain step, the MoE rows are the whole call's (``slots +
-        chunk`` rows: one pass through the expert bank, so no other span
-        may carry them), and the chunk's facts ride as ``chunk_*``. One
-        dispatch, one host sync - a final chunk's first token arrives with
-        the decodes' -, then the chunk's bookkeeping and the decodes'
-        commits in the order the two programs had. ``last_step`` counts
-        what the two calls counted."""
-        rec = self._req.get(ch.uid)
-        self._ssm_args(1, len(ch.tokens))        # ``last_step``'s count
+    # ------------------------------------------------------------------ #
+    # one program in flight: launch | collect (docs/serving.md)
+    # ------------------------------------------------------------------ #
+    def _launch_decode(self, live, seed: int,
+                       ch: Optional[_Chunk] = None) -> None:
+        """Launch one step's decodes over ``live`` - and, with ``ch``, its
+        prefill chunk in the same program (``_decode_chunk_fn``) - under ONE
+        ``decode_step`` span, and keep everything the launch can keep: every
+        argument of the span is a length or a shape (``batch``,
+        ``kv_tokens``, the tile counts and ``ssm_*`` are the decode rows',
+        the MoE rows the whole call's - one pass through the expert bank, so
+        no other span may carry them -, the chunk's facts ride as
+        ``chunk_*``; ``overlapped``: the program before was still unread),
+        each live sequence is one token longer, the chunk's bookkeeping is
+        done, ``last_step`` counts what the call does. The tokens
+        themselves stay on the device until ``_read``."""
+        overlapped = int(bool(self._flight))
+        n_rows = len(self._slot_tokens)
+        chunk_args = {"chunk_tokens": 0}
+        if ch is not None:
+            n_rows += ch.width
+            chunk_args = {"chunk_" + k: v
+                          for k, v in self._chunk_args(ch).items()}
+            self._ssm_args(1, len(ch.tokens))    # ``last_step``'s count
         with self.tracer.span(
                 "decode_step", cat="serving", batch=len(live),
-                **self._moe_args(len(self._slot_tokens) + ch.width),
+                overlapped=overlapped, **self._moe_args(n_rows),
                 **self._ssm_args(len(live), len(live)),
-                **{"chunk_" + k: v
-                   for k, v in self._chunk_args(ch).items()}) as span:
+                **chunk_args) as span:
             with self.tracer.span("engine_prep", cat="serving"):
                 self._reserve(live, repeat(1))
                 extra = self._attn_tile_args()
-                table = self.state.block_table(ch.desc)
-                fn, pre, post = self._chunk_program(ch, live, table)
-            t0 = 0
-            if self._trace_on:
+                if ch is not None:
+                    table = self.state.block_table(ch.desc)
+                    fn, pre, post = self._chunk_program(ch, live, table)
+                else:
+                    # slots hold canonical params: see ``_sampler``
+                    rows = any(self._slot_sp[d.slot] != _GREEDY
+                               for d in live)
+                    fn, pre = self._decode_fn(1, rows), self._slots(live)
+                    post = sp_arrays(self._slot_sp) if rows else ()
+            t0 = None
+            if ch is not None and self._trace_on:
                 self._req_compute_begin(ch.uid)  # first chunk ends queue-wait
                 t0 = time.monotonic_ns()
-            toks, self.cache = self._dispatch(fn, pre, seed, post)
-            with self.tracer.span("engine_wait", cat="serving"):
-                toks = np.asarray(toks)
-            t1 = time.monotonic_ns() if self._trace_on else 0
-            with self.tracer.span("engine_emit", cat="serving"):
-                first = self._chunk_landed(ch, table, int(toks[-1]))
-                out.update((u, [t]) for u, t in first.items())
-                if rec is not None:
+            toks, self.cache = self._dispatch(fn, pre, seed, post, prev=True)
+            kv = 0
+            for d in live:
+                d.seen_tokens += 1
+                self._slot_lens[d.slot] = d.seen_tokens
+                kv += d.seen_tokens
+            self.last_step.update(decode_seqs=len(live), kv_tokens=kv,
+                                  **extra)
+            span.set(kv_tokens=kv, **extra)
+            if ch is not None:
+                self._chunk_landed(ch, table)
+            self._launched(toks, live, ch, t0)
+        self.mixed_steps += ch is not None
+        self.overlapped_steps += overlapped
+
+    def _launched(self, toks, live, ch: Optional[_Chunk],
+                  t0: Optional[int]) -> None:
+        """A decode-shaped program is in flight: its result is what the
+        next one resolves its tokens from - the slots of ``live`` their own
+        entry, the slot a final chunk seated the last one; every other slot
+        the host's copy, which every launch but the newest has filled by
+        then (``launch`` leaves at most one program unread)."""
+        self._prev = toks
+        self._slot_src[:] = _FROM_HOST
+        self._slot_src[[d.slot for d in live]] = _FROM_SLOT
+        if ch is not None and ch.final:
+            self._slot_src[ch.desc.slot] = _FROM_CHUNK
+        self._flight.append(_Flight(toks, tuple(live), ch, t0))
+
+    def _read(self, fl: _Flight) -> None:
+        """The host's one sync on a launched program: its tokens land on
+        their sequences and in ``_out``, in the order the two programs of a
+        step had - the chunk's first token, then the decodes'. A sequence
+        retired since the launch (a split prefill cancelled by ``finish``
+        with a chunk of it in flight) is passed over."""
+        with self.tracer.span("engine_wait", cat="serving"):
+            toks = np.asarray(fl.toks)
+        t1 = time.monotonic_ns() if self._trace_on else 0
+        seqs = self.state.seqs
+        with self.tracer.span("engine_emit", cat="serving"):
+            ch = fl.chunk
+            if ch is not None:
+                final = ch.final and seqs.get(ch.uid) is ch.desc
+                if final:
+                    self._first_token(ch, int(toks[-1]))
+                rec = self._req.get(ch.uid)
+                if rec is not None and fl.t0 is not None:
                     # the request's lifecycle keeps its chunk: ring only
-                    # (the timeline has this call's one span), no rows on it
+                    # (the timeline has the call's one span), no rows on it
                     self.tracer.complete(
-                        "prefill_chunk", t0, t1, cat="serving",
+                        "prefill_chunk", fl.t0, t1, cat="serving",
                         trace=rec["trace"], parent=rec["span"].span_id,
                         table_blocks=self.state.max_blocks_per_seq,
                         **self._chunk_args(ch))
-                    if first:
-                        self._req_first_token(ch.uid, t1)
-                self._commit_ticks(live, toks[None, :-1], out, t1, span,
-                                   extra)
-        self.mixed_steps += 1
+                if final and self._trace_on:
+                    self._req_first_token(ch.uid, t1)
+            for d in fl.live:
+                if seqs.get(d.uid) is not d:
+                    continue
+                tok = int(toks[d.slot])
+                # the call wrote the KV of the token before (its id is
+                # recorded now, so a block it filled is matchable from here
+                # on); the new one is pending its write by the next call
+                d.tokens.append(d.last_token)
+                d.last_token = tok
+                d.generated.append(tok)
+                self._slot_tokens[d.slot] = tok
+                self.state.mark_filled(d)
+                self._out.setdefault(d.uid, []).append(tok)
+                if self._trace_on:
+                    self._req_tokens(d.uid, 1, t1)
+
+    def _drain(self) -> None:
+        """Read every program in flight: what needs a token's VALUE or
+        moves a sequence calls this first (``put``, ``park``, ``fork``, a
+        speculative step, ...). The tokens wait in ``_out`` for the next
+        ``collect``."""
+        while self._flight:
+            self._read(self._flight.popleft())
+
+    @property
+    def in_flight(self) -> int:
+        """Launched programs the host has not read."""
+        return len(self._flight)
+
+    def _flying(self) -> Iterator[int]:
+        """The uids the launched, unread programs hold a token for, one
+        entry a token."""
+        for fl in self._flight:
+            yield from (d.uid for d in fl.live)
+            if fl.chunk is not None and fl.chunk.final:
+                yield fl.chunk.uid
+
+    def tokens_uncollected(self) -> Dict[int, int]:
+        """{uid: tokens that launched programs hold for it, or that a drain
+        has read, and no ``collect`` has handed out}: a scheduler adds them
+        to a stream's length to know, at launch, which streams are at their
+        end."""
+        n = Counter({u: len(toks) for u, toks in self._out.items()})
+        n.update(self._flying())
+        return n
+
+    def forget_flight(self) -> None:
+        """Drop what was launched and not read, UNREAD: an abandoned
+        replica's device is asked for nothing (``ServingScheduler.
+        abandon_all``, which retires every sequence next). The tokens were
+        never handed out; whoever continues the streams samples them
+        again."""
+        self._flight.clear()
+        self._out.clear()
+        self._slot_src[:] = _FROM_HOST
+
+    def launch(self, seed: int = 0, hold=()) -> int:
+        """The first half of ``step()``: advance the oldest split prefill
+        by one chunk and dispatch one decode step over every live sequence
+        but those of ``hold`` (uids the caller knows to be at their end: a
+        count, not a value), WITHOUT reading a token - every live sequence
+        advances by one, so the next launch needs nothing of this one's
+        result but the tokens, and those it takes on the device. Returns
+        the number of programs it left for ``collect`` (0 or 1). At most
+        one program stays unread across a launch; a speculative step reads
+        history and so runs whole here (its tokens wait for ``collect``)."""
+        self.steps += 1
+        if self._spec_on:
+            self._drain()
+        while len(self._flight) > 1:
+            self._read(self._flight.popleft())
+        before = len(self._flight)
+        live, chunk = self._prefill_then_live(
+            seed, self.family.mixed_paged and not self._spec_on, hold)
+        if live and self._spec_on and self._spec_step(live, seed):
+            return 0
+        if chunk is not None:
+            self._launch_decode(live, seed, chunk)
+        elif live:
+            if self._spec_on:
+                # no sequence drafted this step: run the plain decode
+                # program — bit-identical to a non-spec step, and cheaper
+                # than a k+1-wide verify batch with one valid column
+                self.spec_stats["decode_steps"] += 1
+                self.spec_stats["step_seqs"] += len(live)
+                self.spec_stats["emitted_tokens"] += len(live)
+            self._launch_decode(live, seed)
+        return len(self._flight) - before
+
+    def collect(self, ahead: int = 0) -> Dict[int, List[int]]:
+        """The second half of ``step()``: read the programs in flight, all
+        but the newest ``ahead``, and hand out {uid: [tokens]} - theirs and
+        whatever a drain read since the last call, each sequence's in
+        order."""
+        while len(self._flight) > ahead:
+            self._read(self._flight.popleft())
+        out, self._out = self._out, {}
+        return out
 
     def step(self, sp: SamplingParams = SamplingParams(greedy=True),
              seed: int = 0) -> Dict[int, int]:
-        """One decode step over every live sequence → {uid: next_token}.
-        Split-admitted sequences advance one prefill chunk first; a sequence
-        whose prompt completes this step contributes its first token.
+        """One decode step over every live sequence → {uid: next_token}:
+        ``launch`` followed at once by ``collect``. Split-admitted sequences
+        advance one prefill chunk first; a sequence whose prompt completes
+        this step contributes its first token.
 
         Sampling uses each sequence's ADMISSION-time params (per-request
         sampling, like the reference v2 engine); the ``sp`` argument is
@@ -1388,30 +1623,12 @@ class InferenceEngineV2(InferenceEngine):
         the return type widens to {uid: [tokens]} — every value is a list,
         including prefill first-tokens and draft-less fallback steps."""
         self._warn_ignored_sp(sp)
-        self.steps += 1
-        first, live, chunk = self._prefill_then_live(
-            seed, mixed=self.family.mixed_paged and not self._spec_on)
-        out: Dict[int, List[int]] = {u: [t] for u, t in first.items()}
-        spec_out = self._spec_step(live, seed) if live and self._spec_on \
-            else None
-        if spec_out is not None:
-            out.update(spec_out)
-        elif chunk is not None:
-            self._decode_chunk(chunk, live, seed, out)
-        elif live:
-            if self._spec_on:
-                # no sequence drafted this step: run the plain decode
-                # program — bit-identical to a non-spec step, and cheaper
-                # than a k+1-wide verify batch with one valid column
-                self.spec_stats["decode_steps"] += 1
-                self.spec_stats["step_seqs"] += len(live)
-                self.spec_stats["emitted_tokens"] += len(live)
-            with self.tracer.span("decode_step", cat="serving",
-                                  batch=len(live), chunk_tokens=0,
-                                  **self._moe_args(len(self._slot_tokens)),
-                                  **self._ssm_args(len(live), len(live))
-                                  ) as span:
-                self._decode_ticks(1, live, seed, span, out, tiles=True)
+        if not self._spec_on and (self._flight or self._out):
+            raise RuntimeError(
+                "step() returns one token a sequence: collect() what "
+                "launch() left in flight first")
+        self.launch(seed)
+        out = self.collect()
         return out if self._spec_on else {u: s[0] for u, s in out.items()}
 
     def step_many(self, k: int, sp: SamplingParams = SamplingParams(greedy=True),
@@ -1429,8 +1646,8 @@ class InferenceEngineV2(InferenceEngine):
         number of tokens per call. ``generate`` picks ``step()`` when
         ``inference.speculative.enabled`` is set."""
         self._warn_ignored_sp(sp)
-        first, live, _ = self._prefill_then_live(seed)
-        out: Dict[int, List[int]] = {u: [t] for u, t in first.items()}
+        self._drain()
+        live, _ = self._prefill_then_live(seed)
         if live:
             # a tick at seen writes KV position seen, so seen may reach
             # exactly max_seq_len after the last tick — same boundary as the
@@ -1440,8 +1657,8 @@ class InferenceEngineV2(InferenceEngine):
         if live and k > 0:
             with self.tracer.span("decode_quantum", cat="serving", k=k,
                                   batch=len(live)) as span:
-                self._decode_ticks(k, live, seed, span, out)
-        return out
+                self._decode_quantum(k, live, seed, span)
+        return self.collect()
 
     def finish(self, uid: int) -> List[int]:
         """Retire a sequence, free its blocks, return generated tokens.
@@ -1450,6 +1667,8 @@ class InferenceEngineV2(InferenceEngine):
         the uid in the message (one consistent error, whichever internal
         structure would have missed first)."""
         desc = self.state.lookup(uid)
+        if uid in self._flying():
+            self._drain()   # its stream is whole before it is handed back
         self._req_finish(uid, generated=len(desc.generated))
         self._pending_prefill.pop(uid, None)  # cancel an in-flight split
         self._clear_slot(desc.slot)
@@ -1506,6 +1725,7 @@ class InferenceEngineV2(InferenceEngine):
         request's trace record stays open (park/resume is invisible to the
         client except as latency), and an instant marks the gap."""
         desc = self.state.lookup(uid)
+        self._drain()       # the history is every token, those in flight too
         self._pending_prefill.pop(uid, None)   # mid-split park: chunks stop
         history = list(desc.tokens) if desc.prefilling \
             else list(desc.tokens) + [desc.last_token]
@@ -1569,6 +1789,7 @@ class InferenceEngineV2(InferenceEngine):
                           "and the parent's recurrent state would have to "
                           "be copied into a slot of its own, which is not "
                           "written")
+        self._drain()       # the child starts from the parent's last token
         desc = self.state.fork(uid, new_uid)
         self._req_admit(new_uid, desc.seen_tokens)
         self._seat(desc, self.state.block_table(desc),
@@ -1592,6 +1813,7 @@ class InferenceEngineV2(InferenceEngine):
         full blocks first — the handoff planner keys the wire transfer
         (and the destination's dedup probe) on these."""
         desc = self.state.lookup(uid)
+        self._drain()       # a block a decode filled is hashed from its ids
         self.state.mark_filled(desc)
         return list(desc.block_hashes)
 
@@ -1633,6 +1855,7 @@ class InferenceEngineV2(InferenceEngine):
             raise ValueError(f"unknown KV wire format {wire!r}")
         self._refuse_call("export_kv_blocks", _HANDOFF)
         desc = self.state.lookup(uid)
+        self._drain()
         self.state.mark_filled(desc)
         hashes = list(desc.block_hashes)
         skip = max(0, min(int(skip), len(hashes)))
@@ -1820,13 +2043,17 @@ class InferenceEngineV2(InferenceEngine):
 
     def engine_events(self, step: int = 0):
         """``Serving/engine/*`` telemetry events (cumulative): ``steps``,
-        the ``step()`` calls, and ``mixed_steps``, those that ran their
-        prefill chunk and their decodes as one program (``decode_chunk``: a
-        pending chunk met live decodes in a family that takes a mixed
-        call)."""
-        return [("Serving/engine/steps", float(self.steps), step),
-                ("Serving/engine/mixed_steps", float(self.mixed_steps),
-                 step)]
+        the steps launched; ``mixed_steps``, those that ran their prefill
+        chunk and their decodes as one program (``decode_chunk``: a pending
+        chunk met live decodes in a family that takes a mixed call); and
+        ``overlapped_steps``, those whose decode program was launched while
+        the one before was still unread (a scheduler's ticks; 0 through
+        ``step()`` alone)."""
+        return [("Serving/engine/" + name, float(value), step)
+                for name, value in (
+                    ("steps", self.steps),
+                    ("mixed_steps", self.mixed_steps),
+                    ("overlapped_steps", self.overlapped_steps))]
 
     def publish_engine_telemetry(self, step: int = 0):
         return self._publish(self.engine_events(step))

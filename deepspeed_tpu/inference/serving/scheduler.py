@@ -29,10 +29,18 @@ admission, batch composition, preemption, and completion. Design points:
   token-identical to an uninterrupted run (pinned by tests).
 - **Streaming output.** Each submit returns a :class:`RequestHandle` whose
   ``drain()``/``on_token`` surface tokens as the engine emits them.
+- **One program in flight.** A tick LAUNCHES its step's program and only
+  then COLLECTS the program the tick before launched (``engine.launch`` /
+  ``engine.collect``), so admission, preparation, dispatch, harvest and
+  retirement run while the device works; a tick returns the tokens of the
+  program launched one tick earlier. A stream that ends by count gets no row
+  in the program launched past its end; whatever needs a token's value or
+  moves a sequence reads what is in flight first (``_drain``).
 
 The scheduler drives the engine exclusively through its public API (``put``,
-``put_split``, ``step``, ``step_many``, ``park``, ``resume``, ``finish``) —
-serving WITHOUT a scheduler runs the exact pre-scheduler engine code.
+``put_split``, ``launch``, ``collect``, ``step``, ``step_many``, ``park``,
+``resume``, ``finish``) — serving WITHOUT a scheduler runs the engine's
+synchronous ``step()``: the same programs, launched and collected at once.
 """
 
 from __future__ import annotations
@@ -179,6 +187,9 @@ class ServingScheduler:
         # a profiler session records on the span)
         self.last_tick: Dict[str, int] = {}
         self._admit_tokens = 0   # tokens the current tick's admissions streamed
+        # tokens a drain streamed since the last tick returned (``_drain``):
+        # they are in their handles already and count with the next tick
+        self._early: Dict[int, List[int]] = {}
         self._queue_wait_ms: List[float] = []
         self._e2e_ms: List[float] = []
         self._t0 = self._clock()
@@ -208,8 +219,10 @@ class ServingScheduler:
 
     @property
     def pending(self) -> bool:
-        """Work remains: anything queued, parked, or live."""
-        return bool(self._live) or self.queue_depth > 0
+        """Work remains: anything queued, parked, or live - or a program
+        in flight whose tokens no tick has returned yet."""
+        return bool(self._live) or self.queue_depth > 0 or \
+            self.engine.in_flight > 0
 
     def _push(self, handle: RequestHandle,
               parked: Optional[Dict[str, Any]] = None) -> None:
@@ -280,6 +293,7 @@ class ServingScheduler:
         handle objects keep streaming, and parked histories re-prefill on
         the new replica (KV never crosses engines; token history does)."""
         out: List[Tuple[RequestHandle, Optional[Dict[str, Any]]]] = []
+        self._drain()
         for uid, h in list(self._live.items()):
             parked = self.engine.park(uid)
             if h.request.trace_ctx is not None:
@@ -320,6 +334,7 @@ class ServingScheduler:
         here. Unlike :meth:`evict_all` this is the PLANNED move of the
         two-tier pipeline, not a preemption, so the handle's preemption
         count is untouched."""
+        self._drain()       # a first token in flight goes out with its handle
         h = self._live.pop(uid)
         parked = self.engine.park(uid)
         if h.request.trace_ctx is not None:
@@ -342,8 +357,12 @@ class ServingScheduler:
         token the handle emitted, so ``engine.resume`` on the survivor
         continues the stream without re-emitting any of them — and a greedy
         replay of prompt + emitted history regenerates exactly the next
-        stream token (token-identical failover, parity-pinned)."""
+        stream token (token-identical failover, parity-pinned). A program
+        the engine has in flight is NOT read (that would be asking a wedged
+        device for a sync): no client has seen its tokens, and the survivor
+        samples them again."""
         out: List[Tuple[RequestHandle, Optional[Dict[str, Any]]]] = []
+        self.engine.forget_flight()     # host bookkeeping only
         for uid, h in list(self._live.items()):
             del self._live[uid]
             self.handles.pop(uid, None)
@@ -406,9 +425,12 @@ class ServingScheduler:
     # -- the scheduling loop --------------------------------------------- #
     def tick(self, seed: Optional[int] = None) -> Dict[int, List[int]]:
         """One scheduler quantum: expire (optional) → admit/resume →
-        preempt-guard → one engine step (or fused ``decode_quantum``) →
-        stream tokens → retire completions. Returns {uid: tokens emitted
-        this tick} for the requests that produced output."""
+        preempt-guard → LAUNCH this tick's engine step → COLLECT the step
+        the tick before launched (a fused ``decode_quantum`` or a
+        speculative step runs whole) → stream tokens → retire completions.
+        Returns {uid: tokens emitted this tick} for the requests that
+        produced output: the collected program's, and what a drain streamed
+        since the last tick returned."""
         self.stats["ticks"] += 1
         if seed is None:
             seed = self.stats["ticks"]
@@ -428,7 +450,9 @@ class ServingScheduler:
             with span("sched_step_engine", cat="serving"):
                 out, did = self._step_engine(seed)
             with span("sched_harvest", cat="serving"):
-                emitted = self._harvest(out)
+                emitted, self._early = self._early, {}
+                for uid, toks in self._harvest(out).items():
+                    emitted.setdefault(uid, []).extend(toks)
             with span("sched_retire", cat="serving"):
                 self._retire()
             self.last_tick = {
@@ -448,7 +472,8 @@ class ServingScheduler:
         self.stats["chunk_ticks"] += self.last_tick["prefill_tokens"] > 0
         if self.tuning is not None:
             # sched-tick seam: the only point a serving knob may flip —
-            # between ticks no request is mid-admission or mid-harvest
+            # between ticks no request is mid-admission or mid-harvest, and
+            # (``_step_engine``) no program is in flight
             self.tuning.on_sched_tick(self)
         return emitted
 
@@ -599,8 +624,17 @@ class ServingScheduler:
             raise UnknownSequenceError(uid)
         self._park_to_queue(h)
 
+    def _drain(self) -> None:
+        """Before a sequence moves (a park, a hand-off, a replica's drain):
+        read what the engine has in flight and stream it, so the move loses
+        and doubles no token. The tokens are in their handles at once and
+        count with the tick that returns next."""
+        for uid, toks in self._harvest(self.engine.collect()).items():
+            self._early.setdefault(uid, []).extend(toks)
+
     def _park_to_queue(self, h: RequestHandle) -> None:
         uid = h.request.uid
+        self._drain()
         parked = self.engine.park(uid)
         del self._live[uid]
         h.state = PARKED
@@ -612,16 +646,45 @@ class ServingScheduler:
                                 kv_tokens=len(parked["history"]))
 
     def _step_engine(self, seed: int):
-        """One engine step (or fused quantum) → its tokens and what it did
-        (``engine.last_step``; zeros when there was nothing to step)."""
+        """Launch this tick's engine step, collect the one before → the
+        collected tokens and what the LAUNCHED step does
+        (``engine.last_step``; zeros when there was nothing to step). A
+        fused quantum and a speculative step read what is in flight and run
+        whole; with the tuner attached the tick collects its own program,
+        so that a knob flips with nothing in flight."""
         eng = self.engine
         if not eng.state.seqs:
-            return {}, {"decode_seqs": 0, "kv_tokens": 0}
+            return eng.collect(), {"decode_seqs": 0, "kv_tokens": 0}
         if self.cfg.decode_quantum > 1 and not eng._spec_on:
             out = eng.step_many(self.cfg.decode_quantum, seed=seed)
-        else:
+        elif eng._spec_on:
             out = eng.step(seed=seed)
+        else:
+            ahead = eng.launch(seed, hold=self._at_their_end())
+            out = eng.collect(0 if self.tuning is not None else ahead)
         return out, eng.last_step
+
+    def _at_their_end(self) -> set:
+        """The live streams that end by COUNT with the tokens already
+        launched for them (``max_new_tokens``, ``max_seq_len``): known at
+        launch, so the program launched now has no row for them. A stream
+        that may end on its ``eos_token_id`` keeps its row: should the
+        token in flight be the end, the one after it is dropped
+        (``RequestHandle._emit``) and its KV row goes with the sequence's
+        blocks."""
+        eng = self.engine
+        unread = eng.tokens_uncollected()
+        max_len = eng.family.cfg.max_seq_len
+        hold = set()
+        for uid, h in self._live.items():
+            d = eng.state.seqs.get(uid)
+            if d is None or d.prefilling:
+                continue
+            if h.finished_stream or d.seen_tokens >= max_len or \
+                    len(h.tokens) + unread.get(uid, 0) \
+                    >= h.request.max_new_tokens:
+                hold.add(uid)
+        return hold
 
     def _harvest(self, out) -> Dict[int, List[int]]:
         emitted: Dict[int, List[int]] = {}
@@ -638,13 +701,20 @@ class ServingScheduler:
 
     def _retire(self) -> None:
         max_len = self.engine.family.cfg.max_seq_len
+        # a ``finish`` below may read the program in flight: its tokens
+        # move to the next collect and stay counted
+        unread = self.engine.tokens_uncollected()
         for uid, h in list(self._live.items()):
             d = self.engine.state.seqs.get(uid)
             if d is None:
                 continue
             if d.prefilling:
                 continue
-            if h.finished_stream or d.seen_tokens >= max_len:
+            # a stream at the end of its context waits for its last token;
+            # one that has ENDED (a count, its eos) goes now, and a token
+            # still in flight for it is read by ``finish`` and dropped
+            if h.finished_stream or (
+                    d.seen_tokens >= max_len and uid not in unread):
                 self.engine.finish(uid)
                 del self._live[uid]
                 self.handles.pop(uid, None)

@@ -86,9 +86,11 @@ SERVING_SERIES = frozenset(
     # recurrent state (a family with state-space layers; docs/serving.md
     # "Recurrent state" - engine_v2.state_events)
     + ["Serving/state/" + m for m in ("bytes", "bytes_per_slot", "slots_held")]
-    # what step() ran (engine_v2.engine_events): its calls, and those whose
-    # prefill chunk rode in the decode program (``decode_chunk``)
-    + ["Serving/engine/" + m for m in ("steps", "mixed_steps")]
+    # what step() ran (engine_v2.engine_events): its calls, those whose
+    # prefill chunk rode in the decode program (``decode_chunk``), and those
+    # launched while the program before was still unread
+    + ["Serving/engine/" + m for m in (
+        "steps", "mixed_steps", "overlapped_steps")]
     + ["Serving/spec/" + m for m in (
         "verify_steps", "decode_steps", "step_seqs", "drafted_tokens",
         "accepted_tokens", "emitted_tokens", "rolled_back_tokens",

@@ -63,9 +63,16 @@ def test_chip_smoke_train_phase_tiny(capsys):
 def test_chip_smoke_serve_phase_tiny(capsys):
     chip_smoke, cfg, _, serve = _tiny()
     chip_smoke.phase_serve(serve, seed=0, mosaic=False, cfg=cfg)
-    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    row, overlap = map(json.loads,
+                       capsys.readouterr().out.strip().splitlines()[-2:])
     assert row["phase"] == "serve" and row["requests"] == 6
     assert len(row["logit_checks"]) == 2
+    # overlapped ticks against synchronous steps: sampled rows, an eos
+    # ending, a preemption with a token in flight, fused quanta
+    assert overlap["phase"] == "serve_overlap"
+    assert overlap["streams_equal"] == overlap["requests"] == 6
+    assert overlap["eos_stream_tokens"] < 6 and overlap["preempted"] == 1
+    assert overlap["overlapped_steps"] > 0
 
 
 @pytest.mark.slow

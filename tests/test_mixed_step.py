@@ -178,7 +178,8 @@ def test_a_mixed_step_serves_what_the_two_programs_serve(family):
     assert [k for k in one._paged_fns if k[0].startswith("decode_chunk")] \
         == [("decode_chunk", CHUNK)]        # ONE program, mid and final
     assert dict((n, v) for n, v, _ in one.engine_events()) == {
-        "Serving/engine/steps": 5.0, "Serving/engine/mixed_steps": 3.0}
+        "Serving/engine/steps": 5.0, "Serving/engine/mixed_steps": 3.0,
+        "Serving/engine/overlapped_steps": 0.0}
     for uid in (1, 2, 3):
         assert one.finish(uid) == two.finish(uid)
 

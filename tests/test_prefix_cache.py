@@ -337,6 +337,7 @@ def test_cow_partial_shared_block_mid_decode(tiny):
     oc.step(SP)                                 # writes f0's KV, samples f1
     oc.state.seqs[12].last_token = inj          # replay the injection
     oc._slot_tokens[oc.state.seqs[12].slot] = inj
+    oc._slot_src[oc.state.seqs[12].slot] = 0    # the host's copy stands
     assert oc.step(SP)[12] == out[2]
     assert oc.step(SP)[12] == nxt[2]
 

@@ -14,9 +14,10 @@ of the commit before it (72e45a1) served greedily. ``str(jaxpr)`` carries no
 scope names, so the ``kv_write`` scope that ``scan_layers`` gives gpt, falcon
 and exaone4 does not show here; an operation added or moved does.
 
-The last test counts what ONE ``step()`` does on the host before its
-program runs (uploads and keys made inside ``engine_v2``): the dispatch
-path's cost is the serve cells' ``serve_idle_dispatch_share``.
+The last tests count what ONE ``step()`` does on the host before its
+program runs (uploads and keys made inside ``engine_v2``: the dispatch
+path's cost is the serve cells' ``serve_idle_dispatch_share``), and what a
+``launch()`` reads of the device: nothing (ISSUE 35, one program in flight).
 """
 
 import hashlib
@@ -81,7 +82,9 @@ def _args(eng):
     key = s((2,), jnp.uint32)
     prefill = head + (s((2, PAD_T), i32), s((2,), i32), s((2, width), i32))
     slots = (s((SLOTS,), i32), s((SLOTS, width), i32), s((SLOTS,), bool))
-    decode = head + (s((SLOTS,), i32),) + slots + (key,)
+    # the newest launched result [slots + 1] (a device array: one program in
+    # flight, ISSUE 35), then the seat row and the slots
+    decode = head + (s((SLOTS + 1,), i32), s((SLOTS,), i32)) + slots + (key,)
     return {
         "prefill": prefill + (key, s((2,), i32)),
         "prefill_ctx": prefill + (s((2,), i32), key, s((2,), i32)),
@@ -164,22 +167,26 @@ def paged_text(family: str, t: int) -> str:
 
 
 # ISSUE 29's: taken on its finished tree under this directory's conftest (the
-# lines before it were f552895's); the three ``decode_chunk`` lines are ISSUE
-# 32's, which added the program. A PR that means to change one of these
-# programs replaces its line.
+# lines before it were f552895's); the three ``decode_chunk`` lines were ISSUE
+# 32's, which added the program. ISSUE 35 replaced the eight ``decode*`` lines
+# on purpose: those programs take the newest launched token result and build
+# their slots' token row from it on the device (``engine_v2._own_tokens``), and
+# ``decode`` returns its tokens in that result's one shape, ``[slots + 1]``;
+# what they serve is held by ``PARENT_TOKENS`` below. A PR that means to
+# change one of these programs replaces its line.
 PARENT_HASHES = {
     "chunk_prefill.final_greedy": "2fba01067a00967c",
     "chunk_prefill.final_stochastic": "ac75ddded01ea6b7",
     "chunk_prefill.mid": "a4afc6de0baf7628",
     "chunk_prefill.mid.int8": "acc809c779ddf322",
-    "decode.greedy": "0a6f5a9965fc0637",
-    "decode_chunk.greedy": "c8d8932dbd014a34",
-    "decode_chunk.greedy.int8": "abf75ec465c9de69",
-    "decode_chunk.rows": "23136133943497b0",
-    "decode.greedy.int8": "d097770f52fa7f7a",
-    "decode.rows": "df2f47272cb02bc6",
-    "decode_many.greedy": "f0fd8799d66418a7",
-    "decode_many.rows": "9fda1d7c518a3300",
+    "decode.greedy": "98acf8881fef6b9a",
+    "decode_chunk.greedy": "fa69d0ec059ccd3f",
+    "decode_chunk.greedy.int8": "e690a4d521e4c0e8",
+    "decode_chunk.rows": "f59bcbb7d32d62b1",
+    "decode.greedy.int8": "a979f095099b879b",
+    "decode.rows": "741da01e1d860528",
+    "decode_many.greedy": "db20d44910b2f3f1",
+    "decode_many.rows": "db38afbd2bb603d5",
     "exaone4.apply_paged.t1": "9052b4becd0e33b1",
     "exaone4.apply_paged.t8": "b6ec6f09c8e127b0",
     "falcon.apply_paged.t1": "48f438229bd392fb",
@@ -292,25 +299,35 @@ class _Counted:
         return counted
 
 
-# of the parent (f552895): the slot arrays, a key, and in rows mode the four
-# sampling arrays. ``mixed`` (ISSUE 32): a step whose chunk rides in its
-# decode program uploads the slot arrays, the chunk's four and the uid with
-# ONE key, where the two programs uploaded 5 + 4 with two. Queue A2 replaces
-# these lines on purpose.
-PARENT_HOST_OPS = {"greedy": {"asarray": 4, "PRNGKey": 1},
-                   "rows": {"asarray": 8, "PRNGKey": 1},
-                   "mixed": {"asarray": 9, "PRNGKey": 1}}
+# What a step uploads and the keys it makes, since ISSUE 35 (one program in
+# flight). The slots' LAST TOKENS no longer go up: they stay on the device, in
+# the result of the program launched before, which goes into the next program
+# as the device array it is. What goes up in their place is the seat row - the
+# host's value where the host seated a slot since (a one-shot ``put``, a
+# ``resume``), else a code that says where in that result the token lies -, so
+# the count is the parent's (f552895 / ISSUE 32): the four slot arrays, a key,
+# in rows mode the four sampling arrays; ``mixed``: the slot arrays, the
+# chunk's four and the uid with ONE key. Shaving these is what ROADMAP Queue A1
+# has left, for the ticks that cannot overlap.
+STEP_HOST_OPS = {"greedy": {"asarray": 4, "PRNGKey": 1},
+                 "rows": {"asarray": 8, "PRNGKey": 1},
+                 "mixed": {"asarray": 9, "PRNGKey": 1}}
 
 
-@pytest.mark.parametrize("mode", sorted(PARENT_HOST_OPS))
-def test_a_step_uploads_and_makes_keys_as_the_parent_did(monkeypatch, mode):
+def _warmed(mode):
     eng = _engine()
     sp = STOCHASTIC if mode == "rows" else SamplingParams(greedy=True)
     eng.put(1, list(range(5)), sp)
     eng.put(2, list(range(7)))
     if mode == "mixed":
-        eng.put_split(3, list(range(21)))        # three chunks of 8
+        eng.put_split(3, list(range(29)))        # four chunks of 8
     eng.step()                                   # warm: the program exists
+    return eng
+
+
+@pytest.mark.parametrize("mode", sorted(STEP_HOST_OPS))
+def test_a_step_uploads_and_makes_keys_as_the_table_says(monkeypatch, mode):
+    eng = _warmed(mode)
     counts = {"asarray": 0, "PRNGKey": 0}
     monkeypatch.setattr(engine_mod, "jnp",
                         _Counted(jnp, ("asarray",), counts))
@@ -318,5 +335,50 @@ def test_a_step_uploads_and_makes_keys_as_the_parent_did(monkeypatch, mode):
                         _Counted(jax, ("random", "PRNGKey"), counts))
     out = eng.step(seed=1)
     assert sorted(out) == [1, 2]
-    assert counts == PARENT_HOST_OPS[mode]
+    assert counts == STEP_HOST_OPS[mode]
     assert eng.mixed_steps == (2 if mode == "mixed" else 0)
+    assert eng.overlapped_steps == 0             # step() collects at once
+
+
+@pytest.mark.parametrize("mode", sorted(STEP_HOST_OPS))
+def test_a_launch_reads_nothing_of_the_device(monkeypatch, mode):
+    """Between a launch and the next launch the host reads no program
+    result (``np.asarray`` inside ``engine_v2`` is the one way it does)
+    unless something asked for a token's value: ``collect`` reads ONE
+    program, the one launched before the newest; a ``park`` reads what is in
+    flight first. The uploads stay the table's."""
+    import numpy as np
+
+    eng = _warmed(mode)
+    counts = {"asarray": 0, "PRNGKey": 0}
+    reads = {"asarray": 0}
+    monkeypatch.setattr(engine_mod, "jnp",
+                        _Counted(jnp, ("asarray",), counts))
+    monkeypatch.setattr(engine_mod, "jax",
+                        _Counted(jax, ("random", "PRNGKey"), counts))
+    monkeypatch.setattr(engine_mod, "np", _Counted(np, ("asarray",), reads))
+    got = {}
+    assert eng.launch(seed=1) == 1 and reads["asarray"] == 0
+    for seed in (2, 3):
+        assert eng.launch(seed=seed) == 1        # the program before unread
+        assert reads["asarray"] == seed - 2 and eng.in_flight == 2
+        for uid, toks in eng.collect(ahead=1).items():
+            got.setdefault(uid, []).extend(toks)
+        assert reads["asarray"] == seed - 1 and eng.in_flight == 1
+    assert counts == {k: 3 * v for k, v in STEP_HOST_OPS[mode].items()}
+    assert eng.overlapped_steps == 2
+    assert eng.tokens_uncollected() == dict.fromkeys(
+        [1, 2] + [3] * (mode == "mixed"), 1)     # 3's first token: in flight
+    parked = eng.park(1)                         # needs the values: drains
+    assert reads["asarray"] == 3 and eng.in_flight == 0
+    for uid, toks in eng.collect().items():
+        got.setdefault(uid, []).extend(toks)
+    # every token once, in order: what three synchronous steps serve
+    ref = _warmed(mode)
+    want = {}
+    for seed in (1, 2, 3):
+        for uid, tok in ref.step(seed=seed).items():
+            want.setdefault(uid, []).append(tok)
+    assert got == want
+    assert parked["generated"] == ref.state.seqs[1].generated
+    assert eng.state.seqs[2].generated == ref.state.seqs[2].generated

@@ -592,3 +592,354 @@ def test_telemetry_report_serving_sched_and_router(tmp_path):
     assert "router report" in out.stdout
     assert "prefix-affinity hits:   8  (40.0% of placements)" in out.stdout
     assert "drains:                 1" in out.stdout
+
+
+# --------------------------------------------------------------------------- #
+# one program in flight (ISSUE 35): a tick launches program n+1 before it
+# reads program n's tokens; every token once, in order, at most a tick later
+# --------------------------------------------------------------------------- #
+STOCHASTIC = SamplingParams(temperature=0.7, top_k=5, top_p=0.9)
+OVERLAP_FAMILIES = {
+    "llama": lambda: (llama, llama.LlamaConfig.tiny(max_seq_len=64)),
+    "mixtral": lambda: _family("mixtral", "MixtralConfig"),
+    "granite_hybrid": lambda: _family("granite_hybrid",
+                                      "GraniteHybridConfig"),
+}
+
+
+def _family(name, config):
+    import importlib
+
+    module = importlib.import_module(f"deepspeed_tpu.models.{name}")
+    return module, getattr(module, config).tiny(max_seq_len=64)
+
+
+def _overlap_engine(family, **extra):
+    """Four slots, chunks of 8: prompts over 8 tokens are split."""
+    module, cfg = OVERLAP_FAMILIES[family]()
+    mesh_lib.set_mesh(None)
+    eng = build_engine_v2(
+        module, cfg, module.init(cfg, jax.random.PRNGKey(0)),
+        config=dict({"dtype": "float32", "prefill_bucket": 8,
+                     "split_prefill_chunk": 8,
+                     "ragged": {"max_tracked_sequences": 4,
+                                "max_ragged_batch_size": 4,
+                                "memory_config_blocks": 96,
+                                "block_size": 4}}, **extra))
+    assert eng.family.mixed_paged
+    return eng
+
+
+def _synchronous(sched):
+    """The scheduler as it was before ISSUE 35: a tick runs ``engine.step()``
+    - launch, then collect at once - and returns its own program's tokens."""
+    def step_engine(seed):
+        eng = sched.engine
+        if not eng.state.seqs:
+            return {}, {"decode_seqs": 0, "kv_tokens": 0}
+        return eng.step(seed=seed), eng.last_step
+
+    sched._step_engine = step_engine
+    return sched
+
+
+def _overlap_requests(vocab):
+    """Split prompts (21, 30 tokens: three and four chunks), one-shot
+    prompts (5, 7), a stochastic row of each kind, answers of 1 to 9 tokens
+    - as many requests as slots, so none waits for an end to be noticed and
+    a stochastic stream meets the same seeds in both runs -, then a greedy
+    one-shot prompt that waits for a slot."""
+    rng = np.random.default_rng(35)
+    mk = lambda n, m, sp=SP: Request(                       # noqa: E731
+        prompt=rng.integers(1, vocab, (n,)).tolist(), max_new_tokens=m, sp=sp)
+    return [mk(21, 6), mk(5, 9, STOCHASTIC), mk(30, 3, STOCHASTIC), mk(7, 1),
+            mk(6, 4)]
+
+
+def _run_streams(sched, reqs):
+    """Submit, tick until done → (handles, what ``on_token`` saw, what the
+    ticks returned, each tick's ``last_tick``)."""
+    seen = {}
+    handles = [sched.submit(r, on_token=seen.setdefault(i, []).append)
+               for i, r in enumerate(reqs)]
+    returned, ticks = {h.uid: [] for h in handles}, []
+    while sched.pending:
+        assert len(ticks) < 200
+        for uid, toks in sched.tick().items():
+            returned[uid].extend(toks)
+        ticks.append(dict(sched.last_tick))
+    # a tick returns what the engine's steps produced; a one-shot prompt's
+    # first token went to its handle at admission
+    for h in handles:
+        if len(h.request.prompt) <= 8:
+            returned[h.uid].insert(0, h.tokens[0])
+    return handles, seen, returned, ticks
+
+
+@pytest.mark.parametrize("family", sorted(OVERLAP_FAMILIES))
+def test_overlapped_ticks_stream_what_synchronous_steps_stream(family):
+    """(a) Split prompts, one-shot prompts and stochastic rows through the
+    scheduler's launch-then-collect ticks give, request for request, the
+    streams the same requests give through synchronous ``engine.step()``
+    calls with the same seeds: token for token, exactly ``max_new_tokens``,
+    every token once and in order through ``on_token`` and the ticks'
+    returns. A final chunk's first token is seated from the device, and the
+    counter says how often a launch found the program before unread."""
+    vocab = OVERLAP_FAMILIES[family]()[1].vocab_size
+    ref_eng = _overlap_engine(family)
+    want, *_ = _run_streams(
+        _synchronous(ServingScheduler(ref_eng, SchedulerConfig())),
+        _overlap_requests(vocab))
+    assert ref_eng.overlapped_steps == 0        # (e) 0 through step() alone
+
+    eng = _overlap_engine(family)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    from_chunk = []
+    launch = eng.launch
+
+    def spy(*args, **kwargs):
+        n = launch(*args, **kwargs)
+        from_chunk.append(int((eng._slot_src == -2).sum()))
+        return n
+
+    eng.launch = spy
+    handles, seen, returned, ticks = _run_streams(sched,
+                                                  _overlap_requests(vocab))
+    for i, (h, w) in enumerate(zip(handles, want)):
+        assert h.state == DONE and w.state == DONE
+        assert h.tokens == w.tokens, (i, h.tokens, w.tokens)
+        assert len(h.tokens) == h.request.max_new_tokens
+        assert seen[i] == returned[h.uid] == h.tokens
+    assert sum(from_chunk) == 2             # both split prompts' first tokens
+    assert eng.in_flight == 0 and not eng.tokens_uncollected()
+    assert not eng.state.seqs
+    eng.state.debug_check()
+    eng.debug_check_cache()
+    # a tick's counts are the program it LAUNCHED: the rows add up to the
+    # tokens that were decoded, and no row was launched past a count's end
+    assert sum(t["decode_seqs"] for t in ticks) \
+        == sum(r.max_new_tokens - 1 for r in _overlap_requests(vocab))
+    assert 0 < eng.overlapped_steps < eng.steps
+    events = dict((n, v) for n, v, _ in eng.engine_events())
+    assert events["Serving/engine/overlapped_steps"] == eng.overlapped_steps
+    assert validate_events(eng.engine_events()) == []
+
+
+@pytest.mark.parametrize("family", sorted(OVERLAP_FAMILIES))
+def test_overlapped_steps_counts_launches_over_an_unread_program(family):
+    """(e) One one-shot prompt, admitted in the first tick, then split
+    prompts alone: the first launch finds nothing in flight, every later
+    ``decode_step`` launch finds the one before (``put_split`` reads
+    nothing), and the last tick only collects."""
+    vocab = OVERLAP_FAMILIES[family]()[1].vocab_size
+    eng = _overlap_engine(family)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    reqs = _overlap_requests(vocab)
+    _, _, _, ticks = _run_streams(sched, [reqs[1], reqs[0], reqs[2]])
+    decode_ticks = sum(t["decode_seqs"] > 0 for t in ticks)
+    assert eng.overlapped_steps == decode_ticks - 1 > 0
+    assert ticks[-1]["decode_seqs"] == 0 and eng.in_flight == 0
+
+
+@pytest.mark.parametrize("family", sorted(OVERLAP_FAMILIES))
+def test_an_eos_stream_ends_at_its_token_and_frees_the_dropped_row(family):
+    """(b) A stream that ends on its ``eos_token_id`` mid-stream had a row
+    in the program launched before the end was read: the stream stops AT the
+    end token, the row's token is dropped, its block goes back with the
+    sequence's, and the streams beside it are what they are without it."""
+    vocab = OVERLAP_FAMILIES[family]()[1].vocab_size
+    rng = np.random.default_rng(36)
+    prompts = [rng.integers(1, vocab, (n,)).tolist() for n in (21, 6, 11)]
+    # the stream that ends is sampled hot, so that its tokens differ (a tiny
+    # random model repeats itself greedily); the same seeds meet it in both
+    # runs up to its end, and the streams beside it are greedy
+    hot = SamplingParams(temperature=1.5)
+    mk = lambda eos=None: [Request(prompt=p, max_new_tokens=12,   # noqa: E731
+                                   eos_token_id=eos if i == 1 else None,
+                                   sp=hot if i == 1 else SP)
+                           for i, p in enumerate(prompts)]
+    full, *_ = _run_streams(
+        ServingScheduler(_overlap_engine(family), SchedulerConfig()), mk())
+    stream = full[1].tokens
+    # an end token that first shows mid-stream
+    at = next(i for i in range(2, 11) if stream[i] not in stream[:i])
+    eng = _overlap_engine(family)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    handles, seen, returned, _ = _run_streams(sched, mk(stream[at]))
+    assert handles[1].tokens == stream[:at + 1] == seen[1] \
+        == returned[handles[1].uid]
+    assert [h.tokens for h in (handles[0], handles[2])] \
+        == [full[0].tokens, full[2].tokens]
+    assert eng.overlapped_steps > 0
+    assert sched.stats["tokens_emitted"] + 1 == sum(   # one one-shot first
+        len(h.tokens) for h in handles)
+    assert not eng.state.seqs and eng.in_flight == 0
+    assert eng.state.allocator.free_blocks == 95    # every block is back
+    eng.state.debug_check()
+    eng.debug_check_cache()
+
+
+def test_moving_a_sequence_reads_what_is_in_flight_first(tiny):
+    """(c) ``preempt`` (a park), ``evict_all`` and ``fork`` with a program
+    in flight read it first: no token is lost with the move and none comes
+    twice - the streams are the undisturbed run's."""
+    cfg, _ = tiny
+    reqs = lambda: _mk_requests(cfg, 3, gen_len=10)         # noqa: E731
+    want = ServingScheduler(build(tiny, blocks=96), SchedulerConfig())
+    want_handles, *_ = _run_streams(want, reqs())
+
+    # preempt: park with a token in flight, resume, finish
+    eng = build(tiny, blocks=96)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    seen = {}
+    handles = [sched.submit(r, on_token=seen.setdefault(i, []).append)
+               for i, r in enumerate(reqs())]
+    for _ in range(3):
+        sched.tick()
+    assert eng.in_flight == 1
+    had = len(handles[0].tokens)
+    sched.preempt(handles[0].uid)
+    assert eng.in_flight == 0
+    assert len(handles[0].tokens) == had + 1    # streamed before the park
+    parked = next(e["parked"] for *_, e in sched._heap if e["valid"])
+    assert parked["generated"] == handles[0].tokens
+    returned = {}
+    while sched.pending:
+        for uid, toks in sched.tick().items():
+            returned.setdefault(uid, []).extend(toks)
+    for i, (h, w) in enumerate(zip(handles, want_handles)):
+        assert h.tokens == w.tokens == seen[i]
+        # what the ticks returned after the park: the rest, the drained
+        # token first (it counted with the tick that returned next)
+        assert h.tokens[-len(returned[h.uid]):] == returned[h.uid]
+    eng.state.debug_check()
+
+    # evict_all: the replica's drain re-homes whole streams
+    eng = build(tiny, blocks=96)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    handles = [sched.submit(r) for r in reqs()]
+    for _ in range(4):
+        sched.tick()
+    assert eng.in_flight == 1
+    moved = sched.evict_all()
+    assert eng.in_flight == 0 and not eng.state.seqs
+    for h, parked in moved:
+        assert parked["generated"] == h.tokens
+        assert parked["history"] == h.request.prompt + h.tokens
+    other = ServingScheduler(build(tiny, blocks=96), SchedulerConfig())
+    for h, parked in moved:
+        other.accept(h, parked)
+    other.run()
+    assert [h.tokens for h in handles] == [w.tokens for w in want_handles]
+
+    # fork: the child starts from the parent's newest token
+    eng = build(tiny, blocks=96)
+    eng.put(1, reqs()[0].prompt, SP)
+    assert eng.launch(seed=1) == 1
+    child = eng.fork(1, 2)
+    assert eng.in_flight == 0
+    assert child.last_token == eng.state.seqs[1].last_token \
+        == eng.collect()[1][0] == want_handles[0].tokens[1]
+    assert eng.step(seed=2)[2] == want_handles[0].tokens[2]
+
+
+@pytest.mark.parametrize("family", sorted(OVERLAP_FAMILIES))
+def test_a_quantum_of_one_tick_is_the_single_step(family):
+    """``step_many`` clamps its quantum to what the longest live sequence
+    has left of its context, so a quantum of ONE tick is an everyday call
+    (and ``step_many(1)`` / ``generate(steps_per_sync=2, max_new_tokens=1)``
+    ask for it outright): it runs the single step's program, whose result
+    has that program's shape, and gives ``step()``'s tokens; the engine
+    lives on after it."""
+    vocab = OVERLAP_FAMILIES[family]()[1].vocab_size
+    rng = np.random.default_rng(37)
+    prompts = [rng.integers(1, vocab, (n,)).tolist() for n in (5, 21, 7)]
+    eng, ref = _overlap_engine(family), _overlap_engine(family)
+    for e in (eng, ref):
+        e.put(1, prompts[0], SP)
+        e.put_split(2, prompts[1], STOCHASTIC)
+        e.put(3, prompts[2], STOCHASTIC)
+    for seed in range(1, 7):
+        want = ref.step(seed=seed)
+        assert eng.step_many(1, seed=seed) == {u: [t]
+                                               for u, t in want.items()}
+    assert eng.step(seed=9) == ref.step(seed=9)
+    assert eng.generate([prompts[0]], max_new_tokens=1, steps_per_sync=2) \
+        == ref.generate([prompts[0]], max_new_tokens=1)
+    eng.state.debug_check()
+    eng.debug_check_cache()
+
+
+@pytest.mark.parametrize("family", sorted(OVERLAP_FAMILIES))
+def test_fused_quanta_run_to_the_context_end_as_single_steps_do(family):
+    """A scheduler with ``decode_quantum=4`` whose streams run into the end
+    of their context (64 tokens here): the quantum shrinks to 3, 2 and at
+    last ONE tick as the longest stream nears it, and every greedy stream is
+    token for token what synchronous single steps give."""
+    vocab = OVERLAP_FAMILIES[family]()[1].vocab_size
+    rng = np.random.default_rng(38)
+    mk = lambda: [Request(prompt=rng_p, max_new_tokens=m)   # noqa: E731
+                  for rng_p, m in zip(prompts, (100, 100, 9, 100))]
+    prompts = [rng.integers(1, vocab, (n,)).tolist() for n in (23, 6, 5, 31)]
+    want, *_ = _run_streams(
+        _synchronous(ServingScheduler(_overlap_engine(family),
+                                      SchedulerConfig())), mk())
+    eng = _overlap_engine(family)
+    ks = []
+    quantum = eng._decode_quantum
+    eng._decode_quantum = lambda k, *a: (ks.append(k), quantum(k, *a))[1]
+    handles, seen, returned, _ = _run_streams(
+        ServingScheduler(eng, SchedulerConfig(decode_quantum=4)), mk())
+    assert {1, 4} <= set(ks)
+    for i, (h, w) in enumerate(zip(handles, want)):
+        assert h.state == DONE and w.state == DONE
+        assert h.tokens == w.tokens == seen[i] == returned[h.uid], i
+    # the context's end, not the budget, ended the three long streams
+    assert [len(h.request.prompt) + len(h.tokens) for h in handles] \
+        == [65, 65, 14, 65]
+    assert eng.overlapped_steps == 0 and eng.in_flight == 0
+    assert not eng.state.seqs
+    eng.state.debug_check()
+    eng.debug_check_cache()
+
+
+def test_abandon_all_asks_the_device_for_nothing(tiny, monkeypatch):
+    """A failed replica's program in flight is dropped UNREAD (a wedged
+    device would never answer the sync): its tokens had reached no client,
+    so the streams continue on a survivor from what their handles hold,
+    token for token the undisturbed run's, each token once."""
+    cfg, _ = tiny
+    reqs = lambda: _mk_requests(cfg, 3, gen_len=10)         # noqa: E731
+    want, *_ = _run_streams(
+        ServingScheduler(build(tiny, blocks=96), SchedulerConfig()), reqs())
+    eng = build(tiny, blocks=96)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    seen = {}
+    handles = [sched.submit(r, on_token=seen.setdefault(i, []).append)
+               for i, r in enumerate(reqs())]
+    for _ in range(4):
+        sched.tick()
+    assert eng.in_flight == 1
+    had = [list(h.tokens) for h in handles]
+
+    def wedged(fl):
+        raise AssertionError("abandon_all read the program in flight")
+
+    monkeypatch.setattr(eng, "_read", wedged)
+    moved = sched.abandon_all()
+    assert eng.in_flight == 0 and not eng.tokens_uncollected()
+    assert not eng.state.seqs and not sched.pending
+    assert [h.tokens for h in handles] == had
+    for h, parked in moved:
+        assert parked["generated"] == h.tokens
+    eng.state.debug_check()
+    other = ServingScheduler(build(tiny, blocks=96), SchedulerConfig())
+    for h, parked in moved:
+        other.accept(h, parked)
+    other.run()
+    for i, (h, w) in enumerate(zip(handles, want)):
+        assert h.tokens == w.tokens == seen[i]
+    # the abandoned engine starts over clean (its breaker may re-admit it)
+    monkeypatch.undo()
+    again, *_ = _run_streams(sched, reqs())
+    assert [h.tokens for h in again] == [w.tokens for w in want]

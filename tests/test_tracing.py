@@ -564,7 +564,9 @@ TICK_STATS = {"tick", "admitted", "preempted", "live", "queued",
 def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     """Ring OFF, profiler session ON: one ``dstpu:sched_tick`` per tick with
     its phases inside it in order and the tick's counts as stats; the counts
-    add up to the work that was submitted."""
+    add up to the work that was submitted. One program in flight (ISSUE 35):
+    a tick's counts describe the program it LAUNCHED, whose tokens the tick
+    after returns."""
     from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
                                                  ServingScheduler)
 
@@ -591,11 +593,14 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
                for h, (_, m) in zip(handles, sizes))
     spans = prof.named("sched_tick")
     assert len(spans) == len(ticks) == sched.stats["ticks"]
+    launched = 0     # decode rows of the program the tick before launched
     for span, (last, decoded) in zip(spans, ticks):
         assert [c[0] for c in prof.children(span)] == SCHED_CHILDREN
         stats = {k: int(v) for k, v in span[3].items() if k in TICK_STATS}
         assert stats == last and set(stats) == TICK_STATS
-        assert last["decode_seqs"] == decoded
+        assert decoded == launched
+        launched = last["decode_seqs"]
+    assert launched == 0            # the last tick left nothing in flight
     prompt_tokens = sum(n for n, _ in sizes)
     assert sum(t["prefill_tokens"] for t, _ in ticks) == prompt_tokens \
         == sched.stats["prefill_tokens"] == eng.prefill_tokens_written
@@ -604,10 +609,12 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     assert sched.stats["chunk_ticks"] \
         == sum(t["prefill_tokens"] > 0 for t, _ in ticks)
     assert sum(t["tokens_out"] for t, _ in ticks) == sum(m for _, m in sizes)
-    # under the engine step: each dispatch with its host phases, in order;
-    # a chunk that does not end its prompt has no engine_wait
-    # (a chunk beside live decodes rides in their ``decode_step``: one
-    # program, one span, the chunk's facts as ``chunk_*``)
+    # under the engine step: each LAUNCH with its host phases, in order (a
+    # chunk beside live decodes rides in their ``decode_step``: one program,
+    # one span, the chunk's facts as ``chunk_*``); the host's one sync on a
+    # launched program, ``engine_wait`` then ``engine_emit``, lies where it
+    # was read - under ``sched_step_engine`` after the next launch, or under
+    # ``sched_admit`` where a one-shot prefill read it first
     chunks = prof.named("prefill_chunk")
     decodes = prof.named("decode_step")
     mixed = [d for d in decodes if int(d[3]["chunk_tokens"])]
@@ -615,20 +622,29 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     assert sum(int(c[3]["tokens"]) for c in chunks) \
         + sum(int(d[3]["chunk_tokens"]) for d in mixed) \
         == sum(n for n, _ in sizes if n > 16)
-    for c in chunks:
-        want = ["engine_prep", "engine_dispatch"]
-        if c[3]["final"] in ("True", "1", 1, True):
-            want += ["engine_wait", "engine_emit"]
-        assert [k[0] for k in prof.children(c)] == want
     assert decodes and all(
-        [k[0] for k in prof.children(d)] == ["engine_prep", "engine_dispatch",
-                                             "engine_wait", "engine_emit"]
-        for d in decodes)
+        [k[0] for k in prof.children(s)] == ["engine_prep", "engine_dispatch"]
+        for s in chunks + decodes)
     assert sum(int(d[3]["batch"]) for d in decodes) \
         == sched.stats["decode_seq_steps"]
     batches = prof.named("prefill_batch")     # the one-shot prompts, in admit
     assert sum(int(b[3]["n"]) for b in batches) \
         == sum(1 for n, _ in sizes if n <= 16)
+    assert all([k[0] for k in prof.children(b)] == [
+        "engine_prep", "engine_dispatch", "engine_wait", "engine_emit"]
+        for b in batches)
+    # one read a launched program (a final chunk with nothing live beside
+    # it is one) and one a one-shot prefill, each inside a tick's phase
+    final_alone = [c for c in chunks
+                   if c[3]["final"] in ("True", "1", 1, True)]
+    reads = [s for phase in ("sched_step_engine", "sched_admit")
+             for span in prof.named(phase) for s in prof.children(span)
+             if s[0] == "engine_wait"]
+    assert len(reads) == len(decodes) + len(final_alone)
+    assert len(prof.named("engine_wait")) == len(prof.named("engine_emit")) \
+        == len(reads) + len(batches)
+    overlapped = [int(d[3]["overlapped"]) for d in decodes]
+    assert sum(overlapped) == eng.overlapped_steps > 0
     names = {s[0] for line in prof.spans for s in line}
     assert names <= schema.TRACER_SPANS, names - schema.TRACER_SPANS
     ev = dict((n, v) for n, v, _ in sched.sched_events())
@@ -956,6 +972,6 @@ def test_model_step_blocks_are_named_scopes(devices8, family, program, want):
             "ragged": {"max_tracked_sequences": 4, "max_ragged_batch_size": 4,
                        "memory_config_blocks": 64, "block_size": 16}})
         found = _scopes_of(
-            eng._decode_fn(1, False), eng.params, eng.cache,
+            eng._decode_fn(1, False), eng.params, eng.cache, eng._prev,
             *map(jnp.asarray, eng._slots()), jax.random.PRNGKey(0))
     assert want <= found, want - found
