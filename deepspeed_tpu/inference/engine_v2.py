@@ -35,6 +35,7 @@ from ..comm.mesh import MeshManager
 from ..models._paged import MixedCall
 from ..ops.quantization import kv_dequantize_int8, kv_quantize_int8
 from ..telemetry.compile import CompileMonitor
+from ..telemetry.schema import DRAIN_CAUSES
 from ..telemetry.trace import percentiles
 from ..utils.logging import log_dist
 from .config import InferenceConfig
@@ -132,6 +133,7 @@ class _Flight(NamedTuple):
                                 # prompt's first token)
     t0: Optional[int]           # a mixed step's dispatch time, for the
                                 # request's ring record; else None
+    seq: int                    # the launch's number (``_next_seq``)
 
 
 def _last_row(logits, lengths):
@@ -290,6 +292,15 @@ class InferenceEngineV2(InferenceEngine):
         self._slot_src = np.zeros((B,), np.int32)
         self._flight: deque = deque()
         self._out: Dict[int, List[int]] = {}
+        # --- a program's life on one clock (docs/observability.md "A
+        # launched program is one chain"): every program the engine launches
+        # takes the next number (``_next_seq``; from 1, never reused), which
+        # is the ``seq`` of its launch span and of the ``engine_wait`` that
+        # reads it, wherever that nests. A drain - a read of everything in
+        # flight ahead of the tick's own ``collect``, by what needs a
+        # token's value or moves a sequence - is counted by its cause.
+        self._seq = 0
+        self.drains: Dict[str, int] = dict.fromkeys(DRAIN_CAUSES, 0)
         self._slot_tables = np.zeros((B, max_blocks_per_seq), np.int32)
         # per-slot sampling params, recorded at admission — decode honors
         # these (the reference's v2 engine carries per-request sampling)
@@ -867,8 +878,9 @@ class InferenceEngineV2(InferenceEngine):
         ch = self._next_chunk()
         rec = self._req.get(ch.uid)     # the request's ring lifecycle
         rows = ch.width + (len(self._slot_tokens) if mixed else 0)
+        seq = self._next_seq()
         with self.tracer.span(
-                "prefill_chunk", cat="serving",
+                "prefill_chunk", cat="serving", seq=seq,
                 trace=rec["trace"] if rec else None,
                 parent=rec["span"].span_id if rec else None,
                 table_blocks=self.state.max_blocks_per_seq,
@@ -888,10 +900,11 @@ class InferenceEngineV2(InferenceEngine):
                 # nothing), so this span says dispatch, not device
                 return False
             if mixed:
-                self._launched(tok[0], (), ch, None)
+                self._launched(tok[0], (), ch, None, seq)
                 return True
-            self._drain()       # what was launched before it lands first
-            with self.tracer.span("engine_wait", cat="serving"):
+            # what was launched before it lands first
+            self.drain("final_chunk")
+            with self.tracer.span("engine_wait", cat="serving", seq=seq):
                 tok = int(np.asarray(tok[0]))
             if self._trace_on:
                 self._req_first_token(ch.uid, time.monotonic_ns())
@@ -1108,8 +1121,9 @@ class InferenceEngineV2(InferenceEngine):
         self.spec_stats["step_seqs"] += len(live)
         out: Dict[int, List[int]] = {}
         st = self.spec_stats
+        seq = self._next_seq()
         with self.tracer.span(
-                "spec_verify", cat="serving", batch=len(live),
+                "spec_verify", cat="serving", seq=seq, batch=len(live),
                 drafted=sum(len(v) for v in drafts.values())) as span:
             with self.tracer.span("engine_prep", cat="serving"):
                 self._reserve(live, (len(drafts[d.uid]) + 1 for d in live))
@@ -1130,7 +1144,7 @@ class InferenceEngineV2(InferenceEngine):
             m, nxt, self.cache = self._dispatch(
                 fn, self._slots(live, tok_w) + (nvalid, dr_arr), seed,
                 (uids_arr,) + sp_rows)
-            with self.tracer.span("engine_wait", cat="serving"):
+            with self.tracer.span("engine_wait", cat="serving", seq=seq):
                 m, nxt = np.asarray(m), np.asarray(nxt)
             t1 = time.monotonic_ns() if self._trace_on else 0
             with self.tracer.span("engine_emit", cat="serving"):
@@ -1215,7 +1229,8 @@ class InferenceEngineV2(InferenceEngine):
         the cache off) takes the original zero-offset programs unchanged."""
         if not entries:
             return {}
-        self._drain()       # the slots move: no program in flight across it
+        self.drain("put")  # the slots move: no program in flight across it
+        seq = self._next_seq()
         sps = [self._canon_sp(s_) for s_ in sps]
         n = len(entries)
         n_pad = 1 << (n - 1).bit_length()
@@ -1224,7 +1239,7 @@ class InferenceEngineV2(InferenceEngine):
                           self.config.prefill_bucket)
         out: Dict[int, int] = {}
         kv_rows = [len(p) for _, p, _ in entries]  # cached prefix + suffix
-        with self.tracer.span("prefill_batch", cat="serving", n=n,
+        with self.tracer.span("prefill_batch", cat="serving", seq=seq, n=n,
                               pad_t=pad_t,
                               kv_blocks=self._kv_blocks(max(kv_rows)),
                               table_blocks=self.state.max_blocks_per_seq,
@@ -1262,7 +1277,7 @@ class InferenceEngineV2(InferenceEngine):
                 fn, (padded, lengths, tables) + ((ctx,) if with_ctx else ())
                 + ((slots,) if self._recurrent else ()),
                 seed, (uids_arr,) + sp_rows)
-            with self.tracer.span("engine_wait", cat="serving"):
+            with self.tracer.span("engine_wait", cat="serving", seq=seq):
                 toks = np.asarray(toks)
             t1 = time.monotonic_ns() if self._trace_on else 0
             self.prefill_tokens_written += int(lengths.sum())
@@ -1379,10 +1394,11 @@ class InferenceEngineV2(InferenceEngine):
             self._req_tokens(d.uid, len(emitted), t_ns)
         return d.seen_tokens
 
-    def _decode_quantum(self, k: int, live, seed: int, span) -> None:
-        """``k`` decode ticks over ``live`` in one program, inside the
-        caller's ``span``: reserve, dispatch, the ONE host sync, and each
-        sequence's k tokens."""
+    def _decode_quantum(self, k: int, live, seed: int, span,
+                        seq: int) -> None:
+        """``k`` decode ticks over ``live`` in one program, launch ``seq``,
+        inside the caller's ``span``: reserve, dispatch, the ONE host sync,
+        and each sequence's k tokens."""
         with self.tracer.span("engine_prep", cat="serving"):
             self._reserve(live, repeat(k))
             # slots hold canonical params (``_canon_sp``): see ``_sampler``
@@ -1394,16 +1410,16 @@ class InferenceEngineV2(InferenceEngine):
         # the single step's program: (toks [slots + 1], cache)
         toks, *_, self.cache = self._dispatch(fn, self._slots(live), seed,
                                               sp_rows, prev=True)
-        with self.tracer.span("engine_wait", cat="serving"):
+        with self.tracer.span("engine_wait", cat="serving", seq=seq):
             toks = np.asarray(toks).reshape(k, -1)
         t1 = time.monotonic_ns() if self._trace_on else 0
         with self.tracer.span("engine_emit", cat="serving"):
             kv = 0
             for d in live:
-                seq = toks[:, d.slot].tolist()
+                new = toks[:, d.slot].tolist()
                 # KV writes of the call: the previous last_token, then each
                 # sampled token except the newest (still pending its write)
-                kv += self._commit(d, [d.last_token] + seq[:-1], seq, t1)
+                kv += self._commit(d, [d.last_token] + new[:-1], new, t1)
             self.last_step.update(decode_seqs=len(live), kv_tokens=kv)
             span.set(kv_tokens=kv)
 
@@ -1419,11 +1435,14 @@ class InferenceEngineV2(InferenceEngine):
         ``kv_tokens``, the tile counts and ``ssm_*`` are the decode rows',
         the MoE rows the whole call's - one pass through the expert bank, so
         no other span may carry them -, the chunk's facts ride as
-        ``chunk_*``; ``overlapped``: the program before was still unread),
+        ``chunk_*``; ``overlapped``: the program before was still unread;
+        ``seq``: the launch's number, which its ``_Flight`` carries to the
+        ``engine_wait`` that reads it),
         each live sequence is one token longer, the chunk's bookkeeping is
         done, ``last_step`` counts what the call does. The tokens
         themselves stay on the device until ``_read``."""
         overlapped = int(bool(self._flight))
+        seq = self._next_seq()
         n_rows = len(self._slot_tokens)
         chunk_args = {"chunk_tokens": 0}
         if ch is not None:
@@ -1432,7 +1451,7 @@ class InferenceEngineV2(InferenceEngine):
                           for k, v in self._chunk_args(ch).items()}
             self._ssm_args(1, len(ch.tokens))    # ``last_step``'s count
         with self.tracer.span(
-                "decode_step", cat="serving", batch=len(live),
+                "decode_step", cat="serving", seq=seq, batch=len(live),
                 overlapped=overlapped, **self._moe_args(n_rows),
                 **self._ssm_args(len(live), len(live)),
                 **chunk_args) as span:
@@ -1463,12 +1482,12 @@ class InferenceEngineV2(InferenceEngine):
             span.set(kv_tokens=kv, **extra)
             if ch is not None:
                 self._chunk_landed(ch, table)
-            self._launched(toks, live, ch, t0)
+            self._launched(toks, live, ch, t0, seq)
         self.mixed_steps += ch is not None
         self.overlapped_steps += overlapped
 
     def _launched(self, toks, live, ch: Optional[_Chunk],
-                  t0: Optional[int]) -> None:
+                  t0: Optional[int], seq: int) -> None:
         """A decode-shaped program is in flight: its result is what the
         next one resolves its tokens from - the slots of ``live`` their own
         entry, the slot a final chunk seated the last one; every other slot
@@ -1479,7 +1498,7 @@ class InferenceEngineV2(InferenceEngine):
         self._slot_src[[d.slot for d in live]] = _FROM_SLOT
         if ch is not None and ch.final:
             self._slot_src[ch.desc.slot] = _FROM_CHUNK
-        self._flight.append(_Flight(toks, tuple(live), ch, t0))
+        self._flight.append(_Flight(toks, tuple(live), ch, t0, seq))
 
     def _read(self, fl: _Flight) -> None:
         """The host's one sync on a launched program: its tokens land on
@@ -1487,7 +1506,7 @@ class InferenceEngineV2(InferenceEngine):
         step had - the chunk's first token, then the decodes'. A sequence
         retired since the launch (a split prefill cancelled by ``finish``
         with a chunk of it in flight) is passed over."""
-        with self.tracer.span("engine_wait", cat="serving"):
+        with self.tracer.span("engine_wait", cat="serving", seq=fl.seq):
             toks = np.asarray(fl.toks)
         t1 = time.monotonic_ns() if self._trace_on else 0
         seqs = self.state.seqs
@@ -1524,13 +1543,24 @@ class InferenceEngineV2(InferenceEngine):
                 if self._trace_on:
                     self._req_tokens(d.uid, 1, t1)
 
-    def _drain(self) -> None:
+    def _next_seq(self) -> int:
+        """The number of the program about to be launched."""
+        self._seq += 1
+        return self._seq
+
+    def drain(self, cause: str) -> None:
         """Read every program in flight: what needs a token's VALUE or
-        moves a sequence calls this first (``put``, ``park``, ``fork``, a
+        moves a sequence calls this first, and says why (``cause``, one of
+        ``telemetry.schema.DRAIN_CAUSES``: ``put``, ``park``, ``fork``, a
         speculative step, ...). The tokens wait in ``_out`` for the next
-        ``collect``."""
-        while self._flight:
-            self._read(self._flight.popleft())
+        ``collect``. With nothing in flight it is nothing: no span, no
+        count - the synchronous paths stay event-free."""
+        if not self._flight:
+            return
+        self.drains[cause] += 1
+        with self.tracer.span("engine_drain", cat="serving", cause=cause):
+            while self._flight:
+                self._read(self._flight.popleft())
 
     @property
     def in_flight(self) -> int:
@@ -1576,7 +1606,7 @@ class InferenceEngineV2(InferenceEngine):
         history and so runs whole here (its tokens wait for ``collect``)."""
         self.steps += 1
         if self._spec_on:
-            self._drain()
+            self.drain("spec")
         while len(self._flight) > 1:
             self._read(self._flight.popleft())
         before = len(self._flight)
@@ -1646,7 +1676,7 @@ class InferenceEngineV2(InferenceEngine):
         number of tokens per call. ``generate`` picks ``step()`` when
         ``inference.speculative.enabled`` is set."""
         self._warn_ignored_sp(sp)
-        self._drain()
+        self.drain("quantum")
         live, _ = self._prefill_then_live(seed)
         if live:
             # a tick at seen writes KV position seen, so seen may reach
@@ -1655,9 +1685,10 @@ class InferenceEngineV2(InferenceEngine):
             k = min(k, self.family.cfg.max_seq_len
                     - max(d.seen_tokens for d in live))
         if live and k > 0:
-            with self.tracer.span("decode_quantum", cat="serving", k=k,
-                                  batch=len(live)) as span:
-                self._decode_quantum(k, live, seed, span)
+            seq = self._next_seq()
+            with self.tracer.span("decode_quantum", cat="serving", seq=seq,
+                                  k=k, batch=len(live)) as span:
+                self._decode_quantum(k, live, seed, span, seq)
         return self.collect()
 
     def finish(self, uid: int) -> List[int]:
@@ -1668,7 +1699,8 @@ class InferenceEngineV2(InferenceEngine):
         structure would have missed first)."""
         desc = self.state.lookup(uid)
         if uid in self._flying():
-            self._drain()   # its stream is whole before it is handed back
+            # its stream is whole before it is handed back
+            self.drain("finish")
         self._req_finish(uid, generated=len(desc.generated))
         self._pending_prefill.pop(uid, None)  # cancel an in-flight split
         self._clear_slot(desc.slot)
@@ -1725,7 +1757,7 @@ class InferenceEngineV2(InferenceEngine):
         request's trace record stays open (park/resume is invisible to the
         client except as latency), and an instant marks the gap."""
         desc = self.state.lookup(uid)
-        self._drain()       # the history is every token, those in flight too
+        self.drain("park")  # the history is every token, those in flight too
         self._pending_prefill.pop(uid, None)   # mid-split park: chunks stop
         history = list(desc.tokens) if desc.prefilling \
             else list(desc.tokens) + [desc.last_token]
@@ -1789,7 +1821,7 @@ class InferenceEngineV2(InferenceEngine):
                           "and the parent's recurrent state would have to "
                           "be copied into a slot of its own, which is not "
                           "written")
-        self._drain()       # the child starts from the parent's last token
+        self.drain("fork")  # the child starts from the parent's last token
         desc = self.state.fork(uid, new_uid)
         self._req_admit(new_uid, desc.seen_tokens)
         self._seat(desc, self.state.block_table(desc),
@@ -1813,7 +1845,8 @@ class InferenceEngineV2(InferenceEngine):
         full blocks first — the handoff planner keys the wire transfer
         (and the destination's dedup probe) on these."""
         desc = self.state.lookup(uid)
-        self._drain()       # a block a decode filled is hashed from its ids
+        # a block a decode filled is hashed from its ids
+        self.drain("prefix_hash")
         self.state.mark_filled(desc)
         return list(desc.block_hashes)
 
@@ -1855,7 +1888,7 @@ class InferenceEngineV2(InferenceEngine):
             raise ValueError(f"unknown KV wire format {wire!r}")
         self._refuse_call("export_kv_blocks", _HANDOFF)
         desc = self.state.lookup(uid)
-        self._drain()
+        self.drain("export")   # the blocks hold every token's KV first
         self.state.mark_filled(desc)
         hashes = list(desc.block_hashes)
         skip = max(0, min(int(skip), len(hashes)))

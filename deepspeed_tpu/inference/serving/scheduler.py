@@ -293,7 +293,7 @@ class ServingScheduler:
         handle objects keep streaming, and parked histories re-prefill on
         the new replica (KV never crosses engines; token history does)."""
         out: List[Tuple[RequestHandle, Optional[Dict[str, Any]]]] = []
-        self._drain()
+        self._drain("sched_evict")
         for uid, h in list(self._live.items()):
             parked = self.engine.park(uid)
             if h.request.trace_ctx is not None:
@@ -334,7 +334,8 @@ class ServingScheduler:
         here. Unlike :meth:`evict_all` this is the PLANNED move of the
         two-tier pipeline, not a preemption, so the handle's preemption
         count is untouched."""
-        self._drain()       # a first token in flight goes out with its handle
+        # a first token in flight goes out with its handle
+        self._drain("sched_export")
         h = self._live.pop(uid)
         parked = self.engine.park(uid)
         if h.request.trace_ctx is not None:
@@ -439,6 +440,7 @@ class ServingScheduler:
                   tick=self.stats["ticks"]) as tick:
             now = self._clock()
             wrote = eng.prefill_tokens_written
+            drains = sum(eng.drains.values())
             self._admit_tokens = 0
             with span("sched_expire", cat="serving"):
                 if self.cfg.drop_expired:
@@ -465,7 +467,9 @@ class ServingScheduler:
                 # every token streamed to a client this tick: an admission's
                 # first token (one-shot prefill, resume) and the harvest's
                 "tokens_out": self._admit_tokens
-                + sum(len(v) for v in emitted.values())}
+                + sum(len(v) for v in emitted.values()),
+                # the drains that read a program during the tick
+                "drains": sum(eng.drains.values()) - drains}
             tick.set(**self.last_tick)
         self.stats["prefill_tokens"] += self.last_tick["prefill_tokens"]
         self.stats["decode_seq_steps"] += self.last_tick["decode_seqs"]
@@ -624,17 +628,19 @@ class ServingScheduler:
             raise UnknownSequenceError(uid)
         self._park_to_queue(h)
 
-    def _drain(self) -> None:
+    def _drain(self, cause: str) -> None:
         """Before a sequence moves (a park, a hand-off, a replica's drain):
-        read what the engine has in flight and stream it, so the move loses
-        and doubles no token. The tokens are in their handles at once and
-        count with the tick that returns next."""
+        read what the engine has in flight - a drain, under the ``cause``
+        its call site has in ``telemetry.schema.DRAIN_CAUSES`` - and stream
+        it, so the move loses and doubles no token. The tokens are in their
+        handles at once and count with the tick that returns next."""
+        self.engine.drain(cause)
         for uid, toks in self._harvest(self.engine.collect()).items():
             self._early.setdefault(uid, []).extend(toks)
 
     def _park_to_queue(self, h: RequestHandle) -> None:
         uid = h.request.uid
-        self._drain()
+        self._drain("sched_park")
         parked = self.engine.park(uid)
         del self._live[uid]
         h.state = PARKED

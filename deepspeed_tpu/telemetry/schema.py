@@ -60,6 +60,7 @@ __all__ = ["EVENT_NAME_RE", "SERVING_SERIES", "TRAIN_SERIES",
            "RELIABILITY_INTEGRITY_SERIES",
            "TENANT_METRICS", "FLEET_REPLICA_METRICS", "FLEET_AGG_SERIES",
            "FLEET_OUTLIER_SERIES", "TRACER_INSTANTS", "TRACER_SPANS",
+           "DRAIN_CAUSES",
            "TUNE_TOTAL_SERIES", "TUNE_KNOB_METRICS",
            "MFU_SEGMENT_RE", "ANOMALY_PHASES",
            "REMAT_POLICIES", "validate_events", "validate_jsonl_records"]
@@ -324,8 +325,27 @@ TRACER_SPANS = frozenset((
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",
+    # a read of every program in flight, ahead of the tick's own: what
+    # needs a token's value or moves a sequence (``DRAIN_CAUSES``)
+    "engine_drain",
     # v1 generate loop (inference/engine.py)
     "generate/prefill", "generate/decode_chunk"))
+
+# Why a program in flight is read ahead of the tick's own ``collect``: the
+# ``cause`` of an ``engine_drain`` span and the keys of
+# ``InferenceEngineV2.drains``, one name a call site (docs/observability.md
+# "Every drain has a cause" says what each needs a token's value or a
+# slot's position for). CLOSED: the engine counts by these keys and raises
+# on any other.
+DRAIN_CAUSES = (
+    # engine_v2: a one-shot prefill, a final chunk of a family without a
+    # mixed call, a speculative step, a fused quantum, finish() of a stream
+    # with a token in flight, park, fork, kv_chain_hashes, export_kv_blocks
+    "put", "final_chunk", "spec", "quantum", "finish", "park", "fork",
+    "prefix_hash", "export",
+    # serving/scheduler.py: _park_to_queue, evict_all, export_live (the
+    # tokens go to their handles before the sequence moves)
+    "sched_park", "sched_evict", "sched_export")
 
 # Registered Tune/* series (the self-tuning runtime — tuning/tuner.py;
 # docs/tuning.md): the Tune/total/* rollup family is fully enumerated, and

@@ -558,7 +558,8 @@ class _Profiled:
 SCHED_CHILDREN = ["sched_expire", "sched_admit", "sched_preempt_guard",
                   "sched_step_engine", "sched_harvest", "sched_retire"]
 TICK_STATS = {"tick", "admitted", "preempted", "live", "queued",
-              "prefill_tokens", "decode_seqs", "kv_tokens", "tokens_out"}
+              "prefill_tokens", "decode_seqs", "kv_tokens", "tokens_out",
+              "drains"}
 
 
 def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
@@ -634,12 +635,19 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
         "engine_prep", "engine_dispatch", "engine_wait", "engine_emit"]
         for b in batches)
     # one read a launched program (a final chunk with nothing live beside
-    # it is one) and one a one-shot prefill, each inside a tick's phase
+    # it is one) and one a one-shot prefill, each inside a tick's phase:
+    # the tick's own under ``sched_step_engine``, an admission's under the
+    # ``engine_drain{cause=put}`` its ``sched_admit`` opened (ISSUE 36)
     final_alone = [c for c in chunks
                    if c[3]["final"] in ("True", "1", 1, True)]
-    reads = [s for phase in ("sched_step_engine", "sched_admit")
-             for span in prof.named(phase) for s in prof.children(span)
-             if s[0] == "engine_wait"]
+    drains = prof.named("engine_drain")
+    assert drains and {d[3]["cause"] for d in drains} == {"put"}
+    assert all(d in prof.children(a) for d in drains
+               for a in prof.named("sched_admit") if a[1] <= d[1] <= a[2])
+    assert len(drains) == eng.drains["put"] == sum(eng.drains.values()) \
+        == sum(t["drains"] for t, _ in ticks)
+    reads = [s for span in prof.named("sched_step_engine") + drains
+             for s in prof.children(span) if s[0] == "engine_wait"]
     assert len(reads) == len(decodes) + len(final_alone)
     assert len(prof.named("engine_wait")) == len(prof.named("engine_emit")) \
         == len(reads) + len(batches)
