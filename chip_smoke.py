@@ -92,6 +92,8 @@ class ServeSize:
     slots: int = 32               # decode batch width
     prefill_chunk: int = 256      # Dynamic-SplitFuse chunk for long prompts
     prompt_lens: tuple = (128, 200, 256, 384, 512, 640, 768, 1024)
+    # a prompt admitted BESIDE those streams, most of its chunk padding
+    late_prompt: int = 100
     new_tokens: int = 64
     # the two logit checks: (prompt length, decode steps before the probe);
     # one short prompt through the batched prefill, one long through chunks
@@ -329,7 +331,8 @@ def synchronous(sched):
     return sched
 
 
-def check_overlap(eng, prompts, new_tokens: int, greedy) -> dict:
+def check_overlap(eng, prompts, late_prompt, new_tokens: int,
+                  greedy) -> dict:
     """The streams of the ticks as shipped (program n+1 launched before
     program n's tokens are read, the tokens resolved on the device) against
     the same requests through synchronous ``step()`` ticks with the same
@@ -338,16 +341,20 @@ def check_overlap(eng, prompts, new_tokens: int, greedy) -> dict:
     taken from the middle of the stream it has without one, so a row
     launched past its end is dropped; request 1 is preempted with a token in
     flight and resumed; the long prompts' first tokens are seated from the
-    final chunk's result. Then fused quanta (``decode_quantum=4``: each
-    drains what is in flight) against ``greedy``, the single steps' streams
-    of the same prompts, and a quantum of ONE tick."""
+    final chunk's result; ``late_prompt``, shorter than a chunk, comes in
+    beside the live streams: the overlapped tick finds a program in flight
+    and so admits it through the chunk lane - a first-and-final chunk in
+    the tick's one program, no ``put`` drain. Then fused quanta
+    (``decode_quantum=4``: each drains what is in flight) against
+    ``greedy``, the single steps' streams of the same prompts, and a quantum
+    of ONE tick."""
     from deepspeed_tpu.inference.sampling import SamplingParams
     from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
                                                  ServingScheduler)
 
     hot = SamplingParams(temperature=0.8, top_k=40)
 
-    def streams(sched, eos=None):
+    def streams(sched, eos=None, lane_late=False):
         # a sampled row's noise is its SLOT's (one categorical draw over
         # [slots, vocab]): every pass seats its requests in the same slots
         eng.state._free_slots.sort(reverse=True)
@@ -356,23 +363,42 @@ def check_overlap(eng, prompts, new_tokens: int, greedy) -> dict:
             eos_token_id=eos if i == 0 else None,
             **({"sp": hot} if i % 2 else {})))
             for i, p in enumerate(prompts)]
-        before = eng.overlapped_steps
+        before, put_drains = eng.overlapped_steps, eng.drains["put"]
         ticks = 0
         while sched.pending:
+            if ticks == 3:      # the tick after request 1 resumes
+                handles.append(sched.submit(Request(
+                    prompt=late_prompt, max_new_tokens=new_tokens)))
+                if lane_late:
+                    # nothing is ever in flight under synchronous ticks, so
+                    # they would run this prompt as a one-shot ``prefill``,
+                    # ANOTHER program, which rounds a bf16 near-tie its own
+                    # way: told to take the lane (it is the run's last
+                    # admission), they run it in the mixed program the
+                    # overlapped tick runs it in
+                    sched._takes_chunk_lane = lambda n: True
             sched.tick()
             ticks += 1
             if ticks == 2:
                 sched.preempt(handles[1].uid)
         require(all(h.state == "done" for h in handles),
                 f"requests ended {[h.state for h in handles]}")
+        split = sum(len(p) > eng.config.split_prefill_chunk for p in prompts)
+        require(sched.stats["chunked_admissions"] == split + 1,
+                f"the late prompt of {len(late_prompt)} tokens was not "
+                f"admitted through the chunk lane")
         return ([h.tokens for h in handles], ticks,
-                eng.overlapped_steps - before, sched.stats["preempted"])
+                eng.overlapped_steps - before, sched.stats["preempted"],
+                eng.drains["put"] - put_drains)
 
     new = lambda: ServingScheduler(eng, SchedulerConfig())  # noqa: E731
-    whole = streams(synchronous(new()))[0][0]
+    whole = streams(synchronous(new()), lane_late=True)[0][0]
     eos = whole[new_tokens // 2]
-    want, sync_ticks, sync_over, _ = streams(synchronous(new()), eos)
-    got, ticks, overlapped, preempted = streams(new(), eos)
+    want, sync_ticks, sync_over, *_ = streams(synchronous(new()), eos,
+                                              lane_late=True)
+    got, ticks, overlapped, preempted, put_drains = streams(new(), eos)
+    require(put_drains == 0, f"{put_drains} admissions of the overlapped "
+            f"ticks read the program in flight (cause put)")
     differ = [i for i, (a, b) in enumerate(zip(got, want)) if a != b]
     require(not differ, f"overlapped ticks stream other tokens than "
             f"synchronous steps for requests {differ}: "
@@ -403,8 +429,9 @@ def check_overlap(eng, prompts, new_tokens: int, greedy) -> dict:
             f"a quantum of one tick gives {one}")
     require(eng.in_flight == 0 and not eng.state.seqs,
             "the engine did not end empty")
-    return {"requests": len(prompts), "sampled": len(prompts) // 2,
-            "streams_equal": len(prompts) - len(differ),
+    return {"requests": len(got), "sampled": len(prompts) // 2,
+            "streams_equal": len(got) - len(differ),
+            "late_prompt_tokens": len(late_prompt), "put_drains": put_drains,
             "eos_stream_tokens": len(got[0]), "preempted": preempted,
             "ticks": ticks, "synchronous_ticks": sync_ticks,
             "overlapped_steps": overlapped,
@@ -535,8 +562,10 @@ def phase_serve(size: ServeSize, seed: int, mosaic: bool = True,
                 "no Mosaic kernel in the decode step: paged attention ran "
                 "the XLA reference")
     say(phase="serve_overlap",
-        **check_overlap(eng, prompts, size.new_tokens,
-                        [h.tokens for h in handles]))
+        **check_overlap(eng, prompts,
+                        rng.integers(0, cfg.vocab_size,
+                                     size.late_prompt).tolist(),
+                        size.new_tokens, [h.tokens for h in handles]))
 
 
 # --------------------------------------------------------------------------- #

@@ -2238,8 +2238,11 @@ class InferenceEngineV2(InferenceEngine):
             batch_adm = []
             batch_cached = []
             split = self.config.split_prefill_chunk
-            # a prompt that fits one EFFECTIVE chunk gains nothing from the
-            # split path — keep it in the batched one-shot burst
+            # the offline burst: nothing is in flight here and no live
+            # stream waits on a tick, so a prompt that fits one EFFECTIVE
+            # chunk stays in the batched one-shot prefill (many prompts, one
+            # program). A serving tick decides otherwise where a program is
+            # in flight: ``ServingScheduler._takes_chunk_lane``
             eff_chunk = (_round_up(split, self.config.prefill_bucket)
                          if split > 0 else 0)
             while pending and self.state.can_admit(len(pending[0][1])):

@@ -18,8 +18,10 @@ admission, batch composition, preemption, and completion. Design points:
   are rejected at submit instead of thrashing forever.
 - **SLO-aware batch composition.** With the engine's Dynamic-SplitFuse
   chunking enabled, long prompts are admitted via ``put_split`` so ongoing
-  decodes never stall more than one chunk; short prompts batch into one
-  compiled ``put_many`` prefill per sampling config.
+  decodes never stall more than one chunk; a prompt that fits one chunk
+  takes the same lane wherever a one-shot prefill would have to read a
+  program in flight first (``_takes_chunk_lane``), and otherwise batches
+  into one compiled ``put_many`` prefill per sampling config.
 - **Decode preemption.** Before each decode quantum the scheduler asks
   ``StateManager.growth_blocks_short`` whether the next tokens' block needs
   (fresh tails AND copy-on-write) exceed headroom; if so, the least urgent
@@ -35,7 +37,9 @@ admission, batch composition, preemption, and completion. Design points:
   retirement run while the device works; a tick returns the tokens of the
   program launched one tick earlier. A stream that ends by count gets no row
   in the program launched past its end; whatever needs a token's value or
-  moves a sequence reads what is in flight first (``_drain``).
+  moves a sequence reads what is in flight first (``_drain``) - no
+  admission does: beside a program in flight a prompt rides the next
+  launch as a chunk.
 
 The scheduler drives the engine exclusively through its public API (``put``,
 ``put_split``, ``launch``, ``collect``, ``step``, ``step_many``, ``park``,
@@ -509,21 +513,18 @@ class ServingScheduler:
 
     def _admit(self, now: float, seed: int) -> int:
         """Admit while slots + block headroom allow, most urgent first with
-        bounded lookahead past a blocked head. One-shot prefills batch into
-        one ``put_many`` per sampling config; long prompts (and resumes of
-        long histories) take the chunked ``put_split`` path so live decodes
-        keep ticking. The block budget decrements per admission, so the
-        whole burst can never over-commit the pool."""
+        bounded lookahead past a blocked head. A prompt (or a resume's
+        history) enters one of two ways, ``_takes_chunk_lane`` says which:
+        through ``put_split``, chunk by chunk inside the ticks' own
+        programs, so live decodes keep ticking and nothing is read; or as a
+        one-shot prefill, batched into one ``put_many`` per sampling config,
+        whose first token streams in this tick. The block budget decrements
+        per admission, so the whole burst can never over-commit the pool."""
         eng, cfg = self.engine, self.cfg
         st = eng.state
         max_live = cfg.max_live or st.max_sequences
         budget = st.headroom_blocks - cfg.reserve_blocks
         slots = st.free_slots
-        split = eng.config.split_prefill_chunk
-        eff_chunk = 0
-        if split > 0:
-            from ..engine import _round_up
-            eff_chunk = _round_up(split, eng.config.prefill_bucket)
         batches: Dict[SamplingParams, List[Tuple[int, List[int]]]] = {}
         stash: List[Tuple[int, float, int, dict]] = []
         admitted = 0
@@ -565,12 +566,12 @@ class ServingScheduler:
             if h.queue_wait_ms is None:
                 h.queue_wait_ms = (now - h._submit_t) * 1e3
                 self._queue_wait_ms.append(h.queue_wait_ms)
+            lane = self._takes_chunk_lane(len(tokens))
             if parked is not None:
-                toks = eng.resume(parked, seed=seed,
-                                  split=split > 0 and len(tokens) > eff_chunk)
+                toks = eng.resume(parked, seed=seed, split=lane)
                 self._admit_tokens += h._emit(toks)
                 self.stats["resumed"] += 1
-            elif split > 0 and len(tokens) > eff_chunk:
+            elif lane:
                 eng.put_split(uid, tokens, h.request.sp)
                 self.stats["chunked_admissions"] += 1
                 self.stats["admitted"] += 1
@@ -584,6 +585,29 @@ class ServingScheduler:
             for uid, tok in first.items():
                 self._admit_tokens += self.handles[uid]._emit([tok])
         return admitted
+
+    def _takes_chunk_lane(self, n_tokens: int) -> bool:
+        """Whether a prompt of ``n_tokens`` enters through ``put_split``
+        (with SplitFuse chunking on): one that outgrows a chunk always, and
+        one that FITS a chunk wherever the one-shot prefill would have to
+        read a program in flight first (``engine.drain("put")``) and the
+        family's tick carries a chunk in its decode program
+        (``ModelFamily.mixed_paged``) - the prompt then rides the next
+        launch as a first-and-final chunk, its first token comes with the
+        collect after, and the device never waits for the host. With
+        nothing in flight (an idle engine, a fused quantum, a speculative
+        step, an attached tuner: each read its own program before this
+        tick's admissions) the one-shot costs no drain and streams its
+        first token in the admitting tick, so it stays; a family without a
+        mixed call drains for its final chunk anyway."""
+        eng = self.engine
+        split = eng.config.split_prefill_chunk
+        if split <= 0:
+            return False
+        from ..engine import _round_up
+        if n_tokens > _round_up(split, eng.config.prefill_bucket):
+            return True
+        return eng.in_flight > 0 and eng.family.mixed_paged
 
     def _preempt_guard(self) -> int:
         """Park the least urgent live requests until the next decode

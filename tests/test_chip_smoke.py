@@ -45,7 +45,7 @@ def _tiny():
     train = chip_smoke.TrainSize(layers=2, batch=8, seq=64, steps=4)
     serve = chip_smoke.ServeSize(
         layers=2, block_size=16, pool_blocks=96, slots=8, prefill_chunk=32,
-        prompt_lens=(8, 20, 32, 40, 64, 100), new_tokens=6,
+        prompt_lens=(8, 20, 32, 40, 64, 100), late_prompt=12, new_tokens=6,
         probes=((20, 3), (90, 3)))
     return chip_smoke, cfg, train, serve
 
@@ -68,9 +68,11 @@ def test_chip_smoke_serve_phase_tiny(capsys):
     assert row["phase"] == "serve" and row["requests"] == 6
     assert len(row["logit_checks"]) == 2
     # overlapped ticks against synchronous steps: sampled rows, an eos
-    # ending, a preemption with a token in flight, fused quanta
+    # ending, a preemption with a token in flight, a prompt shorter than a
+    # chunk admitted beside the live streams (no ``put`` drain), fused quanta
     assert overlap["phase"] == "serve_overlap"
-    assert overlap["streams_equal"] == overlap["requests"] == 6
+    assert overlap["streams_equal"] == overlap["requests"] == 7
+    assert overlap["late_prompt_tokens"] == 12 and overlap["put_drains"] == 0
     assert overlap["eos_stream_tokens"] < 6 and overlap["preempted"] == 1
     assert overlap["overlapped_steps"] > 0
 

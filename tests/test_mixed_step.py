@@ -49,14 +49,18 @@ def _written(cache, state=()):
             for n, c in cache.items()}
 
 
-@pytest.mark.parametrize("n_valid", [CHUNK, 5], ids=["mid", "final_padded"])
+@pytest.mark.parametrize("n_valid", [CHUNK, 5, 2],
+                         ids=["mid", "final_padded", "mostly_padding"])
 @pytest.mark.parametrize("ctx", [0, 8], ids=["first_chunk", "later_chunk"])
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_a_mixed_call_is_the_chunk_then_the_decode(family, ctx, n_valid):
     """Slots 0 and 1 decode (contexts 5 and 9), slot 2 is the prefilling
     sequence whose chunk rides along - not active -, slot 3 is free. In
     float32 the one call's logits (decode rows, the chunk's last real row)
-    and every block and state row it wrote equal the two calls'."""
+    and every block and state row it wrote equal the two calls'. A first
+    chunk that is mostly padding is what a short prompt admitted beside a
+    program in flight rides in (ISSUE 37): its padded rows write no block
+    but the trash."""
     module, make, cache_kw = FAMILIES[family]
     cfg = make()
     recurrent = "slots" in cache_kw

@@ -648,7 +648,10 @@ def _overlap_requests(vocab):
     prompts (5, 7), a stochastic row of each kind, answers of 1 to 9 tokens
     - as many requests as slots, so none waits for an end to be noticed and
     a stochastic stream meets the same seeds in both runs -, then a greedy
-    one-shot prompt that waits for a slot."""
+    prompt of less than a chunk that waits for a slot. A short prompt
+    admitted beside a program in flight (the last two: a tick admits two of
+    these at most) rides the tick's program (ISSUE 37), where the
+    synchronous ticks run it as a one-shot prefill."""
     rng = np.random.default_rng(35)
     mk = lambda n, m, sp=SP: Request(                       # noqa: E731
         prompt=rng.integers(1, vocab, (n,)).tolist(), max_new_tokens=m, sp=sp)
@@ -663,6 +666,7 @@ def _run_streams(sched, reqs):
     handles = [sched.submit(r, on_token=seen.setdefault(i, []).append)
                for i, r in enumerate(reqs)]
     returned, ticks = {h.uid: [] for h in handles}, []
+    one_shot = _one_shot_uids(sched.engine)
     while sched.pending:
         assert len(ticks) < 200
         for uid, toks in sched.tick().items():
@@ -671,9 +675,22 @@ def _run_streams(sched, reqs):
     # a tick returns what the engine's steps produced; a one-shot prompt's
     # first token went to its handle at admission
     for h in handles:
-        if len(h.request.prompt) <= 8:
+        if h.uid in one_shot:
             returned[h.uid].insert(0, h.tokens[0])
     return handles, seen, returned, ticks
+
+
+def _one_shot_uids(eng):
+    """The uids ``put_many`` prefills from here on (the list fills as the
+    engine runs): the admissions that took the one-shot path."""
+    uids, put_many = [], eng.put_many
+
+    def spy(pairs, *args, **kwargs):
+        uids.extend(uid for uid, _ in pairs)
+        return put_many(pairs, *args, **kwargs)
+
+    eng.put_many = spy
+    return uids
 
 
 @pytest.mark.parametrize("family", sorted(OVERLAP_FAMILIES))
@@ -684,7 +701,10 @@ def test_overlapped_ticks_stream_what_synchronous_steps_stream(family):
     calls with the same seeds: token for token, exactly ``max_new_tokens``,
     every token once and in order through ``on_token`` and the ticks'
     returns. A final chunk's first token is seated from the device, and the
-    counter says how often a launch found the program before unread."""
+    counter says how often a launch found the program before unread. (Until
+    ISSUE 37 the requests of seven and six tokens, admitted beside a
+    program in flight, were one-shot ``put``s that drained it; now their
+    first tokens too are seated from a chunk, and nothing drains.)"""
     vocab = OVERLAP_FAMILIES[family]()[1].vocab_size
     ref_eng = _overlap_engine(family)
     want, *_ = _run_streams(
@@ -710,7 +730,10 @@ def test_overlapped_ticks_stream_what_synchronous_steps_stream(family):
         assert h.tokens == w.tokens, (i, h.tokens, w.tokens)
         assert len(h.tokens) == h.request.max_new_tokens
         assert seen[i] == returned[h.uid] == h.tokens
-    assert sum(from_chunk) == 2             # both split prompts' first tokens
+    # both split prompts' first tokens, and those of the two short prompts
+    # that were admitted with a program in flight
+    assert sum(from_chunk) == 4 == sched.stats["chunked_admissions"]
+    assert eng.drains["put"] == 0 and all(t["drains"] == 0 for t in ticks)
     assert eng.in_flight == 0 and not eng.tokens_uncollected()
     assert not eng.state.seqs
     eng.state.debug_check()
@@ -777,6 +800,140 @@ def test_an_eos_stream_ends_at_its_token_and_frees_the_dropped_row(family):
     assert eng.state.allocator.free_blocks == 95    # every block is back
     eng.state.debug_check()
     eng.debug_check_cache()
+
+
+# --------------------------------------------------------------------------- #
+# no admission drains the pipeline (ISSUE 37): a prompt that fits one chunk
+# rides the tick's program where a one-shot prefill would have to read the
+# program in flight first
+# --------------------------------------------------------------------------- #
+def _live_then_short(sched, vocab, short=(8, 3), ticks=3):
+    """Two streams live (a split prompt and a short one, one of them
+    sampled) and, after ``ticks`` ticks, prompts of a chunk or less (the chunk's
+    8 tokens; 3, most of its chunk padding) into the two free slots, one a
+    tick
+    → (handles, the ``last_tick`` of each admitting tick with the programs
+    it found in flight, what each of those handles held when its admitting
+    tick returned)."""
+    rng = np.random.default_rng(37)
+    mk = lambda n, m, sp=SP: Request(                       # noqa: E731
+        prompt=rng.integers(1, vocab, (n,)).tolist(), max_new_tokens=m, sp=sp)
+    handles = [sched.submit(r) for r in (mk(21, 12), mk(5, 14, STOCHASTIC))]
+    for _ in range(ticks):
+        sched.tick()
+    admitting, held = [], []
+    for n in short:
+        handles.append(sched.submit(mk(n, 5)))
+        flying = sched.engine.in_flight
+        sched.tick()
+        admitting.append(dict(sched.last_tick, found_in_flight=flying))
+        held.append(list(handles[-1].tokens))
+    sched.run()
+    return handles, admitting, held
+
+
+@pytest.mark.parametrize("family", sorted(OVERLAP_FAMILIES))
+def test_a_short_prompt_beside_a_program_in_flight_rides_the_tick(family):
+    """Streams live and a program in flight: a tick that admits a prompt of
+    one chunk or less (8 = the chunk, 3 tokens) reads nothing for it -
+    no ``put`` drain, ``last_tick["drains"]`` 0 -, counts it in
+    ``chunked_admissions``, gives its first token with the NEXT tick's
+    collect, and every stream, the admitted ones included, is token for
+    token what synchronous ``step()`` ticks stream (which admit the same
+    prompts as one-shot prefills: nothing is in flight there)."""
+    vocab = OVERLAP_FAMILIES[family]()[1].vocab_size
+    ref = ServingScheduler(_overlap_engine(family), SchedulerConfig())
+    want, want_admitting, want_held = _live_then_short(_synchronous(ref),
+                                                       vocab)
+    assert [t["found_in_flight"] for t in want_admitting] == [0, 0]
+    assert ref.stats["chunked_admissions"] == 1     # the split prompt alone
+    # the first token at admission, the second from the same tick's step
+    assert all(len(t) == 2 for t in want_held)
+
+    eng = _overlap_engine(family)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    launched = []
+    launch_decode = eng._launch_decode
+    eng._launch_decode = lambda live, seed, ch=None: (
+        launched.append(ch), launch_decode(live, seed, ch))[1]
+    handles, admitting, held = _live_then_short(sched, vocab)
+    assert sched.stats["chunked_admissions"] == 3
+    assert eng.drains["put"] == 0 == sum(eng.drains.values())
+    for tick, tokens in zip(admitting, held):
+        assert tick["found_in_flight"] == 1
+        assert tick["admitted"] == 1 and tick["drains"] == 0
+        assert tick["prefill_tokens"] in (8, 3)     # written by THIS launch
+        assert tokens == []                     # it comes with the next read
+    # each rode the tick's ONE program as a first-and-final chunk
+    rode = [(len(ch.tokens), ch.ctx, ch.final) for ch in launched
+            if ch is not None and ch.ctx == 0 and ch.final]
+    assert rode == [(8, 0, True), (3, 0, True)]
+    for i, (h, w) in enumerate(zip(handles, want)):
+        assert h.state == DONE and w.state == DONE
+        assert h.tokens == w.tokens, (i, h.tokens, w.tokens)
+        assert len(h.tokens) == h.request.max_new_tokens
+    assert eng.in_flight == 0 and not eng.state.seqs
+    eng.state.debug_check()
+    eng.debug_check_cache()
+
+
+@pytest.mark.parametrize("case", ["idle", "quantum"])
+def test_with_nothing_in_flight_a_short_prompt_stays_a_one_shot(case):
+    """The one-shot prefill stays where it costs no drain: the first
+    admission into an idle engine, and a scheduler of fused quanta (which
+    reads its own program every tick) - the first token comes in the
+    admitting tick."""
+    vocab = OVERLAP_FAMILIES["llama"]()[1].vocab_size
+    eng = _overlap_engine("llama")
+    sched = ServingScheduler(eng, SchedulerConfig(
+        decode_quantum=2 if case == "quantum" else 1))
+    rng = np.random.default_rng(38)
+    mk = lambda n, m: Request(                              # noqa: E731
+        prompt=rng.integers(1, vocab, (n,)).tolist(), max_new_tokens=m)
+    first = sched.submit(mk(6, 9))
+    sched.tick()
+    assert first.tokens and sched.last_tick["admitted"] == 1
+    if case == "quantum":
+        sched.tick()
+        assert eng.in_flight == 0               # a quantum runs whole
+        late = sched.submit(mk(5, 4))
+        had = sched.stats["ticks"]
+        sched.tick()
+        assert sched.stats["ticks"] == had + 1 and late.tokens
+    sched.run()
+    assert sched.stats["chunked_admissions"] == 0
+    assert sum(eng.drains.values()) == 0
+    assert first.state == DONE and len(first.tokens) == 9
+
+
+def test_a_family_without_a_mixed_call_keeps_the_one_shot():
+    """``gpt`` runs its chunks apart and reads a final chunk's token where
+    it is launched, so the chunk lane would drain as well: a short prompt
+    beside a program in flight stays a one-shot ``put`` (cause ``put``),
+    its first token in the admitting tick."""
+    from deepspeed_tpu.models import gpt
+
+    cfg = gpt.GPTConfig.tiny(max_seq_len=64)
+    mesh_lib.set_mesh(None)
+    eng = build_engine_v2(
+        gpt, cfg, gpt.init(cfg, jax.random.PRNGKey(0)),
+        config={"dtype": "float32", "prefill_bucket": 8,
+                "split_prefill_chunk": 8,
+                "ragged": {"max_tracked_sequences": 4,
+                           "max_ragged_batch_size": 4,
+                           "memory_config_blocks": 96, "block_size": 4}})
+    assert not eng.family.mixed_paged
+    sched = ServingScheduler(eng, SchedulerConfig())
+    # (a fourth tick first: the third read everything for its final chunk)
+    handles, admitting, held = _live_then_short(sched, cfg.vocab_size,
+                                                ticks=4)
+    assert sched.stats["chunked_admissions"] == 1   # the 21-token prompt
+    assert eng.drains["put"] == 2
+    assert [(t["found_in_flight"], t["drains"]) for t in admitting] \
+        == [(1, 1), (1, 1)]
+    assert all(len(t) == 1 for t in held)
+    assert all(h.state == DONE and len(h.tokens) == h.request.max_new_tokens
+               for h in handles)
 
 
 def test_moving_a_sequence_reads_what_is_in_flight_first(tiny):

@@ -13,6 +13,7 @@ import subprocess
 import sys
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -567,7 +568,11 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     its phases inside it in order and the tick's counts as stats; the counts
     add up to the work that was submitted. One program in flight (ISSUE 35):
     a tick's counts describe the program it LAUNCHED, whose tokens the tick
-    after returns."""
+    after returns. Re-stated by ISSUE 37: a prompt of a chunk or less that
+    is admitted beside a program in flight (16 and 5 tokens here; the 9 of
+    the first tick found none) is no one-shot ``prefill_batch`` under an
+    ``engine_drain{cause=put}`` any more - it rides a ``decode_step`` as a
+    chunk, and no tick of the run drains."""
     from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
                                                  ServingScheduler)
 
@@ -578,6 +583,9 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     handles = [sched.submit(Request(
         prompt=rng.integers(0, cfg.vocab_size, (n,)).tolist(),
         max_new_tokens=m)) for n, m in sizes]
+    lane, put_split = [], eng.put_split     # the prompts admitted by chunks
+    eng.put_split = lambda uid, prompt, *a: (lane.append(len(prompt)),
+                                             put_split(uid, prompt, *a))[1]
     ticks = []   # last_tick and the decode tokens seen from outside, per tick
     with _Profiled(tmp_path) as prof:
         while sched.pending:
@@ -614,39 +622,35 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     # chunk beside live decodes rides in their ``decode_step``: one program,
     # one span, the chunk's facts as ``chunk_*``); the host's one sync on a
     # launched program, ``engine_wait`` then ``engine_emit``, lies where it
-    # was read - under ``sched_step_engine`` after the next launch, or under
-    # ``sched_admit`` where a one-shot prefill read it first
+    # was read - under ``sched_step_engine`` after the next launch
+    assert sorted(lane) == [5, 16, 33, 40, 70]
+    assert sched.stats["chunked_admissions"] == len(lane)
     chunks = prof.named("prefill_chunk")
     decodes = prof.named("decode_step")
     mixed = [d for d in decodes if int(d[3]["chunk_tokens"])]
     assert mixed and len(mixed) == eng.mixed_steps
     assert sum(int(c[3]["tokens"]) for c in chunks) \
-        + sum(int(d[3]["chunk_tokens"]) for d in mixed) \
-        == sum(n for n, _ in sizes if n > 16)
+        + sum(int(d[3]["chunk_tokens"]) for d in mixed) == sum(lane)
     assert decodes and all(
         [k[0] for k in prof.children(s)] == ["engine_prep", "engine_dispatch"]
         for s in chunks + decodes)
     assert sum(int(d[3]["batch"]) for d in decodes) \
         == sched.stats["decode_seq_steps"]
     batches = prof.named("prefill_batch")     # the one-shot prompts, in admit
-    assert sum(int(b[3]["n"]) for b in batches) \
-        == sum(1 for n, _ in sizes if n <= 16)
+    assert sum(int(b[3]["n"]) for b in batches) == len(sizes) - len(lane)
     assert all([k[0] for k in prof.children(b)] == [
         "engine_prep", "engine_dispatch", "engine_wait", "engine_emit"]
         for b in batches)
     # one read a launched program (a final chunk with nothing live beside
     # it is one) and one a one-shot prefill, each inside a tick's phase:
-    # the tick's own under ``sched_step_engine``, an admission's under the
-    # ``engine_drain{cause=put}`` its ``sched_admit`` opened (ISSUE 36)
+    # the tick's own under ``sched_step_engine``. No admission read a
+    # program in flight, so there is no ``engine_drain`` (ISSUE 37; the span
+    # and its cause: tests/test_program_seq.py)
     final_alone = [c for c in chunks
                    if c[3]["final"] in ("True", "1", 1, True)]
-    drains = prof.named("engine_drain")
-    assert drains and {d[3]["cause"] for d in drains} == {"put"}
-    assert all(d in prof.children(a) for d in drains
-               for a in prof.named("sched_admit") if a[1] <= d[1] <= a[2])
-    assert len(drains) == eng.drains["put"] == sum(eng.drains.values()) \
-        == sum(t["drains"] for t, _ in ticks)
-    reads = [s for span in prof.named("sched_step_engine") + drains
+    assert not prof.named("engine_drain")
+    assert sum(eng.drains.values()) == 0 == sum(t["drains"] for t, _ in ticks)
+    reads = [s for span in prof.named("sched_step_engine")
              for s in prof.children(span) if s[0] == "engine_wait"]
     assert len(reads) == len(decodes) + len(final_alone)
     assert len(prof.named("engine_wait")) == len(prof.named("engine_emit")) \
@@ -658,6 +662,65 @@ def test_scheduler_ticks_on_the_profiler_timeline(devices8, tmp_path):
     ev = dict((n, v) for n, v, _ in sched.sched_events())
     assert ev["Serving/sched/prefill_tokens"] == prompt_tokens
     assert schema.validate_events(sched.sched_events()) == []
+
+
+def test_an_admission_beside_a_program_in_flight_reads_nothing(
+        devices8, monkeypatch):
+    """ISSUE 37, on the ring: a tick that admits a prompt of a chunk or
+    less while a program is in flight opens no ``engine_drain`` and no
+    ``prefill_batch``; the host reads the device ONCE in it (``np.asarray``
+    of a device array inside ``engine_v2``: the tick's own collect of the
+    program before), and
+    the prompt rides the tick's ``decode_step`` as a first-and-final chunk
+    launched over an unread program."""
+    from deepspeed_tpu.inference import engine_v2 as engine_mod
+    from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
+                                                 ServingScheduler)
+
+    cfg, eng = _serving_engine(trace=True, split=16)
+    sched = ServingScheduler(eng, SchedulerConfig())
+    rng = np.random.default_rng(37)
+    mk = lambda n, m: Request(                              # noqa: E731
+        prompt=rng.integers(0, cfg.vocab_size, (n,)).tolist(),
+        max_new_tokens=m)
+    live = [sched.submit(mk(n, 12)) for n in (20, 7)]
+    for _ in range(3):
+        sched.tick()
+    assert eng.in_flight == 1 and all(h.tokens for h in live)
+    short = sched.submit(mk(11, 4))
+    seen = len(eng.tracer.events())
+    reads = {"asarray": 0}
+
+    class _CountedNumpy:
+        def __getattr__(self, name):
+            value = getattr(np, name)
+            if name != "asarray":
+                return value
+
+            def counted(x, *args, **kwargs):
+                # a device result, not ``put_split``'s list of prompt ids
+                reads["asarray"] += isinstance(x, jax.Array)
+                return value(x, *args, **kwargs)
+            return counted
+
+    monkeypatch.setattr(engine_mod, "np", _CountedNumpy())
+    sched.tick()
+    monkeypatch.undo()
+    spans = [e for e in eng.tracer.events()[seen:] if e["ph"] == "X"]
+    names = [e["name"] for e in spans]
+    assert "engine_drain" not in names and "prefill_batch" not in names
+    assert names.count("engine_wait") == 1 == reads["asarray"]
+    (step,) = [e["args"] for e in spans if e["name"] == "decode_step"]
+    assert step["chunk_uid"] == short.uid and step["chunk_tokens"] == 11
+    assert step["chunk_ctx"] == 0 and step["chunk_final"]
+    assert step["overlapped"] == 1 and step["batch"] == 2
+    (tick,) = [e["args"] for e in spans if e["name"] == "sched_tick"]
+    assert tick["admitted"] == 1 and tick["drains"] == 0
+    assert tick["prefill_tokens"] == 11
+    assert sched.stats["chunked_admissions"] == 2 and not short.tokens
+    assert sum(eng.drains.values()) == 0
+    sched.run()
+    assert short.done and len(short.tokens) == 4
 
 
 def test_train_step_on_the_profiler_timeline(devices8, tmp_path):
