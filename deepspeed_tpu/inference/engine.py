@@ -59,6 +59,11 @@ class ModelFamily:
     # so a serving step with both runs ONE program; a family without it
     # keeps the two calls
     mixed_paged: bool = False
+    # (cfg, contexts) -> what ONE layer's learned token selection does for
+    # rows at those contexts (``sparse_rows``, ``sparse_ctx_scored``,
+    # ``sparse_kv_selected``), {} for a family without one; a family that
+    # has one keeps a THIRD block pool, the index keys' (models/mixtral.py)
+    sparse_rows: Optional[Callable] = None
 
     @classmethod
     def from_module(cls, module, cfg) -> "ModelFamily":
@@ -76,7 +81,8 @@ class ModelFamily:
                    moe_rows=getattr(module, "moe_rows", None),
                    state_slot_bytes=getattr(module, "state_slot_bytes", None),
                    state_leaves=tuple(getattr(module, "STATE_LEAVES", ())),
-                   mixed_paged=bool(getattr(module, "MIXED_PAGED", False)))
+                   mixed_paged=bool(getattr(module, "MIXED_PAGED", False)),
+                   sparse_rows=getattr(module, "sparse_rows", None))
 
 
 def _round_up(n: int, m: int) -> int:

@@ -40,8 +40,8 @@ from ..telemetry.trace import percentiles
 from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .engine import InferenceEngine, ModelFamily, _round_up
-from .ragged import (RecurrentStateError, StateManager,  # noqa: F401
-                     UnknownSequenceError)                # (re-exports)
+from .ragged import (IndexPoolError, RecurrentStateError,  # noqa: F401
+                     StateManager, UnknownSequenceError)  # (re-exports)
 from .sampling import (SamplingParams, accept_drafts, sample, sample_batch,
                        sp_arrays)
 
@@ -204,6 +204,15 @@ class InferenceEngineV2(InferenceEngine):
         if self._recurrent:
             self._refuse_for_recurrent_state()
             slot_kw = {"slots": rc.max_tracked_sequences}
+        # --- a learned token selection (docs/serving.md "Learned token
+        # selection"): the family's cache has a third block pool, the index
+        # keys'. The block lifecycle carries it as it carries any leaf with
+        # the block axis (copy-on-write, fork); what names the pools it
+        # knows is refused, here or at its call.
+        self._indexed = bool(self.family.sparse_rows
+                             and self.family.sparse_rows(self.family.cfg, ()))
+        if self._indexed:
+            self._refuse_for_index_pool()
         self.state = StateManager(
             rc.max_tracked_sequences, rc.memory_config_blocks, rc.block_size,
             max_blocks_per_seq, prefix_cache=pc.enabled,
@@ -350,6 +359,11 @@ class InferenceEngineV2(InferenceEngine):
         self.steps = 0
         self.mixed_steps = 0
         self.overlapped_steps = 0
+        # what the learned selection did, ONE layer's count, cumulative
+        # (``Serving/sparse/*``): rows that selected, the cached tokens they
+        # scored, the tokens attention then read
+        self.sparse_stats: Dict[str, int] = {
+            "rows": 0, "ctx_scored": 0, "kv_selected": 0}
         # --- recompilation sentinel + per-program MFU attribution
         # (telemetry/compile.py; docs/observability.md). A hub with an
         # ENABLED monitor is shared — serving programs land in the same
@@ -528,6 +542,54 @@ class InferenceEngineV2(InferenceEngine):
         """Call-time refusal for a family with recurrent state."""
         if self._recurrent:
             raise RecurrentStateError(call, why)
+
+    def _refuse_for_index_pool(self) -> None:
+        """Configuration-time refusals for a family with a learned token
+        selection (the disagg block export / import refuse at their call):
+        each feature that names the two pools it knows, or that no test
+        holds over three."""
+        cfg = self.config
+        kq = getattr(cfg, "kv_quant", None)
+        for on, feature, why in (
+                (kq is not None and kq.enabled, "inference.kv_quant",
+                 "the index keys' pool has no quantized mode"),
+                (cfg.prefix_cache.enabled, "inference.prefix_cache",
+                 "a retained prefix's blocks would have to keep their index "
+                 "keys too, and nothing checks that they do"),
+                (getattr(cfg.prefix_cache, "host_spill", False),
+                 "inference.prefix_cache.host_spill",
+                 "it spills and restores prefix-cache blocks"),
+                (cfg.speculative.enabled, "inference.speculative",
+                 "a rejected draft's index keys would have to be rolled "
+                 "back with its keys and values, and nothing checks that "
+                 "they are")):
+            if on:
+                raise IndexPoolError(feature, why)
+
+    def _sparse_args(self, contexts, prefix: str = "") -> Dict[str, int]:
+        """Span arguments of a call's learned selection, ONE layer's: the
+        rows at ``contexts`` (each row's own position + 1), the cached
+        tokens they score and the tokens attention reads
+        (``sparse_ctx_scored``, ``sparse_kv_selected``; the chunk's ride as
+        ``chunk_sparse_*``), counted into ``sparse_stats`` too. None for a
+        family without one."""
+        if not self._indexed:
+            return {}
+        args = self.family.sparse_rows(self.family.cfg, contexts)
+        for name, n in args.items():
+            self.sparse_stats[name[len("sparse_"):]] += n
+        return {prefix + name: n for name, n in args.items()
+                if name != "sparse_rows"}
+
+    def _chunk_contexts(self, ch: _Chunk):
+        return ch.ctx + 1 + np.arange(len(ch.tokens))
+
+    def _refuse_wire(self, call: str) -> None:
+        """The disagg wire carries keys and values, by name."""
+        if self._indexed:
+            raise IndexPoolError(call, "the wire format carries the K and V "
+                                 "pools alone, and a block without its "
+                                 "index keys selects from zeros")
 
     def _jit(self, key, fn, **jit_kwargs):
         """Every paged program routes through the compile monitor's shared
@@ -885,7 +947,8 @@ class InferenceEngineV2(InferenceEngine):
                 parent=rec["span"].span_id if rec else None,
                 table_blocks=self.state.max_blocks_per_seq,
                 **self._chunk_args(ch), **self._moe_args(rows),
-                **self._ssm_args(1, len(ch.tokens))):
+                **self._ssm_args(1, len(ch.tokens)),
+                **self._sparse_args(self._chunk_contexts(ch))):
             with self.tracer.span("engine_prep", cat="serving"):
                 table = self.state.block_table(ch.desc)
                 fn, pre, post = self._chunk_program(ch, (), table, mixed)
@@ -1449,11 +1512,14 @@ class InferenceEngineV2(InferenceEngine):
             n_rows += ch.width
             chunk_args = {"chunk_" + k: v
                           for k, v in self._chunk_args(ch).items()}
+            chunk_args.update(self._sparse_args(self._chunk_contexts(ch),
+                                                "chunk_"))
             self._ssm_args(1, len(ch.tokens))    # ``last_step``'s count
         with self.tracer.span(
                 "decode_step", cat="serving", seq=seq, batch=len(live),
                 overlapped=overlapped, **self._moe_args(n_rows),
                 **self._ssm_args(len(live), len(live)),
+                **self._sparse_args([d.seen_tokens + 1 for d in live]),
                 **chunk_args) as span:
             with self.tracer.span("engine_prep", cat="serving"):
                 self._reserve(live, repeat(1))
@@ -1727,6 +1793,11 @@ class InferenceEngineV2(InferenceEngine):
                "headroom_blocks": st.headroom_blocks,
                "free_slots": st.free_slots,
                "total_blocks": st.allocator.num_blocks - 1}
+        if self._indexed:
+            # a block is a page of every pool, the index keys' among them
+            out["block_bytes"] = sum(
+                leaf.nbytes // leaf.shape[1]
+                for leaf in jax.tree.leaves(self.cache))
         if self._recurrent:
             # a slot is its state row: what a free slot stands for, in bytes
             out.update(state_bytes_per_slot=st.state_slot_bytes,
@@ -1887,6 +1958,7 @@ class InferenceEngineV2(InferenceEngine):
         if wire not in ("native", "int8"):
             raise ValueError(f"unknown KV wire format {wire!r}")
         self._refuse_call("export_kv_blocks", _HANDOFF)
+        self._refuse_wire("export_kv_blocks")
         desc = self.state.lookup(uid)
         self.drain("export")   # the blocks hold every token's KV first
         self.state.mark_filled(desc)
@@ -1940,6 +2012,7 @@ class InferenceEngineV2(InferenceEngine):
         (pool exhausted / retention off) is harmless — resume re-prefills
         that suffix. Returns ``{"imported", "dedup", "dropped"}``."""
         self._refuse_call("import_kv_blocks", _HANDOFF)
+        self._refuse_wire("import_kv_blocks")
         res = {"imported": 0, "dedup": 0, "dropped": 0}
         for h, payload in zip(chain_hashes, blocks):
             if self.state.prefix_cache and h in self.state.index._by_hash:
@@ -2073,6 +2146,19 @@ class InferenceEngineV2(InferenceEngine):
 
     def publish_state_telemetry(self, step: int = 0):
         return self._publish(self.state_events(step))
+
+    def sparse_events(self, step: int = 0):
+        """``Serving/sparse/*`` telemetry events of a family with a learned
+        token selection (none for any other), cumulative and ONE layer's
+        count: ``rows`` that selected, ``ctx_scored``, the cached tokens
+        their indexers scored, ``kv_selected``, the tokens attention read."""
+        if not self._indexed:
+            return []
+        return [(f"Serving/sparse/{k}", float(v), step)
+                for k, v in sorted(self.sparse_stats.items())]
+
+    def publish_sparse_telemetry(self, step: int = 0):
+        return self._publish(self.sparse_events(step))
 
     def engine_events(self, step: int = 0):
         """``Serving/engine/*`` telemetry events (cumulative): ``steps``,
@@ -2296,6 +2382,8 @@ class InferenceEngineV2(InferenceEngine):
             self.publish_kv_quant_telemetry(step_i)
         if self._recurrent and self._hub is not None:
             self.publish_state_telemetry(step_i)
+        if self._indexed and self._hub is not None:
+            self.publish_sparse_telemetry(step_i)
         if self._hub is not None:
             self.publish_engine_telemetry(step_i)
         if self.compile_monitor.enabled and self._hub is not None:

@@ -68,6 +68,17 @@ class RecurrentStateError(NotImplementedError):
             f"state: {why} (docs/serving.md 'Recurrent state')")
 
 
+class IndexPoolError(NotImplementedError):
+    """A serving feature that knows a sequence's cache as TWO pools was asked
+    of a family with a learned token selection, whose cache has a third
+    (the index keys): refused by name instead of serving from a cache one
+    pool short."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(f"{feature} is not available for a family with a "
+                         f"learned token selection: {why}")
+
+
 class BlockedAllocator:
     """Ref-counted free-list allocator over a fixed pool of KV blocks
     (reference ``inference/v2/ragged/blocked_allocator.py``). Block 0 is never

@@ -179,22 +179,27 @@ def scan_layers(body, x, layers, cache, *extras):
     flags) are the scanned inputs. ``body(x, (layer, k_entry, v_entry,
     *extras))`` returns ``(x, (k_entry, v_entry))``, the entries
     :class:`LayerPool` s that it passes through
-    :func:`paged_attention_step`. Returns ``(x, cache)``.
+    :func:`paged_attention_step`. A cache with a THIRD pool, ``kI`` (the
+    index keys of a learned token selection, :func:`sparse_attention_step`),
+    hands its entry over after the other two and takes it back the same
+    way. Returns ``(x, cache)``.
 
     Nothing with a layout preference of its own may touch the pools on the
     way (an XLA scatter on the carry copies the whole ``[L, ...]`` pool a
     layer: PERF.md Findings, PR 29). What the scan itself adds around the
     blocks carries the pool update's name; the blocks' own scopes lie
     inside it."""
+    names = ("k", "v") + (("kI",) if "kI" in cache else ())
+
     def step(carry, scanned):
         x, pools = carry
         layer, index, *rest = scanned
         entries = [LayerPool(pools[n], pools.get(n + "_scale"), index)
-                   for n in ("k", "v")]
-        x, (k_entry, v_entry) = body(x, (layer, *entries, *rest))
-        pools = {"k": k_entry.pool, "v": v_entry.pool}
-        if k_entry.scale is not None:
-            pools.update(k_scale=k_entry.scale, v_scale=v_entry.scale)
+                   for n in names]
+        x, written = body(x, (layer, *entries, *rest))
+        pools = {n: e.pool for n, e in zip(names, written)}
+        if written[0].scale is not None:
+            pools.update(k_scale=written[0].scale, v_scale=written[1].scale)
         return (x, pools), None
 
     num_layers = cache["k"].shape[0]
@@ -319,3 +324,87 @@ def _lane_packed(q, k, v, pack: int):
         return jnp.sum(out.reshape(b, t, nh, pack, hd) * mine, axis=3)
 
     return q, k, v, unpack
+
+
+# --------------------------------------------------------------------------- #
+# learned token selection (``ops/pallas/paged_sparse_attention.py``): a third
+# pool of index keys, and attention over each row's selected tokens alone
+# --------------------------------------------------------------------------- #
+def init_index_pool(num_layers: int, num_blocks: int, block_size: int,
+                    index_head_dim: int, dtype=jnp.bfloat16):
+    """The index keys' pool, ``cache["kI"]``: one key head of
+    ``index_head_dim`` values a token, its blocks the block tables' own, as
+    many tokens a pool row as fill a lane tile (the op's module docstring)."""
+    from ..ops.pallas.paged_sparse_attention import index_pool_shape
+
+    return jnp.zeros(index_pool_shape(num_layers, num_blocks, block_size,
+                                      index_head_dim), dtype)
+
+
+def sparse_attention_step(q, k, v, q_idx, k_idx, w_idx, k_cache, v_cache,
+                          i_cache, block_tables, context_lens, valid, *,
+                          topk: int, scale=None) -> Tuple:
+    """:func:`paged_attention_step` of a layer whose indexer chooses what
+    each row may read. Beside q, k and v (written and laid out as there):
+    ``q_idx [b, t, H, d]`` and ``k_idx [b, t, d]``, the row's roped index
+    queries and index key, ``w_idx [b, t, H]`` the index heads' weights, and
+    ``i_cache`` the index pool's entry. The step writes K, V and the index
+    key, scores every cached token of each row's table (``attn_index``),
+    finds each row's exact ``topk`` threshold (``attn_select``) and attends
+    over the tokens above it. A :class:`MixedCall` splits as it does there:
+    the chunk's rows score and select over the chunk's table, each decode
+    row over its own. Returns ``(out, k_cache, v_cache, i_cache)``."""
+    if isinstance(block_tables, MixedCall):
+        call = block_tables
+        parts = [call.split(a) for a in (q, k, v, q_idx, k_idx, w_idx)]
+        chunk_rows = (jnp.arange(parts[0][1].shape[1])
+                      < call.chunk_valid)[None]
+        out_c, k_cache, v_cache, i_cache = sparse_attention_step(
+            *(p[1] for p in parts), k_cache, v_cache, i_cache,
+            call.chunk_table[None], call.chunk_ctx[None], chunk_rows,
+            topk=topk, scale=scale)
+        out_d, k_cache, v_cache, i_cache = sparse_attention_step(
+            *(p[0] for p in parts), k_cache, v_cache, i_cache, call.tables,
+            call.lens, call.active[:, None], topk=topk, scale=scale)
+        return call.join(out_d, out_c), k_cache, v_cache, i_cache
+    from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
+    from ..ops.pallas import paged_sparse_attention as sparse  # registers
+    from ..ops.registry import get_op
+
+    b, t, nh, hd = q.shape
+    layer = k_cache.layer
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    with jax.named_scope("kv_write"):
+        k_pool, v_pool, *_ = get_op("paged_kv_write")(
+            k, v, k_cache.pool, v_cache.pool, block_tables, context_lens,
+            n_valid, layer=layer)
+    nkv, bs = k_pool.shape[-3:-1]
+    rows = 8 if t == 1 else sparse.prefill_rows(
+        t, nh, nkv, hd, bs, block_tables.shape[1])
+    with jax.named_scope("attn_index"):
+        i_pool = get_op("paged_index_write")(
+            k_idx, i_cache.pool, block_tables, context_lens, n_valid,
+            layer=layer)
+        idx = get_op("paged_index_scores")(
+            q_idx, w_idx, i_pool, block_tables, context_lens, n_valid,
+            layer=layer, rows=rows)
+    with jax.named_scope("attn_select"):
+        # a padded row's own position is -1: it selects from nothing
+        q_abs = context_lens[:, None] + jnp.arange(rows)[None, :]
+        q_abs = jnp.where(jnp.arange(rows)[None, :] < n_valid[:, None],
+                          q_abs, -1)
+        width = 1 if t == 1 else rows
+        tau, cut = get_op("paged_sparse_select")(
+            idx[:, :width].reshape(b * width, -1),
+            q_abs[:, :width].reshape(-1), topk=topk)
+        tau, cut = tau.reshape(b, width), cut.reshape(b, width)
+    if t == 1:
+        out = get_op("paged_sparse_decode_attention")(
+            q[:, 0], k_pool, v_pool, idx, tau[:, 0], cut[:, 0], block_tables,
+            context_lens, scale=scale, layer=layer)[:, None]
+    else:
+        out = get_op("paged_sparse_prefill_attention")(
+            q, k_pool, v_pool, idx, tau, cut, block_tables, context_lens,
+            n_valid, scale=scale, layer=layer)
+    return (out, LayerPool(k_pool, None, layer), LayerPool(v_pool, None, layer),
+            LayerPool(i_pool, None, layer))

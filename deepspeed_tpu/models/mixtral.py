@@ -16,13 +16,15 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..moe.layer import MoELayer, init_moe_ffn, moe_ffn_logical_axes
 from ..moe.sharded_moe import compute_capacity
 from ..ops.attention import attention
-from ._paged import paged_attention_step, row_positions, scan_layers
+from ._paged import (init_index_pool, paged_attention_step, row_positions,
+                     scan_layers, sparse_attention_step)
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -35,6 +37,18 @@ Params = Dict[str, Any]
 # remat saveables; the MoE expert matmuls stay unnamed — their dispatch
 # layout is the compact/einsum implementation's concern)
 CHECKPOINT_NAMES_EMITTED = ("qkv_proj", "attn_mix", "attn_out", "mlp_out")
+
+
+@dataclass(frozen=True)
+class SparseAttention:
+    """A learned token selection inside attention (DeepSeek-Sparse-Attention's
+    indexer on grouped-query attention): ``index_heads`` index queries of
+    ``index_head_dim`` a token score every cached token's ONE index key, and
+    attention reads the ``topk`` best-scoring tokens alone
+    (``ops/pallas/paged_sparse_attention.py`` has the equations)."""
+    index_heads: int
+    index_head_dim: int
+    topk: int
 
 
 @dataclass(frozen=True)
@@ -68,10 +82,23 @@ class MixtralConfig:
     # MoE dispatch implementation: 'einsum' (dense one-hot, MXU) or
     # 'compact' (index-table gather/scatter) — see moe/layer.py
     moe_dispatch: str = "einsum"
+    # a head's width where it is not ``hidden_size / num_heads`` (None: it is)
+    head_dim: Optional[int] = None
+    # an RMSNorm over each HEAD of q and k, before rope (models/llama.py's
+    # ``qk_norm``; ``qk_proj_norm`` above is the other kind)
+    qk_norm: bool = False
+    # a learned token selection inside attention (None: attention reads the
+    # whole context)
+    sparse_attention: Optional[SparseAttention] = None
+    # one chip's share of an expert-parallel deployment: ``(first, count)``,
+    # the experts this program HOLDS of the ``num_experts`` the router
+    # chooses among (None: all of them). What the absent experts would add
+    # is left out (moe/layer.py)
+    experts_held: Optional[Tuple[int, int]] = None
 
     @property
     def head_size(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_dim or self.hidden_size // self.num_heads
 
     @classmethod
     def tiny(cls, **kw) -> "MixtralConfig":
@@ -91,7 +118,12 @@ def init(cfg: MixtralConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
 
     def one_moe(k):
-        p = init_moe_ffn(k, cfg.num_experts, h, cfg.intermediate_size, dtype)
+        if cfg.experts_held is None:
+            p = init_moe_ffn(k, cfg.num_experts, h, cfg.intermediate_size,
+                             dtype)
+        else:
+            p = init_moe_ffn(k, cfg.experts_held[1], h, cfg.intermediate_size,
+                             dtype, routed=cfg.num_experts)
         si = cfg.shared_expert_intermediate_size
         if si:
             ks = jax.random.split(jax.random.fold_in(k, 7), 4)
@@ -125,6 +157,19 @@ def init(cfg: MixtralConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     if cfg.qk_proj_norm:
         out["layers"]["q_norm"] = jnp.ones((L, nh * hd), dtype)
         out["layers"]["k_norm"] = jnp.ones((L, nkv * hd), dtype)
+    if cfg.qk_norm:
+        if cfg.qk_proj_norm:
+            raise ValueError("qk_norm and qk_proj_norm are two norms of the "
+                             "same projections: a model has one")
+        out["layers"]["q_norm"] = jnp.ones((L, hd), dtype)
+        out["layers"]["k_norm"] = jnp.ones((L, hd), dtype)
+    if cfg.sparse_attention is not None:
+        sa = cfg.sparse_attention
+        ki = jax.random.split(keys[7], 3)
+        out["layers"]["wq_idx"] = normal(
+            ki[0], (L, h, sa.index_heads * sa.index_head_dim), h)
+        out["layers"]["wk_idx"] = normal(ki[1], (L, h, sa.index_head_dim), h)
+        out["layers"]["ww_idx"] = normal(ki[2], (L, h, sa.index_heads), h)
     return out
 
 
@@ -156,6 +201,13 @@ def param_logical_axes(cfg: MixtralConfig) -> Params:
     if cfg.qk_proj_norm:
         axes["layers"]["q_norm"] = ("layers", "heads")
         axes["layers"]["k_norm"] = ("layers", "kv_heads")
+    if cfg.qk_norm:
+        axes["layers"]["q_norm"] = ("layers", None)
+        axes["layers"]["k_norm"] = ("layers", None)
+    if cfg.sparse_attention is not None:
+        # the indexer is whole on every chip, as its one key head must be
+        for name in ("wq_idx", "wk_idx", "ww_idx"):
+            axes["layers"][name] = ("layers", "embed", None)
     return axes
 
 
@@ -172,14 +224,65 @@ def _qkv(cfg, layer, y, cos, sin, positions=None, save=False):
     q, k, v = y @ layer["wq"], y @ layer["wk"], y @ layer["wv"]
     if "bq" in layer:
         q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
-    if "q_norm" in layer:
+    if "q_norm" in layer and not cfg.qk_norm:
         q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
     if save:
         q, k, v = (checkpoint_name(a, "qkv_proj") for a in (q, k, v))
+    if cfg.qk_norm:     # each head by itself
+        q = rms_norm(q.reshape(b, t, nh, hd), layer["q_norm"],
+                     cfg.rms_norm_eps)
+        k = rms_norm(k.reshape(b, t, nkv, hd), layer["k_norm"],
+                     cfg.rms_norm_eps)
     q = apply_rotary(q.reshape(b, t, nh, hd), cos, sin, positions)
     k = apply_rotary(k.reshape(b, t, nkv, hd), cos, sin, positions)
     return q, k, v.reshape(b, t, nkv, hd)
+
+
+def index_vectors(cfg, layer, y, cos_i, sin_i, positions=None):
+    """The indexer's view of one block's normed input ``y [b, t, h]``: index
+    queries ``[b, t, H, d]`` and the ONE index key ``[b, t, d]``, both roped
+    over all ``d`` dims at the model's theta, and the index heads' weights
+    ``[b, t, H]``."""
+    sa = cfg.sparse_attention
+    b, t, _ = y.shape
+    q_idx = apply_rotary(
+        (y @ layer["wq_idx"]).reshape(b, t, sa.index_heads,
+                                      sa.index_head_dim),
+        cos_i, sin_i, positions)
+    k_idx = apply_rotary((y @ layer["wk_idx"])[:, :, None], cos_i, sin_i,
+                         positions)[:, :, 0]
+    return q_idx, k_idx, y @ layer["ww_idx"]
+
+
+def _selected_mask(cfg, q_idx, k_idx, w_idx, k_live):
+    """``[b, t, S]``: which of the ``S`` keys each query row may read -
+    the ``topk`` of largest index score among those ``k_live [b, t, S]``
+    marks as the row's own (equal scores: the lower position), all of them
+    while they are fewer. The dense form of what the paged path computes a
+    block table at a time, for ``apply`` and ``apply_cached``."""
+    from ..ops.pallas.paged_sparse_attention import (
+        index_scores_dense, paged_sparse_select_xla, selected)
+
+    s = index_scores_dense(q_idx, k_idx, w_idx)
+    b, t, S = s.shape
+    # the row's own keys are a prefix of the positions: its last one
+    last = jnp.sum(k_live, axis=-1, dtype=jnp.int32) - 1
+    tau, cut = paged_sparse_select_xla(
+        s.reshape(b * t, S), last.reshape(-1),
+        topk=cfg.sparse_attention.topk)
+    keep = selected(s, jnp.arange(S)[None, None, :],
+                    tau.reshape(b, t, 1), cut.reshape(b, t, 1))
+    return jnp.logical_and(keep, k_live)
+
+
+def index_rope(cfg):
+    """The indexer's rope tables (its head is narrower than attention's),
+    None without an indexer."""
+    if cfg.sparse_attention is None:
+        return None
+    return rope_frequencies(cfg.sparse_attention.index_head_dim,
+                            cfg.max_seq_len, cfg.rope_theta)
 
 
 def _head_split(cfg, params, x, compute_dtype):
@@ -204,10 +307,11 @@ def apply(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray, *,
     with jax.named_scope("embed"):
         x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
+    rope_idx = index_rope(cfg)
     moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
                          cfg.min_capacity, cfg.drop_tokens,
                          norm_topk=cfg.norm_topk_prob,
-                         dispatch=cfg.moe_dispatch)
+                         dispatch=cfg.moe_dispatch, held=cfg.experts_held)
 
     layers = jax.tree.map(lambda p: p.astype(compute_dtype)
                           if jnp.issubdtype(p.dtype, jnp.floating) else p,
@@ -225,8 +329,16 @@ def apply(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray, *,
             # K/V pass NARROW (nkv heads) into the attention op: widening —
             # when the gqa_native kernels are off — happens inside the op,
             # never here (the gqa-native lint traces this apply)
+            if cfg.sparse_attention is None:
+                mix = attention(q, k, v, causal=True)
+            else:
+                causal = jnp.broadcast_to(jnp.tril(jnp.ones((s, s), bool)),
+                                          (b, s, s))
+                mix = attention(q, k, v, causal=False, mask=_selected_mask(
+                    cfg, *index_vectors(cfg, layer, y, *rope_idx),
+                    causal)[:, None])
             x = x + checkpoint_name(
-                checkpoint_name(attention(q, k, v, causal=True), "attn_mix")
+                checkpoint_name(mix, "attn_mix")
                 .reshape(b, s, nh * hd) @ layer["wo"], "attn_out")
         with jax.named_scope("norm"):
             y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
@@ -264,12 +376,19 @@ def init_cache(cfg: MixtralConfig, batch_size: int, max_len: int,
                dtype=jnp.bfloat16) -> Params:
     shape = (cfg.num_layers, batch_size, max_len, cfg.num_kv_heads,
              cfg.head_size)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if cfg.sparse_attention is not None:
+        cache["kI"] = jnp.zeros(
+            shape[:3] + (1, cfg.sparse_attention.index_head_dim), dtype)
+    return cache
 
 
 def cache_logical_axes(cfg: MixtralConfig) -> Params:
     spec = ("layers", None, None, "kv_heads", None)
-    return {"k": spec, "v": spec}
+    axes = {"k": spec, "v": spec}
+    if cfg.sparse_attention is not None:
+        axes["kI"] = ("layers", None, None, None, None)
+    return axes
 
 
 def apply_cached(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
@@ -283,19 +402,21 @@ def apply_cached(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     nh, hd = cfg.num_heads, cfg.head_size
     x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
+    rope_idx = index_rope(cfg)
+    sparse = cfg.sparse_attention is not None
     positions = cache_len[:, None] + jnp.arange(t)[None, :]
     # inference never drops tokens: a dropped decode token would silently
     # corrupt the completion (reference v2 mixtral routes without capacity)
     moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
                          cfg.min_capacity, drop_tokens=False,
                          norm_topk=cfg.norm_topk_prob,
-                         dispatch=cfg.moe_dispatch)
+                         dispatch=cfg.moe_dispatch, held=cfg.experts_held)
     layers = jax.tree.map(lambda p: p.astype(compute_dtype)
                           if jnp.issubdtype(p.dtype, jnp.floating) else p,
                           params["layers"])
 
     def scan_body(x, scanned):
-        layer, k_c, v_c = scanned
+        layer, k_c, v_c, *i_c = scanned
         y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(cfg, layer, y, cos, sin, positions)
         k_c = llama_mod._write_cache(k_c, k, cache_len)
@@ -303,16 +424,25 @@ def apply_cached(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
         S = k_c.shape[1]
         kv_pos = jnp.arange(S)[None, None, None, :]
         q_abs = positions[:, None, :, None]
-        attn = attention(q, k_c, v_c, causal=False, mask=kv_pos <= q_abs)
+        mask = kv_pos <= q_abs
+        if sparse:      # the index keys are cached like K: one head of them
+            q_idx, k_idx, w_idx = index_vectors(cfg, layer, y, *rope_idx,
+                                                 positions)
+            i_c = [llama_mod._write_cache(i_c[0], k_idx[:, :, None],
+                                          cache_len)]
+            mask = _selected_mask(cfg, q_idx, i_c[0][:, :, 0], w_idx,
+                                  mask[:, 0])[:, None]
+        attn = attention(q, k_c, v_c, causal=False, mask=mask)
         x = x + attn.reshape(b, t, nh * hd) @ layer["wo"]
         y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
         ffn_out, _aux = moe_layer(layer["moe"], y)
-        return x + ffn_out, (k_c, v_c)
+        return x + ffn_out, (k_c, v_c, *i_c)
 
-    x, (nk, nv) = lax.scan(scan_body, x, (layers, cache["k"], cache["v"]))
+    names = ("k", "v") + (("kI",) if sparse else ())
+    x, new = lax.scan(scan_body, x, (layers, *(cache[n] for n in names)))
     x = rms_norm(x, params["final_norm"].astype(compute_dtype), cfg.rms_norm_eps)
     logits = x @ params["lm_head"].astype(compute_dtype)
-    return logits.astype(jnp.float32), {"k": nk, "v": nv}
+    return logits.astype(jnp.float32), dict(zip(names, new))
 
 
 def loss_fn(cfg: MixtralConfig, params: Params, batch: Dict[str, jnp.ndarray], *,
@@ -372,9 +502,34 @@ def model_spec(cfg: MixtralConfig, compute_dtype=jnp.bfloat16):
 def init_paged_cache(cfg: MixtralConfig, num_blocks: int, block_size: int,
                      dtype=jnp.bfloat16,
                      kv_quant_group: Optional[int] = None) -> Params:
-    return _init_paged_pools(cfg.num_layers, num_blocks, cfg.num_kv_heads,
-                             block_size, cfg.head_size, dtype,
-                             kv_quant_group)
+    cache = _init_paged_pools(cfg.num_layers, num_blocks, cfg.num_kv_heads,
+                              block_size, cfg.head_size, dtype,
+                              kv_quant_group)
+    if cfg.sparse_attention is not None:
+        if kv_quant_group is not None:
+            from ..inference.ragged import IndexPoolError
+
+            raise IndexPoolError(
+                "inference.kv_quant", "the index keys' pool has no quantized "
+                "mode, and the selection reads scores of bf16 keys")
+        cache["kI"] = init_index_pool(
+            cfg.num_layers, num_blocks, block_size,
+            cfg.sparse_attention.index_head_dim, dtype)
+    return cache
+
+
+def sparse_rows(cfg: MixtralConfig, contexts) -> Dict[str, int]:
+    """What ONE layer's selection of a serving call does, from lengths alone
+    (the engine puts it on the call's span): ``contexts``, each row's own
+    position + 1 - the cached tokens its indexer scores; of them attention
+    reads ``min(context, topk)``. Empty without an indexer."""
+    if cfg.sparse_attention is None:
+        return {}
+    contexts = np.asarray(contexts, np.int64)
+    return {"sparse_rows": int(contexts.size),
+            "sparse_ctx_scored": int(contexts.sum()),
+            "sparse_kv_selected": int(np.minimum(
+                contexts, cfg.sparse_attention.topk).sum())}
 
 
 def moe_rows(cfg: MixtralConfig, rows: int) -> Dict[str, int]:
@@ -387,6 +542,12 @@ def moe_rows(cfg: MixtralConfig, rows: int) -> Dict[str, int]:
     capacity = max(compute_capacity(rows, cfg.num_experts, cfg.top_k,
                                     cfg.capacity_factor, cfg.min_capacity),
                    rows)
+    if cfg.experts_held is not None:
+        # one chip's share: the rows a uniform router sends to the HELD
+        # experts, and the slabs of the experts this bank holds
+        held = cfg.experts_held[1]
+        return {"moe_rows_routed": rows * cfg.top_k * held // cfg.num_experts,
+                "moe_rows_computed": held * capacity}
     return {"moe_rows_routed": rows * cfg.top_k,
             "moe_rows_computed": cfg.num_experts * capacity}
 
@@ -410,29 +571,38 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     with jax.named_scope("embed"):
         x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
+    rope_idx = index_rope(cfg)
     positions = row_positions(block_tables, context_lens, t)
     moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
                          cfg.min_capacity, drop_tokens=False,
                          norm_topk=cfg.norm_topk_prob,
-                         dispatch=cfg.moe_dispatch)
+                         dispatch=cfg.moe_dispatch, held=cfg.experts_held)
     layers = jax.tree.map(lambda p: p.astype(compute_dtype)
                           if jnp.issubdtype(p.dtype, jnp.floating) else p,
                           params["layers"])
 
     def scan_body(x, scanned):
-        layer, k_c, v_c = scanned
+        layer, k_c, v_c, *i_c = scanned
         with jax.named_scope("norm"):
             y = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
         with jax.named_scope("attn"):   # the pool update inside is "kv_write"
             q, k, v = _qkv(cfg, layer, y, cos, sin, positions)
-            attn, k_c, v_c = paged_attention_step(
-                q, k, v, k_c, v_c, block_tables, context_lens, positions,
-                valid)
+            if i_c:     # a learned selection: the index keys' pool is there
+                with jax.named_scope("attn_index"):
+                    index = index_vectors(cfg, layer, y, *rope_idx,
+                                           positions)
+                attn, k_c, v_c, *i_c = sparse_attention_step(
+                    q, k, v, *index, k_c, v_c, i_c[0], block_tables,
+                    context_lens, valid, topk=cfg.sparse_attention.topk)
+            else:
+                attn, k_c, v_c = paged_attention_step(
+                    q, k, v, k_c, v_c, block_tables, context_lens, positions,
+                    valid)
             x = x + attn.reshape(b, t, nh * hd) @ layer["wo"]
         with jax.named_scope("norm"):
             y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
         ffn_out, _aux = moe_layer(layer["moe"], y)
-        return x + ffn_out, (k_c, v_c)
+        return x + ffn_out, (k_c, v_c, *i_c)
 
     x, cache = scan_layers(scan_body, x, layers, cache)
     with jax.named_scope("norm"):

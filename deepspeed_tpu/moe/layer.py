@@ -11,7 +11,7 @@ their gradients reduce only within their replica group automatically.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,15 +25,17 @@ Params = Dict[str, Any]
 
 
 def init_moe_ffn(rng: jax.Array, n_experts: int, hidden: int, intermediate: int,
-                 dtype=jnp.float32) -> Params:
-    """Expert SwiGLU FFN bank [E, ...] + router [H, E]."""
+                 dtype=jnp.float32, routed: Optional[int] = None) -> Params:
+    """Expert SwiGLU FFN bank [E, ...] + router [H, E]. ``routed``: the
+    router's width where the bank holds only ``n_experts`` of the experts
+    it chooses among (one chip's share: :class:`MoELayer` ``held``)."""
     ks = jax.random.split(rng, 4)
 
     def normal(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
 
     return {
-        "router": normal(ks[0], (hidden, n_experts), hidden),
+        "router": normal(ks[0], (hidden, routed or n_experts), hidden),
         "w_gate": normal(ks[1], (n_experts, hidden, intermediate), hidden),
         "w_up": normal(ks[2], (n_experts, hidden, intermediate), hidden),
         "w_down": normal(ks[3], (n_experts, intermediate, hidden), intermediate),
@@ -63,12 +65,22 @@ class MoELayer:
 
     Returns (output, aux_loss). Use inside a transformer block in place of the
     dense FFN; add ``aux_loss_coef * aux_loss`` to the training loss.
+
+    ``held = (first, count)``: one chip's share of an expert-parallel
+    deployment. The router runs over all ``n_experts`` at its published
+    width and the gates are what the whole layer would give; the bank
+    (``params["w_*"]``: ``count`` experts) holds experts ``first ..
+    first + count - 1``, the dispatch and combine masks are cut to them
+    before the einsums, and the output is THEIR part of the layer's sum.
+    What the absent experts would add is left out: nothing stands in for the
+    other chips or for their exchange. ``None``: the bank holds them all.
     """
 
     def __init__(self, n_experts: int, top_k: int = 2,
                  capacity_factor: float = 1.25, min_capacity: int = 4,
                  drop_tokens: bool = True, norm_topk: bool = True,
-                 dispatch: str = "einsum"):
+                 dispatch: str = "einsum",
+                 held: Optional[Tuple[int, int]] = None):
         self.n_experts = n_experts
         self.top_k = top_k
         self.capacity_factor = capacity_factor
@@ -84,6 +96,16 @@ class MoELayer:
         # kernel computes — reference inference/v2/kernels/ragged_ops).
         # scripts/moe_dispatch_bench.py measures which wins per backend.
         self.dispatch = dispatch
+        if held is not None:
+            first, count = held
+            if dispatch != "einsum":
+                raise ValueError("a held range of experts is the einsum "
+                                 "dispatch's: 'compact' has none")
+            if not (0 <= first and count >= 1
+                    and first + count <= n_experts):
+                raise ValueError(f"held experts {held} are not a range of "
+                                 f"the {n_experts} routed")
+        self.held = held
 
     def __call__(self, params: Params, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """x: [batch, seq, hidden] → ([batch, seq, hidden], aux_loss)."""
@@ -123,7 +145,8 @@ class MoELayer:
                     [tokens, jnp.zeros((1, h), tokens.dtype)])
                 expert_in = toks_z[token_for]                         # gather
             else:
-                gating: GatingOutput = top_k_gating(logits, self.top_k, **gate_kw)
+                gating: GatingOutput = top_k_gating(
+                    logits, self.top_k, held=self.held, **gate_kw)
                 aux_loss = gating.aux_loss
                 expert_in = jnp.einsum(
                     "tec,th->ech", gating.dispatch_mask.astype(tokens.dtype),

@@ -108,20 +108,29 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
 def top_k_gating(logits: jnp.ndarray, k: int = 1, *,
                  capacity_factor: float = 1.0, min_capacity: int = 4,
                  drop_tokens: bool = True,
-                 norm_topk: bool = True) -> GatingOutput:
+                 norm_topk: bool = True,
+                 held: Optional[Tuple[int, int]] = None) -> GatingOutput:
     """Dense [T, E, C] view of :func:`top_k_gating_compact` — the form the
-    einsum dispatch contracts with (MXU-friendly, but O(T·E·C) memory)."""
+    einsum dispatch contracts with (MXU-friendly, but O(T·E·C) memory).
+    ``held = (first, count)``: the masks of experts ``first .. first + count
+    - 1`` alone, ``[T, count, C]`` - the gating itself (choices, gates, slots,
+    aux loss) is over all the experts either way."""
     cg = top_k_gating_compact(logits, k, capacity_factor=capacity_factor,
                               min_capacity=min_capacity,
                               drop_tokens=drop_tokens, norm_topk=norm_topk)
     tokens, n_experts = logits.shape
+    chosen = cg.topk_idx
+    if held is not None:
+        # an expert outside the range has no column: its one-hot is all zero
+        first, n_experts = held
+        chosen = chosen - first
     combine = jnp.zeros((tokens, n_experts, cg.capacity), jnp.float32)
     for level in range(cg.topk_idx.shape[1]):
         # cg.gates is already keep-masked, and one_hot of an out-of-range
         # position (dropped: pos >= capacity) is all-zero — no extra guards
         combine = combine + (
             cg.gates[:, level][:, None, None]
-            * jax.nn.one_hot(cg.topk_idx[:, level], n_experts,
+            * jax.nn.one_hot(chosen[:, level], n_experts,
                              dtype=jnp.float32)[:, :, None]
             * jax.nn.one_hot(cg.pos[:, level], cg.capacity,
                              dtype=jnp.float32)[:, None, :])

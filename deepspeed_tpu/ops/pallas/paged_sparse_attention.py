@@ -1,0 +1,759 @@
+"""Learned token selection over the paged cache: the kernels of a sparse
+attention whose INDEXER chooses, per query token, the ``topk`` cached tokens
+attention may read (DeepSeek-Sparse-Attention's indexer on grouped-query
+attention; ``models/mixtral.py`` ``SparseAttention``).
+
+Per layer and query token ``t`` (``s <= t`` a cached token):
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])      the index score
+    S_t     = the topk tokens of largest I[t, s]; ties: lower s first
+    attention reads S_t alone
+
+Five calls, each on the layer's index into ``[L, ...]`` pools, like
+``ops/pallas/paged_attention.py``'s three:
+
+``paged_index_write``   the step's index keys into the THIRD pool, in place.
+``paged_index_scores``  ``I`` over a block table -> ``[B, rows, S]`` float32.
+``paged_sparse_select`` the exact threshold of each row: its ``topk``-th
+                        largest score and, among the scores equal to it, the
+                        last position taken. A bisection on the score's bit
+                        pattern, at most 32 counting passes over the row in
+                        VMEM (the tie rule: one more a position bit); no sort.
+``paged_sparse_decode`` / ``paged_sparse_prefill``  the flash walk of
+                        ``paged_attention._paged_kernel`` with one more mask:
+                        a token under its row's threshold is dropped. (The
+                        walk still visits the whole live context - the mask
+                        form; a gather over the selected pages is what the
+                        ``sparse_attn_roofline`` leaves room for.)
+
+The index pool. One key head of ``d`` = 64 values a token is half a lane
+tile, and a ``[.., bs, 64]`` pool has no row-major device layout
+(``models/_paged.lane_pack_of``). So a page holds ``pack = 128 / d`` tokens a
+row: ``[L, num_blocks, 1, bs / pack, pack * d]``, token ``o`` of a block at
+row ``o % (bs / pack)``, lanes ``(o // (bs / pack)) * d ...``: the block's
+first half beside its second. The scores kernel takes a page apart with lane
+masks (rows of a page, zero outside one token's lanes, stacked token-major)
+and contracts over all 128 lanes against the query repeated ``pack`` times,
+so no lane is ever shifted; the same bytes as ``[bs, d]``, in whole tiles.
+
+Scores past a row's own position are never read: the selection masks by
+position, so the scores call skips dead tiles and leaves them unwritten.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._common import dim_semantics as _dim_semantics
+from ._common import interpret as _interpret
+from ._common import mxu_dot as _mxu_dot
+from .paged_attention import (NEG_INF, _MAX_PAGES, _WALK_GRID,
+                              _contract, _decode_tiles, _flash_finish,
+                              _flash_init, _flash_update, _group_rows,
+                              _kv_tile, _layer_scalar, _page_spec,
+                              _prefill_tiles, _write_pages)
+
+KEY_MIN = -2 ** 31          # the sort key of a position that is not the row's
+_SELECT_ROWS = 8            # rows of one selection tile: a sublane tile
+_SELECT_CHUNK = 2048        # score lanes of one counting step
+_SCORE_ROWS = 64            # query tokens of one scores tile, at most
+_INDEX_PAGES = 32           # index-pool pages of one scores step of a decode
+                            # row (a page of one 64-wide key head is 4 KB);
+                            # a call of more rows takes _MAX_PAGES: its
+                            # [heads x rows, KV] scores fill the VMEM sooner
+_DECODE_PAGES = 32          # K / V pages of one step of the two masked walks:
+_PREFILL_PAGES = 32         # 1024 tokens. A step's fixed work (the flash
+                            # rescale of a [rows, 128] accumulator, 64 page
+                            # DMAs' issue) is what a 256-token step mostly
+                            # was: the 512-row walk alone took 3.8 / 2.6 /
+                            # 2.0 ms at 8 / 16 / 32 pages, the decode walk
+                            # 1.87 / 1.72 / 1.67 (scripts/sparse_kernel_
+                            # bench.py on the chip, PR 38)
+
+
+def index_pack(d: int, block_size: int) -> int:
+    """Tokens that share one row of an index-pool page: as many as fill a
+    128-lane tile and divide the block."""
+    lanes = max(1, 128 // d)
+    return max(p for p in range(1, lanes + 1) if block_size % p == 0)
+
+
+def index_pool_shape(num_layers: int, num_blocks: int, block_size: int,
+                     d: int) -> Tuple[int, ...]:
+    pack = index_pack(d, block_size)
+    return (num_layers, num_blocks, 1, block_size // pack, pack * d)
+
+
+def _pack_pages(x, pack: int):
+    """``[.., bs, d]`` index keys as pool pages ``[.., bs / pack, pack * d]``."""
+    bs, d = x.shape[-2:]
+    x = x.reshape(x.shape[:-2] + (pack, bs // pack, d))
+    return jnp.swapaxes(x, -3, -2).reshape(x.shape[:-3]
+                                           + (bs // pack, pack * d))
+
+
+def _unpack_pages(x, pack: int):
+    """The inverse of :func:`_pack_pages`."""
+    rows, width = x.shape[-2:]
+    x = x.reshape(x.shape[:-2] + (rows, pack, width // pack))
+    return jnp.swapaxes(x, -3, -2).reshape(x.shape[:-3]
+                                           + (rows * pack, width // pack))
+
+
+def _gathered_keys(pool, block_tables, layer, d: int):
+    """Dense ``[B, S, d]`` view of the index keys the tables reference (the
+    XLA references' read; the kernels never build it)."""
+    pack = pool.shape[-1] // d
+    g = pool[layer[0], block_tables][:, :, 0]         # [b, mb, rows, pack*d]
+    g = _unpack_pages(g, pack)                        # [b, mb, bs, d]
+    return g.reshape(g.shape[0], -1, d)
+
+
+def score_key(s):
+    """A float32 score's sort key: an int32 that orders as the float does."""
+    bits = jax.lax.bitcast_convert_type(s, jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def index_scores_dense(q_idx, k_idx, w_idx):
+    """``I [.., t, S]`` from index queries ``[.., t, H, d]``, index keys
+    ``[.., S, d]`` and head weights ``[.., t, H]``, all keys at once: the
+    gathered form (the XLA references, ``models/mixtral.py``'s dense paths).
+    -0.0 and 0.0 are one score: one sort key."""
+    s = jnp.einsum("...thd,...sd->...ths", q_idx, k_idx,
+                   preferred_element_type=jnp.float32)
+    s = jnp.sum(jnp.maximum(s, 0.0)
+                * w_idx.astype(jnp.float32)[..., None], axis=-2)
+    return jnp.where(s == 0.0, 0.0, s)
+
+
+def selected(idx, pos, tau, cut):
+    """Whether the token at ``pos`` with index score ``idx`` is one of its
+    row's ``topk``, from the row's threshold (``paged_sparse_select``)."""
+    key = score_key(idx)
+    return jnp.logical_or(key > tau,
+                          jnp.logical_and(key == tau, pos <= cut))
+
+
+# --------------------------------------------------------------------------- #
+# the index keys' write
+# --------------------------------------------------------------------------- #
+def _index_write_kernel(tables, ctx_ref, len_ref, layer, row, page, out, *,
+                        bs, d):
+    """``paged_attention._kv_write_kernel`` on a packed page: slot ``o`` of
+    the page lies at row ``o % rows``, lane segment ``o // rows``."""
+    del tables, layer
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows, width = page.shape[-2:]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (1, rows, width), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, rows, width), 2)
+    seg = sum((lane >= p * d).astype(jnp.int32)
+              for p in range(1, width // d)) if width > d else 0
+    r = j * bs - ctx_ref[b] % bs + sub + rows * seg
+    mine = jnp.logical_and(r >= 0, r < len_ref[b])
+    out[...] = jnp.where(mine, row[...], page[...])
+
+
+def paged_index_write(k_idx, pool, block_tables, context_lens, lengths, *,
+                      layer):
+    """Write a step's index keys ``[B, t, d]`` into layer ``layer`` of the
+    index pool IN PLACE, as ``paged_kv_write`` writes K and V: row ti of
+    sequence b to position ``context_lens[b] + ti`` of its block table, for
+    its first ``lengths[b]`` rows. Returns the pool."""
+    B, t, d = k_idx.shape
+    layer = _layer_scalar(layer, pool)
+    nblocks, rows = pool.shape[1], pool.shape[-2]
+    pack = pool.shape[-1] // d
+    bs = rows * pack
+    max_blocks = block_tables.shape[1]
+    n_pages = _write_pages(t, bs)
+    src = jnp.clip(jnp.arange(n_pages * bs)[None, :]
+                   - (context_lens % bs)[:, None], 0, t - 1)
+    x = jnp.take_along_axis(k_idx.astype(pool.dtype), src[:, :, None], axis=1)
+    x = _pack_pages(x.reshape(B, n_pages, bs, d), pack)[:, :, None]
+
+    def row_map(b, j, *_):
+        return (b, j, 0, 0, 0)
+
+    def page_map(b, j, tables, ctx, lens, layer):
+        pg = ctx[b] // bs + j
+        live = jnp.logical_and(
+            lens[b] > 0, pg <= jnp.minimum((ctx[b] + lens[b] - 1) // bs,
+                                           max_blocks - 1))
+        blk = jnp.where(live, tables[b, jnp.minimum(pg, max_blocks - 1)], 0)
+        return (layer[0], jnp.clip(blk, 0, nblocks - 1), 0, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_index_write_kernel, bs=bs, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B, n_pages),
+            in_specs=[_page_spec(x, 1, row_map),
+                      _page_spec(pool, 1, page_map)],
+            out_specs=_page_spec(pool, 1, page_map)),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={5: 0},    # 4 prefetched scalars, rows, pool
+        compiler_params=_dim_semantics("arbitrary", "arbitrary"),
+        interpret=_interpret(),
+        name="paged_index_write",
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      lengths.astype(jnp.int32), layer, x, pool)
+
+
+def paged_index_write_xla(k_idx, pool, block_tables, context_lens, lengths,
+                          *, layer):
+    """The reference with identical semantics: one scatter on the layer's
+    index, into the packed page's rows and lanes."""
+    B, t, d = k_idx.shape
+    layer = _layer_scalar(layer, pool)
+    nblocks, rows = pool.shape[1], pool.shape[-2]
+    pack = pool.shape[-1] // d
+    bs = rows * pack
+    positions = context_lens[:, None] + jnp.arange(t)[None, :]
+    blk = jnp.take_along_axis(
+        block_tables, jnp.minimum(positions // bs, block_tables.shape[1] - 1),
+        axis=1)
+    blk = jnp.where(jnp.arange(t)[None, :] < lengths[:, None], blk, nblocks)
+    off = positions % bs
+    view = pool.reshape(pool.shape[:4] + (pack, d))
+    view = view.at[layer[0], blk, 0, off % rows, off // rows].set(
+        k_idx.astype(pool.dtype), mode="drop")
+    return view.reshape(pool.shape)
+
+
+# --------------------------------------------------------------------------- #
+# the index scores
+# --------------------------------------------------------------------------- #
+def _pow2_pages(most: int, max_blocks: int) -> int:
+    """Pages of one KV tile: a power of two, so that every call's tiles
+    divide the scores' padded width."""
+    return 1 << (max(1, min(most, max_blocks)).bit_length() - 1)
+
+
+def _score_tiles(rows: int) -> int:
+    """Query tokens of one scores tile: ``rows`` (the call's padded rows)
+    halved until a tile's ``[heads * tq, KV]`` float32 scores are ~2 MB."""
+    tq = rows
+    while tq > _SCORE_ROWS and tq % 16 == 0:
+        tq //= 2
+    return tq
+
+
+def _index_tile(page_refs, d: int):
+    """One KV tile of index keys from its packed pages, token-major
+    ``[pages * bs, 128]``: a token's row is its page row with every other
+    token's lanes zeroed."""
+    out = []
+    for ref in page_refs:
+        page = ref[...]
+        width = page.shape[-1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+        for p in range(width // d):
+            mine = jnp.logical_and(lane >= p * d, lane < (p + 1) * d)
+            out.append(jnp.where(mine, page, jnp.zeros_like(page)))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
+
+
+def _index_scores_kernel(*refs, bs, d, pages, tq, heads):
+    ctx_ref, len_ref = refs[1], refs[2]
+    q_ref, w_ref = refs[4], refs[5]
+    k_refs, o_ref = refs[6:6 + pages], refs[6 + pages]
+    b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kv = pages * bs
+    ctx, n = ctx_ref[b], len_ref[b]
+    q_lo = qi * tq
+    live = jnp.logical_and(q_lo < n,
+                           j * kv < ctx + jnp.minimum(q_lo + tq, n))
+
+    @pl.when(live)
+    def _compute():
+        k = _index_tile(k_refs, d)                      # [kv, 128]
+        s = _mxu_dot(q_ref[...], k, _contract(2, -1),
+                     preferred_element_type=jnp.float32)     # [heads*tq, kv]
+        s = jnp.maximum(s, 0.0) * w_ref[...]
+        if tq == 1:     # one query token: the heads are the tile's rows
+            acc = jnp.sum(s, axis=0, keepdims=True)
+        else:
+            acc = s[:tq]
+            for h in range(1, heads):
+                acc = acc + s[h * tq:(h + 1) * tq]
+        # -0.0 and 0.0 are one score: one sort key
+        o_ref[0:tq, :] = jnp.where(acc == 0.0, 0.0, acc)
+
+
+def paged_index_scores(q_idx, w_idx, pool, block_tables, context_lens,
+                       lengths, *, layer, rows: int = None):
+    """Index scores of a step's rows over their block tables.
+
+    ``q_idx [B, t, H, d]`` (roped), ``w_idx [B, t, H]``; the step's index
+    keys must already be in the pool. Returns ``[B, rows, S]`` float32
+    (``rows``: ``t`` padded to the attention call's tiles, ``S`` the table's
+    width in tokens, padded to whole KV tiles), entry ``[b, ti, s]`` the
+    score of cached token ``s`` for row ti - for ``s`` up to the row's own
+    position ``context_lens[b] + ti`` and a real row; everything else is
+    unspecified (dead tiles are not even written) and the selection never
+    reads it."""
+    B, t, H, d = q_idx.shape
+    layer = _layer_scalar(layer, pool)
+    nblocks, prow = pool.shape[1], pool.shape[-2]
+    pack = pool.shape[-1] // d
+    bs = prow * pack
+    max_blocks = block_tables.shape[1]
+    pages = _pow2_pages(_INDEX_PAGES if t == 1 else _MAX_PAGES, max_blocks)
+    kv = pages * bs
+    n_kv = -(-max_blocks // pages)
+    rows = rows or -(-t // 8) * 8
+    # one query token a sequence (a decode row): its index heads are the
+    # tile's rows, and the result is row 0 of an 8-row block
+    tq = 1 if t == 1 else _score_tiles(rows)
+    n_qt = 1 if t == 1 else rows // tq
+
+    def tiled(x):       # [B, t, H, w] -> [B, n_qt * H * tq, w]: head-major
+        x = jnp.pad(x, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
+        return x.reshape(B, n_qt, tq, H, -1).swapaxes(2, 3) \
+            .reshape(B, n_qt * H * tq, -1)
+
+    q2 = jnp.tile(tiled(q_idx).astype(pool.dtype), (1, 1, pack))
+    w2 = tiled(w_idx[..., None].astype(jnp.float32))
+
+    def qmap(b, qi, j, *_):
+        return (b, qi, 0)
+
+    def page_map(p):
+        def kvmap(b, qi, j, tables, ctx, lens, layer):
+            last = ctx[b] + jnp.minimum(qi * tq + tq, lens[b]) - 1
+            hi_pg = jnp.clip(last // bs, 0, max_blocks - 1)
+            j_eff = jnp.minimum(j, hi_pg // pages)
+            pg = jnp.minimum(j_eff * pages + p, hi_pg)
+            return (layer[0], jnp.clip(tables[b, pg], 0, nblocks - 1), 0,
+                    0, 0)
+        return kvmap
+
+    n_live = jnp.clip(-(-jnp.max(context_lens + lengths) // kv), 1, n_kv)
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, bs=bs, d=d, pages=pages,
+                          tq=tq, heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, n_qt, n_live.astype(jnp.int32)),
+            in_specs=[pl.BlockSpec((None, H * tq, pack * d), qmap),
+                      pl.BlockSpec((None, H * tq, 1), qmap)]
+            + [_page_spec(pool, None, page_map(p)) for p in range(pages)],
+            out_specs=pl.BlockSpec((None, rows // n_qt, kv),
+                                   lambda b, qi, j, *_: (b, qi, j))),
+        out_shape=jax.ShapeDtypeStruct((B, rows, n_kv * kv), jnp.float32),
+        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
+        interpret=_interpret(),
+        name="paged_index_scores",
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      lengths.astype(jnp.int32), layer, q2, w2, *([pool] * pages))
+
+
+def paged_index_scores_xla(q_idx, w_idx, pool, block_tables, context_lens,
+                           lengths, *, layer, rows: int = None):
+    """The reference with identical semantics on every entry the selection
+    reads: gather the table's whole width, one einsum a head."""
+    del context_lens, lengths
+    B, t, H, d = q_idx.shape
+    layer = _layer_scalar(layer, pool)
+    keys = _gathered_keys(pool, block_tables, layer, d)         # [B, S, d]
+    s = index_scores_dense(q_idx.astype(pool.dtype), keys, w_idx)
+    rows = rows or t
+    return jnp.pad(s, ((0, 0), (0, rows - t), (0, 0)))
+
+
+# --------------------------------------------------------------------------- #
+# the selection: each row's exact threshold
+# --------------------------------------------------------------------------- #
+def _select_kernel(hi_ref, s_ref, q_ref, tau_ref, cut_ref, key_scr, *,
+                   topk, chunk, nbits):
+    """Rows ``[tr, S]`` of scores -> each row's ``tau`` and ``cut``:
+    :func:`selected` with them takes the ``topk`` positions ``<= q_abs`` of
+    largest score. The row's sort keys stay in VMEM and every pass counts
+    over the live chunks alone, into a chunk-wide accumulator (lanes add
+    independently; one cross-lane sum a pass).
+
+    The threshold is built bit by bit from the top, in the order-preserving
+    unsigned image of the key (``key ^ KEY_MIN``): a bit is kept where
+    ``topk`` keys still reach the candidate. A row is DONE as soon as
+    exactly ``topk`` keys reach its threshold - the set is then known,
+    whatever the lower bits - or fewer do (a context under ``topk``: its
+    threshold stays at the bottom and takes everything), and the passes end
+    when every row of the tile is. Only a row that runs out of bits with
+    more than ``topk`` keys at its threshold has equal scores there: of
+    those the first by position are taken, and the position bisection runs
+    for that tile alone."""
+    tr = s_ref.shape[0]
+    n_ch = -(-hi_ref[pl.program_id(0)] // chunk)
+    q_abs = q_ref[...]                                       # [tr, 1]
+    key_min = jnp.int32(KEY_MIN)
+
+    def lanes(c):
+        off = pl.multiple_of(c * chunk, chunk)
+        return off, pl.ds(off, chunk)
+
+    def positions(off):
+        return off + jax.lax.broadcasted_iota(jnp.int32, (tr, chunk), 1)
+
+    def fill(c, _):
+        off, at = lanes(c)
+        key_scr[:, at] = jnp.where(positions(off) <= q_abs,
+                                   score_key(s_ref[:, at]), key_min)
+        return 0
+
+    jax.lax.fori_loop(0, n_ch, fill, 0)
+
+    def count(pred, with_pos=False):
+        """Per row, the positions where ``pred`` holds (a float32 count is
+        exact far beyond any table's width)."""
+        def body(c, acc):
+            off, at = lanes(c)
+            key = key_scr[:, at]
+            hit = pred(key, positions(off)) if with_pos else pred(key)
+            return acc + jnp.where(hit, 1.0, 0.0)
+        acc = jax.lax.fori_loop(0, n_ch, body,
+                                jnp.zeros((tr, chunk), jnp.float32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def undecided(reach):
+        return jnp.max(reach) > topk
+
+    def refine(state):
+        bit, t_u, reach = state
+        cand_u = t_u | jax.lax.shift_left(jnp.int32(1), bit)
+        cand = cand_u ^ key_min
+        r = count(lambda key: key >= cand)
+        keep = r >= topk
+        return (bit - 1, jnp.where(keep, cand_u, t_u),
+                jnp.where(keep, r, reach))
+
+    everything = (n_ch * chunk).astype(jnp.float32)     # keys >= KEY_MIN
+    _, t_u, reach = jax.lax.while_loop(
+        lambda st: jnp.logical_and(st[0] >= 0, undecided(st[2])), refine,
+        (jnp.int32(31), jnp.zeros((tr, 1), jnp.int32),
+         jnp.full((tr, 1), everything, jnp.float32)))
+    tau = t_u ^ key_min
+    tau_ref[...] = tau
+    cut_ref[...] = jnp.full((tr, 1), 2 ** 31 - 1, jnp.int32)
+
+    @pl.when(undecided(reach))
+    def _equal_scores():
+        # of the keys equal to tau the first ``need`` by position are taken:
+        # the largest P with fewer than ``need`` of them before it is the
+        # last one taken (a decided row needs them all: P runs to the top)
+        need = topk - count(lambda key: key > tau)
+        cut = jnp.zeros((tr, 1), jnp.int32)
+        for bit in range(nbits - 1, -1, -1):
+            cand = cut | jnp.int32(1 << bit)
+            before = count(lambda key, pos, cand=cand: jnp.logical_and(
+                key == tau, pos < cand), with_pos=True)
+            cut = jnp.where(before < need, cand, cut)
+        cut_ref[...] = jnp.where(reach > topk, cut, 2 ** 31 - 1)
+
+
+def paged_sparse_select(scores, q_abs, *, topk: int):
+    """``scores [N, S]`` float32 and each row's own position ``q_abs [N]``
+    (-1: a padded row, whose result is unspecified) -> ``(tau, cut)`` ``[N]``
+    int32: :func:`selected` with them is true for exactly the
+    ``min(q_abs + 1, topk)`` positions ``<= q_abs`` of largest score, ties to
+    the lower position."""
+    N, S = scores.shape
+    tr = _SELECT_ROWS
+    n_pad = -(-N // tr) * tr
+    width = -(-S // 128) * 128
+    chunk = max(c for c in (128, 256, 512, 1024, _SELECT_CHUNK)
+                if width % c == 0)
+    scores = jnp.pad(scores, ((0, 0), (0, width - S)))
+    S = width
+    scores = jnp.pad(scores, ((0, n_pad - N), (0, 0)))
+    q_abs = jnp.pad(q_abs.astype(jnp.int32), (0, n_pad - N),
+                    constant_values=-1)
+    # positions a tile's rows can reach: the counting loops' bound
+    hi = jnp.clip(jnp.max(q_abs.reshape(-1, tr), axis=1) + 1, 0, S)
+    tau, cut = pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, chunk=chunk,
+                          nbits=max(1, (S - 1).bit_length())),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_pad // tr,),
+            in_specs=[pl.BlockSpec((tr, S), lambda i, *_: (i, 0)),
+                      pl.BlockSpec((tr, 1), lambda i, *_: (i, 0))],
+            out_specs=[pl.BlockSpec((tr, 1), lambda i, *_: (i, 0))] * 2,
+            scratch_shapes=[pltpu.VMEM((tr, S), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.int32)] * 2,
+        compiler_params=_dim_semantics("parallel"),
+        interpret=_interpret(),
+        name="paged_sparse_select",
+    )(hi.astype(jnp.int32), scores, q_abs[:, None])
+    return tau[:N, 0], cut[:N, 0]
+
+
+def paged_sparse_select_xla(scores, q_abs, *, topk: int):
+    """The reference with an identical selection: ``lax.top_k`` (equal
+    values: the lower index first) over the row's own positions."""
+    N, S = scores.shape
+    pos = jnp.arange(S)[None, :]
+    mine = pos <= q_abs[:, None]
+    key = jnp.where(mine, score_key(scores), KEY_MIN)
+    if topk >= S:
+        return (jnp.full((N,), KEY_MIN, jnp.int32),
+                jnp.full((N,), S, jnp.int32))
+    top, at = jax.lax.top_k(key, topk)
+    tau = top[:, -1]
+    cut = jnp.max(jnp.where(top == tau[:, None], at, -1), axis=1)
+    return tau, cut.astype(jnp.int32)
+
+
+# --------------------------------------------------------------------------- #
+# attention over the selected tokens: the flash walk with one more mask
+# --------------------------------------------------------------------------- #
+def _sparse_kernel(*refs, bs, pages, scale, tq, g, decode):
+    """``paged_attention._paged_kernel`` (no window, no int8 pools) that
+    drops the tokens under each row's threshold. ``decode``: one query token
+    a sequence, every KV head a step, the row's threshold two prefetched
+    scalars; else ``g * tq`` rows of one KV head, g-major, the thresholds
+    ``[tq, 1]`` blocks."""
+    ctx_ref, len_ref = refs[1], refs[2]
+    if decode:
+        tau_ref, cut_ref = refs[4], refs[5]
+        refs = refs[6:]
+    else:
+        refs = refs[4:]
+    q_ref, refs = refs[0], refs[1:]
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    idx_ref = refs[2 * pages]
+    if not decode:
+        tau_ref, cut_ref = refs[2 * pages + 1], refs[2 * pages + 2]
+    o_ref, m_scr, l_scr, acc_scr = refs[-4:]
+    b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    kv = pages * bs
+
+    _flash_init(j, m_scr, l_scr, acc_scr)
+    ctx, n = ctx_ref[b], len_ref[b]
+    q_lo = qi * tq
+    live = jnp.logical_and(q_lo < n,
+                           j * kv < ctx + jnp.minimum(q_lo + tq, n))
+
+    @pl.when(live)
+    def _compute():
+        q = q_ref[...]
+        none = (None,) * pages
+        k = _kv_tile(k_refs, none, q.dtype)
+        v = _kv_tile(v_refs, none, q.dtype)
+        s = _mxu_dot(q, k, _contract(q.ndim, -1),
+                     preferred_element_type=jnp.float32) * scale
+        pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
+        # one token's row of the mask - its own positions, of them the
+        # selected - serves its whole query group: computed once a token,
+        # repeated g-major as the rows are
+        if decode:
+            keep = jnp.logical_and(
+                selected(idx_ref[0:1, :], pos, tau_ref[b], cut_ref[b]),
+                pos <= ctx)                                   # [1, kv]
+        else:
+            q_abs = ctx + q_lo + jax.lax.broadcasted_iota(
+                jnp.int32, (tq, 1), 0)
+            keep = jnp.logical_and(
+                selected(idx_ref[...], pos, tau_ref[...], cut_ref[...]),
+                jnp.logical_and(pos <= q_abs, pos < ctx + n))  # [tq, kv]
+        drop = jnp.where(keep, 0.0, NEG_INF)
+        # NEG_INF absorbs any score: a dropped token's is exactly NEG_INF
+        if not decode and g > 1:    # rows are g-major: one mask row a token
+            s = (s.reshape(g, tq, kv) + drop[None]).reshape(g * tq, kv)
+        else:
+            s = s + drop
+        _flash_update(s, v, m_scr, l_scr, acc_scr)
+
+    _flash_finish(j == pl.num_programs(3) - 1, o_ref, l_scr, acc_scr)
+
+
+def _sparse_walk(qg, k_pool, v_pool, idx, tau, cut, block_tables,
+                 context_lens, lengths, layer, *, scale, rows, tq, g, pages,
+                 heads):
+    """The kernel, grid and arguments of one walk over layer ``layer`` of the
+    K and V pools
+    (``paged_attention._table_walk``), the index scores' tile and the rows'
+    thresholds beside each KV tile. The grid's last dimension is DYNAMIC for
+    both calls: the tiles of the longest live context."""
+    B, nkv, _, hd = qg.shape
+    nblocks, bs = k_pool.shape[-4], k_pool.shape[-2]
+    max_blocks = block_tables.shape[1]
+    decode = heads is not None
+    kv = pages * bs
+
+    def qmap(b, h, qi, j, *_):
+        return (b, h, qi, 0)
+
+    def last_tile(b, qi, ctx, lens):
+        last = ctx[b] + jnp.minimum(qi * tq + tq, lens[b]) - 1
+        return jnp.clip(last // bs, 0, max_blocks - 1)
+
+    def page_map(p):
+        def kvmap(b, h, qi, j, tables, ctx, lens, layer, *_):
+            hi_pg = last_tile(b, qi, ctx, lens)
+            j_eff = jnp.minimum(j, hi_pg // pages)
+            pg = jnp.minimum(j_eff * pages + p, hi_pg)
+            return (layer[0], jnp.clip(tables[b, pg], 0, nblocks - 1), h,
+                    0, 0)
+        return kvmap
+
+    def idx_map(b, h, qi, j, tables, ctx, lens, layer, *_):
+        return (b, 0 if decode else qi,
+                jnp.minimum(j, last_tile(b, qi, ctx, lens) // pages))
+
+    in_specs = [pl.BlockSpec((None, heads, rows, hd), qmap)] + [
+        _page_spec(pool, heads, page_map(p))
+        for pool in (k_pool, v_pool) for p in range(pages)] + [
+        pl.BlockSpec((None, idx.shape[1] if decode else tq, kv), idx_map)]
+    operands = [qg] + [pool for pool in (k_pool, v_pool)
+                       for _ in range(pages)] + [idx]
+    prefetch = [block_tables.astype(jnp.int32),
+                context_lens.astype(jnp.int32), lengths.astype(jnp.int32),
+                layer]
+    if decode:
+        prefetch += [tau, cut]
+    else:
+        in_specs += [pl.BlockSpec((None, tq, 1),
+                                  lambda b, h, qi, j, *_: (b, qi, 0))] * 2
+        operands += [tau, cut]
+    n_kv = -(-max_blocks // pages)
+    n_live = jnp.clip(-(-jnp.max(context_lens + lengths) // kv), 1, n_kv)
+    lead = (heads,) if decode else ()
+    kernel = functools.partial(_sparse_kernel, bs=bs, pages=pages,
+                               scale=float(scale), tq=tq, g=g, decode=decode)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, nkv // (heads or 1), qg.shape[2] // rows,
+              n_live.astype(jnp.int32)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, heads, rows, hd), qmap),
+        scratch_shapes=[
+            pltpu.VMEM(lead + (rows, 128), jnp.float32),
+            pltpu.VMEM(lead + (rows, 128), jnp.float32),
+            pltpu.VMEM(lead + (rows, hd), jnp.float32)])
+    return kernel, grid_spec, prefetch + operands
+
+
+def prefill_rows(t: int, nh: int, nkv: int, hd: int, bs: int,
+                 max_blocks: int) -> int:
+    """Rows the scores of a ``t``-token call are padded to: whole query
+    tiles of :func:`paged_sparse_prefill_attention`."""
+    tq, n_qt, _ = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
+    return tq * n_qt
+
+
+def paged_sparse_decode_attention(q, k_pool, v_pool, idx, tau, cut,
+                                  block_tables, context_lens, *,
+                                  scale: float = None, layer=None):
+    """``paged_decode_attention`` over the selected tokens alone. ``idx [B,
+    rows, S]``: the call's index scores, row 0 the query token's; ``tau``,
+    ``cut`` ``[B]``: its threshold. Returns ``[B, nh, hd]``."""
+    B, nh, hd = q.shape
+    layer = _layer_scalar(layer, k_pool, v_pool)
+    nkv, bs = k_pool.shape[-3:-1]
+    g = nh // nkv
+    gpad = _group_rows(g)
+    _, heads, _ = _decode_tiles(nkv, g, hd, bs, block_tables.shape[1],
+                                k_pool.dtype.itemsize, False)
+    pages = _pow2_pages(_DECODE_PAGES, block_tables.shape[1])
+    qg = jnp.pad(q.reshape(B, nkv, g, hd),
+                 ((0, 0), (0, 0), (0, gpad - g), (0, 0)))
+    kernel, grid_spec, args = _sparse_walk(
+        qg, k_pool, v_pool, idx, tau, cut, block_tables, context_lens,
+        jnp.ones((B,), jnp.int32), layer,
+        scale=hd ** -0.5 if scale is None else scale, rows=gpad, tq=1, g=1,
+        pages=pages, heads=heads)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=_WALK_GRID,
+        interpret=_interpret(),
+        name="paged_sparse_decode",
+    )(*args)
+    return out[:, :, :g].reshape(B, nh, hd)
+
+
+def paged_sparse_prefill_attention(q, k_pool, v_pool, idx, tau, cut,
+                                   block_tables, context_lens, lengths, *,
+                                   scale: float = None, layer=None):
+    """``paged_prefill_attention`` over the selected tokens alone. ``idx
+    [B, rows, S]`` with ``rows`` = :func:`prefill_rows`; ``tau``, ``cut``
+    ``[B, rows]``. Returns ``[B, t, nh, hd]``."""
+    B, t, nh, hd = q.shape
+    layer = _layer_scalar(layer, k_pool, v_pool)
+    nkv, bs = k_pool.shape[-3:-1]
+    g = nh // nkv
+    tq, n_qt, _ = _prefill_tiles(t, g, hd, bs, block_tables.shape[1])
+    pages = _pow2_pages(_PREFILL_PAGES, block_tables.shape[1])
+    rows = g * tq
+    assert idx.shape[1] == n_qt * tq, (idx.shape, n_qt, tq)
+    qg = jnp.pad(q, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
+    qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
+        .reshape(B, nkv, n_qt * rows, hd)
+    kernel, grid_spec, args = _sparse_walk(
+        qg, k_pool, v_pool, idx, tau[..., None], cut[..., None],
+        block_tables, context_lens, lengths, layer,
+        scale=hd ** -0.5 if scale is None else scale, rows=rows, tq=tq, g=g,
+        pages=pages, heads=None)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=_WALK_GRID,
+        interpret=_interpret(),
+        name="paged_sparse_prefill",
+    )(*args)
+    return out.reshape(B, nkv, n_qt, g, tq, hd).transpose(0, 2, 4, 1, 3, 5) \
+        .reshape(B, n_qt * tq, nh, hd)[:, :t]
+
+
+def _sparse_attention_xla(q, k_pool, v_pool, idx, tau, cut, block_tables,
+                          context_lens, scale, layer):
+    """Both references: gather the table's whole width, mask by position and
+    by the rows' thresholds, soft-max in float32."""
+    from ..attention import attention_xla
+    from .paged_attention import _gathered_view
+
+    t = q.shape[1]
+    layer = _layer_scalar(layer, k_pool, v_pool)
+    kg = _gathered_view(k_pool, block_tables, layer)
+    vg = _gathered_view(v_pool, block_tables, layer)
+    S = kg.shape[1]
+    pos = jnp.arange(S)[None, None, :]
+    q_abs = (context_lens[:, None] + jnp.arange(t)[None, :])[..., None]
+    keep = selected(idx[:, :t, :S], pos, tau[:, :t, None], cut[:, :t, None])
+    mask = jnp.logical_and(pos <= q_abs, keep)[:, None]     # [B, 1, t, S]
+    return attention_xla(q, kg, vg, causal=False, mask=mask, scale=scale)
+
+
+def paged_sparse_decode_attention_xla(q, k_pool, v_pool, idx, tau, cut,
+                                      block_tables, context_lens, *,
+                                      scale: float = None, layer=None):
+    return _sparse_attention_xla(q[:, None], k_pool, v_pool, idx,
+                                 tau[:, None], cut[:, None], block_tables,
+                                 context_lens, scale, layer)[:, 0]
+
+
+def paged_sparse_prefill_attention_xla(q, k_pool, v_pool, idx, tau, cut,
+                                       block_tables, context_lens, lengths,
+                                       *, scale: float = None, layer=None):
+    del lengths
+    return _sparse_attention_xla(q, k_pool, v_pool, idx, tau, cut,
+                                 block_tables, context_lens, scale, layer)
+
+
+from ..registry import register  # noqa: E402
+
+for _name, _pallas, _xla in (
+        ("paged_index_write", paged_index_write, paged_index_write_xla),
+        ("paged_index_scores", paged_index_scores, paged_index_scores_xla),
+        ("paged_sparse_select", paged_sparse_select, paged_sparse_select_xla),
+        ("paged_sparse_decode_attention", paged_sparse_decode_attention,
+         paged_sparse_decode_attention_xla),
+        ("paged_sparse_prefill_attention", paged_sparse_prefill_attention,
+         paged_sparse_prefill_attention_xla)):
+    register(_name, backend="pallas")(_pallas)
+    register(_name, backend="xla")(_xla)

@@ -87,6 +87,9 @@ SERVING_SERIES = frozenset(
     # recurrent state (a family with state-space layers; docs/serving.md
     # "Recurrent state" - engine_v2.state_events)
     + ["Serving/state/" + m for m in ("bytes", "bytes_per_slot", "slots_held")]
+    # a learned token selection inside attention (docs/serving.md "Learned
+    # token selection" - engine_v2.sparse_events): ONE layer's counts
+    + ["Serving/sparse/" + m for m in ("rows", "ctx_scored", "kv_selected")]
     # what step() ran (engine_v2.engine_events): its calls, those whose
     # prefill chunk rode in the decode program (``decode_chunk``), and those
     # launched while the program before was still unread

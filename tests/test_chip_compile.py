@@ -518,6 +518,49 @@ def _mixed_program(cell_name):
     return forward, args + ((None,) if slot else ())
 
 
+KEYE_CELL = "keye-vl-2.0-30b-a3b.serve-longctx"
+
+
+def test_the_learned_selections_mixed_program_keeps_three_pools_in_place(v5e):
+    """The Keye cell's mixed call (8 decode rows + a 512-row chunk) at its
+    real configuration, compiled for the chip: K, V AND the index keys' pool
+    stay where they are (no pool-shaped copy - the 64-wide index keys lie
+    two tokens a 128-lane row, so no program re-lays them out -, every pool
+    aliased argument-to-result), a layer body is the two segments' writes,
+    scores, thresholds and masked walks, and the whole program with its
+    12 layers, 16 held experts of 128 and 6.5 GB of pools fits the chip."""
+    import math
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = _mixed_program(KEYE_CELL)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cache = args[1]
+    assert set(cache) == {"k", "v", "kI"}
+    assert cache["kI"].shape == (12, 7808, 1, 16, 128)
+    pools = jax.tree.leaves(cache)
+    assert pool_copy_bytes(text, pools) == 0
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert pool_bytes == 7808 * 32 * 12 * 2176
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert 0 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    for name, count in (("paged_kv_write", 2), ("paged_index_write", 2),
+                        ("paged_index_scores", 2), ("paged_sparse_select", 2),
+                        ("paged_sparse_decode", 1),
+                        ("paged_sparse_prefill", 1), ("paged_decode", 0),
+                        ("paged_prefill", 0)):
+        assert calls.count(name) == count, (name, calls.count(name))
+    # the bank holds 16 experts: no matmul over all 128
+    assert not re.findall(r"= bf16\[128,\d+,768\]", text)
+
+
 @pytest.mark.parametrize("cell", SERVE_CELLS + (GRANITE_CELL,))
 def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
     """The four serve cells' mixed call (``slots + 256`` rows) at their real

@@ -1,0 +1,641 @@
+"""Keye-VL-2.0-30B-A3B's language model through ``models/mixtral.py`` (ISSUE
+38) against the benchmark's plain reference (``benchmark/reference/keye.py``)
+at toy widths on the CPU, float32 and seeded: the learned token selection
+(contexts under, at and over a toy ``topk``) along every path - the full
+forward, the dense cache, chunked prefill then decode through the paged
+cache, ``engine_v2.step`` and ``ServingScheduler.tick`` (mixed and
+overlapped) -, the five new ops interpreted against their gathered XLA
+forms, the selected set itself (ties and short contexts included), one
+chip's share of the expert bank tied to the whole layer, and what the engine
+does with a cache of three pools.
+"""
+
+import dataclasses
+import functools
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.families import keye as family
+from benchmark.reference import keye as reference
+from benchmark.reference import keye_variants
+from deepspeed_tpu.inference.engine_v2 import (IndexPoolError,
+                                               build_engine_v2)
+from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
+                                             ServingScheduler)
+from deepspeed_tpu.models import mixtral
+from deepspeed_tpu.models._paged import MixedCall
+from deepspeed_tpu.moe.layer import MoELayer, init_moe_ffn
+from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+TOPK = 8
+TINY = dict(
+    attention_bias=False, decoder_sparse_step=1, head_dim=32,
+    hidden_act="silu", hidden_size=64, intermediate_size=192,
+    max_position_embeddings=128, max_window_layers=2, mlp_only_layers=[],
+    model_type="KeyeVL2", moe_intermediate_size=32, norm_topk_prob=True,
+    num_attention_heads=4, num_experts=8, num_experts_per_tok=4,
+    num_hidden_layers=2, num_key_value_heads=2, num_local_experts=8,
+    rms_norm_eps=1e-6,
+    rope_scaling={"mrope_section": [4, 6, 6], "rope_type": "default",
+                  "type": "default"},
+    rope_theta=10000000,
+    sa_config={"indexer_head_dim": 16, "indexer_num_heads": 4,
+               "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+               "q_chunk_size": 512, "topk": TOPK},
+    sliding_window=None, tie_word_embeddings=False, use_sliding_window=False,
+    vocab_size=256)
+HELD = {**TINY, "num_experts": 2, "experts_first": 4}   # one share of four
+PROMPT, CHUNK, STEPS, BLOCK = 21, 8, 6, 4     # three chunks, the last short
+PATHS = ("apply", "apply_cached", "apply_paged")
+TOL = 1e-4      # float32 on both sides in another order: 1e-5 of unit logits
+
+
+def build(hf=TINY, dtype=jnp.float32):
+    """The configuration, seeded random weights (the norms' too, which
+    ``init`` leaves flat) and a row of tokens, prompt and answer. ``HELD``:
+    the same model with this share's two experts cut out of the bank."""
+    cfg = family.build_cfg(TINY, drop_tokens=False)
+    params = family.init(cfg, jax.random.PRNGKey(0))
+    layers = params["layers"]
+    for i, name in enumerate(("attn_norm", "mlp_norm", "q_norm", "k_norm")):
+        layers[name] = layers[name] * (1.0 + 0.2 * jax.random.normal(
+            jax.random.PRNGKey(10 + i), layers[name].shape))
+    if hf is not TINY:
+        first, count = reference.held_experts(hf)
+        cfg = family.build_cfg(hf, drop_tokens=False)
+        layers["moe"] = {k: v if k == "router" else v[:, first:first + count]
+                         for k, v in layers["moe"].items()}
+    params = jax.tree.map(lambda p: p.astype(dtype), params)
+    row = np.random.default_rng(0).integers(0, 256, PROMPT + STEPS)
+    return cfg, params, row
+
+
+def pieces(row):
+    cuts = list(range(0, PROMPT, CHUNK)) + list(range(PROMPT, len(row)))
+    return [(a, row[a:b]) for a, b in zip(cuts, cuts[1:] + [len(row)])]
+
+
+def program_logits(path, cfg, params, row, dtype=jnp.float32):
+    """Logits ``[len(row), vocab]`` of the program along ``path``
+    (``tests/test_olmoe.py``'s three ways through the model)."""
+    if path == "apply":
+        return mixtral.apply(cfg, params, jnp.asarray(row[None]),
+                             compute_dtype=dtype)[0][0]
+    out = []
+    if path == "apply_cached":
+        cache = mixtral.init_cache(cfg, 1, 32, dtype=dtype)
+        for start, piece in pieces(row):
+            logits, cache = mixtral.apply_cached(
+                cfg, params, jnp.asarray(piece[None]), cache,
+                jnp.asarray([start], jnp.int32), compute_dtype=dtype)
+            out.append(logits[0])
+        return jnp.concatenate(out)
+    cache = mixtral.init_paged_cache(cfg, 16, BLOCK, dtype=dtype)
+    table = jnp.asarray([[3, 1, 7, 2, 9, 4, 5, 0]], jnp.int32)  # 0: trash
+    for start, piece in pieces(row):
+        width = CHUNK if start < PROMPT else 1
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :len(piece)] = piece
+        logits, cache = mixtral.apply_paged(
+            cfg, params, jnp.asarray(padded), cache, table,
+            jnp.asarray([start], jnp.int32),
+            valid=jnp.arange(width)[None] < len(piece), compute_dtype=dtype)
+        out.append(logits[0, :len(piece)])
+    return jnp.concatenate(out)
+
+
+def gap(a, b):
+    return float(jnp.abs(jnp.asarray(a) - jnp.asarray(b)).max())
+
+
+@pytest.fixture(scope="module", params=["whole", "held"])
+def f32(request):
+    hf = TINY if request.param == "whole" else HELD
+    cfg, params, row = build(hf)
+    return hf, cfg, params, row, reference.logits(hf, family.Weights(params),
+                                                  row)
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_program_agrees_with_the_plain_reference_in_float32(f32, path):
+    """Every position of a 27-token row: contexts of 1-8 select everything,
+    9-27 select 8 (``TOPK``), along each path - and with one chip's share of
+    the bank the partial sum is the reference's partial sum."""
+    _, cfg, params, row, want = f32
+    with jax.default_matmul_precision("highest"):
+        got = program_logits(path, cfg, params, row)
+    assert got.shape == want.shape and gap(got, want) < TOL
+
+
+@pytest.mark.parametrize("variant", keye_variants.NAMES)
+def test_each_wrong_variant_fails_the_tolerance(f32, variant):
+    """No selection, the newest 8 in place of the learned 8, the top 4, and
+    index vectors without rope each lie a thousand times beyond what the
+    program, along every path, is held to."""
+    hf, _, params, row, want = f32
+    wrong = keye_variants.logits(variant, hf, family.Weights(params), row)
+    assert gap(wrong, want) > 1000 * TOL
+
+
+# --- what a cell's probes are held to beside their served tokens ------------ #
+ROLE = {"program_options": {"drop_tokens": False}, "weights_dtype": "float32",
+        "engine": {"split_prefill_chunk": CHUNK,
+                   "ragged": {"block_size": BLOCK}},
+        "held": {"why": "float32 on both sides",
+                 "logits_mean_abs_diff": TOL, "selected_share": 1.0}}
+
+
+@pytest.mark.parametrize("name", ("right",) + keye_variants.NAMES)
+def test_the_probes_comparison_passes_the_right_form_alone(f32, name):
+    """``reference.held`` - the program's ``apply_paged`` logits (19 tokens
+    in chunks of 8, then 8 single tokens) and its selected sets at both
+    layers against a reference's - reads inside the limits for the right
+    reference and beyond one for each deliberately wrong one."""
+    hf, _, params, row, want = f32
+    weights = family.Weights(params, role=ROLE)
+    with jax.default_matmul_precision("highest"):
+        got = weights.program.logits(hf, row, reference.HELD_DECODE)
+    assert got.shape == (reference.HELD_DECODE + 1, 256)
+    form = None if name == "right" else keye_variants.form(name, hf)
+    keep = {0: None, 1: None}
+    if form is None:
+        reference.hidden(hf, weights, row, keep=keep)
+    else:
+        want = keye_variants.logits(name, hf, weights, row, keep=keep)
+    with jax.default_matmul_precision("highest"):
+        seen = reference.held(hf, weights, got, want[-got.shape[0]:], keep,
+                              form)
+    why = reference.disagreements(seen, weights.program.limits)
+    assert bool(why) == (name != "right"), (seen, why)
+    assert len(seen["selected"]) == 2
+
+
+def test_a_probe_beyond_a_limit_raises(f32, capsys):
+    """``logits_and_margin`` with the family's weights: the probe's readings
+    are a line of the output, and a program whose indexer is not the
+    reference's (its index queries' matrix negated) raises by name."""
+    hf, _, params, row, want = f32
+    weights = family.Weights(params, role=ROLE)
+    with jax.default_matmul_precision("highest"):
+        got, margin = reference.logits_and_margin(hf, weights, row)
+    assert gap(got, want) == 0 and bool(jnp.isinf(margin).all())
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["phase"] == "held" and line["why_not"] == []
+    assert line["limits"] == {"logits_mean_abs_diff": TOL,
+                              "selected_share": 1.0}
+    layers = dict(params["layers"])
+    layers["wq_idx"] = -layers["wq_idx"]
+    weights.program.params = {**params, "layers": layers}
+    with jax.default_matmul_precision("highest"), \
+            pytest.raises(reference.Disagreement, match="selects"):
+        reference.logits_and_margin(hf, weights, row)
+    # weights that come without their program are the reference's alone
+    bare = family.Weights(params)
+    del bare.program
+    assert gap(reference.logits_and_margin(hf, bare, row)[0], want) == 0
+
+
+@pytest.mark.parametrize("f32", ["whole"], indirect=True)   # one bank is enough
+def test_index_keys_of_a_lower_precision_select_as_many_tokens(f32):
+    """``Program.selected(keys=)``, the precision control of
+    ``tools/keye_check.py``: keys rounded to their own type select what
+    unrounded keys do; rounded to fp8 every row still takes ``topk`` tokens
+    (the selection is exact whatever it is given) and some row takes
+    others."""
+    hf, _, params, row, _ = f32
+    program = family.Weights(params, role=ROLE).program
+    y = reference.hidden(hf, family.Weights(params), row, layers=0)
+    exact = program.selected(hf, 0, y, 16)
+    assert (program.selected(hf, 0, y, 16, keys="float32") == exact).all()
+    below = program.selected(hf, 0, y, 16, keys="float8_e4m3fn")
+    assert (below.sum(1) == exact.sum(1)).all() and (exact.sum(1) == TOPK).all()
+    assert (below != exact).any()
+
+
+@pytest.mark.parametrize("f32", ["whole"], indirect=True)   # one bank is enough
+def test_a_mixed_call_is_its_two_segments(f32):
+    """One chunk's rows beside two decode rows in ONE call of
+    ``apply_paged``: the chunk scores and selects over its own table, each
+    decode row over its own, and every row's logits are the reference's."""
+    hf, cfg, params, row, want = f32
+    rng = np.random.default_rng(5)
+    others = [rng.integers(0, 256, n) for n in (13, 19)]
+    wants = [reference.logits(hf, family.Weights(params), o) for o in others]
+    cache = mixtral.init_paged_cache(cfg, 32, BLOCK, dtype=jnp.float32)
+    tables = np.zeros((4, 8), np.int32)
+    tables[0, :5], tables[1, :5], tables[2, :7] = (np.arange(1, 6),
+                                                   np.arange(6, 11),
+                                                   np.arange(11, 18))
+    with jax.default_matmul_precision("highest"):
+        for i, o in enumerate(others):       # the decode rows' contexts
+            pad = np.zeros((1, 24), np.int32)
+            pad[0, :len(o) - 1] = o[:-1]
+            _, cache = mixtral.apply_paged(
+                cfg, params, jnp.asarray(pad), cache,
+                jnp.asarray(tables[i:i + 1]), jnp.zeros((1,), jnp.int32),
+                valid=jnp.arange(24)[None] < len(o) - 1,
+                compute_dtype=jnp.float32)
+        _, cache = mixtral.apply_paged(      # the chunk's first 16 tokens
+            cfg, params, jnp.asarray(row[None, :16]), cache,
+            jnp.asarray(tables[2:3]), jnp.zeros((1,), jnp.int32),
+            compute_dtype=jnp.float32)
+        call = MixedCall(
+            tables=jnp.asarray(tables), lens=jnp.asarray([12, 18, 0, 0]),
+            active=jnp.asarray([True, True, False, False]),
+            chunk_table=jnp.asarray(tables[2]), chunk_ctx=jnp.asarray(16),
+            chunk_valid=jnp.asarray(5))
+        tokens = np.zeros((1, 4 + 8), np.int32)
+        tokens[0, 0], tokens[0, 1] = others[0][-1], others[1][-1]
+        tokens[0, 4:9] = row[16:21]
+        got, _ = mixtral.apply_paged(cfg, params, jnp.asarray(tokens), cache,
+                                     call, None, valid=call.valid(12),
+                                     compute_dtype=jnp.float32)
+    assert gap(got[0, 0], wants[0][-1]) < TOL
+    assert gap(got[0, 1], wants[1][-1]) < TOL
+    assert gap(got[0, 4:9], want[16:21]) < TOL
+
+
+# --- the selected set ------------------------------------------------------ #
+def selected_sets(select, scores, q_abs, topk):
+    tau, cut = select(jnp.asarray(scores), jnp.asarray(q_abs), topk=topk)
+    pos = np.arange(scores.shape[1])[None]
+    keep = np.asarray(sparse.selected(jnp.asarray(scores), pos, tau[:, None],
+                                      cut[:, None]))
+    return keep & (pos <= np.asarray(q_abs)[:, None])
+
+
+@pytest.mark.parametrize("select", ["xla", "interpreted"])
+@pytest.mark.parametrize("topk", [4, 8, 64])
+def test_the_selected_set_is_the_references(select, topk):
+    """Scores with many exact ties (half-integers, zeros of both signs),
+    rows whose context is under, at and over ``topk``: the threshold form
+    (``tau``, ``cut``) selects exactly what the reference's ``lax.top_k``
+    with its tie rule selects."""
+    rng = np.random.default_rng(topk)
+    scores = np.round(rng.normal(size=(24, 64)) * 2) / 2
+    scores[:, ::7] = -0.0
+    scores = np.where(scores == 0.0, 0.0, scores).astype(np.float32)
+    q_abs = np.concatenate([np.arange(12), rng.integers(12, 64, 12)])
+    fn = sparse.paged_sparse_select_xla if select == "xla" \
+        else sparse.paged_sparse_select
+    got = selected_sets(fn, scores, q_abs.astype(np.int32), topk)
+    want = np.asarray(reference.learned_selection(
+        jnp.asarray(scores), jnp.asarray(q_abs), jnp.arange(64), topk))
+    np.testing.assert_array_equal(got, want)
+    assert (got.sum(1) == np.minimum(q_abs + 1, topk)).all()
+
+
+# --- each new op, interpreted, against its gathered XLA form --------------- #
+@pytest.fixture(scope="module")
+def pools():
+    rng = np.random.default_rng(0)
+    L, nb, bs, d, H, nkv, g, hd = 2, 24, 8, 64, 4, 2, 2, 32
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    tables = jnp.asarray(rng.permutation(np.arange(1, 17)).reshape(2, 8),
+                         jnp.int32)
+    return dict(L=L, bs=bs, d=d, H=H, tables=tables,
+                pool=jnp.zeros(sparse.index_pool_shape(L, nb, bs, d)),
+                keys=f(2, 64, d), q_idx=f(2, 16, H, d), w_idx=f(2, 16, H),
+                k=f(L, nb, nkv, bs, hd), v=f(L, nb, nkv, bs, hd),
+                q=f(2, 16, nkv * g, hd))
+
+
+def written(p, impl):
+    """The index pool after a context of (5, 19) tokens and a step of (16,
+    11) more, written by ``impl``."""
+    ctx, lens = jnp.asarray([5, 19]), jnp.asarray([16, 11])
+    pool = impl(p["keys"][:, :32], p["pool"], p["tables"],
+                jnp.zeros(2, jnp.int32), ctx, layer=1)
+    return impl(p["keys"][:, 32:48], pool, p["tables"], ctx, lens, layer=1), \
+        ctx, lens
+
+
+def test_index_write_interpreted_is_the_scatter(pools):
+    got, _, _ = written(pools, sparse.paged_index_write)
+    want, _, _ = written(pools, sparse.paged_index_write_xla)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(jnp.abs(want[0]).max()) == 0.0    # the other layer: untouched
+    # a page is the block's first half of tokens beside its second
+    keys = sparse._gathered_keys(want, pools["tables"], jnp.asarray([1]), 64)
+    np.testing.assert_array_equal(np.asarray(keys[0, :5]),
+                                  np.asarray(pools["keys"][0, :5]))
+
+
+def live_rows(ctx, lens, rows, width):
+    pos = np.arange(width)[None, None]
+    q_abs = (np.asarray(ctx)[:, None] + np.arange(rows)[None])[..., None]
+    return (pos <= q_abs) & (np.arange(rows)[None, :, None]
+                             < np.asarray(lens)[:, None, None])
+
+
+@pytest.mark.parametrize("t", [16, 1])
+def test_index_scores_interpreted_are_the_gathered_einsum(pools, t):
+    pool, ctx, lens = written(pools, sparse.paged_index_write_xla)
+    rows = 16
+    if t == 1:      # a decode row a sequence: its heads are the tile's rows
+        ctx, lens, rows = jnp.asarray([21, 30]), jnp.ones(2, jnp.int32), 8
+    args = (pools["q_idx"][:, :t], pools["w_idx"][:, :t], pool,
+            pools["tables"], ctx, lens)
+    got = sparse.paged_index_scores(*args, layer=1, rows=rows)
+    want = sparse.paged_index_scores_xla(*args, layer=1, rows=rows)
+    live = live_rows(ctx, lens, rows, 64)
+    assert float(np.abs(np.where(live, got[..., :64] - want, 0)).max()) < 1e-5
+    # the reference's own scores, from the same vectors
+    ref = reference.index_scores(
+        pools["q_idx"][0, :t], sparse._gathered_keys(
+            pool, pools["tables"], jnp.asarray([1]), 64)[0],
+        pools["w_idx"][0, :t])
+    assert float(np.abs(np.where(live[0, :t], ref - want[0, :t],
+                                 0)).max()) < 1e-4
+
+
+@pytest.mark.parametrize("t", [16, 1])
+def test_sparse_attention_interpreted_is_the_gathered_softmax(pools, t):
+    pool, ctx, lens = written(pools, sparse.paged_index_write_xla)
+    if t == 1:
+        ctx, lens, rows = jnp.asarray([21, 30]), jnp.ones(2, jnp.int32), 8
+    else:
+        rows = 16
+    q_idx, w_idx, q = (pools[n][:, :t] for n in ("q_idx", "w_idx", "q"))
+    idx = sparse.paged_index_scores_xla(q_idx, w_idx, pool, pools["tables"],
+                                        ctx, lens, layer=1, rows=rows)
+    q_abs = np.where(np.arange(rows)[None] < np.asarray(lens)[:, None],
+                     np.asarray(ctx)[:, None] + np.arange(rows)[None], -1)
+    width = 1 if t == 1 else rows
+    tau, cut = sparse.paged_sparse_select_xla(
+        idx[:, :width].reshape(2 * width, -1),
+        jnp.asarray(q_abs[:, :width].reshape(-1)), topk=TOPK)
+    tau, cut = tau.reshape(2, width), cut.reshape(2, width)
+    kv = (pools["k"], pools["v"])
+    if t == 1:
+        got = sparse.paged_sparse_decode_attention(
+            q[:, 0], *kv, idx, tau[:, 0], cut[:, 0], pools["tables"], ctx,
+            layer=1)
+        want = sparse.paged_sparse_decode_attention_xla(
+            q[:, 0], *kv, idx, tau[:, 0], cut[:, 0], pools["tables"], ctx,
+            layer=1)
+        assert gap(got, want) < 1e-5
+        return
+    got = sparse.paged_sparse_prefill_attention(
+        q, *kv, idx, tau, cut, pools["tables"], ctx, lens, layer=1)
+    want = sparse.paged_sparse_prefill_attention_xla(
+        q, *kv, idx, tau, cut, pools["tables"], ctx, lens, layer=1)
+    real = (np.arange(16)[None, :] < np.asarray(lens)[:, None])[..., None, None]
+    assert float(np.abs(np.where(real, got - want, 0)).max()) < 1e-5
+
+
+# --- one chip's share of the expert bank ----------------------------------- #
+def test_the_shares_of_the_bank_add_up_to_the_whole_layer():
+    """Four shares of two experts each, attention counted once: the parts of
+    the MoE layer's output that the shares give add up to the uncut layer's,
+    in the program and in the reference."""
+    rng = jax.random.PRNGKey(3)
+    params = init_moe_ffn(rng, 8, 64, 32)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 9, 64))
+    whole, aux = MoELayer(8, 4, drop_tokens=False)(params, x)
+    parts = []
+    for first in (0, 2, 4, 6):
+        share = {k: v if k == "router" else v[first:first + 2]
+                 for k, v in params.items()}
+        out, aux_s = MoELayer(8, 4, drop_tokens=False,
+                              held=(first, 2))(share, x)
+        assert float(aux_s) == float(aux)     # the gating is the whole layer's
+        parts.append(out)
+    assert gap(sum(parts), whole) < 1e-5
+    # the reference: an uncut layer against its four shares' expert sums
+    cfg, params, row = build()
+    weights = family.Weights(params)
+    x = weights.embed[jnp.asarray(row)].astype(jnp.float32)
+    frozen = reference._freeze(TINY)
+    with jax.default_matmul_precision("highest"):
+        whole = reference.layer(x, weights.layer(0), frozen)
+        attn, _, _ = reference._attention_and_route(
+            x, {k: v for k, v in weights.layer(0).items() if k != "experts"},
+            frozen, reference.learned_selection, True)
+        total = attn
+        for first in (0, 2, 4, 6):
+            hf = {**TINY, "num_experts": 2, "experts_first": first}
+            w = dict(weights.layer(0))
+            w["experts"] = w["experts"][first:first + 2]
+            total = total + reference.layer(x, w, reference._freeze(hf)) - attn
+    assert gap(total, whole) < 1e-4
+
+
+def test_a_held_range_of_every_expert_is_the_layer_bit_for_bit():
+    params = init_moe_ffn(jax.random.PRNGKey(3), 8, 64, 32)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 9, 64))
+    whole, _ = MoELayer(8, 4, drop_tokens=False)(params, x)
+    held, _ = MoELayer(8, 4, drop_tokens=False, held=(0, 8))(params, x)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(held))
+    with pytest.raises(ValueError, match="range"):
+        MoELayer(8, 4, held=(6, 4))
+    with pytest.raises(ValueError, match="einsum"):
+        MoELayer(8, 4, dispatch="compact", held=(0, 2))
+
+
+def test_rows_of_a_held_range_are_the_shares():
+    cfg = family.build_cfg(HELD, drop_tokens=False)
+    # 16 rows x 4 experts a token, 2 of 8 experts held: 16 routed rows
+    # expected here; the bank computes 2 slabs of 16
+    assert mixtral.moe_rows(cfg, 16) == {"moe_rows_routed": 16,
+                                         "moe_rows_computed": 32}
+    assert mixtral.sparse_rows(cfg, [3, 8, 20]) == {
+        "sparse_rows": 3, "sparse_ctx_scored": 31, "sparse_kv_selected": 19}
+    assert mixtral.sparse_rows(mixtral.MixtralConfig.tiny(), [3]) == {}
+    shapes = jax.eval_shape(lambda k: mixtral.init(cfg, k),
+                            jax.random.PRNGKey(0))["layers"]
+    assert shapes["moe"]["router"].shape == (2, 64, 8)
+    assert shapes["moe"]["w_gate"].shape == (2, 2, 64, 32)
+    assert shapes["wq"].shape == (2, 64, 4 * 32)      # head_dim is its own
+    assert shapes["q_norm"].shape == (2, 32)
+    assert shapes["wq_idx"].shape == (2, 64, 64) and \
+        shapes["wk_idx"].shape == (2, 64, 16) and \
+        shapes["ww_idx"].shape == (2, 64, 4)
+    axes = mixtral.param_logical_axes(cfg)["layers"]
+    assert set(axes) == set(shapes)
+
+
+# --- the engine over three pools ------------------------------------------- #
+ENGINE = {"dtype": "float32", "prefill_bucket": 8, "split_prefill_chunk": 16,
+          "trace": {"enabled": True},
+          "ragged": {"max_tracked_sequences": 4, "max_ragged_batch_size": 4,
+                     "memory_config_blocks": 64, "block_size": 8}}
+
+
+@pytest.fixture(scope="module")
+def served():
+    cfg, params, _ = build(HELD)
+    return cfg, params
+
+
+def engine(served, **config):
+    """An engine that computes in float32 and whose pools are float32 too
+    (the engine's ``dtype`` is its weights'; ``apply_paged`` computes in bf16
+    and the pools are bf16 unless told otherwise, and a selection over bf16
+    index keys may rightly take another token than the float32 reference's
+    at a threshold - at 8 of 40 tokens one token is a large part of the
+    mix)."""
+    cfg, params = served
+    module = family.module()
+    module.apply_paged = functools.partial(mixtral.apply_paged,
+                                           compute_dtype=jnp.float32)
+    eng = build_engine_v2(module, cfg, params, config={**ENGINE, **config})
+    eng.cache = jax.tree.map(lambda c: c.astype(jnp.float32), eng.cache)
+    return eng
+
+
+def gaps(eng, prompt, out):
+    tokens = np.asarray(list(prompt) + out[:-1], np.int32)
+    want = reference.logits(HELD, family.Weights(eng.params),
+                            tokens)[len(prompt) - 1:]
+    return want.max(-1) - want[np.arange(len(out)), out]
+
+
+def prompts(*lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 256, n).tolist() for n in lengths]
+
+
+def test_step_serves_the_references_tokens(served):
+    """A one-shot prompt and a prompt that enters by chunks beside its
+    decodes (the mixed program), contexts from under ``TOPK`` to five times
+    it: every served token is the top of the reference's logits, and the
+    spans and counters say what one layer's selection did."""
+    eng = engine(served)
+    a, b = prompts(6, 37)
+    first = [int(eng.put(1, a))]
+    eng.put_split(2, b)
+    second = []
+    while len(second) < 5:
+        out = eng.step()
+        first += [out[1]] if 1 in out else []
+        second += [out[2]] if 2 in out else []
+    assert float(gaps(eng, a, first).max()) < 1e-3
+    assert float(gaps(eng, b, second).max()) < 1e-3
+    cache = eng.cache
+    assert set(cache) == {"k", "v", "kI"}
+    assert cache["kI"].shape == (2, 64, 1, 1, 128)   # 8 keys of 16 a row
+    # a block, two layers: K and V (2 heads x 8 tokens x 32) and 8 index
+    # keys of 16, in these pools' float32
+    assert eng.kv_headroom()["block_bytes"] == 2 * (
+        2 * 2 * 8 * 32 * 4 + 8 * 16 * 4)
+    steps = [s for s in eng.tracer.events() if s["name"] == "decode_step"]
+    mixed = [s for s in steps if s["args"].get("chunk_tokens")]
+    assert mixed and all("chunk_sparse_ctx_scored" in s["args"]
+                         for s in mixed)
+    one = mixed[0]["args"]      # b's first chunk: rows at contexts 1..16
+    assert one["chunk_sparse_ctx_scored"] == 16 * 17 // 2
+    assert one["chunk_sparse_kv_selected"] == 36 + 8 * TOPK
+    assert one["sparse_ctx_scored"] == len(a) + 1   # the one decode row
+    events = dict((n, v) for n, v, _ in eng.sparse_events())
+    assert events["Serving/sparse/rows"] > 37
+    assert events["Serving/sparse/kv_selected"] < \
+        events["Serving/sparse/ctx_scored"]
+    from deepspeed_tpu.telemetry.schema import SERVING_SERIES
+    assert set(events) <= SERVING_SERIES
+
+
+def test_generate_lands_the_selections_counts_in_the_hub(served):
+    """``generate`` with a telemetry hub publishes ``Serving/sparse/*`` as
+    it does a recurrent family's ``Serving/state/*``."""
+    class Hub:
+        def __init__(self):
+            self.events = []
+
+        def serving_event(self, name, value, step=0):
+            self.events.append((name, value, step))
+
+    cfg, params = served
+    eng = build_engine_v2(family.module(), cfg, params,
+                          telemetry_hub=(hub := Hub()), config=ENGINE)
+    eng.generate(prompts(12, 20), max_new_tokens=3)
+    landed = {n: v for n, v, _ in hub.events if n.startswith("Serving/sparse")}
+    assert landed == {n: v for n, v, _ in eng.sparse_events()}
+    # the chunked prompt's rows and the decodes' (a one-shot prefill's span
+    # carries none)
+    assert landed["Serving/sparse/rows"] >= 20
+
+
+def test_the_scheduler_serves_the_references_tokens_overlapped(served):
+    """``ServingScheduler.tick``: the launch ahead of the read and the chunk
+    lane, three requests of which two enter by chunks."""
+    eng = engine(served)
+    sched = ServingScheduler(eng, SchedulerConfig(decode_quantum=1,
+                                                  max_admissions_per_tick=1))
+    sent = prompts(9, 40, 27, seed=1)
+    handles = [sched.submit(Request(prompt=p, max_new_tokens=5)) for p in sent]
+    for _ in range(40):
+        sched.tick()
+        if all(h.done for h in handles):
+            break
+    assert all(h.done for h in handles)
+    for p, h in zip(sent, handles):
+        assert float(gaps(eng, p, [int(t) for t in h.tokens]).max()) < 1e-3
+    assert eng.mixed_steps > 0 and eng.overlapped_steps > 0
+
+
+def test_a_fork_carries_the_index_keys(served):
+    """``fork`` shares every block, the partial tail too, and copy-on-write
+    copies a block of EVERY pool: parent and child both continue as the
+    reference does."""
+    eng = engine(served)
+    (p,) = prompts(21, seed=2)
+    out = [int(eng.put(1, p))]
+    out.append(int(eng.step()[1]))
+    eng.fork(1, 2)
+    both = {1: list(out), 2: list(out)}
+    for _ in range(4):
+        for uid, tok in eng.step().items():
+            both[uid].append(int(tok))
+    assert both[1] == both[2]                 # greedy: the same stream
+    assert float(gaps(eng, p, both[2]).max()) < 1e-3
+    eng.state.debug_check()
+
+
+REFUSED_AT_CONFIGURATION = {
+    "prefix_cache": {"prefix_cache": {"enabled": True}},
+    "host_spill": {"prefix_cache": {"enabled": False, "host_spill": True}},
+    "speculative": {"speculative": {"enabled": True}},
+    "kv_quant": {"kv_quant": {"enabled": True}},
+}
+
+
+@pytest.mark.parametrize("feature", sorted(REFUSED_AT_CONFIGURATION))
+def test_what_knows_two_pools_is_refused_at_configuration(served, feature):
+    with pytest.raises(IndexPoolError, match="learned token"):
+        engine(served, **REFUSED_AT_CONFIGURATION[feature])
+
+
+@pytest.mark.parametrize("call", ["export_kv_blocks", "import_kv_blocks"])
+def test_the_disagg_wire_is_refused_at_its_call(served, call):
+    eng = engine(served)
+    eng.put(1, prompts(9)[0])
+    args = {"export_kv_blocks": (1,), "import_kv_blocks": ([], [])}[call]
+    with pytest.raises(IndexPoolError, match=call):
+        getattr(eng, call)(*args)
+    eng.state.debug_check()
+
+
+def test_a_quantized_index_pool_is_refused_by_the_family():
+    cfg = family.build_cfg(HELD, drop_tokens=False)
+    with pytest.raises(IndexPoolError, match="kv_quant"):
+        mixtral.init_paged_cache(cfg, 8, 8, kv_quant_group=32)
+    off = dataclasses.replace(cfg, sparse_attention=None)
+    assert set(mixtral.init_paged_cache(off, 8, 8)) == {"k", "v"}
+
+
+def test_other_families_load_none_of_the_selections_kernels():
+    import subprocess
+    import sys
+
+    code = ("import sys, deepspeed_tpu, deepspeed_tpu.models.mixtral, "
+            "deepspeed_tpu.inference.engine_v2, deepspeed_tpu.ops.pallas\n"
+            "bad = [m for m in sys.modules if 'paged_sparse' in m]\n"
+            "assert not bad, bad")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
+                        "PYTHONPATH": ":".join(sys.path)})

@@ -896,6 +896,7 @@ def _kernel_cases():
     import jax
 
     from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as ps
     from deepspeed_tpu.ops.pallas import ssm
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.norms import (layer_norm_pallas,
@@ -918,6 +919,10 @@ def _kernel_cases():
     x, w = jnp.ones((16, 128), f32), jnp.ones((128,), f32)
     q8, scales = jnp.ones((4, 256), jnp.int8), jnp.ones((4,), f32)
     state, rows = jnp.ones((2, 4, 24, 128), f32), jnp.asarray([0, 2])
+    # a learned token selection's five (ops/pallas/paged_sparse_attention.py,
+    # ISSUE 38): an index pool of 64-wide keys, two tokens a row
+    ipool = jnp.ones(ps.index_pool_shape(1, 8, 16, 64), f32)
+    idx, thr = jnp.ones((2, 16, 64), f32), jnp.zeros((2, 16), jnp.int32)
     return {
         "flash_fwd": (flash, qkv),
         "flash_bwd_dq": (flash_grad, qkv),
@@ -951,6 +956,26 @@ def _kernel_cases():
             lambda p, r, v, bc: ssm.ssm_decode_update(p, 1, r, r == 0, v, v,
                                                       bc, bc)[1],
             [state, rows, jnp.ones((2, 128), f32), jnp.ones((2, 16), f32)]),
+        "paged_index_write": (
+            lambda k, p, bt, n: ps.paged_index_write(k, p, bt, n, n, layer=0),
+            [jnp.ones((2, 3, 64), f32), ipool, tables, lens]),
+        "paged_index_scores": (
+            lambda q, w_, p, bt, n: ps.paged_index_scores(q, w_, p, bt, n, n,
+                                                          layer=0),
+            [jnp.ones((2, 3, 4, 64), f32), jnp.ones((2, 3, 4), f32), ipool,
+             tables, lens]),
+        "paged_sparse_select": (
+            lambda s_, q: ps.paged_sparse_select(s_, q, topk=8)[0],
+            [jnp.ones((8, 128), f32), jnp.arange(8, dtype=jnp.int32)]),
+        "paged_sparse_decode": (
+            lambda q, i, t_, bt, n: ps.paged_sparse_decode_attention(
+                q, pool, pool, i, t_, t_, bt, n),
+            [jnp.ones((2, 4, 32), f32), idx[:, :8], thr[:, 0], tables,
+             lens]),
+        "paged_sparse_prefill": (
+            lambda q, i, t_, bt, n: ps.paged_sparse_prefill_attention(
+                q, pool, pool, i, t_, t_, bt, n, n),
+            [jnp.ones((2, 3, 4, 32), f32), idx, thr, tables, lens]),
     }
 
 
@@ -959,7 +984,9 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "sparse_flash_bwd_dkv", "paged_decode", "paged_prefill",
                 "paged_kv_write", "rms_norm_fwd", "layer_norm_fwd", "quantize_int8",
                 "dequantize_int8", "state_rows_read", "state_rows_write",
-                "ssm_decode_update"]
+                "ssm_decode_update", "paged_index_write", "paged_index_scores",
+                "paged_sparse_select", "paged_sparse_decode",
+                "paged_sparse_prefill"]
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
