@@ -10,6 +10,7 @@ through the scan and is added to the LM loss.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -20,14 +21,15 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ..moe.layer import MoELayer, init_moe_ffn, moe_ffn_logical_axes
-from ..moe.sharded_moe import compute_capacity
+from ..moe.layer import BANK, MoELayer, init_moe_ffn, moe_ffn_logical_axes
+from ..moe.sharded_moe import compute_capacity, row_tile
 from ..ops.attention import attention
 from ._paged import (init_index_pool, paged_attention_step, row_positions,
                      scan_layers, sparse_attention_step)
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
+from ..ops.pallas.grouped_matmul import ROW_SUBTILE
 from ..ops.rotary import apply_rotary, rope_frequencies
 from . import llama as llama_mod
 
@@ -285,6 +287,23 @@ def index_rope(cfg):
                             cfg.max_seq_len, cfg.rope_theta)
 
 
+def _bank_apart(layers, moe_layer):
+    """``(layers, bank)`` of a serving forward. Where its MoE calls take the
+    grouped form: the stacked layers WITHOUT the expert banks, for a layer
+    scan to slice, and the ``[L, E, ...]`` banks whole - the grouped matmul is a Mosaic call, which reads a block
+    of the stack where it lies but would have a scanned slice of it copied
+    out first, a layer's whole bank, every layer (PERF.md Findings, PR 41).
+    Where they build slabs (a program over several devices, ``"compact"``):
+    the layers as they are and no bank apart - an XLA fusion reads its
+    scanned slice in place, and the program is what it was."""
+    if not moe_layer.grouped():
+        return layers, {}
+    moe = layers["moe"]
+    bank = {n: moe[n] for n in BANK}
+    rest = {n: w for n, w in moe.items() if n not in BANK}
+    return {**layers, "moe": rest}, bank
+
+
 def _head_split(cfg, params, x, compute_dtype):
     """Final norm + unembed matrix minus the logits matmul — consumed by
     the tiled fused logits+loss head (``tiled_loss_fn``)."""
@@ -405,15 +424,13 @@ def apply_cached(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     rope_idx = index_rope(cfg)
     sparse = cfg.sparse_attention is not None
     positions = cache_len[:, None] + jnp.arange(t)[None, :]
-    # inference never drops tokens: a dropped decode token would silently
-    # corrupt the completion (reference v2 mixtral routes without capacity)
-    moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
-                         cfg.min_capacity, drop_tokens=False,
-                         norm_topk=cfg.norm_topk_prob,
-                         dispatch=cfg.moe_dispatch, held=cfg.experts_held)
-    layers = jax.tree.map(lambda p: p.astype(compute_dtype)
-                          if jnp.issubdtype(p.dtype, jnp.floating) else p,
-                          params["layers"])
+    moe_layer = _serving_moe(cfg)
+    layers, bank = _bank_apart(jax.tree.map(
+        lambda p: p.astype(compute_dtype)
+        if jnp.issubdtype(p.dtype, jnp.floating) else p, params["layers"]),
+        moe_layer)
+    if bank:    # a grouped call reads the stack at the layer's index
+        layers["index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
 
     def scan_body(x, scanned):
         layer, k_c, v_c, *i_c = scanned
@@ -435,7 +452,8 @@ def apply_cached(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
         attn = attention(q, k_c, v_c, causal=False, mask=mask)
         x = x + attn.reshape(b, t, nh * hd) @ layer["wo"]
         y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-        ffn_out, _aux = moe_layer(layer["moe"], y)
+        ffn_out, _aux = moe_layer({**layer["moe"], **bank}, y,
+                                  layer=layer.get("index"))
         return x + ffn_out, (k_c, v_c, *i_c)
 
     names = ("k", "v") + (("kI",) if sparse else ())
@@ -532,24 +550,58 @@ def sparse_rows(cfg: MixtralConfig, contexts) -> Dict[str, int]:
                 contexts, cfg.sparse_attention.topk).sum())}
 
 
+def _serving_moe(cfg: MixtralConfig) -> MoELayer:
+    """The MoE layer of an inference forward. It never drops a token: a
+    dropped decode token would silently corrupt the completion (reference
+    v2 mixtral routes without capacity)."""
+    return MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
+                    cfg.min_capacity, drop_tokens=False,
+                    norm_topk=cfg.norm_topk_prob, dispatch=cfg.moe_dispatch,
+                    held=cfg.experts_held)
+
+
+@functools.lru_cache(maxsize=None)
+def _expected_tiles(rows: int, share: float, tile: int) -> float:
+    """Row tiles ONE expert's rows take, ``ceil(count / tile)``, in
+    expectation over ``count ~ Binomial(rows, share)``: a uniform router
+    sends each of ``rows`` tokens to the expert with probability ``share``
+    (1 where every expert is every token's: the count is ``rows``)."""
+    if share >= 1.0:
+        return float(-(-rows // tile))
+    pmf, total = (1.0 - share) ** rows, 0.0
+    for count in range(1, rows + 1):
+        pmf *= (rows - count + 1) / count * share / (1.0 - share)
+        total += pmf * -(-count // tile)
+    return total
+
+
 def moe_rows(cfg: MixtralConfig, rows: int) -> Dict[str, int]:
     """What ONE MoE layer of a serving call over ``rows`` token rows does,
     from shapes alone (the engine puts it on the call's span): the rows its
-    router sends to experts, ``rows * top_k``, and the rows its expert bank
-    computes, ``num_experts * capacity`` - serving never drops a token, so
-    the capacity is at least ``rows`` (``sharded_moe.top_k_gating_compact``)
-    and every expert runs over a slab that long."""
-    capacity = max(compute_capacity(rows, cfg.num_experts, cfg.top_k,
-                                    cfg.capacity_factor, cfg.min_capacity),
-                   rows)
-    if cfg.experts_held is not None:
-        # one chip's share: the rows a uniform router sends to the HELD
-        # experts, and the slabs of the experts this bank holds
-        held = cfg.experts_held[1]
-        return {"moe_rows_routed": rows * cfg.top_k * held // cfg.num_experts,
-                "moe_rows_computed": held * capacity}
-    return {"moe_rows_routed": rows * cfg.top_k,
-            "moe_rows_computed": cfg.num_experts * capacity}
+    router sends to experts, ``rows * top_k``; the rows its expert bank
+    computes - the grouped form's row tiles in use, counted in the sub-tiles
+    the kernel works them in (``ROW_SUBTILE`` rows, a whole tile where it is
+    smaller): the routed rows and what pads each expert's rows to whole
+    sub-tiles, in expectation under uniform routing; and ``moe_row_tile``,
+    the tile itself, 0 where the capacity slabs ran (``MoELayer.grouped``):
+    serving never drops a token, so a slab is at least ``rows`` long
+    (``sharded_moe.top_k_gating_compact``) and every expert runs over one.
+    With a held range: the rows a uniform router sends to the HELD experts,
+    and the tiles (slabs) of the experts this bank holds."""
+    held = cfg.num_experts if cfg.experts_held is None else cfg.experts_held[1]
+    routed = rows * cfg.top_k * held // cfg.num_experts
+    if not _serving_moe(cfg).grouped():
+        capacity = max(compute_capacity(rows, cfg.num_experts, cfg.top_k,
+                                        cfg.capacity_factor, cfg.min_capacity),
+                       rows)
+        return {"moe_rows_routed": routed,
+                "moe_rows_computed": held * capacity, "moe_row_tile": 0}
+    tile = row_tile(rows, cfg.num_experts, cfg.top_k, held,
+                    cfg.intermediate_size)
+    sub = min(tile, ROW_SUBTILE)
+    passes = held * _expected_tiles(rows, cfg.top_k / cfg.num_experts, sub)
+    return {"moe_rows_routed": routed,
+            "moe_rows_computed": round(passes * sub), "moe_row_tile": tile}
 
 
 MIXED_PAGED = True      # as llama's: ``apply_paged`` takes a mixed call
@@ -573,13 +625,11 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
     rope_idx = index_rope(cfg)
     positions = row_positions(block_tables, context_lens, t)
-    moe_layer = MoELayer(cfg.num_experts, cfg.top_k, cfg.capacity_factor,
-                         cfg.min_capacity, drop_tokens=False,
-                         norm_topk=cfg.norm_topk_prob,
-                         dispatch=cfg.moe_dispatch, held=cfg.experts_held)
-    layers = jax.tree.map(lambda p: p.astype(compute_dtype)
-                          if jnp.issubdtype(p.dtype, jnp.floating) else p,
-                          params["layers"])
+    moe_layer = _serving_moe(cfg)
+    layers, bank = _bank_apart(jax.tree.map(
+        lambda p: p.astype(compute_dtype)
+        if jnp.issubdtype(p.dtype, jnp.floating) else p, params["layers"]),
+        moe_layer)
 
     def scan_body(x, scanned):
         layer, k_c, v_c, *i_c = scanned
@@ -601,7 +651,8 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
             x = x + attn.reshape(b, t, nh * hd) @ layer["wo"]
         with jax.named_scope("norm"):
             y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-        ffn_out, _aux = moe_layer(layer["moe"], y)
+        ffn_out, _aux = moe_layer({**layer["moe"], **bank}, y,
+                                  layer=k_c.layer if bank else None)
         return x + ffn_out, (k_c, v_c, *i_c)
 
     x, cache = scan_layers(scan_body, x, layers, cache)

@@ -19,9 +19,13 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..comm.mesh import get_mesh
-from .sharded_moe import (GatingOutput, top_k_gating, top_k_gating_compact)
+from ..ops import pallas as _pallas_ops  # noqa: F401 (registers the kernel)
+from ..ops.registry import get_op
+from .sharded_moe import (GatingOutput, row_groups, row_tile, top_k_gating,
+                          top_k_gating_compact)
 
 Params = Dict[str, Any]
+BANK = ("w_gate", "w_up", "w_down")     # the expert bank's leaves, [E, ...]
 
 
 def init_moe_ffn(rng: jax.Array, n_experts: int, hidden: int, intermediate: int,
@@ -66,12 +70,23 @@ class MoELayer:
     Returns (output, aux_loss). Use inside a transformer block in place of the
     dense FFN; add ``aux_loss_coef * aux_loss`` to the training loss.
 
+    Two forms of one function (:meth:`grouped` says which a call takes). A
+    call that may drop tokens builds capacity SLABS, ``[E, C, H]``: a row
+    past its expert's capacity has no slot, and the slabs are what an
+    ``expert`` mesh axis exchanges. A call that may not, on one device and
+    over the layers' STACKED banks (every serving forward of a one-chip
+    engine), runs GROUPED: the routed rows gathered into an expert-major
+    order and through ``moe_grouped_matmul``, which computes the rows the
+    router sent - with no drops a slab is as long as the call, and all but
+    ``k / E`` of the slabs' rows were zeros.
+
     ``held = (first, count)``: one chip's share of an expert-parallel
     deployment. The router runs over all ``n_experts`` at its published
     width and the gates are what the whole layer would give; the bank
     (``params["w_*"]``: ``count`` experts) holds experts ``first ..
-    first + count - 1``, the dispatch and combine masks are cut to them
-    before the einsums, and the output is THEIR part of the layer's sum.
+    first + count - 1``, only rows routed to them are dispatched (the
+    grouped form's groups, the slab form's masks, are the held experts'),
+    and the output is THEIR part of the layer's sum.
     What the absent experts would add is left out: nothing stands in for the
     other chips or for their exchange. ``None``: the bank holds them all.
     """
@@ -107,20 +122,94 @@ class MoELayer:
                                  f"the {n_experts} routed")
         self.held = held
 
-    def __call__(self, params: Params, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """x: [batch, seq, hidden] → ([batch, seq, hidden], aux_loss)."""
+    def grouped(self) -> bool:
+        """Whether a call over the stacked banks takes the grouped form - the
+        rows the router sent, sorted by expert, through a grouped matmul -
+        or the capacity slabs: grouped where nothing may be dropped and the
+        program is one device's. Dropping IS the slab's meaning (a row past
+        its expert's capacity has no slot), an ``expert`` mesh axis exchanges
+        slabs, and the grouped matmul is a per-device kernel
+        (``ops/registry.py``) that Mosaic refuses in a program XLA would
+        have to partition. Which mesh a program spans: the process's mesh
+        (``comm.mesh.get_mesh``: the inference engines install theirs and
+        trace with no mesh context, their operands committed to it) and the
+        mesh of the trace in progress (``MeshManager.activate``)."""
+        traced = jax.sharding.get_abstract_mesh()
+        return (not self.drop_tokens and self.dispatch == "einsum"
+                and get_mesh().world_size == 1
+                and all(n == 1 for n in traced.shape.values()))
+
+    def __call__(self, params: Params, x: jnp.ndarray,
+                 layer=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """x: [batch, seq, hidden] → ([batch, seq, hidden], aux_loss).
+        ``layer``: ``params["w_gate" / "w_up" / "w_down"]`` are the STACKED
+        ``[L, E, ...]`` banks of every layer and this is the one to read
+        (int or traced scalar: the layer scan's index); None: they are one
+        layer's ``[E, ...]`` and the call builds slabs (training: its
+        backward needs a transpose the grouped kernel has not)."""
         b, s, h = x.shape
         tokens = x.reshape(b * s, h)
-        T = tokens.shape[0]
+        bank = [params[n].astype(tokens.dtype) for n in BANK]
         # moe_router: router logits, gating and the dispatch of tokens to
-        # expert slots; moe_experts: the expert bank and the combine
+        # expert rows; moe_experts: the expert bank and the combine
         with jax.named_scope("moe_router"):
             logits = tokens @ params["router"].astype(tokens.dtype)
-            gate_kw = dict(capacity_factor=self.capacity_factor,
-                           min_capacity=self.min_capacity,
-                           drop_tokens=self.drop_tokens,
-                           norm_topk=self.norm_topk)
+        if layer is not None and self.grouped():
+            out, aux_loss = self._grouped(tokens, logits, bank, layer)
+        else:
+            if layer is not None:
+                bank = [w[layer] for w in bank]
+            out, aux_loss = self._slabs(tokens, logits, bank)
+        # Qwen2-MoE shared expert: a dense SwiGLU added to every token,
+        # scaled by a learned sigmoid gate (params present only when used)
+        if "shared_w_gate" in params:
+            with jax.named_scope("moe_experts"):
+                sg = jax.nn.silu(tokens @ params["shared_w_gate"].astype(tokens.dtype))
+                su = tokens @ params["shared_w_up"].astype(tokens.dtype)
+                shared = (sg * su) @ params["shared_w_down"].astype(tokens.dtype)
+                gate = jax.nn.sigmoid(tokens @ params["shared_gate"].astype(tokens.dtype))
+                out = out + gate * shared
+        return out.reshape(b, s, h), aux_loss
 
+    def _gate_kw(self):
+        return dict(capacity_factor=self.capacity_factor,
+                    min_capacity=self.min_capacity,
+                    drop_tokens=self.drop_tokens, norm_topk=self.norm_topk)
+
+    def _grouped(self, tokens, logits, bank, layer):
+        """The no-drop form: O(k·T·H) movement around a bank that computes
+        the routed rows (and what pads each expert's rows to whole tiles)
+        alone; no ``[T, E, C]`` tensor exists."""
+        T, h = tokens.shape
+        with jax.named_scope("moe_router"):
+            cg = top_k_gating_compact(logits, self.top_k, **self._gate_kw())
+            held, inter = bank[0].shape[-3], bank[0].shape[-1]
+            groups = row_groups(
+                cg, row_tile(T, self.n_experts, self.top_k, held, inter),
+                self.held)
+            toks_z = jnp.concatenate([tokens, jnp.zeros((1, h), tokens.dtype)])
+            expert_in = toks_z[groups.source]       # each row to its place
+        with jax.named_scope("moe_experts"):
+            expert_out = get_op("moe_grouped_matmul")(
+                expert_in, *bank, groups.tile_expert, groups.tile_rows,
+                groups.num_tiles, jnp.asarray(layer, jnp.int32),
+                tile=groups.tile)
+            # combine: each token's k results under its gates, products and
+            # their sum in float32 as the combine einsum's are on the MXU. A
+            # row with no place reads nothing: what lies past the tiles in
+            # use was never written
+            placed = groups.place < expert_in.shape[0]
+            picked = expert_out[jnp.where(placed, groups.place, 0)]
+            picked = jnp.where(placed[..., None], picked, 0)
+            gates = cg.gates.astype(tokens.dtype).astype(jnp.float32)
+            out = jnp.sum(gates[..., None] * picked.astype(jnp.float32),
+                          axis=1).astype(tokens.dtype)
+        return out, cg.aux_loss
+
+    def _slabs(self, tokens, logits, bank):
+        """The capacity form: every expert a ``[C, H]`` slab of slots."""
+        T, h = tokens.shape
+        with jax.named_scope("moe_router"):
             # dispatch to [E, C, H], then expert-shard (a2a)
             if self.dispatch == "compact":
                 # O(k·T) end to end: the gating stays compact (no [T, E, C]
@@ -128,7 +217,8 @@ class MoELayer:
                 # per-slot gate come from two scatters — the computation the
                 # reference's moe_scatter/top_k_gating kernels perform
                 # (inference/v2/kernels/ragged_ops)
-                cg = top_k_gating_compact(logits, self.top_k, **gate_kw)
+                cg = top_k_gating_compact(logits, self.top_k,
+                                          **self._gate_kw())
                 aux_loss = cg.aux_loss
                 E, C = self.n_experts, cg.capacity
                 t_ids = jnp.broadcast_to(
@@ -146,7 +236,7 @@ class MoELayer:
                 expert_in = toks_z[token_for]                         # gather
             else:
                 gating: GatingOutput = top_k_gating(
-                    logits, self.top_k, held=self.held, **gate_kw)
+                    logits, self.top_k, held=self.held, **self._gate_kw())
                 aux_loss = gating.aux_loss
                 expert_in = jnp.einsum(
                     "tec,th->ech", gating.dispatch_mask.astype(tokens.dtype),
@@ -161,11 +251,7 @@ class MoELayer:
                 u = xe @ w_up
                 return (g * u) @ w_down
 
-            expert_out = jax.vmap(ffn)(params["w_gate"].astype(tokens.dtype),
-                                       params["w_up"].astype(tokens.dtype),
-                                       params["w_down"].astype(tokens.dtype),
-                                       expert_in)
-            expert_out = _expert_constraint(expert_out)
+            expert_out = _expert_constraint(jax.vmap(ffn)(*bank, expert_in))
 
             # combine: back to [T, H]  (a2a back)
             if self.dispatch == "compact":
@@ -176,12 +262,4 @@ class MoELayer:
                 out = jnp.einsum(
                     "tec,ech->th", gating.combine_weights.astype(tokens.dtype),
                     expert_out)
-            # Qwen2-MoE shared expert: a dense SwiGLU added to every token,
-            # scaled by a learned sigmoid gate (params present only when used)
-            if "shared_w_gate" in params:
-                sg = jax.nn.silu(tokens @ params["shared_w_gate"].astype(tokens.dtype))
-                su = tokens @ params["shared_w_up"].astype(tokens.dtype)
-                shared = (sg * su) @ params["shared_w_down"].astype(tokens.dtype)
-                gate = jax.nn.sigmoid(tokens @ params["shared_gate"].astype(tokens.dtype))
-                out = out + gate * shared
-        return out.reshape(b, s, h), aux_loss
+        return out, aux_loss

@@ -43,6 +43,21 @@ class CompactGating(NamedTuple):
     capacity: int
     aux_loss: jnp.ndarray          # scalar load-balancing loss
     router_probs: jnp.ndarray      # [T, E] f32
+    counts: jnp.ndarray            # [E] int32 — rows each expert was sent
+
+
+class RowGroups(NamedTuple):
+    """The routed rows of a no-drop call in an expert-major order, each
+    expert's rows padded to whole row tiles (``row_groups``): what a grouped
+    matmul (``ops/pallas/grouped_matmul.py``) walks."""
+    place: jnp.ndarray             # [T, k] int32 — each routed row's place;
+                                   #   ``places`` where its expert is absent
+    source: jnp.ndarray            # [places] int32 — the token at each
+                                   #   place; T where the place is padding
+    tile_expert: jnp.ndarray       # [tiles] int32 — the expert of each tile
+    tile_rows: jnp.ndarray         # [tiles] int32 — its rows in use, a prefix
+    num_tiles: jnp.ndarray         # scalar int32 — tiles in use
+    tile: int                      # rows a tile
 
 
 def compute_capacity(tokens: int, n_experts: int, k: int,
@@ -102,7 +117,76 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
 
     return CompactGating(topk_idx=topk_idx, gates=topk_gates * keep,
                          pos=pos, keep=keep, capacity=capacity,
-                         aux_loss=aux_loss, router_probs=probs)
+                         aux_loss=aux_loss, router_probs=probs,
+                         counts=prior_count)
+
+
+def row_tile(tokens: int, n_experts: int, k: int, held: int,
+             inter: int) -> int:
+    """Rows a tile of a grouped call over ``tokens`` token rows, through a
+    bank of ``held`` of the ``n_experts`` routed experts, each ``inter``
+    wide: a power of two, 16 (a bf16 register tile's rows) .. 256, no
+    larger than covers the call's rows. A tile is ONE expert's, and an expert with
+    more rows than a tile takes a second visit, its weights read again; a
+    larger tile pads every expert's rows further, and the padding is
+    gathered (never computed: the kernel works a tile in sub-tiles of an
+    MXU pass and skips the empty ones). What was measured (my chip runs, PR
+    41; PERF.md Findings): a tile should cover the largest group the router
+    sends one expert, up to 256 rows. That group is the routing's, unknown
+    when the call is traced, so the rule below is a FIT to the three cells
+    it was measured at, checked under a uniform router and one skewed one
+    and no further. One and a half times an expert's mean share of the rows
+    where the experts are narrow and many (OLMoE, Keye: 64; 128 there pads
+    a gathered buffer the call pays more for than for the second visits it
+    spares, 9.47 against 9.01 ms and 4.12 against 2.90). ``inter // held``
+    where that is more, which is the largest tile for wide, few experts
+    (Mixtral: 1792 -> 256; its router sends one expert over 128 of a call's
+    272 rows in about half the layers, and a second read of 352 MB cost the
+    cell 5 % of its ``itl_p99_ms`` at 128-row tiles)."""
+    want = min(tokens, max(-(-3 * tokens * k // (2 * n_experts)),
+                           inter // held))
+    return next((t for t in (16, 32, 64, 128) if t >= want), 256)
+
+
+def row_groups(cg: CompactGating, tile: int,
+               held: Optional[Tuple[int, int]] = None) -> RowGroups:
+    """Each routed row's place in an expert-major order, with no sort: the
+    tiles before its expert's first one (the exclusive running sum of the
+    experts' tile counts, ``ceil(count / tile)``) times ``tile``, plus the
+    row's position among its expert's rows (``cg.pos``). ``held = (first,
+    count)``: the groups are the held experts' and a row routed to an absent
+    expert has no place. The buffer is static - ``tiles * tile`` places for
+    the worst routing, every expert's last tile all but empty - and
+    ``num_tiles`` says how many the routing at hand uses."""
+    tokens, k = cg.topk_idx.shape
+    first, count = held or (0, cg.counts.shape[0])
+    counts = cg.counts[first:first + count]
+    chosen = cg.topk_idx - first
+    present = jnp.logical_and(chosen >= 0, chosen < count)
+    tiles = (tokens * min(k, count) + count * (tile - 1)) // tile
+    per_expert = (counts + tile - 1) // tile
+    ends = jnp.cumsum(per_expert)
+    starts = ends - per_expert
+    place = jnp.where(
+        present, starts[jnp.clip(chosen, 0, count - 1)] * tile + cg.pos,
+        tiles * tile)
+    # places are distinct by construction, so the scatter cannot collide; a
+    # row with no place falls out of bounds, each at an index of its own
+    # (``unique_indices`` is a promise about those too)
+    flat = jnp.arange(tokens * k, dtype=jnp.int32)
+    source = jnp.full((tiles * tile,), tokens, jnp.int32).at[
+        jnp.where(present, place, tiles * tile + flat.reshape(tokens, k))
+        .reshape(-1)].set(flat // k, mode="drop", unique_indices=True)
+    index = jnp.arange(tiles, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.sum(index[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        count - 1)
+    # (a tile past the last in use counts past its expert's rows: 0)
+    tile_rows = jnp.clip(
+        counts[tile_expert] - (index - starts[tile_expert]) * tile, 0, tile)
+    return RowGroups(place=place.astype(jnp.int32), source=source,
+                     tile_expert=tile_expert, tile_rows=tile_rows,
+                     num_tiles=ends[-1].astype(jnp.int32), tile=tile)
 
 
 def top_k_gating(logits: jnp.ndarray, k: int = 1, *,

@@ -13,3 +13,4 @@ from . import flash_attention  # noqa: F401
 from . import norms  # noqa: F401
 from . import quantize  # noqa: F401
 from . import paged_attention  # noqa: F401 (registers ops)
+from . import grouped_matmul  # noqa: F401 (registers ops)

@@ -9,8 +9,16 @@ O(T·E·C·H) flops. The compacted alternative (what a Pallas scatter kernel
 would compute) builds the [E,C] token index table from the gating output and
 uses gather / scatter-add — O(k·T·H) memory movement, no E·C blowup.
 
-This script times BOTH paths end-to-end (gating → dispatch → 2-matmul
-expert FFN → combine) at serving/training-realistic shapes and prints one
+``--forms`` (PR 41) times the EXPERT BANK of a no-drop serving call at the
+three MoE cells' shapes, kernels alone, in three forms - today's dense slabs
+(every expert over a slab as long as the call), ``jax.lax.ragged_dot`` as XLA
+compiles it over rows sorted by expert, and the Mosaic grouped matmul
+(``ops/pallas/grouped_matmul.py``) - each through a layer scan over a stacked
+bank, as the serving forward meets it; and the whole MoE layer around the
+slab and the grouped form (gating, gather, bank, combine).
+
+Without the flag this script times BOTH dispatch paths end-to-end (gating →
+dispatch → 2-matmul expert FFN → combine) at realistic shapes and prints one
 JSON line, so the einsum-vs-kernel question is answered with data
 (PERF.md records the verdict: implement the Pallas kernel only if compact
 wins and XLA's lowering of it leaves time on the table).
@@ -30,6 +38,159 @@ RESULT = {"metric": "moe_dispatch_best_impl", "value": 0.0,
           "detail": {}}
 
 
+# the MoE cells' calls (PERF.md section 4): name -> (rows, router width,
+# experts held, experts a token, hidden, expert width, layers of the cell)
+BANK_SHAPES = {
+    "mixtral_272": (272, 8, 8, 2, 4096, 14336, 3),
+    "mixtral_16": (16, 8, 8, 2, 4096, 14336, 3),
+    "olmoe_272": (272, 64, 64, 8, 2048, 1024, 8),
+    "olmoe_16": (16, 64, 64, 8, 2048, 1024, 8),
+    "keye_520": (520, 128, 16, 8, 2048, 768, 12),
+    "keye_8": (8, 128, 16, 8, 2048, 768, 12),
+}
+
+
+def bank_forms(shapes=None, steps: int = 5, tiles=(), skew: float = 0.0):
+    """ms a call's LAYERS (the cell's depth: a tick's) of each form at each
+    of ``shapes`` (names of ``BANK_SHAPES``), on seeded weights and a random
+    router's groups.
+    ``tiles``: row tiles to time the grouped kernel at beside the layer's
+    own choice (``sharded_moe.row_tile``). ``skew``: the spread of a bias
+    on each expert's logit (0: a uniform router; the cells' routers, on
+    hidden states that resemble each other, are not)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from deepspeed_tpu.moe.layer import BANK, MoELayer
+    from deepspeed_tpu.moe.sharded_moe import (row_groups, row_tile,
+                                               top_k_gating_compact)
+    from deepspeed_tpu.ops import pallas as _pallas_ops  # noqa: F401
+    from deepspeed_tpu.ops.registry import get_op
+
+    on_tpu = jax.default_backend() == "tpu"
+    dtype = jnp.bfloat16
+
+    def timed(fn, *args):
+        jf = jax.jit(fn)
+        jax.block_until_ready(jf(*args))
+        t0 = time.perf_counter()
+        for _ in range(steps):
+            out = jf(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / steps * 1e3
+
+    def one_shape(name):
+        T, E, held_n, k, H, F, L = BANK_SHAPES[name]
+        if not on_tpu:      # the CPU run is a schema run at a toy size
+            H, F, L = 64, 128, 2
+        held = None if held_n == E else (E // 8, held_n)
+        keys = jax.random.split(jax.random.PRNGKey(41), 6)
+        bank = {
+            "w_gate": jax.random.normal(keys[0], (L, held_n, H, F), dtype)
+            * H ** -0.5,
+            "w_up": jax.random.normal(keys[1], (L, held_n, H, F), dtype)
+            * H ** -0.5,
+            "w_down": jax.random.normal(keys[2], (L, held_n, F, H), dtype)
+            * F ** -0.5,
+            "router": jax.random.normal(keys[3], (L, H, E), dtype) * H ** -0.5,
+        }
+        x = jax.random.normal(keys[4], (T, H), dtype)
+        cg = top_k_gating_compact(
+            jax.random.normal(keys[5], (T, E), jnp.float32)
+            + skew * jax.random.normal(keys[3], (E,), jnp.float32), k,
+            drop_tokens=False)
+        first, count = held or (0, E)
+        sizes = cg.counts[first:first + count]
+        row = {"largest_group": int(sizes.max())}
+
+        def over_layers(one):
+            """A layer scan as the serving forward's: ``one(x, layer's
+            scanned part, index, *whole)`` a layer, the result fed on."""
+            def fn(x, scanned, *whole):
+                def step(x, sc):
+                    part, i = sc
+                    return (x + 0.01 * one(x, part, i, *whole)[:T]
+                            ).astype(dtype), None
+                return lax.scan(step, x, (scanned, jnp.arange(L)))[0]
+            return fn
+
+        # today's slabs: every expert over T rows (the slab's content does
+        # not change its time)
+        def slabs(x, w, i):
+            xe = jnp.broadcast_to(x, (held_n,) + x.shape)
+            g = jax.nn.silu(jnp.einsum("ech,ehf->ecf", xe, w["w_gate"]))
+            u = jnp.einsum("ech,ehf->ecf", xe, w["w_up"])
+            return jnp.einsum("ecf,efh->ech", g * u, w["w_down"])[0]
+
+        banks = {n: bank[n] for n in BANK}
+        row["dense_slabs"] = timed(over_layers(slabs), x, banks)
+
+        # rows sorted by expert, no padding: XLA's own ragged_dot
+        n_sorted = T * min(k, count)
+
+        def ragged(x, w, i, sizes):
+            xs = jnp.resize(x, (n_sorted, H))
+            g = jax.nn.silu(lax.ragged_dot(xs, w["w_gate"], sizes))
+            u = lax.ragged_dot(xs, w["w_up"], sizes)
+            return lax.ragged_dot(g * u, w["w_down"], sizes)
+
+        try:
+            row["ragged_dot"] = timed(over_layers(ragged), x, banks, sizes)
+        except Exception as e:
+            row["ragged_dot"] = f"error: {str(e)[-200:]}"
+
+        # the Mosaic kernel: the stacked bank whole, the layer an index
+        own = row_tile(T, E, k, held_n, F)
+        for tile in dict.fromkeys((own,) + tuple(tiles)):
+            groups = row_groups(cg, tile, held)
+            places = groups.source.shape[0]
+
+            def grouped(x, _, i, wg, wu, wd, tile_expert, tile_rows,
+                        num_tiles, tile=tile, places=places):
+                xs = jnp.resize(x, (places, H))
+                return get_op("moe_grouped_matmul")(
+                    xs, wg, wu, wd, tile_expert, tile_rows, num_tiles, i,
+                    tile=tile)
+
+            label = f"grouped_tile{tile}" + ("_own" if tile == own else "")
+            try:
+                row[label] = timed(
+                    over_layers(grouped), x, jnp.zeros((L,)),
+                    *(bank[n] for n in BANK), groups.tile_expert,
+                    groups.tile_rows, groups.num_tiles)
+            except Exception as e:
+                row[label] = f"error: {str(e)[-200:]}"
+            row[f"tiles_in_use_{tile}"] = int(groups.num_tiles)
+
+        # the whole MoE layer, both forms (router, gather, bank, combine)
+        for form in ("slab", "grouped"):
+            layer = MoELayer(E, k, drop_tokens=False, held=held)
+
+            # one layer's bank (the scanned part): slabs; the stack: grouped
+            def whole(x, part, i, *stack, layer=layer):
+                params = {**part, **dict(zip(BANK, stack))}
+                return layer(params, x[None], layer=i if stack else None)[0][0]
+
+            try:
+                if form == "slab":
+                    row["layer_slab"] = timed(over_layers(whole), x, bank)
+                else:
+                    row["layer_grouped"] = timed(
+                        over_layers(whole), x, {"router": bank["router"]},
+                        *(bank[n] for n in BANK))
+            except Exception as e:
+                row[f"layer_{form}"] = f"error: {str(e)[-200:]}"
+        return {k_: (round(v, 4) if isinstance(v, float) else v)
+                for k_, v in row.items()}
+
+    rows_out = {}
+    for name in shapes or BANK_SHAPES:     # a shape's bank is freed on return
+        rows_out[name] = one_shape(name)
+        sys.stderr.write(f"[moe bank] {name}: {rows_out[name]}\n")
+    return rows_out
+
+
 def main():
     import jax
 
@@ -45,6 +206,23 @@ def main():
     backend = jax.default_backend()
     RESULT["detail"]["backend"] = backend
     on_tpu = backend == "tpu"
+    if "--forms" in sys.argv:
+        names = [a for a in sys.argv[1:] if a in BANK_SHAPES]
+        tiles = [int(a.split("=")[1]) for a in sys.argv if
+                 a.startswith("--tile=")]
+        # the kernel's weight blocks, to tune them: blocks of columns within
+        # N MB double-buffered, and no expert's matrices whole
+        for a in sys.argv:
+            if a.startswith("--weight-vmem-mb="):
+                from deepspeed_tpu.ops.pallas import grouped_matmul
+                grouped_matmul._BLOCK_VMEM = int(a.split("=")[1]) << 20
+                grouped_matmul._WHOLE_VMEM = 0
+        RESULT["metric"], RESULT["unit"] = "moe_bank_forms", "ms_a_tick"
+        skew = [float(a.split("=")[1]) for a in sys.argv
+                if a.startswith("--skew=")]
+        RESULT["detail"]["bank_forms_ms"] = bank_forms(
+            names or None, tiles=tiles, skew=skew[0] if skew else 0.0)
+        return finalize(RESULT)
     if on_tpu:
         shapes = [(8192, 1024, 8, 2), (8192, 1024, 64, 2),
                   (16384, 2048, 16, 2)]

@@ -63,6 +63,17 @@ def _reset_global_mesh():
 
 
 @pytest.fixture
+def one_device():
+    """The process's mesh on a one-chip host, not this directory's eight
+    virtual devices: what says a program is one device's where the trace
+    has no mesh context (``MoELayer.grouped``; an engine built under it
+    with ``tp_size`` 1 keeps it)."""
+    from deepspeed_tpu.comm import mesh as mesh_mod
+
+    return mesh_mod.init_mesh({"data": 1}, devices=jax.devices()[:1])
+
+
+@pytest.fixture
 def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual devices, got {len(devs)}"

@@ -14,6 +14,7 @@ tests steer the one platform test of the op tier (``registry.on_tpu``).
 """
 
 import dataclasses
+import math
 import os
 import sys
 
@@ -50,15 +51,19 @@ def v5e():
 @pytest.fixture(autouse=True)
 def _as_on_the_chip(monkeypatch):
     """Kernels lower through Mosaic and the registry prefers Pallas, as on
-    the chip; the persistent compile cache is off, because an executable
-    compiled for a described chip cannot be read back without one."""
+    the chip, and the process's mesh is one device's, as a one-chip host's
+    is (a test over four chips makes its own); the persistent compile cache
+    is off, because an executable compiled for a described chip cannot be
+    read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    from deepspeed_tpu.comm import mesh as mesh_lib
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.pallas import _common
 
     monkeypatch.setattr(registry, "on_tpu", lambda: True)
     monkeypatch.setattr(_common, "on_tpu", lambda: True)
+    mesh_lib.init_mesh({"data": 1}, devices=jax.devices()[:1])
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -595,11 +600,81 @@ def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
     assert calls.count("ssm_decode_update") == (
         2 if cell == GRANITE_CELL else 0)
     # every matmul against a weight (bf16; the blocked scan's own are f32)
-    # runs over the call's rows: none over one segment's alone
+    # runs over the call's rows: none over one segment's alone. A MoE
+    # cell's three expert matmuls are its one grouped call (ISSUE 41): q,
+    # k, v, o, the router and the head are left
     rows = args[2].shape[1]
     matmuls = [tuple(map(int, dims.split(","))) for dims in re.findall(
         r"= bf16\[([\d,]+)\]\S* convolution\(", text)]
-    assert len(matmuls) >= 7 and all(rows in dims for dims in matmuls)
+    grouped = calls.count("moe_grouped_matmul")
+    assert grouped == (0 if cell in (SERVE_CELLS[0], GRANITE_CELL) else 1)
+    assert len(matmuls) >= 7 - grouped \
+        and all(rows in dims for dims in matmuls)
+
+
+# --- the expert bank is read where it lies (ISSUE 41) ----------------------- #
+MOE_CELLS = ("mixtral-8x7b.serve-longprompt", "olmoe-1b-7b.serve-longprompt",
+             KEYE_CELL)
+BANK_PROGRAMS = [(cell, "mixed") for cell in MOE_CELLS] + [
+    (cell, program) for cell in MOE_CELLS[:2]
+    for program in ("chunk_prefill", "decode", "decode_many")]
+
+
+@pytest.mark.parametrize("cell,program", BANK_PROGRAMS)
+def test_the_expert_bank_is_read_where_it_lies(v5e, cell, program):
+    """A MoE cell's forward programs compiled for the chip with the grouped
+    form: a layer body is ONE ``moe_grouped_matmul`` over the STACKED bank
+    (the layer a prefetched scalar), so the compiled program holds no copy,
+    slice or loop fusion the size of a layer's bank (``pool_copy_bytes``
+    pointed at the bank's shapes) - a scanned slice handed to a Mosaic call
+    would be copied out of the stack first, 2.8 GB a layer at Mixtral's
+    widths -, no matmul over ``[E, rows, ...]`` slabs and no ``[T, E, C]``
+    mask; the pools stay where they are as before."""
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = (_mixed_program(cell) if program == "mixed"
+                else _pool_program(cell, program, False))
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    moe = args[0]["layers"]["moe"]
+    bank = [moe[n] for n in ("w_gate", "w_up", "w_down")]
+    assert pool_copy_bytes(text, bank) == 0
+    assert pool_copy_bytes(text, jax.tree.leaves(args[1])) == 0
+    assert 0 < compiled.memory_analysis().peak_memory_in_bytes \
+        < V5E_BYTES_LIMIT
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    assert calls.count("moe_grouped_matmul") == 1
+    experts, rows = bank[0].shape[1], math.prod(args[2].shape)
+    assert not re.findall(rf"= bf16\[{experts},{rows},\d+\]", text)
+    assert not re.findall(rf"\[{rows},{experts},{rows}\]", text)
+
+
+# sha256 of the mixed program's jaxpr in the two cells whose families hold no
+# bank, taken on ISSUE 41's parent (166de2b) and again on its finished tree:
+# ``_paged.scan_layers`` is every paged family's, and the bank's way through
+# it (a closure of ``models/mixtral.py``) left theirs alone.
+NO_BANK_PROGRAMS = {
+    "mistral-7b.serve-chat": "4c1badd32e6be1bd",
+    GRANITE_CELL: "adee81593bcafbca",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(NO_BANK_PROGRAMS))
+def test_a_family_with_no_bank_traces_to_the_parents_program(cell):
+    import hashlib
+    import re
+
+    fn, args = _mixed_program(cell)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    assert "moe_grouped_matmul" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == NO_BANK_PROGRAMS[cell]
 
 
 # head size 64 (half a lane tile) in plain pools, and the lane-packed geometry

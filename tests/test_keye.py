@@ -121,7 +121,8 @@ def f32(request):
 
 
 @pytest.mark.parametrize("path", PATHS)
-def test_program_agrees_with_the_plain_reference_in_float32(f32, path):
+def test_program_agrees_with_the_plain_reference_in_float32(f32, path,
+                                                            one_device):
     """Every position of a 27-token row: contexts of 1-8 select everything,
     9-27 select 8 (``TOPK``), along each path - and with one chip's share of
     the bank the partial sum is the reference's partial sum."""
@@ -437,12 +438,14 @@ def test_a_held_range_of_every_expert_is_the_layer_bit_for_bit():
         MoELayer(8, 4, dispatch="compact", held=(0, 2))
 
 
-def test_rows_of_a_held_range_are_the_shares():
+def test_rows_of_a_held_range_are_the_shares(one_device):
     cfg = family.build_cfg(HELD, drop_tokens=False)
     # 16 rows x 4 experts a token, 2 of 8 experts held: 16 routed rows
-    # expected here; the bank computes 2 slabs of 16
+    # expected here; the grouped bank computes a 16-row tile of each of the
+    # two (each is reached: 1 - 2^-16)
     assert mixtral.moe_rows(cfg, 16) == {"moe_rows_routed": 16,
-                                         "moe_rows_computed": 32}
+                                         "moe_rows_computed": 32,
+                                         "moe_row_tile": 16}
     assert mixtral.sparse_rows(cfg, [3, 8, 20]) == {
         "sparse_rows": 3, "sparse_ctx_scored": 31, "sparse_kv_selected": 19}
     assert mixtral.sparse_rows(mixtral.MixtralConfig.tiny(), [3]) == {}

@@ -101,7 +101,8 @@ def f32():
 
 
 @pytest.mark.parametrize("path", PATHS)
-def test_program_agrees_with_the_plain_reference_in_float32(f32, path):
+def test_program_agrees_with_the_plain_reference_in_float32(f32, path,
+                                                            one_device):
     cfg, params, row, want = f32
     with jax.default_matmul_precision("highest"):
         got = program_logits(path, cfg, params, row)
@@ -145,7 +146,7 @@ def test_the_program_itself_with_renormalised_gates_or_no_norm_fails(f32):
         assert gap(got, want) > 1e-2
 
 
-def test_bfloat16_through_the_paged_cache_stays_near_the_reference():
+def test_bfloat16_through_the_paged_cache_stays_near_the_reference(one_device):
     """The served precision. bf16 keeps 8 bits of mantissa: every matmul
     input and every stored activation is rounded to 0.4 %, and unit-variance
     logits that pass 2 layers of such roundings differ from the float32
@@ -172,14 +173,19 @@ def test_bfloat16_through_the_paged_cache_stays_near_the_reference():
         assert mean(got, logits) > 0.06 and gap(got, logits) > 0.45, variant
 
 
-def test_moe_rows_are_the_layers_static_shapes():
+def test_moe_rows_are_the_layers_static_shapes(one_device):
     cfg = family.build_cfg(TINY, **OPTIONS)
-    # 16 rows: 16 x 4 routed; no-drop capacity is the row count: 8 x 16
+    # 16 rows: 16 x 4 routed; the grouped bank computes a 16-row tile of
+    # each of the 8 experts (every one is reached: 1 - 2^-16)
     assert mixtral.moe_rows(cfg, 16) == {"moe_rows_routed": 64,
-                                         "moe_rows_computed": 128}
+                                         "moe_rows_computed": 128,
+                                         "moe_row_tile": 16}
+    # 256 rows, 64 an expert: a 256-row tile of each, of which the kernel
+    # computes the 128-row sub-tile in use (the slabs: 8 x 256)
     wide = mixtral.MixtralConfig(num_experts=8, top_k=2)
     assert mixtral.moe_rows(wide, 256) == {"moe_rows_routed": 512,
-                                           "moe_rows_computed": 2048}
+                                           "moe_rows_computed": 1024,
+                                           "moe_row_tile": 256}
     assert not hasattr(llama, "moe_rows")        # a dense family has none
 
 
@@ -238,17 +244,35 @@ FAMILIES = {
 }
 
 
-def program_hashes():
-    return {f"{name}.{program}": hashlib.sha256(text.encode()).hexdigest()[:16]
-            for name, make in FAMILIES.items()
-            for program, text in program_texts(*make()).items()}
+def program_hashes(one_device=True):
+    """Under a one-device process mesh, as on a one-chip host: where the
+    serving forwards of a MoE family take the grouped form. Else over this
+    directory's eight virtual devices, where they keep the slabs."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+
+    before = mesh_lib._global_mesh
+    mesh_lib.set_mesh(None)
+    if one_device:
+        mesh_lib.init_mesh({"data": 1}, devices=jax.devices()[:1])
+    try:
+        return {f"{name}.{program}":
+                hashlib.sha256(text.encode()).hexdigest()[:16]
+                for name, make in FAMILIES.items()
+                for program, text in program_texts(*make()).items()}
+    finally:
+        mesh_lib.set_mesh(before)
 
 
 # ``program_hashes()`` under this directory's conftest: the dense and the
 # training programs are those of the commit before ISSUE 26 (45ecee2); the six
 # ``apply_paged`` lines are ISSUE 29's, re-taken on its finished tree (the
-# pools became the layer scan's carry). A PR that means to change one of these
-# programs replaces its line; one that does not has changed it by accident.
+# pools became the layer scan's carry). ISSUE 41 re-took the six lines of
+# the MoE families' SERVING forwards on its finished tree (the grouped form,
+# over the stacked banks: ``apply_cached``, ``apply_paged`` and its decode, of
+# ``mixtral`` and ``qwen2_moe``); the four training lines (``apply`` and
+# ``loss_grad``: one layer's bank, the slabs, with ``drop_tokens`` False as
+# with True) and the five ``mistral.*`` are as they were. A PR that means to change one of these programs replaces its line; one
+# that does not has changed it by accident.
 PARENT_HASHES = {
     "mistral.apply": "18726a070296e819",
     "mistral.apply_cached": "26908a8864698d2d",
@@ -256,21 +280,49 @@ PARENT_HASHES = {
     "mistral.apply_paged_decode": "ffe8c14146072ee0",
     "mistral.loss_grad": "6d328cc674130e50",
     "mixtral.apply": "9dda8853ea9faa33",
+    "mixtral.apply_cached": "5156a5e9fc412514",
+    "mixtral.apply_paged": "7ff9f441c4f232a2",
+    "mixtral.apply_paged_decode": "a4a88501885ab542",
+    "mixtral.loss_grad": "0bf2200b1c25dbf1",
+    "qwen2_moe.apply": "85641834200bb56f",
+    "qwen2_moe.apply_cached": "e69ce3f99443956b",
+    "qwen2_moe.apply_paged": "707a9cf1470fc306",
+    "qwen2_moe.apply_paged_decode": "7ae307f800f9e4aa",
+    "qwen2_moe.loss_grad": "e4872d8dfc0a6a34",
+}
+
+# The six serving lines as ISSUE 41's parent (166de2b) had them: what a
+# process whose mesh spans devices still traces (the slabs, the bank a
+# scanned input as before - the grouped matmul is one device's kernel).
+OVER_A_MESH = {
     "mixtral.apply_cached": "8a284dcc95d1bdc8",
     "mixtral.apply_paged": "c7cd0c20ba4f3634",
     "mixtral.apply_paged_decode": "752dc3cfbdd603e9",
-    "mixtral.loss_grad": "0bf2200b1c25dbf1",
-    "qwen2_moe.apply": "85641834200bb56f",
     "qwen2_moe.apply_cached": "d160ec6149557341",
     "qwen2_moe.apply_paged": "8550fe9e4ef9b557",
     "qwen2_moe.apply_paged_decode": "d8bce010d024ebe0",
-    "qwen2_moe.loss_grad": "e4872d8dfc0a6a34",
 }
 
 
 @pytest.fixture(scope="module")
 def hashes():
     return program_hashes()
+
+
+@pytest.fixture(scope="module")
+def mesh_hashes():
+    return program_hashes(one_device=False)
+
+
+@pytest.mark.parametrize("program", sorted(OVER_A_MESH))
+def test_over_a_mesh_a_serving_forward_is_the_parents(mesh_hashes, program):
+    assert mesh_hashes[program] == OVER_A_MESH[program]
+    assert OVER_A_MESH[program] != PARENT_HASHES[program]
+
+
+def test_off_the_serving_forwards_the_mesh_changes_no_program(mesh_hashes):
+    assert {p: h for p, h in mesh_hashes.items() if p not in OVER_A_MESH} \
+        == {p: h for p, h in PARENT_HASHES.items() if p not in OVER_A_MESH}
 
 
 @pytest.mark.parametrize("program", sorted(PARENT_HASHES))

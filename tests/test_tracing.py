@@ -895,6 +895,7 @@ def _kernel_cases():
     """One tiny call into each ``pallas_call`` site -> the kernel's name."""
     import jax
 
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     from deepspeed_tpu.ops.pallas import paged_sparse_attention as ps
     from deepspeed_tpu.ops.pallas import ssm
@@ -976,6 +977,12 @@ def _kernel_cases():
             lambda q, i, t_, bt, n: ps.paged_sparse_prefill_attention(
                 q, pool, pool, i, t_, t_, bt, n, n),
             [jnp.ones((2, 3, 4, 32), f32), idx, thr, tables, lens]),
+        "moe_grouped_matmul": (
+            lambda x, wg, wd, e, n: gm.moe_grouped_matmul(
+                x, wg, wg, wd, e, e + 16, n, 1, tile=16),
+            [jnp.ones((32, 128), f32), jnp.ones((2, 3, 128, 128), f32),
+             jnp.ones((2, 3, 128, 128), f32),
+             jnp.zeros((2,), jnp.int32), jnp.ones((), jnp.int32)]),
     }
 
 
@@ -986,7 +993,7 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "dequantize_int8", "state_rows_read", "state_rows_write",
                 "ssm_decode_update", "paged_index_write", "paged_index_scores",
                 "paged_sparse_select", "paged_sparse_decode",
-                "paged_sparse_prefill"]
+                "paged_sparse_prefill", "moe_grouped_matmul"]
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
