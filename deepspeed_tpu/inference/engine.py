@@ -64,6 +64,12 @@ class ModelFamily:
     # ``sparse_kv_selected``), {} for a family without one; a family that
     # has one keeps a THIRD block pool, the index keys' (models/mixtral.py)
     sparse_rows: Optional[Callable] = None
+    # (cfg) -> {kind: window} for a family whose stack has sliding-window
+    # layers with a KV pool of their own kind beside the full-attention
+    # layers' (``inference.ragged.WindowKind``; models/cohere2_moe.py):
+    # its ``init_paged_cache`` takes ``window_blocks`` and its
+    # ``apply_paged`` a block table of one segment a kind
+    window_kinds: Optional[Callable] = None
 
     @classmethod
     def from_module(cls, module, cfg) -> "ModelFamily":
@@ -82,7 +88,8 @@ class ModelFamily:
                    state_slot_bytes=getattr(module, "state_slot_bytes", None),
                    state_leaves=tuple(getattr(module, "STATE_LEAVES", ())),
                    mixed_paged=bool(getattr(module, "MIXED_PAGED", False)),
-                   sparse_rows=getattr(module, "sparse_rows", None))
+                   sparse_rows=getattr(module, "sparse_rows", None),
+                   window_kinds=getattr(module, "window_kinds", None))
 
 
 def _round_up(n: int, m: int) -> int:

@@ -40,8 +40,9 @@ from ..telemetry.trace import percentiles
 from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .engine import InferenceEngine, ModelFamily, _round_up
-from .ragged import (IndexPoolError, RecurrentStateError,  # noqa: F401
-                     StateManager, UnknownSequenceError)  # (re-exports)
+from .ragged import (IndexPoolError, KVKindError,  # noqa: F401
+                     RecurrentStateError, StateManager, UnknownSequenceError,
+                     WindowKind)  # (re-exports)
 from .sampling import (SamplingParams, accept_drafts, sample, sample_batch,
                        sp_arrays)
 
@@ -213,12 +214,34 @@ class InferenceEngineV2(InferenceEngine):
                              and self.family.sparse_rows(self.family.cfg, ()))
         if self._indexed:
             self._refuse_for_index_pool()
+        # --- kinds of KV state (docs/serving.md "Kinds of KV state"): a
+        # family with sliding-window layers keeps their keys and values in a
+        # pool of their own, sized from what bounds it - the slots, the
+        # window, the most tokens one call writes for a sequence (a
+        # SplitFuse chunk; the whole context without chunking) and the
+        # block size - and gives a block back once it lies behind the
+        # window. ``memory_config_blocks`` stays the full kind's count.
+        self._window = dict(self.family.window_kinds(self.family.cfg)) \
+            if self.family.window_kinds else {}
+        kinds = ()
+        if self._window:
+            self._refuse_for_window_kinds()
+            call = _round_up(self.config.split_prefill_chunk,
+                             self.config.prefill_bucket) \
+                if self.config.split_prefill_chunk > 0 \
+                else self.family.cfg.max_seq_len
+            kinds = tuple(
+                WindowKind.sized(name, window, rc.max_tracked_sequences,
+                                 call, rc.block_size)
+                for name, window in self._window.items())
+            slot_kw["window_blocks"] = {k.name: k.num_blocks for k in kinds}
         self.state = StateManager(
             rc.max_tracked_sequences, rc.memory_config_blocks, rc.block_size,
             max_blocks_per_seq, prefix_cache=pc.enabled,
             max_retained_blocks=pc.max_retained_blocks,
             state_slot_bytes=self.family.state_slot_bytes(
-                self.family.cfg) if self._recurrent else 0)
+                self.family.cfg) if self._recurrent else 0,
+            window_kinds=kinds)
         # --- quantized KV cache (inference.kv_quant; docs/serving.md
         # "Quantized KV cache"). Default OFF → the cache pytree, every
         # compiled paged program, and the token streams are byte-identical
@@ -310,7 +333,7 @@ class InferenceEngineV2(InferenceEngine):
         # token's value or moves a sequence - is counted by its cause.
         self._seq = 0
         self.drains: Dict[str, int] = dict.fromkeys(DRAIN_CAUSES, 0)
-        self._slot_tables = np.zeros((B, max_blocks_per_seq), np.int32)
+        self._slot_tables = np.zeros((B, self.state.table_width), np.int32)
         # per-slot sampling params, recorded at admission — decode honors
         # these (the reference's v2 engine carries per-request sampling)
         self._slot_sp: List[SamplingParams] = [_GREEDY] * B
@@ -566,6 +589,55 @@ class InferenceEngineV2(InferenceEngine):
             if on:
                 raise IndexPoolError(feature, why)
 
+    def _refuse_for_window_kinds(self) -> None:
+        """Configuration-time refusals for a family with window layers' KV
+        state (``fork``, the disagg block export / import and a rollback
+        past the window refuse at their call): each feature that takes a
+        sequence's state to be every block it ever wrote."""
+        cfg = self.config
+        kq = getattr(cfg, "kv_quant", None)
+        for on, feature, why in (
+                (cfg.prefix_cache.enabled, "inference.prefix_cache",
+                 "a retained prefix would have to keep the window layers' "
+                 "blocks the sequence gave back"),
+                (getattr(cfg.prefix_cache, "host_spill", False),
+                 "inference.prefix_cache.host_spill",
+                 "it spills and restores prefix-cache blocks"),
+                (cfg.speculative.enabled, "inference.speculative",
+                 "a rejected draft is rolled back by truncating blocks, and "
+                 "nothing checks a rollback against the blocks given back"),
+                (kq is not None and kq.enabled, "inference.kv_quant",
+                 "the window kind's pool has no quantized mode")):
+            if on:
+                raise KVKindError(feature, why)
+
+    def _kv_kind_args(self, firsts, counts, prefix: str = "") -> Dict[str, int]:
+        """Span arguments of a call in a family with window layers: the
+        cached tokens ONE layer of each kind reads - a full layer every
+        token up to each sequence's last row, a window layer what lies
+        inside its first row's window (``kv_tokens_full``,
+        ``kv_tokens_window``; one sequence a pair of ``firsts``, its first
+        row's position, and ``counts``, its rows). None for any other
+        family."""
+        if not self._window:
+            return {}
+        firsts = np.asarray(firsts, np.int64)
+        ends = firsts + np.asarray(counts, np.int64)
+        out = {prefix + "kv_tokens_full": int(ends.sum())}
+        for window in self._window.values():
+            key = prefix + "kv_tokens_window"
+            out[key] = out.get(key, 0) + int(
+                (ends - np.maximum(firsts - window + 1, 0)).sum())
+        return out
+
+    def _table(self, desc, n: int) -> np.ndarray:
+        """``desc``'s block table for a call that writes its next ``n``
+        tokens: every kind covers them first (a prompt's blocks of the full
+        kind are claimed at admission; a window kind's as the calls reach
+        them, those behind the window given back)."""
+        self.state.extend(desc, n)
+        return self.state.block_table(desc)
+
     def _sparse_args(self, contexts, prefix: str = "") -> Dict[str, int]:
         """Span arguments of a call's learned selection, ONE layer's: the
         rows at ``contexts`` (each row's own position + 1), the cached
@@ -590,6 +662,10 @@ class InferenceEngineV2(InferenceEngine):
             raise IndexPoolError(call, "the wire format carries the K and V "
                                  "pools alone, and a block without its "
                                  "index keys selects from zeros")
+        if self._window:
+            raise KVKindError(call, "the wire format carries the full "
+                              "kind's blocks alone, and the window layers' "
+                              "state would be missing")
 
     def _jit(self, key, fn, **jit_kwargs):
         """Every paged program routes through the compile monitor's shared
@@ -857,7 +933,7 @@ class InferenceEngineV2(InferenceEngine):
             return {}
         live, grid = decode_tile_counts(
             self._slot_lens, self.family.cfg.num_heads, pool.shape,
-            pool.dtype.itemsize, self._slot_tables.shape[1],
+            pool.dtype.itemsize, self.state.max_blocks_per_seq,
             "k_scale" in self.cache)
         return {"attn_tiles_live": live, "attn_tiles_grid": grid,
                 "attn_live_tile_share": live / grid}
@@ -948,9 +1024,10 @@ class InferenceEngineV2(InferenceEngine):
                 table_blocks=self.state.max_blocks_per_seq,
                 **self._chunk_args(ch), **self._moe_args(rows),
                 **self._ssm_args(1, len(ch.tokens)),
-                **self._sparse_args(self._chunk_contexts(ch))):
+                **self._sparse_args(self._chunk_contexts(ch)),
+                **self._kv_kind_args([ch.ctx], [len(ch.tokens)])):
             with self.tracer.span("engine_prep", cat="serving"):
-                table = self.state.block_table(ch.desc)
+                table = self._table(ch.desc, len(ch.tokens))
                 fn, pre, post = self._chunk_program(ch, (), table, mixed)
             if self._trace_on:
                 self._req_compute_begin(ch.uid)  # first chunk ends queue-wait
@@ -1324,7 +1401,7 @@ class InferenceEngineV2(InferenceEngine):
                     lengths[i] = len(suffix)
                     ctx[i] = cached[i]
                     uids_arr[i] = uid
-                    tables[i] = self.state.block_table(desc)
+                    tables[i] = self._table(desc, len(suffix))
                 with_ctx = any(cached)
                 rows = not all(s_ == _GREEDY for s_ in sps)
                 fn = self._prefill_fn(pad_t, n_pad, with_ctx, rows)
@@ -1514,18 +1591,22 @@ class InferenceEngineV2(InferenceEngine):
                           for k, v in self._chunk_args(ch).items()}
             chunk_args.update(self._sparse_args(self._chunk_contexts(ch),
                                                 "chunk_"))
+            chunk_args.update(self._kv_kind_args(
+                [ch.ctx], [len(ch.tokens)], "chunk_"))
             self._ssm_args(1, len(ch.tokens))    # ``last_step``'s count
         with self.tracer.span(
                 "decode_step", cat="serving", seq=seq, batch=len(live),
                 overlapped=overlapped, **self._moe_args(n_rows),
                 **self._ssm_args(len(live), len(live)),
                 **self._sparse_args([d.seen_tokens + 1 for d in live]),
+                **self._kv_kind_args([d.seen_tokens for d in live],
+                                     [1] * len(live)),
                 **chunk_args) as span:
             with self.tracer.span("engine_prep", cat="serving"):
                 self._reserve(live, repeat(1))
                 extra = self._attn_tile_args()
                 if ch is not None:
-                    table = self.state.block_table(ch.desc)
+                    table = self._table(ch.desc, len(ch.tokens))
                     fn, pre, post = self._chunk_program(ch, live, table)
                 else:
                     # slots hold canonical params: see ``_sampler``
@@ -1892,6 +1973,10 @@ class InferenceEngineV2(InferenceEngine):
                           "and the parent's recurrent state would have to "
                           "be copied into a slot of its own, which is not "
                           "written")
+        if self._window:
+            raise KVKindError("fork", "a child shares its parent's blocks, "
+                              "and a window kind's are given back under the "
+                              "one that moves ahead")
         self.drain("fork")  # the child starts from the parent's last token
         desc = self.state.fork(uid, new_uid)
         self._req_admit(new_uid, desc.seen_tokens)
@@ -2147,6 +2232,26 @@ class InferenceEngineV2(InferenceEngine):
     def publish_state_telemetry(self, step: int = 0):
         return self._publish(self.state_events(step))
 
+    def kv_kind_events(self, step: int = 0):
+        """``Serving/kv/*`` telemetry events of a family with window layers'
+        KV state (none for any other): ``full_blocks_live`` and
+        ``window_blocks_live``, the blocks sequences hold of each kind now,
+        and ``window_blocks_released``, the window kinds' blocks given back
+        behind the window, cumulative."""
+        if not self._window:
+            return []
+        st = self.state
+        vals = {"full_blocks_live": st.allocator.num_blocks - 1
+                - st.allocator.free_blocks - st.retained_blocks,
+                "window_blocks_live": sum(st.window_blocks_live(k.name)
+                                          for k in st.window_kinds),
+                "window_blocks_released": st.window_blocks_released}
+        return [(f"Serving/kv/{k}", float(v), step)
+                for k, v in sorted(vals.items())]
+
+    def publish_kv_kind_telemetry(self, step: int = 0):
+        return self._publish(self.kv_kind_events(step))
+
     def sparse_events(self, step: int = 0):
         """``Serving/sparse/*`` telemetry events of a family with a learned
         token selection (none for any other), cumulative and ONE layer's
@@ -2184,7 +2289,8 @@ class InferenceEngineV2(InferenceEngine):
         spill/restore): int8 codes, fp32 scales, one scale vector per
         (block, head, token) with ``head_size // group_size`` groups, all
         finite and non-negative. Raises AssertionError on violation."""
-        keys = set(self.cache.keys()) - set(self.family.state_leaves)
+        keys = set(self.cache.keys()) - set(self.family.state_leaves) \
+            - {f"{kv}_{kind}" for kind in self._window for kv in "kv"}
         if not self._kvq_on:
             assert keys == {"k", "v"}, \
                 f"unquantized cache has unexpected leaves {keys}"

@@ -25,6 +25,18 @@ positions are un-filled, now-empty tail blocks are released, and a shared
 tail block is copied on write so siblings keep the original. All of it is
 host-side — the paged decode kernel reads arbitrary block tables, so shared
 blocks need zero kernel changes.
+
+Kinds of KV state (docs/serving.md "Kinds of KV state"): a family whose
+stack mixes full-attention and sliding-window layers declares a
+:class:`WindowKind` beside the full kind every family has. Each kind has a
+pool, an allocator and a segment of the block table of its own; a window
+kind's blocks that lie wholly behind ``context - window`` are GIVEN BACK
+before the next call is built (``extend``), its table segment holds the
+blocks from the first live one on (and says how many went before), and the
+kernels - which bound their walk by the window - never reach what went.
+What treats a sequence's state as the blocks it ever wrote (prefix
+retention, ``fork``, host spill, the disagg wire, a rollback past the
+window) is refused by name (:class:`KVKindError`).
 """
 
 from __future__ import annotations
@@ -77,6 +89,42 @@ class IndexPoolError(NotImplementedError):
     def __init__(self, feature: str, why: str):
         super().__init__(f"{feature} is not available for a family with a "
                          f"learned token selection: {why}")
+
+
+class KVKindError(NotImplementedError):
+    """A serving feature that takes a sequence's state to be every block it
+    ever wrote was asked of a family with a WINDOW kind of KV state, whose
+    blocks behind the window have been given back: refused by name instead
+    of serving from blocks that are another sequence's by now."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(f"{feature} is not available for a family with "
+                         f"window layers' KV state: {why} (docs/serving.md "
+                         f"'Kinds of KV state')")
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowKind:
+    """A kind of KV state beside the full kind: the layers that attend the
+    last ``window`` tokens alone (a row at position p reads positions
+    ``> p - window``). ``blocks_per_seq``: the most blocks one sequence
+    holds of it; ``num_blocks``: its pool, the trash block included."""
+    name: str
+    window: int
+    blocks_per_seq: int
+    num_blocks: int
+
+    @classmethod
+    def sized(cls, name: str, window: int, slots: int, call_tokens: int,
+              block_size: int) -> "WindowKind":
+        """The kind's pool from what bounds it: a call that writes
+        ``call_tokens`` tokens (a SplitFuse chunk; one token a decode) needs
+        the window behind its first row and its own rows, which is
+        ``(window + call_tokens) / block_size`` blocks and one more where
+        the window starts inside a block; ``slots`` sequences hold that
+        much each, so a free slot is room in this pool too."""
+        per_seq = -(-(window + call_tokens) // block_size) + 1
+        return cls(name, window, per_seq, slots * per_seq + 1)
 
 
 class BlockedAllocator:
@@ -281,6 +329,13 @@ class SequenceDescriptor:
     # len(block_hashes) FULL blocks
     tokens: List[int] = dataclasses.field(default_factory=list)
     block_hashes: List[bytes] = dataclasses.field(default_factory=list)
+    # a window kind's blocks by LOGICAL index (position // block_size), 0
+    # where the block was given back (``StateManager.window_kinds``)
+    window_blocks: Dict[str, List[int]] = dataclasses.field(
+        default_factory=dict)
+    # ... and how many entries at its front are given back (or were never
+    # claimed): the sequence's OFFSET in that kind
+    window_given: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 class StateManager:
@@ -295,8 +350,19 @@ class StateManager:
 
     def __init__(self, max_sequences: int, num_blocks: int, block_size: int,
                  max_blocks_per_seq: int, prefix_cache: bool = False,
-                 max_retained_blocks: int = -1, state_slot_bytes: int = 0):
+                 max_retained_blocks: int = -1, state_slot_bytes: int = 0,
+                 window_kinds: Sequence[WindowKind] = ()):
         self.block_size = block_size
+        # kinds of KV state beside the full one (``num_blocks`` is the full
+        # kind's): an allocator each, and what each has given back so far
+        self.window_kinds: Tuple[WindowKind, ...] = tuple(window_kinds)
+        if self.window_kinds and prefix_cache:
+            raise KVKindError(
+                "inference.prefix_cache", "a retained prefix would have to "
+                "keep the window layers' blocks the sequence gave back")
+        self.window_allocators: Dict[str, BlockedAllocator] = {
+            k.name: BlockedAllocator(k.num_blocks) for k in self.window_kinds}
+        self.window_blocks_released = 0
         # recurrent state (0: none): one fixed-size row a slot, allocated
         # with the pool - a slot IS its state row, so a free slot is the
         # admission check for it and these bytes are what it stands for
@@ -377,7 +443,16 @@ class StateManager:
             for i in range(first, last + 1):
                 if self.allocator.refcount(d.blocks[i]) > 1:
                     need += 1          # COW copy before the write lands
-        return max(0, need - self.headroom_blocks)
+        short = max(0, need - self.headroom_blocks)
+        for kind in self.window_kinds:
+            # what each sequence will hold once it has given back what lies
+            # behind the window, less what it holds now
+            grow = sum(max(0, (d.seen_tokens + n + bs - 1) // bs
+                           - self.first_live(kind, d.seen_tokens)
+                           - self.window_held(d, kind)) for d in descs)
+            short += max(0, grow
+                         - self.window_allocators[kind.name].free_blocks)
+        return short
 
     def _admit_need(self, prompt_len: int) -> int:
         """Blocks for the prompt + one pre-reserved decode block, capped at
@@ -396,7 +471,11 @@ class StateManager:
         Granite-4.0-H-Micro where its KV is 8 KB a token): the free slot
         checked here is that row, so no sequence is admitted without one."""
         avail = self.allocator.free_blocks + self.index.retained_blocks
-        return bool(self._free_slots) and avail >= self._admit_need(prompt_len)
+        return bool(self._free_slots) \
+            and avail >= self._admit_need(prompt_len) \
+            and all(self.window_allocators[k.name].free_blocks
+                    >= min(self._admit_need(prompt_len), k.blocks_per_seq)
+                    for k in self.window_kinds)
 
     def enable_host_spill(self, pool, reader, writer) -> None:
         """Arm the host-spill tier: ``pool`` is a
@@ -405,6 +484,10 @@ class StateManager:
         ``writer(block, data)`` stamps spilled contents into a freshly
         allocated device block. Called by the engine when
         ``inference.prefix_cache.host_spill`` is on."""
+        if self.window_kinds:
+            raise KVKindError(
+                "inference.prefix_cache.host_spill",
+                "it spills and restores prefix-cache blocks")
         self.spill_pool = pool
         self._spill_read = reader
         self._spill_write = writer
@@ -529,6 +612,10 @@ class StateManager:
         ``debug_check`` invariants all apply to imported blocks exactly as
         to locally produced ones. A later ``admit_prompt`` on the same
         token prefix then matches it as an ordinary admit-time hit."""
+        if self.window_kinds:
+            raise KVKindError(
+                "adopt_block (the disagg wire)", "a shipped block is the "
+                "full kind's; the window layers' state would be missing")
         if not self.prefix_cache or h in self.index._by_hash:
             return None
         self._reclaim(1)
@@ -549,6 +636,10 @@ class StateManager:
         """Admit ``new_uid`` sharing ALL of ``uid``'s blocks (parallel
         sampling / best-of-n). Both sequences now share the partial tail
         block; whichever appends first triggers copy-on-write."""
+        if self.window_kinds:
+            raise KVKindError(
+                "fork", "a child shares its parent's blocks, and a window "
+                "kind's are given back under the one that moves ahead")
         parent = self.lookup(uid)
         if parent.prefilling:
             raise ValueError(f"uid {uid} is still prefilling — cannot fork")
@@ -646,6 +737,19 @@ class StateManager:
                 f"(0, {desc.seen_tokens}]")
         bs = self.block_size
         n_keep = (new_len + bs - 1) // bs
+        for kind in self.window_kinds:
+            # the rolled-back suffix is rewritten from ``new_len`` on, and
+            # its first row reads the window behind it
+            held = desc.window_blocks.get(kind.name, [])
+            first = self.first_live(kind, new_len)
+            if any(b == 0 for b in held[first:n_keep]):
+                raise KVKindError(
+                    f"truncate(uid={desc.uid}) to {new_len} tokens",
+                    f"the {kind.name!r} layers gave back blocks inside the "
+                    f"window of position {new_len}")
+            alloc = self.window_allocators[kind.name]
+            while len(held) > n_keep:
+                alloc.free([held.pop()])    # inside the window: held
         while len(desc.blocks) > n_keep:
             self._release_block(desc.blocks.pop())
         del desc.tokens[new_len:]
@@ -679,6 +783,52 @@ class StateManager:
             desc.blocks.extend(self.allocator.allocate(blocks))
         if len(desc.blocks) > self.max_blocks_per_seq:
             raise MemoryError(f"sequence {desc.uid} exceeds max_blocks_per_seq")
+        for kind in self.window_kinds:
+            self._window_extend(desc, kind, need)
+
+    def first_live(self, kind: WindowKind, seen_tokens: int) -> int:
+        """The first block of ``kind`` a call whose first row sits at
+        position ``seen_tokens`` can read: that row's window starts at
+        ``seen_tokens - window + 1``, and every later row's starts later
+        (the kernels' own bound: ``ops/pallas/paged_attention.py``
+        ``_table_walk`` ``lo_pg``)."""
+        return max(0, seen_tokens - kind.window + 1) // self.block_size
+
+    @staticmethod
+    def window_held(desc: SequenceDescriptor, kind: WindowKind) -> int:
+        """Blocks of ``kind`` the sequence holds: all but those at the front
+        of its list."""
+        return len(desc.window_blocks.get(kind.name, ())) \
+            - desc.window_given.get(kind.name, 0)
+
+    def _window_extend(self, desc: SequenceDescriptor, kind: WindowKind,
+                       upto_tokens: int) -> None:
+        """``kind``'s blocks of ``desc`` for a call that writes positions
+        ``[seen_tokens, upto_tokens)``: the blocks wholly behind the first
+        row's window go back to the kind's free list (their table entries
+        to the trash block), then the blocks the call writes into are
+        claimed. Giving back first is what keeps a sequence within
+        ``blocks_per_seq``."""
+        alloc = self.window_allocators[kind.name]
+        held = desc.window_blocks.setdefault(kind.name, [])
+        first = self.first_live(kind, desc.seen_tokens)
+        given = desc.window_given.get(kind.name, 0)
+        for j in range(given, min(first, len(held))):
+            alloc.free([held[j]])
+            held[j] = 0
+            self.window_blocks_released += 1
+        want = (upto_tokens + self.block_size - 1) // self.block_size
+        if want - first > kind.blocks_per_seq:
+            raise MemoryError(
+                f"sequence {desc.uid}: a call of "
+                f"{upto_tokens - desc.seen_tokens} tokens needs "
+                f"{want - first} {kind.name!r} blocks, and the kind was "
+                f"sized for {kind.blocks_per_seq} a sequence")
+        while len(held) < want:
+            # a block behind the window of a sequence that enters mid-way
+            # is never claimed
+            held.append(alloc.allocate(1)[0] if len(held) >= first else 0)
+        desc.window_given[kind.name] = max(given, min(first, len(held)))
 
     def _release_block(self, b: int) -> None:
         """Drop one reference; a block reaching refcount 0 is RETAINED (LRU)
@@ -705,14 +855,45 @@ class StateManager:
         else:
             for b in desc.blocks:
                 self._release_block(b)
+        for name, held in desc.window_blocks.items():
+            self.window_allocators[name].free([b for b in held if b])
+        desc.window_blocks, desc.window_given = {}, {}
         self._free_slots.append(desc.slot)
         return desc
 
+    @property
+    def table_width(self) -> int:
+        """Entries of a sequence's block table: ``max_blocks_per_seq`` of the
+        full kind, then ``1 + blocks_per_seq`` a window kind
+        (``models/_paged.kind_tables``)."""
+        return self.max_blocks_per_seq + sum(
+            1 + k.blocks_per_seq for k in self.window_kinds)
+
     def block_table(self, desc: SequenceDescriptor) -> np.ndarray:
-        """Fixed-width table; unused entries point at the trash block 0."""
-        t = np.zeros((self.max_blocks_per_seq,), np.int32)
+        """Fixed-width table; unused entries point at the trash block 0.
+        With window kinds a segment a kind follows the full kind's: its
+        first entry is the sequence's OFFSET in that kind, the blocks it
+        has given back at its front, and after it the blocks it holds, the
+        first live one first - so the kernels walk ``blocks_per_seq``
+        entries of a window layer, not the context's, at positions counted
+        from ``offset * block_size`` (the family shifts the context lengths
+        by that much; masks and writes only ever compare positions of one
+        sequence, and the offset is whole blocks)."""
+        t = np.zeros((self.table_width,), np.int32)
         t[:len(desc.blocks)] = desc.blocks
+        at = self.max_blocks_per_seq
+        for kind in self.window_kinds:
+            first = desc.window_given.get(kind.name, 0)
+            live = desc.window_blocks.get(kind.name, ())[first:]
+            t[at] = first
+            t[at + 1:at + 1 + len(live)] = live
+            at += 1 + kind.blocks_per_seq
         return t
+
+    def window_blocks_live(self, name: str) -> int:
+        """Blocks of window kind ``name`` that sequences hold."""
+        alloc = self.window_allocators[name]
+        return alloc.num_blocks - 1 - alloc.free_blocks
 
     # ------------------------------------------------------------------ #
     def debug_check(self) -> None:
@@ -746,6 +927,39 @@ class StateManager:
             alloc.num_blocks - 1, "free + live + retained != pool size"
         n_slots = len(self._free_slots) + len(self.seqs)
         assert n_slots == self.max_sequences, "slot accounting broken"
+        for kind in self.window_kinds:
+            alloc = self.window_allocators[kind.name]
+            free = set(alloc._free)
+            assert len(free) == len(alloc._free) and 0 not in free, \
+                f"{kind.name}: free list broken"
+            held: Dict[int, int] = {}
+            for d in self.seqs.values():
+                mine = d.window_blocks.get(kind.name, [])
+                first = self.first_live(kind, d.seen_tokens)
+                given = d.window_given.get(kind.name, 0)
+                assert not any(mine[:given]) and all(mine[given:]), \
+                    f"uid {d.uid}: its {kind.name} blocks are not the " \
+                    f"{given} given back and then the held"
+                # what a call at ``seen_tokens`` can read is there
+                assert all(mine[first:]), \
+                    f"uid {d.uid}: a {kind.name} block inside the window " \
+                    f"of position {d.seen_tokens} was given back"
+                assert len(mine) * self.block_size >= d.seen_tokens, \
+                    f"uid {d.uid}: {kind.name} blocks cannot cover " \
+                    f"{d.seen_tokens} seen tokens"
+                assert self.window_held(d, kind) <= kind.blocks_per_seq, \
+                    f"uid {d.uid}: holds more {kind.name} blocks than " \
+                    f"{kind.blocks_per_seq}"
+                for b in mine:
+                    if b:
+                        held[b] = held.get(b, 0) + 1
+            assert all(n == 1 for n in held.values()), \
+                f"{kind.name}: a block is held twice"
+            assert not free & set(held), f"{kind.name}: a held block is free"
+            assert len(free) + len(held) == alloc.num_blocks - 1, \
+                f"{kind.name}: free + held != pool size"
+            for b in held:
+                assert alloc.refcount(b) == 1, f"{kind.name}: refcount of {b}"
         bs = self.block_size
         for d in self.seqs.values():
             assert len(d.blocks) * bs >= d.seen_tokens, \

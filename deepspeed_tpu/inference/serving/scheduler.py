@@ -445,6 +445,7 @@ class ServingScheduler:
             now = self._clock()
             wrote = eng.prefill_tokens_written
             drains = sum(eng.drains.values())
+            released = eng.state.window_blocks_released
             self._admit_tokens = 0
             with span("sched_expire", cat="serving"):
                 if self.cfg.drop_expired:
@@ -474,6 +475,10 @@ class ServingScheduler:
                 + sum(len(v) for v in emitted.values()),
                 # the drains that read a program during the tick
                 "drains": sum(eng.drains.values()) - drains}
+            if eng.state.window_kinds:
+                # blocks the window layers' kind gave back behind the window
+                self.last_tick["window_blocks_released"] = \
+                    eng.state.window_blocks_released - released
             tick.set(**self.last_tick)
         self.stats["prefill_tokens"] += self.last_tick["prefill_tokens"]
         self.stats["decode_seq_steps"] += self.last_tick["decode_seqs"]

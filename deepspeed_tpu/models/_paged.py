@@ -40,6 +40,18 @@ or the expert bank, the head - reads its weights once for both. Only
 :func:`paged_attention_step` splits the rows back into the two segments it
 has kernels for.
 
+Kinds of KV state (``inference.ragged.WindowKind``): a family whose stack
+mixes full-attention and sliding-window layers keeps ONE such buffer a kind,
+``[L_kind, blocks_kind, nkv, bs, hd]`` (:func:`init_kind_pools`: ``k`` /
+``v`` the full kind's, ``k_<kind>`` / ``v_<kind>`` a window kind's), each
+through its own scan's carry, and its calls' block tables are one segment a
+kind side by side (:func:`kind_tables`): a window kind's is SHORT - the
+blocks a sequence holds from its first live one on, behind the count of
+those it gave back - and its layers walk it at context lengths counted from
+there. A layer hands :func:`paged_attention_step` its kind's pools, its
+kind's table and lengths and, for a window kind, the window - the kernels
+bound their walk by it, so what the manager gave back is never reached.
+
 Reads: both kernels walk the block table over the live context
 (``ops/pallas/paged_attention.py``). Nothing here gathers a dense view of
 the pool; only the ops' XLA references do, and the registry picks those off
@@ -48,7 +60,7 @@ a TPU alone.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,6 +114,55 @@ def init_paged_pools(num_layers: int, num_blocks: int, num_kv_heads: int,
             "v": jnp.zeros(shape, jnp.int8),
             "k_scale": jnp.zeros(sshape, jnp.float32),
             "v_scale": jnp.zeros(sshape, jnp.float32)}
+
+
+def init_kind_pools(layers: Dict[str, int], blocks: Dict[str, int],
+                    num_kv_heads: int, block_size: int, head_size: int,
+                    dtype=jnp.bfloat16):
+    """One K and one V pool a KIND of KV state (:func:`init_paged_pools`
+    each): ``layers`` and ``blocks`` give each kind's layers and blocks;
+    the kind named ``"full"`` keeps the historical ``k`` / ``v`` leaves, any
+    other is ``k_<kind>`` / ``v_<kind>``."""
+    cache = {}
+    for kind, n in layers.items():
+        pools = init_paged_pools(n, blocks[kind], num_kv_heads, block_size,
+                                 head_size, dtype)
+        suffix = "" if kind == "full" else "_" + kind
+        cache.update({name + suffix: pool for name, pool in pools.items()})
+    return cache
+
+
+def kind_tables(block_tables, context_lens, full_width: int,
+                block_size: int) -> Tuple:
+    """A call's block tables and context lengths as each kind's own:
+    ``((tables, context_lens), ...)``, the full kind's first. The table a
+    ``StateManager`` with window kinds builds is the full kind's
+    ``full_width`` entries, then a segment a window kind: the blocks the
+    sequence has given back at its front (its OFFSET in that kind) and the
+    blocks it holds from the first live one on. A window layer walks that
+    short table at context lengths counted from the offset - positions only
+    ever meet positions of the same sequence, in masks and in the page a
+    row is written to, and the offset is whole blocks, so every comparison
+    stands; rope takes the true positions elsewhere. A :class:`MixedCall`
+    splits both of its tables and shifts ``lens`` and ``chunk_ctx`` the same
+    way. (ONE window kind, as the one family with kinds has: everything
+    after the full kind's entries is its segment.)"""
+    mixed = isinstance(block_tables, MixedCall)
+    tables = block_tables.tables if mixed else block_tables
+    if tables.shape[1] <= full_width:   # one table for every kind
+        return ((block_tables, context_lens),)
+    seg = tables[:, full_width:]
+    if not mixed:
+        return ((tables[:, :full_width], context_lens),
+                (seg[:, 1:], context_lens - seg[:, 0] * block_size))
+    call = block_tables
+    chunk_seg = call.chunk_table[full_width:]
+    return ((call._replace(tables=tables[:, :full_width],
+                           chunk_table=call.chunk_table[:full_width]), None),
+            (call._replace(
+                tables=seg[:, 1:], chunk_table=chunk_seg[1:],
+                lens=call.lens - seg[:, 0] * block_size,
+                chunk_ctx=call.chunk_ctx - chunk_seg[0] * block_size), None))
 
 
 class LayerPool(NamedTuple):

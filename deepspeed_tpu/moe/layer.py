@@ -95,7 +95,9 @@ class MoELayer:
                  capacity_factor: float = 1.25, min_capacity: int = 4,
                  drop_tokens: bool = True, norm_topk: bool = True,
                  dispatch: str = "einsum",
-                 held: Optional[Tuple[int, int]] = None):
+                 held: Optional[Tuple[int, int]] = None,
+                 score: str = "softmax",
+                 shared_scale: Optional[float] = None):
         self.n_experts = n_experts
         self.top_k = top_k
         self.capacity_factor = capacity_factor
@@ -121,6 +123,12 @@ class MoELayer:
                 raise ValueError(f"held experts {held} are not a range of "
                                  f"the {n_experts} routed")
         self.held = held
+        # the router's score function (``sharded_moe.SCORES``) and, for
+        # shared experts with no gate of their own, what their summed
+        # output is scaled by (``n`` averaged experts of one width are one
+        # FFN ``n`` times as wide, times ``1 / n``)
+        self.score = score
+        self.shared_scale = shared_scale
 
     def grouped(self) -> bool:
         """Whether a call over the stacked banks takes the grouped form - the
@@ -160,21 +168,30 @@ class MoELayer:
             if layer is not None:
                 bank = [w[layer] for w in bank]
             out, aux_loss = self._slabs(tokens, logits, bank)
-        # Qwen2-MoE shared expert: a dense SwiGLU added to every token,
-        # scaled by a learned sigmoid gate (params present only when used)
+        # shared experts: a dense SwiGLU added to every token (params
+        # present only when used) - Qwen2-MoE's under a learned sigmoid
+        # gate; without a gate of its own (cohere2_moe: ``shared_scale``) a
+        # plain FFN, which runs under that scope
         if "shared_w_gate" in params:
-            with jax.named_scope("moe_experts"):
+            gated = "shared_gate" in params
+            with jax.named_scope("moe_experts" if gated else "ffn"):
                 sg = jax.nn.silu(tokens @ params["shared_w_gate"].astype(tokens.dtype))
                 su = tokens @ params["shared_w_up"].astype(tokens.dtype)
                 shared = (sg * su) @ params["shared_w_down"].astype(tokens.dtype)
-                gate = jax.nn.sigmoid(tokens @ params["shared_gate"].astype(tokens.dtype))
-                out = out + gate * shared
+                if gated:
+                    gate = jax.nn.sigmoid(tokens @ params["shared_gate"].astype(tokens.dtype))
+                    shared = gate * shared
+                elif self.shared_scale is not None:
+                    shared = shared * jnp.asarray(self.shared_scale,
+                                                  shared.dtype)
+                out = out + shared
         return out.reshape(b, s, h), aux_loss
 
     def _gate_kw(self):
         return dict(capacity_factor=self.capacity_factor,
                     min_capacity=self.min_capacity,
-                    drop_tokens=self.drop_tokens, norm_topk=self.norm_topk)
+                    drop_tokens=self.drop_tokens, norm_topk=self.norm_topk,
+                    score=self.score)
 
     def _grouped(self, tokens, logits, bank, layer):
         """The no-drop form: O(k·T·H) movement around a bank that computes

@@ -60,6 +60,11 @@ class RowGroups(NamedTuple):
     tile: int                      # rows a tile
 
 
+# what a router logit becomes before the top-k, by name
+SCORES = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+          "sigmoid": jax.nn.sigmoid}
+
+
 def compute_capacity(tokens: int, n_experts: int, k: int,
                      capacity_factor: float, min_capacity: int = 4) -> int:
     cap = int(math.ceil(k * tokens * capacity_factor / n_experts))
@@ -69,18 +74,26 @@ def compute_capacity(tokens: int, n_experts: int, k: int,
 def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
                          capacity_factor: float = 1.0, min_capacity: int = 4,
                          drop_tokens: bool = True,
-                         norm_topk: bool = True) -> CompactGating:
+                         norm_topk: bool = True,
+                         score: str = "softmax") -> CompactGating:
     """logits: [tokens, experts] → compact assignment (see CompactGating).
 
     The reference's top1/top2/topk gating family as one k-generic routine
     (drop policy = capacity truncation); position assignment is priority by
     token order within each k-level, levels sequential (reference: top1
     first). ``norm_topk=False`` keeps the raw softmax probs of the selected
-    experts (Qwen2-MoE's norm_topk_prob=False). Biggest live tensor is the
-    [T, E] cumsum — the dense [T, E, C] view exists only in
+    experts (Qwen2-MoE's norm_topk_prob=False). ``score``: what a logit
+    becomes before the top-k - ``"softmax"`` over the experts, or
+    ``"sigmoid"``, each expert's score by itself (``expert_selection_fn``
+    of the cohere2_moe family; with ``norm_topk`` the gates are the chosen
+    scores over their sum), in float32 either way. Biggest live tensor is
+    the [T, E] cumsum — the dense [T, E, C] view exists only in
     :func:`top_k_gating` for the einsum dispatch."""
     tokens, n_experts = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if score not in SCORES:
+        raise ValueError(f"score must be one of {sorted(SCORES)}, "
+                         f"got {score!r}")
+    probs = SCORES[score](logits.astype(jnp.float32))
 
     topk_probs, topk_idx = jax.lax.top_k(probs, k)          # [T, k]
     if norm_topk:
@@ -193,7 +206,8 @@ def top_k_gating(logits: jnp.ndarray, k: int = 1, *,
                  capacity_factor: float = 1.0, min_capacity: int = 4,
                  drop_tokens: bool = True,
                  norm_topk: bool = True,
-                 held: Optional[Tuple[int, int]] = None) -> GatingOutput:
+                 held: Optional[Tuple[int, int]] = None,
+                 score: str = "softmax") -> GatingOutput:
     """Dense [T, E, C] view of :func:`top_k_gating_compact` — the form the
     einsum dispatch contracts with (MXU-friendly, but O(T·E·C) memory).
     ``held = (first, count)``: the masks of experts ``first .. first + count
@@ -201,7 +215,8 @@ def top_k_gating(logits: jnp.ndarray, k: int = 1, *,
     aux loss) is over all the experts either way."""
     cg = top_k_gating_compact(logits, k, capacity_factor=capacity_factor,
                               min_capacity=min_capacity,
-                              drop_tokens=drop_tokens, norm_topk=norm_topk)
+                              drop_tokens=drop_tokens, norm_topk=norm_topk,
+                              score=score)
     tokens, n_experts = logits.shape
     chosen = cg.topk_idx
     if held is not None:

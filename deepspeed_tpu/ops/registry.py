@@ -145,8 +145,17 @@ def get_op(name: str) -> Callable:
 
 
 def resolved() -> Dict[str, str]:
-    """op -> the backend a call resolves to right now, for every op."""
-    return {name: _resolve(name)[0] for name in sorted(_REGISTRY)}
+    """op -> the backend a call resolves to right now, for every op; "none"
+    for an op that has no implementation for this platform (a call of it
+    raises: ``ops/pallas/sparse_attention.py`` registers a kernel alone, and
+    a survey of every op must not fall over the one it cannot run)."""
+    out = {}
+    for name in sorted(_REGISTRY):
+        try:
+            out[name] = _resolve(name)[0]
+        except KeyError:
+            out[name] = "none"
+    return out
 
 
 def op(name: str) -> Callable:

@@ -90,6 +90,10 @@ SERVING_SERIES = frozenset(
     # a learned token selection inside attention (docs/serving.md "Learned
     # token selection" - engine_v2.sparse_events): ONE layer's counts
     + ["Serving/sparse/" + m for m in ("rows", "ctx_scored", "kv_selected")]
+    # kinds of KV state (a family with sliding-window layers;
+    # docs/serving.md "Kinds of KV state" - engine_v2.kv_kind_events)
+    + ["Serving/kv/" + m for m in (
+        "full_blocks_live", "window_blocks_live", "window_blocks_released")]
     # what step() ran (engine_v2.engine_events): its calls, those whose
     # prefill chunk rode in the decode program (``decode_chunk``), and those
     # launched while the program before was still unread

@@ -566,6 +566,73 @@ def test_the_learned_selections_mixed_program_keeps_three_pools_in_place(v5e):
     assert not re.findall(r"= bf16\[128,\d+,768\]", text)
 
 
+COMMAND_A_CELL = "command-a-plus-05-2026.serve-longctx"
+
+
+def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
+    """The Command A+ cell's mixed call (16 decode rows + a 512-row chunk) at
+    its real configuration, compiled for the chip: the full kind's pools AND
+    the window kind's stay where they are (no pool-shaped copy, every pool
+    aliased argument-to-result), a period's body is two writes and two walks
+    a kind - the window layers' scan and the full layer's -, and the whole
+    program with its 11.35 GB of weights and 2.56 GB of pools fits the
+    chip."""
+    import math
+    import re
+
+    from benchmark.harness.manifest import Cell
+    from deepspeed_tpu.inference.ragged import WindowKind
+    from deepspeed_tpu.models._paged import MixedCall
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    cell = Cell(COMMAND_A_CELL)
+    engine = cell.role["engine"]
+    ragged = engine["ragged"]
+    slots, bs = ragged["max_tracked_sequences"], ragged["block_size"]
+    chunk = engine["split_prefill_chunk"]
+    cfg = cell.family.build_cfg(cell.model, **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: module.init(cfg, k), jax.random.PRNGKey(0)))
+    kind = WindowKind.sized("window", cfg.sliding_window, slots, chunk, bs)
+    assert (kind.blocks_per_seq, kind.num_blocks) == (145, 2321)
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, ragged["memory_config_blocks"], bs,
+        window_blocks={"window": kind.num_blocks}))
+    assert {k: v.shape[:2] for k, v in cache.items()} == {
+        "k": (1, 12544), "v": (1, 12544),
+        "k_window": (3, 2321), "v_window": (3, 2321)}
+    table = cfg.max_seq_len // bs + 1 + kind.blocks_per_seq
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    call = MixedCall(s((slots, table), i32), s((slots,), i32),
+                     s((slots,), bool), s((table,), i32), s((), i32),
+                     s((), i32))
+    rows = slots + chunk
+
+    def forward(params, cache, tokens, tables, valid):
+        return module.apply_paged(cfg, params, tokens, cache, tables, None,
+                                  valid=valid)
+
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        (params, cache, s((1, rows), i32), call, s((1, rows), bool)))
+    compiled = jax.jit(forward, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pools = jax.tree.leaves(args[1])
+    assert pool_copy_bytes(text, pools) == 0
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert pool_bytes == (12544 + 3 * 2321) * 32 * 4096
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert 0 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    for name, count in (("paged_kv_write", 4), ("paged_decode", 2),
+                        ("paged_prefill", 2), ("moe_grouped_matmul", 2)):
+        assert calls.count(name) == count, (name, calls.count(name))
+
+
 @pytest.mark.parametrize("cell", SERVE_CELLS + (GRANITE_CELL,))
 def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
     """The four serve cells' mixed call (``slots + 256`` rows) at their real

@@ -1046,6 +1046,11 @@ def _scopes_of(fn, *args):
                          "logits", "sample"}),
     ("mixtral", "decode", {"embed", "norm", "attn", "kv_write", "moe_router",
                            "moe_experts", "logits", "sample"}),
+    # window and full layers each under a scope of their own inside attn,
+    # the ungated shared experts under ffn (ISSUE 42)
+    ("cohere2_moe", "decode", {"embed", "norm", "attn", "attn_window",
+                               "attn_full", "kv_write", "moe_router",
+                               "moe_experts", "ffn", "logits", "sample"}),
 ])
 def test_model_step_blocks_are_named_scopes(devices8, family, program, want):
     """The block boundaries of the model step are in the ``op_name`` of the
@@ -1055,7 +1060,9 @@ def test_model_step_blocks_are_named_scopes(devices8, family, program, want):
     import jax
 
     mod = importlib.import_module(f"deepspeed_tpu.models.{family}")
-    cfg = (mod.LlamaConfig if family == "llama" else mod.MixtralConfig).tiny()
+    cfg = {"llama": "LlamaConfig", "mixtral": "MixtralConfig",
+           "cohere2_moe": "Cohere2MoeConfig"}[family]
+    cfg = getattr(mod, cfg).tiny()
     if program == "train":
         engine, *_ = dst.initialize(
             model=mod.model_spec(cfg, compute_dtype=jnp.float32),
