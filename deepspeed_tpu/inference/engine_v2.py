@@ -21,6 +21,7 @@ serving then emits >1 token per model step without a second model.
 
 from __future__ import annotations
 
+import inspect
 import time
 from collections import Counter, deque
 from itertools import repeat
@@ -32,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..comm.mesh import MeshManager
-from ..models._paged import MixedCall
+from ..models._paged import MixedCall, gather_rows
 from ..ops.quantization import kv_dequantize_int8, kv_quantize_int8
 from ..telemetry.compile import CompileMonitor
 from ..telemetry.schema import DRAIN_CAUSES
@@ -137,14 +138,11 @@ class _Flight(NamedTuple):
     seq: int                    # the launch's number (``_next_seq``)
 
 
-def _last_row(logits, lengths):
-    """Logits of each sequence's last REAL row, traced: ``logits`` [n, t, V]
-    with ``lengths`` [n] give [n, V]; one chunk's scalar length gives [V]."""
-    idx = jnp.maximum(lengths - 1, 0)
-    if idx.ndim == 0:
-        return jnp.take_along_axis(logits, idx[None, None, None],
-                                   axis=1)[0, 0]
-    return jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
+def _last_real(lengths):
+    """The index of each sequence's last REAL row, traced: ``lengths`` [n]
+    give [n] (0 for a dummy row of length 0); a chunk's scalar length gives
+    a scalar."""
+    return jnp.maximum(lengths - 1, 0)
 
 
 def _sampler(rows: bool):
@@ -194,6 +192,11 @@ class InferenceEngineV2(InferenceEngine):
             import deepspeed_tpu.models.llama as _llama  # default family
             self._apply_paged = _llama.apply_paged
             self._init_paged = _llama.init_paged_cache
+        # the rows a program reads (``_paged_forward``): an ``apply_paged``
+        # that DECLARES ``rows`` runs its head on those alone; any other
+        # scores every row and the engine picks from the result
+        self._takes_rows = "rows" in inspect.signature(
+            self._apply_paged).parameters
         max_blocks_per_seq = max(
             2, (self.family.cfg.max_seq_len + rc.block_size - 1) // rc.block_size)
         # --- recurrent state (docs/serving.md "Recurrent state"): a family
@@ -382,6 +385,11 @@ class InferenceEngineV2(InferenceEngine):
         self.steps = 0
         self.mixed_steps = 0
         self.overlapped_steps = 0
+        # the token rows the launched forward programs ran and the rows
+        # their heads scored (``_row_args``; ``Serving/engine/{rows,
+        # head_rows}``)
+        self.rows = 0
+        self.head_rows = 0
         # what the learned selection did, ONE layer's count, cumulative
         # (``Serving/sparse/*``): rows that selected, the cached tokens they
         # scored, the tokens attention then read
@@ -682,14 +690,14 @@ class InferenceEngineV2(InferenceEngine):
             **jit_kwargs)
 
     # ------------------------------------------------------------------ #
-    # the programs: ONE forward (``_paged_forward``), the last real row's
-    # logits (``_last_row``), one of two samplers (``_sampler``), in five
+    # the programs: ONE forward (``_paged_forward``) that scores the rows
+    # the program reads, one of two samplers (``_sampler``), in five
     # builders - prefill, chunk_prefill, decode, decode_chunk (a step's
     # chunk and its decodes in one forward), spec_verify. ``_dispatch``
     # launches all of them.
     # ------------------------------------------------------------------ #
     def _paged_forward(self, params, tokens, cache, tables, ctx, valid,
-                       slots=None):
+                       slots=None, rows=None):
         """The engine's ONE call of the family's paged forward, traced inside
         every program: ``tokens`` [b, t] at context offsets ``ctx`` [b]
         through block tables [b, blocks], ``params`` as ``_dq`` hands them
@@ -699,10 +707,21 @@ class InferenceEngineV2(InferenceEngine):
         [b]: each row's sequence slot, for a family with recurrent state and
         a call whose rows are not the slots in order (the prefills; a
         decode-shaped call's row i IS slot i, the family's default).
-        Returns (logits [b, t, V] fp32, cache)."""
+        ``rows`` [b, r]: the rows along ``t`` whose logits the program
+        reads (a prefill's last real rows; a mixed call's decode rows and
+        its chunk's last real row) - the family's head runs on those alone
+        where its ``apply_paged`` declares ``rows`` (``_takes_rows``), else
+        on every row and the rows are picked here; None (``decode``,
+        ``verify``) reads them all.
+        Returns (logits [b, t, V] fp32 - [b, r, V] with ``rows`` -, cache)."""
         kw = {} if slots is None else {"slots": slots}
-        return self._apply_paged(self.family.cfg, params, tokens, cache,
-                                 tables, ctx, valid=valid, **kw)
+        if self._takes_rows:
+            kw["rows"] = rows
+        logits, cache = self._apply_paged(self.family.cfg, params, tokens,
+                                          cache, tables, ctx, valid=valid,
+                                          **kw)
+        return (logits if self._takes_rows
+                else gather_rows(logits, rows)), cache
 
     def _prefill_fn(self, pad_t: int, n: int, with_ctx: bool, rows: bool):
         """One compiled prefill over ``n`` admitted sequences at once —
@@ -742,11 +761,11 @@ class InferenceEngineV2(InferenceEngine):
                 dq = self._dq(params)
                 if not with_ctx:
                     ctx = jnp.zeros((n,), jnp.int32)
-                logits, cache = self._paged_forward(dq, tokens, cache, tables,
-                                                    ctx, valid, slots)
-                last = _last_row(logits, lengths)
+                logits, cache = self._paged_forward(
+                    dq, tokens, cache, tables, ctx, valid, slots,
+                    rows=_last_real(lengths)[:, None])
                 keys = jax.vmap(lambda u: jax.random.fold_in(rng, u))(uids)
-                toks = jax.vmap(pick)(keys, last, *sp_rows)
+                toks = jax.vmap(pick)(keys, logits[:, 0], *sp_rows)
                 return toks.astype(jnp.int32), cache
 
             self._paged_fns[key] = self._jit(key, prefill, donate_argnums=(1,))
@@ -861,13 +880,15 @@ class InferenceEngineV2(InferenceEngine):
                 # sequence's slot (recurrent state), rng, uid
                 *slot, rng, uid = rest
                 valid = (jnp.arange(chunk_t) < n_valid)[None, :]
+                # a chunk that does not end its prompt asks for no logits
+                # (its head is dead code); a final one reads its last real row
                 logits, cache = self._paged_forward(
                     self._dq(params), tokens, cache, table[None], ctx[None],
-                    valid, *(s_[None] for s_ in slot))
+                    valid, *(s_[None] for s_ in slot),
+                    rows=_last_real(n_valid)[None, None] if final else None)
                 if not final:
                     return cache
-                last = _last_row(logits, n_valid)
-                tok = sample(jax.random.fold_in(rng, uid), last, sp)
+                tok = sample(jax.random.fold_in(rng, uid), logits[0, 0], sp)
                 return tok.astype(jnp.int32), cache
 
             self._paged_fns[key] = self._jit(key, chunk_prefill,
@@ -895,6 +916,17 @@ class InferenceEngineV2(InferenceEngine):
         + this call's tokens): what the flash kernel walks of the table's
         ``max_blocks_per_seq``."""
         return -(-kv_tokens // self.state.block_size)
+
+    def _row_args(self, rows: int, read: int) -> Dict[str, int]:
+        """Span arguments of a launch over ``rows`` token rows (padding
+        included) whose program reads ``read`` rows' logits: ``rows`` and
+        ``head_rows``, the rows its head scores - ``read`` where the
+        family's ``apply_paged`` takes the rows (``_takes_rows``), else
+        every row. Counted as they are said (``engine_events``)."""
+        head = read if self._takes_rows else rows
+        self.rows += rows
+        self.head_rows += head
+        return {"rows": rows, "head_rows": head}
 
     def _moe_args(self, rows: int) -> Dict[str, int]:
         """Span arguments of a call over ``rows`` token rows (padding
@@ -1015,7 +1047,8 @@ class InferenceEngineV2(InferenceEngine):
             return False
         ch = self._next_chunk()
         rec = self._req.get(ch.uid)     # the request's ring lifecycle
-        rows = ch.width + (len(self._slot_tokens) if mixed else 0)
+        slots = len(self._slot_tokens) if mixed else 0
+        rows = ch.width + slots
         seq = self._next_seq()
         with self.tracer.span(
                 "prefill_chunk", cat="serving", seq=seq,
@@ -1023,6 +1056,9 @@ class InferenceEngineV2(InferenceEngine):
                 parent=rec["span"].span_id if rec else None,
                 table_blocks=self.state.max_blocks_per_seq,
                 **self._chunk_args(ch), **self._moe_args(rows),
+                # ``chunk_prefill`` reads its last real row, and none of a
+                # chunk that does not end its prompt
+                **self._row_args(rows, slots + int(mixed or ch.final)),
                 **self._ssm_args(1, len(ch.tokens)),
                 **self._sparse_args(self._chunk_contexts(ch)),
                 **self._kv_kind_args([ch.ctx], [len(ch.tokens)])):
@@ -1166,13 +1202,17 @@ class InferenceEngineV2(InferenceEngine):
                 b = tokens.shape[0]
                 call = MixedCall(tables, lens, active, table, ctx, n_valid,
                                  slot)
+                # the rows the program reads: every slot's decode row and
+                # the chunk's last real one, ``slots + 1`` of ``slots +
+                # chunk_t``
+                rows = jnp.concatenate(
+                    [jnp.arange(b), b + _last_real(n_valid)[None]])[None]
                 logits, cache = self._paged_forward(
                     self._dq(params),
                     jnp.concatenate([tokens, chunk[0]])[None], cache, call,
-                    None, call.valid(b + chunk_t))
+                    None, call.valid(b + chunk_t), rows=rows)
                 nxt = pick(rng, logits[0, :b], *(a[:b] for a in sp_rows))
-                first = pick(jax.random.fold_in(rng, uid),
-                             _last_row(logits[:, b:], n_valid),
+                first = pick(jax.random.fold_in(rng, uid), logits[0, b],
                              *(a[b] for a in sp_rows))
                 return jnp.concatenate([nxt, first[None]]).astype(
                     jnp.int32), cache
@@ -1384,6 +1424,7 @@ class InferenceEngineV2(InferenceEngine):
                               kv_blocks=self._kv_blocks(max(kv_rows)),
                               table_blocks=self.state.max_blocks_per_seq,
                               **self._moe_args(n_pad * pad_t),
+                              **self._row_args(n_pad * pad_t, n_pad),
                               **self._ssm_args(
                                   n, sum(kv_rows) - sum(cached), True)):
             with self.tracer.span("engine_prep", cat="serving"):
@@ -1597,6 +1638,8 @@ class InferenceEngineV2(InferenceEngine):
         with self.tracer.span(
                 "decode_step", cat="serving", seq=seq, batch=len(live),
                 overlapped=overlapped, **self._moe_args(n_rows),
+                **self._row_args(n_rows, len(self._slot_tokens)
+                                 + (ch is not None)),
                 **self._ssm_args(len(live), len(live)),
                 **self._sparse_args([d.seen_tokens + 1 for d in live]),
                 **self._kv_kind_args([d.seen_tokens for d in live],
@@ -2272,12 +2315,18 @@ class InferenceEngineV2(InferenceEngine):
         chunk met live decodes in a family that takes a mixed call); and
         ``overlapped_steps``, those whose decode program was launched while
         the one before was still unread (a scheduler's ticks; 0 through
-        ``step()`` alone)."""
+        ``step()`` alone); ``rows``, the token rows the launched prefill
+        and decode programs ran (``prefill_batch``, ``prefill_chunk``,
+        ``decode_step``; padding included), and ``head_rows``, the rows
+        their heads scored - what each program reads, where the family's
+        ``apply_paged`` takes ``rows``."""
         return [("Serving/engine/" + name, float(value), step)
                 for name, value in (
                     ("steps", self.steps),
                     ("mixed_steps", self.mixed_steps),
-                    ("overlapped_steps", self.overlapped_steps))]
+                    ("overlapped_steps", self.overlapped_steps),
+                    ("rows", self.rows),
+                    ("head_rows", self.head_rows))]
 
     def publish_engine_telemetry(self, step: int = 0):
         return self._publish(self.engine_events(step))

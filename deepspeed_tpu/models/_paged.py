@@ -40,6 +40,14 @@ or the expert bank, the head - reads its weights once for both. Only
 :func:`paged_attention_step` splits the rows back into the two segments it
 has kernels for.
 
+The rows a call reads (``apply_paged(..., rows=)``, :func:`gather_rows`): a
+program that samples from a few rows' logits - a prefill its sequences' last
+real rows, a mixed call its decode rows and the chunk's last real row - says
+so, and the family gathers the hidden state to those rows BEFORE the final
+norm and the head: the unembed matmul, its float32 result and whatever
+picked rows out of it run on ``r`` rows and not on ``t``. A call that reads
+every row passes nothing and is the program it was.
+
 Kinds of KV state (``inference.ragged.WindowKind``): a family whose stack
 mixes full-attention and sliding-window layers keeps ONE such buffer a kind,
 ``[L_kind, blocks_kind, nkv, bs, hd]`` (:func:`init_kind_pools`: ``k`` /
@@ -229,6 +237,18 @@ def row_positions(block_tables, context_lens, t: int) -> jnp.ndarray:
         return jnp.concatenate(
             [call.lens, call.chunk_ctx + jnp.arange(t - call.slots)])[None]
     return context_lens[:, None] + jnp.arange(t)[None, :]
+
+
+def gather_rows(x: jnp.ndarray, rows: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """The rows of ``x [b, t, H]`` whose logits the call reads: ``rows
+    [b, r]`` int32 indices along ``t`` (traced values, a static ``r``) give
+    ``[b, r, H]``, for the final norm and the head to run on; None gives
+    ``x`` itself. Every family's ``apply_paged`` ends through it, after its
+    last layer and before its final norm (a per-row operation: the rows kept
+    read what they would have read)."""
+    if rows is None:
+        return x
+    return jnp.take_along_axis(x, rows[:, :, None], axis=1, mode="clip")
 
 
 def scan_layers(body, x, layers, cache, *extras):

@@ -48,7 +48,7 @@ from ..ops.attention import attention
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import layer_norm
 from ..ops.rotary import apply_rotary_interleaved, rope_frequencies
-from ._paged import (LayerPool, init_kind_pools, kind_tables,
+from ._paged import (LayerPool, gather_rows, init_kind_pools, kind_tables,
                      paged_attention_step, row_positions)
 from .mixtral import _bank_apart
 from .mixtral import moe_rows  # noqa: F401  (the same shape facts: the
@@ -480,9 +480,11 @@ def apply_paged(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
                 valid: Optional[jnp.ndarray] = None,
+                rows: Optional[jnp.ndarray] = None,
                 compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """Ragged forward over the two-kind cache (prefill rows, chunks, decode
-    steps or a mixed call): ``llama.apply_paged``'s contract, with
+    steps or a mixed call): ``llama.apply_paged``'s contract (``rows``: the
+    head scores those rows alone), with
     ``block_tables`` one segment a kind of KV state side by side, the full
     kind's first (``_paged.kind_tables``; ``StateManager.block_table``
     builds it; a table of the full kind's width alone serves both kinds
@@ -524,4 +526,4 @@ def apply_paged(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
     x, cache, _ = _scan_nest(
         cfg, _embed(params, tokens, compute_dtype), layers, dict(cache),
         {layer_type: block(layer_type) for layer_type in KINDS})
-    return _head(cfg, params, x, compute_dtype), cache
+    return _head(cfg, params, gather_rows(x, rows), compute_dtype), cache

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.attention import attention
-from ._paged import paged_attention_step, scan_layers
+from ._paged import gather_rows, paged_attention_step, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import layer_norm
@@ -339,6 +339,7 @@ def apply_paged(cfg: FalconConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
                 valid: Optional[jnp.ndarray] = None,
+                rows: Optional[jnp.ndarray] = None,
                 compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """Ragged forward over the paged cache (see llama.apply_paged for the
     contract); handles the parallel / sequential / new-decoder variants."""
@@ -379,4 +380,4 @@ def apply_paged(cfg: FalconConfig, params: Params, tokens: jnp.ndarray,
         return x, (k_c, v_c)
 
     x, cache = scan_layers(scan_body, x, layers, cache)
-    return _head(cfg, params, x, compute_dtype), cache
+    return _head(cfg, params, gather_rows(x, rows), compute_dtype), cache

@@ -22,7 +22,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ._paged import paged_attention_step, scan_layers
+from ._paged import gather_rows, paged_attention_step, scan_layers
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import layer_norm
@@ -328,6 +328,7 @@ def apply_paged(cfg: GPTConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
                 valid: Optional[jnp.ndarray] = None,
+                rows: Optional[jnp.ndarray] = None,
                 compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """Ragged forward over the paged cache (see llama.apply_paged for the
     contract); handles both LN orderings and the relu/gelu variants."""
@@ -354,7 +355,7 @@ def apply_paged(cfg: GPTConfig, params: Params, tokens: jnp.ndarray,
         return x, kv
 
     x, cache = scan_layers(scan_body, x, layers, cache)
-    return _head(cfg, params, x, compute_dtype), cache
+    return _head(cfg, params, gather_rows(x, rows), compute_dtype), cache
 
 
 def loss_fn(cfg: GPTConfig, params: Params, batch: Dict[str, jnp.ndarray], *,

@@ -58,7 +58,7 @@ from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
 from ..ops.pallas import ssm as _ssm_kernels  # noqa: F401 (registers)
 from ..ops.registry import get_op
-from ._paged import (LayerPool, MixedCall, init_paged_pools,
+from ._paged import (LayerPool, MixedCall, gather_rows, init_paged_pools,
                      paged_attention_step, row_positions)
 
 Params = Dict[str, Any]
@@ -565,6 +565,7 @@ def apply_paged(cfg: GraniteHybridConfig, params: Params,
                 block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,
                 valid: Optional[jnp.ndarray] = None,
                 slots: Optional[jnp.ndarray] = None,
+                rows: Optional[jnp.ndarray] = None,
                 compute_dtype=None) -> Tuple[jnp.ndarray, Params]:
     """Ragged forward over the two-kind cache (prefill rows, chunks or
     decode steps): ``llama.apply_paged``'s contract, and ``slots [b]``, each
@@ -572,7 +573,9 @@ def apply_paged(cfg: GraniteHybridConfig, params: Params,
     row at context offset 0 starts its recurrent state from zeros; a row
     with no valid token leaves its slot's state as it was. A mixed call
     (``block_tables`` a ``_paged.MixedCall``): decode row i is slot i and
-    the chunk's rows are ``chunk_slot``'s."""
+    the chunk's rows are ``chunk_slot``'s. ``rows [b, r]``: the head scores
+    those rows alone (``_paged.gather_rows``; the state advances over every
+    valid row as it did)."""
     _check(cfg)
     b, t = tokens.shape
     if valid is None:
@@ -584,17 +587,18 @@ def apply_paged(cfg: GraniteHybridConfig, params: Params,
     if call is None:
         if slots is None:
             slots = jnp.arange(b, dtype=jnp.int32)
-        rows = ssm.pool_rows(slots, valid[:, 0], pool)
+        state_rows = ssm.pool_rows(slots, valid[:, 0], pool)
         fresh = context_lens == 0
     else:
-        rows = (ssm.pool_rows(jnp.arange(call.slots), call.active, pool),
-                ssm.pool_rows(call.chunk_slot[None],
-                              (call.chunk_valid > 0)[None], pool))
+        state_rows = (
+            ssm.pool_rows(jnp.arange(call.slots), call.active, pool),
+            ssm.pool_rows(call.chunk_slot[None],
+                          (call.chunk_valid > 0)[None], pool))
         fresh = (call.lens == 0, (call.chunk_ctx == 0)[None])
 
     def mamba(x, w, pools, index):
-        return _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid,
-                            call)
+        return _mamba_paged(cfg, x, w, pools, index, state_rows, fresh,
+                            valid, call)
 
     def attn(x, w, pools, index):
         return _attention_paged(cfg, x, w, pools, index, block_tables,
@@ -603,7 +607,7 @@ def apply_paged(cfg: GraniteHybridConfig, params: Params,
     x, cache = _scan_nest(cfg, _embed(cfg, params, tokens, compute_dtype),
                           layers, dict(cache),
                           {"mamba": mamba, "attention": attn})
-    return _logits(cfg, params, x, compute_dtype), cache
+    return _logits(cfg, params, gather_rows(x, rows), compute_dtype), cache
 
 
 def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):

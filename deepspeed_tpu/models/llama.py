@@ -31,7 +31,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ._paged import paged_attention_step, row_positions, scan_layers
+from ._paged import (gather_rows, paged_attention_step, row_positions,
+                     scan_layers)
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -610,6 +611,7 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
                 valid: Optional[jnp.ndarray] = None,
+                rows: Optional[jnp.ndarray] = None,
                 compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """Ragged forward over the paged cache (prefill chunks or decode steps).
 
@@ -618,7 +620,11 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     real (non-pad) tokens. A mixed call (``MIXED_PAGED``): ``block_tables``
     is a ``_paged.MixedCall``, ``context_lens`` None and tokens
     [1, slots + t] - every slot's decode token, then one prefill chunk.
-    Returns (logits [B, t, vocab] fp32, cache)."""
+    ``rows`` [B, r]: the rows along ``t`` whose logits the call reads
+    (``_paged.gather_rows``) - the final norm and the head run on those
+    alone; None scores every row.
+    Returns (logits [B, t, vocab] fp32 - [B, r, vocab] with ``rows`` -,
+    cache)."""
     b, t = tokens.shape
     if valid is None:
         valid = jnp.ones((b, t), bool)
@@ -638,6 +644,7 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         return x, (k_c, v_c)
 
     x, cache = scan_layers(scan_body, x, layers, cache)
+    x = gather_rows(x, rows)
     with jax.named_scope("norm"):
         x = rms_norm(x, params["final_norm"].astype(compute_dtype),
                      cfg.rms_norm_eps)

@@ -24,8 +24,8 @@ from jax.ad_checkpoint import checkpoint_name
 from ..moe.layer import BANK, MoELayer, init_moe_ffn, moe_ffn_logical_axes
 from ..moe.sharded_moe import compute_capacity, row_tile
 from ..ops.attention import attention
-from ._paged import (init_index_pool, paged_attention_step, row_positions,
-                     scan_layers, sparse_attention_step)
+from ._paged import (gather_rows, init_index_pool, paged_attention_step,
+                     row_positions, scan_layers, sparse_attention_step)
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm
@@ -611,11 +611,12 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
                 valid: Optional[jnp.ndarray] = None,
+                rows: Optional[jnp.ndarray] = None,
                 compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
     """Ragged forward over the paged cache (see llama.apply_paged for the
-    contract, a mixed call included: its ``slots + t`` rows go through the
-    expert bank as one call's rows); the FFN is the no-drop MoE routing of
-    apply_cached."""
+    contract, a mixed call and ``rows`` included: its ``slots + t`` rows go
+    through the expert bank as one call's rows, the head scores ``rows``);
+    the FFN is the no-drop MoE routing of apply_cached."""
     b, t = tokens.shape
     nh, hd = cfg.num_heads, cfg.head_size
     if valid is None:
@@ -656,6 +657,7 @@ def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
         return x + ffn_out, (k_c, v_c, *i_c)
 
     x, cache = scan_layers(scan_body, x, layers, cache)
+    x = gather_rows(x, rows)
     with jax.named_scope("norm"):
         x = rms_norm(x, params["final_norm"].astype(compute_dtype),
                      cfg.rms_norm_eps)

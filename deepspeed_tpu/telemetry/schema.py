@@ -95,10 +95,11 @@ SERVING_SERIES = frozenset(
     + ["Serving/kv/" + m for m in (
         "full_blocks_live", "window_blocks_live", "window_blocks_released")]
     # what step() ran (engine_v2.engine_events): its calls, those whose
-    # prefill chunk rode in the decode program (``decode_chunk``), and those
-    # launched while the program before was still unread
+    # prefill chunk rode in the decode program (``decode_chunk``), those
+    # launched while the program before was still unread, the token rows
+    # its programs ran and the rows their heads scored
     + ["Serving/engine/" + m for m in (
-        "steps", "mixed_steps", "overlapped_steps")]
+        "steps", "mixed_steps", "overlapped_steps", "rows", "head_rows")]
     + ["Serving/spec/" + m for m in (
         "verify_steps", "decode_steps", "step_seqs", "drafted_tokens",
         "accepted_tokens", "emitted_tokens", "rolled_back_tokens",

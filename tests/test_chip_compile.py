@@ -275,8 +275,8 @@ def _cell_forward(cell_name, quant, depth=POOL_DEPTH):
     """A serve cell's paged forward on shapes, at its published widths and
     its pool geometry (``benchmark/configs``), ``depth`` layers deep (None:
     as the cell runs it):
-    ``(forward(params, cache, tokens, tables, ctx, valid) -> (logits,
-    cache), params, cache, slots, chunk, table width)``."""
+    ``(forward(params, cache, tokens, tables, ctx, valid, rows=None) ->
+    (logits, cache), params, cache, slots, chunk, table width)``."""
     from benchmark.harness.manifest import Cell
 
     cell = Cell(cell_name)
@@ -295,9 +295,9 @@ def _cell_forward(cell_name, quant, depth=POOL_DEPTH):
         cfg, ragged["memory_config_blocks"], ragged["block_size"],
         **({"kv_quant_group": cfg.head_size} if quant else {})))
 
-    def forward(params, cache, tokens, tables, ctx, valid):
+    def forward(params, cache, tokens, tables, ctx, valid, rows=None):
         return module.apply_paged(cfg, params, tokens, cache, tables, ctx,
-                                  valid=valid)
+                                  valid=valid, rows=rows)
 
     return (forward, params, cache, ragged["max_tracked_sequences"],
             engine["split_prefill_chunk"],
@@ -569,21 +569,14 @@ def test_the_learned_selections_mixed_program_keeps_three_pools_in_place(v5e):
 COMMAND_A_CELL = "command-a-plus-05-2026.serve-longctx"
 
 
-def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
-    """The Command A+ cell's mixed call (16 decode rows + a 512-row chunk) at
-    its real configuration, compiled for the chip: the full kind's pools AND
-    the window kind's stay where they are (no pool-shaped copy, every pool
-    aliased argument-to-result), a period's body is two writes and two walks
-    a kind - the window layers' scan and the full layer's -, and the whole
-    program with its 11.35 GB of weights and 2.56 GB of pools fits the
-    chip."""
-    import math
-    import re
-
+def _command_a_mixed_program():
+    """The Command A+ cell's forward of a mixed call (16 decode rows + a
+    512-row chunk) on shapes, at its real configuration and both kinds'
+    pool geometry: ``(forward(params, cache, tokens, call, valid,
+    rows=None), arguments)`` with the cache second."""
     from benchmark.harness.manifest import Cell
     from deepspeed_tpu.inference.ragged import WindowKind
     from deepspeed_tpu.models._paged import MixedCall
-    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
     cell = Cell(COMMAND_A_CELL)
     engine = cell.role["engine"]
@@ -610,14 +603,31 @@ def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
                      s((), i32))
     rows = slots + chunk
 
-    def forward(params, cache, tokens, tables, valid):
+    def forward(params, cache, tokens, tables, valid, rows=None):
         return module.apply_paged(cfg, params, tokens, cache, tables, None,
-                                  valid=valid)
+                                  valid=valid, rows=rows)
 
+    return forward, (params, cache, s((1, rows), i32), call,
+                     s((1, rows), bool))
+
+
+def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
+    """The Command A+ cell's mixed call (16 decode rows + a 512-row chunk) at
+    its real configuration, compiled for the chip: the full kind's pools AND
+    the window kind's stay where they are (no pool-shaped copy, every pool
+    aliased argument-to-result), a period's body is two writes and two walks
+    a kind - the window layers' scan and the full layer's -, and the whole
+    program with its 11.35 GB of weights and 2.56 GB of pools fits the
+    chip."""
+    import math
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    forward, args = _command_a_mixed_program()
     sh = SingleDeviceSharding(v5e.devices[0])
     args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
-        (params, cache, s((1, rows), i32), call, s((1, rows), bool)))
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
     compiled = jax.jit(forward, donate_argnums=(1,)).lower(*args).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     pools = jax.tree.leaves(args[1])
@@ -677,6 +687,75 @@ def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
     assert grouped == (0 if cell in (SERVE_CELLS[0], GRANITE_CELL) else 1)
     assert len(matmuls) >= 7 - grouped \
         and all(rows in dims for dims in matmuls)
+
+
+# --- the head scores the rows a program reads (ISSUE 44) -------------------- #
+def _greedy_mixed_step(forward, reads_its_rows: bool):
+    """``forward`` of a mixed call inside the sampling the engine's
+    ``decode_chunk`` wraps it in (greedy): ``slots + 1`` tokens and the
+    cache. ``reads_its_rows``: the call hands the family the rows it reads
+    (the slots' and the chunk's last real one), as the engine does; else
+    the family scores every row and the rows are picked from the result
+    with the parent's (407791c) expressions."""
+    def step(params, cache, tokens, call, *rest):
+        b = call.slots
+        last = jnp.maximum(call.chunk_valid - 1, 0)
+        if reads_its_rows:
+            rows = jnp.concatenate([jnp.arange(b), b + last[None]])[None]
+            logits, cache = forward(params, cache, tokens, call, *rest,
+                                    rows=rows)
+            nxt, first = logits[0, :b], logits[0, b]
+        else:
+            logits, cache = forward(params, cache, tokens, call, *rest)
+            nxt = logits[0, :b]
+            first = jnp.take_along_axis(
+                logits[:, b:], last[None, None, None], axis=1)[0, 0]
+        return jnp.concatenate([jnp.argmax(nxt, axis=-1),
+                                jnp.argmax(first, axis=-1)[None]]).astype(
+                                    jnp.int32), cache
+
+    return step
+
+
+@pytest.mark.parametrize("cell", [COMMAND_A_CELL, KEYE_CELL, SERVE_CELLS[0]])
+def test_the_mixed_programs_head_scores_the_rows_it_reads(v5e, cell):
+    """The mixed call at Command A+'s (16 + 512 rows, a TIED table of
+    262 144), Keye's (8 + 512, 151 936) and chat's (32 + 256, 32 000) cell
+    shapes with ``rows=`` as the engine hands them, compiled for the chip
+    beside the program that scores every row: no array of ``slots + chunk``
+    rows by the vocabulary is left, of any type, and so no slice of one
+    (the parent's holds both) - the logits are ``slots + 1`` rows -, the
+    table is read where it lies (nothing of its shape but the argument and
+    bitcasts of it: no copy, no transpose for the tied ``embed.T``), and the
+    program's peak is lower."""
+    import re
+
+    forward, args = _command_a_mixed_program() if cell == COMMAND_A_CELL \
+        else _mixed_program(cell)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    text, peak = {}, {}
+    for reads in (True, False):
+        compiled = jax.jit(_greedy_mixed_step(forward, reads),
+                           donate_argnums=(1,)).lower(*args).compile()
+        text[reads] = compiled.as_text()
+        peak[reads] = compiled.memory_analysis().peak_memory_in_bytes
+    params, rows, slots = args[0], args[2].shape[1], args[3].slots
+    table = params.get("lm_head", params["embed"]).shape
+    vocab = max(table)
+    every_row = rf"\w+\[(\d+,)*({rows}|{rows - slots}),{vocab}\]"
+    assert re.search(every_row, text[False])
+    assert not re.search(every_row, text[True])
+    assert re.search(rf"f32\[(\d+,)*{slots + 1},{vocab}\]", text[True])
+    made = re.findall(
+        r"= bf16\[(?:%d,%d|%d,%d)\]\S* ([\w-]+)\((?:.*calls=%%(\w+))?"
+        % (*table, *table[::-1]), text[True])
+    assert made and all(
+        op in ("parameter", "bitcast")
+        or (op == "fusion" and called.startswith("bitcast_fusion"))
+        for op, called in made), made
+    assert 0 < peak[True] < peak[False] < V5E_BYTES_LIMIT
 
 
 # --- the expert bank is read where it lies (ISSUE 41) ----------------------- #

@@ -181,9 +181,17 @@ def test_a_mixed_step_serves_what_the_two_programs_serve(family):
     assert "chunk_prefill" not in {k[0] for k in one._paged_fns}
     assert [k for k in one._paged_fns if k[0].startswith("decode_chunk")] \
         == [("decode_chunk", CHUNK)]        # ONE program, mid and final
+    # the rows the programs ran and the rows their heads scored (ISSUE 44):
+    # two one-shot prefills (8 and 16 rows, one read each), three mixed
+    # calls (4 slots + 8 chunk rows, 4 + 1 read) and two decodes (4 of 4);
+    # the two-program engine's mid chunks read none and its final chunk one
     assert dict((n, v) for n, v, _ in one.engine_events()) == {
         "Serving/engine/steps": 5.0, "Serving/engine/mixed_steps": 3.0,
-        "Serving/engine/overlapped_steps": 0.0}
+        "Serving/engine/overlapped_steps": 0.0,
+        "Serving/engine/rows": 8.0 + 16 + 3 * 12 + 2 * 4,
+        "Serving/engine/head_rows": 1.0 + 1 + 3 * 5 + 2 * 4}
+    assert (two.rows, two.head_rows) == (8 + 16 + 3 * 8 + 5 * 4,
+                                         1 + 1 + 1 + 5 * 4)
     for uid in (1, 2, 3):
         assert one.finish(uid) == two.finish(uid)
 

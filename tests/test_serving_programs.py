@@ -172,17 +172,23 @@ def paged_text(family: str, t: int) -> str:
 # on purpose: those programs take the newest launched token result and build
 # their slots' token row from it on the device (``engine_v2._own_tokens``), and
 # ``decode`` returns its tokens in that result's one shape, ``[slots + 1]``;
-# what they serve is held by ``PARENT_TOKENS`` below. A PR that means to
+# what they serve is held by ``PARENT_TOKENS`` below. ISSUE 44 replaced the
+# four ``prefill*``, the two final ``chunk_prefill`` and the three
+# ``decode_chunk`` lines on purpose: those programs hand the family the rows
+# they read (``rows=``) and the head scores those alone; the programs that
+# read every row or none (``decode*``, ``spec_verify``, a mid
+# ``chunk_prefill``) and the families' ``rows=None`` forwards kept theirs,
+# and ``PARENT_TOKENS`` holds what all of them serve. A PR that means to
 # change one of these programs replaces its line.
 PARENT_HASHES = {
-    "chunk_prefill.final_greedy": "2fba01067a00967c",
-    "chunk_prefill.final_stochastic": "ac75ddded01ea6b7",
+    "chunk_prefill.final_greedy": "9107f8cc146e7190",
+    "chunk_prefill.final_stochastic": "9ae5ee402a00ae2c",
     "chunk_prefill.mid": "a4afc6de0baf7628",
     "chunk_prefill.mid.int8": "acc809c779ddf322",
     "decode.greedy": "98acf8881fef6b9a",
-    "decode_chunk.greedy": "fa69d0ec059ccd3f",
-    "decode_chunk.greedy.int8": "e690a4d521e4c0e8",
-    "decode_chunk.rows": "f59bcbb7d32d62b1",
+    "decode_chunk.greedy": "4ba9752be7c30d72",
+    "decode_chunk.greedy.int8": "884492885c6ea0c5",
+    "decode_chunk.rows": "435b87fe6292d131",
     "decode.greedy.int8": "a979f095099b879b",
     "decode.rows": "741da01e1d860528",
     "decode_many.greedy": "db20d44910b2f3f1",
@@ -193,10 +199,10 @@ PARENT_HASHES = {
     "falcon.apply_paged.t8": "34a9462d4be1d3b6",
     "gpt.apply_paged.t1": "ee3a1123c85ea42a",
     "gpt.apply_paged.t8": "8a8f509981f5c0ca",
-    "prefill.greedy": "711417c7aba9837d",
-    "prefill.rows": "2d4c4e712c3a4a38",
-    "prefill_ctx.greedy": "41e82bf7d475a0dd",
-    "prefill_ctx.rows": "60f3edc57419334f",
+    "prefill.greedy": "b540760f89e42cbe",
+    "prefill.rows": "da4512897bdfc65a",
+    "prefill_ctx.greedy": "c9962616fcb0d7e9",
+    "prefill_ctx.rows": "77ec64d492592b6a",
     "spec_verify": "5a2b0e419537fdc2",
 }
 
