@@ -970,6 +970,35 @@ class InferenceEngineV2(InferenceEngine):
         return {"attn_tiles_live": live, "attn_tiles_grid": grid,
                 "attn_live_tile_share": live / grid}
 
+    def _chunk_tile_args(self, ch: _Chunk) -> Dict[str, int]:
+        """Span arguments of a chunk's ``paged_prefill`` walk, host integers
+        the call already holds: the grid steps that hold context its rows
+        attend, the steps the grid takes and the steps a grid as wide as the
+        block table would take (``chunk_attn_tiles_live`` / ``_grid`` /
+        ``_table``; ``ops/pallas/paged_attention.py prefill_tile_counts``) -
+        of ONE layer's call; in a family with window kinds of one call a
+        kind, each times the kind's layers, summed. None for a family whose
+        chunk takes another walk (a learned selection) or whose paged cache
+        is not ``init_paged_pools``'."""
+        from ..ops.pallas.paged_attention import prefill_tile_counts
+
+        if "k" not in self.cache or self._indexed:
+            return {}
+        state = self.state
+        walks = [(self.cache["k"].shape, state.max_blocks_per_seq, 0, None)]
+        walks += [(self.cache["k_" + kind.name].shape, kind.blocks_per_seq,
+                   state.first_live(kind, ch.ctx) * state.block_size,
+                   kind.window) for kind in state.window_kinds]
+        total = (0, 0, 0)
+        for shape, width, given, window in walks:
+            layers = shape[0] if len(walks) > 1 else 1
+            counts = prefill_tile_counts(
+                [ch.ctx - given], [len(ch.tokens)], ch.width,
+                self.family.cfg.num_heads, shape, width, window)
+            total = tuple(a + layers * n for a, n in zip(total, counts))
+        return dict(zip(("chunk_attn_tiles_live", "chunk_attn_tiles_grid",
+                         "chunk_attn_tiles_table"), total))
+
     def _next_chunk(self) -> _Chunk:
         """The OLDEST pending split prefill's next chunk (FIFO, the
         reference scheduler's arrival order)."""
@@ -1061,7 +1090,8 @@ class InferenceEngineV2(InferenceEngine):
                 **self._row_args(rows, slots + int(mixed or ch.final)),
                 **self._ssm_args(1, len(ch.tokens)),
                 **self._sparse_args(self._chunk_contexts(ch)),
-                **self._kv_kind_args([ch.ctx], [len(ch.tokens)])):
+                **self._kv_kind_args([ch.ctx], [len(ch.tokens)]),
+                **self._chunk_tile_args(ch)):
             with self.tracer.span("engine_prep", cat="serving"):
                 table = self._table(ch.desc, len(ch.tokens))
                 fn, pre, post = self._chunk_program(ch, (), table, mixed)
@@ -1634,6 +1664,7 @@ class InferenceEngineV2(InferenceEngine):
                                                 "chunk_"))
             chunk_args.update(self._kv_kind_args(
                 [ch.ctx], [len(ch.tokens)], "chunk_"))
+            chunk_args.update(self._chunk_tile_args(ch))
             self._ssm_args(1, len(ch.tokens))    # ``last_step``'s count
         with self.tracer.span(
                 "decode_step", cat="serving", seq=seq, batch=len(live),
@@ -1714,7 +1745,7 @@ class InferenceEngineV2(InferenceEngine):
                         "prefill_chunk", fl.t0, t1, cat="serving",
                         trace=rec["trace"], parent=rec["span"].span_id,
                         table_blocks=self.state.max_blocks_per_seq,
-                        **self._chunk_args(ch))
+                        **self._chunk_args(ch), **self._chunk_tile_args(ch))
                 if final and self._trace_on:
                     self._req_first_token(ch.uid, t1)
             for d in fl.live:

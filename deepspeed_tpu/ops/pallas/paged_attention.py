@@ -40,7 +40,17 @@ The last grid dimension is DYNAMIC: the tiles of the longest context in the
 batch, not the table's width, so a step costs what its context costs. Within
 it a shorter sequence's dead steps fold onto its last live page (no DMA, no
 compute). ``paged_prefill`` is the same kernel body (:func:`_paged_kernel`)
-at ``tq`` query tokens a tile and one KV head a step.
+at ``tq`` query tokens a tile and one KV head a step, on a grid
+``(B, KV heads, query tiles, KV tiles)`` whose last dimension is dynamic
+too: the tiles up to the longest sequence's last REAL row, ``clip(ceil(
+max(context_lens + lengths) / KV tile), 1, table tiles)``, computed in the
+program from the call's own operands. The bound cuts the END of the walk
+alone: inside it an early query tile's steps above its last row, a window's
+steps below its first and a shorter sequence's fold as before, and a call
+of zero-length dummies still takes the one step that initialises and writes
+it. :func:`decode_tile_counts` and :func:`prefill_tile_counts` say on the
+host, from the same tile sizes, how many steps a call takes and how many
+hold context.
 
 Quantized KV mode (``inference.kv_quant``, docs/serving.md "Quantized KV
 cache"): ``k_pool``/``v_pool`` hold int8 codes and ``k_scale``/``v_scale``
@@ -235,6 +245,30 @@ def decode_tile_counts(context_lens, nh: int, pool_shape, itemsize: int,
     tiles = np.minimum(np.asarray(context_lens) // (pages * bs) + 1, n_kv)
     return (int(tiles.sum()) * (nkv // heads),
             int(tiles.max()) * tiles.size * (nkv // heads))
+
+
+def prefill_tile_counts(context_lens, lengths, t: int, nh: int, pool_shape,
+                        max_blocks: int, window=None) -> Tuple[int, int, int]:
+    """(live, taken, table-wide) grid steps of ONE ``paged_prefill`` call of
+    ``t`` rows a sequence, ``lengths`` of them real, at ``context_lens``
+    (host integers; ``window``: the layer's, an int or None): the steps whose
+    KV tile a real row of their query tile attends, the steps the grid takes
+    - every (sequence, KV head, query tile) walks as far as the longest
+    sequence's last real row - and the steps of a grid as wide as the table.
+    What the serving engine puts on a chunk's span."""
+    nkv, bs, hd = pool_shape[-3:]
+    tq, n_qt, pages = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
+    kv, n_kv = pages * bs, -(-max_blocks // pages)
+    ctx = np.asarray(context_lens)[:, None, None]
+    n = np.asarray(lengths)[:, None, None]
+    q_lo = (np.arange(n_qt) * tq)[None, :, None]
+    j = np.arange(n_kv)[None, None, :]
+    live = (q_lo < n) & (j * kv < ctx + np.minimum(q_lo + tq, n))
+    if window is not None:
+        live &= j * kv + kv - 1 > ctx + q_lo - window
+    n_live = min(max(-(-int((ctx + n).max()) // kv), 1), n_kv)
+    walks = ctx.size * nkv * n_qt
+    return int(live.sum()) * nkv, walks * n_live, walks * n_kv
 
 
 def _kv_tile(page_refs, scale_refs, dtype):
@@ -524,12 +558,17 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     qg = jnp.pad(q, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
     qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
         .reshape(B, nkv, n_qt * rows, hd)
+    # the walk ends with the last tile any sequence's real rows reach (at
+    # least one step: a call of dummies alone still initialises and writes)
+    n_live = jnp.clip(
+        -(-jnp.max(context_lens + lengths) // (pages * bs)), 1,
+        -(-max_blocks // pages))
     kernel, grid_spec, args = _table_walk(
         qg, k_pool, v_pool, block_tables, context_lens, lengths, layer,
         window, k_scale, v_scale,
         scale=hd ** -0.5 if scale is None else scale,
         rows=rows, tq=tq, pages=pages, heads=None,
-        n_kv=-(-max_blocks // pages))
+        n_kv=n_live.astype(jnp.int32))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),

@@ -329,7 +329,10 @@ TRACER_SPANS = frozenset((
     # scheduler tick and its phases (serving/scheduler.py)
     "sched_tick", "sched_expire", "sched_admit", "sched_preempt_guard",
     "sched_step_engine", "sched_harvest", "sched_retire",
-    # one engine dispatch and its host phases (engine_v2)
+    # one engine dispatch and its host phases (engine_v2). A chunk's
+    # multi-token walk rides ``decode_step`` and ``prefill_chunk`` as
+    # ``chunk_attn_tiles_{live, grid, table}``, beside the decode walk's
+    # ``attn_tiles_{live, grid}`` (plain numbers; docs/observability.md)
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

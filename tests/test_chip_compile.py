@@ -758,6 +758,46 @@ def test_the_mixed_programs_head_scores_the_rows_it_reads(v5e, cell):
     assert 0 < peak[True] < peak[False] < V5E_BYTES_LIMIT
 
 
+# --- the prefill walk ends where the context ends (ISSUE 45) ---------------- #
+# the parent's (cd0afcf) compiled peak of the engine's mixed program
+PARENT_MIXED_PEAK = {COMMAND_A_CELL: 14_182_393_856,
+                     SERVE_CELLS[0]: 5_031_206_912}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_MIXED_PEAK))
+def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(v5e, cell):
+    """Command A+'s and chat's mixed program as the engine runs it, compiled
+    for the chip: every ``paged_prefill`` call (one a table kind) takes its
+    last grid dimension as an operand - a Mosaic call's dynamic grid bound
+    is its FIRST operand, an ``s32[]`` ahead of the prefetched block table,
+    as ``paged_decode``'s has been -, the pools still stay where they are,
+    and the program's peak is the parent's (the bound's own scalars are a
+    few KB: nothing the size of a row, a tile or a table is added)."""
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    forward, args = _command_a_mixed_program() if cell == COMMAND_A_CELL \
+        else _mixed_program(cell)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(_greedy_mixed_step(forward, True),
+                       donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    first = {kernel: re.findall(
+        rf"%{kernel}(?:\.\d+)? = .*? custom-call\(.*" + MOSAIC
+        + r".*?operand_layout_constraints=\{([^,]*),", text)
+        for kernel in ("paged_prefill", "paged_decode", "paged_kv_write")}
+    kinds = 2 if cell == COMMAND_A_CELL else 1
+    assert first["paged_prefill"] == ["s32[]"] * kinds == first["paged_decode"]
+    assert len(first["paged_kv_write"]) == 2 * kinds \
+        and "s32[]" not in first["paged_kv_write"]      # a static grid's
+    assert pool_copy_bytes(text, jax.tree.leaves(args[1])) == 0
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 0 < peak < PARENT_MIXED_PEAK[cell] + (64 << 10)
+
+
 # --- the expert bank is read where it lies (ISSUE 41) ----------------------- #
 MOE_CELLS = ("mixtral-8x7b.serve-longprompt", "olmoe-1b-7b.serve-longprompt",
              KEYE_CELL)
@@ -804,10 +844,13 @@ def test_the_expert_bank_is_read_where_it_lies(v5e, cell, program):
 # sha256 of the mixed program's jaxpr in the two cells whose families hold no
 # bank, taken on ISSUE 41's parent (166de2b) and again on its finished tree:
 # ``_paged.scan_layers`` is every paged family's, and the bank's way through
-# it (a closure of ``models/mixtral.py``) left theirs alone.
+# it (a closure of ``models/mixtral.py``) left theirs alone. Re-taken on
+# ISSUE 45's finished tree, which means to change them: the chunk's
+# ``paged_prefill`` takes a traced grid bound (they were 4c1badd32e6be1bd and
+# adee81593bcafbca).
 NO_BANK_PROGRAMS = {
-    "mistral-7b.serve-chat": "4c1badd32e6be1bd",
-    GRANITE_CELL: "adee81593bcafbca",
+    "mistral-7b.serve-chat": "0185c835790f0de0",
+    GRANITE_CELL: "c8639994fc5fe43d",
 }
 
 
@@ -868,17 +911,18 @@ MULTI_TOKEN_PROGRAMS = {
                                          "traced"),
     "batched_prefill_mqa": (4, 40, 8, 1, 64, 16, 64, 20, 0, None),
 }
-# sha256 of each program's jaxpr (kernel body included): ISSUE 29's, re-taken
-# on its finished tree (the step writes through ``paged_kv_write`` and the
-# walk takes the layer as a prefetched scalar; before it they were 345a122's).
+# sha256 of each program's jaxpr (kernel body included): ISSUE 45's, re-taken
+# on its finished tree (the walk's last grid dimension is a value of the
+# program and the body ends on ``num_programs``; before it they were ISSUE
+# 29's, whose step first wrote through ``paged_kv_write``).
 # A PR that means to change the multi-token walk replaces these; one that does
 # not has changed it by accident.
 PARENT_HASHES = {
-    "mistral_chunk256_bf16": "cd29e389fa1aee2e",
-    "mistral_prompt2816_int8": "d6fdfb6459bdf7d6",
-    "olmoe_chunk256_window": "796ce8ac594e33be",
-    "verify_t5_traced_window_int8_ng2": "bee098fcb8a6c2a6",
-    "batched_prefill_mqa": "825ccdf392f3a9e8",
+    "mistral_chunk256_bf16": "65274280e7fb1e5c",
+    "mistral_prompt2816_int8": "adc273e57d65238f",
+    "olmoe_chunk256_window": "54cb8a57fa9599f8",
+    "verify_t5_traced_window_int8_ng2": "a87eb11e2b12777f",
+    "batched_prefill_mqa": "a5d8074491835204",
 }
 
 

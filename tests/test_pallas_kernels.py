@@ -7,6 +7,7 @@ against torch reference implementations.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -470,6 +471,149 @@ def test_paged_kernels_read_the_layer_they_are_given(op, window):
         outs.append(got)
     assert np.abs(outs[0] - outs[1]).max() > 1e-2
     assert np.abs(outs[1] - outs[2]).max() > 1e-2
+
+
+# --- the multi-token walk ends where the context ends (ISSUE 45) ------------ #
+# t, query heads, KV heads, table width, contexts, real rows, window, int8
+# pools - at head size 128 and blocks of 32, so the tiles are the cells'
+PREFILL_WALKS = {
+    "chat_chunk": (256, 32, 8, 256, [300], [256], None, False),
+    "chat_last_chunk_padded": (256, 32, 8, 256, [512], [77], None, False),
+    "command_a_full": (512, 128, 8, 1024, [8000], [512], None, False),
+    "command_a_window": (512, 128, 8, 145, [4100], [512], 4096, False),
+    "olmoe_chunk": (256, 16, 16, 128, [1500], [256], None, False),
+    "batched_unequal_dummy": (40, 32, 8, 256, [0, 700, 0, 64],
+                              [40, 25, 0, 7], None, False),
+    "verify_window_int8": (5, 32, 8, 256, [100, 900, 3000, 10], [5] * 4,
+                           "traced", True),
+    "window_starts_past_tile_0": (16, 8, 2, 64, [1500], [16], 300, False),
+    "context_fills_the_table": (64, 8, 2, 16, [448], [64], None, False),
+    "context_zero": (256, 32, 8, 256, [0], [256], None, False),
+}
+# an interpreted grid step costs what a compiled one does not: the two widest
+# walks are held to the table-wide grid on the chip alone (PERF.md, PR 45)
+CHEAP_WALKS = sorted(set(PREFILL_WALKS) - {"command_a_full",
+                                           "command_a_window"})
+
+
+def _prefill_walk(case):
+    """``(walk(ctx, lens, tables=) -> out, reference(kv head) -> its query
+    group's out, ctx, lens)`` of one case: the kernel and, a KV head at a
+    time and over the table entries the contexts reach (every position past
+    them is masked for every real row; the whole width's f32 scores of
+    command-a's full layer are 8.6 GB), its XLA reference."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.quantization import kv_quantize_int8
+
+    t, nh, nkv, table, ctx, lens, window, int8 = PREFILL_WALKS[case]
+    bs, hd, b = 32, 128, len(ctx)
+    reach = max(1, max(-(-(c + n) // bs) for c, n in zip(ctx, lens)))
+    rs = np.random.RandomState(11)
+    q = jnp.asarray(rs.randn(b, t, nh, hd).astype(np.float32))
+    pools = [jnp.asarray(rs.randn(reach * b + 1, nkv, bs, hd)
+                         .astype(np.float32)) for _ in range(2)]
+    tables = jnp.asarray(rs.randint(1, reach * b + 1, (b, table)), jnp.int32)
+    scales = []
+    if int8:
+        (pools[0], ks), (pools[1], vs) = (kv_quantize_int8(p, hd)
+                                          for p in pools)
+        scales = [ks, vs]
+    kw = {} if window is None else {
+        "window": jnp.int32(4096) if window == "traced" else window}
+    ctx, lens = jnp.asarray(ctx, jnp.int32), jnp.asarray(lens, jnp.int32)
+
+    def walk(ctx, lens):
+        return pa.paged_prefill_attention(
+            q, *pools, tables, ctx, lens, **kw,
+            **dict(zip(("k_scale", "v_scale"), scales)))
+
+    def reference(h):
+        g = nh // nkv
+        return pa.paged_prefill_attention_xla(
+            q[:, :, h * g:(h + 1) * g], *(p[:, h:h + 1] for p in pools),
+            tables[:, :min(reach, table)], ctx, lens, **kw,
+            **dict(zip(("k_scale", "v_scale"),
+                       (s[:, h:h + 1] for s in scales))))
+
+    return walk, reference, ctx, lens
+
+
+def _walk_bound(case):
+    """KV tiles the case's grid must take: the longest ``context + real
+    rows`` in 256-token tiles, at least one, at most the table's."""
+    t, nh, nkv, table, ctx, lens, *_ = PREFILL_WALKS[case]
+    longest = max(c + n for c, n in zip(ctx, lens))
+    return min(max(-(-longest // 256), 1), -(-table // 8))
+
+
+@pytest.mark.parametrize("case", sorted(PREFILL_WALKS))
+def test_prefill_walk_that_ends_with_the_context_agrees_with_xla(case):
+    """``paged_prefill``'s grid ends with the longest context's last tile,
+    not the table's: at the serve cells' chunk shapes, a padded last chunk,
+    a batched call of unequal lengths with zero-length dummies, the int8
+    verify window under a traced window, a window whose live range starts
+    past tile 0, a context that fills its table and one of 0, every REAL
+    row is the XLA reference's and every row is finite (a dummy's walk
+    still takes the one step that initialises and writes it)."""
+    walk, reference, ctx, lens = _prefill_walk(case)
+    out = np.asarray(jax.jit(walk)(ctx, lens))
+    assert np.isfinite(out).all()
+    nkv = PREFILL_WALKS[case][2]
+    want = np.concatenate([np.asarray(reference(h)) for h in range(nkv)],
+                          axis=2)
+    for b, n in enumerate(np.asarray(lens)):
+        np.testing.assert_allclose(out[b, :n], want[b, :n], rtol=2e-5,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("case", CHEAP_WALKS)
+def test_prefill_walk_is_bit_for_bit_the_table_wide_grids(case, monkeypatch):
+    """The steps the bound takes out computed nothing: real rows are the
+    table-wide grid's to the bit (``_table_walk`` handed the table's static
+    tile count, as the parent handed it)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    walk, _, ctx, lens = _prefill_walk(case)
+    out = np.asarray(jax.jit(walk)(ctx, lens))
+    table_walk = pa._table_walk
+    monkeypatch.setattr(
+        pa, "_table_walk", lambda *a, n_kv, pages, **kw: table_walk(
+            *a, n_kv=-(-a[3].shape[1] // pages), pages=pages, **kw))
+    wide = np.asarray(jax.jit(walk)(ctx, lens))
+    for b, n in enumerate(np.asarray(lens)):
+        np.testing.assert_array_equal(out[b, :n], wide[b, :n])
+
+
+@pytest.mark.parametrize("case", sorted(PREFILL_WALKS))
+def test_prefill_grids_last_dimension_is_traced(case):
+    """The ``pallas_call``'s last grid dimension is a value of the program,
+    computed from the call's own ``context_lens`` and ``lengths`` (no new
+    argument, one compilation for every context), and it is the tiles of the
+    longest context; ``prefill_tile_counts`` says the same on the host."""
+    from jax.extend import core as jex_core
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    walk, _, ctx, lens = _prefill_walk(case)
+    jaxpr = jax.make_jaxpr(walk)(ctx, lens)
+    (at, call), = [(i, e) for i, e in enumerate(jaxpr.jaxpr.eqns)
+                   if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert mapping.num_dynamic_grid_bounds == 1
+    assert all(isinstance(n, int) for n in mapping.grid[:3]) \
+        and not isinstance(mapping.grid[3], int)
+    bound = jex_core.Jaxpr(jaxpr.jaxpr.constvars, jaxpr.jaxpr.invars,
+                           [call.invars[0]], jaxpr.jaxpr.eqns[:at],
+                           debug_info=jaxpr.jaxpr.debug_info)
+    (n_live,) = jax.core.eval_jaxpr(bound, jaxpr.consts, ctx, lens)
+    assert n_live.dtype == jnp.int32 and int(n_live) == _walk_bound(case)
+    t, nh, nkv, table, _, _, window, _ = PREFILL_WALKS[case]
+    live, taken, wide = pa.prefill_tile_counts(
+        np.asarray(ctx), np.asarray(lens), t, nh, (nkv, 32, 128), table,
+        4096 if window == "traced" else window)
+    walks = math.prod(mapping.grid[:3])
+    assert taken == walks * _walk_bound(case) \
+        and wide == walks * -(-table // 8) and 0 <= live <= taken <= wide
 
 
 @pytest.mark.parametrize("backend", ["pallas", "xla"])

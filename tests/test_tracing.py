@@ -891,6 +891,126 @@ def test_decode_span_says_how_much_of_the_grid_is_live(devices8,
                                  eng.state.max_blocks_per_seq, False) == (6, 12)
 
 
+# the multi-token walk's counter (ISSUE 45): sequences' (contexts, real rows),
+# rows a sequence, table blocks, window -> (live, taken, table-wide) steps A
+# KV HEAD, counted by hand at 32-token KV tiles and 16-row query tiles
+HAND_COUNTED_WALKS = {
+    # one tile of rows: context 40 + 13 real rows reach tiles 0 and 1 of 4
+    "one_chunk": (([40], [13]), 16, 8, None, (2, 2, 4)),
+    # the dummy takes the longest's two steps and none of them is live
+    "batched_with_a_dummy": (([40, 0], [13, 0]), 16, 8, None, (2, 4, 8)),
+    # three query tiles at context 40: their last rows end at 56, 72 and 80,
+    # so they hold 2, 3 and 3 live tiles and each walks the longest's 3 of 8
+    "three_query_tiles": (([40], [40]), 40, 16, None, (8, 9, 24)),
+    # a window of 20 behind position 70 starts in tile 1: tile 0 is dead at
+    # the FRONT, and the grid still starts there
+    "window_starts_in_tile_1": (([70], [16]), 16, 8, 20, (2, 3, 4)),
+    # a context that fills its table walks all of it
+    "context_fills_the_table": (([112], [16]), 16, 8, None, (4, 4, 4)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HAND_COUNTED_WALKS))
+def test_prefill_tile_counts_are_the_hand_count(case, monkeypatch):
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_TOKENS", 32)   # two 16-token pages a tile
+    monkeypatch.setattr(pa, "_Q_ROWS", 32)      # 16 tokens x a group of 2
+    (ctx, lens), t, table, window, want = HAND_COUNTED_WALKS[case]
+    nkv = 3
+    assert pa.prefill_tile_counts(ctx, lens, t, 2 * nkv, (nkv, 16, 128),
+                                  table, window) \
+        == tuple(nkv * n for n in want)
+
+
+def _chunk_tile_spans(eng, prompt_tokens):
+    """The ``chunk_attn_tiles_*`` of every mixed ``decode_step`` and every
+    ``prefill_chunk`` while a split prompt enters beside a live stream."""
+    rng = np.random.default_rng(1)
+    vocab = eng.family.cfg.vocab_size
+    eng.put(1, rng.integers(1, vocab, (9,)).tolist())
+    eng.put_split(0, rng.integers(1, vocab, (prompt_tokens,)).tolist())
+    while eng.state.seqs[0].prefilling:
+        eng.step()
+    eng.step()
+    spans = [e for e in eng.tracer.events() if e["ph"] == "X"]
+    keys = ["chunk_attn_tiles_" + k for k in ("live", "grid", "table")]
+    mixed = [e["args"] for e in spans if e["name"] == "decode_step"
+             and e["args"]["chunk_tokens"]]
+    chunks = [e["args"] for e in spans if e["name"] == "prefill_chunk"]
+    plain = [e["args"] for e in spans if e["name"] == "decode_step"
+             and not e["args"]["chunk_tokens"]]
+    assert mixed and plain and not any(k in a for a in plain for k in keys)
+    for a in mixed + chunks:
+        assert all(type(a[k]) is int for k in keys)     # numbers, no lists
+    return ([(a["chunk_ctx"], a["chunk_tokens"]) + tuple(a[k] for k in keys)
+             for a in mixed],
+            [(a["ctx"], a["tokens"]) + tuple(a[k] for k in keys)
+             for a in chunks])
+
+
+def test_chunk_spans_say_how_far_the_prefill_walk_went(devices8,
+                                                       monkeypatch):
+    """A mixed tick's ``decode_step`` and the request's ``prefill_chunk``
+    carry, for ONE layer's ``paged_prefill`` call, the grid steps that hold
+    context the chunk attends, the steps the grid takes and the steps a
+    grid as wide as the block table would take - host integers of the
+    launch, from the kernel's own tile sizes."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_TOKENS", 32)   # two 16-token pages a tile
+    cfg, eng = _serving_engine(trace=True, split=16)
+    assert (cfg.num_kv_heads, eng.state.max_blocks_per_seq) == (2, 8)
+    mixed, chunks = _chunk_tile_spans(eng, 60)
+    # 2 KV heads x (1, 1, 2, 2 of the table's 4 tiles): the walk ends with
+    # the chunk's last row
+    assert mixed == chunks == [(0, 16, 2, 2, 8), (16, 16, 2, 2, 8),
+                               (32, 16, 4, 4, 8), (48, 12, 4, 4, 8)]
+
+
+def test_chunk_spans_sum_both_kinds_walks_over_their_layers(monkeypatch):
+    """A family with two table kinds: one call a kind - the window kind's at
+    the context counted from the blocks it gave back, through its own short
+    table, under its window -, each times the kind's layers, summed into
+    plain numbers."""
+    from deepspeed_tpu.inference.engine_v2 import build_engine_v2
+    from deepspeed_tpu.models import cohere2_moe
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_TOKENS", 8)    # two 4-token pages a tile
+    cfg = cohere2_moe.Cohere2MoeConfig.tiny()
+    eng = build_engine_v2(
+        cohere2_moe, cfg, cohere2_moe.init(cfg, jax.random.PRNGKey(0)),
+        config={"dtype": "float32", "prefill_bucket": 8,
+                "split_prefill_chunk": 8,
+                "trace": {"enabled": True, "ring_size": 4096,
+                          "dump_on_crash": False},
+                "ragged": {"max_tracked_sequences": 2,
+                           "max_ragged_batch_size": 2,
+                           "memory_config_blocks": 70, "block_size": 4}})
+    kind, = eng.state.window_kinds
+    full, window = eng.cache["k"].shape, eng.cache["k_window"].shape
+    assert (full[0], window[0], kind.window, kind.blocks_per_seq,
+            cfg.num_kv_heads) == (1, 3, 16, 7, 2)
+    mixed, chunks = _chunk_tile_spans(eng, 51)
+    assert mixed == chunks and [c[:2] for c in chunks] == [
+        (8 * i, 8) for i in range(6)] + [(48, 3)]
+    table = eng.state.max_blocks_per_seq
+    for ctx, n, *got in chunks:
+        given = max(0, ctx - 16 + 1) // 4 * 4   # tokens the kind gave back
+        want = np.asarray(pa.prefill_tile_counts(
+            [ctx], [n], 8, cfg.num_heads, full, table)) \
+            + 3 * np.asarray(pa.prefill_tile_counts(
+                [ctx - given], [n], 8, cfg.num_heads, window, 7, 16))
+        assert got == want.tolist()
+    # by hand, the last chunk (context 48, 3 real rows), 2 KV heads: the
+    # full layer holds 7 live tiles of 8 tokens, takes 7 and its table has
+    # max_blocks / 2; a window layer, 8 blocks given back, sits at context
+    # 16 of a 7-block table: tiles 0-2 live, 3 taken, 4 table-wide
+    assert chunks[-1][2:] == (2 * (7 + 3 * 3), 2 * (7 + 3 * 3),
+                              2 * (-(-table // 2) + 3 * 4))
+
+
 def _kernel_cases():
     """One tiny call into each ``pallas_call`` site -> the kernel's name."""
     import jax
