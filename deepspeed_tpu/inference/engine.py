@@ -36,7 +36,10 @@ from .sampling import SamplingParams, sample
 @dataclasses.dataclass
 class ModelFamily:
     """What the engine needs from a model family: pure functions over a param
-    pytree (the counterpart of passing an ``nn.Module`` + injection policy)."""
+    pytree (the counterpart of passing an ``nn.Module`` + injection policy).
+    The paged forward a serving engine calls, ``apply_paged``, takes ``rows=``
+    (the rows whose logits the program reads) and a mixed call
+    (``models/_paged.py MixedCall``) in every family."""
 
     cfg: Any
     apply_fn: Callable  # (cfg, params, tokens) -> logits
@@ -54,11 +57,6 @@ class ModelFamily:
     # its ``apply_paged`` takes each row's ``slots`` (models/granite_hybrid.py)
     state_slot_bytes: Optional[Callable] = None
     state_leaves: Tuple[str, ...] = ()
-    # the family's ``apply_paged`` takes a mixed call (``models/_paged.py``
-    # ``MixedCall``: a prefill chunk's rows beside every slot's decode row),
-    # so a serving step with both runs ONE program; a family without it
-    # keeps the two calls
-    mixed_paged: bool = False
     # (cfg, contexts) -> what ONE layer's learned token selection does for
     # rows at those contexts (``sparse_rows``, ``sparse_ctx_scored``,
     # ``sparse_kv_selected``), {} for a family without one; a family that
@@ -87,7 +85,6 @@ class ModelFamily:
                    moe_rows=getattr(module, "moe_rows", None),
                    state_slot_bytes=getattr(module, "state_slot_bytes", None),
                    state_leaves=tuple(getattr(module, "STATE_LEAVES", ())),
-                   mixed_paged=bool(getattr(module, "MIXED_PAGED", False)),
                    sparse_rows=getattr(module, "sparse_rows", None),
                    window_kinds=getattr(module, "window_kinds", None))
 

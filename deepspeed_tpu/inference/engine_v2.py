@@ -21,7 +21,6 @@ serving then emits >1 token per model step without a second model.
 
 from __future__ import annotations
 
-import inspect
 import time
 from collections import Counter, deque
 from itertools import repeat
@@ -33,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..comm.mesh import MeshManager
-from ..models._paged import MixedCall, gather_rows
+from ..models._paged import MixedCall
 from ..ops.quantization import kv_dequantize_int8, kv_quantize_int8
 from ..telemetry.compile import CompileMonitor
 from ..telemetry.schema import DRAIN_CAUSES
@@ -79,13 +78,68 @@ def prompt_lookup_draft(history, max_tokens: int, ngram_max: int = 3,
 _NO_WORK = {"prefill_tokens": 0, "prefill_kv_tokens": 0, "decode_seqs": 0,
             "kv_tokens": 0}
 
-# the one static sampling config the programs are built with (a final prefill
-# chunk apart): every greedy-equivalent request canonicalizes to it
+# the one static sampling config the programs are built with: every
+# greedy-equivalent request canonicalizes to it
 _GREEDY = SamplingParams(greedy=True)
 
-# why a family with recurrent state refuses the disagg block export / import
-_HANDOFF = ("a sequence's blocks are not its whole state, and the "
-            "destination resumes through the prefix cache")
+# What a kind of cache state refuses: a row a kind a family's cache may hold
+# beside its K and V blocks (the error it raises, then {what is refused: why},
+# in the order tried), a column a configuration feature, refused at
+# construction (``_refuse_features``), or a call, refused when it is made
+# (``_refuse_call``: ``fork``, and ``handoff``, the disagg block export /
+# import). A column a row does not have is served.
+_SPILLS = "it spills and restores prefix-cache blocks"
+_REFUSALS = {
+    "recurrent_state": (RecurrentStateError, {
+        "inference.prefix_cache":
+            "a cached prefix's blocks hold its keys and values but not "
+            "the recurrent state at its end, and no snapshot of that "
+            "state is kept",
+        "inference.prefix_cache.host_spill": _SPILLS,
+        "inference.speculative":
+            "a rejected draft is rolled back by truncating blocks, and "
+            "a recurrent state cannot be rolled back",
+        "inference.kv_quant": "the family's cache has no quantized mode",
+        "fork":
+            "the child shares the parent's KV blocks, and the parent's "
+            "recurrent state would have to be copied into a slot of its "
+            "own, which is not written",
+        "handoff":
+            "a sequence's blocks are not its whole state, and the "
+            "destination resumes through the prefix cache"}),
+    # each feature that names the two pools it knows, or that no test holds
+    # over three
+    "index_pool": (IndexPoolError, {
+        "inference.kv_quant": "the index keys' pool has no quantized mode",
+        "inference.prefix_cache":
+            "a retained prefix's blocks would have to keep their index "
+            "keys too, and nothing checks that they do",
+        "inference.prefix_cache.host_spill": _SPILLS,
+        "inference.speculative":
+            "a rejected draft's index keys would have to be rolled "
+            "back with its keys and values, and nothing checks that "
+            "they are",
+        "handoff":
+            "the wire format carries the K and V pools alone, and a block "
+            "without its index keys selects from zeros"}),
+    # each feature that takes a sequence's state to be every block it ever
+    # wrote (a rollback past the window is refused in ``StateManager``)
+    "window_kinds": (KVKindError, {
+        "inference.prefix_cache":
+            "a retained prefix would have to keep the window layers' "
+            "blocks the sequence gave back",
+        "inference.prefix_cache.host_spill": _SPILLS,
+        "inference.speculative":
+            "a rejected draft is rolled back by truncating blocks, and "
+            "nothing checks a rollback against the blocks given back",
+        "inference.kv_quant": "the window kind's pool has no quantized mode",
+        "fork":
+            "a child shares its parent's blocks, and a window kind's are "
+            "given back under the one that moves ahead",
+        "handoff":
+            "the wire format carries the full kind's blocks alone, and the "
+            "window layers' state would be missing"}),
+}
 
 
 # how a slot's row of a decode-shaped program finds its token (``_slot_src``;
@@ -192,11 +246,6 @@ class InferenceEngineV2(InferenceEngine):
             import deepspeed_tpu.models.llama as _llama  # default family
             self._apply_paged = _llama.apply_paged
             self._init_paged = _llama.init_paged_cache
-        # the rows a program reads (``_paged_forward``): an ``apply_paged``
-        # that DECLARES ``rows`` runs its head on those alone; any other
-        # scores every row and the engine picks from the result
-        self._takes_rows = "rows" in inspect.signature(
-            self._apply_paged).parameters
         max_blocks_per_seq = max(
             2, (self.family.cfg.max_seq_len + rc.block_size - 1) // rc.block_size)
         # --- recurrent state (docs/serving.md "Recurrent state"): a family
@@ -204,10 +253,8 @@ class InferenceEngineV2(InferenceEngine):
         # sequence slot a state-space layer beside its KV blocks. What treats
         # a sequence's state as its blocks is refused here or at its call.
         self._recurrent = self.family.state_slot_bytes is not None
-        slot_kw = {}
-        if self._recurrent:
-            self._refuse_for_recurrent_state()
-            slot_kw = {"slots": rc.max_tracked_sequences}
+        slot_kw = {"slots": rc.max_tracked_sequences} \
+            if self._recurrent else {}
         # --- a learned token selection (docs/serving.md "Learned token
         # selection"): the family's cache has a third block pool, the index
         # keys'. The block lifecycle carries it as it carries any leaf with
@@ -215,8 +262,6 @@ class InferenceEngineV2(InferenceEngine):
         # knows is refused, here or at its call.
         self._indexed = bool(self.family.sparse_rows
                              and self.family.sparse_rows(self.family.cfg, ()))
-        if self._indexed:
-            self._refuse_for_index_pool()
         # --- kinds of KV state (docs/serving.md "Kinds of KV state"): a
         # family with sliding-window layers keeps their keys and values in a
         # pool of their own, sized from what bounds it - the slots, the
@@ -226,9 +271,14 @@ class InferenceEngineV2(InferenceEngine):
         # window. ``memory_config_blocks`` stays the full kind's count.
         self._window = dict(self.family.window_kinds(self.family.cfg)) \
             if self.family.window_kinds else {}
+        # what those kinds refuse: a feature here, a call when it is made
+        self._refusals = [_REFUSALS[kind] for kind, has in (
+            ("recurrent_state", self._recurrent),
+            ("index_pool", self._indexed),
+            ("window_kinds", self._window)) if has]
+        self._refuse_features()
         kinds = ()
         if self._window:
-            self._refuse_for_window_kinds()
             call = _round_up(self.config.split_prefill_chunk,
                              self.config.prefill_bucket) \
                 if self.config.split_prefill_chunk > 0 \
@@ -547,77 +597,26 @@ class InferenceEngineV2(InferenceEngine):
             rec["span"].end(cancelled=True)
 
     # ------------------------------------------------------------------ #
-    def _refuse_for_recurrent_state(self) -> None:
-        """Configuration-time refusals for a family with recurrent state
-        (``fork`` and the disagg block export / import refuse at their
-        call)."""
+    def _refuse_features(self) -> None:
+        """Configuration-time refusals (``_REFUSALS``): the first feature
+        that is on and that a kind of the family's cache state refuses."""
         cfg = self.config
         kq = getattr(cfg, "kv_quant", None)
-        for on, feature, why in (
-                (cfg.prefix_cache.enabled, "inference.prefix_cache",
-                 "a cached prefix's blocks hold its keys and values but not "
-                 "the recurrent state at its end, and no snapshot of that "
-                 "state is kept"),
-                (getattr(cfg.prefix_cache, "host_spill", False),
-                 "inference.prefix_cache.host_spill",
-                 "it spills and restores prefix-cache blocks"),
-                (cfg.speculative.enabled, "inference.speculative",
-                 "a rejected draft is rolled back by truncating blocks, and "
-                 "a recurrent state cannot be rolled back"),
-                (kq is not None and kq.enabled, "inference.kv_quant",
-                 "the family's cache has no quantized mode")):
-            if on:
-                raise RecurrentStateError(feature, why)
+        on = {"inference.prefix_cache": cfg.prefix_cache.enabled,
+              "inference.prefix_cache.host_spill":
+                  getattr(cfg.prefix_cache, "host_spill", False),
+              "inference.speculative": cfg.speculative.enabled,
+              "inference.kv_quant": kq is not None and kq.enabled}
+        for error, refused in self._refusals:
+            for feature, why in refused.items():
+                if on.get(feature):
+                    raise error(feature, why)
 
-    def _refuse_call(self, call: str, why: str) -> None:
-        """Call-time refusal for a family with recurrent state."""
-        if self._recurrent:
-            raise RecurrentStateError(call, why)
-
-    def _refuse_for_index_pool(self) -> None:
-        """Configuration-time refusals for a family with a learned token
-        selection (the disagg block export / import refuse at their call):
-        each feature that names the two pools it knows, or that no test
-        holds over three."""
-        cfg = self.config
-        kq = getattr(cfg, "kv_quant", None)
-        for on, feature, why in (
-                (kq is not None and kq.enabled, "inference.kv_quant",
-                 "the index keys' pool has no quantized mode"),
-                (cfg.prefix_cache.enabled, "inference.prefix_cache",
-                 "a retained prefix's blocks would have to keep their index "
-                 "keys too, and nothing checks that they do"),
-                (getattr(cfg.prefix_cache, "host_spill", False),
-                 "inference.prefix_cache.host_spill",
-                 "it spills and restores prefix-cache blocks"),
-                (cfg.speculative.enabled, "inference.speculative",
-                 "a rejected draft's index keys would have to be rolled "
-                 "back with its keys and values, and nothing checks that "
-                 "they are")):
-            if on:
-                raise IndexPoolError(feature, why)
-
-    def _refuse_for_window_kinds(self) -> None:
-        """Configuration-time refusals for a family with window layers' KV
-        state (``fork``, the disagg block export / import and a rollback
-        past the window refuse at their call): each feature that takes a
-        sequence's state to be every block it ever wrote."""
-        cfg = self.config
-        kq = getattr(cfg, "kv_quant", None)
-        for on, feature, why in (
-                (cfg.prefix_cache.enabled, "inference.prefix_cache",
-                 "a retained prefix would have to keep the window layers' "
-                 "blocks the sequence gave back"),
-                (getattr(cfg.prefix_cache, "host_spill", False),
-                 "inference.prefix_cache.host_spill",
-                 "it spills and restores prefix-cache blocks"),
-                (cfg.speculative.enabled, "inference.speculative",
-                 "a rejected draft is rolled back by truncating blocks, and "
-                 "nothing checks a rollback against the blocks given back"),
-                (kq is not None and kq.enabled, "inference.kv_quant",
-                 "the window kind's pool has no quantized mode")):
-            if on:
-                raise KVKindError(feature, why)
+    def _refuse_call(self, call: str, column: str) -> None:
+        """Call-time refusal of ``call``, the table's ``column``."""
+        for error, refused in self._refusals:
+            if column in refused:
+                raise error(call, refused[column])
 
     def _kv_kind_args(self, firsts, counts, prefix: str = "") -> Dict[str, int]:
         """Span arguments of a call in a family with window layers: the
@@ -664,17 +663,6 @@ class InferenceEngineV2(InferenceEngine):
     def _chunk_contexts(self, ch: _Chunk):
         return ch.ctx + 1 + np.arange(len(ch.tokens))
 
-    def _refuse_wire(self, call: str) -> None:
-        """The disagg wire carries keys and values, by name."""
-        if self._indexed:
-            raise IndexPoolError(call, "the wire format carries the K and V "
-                                 "pools alone, and a block without its "
-                                 "index keys selects from zeros")
-        if self._window:
-            raise KVKindError(call, "the wire format carries the full "
-                              "kind's blocks alone, and the window layers' "
-                              "state would be missing")
-
     def _jit(self, key, fn, **jit_kwargs):
         """Every paged program routes through the compile monitor's shared
         registration helper. ``key[0]`` is the program FAMILY name, so a new
@@ -691,10 +679,10 @@ class InferenceEngineV2(InferenceEngine):
 
     # ------------------------------------------------------------------ #
     # the programs: ONE forward (``_paged_forward``) that scores the rows
-    # the program reads, one of two samplers (``_sampler``), in five
-    # builders - prefill, chunk_prefill, decode, decode_chunk (a step's
-    # chunk and its decodes in one forward), spec_verify. ``_dispatch``
-    # launches all of them.
+    # the program reads, one of two samplers (``_sampler``), in four
+    # builders - prefill, decode, decode_chunk (a prompt chunk, with a
+    # step's decodes beside it in the one forward), spec_verify.
+    # ``_dispatch`` launches all of them.
     # ------------------------------------------------------------------ #
     def _paged_forward(self, params, tokens, cache, tables, ctx, valid,
                        slots=None, rows=None):
@@ -709,19 +697,12 @@ class InferenceEngineV2(InferenceEngine):
         decode-shaped call's row i IS slot i, the family's default).
         ``rows`` [b, r]: the rows along ``t`` whose logits the program
         reads (a prefill's last real rows; a mixed call's decode rows and
-        its chunk's last real row) - the family's head runs on those alone
-        where its ``apply_paged`` declares ``rows`` (``_takes_rows``), else
-        on every row and the rows are picked here; None (``decode``,
-        ``verify``) reads them all.
+        its chunk's last real row) - the family's head runs on those alone;
+        None (``decode``, ``verify``) reads them all.
         Returns (logits [b, t, V] fp32 - [b, r, V] with ``rows`` -, cache)."""
         kw = {} if slots is None else {"slots": slots}
-        if self._takes_rows:
-            kw["rows"] = rows
-        logits, cache = self._apply_paged(self.family.cfg, params, tokens,
-                                          cache, tables, ctx, valid=valid,
-                                          **kw)
-        return (logits if self._takes_rows
-                else gather_rows(logits, rows)), cache
+        return self._apply_paged(self.family.cfg, params, tokens, cache,
+                                 tables, ctx, valid=valid, rows=rows, **kw)
 
     def _prefill_fn(self, pad_t: int, n: int, with_ctx: bool, rows: bool):
         """One compiled prefill over ``n`` admitted sequences at once —
@@ -861,40 +842,6 @@ class InferenceEngineV2(InferenceEngine):
             self.cache = fn(self.cache, jnp.asarray(src, jnp.int32),
                             jnp.asarray(dst, jnp.int32))
 
-    def _chunk_prefill_fn(self, chunk_t: int, final: bool,
-                          sp: SamplingParams):
-        """One compiled prefill CHUNK for one sequence at an arbitrary
-        context offset — the Dynamic-SplitFuse unit (reference
-        blogs/deepspeed-fastgen: 'decompose long prompts into chunks').
-        Mid chunks only write KV; the final chunk also samples the first
-        token. One compile per (chunk_t, final) for mid chunks — sp is
-        unused there, so keying on it would recompile identical programs
-        per client config — plus one per sp for final chunks (the one
-        program still compiled per client sampling config)."""
-        key = ("chunk_prefill", chunk_t, sp if final else None, final)
-        if key not in self._paged_fns:
-
-            def chunk_prefill(params, cache, tokens, n_valid, ctx, table,
-                              *rest):
-                # tokens [1, chunk_t]; ctx = tokens already cached; then the
-                # sequence's slot (recurrent state), rng, uid
-                *slot, rng, uid = rest
-                valid = (jnp.arange(chunk_t) < n_valid)[None, :]
-                # a chunk that does not end its prompt asks for no logits
-                # (its head is dead code); a final one reads its last real row
-                logits, cache = self._paged_forward(
-                    self._dq(params), tokens, cache, table[None], ctx[None],
-                    valid, *(s_[None] for s_ in slot),
-                    rows=_last_real(n_valid)[None, None] if final else None)
-                if not final:
-                    return cache
-                tok = sample(jax.random.fold_in(rng, uid), logits[0, 0], sp)
-                return tok.astype(jnp.int32), cache
-
-            self._paged_fns[key] = self._jit(key, chunk_prefill,
-                                             donate_argnums=(1,))
-        return self._paged_fns[key]
-
     def _dispatch(self, fn, pre, seed: int, post=(), prev: bool = False):
         """The one place a forward program is launched: ``pre`` and ``post``
         are the call's host arrays in the program's argument order, on
@@ -920,13 +867,11 @@ class InferenceEngineV2(InferenceEngine):
     def _row_args(self, rows: int, read: int) -> Dict[str, int]:
         """Span arguments of a launch over ``rows`` token rows (padding
         included) whose program reads ``read`` rows' logits: ``rows`` and
-        ``head_rows``, the rows its head scores - ``read`` where the
-        family's ``apply_paged`` takes the rows (``_takes_rows``), else
-        every row. Counted as they are said (``engine_events``)."""
-        head = read if self._takes_rows else rows
+        ``head_rows``, the rows its head scores. Counted as they are said
+        (``engine_events``)."""
         self.rows += rows
-        self.head_rows += head
-        return {"rows": rows, "head_rows": head}
+        self.head_rows += read
+        return {"rows": rows, "head_rows": read}
 
     def _moe_args(self, rows: int) -> Dict[str, int]:
         """Span arguments of a call over ``rows`` token rows (padding
@@ -1042,19 +987,13 @@ class InferenceEngineV2(InferenceEngine):
         self._slot_tokens[desc.slot] = tok
         self._out.setdefault(ch.uid, []).append(tok)
 
-    def _chunk_program(self, ch: _Chunk, live, table, mixed: bool = True):
+    def _chunk_program(self, ch: _Chunk, live, table):
         """(program, its arrays before the key, its arrays after) for one
-        chunk. ``mixed``: the family's ONE chunk program, ``decode_chunk``,
-        whose decode rows are the slots of ``live`` - none, where nothing
-        decodes beside the chunk -, so that every chunk of a ``step()``
-        runs (and a server's start-up loads) one program; else
-        ``chunk_prefill`` (a family that takes no mixed call, ``step_many``,
-        a speculative step)."""
+        chunk: ``decode_chunk``, whose decode rows are the slots of
+        ``live`` - none, where nothing decodes beside the chunk -, so that
+        every chunk runs (and a server's start-up loads) one program."""
         chunk = ch.arrays(table, self._recurrent)
         uid = (np.int32(ch.uid),)
-        if not mixed:
-            return self._chunk_prefill_fn(ch.width, ch.final, ch.sp), \
-                chunk, uid
         # a chunk that does not end its prompt samples for nothing; slots
         # hold canonical params (``_canon_sp``): see ``_sampler``
         sp = self._canon_sp(ch.sp) if ch.final else _GREEDY
@@ -1064,19 +1003,18 @@ class InferenceEngineV2(InferenceEngine):
                 self._slots(live) + chunk,
                 uid + (sp_arrays(self._slot_sp + [sp]) if rows else ()))
 
-    def _advance_prefill(self, seed: int = 0, mixed: bool = False) -> bool:
+    def _advance_prefill(self, seed: int = 0) -> bool:
         """Advance the oldest pending split prefill by one chunk with
-        nothing decoding beside it, sampling with the SamplingParams given
-        at put_split time (``mixed``: see ``_chunk_program``). Returns
+        nothing decoding beside it (``decode_chunk`` with no slot active),
+        sampling with the SamplingParams given at put_split time. Returns
         whether that chunk completed its prompt. Its first token is then in
-        flight (``mixed``: the program is decode-shaped, and the next one
-        takes the token from its result) or, from ``chunk_prefill``, read
-        here and now."""
+        flight: the program is decode-shaped, and the next one takes the
+        token from its result."""
         if not self._pending_prefill:
             return False
         ch = self._next_chunk()
         rec = self._req.get(ch.uid)     # the request's ring lifecycle
-        slots = len(self._slot_tokens) if mixed else 0
+        slots = len(self._slot_tokens)
         rows = ch.width + slots
         seq = self._next_seq()
         with self.tracer.span(
@@ -1085,38 +1023,25 @@ class InferenceEngineV2(InferenceEngine):
                 parent=rec["span"].span_id if rec else None,
                 table_blocks=self.state.max_blocks_per_seq,
                 **self._chunk_args(ch), **self._moe_args(rows),
-                # ``chunk_prefill`` reads its last real row, and none of a
-                # chunk that does not end its prompt
-                **self._row_args(rows, slots + int(mixed or ch.final)),
+                **self._row_args(rows, slots + 1),
                 **self._ssm_args(1, len(ch.tokens)),
                 **self._sparse_args(self._chunk_contexts(ch)),
                 **self._kv_kind_args([ch.ctx], [len(ch.tokens)]),
                 **self._chunk_tile_args(ch)):
             with self.tracer.span("engine_prep", cat="serving"):
                 table = self._table(ch.desc, len(ch.tokens))
-                fn, pre, post = self._chunk_program(ch, (), table, mixed)
+                fn, pre, post = self._chunk_program(ch, (), table)
             if self._trace_on:
                 self._req_compute_begin(ch.uid)  # first chunk ends queue-wait
-            *tok, self.cache = self._dispatch(fn, pre, seed, post,
-                                              prev=mixed)
+            tok, self.cache = self._dispatch(fn, pre, seed, post, prev=True)
             self._chunk_landed(ch, table)
-            if not ch.final:
-                # no engine_wait: the call is asynchronous and nothing here
-                # blocks on it (nor reads a token the program sampled for
-                # nothing), so this span says dispatch, not device
-                return False
-            if mixed:
-                self._launched(tok[0], (), ch, None, seq)
-                return True
-            # what was launched before it lands first
-            self.drain("final_chunk")
-            with self.tracer.span("engine_wait", cat="serving", seq=seq):
-                tok = int(np.asarray(tok[0]))
-            if self._trace_on:
-                self._req_first_token(ch.uid, time.monotonic_ns())
-            with self.tracer.span("engine_emit", cat="serving"):
-                self._first_token(ch, tok)
-            return True
+            # no engine_wait: the call is asynchronous and nothing here
+            # blocks on it (nor reads a token a chunk that does not end its
+            # prompt sampled for nothing), so this span says dispatch, not
+            # device
+            if ch.final:
+                self._launched(tok, (), ch, None, seq)
+            return ch.final
 
     def _seat(self, desc, table, sp: SamplingParams) -> None:
         """The sequence's slot as the next decode-shaped call reads it."""
@@ -1200,20 +1125,18 @@ class InferenceEngineV2(InferenceEngine):
         return self._paged_fns[key]
 
     def _decode_chunk_fn(self, chunk_t: int, rows: bool):
-        """The step's prefill chunk AND its decodes in ONE forward (a family
-        whose ``apply_paged`` takes a mixed call: ``ModelFamily.
-        mixed_paged``): every slot's token and the chunk's ``chunk_t``
-        tokens are one row dimension of ``slots + chunk_t`` rows, so the
-        tick reads every weight once where ``chunk_prefill`` then ``decode``
-        read them twice; only attention (and a recurrent state) runs a
-        segment at a time (``models/_paged.py``). ONE variant for mid and
-        final chunks: the chunk's last real row is always sampled (its key
-        folds in the uid, as ``chunk_prefill``'s) and the host drops the
-        token of a chunk that does not end its prompt - so the program
-        compiles once a ``(slots, chunk_t)``, greedy or ``rows`` (see
-        ``_sampler``; the per-row arrays are the slots' and then the
-        chunk's). The slots' tokens come as ``decode``'s do. Returns (tokens
-        [slots + 1], cache)."""
+        """A prefill chunk AND the step's decodes in ONE forward (a mixed
+        call: ``models/_paged.py MixedCall``): every slot's token and the
+        chunk's ``chunk_t`` tokens are one row dimension of ``slots +
+        chunk_t`` rows, so the tick reads every weight once; only attention
+        (and a recurrent state) runs a segment at a time. ONE variant for
+        mid and final chunks: the chunk's last real row is always sampled
+        (its key folds in the uid) and the host drops the token of a chunk
+        that does not end its prompt - so the program compiles once a
+        ``(slots, chunk_t)``, greedy or ``rows`` (see ``_sampler``; the
+        per-row arrays are the slots' and then the chunk's). The slots'
+        tokens come as ``decode``'s do. Returns (tokens [slots + 1],
+        cache)."""
         name = "decode_chunk" + ("_dyn" if rows else "")
         key = (name, chunk_t)
         if key not in self._paged_fns:
@@ -1221,10 +1144,10 @@ class InferenceEngineV2(InferenceEngine):
 
             def decode_chunk(params, cache, prev, seat, lens, tables, active,
                              chunk, n_valid, ctx, table, *rest):
-                # the slots as ``decode`` takes them; the chunk as
-                # ``chunk_prefill`` does: chunk [1, chunk_t], then the
-                # sequence's slot (recurrent state), rng, uid; then the
-                # sampling arrays [slots + 1] (rows)
+                # the slots as ``decode`` takes them; the chunk [1,
+                # chunk_t], its real tokens, context offset and block
+                # table, then the sequence's slot (recurrent state), rng,
+                # uid; then the sampling arrays [slots + 1] (rows)
                 rest = list(rest)
                 slot = rest.pop(0) if self._recurrent else None
                 rng, uid, *sp_rows = rest
@@ -1310,7 +1233,8 @@ class InferenceEngineV2(InferenceEngine):
         ``max_draft_tokens + 1``, for the next ``collect``. Returns False
         when no sequence produced a draft (the caller runs the plain decode
         program, keeping draft-less steps bit-identical to non-spec
-        serving). Drafts read history, so nothing may be in flight."""
+        serving). Drafts read history, so no token of ``live`` may be in
+        flight (a prompt's first token may: its sequence is not live yet)."""
         drafts = {d.uid: self._draft_tokens(d) for d in live}
         bs = self.state.block_size
         # capacity guard: verification may need blocks for up to k+1 new
@@ -1517,7 +1441,7 @@ class InferenceEngineV2(InferenceEngine):
     # ------------------------------------------------------------------ #
     # what launch(), step_many() and _spec_step() share around their program
     # ------------------------------------------------------------------ #
-    def _prefill_then_live(self, seed: int, mixed: bool = False, hold=()):
+    def _prefill_then_live(self, seed: int, ride: bool = False, hold=()):
         """How a step begins: ``last_step`` starts over - the one-shot
         prefills that ran since the previous step (``put``/``put_many``, a
         scheduler tick's admissions) count with this step's
@@ -1525,10 +1449,10 @@ class InferenceEngineV2(InferenceEngine):
         prefilling one is not among them, the one this step's chunk
         completes included: it has its first token only; nor one of
         ``hold``, the uids the caller knows to be at their end), and the
-        oldest split prefill advances one chunk. ``mixed``: the caller can
-        run a chunk and its decodes as ONE program (``_launch_decode``);
-        where there are both, the chunk is left to it. Returns (live, the
-        chunk left over or None)."""
+        oldest split prefill advances one chunk. ``ride``: the caller runs
+        a chunk and its decodes as ONE launch (``_launch_decode``; not a
+        fused quantum, nor under speculation); where there are both, the
+        chunk is left to it. Returns (live, the chunk left over or None)."""
         self.last_step = dict(_NO_WORK,
                               prefill_kv_tokens=self._admitted_kv_tokens,
                               **self._admitted_ssm)
@@ -1536,9 +1460,9 @@ class InferenceEngineV2(InferenceEngine):
         self._admitted_ssm = {}
         live = [d for d in self.state.seqs.values()
                 if not d.finished and not d.prefilling and d.uid not in hold]
-        if mixed and live and self._pending_prefill:
+        if ride and live and self._pending_prefill:
             return live, self._next_chunk()
-        done = self._advance_prefill(seed, mixed)
+        done = self._advance_prefill(seed)
         if not live:
             # no decodes in flight: the one-chunk-per-step bound exists to
             # protect live decodes from prefill stalls — with none to
@@ -1546,7 +1470,7 @@ class InferenceEngineV2(InferenceEngine):
             # until it completes (it holds KV blocks the whole time), then
             # stop: the completed sequence is a live decode to protect again
             while self._pending_prefill and not done:
-                done = self._advance_prefill(seed, mixed)
+                done = self._advance_prefill(seed)
         return live, None
 
     def _reserve(self, live, counts) -> None:
@@ -1822,22 +1746,21 @@ class InferenceEngineV2(InferenceEngine):
         count, not a value), WITHOUT reading a token - every live sequence
         advances by one, so the next launch needs nothing of this one's
         result but the tokens, and those it takes on the device. Returns
-        the number of programs it left for ``collect`` (0 or 1). At most
-        one program stays unread across a launch; a speculative step reads
-        history and so runs whole here (its tokens wait for ``collect``)."""
+        the number of programs it left for ``collect`` (0 or 1; a prompt's
+        final chunk is one more under speculation, which runs it apart).
+        At most one program stays unread across a launch; a speculative
+        step reads its sequences' history and so runs whole here (its
+        tokens wait for ``collect``)."""
         self.steps += 1
         if self._spec_on:
             self.drain("spec")
         while len(self._flight) > 1:
             self._read(self._flight.popleft())
         before = len(self._flight)
-        live, chunk = self._prefill_then_live(
-            seed, self.family.mixed_paged and not self._spec_on, hold)
-        if live and self._spec_on and self._spec_step(live, seed):
-            return 0
+        live, chunk = self._prefill_then_live(seed, not self._spec_on, hold)
         if chunk is not None:
             self._launch_decode(live, seed, chunk)
-        elif live:
+        elif live and not (self._spec_on and self._spec_step(live, seed)):
             if self._spec_on:
                 # no sequence drafted this step: run the plain decode
                 # program — bit-identical to a non-spec step, and cheaper
@@ -2043,14 +1966,7 @@ class InferenceEngineV2(InferenceEngine):
         whichever appends first gets a private copy via copy-on-write. The
         child starts with an empty ``generated`` list and, unless ``sp`` is
         given, the parent's sampling params."""
-        self._refuse_call("fork", "the child shares the parent's KV blocks, "
-                          "and the parent's recurrent state would have to "
-                          "be copied into a slot of its own, which is not "
-                          "written")
-        if self._window:
-            raise KVKindError("fork", "a child shares its parent's blocks, "
-                              "and a window kind's are given back under the "
-                              "one that moves ahead")
+        self._refuse_call("fork", "fork")
         self.drain("fork")  # the child starts from the parent's last token
         desc = self.state.fork(uid, new_uid)
         self._req_admit(new_uid, desc.seen_tokens)
@@ -2116,8 +2032,7 @@ class InferenceEngineV2(InferenceEngine):
         did NOT cost."""
         if wire not in ("native", "int8"):
             raise ValueError(f"unknown KV wire format {wire!r}")
-        self._refuse_call("export_kv_blocks", _HANDOFF)
-        self._refuse_wire("export_kv_blocks")
+        self._refuse_call("export_kv_blocks", "handoff")
         desc = self.state.lookup(uid)
         self.drain("export")   # the blocks hold every token's KV first
         self.state.mark_filled(desc)
@@ -2170,8 +2085,7 @@ class InferenceEngineV2(InferenceEngine):
         stamp the converted payload into the device pool. A dropped block
         (pool exhausted / retention off) is harmless — resume re-prefills
         that suffix. Returns ``{"imported", "dedup", "dropped"}``."""
-        self._refuse_call("import_kv_blocks", _HANDOFF)
-        self._refuse_wire("import_kv_blocks")
+        self._refuse_call("import_kv_blocks", "handoff")
         res = {"imported": 0, "dedup": 0, "dropped": 0}
         for h, payload in zip(chain_hashes, blocks):
             if self.state.prefix_cache and h in self.state.index._by_hash:

@@ -595,16 +595,14 @@ class ServingScheduler:
         """Whether a prompt of ``n_tokens`` enters through ``put_split``
         (with SplitFuse chunking on): one that outgrows a chunk always, and
         one that FITS a chunk wherever the one-shot prefill would have to
-        read a program in flight first (``engine.drain("put")``) and the
-        family's tick carries a chunk in its decode program
-        (``ModelFamily.mixed_paged``) - the prompt then rides the next
-        launch as a first-and-final chunk, its first token comes with the
-        collect after, and the device never waits for the host. With
-        nothing in flight (an idle engine, a fused quantum, a speculative
-        step, an attached tuner: each read its own program before this
-        tick's admissions) the one-shot costs no drain and streams its
-        first token in the admitting tick, so it stays; a family without a
-        mixed call drains for its final chunk anyway."""
+        read a program in flight first (``engine.drain("put")``) - the
+        prompt then rides the next launch's decode program as a
+        first-and-final chunk, its first token comes with the collect
+        after, and the device never waits for the host. With nothing in
+        flight (an idle engine, a fused quantum, a speculative step, an
+        attached tuner: each read its own program before this tick's
+        admissions) the one-shot costs no drain and streams its first token
+        in the admitting tick, so it stays."""
         eng = self.engine
         split = eng.config.split_prefill_chunk
         if split <= 0:
@@ -612,7 +610,7 @@ class ServingScheduler:
         from ..engine import _round_up
         if n_tokens > _round_up(split, eng.config.prefill_bucket):
             return True
-        return eng.in_flight > 0 and eng.family.mixed_paged
+        return eng.in_flight > 0
 
     def _preempt_guard(self) -> int:
         """Park the least urgent live requests until the next decode

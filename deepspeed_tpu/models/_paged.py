@@ -196,8 +196,7 @@ class MixedCall(NamedTuple):
     them real (both scalars). ``chunk_slot``: the chunk's sequence slot, for
     a family with recurrent state. The chunk's sequence is not an active
     slot. A family passes the structure on to :func:`paged_attention_step`
-    untouched; whether its ``apply_paged`` takes one is the family's
-    ``MIXED_PAGED`` (``inference.engine.ModelFamily.mixed_paged``)."""
+    untouched."""
     tables: jnp.ndarray
     lens: jnp.ndarray
     active: jnp.ndarray

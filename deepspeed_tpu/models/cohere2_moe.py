@@ -473,9 +473,6 @@ def init_paged_cache(cfg: Cohere2MoeConfig, num_blocks: int,
                            cfg.head_size, dtype)
 
 
-MIXED_PAGED = True      # as llama's: ``apply_paged`` takes a mixed call
-
-
 def apply_paged(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,

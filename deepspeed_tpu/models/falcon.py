@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.attention import attention
-from ._paged import gather_rows, paged_attention_step, scan_layers
+from ._paged import (gather_rows, paged_attention_step, row_positions,
+                     scan_layers)
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import layer_norm
@@ -349,7 +350,7 @@ def apply_paged(cfg: FalconConfig, params: Params, tokens: jnp.ndarray,
         valid = jnp.ones((b, t), bool)
     x = embedding_lookup(params["embed"], tokens, compute_dtype)
     cos, sin = rope_frequencies(cfg.head_size, cfg.max_seq_len, cfg.rope_theta)
-    positions = context_lens[:, None] + jnp.arange(t)[None, :]
+    positions = row_positions(block_tables, context_lens, t)
     layers = _cast_layers(params, compute_dtype)
 
     def scan_body(x, scanned):

@@ -22,7 +22,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ._paged import gather_rows, paged_attention_step, scan_layers
+from ._paged import (gather_rows, paged_attention_step, row_positions,
+                     scan_layers)
 from ._paged import init_paged_pools as _init_paged_pools
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import layer_norm
@@ -335,7 +336,7 @@ def apply_paged(cfg: GPTConfig, params: Params, tokens: jnp.ndarray,
     b, t = tokens.shape
     if valid is None:
         valid = jnp.ones((b, t), bool)
-    positions = context_lens[:, None] + jnp.arange(t)[None, :]
+    positions = row_positions(block_tables, context_lens, t)
     # clamp ONLY the learned-position lookup; the cache scatter/mask must see
     # the true absolute positions or slots past max_seq_len silently collide
     pos_idx = jnp.minimum(positions, cfg.max_seq_len - 1)

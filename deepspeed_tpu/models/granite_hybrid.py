@@ -557,9 +557,6 @@ def init_paged_cache(cfg: GraniteHybridConfig, num_blocks: int,
                          jnp.dtype(cfg.state_dtype))}
 
 
-MIXED_PAGED = True      # as llama's: ``apply_paged`` takes a mixed call
-
-
 def apply_paged(cfg: GraniteHybridConfig, params: Params,
                 tokens: jnp.ndarray, cache: Params,
                 block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,

@@ -602,11 +602,6 @@ def _block_paged(cfg: LlamaConfig, x: jnp.ndarray, layer: Params,
     return x, k_cache, v_cache
 
 
-# ``apply_paged`` takes a mixed call (``_paged.MixedCall``): the engine reads
-# this through ``ModelFamily.mixed_paged``
-MIXED_PAGED = True
-
-
 def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,
@@ -617,7 +612,7 @@ def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
 
     tokens [B, t]; context_lens [B] tokens already cached per sequence;
     block_tables [B, max_blocks] into the shared pool; valid [B, t] marks
-    real (non-pad) tokens. A mixed call (``MIXED_PAGED``): ``block_tables``
+    real (non-pad) tokens. A mixed call: ``block_tables``
     is a ``_paged.MixedCall``, ``context_lens`` None and tokens
     [1, slots + t] - every slot's decode token, then one prefill chunk.
     ``rows`` [B, r]: the rows along ``t`` whose logits the call reads

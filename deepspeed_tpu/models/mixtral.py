@@ -604,9 +604,6 @@ def moe_rows(cfg: MixtralConfig, rows: int) -> Dict[str, int]:
             "moe_rows_computed": round(passes * sub), "moe_row_tile": tile}
 
 
-MIXED_PAGED = True      # as llama's: ``apply_paged`` takes a mixed call
-
-
 def apply_paged(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray,
                 cache: Params, block_tables: jnp.ndarray,
                 context_lens: jnp.ndarray, *,

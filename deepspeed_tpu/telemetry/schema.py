@@ -349,11 +349,11 @@ TRACER_SPANS = frozenset((
 # slot's position for). CLOSED: the engine counts by these keys and raises
 # on any other.
 DRAIN_CAUSES = (
-    # engine_v2: a one-shot prefill, a final chunk of a family without a
-    # mixed call, a speculative step, a fused quantum, finish() of a stream
-    # with a token in flight, park, fork, kv_chain_hashes, export_kv_blocks
-    "put", "final_chunk", "spec", "quantum", "finish", "park", "fork",
-    "prefix_hash", "export",
+    # engine_v2: a one-shot prefill, a speculative step, a fused quantum,
+    # finish() of a stream with a token in flight, park, fork,
+    # kv_chain_hashes, export_kv_blocks
+    "put", "spec", "quantum", "finish", "park", "fork", "prefix_hash",
+    "export",
     # serving/scheduler.py: _park_to_queue, evict_all, export_live (the
     # tokens go to their handles before the sequence moves)
     "sched_park", "sched_evict", "sched_export")

@@ -176,8 +176,8 @@ CELL_BS, CELL_TABLE, CELL_POOL = 32, 256, 896
 
 
 def _prefill_step(quant, window=None):
-    """The multi-token branch of the model step as ``chunk_prefill`` traces
-    it: each sequence's K/V written into the pool, then the op. A
+    """The multi-token branch of the model step as a chunk's ``[1, t]``
+    call traces it: each sequence's K/V written into the pool, then the op. A
     ``"traced"`` window is the step's last argument."""
     from deepspeed_tpu.models._paged import LayerPool, paged_attention_step
 
@@ -312,7 +312,7 @@ def _pool_program(cell_name, program, quant):
     forward, params, cache, slots, chunk, table = _cell_forward(cell_name,
                                                                 quant)
     i32, s = jnp.int32, jax.ShapeDtypeStruct
-    b, t = (1, chunk) if program == "chunk_prefill" else (slots, 1)
+    b, t = (1, chunk) if program == "chunk" else (slots, 1)
     args = (params, cache, s((b, t), i32), s((b, table), i32), s((b,), i32),
             s((b, t), bool))
     if program != "decode_many":
@@ -333,7 +333,7 @@ def _pool_program(cell_name, program, quant):
 
 
 POOL_PROGRAMS = [(cell, program, pool) for cell in SERVE_CELLS
-                 for program in ("chunk_prefill", "decode")
+                 for program in ("chunk", "decode")
                  for pool in ("bf16", "int8")] \
     + [(cell, "decode_many", "bf16") for cell in SERVE_CELLS]
 
@@ -377,7 +377,7 @@ def test_the_pools_stay_where_they_are(v5e, cell, program, pool):
         assert mem.temp_size_in_bytes < padded + layer_pool
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
-    attn = "paged_prefill" if program == "chunk_prefill" else "paged_decode"
+    attn = "paged_prefill" if program == "chunk" else "paged_decode"
     assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
 
 
@@ -451,9 +451,9 @@ def _granite_program(program, periods=1):
                      s((b,), i32), s((b, t), bool), s((b,), i32))
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_the_state_pool_stays_where_it_is(v5e, program):
-    """Granite-4.0-H-Micro's ``decode`` (64 rows) and ``chunk_prefill`` (256
+    """Granite-4.0-H-Micro's ``decode`` (64 rows) and one ``chunk`` (256
     tokens) at the cell's geometry, one period deep, compiled for the chip:
     no copy - plain or ``copy-start`` -, slice, update, buffer or loop
     fusion of the state pool's, the KV pools' or one layer's shape; every
@@ -803,7 +803,7 @@ MOE_CELLS = ("mixtral-8x7b.serve-longprompt", "olmoe-1b-7b.serve-longprompt",
              KEYE_CELL)
 BANK_PROGRAMS = [(cell, "mixed") for cell in MOE_CELLS] + [
     (cell, program) for cell in MOE_CELLS[:2]
-    for program in ("chunk_prefill", "decode", "decode_many")]
+    for program in ("chunk", "decode", "decode_many")]
 
 
 @pytest.mark.parametrize("cell,program", BANK_PROGRAMS)

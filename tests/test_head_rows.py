@@ -3,13 +3,14 @@
 ``apply_paged(..., rows=)`` gathers the hidden state to ``rows [b, r]``
 before the final norm and the head (``_paged.gather_rows``), so its logits
 are ``[b, r, V]`` and must be the rows ``r`` of what the same call gives for
-every row - for each of the seven paged families on a ``[b, t]`` call and,
-where the family takes one, on a ``MixedCall``. The engine hands over what
-its programs read (a prefill its last real rows, a mixed step its slots'
-rows and the chunk's last real one): held here against an engine of the same
-weights whose ``apply_paged`` does not declare ``rows`` - it scores every row
-and the engine picks, the parent's programs - through ``ServingScheduler.
-tick``, to the token.
+every row - for each of the seven paged families on a ``[b, t]`` call and
+on a ``MixedCall``. The engine hands over what its programs read (a prefill
+its last real rows, a mixed step its slots' rows and the chunk's last real
+one): held here against an engine of the same weights around a caller's own
+``apply_paged`` that scores every row and picks ``rows`` from the result -
+ISSUE 44's parent's programs - through ``ServingScheduler.tick``, to the
+token. ``rows=`` is part of the ``apply_paged`` contract (ISSUE 46): a
+caller's callable declares it.
 """
 
 import jax
@@ -48,7 +49,7 @@ FAMILIES = {
     "exaone4": (exaone4, lambda: exaone4.Exaone4Config.tiny(max_seq_len=32),
                 {}),
 }
-MIXED = ("cohere2_moe", "granite_hybrid", "llama", "mixtral")
+SERVED = ("cohere2_moe", "granite_hybrid", "llama", "mixtral")
 
 
 def test_gather_rows_picks_each_sequences_own_rows():
@@ -109,7 +110,7 @@ def test_rows_of_a_batched_call_are_the_rows_of_every_rows_logits(family):
 
 
 @pytest.mark.parametrize("n_valid", [CHUNK, 5], ids=["mid", "final_padded"])
-@pytest.mark.parametrize("family", MIXED)
+@pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_rows_of_a_mixed_call_are_the_rows_of_every_rows_logits(family,
                                                                 n_valid):
     """Slots 0 and 1 decode (contexts 5 and 9), slot 2's chunk rides along
@@ -142,20 +143,21 @@ CONFIG = {"dtype": "float32", "prefill_bucket": CHUNK,
 
 
 def _scores_every_row(module):
-    """``module.apply_paged`` as a caller's own callable that declares no
-    ``rows``: the parent's forward."""
+    """``module.apply_paged`` as a caller's own callable whose head scores
+    every row, ``rows`` picked from the result: ISSUE 44's parent's
+    forward."""
     def apply_paged(cfg, params, tokens, cache, tables, ctx, *, valid=None,
-                    **kw):
-        assert "rows" not in kw
-        return module.apply_paged(cfg, params, tokens, cache, tables, ctx,
-                                  valid=valid, **kw)
+                    rows=None, **kw):
+        logits, cache = module.apply_paged(cfg, params, tokens, cache,
+                                           tables, ctx, valid=valid, **kw)
+        return gather_rows(logits, rows), cache
 
     return apply_paged
 
 
 def _engines(family):
     """(the engine as ``build_engine_v2`` makes it, one of the same weights
-    around a caller-supplied ``apply_paged`` without ``rows``)."""
+    around a caller-supplied ``apply_paged`` that scores every row)."""
     module, make, _ = FAMILIES[family]
     cfg = make()
     params = module.init(cfg, jax.random.PRNGKey(0))
@@ -166,7 +168,6 @@ def _engines(family):
         InferenceConfig.from_dict(CONFIG),
         init_paged_cache=module.init_paged_cache,
         apply_paged=_scores_every_row(module))
-    assert one._takes_rows and not two._takes_rows
     return one, two
 
 
@@ -201,23 +202,21 @@ def _launches(eng):
                               "decode_step") and "head_rows" in e["args"]]
 
 
-@pytest.mark.parametrize("family", MIXED)
+@pytest.mark.parametrize("family", SERVED)
 def test_the_engine_serves_the_tokens_of_the_programs_that_score_every_row(
         family):
     """``prefill``, the mixed ``decode_chunk`` (mid and final chunks) and
     ``decode`` with the rows handed over, against the same programs around
-    an ``apply_paged`` that takes none: the same tokens for every request,
-    the same programs launched over the same rows, and ``head_rows`` says
-    what each head scored - ``slots + 1`` of a mixed step's ``slots +
-    chunk``, one a sequence of a prefill's ``n x pad_t`` - where the other
-    engine's scored them all. A caller-supplied ``apply_paged`` without
-    ``rows`` still serves."""
+    an ``apply_paged`` whose head scores every row: the same tokens for
+    every request, the same programs launched over the same rows, and
+    ``head_rows`` says what each program read - ``slots + 1`` of a mixed
+    step's ``slots + chunk``, one a sequence of a prefill's ``n x pad_t``
+    (what a caller's own callable does with ``rows`` is its business: the
+    engine counts what it asked for)."""
     one, two = _engines(family)
-    assert one.family.mixed_paged and two.family.mixed_paged
     assert _serve(one) == _serve(two)
     mine, theirs = _launches(one), _launches(two)
-    assert [(n, r) for n, r, _ in mine] == [(n, r) for n, r, _ in theirs]
-    assert all(head == rows for _, rows, head in theirs)
+    assert mine == theirs
     mixed = [(r, h) for n, r, h in mine if n == "decode_step" and r > SLOTS]
     assert mixed and set(mixed) == {(SLOTS + CHUNK, SLOTS + 1)}
     assert {(r, h) for n, r, h in mine if n == "decode_step"
@@ -228,24 +227,3 @@ def test_the_engine_serves_the_tokens_of_the_programs_that_score_every_row(
     assert events["Serving/engine/rows"] == sum(r for _, r, _ in mine)
     assert events["Serving/engine/head_rows"] == sum(h for *_, h in mine) \
         < events["Serving/engine/rows"]
-    assert two.head_rows == two.rows == one.rows
-
-
-def test_a_final_chunk_prefill_reads_one_row_and_a_mid_chunk_none():
-    """A family that takes no mixed call keeps ``chunk_prefill``: its final
-    chunk hands over its last real row, a chunk that does not end its prompt
-    asks for no logits - on the span, ``head_rows`` 1 and 0 - and the tokens
-    are those of the engine that scores every row."""
-    one, two = _engines("gpt")
-    assert not one.family.mixed_paged
-    prompt = np.random.default_rng(7).integers(1, 200, 21).tolist()
-    outs = []
-    for eng in (one, two):
-        eng.put(1, prompt[:5])
-        eng.put_split(2, prompt)
-        outs.append([eng.step(seed=s) for s in range(5)])
-    assert outs[0] == outs[1] and 2 in outs[0][2]
-    chunks = [(r, h) for n, r, h in _launches(one) if n == "prefill_chunk"]
-    assert chunks == [(CHUNK, 0), (CHUNK, 0), (CHUNK, 1)]
-    assert [h for n, _, h in _launches(two) if n == "prefill_chunk"] \
-        == [CHUNK] * 3

@@ -776,9 +776,12 @@ def test_v2_step_warns_on_ignored_sampling_params(tiny):
     assert not any(issubclass(x.category, DeprecationWarning) for x in w)
 
 
-def test_v2_midchunk_prefill_compiles_shared_across_sampling_params(tiny):
-    """ADVICE r4: mid prefill chunks never sample, so every sampling
-    config must share ONE compiled mid-chunk program."""
+def test_v2_final_chunks_share_one_program_across_sampling_params(tiny):
+    """ADVICE r4, re-stated by ISSUE 46: a chunk is ``decode_chunk`` keyed
+    on its width and on greedy-or-rows, never on a client's
+    ``SamplingParams`` - two prompts whose final chunks sample with
+    different configs compile ONE program (``chunk_prefill`` compiled one a
+    config)."""
     cfg, params = tiny
     mesh_lib.set_mesh(None)
     eng = build_engine_v2(
@@ -788,14 +791,18 @@ def test_v2_midchunk_prefill_compiles_shared_across_sampling_params(tiny):
                 "ragged": {"max_tracked_sequences": 4,
                            "max_ragged_batch_size": 4,
                            "memory_config_blocks": 64, "block_size": 16}})
-    f1 = eng._chunk_prefill_fn(32, False, SamplingParams(temperature=0.7))
-    f2 = eng._chunk_prefill_fn(32, False,
-                               SamplingParams(temperature=1.3, top_k=5))
-    assert f1 is f2
-    g1 = eng._chunk_prefill_fn(32, True, SamplingParams(temperature=0.7))
-    g2 = eng._chunk_prefill_fn(32, True,
-                               SamplingParams(temperature=1.3, top_k=5))
-    assert g1 is not g2  # final chunks DO sample with their own sp
+    rng = np.random.default_rng(46)
+    for uid, sp in ((1, SamplingParams(temperature=0.7)),
+                    (2, SamplingParams(temperature=1.3, top_k=5))):
+        eng.put_split(uid, rng.integers(0, cfg.vocab_size, (40,)).tolist(),
+                      sp)
+        assert set(eng.step(seed=uid)) == {uid}     # both chunks, one step
+        eng.finish(uid)
+    chunk = [k for k in eng._paged_fns if k[0].startswith("decode_chunk")]
+    # the mid chunks sample greedily for nothing; the final ones by rows
+    assert sorted(chunk) == [("decode_chunk", 32), ("decode_chunk_dyn", 32)]
+    assert eng.compile_monitor.enabled is False     # plain jit objects:
+    assert all(eng._paged_fns[k]._cache_size() == 1 for k in chunk)
 
 
 def test_sample_batch_top_p_disabled_is_noop():

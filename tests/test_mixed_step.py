@@ -1,13 +1,15 @@
-"""A step's prefill chunk rides in its decode program (ISSUE 32).
+"""A prompt chunk rides in the decode program (ISSUE 32; since ISSUE 46 in
+every family and from every caller: it is the one chunk program).
 
-A family whose ``apply_paged`` takes a mixed call (``_paged.MixedCall``:
-every slot's decode token and one sequence's chunk as ONE row dimension)
-must give what the chunk-then-decode pair of ``[b, t]`` calls gives: the same
-K/V blocks (and state rows), the same logits for the decode rows and the
-chunk's last real row. Held here at tiny sizes on the CPU: the forward alone
-in float32, then the engine's ``step()`` against an engine of the same
-weights that keeps the two programs, to the token, and what its one span
-says.
+Every family's ``apply_paged`` takes a mixed call (``_paged.MixedCall``:
+every slot's decode token and one sequence's chunk as ONE row dimension) and
+must give what the chunk-then-decode pair of ``[b, t]`` calls gives - the
+forms ``prefill`` and ``decode`` keep making -: the same K/V blocks (and
+state rows), the same logits for the decode rows and the chunk's last real
+row. Held here at tiny sizes on the CPU: the forward alone in float32, then
+the engine's ``step()`` against the UNSPLIT engine of the same weights
+(``split_prefill_chunk=0``: one-shot ``prefill`` then ``decode``), to the
+token, and what its one span says.
 """
 
 import jax
@@ -17,7 +19,8 @@ import pytest
 
 from deepspeed_tpu.comm import mesh as mesh_lib
 from deepspeed_tpu.inference import SamplingParams, build_engine_v2
-from deepspeed_tpu.models import granite_hybrid, llama, mixtral
+from deepspeed_tpu.models import (exaone4, falcon, gpt, granite_hybrid, llama,
+                                  mixtral)
 from deepspeed_tpu.models._paged import MixedCall
 
 SLOTS, BLOCK, WIDTH, BLOCKS, CHUNK = 4, 4, 8, 40, 8
@@ -33,6 +36,10 @@ FAMILIES = {
     "granite_hybrid": (granite_hybrid,
                        lambda: granite_hybrid.GraniteHybridConfig.tiny(
                            max_seq_len=32), {"slots": SLOTS}),
+    "gpt": (gpt, lambda: gpt.GPTConfig.tiny(max_seq_len=32), {}),
+    "falcon": (falcon, lambda: falcon.FalconConfig.tiny(max_seq_len=32), {}),
+    "exaone4": (exaone4, lambda: exaone4.Exaone4Config.tiny(max_seq_len=32),
+                {}),
 }
 
 
@@ -120,11 +127,13 @@ def test_row_positions_of_both_kinds_of_call():
 
 
 # --- the engine's step() --------------------------------------------------- #
-def _engine(family, mixed=True, trace=False, **extra):
+def _engine(family, split=CHUNK, trace=False, **extra):
+    """``split=0``: the UNSPLIT engine, whose prompts are one-shot
+    ``prefill`` calls and whose steps are ``decode`` alone."""
     module, make, cache_kw = FAMILIES[family]
     cfg = make()
     mesh_lib.set_mesh(None)
-    config = {"prefill_bucket": CHUNK, "split_prefill_chunk": CHUNK,
+    config = {"prefill_bucket": CHUNK, "split_prefill_chunk": split,
               "ragged": {"max_tracked_sequences": SLOTS,
                          "max_ragged_batch_size": SLOTS,
                          "memory_config_blocks": BLOCKS,
@@ -134,43 +143,62 @@ def _engine(family, mixed=True, trace=False, **extra):
     if trace:
         config["trace"] = {"enabled": True}
     config.update(extra)
-    eng = build_engine_v2(module, cfg, module.init(cfg, jax.random.PRNGKey(0)),
-                          config=config)
-    assert eng.family.mixed_paged
-    eng.family.mixed_paged = mixed      # this engine's own ModelFamily
-    return eng
+    return build_engine_v2(module, cfg,
+                           module.init(cfg, jax.random.PRNGKey(0)),
+                           config=config)
 
 
-def _admit(eng, sp=SamplingParams(greedy=True)):
-    """Two live sequences, a split prompt of three chunks (8, 8, 5), and a
-    slot left free."""
+def _prompts():
     rng = np.random.RandomState(11)
-    eng.put(1, rng.randint(1, 200, 5).tolist())
-    eng.put(2, rng.randint(1, 200, 9).tolist(), sp)
-    eng.put_split(3, rng.randint(1, 200, 21).tolist(), sp)
+    return [rng.randint(1, 200, n).tolist() for n in (5, 9, 21)]
+
+
+def _admit(eng, sp=SamplingParams(greedy=True), split=True):
+    """Two live sequences and a slot left free; ``split``: and a split
+    prompt of three chunks (8, 8, 5)."""
+    a, b, c = _prompts()
+    eng.put(1, a)
+    eng.put(2, b, sp)
+    if split:
+        eng.put_split(3, c, sp)
+
+
+def _streams(outs):
+    """``{uid: tokens}`` of a run of ``step_many`` / speculative ``step``
+    results, ``{uid: [tokens]}`` each."""
+    streams = {}
+    for out in outs:
+        for uid, toks in out.items():
+            streams.setdefault(uid, []).extend(toks)
+    return streams
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_a_mixed_step_serves_what_the_two_programs_serve(family):
     """bf16, to the token: an engine whose steps run the chunk inside the
-    decode program streams the tokens of one that keeps ``chunk_prefill``
-    then ``decode``, counts the same ``last_step``, and leaves the same
-    blocks (and state rows) behind; mid chunks, the final one, and the slot
-    it seats - which has its first token only, and decodes from the next
-    step on."""
-    one, two = _engine(family), _engine(family, mixed=False)
+    decode program streams the tokens of the UNSPLIT one - which runs the
+    prompt as a one-shot ``prefill`` where the split one's final chunk ran,
+    and ``decode`` alone - and from there on leaves the same blocks (and
+    state rows) behind; mid chunks, the final one, and the slot it seats -
+    which has its first token only, and decodes from the next step on."""
+    one, two = _engine(family), _engine(family, split=0)
     _admit(one)
-    _admit(two)
+    _admit(two, split=False)
     for step in range(5):
-        out = one.step(seed=step)
-        assert out == two.step(seed=step)
-        assert one.last_step == two.last_step
+        out, want = one.step(seed=step), two.step(seed=step)
+        if step == 2:
+            want[3] = two.put(3, _prompts()[2])
+        assert out == want
         assert (3 in out) == (step >= 2)
+        assert one.last_step["decode_seqs"] == two.last_step["decode_seqs"]
+        assert one.last_step["kv_tokens"] == two.last_step["kv_tokens"]
         d = one.state.seqs[3]
         if step == 2:       # seated by this step's final chunk: not decoded
             assert (d.seen_tokens, len(d.generated), d.prefilling) \
                 == (21, 1, False)
             assert one.last_step["decode_seqs"] == 2
+        if step < 2:
+            continue        # the unsplit engine has not met the prompt yet
         state = one.family.state_leaves
         for name, got in _written(one.cache, state).items():
             np.testing.assert_allclose(
@@ -178,19 +206,19 @@ def test_a_mixed_step_serves_what_the_two_programs_serve(family):
                 err_msg=f"{name} after step {step}")
     assert (one.steps, one.mixed_steps, two.mixed_steps) == (5, 3, 0)
     assert one.state.seqs[3].seen_tokens == 21 + 2
-    assert "chunk_prefill" not in {k[0] for k in one._paged_fns}
     assert [k for k in one._paged_fns if k[0].startswith("decode_chunk")] \
         == [("decode_chunk", CHUNK)]        # ONE program, mid and final
+    assert not any(k[0].startswith("decode_chunk") for k in two._paged_fns)
     # the rows the programs ran and the rows their heads scored (ISSUE 44):
     # two one-shot prefills (8 and 16 rows, one read each), three mixed
     # calls (4 slots + 8 chunk rows, 4 + 1 read) and two decodes (4 of 4);
-    # the two-program engine's mid chunks read none and its final chunk one
+    # the unsplit engine's third prefill ran 24 rows and read one
     assert dict((n, v) for n, v, _ in one.engine_events()) == {
         "Serving/engine/steps": 5.0, "Serving/engine/mixed_steps": 3.0,
         "Serving/engine/overlapped_steps": 0.0,
         "Serving/engine/rows": 8.0 + 16 + 3 * 12 + 2 * 4,
         "Serving/engine/head_rows": 1.0 + 1 + 3 * 5 + 2 * 4}
-    assert (two.rows, two.head_rows) == (8 + 16 + 3 * 8 + 5 * 4,
+    assert (two.rows, two.head_rows) == (8 + 16 + 24 + 5 * 4,
                                          1 + 1 + 1 + 5 * 4)
     for uid in (1, 2, 3):
         assert one.finish(uid) == two.finish(uid)
@@ -211,40 +239,27 @@ def test_a_stochastic_request_takes_the_rows_variant_once():
         == ["decode_chunk_dyn", "decode_dyn"]
 
 
-def test_a_family_without_the_attribute_keeps_the_two_programs():
-    from deepspeed_tpu.inference.engine import ModelFamily
-    from deepspeed_tpu.models import exaone4, falcon, gpt
-
-    for module in (gpt, falcon, exaone4):
-        assert not ModelFamily.from_module(module, None).mixed_paged
-    for module in (llama, mixtral, granite_hybrid):
-        assert ModelFamily.from_module(module, None).mixed_paged
-    eng = _engine("llama", mixed=False)
-    _admit(eng)
-    eng.step()
-    assert {k[0] for k in eng._paged_fns} >= {"chunk_prefill", "decode"}
-
-
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_a_chunk_with_nothing_live_runs_the_same_one_program(family):
     """A split prompt alone in the engine (a server's start-up, a lone
     request): its chunks run ``decode_chunk`` with no active slot - the
     program the loaded server runs, so nothing else is lowered for them -,
     under ``prefill_chunk`` spans whose MoE rows are the call's, chunk after
-    chunk in one step, and serve what ``chunk_prefill`` serves."""
-    one, two = _engine(family, trace=True), _engine(family, mixed=False)
+    chunk in one step, and serve what the unsplit engine's one-shot
+    ``prefill`` serves."""
+    one, two = _engine(family, trace=True), _engine(family, split=0)
     prompt = np.random.RandomState(5).randint(1, 200, 21).tolist()
-    for eng in (one, two):
-        eng.put_split(3, prompt)
-    assert one.step(seed=0) == two.step(seed=0)     # the first token
-    assert one.step(seed=1) == two.step(seed=1)     # a decode alone
-    assert one.last_step == two.last_step
+    one.put_split(3, prompt)
+    assert one.step(seed=0) == {3: two.put(3, prompt)}  # the first token
+    assert one.step(seed=1) == two.step(seed=1)         # a decode alone
     assert one.mixed_steps == 0         # counts chunks BESIDE decodes
     assert {k[0] for k in one._paged_fns} == {"decode_chunk", "decode"}
-    assert {k[0] for k in two._paged_fns} == {"chunk_prefill", "decode"}
+    assert {k[0] for k in two._paged_fns} == {"prefill", "decode"}
     chunks = [e for e in one.tracer.events() if e["name"] == "prefill_chunk"]
-    assert [(c["args"]["tokens"], c["args"]["final"]) for c in chunks] \
-        == [(8, False), (8, False), (5, True)]
+    assert [(c["args"]["tokens"], c["args"]["final"], c["args"]["rows"],
+             c["args"]["head_rows"]) for c in chunks] \
+        == [(8, False, SLOTS + CHUNK, SLOTS + 1)] * 2 \
+        + [(5, True, SLOTS + CHUNK, SLOTS + 1)]
     if one.family.moe_rows:
         want = one.family.moe_rows(one.family.cfg, SLOTS + CHUNK)
         assert all({k: c["args"][k] for k in want} == want for c in chunks)
@@ -254,18 +269,34 @@ def test_a_chunk_with_nothing_live_runs_the_same_one_program(family):
                                    atol=0.05, err_msg=name)
 
 
-def test_speculation_and_the_fused_quantum_keep_their_programs():
-    eng = _engine("llama")
-    _admit(eng)
-    eng.step_many(2)
-    assert eng.mixed_steps == 0 and ("chunk_prefill", CHUNK, None, False) \
-        in eng._paged_fns
-    eng = _engine("llama", speculative={"enabled": True,
-                                        "max_draft_tokens": 3})
-    _admit(eng)
-    eng.step()
-    assert eng.mixed_steps == 0 and not any(
-        k[0].startswith("decode_chunk") for k in eng._paged_fns)
+@pytest.mark.parametrize("mode", ["quantum", "spec"])
+def test_speculation_and_the_fused_quantum_take_their_chunk_through_decode_chunk(
+        mode):
+    """``step_many`` and a speculative ``step()`` advance a split prompt
+    through ``decode_chunk`` with no slot active (never beside their
+    decodes: ``mixed_steps`` 0), hand back the prompt's first token in the
+    call whose chunk completes it - a 1-list -, and stream, greedily, what
+    the unsplit engine streams."""
+    extra = {} if mode == "quantum" else {
+        "speculative": {"enabled": True, "max_draft_tokens": 3}}
+    call = (lambda e: e.step_many(2)) if mode == "quantum" \
+        else (lambda e: e.step())
+    one, two = _engine("llama", **extra), _engine("llama", split=0, **extra)
+    _admit(one)
+    _admit(two, split=False)
+    first = two.put(3, _prompts()[2])
+    outs = [call(one) for _ in range(4)]
+    assert [3 in out for out in outs] == [False, False, True, True]
+    assert outs[2][3] == [first]
+    got = _streams(outs)
+    want = _streams(call(two) for _ in range(4))
+    want[3].insert(0, first)
+    for uid in (1, 2, 3):
+        assert got[uid] == want[uid][:len(got[uid])] and len(got[uid]) >= 2
+    assert one.mixed_steps == 0
+    assert [k[0] for k in one._paged_fns if "chunk" in k[0]] \
+        == ["decode_chunk"]
+    assert one.in_flight == 0 and sum(one.drains.values()) == 0
 
 
 # --- the span contract (what the benchmark's readers rest on) --------------- #

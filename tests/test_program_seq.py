@@ -100,8 +100,10 @@ def engines():
                 "llama": lambda: _engine(),
                 "spec": lambda: _engine(speculative={
                     "enabled": True, "max_draft_tokens": 2}),
-                # a family without a mixed call: its chunks run apart
-                "gpt": lambda: _engine(gpt)}[kind]()
+                # learned positions; and an engine that splits no prompt,
+                # whose every admission is a one-shot ``put``
+                "gpt": lambda: _engine(gpt),
+                "unsplit": lambda: _engine(split_prefill_chunk=0)}[kind]()
         eng = made[kind]
         for uid in list(eng.state.seqs):        # a clean slate, event-free
             eng.finish(uid)
@@ -171,6 +173,12 @@ def _synchronous_steps(eng):
         eng.step(seed=seed)
 
 
+def _lone_prompt(eng):
+    eng.put_split(2, _prompt(19))   # nothing decodes beside its chunks
+    for seed in range(2):
+        eng.step(seed=seed)
+
+
 def _puts(eng):
     eng.put(1, _prompt(5))
     eng.put_many([(2, _prompt(3)), (3, _prompt(6))])
@@ -213,7 +221,7 @@ SCENARIOS = {"scheduler_ticks": ("llama", _scheduler_ticks),
              "quanta": ("llama", _quanta),
              "preempt_and_resume": ("llama", _preempt_and_resume),
              "speculative_steps": ("spec", _speculative_steps),
-             "chunks_apart": ("gpt", _synchronous_steps)}
+             "chunks_apart": ("gpt", _lone_prompt)}
 
 
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
@@ -299,16 +307,6 @@ def _scheduler_in_flight(eng):
     return sched, [h.request.uid for h in handles]
 
 
-def _final_chunk(eng, flying):
-    if flying:
-        _in_flight(eng)
-    eng.put_split(9, _prompt(CHUNK + 3))        # two chunks
-    eng.launch()        # the first chunk: launched, nothing of it is read
-    if flying:
-        assert eng.in_flight == 2 and not sum(eng.drains.values())
-    eng.launch()        # the final one reads its token: what flies lands first
-
-
 def _spec(eng, flying):
     eng.put(1, _prompt(6))          # no n-gram repeats: nothing is drafted,
     if flying:                      # so a step is the plain decode, launched
@@ -341,7 +339,6 @@ def _sched_site(call):
 # cause -> (engine kind, how its call site is reached)
 SITES = {
     "put": ("llama", _site(lambda e: e.put(5, _prompt(4)))),
-    "final_chunk": ("gpt", _final_chunk),
     "spec": ("spec", _spec),
     "quantum": ("llama", _site(lambda e: e.step_many(2))),
     "finish": ("llama", _site(lambda e: e.finish(1))),
@@ -378,7 +375,7 @@ def test_a_drain_with_a_program_in_flight_is_one_span_and_one_count(
     waits = [r["args"]["seq"] for r in inside if r["name"] == "engine_wait"]
     assert waits == sorted(set(waits))
     # nothing is left unread, but by the step that went on to launch
-    assert eng.in_flight == (cause in ("spec", "final_chunk"))
+    assert eng.in_flight == (cause == "spec")
 
 
 @pytest.mark.parametrize("cause", schema.DRAIN_CAUSES)
@@ -393,11 +390,14 @@ def test_a_drain_of_nothing_opens_no_span_and_counts_nothing(
     assert eng.drains == before
 
 
-def test_an_unknown_cause_is_refused(devices8, engines):
+@pytest.mark.parametrize("cause", ["because", "final_chunk"])
+def test_an_unknown_cause_is_refused(devices8, engines, cause):
+    """``final_chunk`` went with the program that read its token where it
+    was launched (ISSUE 46): a name like any other unknown one."""
     eng = engines("llama")
     _in_flight(eng)
     with pytest.raises(KeyError):
-        eng.drain("because")
+        eng.drain(cause)
     assert eng.in_flight == 1                   # and nothing was read
 
 
@@ -420,12 +420,13 @@ def test_the_ticks_own_reads_are_no_drain(devices8, engines):
                         if r["name"] in LAUNCHES))
 
 
-@pytest.mark.parametrize("kind", ["llama", "gpt"])
+@pytest.mark.parametrize("kind", ["llama", "gpt", "unsplit"])
 def test_last_tick_counts_the_drains_of_its_tick(devices8, engines, kind):
-    """Re-stated by ISSUE 37: a short prompt admitted beside a program in
-    flight is a one-shot ``put``, whose drain the tick counts, only in a
-    family without a mixed call (``gpt``); in ``llama`` it rides the tick's
-    program and the tick counts no drain."""
+    """Re-stated by ISSUE 37 and ISSUE 46: a short prompt admitted beside a
+    program in flight is a one-shot ``put``, whose drain the tick counts,
+    only where no prompt is split (``split_prefill_chunk=0``); in every
+    family that splits it rides the tick's program and the tick counts no
+    drain."""
     eng = engines(kind)
     sched, uids = _scheduler_in_flight(eng)
     put = eng.drains["put"]
@@ -433,7 +434,7 @@ def test_last_tick_counts_the_drains_of_its_tick(devices8, engines, kind):
     sched.tick()
     # the admission's, cause put - where the admission reads at all
     assert sched.last_tick["drains"] == eng.drains["put"] - put \
-        == (kind == "gpt")
+        == (kind == "unsplit")
     sched.tick()
     assert sched.last_tick["drains"] == 0
     sched.preempt(uids[0])          # between ticks: the next tick's count

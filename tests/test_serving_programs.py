@@ -1,9 +1,9 @@
 """The serving engine's programs, to the letter.
 
-``inference/engine_v2.py`` builds five forward programs (prefill, chunk
-prefill, decode, a step's chunk inside its decode - ISSUE 32's
-``decode_chunk`` -, speculative verify) in the variants the dispatch sites
-choose between, and five families route their paged pools through
+``inference/engine_v2.py`` builds four forward programs (prefill, decode, a
+prompt chunk inside a step's decode - ISSUE 32's ``decode_chunk``, since
+ISSUE 46 the one chunk program -, speculative verify) in the variants the
+dispatch sites choose between, and five families route their paged pools through
 ``models/_paged.scan_layers``. A PR that reshapes that code without meaning
 to change a program must leave every jaxpr here as it was. The hashes below
 are ISSUE 29's, re-taken one for one on its finished tree: that PR changed
@@ -88,10 +88,9 @@ def _args(eng):
     return {
         "prefill": prefill + (key, s((2,), i32)),
         "prefill_ctx": prefill + (s((2,), i32), key, s((2,), i32)),
-        "chunk": head + (s((1, PAD_T), i32), s((), i32), s((), i32),
-                         s((width,), i32), key, s((), i32)),
         "decode": decode,
-        # the slots as decode takes them, the chunk as chunk_prefill does
+        # the slots as decode takes them, then the chunk, its real tokens,
+        # its context offset and its block table, the key and the uid
         "decode_chunk": decode[:-1] + (
             s((1, PAD_T), i32), s((), i32), s((), i32), s((width,), i32),
             key, s((), i32)),
@@ -113,12 +112,6 @@ PROGRAMS = {
     "prefill_ctx.rows": ("bf16",
                          lambda e: e._prefill_fn(PAD_T, 2, True, True),
                          ("prefill_ctx", "rows2")),
-    "chunk_prefill.mid": ("bf16", lambda e: e._chunk_prefill_fn(
-        PAD_T, False, SamplingParams(greedy=True)), ("chunk",)),
-    "chunk_prefill.final_greedy": ("bf16", lambda e: e._chunk_prefill_fn(
-        PAD_T, True, SamplingParams(greedy=True)), ("chunk",)),
-    "chunk_prefill.final_stochastic": ("bf16", lambda e: e._chunk_prefill_fn(
-        PAD_T, True, STOCHASTIC), ("chunk",)),
     "decode.greedy": ("bf16", lambda e: e._decode_fn(1, False), ("decode",)),
     "decode.rows": ("bf16", lambda e: e._decode_fn(1, True),
                     ("decode", "rows")),
@@ -129,8 +122,6 @@ PROGRAMS = {
     "spec_verify": ("bf16", lambda e: e._verify_fn(KP1), ("verify",)),
     "decode.greedy.int8": ("int8", lambda e: e._decode_fn(1, False),
                            ("decode",)),
-    "chunk_prefill.mid.int8": ("int8", lambda e: e._chunk_prefill_fn(
-        PAD_T, False, SamplingParams(greedy=True)), ("chunk",)),
     "decode_chunk.greedy": ("bf16", lambda e: e._decode_chunk_fn(
         PAD_T, False), ("decode_chunk",)),
     "decode_chunk.rows": ("bf16", lambda e: e._decode_chunk_fn(PAD_T, True),
@@ -178,13 +169,12 @@ def paged_text(family: str, t: int) -> str:
 # they read (``rows=``) and the head scores those alone; the programs that
 # read every row or none (``decode*``, ``spec_verify``, a mid
 # ``chunk_prefill``) and the families' ``rows=None`` forwards kept theirs,
-# and ``PARENT_TOKENS`` holds what all of them serve. A PR that means to
-# change one of these programs replaces its line.
+# and ``PARENT_TOKENS`` holds what all of them serve. ISSUE 46 took the four
+# ``chunk_prefill`` lines out with the program; no other line was re-taken
+# (gpt, falcon and exaone4 now take their positions from
+# ``_paged.row_positions``: the same operations on a ``[b, t]`` call). A PR
+# that means to change one of these programs replaces its line.
 PARENT_HASHES = {
-    "chunk_prefill.final_greedy": "9107f8cc146e7190",
-    "chunk_prefill.final_stochastic": "9ae5ee402a00ae2c",
-    "chunk_prefill.mid": "a4afc6de0baf7628",
-    "chunk_prefill.mid.int8": "acc809c779ddf322",
     "decode.greedy": "98acf8881fef6b9a",
     "decode_chunk.greedy": "4ba9752be7c30d72",
     "decode_chunk.greedy.int8": "884492885c6ea0c5",
@@ -270,12 +260,13 @@ def test_the_engine_serves_the_parents_tokens(mode):
 
 
 def test_the_programs_keep_the_names_the_benchmark_reads(engines):
-    """``benchmark/readers`` find ``jit_decode`` and ``jit_chunk_prefill``
-    by the inner functions' names, which the pinned text carries; the
-    ``^jit_decode`` pattern also finds a mixed step's ``jit_decode_chunk``."""
+    """``benchmark/readers`` find ``jit_decode`` by the inner functions'
+    names, which the pinned text carries; the ``^jit_decode`` pattern also
+    finds ``jit_decode_chunk``, a step with a prompt chunk. No program is
+    named ``chunk_prefill`` (ISSUE 46): ``^jit_chunk_prefill`` finds
+    nothing."""
     for name, want in (("decode.greedy", "name=decode"),
                        ("decode_many.rows", "name=decode_many"),
-                       ("chunk_prefill.mid", "name=chunk_prefill"),
                        ("decode_chunk.greedy", "name=decode_chunk"),
                        ("decode_chunk.rows", "name=decode_chunk"),
                        ("prefill_ctx.rows", "name=prefill"),

@@ -626,7 +626,6 @@ def _overlap_engine(family, **extra):
                                 "max_ragged_batch_size": 4,
                                 "memory_config_blocks": 96,
                                 "block_size": 4}}, **extra))
-    assert eng.family.mixed_paged
     return eng
 
 
@@ -807,9 +806,9 @@ def test_an_eos_stream_ends_at_its_token_and_frees_the_dropped_row(family):
 # rides the tick's program where a one-shot prefill would have to read the
 # program in flight first
 # --------------------------------------------------------------------------- #
-def _live_then_short(sched, vocab, short=(8, 3), ticks=3):
+def _live_then_short(sched, vocab, short=(8, 3)):
     """Two streams live (a split prompt and a short one, one of them
-    sampled) and, after ``ticks`` ticks, prompts of a chunk or less (the chunk's
+    sampled) and, after three ticks, prompts of a chunk or less (the chunk's
     8 tokens; 3, most of its chunk padding) into the two free slots, one a
     tick
     → (handles, the ``last_tick`` of each admitting tick with the programs
@@ -819,7 +818,7 @@ def _live_then_short(sched, vocab, short=(8, 3), ticks=3):
     mk = lambda n, m, sp=SP: Request(                       # noqa: E731
         prompt=rng.integers(1, vocab, (n,)).tolist(), max_new_tokens=m, sp=sp)
     handles = [sched.submit(r) for r in (mk(21, 12), mk(5, 14, STOCHASTIC))]
-    for _ in range(ticks):
+    for _ in range(3):
         sched.tick()
     admitting, held = [], []
     for n in short:
@@ -906,11 +905,12 @@ def test_with_nothing_in_flight_a_short_prompt_stays_a_one_shot(case):
     assert first.state == DONE and len(first.tokens) == 9
 
 
-def test_a_family_without_a_mixed_call_keeps_the_one_shot():
-    """``gpt`` runs its chunks apart and reads a final chunk's token where
-    it is launched, so the chunk lane would drain as well: a short prompt
-    beside a program in flight stays a one-shot ``put`` (cause ``put``),
-    its first token in the admitting tick."""
+def test_a_gpt_prompt_that_fits_a_chunk_takes_the_chunk_lane():
+    """Every family's tick carries a chunk in its decode program (ISSUE 46;
+    ``gpt`` ran its chunks apart and read a final chunk's token where it
+    was launched, so it kept the one-shot): a short ``gpt`` prompt beside a
+    program in flight rides the next launch, no tick reads anything for an
+    admission, and its first token comes with the next tick's collect."""
     from deepspeed_tpu.models import gpt
 
     cfg = gpt.GPTConfig.tiny(max_seq_len=64)
@@ -922,16 +922,14 @@ def test_a_family_without_a_mixed_call_keeps_the_one_shot():
                 "ragged": {"max_tracked_sequences": 4,
                            "max_ragged_batch_size": 4,
                            "memory_config_blocks": 96, "block_size": 4}})
-    assert not eng.family.mixed_paged
     sched = ServingScheduler(eng, SchedulerConfig())
-    # (a fourth tick first: the third read everything for its final chunk)
-    handles, admitting, held = _live_then_short(sched, cfg.vocab_size,
-                                                ticks=4)
-    assert sched.stats["chunked_admissions"] == 1   # the 21-token prompt
-    assert eng.drains["put"] == 2
+    handles, admitting, held = _live_then_short(sched, cfg.vocab_size)
+    assert sched.stats["chunked_admissions"] == 3
+    assert sum(eng.drains.values()) == 0
     assert [(t["found_in_flight"], t["drains"]) for t in admitting] \
-        == [(1, 1), (1, 1)]
-    assert all(len(t) == 1 for t in held)
+        == [(1, 0), (1, 0)]
+    assert held == [[], []]
+    assert eng.mixed_steps >= 5     # three chunks of 21 tokens, and two
     assert all(h.state == DONE and len(h.tokens) == h.request.max_new_tokens
                for h in handles)
 

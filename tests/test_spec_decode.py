@@ -20,6 +20,7 @@ from deepspeed_tpu.inference import (InferenceConfig, SamplingParams,
 from deepspeed_tpu.inference.ragged import StateManager
 from deepspeed_tpu.inference.sampling import filter_logits
 from deepspeed_tpu.models import llama
+from deepspeed_tpu.models._paged import gather_rows
 
 SP = SamplingParams(greedy=True)
 
@@ -92,9 +93,10 @@ def _pattern_module(vocab, break_every=0, fixed_logits=None, max_seq_len=128):
         return _next_logits(tokens, pos), cache
 
     def apply_paged(cfg, params, tokens, cache, tables, ctx, valid=None,
-                    **kw):
+                    rows=None, **kw):
         pos = ctx[:, None] + jnp.arange(tokens.shape[1])[None, :]
-        return _next_logits(tokens, pos), cache
+        # ``rows=`` is part of the contract: the rows the program reads
+        return gather_rows(_next_logits(tokens, pos), rows), cache
 
     mod = types.SimpleNamespace(
         apply=apply, apply_cached=apply_cached,
