@@ -68,6 +68,11 @@ class ModelFamily:
     # its ``init_paged_cache`` takes ``window_blocks`` and its
     # ``apply_paged`` a block table of one segment a kind
     window_kinds: Optional[Callable] = None
+    # (cfg) -> {"key_width", "value_width"} for a family whose ONE kind of
+    # KV state is a latent pool (MLA; models/axk1.py): one row a token a
+    # layer, ``cache["latent"]``, the row's first ``value_width`` numbers
+    # its values; its ``apply_paged`` attends in the absorbed form
+    latent_kind: Optional[Callable] = None
 
     @classmethod
     def from_module(cls, module, cfg) -> "ModelFamily":
@@ -86,7 +91,8 @@ class ModelFamily:
                    state_slot_bytes=getattr(module, "state_slot_bytes", None),
                    state_leaves=tuple(getattr(module, "STATE_LEAVES", ())),
                    sparse_rows=getattr(module, "sparse_rows", None),
-                   window_kinds=getattr(module, "window_kinds", None))
+                   window_kinds=getattr(module, "window_kinds", None),
+                   latent_kind=getattr(module, "latent_kind", None))
 
 
 def _round_up(n: int, m: int) -> int:

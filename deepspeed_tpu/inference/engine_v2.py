@@ -40,7 +40,7 @@ from ..telemetry.trace import percentiles
 from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .engine import InferenceEngine, ModelFamily, _round_up
-from .ragged import (IndexPoolError, KVKindError,  # noqa: F401
+from .ragged import (IndexPoolError, KVKindError, LatentKVError,  # noqa: F401
                      RecurrentStateError, StateManager, UnknownSequenceError,
                      WindowKind)  # (re-exports)
 from .sampling import (SamplingParams, accept_drafts, sample, sample_batch,
@@ -139,6 +139,21 @@ _REFUSALS = {
         "handoff":
             "the wire format carries the full kind's blocks alone, and the "
             "window layers' state would be missing"}),
+    # each feature that reads the K and V leaves by name, or that no test
+    # holds over one pool (prefix reuse and ``fork`` are served: the block
+    # lifecycle carries any leaf with the block axis)
+    "latent_kind": (LatentKVError, {
+        "inference.kv_quant":
+            "the latent pool has no quantized mode (a row's latent and its "
+            "roped key would want scales of their own)",
+        "inference.prefix_cache.host_spill": _SPILLS
+            + ", and nothing checks a restored latent block",
+        "inference.speculative":
+            "a rejected draft is rolled back by truncating blocks, and "
+            "nothing checks a rollback over the latent pool",
+        "handoff":
+            "the wire format carries the K and V pools, and this cache "
+            "has neither"}),
 }
 
 
@@ -271,11 +286,17 @@ class InferenceEngineV2(InferenceEngine):
         # window. ``memory_config_blocks`` stays the full kind's count.
         self._window = dict(self.family.window_kinds(self.family.cfg)) \
             if self.family.window_kinds else {}
+        # --- a latent (MLA) cache (docs/serving.md "Latent (MLA) cache"):
+        # the family's ONE pool, ``cache["latent"]``, sized by
+        # ``memory_config_blocks`` as the K and V pools are
+        self._latent = dict(self.family.latent_kind(self.family.cfg)) \
+            if self.family.latent_kind else {}
         # what those kinds refuse: a feature here, a call when it is made
         self._refusals = [_REFUSALS[kind] for kind, has in (
             ("recurrent_state", self._recurrent),
             ("index_pool", self._indexed),
-            ("window_kinds", self._window)) if has]
+            ("window_kinds", self._window),
+            ("latent_kind", self._latent)) if has]
         self._refuse_features()
         kinds = ()
         if self._window:
@@ -624,12 +645,15 @@ class InferenceEngineV2(InferenceEngine):
         token up to each sequence's last row, a window layer what lies
         inside its first row's window (``kv_tokens_full``,
         ``kv_tokens_window``; one sequence a pair of ``firsts``, its first
-        row's position, and ``counts``, its rows). None for any other
-        family."""
-        if not self._window:
+        row's position, and ``counts``, its rows). In a family with a latent
+        cache: ``kv_tokens_latent``, the cached rows ONE layer reads. None
+        for any other family."""
+        if not (self._window or self._latent):
             return {}
         firsts = np.asarray(firsts, np.int64)
         ends = firsts + np.asarray(counts, np.int64)
+        if self._latent:
+            return {prefix + "kv_tokens_latent": int(ends.sum())}
         out = {prefix + "kv_tokens_full": int(ends.sum())}
         for window in self._window.values():
             key = prefix + "kv_tokens_window"
@@ -897,6 +921,11 @@ class InferenceEngineV2(InferenceEngine):
             into[key] = into.get(key, 0) + n
         return {"ssm_rows": rows, "ssm_tokens": tokens}
 
+    def _walked_pool(self):
+        """The pool whose shape says the paged walks' tile sizes: the K pool,
+        or a latent cache's one pool; None for a family that has neither."""
+        return self.cache.get("k", self.cache.get("latent"))
+
     def _attn_tile_args(self) -> Dict[str, float]:
         """Span arguments of a decode dispatch over the slots as they stand:
         of ONE layer's ``paged_decode`` call, the grid's KV tiles that hold
@@ -905,13 +934,13 @@ class InferenceEngineV2(InferenceEngine):
         for a family whose paged cache is not ``init_paged_pools``'."""
         from ..ops.pallas.paged_attention import decode_tile_counts
 
-        pool = self.cache.get("k")
+        pool = self._walked_pool()
         if pool is None:
             return {}
         live, grid = decode_tile_counts(
             self._slot_lens, self.family.cfg.num_heads, pool.shape,
             pool.dtype.itemsize, self.state.max_blocks_per_seq,
-            "k_scale" in self.cache)
+            "k_scale" in self.cache, 1 if self._latent else 2)
         return {"attn_tiles_live": live, "attn_tiles_grid": grid,
                 "attn_live_tile_share": live / grid}
 
@@ -924,13 +953,15 @@ class InferenceEngineV2(InferenceEngine):
         of ONE layer's call; in a family with window kinds of one call a
         kind, each times the kind's layers, summed. None for a family whose
         chunk takes another walk (a learned selection) or whose paged cache
-        is not ``init_paged_pools``'."""
+        is not ``init_paged_pools``' (a latent pool is: one KV head, every
+        query head in its group)."""
         from ..ops.pallas.paged_attention import prefill_tile_counts
 
-        if "k" not in self.cache or self._indexed:
+        pool = self._walked_pool()
+        if pool is None or self._indexed:
             return {}
         state = self.state
-        walks = [(self.cache["k"].shape, state.max_blocks_per_seq, 0, None)]
+        walks = [(pool.shape, state.max_blocks_per_seq, 0, None)]
         walks += [(self.cache["k_" + kind.name].shape, kind.blocks_per_seq,
                    state.first_live(kind, ch.ctx) * state.block_size,
                    kind.window) for kind in state.window_kinds]
@@ -2221,21 +2252,32 @@ class InferenceEngineV2(InferenceEngine):
         return self._publish(self.state_events(step))
 
     def kv_kind_events(self, step: int = 0):
-        """``Serving/kv/*`` telemetry events of a family with window layers'
+        """``Serving/kv/*`` telemetry events. Of a family with a latent
+        cache: ``latent_blocks_live``, the blocks sequences hold of its one
+        pool now. Of a family with window layers'
         KV state (none for any other): ``full_blocks_live`` and
         ``window_blocks_live``, the blocks sequences hold of each kind now,
         and ``window_blocks_released``, the window kinds' blocks given back
         behind the window, cumulative."""
+        st = self.state
+        if self._latent:
+            return [("Serving/kv/latent_blocks_live",
+                     float(self.blocks_live()), step)]
         if not self._window:
             return []
-        st = self.state
-        vals = {"full_blocks_live": st.allocator.num_blocks - 1
-                - st.allocator.free_blocks - st.retained_blocks,
+        vals = {"full_blocks_live": self.blocks_live(),
                 "window_blocks_live": sum(st.window_blocks_live(k.name)
                                           for k in st.window_kinds),
                 "window_blocks_released": st.window_blocks_released}
         return [(f"Serving/kv/{k}", float(v), step)
                 for k, v in sorted(vals.items())]
+
+    def blocks_live(self) -> int:
+        """Blocks of the full kind's pool (a latent cache's one pool) that
+        sequences hold now."""
+        alloc = self.state.allocator
+        return alloc.num_blocks - 1 - alloc.free_blocks \
+            - self.state.retained_blocks
 
     def publish_kv_kind_telemetry(self, step: int = 0):
         return self._publish(self.kv_kind_events(step))

@@ -103,6 +103,18 @@ class KVKindError(NotImplementedError):
                          f"'Kinds of KV state')")
 
 
+class LatentKVError(NotImplementedError):
+    """A serving feature that reads a sequence's cache as K and V pools was
+    asked of a family whose cache is ONE latent pool (MLA: a row a token a
+    layer, keys and values both inside it): refused by name instead of
+    looking for leaves that are not there."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(f"{feature} is not available for a family with a "
+                         f"latent (MLA) cache: {why} (docs/serving.md "
+                         f"'Latent (MLA) cache')")
+
+
 @dataclasses.dataclass(frozen=True)
 class WindowKind:
     """A kind of KV state beside the full kind: the layers that attend the

@@ -479,6 +479,13 @@ class ServingScheduler:
                 # blocks the window layers' kind gave back behind the window
                 self.last_tick["window_blocks_released"] = \
                     eng.state.window_blocks_released - released
+            if eng.family.latent_kind:
+                # the latent pool: the cached rows ONE layer's decode rows
+                # read this tick, the blocks held and the blocks there are
+                self.last_tick.update(
+                    kv_tokens_latent=did["kv_tokens"],
+                    latent_blocks_live=eng.blocks_live(),
+                    latent_blocks=eng.state.allocator.num_blocks - 1)
             tick.set(**self.last_tick)
         self.stats["prefill_tokens"] += self.last_tick["prefill_tokens"]
         self.stats["decode_seq_steps"] += self.last_tick["decode_seqs"]

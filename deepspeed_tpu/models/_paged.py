@@ -60,6 +60,13 @@ there. A layer hands :func:`paged_attention_step` its kind's pools, its
 kind's table and lengths and, for a window kind, the window - the kernels
 bound their walk by it, so what the manager gave back is never reached.
 
+A latent (MLA) cache (:func:`init_latent_pool`,
+:func:`latent_attention_step`): ONE pool, ``cache["latent"]``, one row a
+token a layer - the token's normed latent and its roped key side by side -
+attended in the absorbed form: one KV head, every query head in its group,
+keys the whole row and values its first lanes, through the same write and
+the same two walks.
+
 Reads: both kernels walk the block table over the live context
 (``ops/pallas/paged_attention.py``). Nothing here gathers a dense view of
 the pool; only the ops' XLA references do, and the registry picks those off
@@ -404,6 +411,76 @@ def _lane_packed(q, k, v, pack: int):
         return jnp.sum(out.reshape(b, t, nh, pack, hd) * mine, axis=3)
 
     return q, k, v, unpack
+
+
+# --------------------------------------------------------------------------- #
+# a latent (MLA) cache: ONE pool, one row a token a layer
+# --------------------------------------------------------------------------- #
+def latent_row_width(key_width: int) -> int:
+    """Lanes of a latent pool's row: the ``key_width`` numbers a token
+    keeps (its normed latent, then its roped key), zero-padded to whole
+    128-lane tiles. A Mosaic operand is row-major (:func:`lane_pack_of`'s
+    finding) and the device's tiled layout pads a minor dimension to whole
+    tiles either way, so the padding is said here and costs what the device
+    would have spent unasked."""
+    return -(-key_width // 128) * 128
+
+
+def init_latent_pool(num_layers: int, num_blocks: int, block_size: int,
+                     key_width: int, dtype=jnp.bfloat16):
+    """``cache["latent"]``: ``[L, num_blocks, 1, block_size, row width]`` -
+    the block pools' shape at one "head", so every block-lifecycle op
+    (copy-on-write, fork, prefix reuse) carries it as it carries any leaf
+    with the block axis."""
+    return {"latent": jnp.zeros((num_layers, num_blocks, 1, block_size,
+                                 latent_row_width(key_width)), dtype)}
+
+
+def latent_attention_step(q, row, cache: LayerPool, block_tables,
+                          context_lens, valid, *, value_width: int,
+                          scale: float) -> Tuple:
+    """:func:`paged_attention_step` over a latent pool, in the ABSORBED
+    form: multi-query attention at one KV head. ``q [b, t, nh, key_width]``
+    is each head's query against a cached row (the up-projection of the keys
+    folded in by the family), ``row [b, t, 1, key_width]`` the step's rows to
+    cache; a token's values are the first ``value_width`` numbers of its
+    row. Both are zero-padded to the pool's row here. The write and both
+    walks are the paged ops at ``v_pool`` None (``ops/pallas/
+    paged_attention.py``): the key page is read once and serves both
+    matmuls. ``scale`` is the family's (the absorbed query is not the width
+    the softmax scale comes from). A :class:`MixedCall` splits as it does
+    there. Returns ``(out [b, t, nh, value_width], cache)``."""
+    if isinstance(block_tables, MixedCall):
+        call = block_tables
+        (q_d, q_c), (r_d, r_c) = call.split(q), call.split(row)
+        chunk_rows = (jnp.arange(q_c.shape[1]) < call.chunk_valid)[None]
+        kw = dict(value_width=value_width, scale=scale)
+        out_c, cache = latent_attention_step(
+            q_c, r_c, cache, call.chunk_table[None], call.chunk_ctx[None],
+            chunk_rows, **kw)
+        out_d, cache = latent_attention_step(
+            q_d, r_d, cache, call.tables, call.lens, call.active[:, None],
+            **kw)
+        return call.join(out_d, out_c), cache
+    from ..ops import pallas as _pallas_ops  # noqa: F401 (registers)
+    from ..ops.registry import get_op
+
+    pad = cache.pool.shape[-1] - q.shape[-1]
+    q, row = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),)) for a in (q, row))
+    layer = cache.layer
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    with jax.named_scope("kv_write"):
+        pool = get_op("paged_kv_write")(
+            row, None, cache.pool, None, block_tables, context_lens, n_valid,
+            layer=layer)[0]
+    kw = dict(scale=scale, layer=layer, value_width=value_width)
+    if q.shape[1] == 1:
+        out = get_op("paged_decode_attention")(
+            q[:, 0], pool, None, block_tables, context_lens, **kw)[:, None]
+    else:
+        out = get_op("paged_prefill_attention")(
+            q, pool, None, block_tables, context_lens, n_valid, **kw)
+    return out, LayerPool(pool, None, layer)
 
 
 # --------------------------------------------------------------------------- #

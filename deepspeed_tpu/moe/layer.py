@@ -97,7 +97,9 @@ class MoELayer:
                  dispatch: str = "einsum",
                  held: Optional[Tuple[int, int]] = None,
                  score: str = "softmax",
-                 shared_scale: Optional[float] = None):
+                 shared_scale: Optional[float] = None,
+                 groups: Optional[Tuple[int, int]] = None,
+                 route_scale: Optional[float] = None):
         self.n_experts = n_experts
         self.top_k = top_k
         self.capacity_factor = capacity_factor
@@ -129,6 +131,11 @@ class MoELayer:
         # FFN ``n`` times as wide, times ``1 / n``)
         self.score = score
         self.shared_scale = shared_scale
+        # group-limited routing (``sharded_moe.top_k_gating_compact``
+        # ``groups``) and what the ROUTED experts' sum is scaled by before a
+        # shared expert is added (DeepSeek-V3's ``routed_scaling_factor``)
+        self.groups = groups
+        self.route_scale = route_scale
 
     def grouped(self) -> bool:
         """Whether a call over the stacked banks takes the grouped form - the
@@ -168,6 +175,8 @@ class MoELayer:
             if layer is not None:
                 bank = [w[layer] for w in bank]
             out, aux_loss = self._slabs(tokens, logits, bank)
+        if self.route_scale is not None:
+            out = out * jnp.asarray(self.route_scale, out.dtype)
         # shared experts: a dense SwiGLU added to every token (params
         # present only when used) - Qwen2-MoE's under a learned sigmoid
         # gate; without a gate of its own (cohere2_moe: ``shared_scale``) a
@@ -191,7 +200,7 @@ class MoELayer:
         return dict(capacity_factor=self.capacity_factor,
                     min_capacity=self.min_capacity,
                     drop_tokens=self.drop_tokens, norm_topk=self.norm_topk,
-                    score=self.score)
+                    score=self.score, groups=self.groups)
 
     def _grouped(self, tokens, logits, bank, layer):
         """The no-drop form: O(k·T·H) movement around a bank that computes

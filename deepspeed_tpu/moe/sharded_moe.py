@@ -75,7 +75,9 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
                          capacity_factor: float = 1.0, min_capacity: int = 4,
                          drop_tokens: bool = True,
                          norm_topk: bool = True,
-                         score: str = "softmax") -> CompactGating:
+                         score: str = "softmax",
+                         groups: Optional[Tuple[int, int]] = None
+                         ) -> CompactGating:
     """logits: [tokens, experts] → compact assignment (see CompactGating).
 
     The reference's top1/top2/topk gating family as one k-generic routine
@@ -86,7 +88,13 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
     becomes before the top-k - ``"softmax"`` over the experts, or
     ``"sigmoid"``, each expert's score by itself (``expert_selection_fn``
     of the cohere2_moe family; with ``norm_topk`` the gates are the chosen
-    scores over their sum), in float32 either way. Biggest live tensor is
+    scores over their sum), in float32 either way. ``groups = (n_group,
+    topk_group)``: group-limited routing (DeepSeek-V3, arXiv:2412.19437
+    section 2.1.2, without its score-correction bias) - the experts lie in
+    ``n_group`` equal groups side by side, a group's score is the sum of its
+    two best experts', and the top-k is taken among the experts of the
+    ``topk_group`` best groups alone; the gates are the chosen experts' own
+    scores as before. Biggest live tensor is
     the [T, E] cumsum — the dense [T, E, C] view exists only in
     :func:`top_k_gating` for the einsum dispatch."""
     tokens, n_experts = logits.shape
@@ -95,7 +103,11 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
                          f"got {score!r}")
     probs = SCORES[score](logits.astype(jnp.float32))
 
-    topk_probs, topk_idx = jax.lax.top_k(probs, k)          # [T, k]
+    if groups is None:
+        topk_probs, topk_idx = jax.lax.top_k(probs, k)      # [T, k]
+    else:
+        topk_idx = jax.lax.top_k(_group_limited(probs, *groups), k)[1]
+        topk_probs = jnp.take_along_axis(probs, topk_idx, axis=1)
     if norm_topk:
         # renormalize the selected gates (reference top2: gates /= denom)
         denom = jnp.sum(topk_probs, axis=-1, keepdims=True)
@@ -132,6 +144,24 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
                          pos=pos, keep=keep, capacity=capacity,
                          aux_loss=aux_loss, router_probs=probs,
                          counts=prior_count)
+
+
+def _group_limited(probs: jnp.ndarray, n_group: int,
+                   topk_group: int) -> jnp.ndarray:
+    """``probs [T, E]`` with every expert outside its row's ``topk_group``
+    best groups at -inf: what the top-k may choose from."""
+    tokens, n_experts = probs.shape
+    if n_experts % n_group or not 1 <= topk_group <= n_group:
+        raise ValueError(f"groups {(n_group, topk_group)} do not divide "
+                         f"{n_experts} experts")
+    grouped = probs.reshape(tokens, n_group, n_experts // n_group)
+    best = jnp.sum(jax.lax.top_k(grouped, min(2, grouped.shape[-1]))[0],
+                   axis=-1)                                  # [T, n_group]
+    chosen = jax.lax.top_k(best, topk_group)[1]
+    allowed = jnp.any(jax.nn.one_hot(chosen, n_group, dtype=jnp.bool_),
+                      axis=1)                                # [T, n_group]
+    return jnp.where(allowed[:, :, None], grouped, -jnp.inf) \
+        .reshape(tokens, n_experts)
 
 
 def row_tile(tokens: int, n_experts: int, k: int, held: int,
@@ -207,7 +237,8 @@ def top_k_gating(logits: jnp.ndarray, k: int = 1, *,
                  drop_tokens: bool = True,
                  norm_topk: bool = True,
                  held: Optional[Tuple[int, int]] = None,
-                 score: str = "softmax") -> GatingOutput:
+                 score: str = "softmax",
+                 groups: Optional[Tuple[int, int]] = None) -> GatingOutput:
     """Dense [T, E, C] view of :func:`top_k_gating_compact` — the form the
     einsum dispatch contracts with (MXU-friendly, but O(T·E·C) memory).
     ``held = (first, count)``: the masks of experts ``first .. first + count
@@ -216,7 +247,7 @@ def top_k_gating(logits: jnp.ndarray, k: int = 1, *,
     cg = top_k_gating_compact(logits, k, capacity_factor=capacity_factor,
                               min_capacity=min_capacity,
                               drop_tokens=drop_tokens, norm_topk=norm_topk,
-                              score=score)
+                              score=score, groups=groups)
     tokens, n_experts = logits.shape
     chosen = cg.topk_idx
     if held is not None:

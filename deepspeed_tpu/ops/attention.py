@@ -134,7 +134,8 @@ def _attention_xla_grouped(q, k, v, *, causal, scale, mask, bias, q_offset,
     probs = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
     probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
     out = jnp.einsum("...ngqk,...knd->...qngd", probs.astype(v.dtype), v)
-    return out.reshape(q.shape).astype(q.dtype)
+    # (values may be narrower than keys: a latent pool's)
+    return out.reshape(q.shape[:-1] + v.shape[-1:]).astype(q.dtype)
 
 
 @register("attention", backend="xla")

@@ -180,6 +180,14 @@ def _gathered_view(pool, block_tables, layer):
     return g.reshape((b, max_blocks * g.shape[2]) + g.shape[3:])
 
 
+def _latent_values(keys, value_width):
+    """A latent pool's values: the first ``value_width`` lanes of its key
+    rows (the XLA references' form of the kernel's in-VMEM slice)."""
+    assert value_width is not None, "a pool without a V pool is a latent " \
+        "pool and says how wide its values are"
+    return keys[..., :value_width]
+
+
 # --------------------------------------------------------------------------- #
 # the one flash walk over the block table. Both ops are this kernel:
 # ``paged_prefill`` at ``tq`` query tokens a tile and one KV head a grid
@@ -214,7 +222,8 @@ def _group_rows(g: int) -> int:
 
 
 def _decode_tiles(nkv: int, g: int, hd: int, bs: int, max_blocks: int,
-                  itemsize: int, quant: bool) -> Tuple[int, int, int]:
+                  itemsize: int, quant: bool,
+                  pools: int = 2) -> Tuple[int, int, int]:
     """(pages a KV tile, KV heads a grid step, KV tiles the table holds).
     One page of every KV head is one contiguous block of the pool, so a
     grid step takes them all, and as many pages as make ~256 tokens - as
@@ -222,9 +231,11 @@ def _decode_tiles(nkv: int, g: int, hd: int, bs: int, max_blocks: int,
     ``_TILE_VMEM``: the K and V tiles, double-buffered (an int8 page's f32
     scale tile pads its group lanes to 128), and the group's f32 scores and
     probabilities. Many or wide KV heads get a head block that divides
-    ``nkv``; only a single head over the budget gets fewer pages."""
+    ``nkv``; only a single head over the budget gets fewer pages.
+    ``pools``: the pools a step reads a page of (1: a latent pool, whose
+    values are lanes of its keys' page)."""
     pages = max(1, min(_MAX_PAGES, _KV_TOKENS // bs, max_blocks))
-    page = 4 * bs * (hd * itemsize + (128 * 4 if quant else 0)) \
+    page = 2 * pools * bs * (hd * itemsize + (128 * 4 if quant else 0)) \
         + 2 * _group_rows(g) * bs * 4
     room = _TILE_VMEM // page               # (head, page) pairs a step
     heads = max([h for h in range(1, nkv + 1)
@@ -234,14 +245,15 @@ def _decode_tiles(nkv: int, g: int, hd: int, bs: int, max_blocks: int,
 
 
 def decode_tile_counts(context_lens, nh: int, pool_shape, itemsize: int,
-                       max_blocks: int, quant: bool) -> Tuple[int, int]:
+                       max_blocks: int, quant: bool,
+                       pools: int = 2) -> Tuple[int, int]:
     """(live, visited) KV tiles of ONE ``paged_decode`` call over slots at
     ``context_lens`` (host integers): the grid steps that hold context and
     the steps the grid takes - every slot walks as far as the longest. What
     the serving engine puts on its ``decode_step`` span."""
     nkv, bs, hd = pool_shape[-3:]
     pages, heads, n_kv = _decode_tiles(nkv, nh // nkv, hd, bs, max_blocks,
-                                       itemsize, quant)
+                                       itemsize, quant, pools)
     tiles = np.minimum(np.asarray(context_lens) // (pages * bs) + 1, n_kv)
     return (int(tiles.sum()) * (nkv // heads),
             int(tiles.max()) * tiles.size * (nkv // heads))
@@ -279,15 +291,18 @@ def _kv_tile(page_refs, scale_refs, dtype):
     return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=-2)
 
 
-def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
+def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant,
+                  vd=None):
     """q ``[.., rows, hd]`` against the KV tile ``[.., pages * bs, hd]`` of
     grid step ``j``; a leading axis is the KV heads of the step. ``n_kv``
-    None: the grid's last dimension is dynamic."""
+    None: the grid's last dimension is dynamic. ``vd``: there is no V pool
+    and a token's values are the first ``vd`` lanes of its key row (a latent
+    pool: the key page is read once and serves both matmuls)."""
     ctx_ref, len_ref = refs[1], refs[2]    # after the tables, before the layer
     wnd_ref = refs[4] if has_window else None
     refs = refs[4 + int(has_window):]
     q_ref, refs = refs[0], refs[1:]
-    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]   # (no V: unread)
     ks_refs, vs_refs = ((refs[2 * pages:3 * pages], refs[3 * pages:4 * pages])
                         if quant else ((None,) * pages,) * 2)
     o_ref, m_scr, l_scr, acc_scr = refs[-4:]
@@ -312,7 +327,8 @@ def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
     def _compute():
         q = q_ref[...]                     # [.., rows, hd]
         k = _kv_tile(k_refs, ks_refs, q.dtype)   # [.., kv, hd]
-        v = _kv_tile(v_refs, vs_refs, q.dtype)
+        v = _kv_tile(v_refs, vs_refs, q.dtype) if vd is None \
+            else k[..., :vd]
         s = _mxu_dot(q, k, _contract(q.ndim, -1),
                      preferred_element_type=jnp.float32) * scale
         rows = s.shape[-2]
@@ -332,7 +348,7 @@ def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant):
 
 def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
                 layer, window, k_scale, v_scale, *, scale, rows, tq, pages,
-                heads, n_kv):
+                heads, n_kv, vd=None):
     """The kernel, grid and arguments of one walk over layer ``layer`` of the
     ``[L, num_blocks, nkv, bs, hd]`` pools: the layer is one more prefetched
     scalar and the first coordinate of every pool page, so the pools are read
@@ -346,16 +362,22 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
     below its first row's window skips its compute and FOLDS onto a live
     page index: Pallas elides the DMA when consecutive steps map to the same
     block, so HBM traffic is the live context per (KV-head block, query
-    tile), with or without a window."""
+    tile), with or without a window. ``v_pool`` None with ``vd``: the key
+    width and the value width differ and the values are the first ``vd``
+    lanes of the key page (a latent pool) - one pool, one DMA a page, an
+    output ``vd`` wide."""
     B, nkv, _, hd = qg.shape
     nblocks, bs = k_pool.shape[-4], k_pool.shape[-2]
     max_blocks = block_tables.shape[1]
     has_window, quant = window is not None, k_scale is not None
+    assert (v_pool is None) == (vd is not None) and not (vd and quant), \
+        "values come from a V pool or from the key page's lanes"
     static = isinstance(n_kv, int)
     kernel = functools.partial(_paged_kernel, bs=bs, pages=pages,
                                scale=float(scale),
                                n_kv=n_kv if static else None, tq=tq,
-                               has_window=has_window, quant=quant)
+                               has_window=has_window, quant=quant, vd=vd)
+    od = vd or hd                       # the output's (values') width
 
     # index maps are called positionally with one trailing arg per
     # prefetched scalar array
@@ -379,7 +401,8 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
 
     # scale tiles ride the same maps as their code tiles, so a dead step
     # elides both DMAs together
-    pools = (k_pool, v_pool) + ((k_scale, v_scale) if quant else ())
+    pools = (k_pool,) + (() if v_pool is None else (v_pool,)) \
+        + ((k_scale, v_scale) if quant else ())
     in_specs = [pl.BlockSpec((None, heads, rows, hd), qmap)] + [
         _page_spec(pool, heads, page_map(p))
         for pool in pools for p in range(pages)]
@@ -389,11 +412,11 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
         num_scalar_prefetch=4 + int(has_window),
         grid=(B, nkv // (heads or 1), qg.shape[2] // rows, n_kv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, heads, rows, hd), qmap),
+        out_specs=pl.BlockSpec((None, heads, rows, od), qmap),
         scratch_shapes=[
             pltpu.VMEM(lead + (rows, 128), jnp.float32),
             pltpu.VMEM(lead + (rows, 128), jnp.float32),
-            pltpu.VMEM(lead + (rows, hd), jnp.float32),
+            pltpu.VMEM(lead + (rows, od), jnp.float32),
         ],
     )
     prefetch = [block_tables.astype(jnp.int32),
@@ -412,8 +435,12 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            context_lens: jnp.ndarray, *,
                            scale: float = None,
                            window=None, k_scale=None,
-                           v_scale=None, layer=None) -> jnp.ndarray:
-    """See module docstring. Returns [B, nh, hd]. ``layer``: the layer of
+                           v_scale=None, layer=None,
+                           value_width: int = None) -> jnp.ndarray:
+    """See module docstring. Returns [B, nh, hd]. ``v_pool`` None with
+    ``value_width``: a latent pool - a token's values are the first
+    ``value_width`` lanes of its key row, q is as wide as that row and the
+    result ``[B, nh, value_width]``. ``layer``: the layer of
     ``[L, num_blocks, nkv, bs, hd]`` pools to attend over (int or traced
     scalar - the layer scan's index); a 4-D pool is one layer's. ``window``: optional
     sliding-window length (int or traced scalar — exaone4 scans per-layer
@@ -433,7 +460,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         window = _checked_window(window)
     pages, heads, n_kv = _decode_tiles(
         nkv, g, hd, bs, block_tables.shape[1], k_pool.dtype.itemsize,
-        k_scale is not None)
+        k_scale is not None, 1 if v_pool is None else 2)
     # [B, nkv, gpad, hd] query groups; the walk ends with the longest
     # context's last tile (the current token included)
     qg = jnp.pad(q.reshape(B, nkv, g, hd),
@@ -443,15 +470,17 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         qg, k_pool, v_pool, block_tables, context_lens,
         jnp.ones((B,), jnp.int32), layer, window, k_scale, v_scale,
         scale=hd ** -0.5 if scale is None else scale, rows=gpad, tq=1,
-        pages=pages, heads=heads, n_kv=n_live.astype(jnp.int32))
+        pages=pages, heads=heads, n_kv=n_live.astype(jnp.int32),
+        vd=value_width)
+    od = value_width or hd
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), q.dtype),
         compiler_params=_WALK_GRID,
         interpret=_interpret(),
         name="paged_decode",
     )(*args)
-    return out[:, :, :g].reshape(B, nh, hd)
+    return out[:, :, :g].reshape(B, nh, od)
 
 
 def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -459,7 +488,8 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
                                context_lens: jnp.ndarray, *,
                                scale: float = None,
                                window=None, k_scale=None,
-                               v_scale=None, layer=None) -> jnp.ndarray:
+                               v_scale=None, layer=None,
+                               value_width: int = None) -> jnp.ndarray:
     """Dense-gather fallback with identical semantics (compiled XLA — the
     right choice off-TPU, where the Pallas path runs interpreted).
     ``k_scale``/``v_scale``: the quantized-KV reference path — int8 code
@@ -473,7 +503,8 @@ def paged_decode_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     max_blocks = block_tables.shape[1]
     S = max_blocks * bs
     kg = _gathered_view(k_pool, block_tables, layer)
-    vg = _gathered_view(v_pool, block_tables, layer)
+    vg = _latent_values(kg, value_width) if v_pool is None \
+        else _gathered_view(v_pool, block_tables, layer)
     if window is not None:
         window = _checked_window(window)
     if k_scale is not None and k_scale.shape[-1] == 1:
@@ -520,7 +551,8 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                             v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                             context_lens: jnp.ndarray, lengths=None, *,
                             scale: float = None, window=None, k_scale=None,
-                            v_scale=None, layer=None) -> jnp.ndarray:
+                            v_scale=None, layer=None,
+                            value_width: int = None) -> jnp.ndarray:
     """Multi-token attention over the paged pools, flash over the block
     table: every ``t > 1`` call of ``models/_paged.paged_attention_step`` — a
     SplitFuse prefill chunk at a context offset, a batched prefill, a
@@ -535,9 +567,10 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     output is unspecified (callers discard it), and they neither extend the
     live range nor reach table entries past the sequence's blocks — a
     zero-length dummy row of a batched prefill computes nothing.
-    ``window``/``k_scale``/``v_scale``/``layer`` as in
+    ``window``/``k_scale``/``v_scale``/``layer``/``value_width`` as in
     :func:`paged_decode_attention`.
-    Returns ``[B, t, nh, hd]``. The walk: :func:`_table_walk`, one KV head a
+    Returns ``[B, t, nh, hd]`` (``value_width`` for ``hd`` over a latent
+    pool). The walk: :func:`_table_walk`, one KV head a
     grid step, query tiles of ``g * tq`` rows."""
     B, t, nh, hd = q.shape
     assert (k_scale is None) == (v_scale is None), \
@@ -568,16 +601,17 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         window, k_scale, v_scale,
         scale=hd ** -0.5 if scale is None else scale,
         rows=rows, tq=tq, pages=pages, heads=None,
-        n_kv=n_live.astype(jnp.int32))
+        n_kv=n_live.astype(jnp.int32), vd=value_width)
+    od = value_width or hd
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), q.dtype),
         compiler_params=_WALK_GRID,
         interpret=_interpret(),
         name="paged_prefill",
     )(*args)
-    return out.reshape(B, nkv, n_qt, g, tq, hd).transpose(0, 2, 4, 1, 3, 5) \
-        .reshape(B, n_qt * tq, nh, hd)[:, :t]
+    return out.reshape(B, nkv, n_qt, g, tq, od).transpose(0, 2, 4, 1, 3, 5) \
+        .reshape(B, n_qt * tq, nh, od)[:, :t]
 
 
 def paged_prefill_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -586,7 +620,8 @@ def paged_prefill_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
                                 context_lens: jnp.ndarray, lengths=None, *,
                                 scale: float = None, window=None,
                                 k_scale=None, v_scale=None,
-                                layer=None) -> jnp.ndarray:
+                                layer=None,
+                                value_width: int = None) -> jnp.ndarray:
     """The reference with identical semantics on every real row: gather the
     table's whole width, mask, soft-max in f32 (the right choice off-TPU,
     where the Pallas path runs interpreted; what every multi-token paged
@@ -599,7 +634,8 @@ def paged_prefill_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     t = q.shape[1]
     layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
     kg = _gathered_view(k_pool, block_tables, layer)
-    vg = _gathered_view(v_pool, block_tables, layer)
+    vg = _latent_values(kg, value_width) if v_pool is None \
+        else _gathered_view(v_pool, block_tables, layer)
     if k_scale is not None:
         kg = kv_dequantize_int8(
             kg, _gathered_view(k_scale, block_tables, layer), q.dtype)
@@ -621,6 +657,8 @@ def _stored_rows(k, v, k_pool, k_scale):
     the pool's dtype, or int8 codes and their fp32 per-(token, head, group)
     scales ``(qk, qv, sk, sv)`` - fill-time quantisation, in the same step
     as the write."""
+    if v is None:                       # one pool: a latent row a token
+        return (k.astype(k_pool.dtype),)
     if k_scale is None:
         return k.astype(k_pool.dtype), v.astype(k_pool.dtype)
     from ..quantization import kv_quantize_int8
@@ -628,6 +666,14 @@ def _stored_rows(k, v, k_pool, k_scale):
     group = k.shape[-1] // k_scale.shape[-1]
     (qk, sk), (qv, sv) = kv_quantize_int8(k, group), kv_quantize_int8(v, group)
     return qk, qv, sk, sv
+
+
+def _four(outs, v_pool) -> Tuple:
+    """A write's results as ``(k_pool, v_pool, k_scale, v_scale)``."""
+    outs = tuple(outs)
+    if v_pool is None:
+        outs = outs[:1] + (None,)
+    return outs + (None,) * (4 - len(outs))
 
 
 def _write_pages(t: int, bs: int) -> int:
@@ -658,8 +704,9 @@ def paged_kv_write(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
     goes to position ``context_lens[b] + ti`` of its block table, for its
     first ``lengths[b]`` rows; a padded row and a zero-length dummy sequence
     write nothing. With ``k_scale``/``v_scale`` the pools hold int8 codes and
-    the same call writes codes and scales. Returns ``(k_pool, v_pool,
-    k_scale, v_scale)``.
+    the same call writes codes and scales. ``v`` and ``v_pool`` None: ONE
+    pool, one row a token (a latent pool; ``k [B, t, 1, W]``). Returns
+    ``(k_pool, v_pool, k_scale, v_scale)``, None what there is none of.
 
     One Mosaic call whose pools are aliased to its results, grid (sequence,
     page the step can touch): a grid step reads one page of every KV head
@@ -679,7 +726,7 @@ def paged_kv_write(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
     nblocks, nkv, bs = k_pool.shape[-4:-1]
     max_blocks = block_tables.shape[1]
     n_pages = _write_pages(t, bs)
-    pools = [k_pool, v_pool] + ([] if k_scale is None else [k_scale, v_scale])
+    pools = [p for p in (k_pool, v_pool, k_scale, v_scale) if p is not None]
 
     # slot o of page j <- row j * bs + o - ctx % bs (clipped: the kernel's
     # mask drops what is not the sequence's own)
@@ -722,7 +769,7 @@ def paged_kv_write(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
         name="paged_kv_write",
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       lengths.astype(jnp.int32), layer, *rows, *pools)
-    return tuple(outs) + (None,) * (4 - len(outs))
+    return _four(outs, v_pool)
 
 
 def paged_kv_write_xla(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
@@ -747,9 +794,10 @@ def paged_kv_write_xla(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
     outs = tuple(
         (p.at[blk, :, off] if p.ndim == 4 else p.at[layer[0], blk, :, off])
         .set(r, mode="drop")
-        for p, r in zip((k_pool, v_pool, k_scale, v_scale),
+        for p, r in zip((p for p in (k_pool, v_pool, k_scale, v_scale)
+                         if p is not None),
                         _stored_rows(k, v, k_pool, k_scale)))
-    return outs + (None,) * (4 - len(outs))
+    return _four(outs, v_pool)
 
 
 # speculative verification is the same computation at t = 1 + draft tokens:

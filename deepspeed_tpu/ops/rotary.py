@@ -7,7 +7,10 @@ the elementwise rotation fuses into the surrounding matmuls on TPU.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
+import numpy as np
 
 from .registry import op, register
 
@@ -19,6 +22,52 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0,
     t = jnp.arange(max_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+
+
+def yarn_inv_frequencies(head_dim: int, theta: float, factor: float,
+                         original_max_len: int, beta_fast: float = 32.0,
+                         beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's inverse frequencies ``[head_dim / 2]`` (arXiv:2309.00071, as
+    DeepSeek-V2/V3 publish it): dimension ``i`` turns ``original_max_len *
+    f_i / 2 pi`` times over the original context; the dimensions that turn
+    more than ``beta_fast`` times keep ``f_i = theta ** (-2 i / d)``, those
+    that turn fewer than ``beta_slow`` times are interpolated to ``f_i /
+    factor``, and a linear ramp between the two correction dimensions
+    (floor and ceil of ``d ln(L / (2 pi beta)) / (2 ln theta)``) blends the
+    rest."""
+    half = head_dim // 2
+    freq = theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+
+    def correction_dim(turns):
+        return head_dim * math.log(original_max_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return (freq / factor * ramp + freq * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``0.1 * mscale * ln(factor) + 1`` (1 at ``factor <= 1``): YaRN's
+    attention temperature, by which a family scales its softmax scale
+    (squared) or its cos / sin tables."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(head_dim: int, max_len: int, theta: float,
+                     factor: float, original_max_len: int,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0,
+                     table_scale: float = 1.0, dtype=jnp.float32):
+    """cos/sin tables ``[max_len, head_dim / 2]`` at YaRN's frequencies
+    (:func:`yarn_inv_frequencies`), times ``table_scale`` (the published
+    ``mscale / mscale_all_dim`` quotient). Made once, as
+    :func:`rope_frequencies`' are."""
+    inv_freq = jnp.asarray(yarn_inv_frequencies(
+        head_dim, theta, factor, original_max_len, beta_fast, beta_slow))
+    freqs = jnp.outer(jnp.arange(max_len, dtype=jnp.float32), inv_freq)
+    return ((jnp.cos(freqs) * table_scale).astype(dtype),
+            (jnp.sin(freqs) * table_scale).astype(dtype))
 
 
 @register("rotary_embed", backend="xla")

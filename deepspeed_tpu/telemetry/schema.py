@@ -93,7 +93,10 @@ SERVING_SERIES = frozenset(
     # kinds of KV state (a family with sliding-window layers;
     # docs/serving.md "Kinds of KV state" - engine_v2.kv_kind_events)
     + ["Serving/kv/" + m for m in (
-        "full_blocks_live", "window_blocks_live", "window_blocks_released")]
+        "full_blocks_live", "window_blocks_live", "window_blocks_released",
+        # a latent (MLA) cache's one pool (docs/serving.md "Latent (MLA)
+        # cache")
+        "latent_blocks_live")]
     # what step() ran (engine_v2.engine_events): its calls, those whose
     # prefill chunk rode in the decode program (``decode_chunk``), those
     # launched while the program before was still unread, the token rows

@@ -1131,3 +1131,75 @@ def test_decode_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
     decode = eng._decode_fn(1, False)
     compiled = decode._jitted.lower(*args).compile()
     assert MOSAIC in compiled.as_text()
+
+
+AXK1_CELL = "a.x-k1.serve-longctx"
+
+
+def _axk1_mixed_program():
+    """The A.X-K1 cell's forward of a mixed call (16 decode rows + a 512-row
+    chunk) on shapes, at its real configuration and its latent pool's
+    geometry: ``(forward(params, cache, tokens, call, valid), arguments)``
+    with the cache second."""
+    from benchmark.harness.manifest import Cell
+    from deepspeed_tpu.models._paged import MixedCall
+
+    cell = Cell(AXK1_CELL)
+    engine = cell.role["engine"]
+    ragged = engine["ragged"]
+    slots, bs = ragged["max_tracked_sequences"], ragged["block_size"]
+    chunk = engine["split_prefill_chunk"]
+    cfg = cell.family.build_cfg(cell.model, **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: module.init(cfg, k), jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, ragged["memory_config_blocks"], bs))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "latent": (5, 3152, 1, 128, 640)}
+    table = cfg.max_seq_len // bs
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    call = MixedCall(s((slots, table), i32), s((slots,), i32),
+                     s((slots,), bool), s((table,), i32), s((), i32),
+                     s((), i32))
+    rows = slots + chunk
+
+    def forward(params, cache, tokens, tables, valid, read):
+        return module.apply_paged(cfg, params, tokens, cache, tables, None,
+                                  valid=valid, rows=read)
+
+    return forward, (params, cache, s((1, rows), i32), call,
+                     s((1, rows), bool), s((1, slots + 1), i32))
+
+
+def test_the_latent_pool_stays_where_it_is_in_the_mixed_program(v5e):
+    """The A.X-K1 cell's mixed call (16 decode rows + a 512-row chunk) at
+    its real configuration, compiled for the chip: the walk lowers at one KV
+    head, a group of 64, keys 640 lanes wide and values the first 512 of the
+    same page; the ONE pool stays where it is (no pool-shaped copy, aliased
+    argument-to-result); the dense layer and the scanned sparse layer are
+    one write and two walks each; and the whole program with its 11.1 GB of
+    weights and 2.58 GB of pool fits the chip."""
+    import math
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    forward, args = _axk1_mixed_program()
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(forward, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pools = jax.tree.leaves(args[1])
+    assert pool_copy_bytes(text, pools) == 0
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert pool_bytes == 5 * 3152 * 128 * 1280
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert 12e9 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    for name, count in (("paged_kv_write", 4), ("paged_decode", 2),
+                        ("paged_prefill", 2), ("moe_grouped_matmul", 1)):
+        assert calls.count(name) == count, (name, calls.count(name))
