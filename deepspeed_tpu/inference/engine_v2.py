@@ -764,8 +764,8 @@ class InferenceEngineV2(InferenceEngine):
                 rng, uids, *sp_rows = rest
                 valid = jnp.arange(pad_t)[None, :] < lengths[:, None]
                 dq = self._dq(params)
-                if not with_ctx:
-                    ctx = jnp.zeros((n,), jnp.int32)
+                if not with_ctx:   # numpy: a constant the ops can read
+                    ctx = np.zeros((n,), np.int32)
                 logits, cache = self._paged_forward(
                     dq, tokens, cache, tables, ctx, valid, slots,
                     rows=_last_real(lengths)[:, None])
@@ -951,11 +951,14 @@ class InferenceEngineV2(InferenceEngine):
         block table would take (``chunk_attn_tiles_live`` / ``_grid`` /
         ``_table``; ``ops/pallas/paged_attention.py prefill_tile_counts``) -
         of ONE layer's call; in a family with window kinds of one call a
-        kind, each times the kind's layers, summed. None for a family whose
-        chunk takes another walk (a learned selection) or whose paged cache
-        is not ``init_paged_pools``' (a latent pool is: one KV head, every
-        query head in its group)."""
-        from ..ops.pallas.paged_attention import prefill_tile_counts
+        kind, each times the kind's layers, summed - and the KV tokens of a
+        grid step of the walk, ``chunk_attn_kv_tile`` (the widest of the
+        kinds': 256 where no walk of the launch took the wide tile). None
+        for a family whose chunk takes another walk (a learned selection) or
+        whose paged cache is not ``init_paged_pools``' (a latent pool is:
+        one KV head, every query head in its group)."""
+        from ..ops.pallas.paged_attention import (prefill_kv_pages,
+                                                  prefill_tile_counts)
 
         pool = self._walked_pool()
         if pool is None or self._indexed:
@@ -965,15 +968,19 @@ class InferenceEngineV2(InferenceEngine):
         walks += [(self.cache["k_" + kind.name].shape, kind.blocks_per_seq,
                    state.first_live(kind, ch.ctx) * state.block_size,
                    kind.window) for kind in state.window_kinds]
-        total = (0, 0, 0)
+        how = (pool.dtype.itemsize, "k_scale" in self.cache,
+               1 if self._latent else 2)
+        total, tile = (0, 0, 0), 0
         for shape, width, given, window in walks:
             layers = shape[0] if len(walks) > 1 else 1
-            counts = prefill_tile_counts(
-                [ch.ctx - given], [len(ch.tokens)], ch.width,
-                self.family.cfg.num_heads, shape, width, window)
+            call = ([ch.ctx - given], [len(ch.tokens)], ch.width,
+                    self.family.cfg.num_heads, shape, width)
+            counts = prefill_tile_counts(*call, window, *how)
             total = tuple(a + layers * n for a, n in zip(total, counts))
+            tile = max(tile, prefill_kv_pages(*call, *how) * shape[-2])
         return dict(zip(("chunk_attn_tiles_live", "chunk_attn_tiles_grid",
-                         "chunk_attn_tiles_table"), total))
+                         "chunk_attn_tiles_table"), total),
+                    chunk_attn_kv_tile=tile)
 
     def _next_chunk(self) -> _Chunk:
         """The OLDEST pending split prefill's next chunk (FIFO, the

@@ -48,9 +48,19 @@ program from the call's own operands. The bound cuts the END of the walk
 alone: inside it an early query tile's steps above its last row, a window's
 steps below its first and a shorter sequence's fold as before, and a call
 of zero-length dummies still takes the one step that initialises and writes
-it. :func:`decode_tile_counts` and :func:`prefill_tile_counts` say on the
-host, from the same tile sizes, how many steps a call takes and how many
-hold context.
+it. The multi-token walk's KV tile has TWO widths, and the program picks:
+~256 tokens where the walk is short, up to 1024 (:func:`_wide_pages`, from
+the shapes and a VMEM budget) where that same bound reaches two wide tiles
+(:func:`_takes_wide`; a ``lax.cond`` over two calls of the one walk, both
+named ``paged_prefill``) - a step's fixed work, the flash rescale of its
+``[rows, 128]`` scratch, is most of a 256-key step and a third of a
+1024-key one, and at few query rows one mostly dead wide step costs a short
+walk more than its one or two narrow ones. A context that is a constant of
+the program bounds the walk statically, and a walk that cannot be long is
+built narrow alone. :func:`decode_tile_counts` and
+:func:`prefill_tile_counts` say on the host, from the same tile sizes and
+the same rule (:func:`prefill_kv_pages`), how many steps a call takes and
+how many hold context.
 
 Quantized KV mode (``inference.kv_quant``, docs/serving.md "Quantized KV
 cache"): ``k_pool``/``v_pool`` hold int8 codes and ``k_scale``/``v_scale``
@@ -199,6 +209,9 @@ _Q_ROWS = 1024      # query rows (GQA group x tokens) of one tile at hd <= 128
 _KV_TOKENS = 256    # KV tokens of one grid step: the matmul N, the softmax lanes
 _MAX_PAGES = 8      # pool pages gathered into one KV tile (operands per pool)
 _TILE_VMEM = 8 << 20    # a decode step's double-buffered KV tiles and scores
+_WIDE_KV_TOKENS = 1024  # KV tokens of one grid step of a LONG multi-token walk
+_WIDE_WALK_TILES = 2    # ... long: its bound reaches this many wide tiles
+_WIDE_VMEM = 12 << 20   # what a wide step may keep of Mosaic's 16 MiB of VMEM
 
 
 def _prefill_tiles(t: int, g: int, hd: int, bs: int,
@@ -214,6 +227,44 @@ def _prefill_tiles(t: int, g: int, hd: int, bs: int,
     tq = -(-(-(-t // n_qt)) // 16) * 16     # balanced, sublane-aligned
     pages = max(1, min(_MAX_PAGES, _KV_TOKENS // bs, max_blocks))
     return tq, n_qt, pages
+
+
+def _wide_pages(rows: int, hd: int, bs: int, max_blocks: int, narrow: int,
+                itemsize: int, quant: bool, pools: int = 2) -> int:
+    """Pages of the KV tile a LONG multi-token walk takes, ``narrow`` (the
+    ~256 tokens of :func:`_prefill_tiles`) where there is no wider one. A
+    grid step's FIXED work does not depend on its tile's width - the flash
+    rescale of the ``[rows, 128]`` m / l scratch and the ``[rows, hd]`` f32
+    accumulator, the pages' DMA issue - and at 256 keys it is most of a live
+    step (PERF.md section 6, PRs 38 and 48), so a walk of
+    ``_WIDE_WALK_TILES`` wide tiles or more takes up to ``_WIDE_KV_TOKENS``
+    keys a step: the widest doubling of ``narrow`` whose step fits
+    ``_WIDE_VMEM`` - the K and V (or one latent) tiles double-buffered, the
+    ``[rows, KV]`` f32 scores and probabilities, the q and output blocks
+    double-buffered and the m / l / accumulator scratch - and of which the
+    table holds a long walk. A table that holds none keeps the narrow tile
+    alone (and the program it had), and so do int8 pools: a layer's f32
+    scale pools reach each kernel lane-padded (PERF.md section 7), and a
+    second walk would keep a second padded copy of both. From shapes alone:
+    WHICH tile a call takes is the program's to decide, from its walk's
+    bound (:func:`_takes_wide`)."""
+    def step(kv):
+        return (2 * pools * kv * hd * itemsize + 2 * rows * kv * 4
+                + 4 * rows * hd * 2 + rows * (256 + hd) * 4)
+
+    pages = narrow
+    while not quant and 2 * pages * bs <= _WIDE_KV_TOKENS \
+            and _WIDE_WALK_TILES * 2 * pages <= max_blocks \
+            and step(2 * pages * bs) <= _WIDE_VMEM:
+        pages *= 2
+    return pages
+
+
+def _takes_wide(bound, wide: int, bs: int):
+    """Whether a multi-token walk whose longest sequence's last real row sits
+    at ``bound`` (``max(context_lens + lengths)``: the program's value, or
+    the host's integer) takes the wide tile of ``wide`` pages."""
+    return bound >= _WIDE_WALK_TILES * wide * bs
 
 
 def _group_rows(g: int) -> int:
@@ -259,17 +310,37 @@ def decode_tile_counts(context_lens, nh: int, pool_shape, itemsize: int,
             int(tiles.max()) * tiles.size * (nkv // heads))
 
 
+def prefill_kv_pages(context_lens, lengths, t: int, nh: int, pool_shape,
+                     max_blocks: int, itemsize: int = 2, quant: bool = False,
+                     pools: int = 2) -> int:
+    """Pages of the KV tile ONE ``paged_prefill`` call takes (host integers,
+    the rule of the program: the wide tile where the longest sequence's last
+    real row reaches :func:`_takes_wide`'s bound). Times the pool's block
+    size it is the ``chunk_attn_kv_tile`` of a chunk's span."""
+    nkv, bs, hd = pool_shape[-3:]
+    tq, _, narrow = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
+    wide = _wide_pages(nh // nkv * tq, hd, bs, max_blocks, narrow, itemsize,
+                       quant, pools)
+    bound = int((np.asarray(context_lens) + np.asarray(lengths)).max())
+    return wide if wide > narrow and _takes_wide(bound, wide, bs) else narrow
+
+
 def prefill_tile_counts(context_lens, lengths, t: int, nh: int, pool_shape,
-                        max_blocks: int, window=None) -> Tuple[int, int, int]:
+                        max_blocks: int, window=None, itemsize: int = 2,
+                        quant: bool = False,
+                        pools: int = 2) -> Tuple[int, int, int]:
     """(live, taken, table-wide) grid steps of ONE ``paged_prefill`` call of
     ``t`` rows a sequence, ``lengths`` of them real, at ``context_lens``
-    (host integers; ``window``: the layer's, an int or None): the steps whose
-    KV tile a real row of their query tile attends, the steps the grid takes
-    - every (sequence, KV head, query tile) walks as far as the longest
-    sequence's last real row - and the steps of a grid as wide as the table.
-    What the serving engine puts on a chunk's span."""
+    (host integers; ``window``: the layer's, an int or None), at the KV tile
+    the call takes (:func:`prefill_kv_pages`): the steps whose KV tile a
+    real row of their query tile attends, the steps the grid takes - every
+    (sequence, KV head, query tile) walks as far as the longest sequence's
+    last real row - and the steps of a grid as wide as the table. What the
+    serving engine puts on a chunk's span."""
     nkv, bs, hd = pool_shape[-3:]
-    tq, n_qt, pages = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
+    tq, n_qt, _ = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
+    pages = prefill_kv_pages(context_lens, lengths, t, nh, pool_shape,
+                             max_blocks, itemsize, quant, pools)
     kv, n_kv = pages * bs, -(-max_blocks // pages)
     ctx = np.asarray(context_lens)[:, None, None]
     n = np.asarray(lengths)[:, None, None]
@@ -346,6 +417,32 @@ def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant,
                   o_ref, l_scr, acc_scr)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("tq", "bs", "pages", "max_blocks"))
+def _live_page(j, p, qi, ctx, n, window, *, tq, bs, pages, max_blocks):
+    """The table entry page ``p`` of KV tile ``j`` reads, for query tile
+    ``qi`` of a sequence at ``ctx`` with ``n`` real rows: the tile's live
+    pages are ``[lo_pg, hi_pg]`` - up to its last real row, from its first
+    row's window on - and every other (tile, page) folds onto the nearest of
+    them. Jitted, and in ``lax`` primitives: a walk has one index map a page
+    of its KV tile (64 at a wide tile), each traced and lowered on its own
+    and evaluated on the scalar core every grid step; under one ``jit`` they
+    share ONE trace (an index map is then a few reads and this call: set-up
+    seconds a program otherwise), and ``jnp.clip`` or ``//`` would be a dozen
+    scalar operations each where ``min`` / ``max`` / ``div`` are one (the
+    operands are never negative where the result is used)."""
+    add, mul, div = jax.lax.add, jax.lax.mul, jax.lax.div
+    lo, hi = jax.lax.max, jax.lax.min
+    q_lo = mul(qi, tq)
+    last = add(add(ctx, hi(add(q_lo, tq), n)), -1)
+    hi_pg = hi(lo(div(last, bs), 0), max_blocks - 1)
+    lo_pg = 0 if window is None else hi(
+        div(lo(add(add(ctx, q_lo), add(1, -window)), 0), bs), hi_pg)
+    j_eff = hi(lo(j, 0 if window is None else div(lo_pg, pages)),
+               div(hi_pg, pages))
+    return hi(lo(add(mul(j_eff, pages), p), lo_pg), hi_pg)
+
+
 def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
                 layer, window, k_scale, v_scale, *, scale, rows, tq, pages,
                 heads, n_kv, vd=None):
@@ -386,17 +483,11 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
 
     def page_map(p):
         def kvmap(b, h, qi, j, tables, ctx, lens, layer, *rest):
-            # the tile's live pages [lo_pg, hi_pg]; every other (tile, page)
-            # folds onto the nearest of them
-            last = ctx[b] + jnp.minimum(qi * tq + tq, lens[b]) - 1
-            hi_pg = jnp.clip(last // bs, 0, max_blocks - 1)
-            lo_pg = (jnp.minimum(jnp.maximum(
-                ctx[b] + qi * tq - rest[0][0] + 1, 0) // bs, hi_pg)
-                if rest else 0)
-            j_eff = jnp.clip(j, lo_pg // pages, hi_pg // pages)
-            pg = jnp.clip(j_eff * pages + p, lo_pg, hi_pg)
-            return (layer[0], jnp.clip(tables[b, pg], 0, nblocks - 1), h,
-                    0, 0)
+            pg = _live_page(j, p, qi, ctx[b], lens[b],
+                            rest[0][0] if rest else None, tq=tq, bs=bs,
+                            pages=pages, max_blocks=max_blocks)
+            return (layer[0], jax.lax.min(jax.lax.max(tables[b, pg], 0),
+                                          nblocks - 1), h, 0, 0)
         return kvmap
 
     # scale tiles ride the same maps as their code tiles, so a dead step
@@ -591,25 +682,42 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     qg = jnp.pad(q, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
     qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
         .reshape(B, nkv, n_qt * rows, hd)
-    # the walk ends with the last tile any sequence's real rows reach (at
-    # least one step: a call of dummies alone still initialises and writes)
-    n_live = jnp.clip(
-        -(-jnp.max(context_lens + lengths) // (pages * bs)), 1,
-        -(-max_blocks // pages))
-    kernel, grid_spec, args = _table_walk(
-        qg, k_pool, v_pool, block_tables, context_lens, lengths, layer,
-        window, k_scale, v_scale,
-        scale=hd ** -0.5 if scale is None else scale,
-        rows=rows, tq=tq, pages=pages, heads=None,
-        n_kv=n_live.astype(jnp.int32), vd=value_width)
     od = value_width or hd
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), q.dtype),
-        compiler_params=_WALK_GRID,
-        interpret=_interpret(),
-        name="paged_prefill",
-    )(*args)
+    bound = jnp.max(context_lens + lengths)
+
+    def walk(pages):
+        # the walk ends with the last tile any sequence's real rows reach (at
+        # least one step: a call of dummies alone still initialises and
+        # writes)
+        n_live = jnp.clip(-(-bound // (pages * bs)), 1,
+                          -(-max_blocks // pages))
+        kernel, grid_spec, args = _table_walk(
+            qg, k_pool, v_pool, block_tables, context_lens, lengths, layer,
+            window, k_scale, v_scale,
+            scale=hd ** -0.5 if scale is None else scale,
+            rows=rows, tq=tq, pages=pages, heads=None,
+            n_kv=n_live.astype(jnp.int32), vd=value_width)
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), q.dtype),
+            compiler_params=_WALK_GRID,
+            interpret=_interpret(),
+            name="paged_prefill",
+        )(*args)
+
+    # one walk, two tile widths: which a call takes is its own bound's to
+    # say (a short walk's one or two narrow steps cost less than a wide one).
+    # Under a context that is a constant of the program (a one-shot
+    # prefill's zeros) the most the bound can be is one too, and a walk that
+    # cannot be long has no wide form to trace, lower and compile
+    wide = _wide_pages(rows, hd, bs, max_blocks, pages,
+                       k_pool.dtype.itemsize, k_scale is not None,
+                       1 if v_pool is None else 2)
+    reach = int(context_lens.max()) + t \
+        if isinstance(context_lens, np.ndarray) else max_blocks * bs
+    out = walk(pages) if wide == pages or not _takes_wide(reach, wide, bs) \
+        else jax.lax.cond(_takes_wide(bound, wide, bs), lambda: walk(wide),
+                          lambda: walk(pages))
     return out.reshape(B, nkv, n_qt, g, tq, od).transpose(0, 2, 4, 1, 3, 5) \
         .reshape(B, n_qt * tq, nh, od)[:, :t]
 

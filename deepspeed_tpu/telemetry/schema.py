@@ -335,7 +335,9 @@ TRACER_SPANS = frozenset((
     # one engine dispatch and its host phases (engine_v2). A chunk's
     # multi-token walk rides ``decode_step`` and ``prefill_chunk`` as
     # ``chunk_attn_tiles_{live, grid, table}``, beside the decode walk's
-    # ``attn_tiles_{live, grid}`` (plain numbers; docs/observability.md)
+    # ``attn_tiles_{live, grid}``, and the KV tokens a grid step of that
+    # walk took as ``chunk_attn_kv_tile`` (256, or the wide tile of a long
+    # walk; plain numbers; docs/observability.md)
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

@@ -377,8 +377,12 @@ def test_the_pools_stay_where_they_are(v5e, cell, program, pool):
         assert mem.temp_size_in_bytes < padded + layer_pool
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
-    attn = "paged_prefill" if program == "chunk" else "paged_decode"
-    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
+    # a chunk's walk over bf16 pools is two Mosaic calls under one ``cond``
+    # (ISSUE 48: the narrow KV tile and the wide one a long walk takes);
+    # int8 pools keep the one narrow walk, and ONE padded copy of the scales
+    attn, walks = ("paged_prefill", 2 if pool == "bf16" else 1) \
+        if program == "chunk" else ("paged_decode", 1)
+    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == walks
 
 
 def test_pool_copy_bytes_counts_what_the_scanned_pools_cost(v5e):
@@ -491,8 +495,10 @@ def test_the_state_pool_stays_where_it_is(v5e, program):
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     assert calls.count("ssm_decode_update") == (2 if program == "decode"
                                                 else 0)
-    attn = "paged_decode" if program == "decode" else "paged_prefill"
-    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
+    # (a chunk's walk: the narrow tile's and the wide one's, ISSUE 48)
+    attn, walks = ("paged_decode", 1) if program == "decode" \
+        else ("paged_prefill", 2)
+    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == walks
 
 
 # --- a step's chunk rides in its decode program (ISSUE 32) ----------------- #
@@ -639,7 +645,7 @@ def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     for name, count in (("paged_kv_write", 4), ("paged_decode", 2),
-                        ("paged_prefill", 2), ("moe_grouped_matmul", 2)):
+                        ("paged_prefill", 4), ("moe_grouped_matmul", 2)):
         assert calls.count(name) == count, (name, calls.count(name))
 
 
@@ -649,7 +655,8 @@ def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
     configurations, compiled for the chip: the pools stay where they are
     (no pool-shaped copy, every pool - Granite's state pool too - aliased
     argument-to-result), a layer body that attends is one ``paged_decode``,
-    one ``paged_prefill`` and a ``paged_kv_write`` a segment, a Mamba layer
+    one ``paged_prefill`` a KV tile its walk may take (two under one
+    ``cond``, ISSUE 48) and a ``paged_kv_write`` a segment, a Mamba layer
     body one ``ssm_decode_update`` beside the chunk's state rows, the whole
     program fits the chip, and each FFN / expert-bank weight meets ONE
     matmul a layer body: ``slots + 256`` rows wide, where the two programs
@@ -673,7 +680,7 @@ def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     assert (calls.count("paged_decode"), calls.count("paged_prefill"),
-            calls.count("paged_kv_write")) == (1, 1, 2)
+            calls.count("paged_kv_write")) == (1, 2, 2)    # narrow and wide
     assert calls.count("ssm_decode_update") == (
         2 if cell == GRANITE_CELL else 0)
     # every matmul against a weight (bf16; the blocked scan's own are f32)
@@ -790,7 +797,9 @@ def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(v5e, cell):
         + r".*?operand_layout_constraints=\{([^,]*),", text)
         for kernel in ("paged_prefill", "paged_decode", "paged_kv_write")}
     kinds = 2 if cell == COMMAND_A_CELL else 1
-    assert first["paged_prefill"] == ["s32[]"] * kinds == first["paged_decode"]
+    assert first["paged_decode"] == ["s32[]"] * kinds
+    # two walks a kind since ISSUE 48, the narrow tile's and the wide one's
+    assert first["paged_prefill"] == ["s32[]"] * 2 * kinds
     assert len(first["paged_kv_write"]) == 2 * kinds \
         and "s32[]" not in first["paged_kv_write"]      # a static grid's
     assert pool_copy_bytes(text, jax.tree.leaves(args[1])) == 0
@@ -847,10 +856,12 @@ def test_the_expert_bank_is_read_where_it_lies(v5e, cell, program):
 # it (a closure of ``models/mixtral.py``) left theirs alone. Re-taken on
 # ISSUE 45's finished tree, which means to change them: the chunk's
 # ``paged_prefill`` takes a traced grid bound (they were 4c1badd32e6be1bd and
-# adee81593bcafbca).
+# adee81593bcafbca), and again on ISSUE 48's, which means to change them too:
+# the chunk's walk is a ``cond`` over two tile widths (0185c835790f0de0 and
+# c8639994fc5fe43d).
 NO_BANK_PROGRAMS = {
-    "mistral-7b.serve-chat": "0185c835790f0de0",
-    GRANITE_CELL: "c8639994fc5fe43d",
+    "mistral-7b.serve-chat": "958bdb530c8e0656",
+    GRANITE_CELL: "a7f9637cb43eb148",
 }
 
 
@@ -900,6 +911,60 @@ def test_paged_kernels_compile_at_head_size_64(v5e, op, geometry):
     assert text.count(MOSAIC) >= 2
 
 
+# --- a long walk takes a wide KV tile (ISSUE 48) ---------------------------- #
+# query heads, KV heads, key width, value width (None: a V pool), block,
+# table width, pool blocks, window -> pages of the wide tile (a.x-k1's 2: the
+# VMEM budget leaves its 640-lane rows the 256 keys they had, and one walk)
+WIDE_WALKS = {
+    "command_a_full": (128, 8, 128, None, 32, 1024, 12544, None, 32),
+    "command_a_full_under_a_window": (128, 8, 128, None, 32, 1024, 12544,
+                                      4096, 32),
+    "command_a_window_kind": (128, 8, 128, None, 32, 145, 2321, 4096, 32),
+    "command_a_window_kind_no_window": (128, 8, 128, None, 32, 145, 2321,
+                                        None, 32),
+    "axk1_latent": (64, 1, 640, 512, 128, 256, 3152, None, 2),
+}
+
+
+@pytest.mark.parametrize("t", [512, 5])     # a chunk; a verify window (tq 16)
+@pytest.mark.parametrize("geometry", sorted(WIDE_WALKS))
+def test_the_wide_prefill_walk_lowers_at_the_long_context_cells(v5e,
+                                                                geometry, t):
+    """The multi-token walk at the two long-context cells' geometries -
+    command-a's group of 16 over both table widths, with and without a
+    window, and a.x-k1's one latent pool (keys 640 lanes, values the first
+    512, 128-token blocks) - compiles for the chip with BOTH walks in the
+    program, each a Mosaic call named ``paged_prefill`` with a traced grid
+    bound: the narrow tile's and the wide one's, which reads ``pages`` pool
+    pages a step (a.x-k1's has no wider tile in the budget: the one walk it
+    had)."""
+    import re
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    nh, nkv, hd, vd, bs, table, blocks, window, pages = WIDE_WALKS[geometry]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    narrow = pa._prefill_tiles(t, nh // nkv, hd, bs, table)[2]
+
+    def step(q, pool, *rest):
+        v_pool = None if vd else rest[0]
+        tables, ctx, n = rest[-3:]
+        return pa.paged_prefill_attention(
+            q, pool, v_pool, tables, ctx, n, layer=jnp.int32(1),
+            window=window, value_width=vd)
+
+    pool = ((4, blocks, nkv, bs, hd), bf)
+    text = _compile(step, ((1, t, nh, hd), bf), *((pool,) * (1 if vd else 2)),
+                    ((1, table), i32), ((1,), i32), ((1,), i32),
+                    device=v5e.devices[0]).as_text()
+    calls = re.findall(r"%paged_prefill(?:\.\d+)? = .*? custom-call\((.*?)\), "
+                       r"custom_call_target=\"" + MOSAIC, text)
+    # operands: the grid bound, 4 scalars (5 under a window), q, the pages
+    fixed = 6 + (window is not None)
+    assert sorted(c.count("%") - fixed for c in calls) \
+        == sorted(p * (1 if vd else 2) for p in {narrow, pages})
+
+
 # --- the ``t > 1`` programs are the parent's ------------------------------- #
 # b, t, query heads, KV heads, head size, block, pool blocks, table width,
 # int8 pools (scale groups), window
@@ -916,11 +981,14 @@ MULTI_TOKEN_PROGRAMS = {
 # program and the body ends on ``num_programs``; before it they were ISSUE
 # 29's, whose step first wrote through ``paged_kv_write``).
 # A PR that means to change the multi-token walk replaces these; one that does
-# not has changed it by accident.
+# not has changed it by accident. ISSUE 48 replaced the two whose tables hold
+# a long walk over bf16 pools (a ``cond`` over two tile widths; they were
+# 65274280e7fb1e5c and 54cb8a57fa9599f8): int8 pools and a 20-entry table
+# keep the one walk they had, to the letter.
 PARENT_HASHES = {
-    "mistral_chunk256_bf16": "65274280e7fb1e5c",
+    "mistral_chunk256_bf16": "d63d7041f4528c75",
     "mistral_prompt2816_int8": "adc273e57d65238f",
-    "olmoe_chunk256_window": "54cb8a57fa9599f8",
+    "olmoe_chunk256_window": "e89f3ddd0d3a8bda",
     "verify_t5_traced_window_int8_ng2": "a87eb11e2b12777f",
     "batched_prefill_mqa": "a5d8074491835204",
 }

@@ -538,12 +538,59 @@ def _prefill_walk(case):
     return walk, reference, ctx, lens
 
 
+def _walk_tile(case):
+    """Pages of the KV tile the case's walk takes, by hand: the wide tile
+    where the longest ``context + real rows`` reaches two of them and the
+    table holds them, else the 8 (256 keys) every walk took before ISSUE 48.
+    The wide tile is 32 pages (1 024 keys), and 16 where the VMEM budget
+    says so: these cases' pools are float32, and under command-a's 1 024
+    query rows a 1 024-key step of float32 pages is past it. int8 pools
+    have no wide tile."""
+    t, nh, nkv, table, ctx, lens, _, int8 = PREFILL_WALKS[case]
+    longest = max(c + n for c, n in zip(ctx, lens))
+    wide = 16 if nh // nkv * min(t, 64) == 1024 else 32
+    return wide if longest >= 2 * 32 * wide and table >= 2 * wide \
+        and not int8 else 8
+
+
 def _walk_bound(case):
     """KV tiles the case's grid must take: the longest ``context + real
-    rows`` in 256-token tiles, at least one, at most the table's."""
+    rows`` in tiles of the walk's own width, at least one, at most the
+    table's."""
     t, nh, nkv, table, ctx, lens, *_ = PREFILL_WALKS[case]
     longest = max(c + n for c, n in zip(ctx, lens))
-    return min(max(-(-longest // 256), 1), -(-table // 8))
+    pages = _walk_tile(case)
+    return min(max(-(-longest // (32 * pages)), 1), -(-table // pages))
+
+
+def _taken_walk(walk, ctx, lens, pools=2):
+    """``(the pallas_call equation, its grid bound's value, pages a KV tile,
+    whether the program chose between two)`` of the walk the program TAKES
+    at these operands: where the call is a ``cond`` over two walks, the
+    branch its own predicate picks, read out of the jaxpr. ``pools``: the
+    pools a step reads a page of (int8 pools' scale pools too)."""
+    from jax.extend import core as jex_core
+
+    def upto(jaxpr, consts, at, outvars, *args):
+        head = jex_core.Jaxpr(jaxpr.constvars, jaxpr.invars, outvars,
+                              jaxpr.eqns[:at], debug_info=jaxpr.debug_info)
+        return jax.core.eval_jaxpr(head, consts, *args)
+
+    closed = jax.make_jaxpr(walk)(ctx, lens)
+    jaxpr, consts, args = closed.jaxpr, closed.consts, (ctx, lens)
+    (at, eqn), = [(i, e) for i, e in enumerate(jaxpr.eqns)
+                  if e.primitive.name in ("pallas_call", "cond")]
+    chose = eqn.primitive.name == "cond"
+    if chose:
+        index, *args = upto(jaxpr, consts, at, list(eqn.invars), *args)
+        assert len(eqn.params["branches"]) == 2
+        branch = eqn.params["branches"][int(index)]
+        jaxpr, consts = branch.jaxpr, branch.consts
+        (at, eqn), = [(i, e) for i, e in enumerate(jaxpr.eqns)
+                      if e.primitive.name == "pallas_call"]
+    (n_live,) = upto(jaxpr, consts, at, [eqn.invars[0]], *args)
+    pages = (eqn.params["grid_mapping"].num_inputs - 1) // pools   # less q
+    return eqn, n_live, pages, chose
 
 
 @pytest.mark.parametrize("case", sorted(PREFILL_WALKS))
@@ -589,31 +636,261 @@ def test_prefill_grids_last_dimension_is_traced(case):
     """The ``pallas_call``'s last grid dimension is a value of the program,
     computed from the call's own ``context_lens`` and ``lengths`` (no new
     argument, one compilation for every context), and it is the tiles of the
-    longest context; ``prefill_tile_counts`` says the same on the host."""
-    from jax.extend import core as jex_core
-
+    longest context - at the KV tile the program takes for that context
+    (ISSUE 48: 1 024 keys from two such tiles on, chosen by a ``cond`` on
+    the same bound); ``prefill_tile_counts`` says the same on the host."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     walk, _, ctx, lens = _prefill_walk(case)
-    jaxpr = jax.make_jaxpr(walk)(ctx, lens)
-    (at, call), = [(i, e) for i, e in enumerate(jaxpr.jaxpr.eqns)
-                   if e.primitive.name == "pallas_call"]
+    t, nh, nkv, table, _, _, window, int8 = PREFILL_WALKS[case]
+    call, n_live, pages, chose = _taken_walk(walk, ctx, lens,
+                                             4 if int8 else 2)
+    assert pages == _walk_tile(case) and chose == (table >= 64 and not int8)
     mapping = call.params["grid_mapping"]
     assert mapping.num_dynamic_grid_bounds == 1
     assert all(isinstance(n, int) for n in mapping.grid[:3]) \
         and not isinstance(mapping.grid[3], int)
-    bound = jex_core.Jaxpr(jaxpr.jaxpr.constvars, jaxpr.jaxpr.invars,
-                           [call.invars[0]], jaxpr.jaxpr.eqns[:at],
-                           debug_info=jaxpr.jaxpr.debug_info)
-    (n_live,) = jax.core.eval_jaxpr(bound, jaxpr.consts, ctx, lens)
     assert n_live.dtype == jnp.int32 and int(n_live) == _walk_bound(case)
-    t, nh, nkv, table, _, _, window, _ = PREFILL_WALKS[case]
+    how = dict(itemsize=1 if int8 else 4, quant=int8)
     live, taken, wide = pa.prefill_tile_counts(
         np.asarray(ctx), np.asarray(lens), t, nh, (nkv, 32, 128), table,
-        4096 if window == "traced" else window)
+        4096 if window == "traced" else window, **how)
+    assert pa.prefill_kv_pages(np.asarray(ctx), np.asarray(lens), t, nh,
+                               (nkv, 32, 128), table, **how) == pages
     walks = math.prod(mapping.grid[:3])
     assert taken == walks * _walk_bound(case) \
-        and wide == walks * -(-table // 8) and 0 <= live <= taken <= wide
+        and wide == walks * -(-table // pages) and 0 <= live <= taken <= wide
+
+
+# --- a long walk takes a wide KV tile (ISSUE 48) ---------------------------- #
+# Small tiles (``_small_tiles``): blocks of 8 tokens, a narrow KV tile of 2
+# pages (16 keys), a wide one of 8 (64 keys), query tiles of 16 tokens at a
+# group of 2; the table is 24 blocks, three wide tiles, so a walk is LONG from
+# 128 keys on. t, contexts, real rows and what differs from 4 query / 2 KV
+# heads of 32 over bf16-free float32 pools.
+WIDE_WALKS = {
+    "plain": dict(ctx=[150], lens=[16]),
+    "three_query_tiles": dict(t=40, ctx=[140], lens=[40]),
+    "window_static": dict(ctx=[150], lens=[16], window=40),
+    "window_traced": dict(ctx=[150], lens=[16], window=40, traced=True),
+    "window_wider_than_a_wide_tile": dict(ctx=[170], lens=[16], window=100),
+    "padded_rows_and_zero_length_dummies": dict(ctx=[140, 0, 30, 0],
+                                                lens=[9, 0, 16, 0]),
+    "latent_pool": dict(ctx=[150], lens=[16], nh=4, nkv=1, value_width=16),
+    "int8_pools": dict(ctx=[150], lens=[16], ngroups=1),
+    "int8_two_groups_traced_window": dict(ctx=[150], lens=[16], ngroups=2,
+                                          window=40, traced=True),
+    "verify_window_t4": dict(t=4, ctx=[130, 10, 171], lens=[4, 4, 4]),
+    "on_a_wide_tile_edge": dict(ctx=[112], lens=[16]),      # ends at key 128
+    "one_key_past_the_edge": dict(ctx=[113], lens=[16]),
+    "mha_group_of_1": dict(ctx=[150], lens=[16], nh=2, nkv=2),
+}
+# the same shapes a key short of a long walk, and what decides it
+NARROW_WALKS = {
+    "one_key_short_of_two_wide_tiles": dict(ctx=[111], lens=[16]),
+    "real_rows_count_and_padded_rows_do_not": dict(ctx=[120], lens=[7]),
+    "every_sequence_short": dict(ctx=[100, 0, 60], lens=[16, 0, 16]),
+    "context_zero": dict(ctx=[0], lens=[16]),
+}
+LONGEST_DECIDES = {
+    "one_long_sequence_of_three": dict(ctx=[3, 112, 40], lens=[16, 16, 16]),
+}
+
+
+def _small_tiles(monkeypatch):
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_TOKENS", 16)
+    monkeypatch.setattr(pa, "_WIDE_KV_TOKENS", 64)
+    monkeypatch.setattr(pa, "_Q_ROWS", 32)
+
+
+def _tile_walk(c):
+    """``(walk(ctx, lens), reference(), ctx, lens)`` of one small case. Table
+    entries past a sequence's blocks (every entry of a zero-length dummy)
+    point at a poisoned block in the kernel's copy - NaN rows, or NaN scales
+    over int8 codes - and at the trash block in the reference's (``room``:
+    a context the sequences' blocks reach to, where the case's is shorter)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.quantization import kv_quantize_int8
+
+    c = dict(dict(t=16, nh=4, nkv=2, window=None, traced=False, ngroups=0,
+                  value_width=None, room=0), **c)
+    t, nh, nkv, bs, hd, mb, nb = c["t"], c["nh"], c["nkv"], 8, 32, 24, 64
+    rs = np.random.RandomState(7)
+    b, poison = len(c["ctx"]), nb - 1
+    q = jnp.asarray(rs.randn(b, t, nh, hd).astype(np.float32))
+    pools = [jnp.asarray(rs.randn(nb, nkv, bs, hd).astype(np.float32))
+             for _ in range(1 if c["value_width"] else 2)]
+    tables = np.zeros((b, mb), np.int32)
+    poisoned = np.full((b, mb), poison, np.int32)
+    for i, (x, n) in enumerate(zip(c["ctx"], c["lens"])):
+        need = -(-(max(x, c["room"]) + n) // bs) if n else 0
+        tables[i, :need] = poisoned[i, :need] = rs.randint(1, poison, need)
+    scales = bad_scales = []
+    if c["ngroups"]:
+        (pools[0], ks), (pools[1], vs) = (
+            kv_quantize_int8(p, hd // c["ngroups"]) for p in pools)
+        scales = [ks, vs]
+        bad, bad_scales = pools, [s.at[poison].set(jnp.nan) for s in scales]
+    else:
+        bad = [p.at[poison].set(jnp.nan) for p in pools]
+    if c["value_width"]:
+        pools, bad = pools + [None], bad + [None]
+    window = c["window"]
+    kw = {} if c["value_width"] is None else {"value_width": c["value_width"]}
+    ctx = jnp.asarray(c["ctx"], jnp.int32)
+    lens = jnp.asarray(c["lens"], jnp.int32)
+
+    def walk(ctx, lens, window=window):
+        return pa.paged_prefill_attention(
+            q, *bad, jnp.asarray(poisoned), ctx, lens, window=window, **kw,
+            **dict(zip(("k_scale", "v_scale"), bad_scales)))
+
+    def reference():
+        return pa.paged_prefill_attention_xla(
+            q, *pools, jnp.asarray(tables), ctx, lens, window=window, **kw,
+            **dict(zip(("k_scale", "v_scale"), scales)))
+
+    if c["traced"]:       # the window is a value of the program
+        traced = walk
+        walk = lambda ctx, lens: traced(ctx, lens, jnp.int32(window))  # noqa
+    return walk, reference, ctx, lens
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_WALKS))
+def test_long_walk_at_the_wide_tile_agrees_with_xla_and_the_narrow_tile(
+        case, monkeypatch):
+    """A walk whose bound reaches two wide tiles takes the wide one, and
+    every REAL row of its result is the XLA reference's and - to float32
+    rounding: the flash sum runs in another order - the narrow tile's:
+    plain, under a static and a traced window (narrower and wider than a
+    wide tile), padded rows beside zero-length dummies, a latent pool's
+    values out of its key page, the
+    verify window's four rows, a context that ends exactly on a wide tile's
+    edge and one key past it, a group of one. int8 pools - one and two scale
+    groups - have no wide tile: the same long walk keeps its one narrow walk
+    and agrees. No step reads a table entry past its sequence's blocks (they
+    are poisoned)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    _small_tiles(monkeypatch)
+    c = WIDE_WALKS[case]
+    walk, reference, ctx, lens = _tile_walk(c)
+    pools = 4 if c.get("ngroups") else 1 if c.get("value_width") else 2
+    _, n_live, pages, chose = _taken_walk(walk, ctx, lens, pools)
+    longest = int(np.max(np.asarray(ctx) + np.asarray(lens)))
+    wide = 2 if c.get("ngroups") else 8
+    assert chose == (wide == 8) and pages == wide \
+        and int(n_live) == -(-longest // (8 * wide))
+    out = np.asarray(jax.jit(walk)(ctx, lens))
+    assert np.isfinite(out).all()
+    want = np.asarray(reference())
+    monkeypatch.setattr(pa, "_wide_pages",
+                        lambda rows, hd, bs, mb, narrow, *a: narrow)
+    walk, *_ = _tile_walk(c)        # a new function: nothing traced is kept
+    _, n_narrow, pages, chose = _taken_walk(walk, ctx, lens, pools)
+    assert not chose and pages == 2 and int(n_narrow) == -(-longest // 16)
+    narrow = np.asarray(jax.jit(walk)(ctx, lens))
+    for b, n in enumerate(np.asarray(lens)):
+        np.testing.assert_allclose(out[b, :n], want[b, :n], rtol=2e-5,
+                                   atol=2e-5)
+        np.testing.assert_allclose(out[b, :n], narrow[b, :n], rtol=2e-5,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("case", sorted({**NARROW_WALKS, **LONGEST_DECIDES}))
+def test_the_program_takes_the_wide_tile_from_two_wide_tiles_on(case,
+                                                                monkeypatch):
+    """ONE program, two walks: the ``cond``'s own predicate - the bound the
+    grid already has, ``max(context_lens + lengths)`` against two wide tiles
+    - picks the 16-key tile under 128 keys and the 64-key one from there on,
+    the longest sequence of a batch deciding for all of it; the same jitted
+    program serves both sides of the threshold (one compilation) and agrees
+    with the reference on each; the host's mirror says which it took."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    _small_tiles(monkeypatch)
+    c = dict({**NARROW_WALKS, **LONGEST_DECIDES}[case], room=150)
+    walk, reference, ctx, lens = _tile_walk(c)
+    long = case in LONGEST_DECIDES
+    _, n_live, pages, chose = _taken_walk(walk, ctx, lens)
+    longest = int(np.max(np.asarray(ctx) + np.asarray(lens)))
+    assert chose and pages == (8 if long else 2) and (longest >= 128) == long
+    assert int(n_live) == max(-(-longest // (8 * pages)), 1)
+    assert pa.prefill_kv_pages(c["ctx"], c["lens"], 16, 4, (2, 8, 32), 24,
+                               itemsize=4) == pages
+    f = jax.jit(walk)
+    out, want = np.asarray(f(ctx, lens)), np.asarray(reference())
+    for b, n in enumerate(c["lens"]):
+        np.testing.assert_allclose(out[b, :n], want[b, :n], rtol=2e-5,
+                                   atol=2e-5)
+    # the other side of the threshold, through the same compiled program
+    other = jnp.where(jnp.arange(len(c["ctx"])) == np.argmax(c["ctx"]),
+                      30 if long else 150, ctx)
+    walk2, reference2, _, _ = _tile_walk(dict(c, ctx=np.asarray(other)
+                                              .tolist()))
+    assert _taken_walk(walk2, other, lens)[2] == (2 if long else 8)
+    out2, want2 = np.asarray(f(other, lens)), np.asarray(reference2())
+    assert f._cache_size() == 1
+    for b, n in enumerate(c["lens"]):
+        np.testing.assert_allclose(out2[b, :n], want2[b, :n], rtol=2e-5,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("ctx,builds", [(0, False), (111, False),
+                                        (112, True)])
+def test_a_constant_context_says_whether_there_is_a_wide_walk_to_build(
+        ctx, builds, monkeypatch):
+    """A context that is a CONSTANT of the program (numpy: a one-shot
+    prefill's zeros) bounds the walk statically - it plus the call's rows -
+    and a walk that cannot reach two wide tiles is the one narrow
+    ``pallas_call``, no ``cond`` and no second kernel to trace, lower and
+    compile; one that can keeps the program's choice. The result is the
+    reference's either way."""
+    _small_tiles(monkeypatch)
+    walk, reference, _, lens = _tile_walk(dict(ctx=[ctx], lens=[16],
+                                               room=150))
+    const = np.asarray([ctx], np.int32)
+    names = [e.primitive.name for e in jax.make_jaxpr(
+        lambda lens: walk(const, lens))(lens).jaxpr.eqns]
+    assert ("cond" in names) == builds \
+        and ("pallas_call" in names) == (not builds)
+    out = np.asarray(jax.jit(lambda lens: walk(const, lens))(lens))
+    np.testing.assert_allclose(out[0], np.asarray(reference())[0], rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_wide_tile_comes_from_the_shapes_and_leaves_the_query_tiles():
+    """``_wide_pages`` at the cells' geometries: 1 024 keys a step where
+    1 024 rows of head size 128 walk bf16 pools (command-a both table kinds,
+    chat, OLMoE), less where the budget says (a.x-k1's 640-lane latent rows
+    keep their 256, float32 pools under 1 024 rows get 512), the narrow tile
+    for int8 pools and where the table holds no long walk; the query tiles - which Keye's kernels size their scores by - are
+    what they were."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+    wide = pa._wide_pages
+    assert wide(1024, 128, 32, 1024, 8, 2, False) == 32     # command-a, full
+    assert wide(1024, 128, 32, 145, 8, 2, False) == 32      # ... window kind
+    assert wide(1024, 128, 32, 256, 8, 2, False) == 32      # chat, Mixtral
+    assert wide(256, 128, 32, 128, 8, 2, False) == 32       # OLMoE (group 1)
+    assert wide(64, 128, 32, 256, 8, 2, False) == 32        # a verify window
+    assert wide(1024, 128, 32, 31, 8, 2, False) == 8        # a 992-key table
+    assert wide(1024, 128, 32, 48, 8, 2, False) == 16       # 1 536 keys
+    assert wide(1024, 128, 512, 16, 1, 2, False) == 2       # 512-key pages
+    assert wide(1024, 128, 32, 3, 3, 2, False) == 3         # a short table
+    assert wide(1024, 640, 128, 256, 2, 2, False, 1) == 2   # a.x-k1's latent
+    assert wide(1024, 128, 32, 1024, 8, 4, False) == 16     # float32 pools
+    assert wide(1024, 128, 32, 256, 8, 1, True) == 8        # int8 pools
+    assert pa._prefill_tiles(512, 16, 128, 32, 1024)[:2] == (64, 8)
+    assert pa._prefill_tiles(512, 16, 128, 32, 145)[:2] == (64, 8)
+    assert pa._prefill_tiles(512, 64, 640, 128, 256) == (16, 32, 2)
+    assert pa._prefill_tiles(512, 8, 128, 32, 1024) == (128, 4, 8)   # Keye
+    assert sparse.prefill_rows(512, 32, 4, 128, 32, 1024) == 512
+    assert (pa._MAX_PAGES, pa._KV_TOKENS, sparse._PREFILL_PAGES) \
+        == (8, 256, 32)
 
 
 @pytest.mark.parametrize("backend", ["pallas", "xla"])

@@ -905,8 +905,24 @@ HAND_COUNTED_WALKS = {
     # a window of 20 behind position 70 starts in tile 1: tile 0 is dead at
     # the FRONT, and the grid still starts there
     "window_starts_in_tile_1": (([70], [16]), 16, 8, 20, (2, 3, 4)),
-    # a context that fills its table walks all of it
-    "context_fills_the_table": (([112], [16]), 16, 8, None, (4, 4, 4)),
+    # a key short of two wide (64-key) tiles: four narrow tiles, all of the
+    # table's
+    "a_key_short_of_two_wide_tiles": (([111], [16]), 16, 8, None, (4, 4, 4)),
+    # ISSUE 48, from 128 keys on a walk takes 64-key tiles: a context that
+    # fills its table walks all of it, in two steps
+    "context_fills_the_table": (([112], [16]), 16, 8, None, (2, 2, 2)),
+    "two_wide_tiles_of_the_tables_four": (([112], [16]), 16, 16, None,
+                                          (2, 2, 4)),
+    # the longest sequence decides for the batch: the short one and the
+    # dummy take its three wide steps, the short one's first is live
+    "the_longest_decides_for_the_batch": (([150, 10, 0], [16, 16, 0]), 16, 16,
+                                          None, (4, 9, 12)),
+    # a window of 20 behind position 200: wide tiles 0 and 1 are dead at the
+    # front, 2 and 3 live
+    "wide_tiles_under_a_window": (([200], [16]), 16, 16, 20, (2, 4, 4)),
+    # three query tiles at context 140 end at 156, 172 and 180: three wide
+    # tiles each
+    "wide_tiles_three_query_tiles": (([140], [40]), 40, 16, None, (9, 9, 12)),
 }
 
 
@@ -915,17 +931,22 @@ def test_prefill_tile_counts_are_the_hand_count(case, monkeypatch):
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     monkeypatch.setattr(pa, "_KV_TOKENS", 32)   # two 16-token pages a tile
+    monkeypatch.setattr(pa, "_WIDE_KV_TOKENS", 64)  # four, from 128 keys on
     monkeypatch.setattr(pa, "_Q_ROWS", 32)      # 16 tokens x a group of 2
     (ctx, lens), t, table, window, want = HAND_COUNTED_WALKS[case]
     nkv = 3
     assert pa.prefill_tile_counts(ctx, lens, t, 2 * nkv, (nkv, 16, 128),
                                   table, window) \
         == tuple(nkv * n for n in want)
+    longest = max(c + n for c, n in zip(ctx, lens))
+    assert pa.prefill_kv_pages(ctx, lens, t, 2 * nkv, (nkv, 16, 128), table) \
+        == (4 if longest >= 128 else 2)
 
 
 def _chunk_tile_spans(eng, prompt_tokens):
-    """The ``chunk_attn_tiles_*`` of every mixed ``decode_step`` and every
-    ``prefill_chunk`` while a split prompt enters beside a live stream."""
+    """The ``chunk_attn_tiles_*`` and ``chunk_attn_kv_tile`` of every mixed
+    ``decode_step`` and every ``prefill_chunk`` while a split prompt enters
+    beside a live stream."""
     rng = np.random.default_rng(1)
     vocab = eng.family.cfg.vocab_size
     eng.put(1, rng.integers(1, vocab, (9,)).tolist())
@@ -934,7 +955,8 @@ def _chunk_tile_spans(eng, prompt_tokens):
         eng.step()
     eng.step()
     spans = [e for e in eng.tracer.events() if e["ph"] == "X"]
-    keys = ["chunk_attn_tiles_" + k for k in ("live", "grid", "table")]
+    keys = ["chunk_attn_tiles_" + k for k in ("live", "grid", "table")] \
+        + ["chunk_attn_kv_tile"]
     mixed = [e["args"] for e in spans if e["name"] == "decode_step"
              and e["args"]["chunk_tokens"]]
     chunks = [e["args"] for e in spans if e["name"] == "prefill_chunk"]
@@ -963,9 +985,36 @@ def test_chunk_spans_say_how_far_the_prefill_walk_went(devices8,
     assert (cfg.num_kv_heads, eng.state.max_blocks_per_seq) == (2, 8)
     mixed, chunks = _chunk_tile_spans(eng, 60)
     # 2 KV heads x (1, 1, 2, 2 of the table's 4 tiles): the walk ends with
-    # the chunk's last row
-    assert mixed == chunks == [(0, 16, 2, 2, 8), (16, 16, 2, 2, 8),
-                               (32, 16, 4, 4, 8), (48, 12, 4, 4, 8)]
+    # the chunk's last row; no walk is long, every one at the 32-key tile
+    assert mixed == chunks == [(0, 16, 2, 2, 8, 32), (16, 16, 2, 2, 8, 32),
+                               (32, 16, 4, 4, 8, 32), (48, 12, 4, 4, 8, 32)]
+
+
+def test_chunk_spans_say_which_kv_tile_the_walk_took(devices8, monkeypatch):
+    """``chunk_attn_kv_tile`` on every mixed ``decode_step`` and every
+    ``prefill_chunk``: the KV tokens a grid step of the chunk's walk takes,
+    by the program's own rule (``paged_attention._takes_wide``, which
+    ``tests/test_pallas_kernels.py`` holds the program's ``cond`` to) - the
+    narrow tile until the chunk's last row reaches two wide tiles, the wide
+    one from there on - and the tile counts are counted at that tile."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_TOKENS", 16)       # one 16-token page
+    monkeypatch.setattr(pa, "_WIDE_KV_TOKENS", 32)  # two, from 64 keys on
+    cfg, eng = _serving_engine(trace=True, split=16)
+    nkv, table = cfg.num_kv_heads, eng.state.max_blocks_per_seq
+    assert (nkv, table) == (2, 8)
+    mixed, chunks = _chunk_tile_spans(eng, 100)
+    assert mixed == chunks and [c[:2] for c in chunks] == [
+        (16 * i, 16) for i in range(6)] + [(96, 4)]
+    assert [c[-1] for c in chunks] == [16, 16, 16, 32, 32, 32, 32]
+    for ctx, n, live, grid, wide, tile in chunks:
+        assert tile == 16 * pa.prefill_kv_pages(
+            [ctx], [n], 16, cfg.num_heads, eng.cache["k"].shape, table,
+            itemsize=4)
+        steps = -(-(ctx + n) // tile)       # one query tile, all of it live
+        assert (live, grid, wide) == (nkv * steps, nkv * steps,
+                                      nkv * 128 // tile)
 
 
 def test_chunk_spans_sum_both_kinds_walks_over_their_layers(monkeypatch):
@@ -996,19 +1045,21 @@ def test_chunk_spans_sum_both_kinds_walks_over_their_layers(monkeypatch):
     assert mixed == chunks and [c[:2] for c in chunks] == [
         (8 * i, 8) for i in range(6)] + [(48, 3)]
     table = eng.state.max_blocks_per_seq
-    for ctx, n, *got in chunks:
+    for ctx, n, *got, tile in chunks:
         given = max(0, ctx - 16 + 1) // 4 * 4   # tokens the kind gave back
         want = np.asarray(pa.prefill_tile_counts(
-            [ctx], [n], 8, cfg.num_heads, full, table)) \
+            [ctx], [n], 8, cfg.num_heads, full, table, itemsize=4)) \
             + 3 * np.asarray(pa.prefill_tile_counts(
-                [ctx - given], [n], 8, cfg.num_heads, window, 7, 16))
+                [ctx - given], [n], 8, cfg.num_heads, window, 7, 16,
+                itemsize=4))
         assert got == want.tolist()
+        assert tile == 8    # neither kind's walk is long: the widest is narrow
     # by hand, the last chunk (context 48, 3 real rows), 2 KV heads: the
     # full layer holds 7 live tiles of 8 tokens, takes 7 and its table has
     # max_blocks / 2; a window layer, 8 blocks given back, sits at context
     # 16 of a 7-block table: tiles 0-2 live, 3 taken, 4 table-wide
-    assert chunks[-1][2:] == (2 * (7 + 3 * 3), 2 * (7 + 3 * 3),
-                              2 * (-(-table // 2) + 3 * 4))
+    assert chunks[-1][2:5] == (2 * (7 + 3 * 3), 2 * (7 + 3 * 3),
+                               2 * (-(-table // 2) + 3 * 4))
 
 
 def _kernel_cases():
