@@ -928,10 +928,12 @@ class InferenceEngineV2(InferenceEngine):
 
     def _attn_tile_args(self) -> Dict[str, float]:
         """Span arguments of a decode dispatch over the slots as they stand:
-        of ONE layer's ``paged_decode`` call, the grid's KV tiles that hold
-        live context, the tiles the grid visits, and their ratio (the
-        kernel's own tile sizes: ``ops/pallas/paged_attention.py``). None
-        for a family whose paged cache is not ``init_paged_pools``'."""
+        of ONE layer's ``paged_decode`` call, the KV tiles that hold live
+        context, the tiles the walk takes - the same tiles where it fetches
+        its own pages, every slot as far as the longest where it is a grid
+        of ``BlockSpec`` pages - and their ratio (the kernel's own tile
+        sizes: ``ops/pallas/paged_attention.py``). None for a family whose
+        paged cache is not ``init_paged_pools``'."""
         from ..ops.pallas.paged_attention import decode_tile_counts
 
         pool = self._walked_pool()

@@ -15,8 +15,9 @@ Reference parity: the inference v2 ragged kernels
 ``BlockedKVCache``, ``inference/v2/ragged/kv_cache.py``). Round-1 shipped a
 gather-based XLA path (``models/llama.py apply_paged``) that materializes a
 dense [B, max_blocks*bs, ...] KV view per layer; the kernels read KV blocks
-straight out of the shared pool via block-table-indexed ``BlockSpec``s
-(scalar-prefetch), online-softmax accumulating — no dense copy, HBM traffic
+straight out of the shared pool via the block table (scalar-prefetch:
+page DMAs the decode walk issues itself, table-indexed ``BlockSpec``s in the
+multi-token walk), online-softmax accumulating — no dense copy, HBM traffic
 = exactly the live context. The gathered expressions survive as the ops' XLA
 references (``*_xla``), which the registry resolves to off a TPU.
 
@@ -31,18 +32,35 @@ Decode layout: one query token per sequence.
   context_lens [B] int32 — tokens ALREADY cached; the current token's K/V
                must be written to the pool before calling (so the effective
                length is context_lens + 1).
-Grid: (B, KV-head blocks, 1, KV tiles), KV tiles innermost/sequential. A grid
-step takes every KV head of a sequence (a divisor of ``nkv`` where they do not
-fit: :func:`_decode_tiles`) and a KV tile of several pages (~256 tokens); the
-scores are one head-batched ``[nkv, gpad, hd] x [nkv, kv, hd]`` contraction,
-the GQA query group (g = nh/nkv rows, sublane-padded) on the MXU sublanes.
-The last grid dimension is DYNAMIC: the tiles of the longest context in the
-batch, not the table's width, so a step costs what its context costs. Within
-it a shorter sequence's dead steps fold onto its last live page (no DMA, no
-compute). ``paged_prefill`` is the same kernel body (:func:`_paged_kernel`)
-at ``tq`` query tokens a tile and one KV head a step, on a grid
-``(B, KV heads, query tiles, KV tiles)`` whose last dimension is dynamic
-too: the tiles up to the longest sequence's last REAL row, ``clip(ceil(
+Walk: grid (B, KV-head blocks), both sequential; a grid step is one
+sequence's whole walk over one block of KV heads (every KV head where they
+fit, a divisor of ``nkv`` where not: :func:`_decode_tiles`). The pools reach
+the kernel where they lie (``memory_space=pl.ANY``) and the walk FETCHES ITS
+OWN PAGES (:func:`_decode_kernel`): an in-kernel loop with a dynamic trip
+count runs from the sequence's first live page (its window's; page 0 without
+one) to the page of ``context_lens[b]`` and no further, a KV tile of several
+pages (up to ``_DECODE_KV_TOKENS`` tokens) an iteration, and each page of a
+tile is one DMA a pool - ``pool[layer, tables[b, pg], head block]``, read
+from the table in SMEM, into the page's ``bs`` rows of a double-buffered
+``[2, heads, tile tokens, hd]`` VMEM scratch, which is the layout the matmul
+wants (no join in VMEM). The next tile is in flight while this one is
+computed, across sequences too: a walk's last iteration starts the first
+tile of the next grid step, and which half of the scratch holds it is
+carried in SMEM. No step is dead: a slot at context 0 takes the one tile that
+initialises and writes its row, pages past a sequence's end are never
+fetched, and table entries there are never read. The scores are one
+head-batched ``[nkv, gpad, hd] x [nkv, kv, hd]`` contraction, the GQA query
+group (g = nh/nkv rows, sublane-padded) on the MXU sublanes. Mosaic slices a
+page out of a pool for a DMA only where the pool's rows are whole 128-lane
+tiles (:func:`_fetches_pages`): int8 pools (their ``[.., bs, ngroups]`` f32
+scales) and plain pools of heads narrower than a tile keep the multi-token
+walk below at one token a sequence and every KV head a step - a grid
+``(B, KV-head blocks, 1, KV tiles)`` as long as the longest context.
+``paged_prefill`` shares the flash body (:func:`_flash_update`) at ``tq``
+query tokens a tile and one KV head a step (:func:`_paged_kernel`), on a grid
+``(B, KV heads, query tiles, KV tiles)`` of table-indexed ``BlockSpec``
+pages, joined in VMEM, whose last dimension is DYNAMIC: the tiles up to the
+longest sequence's last REAL row, ``clip(ceil(
 max(context_lens + lengths) / KV tile), 1, table tiles)``, computed in the
 program from the call's own operands. The bound cuts the END of the walk
 alone: inside it an early query tile's steps above its last row, a window's
@@ -199,16 +217,18 @@ def _latent_values(keys, value_width):
 
 
 # --------------------------------------------------------------------------- #
-# the one flash walk over the block table. Both ops are this kernel:
-# ``paged_prefill`` at ``tq`` query tokens a tile and one KV head a grid
-# step, ``paged_decode`` at one query token and every KV head of a sequence
-# a grid step. Tile sizes come from the shapes alone (:func:`_prefill_tiles`,
-# :func:`_decode_tiles`).
+# the flash walks over the block table: ``paged_prefill`` at ``tq`` query
+# tokens a tile and one KV head a grid step over ``BlockSpec`` pages
+# (:func:`_table_walk`), ``paged_decode`` at one query token and every KV
+# head of a sequence a grid step over pages it fetches itself
+# (:func:`_decode_kernel`). Tile sizes come from the shapes alone
+# (:func:`_prefill_tiles`, :func:`_decode_tiles`).
 # --------------------------------------------------------------------------- #
 _Q_ROWS = 1024      # query rows (GQA group x tokens) of one tile at hd <= 128
 _KV_TOKENS = 256    # KV tokens of one grid step: the matmul N, the softmax lanes
 _MAX_PAGES = 8      # pool pages gathered into one KV tile (operands per pool)
 _TILE_VMEM = 8 << 20    # a decode step's double-buffered KV tiles and scores
+_DECODE_KV_TOKENS = 1024    # KV tokens of one tile of the decode walk, at most
 _WIDE_KV_TOKENS = 1024  # KV tokens of one grid step of a LONG multi-token walk
 _WIDE_WALK_TILES = 2    # ... long: its bound reaches this many wide tiles
 _WIDE_VMEM = 12 << 20   # what a wide step may keep of Mosaic's 16 MiB of VMEM
@@ -272,19 +292,36 @@ def _group_rows(g: int) -> int:
     return max(8, 1 << (g - 1).bit_length())
 
 
+def _fetches_pages(hd: int, quant: bool) -> bool:
+    """Whether the decode walk fetches its pools' pages itself. Mosaic
+    slices a page out of a pool for a DMA only where the pool's rows are
+    whole 128-lane tiles: not out of an int8 pool's ``[.., bs, ngroups]``
+    f32 scales, nor out of plain pools of heads narrower than a tile (both
+    reach a kernel with their rows padded to 128 lanes). Those keep the
+    grid of ``BlockSpec`` pages."""
+    return not quant and hd % 128 == 0
+
+
 def _decode_tiles(nkv: int, g: int, hd: int, bs: int, max_blocks: int,
                   itemsize: int, quant: bool,
                   pools: int = 2) -> Tuple[int, int, int]:
-    """(pages a KV tile, KV heads a grid step, KV tiles the table holds).
-    One page of every KV head is one contiguous block of the pool, so a
-    grid step takes them all, and as many pages as make ~256 tokens - as
-    long as what the step keeps in VMEM for each (head, page) fits
-    ``_TILE_VMEM``: the K and V tiles, double-buffered (an int8 page's f32
-    scale tile pads its group lanes to 128), and the group's f32 scores and
-    probabilities. Many or wide KV heads get a head block that divides
-    ``nkv``; only a single head over the budget gets fewer pages.
-    ``pools``: the pools a step reads a page of (1: a latent pool, whose
-    values are lanes of its keys' page)."""
+    """(pages a KV tile, KV heads a grid step, KV tiles the table holds) of
+    the decode walk. One page of every KV head is one contiguous block of
+    the pool, so a step takes them all, at as many pages as make ~256
+    tokens - as long as what the walk keeps in VMEM for each (head, page)
+    fits ``_TILE_VMEM``: the K and V rows of its two tiles (the one computed
+    and the one in flight; an int8 page's f32 scale rows pad their group
+    lanes to 128) and the group's f32 scores and probabilities. Many or wide
+    KV heads get a head block that divides ``nkv``; only a single head over
+    the budget gets fewer pages. Where the walk fetches its own pages
+    (:func:`_fetches_pages`) the tile then WIDENS, by doubling, to up to
+    ``_DECODE_KV_TOKENS`` where the same budget and the table hold it: a
+    tile's fixed work (the loop's scalar side, the flash rescale, the
+    matmuls' fill and drain) does not depend on its width, and at 64 query
+    rows of 640 lanes it is a third of a 256-token tile (PERF.md section 6,
+    PR 49). ``pools``: the
+    pools a step reads a page of (1: a latent pool, whose values are lanes
+    of its keys' page)."""
     pages = max(1, min(_MAX_PAGES, _KV_TOKENS // bs, max_blocks))
     page = 2 * pools * bs * (hd * itemsize + (128 * 4 if quant else 0)) \
         + 2 * _group_rows(g) * bs * 4
@@ -292,6 +329,9 @@ def _decode_tiles(nkv: int, g: int, hd: int, bs: int, max_blocks: int,
     heads = max([h for h in range(1, nkv + 1)
                  if nkv % h == 0 and h * pages <= room], default=1)
     pages = max(1, min(pages, room // heads))
+    while _fetches_pages(hd, quant) and 2 * pages * bs <= _DECODE_KV_TOKENS \
+            and 2 * pages <= max_blocks and 2 * pages * heads <= room:
+        pages *= 2
     return pages, heads, -(-max_blocks // pages)
 
 
@@ -299,15 +339,20 @@ def decode_tile_counts(context_lens, nh: int, pool_shape, itemsize: int,
                        max_blocks: int, quant: bool,
                        pools: int = 2) -> Tuple[int, int]:
     """(live, visited) KV tiles of ONE ``paged_decode`` call over slots at
-    ``context_lens`` (host integers): the grid steps that hold context and
-    the steps the grid takes - every slot walks as far as the longest. What
-    the serving engine puts on its ``decode_step`` span."""
+    ``context_lens`` (host integers, no window): the tiles that hold context
+    and the tiles the walk takes. Each slot walks to its own end, so they
+    are the same tiles - a slot at context 0 its one - but where the walk
+    is the grid of ``BlockSpec`` pages (:func:`_fetches_pages`), which takes
+    every slot as far as the longest. What the serving engine puts on its
+    ``decode_step`` span."""
     nkv, bs, hd = pool_shape[-3:]
     pages, heads, n_kv = _decode_tiles(nkv, nh // nkv, hd, bs, max_blocks,
                                        itemsize, quant, pools)
     tiles = np.minimum(np.asarray(context_lens) // (pages * bs) + 1, n_kv)
-    return (int(tiles.sum()) * (nkv // heads),
-            int(tiles.max()) * tiles.size * (nkv // heads))
+    live = int(tiles.sum())
+    return (live * (nkv // heads),
+            (live if _fetches_pages(hd, quant)
+             else int(tiles.max()) * tiles.size) * (nkv // heads))
 
 
 def prefill_kv_pages(context_lens, lengths, t: int, nh: int, pool_shape,
@@ -521,6 +566,106 @@ def _table_walk(qg, k_pool, v_pool, block_tables, context_lens, lengths,
 _WALK_GRID = _dim_semantics("parallel", "parallel", "parallel", "arbitrary")
 
 
+def _decode_kernel(*refs, bs, pages, heads, scale, max_blocks, nblocks,
+                   has_window, vd, layered):
+    """``paged_decode``: grid step ``(b, h)`` walks KV-head block ``h`` of
+    sequence ``b`` from its first live page to the page of its context, a
+    tile of ``pages`` pages an iteration of an in-kernel loop, and fetches
+    those pages itself: one DMA a (page, pool) from the block table in SMEM
+    into the tile's rows of a double-buffered ``[2, heads, pages * bs, hd]``
+    scratch. The NEXT tile - this walk's, or the first of the next grid
+    step's - is started before this one is waited for; which half of the
+    scratch holds the tile in flight is carried in SMEM from grid step to
+    grid step (the grid is sequential). ``vd``: one pool, and a token's
+    values are the first ``vd`` lanes of its key row."""
+    n_pools = 1 if vd else 2
+    tables_ref, ctx_ref, layer_ref = refs[:3]
+    wnd_ref = refs[3] if has_window else None
+    refs = refs[3 + int(has_window):]
+    q_ref, hbm, o_ref = refs[0], refs[1:1 + n_pools], refs[1 + n_pools]
+    bufs = refs[2 + n_pools:2 + 2 * n_pools]
+    sems, slot_ref, m_scr, l_scr, acc_scr = refs[2 + 2 * n_pools:]
+    b, h = pl.program_id(0), pl.program_id(1)
+    kv = pages * bs
+    add, mul, div = jax.lax.add, jax.lax.mul, jax.lax.div
+    lo, hi = jax.lax.max, jax.lax.min
+
+    def span(b):
+        """(first, last) live page of sequence ``b``: its window's first
+        page, or page 0, to the page of the current token."""
+        last = hi(div(ctx_ref[b], bs), max_blocks - 1)
+        if not has_window:
+            return 0, last
+        return hi(div(lo(add(ctx_ref[b], add(1, -wnd_ref[0])), 0), bs),
+                  last), last
+
+    def tile_copies(b, h, pg0, last, slot, fetch):
+        """The page copies of the tile of (sequence, head block) that begins
+        at table entry ``pg0`` - of its pages up to the sequence's ``last``
+        alone - started (``fetch``) or waited for; a wait takes the copy's
+        shape and semaphore, and no source."""
+        def page(p, _):
+            blk = hi(lo(tables_ref[b, add(pg0, p)], 0), nblocks - 1) \
+                if fetch else 0
+            rows = pl.ds(pl.multiple_of(mul(p, bs), bs), bs)
+            for i, (pool, buf) in enumerate(zip(hbm, bufs)):
+                src = pool.at[layer_ref[0], blk] if layered else pool.at[blk]
+                copy = pltpu.make_async_copy(
+                    src.at[pl.ds(mul(h, heads), heads)],
+                    buf.at[slot, :, rows], sems.at[i, slot])
+                copy.start() if fetch else copy.wait()
+            return _
+        jax.lax.fori_loop(0, hi(pages, add(add(last, 1), -pg0)), page, 0)
+
+    first, last = span(b)
+
+    @pl.when(jnp.logical_and(b == 0, h == 0))
+    def _prime():
+        # a tile's rows past its sequence's last page are never fetched and
+        # always masked, so what the values' scratch holds there has to be
+        # finite: every earlier tile's rows are, fresh VMEM need not be
+        bufs[-1][...] = jnp.zeros_like(bufs[-1])
+        slot_ref[0] = 0
+        tile_copies(b, h, first, last, 0, True)
+
+    _flash_init(0, m_scr, l_scr, acc_scr)
+    ctx = ctx_ref[b]
+    n = add(div(add(last, -first), pages), 1)
+
+    def tile(j, _):
+        slot = slot_ref[0]
+        pg0 = add(first, mul(j, pages))
+        more = j + 1 < n
+        step = jnp.logical_and(jnp.logical_not(more),
+                               h + 1 == pl.num_programs(1))
+        b_next = jnp.where(step, b + 1, b)
+        h_next = jnp.where(more, h, jnp.where(step, 0, h + 1))
+
+        @pl.when(b_next < pl.num_programs(0))
+        def _fetch_next():
+            first_next, last_next = span(b_next)
+            tile_copies(b_next, h_next,
+                        jnp.where(more, add(pg0, pages), first_next),
+                        last_next, 1 - slot, True)
+
+        tile_copies(b, h, pg0, last, slot, False)
+        q = q_ref[...]                             # [heads, gpad, hd]
+        k = bufs[0][slot]                          # [heads, kv, hd]
+        v = bufs[1][slot] if vd is None else k[..., :vd]
+        s = _mxu_dot(q, k, _contract(q.ndim, -1),
+                     preferred_element_type=jnp.float32) * scale
+        pos = mul(pg0, bs) + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
+        valid = pos <= ctx                          # the current token too
+        if has_window:
+            valid = jnp.logical_and(valid, pos > ctx - wnd_ref[0])
+        _flash_update(jnp.where(valid, s, NEG_INF), v, m_scr, l_scr, acc_scr)
+        slot_ref[0] = 1 - slot
+        return _
+
+    jax.lax.fori_loop(0, n, tile, 0)
+    _flash_finish(True, o_ref, l_scr, acc_scr)
+
+
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                            context_lens: jnp.ndarray, *,
@@ -535,39 +680,77 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     ``[L, num_blocks, nkv, bs, hd]`` pools to attend over (int or traced
     scalar - the layer scan's index); a 4-D pool is one layer's. ``window``: optional
     sliding-window length (int or traced scalar — exaone4 scans per-layer
-    windows): only the last ``window`` positions are attended; tiles
-    entirely outside the window skip their compute. ``k_scale``/``v_scale``:
+    windows): only the last ``window`` positions are attended, and the walk
+    begins at the window's first page. ``k_scale``/``v_scale``:
     per-block-per-group fp32 scale pools ``[num_blocks, nkv, bs, ngroups]``
     for int8 code pools — the quantized-KV mode with dequant fused into the
     flash loop (both or neither must be given)."""
     B, nh, hd = q.shape
     assert (k_scale is None) == (v_scale is None), \
         "k_scale and v_scale must be given together"
+    quant, vd = k_scale is not None, value_width
+    assert (v_pool is None) == (vd is not None) and not (vd and quant), \
+        "values come from a V pool or from the key page's lanes"
     layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
-    nkv, bs = k_pool.shape[-3:-1]
+    nblocks, nkv, bs = k_pool.shape[-4:-1]
+    max_blocks = block_tables.shape[1]
     g = nh // nkv
     gpad = _group_rows(g)
     if window is not None:
         window = _checked_window(window)
     pages, heads, n_kv = _decode_tiles(
-        nkv, g, hd, bs, block_tables.shape[1], k_pool.dtype.itemsize,
-        k_scale is not None, 1 if v_pool is None else 2)
-    # [B, nkv, gpad, hd] query groups; the walk ends with the longest
-    # context's last tile (the current token included)
+        nkv, g, hd, bs, max_blocks, k_pool.dtype.itemsize, quant,
+        1 if v_pool is None else 2)
+    scale = float(hd ** -0.5 if scale is None else scale)
+    od = vd or hd                       # the output's (values') width
+    # [B, nkv, gpad, hd] query groups
     qg = jnp.pad(q.reshape(B, nkv, g, hd),
                  ((0, 0), (0, 0), (0, gpad - g), (0, 0)))
-    n_live = jnp.clip(jnp.max(context_lens) // (pages * bs) + 1, 1, n_kv)
-    kernel, grid_spec, args = _table_walk(
-        qg, k_pool, v_pool, block_tables, context_lens,
-        jnp.ones((B,), jnp.int32), layer, window, k_scale, v_scale,
-        scale=hd ** -0.5 if scale is None else scale, rows=gpad, tq=1,
-        pages=pages, heads=heads, n_kv=n_live.astype(jnp.int32),
-        vd=value_width)
-    od = value_width or hd
+    if not _fetches_pages(hd, quant):
+        # the multi-token op's walk of BlockSpec pages, one token a sequence:
+        # its grid ends with the longest context's last tile (the current
+        # token included)
+        n_live = jnp.clip(jnp.max(context_lens) // (pages * bs) + 1, 1, n_kv)
+        kernel, grid_spec, args = _table_walk(
+            qg, k_pool, v_pool, block_tables, context_lens,
+            jnp.ones((B,), jnp.int32), layer, window, k_scale, v_scale,
+            scale=scale, rows=gpad, tq=1, pages=pages, heads=heads,
+            n_kv=n_live.astype(jnp.int32), vd=vd)
+        order = _WALK_GRID
+    else:
+        pools = [k_pool] + ([] if v_pool is None else [v_pool])
+        kernel = functools.partial(
+            _decode_kernel, bs=bs, pages=pages, heads=heads, scale=scale,
+            max_blocks=max_blocks, nblocks=nblocks,
+            has_window=window is not None, vd=vd, layered=k_pool.ndim == 5)
+
+        def qmap(b, h, *_):
+            return (b, h, 0, 0)
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3 + int(window is not None),
+            grid=(B, nkv // heads),
+            in_specs=[pl.BlockSpec((None, heads, gpad, hd), qmap)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((None, heads, gpad, od), qmap),
+            scratch_shapes=[pltpu.VMEM((2, heads, pages * bs, hd), p.dtype)
+                            for p in pools] + [
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((heads, gpad, 128), jnp.float32),
+                pltpu.VMEM((heads, gpad, 128), jnp.float32),
+                pltpu.VMEM((heads, gpad, od), jnp.float32),
+            ],
+        )
+        args = [block_tables.astype(jnp.int32),
+                context_lens.astype(jnp.int32), layer] \
+            + ([] if window is None else [window.reshape(1)]) + [qg] + pools
+        # in order: the tile in flight belongs to the NEXT grid step
+        order = _dim_semantics("arbitrary", "arbitrary")
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), q.dtype),
-        compiler_params=_WALK_GRID,
+        compiler_params=order,
         interpret=_interpret(),
         name="paged_decode",
     )(*args)

@@ -149,6 +149,51 @@ def main():
 
     check("paged_kv_write_layered_pools", paged_kv_write_layered)
 
+    # the decode walk fetches its own pages (ISSUE 49): what the interpreter
+    # cannot show - real DMAs out of the pools, the semaphores, the SMEM
+    # carry across grid steps - at the cells' geometries: contexts 0, one
+    # token short of a tile, a tile, the table; table entries past a
+    # sequence's end out of range (a walk that fetched them would fault or
+    # read NaN: the last block is poisoned); a static and a traced window
+    # that begin past page 0; a latent pool; heads in blocks
+    def paged_decode_own_pages():
+        from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+        L, bs, mb = 2, 32, 64
+        for nkv, g, hd, vd in ((8, 4, 128, None), (16, 1, 128, None),
+                               (8, 16, 128, None), (1, 64, 640, 512),
+                               (64, 1, 256, None)):     # eight head blocks
+            nb = 600
+            pages, _, _ = pa._decode_tiles(nkv, g, hd, bs, mb, 2, False,
+                                           1 if vd else 2)
+            tile = pages * bs
+            ctx = np.asarray([0, tile - 2, tile - 1, tile, mb * bs - 1, 700,
+                              0, 33], np.int32)
+            B = len(ctx)
+            pools = [randn(L, nb, nkv, bs, hd).astype(jnp.bfloat16)
+                     .at[:, nb - 1].set(jnp.nan)
+                     for _ in range(1 if vd else 2)] + [None] * bool(vd)
+            bt = rs.randint(1, nb - 1, (B, mb)).astype(np.int32)
+            bad = np.resize(np.asarray([nb - 1, 10 ** 6, -3], np.int32),
+                            (B, mb))
+            live = np.arange(mb)[None, :] <= ctx[:, None] // bs
+            bad, bt = jnp.asarray(np.where(live, bt, bad)), \
+                jnp.asarray(np.where(live, bt, 0))
+            q = randn(B, nkv * g, hd).astype(jnp.bfloat16)
+            ctx = jnp.asarray(ctx)
+            kw = dict(layer=1, value_width=vd)
+            walk = jax.jit(lambda w: pa.paged_decode_attention(
+                q, *pools, bad, ctx, window=w, **kw))
+            for w in (None, 40, 1000):
+                want = pa.paged_decode_attention_xla(q, *pools, bt, ctx,
+                                                     window=w, **kw)
+                diff_ok(pa.paged_decode_attention(q, *pools, bad, ctx,
+                                                  window=w, **kw), want, 0.05)
+                if w:
+                    diff_ok(walk(jnp.asarray(w, jnp.int32)), want, 0.05)
+
+    check("paged_decode_own_pages", paged_decode_own_pages)
+
     # compact MoE dispatch parity ON CHIP at true-f32 matmul precision —
     # round-4's 1.1e-2 divergence (bench_runs/MOE_20260731T034754Z.json)
     # was captured before the 06:54Z compact-gating rewrite; this pins the

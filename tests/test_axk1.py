@@ -533,13 +533,15 @@ def test_the_one_pool_write_in_interpret_mode_is_its_xla_twin():
 
 
 def test_a_latent_pool_is_one_page_a_step_in_the_tile_counts():
-    """One pool halves what a (head, page) pair keeps in VMEM, and the
-    counts follow the kernel's own tile sizes."""
+    """One pool halves what a (head, page) pair keeps in VMEM, the walk
+    fetches its 640-lane rows itself and widens to eight 128-token pages,
+    and the counts follow the kernel's own tile sizes: each slot its own
+    tiles, a slot at context 0 the one that writes its row."""
     assert pa._decode_tiles(1, 64, 640, 128, 256, 2, False, pools=1) \
-        == (2, 1, 128)
-    live, grid = pa.decode_tile_counts([100, 1000, 0], 64, (1, 128, 640), 2,
+        == (8, 1, 32)
+    live, grid = pa.decode_tile_counts([100, 3000, 0], 64, (1, 128, 640), 2,
                                        256, False, pools=1)
-    assert (live, grid) == (1 + 4 + 1, 3 * 4)
+    assert (live, grid) == (1 + 3 + 1,) * 2
     assert pa._prefill_tiles(512, 64, 640, 128, 256) == (16, 32, 2)
 
 

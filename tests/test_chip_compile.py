@@ -224,31 +224,61 @@ def test_prefill_attention_walks_the_table_at_the_cells_geometry(v5e, t,
     assert not re.search(rf"f32\[[0-9,]*\b{wide}\]", text)
 
 
-# the three serve cells' decode geometry: slots, query heads, KV heads, table
-# width, pool blocks (head size 128, blocks of 32) -> the layer's static grid
+# the serve cells' decode geometry: slots, query heads, KV heads, key width,
+# value width (None: a V pool), block, table width, pool blocks, the kind's
+# window -> (pages a KV tile, KV heads a grid step, tiles the table holds)
 CELL_DECODES = {
-    "mistral-7b.serve-chat": (32, 32, 8, 256, 896, 1024),
-    "mixtral-8x7b.serve-longprompt": (16, 32, 8, 256, 1536, 512),
-    "olmoe-1b-7b.serve-longprompt": (16, 16, 16, 128, 1536, 256),
+    "mistral-7b.serve-chat": (32, 32, 8, HD, None, 32, 256, 896, None,
+                              (16, 8, 16)),
+    "mixtral-8x7b.serve-longprompt": (16, 32, 8, HD, None, 32, 256, 1536,
+                                      None, (16, 8, 16)),
+    "olmoe-1b-7b.serve-longprompt": (16, 16, 16, HD, None, 32, 128, 1536,
+                                     None, (8, 16, 16)),
+    "command-a-plus-05-2026.serve-longctx/full": (
+        16, 128, 8, HD, None, 32, 1024, 12544, None, (16, 8, 64)),
+    "command-a-plus-05-2026.serve-longctx/window": (
+        16, 128, 8, HD, None, 32, 145, 2321, 4096, (16, 8, 10)),
+    "a.x-k1.serve-longctx/latent": (16, 64, 1, 640, 512, 128, 256, 3152,
+                                    None, (8, 1, 32)),
 }
+# every cell's walk as the cell runs it (its kind's window, a traced layer
+# of 5-D pools), and the three oldest cells' under a window and over int8
+# pools, which no cell runs
+DECODE_LOWERINGS = [(cell, "bf16") for cell in sorted(CELL_DECODES)] + [
+    (cell, pool) for cell in sorted(CELL_DECODES)[3:]
+    for pool in ("windowed", "int8")]
 
 
-@pytest.mark.parametrize("pool", ["bf16", "windowed", "int8"])
-@pytest.mark.parametrize("cell", sorted(CELL_DECODES))
+@pytest.mark.parametrize("cell,pool", DECODE_LOWERINGS)
 def test_decode_kernel_lowers_at_the_cells_geometry(v5e, cell, pool):
-    """Every KV head and eight pages a grid step compile for the chip at
-    each serve cell's shapes, and the Mosaic call is still the instruction
-    ``paged_decode.N`` that ``paged_decode_roofline`` looks for."""
+    """The decode walk compiles for the chip at each serve cell's shapes -
+    page DMAs out of pools left where they lie, a double-buffered tile of
+    every KV head in VMEM; int8 pools on their grid of ``BlockSpec`` pages -
+    as ONE Mosaic call, still the instruction ``paged_decode.N`` that
+    ``paged_decode_roofline``, ``mixed_kv_decode_roofline`` and
+    ``mla_decode_roofline`` look for."""
     import re
 
-    slots, nq, nkv, table, blocks, _ = CELL_DECODES[cell]
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    slots, nq, nkv, hd, vd, bs, table, blocks, window, _ = CELL_DECODES[cell]
     quant = pool == "int8"
-    kv = ((blocks, nkv, CELL_BS, HD), jnp.int8 if quant else jnp.bfloat16)
-    shapes = (((slots, nq, HD), jnp.bfloat16), kv, kv,
-              ((slots, table), jnp.int32), ((slots,), jnp.int32))
+    window = 4096 if pool == "windowed" else window
+    lead = () if quant else (2,)    # a layer's scale pools are cut out
+    kv = (lead + (blocks, nkv, bs, hd), jnp.int8 if quant else jnp.bfloat16)
+    shapes = (((slots, nq, hd), jnp.bfloat16),) + (kv,) * (1 if vd else 2) \
+        + (((slots, table), jnp.int32), ((slots,), jnp.int32), ((), jnp.int32))
     if quant:
-        shapes += (((blocks, nkv, CELL_BS, 1), jnp.float32),) * 2
-    fn = _paged(**({"window": 4096} if pool == "windowed" else {}))
+        shapes += (((blocks, nkv, bs, 1), jnp.float32),) * 2
+
+    def fn(q, k, *rest):
+        v, (bt, cl, layer, *scales) = (None, rest) if vd \
+            else (rest[0], rest[1:])
+        return pa.paged_decode_attention(
+            q, k, v, bt, cl, window=window, value_width=vd,
+            layer=None if quant else layer,
+            **(dict(k_scale=scales[0], v_scale=scales[1]) if quant else {}))
+
     text = _compile(fn, *shapes, device=v5e.devices[0]).as_text()
     calls = re.findall(r"%(\S+) = \S+ custom-call\(.*" + MOSAIC, text)
     assert len(calls) == 1 and re.fullmatch(r"paged_decode(\.\d+)?", calls[0])
@@ -256,13 +286,22 @@ def test_decode_kernel_lowers_at_the_cells_geometry(v5e, cell, pool):
 
 @pytest.mark.parametrize("cell", sorted(CELL_DECODES))
 def test_decode_grid_is_sized_by_the_shapes(cell):
-    from deepspeed_tpu.ops.pallas.paged_attention import _decode_tiles
+    """The walk's tile comes from the cell's shapes and the VMEM budget
+    alone: every KV head a grid step, and the widest doubling of ~256 tokens
+    up to ``_DECODE_KV_TOKENS`` whose two tiles fit - and a call takes the
+    tiles its slots' contexts hold, not slots x the longest's."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (_decode_tiles,
+                                                          decode_tile_counts)
 
-    slots, nq, nkv, table, _, steps = CELL_DECODES[cell]
-    pages, heads, n_kv = _decode_tiles(nkv, nq // nkv, HD, CELL_BS, table, 2,
-                                       False)
-    assert (pages, heads) == (8, nkv)
-    assert slots * (nkv // heads) * n_kv == steps
+    slots, nq, nkv, hd, vd, bs, table, _, _, tiles = CELL_DECODES[cell]
+    pools = 1 if vd else 2
+    assert _decode_tiles(nkv, nq // nkv, hd, bs, table, 2, False,
+                         pools) == tiles
+    pages, heads, n_kv = tiles
+    assert heads == nkv and n_kv == -(-table // pages)
+    ctx = [0, pages * bs - 1, pages * bs, table * bs - 1][:slots]
+    assert decode_tile_counts(ctx, nq, (nkv, bs, hd), 2, table, False,
+                              pools) == (4 + n_kv,) * 2
 
 
 # --- the pools stay where they are (ISSUE 29) ------------------------------ #
@@ -776,10 +815,13 @@ def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(v5e, cell):
     """Command A+'s and chat's mixed program as the engine runs it, compiled
     for the chip: every ``paged_prefill`` call (one a table kind) takes its
     last grid dimension as an operand - a Mosaic call's dynamic grid bound
-    is its FIRST operand, an ``s32[]`` ahead of the prefetched block table,
-    as ``paged_decode``'s has been -, the pools still stay where they are,
-    and the program's peak is the parent's (the bound's own scalars are a
-    few KB: nothing the size of a row, a tile or a table is added)."""
+    is its FIRST operand, an ``s32[]`` ahead of the prefetched block table;
+    ``paged_decode``'s grid is static since ISSUE 49 (a sequence a step: the
+    walk's length is a loop's trip count inside the kernel) and its first
+    operand is the table -, the pools still stay where they are, also those
+    the decode walk takes whole (``memory_space=pl.ANY``), and the program's
+    peak is the parent's (the bound's own scalars are a few KB: nothing the
+    size of a row, a tile or a table is added)."""
     import re
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
@@ -797,7 +839,7 @@ def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(v5e, cell):
         + r".*?operand_layout_constraints=\{([^,]*),", text)
         for kernel in ("paged_prefill", "paged_decode", "paged_kv_write")}
     kinds = 2 if cell == COMMAND_A_CELL else 1
-    assert first["paged_decode"] == ["s32[]"] * kinds
+    assert first["paged_decode"] == [f"s32[{args[3].slots}"] * kinds
     # two walks a kind since ISSUE 48, the narrow tile's and the wide one's
     assert first["paged_prefill"] == ["s32[]"] * 2 * kinds
     assert len(first["paged_kv_write"]) == 2 * kinds \
@@ -858,10 +900,12 @@ def test_the_expert_bank_is_read_where_it_lies(v5e, cell, program):
 # ``paged_prefill`` takes a traced grid bound (they were 4c1badd32e6be1bd and
 # adee81593bcafbca), and again on ISSUE 48's, which means to change them too:
 # the chunk's walk is a ``cond`` over two tile widths (0185c835790f0de0 and
-# c8639994fc5fe43d).
+# c8639994fc5fe43d), and again on ISSUE 49's, which means to change them too:
+# the slots' ``paged_decode`` fetches its own pages (958bdb530c8e0656 and
+# a7f9637cb43eb148).
 NO_BANK_PROGRAMS = {
-    "mistral-7b.serve-chat": "958bdb530c8e0656",
-    GRANITE_CELL: "a7f9637cb43eb148",
+    "mistral-7b.serve-chat": "1a7c864441030658",
+    GRANITE_CELL: "9d8372bd5608f6e3",
 }
 
 

@@ -608,14 +608,21 @@ def test_paged_decode_kernel_matches_reference(case, monkeypatch):
 
 
 def test_decode_tiles_come_from_the_shapes():
-    """The serve cells' geometries: every KV head and eight 32-token pages a
-    grid step, so a layer's static grid is 1024 / 512 / 256 tiles (it was
-    65 536 / 32 768 / 32 768 steps); int8 pools count their lane-padded
-    scale tiles; wide or many heads get a head block, one head over the
-    budget fewer pages; the table is never overshot."""
+    """The serve cells' geometries: every KV head a grid step and, where the
+    walk fetches its own pages (bf16 pools of whole-lane-tile heads), the
+    widest doubling of eight 32-token pages up to 1 024 tokens whose two
+    tiles fit the VMEM budget - chat's, Mixtral's and command-a's eight heads
+    sixteen pages, OLMoE's sixteen heads eight, Granite's four packed rows
+    thirty-two; int8 pools (their lane-padded scale tiles counted)
+    and heads of 64 keep the grid of ``BlockSpec`` pages and its ~256
+    tokens; wide or many heads get a head block, one head over the budget
+    fewer pages; the table is never overshot."""
     tiles = paged_mod._decode_tiles
-    assert tiles(8, 4, 128, 32, 256, 2, False) == (8, 8, 32)     # x 32 slots
-    assert tiles(16, 1, 128, 32, 128, 2, False) == (8, 16, 16)   # x 16 slots
+    assert tiles(8, 4, 128, 32, 256, 2, False) == (16, 8, 16)    # chat
+    assert tiles(16, 1, 128, 32, 128, 2, False) == (8, 16, 16)   # OLMoE
+    assert tiles(8, 16, 128, 32, 1024, 2, False) == (16, 8, 64)  # command-a
+    assert tiles(8, 16, 128, 32, 145, 2, False) == (16, 8, 10)   # its window
+    assert tiles(4, 8, 128, 32, 256, 2, False) == (32, 4, 8)     # Granite
     assert tiles(8, 4, 128, 32, 256, 1, True) == (8, 8, 32)
     assert tiles(16, 1, 128, 32, 128, 1, True) == (8, 8, 16)     # two blocks
     assert tiles(1, 71, 64, 32, 64, 2, False) == (8, 1, 8)       # falcon
@@ -623,6 +630,13 @@ def test_decode_tiles_come_from_the_shapes():
     assert tiles(8, 4, 128, 512, 16, 2, False) == (1, 8, 16)     # a wide page
     assert tiles(1, 8, 128, 4096, 4, 4, False) == (1, 1, 4)
     assert tiles(8, 4, 128, 32, 3, 2, False) == (3, 8, 1)        # short table
+    assert tiles(8, 4, 128, 32, 12, 2, False) == (8, 8, 2)   # no 16 pages in it
+    # the learned selection's decode walk takes its head block from here
+    # (paged_sparse_attention.py): Keye's four KV heads, as before
+    assert tiles(4, 8, 128, 32, 1024, 2, False)[1] == 4
+    assert [paged_mod._fetches_pages(hd, quant) for hd, quant in
+            ((128, False), (640, False), (256, False), (64, False),
+             (128, True))] == [True, True, True, False, False]
 
 
 def test_prefill_tiles_come_from_the_shapes():

@@ -893,6 +893,136 @@ def test_wide_tile_comes_from_the_shapes_and_leaves_the_query_tiles():
         == (8, 256, 32)
 
 
+# --- the decode walk fetches its own pages (ISSUE 49) ----------------------- #
+# Blocks of 8 tokens, a table 20 wide and a KV tile held to 64 tokens: two
+# whole tiles and half a third. A context of n is n cached tokens plus the
+# current one, so 62 is one token short of a tile, 63 exactly a tile, 64 the
+# first token of the second and 159 exactly the table.
+_ROUND_A_TILE = [0, 62, 63, 64, 159]
+DECODE_WALKS = {
+    "group_1": dict(nkv=4, g=1),
+    "group_4": dict(nkv=2, g=4),
+    "group_8": dict(nkv=1, g=8),
+    "group_16": dict(nkv=2, g=16),
+    "group_64_latent": dict(nkv=1, g=64, hd=256, vd=128),
+    "ragged_beside_empty_slots": dict(nkv=2, g=4, ctx=[159, 0, 5, 0, 100]),
+    "every_slot_empty": dict(nkv=2, g=4, ctx=[0, 0, 0]),
+    "window_static": dict(nkv=2, g=2, window=9),
+    "window_wider_than_a_tile": dict(nkv=2, g=2, window=70),
+    "window_traced": dict(nkv=2, g=2, window=9, traced=True),
+    # ctx 159 under a window of 9: the walk begins at page 18 of 20
+    "window_walk_begins_past_page_0": dict(nkv=2, g=4, ctx=[159, 150, 100],
+                                           window=9),
+    "layer_of_a_5d_pool": dict(nkv=2, g=4, layers=3),
+    "latent_pool": dict(nkv=1, g=8, hd=256, vd=128, layers=2),
+    "latent_pool_windowed": dict(nkv=1, g=8, hd=256, vd=128, window=20),
+    # two heads of 64 side by side in a 128-lane row: a query row is zero on
+    # the other head's lanes and the scale is the head's
+    "lane_packed_head_64": dict(nkv=2, g=8, pack=2),
+    "two_head_blocks": dict(nkv=4, g=2, vmem=288 << 10),
+    # plain pools of heads narrower than a lane tile keep the grid of
+    # BlockSpec pages, as int8 pools do
+    "head_64_in_plain_pools": dict(nkv=2, g=4, hd=64),
+    "latent_rows_off_a_lane_tile": dict(nkv=1, g=8, hd=192, vd=128),
+    "int8_pools": dict(nkv=2, g=4, ngroups=1),
+    "int8_pools_windowed": dict(nkv=2, g=4, ngroups=2, window=20),
+}
+
+
+def _decode_walk_case(case):
+    """``(kernel's operands, reference's operands, keywords)``: the
+    kernel's tables hold garbage past each sequence's last block - a block
+    of NaN rows (NaN scales over int8 codes), an index past the pool and a
+    negative one, in turn - where the reference's hold the trash block."""
+    from deepspeed_tpu.ops.quantization import kv_quantize_int8
+
+    c = dict(dict(hd=128, vd=None, ctx=_ROUND_A_TILE, window=None,
+                  traced=False, layers=0, pack=1, ngroups=0, vmem=None),
+             **DECODE_WALKS[case])
+    rng = np.random.default_rng(7)
+    nkv, g, hd, bs, mb, nb = c["nkv"], c["g"], c["hd"], 8, 20, 48
+    B, poison = len(c["ctx"]), nb - 1
+    lead = (c["layers"],) if c["layers"] else ()
+    q = rng.standard_normal((B, nkv * g, hd)).astype(np.float32)
+    if c["pack"] > 1:                   # head i of a row keeps its own lanes
+        lanes = np.arange(hd) // (hd // c["pack"])
+        q = q * (lanes[None, :] == (np.arange(g) % c["pack"])[:, None])[
+            None, None].repeat(nkv, 1).reshape(1, nkv * g, hd)
+    pools = [jnp.asarray(rng.standard_normal(lead + (nb, nkv, bs, hd)),
+                         jnp.float32) for _ in range(1 if c["vd"] else 2)]
+    tables = np.zeros((B, mb), np.int32)
+    garbage = np.resize(np.asarray([poison, 10 ** 6, -3], np.int32), (B, mb))
+    for b, x in enumerate(c["ctx"]):
+        need = x // bs + 1
+        tables[b, :need] = garbage[b, :need] = rng.integers(1, poison, need)
+    kw = {"value_width": c["vd"]} if c["vd"] else {}
+    if c["layers"]:
+        kw["layer"] = c["layers"] - 1
+    if c["pack"] > 1:
+        kw["scale"] = (hd // c["pack"]) ** -0.5
+    bad = [p.at[..., poison, :, :, :].set(jnp.nan) for p in pools]
+    kw_bad = kw
+    if c["ngroups"]:
+        (kp, ks), (vp, vs) = (kv_quantize_int8(p, hd // c["ngroups"])
+                              for p in pools)
+        pools = bad = [kp, vp]
+        kw, kw_bad = (dict(kw, k_scale=a, v_scale=b) for a, b in (
+            (ks, vs), (ks.at[poison].set(jnp.nan),
+                       vs.at[poison].set(jnp.nan))))
+    pools, bad = (ps + [None] * (2 - len(ps)) for ps in (pools, bad))
+    ctx = jnp.asarray(c["ctx"], jnp.int32)
+    return (c, (jnp.asarray(q), *bad, jnp.asarray(garbage), ctx), kw_bad,
+            (jnp.asarray(q), *pools, jnp.asarray(tables), ctx), kw)
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_WALKS))
+def test_decode_walk_that_fetches_its_own_pages_agrees_with_xla(
+        case, monkeypatch):
+    """``paged_decode`` (interpreted: the interpreter runs its DMAs, its
+    semaphores and its SMEM carry) against the gathered XLA op: every query
+    group; contexts ragged across slots, 0, either side of a tile's end and
+    the table's; garbage table entries past a sequence's end, which a walk
+    that read them - even under its mask - would turn into NaN; static and
+    traced windows and a walk that begins past page 0; a layer of a 5-D
+    pool; the latent form; the lane-packed geometry; two head blocks; int8
+    pools and heads of 64 in plain pools, which keep the grid of
+    ``BlockSpec`` pages. The counter says what the walk takes: each slot's
+    own tiles, and on that grid the longest's for every slot."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_DECODE_KV_TOKENS", 64)
+    c, bad, kw_bad, good, kw = _decode_walk_case(case)
+    if c["vmem"]:
+        monkeypatch.setattr(pa, "_TILE_VMEM", c["vmem"])
+    nh, quant = c["nkv"] * c["g"], bool(c["ngroups"])
+    own = pa._fetches_pages(c["hd"], quant)
+    assert own == (not quant and c["hd"] % 128 == 0)
+    pages, heads, n_kv = pa._decode_tiles(
+        c["nkv"], c["g"], c["hd"], 8, 20, 1 if quant else 4, quant,
+        1 if c["vd"] else 2)
+    assert (pages, heads, n_kv) == (8, 2 if c["vmem"] else c["nkv"], 3)
+    windows = [None] if c["window"] is None else [c["window"], 70]
+    if c["traced"]:
+        walk = jax.jit(lambda w: pa.paged_decode_attention(
+            *bad, window=w, **kw_bad))
+        outs = [walk(jnp.asarray(w, jnp.int32)) for w in windows]
+        assert walk._cache_size() == 1
+    else:
+        outs = [pa.paged_decode_attention(*bad, window=w, **kw_bad)
+                for w in windows[:1]]
+    for w, out in zip(windows, outs):
+        want = pa.paged_decode_attention_xla(*good, window=w, **kw)
+        assert out.shape == (len(c["ctx"]), nh, c["vd"] or c["hd"])
+        np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    tiles = [x // 64 + 1 for x in c["ctx"]]
+    assert pa.decode_tile_counts(
+        c["ctx"], nh, good[1].shape, 1 if quant else 4, 20, quant,
+        1 if c["vd"] else 2) == (
+        sum(tiles) * (c["nkv"] // heads),
+        (sum(tiles) if own else max(tiles) * len(tiles))
+        * (c["nkv"] // heads))
+
+
 @pytest.mark.parametrize("backend", ["pallas", "xla"])
 @pytest.mark.parametrize("t", [1, 5])
 def test_paged_attention_step_hands_its_scale_to_both_kernels(t, backend):

@@ -248,10 +248,10 @@ def test_fault_crash_and_preemption_dump_traces(tmp_path):
 # --------------------------------------------------------------------------- #
 # serving: request lifecycle + latency SLOs
 # --------------------------------------------------------------------------- #
-def _serving_engine(trace=False, hub=None, split=0):
+def _serving_engine(trace=False, hub=None, split=0, **widths):
     from deepspeed_tpu.inference.engine_v2 import build_engine_v2
 
-    cfg = llama.LlamaConfig.tiny()
+    cfg = llama.LlamaConfig.tiny(**widths)
     params = llama.init(cfg, __import__("jax").random.PRNGKey(0))
     config = {"dtype": "float32", "prefill_bucket": 16,
               "split_prefill_chunk": split,
@@ -857,21 +857,28 @@ def test_prefill_spans_say_what_the_kernel_walked(devices8):
     assert eng.last_step["prefill_kv_tokens"] == 0
 
 
+@pytest.mark.parametrize("head", [128, 16])
 def test_decode_span_says_how_much_of_the_grid_is_live(devices8,
-                                                       monkeypatch):
+                                                       monkeypatch, head):
     """``decode_step`` (and ``last_step``) carry, for one layer's
     ``paged_decode`` call, the KV tiles that hold live context, the tiles
-    the grid visits - every slot walks as far as the longest - and their
-    ratio, from the kernel's own tile sizes and the slots' lengths."""
+    the walk takes and their ratio, from the kernel's own tile sizes and the
+    slots' lengths. Heads of a whole lane tile: the walk fetches its own
+    pages, each slot walks to its own end and the two counts are one - free
+    slots take the one tile that writes their row. Narrower heads keep the
+    grid of ``BlockSpec`` pages: every slot walks as far as the longest."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     monkeypatch.setattr(pa, "_KV_TOKENS", 32)   # two 16-token pages a tile
-    cfg, eng = _serving_engine(trace=True)
+    monkeypatch.setattr(pa, "_DECODE_KV_TOKENS", 32)
+    cfg, eng = _serving_engine(trace=True, hidden_size=4 * head)
     nkv, hd = cfg.num_kv_heads, cfg.head_size
     pages, heads, n_kv = pa._decode_tiles(
         nkv, cfg.num_heads // nkv, hd, 16, eng.state.max_blocks_per_seq, 4,
         False)
-    assert (pages, heads) == (2, nkv) and n_kv > 2
+    assert hd == head and (pages, heads) == (2, nkv) and n_kv > 2
+    own = pa._fetches_pages(hd, False)
+    assert own == (head == 128)
     rng = np.random.default_rng(2)
     eng.put_many([(uid, rng.integers(0, cfg.vocab_size, (n,)).tolist())
                   for uid, n in ((0, 70), (1, 9))])
@@ -881,14 +888,20 @@ def test_decode_span_says_how_much_of_the_grid_is_live(devices8,
         args = [e["args"] for e in eng.tracer.events()
                 if e["ph"] == "X" and e["name"] == "decode_step"][-1]
         tiles = lens // 32 + 1              # the current token included
-        assert sorted(tiles) == [1, 1, 1, 3]   # two free slots: one dead tile
+        assert sorted(tiles) == [1, 1, 1, 3]   # two free slots: a tile each
         assert args["attn_tiles_live"] == tiles.sum() == 6
-        assert args["attn_tiles_grid"] == 4 * tiles.max() == 12
-        assert args["attn_live_tile_share"] == 0.5
+        assert args["attn_tiles_grid"] == (6 if own else 4 * tiles.max())
+        assert args["attn_live_tile_share"] == (1.0 if own else 0.5)
         assert {k: eng.last_step[k] for k in args if k.startswith("attn_")} \
             == {k: v for k, v in args.items() if k.startswith("attn_")}
     assert pa.decode_tile_counts(lens, cfg.num_heads, eng.cache["k"].shape, 4,
-                                 eng.state.max_blocks_per_seq, False) == (6, 12)
+                                 eng.state.max_blocks_per_seq, False) \
+        == (6, 6 if own else 12)
+    # a wider tile where the budget and the table hold it: the counts follow
+    monkeypatch.setattr(pa, "_DECODE_KV_TOKENS", 64)
+    assert pa.decode_tile_counts(lens, cfg.num_heads, eng.cache["k"].shape, 4,
+                                 eng.state.max_blocks_per_seq, False) \
+        == ((5, 5) if own else (6, 12))
 
 
 # the multi-token walk's counter (ISSUE 45): sequences' (contexts, real rows),
