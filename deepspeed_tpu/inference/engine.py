@@ -73,6 +73,10 @@ class ModelFamily:
     # layer, ``cache["latent"]``, the row's first ``value_width`` numbers
     # its values; its ``apply_paged`` attends in the absorbed form
     latent_kind: Optional[Callable] = None
+    # parameter leaves (by their own key) the engine keeps in the type they
+    # come in where it casts the rest to its own: a router published in
+    # float32 (models/nemotron_h.py ``FLOAT32_PARAMS``)
+    float32_params: Tuple[str, ...] = ()
 
     @classmethod
     def from_module(cls, module, cfg) -> "ModelFamily":
@@ -92,7 +96,9 @@ class ModelFamily:
                    state_leaves=tuple(getattr(module, "STATE_LEAVES", ())),
                    sparse_rows=getattr(module, "sparse_rows", None),
                    window_kinds=getattr(module, "window_kinds", None),
-                   latent_kind=getattr(module, "latent_kind", None))
+                   latent_kind=getattr(module, "latent_kind", None),
+                   float32_params=tuple(getattr(module, "FLOAT32_PARAMS",
+                                                ())))
 
 
 def _round_up(n: int, m: int) -> int:
@@ -158,7 +164,8 @@ class InferenceEngine:
             from ..utils.tree import cast_floating
 
             self.params = jax.device_put(
-                cast_floating(jax.tree.map(jnp.asarray, params), self.dtype),
+                cast_floating(jax.tree.map(jnp.asarray, params), self.dtype,
+                              keep=family.float32_params),
                 self.param_shardings)
         log_dist(f"init_inference: {family.name} sharded over "
                  f"tensor={mesh_mgr.tp_world_size} (dtype={self.dtype})")

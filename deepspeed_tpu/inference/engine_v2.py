@@ -107,6 +107,16 @@ _REFUSALS = {
         "handoff":
             "a sequence's blocks are not its whole state, and the "
             "destination resumes through the prefix cache"}),
+    # a family that declares recurrent state AND experts (``state_slot_bytes``
+    # and ``moe_rows``: models/nemotron_h.py). A state family without experts
+    # is served over a tensor mesh with its mixers whole on every device, as
+    # it was (models/granite_hybrid.py ``param_logical_axes``)
+    "state_and_experts": (RecurrentStateError, {
+        "inference.tensor_parallel":
+            "beside an expert bank a state-space mixer's heads, and the "
+            "state pool's rows with them, would have to be split over the "
+            "tensor axis the bank is split over: a mesh over such a family "
+            "is not written"}),
     # each feature that names the two pools it knows, or that no test holds
     # over three
     "index_pool": (IndexPoolError, {
@@ -294,6 +304,8 @@ class InferenceEngineV2(InferenceEngine):
         # what those kinds refuse: a feature here, a call when it is made
         self._refusals = [_REFUSALS[kind] for kind, has in (
             ("recurrent_state", self._recurrent),
+            ("state_and_experts",
+             self._recurrent and self.family.moe_rows is not None),
             ("index_pool", self._indexed),
             ("window_kinds", self._window),
             ("latent_kind", self._latent)) if has]
@@ -627,7 +639,8 @@ class InferenceEngineV2(InferenceEngine):
               "inference.prefix_cache.host_spill":
                   getattr(cfg.prefix_cache, "host_spill", False),
               "inference.speculative": cfg.speculative.enabled,
-              "inference.kv_quant": kq is not None and kq.enabled}
+              "inference.kv_quant": kq is not None and kq.enabled,
+              "inference.tensor_parallel": cfg.tensor_parallel.tp_size > 1}
         for error, refused in self._refusals:
             for feature, why in refused.items():
                 if on.get(feature):

@@ -297,6 +297,87 @@ def scan_layers(body, x, layers, cache, *extras):
     return x, cache
 
 
+def layer_plan(layer_types) -> Tuple[int, list, Dict[str, int]]:
+    """How :func:`scan_nest` runs a stack of several KINDS of layer:
+    ``(periods, runs, layers of each kind a period)`` - the smallest period
+    the pattern repeats with, and that period cut, left to right, into RUNS:
+    a UNIT of layers repeated ``count`` times, the unit and count that cover
+    the most layers from where the run starts (a unit of more than one layer
+    only where it repeats). A run is ``(kind, first layer of the kind inside
+    the period, count)`` for a unit of one layer, and ``(kinds, first layer
+    of its kind inside the period for each of the unit's layers, count)``
+    for a longer one. Granite-4.0-H: 4 periods of [5 Mamba, 1 attention, 4
+    Mamba]; Nemotron-3-Nano's 52 layers have no period and are 5 x
+    ``MEMEM*E``, 3 x ``ME``, ``M``, ``*``, 4 x ``EM``, ``E``."""
+    kinds = tuple(layer_types)
+    n = len(kinds)
+    period = next(p for p in range(1, n + 1) if n % p == 0 and all(
+        kinds[i] == kinds[i % p] for i in range(n)))
+    kinds = kinds[:period]
+    runs, seen, at = [], dict.fromkeys(kinds, 0), 0
+    while at < period:
+        unit, count = 1, 1
+        for u in range(1, max(1, (period - at) // 2) + 1):
+            c = 1       # (a slice past the period's end is short: unequal)
+            while kinds[at + c * u:at + (c + 1) * u] == kinds[at:at + u]:
+                c += 1
+            if (u == 1 or c > 1) and u * c > unit * count:
+                unit, count = u, c
+        firsts = []
+        for kind in kinds[at:at + unit]:
+            firsts.append(seen[kind])
+            seen[kind] += 1
+        for kind in kinds[at:at + unit]:     # the unit's other repeats
+            seen[kind] += count - 1
+        runs.append((kinds[at], firsts[0], count) if unit == 1
+                    else (kinds[at:at + unit], tuple(firsts), count))
+        at += unit * count
+    return n // period, runs, seen
+
+
+def scan_nest(layer_types, layers, x, pools, blocks):
+    """A stack of several kinds of layer as ``layer_types`` spells it
+    (:func:`layer_plan`): an outer scan over the pattern's periods whose
+    body scans each run, a run's step being its unit's layers one after
+    another - so one body a kind of run is compiled whatever the depth.
+    ``layers[kind]`` is the weights of that kind's layers, STACKED; a layer
+    takes its own by its index into them (what a scan's per-step slice of
+    its inputs is), so no period's slab is cut out on the way.
+    ``blocks[kind](x, weights, pools, index) -> (x, pools)``, ``index`` the
+    layer's among its kind; ``pools`` (None without a cache) is the carry of
+    every scan, beside ``x``."""
+    periods, runs, per_period = layer_plan(layer_types)
+
+    def run(unit, firsts, carry, p, count):
+        each = {kind: unit.count(kind) for kind in unit}
+
+        def step(carry, i):
+            for kind, first in zip(unit, firsts):
+                # (a unit of one layer: the steps ARE its kind's indices)
+                index = i if len(unit) == 1 \
+                    else p * per_period[kind] + first + i * each[kind]
+                w = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                    a, index, 0, keepdims=False), layers[kind])
+                x, pools = carry
+                carry = blocks[kind](x, w, pools, index)
+            return carry, None
+
+        steps = (p * per_period[unit[0]] + firsts[0] if len(unit) == 1
+                 else 0) + jnp.arange(count, dtype=jnp.int32)
+        return lax.scan(step, carry, steps)[0]
+
+    def period(carry, p):
+        for unit, firsts, count in runs:
+            if isinstance(unit, str):
+                unit, firsts = (unit,), (firsts,)
+            carry = run(unit, firsts, carry, p, count)
+        return carry, None
+
+    with jax.named_scope("kv_write"):   # as scan_layers names its scan
+        return lax.scan(period, (x, pools),
+                        jnp.arange(periods, dtype=jnp.int32))[0]
+
+
 def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,
                          context_lens, positions, valid, *,
                          window=None, scale=None) -> Tuple:

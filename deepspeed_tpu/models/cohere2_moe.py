@@ -12,7 +12,7 @@ projection inside it:
   and k (adjacent pairs rotated, ``rope_gptj``) and attends the last
   ``sliding_window`` positions; a full layer has NO positional embedding
   and attends the whole context. The stack is a scan NEST over whole periods
-  of the pattern (``granite_hybrid._scan_nest`` is the pattern), so each
+  of the pattern (``_paged.scan_nest`` is the pattern), so each
   kind's window is a static number and each kind's KV pool rides its own
   carry.
 - **Two kinds of KV state** (``window_kinds``; ``inference/ragged.py``

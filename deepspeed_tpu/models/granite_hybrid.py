@@ -15,7 +15,11 @@ k^T``. Mamba-2 layers: ``in_proj -> [z | xBC | dt]``; ``xBC <- silu(conv1d(xBC)
 + b)`` (depthwise, causal, over the sequence's own previous ``K - 1`` rows);
 ``xBC -> x | B | C``; ``dt <- softplus(dt + dt_bias)``; per head ``H_t =
 exp(dt_t A) H_(t-1) + dt_t x_t B_t^T``, ``y_t = H_t C_t + D x_t``; ``y <-
-RMSNorm(y * silu(z))`` (gate first, then norm) and ``out_proj``. (The
+RMSNorm(y * silu(z))`` (gate first, then norm) and ``out_proj``. The mixer's
+functions take any number of B / C groups (``mamba_groups``: ``B``, ``C``
+``[.., G, N]``, the gate's norm over each group's part of the inner width -
+``models/nemotron_h.py`` runs them at 8); this family's release has one, and
+with one the traced program is what it was before groups were written. (The
 published ``in_proj`` is kept as two matrices: its ``[z | xBC]`` columns and
 its ``dt`` columns, ``dt_proj``.)
 
@@ -43,7 +47,6 @@ the state pools.
 
 from __future__ import annotations
 
-import itertools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -55,11 +58,12 @@ from jax import lax
 from ..ops import ssm
 from ..ops.attention import attention
 from ..ops.embedding import embedding_lookup
-from ..ops.norms import rms_norm
+from ..ops.norms import rms_norm, rms_norm_xla
 from ..ops.pallas import ssm as _ssm_kernels  # noqa: F401 (registers)
 from ..ops.registry import get_op
+from ._paged import layer_plan  # noqa: F401  (this family's plan, by name)
 from ._paged import (LayerPool, MixedCall, gather_rows, init_paged_pools,
-                     paged_attention_step, row_positions)
+                     paged_attention_step, row_positions, scan_nest)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -67,8 +71,54 @@ KINDS = {"mamba": "mamba", "attention": "attn"}   # layer type -> params key
 STATE_LEAVES = ("ssm",)           # the cache leaves with no block axis
 
 
+class MambaSizes:
+    """What the Mamba-2 mixer's functions below read off a configuration
+    beside its ``mamba_*`` fields and ``rms_norm_eps`` (this family's and
+    ``models/nemotron_h.py``'s share them)."""
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_groups * self.mamba_state
+
+    @property
+    def state_part(self) -> Tuple[int, int, int]:
+        """The recurrent state's part of a slot's row: ``(first sublane,
+        sublanes, lanes)`` = the first ``N`` sublanes, every lane."""
+        return 0, self.mamba_state, self.d_inner
+
+    @property
+    def tail_part(self) -> Tuple[int, int, int]:
+        """The convolution tail's part of a slot's row, under the state:
+        ``[K - 1, conv_dim]`` flattened into whole sublanes of as few whole
+        128-lane tiles as hold it (8 x 1664 for Granite's published 3 x
+        4352, 8 x 2304 for Nemotron-3-Nano's 3 x 6144)."""
+        flat = (self.mamba_conv - 1) * self.conv_dim
+        width = self.d_inner
+        sublanes = 8 * -(-flat // (8 * width))
+        lanes = width if width % 128 else min(
+            width, -(-flat // (sublanes * 128)) * 128)
+        assert self.mamba_state % sublanes == 0, \
+            "the tail starts on a block of its own size"
+        return self.mamba_state, sublanes, lanes
+
+    @property
+    def state_sublanes(self) -> int:
+        """Sublanes of a slot's row: the state and the tail under it."""
+        return self.mamba_state + self.tail_part[1]
+
+    @property
+    def state_row_bytes(self) -> int:
+        """Bytes of ONE slot's row of ONE Mamba layer, in ``state_dtype``."""
+        return self.state_sublanes * self.d_inner \
+            * jnp.dtype(self.state_dtype).itemsize
+
+
 @dataclass(frozen=True)
-class GraniteHybridConfig:
+class GraniteHybridConfig(MambaSizes):
     vocab_size: int = 100352
     hidden_size: int = 2048
     intermediate_size: int = 8192        # shared_intermediate_size
@@ -86,6 +136,7 @@ class GraniteHybridConfig:
     mamba_heads: int = 64
     mamba_head_dim: int = 64
     mamba_state: int = 128
+    mamba_groups: int = 1       # groups of B and C (the release has one)
     mamba_conv: int = 4
     mamba_chunk: int = 256      # how the scan is blocked, not part of the result
     state_dtype: str = "float32"
@@ -98,34 +149,6 @@ class GraniteHybridConfig:
     @property
     def num_layers(self) -> int:
         return len(self.layer_types)
-
-    @property
-    def d_inner(self) -> int:
-        return self.mamba_heads * self.mamba_head_dim
-
-    @property
-    def conv_dim(self) -> int:
-        return self.d_inner + 2 * self.mamba_state      # one group of B, C
-
-    @property
-    def state_part(self) -> Tuple[int, int, int]:
-        """The recurrent state's part of a slot's row: ``(first sublane,
-        sublanes, lanes)`` = the first ``N`` sublanes, every lane."""
-        return 0, self.mamba_state, self.d_inner
-
-    @property
-    def tail_part(self) -> Tuple[int, int, int]:
-        """The convolution tail's part of a slot's row, under the state:
-        ``[K - 1, conv_dim]`` flattened into whole sublanes of as few whole
-        128-lane tiles as hold it (8 x 1664 for the published 3 x 4352)."""
-        flat = (self.mamba_conv - 1) * self.conv_dim
-        width = self.d_inner
-        sublanes = 8 * -(-flat // (8 * width))
-        lanes = width if width % 128 else min(
-            width, -(-flat // (sublanes * 128)) * 128)
-        assert self.mamba_state % sublanes == 0, \
-            "the tail starts on a block of its own size"
-        return self.mamba_state, sublanes, lanes
 
     def count(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)
@@ -152,21 +175,6 @@ def _check(cfg: GraniteHybridConfig) -> None:
                          f"has {sorted(KINDS)}")
 
 
-def layer_plan(layer_types) -> Tuple[int, list, Dict[str, int]]:
-    """``(periods, runs, layers of each kind a period)``: the smallest
-    period the pattern repeats with, and that period's runs of one kind as
-    ``(kind, first layer of the kind inside the period, count)``."""
-    n = len(layer_types)
-    period = next(p for p in range(1, n + 1) if n % p == 0 and all(
-        layer_types[i] == layer_types[i % p] for i in range(n)))
-    runs, seen = [], {kind: 0 for kind in KINDS}
-    for kind, group in itertools.groupby(layer_types[:period]):
-        count = len(list(group))
-        runs.append((kind, seen[kind], count))
-        seen[kind] += count
-    return n // period, runs, seen
-
-
 # --------------------------------------------------------------------------- #
 # parameters
 # --------------------------------------------------------------------------- #
@@ -181,7 +189,6 @@ def init(cfg: GraniteHybridConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     _check(cfg)
     h, i, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
-    H, K, C, d_in = cfg.mamba_heads, cfg.mamba_conv, cfg.conv_dim, cfg.d_inner
     keys = iter(jax.random.split(rng, 16))
 
     def normal(shape, fan_in):
@@ -200,26 +207,12 @@ def init(cfg: GraniteHybridConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
                 "w_out": normal((n, i, h), i)}
 
     m, a = cfg.count("mamba"), cfg.count("attention")
-    dt = jnp.exp(uniform((m, H), math.log(1e-3), math.log(1e-1)))
+    dt = draw_dt(cfg, m, uniform)
     params: Params = {
         "embed": (normal((v, h), h) * cfg.logits_scaling).astype(dtype),
         "final_norm": jnp.ones((h,), dtype),
-        "mamba": {
-            "norm": jnp.ones((m, h), dtype),
-            # in_proj's columns [z | xBC] and, by themselves, [dt]: the whole
-            # 8512 is no multiple of 128 lanes, the device's default layout
-            # of the stack is then not row-major, and every program copied
-            # all 1.25 GB of it (compiled for a described v5e)
-            "in_proj": normal((m, h, d_in + C), h),
-            "dt_proj": normal((m, h, H), h),
-            "conv_w": uniform((m, K, C), -K ** -0.5, K ** -0.5).astype(dtype),
-            "conv_b": uniform((m, C), -K ** -0.5, K ** -0.5).astype(dtype),
-            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
-            "A_log": jnp.log(uniform((m, H), 1.0, 16.0)).astype(dtype),
-            "D": jnp.ones((m, H), dtype),
-            "gate_norm": jnp.ones((m, d_in), dtype),
-            "out_proj": normal((m, d_in, h), d_in),
-            **mlp(m)},
+        "mamba": {**init_mixer(cfg, m, dt, normal, uniform, dtype),
+                  **mlp(m)},
         "attn": {
             "norm": jnp.ones((a, h), dtype),
             "wq": normal((a, h, nh * hd), h),
@@ -231,22 +224,57 @@ def init(cfg: GraniteHybridConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     return params
 
 
+def draw_dt(cfg, m: int, uniform):
+    """Mamba-2's published time steps, log-uniform 0.001-0.1, ``[m,
+    heads]``: what :func:`init_mixer` makes ``dt_bias`` of."""
+    return jnp.exp(uniform((m, cfg.mamba_heads), math.log(1e-3),
+                           math.log(1e-1)))
+
+
+def init_mixer(cfg, m: int, dt, normal, uniform, dtype) -> Params:
+    """``m`` stacked Mamba-2 mixers with their norm, by :func:`init`'s rule
+    (``normal(shape, fan_in)`` and ``uniform(shape, lo, hi)`` are the
+    caller's draws)."""
+    h, H, K = cfg.hidden_size, cfg.mamba_heads, cfg.mamba_conv
+    C, d_in = cfg.conv_dim, cfg.d_inner
+    return {
+        "norm": jnp.ones((m, h), dtype),
+        # in_proj's columns [z | xBC] and, by themselves, [dt]: the whole
+        # (Granite's 8512, Nemotron-3's 10304) is no multiple of 128 lanes,
+        # the device's default layout of the stack is then not row-major,
+        # and every program copied all 1.25 GB of it (compiled for a
+        # described v5e)
+        "in_proj": normal((m, h, d_in + C), h),
+        "dt_proj": normal((m, h, H), h),
+        "conv_w": uniform((m, K, C), -K ** -0.5, K ** -0.5).astype(dtype),
+        "conv_b": uniform((m, C), -K ** -0.5, K ** -0.5).astype(dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "A_log": jnp.log(uniform((m, H), 1.0, 16.0)).astype(dtype),
+        "D": jnp.ones((m, H), dtype),
+        "gate_norm": jnp.ones((m, d_in), dtype),
+        "out_proj": normal((m, d_in, h), d_in)}
+
+
+def mixer_logical_axes() -> Params:
+    """The Mamba mixer's own weights unsharded (one chip serves the model
+    whole; a tensor-parallel mixer splits its heads and is not written)."""
+    flat = ("layers", None)
+    return {"norm": ("layers", "embed"),
+            "in_proj": ("layers", "embed", None),
+            "dt_proj": ("layers", "embed", None),
+            "conv_w": ("layers", None, None), "conv_b": flat,
+            "dt_bias": flat, "A_log": flat, "D": flat,
+            "gate_norm": flat, "out_proj": ("layers", None, "embed")}
+
+
 def param_logical_axes(cfg: GraniteHybridConfig) -> Params:
-    """Attention and feed-forward as ``llama``; the Mamba mixer's own
-    weights unsharded (one chip serves the model whole; a tensor-parallel
-    mixer splits its heads and is not written)."""
+    """Attention and feed-forward as ``llama``; the Mamba mixer's as
+    :func:`mixer_logical_axes` has them."""
     mlp = {"mlp_norm": ("layers", "embed"), "w_in": ("layers", "embed", None),
            "w_out": ("layers", "mlp", "embed")}
-    flat = ("layers", None)
     return {
         "embed": ("vocab", "embed"), "final_norm": ("embed",),
-        "mamba": {"norm": ("layers", "embed"),
-                  "in_proj": ("layers", "embed", None),
-                  "dt_proj": ("layers", "embed", None),
-                  "conv_w": ("layers", None, None), "conv_b": flat,
-                  "dt_bias": flat, "A_log": flat, "D": flat,
-                  "gate_norm": flat, "out_proj": ("layers", None, "embed"),
-                  **mlp},
+        "mamba": {**mixer_logical_axes(), **mlp},
         "attn": {"norm": ("layers", "embed"),
                  "wq": ("layers", "embed", "heads"),
                  "wk": ("layers", "embed", "kv_heads"),
@@ -295,8 +323,11 @@ def _conv(cfg, xbc, tail, w):
     taps = w["conv_w"].astype(F32)
     out = sum(ext[:, k:k + t].astype(F32) * taps[k]
               for k in range(cfg.mamba_conv)) + w["conv_b"].astype(F32)
+    G, N = cfg.mamba_groups, cfg.mamba_state
     x, B, C = jnp.split(jax.nn.silu(out).astype(xbc.dtype),
-                        [cfg.d_inner, cfg.d_inner + cfg.mamba_state], axis=-1)
+                        [cfg.d_inner, cfg.d_inner + G * N], axis=-1)
+    if G > 1:       # [b, t, G, N]: head h reads group h // (heads / G)
+        B, C = (a.reshape(a.shape[:2] + (G, N)) for a in (B, C))
     return x, B, C, ext
 
 
@@ -317,13 +348,22 @@ def _unpack_tail(cfg, part, dtype):
 
 
 def _mixer_out(cfg, y, x, z, w):
-    """The skip ``D x``, the gate, the norm over the whole inner width (gate
-    first, then norm) and ``out_proj``. ``y [b, t, heads * P]`` float32."""
+    """The skip ``D x``, the gate, the norm over each GROUP's part of the
+    inner width - the whole of it with one group - (gate first, then norm)
+    and ``out_proj``. ``y [b, t, heads * P]`` float32."""
     with jax.named_scope("ssm_proj"):
         y = y + jnp.repeat(w["D"].astype(F32), cfg.mamba_head_dim) \
             * x.astype(F32)
         y = (y * jax.nn.silu(z.astype(F32))).astype(z.dtype)
-        return rms_norm(y, w["gate_norm"], cfg.rms_norm_eps) @ w["out_proj"]
+        if cfg.mamba_groups > 1:
+            # (the XLA form by name: the kernel's weight is one row)
+            by_group = y.shape[:-1] + (cfg.mamba_groups, -1)
+            y = rms_norm_xla(y.reshape(by_group),
+                             w["gate_norm"].reshape(cfg.mamba_groups, -1),
+                             cfg.rms_norm_eps).reshape(y.shape)
+        else:
+            y = rms_norm(y, w["gate_norm"], cfg.rms_norm_eps)
+        return y @ w["out_proj"]
 
 
 def _mamba_mixer(cfg, y, w, tail, h0, token_valid):
@@ -391,34 +431,61 @@ def _ssm_rows(cfg, w, state, index, rows, fresh, xbc, dt, A, n_valid):
     return state, mixed.reshape(b, t, -1), xs
 
 
+def _mixer_paged(cfg, y, w, state, index, rows, fresh, valid, call=None):
+    """One Mamba mixer over the state pool (``_ssm_rows``), from the
+    layer's normed input: ``(out [b, t, h], state pool)``. ``rows`` already
+    aims rows that must write nothing at the trash row. In a mixed call
+    (``call``, a ``_paged.MixedCall``; ``y [1, slots + t, h]``) ``in_proj``,
+    the gate and ``out_proj`` see every row at once; only the state's part
+    splits into the two segments, the chunk's first as the two programs
+    ran, and ``rows`` / ``fresh`` are (the decode rows', the chunk's)
+    pairs."""
+    z, xbc, dt, A = _mixer_in(cfg, y, w, valid)
+    if call is None:
+        state, mixed, xs = _ssm_rows(
+            cfg, w, state, index, rows, fresh, xbc, dt, A,
+            jnp.sum(valid, axis=1, dtype=jnp.int32))
+    else:
+        (xbc_d, xbc_c), (dt_d, dt_c) = call.split(xbc), call.split(dt)
+        state, mixed_c, xs_c = _ssm_rows(
+            cfg, w, state, index, rows[1], fresh[1], xbc_c, dt_c, A,
+            call.chunk_valid[None])
+        state, mixed_d, xs_d = _ssm_rows(
+            cfg, w, state, index, rows[0], fresh[0], xbc_d, dt_d, A,
+            None)
+        mixed = call.join(mixed_d, mixed_c)
+        xs = call.join(xs_d, xs_c)
+    return _mixer_out(cfg, mixed, xs, z, w), state
+
+
+def state_call(pool, block_tables, context_lens, valid, slots):
+    """``(state rows, fresh, call)`` of a paged forward over the state pool
+    ``pool``: each call row's pool row (the trash row where it must write
+    nothing) and whether it starts from zeros - one array each, or, in a
+    mixed call (``block_tables`` a ``_paged.MixedCall``, returned as
+    ``call``: decode row i is slot i and the chunk's rows are
+    ``chunk_slot``'s), (the decode rows', the chunk's) pairs."""
+    if not isinstance(block_tables, MixedCall):
+        if slots is None:
+            slots = jnp.arange(valid.shape[0], dtype=jnp.int32)
+        return ssm.pool_rows(slots, valid[:, 0], pool), context_lens == 0, \
+            None
+    call = block_tables
+    rows = (ssm.pool_rows(jnp.arange(call.slots), call.active, pool),
+            ssm.pool_rows(call.chunk_slot[None],
+                          (call.chunk_valid > 0)[None], pool))
+    return rows, (call.lens == 0, (call.chunk_ctx == 0)[None]), call
+
+
 def _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid, call=None):
-    """One Mamba layer over the state pools (``_ssm_rows``). ``rows``
-    already aims rows that must write nothing at the trash row. In a mixed
-    call (``call``, a ``_paged.MixedCall``; ``x [1, slots + t, h]``)
-    ``in_proj``, the gate, ``out_proj`` and the MLP see every row at once;
-    only the state's part splits into the two segments, the chunk's first as
-    the two programs ran, and ``rows`` / ``fresh`` are (the decode rows',
-    the chunk's) pairs."""
-    state = pools["ssm"]
+    """One Mamba layer over the state pools (``_mixer_paged``), then its
+    MLP."""
     with jax.named_scope("norm"):
         y = rms_norm(x, w["norm"], cfg.rms_norm_eps)
     with jax.named_scope("attn"):       # this layer's token mixer
-        z, xbc, dt, A = _mixer_in(cfg, y, w, valid)
-        if call is None:
-            state, mixed, xs = _ssm_rows(
-                cfg, w, state, index, rows, fresh, xbc, dt, A,
-                jnp.sum(valid, axis=1, dtype=jnp.int32))
-        else:
-            (xbc_d, xbc_c), (dt_d, dt_c) = call.split(xbc), call.split(dt)
-            state, mixed_c, xs_c = _ssm_rows(
-                cfg, w, state, index, rows[1], fresh[1], xbc_c, dt_c, A,
-                call.chunk_valid[None])
-            state, mixed_d, xs_d = _ssm_rows(
-                cfg, w, state, index, rows[0], fresh[0], xbc_d, dt_d, A,
-                None)
-            mixed = call.join(mixed_d, mixed_c)
-            xs = call.join(xs_d, xs_c)
-        x = x + cfg.residual_multiplier * _mixer_out(cfg, mixed, xs, z, w)
+        out, state = _mixer_paged(cfg, y, w, pools["ssm"], index, rows,
+                                  fresh, valid, call)
+        x = x + cfg.residual_multiplier * out
     return _mlp(cfg, x, w), {**pools, "ssm": state}
 
 
@@ -437,33 +504,11 @@ def _attention_paged(cfg, x, w, pools, index, tables, ctx, positions, valid):
 
 
 def _scan_nest(cfg, x, layers, pools, blocks):
-    """The stack as ``layer_types`` spells it: an outer scan over the
-    pattern's periods whose body scans each run of one kind. ``layers`` is
-    the weights stacked by kind; a layer takes its own by its index into
-    them (what a scan's per-step slice of its inputs is), so no period's
-    slab is cut out on the way. ``blocks[kind](x, weights, pools, index) ->
-    (x, pools)``; ``pools`` (None without a cache) is the carry of every
-    scan, beside ``x``."""
-    periods, runs, per_period = layer_plan(cfg.layer_types)
-
-    def run(kind, carry, first, count):
-        def step(carry, index):
-            w = jax.tree.map(lambda p: lax.dynamic_index_in_dim(
-                p, index, 0, keepdims=False), layers[KINDS[kind]])
-            x, pools = carry
-            return blocks[kind](x, w, pools, index), None
-
-        return lax.scan(step, carry,
-                        first + jnp.arange(count, dtype=jnp.int32))[0]
-
-    def period(carry, p):
-        for kind, first, count in runs:
-            carry = run(kind, carry, p * per_period[kind] + first, count)
-        return carry, None
-
-    with jax.named_scope("kv_write"):   # as _paged.scan_layers names its scan
-        return lax.scan(period, (x, pools),
-                        jnp.arange(periods, dtype=jnp.int32))[0]
+    """The stack as ``layer_types`` spells it (``_paged.scan_nest``), the
+    weights stacked by kind under ``KINDS``' keys."""
+    return scan_nest(cfg.layer_types,
+                     {kind: layers[key] for kind, key in KINDS.items()},
+                     x, pools, blocks)
 
 
 def _compute_layers(cfg, params, compute_dtype):
@@ -533,12 +578,7 @@ def state_slot_bytes(cfg: GraniteHybridConfig) -> int:
     layer (the state and, under it, the convolution's tail, in
     ``state_dtype``): what an admission occupies beside its KV blocks. Its
     presence is how a family declares recurrent state to the engine."""
-    return cfg.count("mamba") * _state_sublanes(cfg) * cfg.d_inner \
-        * jnp.dtype(cfg.state_dtype).itemsize
-
-
-def _state_sublanes(cfg) -> int:
-    return cfg.mamba_state + cfg.tail_part[1]
+    return cfg.count("mamba") * cfg.state_row_bytes
 
 
 def init_paged_cache(cfg: GraniteHybridConfig, num_blocks: int,
@@ -553,7 +593,7 @@ def init_paged_cache(cfg: GraniteHybridConfig, num_blocks: int,
         **init_paged_pools(cfg.count("attention"), num_blocks,
                            cfg.num_kv_heads, block_size, cfg.head_size,
                            dtype, lane_pack=True),
-        "ssm": jnp.zeros((m, slots + 1, _state_sublanes(cfg), cfg.d_inner),
+        "ssm": jnp.zeros((m, slots + 1, cfg.state_sublanes, cfg.d_inner),
                          jnp.dtype(cfg.state_dtype))}
 
 
@@ -579,19 +619,8 @@ def apply_paged(cfg: GraniteHybridConfig, params: Params,
         valid = jnp.ones((b, t), bool)
     compute_dtype, layers = _compute_layers(cfg, params, compute_dtype)
     positions = row_positions(block_tables, context_lens, t)
-    pool = cache["ssm"]
-    call = block_tables if isinstance(block_tables, MixedCall) else None
-    if call is None:
-        if slots is None:
-            slots = jnp.arange(b, dtype=jnp.int32)
-        state_rows = ssm.pool_rows(slots, valid[:, 0], pool)
-        fresh = context_lens == 0
-    else:
-        state_rows = (
-            ssm.pool_rows(jnp.arange(call.slots), call.active, pool),
-            ssm.pool_rows(call.chunk_slot[None],
-                          (call.chunk_valid > 0)[None], pool))
-        fresh = (call.lens == 0, (call.chunk_ctx == 0)[None])
+    state_rows, fresh, call = state_call(cache["ssm"], block_tables,
+                                         context_lens, valid, slots)
 
     def mamba(x, w, pools, index):
         return _mamba_paged(cfg, x, w, pools, index, state_rows, fresh,

@@ -1207,14 +1207,14 @@ def granitemoehybrid_config_from_hf(hf_config) -> "Any":
     from .granite_hybrid import GraniteHybridConfig
 
     get = lambda key, default=None: getattr(hf_config, key, default)
-    if get("num_local_experts", 0) or get("mamba_n_groups", 1) != 1 \
+    if get("num_local_experts", 0) \
             or get("attention_bias") or get("mamba_proj_bias") \
             or get("position_embedding_type", "nope") != "nope" \
             or not get("tie_word_embeddings", True):
         raise ValueError(
             "models/granite_hybrid.py runs the dense hybrid as Granite-4.0-H-"
-            "Micro publishes it: no sparse branch, one group of B and C, no "
-            "biases on the projections, no positional embedding, a tied head")
+            "Micro publishes it: no sparse branch, no biases on the "
+            "projections, no positional embedding, a tied head")
     return GraniteHybridConfig(
         vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
         intermediate_size=hf_config.shared_intermediate_size,
@@ -1230,6 +1230,7 @@ def granitemoehybrid_config_from_hf(hf_config) -> "Any":
         mamba_heads=hf_config.mamba_n_heads,
         mamba_head_dim=hf_config.mamba_d_head,
         mamba_state=hf_config.mamba_d_state,
+        mamba_groups=get("mamba_n_groups", 1),
         mamba_conv=hf_config.mamba_d_conv,
         mamba_chunk=hf_config.mamba_chunk_size)
 
@@ -1289,6 +1290,118 @@ def granitemoehybrid_params_from_hf(src, cfg) -> Params:
     return params
 
 
+def nemotron_h_config_from_hf(hf_config) -> "Any":
+    """HF ``NemotronHConfig`` -> ``models/nemotron_h.NemotronHConfig``. What
+    the module does not have is refused, never dropped."""
+    from .nemotron_h import NemotronHConfig
+
+    get = lambda key, default=None: getattr(hf_config, key, default)
+    if get("attention_bias") or get("mamba_proj_bias") or get("mlp_bias") \
+            or get("use_bias") or get("tie_word_embeddings") \
+            or get("mlp_hidden_act", "relu2") != "relu2" \
+            or get("n_group", 1) != 1 or get("topk_group", 1) != 1 \
+            or get("n_shared_experts", 1) != 1 \
+            or not get("norm_topk_prob", True) \
+            or not get("use_conv_bias", True):
+        raise ValueError(
+            "models/nemotron_h.py runs the hybrid as Nemotron-3-Nano "
+            "publishes it: no biases but the convolution's, relu2 experts of "
+            "two matrices, one group of experts, one shared expert, "
+            "normalised gates, an untied head")
+    return NemotronHConfig(
+        vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
+        pattern=hf_config.hybrid_override_pattern,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        head_dim=hf_config.head_dim,
+        mamba_heads=hf_config.mamba_num_heads,
+        mamba_head_dim=hf_config.mamba_head_dim,
+        mamba_state=hf_config.ssm_state_size,
+        mamba_groups=hf_config.n_groups,
+        mamba_conv=hf_config.conv_kernel, mamba_chunk=hf_config.chunk_size,
+        intermediate_size=hf_config.moe_intermediate_size,
+        shared_intermediate_size=hf_config.
+        moe_shared_expert_intermediate_size,
+        num_experts=hf_config.n_routed_experts,
+        top_k=hf_config.num_experts_per_tok,
+        route_scale=float(hf_config.routed_scaling_factor),
+        max_seq_len=hf_config.max_position_embeddings,
+        rms_norm_eps=float(hf_config.layer_norm_epsilon))
+
+
+def nemotron_h_params_from_hf(src, cfg) -> Params:
+    """HF ``NemotronHForCausalLM`` -> ``models/nemotron_h`` pytree: the
+    layers stacked BY KIND in stack order (every layer's module is
+    ``backbone.layers.N.mixer``, its one norm ``backbone.layers.N.norm``),
+    ``in_proj`` split into its ``[z | xBC]`` and ``dt`` columns, the
+    depthwise ``conv1d`` as ``[K, channels]`` taps, the experts' ``up_proj``
+    / ``down_proj`` stacked into the two-matrix bank (in the program's
+    layout: ``nemotron_h.pad_bank``), the router
+    (``gate.weight``) and its choice bias (``gate.e_score_correction_bias``)
+    in float32."""
+    from .nemotron_h import pad_bank
+
+    sd = _normalize_state_dict(src)
+    lay = "backbone.layers.{i}."
+    by_kind = {kind: [i for i, t in enumerate(cfg.pattern) if t == kind]
+               for kind in "ME*"}
+
+    def one(key, transpose=False):
+        if key not in sd:
+            raise KeyError(f"missing weight {key}")
+        return sd[key].T if transpose else sd[key]
+
+    def stack(kind, suffix, transpose=False):
+        return np.stack([one(lay.format(i=i) + suffix, transpose)
+                         for i in by_kind[kind]])
+
+    def bank(suffix):
+        return np.stack([np.stack([
+            one(lay.format(i=i) + f"mixer.experts.{e}.{suffix}.weight", True)
+            for e in range(cfg.num_experts)]) for i in by_kind["E"]])
+
+    in_proj = stack("M", "mixer.in_proj.weight", transpose=True)
+    split = cfg.d_inner + cfg.conv_dim
+    params: Params = {
+        "embed": one("backbone.embeddings.weight"),
+        "final_norm": one("backbone.norm_f.weight"),
+        "lm_head": one("lm_head.weight", True),
+        "mamba": {
+            "norm": stack("M", "norm.weight"),
+            "in_proj": in_proj[:, :, :split], "dt_proj": in_proj[:, :, split:],
+            # conv1d.weight [channels, 1, K] -> taps [K, channels]
+            "conv_w": stack("M", "mixer.conv1d.weight")[:, :, 0, :]
+            .transpose(0, 2, 1),
+            "conv_b": stack("M", "mixer.conv1d.bias"),
+            "dt_bias": stack("M", "mixer.dt_bias"),
+            "A_log": stack("M", "mixer.A_log"),
+            "D": stack("M", "mixer.D"),
+            "gate_norm": stack("M", "mixer.norm.weight"),
+            "out_proj": stack("M", "mixer.out_proj.weight", True)},
+        "moe": {
+            "norm": stack("E", "norm.weight"),
+            "router": stack("E", "mixer.gate.weight", True)
+            .astype(np.float32),
+            "router_bias": stack("E", "mixer.gate.e_score_correction_bias")
+            .astype(np.float32),
+            **{k: np.asarray(v) for k, v in pad_bank(
+                cfg, bank("up_proj"), bank("down_proj")).items()},
+            "shared_w_up": stack(
+                "E", "mixer.shared_experts.up_proj.weight", True),
+            "shared_w_down": stack(
+                "E", "mixer.shared_experts.down_proj.weight", True)},
+        "attn": {
+            "norm": stack("*", "norm.weight"),
+            **{ours: stack("*", f"mixer.{theirs}.weight", True)
+               for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                    ("wv", "v_proj"), ("wo", "o_proj"))}},
+    }
+    log_dist(f"imported HF nemotron_h weights: "
+             + ", ".join(f"{len(v)} {k!r}" for k, v in by_kind.items())
+             + " layers")
+    return params
+
+
 def resolve_module(family: str):
     """Family name → the ``deepspeed_tpu.models`` module that executes it."""
     from . import bloom, falcon, gpt, gptneox, llama, mixtral
@@ -1301,6 +1414,10 @@ def resolve_module(family: str):
         from . import granite_hybrid
 
         return granite_hybrid
+    if family == "nemotron_h":
+        from . import nemotron_h
+
+        return nemotron_h
     modules = {
         "llama": llama, "mistral": llama, "qwen2": llama, "qwen3": llama,
         "phi3": llama,
@@ -1366,6 +1483,7 @@ _FAMILIES = {
     "exaone4": (exaone4_config_from_hf, exaone4_params_from_hf),
     "granitemoehybrid": (granitemoehybrid_config_from_hf,
                          granitemoehybrid_params_from_hf),
+    "nemotron_h": (nemotron_h_config_from_hf, nemotron_h_params_from_hf),
 }
 
 

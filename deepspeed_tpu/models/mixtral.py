@@ -299,7 +299,7 @@ def _bank_apart(layers, moe_layer):
     if not moe_layer.grouped():
         return layers, {}
     moe = layers["moe"]
-    bank = {n: moe[n] for n in BANK}
+    bank = {n: moe[n] for n in BANK if n in moe}
     rest = {n: w for n, w in moe.items() if n not in BANK}
     return {**layers, "moe": rest}, bank
 

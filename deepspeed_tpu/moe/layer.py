@@ -25,25 +25,51 @@ from .sharded_moe import (GatingOutput, row_groups, row_tile, top_k_gating,
                           top_k_gating_compact)
 
 Params = Dict[str, Any]
-BANK = ("w_gate", "w_up", "w_down")     # the expert bank's leaves, [E, ...]
+# the expert bank's leaves, [E, ...]. A bank has one of two FORMS, and a
+# call reads which off the bank it is given: all three - a SwiGLU,
+# ``down(silu(gate(x)) * up(x))`` - or ``w_up`` and ``w_down`` alone: the
+# two-matrix ``down(relu(up(x)) ** 2)`` (Nemotron-H's ``relu2`` experts). A
+# shared expert (``shared_w_*``) has the form its own leaves give the same way.
+BANK = ("w_gate", "w_up", "w_down")
 
 
 def init_moe_ffn(rng: jax.Array, n_experts: int, hidden: int, intermediate: int,
-                 dtype=jnp.float32, routed: Optional[int] = None) -> Params:
-    """Expert SwiGLU FFN bank [E, ...] + router [H, E]. ``routed``: the
-    router's width where the bank holds only ``n_experts`` of the experts
-    it chooses among (one chip's share: :class:`MoELayer` ``held``)."""
+                 dtype=jnp.float32, routed: Optional[int] = None,
+                 gated: bool = True) -> Params:
+    """Expert FFN bank [E, ...] + router [H, E]: a SwiGLU bank of three
+    matrices an expert, or, not ``gated``, the two-matrix relu^2 form (no
+    ``w_gate``). ``routed``: the router's width where the bank holds only
+    ``n_experts`` of the experts it chooses among (one chip's share:
+    :class:`MoELayer` ``held``)."""
     ks = jax.random.split(rng, 4)
 
     def normal(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
 
-    return {
+    bank = {
         "router": normal(ks[0], (hidden, routed or n_experts), hidden),
         "w_gate": normal(ks[1], (n_experts, hidden, intermediate), hidden),
         "w_up": normal(ks[2], (n_experts, hidden, intermediate), hidden),
         "w_down": normal(ks[3], (n_experts, intermediate, hidden), intermediate),
     }
+    if not gated:
+        del bank["w_gate"]
+    return bank
+
+
+def relu2(u):
+    """``relu(u) ** 2``, the activation and its square each a value of
+    ``u``'s type (as the slab form's ``xe @ w`` rounds a matmul's result)."""
+    r = jax.nn.relu(u)
+    return r * r
+
+
+def expert_ffn(w_gate, w_up, w_down, x):
+    """One expert (or a dense FFN of an expert's form) over its rows: the
+    SwiGLU, or the two-matrix relu^2 form where ``w_gate`` is None."""
+    if w_gate is None:
+        return relu2(x @ w_up) @ w_down
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
 def moe_ffn_logical_axes() -> Params:
@@ -68,7 +94,10 @@ class MoELayer:
     """Functional MoE FFN. Call with params from :func:`init_moe_ffn`.
 
     Returns (output, aux_loss). Use inside a transformer block in place of the
-    dense FFN; add ``aux_loss_coef * aux_loss`` to the training loss.
+    dense FFN; add ``aux_loss_coef * aux_loss`` to the training loss. The
+    experts' form (a SwiGLU, or two matrices and relu^2), a score-correction
+    bias on the router's choice (``params["router_bias"]``) and a float32
+    router are read off ``params`` (``BANK``), not options of the layer.
 
     Two forms of one function (:meth:`grouped` says which a call takes). A
     call that may drop tokens builds capacity SLABS, ``[E, C, H]``: a row
@@ -164,29 +193,41 @@ class MoELayer:
         backward needs a transpose the grouped kernel has not)."""
         b, s, h = x.shape
         tokens = x.reshape(b * s, h)
-        bank = [params[n].astype(tokens.dtype) for n in BANK]
+        # (``w_gate`` None: a two-matrix bank, ``BANK``)
+        bank = [params[n].astype(tokens.dtype) if n in params else None
+                for n in BANK]
         # moe_router: router logits, gating and the dispatch of tokens to
         # expert rows; moe_experts: the expert bank and the combine
         with jax.named_scope("moe_router"):
-            logits = tokens @ params["router"].astype(tokens.dtype)
+            router = params["router"]
+            if router.dtype == jnp.float32 != tokens.dtype:
+                # a router kept in float32 beside narrower weights is
+                # applied to float32 rows, as its family publishes it
+                logits = jnp.dot(tokens.astype(jnp.float32), router,
+                                 precision=lax.Precision.HIGHEST)
+            else:
+                logits = tokens @ router.astype(tokens.dtype)
+        bias = params.get("router_bias")     # enters the CHOICE alone
         if layer is not None and self.grouped():
-            out, aux_loss = self._grouped(tokens, logits, bank, layer)
+            out, aux_loss = self._grouped(tokens, logits, bank, layer, bias)
         else:
             if layer is not None:
-                bank = [w[layer] for w in bank]
-            out, aux_loss = self._slabs(tokens, logits, bank)
+                bank = [w if w is None else w[layer] for w in bank]
+            out, aux_loss = self._slabs(tokens, logits, bank, bias)
         if self.route_scale is not None:
             out = out * jnp.asarray(self.route_scale, out.dtype)
-        # shared experts: a dense SwiGLU added to every token (params
-        # present only when used) - Qwen2-MoE's under a learned sigmoid
-        # gate; without a gate of its own (cohere2_moe: ``shared_scale``) a
-        # plain FFN, which runs under that scope
-        if "shared_w_gate" in params:
+        # shared experts: a dense FFN of an expert's form (a SwiGLU, or two
+        # matrices and relu^2 where ``shared_w_gate`` is absent) added to
+        # every token (params present only when used) - Qwen2-MoE's under a
+        # learned sigmoid gate; without a gate of its own (cohere2_moe:
+        # ``shared_scale``) a plain FFN, which runs under that scope
+        if "shared_w_up" in params:
             gated = "shared_gate" in params
             with jax.named_scope("moe_experts" if gated else "ffn"):
-                sg = jax.nn.silu(tokens @ params["shared_w_gate"].astype(tokens.dtype))
-                su = tokens @ params["shared_w_up"].astype(tokens.dtype)
-                shared = (sg * su) @ params["shared_w_down"].astype(tokens.dtype)
+                shared = expert_ffn(*(
+                    params["shared_" + n].astype(tokens.dtype)
+                    if "shared_" + n in params else None for n in BANK),
+                    tokens)
                 if gated:
                     gate = jax.nn.sigmoid(tokens @ params["shared_gate"].astype(tokens.dtype))
                     shared = gate * shared
@@ -202,14 +243,15 @@ class MoELayer:
                     drop_tokens=self.drop_tokens, norm_topk=self.norm_topk,
                     score=self.score, groups=self.groups)
 
-    def _grouped(self, tokens, logits, bank, layer):
+    def _grouped(self, tokens, logits, bank, layer, bias=None):
         """The no-drop form: O(k·T·H) movement around a bank that computes
         the routed rows (and what pads each expert's rows to whole tiles)
         alone; no ``[T, E, C]`` tensor exists."""
         T, h = tokens.shape
         with jax.named_scope("moe_router"):
-            cg = top_k_gating_compact(logits, self.top_k, **self._gate_kw())
-            held, inter = bank[0].shape[-3], bank[0].shape[-1]
+            cg = top_k_gating_compact(logits, self.top_k, bias=bias,
+                                      **self._gate_kw())
+            held, inter = bank[1].shape[-3], bank[1].shape[-1]
             groups = row_groups(
                 cg, row_tile(T, self.n_experts, self.top_k, held, inter),
                 self.held)
@@ -232,7 +274,7 @@ class MoELayer:
                           axis=1).astype(tokens.dtype)
         return out, cg.aux_loss
 
-    def _slabs(self, tokens, logits, bank):
+    def _slabs(self, tokens, logits, bank, bias=None):
         """The capacity form: every expert a ``[C, H]`` slab of slots."""
         T, h = tokens.shape
         with jax.named_scope("moe_router"):
@@ -243,7 +285,7 @@ class MoELayer:
                 # per-slot gate come from two scatters — the computation the
                 # reference's moe_scatter/top_k_gating kernels perform
                 # (inference/v2/kernels/ragged_ops)
-                cg = top_k_gating_compact(logits, self.top_k,
+                cg = top_k_gating_compact(logits, self.top_k, bias=bias,
                                           **self._gate_kw())
                 aux_loss = cg.aux_loss
                 E, C = self.n_experts, cg.capacity
@@ -262,7 +304,8 @@ class MoELayer:
                 expert_in = toks_z[token_for]                         # gather
             else:
                 gating: GatingOutput = top_k_gating(
-                    logits, self.top_k, held=self.held, **self._gate_kw())
+                    logits, self.top_k, held=self.held, bias=bias,
+                    **self._gate_kw())
                 aux_loss = gating.aux_loss
                 expert_in = jnp.einsum(
                     "tec,th->ech", gating.dispatch_mask.astype(tokens.dtype),
@@ -272,12 +315,9 @@ class MoELayer:
 
             # expert FFN bank, vmapped over E (each expert's compute lands on its
             # own 'expert' shard)
-            def ffn(w_gate, w_up, w_down, xe):
-                g = jax.nn.silu(xe @ w_gate)
-                u = xe @ w_up
-                return (g * u) @ w_down
-
-            expert_out = _expert_constraint(jax.vmap(ffn)(*bank, expert_in))
+            expert_out = _expert_constraint(jax.vmap(
+                expert_ffn, in_axes=(None if bank[0] is None else 0, 0, 0,
+                                     0))(*bank, expert_in))
 
             # combine: back to [T, H]  (a2a back)
             if self.dispatch == "compact":

@@ -76,7 +76,8 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
                          drop_tokens: bool = True,
                          norm_topk: bool = True,
                          score: str = "softmax",
-                         groups: Optional[Tuple[int, int]] = None
+                         groups: Optional[Tuple[int, int]] = None,
+                         bias: Optional[jnp.ndarray] = None
                          ) -> CompactGating:
     """logits: [tokens, experts] → compact assignment (see CompactGating).
 
@@ -103,10 +104,13 @@ def top_k_gating_compact(logits: jnp.ndarray, k: int = 1, *,
                          f"got {score!r}")
     probs = SCORES[score](logits.astype(jnp.float32))
 
-    if groups is None:
+    if groups is None and bias is None:
         topk_probs, topk_idx = jax.lax.top_k(probs, k)      # [T, k]
     else:
-        topk_idx = jax.lax.top_k(_group_limited(probs, *groups), k)[1]
+        choice = probs if bias is None else probs + bias.astype(jnp.float32)
+        if groups is not None:
+            choice = _group_limited(choice, *groups)
+        topk_idx = jax.lax.top_k(choice, k)[1]
         topk_probs = jnp.take_along_axis(probs, topk_idx, axis=1)
     if norm_topk:
         # renormalize the selected gates (reference top2: gates /= denom)
@@ -238,7 +242,8 @@ def top_k_gating(logits: jnp.ndarray, k: int = 1, *,
                  norm_topk: bool = True,
                  held: Optional[Tuple[int, int]] = None,
                  score: str = "softmax",
-                 groups: Optional[Tuple[int, int]] = None) -> GatingOutput:
+                 groups: Optional[Tuple[int, int]] = None,
+                 bias: Optional[jnp.ndarray] = None) -> GatingOutput:
     """Dense [T, E, C] view of :func:`top_k_gating_compact` — the form the
     einsum dispatch contracts with (MXU-friendly, but O(T·E·C) memory).
     ``held = (first, count)``: the masks of experts ``first .. first + count
@@ -247,7 +252,7 @@ def top_k_gating(logits: jnp.ndarray, k: int = 1, *,
     cg = top_k_gating_compact(logits, k, capacity_factor=capacity_factor,
                               min_capacity=min_capacity,
                               drop_tokens=drop_tokens, norm_topk=norm_topk,
-                              score=score, groups=groups)
+                              score=score, groups=groups, bias=bias)
     tokens, n_experts = logits.shape
     chosen = cg.topk_idx
     if held is not None:

@@ -25,6 +25,10 @@ of gate and of up and one ``[tf, H]`` block of down, and the tile's result
 accumulates in VMEM over the F blocks. A tile past ``num_tiles`` skips its
 compute and folds every index onto the last step in use, so Pallas elides
 its DMAs: an expert's weights come once a tile of its rows.
+
+A bank of TWO matrices (``w_gate`` None: ``moe/layer.py BANK``) computes
+``relu(x @ w_up[e]) ** 2 @ w_down[e]`` the same way, one block of up and one
+of down a step; the budgets count the matrices the bank has.
 """
 
 from __future__ import annotations
@@ -54,13 +58,15 @@ _BLOCK_VMEM = 6 << 20
 _MATMUL = (((1,), (0,)), ((), ()))
 
 
-def f_block(hidden: int, inter: int, itemsize: int = 2) -> int:
+def f_block(hidden: int, inter: int, itemsize: int = 2,
+            matrices: int = 3) -> int:
     """Columns of gate / up (rows of down) a grid step reads: all of them
     where an expert's matrices fit ``_WHOLE_VMEM`` twice over (or ``inter``
     is no multiple of 128: a block is then the whole dimension), else the
-    largest multiple of 128 that divides ``inter`` whose three blocks fit
-    ``_BLOCK_VMEM`` twice over (128 at the least)."""
-    step = 6 * hidden * itemsize            # bytes a column, double-buffered
+    largest multiple of 128 that divides ``inter`` whose blocks (one a
+    matrix of the bank: ``matrices``) fit ``_BLOCK_VMEM`` twice over (128 at
+    the least)."""
+    step = 2 * matrices * hidden * itemsize  # bytes a column, double-buffered
     if inter % 128 or inter * step <= _WHOLE_VMEM:
         return inter
     return max(tf for tf in range(128, inter, 128)
@@ -76,9 +82,19 @@ def _swiglu_rounded(g, u, dtype):
             * u).astype(dtype)
 
 
-def _kernel(tile_expert, tile_rows, num_tiles, layer, x_ref, wg_ref, wu_ref,
-            wd_ref, o_ref, acc, *, nf, sub):
+def _relu2_rounded(u, dtype):
+    """``relu(u) ** 2`` of a float32 accumulator with the roundings of the
+    slab form (``moe/layer.py relu2``): the matmul's result and the square
+    are ``dtype`` values."""
+    r = jax.nn.relu(u.astype(dtype))
+    return r * r
+
+
+def _kernel(tile_expert, tile_rows, num_tiles, layer, x_ref, *refs, nf, sub):
+    """``refs``: the bank's weight blocks (gate, up, down - or up, down of a
+    two-matrix bank), the result and the accumulator."""
     del tile_expert, layer          # the index maps read them
+    *gate_up, wd_ref, o_ref, acc = refs
     t, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t < num_tiles[0])
@@ -89,8 +105,9 @@ def _kernel(tile_expert, tile_rows, num_tiles, layer, x_ref, wg_ref, wu_ref,
             @pl.when(r * sub < tile_rows[t])
             def _sub_tile(rows=slice(r * sub, (r + 1) * sub)):
                 x = x_ref[rows, :]
-                h = _swiglu_rounded(mxu_dot(x, wg_ref[...], _MATMUL),
-                                    mxu_dot(x, wu_ref[...], _MATMUL), x.dtype)
+                ups = [mxu_dot(x, w[...], _MATMUL) for w in gate_up]
+                h = _swiglu_rounded(*ups, x.dtype) if len(ups) == 2 \
+                    else _relu2_rounded(*ups, x.dtype)
                 part = mxu_dot(h, wd_ref[...], _MATMUL)
 
                 @pl.when(f == 0)
@@ -111,16 +128,19 @@ def moe_grouped_matmul(x: jnp.ndarray, w_gate: jnp.ndarray,
                        tile_expert: jnp.ndarray, tile_rows: jnp.ndarray,
                        num_tiles: jnp.ndarray, layer, *,
                        tile: int) -> jnp.ndarray:
-    """See the module docstring. ``x [tiles * tile, H]``; ``w_gate``, ``w_up``
-    ``[L, E, H, F]`` and ``w_down [L, E, F, H]``; ``tile_expert``,
-    ``tile_rows`` ``[tiles]``, ``num_tiles`` and ``layer`` (scalars, int or
-    traced) int32. Returns ``[tiles * tile, H]`` in ``x``'s dtype."""
+    """See the module docstring. ``x [tiles * tile, H]``; ``w_gate`` (None
+    in a two-matrix bank), ``w_up`` ``[L, E, H, F]`` and ``w_down [L, E, F,
+    H]``; ``tile_expert``, ``tile_rows`` ``[tiles]``, ``num_tiles`` and
+    ``layer`` (scalars, int or traced) int32. Returns ``[tiles * tile, H]``
+    in ``x``'s dtype."""
     places, hidden = x.shape
-    n_exp, inter = w_gate.shape[1], w_gate.shape[3]
+    n_exp, inter = w_up.shape[1], w_up.shape[3]
     tiles = places // tile
     assert tiles * tile == places and tiles > 0
     size = x.dtype.itemsize
-    tf = f_block(hidden, inter, size)
+    gate_up = [w_up] if w_gate is None else [w_gate, w_up]
+    matrices = len(gate_up) + 1
+    tf = f_block(hidden, inter, size, matrices)
     nf = inter // tf
 
     # index maps are called with one trailing arg per prefetched scalar
@@ -141,7 +161,7 @@ def moe_grouped_matmul(x: jnp.ndarray, w_gate: jnp.ndarray,
             return (layer[0], e, f, 0) if down else (layer[0], e, 0, f)
         return block
 
-    need = (6 * hidden * tf * size          # three weight blocks, twice
+    need = (2 * matrices * hidden * tf * size   # the weight blocks, twice
             + 4 * tile * hidden * size      # the row tile in and out, twice
             + tile * hidden * 4             # the accumulator
             + 4 * tile * tf * 4)            # a step's gate, up and product
@@ -151,8 +171,8 @@ def moe_grouped_matmul(x: jnp.ndarray, w_gate: jnp.ndarray,
             num_scalar_prefetch=4, grid=(tiles, nf),
             in_specs=[
                 pl.BlockSpec((tile, hidden), rows),
-                pl.BlockSpec((None, None, hidden, tf), weights(False)),
-                pl.BlockSpec((None, None, hidden, tf), weights(False)),
+                *(pl.BlockSpec((None, None, hidden, tf), weights(False))
+                  for _ in gate_up),
                 pl.BlockSpec((None, None, tf, hidden), weights(True))],
             out_specs=pl.BlockSpec((tile, hidden), rows),
             scratch_shapes=[pltpu.VMEM((tile, hidden), jnp.float32)]),
@@ -164,7 +184,7 @@ def moe_grouped_matmul(x: jnp.ndarray, w_gate: jnp.ndarray,
         name="moe_grouped_matmul",
     )(tile_expert.astype(jnp.int32), tile_rows.astype(jnp.int32),
       jnp.asarray(num_tiles, jnp.int32).reshape(1),
-      jnp.asarray(layer, jnp.int32).reshape(1), x, w_gate, w_up, w_down)
+      jnp.asarray(layer, jnp.int32).reshape(1), x, *gate_up, w_down)
 
 
 def moe_grouped_matmul_xla(x: jnp.ndarray, w_gate: jnp.ndarray,
@@ -176,13 +196,18 @@ def moe_grouped_matmul_xla(x: jnp.ndarray, w_gate: jnp.ndarray,
     gathered a tile at a time (``[tiles, H, F]``: for the CPU's sizes), the
     tiles not in use zero (``tile_rows`` only spares the kernel work)."""
     del tile_rows
-    w_gate, w_up, w_down = (w[layer] for w in (w_gate, w_up, w_down))
+    w_gate, w_up, w_down = (w if w is None else w[layer]
+                            for w in (w_gate, w_up, w_down))
     tiles = x.shape[0] // tile
     xt = x.reshape(tiles, tile, x.shape[1])
-    experts = jnp.clip(tile_expert, 0, w_gate.shape[0] - 1)
-    g = jax.nn.silu(jnp.einsum("tmh,thf->tmf", xt, w_gate[experts]))
-    u = jnp.einsum("tmh,thf->tmf", xt, w_up[experts])
-    y = jnp.einsum("tmf,tfh->tmh", g * u, w_down[experts])
+    experts = jnp.clip(tile_expert, 0, w_up.shape[0] - 1)
+    if w_gate is None:      # a two-matrix bank: relu(u) ** 2
+        h = jnp.square(jax.nn.relu(
+            jnp.einsum("tmh,thf->tmf", xt, w_up[experts])))
+    else:
+        g = jax.nn.silu(jnp.einsum("tmh,thf->tmf", xt, w_gate[experts]))
+        h = g * jnp.einsum("tmh,thf->tmf", xt, w_up[experts])
+    y = jnp.einsum("tmf,tfh->tmh", h, w_down[experts])
     live = jnp.arange(tiles) < jnp.asarray(num_tiles).reshape(())
     return jnp.where(live[:, None, None], y, 0).reshape(x.shape)
 

@@ -22,6 +22,8 @@ not by ``ops/pallas/__init__``: no other program pays for its import.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -128,13 +130,29 @@ def _column(row_ref):
 
 
 def _decode_kernel(layer, rows, fresh, order, decay, dtx, b_ref, c_ref, h_ref,
-                   h_out, y_out):
+                   h_out, y_out, *, groups, spans):
+    """``groups``: the groups of B and C (``b_ref``, ``c_ref`` ``[groups,
+    N]``); ``spans``: how many of them this ``[N, lanes]`` block spans side
+    by side (1: the block lies inside one group - with one group, all of
+    it)."""
     del layer, rows
     i = order[pl.program_id(1)]
-    h = jnp.where(fresh[i] > 0, 0.0, h_ref[...])            # [N, lanes]
-    h = h * decay[...] + _column(b_ref) * dtx[...]
-    h_out[...] = h
-    y_out[...] = jnp.sum(h * _column(c_ref), axis=0, keepdims=True)
+    width = h_ref.shape[-1] // spans
+    for k in range(spans):
+        at = (slice(None), slice(k * width, (k + 1) * width)) if spans > 1 \
+            else Ellipsis
+        if groups == 1:
+            b_row, c_row = b_ref, c_ref
+        else:
+            # the block's first group: blocks before it times their span,
+            # or several blocks a group
+            g = pl.program_id(0) * spans + k if spans > 1 else \
+                pl.program_id(0) * groups // pl.num_programs(0)
+            b_row, c_row = b_ref.at[pl.ds(g, 1)], c_ref.at[pl.ds(g, 1)]
+        h = jnp.where(fresh[i] > 0, 0.0, h_ref[at])        # [N, width]
+        h = h * decay[at] + _column(b_row) * dtx[at]
+        h_out[at] = h.astype(h_out.dtype)
+        y_out[at] = jnp.sum(h * _column(c_row), axis=0, keepdims=True)
 
 
 def ssm_decode_update(pool, layer, rows, fresh, decay, dtx, B, C):
@@ -147,7 +165,11 @@ def ssm_decode_update(pool, layer, rows, fresh, decay, dtx, B, C):
     writes it back to the aliased pool and reduces it against ``C`` over the
     sublanes into ``y``. ``decay`` and ``dtx`` ``[b, HP]`` float32 arrive
     per lane, so nothing a token brings moves between lanes and sublanes but
-    the ``N`` values of ``B`` and ``C`` (:func:`_column`).
+    the ``N`` values of ``B`` and ``C`` (:func:`_column`). ``B``, ``C``
+    ``[b, G, N]``: ``G`` groups, each its own ``HP / G`` lanes' - a step
+    takes the row's ``[G, N]`` whole (1 KB a vector) and gives each group
+    its lanes of the block (a 2048-lane block of Nemotron-3's 4096 spans
+    four 512-lane groups); nothing is expanded per lane in HBM.
 
     The rows are walked live ones first (``order``), the rows aimed at the
     trash row last and one after another: consecutive steps on one block
@@ -155,7 +177,13 @@ def ssm_decode_update(pool, layer, rows, fresh, decay, dtx, B, C):
     state traffic (at 56 of 64 slots live, an eighth of the call's)."""
     b, n, width = rows.shape[0], B.shape[-1], pool.shape[3]
     lanes = _lane_block(width)
+    groups = 1 if B.ndim == 2 else B.shape[1]
+    spans = max(1, lanes * groups // width)
+    assert width % groups == 0 and (
+        lanes % (width // groups) == 0 or (width // groups) % lanes == 0), \
+        (width, groups, lanes)
     vec = lambda a: a.astype(jnp.float32).reshape(b, 1, -1)
+    grouped = lambda a: a.astype(jnp.float32).reshape(b, groups, n)
     order = jnp.argsort(rows == pool.shape[1] - 1, stable=True)
 
     def row(j, i, layer, rows, fresh, order):     # a call row's vectors
@@ -165,10 +193,10 @@ def ssm_decode_update(pool, layer, rows, fresh, decay, dtx, B, C):
         return (layer[0], rows[order[i]], 0, j)
 
     per_lane = pl.BlockSpec((None, 1, lanes), row)
-    whole = pl.BlockSpec((None, 1, n), lambda j, i, *s: (s[3][i], 0, 0))
+    whole = pl.BlockSpec((None, groups, n), lambda j, i, *s: (s[3][i], 0, 0))
     block = pl.BlockSpec((None, None, n, lanes), state)
     pool, y = pl.pallas_call(
-        _decode_kernel,
+        functools.partial(_decode_kernel, groups=groups, spans=spans),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(width // lanes, b),
             in_specs=[per_lane, per_lane, whole, whole, block],
@@ -179,8 +207,8 @@ def ssm_decode_update(pool, layer, rows, fresh, decay, dtx, B, C):
         compiler_params=_dim_semantics("arbitrary", "arbitrary"),
         interpret=_interpret(),
         name="ssm_decode_update",
-    )(*_scalars(layer, rows, fresh, order), vec(decay), vec(dtx), vec(B),
-      vec(C), pool)
+    )(*_scalars(layer, rows, fresh, order), vec(decay), vec(dtx),
+      grouped(B), grouped(C), pool)
     return pool, y[:, 0]
 
 

@@ -6,8 +6,12 @@ A head with input ``x_t`` in R^P, state ``H`` in R^(P x N), one scalar
 
     H_t = exp(dt_t A) H_(t-1) + dt_t x_t B_t^T        y_t = H_t C_t
 
-(``B_t``, ``C_t`` in R^N are shared by the heads of a group; the skip
-``D x_t`` is the caller's). Two forms of it live here:
+(``B_t``, ``C_t`` in R^N are shared by the heads of a GROUP: ``G`` groups of
+``H / G`` neighbouring heads each, head ``h`` reads group ``h // (H / G)``.
+Every function here takes ``B`` and ``C`` with ONE group as ``[.., N]`` -
+the program it traces is then what it was before groups were written - or
+with ``G`` as ``[.., G, N]``. The skip ``D x_t`` is the caller's). Two forms
+of it live here:
 
 - :func:`ssd_chunked_scan`: many tokens a call, in blocks ("SSD",
   arXiv:2405.21060 section 6): inside a block the tokens meet through one
@@ -71,14 +75,30 @@ def state_from_heads(h):
 # --------------------------------------------------------------------------- #
 # many tokens a call
 # --------------------------------------------------------------------------- #
+def _by_group(scan, x, dt, A, B, C, h0, *more):
+    """``scan`` (one group's: ``B``, ``C`` ``[b, t, N]``) over the ``G``
+    groups of ``B``, ``C`` ``[b, t, G, N]``: the heads split ``[G, H / G]``
+    and each group's run beside the others."""
+    b, t, H, P = x.shape
+    G = B.shape[2]
+    assert H % G == 0, (H, G)
+    y, h_t = jax.vmap(lambda *a: scan(*a, *more),
+                      in_axes=(2, 2, 0, 2, 2, 1), out_axes=(2, 1))(
+        x.reshape(b, t, G, H // G, P), dt.reshape(b, t, G, H // G),
+        A.reshape(G, H // G), B, C,
+        h0.reshape(b, G, H // G, P, h0.shape[-1]))
+    return y.reshape(b, t, H, P), h_t.reshape(h0.shape)
+
+
 def ssd_chunked_scan(x, dt, A, B, C, h0, chunk: int) -> Tuple:
     """The recurrence over ``t`` tokens of ``b`` rows in blocks of ``chunk``.
 
     ``x [b, t, H, P]``; ``dt [b, t, H]`` float32, after its softplus, and 0
     on a row's padding (a token with ``dt = 0`` neither decays nor feeds the
     state, so the state after the call is the state after the real tokens);
-    ``A [H]`` float32, negative; ``B``, ``C`` ``[b, t, N]``; ``h0 [b, H, P,
-    N]`` float32. Returns ``(y [b, t, H, P] float32, h_t [b, H, P, N])``.
+    ``A [H]`` float32, negative; ``B``, ``C`` ``[b, t, N]`` (one group) or
+    ``[b, t, G, N]``; ``h0 [b, H, P, N]`` float32. Returns ``(y [b, t, H, P]
+    float32, h_t [b, H, P, N])``.
 
     Per block, with ``cs`` the running sum of ``dt A`` inside it: token s
     reaches token t >= s decayed by ``exp(cs_t - cs_s)``, so ``y`` inside a
@@ -86,6 +106,8 @@ def ssd_chunked_scan(x, dt, A, B, C, h0, chunk: int) -> Tuple:
     head -, the state entering the block adds ``exp(cs_t) H C_t``, and the
     block leaves ``exp(cs_q) H + sum_s exp(cs_q - cs_s) dt_s x_s B_s^T``.
     Every exponent is <= 0: nothing here can overflow."""
+    if B.ndim == 4:
+        return _by_group(ssd_chunked_scan, x, dt, A, B, C, h0, chunk)
     b, t, H, P = x.shape
     q = min(chunk, t)
     pad = -t % q
@@ -127,6 +149,8 @@ def ssd_chunked_scan(x, dt, A, B, C, h0, chunk: int) -> Tuple:
 def ssm_recurrence(x, dt, A, B, C, h0) -> Tuple:
     """The same recurrence a token at a time (``lax.scan`` over ``t``):
     what :func:`ssd_chunked_scan` is tested against."""
+    if B.ndim == 4:
+        return _by_group(ssm_recurrence, x, dt, A, B, C, h0)
     A = A.astype(F32)
 
     def step(h, token):
@@ -163,17 +187,27 @@ def state_rows_write_xla(pool, layer, rows, new, part):
         new.astype(pool.dtype))
 
 
+def _per_lane(vectors, h):
+    """``B`` or ``C`` against the state ``h [b, N, HP]``: ``[b, N, 1]`` of
+    one group, ``[b, N, HP]`` - each group's over its own lanes - of more."""
+    vectors = vectors.astype(F32)
+    if vectors.ndim == 2:
+        return vectors[:, :, None]
+    return jnp.repeat(vectors.swapaxes(1, 2),
+                      h.shape[-1] // vectors.shape[1], axis=2)
+
+
 def ssm_decode_update_xla(pool, layer, rows, fresh, decay, dtx, B, C):
     """One token of ``b`` rows on the state pool ``[L, S + 1, >= N, HP]``:
     row i's state, the first ``N`` sublanes of ``[layer, rows[i]]`` (zeros
     where ``fresh[i]``: a sequence's first token), becomes ``decay[i] * H + B[i] dtx[i]^T``
     (``decay``, ``dtx`` ``[b, HP]`` float32, per lane; ``B``, ``C`` ``[b,
-    N]``) and reads out ``y[i] = C[i]^T H``. Returns ``(pool, y [b, HP]
-    float32)``."""
+    N]``, or ``[b, G, N]``: group ``g``'s over its ``HP / G`` lanes) and
+    reads out ``y[i] = C[i]^T H``. Returns ``(pool, y [b, HP] float32)``."""
     layer, n = _layer(layer), B.shape[-1]
     h = jnp.where(fresh[:, None, None], 0.0, pool[layer, rows, :n])
-    h = h * decay[:, None, :] + B.astype(F32)[:, :, None] * dtx[:, None, :]
-    y = jnp.sum(h * C.astype(F32)[:, :, None], axis=1)
+    h = h * decay[:, None, :] + _per_lane(B, h) * dtx[:, None, :]
+    y = jnp.sum(h * _per_lane(C, h), axis=1)
     return pool.at[layer, rows, :n].set(h.astype(pool.dtype)), y
 
 

@@ -337,7 +337,15 @@ TRACER_SPANS = frozenset((
     # ``chunk_attn_tiles_{live, grid, table}``, beside the decode walk's
     # ``attn_tiles_{live, grid}``, and the KV tokens a grid step of that
     # walk took as ``chunk_attn_kv_tile`` (256, or the wide tile of a long
-    # walk; plain numbers; docs/observability.md)
+    # walk; plain numbers; docs/observability.md). A family with recurrent
+    # state AND experts (models/nemotron_h.py, the first with both) carries
+    # ``ssm_rows`` / ``ssm_tokens`` and ``moe_rows_routed`` /
+    # ``moe_rows_computed`` / ``moe_row_tile`` on the SAME ``decode_step``,
+    # ``prefill_chunk`` and ``prefill_batch`` spans: the benchmark's
+    # ``ssm_grouped_decode_roofline`` reads ``decode_step.ssm_rows``, its
+    # ``moe_relu2_experts_roofline`` and ``moe_padded_row_share`` the
+    # ``moe_rows_*`` of all three (docs/observability.md "state and experts
+    # in one family")
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

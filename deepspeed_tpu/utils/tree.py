@@ -6,11 +6,16 @@ import jax
 import jax.numpy as jnp
 
 
-def cast_floating(tree, dtype):
-    """astype(dtype) on floating leaves; everything else untouched."""
-    return jax.tree.map(
-        lambda x: x.astype(dtype)
-        if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else x, tree)
+def cast_floating(tree, dtype, keep=()):
+    """astype(dtype) on floating leaves; everything else untouched, and so
+    is a leaf whose own key (the last of its path) is in ``keep``."""
+    def cast(path, x):
+        if keep and getattr(path[-1], "key", None) in keep:
+            return x
+        return x.astype(dtype) \
+            if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else x
+
+    return jax.tree_util.tree_map_with_path(cast, tree)
 
 
 def path_to_str(path, sep: str = ".") -> str:

@@ -1315,3 +1315,129 @@ def test_the_latent_pool_stays_where_it_is_in_the_mixed_program(v5e):
     for name, count in (("paged_kv_write", 4), ("paged_decode", 2),
                         ("paged_prefill", 2), ("moe_grouped_matmul", 1)):
         assert calls.count(name) == count, (name, calls.count(name))
+
+
+# --- Nemotron-3-Nano: state and experts in one stack (ISSUE 50) ------------ #
+NEMOTRON_CELL = "nemotron-3-nano-30b-a3b.serve-reason-64"
+
+
+def _grouped_state_update(pool, layer, rows, fresh, decay, dtx, B, C):
+    from deepspeed_tpu.ops.pallas.ssm import ssm_decode_update
+
+    return ssm_decode_update(pool, layer, rows, fresh, decay, dtx, B, C)
+
+
+def _two_matrix_bank(tile):
+    def call(x, w_up, w_down, tile_expert, tile_rows, num_tiles, layer):
+        from deepspeed_tpu.ops.pallas.grouped_matmul import \
+            moe_grouped_matmul
+
+        return moe_grouped_matmul(x, None, w_up, w_down, tile_expert,
+                                  tile_rows, num_tiles, layer, tile=tile)
+    return call
+
+
+def _bank_args(rows, tile, inter):
+    tiles = (rows * 6 + 8 * (tile - 1)) // tile
+    i32 = jnp.int32
+    return (((tiles * tile, 2688), jnp.bfloat16),
+            ((23, 8, 2688, inter), jnp.bfloat16),
+            ((23, 8, inter, 2688), jnp.bfloat16), ((tiles,), i32),
+            ((tiles,), i32), ((), i32), ((), i32))
+
+
+# the state update over 8 groups of B and C at the cell's pool (a 2048-lane
+# block spans four 512-lane groups); the two-matrix grouped matmul at 2688 /
+# 1920 (the bank's layout: 1856 in whole lane tiles) over a decode's rows and
+# a mixed call's, and at the published 1856 whole (Mosaic takes it: it is the
+# STACK's layout in HBM that wants whole tiles, the mixed program below)
+NEMOTRON_KERNELS = {
+    "ssm_decode_update_8_groups": (_grouped_state_update, (
+        ((23, 65, 136, 4096), jnp.float32), ((), jnp.int32),
+        ((64,), jnp.int32), ((64,), jnp.bool_), ((64, 4096), jnp.float32),
+        ((64, 4096), jnp.float32), ((64, 8, 128), jnp.bfloat16),
+        ((64, 8, 128), jnp.bfloat16))),
+    "relu2_bank_decode_rows": (_two_matrix_bank(64), _bank_args(64, 64, 1920)),
+    "relu2_bank_mixed_rows": (_two_matrix_bank(256),
+                              _bank_args(576, 256, 1920)),
+    "relu2_bank_unpadded_1856": (_two_matrix_bank(64),
+                                 _bank_args(64, 64, 1856)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NEMOTRON_KERNELS))
+def test_nemotron_kernel_compiles_for_v5e(v5e, case):
+    fn, shapes = NEMOTRON_KERNELS[case]
+    compiled = _compile(fn, *shapes, device=v5e.devices[0])
+    assert MOSAIC in compiled.as_text(), \
+        f"{case}: compiled without a Mosaic kernel"
+
+
+def test_state_and_experts_stay_where_they_are_in_nemotrons_mixed_program(
+        v5e):
+    """The Nemotron-3-Nano cell's mixed call (64 decode rows + a 512-row
+    chunk) at its real configuration - all 52 layers, 8 of 128 experts, 65
+    rows of state and 8256 KV blocks - compiled for the chip: the three
+    pools stay where they are (no pool-shaped copy, aliased argument-to-
+    result); the expert bank is read where it lies - no buffer of a bank's
+    shape in ANOTHER layout (what the unpadded 1856 columns cost: a copy of
+    the whole 1.8 GB of ``w_up`` a program) and next to no temporaries; the
+    nest compiles 6 Mamba and 6 expert bodies and 2 attention bodies for
+    the 23 + 23 + 6 layers; and the program with its 8.2 GB of weights and
+    4.95 GB of pools fits the chip with room for a probe's reference."""
+    import re
+
+    from benchmark.harness.manifest import Cell
+    from deepspeed_tpu.models._paged import MixedCall
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    cell = Cell(NEMOTRON_CELL)
+    engine = cell.role["engine"]
+    ragged = engine["ragged"]
+    slots, bs = ragged["max_tracked_sequences"], ragged["block_size"]
+    chunk = engine["split_prefill_chunk"]
+    cfg = cell.family.build_cfg(cell.model, **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.eval_shape(
+        lambda k: module.init(cfg, k, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    assert params["moe"]["router"].dtype == jnp.float32
+    assert params["moe"]["w_up"].shape == (23, 8, 2688, 1920)
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, ragged["memory_config_blocks"], bs, slots=slots))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (6, 8256, 2, 32, 128), "v": (6, 8256, 2, 32, 128),
+        "ssm": (23, 65, 136, 4096)}
+    table = cfg.max_seq_len // bs
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    call = MixedCall(s((slots, table), i32), s((slots,), i32),
+                     s((slots,), bool), s((table,), i32), s((), i32),
+                     s((), i32), s((), i32))
+    rows = slots + chunk
+
+    def forward(params, cache, tokens, tables, valid, read):
+        return module.apply_paged(cfg, params, tokens, cache, tables, None,
+                                  valid=valid, rows=read)
+
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        (params, cache, s((1, rows), i32), call, s((1, rows), bool),
+         s((1, slots + 1), i32)))
+    compiled = jax.jit(forward, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pools = jax.tree.leaves(args[1])
+    assert pool_copy_bytes(text, pools) == 0
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes > 4.9e9
+    layouts = re.findall(
+        r"bf16\[23,8,(?:2688,1920|1920,2688)\]\{([\d,]+)", text)
+    assert layouts and set(layouts) == {"3,2,1,0"}
+    assert mem.temp_size_in_bytes < 0.2e9
+    assert 12.9e9 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT - 2.5e9
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    for name, count in (("ssm_decode_update", 6), ("moe_grouped_matmul", 6),
+                        ("paged_kv_write", 4), ("paged_decode", 2),
+                        ("paged_prefill", 4)):
+        assert calls.count(name) == count, (name, calls.count(name))
