@@ -941,7 +941,8 @@ class InferenceEngineV2(InferenceEngine):
 
     def _attn_tile_args(self) -> Dict[str, float]:
         """Span arguments of a decode dispatch over the slots as they stand:
-        of ONE layer's ``paged_decode`` call, the KV tiles that hold live
+        of ONE layer's ``paged_decode`` call (``paged_sparse_decode``, the
+        same walk, under a learned selection), the KV tiles that hold live
         context, the tiles the walk takes - the same tiles where it fetches
         its own pages, every slot as far as the longest where it is a grid
         of ``BlockSpec`` pages - and their ratio (the kernel's own tile

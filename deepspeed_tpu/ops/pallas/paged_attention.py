@@ -55,7 +55,12 @@ page out of a pool for a DMA only where the pool's rows are whole 128-lane
 tiles (:func:`_fetches_pages`): int8 pools (their ``[.., bs, ngroups]`` f32
 scales) and plain pools of heads narrower than a tile keep the multi-token
 walk below at one token a sequence and every KV head a step - a grid
-``(B, KV-head blocks, 1, KV tiles)`` as long as the longest context.
+``(B, KV-head blocks, 1, KV tiles)`` as long as the longest context. The
+same walk serves ``paged_sparse_decode`` (``paged_sparse_attention.py``: a
+learned selection's decode rows) with one more mask, whose operands - the
+row's threshold, two more prefetched scalars, and the tile's slice of its
+index scores, one more DMA a tile - exist in the call only where a caller
+hands them (:func:`_page_walk`).
 ``paged_prefill`` shares the flash body (:func:`_flash_update`) at ``tq``
 query tokens a tile and one KV head a step (:func:`_paged_kernel`), on a grid
 ``(B, KV heads, query tiles, KV tiles)`` of table-indexed ``BlockSpec``
@@ -339,12 +344,13 @@ def decode_tile_counts(context_lens, nh: int, pool_shape, itemsize: int,
                        max_blocks: int, quant: bool,
                        pools: int = 2) -> Tuple[int, int]:
     """(live, visited) KV tiles of ONE ``paged_decode`` call over slots at
-    ``context_lens`` (host integers, no window): the tiles that hold context
-    and the tiles the walk takes. Each slot walks to its own end, so they
-    are the same tiles - a slot at context 0 its one - but where the walk
-    is the grid of ``BlockSpec`` pages (:func:`_fetches_pages`), which takes
-    every slot as far as the longest. What the serving engine puts on its
-    ``decode_step`` span."""
+    ``context_lens`` (host integers, no window) - or of one
+    ``paged_sparse_decode`` call, the same walk under one more mask: the
+    tiles that hold context and the tiles the walk takes. Each slot walks to
+    its own end, so they are the same tiles - a slot at context 0 its one -
+    but where the walk is the grid of ``BlockSpec`` pages
+    (:func:`_fetches_pages`), which takes every slot as far as the longest.
+    What the serving engine puts on its ``decode_step`` span."""
     nkv, bs, hd = pool_shape[-3:]
     pages, heads, n_kv = _decode_tiles(nkv, nh // nkv, hd, bs, max_blocks,
                                        itemsize, quant, pools)
@@ -567,7 +573,7 @@ _WALK_GRID = _dim_semantics("parallel", "parallel", "parallel", "arbitrary")
 
 
 def _decode_kernel(*refs, bs, pages, heads, scale, max_blocks, nblocks,
-                   has_window, vd, layered):
+                   has_window, vd, layered, selected=None):
     """``paged_decode``: grid step ``(b, h)`` walks KV-head block ``h`` of
     sequence ``b`` from its first live page to the page of its context, a
     tile of ``pages`` pages an iteration of an in-kernel loop, and fetches
@@ -577,14 +583,22 @@ def _decode_kernel(*refs, bs, pages, heads, scale, max_blocks, nblocks,
     step's - is started before this one is waited for; which half of the
     scratch holds the tile in flight is carried in SMEM from grid step to
     grid step (the grid is sequential). ``vd``: one pool, and a token's
-    values are the first ``vd`` lanes of its key row."""
-    n_pools = 1 if vd else 2
+    values are the first ``vd`` lanes of its key row. ``selected``: the walk
+    of ``paged_sparse_decode`` - one more mask, ``selected(scores, positions,
+    tau, cut)`` (``paged_sparse_attention.selected``): the row's threshold is
+    two more prefetched scalars a sequence, and a tile's slice of the call's
+    index scores ``[B, rows, S]`` (row 0 the query token's) one more DMA a
+    tile, after the pools' in the operands, the scratch and the semaphores."""
+    n_pools, selects = 1 if vd else 2, selected is not None
+    n_src = n_pools + int(selects)
     tables_ref, ctx_ref, layer_ref = refs[:3]
     wnd_ref = refs[3] if has_window else None
     refs = refs[3 + int(has_window):]
-    q_ref, hbm, o_ref = refs[0], refs[1:1 + n_pools], refs[1 + n_pools]
-    bufs = refs[2 + n_pools:2 + 2 * n_pools]
-    sems, slot_ref, m_scr, l_scr, acc_scr = refs[2 + 2 * n_pools:]
+    if selects:
+        (tau_ref, cut_ref), refs = refs[:2], refs[2:]
+    q_ref, hbm, o_ref = refs[0], refs[1:1 + n_src], refs[1 + n_src]
+    bufs = refs[2 + n_src:2 + 2 * n_src]
+    sems, slot_ref, m_scr, l_scr, acc_scr = refs[2 + 2 * n_src:]
     b, h = pl.program_id(0), pl.program_id(1)
     kv = pages * bs
     add, mul, div = jax.lax.add, jax.lax.mul, jax.lax.div
@@ -608,7 +622,7 @@ def _decode_kernel(*refs, bs, pages, heads, scale, max_blocks, nblocks,
             blk = hi(lo(tables_ref[b, add(pg0, p)], 0), nblocks - 1) \
                 if fetch else 0
             rows = pl.ds(pl.multiple_of(mul(p, bs), bs), bs)
-            for i, (pool, buf) in enumerate(zip(hbm, bufs)):
+            for i, (pool, buf) in enumerate(zip(hbm[:n_pools], bufs)):
                 src = pool.at[layer_ref[0], blk] if layered else pool.at[blk]
                 copy = pltpu.make_async_copy(
                     src.at[pl.ds(mul(h, heads), heads)],
@@ -616,6 +630,12 @@ def _decode_kernel(*refs, bs, pages, heads, scale, max_blocks, nblocks,
                 copy.start() if fetch else copy.wait()
             return _
         jax.lax.fori_loop(0, hi(pages, add(add(last, 1), -pg0)), page, 0)
+        if selects:     # the tile's index scores: whole, whatever is live
+            copy = pltpu.make_async_copy(
+                hbm[n_pools].at[b, :, pl.ds(pl.multiple_of(mul(pg0, bs), kv),
+                                            kv)],
+                bufs[n_pools].at[slot], sems.at[n_pools, slot])
+            copy.start() if fetch else copy.wait()
 
     first, last = span(b)
 
@@ -624,7 +644,7 @@ def _decode_kernel(*refs, bs, pages, heads, scale, max_blocks, nblocks,
         # a tile's rows past its sequence's last page are never fetched and
         # always masked, so what the values' scratch holds there has to be
         # finite: every earlier tile's rows are, fresh VMEM need not be
-        bufs[-1][...] = jnp.zeros_like(bufs[-1])
+        bufs[n_pools - 1][...] = jnp.zeros_like(bufs[n_pools - 1])
         slot_ref[0] = 0
         tile_copies(b, h, first, last, 0, True)
 
@@ -658,12 +678,66 @@ def _decode_kernel(*refs, bs, pages, heads, scale, max_blocks, nblocks,
         valid = pos <= ctx                          # the current token too
         if has_window:
             valid = jnp.logical_and(valid, pos > ctx - wnd_ref[0])
+        if selects:     # scores past ``ctx`` may be anything: ``valid`` wins
+            valid = jnp.logical_and(valid, selected(
+                bufs[n_pools][slot, 0:1], pos, tau_ref[b], cut_ref[b]))
         _flash_update(jnp.where(valid, s, NEG_INF), v, m_scr, l_scr, acc_scr)
         slot_ref[0] = 1 - slot
         return _
 
     jax.lax.fori_loop(0, n, tile, 0)
     _flash_finish(True, o_ref, l_scr, acc_scr)
+
+
+def _page_walk(qg, pools, block_tables, context_lens, layer, window, *,
+               scale, pages, heads, vd=None, selected=None, selection=()):
+    """The kernel, grid and arguments of one walk that fetches its own pages
+    (:func:`_decode_kernel`) over layer ``layer`` of ``pools`` (K and V, or
+    one latent pool with ``vd``), which reach it where they lie. ``qg``
+    ``[B, nkv, gpad, hd]``. ``selected`` with ``selection``: one more mask
+    (``selected(scores, positions, tau, cut)``) and its ``(idx, tau, cut)`` -
+    the call's index scores ``[B, rows, S]`` (``S`` whole KV tiles; left in
+    HBM like the pools) and each sequence's threshold ``[B]``."""
+    B, nkv, gpad, hd = qg.shape
+    nblocks, bs = pools[0].shape[-4], pools[0].shape[-2]
+    od = vd or hd                       # the output's (values') width
+    scores, taus = list(selection[:1]), list(selection[1:])
+    hbm = pools + scores
+    kernel = functools.partial(
+        _decode_kernel, bs=bs, pages=pages, heads=heads, scale=scale,
+        max_blocks=block_tables.shape[1], nblocks=nblocks,
+        has_window=window is not None, vd=vd, layered=pools[0].ndim == 5,
+        selected=selected)
+
+    def qmap(b, h, *_):
+        return (b, h, 0, 0)
+
+    tiles = [(heads, pages * bs, hd)] * len(pools) \
+        + [(idx.shape[1], pages * bs) for idx in scores]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3 + int(window is not None) + len(taus),
+        grid=(B, nkv // heads),
+        in_specs=[pl.BlockSpec((None, heads, gpad, hd), qmap)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(hbm),
+        out_specs=pl.BlockSpec((None, heads, gpad, od), qmap),
+        scratch_shapes=[pltpu.VMEM((2,) + tile, p.dtype)
+                        for tile, p in zip(tiles, hbm)] + [
+            pltpu.SemaphoreType.DMA((len(hbm), 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((heads, gpad, 128), jnp.float32),
+            pltpu.VMEM((heads, gpad, 128), jnp.float32),
+            pltpu.VMEM((heads, gpad, od), jnp.float32),
+        ],
+    )
+    args = [block_tables.astype(jnp.int32),
+            context_lens.astype(jnp.int32), layer] \
+        + ([] if window is None else [window.reshape(1)]) + taus \
+        + [qg] + hbm
+    return kernel, grid_spec, args
+
+
+# in order: the tile in flight belongs to the NEXT grid step
+_PAGE_WALK_GRID = _dim_semantics("arbitrary", "arbitrary")
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -692,7 +766,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     assert (v_pool is None) == (vd is not None) and not (vd and quant), \
         "values come from a V pool or from the key page's lanes"
     layer = _layer_scalar(layer, k_pool, v_pool, k_scale, v_scale)
-    nblocks, nkv, bs = k_pool.shape[-4:-1]
+    nkv, bs = k_pool.shape[-3:-1]
     max_blocks = block_tables.shape[1]
     g = nh // nkv
     gpad = _group_rows(g)
@@ -718,35 +792,11 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             n_kv=n_live.astype(jnp.int32), vd=vd)
         order = _WALK_GRID
     else:
-        pools = [k_pool] + ([] if v_pool is None else [v_pool])
-        kernel = functools.partial(
-            _decode_kernel, bs=bs, pages=pages, heads=heads, scale=scale,
-            max_blocks=max_blocks, nblocks=nblocks,
-            has_window=window is not None, vd=vd, layered=k_pool.ndim == 5)
-
-        def qmap(b, h, *_):
-            return (b, h, 0, 0)
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3 + int(window is not None),
-            grid=(B, nkv // heads),
-            in_specs=[pl.BlockSpec((None, heads, gpad, hd), qmap)]
-            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-            out_specs=pl.BlockSpec((None, heads, gpad, od), qmap),
-            scratch_shapes=[pltpu.VMEM((2, heads, pages * bs, hd), p.dtype)
-                            for p in pools] + [
-                pltpu.SemaphoreType.DMA((len(pools), 2)),
-                pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((heads, gpad, 128), jnp.float32),
-                pltpu.VMEM((heads, gpad, 128), jnp.float32),
-                pltpu.VMEM((heads, gpad, od), jnp.float32),
-            ],
-        )
-        args = [block_tables.astype(jnp.int32),
-                context_lens.astype(jnp.int32), layer] \
-            + ([] if window is None else [window.reshape(1)]) + [qg] + pools
-        # in order: the tile in flight belongs to the NEXT grid step
-        order = _dim_semantics("arbitrary", "arbitrary")
+        kernel, grid_spec, args = _page_walk(
+            qg, [k_pool] + ([] if v_pool is None else [v_pool]),
+            block_tables, context_lens, layer, window, scale=scale,
+            pages=pages, heads=heads, vd=vd)
+        order = _PAGE_WALK_GRID
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), q.dtype),

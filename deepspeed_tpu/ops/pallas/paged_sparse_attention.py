@@ -19,10 +19,19 @@ Five calls, each on the layer's index into ``[L, ...]`` pools, like
                         last position taken. A bisection on the score's bit
                         pattern, at most 32 counting passes over the row in
                         VMEM (the tie rule: one more a position bit); no sort.
-``paged_sparse_decode`` / ``paged_sparse_prefill``  the flash walk of
-                        ``paged_attention._paged_kernel`` with one more mask:
-                        a token under its row's threshold is dropped. (The
-                        walk still visits the whole live context - the mask
+``paged_sparse_decode`` / ``paged_sparse_prefill``  the flash walks of
+                        ``paged_attention`` with one more mask: a token
+                        under its row's threshold is dropped. The decode
+                        rows take ``paged_decode``'s walk, which fetches its
+                        own pages (``_decode_kernel``: each sequence to its
+                        own end, page DMAs issued from the block table, a
+                        tile and a sequence ahead) and, a tile, one more DMA:
+                        its slice of the row's index scores. The chunk's
+                        rows take ``_paged_kernel``'s grid of table-indexed
+                        ``BlockSpec`` pages - as a decode call does where
+                        Mosaic cannot slice a page out of the pool (heads
+                        narrower than a lane tile), at one token a sequence.
+                        (Both still visit the whole live context - the mask
                         form; a gather over the selected pages is what the
                         ``sparse_attn_roofline`` leaves room for.)
 
@@ -53,10 +62,11 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import dim_semantics as _dim_semantics
 from ._common import interpret as _interpret
 from ._common import mxu_dot as _mxu_dot
-from .paged_attention import (NEG_INF, _MAX_PAGES, _WALK_GRID,
-                              _contract, _decode_tiles, _flash_finish,
-                              _flash_init, _flash_update, _group_rows,
-                              _kv_tile, _layer_scalar, _page_spec,
+from .paged_attention import (NEG_INF, _MAX_PAGES, _PAGE_WALK_GRID,
+                              _WALK_GRID, _contract, _decode_tiles,
+                              _fetches_pages, _flash_finish, _flash_init,
+                              _flash_update, _group_rows, _kv_tile,
+                              _layer_scalar, _page_spec, _page_walk,
                               _prefill_tiles, _write_pages)
 
 KEY_MIN = -2 ** 31          # the sort key of a position that is not the row's
@@ -67,14 +77,15 @@ _INDEX_PAGES = 32           # index-pool pages of one scores step of a decode
                             # row (a page of one 64-wide key head is 4 KB);
                             # a call of more rows takes _MAX_PAGES: its
                             # [heads x rows, KV] scores fill the VMEM sooner
-_DECODE_PAGES = 32          # K / V pages of one step of the two masked walks:
-_PREFILL_PAGES = 32         # 1024 tokens. A step's fixed work (the flash
-                            # rescale of a [rows, 128] accumulator, 64 page
-                            # DMAs' issue) is what a 256-token step mostly
-                            # was: the 512-row walk alone took 3.8 / 2.6 /
-                            # 2.0 ms at 8 / 16 / 32 pages, the decode walk
-                            # 1.87 / 1.72 / 1.67 (scripts/sparse_kernel_
-                            # bench.py on the chip, PR 38)
+_PREFILL_PAGES = 32         # K / V pages of one step of the multi-token
+                            # masked walk: 1024 tokens. A step's fixed work
+                            # (the flash rescale of a [rows, 128]
+                            # accumulator, 64 page DMAs' issue) is what a
+                            # 256-token step mostly was: the 512-row walk
+                            # alone took 3.8 / 2.6 / 2.0 ms at 8 / 16 / 32
+                            # pages (scripts/sparse_kernel_bench.py on the
+                            # chip, PR 38). The decode rows' tile is
+                            # ``paged_attention._decode_tiles``'.
 
 
 def index_pack(d: int, block_size: int) -> int:
@@ -511,23 +522,15 @@ def paged_sparse_select_xla(scores, q_abs, *, topk: int):
 # --------------------------------------------------------------------------- #
 # attention over the selected tokens: the flash walk with one more mask
 # --------------------------------------------------------------------------- #
-def _sparse_kernel(*refs, bs, pages, scale, tq, g, decode):
+def _sparse_kernel(*refs, bs, pages, scale, tq, g):
     """``paged_attention._paged_kernel`` (no window, no int8 pools) that
-    drops the tokens under each row's threshold. ``decode``: one query token
-    a sequence, every KV head a step, the row's threshold two prefetched
-    scalars; else ``g * tq`` rows of one KV head, g-major, the thresholds
-    ``[tq, 1]`` blocks."""
+    drops the tokens under each row's threshold: ``g * tq`` rows of one KV
+    head, g-major, the thresholds ``[tq, 1]`` blocks."""
     ctx_ref, len_ref = refs[1], refs[2]
-    if decode:
-        tau_ref, cut_ref = refs[4], refs[5]
-        refs = refs[6:]
-    else:
-        refs = refs[4:]
+    refs = refs[4:]
     q_ref, refs = refs[0], refs[1:]
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
-    idx_ref = refs[2 * pages]
-    if not decode:
-        tau_ref, cut_ref = refs[2 * pages + 1], refs[2 * pages + 2]
+    idx_ref, tau_ref, cut_ref = refs[2 * pages:2 * pages + 3]
     o_ref, m_scr, l_scr, acc_scr = refs[-4:]
     b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     kv = pages * bs
@@ -550,19 +553,14 @@ def _sparse_kernel(*refs, bs, pages, scale, tq, g, decode):
         # one token's row of the mask - its own positions, of them the
         # selected - serves its whole query group: computed once a token,
         # repeated g-major as the rows are
-        if decode:
-            keep = jnp.logical_and(
-                selected(idx_ref[0:1, :], pos, tau_ref[b], cut_ref[b]),
-                pos <= ctx)                                   # [1, kv]
-        else:
-            q_abs = ctx + q_lo + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, 1), 0)
-            keep = jnp.logical_and(
-                selected(idx_ref[...], pos, tau_ref[...], cut_ref[...]),
-                jnp.logical_and(pos <= q_abs, pos < ctx + n))  # [tq, kv]
+        q_abs = ctx + q_lo + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, 1), 0)
+        keep = jnp.logical_and(
+            selected(idx_ref[...], pos, tau_ref[...], cut_ref[...]),
+            jnp.logical_and(pos <= q_abs, pos < ctx + n))  # [tq, kv]
         drop = jnp.where(keep, 0.0, NEG_INF)
         # NEG_INF absorbs any score: a dropped token's is exactly NEG_INF
-        if not decode and g > 1:    # rows are g-major: one mask row a token
+        if g > 1:       # rows are g-major: one mask row a token
             s = (s.reshape(g, tq, kv) + drop[None]).reshape(g * tq, kv)
         else:
             s = s + drop
@@ -572,17 +570,15 @@ def _sparse_kernel(*refs, bs, pages, scale, tq, g, decode):
 
 
 def _sparse_walk(qg, k_pool, v_pool, idx, tau, cut, block_tables,
-                 context_lens, lengths, layer, *, scale, rows, tq, g, pages,
-                 heads):
+                 context_lens, lengths, layer, *, scale, rows, tq, g, pages):
     """The kernel, grid and arguments of one walk over layer ``layer`` of the
     K and V pools
     (``paged_attention._table_walk``), the index scores' tile and the rows'
-    thresholds beside each KV tile. The grid's last dimension is DYNAMIC for
-    both calls: the tiles of the longest live context."""
+    thresholds beside each KV tile. The grid's last dimension is DYNAMIC:
+    the tiles of the longest live context."""
     B, nkv, _, hd = qg.shape
     nblocks, bs = k_pool.shape[-4], k_pool.shape[-2]
     max_blocks = block_tables.shape[1]
-    decode = heads is not None
     kv = pages * bs
 
     def qmap(b, h, qi, j, *_):
@@ -602,39 +598,31 @@ def _sparse_walk(qg, k_pool, v_pool, idx, tau, cut, block_tables,
         return kvmap
 
     def idx_map(b, h, qi, j, tables, ctx, lens, layer, *_):
-        return (b, 0 if decode else qi,
-                jnp.minimum(j, last_tile(b, qi, ctx, lens) // pages))
+        return (b, qi, jnp.minimum(j, last_tile(b, qi, ctx, lens) // pages))
 
-    in_specs = [pl.BlockSpec((None, heads, rows, hd), qmap)] + [
-        _page_spec(pool, heads, page_map(p))
+    in_specs = [pl.BlockSpec((None, None, rows, hd), qmap)] + [
+        _page_spec(pool, None, page_map(p))
         for pool in (k_pool, v_pool) for p in range(pages)] + [
-        pl.BlockSpec((None, idx.shape[1] if decode else tq, kv), idx_map)]
+        pl.BlockSpec((None, tq, kv), idx_map)] + [
+        pl.BlockSpec((None, tq, 1), lambda b, h, qi, j, *_: (b, qi, 0))] * 2
     operands = [qg] + [pool for pool in (k_pool, v_pool)
-                       for _ in range(pages)] + [idx]
+                       for _ in range(pages)] + [idx, tau, cut]
     prefetch = [block_tables.astype(jnp.int32),
                 context_lens.astype(jnp.int32), lengths.astype(jnp.int32),
                 layer]
-    if decode:
-        prefetch += [tau, cut]
-    else:
-        in_specs += [pl.BlockSpec((None, tq, 1),
-                                  lambda b, h, qi, j, *_: (b, qi, 0))] * 2
-        operands += [tau, cut]
     n_kv = -(-max_blocks // pages)
     n_live = jnp.clip(-(-jnp.max(context_lens + lengths) // kv), 1, n_kv)
-    lead = (heads,) if decode else ()
     kernel = functools.partial(_sparse_kernel, bs=bs, pages=pages,
-                               scale=float(scale), tq=tq, g=g, decode=decode)
+                               scale=float(scale), tq=tq, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, nkv // (heads or 1), qg.shape[2] // rows,
-              n_live.astype(jnp.int32)),
+        grid=(B, nkv, qg.shape[2] // rows, n_live.astype(jnp.int32)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, heads, rows, hd), qmap),
+        out_specs=pl.BlockSpec((None, None, rows, hd), qmap),
         scratch_shapes=[
-            pltpu.VMEM(lead + (rows, 128), jnp.float32),
-            pltpu.VMEM(lead + (rows, 128), jnp.float32),
-            pltpu.VMEM(lead + (rows, hd), jnp.float32)])
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32)])
     return kernel, grid_spec, prefetch + operands
 
 
@@ -646,43 +634,11 @@ def prefill_rows(t: int, nh: int, nkv: int, hd: int, bs: int,
     return tq * n_qt
 
 
-def paged_sparse_decode_attention(q, k_pool, v_pool, idx, tau, cut,
-                                  block_tables, context_lens, *,
-                                  scale: float = None, layer=None):
-    """``paged_decode_attention`` over the selected tokens alone. ``idx [B,
-    rows, S]``: the call's index scores, row 0 the query token's; ``tau``,
-    ``cut`` ``[B]``: its threshold. Returns ``[B, nh, hd]``."""
-    B, nh, hd = q.shape
-    layer = _layer_scalar(layer, k_pool, v_pool)
-    nkv, bs = k_pool.shape[-3:-1]
-    g = nh // nkv
-    gpad = _group_rows(g)
-    _, heads, _ = _decode_tiles(nkv, g, hd, bs, block_tables.shape[1],
-                                k_pool.dtype.itemsize, False)
-    pages = _pow2_pages(_DECODE_PAGES, block_tables.shape[1])
-    qg = jnp.pad(q.reshape(B, nkv, g, hd),
-                 ((0, 0), (0, 0), (0, gpad - g), (0, 0)))
-    kernel, grid_spec, args = _sparse_walk(
-        qg, k_pool, v_pool, idx, tau, cut, block_tables, context_lens,
-        jnp.ones((B,), jnp.int32), layer,
-        scale=hd ** -0.5 if scale is None else scale, rows=gpad, tq=1, g=1,
-        pages=pages, heads=heads)
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=_WALK_GRID,
-        interpret=_interpret(),
-        name="paged_sparse_decode",
-    )(*args)
-    return out[:, :, :g].reshape(B, nh, hd)
-
-
-def paged_sparse_prefill_attention(q, k_pool, v_pool, idx, tau, cut,
-                                   block_tables, context_lens, lengths, *,
-                                   scale: float = None, layer=None):
-    """``paged_prefill_attention`` over the selected tokens alone. ``idx
-    [B, rows, S]`` with ``rows`` = :func:`prefill_rows`; ``tau``, ``cut``
-    ``[B, rows]``. Returns ``[B, t, nh, hd]``."""
+def _selected_table_walk(q, k_pool, v_pool, idx, tau, cut, block_tables,
+                         context_lens, lengths, scale, layer):
+    """The multi-token masked walk of ``q [B, t, nh, hd]`` as ``(kernel, grid,
+    arguments, result's shape, the result as [B, t, nh, hd])``: query tiles
+    of ``g * tq`` rows, one KV head a step (:func:`_sparse_walk`)."""
     B, t, nh, hd = q.shape
     layer = _layer_scalar(layer, k_pool, v_pool)
     nkv, bs = k_pool.shape[-3:-1]
@@ -694,20 +650,92 @@ def paged_sparse_prefill_attention(q, k_pool, v_pool, idx, tau, cut,
     qg = jnp.pad(q, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
     qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
         .reshape(B, nkv, n_qt * rows, hd)
-    kernel, grid_spec, args = _sparse_walk(
+
+    def token_major(out):
+        return out.reshape(B, nkv, n_qt, g, tq, hd) \
+            .transpose(0, 2, 4, 1, 3, 5).reshape(B, n_qt * tq, nh, hd)[:, :t]
+
+    return _sparse_walk(
         qg, k_pool, v_pool, idx, tau[..., None], cut[..., None],
         block_tables, context_lens, lengths, layer,
         scale=hd ** -0.5 if scale is None else scale, rows=rows, tq=tq, g=g,
-        pages=pages, heads=None)
-    out = pl.pallas_call(
+        pages=pages) + (qg.shape, token_major)
+
+
+def paged_sparse_decode_attention(q, k_pool, v_pool, idx, tau, cut,
+                                  block_tables, context_lens, *,
+                                  scale: float = None, layer=None):
+    """``paged_decode_attention`` over the selected tokens alone. ``idx [B,
+    rows, S]``: the call's index scores, row 0 the query token's; ``tau``,
+    ``cut`` ``[B]``: its threshold. Returns ``[B, nh, hd]``. The walk is
+    ``paged_decode``'s (``paged_attention._page_walk``) with the selection
+    as one more operand; where that walk cannot fetch its own pages
+    (``_fetches_pages``: heads narrower than a lane tile) the multi-token
+    walk serves, at one token a sequence."""
+    B, nh, hd = q.shape
+    nkv, bs = k_pool.shape[-3:-1]
+    max_blocks = block_tables.shape[1]
+    g = nh // nkv
+    if _fetches_pages(hd, False):
+        gpad = _group_rows(g)
+        pages, heads, n_kv = _decode_tiles(nkv, g, hd, bs, max_blocks,
+                                           k_pool.dtype.itemsize, False)
+        short = n_kv * pages * bs - idx.shape[2]
+        if short > 0:   # a table that is no multiple of the walk's tile and
+            # scores no wider than it: the last tile's DMA stays inside them
+            idx = jnp.pad(idx, ((0, 0), (0, 0), (0, short)))
+        qg = jnp.pad(q.reshape(B, nkv, g, hd),
+                     ((0, 0), (0, 0), (0, gpad - g), (0, 0)))
+        kernel, grid_spec, args = _page_walk(
+            qg, [k_pool, v_pool], block_tables, context_lens,
+            _layer_scalar(layer, k_pool, v_pool), None,
+            scale=float(hd ** -0.5 if scale is None else scale), pages=pages,
+            heads=heads, selected=selected, selection=(idx, tau, cut))
+        out_shape, order = qg.shape, _PAGE_WALK_GRID
+
+        def rows_of(out):
+            return out[:, :, :g].reshape(B, nh, hd)
+    else:
+        rows = prefill_rows(1, nh, nkv, hd, bs, max_blocks)
+
+        def row_0(x):                   # [B, ..] -> [B, rows, ..], row 0 real
+            return jnp.pad(x[:, None], ((0, 0), (0, rows - 1))
+                           + ((0, 0),) * (x.ndim - 1))
+
+        kernel, grid_spec, args, out_shape, token_major = \
+            _selected_table_walk(
+                q[:, None], k_pool, v_pool, row_0(idx[:, 0]), row_0(tau),
+                row_0(cut), block_tables, context_lens,
+                jnp.ones((B,), jnp.int32), scale, layer)
+        order = _WALK_GRID
+
+        def rows_of(out):
+            return token_major(out)[:, 0]
+    return rows_of(pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        compiler_params=order,
+        interpret=_interpret(),
+        name="paged_sparse_decode",
+    )(*args))
+
+
+def paged_sparse_prefill_attention(q, k_pool, v_pool, idx, tau, cut,
+                                   block_tables, context_lens, lengths, *,
+                                   scale: float = None, layer=None):
+    """``paged_prefill_attention`` over the selected tokens alone. ``idx
+    [B, rows, S]`` with ``rows`` = :func:`prefill_rows`; ``tau``, ``cut``
+    ``[B, rows]``. Returns ``[B, t, nh, hd]``."""
+    kernel, grid_spec, args, out_shape, token_major = _selected_table_walk(
+        q, k_pool, v_pool, idx, tau, cut, block_tables, context_lens,
+        lengths, scale, layer)
+    return token_major(pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=_WALK_GRID,
         interpret=_interpret(),
         name="paged_sparse_prefill",
-    )(*args)
-    return out.reshape(B, nkv, n_qt, g, tq, hd).transpose(0, 2, 4, 1, 3, 5) \
-        .reshape(B, n_qt * tq, nh, hd)[:, :t]
+    )(*args))
 
 
 def _sparse_attention_xla(q, k_pool, v_pool, idx, tau, cut, block_tables,

@@ -2,34 +2,162 @@
 """Times the learned selection's kernels alone, on the chip, at the Keye
 cell's shapes (``chiprun -- python3 scripts/sparse_kernel_bench.py``): one
 layer's calls of a tick - a 512-row chunk and 8 decode rows at ``--ctx``
-cached tokens each - at several tile sizes. Prints one JSON line a case:
+cached tokens each - at several tile sizes, one JSON line a case:
 milliseconds a call, median of ``--reps``. How the tile sizes in
 ``ops/pallas/paged_sparse_attention.py`` were chosen (PERF.md section 6,
-PR 38); a number from here is a kernel's, never a cell's."""
+PR 38).
+
+Before them, the decode rows' masked walk (``paged_sparse_decode``) as the
+PARENT commit builds it against this tree's, each in a process of its own
+(a chip belongs to one process, so this one stays off JAX until both are
+done): 8 slots of which half are idle, contexts drawn log-uniformly over
+6-31 k tokens as the cell's prompts are, block ids scattered over the pool.
+One JSON line a (side, seed): microseconds a call (``--layers`` calls in one
+program, median of ``--reps``), the share of the dense-read floor (the
+decoding rows' whole live context, K and V, at the chip's published HBM
+rate) and how far the result lies from the gathered XLA op's. The parent is
+``--parent DIR`` (an unpacked ``git archive``: what a chip machine, which has
+no ``.git``, needs) or else ``git archive --parent-rev`` unpacked under
+``/tmp``. A number from here is a kernel's, never a cell's."""
 
 import argparse
 import json
 import os
 import statistics
+import subprocess
 import sys
+import tempfile
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM_BYTES_PER_S = {"TPU v5 lite": 819e9}    # Google Cloud, "TPU v5e"
+L, NB, BS, D, H = 2, 7808, 32, 64, 16
+NKV, NH, HD, MB, TOPK = 4, 32, 128, 1024, 2048
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--ctx", type=int, default=15000)
-    ap.add_argument("--reps", type=int, default=5)
-    args = ap.parse_args()
+def decode_walk(args) -> int:
+    """One side's timing of the decode rows' walk: ``deepspeed_tpu`` is
+    whatever ``args.walk_of`` holds."""
+    sys.path.insert(0, os.path.abspath(args.walk_of))
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from deepspeed_tpu.ops.pallas import paged_sparse_attention as S
 
-    L, NB, BS, D, H = 2, 7808, 32, 64, 16
-    NKV, NH, HD, MB, TOPK = 4, 32, 128, 1024, 2048
+    assert os.path.abspath(S.__file__).startswith(
+        os.path.abspath(args.walk_of)), S.__file__
+    nb, mb, slots = (80, 16, 4) if args.tiny else (NB, MB, 8)
+    bf, i32 = jnp.bfloat16, jnp.int32
+    key = jax.random.PRNGKey(0)
+    kpool, vpool = (jax.random.normal(jax.random.fold_in(key, i),
+                                      (L, nb, NKV, BS, HD), bf)
+                    for i in range(2))
+    q = jax.random.normal(key, (slots, NH, HD), bf)
+    kind = jax.devices()[0].device_kind
+
+    def walk(q_, k_, v_, idx_, tau_, cut_, tb_, ctx_, layer):
+        return S.paged_sparse_decode_attention(q_, k_, v_, idx_, tau_, cut_,
+                                               tb_, ctx_, layer=layer)
+
+    def program(*ops):
+        def layer(i, acc):
+            return acc + walk(*ops, i % L).astype(jnp.float32)
+        return jax.lax.fori_loop(0, args.layers, layer,
+                                 jnp.zeros((slots, NH, HD), jnp.float32))
+
+    fn, one = jax.jit(program), jax.jit(walk)
+    select = jax.jit(lambda s_, q_: S.paged_sparse_select(
+        s_, q_, topk=min(TOPK, mb * BS // 4)))
+    for seed in args.seeds:
+        rng = np.random.default_rng(seed)
+        lo, hi = (6144, 30720) if not args.tiny else (100, mb * BS - 2)
+        ctx = np.exp(rng.uniform(np.log(lo), np.log(hi), slots)) \
+            .astype(np.int64)
+        idle = rng.permutation(slots) < slots // 2
+        ctx[idle] = 0
+        tables = rng.integers(1, nb, (slots, mb))
+        tables[idle] = 0
+        idx = jax.random.normal(jax.random.fold_in(key, seed),
+                                (slots, 8, mb * BS), jnp.float32)
+        tau, cut = select(idx[:, 0], jnp.asarray(ctx, i32))
+        ops = (q, kpool, vpool, idx, tau, cut, jnp.asarray(tables, i32),
+               jnp.asarray(ctx, i32))
+        got = np.asarray(one(*ops, 1), np.float32)
+        want = np.asarray(S.paged_sparse_decode_attention_xla(*ops, layer=1),
+                          np.float32)
+        jax.block_until_ready(fn(*ops))
+        ts = []
+        for _ in range(args.reps):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*ops))
+            ts.append(time.perf_counter() - t0)
+        us = statistics.median(ts) / args.layers * 1e6
+        floor = float((ctx[~idle] + 1).sum()) * NKV * HD * 2 * 2 \
+            / HBM_BYTES_PER_S[kind] * 1e6 if kind in HBM_BYTES_PER_S else None
+        print(json.dumps({
+            "case": "sparse_decode walk", "side": args.side, "seed": seed,
+            "contexts": ctx.tolist(), "us": us, "floor_us": floor,
+            "floor_share": floor and floor / us,
+            "max_abs_diff_from_xla": float(np.abs(
+                got - want)[~idle].max()), "device": kind}), flush=True)
+    return 0
+
+
+def both_walks(args) -> int:
+    """The parent's walk, then this tree's, each in its own process."""
+    with tempfile.TemporaryDirectory(dir="/tmp") as tmp:
+        if args.parent is None:
+            tar = subprocess.run(["git", "archive", args.parent_rev],
+                                 cwd=ROOT, capture_output=True)
+            if tar.returncode:
+                print("no --parent DIR and no git archive "
+                      f"{args.parent_rev}: {tar.stderr.decode().strip()}",
+                      file=sys.stderr)
+                return 2
+            subprocess.run(["tar", "-x", "-C", tmp], input=tar.stdout,
+                           check=True)
+        for side, root in (("parent", args.parent or tmp), ("change", ROOT)):
+            rc = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "--walk-of", root,
+                 "--side", side, "--layers", str(args.layers), "--reps",
+                 str(args.reps), "--seeds", *map(str, args.seeds)]
+                + ["--tiny"] * args.tiny).returncode
+            if rc:
+                return rc
+    return 0
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--ctx", type=int, default=15000)
+    ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--only", choices=["walk", "tiles"],
+                    help="the decode rows' walk, parent against tree, or "
+                    "the other kernels' tile sizes; both where not given")
+    ap.add_argument("--parent", help="the parent commit, unpacked")
+    ap.add_argument("--parent-rev", default="HEAD")
+    ap.add_argument("--layers", type=int, default=24)
+    ap.add_argument("--seeds", type=int, nargs="*", default=[0, 1, 2])
+    ap.add_argument("--tiny", action="store_true",
+                    help="the walk as a schema run at a toy table (the "
+                    "CPU's interpreter; heads stay 128 lanes)")
+    ap.add_argument("--walk-of", help=argparse.SUPPRESS)
+    ap.add_argument("--side", help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.walk_of:
+        return decode_walk(args)
+    if args.only != "tiles":
+        rc = both_walks(args)
+        if rc or args.only == "walk":
+            return rc
+    sys.path.insert(0, ROOT)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as S
+
     key = jax.random.PRNGKey(0)
     bf, i32 = jnp.bfloat16, jnp.int32
     ipool = jax.random.normal(key, S.index_pool_shape(L, NB, BS, D), bf)
@@ -83,13 +211,11 @@ def main() -> int:
         idx = jnp.broadcast_to(scores.reshape(B, width, -1)[:, :1],
                                (B, rows, scores.shape[-1])) if t == 1 \
             else scores.reshape(B, rows, -1)
-        if t == 1:
-            for pages in (8, 16, 32):
-                S._DECODE_PAGES = pages
-                fn = jax.jit(lambda *a: S.paged_sparse_decode_attention(
-                    *a, layer=layer))
-                timed("sparse_decode 8 rows", fn, q[:, 0], kpool, vpool, idx,
-                      tau[:, 0], cut[:, 0], tb, ctx, pages=pages)
+        if t == 1:      # its tile is paged_attention._decode_tiles' to say
+            fn = jax.jit(lambda *a: S.paged_sparse_decode_attention(
+                *a, layer=layer))
+            timed("sparse_decode 8 rows", fn, q[:, 0], kpool, vpool, idx,
+                  tau[:, 0], cut[:, 0], tb, ctx)
         else:
             for pages in (8, 16, 32):
                 S._PREFILL_PAGES = pages

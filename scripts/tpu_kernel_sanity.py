@@ -156,6 +156,16 @@ def main():
     # sequence's end out of range (a walk that fetched them would fault or
     # read NaN: the last block is poisoned); a static and a traced window
     # that begin past page 0; a latent pool; heads in blocks
+    def garbage_past_the_end(ctx, bs, mb, nb):
+        """Random tables for sequences at ``ctx``: ``(bad, clean)`` - past a
+        sequence's last block the poisoned block ``nb - 1``, an index past
+        the pool and a negative one, in turn, or the trash block."""
+        bt = rs.randint(1, nb - 1, (len(ctx), mb)).astype(np.int32)
+        bad = np.resize(np.asarray([nb - 1, 10 ** 6, -3], np.int32), bt.shape)
+        live = np.arange(mb)[None, :] <= ctx[:, None] // bs
+        return jnp.asarray(np.where(live, bt, bad)), \
+            jnp.asarray(np.where(live, bt, 0))
+
     def paged_decode_own_pages():
         from deepspeed_tpu.ops.pallas import paged_attention as pa
 
@@ -173,12 +183,7 @@ def main():
             pools = [randn(L, nb, nkv, bs, hd).astype(jnp.bfloat16)
                      .at[:, nb - 1].set(jnp.nan)
                      for _ in range(1 if vd else 2)] + [None] * bool(vd)
-            bt = rs.randint(1, nb - 1, (B, mb)).astype(np.int32)
-            bad = np.resize(np.asarray([nb - 1, 10 ** 6, -3], np.int32),
-                            (B, mb))
-            live = np.arange(mb)[None, :] <= ctx[:, None] // bs
-            bad, bt = jnp.asarray(np.where(live, bt, bad)), \
-                jnp.asarray(np.where(live, bt, 0))
+            bad, bt = garbage_past_the_end(ctx, bs, mb, nb)
             q = randn(B, nkv * g, hd).astype(jnp.bfloat16)
             ctx = jnp.asarray(ctx)
             kw = dict(layer=1, value_width=vd)
@@ -193,6 +198,38 @@ def main():
                     diff_ok(walk(jnp.asarray(w, jnp.int32)), want, 0.05)
 
     check("paged_decode_own_pages", paged_decode_own_pages)
+
+    # the same walk under the learned selection's mask (Keye's decode rows,
+    # ISSUE 51): the tile's index scores one more DMA, the row's threshold
+    # two more scalars. Garbage table entries past a sequence's end, and
+    # garbage SCORES past each row's own position (the scores call never
+    # writes them): NaN, which only the position mask keeps out
+    def paged_sparse_decode_own_pages():
+        from deepspeed_tpu.ops.pallas import paged_attention as pa
+        from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+        L, bs, mb, nb, topk = 2, 32, 64, 600, 256
+        for nkv, g in ((4, 8), (8, 4)):
+            pages, _, _ = pa._decode_tiles(nkv, g, 128, bs, mb, 2, False)
+            tile = pages * bs
+            ctx = np.asarray([0, tile - 2, tile - 1, tile, mb * bs - 1, 700,
+                              0, 33], np.int32)
+            B = len(ctx)
+            k, v = (randn(L, nb, nkv, bs, 128).astype(jnp.bfloat16)
+                    .at[:, nb - 1].set(jnp.nan) for _ in range(2))
+            bad, bt = garbage_past_the_end(ctx, bs, mb, nb)
+            q = randn(B, nkv * g, 128).astype(jnp.bfloat16)
+            idx = randn(B, 8, mb * bs)
+            past = np.arange(mb * bs)[None, None] > ctx[:, None, None]
+            ctx = jnp.asarray(ctx)
+            tau, cut = sparse.paged_sparse_select(idx[:, 0], ctx, topk=topk)
+            want = sparse.paged_sparse_decode_attention_xla(
+                q, k, v, idx, tau, cut, bt, ctx, layer=1)
+            diff_ok(sparse.paged_sparse_decode_attention(
+                q, k, v, jnp.where(past, jnp.nan, idx), tau, cut, bad, ctx,
+                layer=1), want, 0.05)
+
+    check("paged_sparse_decode_own_pages", paged_sparse_decode_own_pages)
 
     # compact MoE dispatch parity ON CHIP at true-f32 matmul precision —
     # round-4's 1.1e-2 divergence (bench_runs/MOE_20260731T034754Z.json)

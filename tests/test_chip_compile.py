@@ -240,12 +240,18 @@ CELL_DECODES = {
         16, 128, 8, HD, None, 32, 145, 2321, 4096, (16, 8, 10)),
     "a.x-k1.serve-longctx/latent": (16, 64, 1, 640, 512, 128, 256, 3152,
                                     None, (8, 1, 32)),
+    "keye-vl-2.0-30b-a3b.serve-longctx": (8, 32, 4, HD, None, 32, 1024, 7808,
+                                          None, (32, 4, 32)),
 }
+# the cells whose decode rows bring a learned selection: the same walk
+# through ``paged_sparse_decode_attention``, with the row's index scores
+# ``[slots, 8, table tokens]`` and its threshold as operands
+SELECTED = {"keye-vl-2.0-30b-a3b.serve-longctx"}
 # every cell's walk as the cell runs it (its kind's window, a traced layer
 # of 5-D pools), and the three oldest cells' under a window and over int8
 # pools, which no cell runs
 DECODE_LOWERINGS = [(cell, "bf16") for cell in sorted(CELL_DECODES)] + [
-    (cell, pool) for cell in sorted(CELL_DECODES)[3:]
+    (cell, pool) for cell in sorted(CELL_DECODES)[-3:]
     for pool in ("windowed", "int8")]
 
 
@@ -256,13 +262,16 @@ def test_decode_kernel_lowers_at_the_cells_geometry(v5e, cell, pool):
     every KV head in VMEM; int8 pools on their grid of ``BlockSpec`` pages -
     as ONE Mosaic call, still the instruction ``paged_decode.N`` that
     ``paged_decode_roofline``, ``mixed_kv_decode_roofline`` and
-    ``mla_decode_roofline`` look for."""
+    ``mla_decode_roofline`` look for - ``paged_sparse_decode.N``, which
+    ``sparse_attn_roofline`` looks for, where the rows bring a selection."""
     import re
 
     from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
 
     slots, nq, nkv, hd, vd, bs, table, blocks, window, _ = CELL_DECODES[cell]
     quant = pool == "int8"
+    name = "paged_sparse_decode" if cell in SELECTED else "paged_decode"
     window = 4096 if pool == "windowed" else window
     lead = () if quant else (2,)    # a layer's scale pools are cut out
     kv = (lead + (blocks, nkv, bs, hd), jnp.int8 if quant else jnp.bfloat16)
@@ -270,18 +279,24 @@ def test_decode_kernel_lowers_at_the_cells_geometry(v5e, cell, pool):
         + (((slots, table), jnp.int32), ((slots,), jnp.int32), ((), jnp.int32))
     if quant:
         shapes += (((blocks, nkv, bs, 1), jnp.float32),) * 2
+    if cell in SELECTED:
+        shapes += (((slots, 8, table * bs), jnp.float32),) \
+            + (((slots,), jnp.int32),) * 2
 
     def fn(q, k, *rest):
-        v, (bt, cl, layer, *scales) = (None, rest) if vd \
+        v, (bt, cl, layer, *more) = (None, rest) if vd \
             else (rest[0], rest[1:])
+        if cell in SELECTED:
+            return sparse.paged_sparse_decode_attention(
+                q, k, v, *more, bt, cl, layer=layer)
         return pa.paged_decode_attention(
             q, k, v, bt, cl, window=window, value_width=vd,
             layer=None if quant else layer,
-            **(dict(k_scale=scales[0], v_scale=scales[1]) if quant else {}))
+            **(dict(k_scale=more[0], v_scale=more[1]) if quant else {}))
 
     text = _compile(fn, *shapes, device=v5e.devices[0]).as_text()
     calls = re.findall(r"%(\S+) = \S+ custom-call\(.*" + MOSAIC, text)
-    assert len(calls) == 1 and re.fullmatch(r"paged_decode(\.\d+)?", calls[0])
+    assert len(calls) == 1 and re.fullmatch(name + r"(\.\d+)?", calls[0])
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_DECODES))

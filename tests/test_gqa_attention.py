@@ -631,9 +631,10 @@ def test_decode_tiles_come_from_the_shapes():
     assert tiles(1, 8, 128, 4096, 4, 4, False) == (1, 1, 4)
     assert tiles(8, 4, 128, 32, 3, 2, False) == (3, 8, 1)        # short table
     assert tiles(8, 4, 128, 32, 12, 2, False) == (8, 8, 2)   # no 16 pages in it
-    # the learned selection's decode walk takes its head block from here
-    # (paged_sparse_attention.py): Keye's four KV heads, as before
-    assert tiles(4, 8, 128, 32, 1024, 2, False)[1] == 4
+    # the learned selection's decode rows take this walk whole, tile and
+    # head block (paged_sparse_attention.py, ISSUE 51): Keye's four KV heads
+    # at thirty-two pages - two 4 MB tiles of K and V and 64 KB of scores
+    assert tiles(4, 8, 128, 32, 1024, 2, False) == (32, 4, 32)
     assert [paged_mod._fetches_pages(hd, quant) for hd, quant in
             ((128, False), (640, False), (256, False), (64, False),
              (128, True))] == [True, True, True, False, False]

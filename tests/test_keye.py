@@ -354,8 +354,81 @@ def test_index_scores_interpreted_are_the_gathered_einsum(pools, t):
                                  0)).max()) < 1e-4
 
 
-@pytest.mark.parametrize("t", [16, 1])
-def test_sparse_attention_interpreted_is_the_gathered_softmax(pools, t):
+# The decode rows walk their own pages (ISSUE 51): heads of 128 lanes, blocks
+# of 8 tokens, a table 20 wide and a KV tile held to 64 tokens - two whole
+# tiles and half a third. A context of n is n cached tokens plus the current
+# one: 63 is exactly a tile, 64 the first token of the second, 159 the table.
+OWN_PAGES = {
+    "contexts_round_a_block": dict(ctx=[0, 7, 8, 9]),
+    "contexts_round_a_tile": dict(ctx=[62, 63, 64, 65, 127, 128]),
+    # scores as wide as the table, which is no multiple of the tile: the
+    # last tile's slice of them must not be fetched past their end
+    "table_no_multiple_of_the_tile": dict(ctx=[159, 100, 130], width=160),
+    "trash_block_beside_full_slots": dict(ctx=[159, 0, 159, 0],
+                                          trash=[1, 3]),
+    "contexts_under_topk": dict(ctx=[3, 6, 0]),
+    "two_head_blocks": dict(ctx=[0, 63, 64, 159], nkv=4, g=2,
+                            vmem=288 << 10),
+}
+
+
+def _decode_rows_walk_their_own_pages(case, monkeypatch):
+    """``paged_sparse_decode`` (interpreted: the interpreter runs its DMAs,
+    its semaphores and its SMEM carry) against the gathered XLA op. The
+    kernel's copy of the operands is POISONED wherever it must not look:
+    table entries past a sequence's last block hold a block of NaN rows, an
+    index past the pool and a negative one, in turn, and the index scores
+    past each row's own position - which ``paged_index_scores`` never
+    writes - are NaN."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    c = dict(dict(nkv=2, g=4, width=256, trash=(), vmem=None),
+             **OWN_PAGES[case])
+    monkeypatch.setattr(pa, "_DECODE_KV_TOKENS", 64)
+    if c["vmem"]:
+        monkeypatch.setattr(pa, "_TILE_VMEM", c["vmem"])
+    rng = np.random.default_rng(3)
+    L, nb, bs, mb, hd = 2, 48, 8, 20, 128
+    nkv, nh, B, poison = c["nkv"], c["nkv"] * c["g"], len(c["ctx"]), nb - 1
+    assert pa._fetches_pages(hd, False)
+    pages, heads, n_kv = pa._decode_tiles(nkv, c["g"], hd, bs, mb, 4, False)
+    assert (pages, heads, n_kv) == (8, 2, 3)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    k, v, q = f(L, nb, nkv, bs, hd), f(L, nb, nkv, bs, hd), f(B, nh, hd)
+    tables = np.zeros((B, mb), np.int32)
+    garbage = np.resize(np.asarray([poison, 10 ** 6, -3], np.int32), (B, mb))
+    for b, x in enumerate(c["ctx"]):
+        need = 0 if b in c["trash"] else x // bs + 1
+        tables[b, :need] = garbage[b, :need] = rng.integers(1, poison, need)
+    garbage[list(c["trash"])] = 0
+    ctx = jnp.asarray(c["ctx"], jnp.int32)
+    idx = f(B, 8, c["width"])
+    tau, cut = sparse.paged_sparse_select_xla(idx[:, 0], ctx, topk=TOPK)
+    past = np.arange(c["width"])[None, None] > np.asarray(c["ctx"])[:, None,
+                                                                    None]
+    got = sparse.paged_sparse_decode_attention(
+        q, *(p.at[:, poison].set(jnp.nan) for p in (k, v)),
+        jnp.where(past, jnp.nan, idx), tau, cut, jnp.asarray(garbage), ctx,
+        layer=1)
+    want = sparse.paged_sparse_decode_attention_xla(
+        q, k, v, idx, tau, cut, jnp.asarray(tables), ctx, layer=1)
+    assert got.shape == (B, nh, hd) and gap(got, want) < 1e-5
+    # the span's counter says what the walk takes: each slot's own tiles
+    tiles = sum(x // 64 + 1 for x in c["ctx"]) * (nkv // heads)
+    assert pa.decode_tile_counts(c["ctx"], nh, k.shape, 4, mb,
+                                 False) == (tiles, tiles)
+
+
+@pytest.mark.parametrize("t", [16, 1] + sorted(OWN_PAGES))
+def test_sparse_attention_interpreted_is_the_gathered_softmax(pools, t,
+                                                              monkeypatch):
+    """The two masked walks against the gathered softmax. ``t`` = 1 at the
+    fixture's heads of 32 lanes is the grid of ``BlockSpec`` pages a decode
+    call keeps where Mosaic cannot slice a page out of the pool (the
+    multi-token walk at one token a sequence); the named cases are the walk
+    that fetches its own pages."""
+    if t in OWN_PAGES:
+        return _decode_rows_walk_their_own_pages(t, monkeypatch)
     pool, ctx, lens = written(pools, sparse.paged_index_write_xla)
     if t == 1:
         ctx, lens, rows = jnp.asarray([21, 30]), jnp.ones(2, jnp.int32), 8
@@ -373,6 +446,9 @@ def test_sparse_attention_interpreted_is_the_gathered_softmax(pools, t):
     tau, cut = tau.reshape(2, width), cut.reshape(2, width)
     kv = (pools["k"], pools["v"])
     if t == 1:
+        from deepspeed_tpu.ops.pallas.paged_attention import _fetches_pages
+
+        assert not _fetches_pages(q.shape[-1], False)
         got = sparse.paged_sparse_decode_attention(
             q[:, 0], *kv, idx, tau[:, 0], cut[:, 0], pools["tables"], ctx,
             layer=1)
