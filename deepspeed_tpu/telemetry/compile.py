@@ -29,10 +29,31 @@ the flops went). This module answers both:
   registered with its KV ``pools`` also says what it moves to re-house them
   (``pool_copy_bytes``: 0 for one that writes them where they lie) and what
   it aliases argument-to-result (``aliased_bytes``).
+- :class:`CompileAccount` (one a process: :func:`process_account`) says where
+  START-UP went. The monitor above sees only the programs registered with it;
+  JAX publishes every trace, lowering, backend compile and persistent-cache
+  request of the whole process through ``jax.monitoring``, and the account
+  listens: the first ENABLED monitor registers one event listener and one
+  duration listener (a disabled monitor registers none - the default path
+  stays event-free). It keeps each event as an interval on the
+  ``time.perf_counter`` clock and answers in seconds of the intervals' UNION
+  (an outer function's trace contains the traces of the functions it calls),
+  for the whole process or up to a moment (``totals(before=...)``), and by
+  function (``by_program``). The monitor's own inspection of each compiled
+  program (cost and memory analysis, the HLO text's pool scan) is timed into
+  it too (``monitor_analysis_s``): tracing code on the set-up path pays its
+  way in the open.
+
+Two caches, two words: ``cache_hits`` (``ProgramStats``,
+``Compile/<program>/cache_hits``) counts DISPATCHES the monitor's in-memory
+signature table served; ``persistent_cache_hits`` / ``_misses`` and the
+account's ``cache_hits`` / ``cache_misses`` count ``compile()`` calls JAX's
+persistent compilation cache served from disk or had to compile and write.
 
 Event names (``Compile/<program>/<metric>``, ``Compile/total/*``,
-``<group>/mfu/<program>``) are registered in ``telemetry/schema.py``;
-``telemetry_report.py --compile`` renders the offline summary.
+``Compile/process/*``, ``<group>/mfu/<program>``) are registered in
+``telemetry/schema.py``; ``telemetry_report.py --compile`` renders the
+offline summary.
 """
 
 from __future__ import annotations
@@ -52,7 +73,7 @@ from .trace import NULL_TRACER
 
 __all__ = ["CompileMonitorConfig", "CompileMonitor", "MonitoredFunction",
            "ProgramStats", "RecompileBudgetExceeded", "peak_flops_total",
-           "pool_copy_bytes"]
+           "pool_copy_bytes", "CompileAccount", "process_account"]
 
 Event = Tuple[str, float, int]
 
@@ -94,7 +115,7 @@ class ProgramStats:
     name: str
     group: str = "Train"            # event group for the MFU gauges
     compiles: int = 0               # lower+compile executions (signatures)
-    cache_hits: int = 0             # dispatches served by a compiled program
+    cache_hits: int = 0             # dispatches served by the monitor's table
     recompiles: int = 0             # compiles beyond the first signature
     lower_ms: float = 0.0           # cumulative lowering wall time
     compile_ms: float = 0.0         # cumulative backend-compile wall time
@@ -103,6 +124,9 @@ class ProgramStats:
     peak_memory_bytes: int = 0      # largest compiled signature's device peak
     pool_copy_bytes: int = 0        # last compile: pool-shaped copies (``pools``)
     aliased_bytes: int = 0          # last compile: arguments aliased to results
+    analysis_ms: float = 0.0        # cumulative: the monitor's own inspection
+    persistent_cache_hits: int = 0  # compile() calls JAX's disk cache served
+    persistent_cache_misses: int = 0    # ... and those it compiled and wrote
     calls_since_drain: int = 0      # executions since the last events() drain
     signatures: List[Any] = field(default_factory=list)
 
@@ -236,6 +260,232 @@ def _aliased_bytes(compiled) -> int:
         return 0
 
 
+# jax.monitoring's names (public, JAX 0.9) -> the account's phases. A duration
+# is an interval; a count is a moment. Every other event is let pass.
+_DURATION_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_COUNT_PHASES = {
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_request",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+# what totals() answers: seconds are the union of these phases' intervals,
+# counts the number of these phases' records
+_ACCOUNT_SECONDS = {
+    "trace_lower_s": ("trace", "lower"),
+    "backend_compile_s": ("backend_compile",),
+    "cache_retrieval_s": ("cache_retrieval",),
+    "monitor_analysis_s": ("monitor_analysis",),
+}
+_ACCOUNT_COUNTS = {
+    "cache_hits": "cache_hit",
+    "cache_misses": "cache_miss",
+    "cache_requests": "cache_request",
+    "programs_compiled": "backend_compile",
+}
+# phases that end a whole program on their thread (see CompileAccount._fold)
+_WHOLE_PROGRAM = ("backend_compile", "monitor_analysis")
+_JIT_WRAPPED = re.compile(r"^p?jit\((.*)\)$")
+
+# (phase, fun_name, t0, t1, thread): perf_counter seconds, get_ident()
+AccountRecord = Tuple[str, str, float, float, int]
+
+
+def _union_s(intervals) -> float:
+    """Length of the union of ``(t0, t1)`` intervals."""
+    total, end = 0.0, -math.inf
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+def _tally(records) -> Dict[str, float]:
+    """The account's answer over ``records``."""
+    out: Dict[str, float] = {}
+    for key, phases in _ACCOUNT_SECONDS.items():
+        out[key] = _union_s((r[2], r[3]) for r in records if r[0] in phases)
+    for key, phase in _ACCOUNT_COUNTS.items():
+        out[key] = sum(r[0] == phase for r in records)
+    return out
+
+
+def _add_tally(into: Dict[str, float], records) -> None:
+    for key, value in _tally(records).items():
+        into[key] = into.get(key, 0) + value
+
+
+def _add_tally_by_name(into: Dict[str, Dict[str, float]], records) -> None:
+    by_name: Dict[str, List[AccountRecord]] = {}
+    for r in records:
+        by_name.setdefault(r[1], []).append(r)
+    for name, mine in by_name.items():
+        _add_tally(into.setdefault(name, {}), mine)
+
+
+class CompileAccount:
+    """Where the process's compile time went, from ``jax.monitoring`` (module
+    docstring). One record an event, ``(phase, fun_name, t0, t1, thread)``
+    with ``t1 = time.perf_counter()`` at the callback and ``t0 = t1 -
+    duration``; phases ``trace``, ``lower``, ``backend_compile`` (which holds
+    a cache retrieval where there was one), ``cache_retrieval``, the three
+    cache counts, and ``monitor_analysis`` (the compile monitor's own work
+    after a compile, :meth:`record`).
+
+    Memory is bounded: past ``cap`` records (far above any set-up's; a
+    server that keeps compiling for a week must not grow) all of them are
+    FOLDED into running totals, and a ``before`` earlier than that moment is
+    refused - the account cannot split what it has folded. The fold waits
+    for the end of a whole program (a backend compile or the monitor's
+    analysis of it: nothing of that thread is in flight then, so no interval
+    straddles the fold and the union stays exact) or, failing that, for
+    twice the cap. A callback takes
+    the account's own lock, which no dispatch takes, for an append; in a
+    steady window nothing compiles and no callback runs."""
+
+    def __init__(self, cap: int = 1 << 17):
+        self.cap = max(2, int(cap))
+        self.events_seen = 0              # records ever taken (folded too)
+        self._records: List[AccountRecord] = []
+        self._folded: Dict[str, float] = {}
+        self._folded_by_name: Dict[str, Dict[str, float]] = {}
+        self._folded_until = -math.inf
+        self._totals: Optional[Dict[str, float]] = None   # of everything
+        self._installed = False
+        self._lock = threading.Lock()
+
+    # ------------------------------------------------------------------ #
+    def install(self) -> None:
+        """Register the two listeners with ``jax.monitoring``, once."""
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        jax.monitoring.register_event_listener(self.on_event)
+        jax.monitoring.register_event_duration_secs_listener(self.on_duration)
+
+    def on_event(self, event: str, **_kwargs) -> None:
+        phase = _COUNT_PHASES.get(event)
+        if phase is not None:
+            now = time.perf_counter()
+            self.record(phase, "", now, now)
+
+    def on_duration(self, event: str, duration_secs: float,
+                    **kwargs) -> None:
+        phase = _DURATION_PHASES.get(event)
+        if phase is not None:
+            now = time.perf_counter()
+            self.record(phase, str(kwargs.get("fun_name", "")),
+                        now - duration_secs, now)
+
+    def record(self, phase: str, fun_name: str, t0: float,
+               t1: float) -> None:
+        m = _JIT_WRAPPED.match(fun_name)   # 'jit(f)' lowers what 'f' traced
+        name = m.group(1) if m else fun_name
+        thread = threading.get_ident()
+        with self._lock:
+            recs = self._records
+            if phase == "backend_compile":
+                # the cache's events carry no name: they happened inside
+                # this compile, on this thread
+                i = len(recs) - 1
+                while i >= 0 and recs[i][3] >= t0:
+                    r = recs[i]
+                    if r[4] == thread and r[0].startswith("cache_"):
+                        recs[i] = (r[0], name) + r[2:]
+                    i -= 1
+            recs.append((phase, name, t0, t1, thread))
+            self.events_seen += 1
+            self._totals = None
+            if len(recs) > self.cap and (phase in _WHOLE_PROGRAM
+                                         or len(recs) > 2 * self.cap):
+                self._fold(t1)
+
+    def _fold(self, until: float) -> None:
+        _add_tally(self._folded, self._records)
+        _add_tally_by_name(self._folded_by_name, self._records)
+        self._records = []
+        self._folded_until = until
+
+    # ------------------------------------------------------------------ #
+    def _ended_before(self, before: Optional[float]):
+        with self._lock:
+            if before is not None and before < self._folded_until:
+                raise ValueError(
+                    f"the account has folded its records up to "
+                    f"{self._folded_until} and cannot split them at "
+                    f"{before}")
+            recs = list(self._records)
+            folded = dict(self._folded)
+            by_name = {n: dict(t) for n, t in self._folded_by_name.items()}
+        if before is not None:
+            recs = [r for r in recs if r[3] <= before]
+        return recs, folded, by_name
+
+    def totals(self, before: Optional[float] = None) -> Dict[str, float]:
+        """Seconds by phase (union of intervals, all threads together) and
+        counts, over the events that ended before ``before`` (a
+        ``perf_counter`` time; everything if None): ``trace_lower_s``,
+        ``backend_compile_s``, ``cache_retrieval_s``, ``monitor_analysis_s``,
+        ``cache_hits``, ``cache_misses``, ``cache_requests`` and
+        ``programs_compiled`` (every program the process asked the backend
+        for, hit or miss, eager one-op programs too)."""
+        cached = self._totals
+        if before is None and cached is not None:
+            return dict(cached)       # a drain every step re-adds nothing
+        seen = self.events_seen
+        recs, folded, _ = self._ended_before(before)
+        _add_tally(folded, recs)
+        if before is None:
+            with self._lock:
+                if self.events_seen == seen:
+                    self._totals = dict(folded)
+        return folded
+
+    def by_program(self, before: Optional[float] = None,
+                   top: int = 10) -> List[Dict[str, Any]]:
+        """:meth:`totals` by ``fun_name`` (``jit(f)`` counted with ``f``),
+        the ``top`` costliest in seconds first. A function's own seconds
+        contain those of the functions it calls."""
+        recs, _, by_name = self._ended_before(before)
+        _add_tally_by_name(by_name, recs)
+        rows = [{"program": name, **t} for name, t in by_name.items()]
+        rows.sort(key=lambda r: -(r["trace_lower_s"] + r["backend_compile_s"]
+                                  + r["monitor_analysis_s"]))
+        return rows[:top]
+
+    def cache_outcome(self, since: float) -> Tuple[int, int, int]:
+        """``(hits, misses, requests)`` of the persistent cache on the
+        CALLING thread since ``since``: what one ``compile()`` met."""
+        thread = threading.get_ident()
+        counts = dict.fromkeys(("cache_hit", "cache_miss", "cache_request"),
+                               0)
+        with self._lock:
+            for r in reversed(self._records):
+                if r[3] < since:
+                    break
+                if r[4] == thread and r[0] in counts:
+                    counts[r[0]] += 1
+        return (counts["cache_hit"], counts["cache_miss"],
+                counts["cache_request"])
+
+
+_PROCESS_ACCOUNT = CompileAccount()
+
+
+def process_account() -> CompileAccount:
+    """The one account of this process: ``jax.monitoring``'s listeners are
+    process-wide, so what they feed is too. Empty until the first enabled
+    :class:`CompileMonitor` installs it, and deaf to what compiled before
+    (weights materialised ahead of the engine's monitor)."""
+    return _PROCESS_ACCOUNT
+
+
 class MonitoredFunction:
     """A jitted entry point dispatching through the monitor's own
     signature → compiled-program cache. A signature miss runs the explicit
@@ -300,7 +550,8 @@ class MonitoredFunction:
             self._monitor._record_compile(
                 self._name, self._group, sig, lower_ms=(t1 - t0) * 1e3,
                 compile_ms=(t2 - t1) * 1e3, compiled=compiled, span=span,
-                pools=self._pools)
+                pools=self._pools,
+                cache=self._monitor.account.cache_outcome(since=t1))
         return compiled(*args, **kwargs)
 
     def _degrade(self, why: str) -> None:
@@ -330,6 +581,11 @@ class CompileMonitor:
         self.unexpected_recompiles = 0
         self._budget_tripped = False
         self._lock = threading.Lock()
+        # the process's compile account: installed by the first enabled
+        # monitor, never by a disabled one (the default path is event-free)
+        self.account = process_account()
+        if self.enabled:
+            self.account.install()
         # per-caller drain timestamps and first-dispatch marks, keyed by
         # event group ('' = an unscoped drain over every group). A drain's
         # first wall window is anchored at the group's first POST-compile
@@ -371,7 +627,11 @@ class CompileMonitor:
             self._dispatch_t0.setdefault(st.group, time.monotonic())
 
     def _record_compile(self, name: str, group: str, sig, lower_ms: float,
-                        compile_ms: float, compiled, span, pools=()) -> None:
+                        compile_ms: float, compiled, span, pools=(),
+                        cache=(0, 0, 0)) -> None:
+        """``cache``: ``(hits, misses, requests)`` of JAX's persistent cache
+        during this program's ``compile()`` (``CompileAccount.cache_outcome``)."""
+        t_analysis = time.perf_counter()
         flops = bytes_ = 0.0
         if self.cost_analysis:
             flops, bytes_ = _cost_analysis(compiled)
@@ -381,6 +641,10 @@ class CompileMonitor:
             pool_attrs = {
                 "pool_copy_bytes": pool_copy_bytes(compiled.as_text(), pools),
                 "aliased_bytes": _aliased_bytes(compiled)}
+        t_analysed = time.perf_counter()
+        self.account.record("monitor_analysis", name, t_analysis, t_analysed)
+        analysis_ms = (t_analysed - t_analysis) * 1e3
+        hits, misses, requests = cache
         with self._lock:
             st = self.stats[name]
             recompile = len(st.signatures) >= 1
@@ -391,6 +655,9 @@ class CompileMonitor:
             st.recompiles += int(recompile)
             st.lower_ms += lower_ms
             st.compile_ms += compile_ms
+            st.analysis_ms += analysis_ms
+            st.persistent_cache_hits += hits
+            st.persistent_cache_misses += misses
             if flops > 0:
                 st.cost_flops = flops
             if bytes_ > 0:
@@ -408,7 +675,9 @@ class CompileMonitor:
             # marks the start of the group's executed window
             self._dispatch_t0.setdefault(group, time.monotonic())
         span.set(lower_ms=round(lower_ms, 3), compile_ms=round(compile_ms, 3),
-                 recompile=recompile, **pool_attrs)
+                 recompile=recompile, analysis_ms=round(analysis_ms, 3),
+                 persistent_cache="hit" if hits else
+                 "miss" if requests else "off", **pool_attrs)
         if recompile:
             logger.warning(
                 f"recompilation detected: program '{name}' compiled a new "
@@ -440,6 +709,10 @@ class CompileMonitor:
                         "peak_memory_bytes": st.peak_memory_bytes,
                         "pool_copy_bytes": st.pool_copy_bytes,
                         "aliased_bytes": st.aliased_bytes,
+                        "analysis_ms": st.analysis_ms,
+                        "persistent_cache_hits": st.persistent_cache_hits,
+                        "persistent_cache_misses":
+                            st.persistent_cache_misses,
                         "signatures": len(st.signatures)}
                     for n, st in self.stats.items()}
 
@@ -458,7 +731,9 @@ class CompileMonitor:
         would attribute serving calls over the train-step window (and vice
         versa). ``Compile/total/*`` stays cumulative over EVERY program
         regardless of the filter: one monotone series whichever caller
-        drains."""
+        drains - and so does ``Compile/process/*``, the process's compile
+        account (every program JAX traced, lowered or compiled, registered
+        here or not)."""
         if not self.enabled:
             return []
         now = time.monotonic()
@@ -495,7 +770,12 @@ class CompileMonitor:
                     (f"Compile/{name}/recompiles", float(st.recompiles),
                      step),
                     (f"Compile/{name}/lower_ms", st.lower_ms, step),
-                    (f"Compile/{name}/compile_ms", st.compile_ms, step)]
+                    (f"Compile/{name}/compile_ms", st.compile_ms, step),
+                    (f"Compile/{name}/analysis_ms", st.analysis_ms, step),
+                    (f"Compile/{name}/persistent_cache_hits",
+                     float(st.persistent_cache_hits), step),
+                    (f"Compile/{name}/persistent_cache_misses",
+                     float(st.persistent_cache_misses), step)]
                 if st.cost_flops > 0:
                     events.append((f"Compile/{name}/cost_flops",
                                    st.cost_flops, step))
@@ -520,4 +800,6 @@ class CompileMonitor:
             for key in ("programs", "compiles", "cache_hits", "recompiles",
                         "lower_ms", "compile_ms"):
                 events.append((f"Compile/total/{key}", float(tot[key]), step))
+        for key, value in self.account.totals().items():
+            events.append((f"Compile/process/{key}", float(value), step))
         return events

@@ -384,7 +384,7 @@ class TelemetryHub:
             rows.append((name, float(count), "counter"))
         for name, value in sorted(self.compile_values.items()):
             parts = name.split("/")
-            if name.startswith("Compile/total/"):
+            if name.startswith(("Compile/total/", "Compile/process/")):
                 rows.append((name, float(value), "counter"))
             elif name.startswith("Compile/") and len(parts) == 3:
                 # per-program series fold onto one metric with a program

@@ -37,8 +37,9 @@ Checked invariants:
   ``--comm-efficiency`` report would silently drop) fails validation.
 - ``Compile/*`` names follow the same shape: program names are open-ended
   (any entry point registered with the CompileMonitor), but the metric
-  suffix must come from ``COMPILE_METRICS`` and the ``Compile/total/*``
-  rollup family from ``COMPILE_TOTAL_SERIES``;
+  suffix must come from ``COMPILE_METRICS``, the ``Compile/total/*``
+  rollup family from ``COMPILE_TOTAL_SERIES`` and the process-wide compile
+  account's ``Compile/process/*`` from ``COMPILE_PROCESS_SERIES``;
 - ``Anomaly/*`` names come from the CLOSED ``ANOMALY_SERIES`` registry (the
   step-time/per-phase spike+drift series and the per-host straggler);
 - ``Train/mfu/*`` and ``Serving/mfu/*`` carry one lowercase snake_case
@@ -55,7 +56,8 @@ from typing import Any, Dict, Iterable, List, Tuple
 __all__ = ["EVENT_NAME_RE", "SERVING_SERIES", "TRAIN_SERIES",
            "TRAIN_STEP_SERIES", "SCORE_SERIES",
            "COMM_METRICS", "COMM_TOTAL_SERIES", "COMM_RING_SERIES",
-           "COMPILE_METRICS", "COMPILE_TOTAL_SERIES", "ANOMALY_SERIES",
+           "COMPILE_METRICS", "COMPILE_TOTAL_SERIES",
+           "COMPILE_PROCESS_SERIES", "ANOMALY_SERIES",
            "MEMORY_TIER_SERIES", "RELIABILITY_ELASTIC_SERIES",
            "RELIABILITY_INTEGRITY_SERIES",
            "TENANT_METRICS", "FLEET_REPLICA_METRICS", "FLEET_AGG_SERIES",
@@ -208,11 +210,21 @@ COMM_RING_SERIES = frozenset(
 COMPILE_METRICS = frozenset((
     "compiles", "cache_hits", "recompiles", "lower_ms", "compile_ms",
     "cost_flops", "cost_bytes", "peak_memory_bytes", "pool_copy_bytes",
-    "aliased_bytes"))
+    "aliased_bytes", "analysis_ms", "persistent_cache_hits",
+    "persistent_cache_misses"))
 COMPILE_TOTAL_SERIES = frozenset(
     "Compile/total/" + m for m in (
         "programs", "compiles", "cache_hits", "recompiles", "lower_ms",
         "compile_ms"))
+# Compile/process/*: the process-wide compile account (telemetry/compile.py
+# CompileAccount.totals, fed by jax.monitoring) - every program of the
+# process, registered with a monitor or not. CLOSED. Here ``cache_hits`` is
+# JAX's persistent cache's, not a monitor's dispatch table's.
+COMPILE_PROCESS_SERIES = frozenset(
+    "Compile/process/" + m for m in (
+        "trace_lower_s", "backend_compile_s", "cache_retrieval_s",
+        "monitor_analysis_s", "cache_hits", "cache_misses", "cache_requests",
+        "programs_compiled"))
 
 # The phase keys the hub's step-breakdown timers can emit (hub._STEP_TIMERS
 # event suffixes) — the anomaly detector tracks one series per phase.
@@ -507,6 +519,13 @@ def validate_events(events: Iterable[Tuple[str, float, int]]) -> List[str]:
                 problems.append(
                     f"event #{i}: compile rollup series {name!r} is not "
                     f"registered in telemetry.schema.COMPILE_TOTAL_SERIES")
+                continue
+        elif name.startswith("Compile/process/"):
+            if name not in COMPILE_PROCESS_SERIES:
+                problems.append(
+                    f"event #{i}: compile account series {name!r} is not "
+                    f"registered in "
+                    f"telemetry.schema.COMPILE_PROCESS_SERIES")
                 continue
         elif name.startswith("Compile/"):
             parts = name.split("/")

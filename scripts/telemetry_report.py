@@ -298,10 +298,45 @@ def _overlap_remat_sections(events: List[dict]) -> List[str]:
     return lines
 
 
+def _startup_block(proc: Dict[str, float],
+                   per: Dict[str, Dict[str, float]]) -> List[str]:
+    """Where start-up went: the process-wide compile account
+    (``Compile/process/*``: every program JAX traced, lowered or compiled,
+    seconds as the union of the intervals) and the ten registered programs
+    that cost most (lowering + compile + the monitor's own analysis)."""
+    lines = ["", "start-up, whole process (jax.monitoring)"]
+    for key, label in (("trace_lower_s", "tracing + lowering"),
+                       ("backend_compile_s", "backend compile"),
+                       ("cache_retrieval_s", "  of it cache retrieval"),
+                       ("monitor_analysis_s", "monitor's own analysis")):
+        lines.append(f"  {label + ':':<26} {proc.get(key, 0.0):>9.2f} s")
+    lines.append(
+        f"  persistent cache:          "
+        f"{int(proc.get('cache_hits', 0))} hits, "
+        f"{int(proc.get('cache_misses', 0))} misses of "
+        f"{int(proc.get('cache_requests', 0))} requests; "
+        f"{int(proc.get('programs_compiled', 0))} programs asked of the "
+        f"backend")
+    cost = lambda m: (m.get("lower_ms", 0.0) + m.get("compile_ms", 0.0)
+                      + m.get("analysis_ms", 0.0))
+    lines.append(f"  {'costliest programs':<18} {'lower ms':>10} "
+                 f"{'compile ms':>11} {'analysis ms':>12} {'disk cache':>14}")
+    for prog in sorted(per, key=lambda p: -cost(per[p]))[:10]:
+        m = per[prog]
+        disk = (f"{int(m.get('persistent_cache_hits', 0))} hit "
+                f"{int(m.get('persistent_cache_misses', 0))} miss")
+        lines.append(
+            f"  {prog:<18} {m.get('lower_ms', 0.0):>10.1f} "
+            f"{m.get('compile_ms', 0.0):>11.1f} "
+            f"{m.get('analysis_ms', 0.0):>12.1f} {disk:>14}")
+    return lines
+
+
 def compile_report(events: List[dict]) -> str:
     """``--compile``: recompilation-sentinel counters per jitted program
     (compiles, cache hits, RECOMPILES, lowering/compile wall time, analytic
-    cost-model flops) from the ``Compile/*`` stream, plus the per-program
+    cost-model flops) from the ``Compile/*`` stream, where start-up went
+    (``Compile/process/*``: :func:`_startup_block`), plus the per-program
     MFU attribution from ``Train/mfu/*`` / ``Serving/mfu/*`` — the
     decomposition of the ThroughputTimer headline (docs/observability.md).
     Cumulative counters and gauges: last sample per series wins."""
@@ -317,6 +352,7 @@ def compile_report(events: List[dict]) -> str:
             _, prog, metric = e["name"].split("/", 2)
             per.setdefault(prog, {})[metric] = e["value"]   # last wins
         tot = per.pop("total", {})
+        proc = per.pop("process", {})
         lines.append(f"compile report ({len(comp)} events)")
         lines.append(f"  {'program':<18} {'compiles':>8} {'hits':>8} "
                      f"{'recompiles':>10} {'compile ms':>11} "
@@ -342,6 +378,8 @@ def compile_report(events: List[dict]) -> str:
         lines.append(f"  compile wall time:      "
                      f"{tot.get('compile_ms', 0.0) / 1e3:.2f} s "
                      f"(+ {tot.get('lower_ms', 0.0) / 1e3:.2f} s lowering)")
+        if proc:
+            lines += _startup_block(proc, per)
     if mfu:
         last: Dict[str, float] = {}
         for e in mfu:
