@@ -939,7 +939,7 @@ class InferenceEngineV2(InferenceEngine):
         or a latent cache's one pool; None for a family that has neither."""
         return self.cache.get("k", self.cache.get("latent"))
 
-    def _attn_tile_args(self) -> Dict[str, float]:
+    def _attn_tile_args(self, live) -> Dict[str, float]:
         """Span arguments of a decode dispatch over the slots as they stand:
         of ONE layer's ``paged_decode`` call (``paged_sparse_decode``, the
         same walk, under a learned selection), the KV tiles that hold live
@@ -947,18 +947,37 @@ class InferenceEngineV2(InferenceEngine):
         its own pages, every slot as far as the longest where it is a grid
         of ``BlockSpec`` pages - and their ratio (the kernel's own tile
         sizes: ``ops/pallas/paged_attention.py``). None for a family whose
-        paged cache is not ``init_paged_pools``'."""
+        paged cache is not ``init_paged_pools``'. A learned selection's
+        ``paged_index_scores`` call over the slots of ``live``, the
+        sequences that decode, says the same of its own tiles as
+        ``index_tiles_live`` / ``index_tiles_grid`` /
+        ``index_live_tile_share`` (``paged_sparse_attention.py
+        index_tile_counts``; no share where nothing decodes and the call
+        takes no tile)."""
         from ..ops.pallas.paged_attention import decode_tile_counts
 
         pool = self._walked_pool()
         if pool is None:
             return {}
-        live, grid = decode_tile_counts(
+        width = self.state.max_blocks_per_seq
+        n_live, grid = decode_tile_counts(
             self._slot_lens, self.family.cfg.num_heads, pool.shape,
-            pool.dtype.itemsize, self.state.max_blocks_per_seq,
-            "k_scale" in self.cache, 1 if self._latent else 2)
-        return {"attn_tiles_live": live, "attn_tiles_grid": grid,
-                "attn_live_tile_share": live / grid}
+            pool.dtype.itemsize, width, "k_scale" in self.cache,
+            1 if self._latent else 2)
+        out = {"attn_tiles_live": n_live, "attn_tiles_grid": grid,
+               "attn_live_tile_share": n_live / grid}
+        if self._indexed:
+            from ..ops.pallas.paged_sparse_attention import index_tile_counts
+
+            decoding = np.zeros(self._slot_lens.shape, np.int32)
+            decoding[[d.slot for d in live]] = 1
+            n_live, grid = index_tile_counts(
+                self._slot_lens, decoding, self.cache["kI"].shape,
+                self.state.block_size, width)
+            out.update(index_tiles_live=n_live, index_tiles_grid=grid)
+            if grid:
+                out["index_live_tile_share"] = n_live / grid
+        return out
 
     def _chunk_tile_args(self, ch: _Chunk) -> Dict[str, int]:
         """Span arguments of a chunk's ``paged_prefill`` walk, host integers
@@ -1656,7 +1675,7 @@ class InferenceEngineV2(InferenceEngine):
                 **chunk_args) as span:
             with self.tracer.span("engine_prep", cat="serving"):
                 self._reserve(live, repeat(1))
-                extra = self._attn_tile_args()
+                extra = self._attn_tile_args(live)
                 if ch is not None:
                     table = self._table(ch.desc, len(ch.tokens))
                     fn, pre, post = self._chunk_program(ch, live, table)

@@ -14,6 +14,18 @@ Five calls, each on the layer's index into ``[L, ...]`` pools, like
 
 ``paged_index_write``   the step's index keys into the THIRD pool, in place.
 ``paged_index_scores``  ``I`` over a block table -> ``[B, rows, S]`` float32.
+                        One token a sequence (the decode rows) takes
+                        ``paged_decode``'s walk over the index pool
+                        (``_index_walk_kernel``): each sequence to its own
+                        end and an idle slot nowhere, page DMAs issued from
+                        the block table, a tile and a sequence ahead. A
+                        packed page is one whole ``[bs / pack, 128]`` tile
+                        of the pool, so one DMA moves it, and behind a
+                        ``BlockSpec`` each 4 KB page cost a grid step more
+                        than its bytes did. A chunk's rows keep the grid of
+                        table-indexed ``BlockSpec`` pages, as long as the
+                        longest slot - as a decode row does where Mosaic
+                        cannot slice the page (``_fetches_index_pages``).
 ``paged_sparse_select`` the exact threshold of each row: its ``topk``-th
                         largest score and, among the scores equal to it, the
                         last position taken. A bisection on the score's bit
@@ -46,7 +58,8 @@ and contracts over all 128 lanes against the query repeated ``pack`` times,
 so no lane is ever shifted; the same bytes as ``[bs, d]``, in whole tiles.
 
 Scores past a row's own position are never read: the selection masks by
-position, so the scores call skips dead tiles and leaves them unwritten.
+position, so the scores call skips dead tiles and leaves them unwritten (a
+slot with no row: all of them).
 """
 
 from __future__ import annotations
@@ -56,6 +69,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,10 +87,22 @@ KEY_MIN = -2 ** 31          # the sort key of a position that is not the row's
 _SELECT_ROWS = 8            # rows of one selection tile: a sublane tile
 _SELECT_CHUNK = 2048        # score lanes of one counting step
 _SCORE_ROWS = 64            # query tokens of one scores tile, at most
-_INDEX_PAGES = 32           # index-pool pages of one scores step of a decode
-                            # row (a page of one 64-wide key head is 4 KB);
-                            # a call of more rows takes _MAX_PAGES: its
-                            # [heads x rows, KV] scores fill the VMEM sooner
+_INDEX_PAGES = 64           # index-pool pages of one tile of a decode row's
+                            # scores (a page of one 64-wide key head is
+                            # 4 KB, a tile 2 048 tokens): the walk that
+                            # fetches its own pages, or the grid's step
+                            # where it cannot. The walk is bound by its
+                            # pages, ~25 ns each, and a tile's own cost is
+                            # small: 8 slots of which 4 decode over 6-31 k
+                            # tokens take 71-86 us a call (the grid of
+                            # BlockSpec pages: 598-706), and 32 / 128 pages
+                            # read 3 % over / 2 % under 64
+                            # (scripts/sparse_kernel_bench.py --only index
+                            # on the chip, PR 54); a sequence's last tile
+                            # is computed whole, so the widest is not
+                            # taken. A call of more rows takes _MAX_PAGES:
+                            # its [heads x rows, KV] scores fill the VMEM
+                            # sooner
 _PREFILL_PAGES = 32         # K / V pages of one step of the multi-token
                             # masked walk: 1024 tokens. A step's fixed work
                             # (the flash rescale of a [rows, 128]
@@ -256,21 +282,41 @@ def _score_tiles(rows: int) -> int:
 
 
 def _index_tile(page_refs, d: int):
-    """One KV tile of index keys from its packed pages, token-major
+    """One KV tile of index keys from its packed pages (refs, or the pages'
+    values), token-major
     ``[pages * bs, 128]``: a token's row is its page row with every other
     token's lanes zeroed."""
-    out = []
-    for ref in page_refs:
-        page = ref[...]
-        width = page.shape[-1]
-        lane = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
-        for p in range(width // d):
-            mine = jnp.logical_and(lane >= p * d, lane < (p + 1) * d)
-            out.append(jnp.where(mine, page, jnp.zeros_like(page)))
+    pages = [ref[...] for ref in page_refs]
+    shape, width = pages[0].shape, pages[0].shape[-1]
+    # every page has one shape: the tokens' lane masks are made once (a
+    # traced equation is host time in every program that holds the kernel)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mine = [jnp.logical_and(lane >= p * d, lane < (p + 1) * d)
+            for p in range(width // d)]
+    zeros = jnp.zeros(shape, pages[0].dtype)
+    out = [jnp.where(m, page, zeros) for page in pages for m in mine]
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
 
 
-def _index_scores_kernel(*refs, bs, d, pages, tq, heads):
+def _tile_scores(q_ref, w_ref, page_refs, d: int, tq: int):
+    """The scores ``[tq, KV]`` of one KV tile's pages for ``tq`` query
+    tokens, their packed index queries ``[heads * tq, 128]`` head-major."""
+    heads = q_ref.shape[0] // tq
+    k = _index_tile(page_refs, d)                       # [kv, 128]
+    s = _mxu_dot(q_ref[...], k, _contract(2, -1),
+                 preferred_element_type=jnp.float32)         # [heads*tq, kv]
+    s = jnp.maximum(s, 0.0) * w_ref[...]
+    if tq == 1:     # one query token: the heads are the tile's rows
+        acc = jnp.sum(s, axis=0, keepdims=True)
+    else:
+        acc = s[:tq]
+        for h in range(1, heads):
+            acc = acc + s[h * tq:(h + 1) * tq]
+    # -0.0 and 0.0 are one score: one sort key
+    return jnp.where(acc == 0.0, 0.0, acc)
+
+
+def _index_scores_kernel(*refs, bs, d, pages, tq):
     ctx_ref, len_ref = refs[1], refs[2]
     q_ref, w_ref = refs[4], refs[5]
     k_refs, o_ref = refs[6:6 + pages], refs[6 + pages]
@@ -283,18 +329,169 @@ def _index_scores_kernel(*refs, bs, d, pages, tq, heads):
 
     @pl.when(live)
     def _compute():
-        k = _index_tile(k_refs, d)                      # [kv, 128]
-        s = _mxu_dot(q_ref[...], k, _contract(2, -1),
-                     preferred_element_type=jnp.float32)     # [heads*tq, kv]
-        s = jnp.maximum(s, 0.0) * w_ref[...]
-        if tq == 1:     # one query token: the heads are the tile's rows
-            acc = jnp.sum(s, axis=0, keepdims=True)
-        else:
-            acc = s[:tq]
-            for h in range(1, heads):
-                acc = acc + s[h * tq:(h + 1) * tq]
-        # -0.0 and 0.0 are one score: one sort key
-        o_ref[0:tq, :] = jnp.where(acc == 0.0, 0.0, acc)
+        o_ref[0:tq, :] = _tile_scores(q_ref, w_ref, k_refs, d, tq)
+
+
+def _index_walk_kernel(tables_ref, ctx_ref, len_ref, layer_ref, q_ref, w_ref,
+                       pool, o_ref, buf, sems, slot_ref, *, d, pages,
+                       max_blocks, nblocks):
+    """The scores of one query token a sequence, by the walk of
+    ``paged_attention._decode_kernel`` over the packed index pool: grid step
+    ``b`` walks sequence ``b`` from page 0 to the page of its own position,
+    a tile of ``pages`` pages an iteration of an in-kernel loop, and fetches
+    those pages itself - one DMA a page (``[bs / pack, 128]``: one whole
+    tile of the pool) from the block table in SMEM into the page's rows of a
+    double-buffered ``[2, pages * bs / pack, 128]`` scratch. The NEXT tile -
+    this sequence's, or the first of the next sequence that decodes - is
+    started before this one is waited for; which half holds the tile in
+    flight is carried in SMEM from grid step to grid step (the grid is
+    sequential). A sequence with no row (``lengths[b] == 0``) fetches and
+    computes nothing, and its table row is never read."""
+    b, n_seq = pl.program_id(0), len_ref.shape[0]
+    prow = buf.shape[1] // pages
+    bs = prow * (buf.shape[2] // d)
+    kv = pages * bs
+    add, mul, div = jax.lax.add, jax.lax.mul, jax.lax.div
+    lo, hi = jax.lax.max, jax.lax.min
+
+    def rows_of(p):
+        return pl.ds(pl.multiple_of(mul(p, prow), prow), prow)
+
+    def last_page(b):
+        """The page of sequence ``b``'s own position."""
+        return hi(div(ctx_ref[b], bs), max_blocks - 1)
+
+    def tile_copies(b, pg0, slot, fetch):
+        """The page copies of sequence ``b``'s tile that begins at table
+        entry ``pg0`` - of its pages up to the sequence's last alone -
+        started (``fetch``) or waited for; a wait takes the copy's shape
+        and semaphore, and no source."""
+        src, dst, sem = pool.at[layer_ref[0]], buf.at[slot], sems.at[slot]
+
+        def page(p, _):
+            blk = hi(lo(tables_ref[b, add(pg0, p)], 0), nblocks - 1) \
+                if fetch else 0
+            copy = pltpu.make_async_copy(src.at[blk, 0], dst.at[rows_of(p)],
+                                         sem)
+            copy.start() if fetch else copy.wait()
+            return _
+
+        n = hi(pages, add(add(last_page(b), 1), -pg0))
+
+        # a whole tile's copies in straight-line code: the walk is bound by
+        # its pages' issue and wait on the scalar core, and a loop with a
+        # dynamic trip count costs each page half as much again. Unrolled
+        # where the loop is LOWERED, not in Python: every traced copy costs
+        # a TPU host ~18 ms (each static index of its indexers becomes a
+        # device array), 64 pages x 3 sites x every program that holds one
+        @pl.when(n == pages)
+        def _whole_tile():
+            jax.lax.fori_loop(0, pages, page, 0, unroll=True)
+
+        @pl.when(n < pages)
+        def _last_tile():
+            jax.lax.fori_loop(0, n, page, 0)
+
+    def decoding_after(b):
+        """The first sequence after ``b`` that has a row; ``n_seq``: none."""
+        def earlier(i, found):
+            s = n_seq - 1 - i
+            return jnp.where(jnp.logical_and(s > b, len_ref[s] > 0), s, found)
+        return jax.lax.fori_loop(0, n_seq, earlier, n_seq)
+
+    @pl.when(b == 0)
+    def _prime():
+        # a tile's rows past its sequence's last page are never fetched and
+        # their scores never read, but they go through the matmul: what the
+        # scratch holds there has to be finite, which every earlier tile's
+        # rows are and fresh VMEM need not be
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        first = decoding_after(-1)
+
+        @pl.when(first < n_seq)
+        def _fetch_first():
+            tile_copies(first, 0, 0, True)
+
+    @pl.when(len_ref[b] > 0)
+    def _walk():
+        n = add(div(last_page(b), pages), 1)
+        b_after = decoding_after(b)
+
+        def tile(j, _):
+            slot = slot_ref[0]
+            pg0 = mul(j, pages)
+            more = j + 1 < n
+            b_next = jnp.where(more, b, b_after)
+
+            @pl.when(b_next < n_seq)
+            def _fetch_next():
+                tile_copies(b_next, jnp.where(more, add(pg0, pages), 0),
+                            1 - slot, True)
+
+            tile_copies(b, pg0, slot, False)
+            keys = buf[slot]        # one load: a page's rows are a slice
+            o_ref[0:1, pl.ds(pl.multiple_of(mul(pg0, bs), kv), kv)] = \
+                _tile_scores(q_ref, w_ref,
+                             [keys[p * prow:(p + 1) * prow]
+                              for p in range(pages)], d, 1)
+            slot_ref[0] = 1 - slot
+            return _
+
+        jax.lax.fori_loop(0, n, tile, 0)
+
+
+def _fetches_index_pages(pool_shape) -> bool:
+    """Whether a decode row's scores fetch their index pages themselves
+    (``paged_attention._fetches_pages``' rule on the packed page): Mosaic
+    slices a page out of the pool, and the page's rows out of the scratch,
+    for a DMA only in whole ``(8, 128)`` tiles - an index head width that
+    divides 128, blocks of eight pool rows or more. Any other pool keeps the
+    grid of ``BlockSpec`` pages."""
+    return pool_shape[-1] % 128 == 0 and pool_shape[-2] % 8 == 0
+
+
+def _index_pages(t: int, max_blocks: int) -> int:
+    """Pool pages of one KV tile of a scores call of ``t`` tokens a
+    sequence."""
+    return _pow2_pages(_INDEX_PAGES if t == 1 else _MAX_PAGES, max_blocks)
+
+
+def index_tile_counts(context_lens, lengths, pool_shape, block_size: int,
+                      max_blocks: int) -> Tuple[int, int]:
+    """(live, taken) KV tiles of ONE ``paged_index_scores`` call of one
+    token a sequence over slots at ``context_lens`` (host integers), of
+    which those with ``lengths`` > 0 decode: the tiles that hold context a
+    decoding slot scores, and the tiles the call takes - the same tiles
+    where it fetches its own pages (:func:`_fetches_index_pages`), every
+    slot as far as the longest where it is the grid of ``BlockSpec`` pages.
+    What the serving engine puts on its ``decode_step`` span, as
+    ``paged_attention.decode_tile_counts`` for the decode walk."""
+    pages = _index_pages(1, max_blocks)
+    kv, n_kv = pages * block_size, -(-max_blocks // pages)
+    ctx, n = np.asarray(context_lens), np.asarray(lengths)
+    live = int(np.minimum(ctx[n > 0] // kv + 1, n_kv).sum())
+    if _fetches_index_pages(pool_shape):
+        return live, live
+    longest = min(max(-(-int((ctx + n).max()) // kv), 1), n_kv)
+    return live, longest * ctx.size
+
+
+def _packed_queries(q_idx, w_idx, pool, tq: int, n_qt: int):
+    """A call's index queries and head weights as the kernels take them:
+    ``[B, n_qt * H * tq, pack * d]`` (the query repeated over a page row's
+    ``pack`` tokens) and ``[B, n_qt * H * tq, 1]`` float32, head-major
+    inside each of the ``n_qt`` query tiles of ``tq`` tokens."""
+    B, t, H, d = q_idx.shape
+
+    def tiled(x):       # [B, t, H, w] -> [B, n_qt * H * tq, w]
+        x = jnp.pad(x, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
+        return x.reshape(B, n_qt, tq, H, -1).swapaxes(2, 3) \
+            .reshape(B, n_qt * H * tq, -1)
+
+    return (jnp.tile(tiled(q_idx).astype(pool.dtype),
+                     (1, 1, pool.shape[-1] // d)),
+            tiled(w_idx[..., None].astype(jnp.float32)))
 
 
 def paged_index_scores(q_idx, w_idx, pool, block_tables, context_lens,
@@ -308,29 +505,83 @@ def paged_index_scores(q_idx, w_idx, pool, block_tables, context_lens,
     score of cached token ``s`` for row ti - for ``s`` up to the row's own
     position ``context_lens[b] + ti`` and a real row; everything else is
     unspecified (dead tiles are not even written) and the selection never
-    reads it."""
+    reads it. One token a sequence (a decode row) walks each sequence's own
+    pages (:func:`_index_walk_kernel`) where Mosaic can slice a page out of
+    the pool (:func:`_fetches_index_pages`); every other call is the grid of
+    table-indexed ``BlockSpec`` pages."""
+    t = q_idx.shape[1]
+    return _index_scores(
+        _index_walk if t == 1 and _fetches_index_pages(pool.shape)
+        else _index_grid, q_idx, w_idx, pool, block_tables, context_lens,
+        lengths, layer=layer, rows=rows or -(-t // 8) * 8)
+
+
+def _index_scores(walk, q_idx, w_idx, pool, block_tables, context_lens,
+                  lengths, *, layer, rows: int):
+    """The scores call of one ``walk`` (:func:`_index_walk`,
+    :func:`_index_grid`)."""
+    kernel, grid_spec, operands, width, order = walk(
+        q_idx, w_idx, pool, block_tables.shape[1],
+        jnp.max(context_lens + lengths), rows)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((q_idx.shape[0], rows, width),
+                                       jnp.float32),
+        compiler_params=order,
+        interpret=_interpret(),
+        name="paged_index_scores",
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      lengths.astype(jnp.int32), _layer_scalar(layer, pool), *operands)
+
+
+def _index_walk(q_idx, w_idx, pool, max_blocks: int, bound, rows: int):
+    """One token a sequence, each sequence's own pages
+    (:func:`_index_walk_kernel`) as ``(kernel, grid, operands, S, grid
+    order)``: grid ``(B,)``, sequential - the tile in flight belongs to the
+    NEXT grid step; the pool stays in HBM and a sequence's ``[rows, S]``
+    scores are one output block, row 0 the query token's."""
+    del bound           # each sequence walks to its own end
+    B, _, H, d = q_idx.shape
+    nblocks, prow, width = pool.shape[1], pool.shape[-2], pool.shape[-1]
+    pages = _index_pages(1, max_blocks)
+    S = -(-max_blocks // pages) * pages * prow * (width // d)
+
+    def row_map(b, *_):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B,),
+        in_specs=[pl.BlockSpec((None, H, width), row_map),
+                  pl.BlockSpec((None, H, 1), row_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, rows, S), row_map),
+        scratch_shapes=[pltpu.VMEM((2, pages * prow, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)])
+    kernel = functools.partial(_index_walk_kernel, d=d, pages=pages,
+                               max_blocks=max_blocks, nblocks=nblocks)
+    return (kernel, grid_spec,
+            _packed_queries(q_idx, w_idx, pool, 1, 1) + (pool,), S,
+            _dim_semantics("arbitrary"))
+
+
+def _index_grid(q_idx, w_idx, pool, max_blocks: int, bound, rows: int):
+    """The grid ``(B, query tiles, KV tiles)`` of table-indexed ``BlockSpec``
+    pages, its last dimension DYNAMIC - the tiles up to ``bound``, the
+    longest slot's last real row - as ``(kernel, grid, operands, S, grid
+    order)``: the chunk's rows, and a decode row's where the walk cannot
+    slice its pages."""
     B, t, H, d = q_idx.shape
-    layer = _layer_scalar(layer, pool)
     nblocks, prow = pool.shape[1], pool.shape[-2]
     pack = pool.shape[-1] // d
     bs = prow * pack
-    max_blocks = block_tables.shape[1]
-    pages = _pow2_pages(_INDEX_PAGES if t == 1 else _MAX_PAGES, max_blocks)
+    pages = _index_pages(t, max_blocks)
     kv = pages * bs
     n_kv = -(-max_blocks // pages)
-    rows = rows or -(-t // 8) * 8
     # one query token a sequence (a decode row): its index heads are the
     # tile's rows, and the result is row 0 of an 8-row block
     tq = 1 if t == 1 else _score_tiles(rows)
     n_qt = 1 if t == 1 else rows // tq
-
-    def tiled(x):       # [B, t, H, w] -> [B, n_qt * H * tq, w]: head-major
-        x = jnp.pad(x, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
-        return x.reshape(B, n_qt, tq, H, -1).swapaxes(2, 3) \
-            .reshape(B, n_qt * H * tq, -1)
-
-    q2 = jnp.tile(tiled(q_idx).astype(pool.dtype), (1, 1, pack))
-    w2 = tiled(w_idx[..., None].astype(jnp.float32))
 
     def qmap(b, qi, j, *_):
         return (b, qi, 0)
@@ -345,24 +596,20 @@ def paged_index_scores(q_idx, w_idx, pool, block_tables, context_lens,
                     0, 0)
         return kvmap
 
-    n_live = jnp.clip(-(-jnp.max(context_lens + lengths) // kv), 1, n_kv)
-    return pl.pallas_call(
-        functools.partial(_index_scores_kernel, bs=bs, d=d, pages=pages,
-                          tq=tq, heads=H),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(B, n_qt, n_live.astype(jnp.int32)),
-            in_specs=[pl.BlockSpec((None, H * tq, pack * d), qmap),
-                      pl.BlockSpec((None, H * tq, 1), qmap)]
-            + [_page_spec(pool, None, page_map(p)) for p in range(pages)],
-            out_specs=pl.BlockSpec((None, rows // n_qt, kv),
-                                   lambda b, qi, j, *_: (b, qi, j))),
-        out_shape=jax.ShapeDtypeStruct((B, rows, n_kv * kv), jnp.float32),
-        compiler_params=_dim_semantics("parallel", "parallel", "arbitrary"),
-        interpret=_interpret(),
-        name="paged_index_scores",
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      lengths.astype(jnp.int32), layer, q2, w2, *([pool] * pages))
+    n_live = jnp.clip(-(-bound // kv), 1, n_kv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B, n_qt, n_live.astype(jnp.int32)),
+        in_specs=[pl.BlockSpec((None, H * tq, pack * d), qmap),
+                  pl.BlockSpec((None, H * tq, 1), qmap)]
+        + [_page_spec(pool, None, page_map(p)) for p in range(pages)],
+        out_specs=pl.BlockSpec((None, rows // n_qt, kv),
+                               lambda b, qi, j, *_: (b, qi, j)))
+    kernel = functools.partial(_index_scores_kernel, bs=bs, d=d, pages=pages,
+                               tq=tq)
+    return (kernel, grid_spec,
+            _packed_queries(q_idx, w_idx, pool, tq, n_qt)
+            + (pool,) * pages, n_kv * kv,
+            _dim_semantics("parallel", "parallel", "arbitrary"))
 
 
 def paged_index_scores_xla(q_idx, w_idx, pool, block_tables, context_lens,

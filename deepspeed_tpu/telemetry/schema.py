@@ -347,7 +347,9 @@ TRACER_SPANS = frozenset((
     # one engine dispatch and its host phases (engine_v2). A chunk's
     # multi-token walk rides ``decode_step`` and ``prefill_chunk`` as
     # ``chunk_attn_tiles_{live, grid, table}``, beside the decode walk's
-    # ``attn_tiles_{live, grid}``, and the KV tokens a grid step of that
+    # ``attn_tiles_{live, grid}`` (and, under a learned selection, the
+    # decode rows' index scores' ``index_tiles_{live, grid}`` and
+    # ``index_live_tile_share``), and the KV tokens a grid step of that
     # walk took as ``chunk_attn_kv_tile`` (256, or the wide tile of a long
     # walk; plain numbers; docs/observability.md). A family with recurrent
     # state AND experts (models/nemotron_h.py, the first with both) carries

@@ -7,15 +7,18 @@ milliseconds a call, median of ``--reps``. How the tile sizes in
 ``ops/pallas/paged_sparse_attention.py`` were chosen (PERF.md section 6,
 PR 38).
 
-Before them, the decode rows' masked walk (``paged_sparse_decode``) as the
-PARENT commit builds it against this tree's, each in a process of its own
-(a chip belongs to one process, so this one stays off JAX until both are
-done): 8 slots of which half are idle, contexts drawn log-uniformly over
-6-31 k tokens as the cell's prompts are, block ids scattered over the pool.
-One JSON line a (side, seed): microseconds a call (``--layers`` calls in one
-program, median of ``--reps``), the share of the dense-read floor (the
-decoding rows' whole live context, K and V, at the chip's published HBM
-rate) and how far the result lies from the gathered XLA op's. The parent is
+Before them, the decode rows' two walks - their masked attention
+(``paged_sparse_decode``, ``--only walk``) and their index scores
+(``paged_index_scores`` at one token a sequence, ``--only index``) - as the
+PARENT commit builds each against this tree's, each side in a process of its
+own (a chip belongs to one process, so this one stays off JAX until all are
+done): 8 slots of which half are idle (the scores: all 8 decoding too),
+contexts drawn log-uniformly over 6-31 k tokens as the cell's prompts are,
+block ids scattered over the pool. One JSON line a (side, seed):
+microseconds a call (``--layers`` calls in one program, median of
+``--reps``), the share of the dense-read floor (the decoding rows' whole live
+context - K and V, or the index keys - at the chip's published HBM rate) and
+how far the result lies from the gathered XLA op's. The parent is
 ``--parent DIR`` (an unpacked ``git archive``: what a chip machine, which has
 no ``.git``, needs) or else ``git archive --parent-rev`` unpacked under
 ``/tmp``. A number from here is a kernel's, never a cell's."""
@@ -35,19 +38,115 @@ L, NB, BS, D, H = 2, 7808, 32, 64, 16
 NKV, NH, HD, MB, TOPK = 4, 32, 128, 1024, 2048
 
 
-def decode_walk(args) -> int:
-    """One side's timing of the decode rows' walk: ``deepspeed_tpu`` is
-    whatever ``args.walk_of`` holds."""
+def side_of(args):
+    """The selection's kernels of the side this process times:
+    ``deepspeed_tpu`` is whatever ``args.walk_of`` holds."""
     sys.path.insert(0, os.path.abspath(args.walk_of))
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
     from deepspeed_tpu.ops.pallas import paged_sparse_attention as S
 
     assert os.path.abspath(S.__file__).startswith(
         os.path.abspath(args.walk_of)), S.__file__
-    nb, mb, slots = (80, 16, 4) if args.tiny else (NB, MB, 8)
+    return S
+
+
+def table_of(args):
+    """(pool blocks, table width, slots) of a walk's case."""
+    return (80, 16, 4) if args.tiny else (NB, MB, 8)
+
+
+def drawn_slots(args, seed: int, idle_share: int = 2):
+    """(contexts, idle, block tables) of a case's slots: contexts
+    log-uniform over the cell's prompts' range, one slot in ``idle_share``
+    idle (0: none) at context 0 on the trash block, block ids scattered."""
+    import numpy as np
+
+    nb, mb, slots = table_of(args)
+    rng = np.random.default_rng(seed)
+    lo, hi = (6144, 30720) if not args.tiny else (100, mb * BS - 2)
+    ctx = np.exp(rng.uniform(np.log(lo), np.log(hi), slots)).astype(np.int64)
+    idle = rng.permutation(slots) < (slots // idle_share if idle_share
+                                     else 0)
+    ctx[idle] = 0
+    tables = rng.integers(1, nb, (slots, mb))
+    tables[idle] = 0
+    return ctx, idle, tables
+
+
+def median_us(fn, ops, args) -> float:
+    """Microseconds a call of ``fn``, a program of ``args.layers`` calls."""
+    import jax
+
+    jax.block_until_ready(fn(*ops))
+    ts = []
+    for _ in range(args.reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*ops))
+        ts.append(time.perf_counter() - t0)
+    return statistics.median(ts) / args.layers * 1e6
+
+
+def index_scores(args) -> int:
+    """One side's timing of the decode rows' index scores."""
+    S = side_of(args)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    nb, mb, slots = table_of(args)
+    bf, i32 = jnp.bfloat16, jnp.int32
+    key = jax.random.PRNGKey(0)
+    pool = jax.random.normal(key, S.index_pool_shape(L, nb, BS, D), bf)
+    q_idx = jax.random.normal(jax.random.fold_in(key, 1), (slots, 1, H, D),
+                              bf)
+    w_idx = jax.random.normal(jax.random.fold_in(key, 2), (slots, 1, H), bf)
+    kind = jax.devices()[0].device_kind
+    if args.index_pages:
+        S._INDEX_PAGES = args.index_pages
+
+    def scores(q_, w_, p_, tb_, ctx_, lens_, layer):
+        return S.paged_index_scores(q_, w_, p_, tb_, ctx_, lens_,
+                                    layer=layer, rows=8)
+
+    def program(*ops):
+        def layer(i, acc):      # a lane tile of every row keeps the call
+            return acc + scores(*ops, i % L)[:, 0, :128]
+        return jax.lax.fori_loop(0, args.layers, layer,
+                                 jnp.zeros((slots, 128), jnp.float32))
+
+    fn, one = jax.jit(program), jax.jit(scores)
+    for seed in args.seeds:
+        for idle_share in (2, 0):
+            ctx, idle, tables = drawn_slots(args, seed, idle_share)
+            ops = (q_idx, w_idx, pool, jnp.asarray(tables, i32),
+                   jnp.asarray(ctx, i32), jnp.asarray(~idle, i32))
+            got = np.asarray(one(*ops, 1))[:, 0]
+            want = np.asarray(S.paged_index_scores_xla(*ops, layer=1))[:, 0]
+            read = (np.arange(want.shape[1])[None] <= ctx[:, None]) \
+                & ~idle[:, None]
+            us = median_us(fn, ops, args)
+            floor = float((ctx[~idle] + 1).sum()) * D * 2 \
+                / HBM_BYTES_PER_S[kind] * 1e6 \
+                if kind in HBM_BYTES_PER_S else None
+            print(json.dumps({
+                "case": "index_scores decode rows", "side": args.side,
+                "seed": seed, "decoding": int((~idle).sum()),
+                "index_pages": S._INDEX_PAGES, "contexts": ctx.tolist(),
+                "us": us, "floor_us": floor,
+                "floor_share": floor and floor / us,
+                "max_abs_diff_from_xla": float(np.abs(np.where(
+                    read, got[:, :want.shape[1]] - want, 0)).max()),
+                "device": kind}), flush=True)
+    return 0
+
+
+def decode_walk(args) -> int:
+    """One side's timing of the decode rows' masked walk."""
+    S = side_of(args)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    nb, mb, slots = table_of(args)
     bf, i32 = jnp.bfloat16, jnp.int32
     key = jax.random.PRNGKey(0)
     kpool, vpool = (jax.random.normal(jax.random.fold_in(key, i),
@@ -70,14 +169,7 @@ def decode_walk(args) -> int:
     select = jax.jit(lambda s_, q_: S.paged_sparse_select(
         s_, q_, topk=min(TOPK, mb * BS // 4)))
     for seed in args.seeds:
-        rng = np.random.default_rng(seed)
-        lo, hi = (6144, 30720) if not args.tiny else (100, mb * BS - 2)
-        ctx = np.exp(rng.uniform(np.log(lo), np.log(hi), slots)) \
-            .astype(np.int64)
-        idle = rng.permutation(slots) < slots // 2
-        ctx[idle] = 0
-        tables = rng.integers(1, nb, (slots, mb))
-        tables[idle] = 0
+        ctx, idle, tables = drawn_slots(args, seed)
         idx = jax.random.normal(jax.random.fold_in(key, seed),
                                 (slots, 8, mb * BS), jnp.float32)
         tau, cut = select(idx[:, 0], jnp.asarray(ctx, i32))
@@ -86,13 +178,7 @@ def decode_walk(args) -> int:
         got = np.asarray(one(*ops, 1), np.float32)
         want = np.asarray(S.paged_sparse_decode_attention_xla(*ops, layer=1),
                           np.float32)
-        jax.block_until_ready(fn(*ops))
-        ts = []
-        for _ in range(args.reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*ops))
-            ts.append(time.perf_counter() - t0)
-        us = statistics.median(ts) / args.layers * 1e6
+        us = median_us(fn, ops, args)
         floor = float((ctx[~idle] + 1).sum()) * NKV * HD * 2 * 2 \
             / HBM_BYTES_PER_S[kind] * 1e6 if kind in HBM_BYTES_PER_S else None
         print(json.dumps({
@@ -104,8 +190,9 @@ def decode_walk(args) -> int:
     return 0
 
 
-def both_walks(args) -> int:
-    """The parent's walk, then this tree's, each in its own process."""
+def both_sides(args, case: str) -> int:
+    """The parent's kernel of ``case``, then this tree's, each in its own
+    process."""
     with tempfile.TemporaryDirectory(dir="/tmp") as tmp:
         if args.parent is None:
             tar = subprocess.run(["git", "archive", args.parent_rev],
@@ -120,9 +207,12 @@ def both_walks(args) -> int:
         for side, root in (("parent", args.parent or tmp), ("change", ROOT)):
             rc = subprocess.run(
                 [sys.executable, os.path.abspath(__file__), "--walk-of", root,
-                 "--side", side, "--layers", str(args.layers), "--reps",
+                 "--side", side, "--case", case, "--layers", str(args.layers), "--reps",
                  str(args.reps), "--seeds", *map(str, args.seeds)]
-                + ["--tiny"] * args.tiny).returncode
+                + ["--tiny"] * args.tiny
+                + (["--index-pages", str(args.index_pages)]
+                   if args.index_pages and side == "change" else [])
+            ).returncode
             if rc:
                 return rc
     return 0
@@ -132,9 +222,10 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--ctx", type=int, default=15000)
     ap.add_argument("--reps", type=int, default=5)
-    ap.add_argument("--only", choices=["walk", "tiles"],
-                    help="the decode rows' walk, parent against tree, or "
-                    "the other kernels' tile sizes; both where not given")
+    ap.add_argument("--only", choices=["walk", "index", "tiles"],
+                    help="the decode rows' masked walk or their index "
+                    "scores, parent against tree, or the other kernels' "
+                    "tile sizes; all three where not given")
     ap.add_argument("--parent", help="the parent commit, unpacked")
     ap.add_argument("--parent-rev", default="HEAD")
     ap.add_argument("--layers", type=int, default=24)
@@ -144,13 +235,20 @@ def main() -> int:
                     "CPU's interpreter; heads stay 128 lanes)")
     ap.add_argument("--walk-of", help=argparse.SUPPRESS)
     ap.add_argument("--side", help=argparse.SUPPRESS)
+    ap.add_argument("--case", help=argparse.SUPPRESS)
+    ap.add_argument("--index-pages", type=int,
+                    help="--only index: the tree's scores at a forced tile "
+                    "of this many pages (a power of two)")
     args = ap.parse_args()
     if args.walk_of:
-        return decode_walk(args)
-    if args.only != "tiles":
-        rc = both_walks(args)
-        if rc or args.only == "walk":
-            return rc
+        return {"walk": decode_walk, "index": index_scores}[args.case](args)
+    for case in ("walk", "index"):
+        if args.only in (None, case):
+            rc = both_sides(args, case)
+            if rc:
+                return rc
+    if args.only not in (None, "tiles"):
+        return 0
     sys.path.insert(0, ROOT)
     import jax
     import jax.numpy as jnp
@@ -195,8 +293,11 @@ def main() -> int:
         q = jax.random.normal(key, (B, t, NH, HD), bf)
         rows = 8 if t == 1 else S.prefill_rows(t, NH, NKV, HD, BS, MB)
         idx = None
-        for pages in (8, 32):
-            S._INDEX_PAGES = pages
+        # the decode rows' scores walk their own pages: the walk's tile; a
+        # chunk's scores take ``_MAX_PAGES``
+        for pages in (32, 64, 128) if t == 1 else (None,):
+            if pages:
+                S._INDEX_PAGES = pages
             fn = jax.jit(lambda q_, w_, p_, tb_, c_, l_: S.paged_index_scores(
                 q_, w_, p_, tb_, c_, l_, layer=layer, rows=rows))
             idx = timed(f"index_scores {B}x{t}", fn, q_idx, w_idx, ipool, tb,

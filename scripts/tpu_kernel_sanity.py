@@ -231,6 +231,47 @@ def main():
 
     check("paged_sparse_decode_own_pages", paged_sparse_decode_own_pages)
 
+    # the decode rows' index scores walk their own pages too (ISSUE 54): one
+    # DMA a packed index page. Garbage table entries past a sequence's end,
+    # idle slots (no row: their whole table row is garbage) first, between
+    # and last, NaN in every block no live sequence holds; compared on the
+    # entries the selection reads, with the XLA op and - bit for bit - with
+    # the grid of ``BlockSpec`` pages at the same tile
+    def paged_index_scores_own_pages():
+        from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+        L, bs, mb, nb, H, d = 2, 32, 160, 900, 16, 64
+        tile = sparse._index_pages(1, mb) * bs
+        ctx = np.asarray([0, 5, tile - 2, 0, tile - 1, tile, mb * bs - 1,
+                          2 * tile + 700, 33, 0], np.int32)
+        lens = np.asarray([0, 1, 1, 0, 1, 1, 1, 1, 1, 0], np.int32)
+        assert sparse._fetches_index_pages(
+            sparse.index_pool_shape(L, nb, bs, d))
+        bad, bt = garbage_past_the_end(ctx, bs, mb, nb)
+        bad = jnp.where(lens[:, None] > 0, bad, bad[:, -1:])
+        held = np.unique(np.asarray(bt)[lens > 0])
+        pool = randn(*sparse.index_pool_shape(L, nb, bs, d)) \
+            .astype(jnp.bfloat16)
+        poisoned = jnp.full_like(pool, jnp.nan).at[:, held].set(pool[:, held])
+        q_idx = randn(len(ctx), 1, H, d).astype(jnp.bfloat16)
+        w_idx = randn(len(ctx), 1, H).astype(jnp.bfloat16)
+        args = (q_idx, w_idx, poisoned, bad, jnp.asarray(ctx),
+                jnp.asarray(lens))
+        # the result is padded to whole tiles: the table's width of it
+        got = sparse.paged_index_scores(*args, layer=1,
+                                        rows=8)[:, 0, :mb * bs]
+        grid = sparse._index_scores(sparse._index_grid, *args, layer=1,
+                                     rows=8)[:, 0, :mb * bs]
+        want = sparse.paged_index_scores_xla(
+            q_idx, w_idx, pool, bt, None, None, layer=1)[:, 0]
+        read = (np.arange(mb * bs)[None] <= ctx[:, None]) \
+            & (lens[:, None] > 0)
+        diff_ok(jnp.where(read, got, 0), jnp.where(read, want, 0), 0.05)
+        assert bool(jnp.all(jnp.where(read, got == grid, True))), \
+            "the walk's scores are not the grid's, bit for bit"
+
+    check("paged_index_scores_own_pages", paged_index_scores_own_pages)
+
     # compact MoE dispatch parity ON CHIP at true-f32 matmul precision —
     # round-4's 1.1e-2 divergence (bench_runs/MOE_20260731T034754Z.json)
     # was captured before the 06:54Z compact-gating rewrite; this pins the

@@ -299,6 +299,45 @@ def test_decode_kernel_lowers_at_the_cells_geometry(v5e, cell, pool):
     assert len(calls) == 1 and re.fullmatch(name + r"(\.\d+)?", calls[0])
 
 
+def test_the_decode_rows_scores_fetch_their_own_index_pages(v5e):
+    """``paged_index_scores`` at one token a sequence and the Keye cell's
+    geometry (8 slots, a 1 024-block table, 16 index heads of 64 over the
+    packed ``[12, 7808, 1, 16, 128]`` pool) compiles for the chip as ONE
+    Mosaic call, still the instruction ``paged_index_scores.N`` that
+    ``sparse_index_roofline`` looks for, and the index pool reaches it ONCE,
+    where it lies - not once a page of a grid step's tile."""
+    import re
+
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+    slots, _, _, _, _, bs, table, blocks, _, _ = CELL_DECODES[
+        "keye-vl-2.0-30b-a3b.serve-longctx"]
+    heads, d = 16, 64
+    pool = sparse.index_pool_shape(12, blocks, bs, d)
+    assert pool == (12, 7808, 1, 16, 128)
+    assert sparse._fetches_index_pages(pool)
+
+    def fn(q_idx, w_idx, pool, tables, ctx, lens, layer):
+        return sparse.paged_index_scores(q_idx, w_idx, pool, tables, ctx,
+                                         lens, layer=layer, rows=8)
+
+    compiled = _compile(
+        fn, ((slots, 1, heads, d), jnp.bfloat16),
+        ((slots, 1, heads), jnp.bfloat16), (pool, jnp.bfloat16),
+        ((slots, table), jnp.int32), ((slots,), jnp.int32),
+        ((slots,), jnp.int32), ((), jnp.int32), device=v5e.devices[0])
+    text = compiled.as_text()
+    calls = re.findall(r"%(\S+) = (\S+) custom-call\((.*?)\), .*" + MOSAIC,
+                       text)
+    assert len(calls) == 1, calls
+    name, result, operands = calls[0]
+    assert re.fullmatch(r"paged_index_scores(\.\d+)?", name)
+    assert result.startswith(f"f32[{slots},8,{table * bs}]")
+    # the tables, contexts, rows and layer, the queries and their weights,
+    # and the pool: 7 operands (the grid of pages had the pool 32 times)
+    assert operands.count("%") == 7, operands
+
+
 @pytest.mark.parametrize("cell", sorted(CELL_DECODES))
 def test_decode_grid_is_sized_by_the_shapes(cell):
     """The walk's tile comes from the cell's shapes and the VMEM budget

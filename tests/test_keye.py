@@ -354,6 +354,104 @@ def test_index_scores_interpreted_are_the_gathered_einsum(pools, t):
                                  0)).max()) < 1e-4
 
 
+# The decode rows SCORE their own pages (ISSUE 54): one token a sequence, the
+# index pool's pages fetched by the kernel itself. Blocks of 16 tokens, two
+# 64-wide keys a pool row, a table 10 wide and a tile held to two pages: five
+# tiles of 32 tokens. A context of n is n cached tokens plus the row's own:
+# 31 is exactly a tile, 32 the first token of the second, 159 the table. A
+# slot with no row (``rows`` 0) is idle: its whole table row is garbage.
+SCORES_OWN_PAGES = {
+    "scattered_blocks": dict(ctx=[40, 5, 77, 100]),
+    "idle_first_between_and_last": dict(ctx=[9, 33, 0, 80, 50],
+                                        rows=[0, 1, 0, 1, 0]),
+    "every_slot_idle": dict(ctx=[0, 12, 40, 0], rows=[0, 0, 0, 0]),
+    "contexts_round_a_tile": dict(ctx=[30, 31, 32, 63, 64]),
+    "contexts_round_a_page": dict(ctx=[14, 15, 16, 0, 7]),
+    "a_sequence_fills_the_table": dict(ctx=[159, 3, 159, 158]),
+    "narrow_heads_keep_the_grid": dict(ctx=[40, 31, 32, 159],
+                                       rows=[1, 0, 1, 1], d=48),
+    "through_the_selection_and_the_masked_walk": dict(ctx=[159, 70, 3, 100],
+                                                      rows=[1, 1, 0, 1],
+                                                      chain=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCORES_OWN_PAGES))
+def test_decode_rows_score_their_own_pages(case, monkeypatch):
+    """``paged_index_scores`` at one token a sequence (interpreted: the
+    interpreter runs its DMAs, its semaphores and its SMEM carry) on every
+    entry the selection reads: against the gathered XLA op, and BIT FOR BIT
+    against the grid of ``BlockSpec`` pages at the same tile. The kernels'
+    copy of the operands is POISONED wherever they must not look: the table
+    past a sequence's last block - an idle slot's whole row - holds a block
+    of NaN keys, an index past the pool and a negative one, in turn, and
+    every block no live sequence holds is NaN. An index head width that
+    does not divide 128 keeps the grid; ``chain``: the scores go on through
+    ``paged_sparse_select`` and ``paged_sparse_decode`` - whose tile is
+    another width - and the attention is the XLA chain's."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    c = dict(dict(rows=None, d=64, chain=False), **SCORES_OWN_PAGES[case])
+    monkeypatch.setattr(sparse, "_INDEX_PAGES", 2)
+    rng = np.random.default_rng(4)
+    L, nb, bs, mb, H, d = 2, 64, 16, 10, 4, c["d"]
+    ctx = np.asarray(c["ctx"], np.int32)
+    rows = np.asarray(c["rows"] or [1] * len(ctx), np.int32)
+    B, poison = len(ctx), nb - 1
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    pool = f(*sparse.index_pool_shape(L, nb, bs, d))
+    own = sparse._fetches_index_pages(pool.shape)
+    assert own == (d == 64) and sparse._index_pages(1, mb) == 2
+    tables = np.zeros((B, mb), np.int32)
+    garbage = np.resize(np.asarray([poison, 10 ** 6, -3], np.int32), (B, mb))
+    for b in np.flatnonzero(rows):
+        need = ctx[b] // bs + 1
+        tables[b, :need] = garbage[b, :need] = rng.choice(
+            np.arange(1, poison), need, replace=False)
+    held = np.unique(tables)
+    poisoned = jnp.full_like(pool, jnp.nan).at[:, held].set(pool[:, held])
+    q_idx, w_idx = f(B, 1, H, d), f(B, 1, H)
+    args = (q_idx, w_idx, poisoned, jnp.asarray(garbage), jnp.asarray(ctx),
+            jnp.asarray(rows))
+    got = sparse.paged_index_scores(*args, layer=1, rows=8)
+    grid = sparse._index_scores(sparse._index_grid, *args, layer=1, rows=8)
+    want = sparse.paged_index_scores_xla(q_idx, w_idx, pool,
+                                         jnp.asarray(tables), None, None,
+                                         layer=1)
+    assert got.shape == grid.shape == (B, 8, mb * bs)
+    read = (np.arange(mb * bs)[None] <= ctx[:, None]) & (rows[:, None] > 0)
+    assert np.isfinite(np.asarray(got[:, 0])[read]).all()
+    np.testing.assert_array_equal(np.asarray(got[:, 0])[read],
+                                  np.asarray(grid[:, 0])[read])
+    assert gap(jnp.where(read, got[:, 0], 0), jnp.where(read, want[:, 0], 0)) \
+        < 1e-5
+    # the span's counter says what the call takes: each decoding slot's own
+    # tiles, or every slot as far as the longest where it is the grid
+    tiles = int((ctx[rows > 0] // 32 + 1).sum())
+    assert sparse.index_tile_counts(ctx, rows, pool.shape, bs, mb) == (
+        tiles, tiles if own else B * min(-(-int((ctx + rows).max()) // 32), 5))
+    if not c["chain"]:
+        return
+    monkeypatch.setattr(pa, "_DECODE_KV_TOKENS", 64)
+    nkv, g, hd = 2, 2, 128
+    pages, _, n_kv = pa._decode_tiles(nkv, g, hd, bs, mb, 4, False)
+    assert pages * n_kv * bs > got.shape[2]     # its last tile overhangs
+    k, v, q = f(L, nb, nkv, bs, hd), f(L, nb, nkv, bs, hd), f(B, nkv * g, hd)
+    live = rows > 0
+    tau, cut = sparse.paged_sparse_select(
+        got[:, 0], jnp.asarray(np.where(live, ctx, -1)), topk=TOPK)
+    out = sparse.paged_sparse_decode_attention(
+        q, *(p.at[:, poison].set(jnp.nan) for p in (k, v)), got, tau, cut,
+        jnp.asarray(np.where(live[:, None], garbage, 0)), jnp.asarray(ctx),
+        layer=1)
+    tau_x, cut_x = sparse.paged_sparse_select_xla(want[:, 0],
+                                                  jnp.asarray(ctx), topk=TOPK)
+    out_x = sparse.paged_sparse_decode_attention_xla(
+        q, k, v, want, tau_x, cut_x, jnp.asarray(tables), jnp.asarray(ctx),
+        layer=1)
+    assert gap(out[live], out_x[live]) < 1e-5
+
+
 # The decode rows walk their own pages (ISSUE 51): heads of 128 lanes, blocks
 # of 8 tokens, a table 20 wide and a KV tile held to 64 tokens - two whole
 # tiles and half a third. A context of n is n cached tokens plus the current
@@ -610,6 +708,10 @@ def test_step_serves_the_references_tokens(served):
     assert one["chunk_sparse_ctx_scored"] == 16 * 17 // 2
     assert one["chunk_sparse_kv_selected"] == 36 + 8 * TOPK
     assert one["sparse_ctx_scored"] == len(a) + 1   # the one decode row
+    # its scores' one tile; these pools' pages are one row of eight keys, no
+    # whole tile, so the call is the grid: every slot as far as the longest
+    assert (one["index_tiles_live"], one["index_tiles_grid"],
+            one["index_live_tile_share"]) == (1, 4, 0.25)
     events = dict((n, v) for n, v, _ in eng.sparse_events())
     assert events["Serving/sparse/rows"] > 37
     assert events["Serving/sparse/kv_selected"] < \
