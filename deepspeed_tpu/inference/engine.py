@@ -53,10 +53,17 @@ class ModelFamily:
     moe_rows: Optional[Callable] = None
     # (cfg) -> bytes of recurrent state ONE sequence slot holds;
     # a family that has it declares recurrent state: its paged cache carries
-    # per-slot leaves (named in ``state_leaves``) beside the block pools and
-    # its ``apply_paged`` takes each row's ``slots`` (models/granite_hybrid.py)
+    # per-slot leaves (named in ``state_leaves``) - beside its block pools
+    # (models/granite_hybrid.py) or, where its cache has no leaf with a
+    # block axis, alone (models/brumby.py) - and its ``apply_paged`` takes
+    # each row's ``slots``
     state_slot_bytes: Optional[Callable] = None
     state_leaves: Tuple[str, ...] = ()
+    # (cfg, rows, chunk_rows) -> span arguments a step's launch says of ONE
+    # recurrent layer of its call beside ``ssm_rows`` / ``ssm_tokens``: the
+    # live single-token rows and the rows of the chunk riding with them,
+    # under the family's own names (models/brumby.py ``retention_rows``)
+    state_rows: Optional[Callable] = None
     # (cfg, contexts) -> what ONE layer's learned token selection does for
     # rows at those contexts (``sparse_rows``, ``sparse_ctx_scored``,
     # ``sparse_kv_selected``), {} for a family without one; a family that
@@ -94,6 +101,7 @@ class ModelFamily:
                    moe_rows=getattr(module, "moe_rows", None),
                    state_slot_bytes=getattr(module, "state_slot_bytes", None),
                    state_leaves=tuple(getattr(module, "STATE_LEAVES", ())),
+                   state_rows=getattr(module, "state_rows", None),
                    sparse_rows=getattr(module, "sparse_rows", None),
                    window_kinds=getattr(module, "window_kinds", None),
                    latent_kind=getattr(module, "latent_kind", None),

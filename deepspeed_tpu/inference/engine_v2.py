@@ -83,7 +83,8 @@ _NO_WORK = {"prefill_tokens": 0, "prefill_kv_tokens": 0, "decode_seqs": 0,
 _GREEDY = SamplingParams(greedy=True)
 
 # What a kind of cache state refuses: a row a kind a family's cache may hold
-# beside its K and V blocks (the error it raises, then {what is refused: why},
+# beside its K and V blocks or in their place (the error it raises, then
+# {what is refused: why},
 # in the order tried), a column a configuration feature, refused at
 # construction (``_refuse_features``), or a call, refused when it is made
 # (``_refuse_call``: ``fork``, and ``handoff``, the disagg block export /
@@ -92,18 +93,18 @@ _SPILLS = "it spills and restores prefix-cache blocks"
 _REFUSALS = {
     "recurrent_state": (RecurrentStateError, {
         "inference.prefix_cache":
-            "a cached prefix's blocks hold its keys and values but not "
-            "the recurrent state at its end, and no snapshot of that "
-            "state is kept",
+            "a cached prefix's blocks hold its keys and values (none at "
+            "all in a family with no KV cache) but not the recurrent state "
+            "at its end, and no snapshot of that state is kept",
         "inference.prefix_cache.host_spill": _SPILLS,
         "inference.speculative":
             "a rejected draft is rolled back by truncating blocks, and "
             "a recurrent state cannot be rolled back",
         "inference.kv_quant": "the family's cache has no quantized mode",
         "fork":
-            "the child shares the parent's KV blocks, and the parent's "
-            "recurrent state would have to be copied into a slot of its "
-            "own, which is not written",
+            "the child shares whatever KV blocks the parent holds, and the "
+            "parent's recurrent state would have to be copied into a slot "
+            "of its own, which is not written",
         "handoff":
             "a sequence's blocks are not its whole state, and the "
             "destination resumes through the prefix cache"}),
@@ -275,11 +276,18 @@ class InferenceEngineV2(InferenceEngine):
             2, (self.family.cfg.max_seq_len + rc.block_size - 1) // rc.block_size)
         # --- recurrent state (docs/serving.md "Recurrent state"): a family
         # that declares it (``state_slot_bytes``) keeps one fixed-size row a
-        # sequence slot a state-space layer beside its KV blocks. What treats
-        # a sequence's state as its blocks is refused here or at its call.
+        # sequence slot a recurrent layer - beside its KV blocks, or, where
+        # its cache has no leaf with a block axis, as ALL a sequence holds
+        # ("A state and no cache": no block is allocated, counted or walked,
+        # and a free slot is the whole admission check). What treats a
+        # sequence's state as its blocks is refused here or at its call.
         self._recurrent = self.family.state_slot_bytes is not None
         slot_kw = {"slots": rc.max_tracked_sequences} \
             if self._recurrent else {}
+        self._blockless = self._recurrent and not (
+            set(jax.eval_shape(lambda: self._init_paged(
+                self.family.cfg, 2, rc.block_size, **slot_kw)))
+            - set(self.family.state_leaves))
         # --- a learned token selection (docs/serving.md "Learned token
         # selection"): the family's cache has a third block pool, the index
         # keys'. The block lifecycle carries it as it carries any leaf with
@@ -322,8 +330,12 @@ class InferenceEngineV2(InferenceEngine):
                 for name, window in self._window.items())
             slot_kw["window_blocks"] = {k.name: k.num_blocks for k in kinds}
         self.state = StateManager(
-            rc.max_tracked_sequences, rc.memory_config_blocks, rc.block_size,
-            max_blocks_per_seq, prefix_cache=pc.enabled,
+            rc.max_tracked_sequences,
+            # (the allocator wants its trash block and one more; neither is
+            # a device byte where no leaf has a block axis)
+            2 if self._blockless else rc.memory_config_blocks, rc.block_size,
+            1 if self._blockless else max_blocks_per_seq,
+            blockless=self._blockless, prefix_cache=pc.enabled,
             max_retained_blocks=pc.max_retained_blocks,
             state_slot_bytes=self.family.state_slot_bytes(
                 self.family.cfg) if self._recurrent else 0,
@@ -371,6 +383,10 @@ class InferenceEngineV2(InferenceEngine):
         # compile once for the fresh pool and again for every later call
         # (the serving twin of the scalar placement in runtime/engine.py)
         self.cache = jax.device_put(self.cache, self.mesh_mgr.replicated())
+        self._kv_bytes = sum(
+            leaf.nbytes for name, tree in self.cache.items()
+            if name not in self.family.state_leaves
+            for leaf in jax.tree.leaves(tree))
         self._paged_fns: Dict[Tuple, Callable] = {}
         # --- host-spill tier for evicted prefix-cache blocks
         # (inference.prefix_cache.host_spill; docs/memory.md). Default OFF →
@@ -501,8 +517,9 @@ class InferenceEngineV2(InferenceEngine):
         self._lat: Dict[str, List[float]] = {
             "ttft_ms": [], "itl_ms": [], "queue_ms": [], "e2e_ms": []}
         spec_lbl = "on(k=%d)" % self._spec_k if self._spec_on else "off"
-        log_dist(f"InferenceEngineV2: {rc.memory_config_blocks} blocks × "
-                 f"{rc.block_size} tokens, {B} sequence slots, "
+        blocks = "no KV blocks" if self._blockless else \
+            f"{rc.memory_config_blocks} blocks × {rc.block_size} tokens"
+        log_dist(f"InferenceEngineV2: {blocks}, {B} sequence slots, "
                  f"kv_quant={'int8(g=%d)' % self._kvq_group if self._kvq_on else 'off'}, "
                  f"prefix_cache={'on' if pc.enabled else 'off'}, "
                  f"recurrent_state={'%d B/slot' % self.state.state_slot_bytes if self._recurrent else 'none'}, "
@@ -934,6 +951,14 @@ class InferenceEngineV2(InferenceEngine):
             into[key] = into.get(key, 0) + n
         return {"ssm_rows": rows, "ssm_tokens": tokens}
 
+    def _state_args(self, rows: int, chunk_rows: int) -> Dict[str, int]:
+        """Span arguments of a step's launch in a family that names its
+        recurrent layer's rows itself (``ModelFamily.state_rows``): the live
+        single-token rows and the rows of the chunk riding with them, of ONE
+        layer of the call. None for any other family."""
+        fn = self.family.state_rows
+        return fn(self.family.cfg, rows, chunk_rows) if fn else {}
+
     def _walked_pool(self):
         """The pool whose shape says the paged walks' tile sizes: the K pool,
         or a latent cache's one pool; None for a family that has neither."""
@@ -1098,6 +1123,7 @@ class InferenceEngineV2(InferenceEngine):
                 **self._chunk_args(ch), **self._moe_args(rows),
                 **self._row_args(rows, slots + 1),
                 **self._ssm_args(1, len(ch.tokens)),
+                **self._state_args(0, len(ch.tokens)),
                 **self._sparse_args(self._chunk_contexts(ch)),
                 **self._kv_kind_args([ch.ctx], [len(ch.tokens)]),
                 **self._chunk_tile_args(ch)):
@@ -1669,6 +1695,8 @@ class InferenceEngineV2(InferenceEngine):
                 **self._row_args(n_rows, len(self._slot_tokens)
                                  + (ch is not None)),
                 **self._ssm_args(len(live), len(live)),
+                **self._state_args(len(live),
+                                   len(ch.tokens) if ch is not None else 0),
                 **self._sparse_args([d.seen_tokens + 1 for d in live]),
                 **self._kv_kind_args([d.seen_tokens for d in live],
                                      [1] * len(live)),
@@ -1944,6 +1972,11 @@ class InferenceEngineV2(InferenceEngine):
                "headroom_blocks": st.headroom_blocks,
                "free_slots": st.free_slots,
                "total_blocks": st.allocator.num_blocks - 1}
+        # the bytes of every leaf with a block axis: 0 where the family's
+        # state is all it keeps, and then no block is there to count
+        out["kv_bytes"] = self._kv_bytes
+        if self._blockless:
+            out.update(free_blocks=0, headroom_blocks=0, total_blocks=0)
         if self._indexed:
             # a block is a page of every pool, the index keys' among them
             out["block_bytes"] = sum(
@@ -2369,6 +2402,9 @@ class InferenceEngineV2(InferenceEngine):
         finite and non-negative. Raises AssertionError on violation."""
         keys = set(self.cache.keys()) - set(self.family.state_leaves) \
             - {f"{kv}_{kind}" for kind in self._window for kv in "kv"}
+        if self._blockless:
+            assert not keys, f"a cache with no block pool has leaves {keys}"
+            return
         if not self._kvq_on:
             assert keys == {"k", "v"}, \
                 f"unquantized cache has unexpected leaves {keys}"
@@ -2495,10 +2531,9 @@ class InferenceEngineV2(InferenceEngine):
         results: Dict[int, List[int]] = {}
         # reject prompts that can NEVER be admitted (need more blocks than the
         # pool holds even when empty) instead of spinning forever
-        bs = self.state.block_size
         capacity = self.state.allocator.num_blocks - 1
         for _, p in pending:
-            need = (len(p) + bs - 1) // bs + 1
+            need = self.state.blocks_needed(len(p))
             if need > capacity:
                 raise MemoryError(
                     f"prompt of {len(p)} tokens needs {need} KV blocks but the "

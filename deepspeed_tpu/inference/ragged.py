@@ -69,7 +69,8 @@ class UnknownSequenceError(KeyError):
 class RecurrentStateError(NotImplementedError):
     """A serving feature that treats a sequence's state as its KV blocks was
     asked of a family with RECURRENT state (a fixed-size row a sequence slot
-    a state-space layer, rewritten every token): sharing, copying, rolling
+    a recurrent layer, rewritten every token - beside the family's KV
+    blocks, or all it keeps): sharing, copying, rolling
     back or shipping blocks says nothing about that row, and without state
     snapshots the feature would serve wrong tokens. Raised at configuration
     or call time instead (docs/serving.md "Recurrent state")."""
@@ -363,8 +364,14 @@ class StateManager:
     def __init__(self, max_sequences: int, num_blocks: int, block_size: int,
                  max_blocks_per_seq: int, prefix_cache: bool = False,
                  max_retained_blocks: int = -1, state_slot_bytes: int = 0,
-                 window_kinds: Sequence[WindowKind] = ()):
+                 window_kinds: Sequence[WindowKind] = (),
+                 blockless: bool = False):
         self.block_size = block_size
+        # a family whose cache has NO leaf with a block axis (its recurrent
+        # state is all a sequence holds: models/brumby.py): no sequence
+        # claims a block, a table is one trash entry wide, and a free slot
+        # is the whole of admission (``_cover``)
+        self.blockless = blockless
         # kinds of KV state beside the full one (``num_blocks`` is the full
         # kind's): an allocator each, and what each has given back so far
         self.window_kinds: Tuple[WindowKind, ...] = tuple(window_kinds)
@@ -428,6 +435,11 @@ class StateManager:
         except KeyError:
             raise UnknownSequenceError(uid) from None
 
+    def _cover(self, tokens: int) -> int:
+        """Blocks that cover ``tokens`` tokens of one sequence: none where
+        the family keeps no block pool."""
+        return 0 if self.blockless else -(-tokens // self.block_size)
+
     def blocks_needed(self, prompt_len: int) -> int:
         """Blocks ``admit``/``admit_prompt`` would claim for a prompt of
         this length (prompt coverage + one pre-reserved decode block) —
@@ -449,7 +461,7 @@ class StateManager:
         need = 0
         for d in descs:
             want = d.seen_tokens + n
-            need += max(0, (want + bs - 1) // bs - len(d.blocks))
+            need += max(0, self._cover(want) - len(d.blocks))
             first = d.seen_tokens // bs
             last = min((want - 1) // bs, len(d.blocks) - 1)
             for i in range(first, last + 1):
@@ -470,7 +482,9 @@ class StateManager:
         """Blocks for the prompt + one pre-reserved decode block, capped at
         the fixed table width (a prompt near max_seq_len already owns the
         last block — reserving past the table would overflow it)."""
-        need = (prompt_len + self.block_size - 1) // self.block_size + 1
+        if self.blockless:
+            return 0
+        need = self._cover(prompt_len) + 1
         return min(need, self.max_blocks_per_seq)
 
     def can_admit(self, prompt_len: int) -> bool:
@@ -481,7 +495,9 @@ class StateManager:
         this is exactly the free-list check). A family's recurrent state
         is one row a slot (``state_slot_bytes``, 80.2 MB a sequence for
         Granite-4.0-H-Micro where its KV is 8 KB a token): the free slot
-        checked here is that row, so no sequence is admitted without one."""
+        checked here is that row, so no sequence is admitted without one -
+        and where the family keeps no block pool at all (``blockless``:
+        ``_admit_need`` is 0) the slot is the whole check."""
         avail = self.allocator.free_blocks + self.index.retained_blocks
         return bool(self._free_slots) \
             and avail >= self._admit_need(prompt_len) \
@@ -788,7 +804,8 @@ class StateManager:
         multi-step decode path: capacity is reserved up front so a fused
         k-step scan never needs host allocation mid-flight)."""
         need = desc.seen_tokens + n
-        short = need - len(desc.blocks) * self.block_size
+        short = 0 if self.blockless \
+            else need - len(desc.blocks) * self.block_size
         if short > 0:
             blocks = (short + self.block_size - 1) // self.block_size
             self._reclaim(blocks)
@@ -974,7 +991,7 @@ class StateManager:
                 assert alloc.refcount(b) == 1, f"{kind.name}: refcount of {b}"
         bs = self.block_size
         for d in self.seqs.values():
-            assert len(d.blocks) * bs >= d.seen_tokens, \
+            assert len(d.blocks) >= self._cover(d.seen_tokens), \
                 f"uid {d.uid}: {len(d.blocks)} blocks cannot cover " \
                 f"{d.seen_tokens} seen tokens"
             assert len(d.block_hashes) <= len(d.blocks), \

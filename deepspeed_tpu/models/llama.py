@@ -593,13 +593,19 @@ def _block_paged(cfg: LlamaConfig, x: jnp.ndarray, layer: Params,
             valid)
         x = x + attn_out.reshape(b, t, nh * hd) @ layer["wo"]
 
+    return swiglu_block(cfg, x, layer), k_cache, v_cache
+
+
+def swiglu_block(cfg, x: jnp.ndarray, layer: Params) -> jnp.ndarray:
+    """A served block's second half: ``x + W_down(silu(W_gate n) * W_up n)``,
+    ``n = RMSNorm(x)``, under the ``norm`` and ``ffn`` scopes (this family's
+    paged block and ``models/brumby.py``'s)."""
     with jax.named_scope("norm"):
         y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
     with jax.named_scope("ffn"):
         gate = jax.nn.silu(y @ layer["w_gate"])
         up = y @ layer["w_up"]
-        x = x + (gate * up) @ layer["w_down"]
-    return x, k_cache, v_cache
+        return x + (gate * up) @ layer["w_down"]
 
 
 def apply_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,

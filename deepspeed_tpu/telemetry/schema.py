@@ -359,7 +359,15 @@ TRACER_SPANS = frozenset((
     # ``ssm_grouped_decode_roofline`` reads ``decode_step.ssm_rows``, its
     # ``moe_relu2_experts_roofline`` and ``moe_padded_row_share`` the
     # ``moe_rows_*`` of all three (docs/observability.md "state and experts
-    # in one family")
+    # in one family"). A family that names its recurrent layer's rows
+    # itself (``ModelFamily.state_rows``; models/brumby.py, the first with a
+    # state and NO cache) carries ``retention_rows`` - the live
+    # single-token rows whose state ONE retention layer advances - and
+    # ``retention_chunk_rows`` - the rows of the chunk riding with them - on
+    # ``decode_step``, and ``retention_chunk_rows`` on ``prefill_chunk``:
+    # the benchmark's ``retention_decode_roofline`` and
+    # ``retention_chunk_roofline`` read them (docs/observability.md "a
+    # state and no cache")
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

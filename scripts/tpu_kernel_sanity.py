@@ -393,6 +393,63 @@ def main():
 
     check("sparse_flash_attention", sparse)
 
+    # a retention layer's two kernels over the state pool where it lies
+    # (ops/pallas/retention.py, ISSUE 55) at the Brumby cell's head geometry,
+    # each against its XLA twin: a live row, a fresh one, a row aimed at the
+    # trash row whose state is NaN
+    def retention_pool():
+        from deepspeed_tpu.ops import retention as ret
+
+        pool = 0.05 * jnp.abs(randn(*ret.state_shape(2, 3, 8, 128)))
+        return pool.at[:, -1].set(jnp.nan)
+
+    def retention_operands(*lead):
+        bf = lambda x: x.astype(jnp.bfloat16)
+        return (bf(randn(*lead, 40, 128)), bf(randn(*lead, 8, 128)),
+                bf(randn(*lead, 8, 128)),
+                jax.nn.log_sigmoid(2 + randn(*lead, 8)))
+
+    def retention_decode():
+        from deepspeed_tpu.ops import retention as ret
+        from deepspeed_tpu.ops.pallas import retention as kernels
+
+        rows = jnp.asarray([1, 3, 0, 3], jnp.int32)
+        fresh = jnp.asarray([False, False, True, False])
+        args = retention_operands(4)
+        want_pool, want = ret.retention_decode_update_xla(
+            retention_pool(), 1, rows, fresh, *args)
+        pool, got = jax.jit(kernels.retention_decode_update)(
+            retention_pool(), 1, rows, fresh, *args)
+        live = jnp.asarray([0, 2])
+        assert bool(jnp.isfinite(got[live]).all())
+        diff_ok(got[live], want[live], 2e-3)
+        diff_ok(pool[1, :2], want_pool[1, :2], 1e-4)
+        diff_ok(pool[0, :3], retention_pool()[0, :3], 1e-9)
+        diff_ok(pool[1, 2], retention_pool()[1, 2], 1e-9)
+
+    check("retention_decode_update_in_place", retention_decode)
+
+    def retention_chunked():
+        from deepspeed_tpu.ops import retention as ret
+        from deepspeed_tpu.ops.pallas import retention as kernels
+
+        rows = jnp.asarray([2, 0], jnp.int32)
+        fresh = jnp.asarray([False, True])
+        args = retention_operands(2, 200)       # two tiles, the last short
+        with jax.default_matmul_precision("highest"):
+            want_pool, want = ret.retention_chunk_xla(
+                retention_pool(), 0, rows, fresh, *args)
+        pool, got = jax.jit(kernels.retention_chunk)(
+            retention_pool(), 0, rows, fresh, *args)
+        diff_ok(got, want, 0.05)                # bf16 operands on the MXU
+        scale = float(jnp.abs(want_pool[0, 2]).mean())
+        diff_ok(pool[0, jnp.asarray([2, 0])] / scale,
+                want_pool[0, jnp.asarray([2, 0])] / scale, 0.5)
+        diff_ok(pool[1, :3], retention_pool()[1, :3], 1e-9)
+        diff_ok(pool[0, 1], retention_pool()[0, 1], 1e-9)
+
+    check("retention_chunk_in_place", retention_chunked)
+
     RESULT["value"] = sum(1 for v in rows.values() if v == "ok")
     RESULT["detail"]["total"] = len(rows)
     emit_and_exit(ok=RESULT["value"] == len(rows))

@@ -1495,3 +1495,83 @@ def test_state_and_experts_stay_where_they_are_in_nemotrons_mixed_program(
                         ("paged_kv_write", 4), ("paged_decode", 2),
                         ("paged_prefill", 4)):
         assert calls.count(name) == count, (name, calls.count(name))
+
+
+# --- a state and no cache: Brumby's retention layers (ISSUE 55) ------------- #
+BRUMBY_CELL = "brumby-14b-base.serve-reason-32"
+
+
+def _brumby_program(program):
+    """The Brumby cell's paged forward on shapes at its published widths,
+    all of the cell's 5 layers, as the engine calls it: ``decode`` (32
+    rows of one token), ``chunk`` (one row of 512) or ``mixed`` (both as
+    ``slots + chunk`` rows). ``(forward, arguments)`` with the cache
+    second."""
+    from benchmark.harness.manifest import Cell
+    from deepspeed_tpu.models._paged import MixedCall
+
+    cell = Cell(BRUMBY_CELL)
+    engine = cell.role["engine"]
+    cfg = cell.family.build_cfg(cell.model, **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: module.init(cfg, k), jax.random.PRNGKey(0)))
+    slots = engine["ragged"]["max_tracked_sequences"]
+    chunk = engine["split_prefill_chunk"]
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, 2, engine["ragged"]["block_size"], slots=slots))
+
+    def forward(params, cache, tokens, tables, ctx, valid, rows):
+        return module.apply_paged(cfg, params, tokens, cache, tables, ctx,
+                                  valid=valid, slots=rows)
+
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    if program == "mixed":
+        call = MixedCall(s((slots, 1), i32), s((slots,), i32),
+                         s((slots,), bool), s((1,), i32), s((), i32),
+                         s((), i32), s((), i32))
+        rows = slots + chunk
+        return forward, (params, cache, s((1, rows), i32), call, None,
+                         s((1, rows), bool), None)
+    b, t = (slots, 1) if program == "decode" else (1, chunk)
+    return forward, (params, cache, s((b, t), i32), s((b, 1), i32),
+                     s((b,), i32), s((b, t), bool), s((b,), i32))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
+def test_a_state_and_no_cache_stays_where_it_is(v5e, program):
+    """The Brumby cell's ``decode`` (32 rows), one ``chunk`` (512 tokens)
+    and the ``mixed`` call of both at the published widths, its 5 layers,
+    compiled for the chip: the cache is the state pool alone (5.9 GB), it is
+    aliased argument-to-result with no copy - plain or ``copy-start`` - of
+    its shape or a layer's, the weights are read where they lie, the whole
+    program fits the chip, and a layer body is ONE Mosaic call of each
+    kernel its segments need, by its literal name."""
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = _brumby_program(program)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    (pool,) = jax.tree.leaves(args[1])
+    assert tuple(pool.shape) == (5, 33, 1032, 8704) and pool.dtype == "float32"
+    assert pool_copy_bytes(text, [pool]) == 0
+    dims = {",".join(map(str, shape)) for shape in (pool.shape,
+                                                    pool.shape[1:])}
+    assert not [line for line in text.splitlines() if "copy-start(" in line
+                and any(f"[{d}]" in line for d in dims)]
+    pool_bytes = math.prod(pool.shape) * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert 0 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT
+    # less than one slot's rows of temporaries: nothing pool-sized hides
+    assert mem.temp_size_in_bytes < pool_bytes // 33
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    assert (calls.count("retention_decode_update"),
+            calls.count("retention_chunk")) == {
+        "decode": (1, 0), "chunk": (0, 1), "mixed": (1, 1)}[program]

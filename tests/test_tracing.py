@@ -1082,7 +1082,7 @@ def _kernel_cases():
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     from deepspeed_tpu.ops.pallas import paged_sparse_attention as ps
-    from deepspeed_tpu.ops.pallas import ssm
+    from deepspeed_tpu.ops.pallas import retention, ssm
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.norms import (layer_norm_pallas,
                                                 rms_norm_pallas)
@@ -1141,6 +1141,18 @@ def _kernel_cases():
             lambda p, r, v, bc: ssm.ssm_decode_update(p, 1, r, r == 0, v, v,
                                                       bc, bc)[1],
             [state, rows, jnp.ones((2, 128), f32), jnp.ones((2, 16), f32)]),
+        # a retention layer's two (ops/pallas/retention.py, ISSUE 55): a pool
+        # of two slots at two key-value heads of 16, two query heads each
+        "retention_decode_update": (
+            lambda p, r, q: retention.retention_decode_update(
+                p, 1, r, r == 0, q, q[:, :2], q[:, :2], q[:, :2, 0])[1],
+            [jnp.ones((2, 3, 40, 192), f32), rows, jnp.ones((2, 4, 16), f32)]),
+        "retention_chunk": (
+            lambda p, r, q: retention.retention_chunk(
+                p, 1, r, r == 0, q, q[:, :, :2], q[:, :, :2],
+                q[:, :, :2, 0], tile=8)[1],
+            [jnp.ones((2, 3, 40, 192), f32), rows,
+             jnp.ones((2, 12, 4, 16), f32)]),
         "paged_index_write": (
             lambda k, p, bt, n: ps.paged_index_write(k, p, bt, n, n, layer=0),
             [jnp.ones((2, 3, 64), f32), ipool, tables, lens]),
@@ -1177,7 +1189,8 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "dequantize_int8", "state_rows_read", "state_rows_write",
                 "ssm_decode_update", "paged_index_write", "paged_index_scores",
                 "paged_sparse_select", "paged_sparse_decode",
-                "paged_sparse_prefill", "moe_grouped_matmul"]
+                "paged_sparse_prefill", "moe_grouped_matmul",
+                "retention_decode_update", "retention_chunk"]
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
