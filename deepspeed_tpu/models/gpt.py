@@ -240,14 +240,11 @@ def apply(cfg: GPTConfig, params: Params, tokens: jnp.ndarray, *,
     layers = _cast_layers(params, compute_dtype)
     block = partial(_block, cfg)
     if cfg.remat:
-        # route through the shared remat-policy registry (same name map as
-        # models/llama.py) so the config knob and the model agree
+        # the shared remat-policy registry: the policy the config names,
+        # else the one the engine was named or chose (ac.remat_block)
         from ..runtime.activation_checkpointing import checkpointing as ac
 
-        name = {"none": "full", "full": "full",
-                "dots": "dots_saveable"}.get(cfg.remat_policy,
-                                             cfg.remat_policy)
-        block = jax.checkpoint(block, policy=ac.get_policy(name))
+        block = ac.remat_block(block, cfg.remat_policy)
 
     from ..comm import overlap as ov
 

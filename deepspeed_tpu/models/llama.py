@@ -63,7 +63,9 @@ class LlamaConfig:
     attention_bias: bool = False  # QKV biases (Qwen2; HF attention_bias flag)
     qk_norm: bool = False         # per-head RMSNorm on q/k pre-rotary (Qwen3)
     remat: bool = False          # jax.checkpoint each block
-    remat_policy: str = "none"   # none | full | dots
+    remat_policy: str = "none"   # none (unnamed: the registry's default - what
+    #                              the engine was named or chose) | full |
+    #                              dots | any registry policy
     attention_impl: str = "auto"  # auto | xla | ulysses | ring | fpdt | ulysses_fpdt
     fpdt_chunks: int = 4         # query/KV chunk count for the fpdt impls
     fpdt_offload_kv: bool = False  # park K/V in host memory between chunks
@@ -426,14 +428,11 @@ def apply(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray, *,
         x = lax.with_sharding_constraint(x, res_sharding)
     block = partial(_block, cfg, attn_fn=attn_fn, res_sharding=res_sharding)
     if cfg.remat:
-        # route through the shared remat-policy registry
-        # (runtime/activation_checkpointing) so the config knob and the model
-        # agree on policy names
+        # the shared remat-policy registry: the policy the config names,
+        # else the one the engine was named or chose (ac.remat_block)
         from ..runtime.activation_checkpointing import checkpointing as ac
 
-        name = {"none": "full", "full": "full",
-                "dots": "dots_saveable"}.get(cfg.remat_policy, cfg.remat_policy)
-        block = jax.checkpoint(block, policy=ac.get_policy(name))
+        block = ac.remat_block(block, cfg.remat_policy)
 
     if pipe_stages > 1:
         from ..runtime.pipe import pipeline_apply
@@ -674,6 +673,8 @@ def model_spec(cfg: LlamaConfig, compute_dtype=jnp.bfloat16):
         pipeline_capable=cfg.use_pipeline,
         pipeline_grad_fn=(make_pipeline_grad_fn(cfg, compute_dtype)
                           if cfg.use_pipeline else None),
+        remat_probe=lambda params, batch: remat_probe(
+            cfg, params, batch, compute_dtype=compute_dtype),
     )
 
 
@@ -776,6 +777,36 @@ def loss_fn(cfg: LlamaConfig, params: Params, batch: Dict[str, jnp.ndarray], *,
         denom = jnp.maximum(valid.sum(), 1)
         loss = jnp.where(valid, token_loss, 0.0).sum() / denom
     return loss, {"loss": loss, "ntokens": valid.sum()}
+
+
+def remat_probe(cfg: LlamaConfig, params: Params,
+                batch: Dict[str, jnp.ndarray], compute_dtype=jnp.bfloat16):
+    """``ModelSpec.remat_probe``: the block ``apply`` scans, over shapes
+    alone - ``params`` the parameter tree's and ``batch`` ONE device's
+    micro-batch. ``None`` where this config leaves the engine nothing to
+    choose: no rematerialization, or a policy the user named."""
+    from ..runtime.activation_checkpointing.checkpointing import RematProbe
+
+    if not cfg.remat or cfg.remat_policy != "none":
+        return None
+    b, s = batch["tokens"].shape
+    if "labels" not in batch:
+        s -= 1
+    x = jax.ShapeDtypeStruct((b, s, cfg.hidden_size), compute_dtype)
+    layer = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(
+            p.shape[1:], compute_dtype
+            if jnp.issubdtype(p.dtype, jnp.floating) else p.dtype),
+        params["layers"])
+    cos, sin = jax.eval_shape(partial(
+        rope_frequencies, cfg.head_size, cfg.max_seq_len, cfg.rope_theta))
+    attn_fn = _resolve_attention(cfg)
+
+    def block(x, layer, cos, sin):
+        return _block(cfg, x, layer, cos, sin, None, attn_fn=attn_fn)
+
+    return RematProbe(block=block, block_args=(x, layer, cos, sin),
+                      layers=cfg.num_layers)
 
 
 def tiled_loss_fn(cfg: LlamaConfig, params: Params,

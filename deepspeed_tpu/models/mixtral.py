@@ -365,13 +365,11 @@ def apply(cfg: MixtralConfig, params: Params, tokens: jnp.ndarray, *,
         return x + checkpoint_name(ffn_out, "mlp_out"), aux
 
     if cfg.remat:
-        # shared remat-policy registry (same name map as models/llama.py)
+        # the shared remat-policy registry: the policy the config names,
+        # else the one the engine was named or chose (ac.remat_block)
         from ..runtime.activation_checkpointing import checkpointing as ac
 
-        name = {"none": "full", "full": "full",
-                "dots": "dots_saveable"}.get(cfg.remat_policy,
-                                             cfg.remat_policy)
-        block = jax.checkpoint(block, policy=ac.get_policy(name))
+        block = ac.remat_block(block, cfg.remat_policy)
 
     from ..comm import overlap as ov
 

@@ -43,6 +43,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -733,6 +734,15 @@ def _flash_bwd(q, k, v, o, lse, do, bias=None, *, causal, scale, q_offset,
 # differentiable wrappers ([BH, S, d] widened layout, and the native-GQA
 # [B*nkv, g, S, d] / narrow [B*nkv, S, d] layout)
 # --------------------------------------------------------------------------- #
+def _named(o, lse):
+    """The forward rules' own residuals under a checkpoint name: a remat
+    policy that saves ``attn_flash`` (``save_big_matmuls`` does) spares the
+    backward the kernel's forward replay - the output in the kernel's layout
+    and the log-sum-exp are all ``_flash_bwd`` needs beside q, k and v.
+    Identity outside such a policy."""
+    return checkpoint_name(o, "attn_flash"), checkpoint_name(lse, "attn_flash")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, scale, q_offset, window=None):
     o, _ = _flash_fwd(q, k, v, causal=causal, scale=scale, q_offset=q_offset,
@@ -741,8 +751,8 @@ def _flash(q, k, v, causal, scale, q_offset, window=None):
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, q_offset, window=None):
-    o, lse = _flash_fwd(q, k, v, causal=causal, scale=scale,
-                        q_offset=q_offset, window=window)
+    o, lse = _named(*_flash_fwd(q, k, v, causal=causal, scale=scale,
+                                q_offset=q_offset, window=window))
     return o, (q, k, v, o, lse)
 
 
@@ -767,8 +777,9 @@ def _flash_gqa(q, k, v, causal, scale, q_offset, window=None):
 
 
 def _flash_gqa_vjp_fwd(q, k, v, causal, scale, q_offset, window=None):
-    o, lse = _flash_fwd(q, k, v, causal=causal, scale=scale,
-                        q_offset=q_offset, g=q.shape[1], window=window)
+    o, lse = _named(*_flash_fwd(q, k, v, causal=causal, scale=scale,
+                                q_offset=q_offset, g=q.shape[1],
+                                window=window))
     return o, (q, k, v, o, lse)
 
 
@@ -791,8 +802,8 @@ def _flash_b(q, k, v, bias, causal, scale, q_offset):
 
 
 def _flash_b_vjp_fwd(q, k, v, bias, causal, scale, q_offset):
-    o, lse = _flash_fwd(q, k, v, bias, causal=causal, scale=scale,
-                        q_offset=q_offset)
+    o, lse = _named(*_flash_fwd(q, k, v, bias, causal=causal, scale=scale,
+                                q_offset=q_offset))
     return o, (q, k, v, bias, o, lse)
 
 

@@ -24,6 +24,7 @@ import contextlib
 import dataclasses
 import functools
 import os
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
@@ -78,6 +79,12 @@ class ModelSpec:
     # Routed instead of loss_fn when config.sequence.tiled_loss is on
     # (sequence/tiled.py tiled_fused_logits_loss).
     tiled_loss_fn: Optional[Callable[..., Any]] = None
+    # optional: (param shapes, ONE device's micro-batch shapes) -> the
+    # layer block the model rematerializes
+    # (activation_checkpointing.RematProbe), or None where the model leaves
+    # the engine nothing to choose. With it, ``remat: true`` and no policy
+    # named means the richest rung of the ladder that fits the device.
+    remat_probe: Optional[Callable[..., Any]] = None
 
     def materialize(self, rng: jax.Array):
         if self.params is not None:
@@ -113,6 +120,19 @@ class StepOutput(NamedTuple):
 
 
 from .utils import global_norm as _global_norm  # shared with runtime.utils
+
+
+def _agreed_max(values) -> List[int]:
+    """Whole numbers as every process of the job agrees on them: the largest
+    each has (what hosts of ONE program must decide alike - the program they
+    lower - they decide from these). The values themselves where the job is
+    one process."""
+    if jax.process_count() == 1:
+        return [int(v) for v in values]
+    from jax.experimental import multihost_utils
+
+    return np.max(multihost_utils.process_allgather(
+        np.asarray(values, np.int64)), axis=0).tolist()
 
 
 class DeepSpeedTPUEngine:
@@ -471,11 +491,23 @@ class DeepSpeedTPUEngine:
         # make the config's remat policy the process-wide default for
         # activation_checkpointing.checkpoint() (reference engine wires
         # checkpointing.configure at init, runtime/engine.py:395-408 region)
-        if config.activation_checkpointing.policy != "none" or \
-                config.activation_checkpointing.cpu_checkpointing:
-            from .activation_checkpointing import checkpointing as _ac
+        from .activation_checkpointing import checkpointing as _ac
 
+        # where the config names no policy and the model shows its block
+        # (ModelSpec.remat_probe), every batch signature's first lowering of
+        # the step chooses a rung from the device's memory: _remat_for
+        self._remat_choices = {}      # batch signature -> RematChoice
+        self._remat_choice = None     # the latest one published
+        self._remat_unreported = None
+        named = config.activation_checkpointing.policy != "none" or \
+            config.activation_checkpointing.cpu_checkpointing
+        if named:
             _ac.configure(deepspeed_config=config)
+        else:
+            # the latest engine wins: what an earlier one was named, or
+            # chose for ITS memory, is not ours
+            _ac.reset()
+        self._remat_chooses = not named and model.remat_probe is not None
 
         # --- attention.gqa_native: publish the native-GQA kernel gate
         # process-wide (latest engine wins, same contract as the remat
@@ -1677,6 +1709,148 @@ class DeepSpeedTPUEngine:
             "train_step", self._make_step_fn(), donate_argnums=(0,))
         return self._train_step
 
+    # ------------------------------------------------------------------ #
+    # remat: true with no policy named -> keep what fits
+    # ------------------------------------------------------------------ #
+    def _remat_for(self, batch) -> bool:
+        """Before anything lowers or runs the step on ``batch`` (sharded):
+        the rung of this batch's signature becomes the registry's default
+        policy, which the model reads whenever the step is traced. A
+        signature never seen before is chosen for first
+        (``_choose_remat_rung``) - kept bytes grow with the batch, so the
+        rung a short curriculum bucket holds is not the long one's - and
+        ``True`` says so: the call that follows lowers the step, and goes
+        through ``_first_lowering``."""
+        if not self._remat_chooses:
+            return False
+        from .activation_checkpointing import checkpointing as ac
+
+        sig = tuple((x.shape, x.dtype) for x in jax.tree.leaves(batch))
+        choice = self._remat_choices.get(sig)
+        first = choice is None
+        if first:
+            choice = self._choose_remat_rung(batch)
+            if choice is None:        # the model leaves nothing to choose
+                self._remat_chooses = False
+                return False
+            self._remat_choices[sig] = self._remat_unreported = choice
+        if ac.last_choice() is not choice:
+            ac.configure(choice=choice)
+        self._remat_choice = choice
+        return first
+
+    def _choose_remat_rung(self, batch):
+        """Which residuals the model's rematerialized layer scan keeps for
+        ``batch`` (sharded), from the memory this device has
+        (``activation_checkpointing.choose``): the allocator's limit and
+        what the device holds - the larger of this engine's state and the
+        allocator's count - as the job's processes agree on them, so every
+        host of one program lowers the same one. Activations sharded over
+        more than the batch axes (tensor, seq, pipe) are not modelled:
+        there, and where the device reports no limit (the CPU mesh), the
+        rung is ``full`` and the program is what it always was. ``None``
+        where the model's config leaves nothing to choose."""
+        from .activation_checkpointing import checkpointing as ac
+
+        t0 = time.perf_counter()
+        gas = self.gradient_accumulation_steps()
+        mm = self.mesh_mgr
+
+        def one_device(x):   # a micro-batch leaf as one device holds it
+            shape = x.sharding.shard_shape(x.shape)
+            return jax.ShapeDtypeStruct(shape[1 if gas > 1 else 0:], x.dtype)
+
+        probe = self.model.remat_probe(
+            jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                         self.state.params),
+            jax.tree.map(one_device, batch))
+        if probe is None:
+            return None
+        with self.telemetry.tracer.span("train_remat_choose", cat="train"):
+            mem = self.telemetry.memory.snapshot()
+            modelled = mm.world_size == mm.dp_world_size
+            held, limit = _agreed_max([
+                max(ac.device_bytes(self.state), int(mem["bytes_in_use"])),
+                -int(mem["bytes_limit"] if modelled else 0)])
+            choice = ac.choose(
+                probe, self.state.params, self._param_shardings,
+                self.precision.compute_dtype, limit_bytes=-limit,
+                held_bytes=held,
+                gathers_at_use=self.config.zero_config.stage >= 3
+                and mm.zero_world_size > 1,
+                accumulator_shardings=self._grad_shardings
+                if gas > 1 else None)
+        log_dist(f"remat: rung '{choice.rung}' of {ac.LADDER} "
+                 f"(keeps {choice.kept_bytes.get(choice.rung, 0) / 1e9:.2f} "
+                 f"GB, head-room {choice.headroom_bytes}; chosen in "
+                 f"{time.perf_counter() - t0:.2f} s)")
+        return choice
+
+    def _first_lowering(self, batch, run):
+        """``run()`` lowers and compiles the step for a batch signature new
+        to it, under the rung ``_remat_for`` published. The head-room
+        estimate gates the attempt; this is the net behind it: a compile
+        that ends in RESOURCE_EXHAUSTED (nothing has run, the donated state
+        is whole) falls back to ``full``, rebuilt and counted. Where the
+        job has several processes the step is compiled apart from its run,
+        and they fall back together or not at all: none has dispatched a
+        program its peers do not hold."""
+        choice = self._remat_choice
+        try:
+            if choice.rung != "full" and jax.process_count() > 1:
+                failed = None
+                try:
+                    self._train_step.lower(self.state, batch,
+                                           self._lr_override).compile()
+                except jax.errors.JaxRuntimeError as e:
+                    failed = e
+                if _agreed_max([failed is not None])[0]:
+                    raise failed or jax.errors.JaxRuntimeError(
+                        "RESOURCE_EXHAUSTED: on another process")
+            return run()
+        except jax.errors.JaxRuntimeError as e:
+            if choice.rung == "full" \
+                    or "RESOURCE_EXHAUSTED" not in str(e) \
+                    or any(x.is_deleted()
+                           for x in jax.tree.leaves(self.state)):
+                raise
+            logger.warning(f"remat: rung '{choice.rung}' did not fit "
+                           f"({str(e).splitlines()[0][:200]}); falling back "
+                           f"to 'full'")
+            from .activation_checkpointing import checkpointing as ac
+
+            choice.rung = "full"
+            choice.fallbacks += 1
+            ac.configure(choice=choice)
+            # the step is rebuilt, or the trace under the lost rung would
+            # be served again; signatures compiled before retrace on their
+            # next sight, each under its own rung
+            self._build_train_step()
+            return run()
+
+    def _report_remat(self, span) -> None:
+        """A choice, once its step has compiled: ``Train/remat/*`` gauges
+        and arguments on that ``train_step`` span."""
+        from .activation_checkpointing import checkpointing as ac
+
+        choice, self._remat_unreported = self._remat_unreported, None
+        peak = self.telemetry.compile.summary().get("train_step", {})
+        choice.compiled_peak_bytes = int(peak.get("peak_memory_bytes", 0))
+        values = {"rung": ac.LADDER.index(choice.rung),
+                  "kept_bytes": choice.kept_bytes.get(choice.rung, 0),
+                  "headroom_bytes": choice.headroom_bytes or 0,
+                  "predicted_peak_bytes": choice.predicted_peak_bytes,
+                  "compiled_peak_bytes": choice.compiled_peak_bytes,
+                  "fallbacks": choice.fallbacks}
+        for name, value in values.items():
+            self.telemetry.train_event(f"remat/{name}", value,
+                                       self.global_steps)
+        for rung, kept in choice.kept_bytes.items():
+            self.telemetry.train_event(f"remat/saved_bytes_{rung}", kept,
+                                       self.global_steps)
+        span.set(**{**{f"remat_{k}": v for k, v in values.items()},
+                    "remat_rung": choice.rung})
+
     def _ensure_audit_step(self):
         """The shadow-recompute executable for integrity audits: the SAME
         step function as ``_train_step`` but WITHOUT input donation, so the
@@ -1824,8 +1998,11 @@ class DeepSpeedTPUEngine:
         on the profiler's timeline, with the host phases of the step as its
         children (docs/observability.md)."""
         with self.telemetry.tracer.step_span(
-                "train_step", self.global_steps + 1, cat="train"):
-            return self._train_batch(batch)
+                "train_step", self.global_steps + 1, cat="train") as span:
+            out = self._train_batch(batch)
+            if self._remat_unreported is not None:
+                self._report_remat(span)
+            return out
 
     def _train_batch(self, batch) -> StepOutput:
         tracer = self.telemetry.tracer
@@ -1846,6 +2023,9 @@ class DeepSpeedTPUEngine:
             batch = self.curriculum_scheduler.truncate(batch, self.global_steps)
         with tracer.span("train_shard_batch", cat="train"):
             batch = self._shard_batch(batch, with_gas_dim=True)
+        # before anything lowers the step: the flops estimate and the
+        # integrity audit below do, and a trace is cached
+        first_lowering = not breakdown and self._remat_for(batch)
         if not self._flops_estimated and self.config.flops_profiler.enabled:
             self._estimate_step_flops(batch)
         if breakdown:
@@ -1864,8 +2044,13 @@ class DeepSpeedTPUEngine:
             # wall_clock_breakdown)
             with tracer.span("train/train_batch", cat="train",
                              step=self.global_steps + 1):
-                self.state, out = self._train_step(self.state, batch,
-                                                   self._lr_override)
+                if first_lowering:
+                    self.state, out = self._first_lowering(
+                        batch, lambda: self._train_step(
+                            self.state, batch, self._lr_override))
+                else:
+                    self.state, out = self._train_step(self.state, batch,
+                                                       self._lr_override)
         self.global_steps += 1
         self._last_grad_norm = out.grad_norm
         self.lr_scheduler.last_step = self.global_steps
@@ -2041,9 +2226,12 @@ class DeepSpeedTPUEngine:
                 example_batch = self.curriculum_scheduler.truncate(
                     example_batch, self.global_steps)
             batch = self._shard_batch(example_batch, with_gas_dim=True)
-            lowered = self._train_step.lower(self.state, batch,
-                                             self._lr_override)
-            compiled = lowered.compile()
+            def lower():
+                return self._train_step.lower(
+                    self.state, batch, self._lr_override).compile()
+
+            compiled = self._first_lowering(batch, lower) \
+                if self._remat_for(batch) else lower()
             cost = compiled.cost_analysis() or {}
             if isinstance(cost, (list, tuple)):
                 cost = cost[0] if cost else {}

@@ -160,6 +160,15 @@ TRAIN_SERIES = frozenset(
         "hidden_comm_frac")]
     + [f"Train/remat/{m}_{p}" for p in REMAT_POLICIES
        for m in ("saved_bytes", "peak_bytes", "step_ms")]
+    # the rung the engine chose for ``remat: true`` with no policy named
+    # (engine._report_remat, after a batch signature's first compile): its
+    # index on checkpointing.LADDER (0 = richest), the bytes it was
+    # predicted to keep on a device, the head-room it was given, the
+    # predicted and the compiled peak of the step, and compiles that ended
+    # in RESOURCE_EXHAUSTED and fell back to ``full``
+    + ["Train/remat/" + m for m in (
+        "rung", "kept_bytes", "headroom_bytes", "predicted_peak_bytes",
+        "compiled_peak_bytes", "fallbacks")]
     # native-GQA attention accounting (attention.gqa_native; bench.py
     # detail.attn_probe GQA sweep — docs/performance.md "Native GQA
     # attention"): per-step K/V HBM bytes the narrow kernels avoid, and
@@ -336,6 +345,9 @@ TRACER_SPANS = frozenset((
     # of the breakdown / API-parity paths
     "train/train_batch", "train/fwd", "train/bwd", "train/step",
     "train/fwd_micro", "train/eval_batch",
+    # ... and, ahead of a batch signature's first lowering, the choice of
+    # the remat rung (engine._remat_for: the chooser's traces)
+    "train_remat_choose",
     "checkpoint/save", "checkpoint/publish",
     # one lower + compile of a monitored program (telemetry/compile.py)
     "compile",

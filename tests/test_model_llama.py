@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models import llama
+from deepspeed_tpu.runtime.activation_checkpointing import checkpointing as ac
 
 
 @pytest.fixture(scope="module")
@@ -79,7 +80,12 @@ def test_tied_embeddings():
     assert logits.shape == (1, 4, cfg.vocab_size)
 
 
-def test_remat_matches_no_remat():
+@pytest.mark.parametrize("rung", [*ac.LADDER, "none", "save_attn_out"])
+def test_remat_matches_no_remat(rung):
+    """Every rung of the ladder the engine chooses from (``remat: true``, no
+    policy named: the registry's default decides), and each candidate the
+    A/B turned down, gives the loss and the gradients of the
+    un-rematerialized model."""
     cfg = llama.LlamaConfig.tiny()
     cfg_remat = llama.LlamaConfig.tiny(remat=True)
     params = llama.init(cfg, jax.random.PRNGKey(0))
@@ -88,8 +94,13 @@ def test_remat_matches_no_remat():
     def loss(c, p):
         return llama.loss_fn(c, p, {"tokens": tokens}, compute_dtype=jnp.float32)[0]
 
-    g1 = jax.grad(lambda p: loss(cfg, p))(params)
-    g2 = jax.grad(lambda p: loss(cfg_remat, p))(params)
+    l1, g1 = jax.value_and_grad(lambda p: loss(cfg, p))(params)
+    ac.configure(choice=ac.RematChoice(rung=rung))
+    try:
+        l2, g2 = jax.value_and_grad(lambda p: loss(cfg_remat, p))(params)
+    finally:
+        ac.reset()
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), g1, g2)
 

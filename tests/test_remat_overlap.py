@@ -36,6 +36,7 @@ import deepspeed_tpu as dst
 from deepspeed_tpu.comm import mesh as mesh_lib
 from deepspeed_tpu.comm import overlap as ov
 from deepspeed_tpu.models import gpt, llama, mixtral
+from deepspeed_tpu.ops import registry
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing as ac
 from deepspeed_tpu.telemetry import schema
 
@@ -189,7 +190,7 @@ def test_unknown_policy_raises():
 def test_policy_saved_names_mapping():
     assert ac.POLICY_SAVED_NAMES["save_attn_out"] == ("attn_out",)
     assert set(ac.POLICY_SAVED_NAMES["save_big_matmuls"]) == \
-        set(ac.MATMUL_CHECKPOINT_NAMES)
+        set(ac.MATMUL_CHECKPOINT_NAMES) | set(ac.KERNEL_CHECKPOINT_NAMES)
     # every mapped policy resolves in the registry
     for name in ac.POLICY_SAVED_NAMES:
         assert ac.get_policy(name) is not None
@@ -289,12 +290,14 @@ def test_saved_bytes_ordering():
 # --------------------------------------------------------------------------- #
 # CI lint: policy names must be emitted by the model families
 # --------------------------------------------------------------------------- #
-def _training_jaxpr(mod, cfg):
+def _training_jaxpr(mod, cfg, grad=False):
     params = mod.init(cfg, jax.random.PRNGKey(0))
     batch = {"tokens": jnp.zeros((2, 17), jnp.int32)}
-    return str(jax.make_jaxpr(
-        lambda p: mod.loss_fn(cfg, p, batch,
-                              compute_dtype=jnp.float32)[0])(params))
+
+    def loss(p):
+        return mod.loss_fn(cfg, p, batch, compute_dtype=jnp.float32)[0]
+
+    return str(jax.make_jaxpr(jax.grad(loss) if grad else loss)(params))
 
 
 FAMILIES = ((llama, llama.LlamaConfig.tiny(use_pipeline=False)),
@@ -317,6 +320,19 @@ def test_remat_policy_names_emitted_by_model_families():
                 f"{mod.__name__} declares {name!r} but its training jaxpr " \
                 f"never emits it"
         emitted_union |= declared
+    # the names a KERNEL emits (inside the flash kernel's forward rule) are
+    # met where that kernel runs: llama's training jaxpr on the Pallas path
+    # (its GRADIENT: the forward rule runs only under differentiation)
+    registry.set_backend("attention", "pallas")
+    try:
+        flash_jaxpr = _training_jaxpr(*FAMILIES[0], grad=True)
+    finally:
+        registry.set_backend("attention", None)
+    for name in ac.KERNEL_CHECKPOINT_NAMES:
+        assert f"name={name}" in flash_jaxpr, name
+        assert f"name={name}" not in _training_jaxpr(*FAMILIES[0],
+                                                     grad=True), name
+    emitted_union |= set(ac.KERNEL_CHECKPOINT_NAMES)
     for policy, names in ac.POLICY_SAVED_NAMES.items():
         for name in names:
             if name in ("residual", "block_out"):
