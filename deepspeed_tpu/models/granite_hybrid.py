@@ -62,8 +62,11 @@ from ..ops.norms import rms_norm, rms_norm_xla
 from ..ops.pallas import ssm as _ssm_kernels  # noqa: F401 (registers)
 from ..ops.registry import get_op
 from ._paged import layer_plan  # noqa: F401  (this family's plan, by name)
-from ._paged import (LayerPool, MixedCall, gather_rows, init_paged_pools,
+from ._paged import (LayerPool, gather_rows, init_paged_pools,
                      paged_attention_step, row_positions, scan_nest)
+from ._state import state_call  # noqa: F401  (the state families import it)
+from ._state import (next_tail, pack_tail, short_conv, tail_part,
+                     unpack_tail)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -96,14 +99,8 @@ class MambaSizes:
         ``[K - 1, conv_dim]`` flattened into whole sublanes of as few whole
         128-lane tiles as hold it (8 x 1664 for Granite's published 3 x
         4352, 8 x 2304 for Nemotron-3-Nano's 3 x 6144)."""
-        flat = (self.mamba_conv - 1) * self.conv_dim
-        width = self.d_inner
-        sublanes = 8 * -(-flat // (8 * width))
-        lanes = width if width % 128 else min(
-            width, -(-flat // (sublanes * 128)) * 128)
-        assert self.mamba_state % sublanes == 0, \
-            "the tail starts on a block of its own size"
-        return self.mamba_state, sublanes, lanes
+        return tail_part(self.mamba_state,
+                         (self.mamba_conv - 1) * self.conv_dim, self.d_inner)
 
     @property
     def state_sublanes(self) -> int:
@@ -318,33 +315,20 @@ def _conv(cfg, xbc, tail, w):
     """The causal depthwise convolution of ``xbc [b, t, C]`` after the
     sequence's previous ``K - 1`` rows ``tail``: ``(silu(conv + b) -> x, B,
     C``, the rows it ran over ``[b, K - 1 + t, C])``."""
-    t = xbc.shape[1]
-    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-    taps = w["conv_w"].astype(F32)
-    out = sum(ext[:, k:k + t].astype(F32) * taps[k]
-              for k in range(cfg.mamba_conv)) + w["conv_b"].astype(F32)
+    out, ext = short_conv(xbc, tail, w["conv_w"], w["conv_b"])
     G, N = cfg.mamba_groups, cfg.mamba_state
-    x, B, C = jnp.split(jax.nn.silu(out).astype(xbc.dtype),
-                        [cfg.d_inner, cfg.d_inner + G * N], axis=-1)
+    x, B, C = jnp.split(out, [cfg.d_inner, cfg.d_inner + G * N], axis=-1)
     if G > 1:       # [b, t, G, N]: head h reads group h // (heads / G)
         B, C = (a.reshape(a.shape[:2] + (G, N)) for a in (B, C))
     return x, B, C, ext
 
 
 def _pack_tail(cfg, tail):
-    """``[b, K - 1, C]`` as its part of the pool's rows (``cfg.tail_part``),
-    in the pool's type: every value of the compute type is one of it."""
-    b = tail.shape[0]
-    _, sublanes, lanes = cfg.tail_part
-    flat = tail.reshape(b, -1)
-    return jnp.pad(flat, ((0, 0), (0, sublanes * lanes - flat.shape[1]))) \
-        .reshape(b, sublanes, lanes)
+    return pack_tail(tail, cfg.tail_part)
 
 
 def _unpack_tail(cfg, part, dtype):
-    b = part.shape[0]
-    k, c = cfg.mamba_conv - 1, cfg.conv_dim
-    return part.reshape(b, -1)[:, :k * c].reshape(b, k, c).astype(dtype)
+    return unpack_tail(part, cfg.mamba_conv - 1, cfg.conv_dim, dtype)
 
 
 def _mixer_out(cfg, y, x, z, w):
@@ -421,8 +405,7 @@ def _ssm_rows(cfg, w, state, index, rows, fresh, xbc, dt, A, n_valid):
             C, h0, cfg.mamba_chunk)
     with jax.named_scope("ssm_conv"):
         # the last K - 1 rows of [tail | the row's real tokens]
-        new_tail = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
-            e, n, cfg.mamba_conv - 1, axis=0))(ext, n_valid)
+        new_tail = next_tail(ext, n_valid, cfg.mamba_conv - 1)
         state = write(state, index, rows,
                       _pack_tail(cfg, new_tail), cfg.tail_part)
     with jax.named_scope("ssm_state"):
@@ -456,25 +439,6 @@ def _mixer_paged(cfg, y, w, state, index, rows, fresh, valid, call=None):
         mixed = call.join(mixed_d, mixed_c)
         xs = call.join(xs_d, xs_c)
     return _mixer_out(cfg, mixed, xs, z, w), state
-
-
-def state_call(pool, block_tables, context_lens, valid, slots):
-    """``(state rows, fresh, call)`` of a paged forward over the state pool
-    ``pool``: each call row's pool row (the trash row where it must write
-    nothing) and whether it starts from zeros - one array each, or, in a
-    mixed call (``block_tables`` a ``_paged.MixedCall``, returned as
-    ``call``: decode row i is slot i and the chunk's rows are
-    ``chunk_slot``'s), (the decode rows', the chunk's) pairs."""
-    if not isinstance(block_tables, MixedCall):
-        if slots is None:
-            slots = jnp.arange(valid.shape[0], dtype=jnp.int32)
-        return ssm.pool_rows(slots, valid[:, 0], pool), context_lens == 0, \
-            None
-    call = block_tables
-    rows = (ssm.pool_rows(jnp.arange(call.slots), call.active, pool),
-            ssm.pool_rows(call.chunk_slot[None],
-                          (call.chunk_valid > 0)[None], pool))
-    return rows, (call.lens == 0, (call.chunk_ctx == 0)[None]), call
 
 
 def _mamba_paged(cfg, x, w, pools, index, rows, fresh, valid, call=None):

@@ -379,7 +379,12 @@ TRACER_SPANS = frozenset((
     # ``decode_step``, and ``retention_chunk_rows`` on ``prefill_chunk``:
     # the benchmark's ``retention_decode_roofline`` and
     # ``retention_chunk_roofline`` read them (docs/observability.md "a
-    # state and no cache")
+    # state and no cache"). models/solar_open2.py (delta-rule state BESIDE
+    # KV blocks, under experts) names its own the same way: ``delta_rows``
+    # and ``delta_chunk_rows`` on ``decode_step``, ``delta_chunk_rows`` on
+    # ``prefill_chunk``, beside ``ssm_rows`` / ``ssm_tokens`` and the
+    # ``moe_rows_*``: ``delta_decode_roofline`` and ``delta_chunk_roofline``
+    # read them (docs/observability.md "a delta rule beside blocks")
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

@@ -62,6 +62,21 @@ def _reset_global_mesh():
     mesh_mod._global_mesh = None
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Every compiled CPU program holds memory mappings of its own, and a
+    worker that ran a few compile-heavy files in a row (``--dist
+    loadfile``) reached the kernel's limit (``vm.max_map_count`` 65 530):
+    the next compile's ``mmap`` failed and XLA segfaulted (PR 57: after
+    ``test_mixed_step.py`` and ``test_solar_open2.py``, 65 284 mappings in
+    ``test_head_rows.py``). A file's programs go when the file is done."""
+    yield
+    import gc
+
+    jax.clear_caches()
+    gc.collect()
+
+
 @pytest.fixture
 def one_device():
     """The process's mesh on a one-chip host, not this directory's eight

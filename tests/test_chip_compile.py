@@ -1575,3 +1575,91 @@ def test_a_state_and_no_cache_stays_where_it_is(v5e, program):
     assert (calls.count("retention_decode_update"),
             calls.count("retention_chunk")) == {
         "decode": (1, 0), "chunk": (0, 1), "mixed": (1, 1)}[program]
+
+
+# --- delta-rule state beside KV blocks under experts: Solar-Open2 (ISSUE 57) #
+SOLAR_CELL = "solar-open2-250b.serve-longctx"
+
+
+def _solar_program(program):
+    """The Solar-Open2 cell's paged forward on shapes at its published
+    widths, the cell's 4 layers and 40 held experts, as the engine calls it:
+    ``decode`` (16 rows of one token), ``chunk`` (one row of 512) or
+    ``mixed`` (both as ``slots + chunk`` rows). ``(forward, arguments)``
+    with the cache second."""
+    from benchmark.harness.manifest import Cell
+    from deepspeed_tpu.models._paged import MixedCall
+
+    cell = Cell(SOLAR_CELL)
+    engine = cell.role["engine"]
+    ragged = engine["ragged"]
+    cfg = cell.family.build_cfg(cell.model, **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.eval_shape(
+        lambda k: module.init(cfg, k, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    slots, bs = ragged["max_tracked_sequences"], ragged["block_size"]
+    chunk = engine["split_prefill_chunk"]
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, ragged["memory_config_blocks"], bs, slots=slots))
+    table = cfg.max_seq_len // bs
+
+    def forward(params, cache, tokens, tables, ctx, valid, rows):
+        return module.apply_paged(cfg, params, tokens, cache, tables, ctx,
+                                  valid=valid, slots=rows)
+
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    if program == "mixed":
+        call = MixedCall(s((slots, table), i32), s((slots,), i32),
+                         s((slots,), bool), s((table,), i32), s((), i32),
+                         s((), i32), s((), i32))
+        rows = slots + chunk
+        return forward, (params, cache, s((1, rows), i32), call, None,
+                         s((1, rows), bool), None)
+    b, t = (slots, 1) if program == "decode" else (1, chunk)
+    return forward, (params, cache, s((b, t), i32), s((b, table), i32),
+                     s((b,), i32), s((b, t), bool), s((b,), i32))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
+def test_delta_state_kv_blocks_and_experts_stay_where_they_are(v5e, program):
+    """The Solar-Open2 cell's ``decode`` (16 rows), one ``chunk`` (512
+    tokens) and the ``mixed`` call of both at the published widths - 1 GQA
+    and 3 KDA layers, 40 of 320 experts a layer, 17 rows of state and 3152
+    KV blocks - compiled for the chip: the three pools are aliased argument-
+    to-result with no copy of their shape, the expert banks are read where
+    they lie (next to no temporaries), the program with its 9.45 GB of
+    weights fits the chip with room for a probe's reference, and a KDA layer
+    body is ONE Mosaic call of the state update by its literal name, the
+    chunked form between a read and a write of the row."""
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = _solar_program(program)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    params, cache = args[0], args[1]
+    assert params["delta"]["moe"]["router"].dtype == jnp.float32
+    assert params["delta"]["moe"]["w_up"].shape == (3, 40, 4096, 1280)
+    assert {k: (tuple(v.shape), v.dtype.name) for k, v in cache.items()} == {
+        "k": ((1, 3152, 8, 128, 128), "bfloat16"),
+        "v": ((1, 3152, 8, 128, 128), "bfloat16"),
+        "delta": ((3, 17, 144, 8192), "float32")}
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pools = jax.tree.leaves(cache)
+    assert pool_copy_bytes(text, pools) == 0
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes > 1.85e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert 11.3e9 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT - 2.5e9
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    decode, chunk = program != "chunk", program != "decode"
+    assert calls.count("delta_decode_update") == int(decode)
+    # the tail's rows a segment, and the state's around the chunked form
+    assert calls.count("state_rows_read") == decode + 2 * chunk
+    assert calls.count("state_rows_write") == decode + 2 * chunk
+    assert calls.count("moe_grouped_matmul") >= 2

@@ -1082,7 +1082,7 @@ def _kernel_cases():
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     from deepspeed_tpu.ops.pallas import paged_sparse_attention as ps
-    from deepspeed_tpu.ops.pallas import retention, ssm
+    from deepspeed_tpu.ops.pallas import delta, retention, ssm
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.norms import (layer_norm_pallas,
                                                 rms_norm_pallas)
@@ -1153,6 +1153,13 @@ def _kernel_cases():
                 q[:, :, :2, 0], tile=8)[1],
             [jnp.ones((2, 3, 40, 192), f32), rows,
              jnp.ones((2, 12, 4, 16), f32)]),
+        # a delta-rule layer's one (ops/pallas/delta.py, ISSUE 57; its
+        # chunked form meets the pool through the row-table kernels above):
+        # a pool of two slots at four heads of 16 x 16 over a tail
+        "delta_decode_update": (
+            lambda p, r, q: delta.delta_decode_update(
+                p, 1, r, r == 0, q, q, q, -q, q[:, :, 0])[1],
+            [jnp.ones((2, 3, 32, 64), f32), rows, jnp.ones((2, 4, 16), f32)]),
         "paged_index_write": (
             lambda k, p, bt, n: ps.paged_index_write(k, p, bt, n, n, layer=0),
             [jnp.ones((2, 3, 64), f32), ipool, tables, lens]),
@@ -1190,7 +1197,8 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "ssm_decode_update", "paged_index_write", "paged_index_scores",
                 "paged_sparse_select", "paged_sparse_decode",
                 "paged_sparse_prefill", "moe_grouped_matmul",
-                "retention_decode_update", "retention_chunk"]
+                "retention_decode_update", "retention_chunk",
+                "delta_decode_update"]
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
