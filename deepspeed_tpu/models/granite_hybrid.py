@@ -41,8 +41,8 @@ tail ``[K - 1, conv_dim]`` under it (``tail_part``; ``ops/ssm.py`` has the
 layout, the trash row and why one pool). A
 call row is addressed by its sequence's slot; a row whose context offset is
 0 starts from zeros inside the program; a row that is not ``valid`` leaves
-both states exactly as they were. Only ``ops/pallas/ssm.py``'s kernels touch
-the state pools.
+both states exactly as they were. Only the kernels of ``ops/pallas/ssm.py``
+and ``ops/pallas/ssm_scan.py`` (a segment of many tokens) touch the pools.
 """
 
 from __future__ import annotations
@@ -60,7 +60,8 @@ from ..ops.attention import attention
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import rms_norm, rms_norm_xla
 from ..ops.pallas import ssm as _ssm_kernels  # noqa: F401 (registers)
-from ..ops.registry import get_op
+from ..ops.pallas import ssm_scan as _ssm_scan
+from ..ops.registry import backend_of, get_op
 from ._paged import layer_plan  # noqa: F401  (this family's plan, by name)
 from ._paged import (LayerPool, gather_rows, init_paged_pools,
                      paged_attention_step, row_positions, scan_nest)
@@ -371,9 +372,9 @@ def _ssm_rows(cfg, w, state, index, rows, fresh, xbc, dt, A, n_valid):
     [b, t, H]`` (``_mixer_in``'s) from each row's state at ``[index,
     rows[i]]`` (zeros where ``fresh[i]``), which is advanced over the row's
     ``n_valid[i]`` real tokens and written back there. One token a row is
-    the in-place ``ssm_decode_update``; more are the blocked scan between a
-    read and a write of the rows. Returns ``(state pool, y [b, t, heads *
-    P] float32, x [b, t, heads * P])``."""
+    the in-place ``ssm_decode_update``; more are ``ssm_chunk_scan`` from
+    the rows where they lie, then their write. Returns ``(state pool, y [b,
+    t, heads * P] float32, x [b, t, heads * P])``."""
     b, t = xbc.shape[:2]
     read, write = get_op("state_rows_read"), get_op("state_rows_write")
     with jax.named_scope("ssm_conv"):
@@ -392,26 +393,23 @@ def _ssm_rows(cfg, w, state, index, rows, fresh, xbc, dt, A, n_valid):
                 per_lane(dt[:, 0]) * xs[:, 0].astype(F32),
                 B[:, 0], C[:, 0])
         return state, mixed[:, None], xs
-    with jax.named_scope("ssm_state"):
-        h0 = jnp.where(fresh[:, None, None, None], 0.0,
-                       ssm.state_to_heads(
-                           read(state, index, rows, cfg.state_part),
-                           cfg.mamba_heads))
     with jax.named_scope("ssm_conv"):
         xs, B, C, ext = _conv(cfg, xbc, tail, w)
     with jax.named_scope("ssm_state"):
-        mixed, h_t = ssm.ssd_chunked_scan(
+        mixed, new = get_op("ssm_chunk_scan")(
+            state, index, rows, fresh,
             xs.reshape(b, t, cfg.mamba_heads, cfg.mamba_head_dim), dt, A, B,
-            C, h0, cfg.mamba_chunk)
+            C, cfg.mamba_chunk)
     with jax.named_scope("ssm_conv"):
         # the last K - 1 rows of [tail | the row's real tokens]
         new_tail = next_tail(ext, n_valid, cfg.mamba_conv - 1)
         state = write(state, index, rows,
                       _pack_tail(cfg, new_tail), cfg.tail_part)
     with jax.named_scope("ssm_state"):
-        state = write(state, index, rows,
-                      ssm.state_from_heads(h_t), cfg.state_part)
-    return state, mixed.reshape(b, t, -1), xs
+        # (the tail's write, then the state's: the order the compiler
+        # leaves every pool in place for, ``ops/pallas/ssm_scan.py``)
+        state = write(state, index, rows, new, cfg.state_part)
+    return state, mixed, xs
 
 
 def _mixer_paged(cfg, y, w, state, index, rows, fresh, valid, call=None):
@@ -543,6 +541,20 @@ def state_slot_bytes(cfg: GraniteHybridConfig) -> int:
     ``state_dtype``): what an admission occupies beside its KV blocks. Its
     presence is how a family declares recurrent state to the engine."""
     return cfg.count("mamba") * cfg.state_row_bytes
+
+
+def state_rows(cfg, rows: int, chunk_rows: int) -> Dict[str, int]:
+    """What a step's span says of ONE Mamba layer of its call beside
+    ``ssm_rows`` / ``ssm_tokens`` (``telemetry/schema.py``):
+    ``ssm_chunk_rows``, the tokens of the chunk that rides with the decode
+    rows where the Mosaic scan takes them (``ops/pallas/ssm_scan.py``) - 0
+    where no chunk rides and where ``ssm_chunk_scan`` resolves to the XLA
+    form (off a TPU, or at sizes the kernel does not tile)."""
+    del rows
+    mosaic = backend_of("ssm_chunk_scan") == "pallas" and _ssm_scan.takes(
+        cfg.mamba_state, cfg.mamba_heads, cfg.mamba_head_dim,
+        cfg.mamba_groups, cfg.state_dtype)
+    return {"ssm_chunk_rows": chunk_rows if mosaic else 0}
 
 
 def init_paged_cache(cfg: GraniteHybridConfig, num_blocks: int,

@@ -56,6 +56,7 @@ from ..ops.norms import rms_norm
 from ..utils.tree import cast_floating
 from ._paged import (LayerPool, gather_rows, init_paged_pools,
                      paged_attention_step, row_positions, scan_nest)
+from .granite_hybrid import state_rows  # noqa: F401 (``ssm_chunk_rows``)
 from .granite_hybrid import (MambaSizes, _mamba_mixer, _mixer_paged, draw_dt,
                              init_mixer, mixer_logical_axes, state_call)
 from . import mixtral

@@ -167,3 +167,9 @@ def op(name: str) -> Callable:
 
     dispatch.__name__ = name
     return dispatch
+
+
+def backend_of(name: str) -> str:
+    """The backend a call of op ``name`` resolves to right now (what
+    :func:`resolved` says of every op, of one)."""
+    return _resolve(name)[0]

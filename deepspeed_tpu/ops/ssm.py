@@ -35,8 +35,12 @@ sublanes, lanes)``, block-aligned. ONE pool, because a pool that fits the
 chip's fast memory (the 62 MB a pool of tails alone would be) is copied
 there and back around every kernel that takes it - a whole-pool copy a
 layer that no ``copy`` instruction shows (``copy-start``; PERF.md Findings,
-PR 31). Only the three ops below touch a pool:
-``state_rows_read``, ``state_rows_write`` and ``ssm_decode_update``; an XLA
+PR 31). Only the four ops below touch a pool:
+``state_rows_read``, ``state_rows_write``, ``ssm_decode_update`` and
+``ssm_chunk_scan`` (many tokens of every row, read where they lie: off a
+TPU the read and the blocked scan below; on one ``ops/pallas/ssm_scan.py``,
+the row's state carried in fast memory through the segment's tokens; the
+rows' new state comes back for ``state_rows_write``); an XLA
 slice, gather or scatter on a carried ``[L, ...]`` pool can cost a copy of
 the whole of it a layer (PERF.md Findings, PR 29).
 """
@@ -49,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .registry import register
+from .registry import get_op, register
 
 F32 = jnp.float32
 
@@ -211,6 +215,24 @@ def ssm_decode_update_xla(pool, layer, rows, fresh, decay, dtx, B, C):
     return pool.at[layer, rows, :n].set(h.astype(pool.dtype)), y
 
 
+def ssm_chunk_scan_xla(pool, layer, rows, fresh, x, dt, A, B, C, chunk):
+    """Many tokens of ``b`` rows from the state pool: row i's state, the
+    first ``N`` sublanes of ``[layer, rows[i]]`` (zeros where
+    ``fresh[i]``), advanced over ``x [b, t, H, P]``, ``dt [b, t, H]``,
+    ``B``, ``C`` (:func:`ssd_chunked_scan`'s arguments, blocked by
+    ``chunk``). Returns ``(y [b, t, H * P] float32, the rows' new state [b,
+    N, H * P] float32)``, the state in the pool's layout for
+    ``state_rows_write``. The rows are read through the pool's own op and
+    meet the scan head-major: two transposes of the state a call."""
+    b, t, H, P = x.shape
+    h0 = jnp.where(fresh[:, None, None, None], 0.0, state_to_heads(
+        get_op("state_rows_read")(pool, layer, rows,
+                                  (0, B.shape[-1], H * P)), H))
+    y, h_t = ssd_chunked_scan(x, dt, A, B, C, h0, chunk)
+    return y.reshape(b, t, H * P), state_from_heads(h_t)
+
+
 register("state_rows_read", backend="xla")(state_rows_read_xla)
 register("state_rows_write", backend="xla")(state_rows_write_xla)
 register("ssm_decode_update", backend="xla")(ssm_decode_update_xla)
+register("ssm_chunk_scan", backend="xla")(ssm_chunk_scan_xla)

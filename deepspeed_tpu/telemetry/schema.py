@@ -384,7 +384,11 @@ TRACER_SPANS = frozenset((
     # and ``delta_chunk_rows`` on ``decode_step``, ``delta_chunk_rows`` on
     # ``prefill_chunk``, beside ``ssm_rows`` / ``ssm_tokens`` and the
     # ``moe_rows_*``: ``delta_decode_roofline`` and ``delta_chunk_roofline``
-    # read them (docs/observability.md "a delta rule beside blocks")
+    # read them (docs/observability.md "a delta rule beside blocks"). The
+    # state-space families (models/granite_hybrid.py ``state_rows``) say
+    # ``ssm_chunk_rows`` on ``decode_step`` and ``prefill_chunk``: the
+    # chunk's token rows ONE Mamba layer took through the Mosaic scan
+    # ``ssm_chunk_scan``, 0 where the op ran its XLA form; no metric reads it
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

@@ -588,6 +588,8 @@ def test_the_state_pool_stays_where_it_is(v5e, program):
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     assert calls.count("ssm_decode_update") == (2 if program == "decode"
                                                 else 0)
+    # a chunk's scan is ONE kernel a Mamba layer body (ISSUE 58)
+    assert calls.count("ssm_chunk_scan") == (0 if program == "decode" else 2)
     # (a chunk's walk: the narrow tile's and the wide one's, ISSUE 48)
     attn, walks = ("paged_decode", 1) if program == "decode" \
         else ("paged_prefill", 2)
@@ -774,8 +776,8 @@ def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     assert (calls.count("paged_decode"), calls.count("paged_prefill"),
             calls.count("paged_kv_write")) == (1, 2, 2)    # narrow and wide
-    assert calls.count("ssm_decode_update") == (
-        2 if cell == GRANITE_CELL else 0)
+    assert calls.count("ssm_decode_update") == calls.count(
+        "ssm_chunk_scan") == (2 if cell == GRANITE_CELL else 0)
     # every matmul against a weight (bf16; the blocked scan's own are f32)
     # runs over the call's rows: none over one segment's alone. A MoE
     # cell's three expert matmuls are its one grouped call (ISSUE 41): q,
@@ -956,10 +958,12 @@ def test_the_expert_bank_is_read_where_it_lies(v5e, cell, program):
 # the chunk's walk is a ``cond`` over two tile widths (0185c835790f0de0 and
 # c8639994fc5fe43d), and again on ISSUE 49's, which means to change them too:
 # the slots' ``paged_decode`` fetches its own pages (958bdb530c8e0656 and
-# a7f9637cb43eb148).
+# a7f9637cb43eb148). Granite's alone on ISSUE 58's, which means to change
+# it: the chunk's scan is one ``ssm_chunk_scan`` kernel (it was
+# 9d8372bd5608f6e3); chat's stands.
 NO_BANK_PROGRAMS = {
     "mistral-7b.serve-chat": "1a7c864441030658",
-    GRANITE_CELL: "9d8372bd5608f6e3",
+    GRANITE_CELL: "0a3ea1df291b5e31",
 }
 
 
@@ -1381,6 +1385,23 @@ def _grouped_state_update(pool, layer, rows, fresh, decay, dtx, B, C):
     return ssm_decode_update(pool, layer, rows, fresh, decay, dtx, B, C)
 
 
+def _chunk_scan(pool, layer, rows, fresh, x, dt, A, B, C):
+    from deepspeed_tpu.ops.pallas.ssm_scan import ssm_chunk_scan
+
+    return ssm_chunk_scan(pool, layer, rows, fresh, x, dt, A, B, C, 128)
+
+
+def _chunk_scan_args(tokens, groups):
+    """One row of ``tokens`` tokens at the published widths (64 heads of 64
+    channels, N = 128) on the cells' ``[.., 65, 136, 4096]`` pool."""
+    bc = (1, tokens, 128) if groups == 1 else (1, tokens, groups, 128)
+    return (((23, 65, 136, 4096), jnp.float32), ((), jnp.int32),
+            ((1,), jnp.int32), ((1,), jnp.bool_),
+            ((1, tokens, 64, 64), jnp.bfloat16),
+            ((1, tokens, 64), jnp.float32), ((64,), jnp.float32),
+            (bc, jnp.bfloat16), (bc, jnp.bfloat16))
+
+
 def _two_matrix_bank(tile):
     def call(x, w_up, w_down, tile_expert, tile_rows, num_tiles, layer):
         from deepspeed_tpu.ops.pallas.grouped_matmul import \
@@ -1411,6 +1432,11 @@ NEMOTRON_KERNELS = {
         ((64,), jnp.int32), ((64,), jnp.bool_), ((64, 4096), jnp.float32),
         ((64, 4096), jnp.float32), ((64, 8, 128), jnp.bfloat16),
         ((64, 8, 128), jnp.bfloat16))),
+    # a segment of many tokens (ISSUE 58): Nemotron's 512-row chunk over 8
+    # groups, Granite's 256 rows over one, the probes' 2048-row call
+    "ssm_chunk_scan_8_groups_512": (_chunk_scan, _chunk_scan_args(512, 8)),
+    "ssm_chunk_scan_1_group_256": (_chunk_scan, _chunk_scan_args(256, 1)),
+    "ssm_chunk_scan_8_groups_2048": (_chunk_scan, _chunk_scan_args(2048, 8)),
     "relu2_bank_decode_rows": (_two_matrix_bank(64), _bank_args(64, 64, 1920)),
     "relu2_bank_mixed_rows": (_two_matrix_bank(256),
                               _bank_args(576, 256, 1920)),
@@ -1491,7 +1517,8 @@ def test_state_and_experts_stay_where_they_are_in_nemotrons_mixed_program(
     assert 12.9e9 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT - 2.5e9
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
-    for name, count in (("ssm_decode_update", 6), ("moe_grouped_matmul", 6),
+    for name, count in (("ssm_decode_update", 6), ("ssm_chunk_scan", 6),
+                        ("moe_grouped_matmul", 6),
                         ("paged_kv_write", 4), ("paged_decode", 2),
                         ("paged_prefill", 4)):
         assert calls.count(name) == count, (name, calls.count(name))

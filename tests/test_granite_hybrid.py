@@ -315,6 +315,52 @@ def test_a_prompt_that_completes_beside_a_decode_advances_its_state_once(
     assert len(set(first + second)) > 6      # no one token repeated
 
 
+TRACED = {"trace": {"enabled": True, "ring_size": 4096,
+                    "dump_on_crash": False}}
+
+
+def test_both_forms_of_the_chunk_scan_serve_a_two_chunk_prompt_alike():
+    """``ssm_chunk_scan``'s two backends - the XLA read, scan and write, and
+    the Mosaic kernel (interpreted; at sizes it tiles: a 128-lane inner
+    width, N = 128) - under one engine run each: a 27-token prompt enters
+    in a 16-token chunk and a ragged one of 11 beside a live stream; both
+    serve the same tokens and leave the same state rows, and a step's span
+    says ``ssm_chunk_rows`` - the chunk's tokens on a chunk's tick where the
+    kernel took them, 0 on a decode-only tick and wherever the XLA form
+    ran."""
+    from deepspeed_tpu.ops import registry
+
+    hf = dict(published(), hidden_size=64, mamba_n_heads=4, mamba_d_head=32,
+              mamba_d_state=128)
+    cfg = family.build_cfg(hf, compute_dtype="float32")
+    assert gh._ssm_scan.takes(128, 4, 32, 1, cfg.state_dtype)
+    params = family.init(cfg, jax.random.PRNGKey(0))
+    a, b = prompts(9, 27)
+    runs = {}
+    for backend in ("xla", "pallas"):
+        registry.set_backend("ssm_chunk_scan", backend)
+        try:
+            eng = build_engine_v2(gh, cfg, params,
+                                  config={**ENGINE, **TRACED})
+            out = [eng.put(1, a)]
+            eng.put_split(2, b)
+            for _ in range(5):
+                out += sorted(eng.step().items())
+        finally:
+            registry.set_backend("ssm_chunk_scan", None)
+        steps = [e["args"] for e in eng.tracer.events()
+                 if e["ph"] == "X" and e["name"] == "decode_step"]
+        runs[backend] = (out, np.asarray(eng.cache["ssm"])[:, :-1], [
+            (s["chunk_tokens"], s["ssm_chunk_rows"]) for s in steps])
+    (out, state, said), (out_k, state_k, said_k) = runs["xla"], runs["pallas"]
+    assert out_k == out
+    # (rows of magnitude ~4; the first five layers agree to 2e-6, the
+    # attention layer after them widens that to 5e-4)
+    np.testing.assert_allclose(state_k, state, rtol=1e-3, atol=2e-3)
+    assert said == [(16, 0), (11, 0), (0, 0), (0, 0), (0, 0)]
+    assert said_k == [(16, 16), (11, 11), (0, 0), (0, 0), (0, 0)]
+
+
 def test_a_retired_slot_leaks_nothing_into_the_next_sequence(served):
     """A sequence served in a slot another has just left (its row still
     holds the former state) gives the logits of a fresh start: offset 0

@@ -507,6 +507,47 @@ def test_slots_keep_release_and_restart_their_state(served):
     eng.finish(9)
 
 
+def test_both_forms_of_the_chunk_scan_serve_a_two_chunk_prompt_alike():
+    """``ssm_chunk_scan``'s two backends - the XLA read, scan and write, and
+    the Mosaic kernel (interpreted; at sizes it tiles: two groups of one
+    128-lane tile each, N = 128) - under one engine run each: a 27-token
+    prompt enters in a 16-token chunk and a ragged one of 11 with nothing
+    decoding beside it, then decodes; both serve the same tokens and leave
+    the same state rows, and the spans say ``ssm_chunk_rows`` - the chunk's
+    tokens on ``prefill_chunk`` where the kernel took them, 0 on a
+    decode-only ``decode_step`` and wherever the XLA form ran."""
+    from deepspeed_tpu.ops import registry
+
+    hf, cfg, params, _ = build(mamba_num_heads=8, mamba_head_dim=32,
+                               ssm_state_size=128)
+    assert nh.state_rows is not None and cfg.mamba_groups == 2
+    (b,) = prompts(27)
+    runs = {}
+    for backend in ("xla", "pallas"):
+        registry.set_backend("ssm_chunk_scan", backend)
+        try:
+            eng = build_engine_v2(nh, cfg, params, config={
+                **ENGINE, "trace": {"enabled": True, "ring_size": 4096,
+                                    "dump_on_crash": False}})
+            eng.put_split(2, b)
+            out = [sorted(eng.step().items()) for _ in range(5)]
+        finally:
+            registry.set_backend("ssm_chunk_scan", None)
+        said = [(e["name"], e["args"]["ssm_chunk_rows"])
+                for e in eng.tracer.events() if e["ph"] == "X"
+                and e["name"] in ("prefill_chunk", "decode_step")]
+        runs[backend] = out, np.asarray(eng.cache["ssm"])[:, :-1], said
+    (out, state, said), (out_k, state_k, said_k) = runs["xla"], runs["pallas"]
+    assert out_k == out and sum(map(len, out)) >= 3
+    np.testing.assert_allclose(state_k, state, rtol=1e-3, atol=2e-3)
+    assert [n for n, _ in said] == [n for n, _ in said_k]
+    assert {n for n, _ in said} == {"prefill_chunk", "decode_step"}
+    assert not any(rows for _, rows in said)
+    assert [rows for name, rows in said_k if name == "prefill_chunk"] \
+        == [16, 11]
+    assert not any(rows for name, rows in said_k if name == "decode_step")
+
+
 def test_admission_reports_the_state_beside_the_blocks(served):
     hf, cfg, _, eng = served
     per_slot = nh.state_slot_bytes(cfg)

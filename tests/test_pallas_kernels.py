@@ -1373,3 +1373,109 @@ def test_mesh_of_the_trace_decides_not_the_global_mesh(devices8,
         manual = jax.shard_map(norm, in_specs=(P("data"), P()),
                                out_specs=P("data"), check_vma=False)
         assert str(jax.make_jaxpr(manual)(x, w)).count("shard_map") == 1
+
+
+# --------------------------------------------------------------------------- #
+# a multi-token segment's state-space scan on the state pool
+# --------------------------------------------------------------------------- #
+SSM_SCAN_CASES = {
+    # (b, t, heads, P, groups, rows (5: the trash row), fresh): what it holds
+    "one_group_whole_tiles": (1, 256, 4, 32, 1, (2,), (False,)),
+    "eight_groups": (1, 128, 32, 32, 8, (3,), (False,)),
+    "tokens_off_the_tile": (1, 200, 4, 64, 2, (0,), (False,)),
+    "under_one_tile": (2, 24, 4, 32, 1, (4, 1), (False, False)),
+    "fresh_beside_carried": (2, 128, 4, 64, 1, (1, 3), (True, False)),
+    "a_row_on_the_trash_row": (3, 128, 2, 128, 1, (2, 5, 0),
+                               (False, False, True)),
+    "two_trash_rows_last": (3, 130, 8, 32, 2, (4, 5, 5),
+                            (False, True, False)),
+}
+
+
+def _ssm_scan_case(case, N=128, tail=8, dtype=jnp.float32):
+    b, t, H, P, G, rows, fresh = SSM_SCAN_CASES[case]
+    k = jax.random.split(jax.random.PRNGKey(len(case)), 6)
+    pool = jax.random.normal(k[0], (3, 6, N + tail, H * P), jnp.float32)
+    x = jax.random.normal(k[1], (b, t, H, P), dtype)
+    # a row's last tokens are padding (dt = 0), a different count a row
+    real = t - jnp.arange(b) * 5 - 3
+    dt = jnp.where(jnp.arange(t)[None, :, None] < real[:, None, None],
+                   jax.nn.softplus(jax.random.normal(k[2], (b, t, H)) - 1),
+                   0.0)
+    A = -jax.random.uniform(k[3], (H,), jnp.float32, 1.0, 16.0)
+    shape = (b, t, N) if G == 1 else (b, t, G, N)
+    B, C = (jax.random.normal(k[i], shape, dtype) for i in (4, 5))
+    return pool, jnp.asarray(rows), jnp.asarray(fresh), x, dt, A, B, C
+
+
+@pytest.mark.parametrize("case", sorted(SSM_SCAN_CASES))
+def test_ssm_chunk_scan_is_the_recurrence_on_the_pool(case):
+    """``ssm_chunk_scan`` (Mosaic, interpreted) and the write of its rows
+    against the token-by-token recurrence in float32: ``y`` of every live
+    row, the rows' new state on the state part of their layer, and NOTHING
+    else of the pool touched - the tail part under the state, the other
+    rows, the other layers - the trash row apart, whose call rows read
+    zeros."""
+    from deepspeed_tpu.ops import ssm
+    from deepspeed_tpu.ops.pallas import ssm as kernels
+    from deepspeed_tpu.ops.pallas import ssm_scan
+
+    pool, rows, fresh, x, dt, A, B, C = _ssm_scan_case(case)
+    b, t, H, P = x.shape
+    N, trash, layer = B.shape[-1], pool.shape[1] - 1, 1
+    assert ssm_scan.takes(N, H, P, 1 if B.ndim == 3 else B.shape[2],
+                          pool.dtype)
+    y, new = jax.jit(ssm_scan.ssm_chunk_scan, static_argnums=9)(
+        pool, layer, rows, fresh, x, dt, A, B, C, 16)
+    got = kernels.state_rows_write(pool, layer, rows, new, (0, N, H * P))
+    h0 = jnp.where(fresh[:, None, None, None], 0.0,
+                   ssm.state_to_heads(pool[layer, rows, :N], H))
+    want_y, want_h = ssm.ssm_recurrence(x, dt, A, B, C, h0)
+    live = np.asarray(rows) != trash
+    np.testing.assert_allclose(
+        np.asarray(y)[live], np.asarray(want_y.reshape(b, t, -1))[live],
+        rtol=2e-5, atol=2e-4)
+    assert not np.asarray(y)[~live].any()
+    want = np.array(pool)
+    want[layer, np.asarray(rows)[live], :N] = \
+        np.asarray(ssm.state_from_heads(want_h))[live]
+    got = np.asarray(got)
+    np.testing.assert_allclose(got[layer, :trash, :N],
+                               want[layer, :trash, :N], rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(got[layer, :trash, N:],
+                                  want[layer, :trash, N:])
+    np.testing.assert_array_equal(got[[0, 2]], want[[0, 2]])
+
+
+@pytest.mark.parametrize("why, sizes", [
+    ("lanes_off_the_tile", (16, 8, 8, 1, "float32")),
+    ("a_group_off_the_tile", (128, 4, 64, 4, "float32")),
+    ("state_off_the_tile", (64, 4, 64, 1, "float32")),
+    ("a_narrow_head", (128, 16, 16, 1, "float32")),
+    ("a_bfloat16_state", (128, 4, 64, 1, "bfloat16")),
+])
+def test_ssm_chunk_scan_leaves_what_it_cannot_tile_to_xla(why, sizes):
+    """The shape predicate: sizes the kernel does not tile take the XLA
+    form inside the Pallas wrapper - the same program as the XLA backend's,
+    no ``pallas_call`` of the scan's in it."""
+    from deepspeed_tpu.ops import ssm
+    from deepspeed_tpu.ops.pallas import ssm_scan
+
+    N, H, P, G, dtype = sizes
+    assert not ssm_scan.takes(N, H, P, G, dtype)
+    k = jax.random.split(jax.random.PRNGKey(0), 5)
+    b, t = 2, 24
+    pool = jax.random.normal(k[0], (2, 4, N + 8, H * P), jnp.dtype(dtype))
+    x = jax.random.normal(k[1], (b, t, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[2], (b, t, H)))
+    shape = (b, t, N) if G == 1 else (b, t, G, N)
+    B, C = (jax.random.normal(k[i], shape) for i in (3, 4))
+    args = (pool, 1, jnp.asarray([2, 0]), jnp.asarray([True, False]), x, dt,
+            -jnp.ones((H,)), B, C)
+    text = str(jax.make_jaxpr(
+        lambda *a: ssm_scan.ssm_chunk_scan(*a, 8))(*args))
+    assert "ssm_chunk_scan" not in text
+    got = ssm_scan.ssm_chunk_scan(*args, 8)
+    want = ssm.ssm_chunk_scan_xla(*args, 8)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -1082,7 +1082,7 @@ def _kernel_cases():
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     from deepspeed_tpu.ops.pallas import paged_sparse_attention as ps
-    from deepspeed_tpu.ops.pallas import delta, retention, ssm
+    from deepspeed_tpu.ops.pallas import delta, retention, ssm, ssm_scan
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.norms import (layer_norm_pallas,
                                                 rms_norm_pallas)
@@ -1141,6 +1141,14 @@ def _kernel_cases():
             lambda p, r, v, bc: ssm.ssm_decode_update(p, 1, r, r == 0, v, v,
                                                       bc, bc)[1],
             [state, rows, jnp.ones((2, 128), f32), jnp.ones((2, 16), f32)]),
+        # a segment of many tokens (ops/pallas/ssm_scan.py, ISSUE 58): 16
+        # tokens of two rows, 4 heads of 32 channels, N = 128
+        "ssm_chunk_scan": (
+            lambda p, r, x, bc: ssm_scan.ssm_chunk_scan(
+                p, 1, r, r == 0, x, x[..., 0], -jnp.ones((4,), f32), bc, bc,
+                8)[0],
+            [jnp.ones((2, 3, 136, 128), f32), rows,
+             jnp.ones((2, 16, 4, 32), f32), jnp.ones((2, 16, 128), f32)]),
         # a retention layer's two (ops/pallas/retention.py, ISSUE 55): a pool
         # of two slots at two key-value heads of 16, two query heads each
         "retention_decode_update": (
@@ -1194,7 +1202,8 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "sparse_flash_bwd_dkv", "paged_decode", "paged_prefill",
                 "paged_kv_write", "rms_norm_fwd", "layer_norm_fwd", "quantize_int8",
                 "dequantize_int8", "state_rows_read", "state_rows_write",
-                "ssm_decode_update", "paged_index_write", "paged_index_scores",
+                "ssm_decode_update", "ssm_chunk_scan", "paged_index_write",
+                "paged_index_scores",
                 "paged_sparse_select", "paged_sparse_decode",
                 "paged_sparse_prefill", "moe_grouped_matmul",
                 "retention_decode_update", "retention_chunk",
@@ -1212,7 +1221,9 @@ def test_every_pallas_call_site_names_its_kernel(kernel):
 
     fn, args = _kernel_cases()[kernel]
     text = jax.jit(fn).lower(*args).as_text(debug_info=True)
-    assert re.search(rf"[/(]{kernel}\)*/pallas_call", text)
+    # (a call site inside a jitted wrapper - ``ssm_chunk_scan`` - opens the
+    # wrapper's own name stack: the name then starts the location)
+    assert re.search(rf"[/(\"]{kernel}\)*/pallas_call", text)
 
 
 def test_every_pallas_call_site_is_in_the_list():
