@@ -14,6 +14,14 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 
 
+def _say(line: str) -> None:
+    """One line in ONE write: the launcher's ranks share its stdout, and
+    ``print`` writes the text and the newline apart where Python runs
+    unbuffered (``PYTHONUNBUFFERED``), so two ranks' lines ran together."""
+    sys.stdout.write(line + "\n")
+    sys.stdout.flush()
+
+
 def run(pid: int, n: int, tp: int = 1, mode: str = "train"):
     """Build the engine from the ambient DSTPU_* env and train 5 fixed
     steps, printing one `LOSSES {pid}/{n} ...` line."""
@@ -45,8 +53,7 @@ def run(pid: int, n: int, tp: int = 1, mode: str = "train"):
     if mode == "preempt":
         return preempt_mode(eng, fixed, pid)
     losses = [float(eng.train_batch(fixed).loss) for _ in range(5)]
-    print(f"LOSSES {pid}/{n} {' '.join(f'{l:.6f}' for l in losses)}",
-          flush=True)
+    _say(f"LOSSES {pid}/{n} {' '.join(f'{l:.6f}' for l in losses)}")
     assert losses[-1] < losses[0] - 1.0, losses
 
 
@@ -80,7 +87,7 @@ def preempt_mode(eng, fixed, pid):
         if pid == 1 and i == 2:  # the resource manager preempts rank 1 only
             os.kill(os.getpid(), signal.SIGUSR1)
         if guard.step_boundary(eng):
-            print(f"PREEMPTED {pid} at_boundary {i}", flush=True)
+            _say(f"PREEMPTED {pid} at_boundary {i}")
             return
     raise SystemExit(f"rank {pid} never observed the peer preemption")
 

@@ -88,6 +88,10 @@ def test_autotuner_end_to_end_trials(devices8):
     assert ds_cfg["train_micro_batch_size_per_gpu"] in (1, 2)
 
 
+# slow: the CLI's sweep starts a worker process a trial (30-40 s of JAX
+# start-ups); no cell and no safety property runs the autotuner, and
+# `test_autotuner_end_to_end_trials` holds the same search in process
+@pytest.mark.slow
 def test_autotuning_cli_subprocess_trials(tmp_path):
     """End-to-end CLI (reference launcher/runner.py:407 --autotuning): a job
     JSON → isolated per-trial worker processes (fresh jit cache each; an OOM

@@ -13,6 +13,7 @@ file.
 """
 
 import dataclasses
+import functools
 import math
 import os
 
@@ -87,24 +88,30 @@ def program_logits(path, cfg, params, row):
         return axk1.apply(cfg, params, jnp.asarray(row[None]),
                           compute_dtype=f32)[0][0]
     out = []
+    # a jit of this call's own (the ops registry is read at trace time): one
+    # compile a shape - the chunk's, the token's - not one a piece
     if path == "apply_cached":
+        cached = jax.jit(functools.partial(axk1.apply_cached, cfg,
+                                           compute_dtype=f32))
         cache = axk1.init_cache(cfg, 1, 64, dtype=f32)
         for start, piece in pieces(row):
-            logits, cache = axk1.apply_cached(
-                cfg, params, jnp.asarray(piece[None]), cache,
-                jnp.asarray([start], jnp.int32), compute_dtype=f32)
+            logits, cache = cached(
+                params, jnp.asarray(piece[None]), cache,
+                jnp.asarray([start], jnp.int32))
             out.append(logits[0])
         return jnp.concatenate(out)
+    paged = jax.jit(functools.partial(axk1.apply_paged, cfg,
+                                      compute_dtype=f32))
     cache = axk1.init_paged_cache(cfg, 40, BLOCK, dtype=f32)
     table = jnp.asarray(1 + np.arange(32, dtype=np.int32))[None]
     for start, piece in pieces(row):
         width = CHUNK if start < PROMPT else 1
         padded = np.zeros((1, width), np.int32)
         padded[0, :len(piece)] = piece
-        logits, cache = axk1.apply_paged(
-            cfg, params, jnp.asarray(padded), cache, table,
+        logits, cache = paged(
+            params, jnp.asarray(padded), cache, table,
             jnp.asarray([start], jnp.int32),
-            valid=jnp.arange(width)[None] < len(piece), compute_dtype=f32)
+            valid=jnp.arange(width)[None] < len(piece))
         out.append(logits[0, :len(piece)])
     return jnp.concatenate(out)
 
@@ -167,20 +174,19 @@ def test_a_mixed_call_is_its_two_segments(f32, one_device):
     others = [rng.integers(0, 256, n) for n in (13, 27)]
     wants = [reference.logits(hf, family.Weights(params), o) for o in others]
     f = jnp.float32
+    paged = jax.jit(functools.partial(axk1.apply_paged, cfg, compute_dtype=f))
     cache = axk1.init_paged_cache(cfg, 40, BLOCK, dtype=f)
     tables = np.zeros((4, 32), np.int32)
     for i in range(3):
         tables[i, :8] = 1 + 8 * i + np.arange(8)
     with jax.default_matmul_precision("highest"):
         for i, o in enumerate(others):       # the decode rows' contexts
-            _, cache = axk1.apply_paged(
-                cfg, params, jnp.asarray(o[None, :-1]), cache,
-                jnp.asarray(tables[i:i + 1]), jnp.zeros((1,), jnp.int32),
-                compute_dtype=f)
-        _, cache = axk1.apply_paged(         # the chunk's first 16 tokens
-            cfg, params, jnp.asarray(row[None, :16]), cache,
-            jnp.asarray(tables[2:3]), jnp.zeros((1,), jnp.int32),
-            compute_dtype=f)
+            _, cache = paged(
+                params, jnp.asarray(o[None, :-1]), cache,
+                jnp.asarray(tables[i:i + 1]), jnp.zeros((1,), jnp.int32))
+        _, cache = paged(                    # the chunk's first 16 tokens
+            params, jnp.asarray(row[None, :16]), cache,
+            jnp.asarray(tables[2:3]), jnp.zeros((1,), jnp.int32))
         call = MixedCall(
             tables=jnp.asarray(tables), lens=jnp.asarray([12, 26, 0, 0]),
             active=jnp.asarray([True, True, False, False]),
@@ -189,9 +195,8 @@ def test_a_mixed_call_is_its_two_segments(f32, one_device):
         tokens = np.zeros((1, 4 + 8), np.int32)
         tokens[0, 0], tokens[0, 1] = others[0][-1], others[1][-1]
         tokens[0, 4:9] = row[16:21]
-        got, _ = axk1.apply_paged(
-            cfg, params, jnp.asarray(tokens), cache, call, None,
-            valid=call.valid(12), compute_dtype=f)
+        got, _ = paged(params, jnp.asarray(tokens), cache, call, None,
+                       valid=call.valid(12))
     assert gap(got[0, 0], wants[0][-1]) < TOL
     assert gap(got[0, 1], wants[1][-1]) < TOL
     assert gap(got[0, 4:9], want[16:21]) < TOL

@@ -14,6 +14,7 @@ tests steer the one platform test of the op tier (``registry.on_tpu``).
 """
 
 import dataclasses
+import functools
 import math
 import os
 import sys
@@ -432,7 +433,7 @@ POOL_PROGRAMS = [(cell, program, pool) for cell in SERVE_CELLS
 
 
 @pytest.mark.parametrize("cell,program,pool", POOL_PROGRAMS)
-def test_the_pools_stay_where_they_are(v5e, cell, program, pool):
+def test_the_pools_stay_where_they_are(compiled_for_v5e, cell, program, pool):
     """The KV pools are ONE ``[L, ...]`` buffer from a program's donated
     argument to its result: the compiled program holds no copy, slice,
     update, buffer or loop fusion the size of a pool or of a layer's pool
@@ -446,11 +447,7 @@ def test_the_pools_stay_where_they_are(v5e, cell, program, pool):
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
-    fn, args = _pool_program(cell, program, pool == "int8")
-    sh = SingleDeviceSharding(v5e.devices[0])
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    compiled, args = compiled_for_v5e("pool", cell, program, pool == "int8")
     text, mem = compiled.as_text(), compiled.memory_analysis()
     cache = args[1]
 
@@ -624,10 +621,45 @@ def _mixed_program(cell_name):
     return forward, args + ((None,) if slot else ())
 
 
+def _cell_program(kind, cell_name, *how):
+    """``(function, arguments)`` of one of a serve cell's programs, the cache
+    its second argument: ``"pool"`` (``_pool_program``: then the program's
+    name and whether the pools are int8), ``"mixed"`` (``_mixed_program``;
+    Command A+'s own) or ``"greedy_mixed"`` (the mixed call inside the
+    engine's sampling, ``_greedy_mixed_step``: then whether it reads its
+    rows)."""
+    if kind == "pool":
+        return _pool_program(cell_name, *how)
+    forward, args = _command_a_mixed_program() \
+        if cell_name == COMMAND_A_CELL else _mixed_program(cell_name)
+    if kind == "greedy_mixed":
+        forward = _greedy_mixed_step(forward, *how)
+    return forward, args
+
+
+@pytest.fixture(scope="module")
+def compiled_for_v5e(v5e):
+    """``(kind, cell, ...) -> (compiled, arguments)``: a cell's program
+    (``_cell_program``) compiled for the described chip with its cache
+    donated, ONCE a module - several tests read different facts of one
+    compiled program (its pools, its bank, its head's rows, its grids)."""
+    @functools.cache
+    def compiled(*key):
+        fn, args = _cell_program(*key)
+        sh = SingleDeviceSharding(v5e.devices[0])
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            args)
+        return jax.jit(fn, donate_argnums=(1,)).lower(*args).compile(), args
+
+    return compiled
+
+
 KEYE_CELL = "keye-vl-2.0-30b-a3b.serve-longctx"
 
 
-def test_the_learned_selections_mixed_program_keeps_three_pools_in_place(v5e):
+def test_the_learned_selections_mixed_program_keeps_three_pools_in_place(
+        compiled_for_v5e):
     """The Keye cell's mixed call (8 decode rows + a 512-row chunk) at its
     real configuration, compiled for the chip: K, V AND the index keys' pool
     stay where they are (no pool-shaped copy - the 64-wide index keys lie
@@ -640,11 +672,7 @@ def test_the_learned_selections_mixed_program_keeps_three_pools_in_place(v5e):
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
-    fn, args = _mixed_program(KEYE_CELL)
-    sh = SingleDeviceSharding(v5e.devices[0])
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    compiled, args = compiled_for_v5e("mixed", KEYE_CELL)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     cache = args[1]
     assert set(cache) == {"k", "v", "kI"}
@@ -712,7 +740,8 @@ def _command_a_mixed_program():
                      s((1, rows), bool))
 
 
-def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
+def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(
+        compiled_for_v5e):
     """The Command A+ cell's mixed call (16 decode rows + a 512-row chunk) at
     its real configuration, compiled for the chip: the full kind's pools AND
     the window kind's stay where they are (no pool-shaped copy, every pool
@@ -725,11 +754,7 @@ def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
-    forward, args = _command_a_mixed_program()
-    sh = SingleDeviceSharding(v5e.devices[0])
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
-    compiled = jax.jit(forward, donate_argnums=(1,)).lower(*args).compile()
+    compiled, args = compiled_for_v5e("mixed", COMMAND_A_CELL)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     pools = jax.tree.leaves(args[1])
     assert pool_copy_bytes(text, pools) == 0
@@ -745,7 +770,7 @@ def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(v5e):
 
 
 @pytest.mark.parametrize("cell", SERVE_CELLS + (GRANITE_CELL,))
-def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
+def test_the_mixed_program_reads_a_layers_weights_once(compiled_for_v5e, cell):
     """The four serve cells' mixed call (``slots + 256`` rows) at their real
     configurations, compiled for the chip: the pools stay where they are
     (no pool-shaped copy, every pool - Granite's state pool too - aliased
@@ -761,11 +786,7 @@ def test_the_mixed_program_reads_a_layers_weights_once(v5e, cell):
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
-    fn, args = _mixed_program(cell)
-    sh = SingleDeviceSharding(v5e.devices[0])
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    compiled, args = compiled_for_v5e("mixed", cell)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     pools = jax.tree.leaves(args[1])
     assert pool_copy_bytes(text, pools) == 0
@@ -820,7 +841,8 @@ def _greedy_mixed_step(forward, reads_its_rows: bool):
 
 
 @pytest.mark.parametrize("cell", [COMMAND_A_CELL, KEYE_CELL, SERVE_CELLS[0]])
-def test_the_mixed_programs_head_scores_the_rows_it_reads(v5e, cell):
+def test_the_mixed_programs_head_scores_the_rows_it_reads(compiled_for_v5e,
+                                                          cell):
     """The mixed call at Command A+'s (16 + 512 rows, a TIED table of
     262 144), Keye's (8 + 512, 151 936) and chat's (32 + 256, 32 000) cell
     shapes with ``rows=`` as the engine hands them, compiled for the chip
@@ -832,15 +854,9 @@ def test_the_mixed_programs_head_scores_the_rows_it_reads(v5e, cell):
     program's peak is lower."""
     import re
 
-    forward, args = _command_a_mixed_program() if cell == COMMAND_A_CELL \
-        else _mixed_program(cell)
-    sh = SingleDeviceSharding(v5e.devices[0])
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
     text, peak = {}, {}
     for reads in (True, False):
-        compiled = jax.jit(_greedy_mixed_step(forward, reads),
-                           donate_argnums=(1,)).lower(*args).compile()
+        compiled, args = compiled_for_v5e("greedy_mixed", cell, reads)
         text[reads] = compiled.as_text()
         peak[reads] = compiled.memory_analysis().peak_memory_in_bytes
     params, rows, slots = args[0], args[2].shape[1], args[3].slots
@@ -867,7 +883,8 @@ PARENT_MIXED_PEAK = {COMMAND_A_CELL: 14_182_393_856,
 
 
 @pytest.mark.parametrize("cell", sorted(PARENT_MIXED_PEAK))
-def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(v5e, cell):
+def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(
+        compiled_for_v5e, cell):
     """Command A+'s and chat's mixed program as the engine runs it, compiled
     for the chip: every ``paged_prefill`` call (one a table kind) takes its
     last grid dimension as an operand - a Mosaic call's dynamic grid bound
@@ -882,13 +899,7 @@ def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(v5e, cell):
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
-    forward, args = _command_a_mixed_program() if cell == COMMAND_A_CELL \
-        else _mixed_program(cell)
-    sh = SingleDeviceSharding(v5e.devices[0])
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
-    compiled = jax.jit(_greedy_mixed_step(forward, True),
-                       donate_argnums=(1,)).lower(*args).compile()
+    compiled, args = compiled_for_v5e("greedy_mixed", cell, True)
     text = compiled.as_text()
     first = {kernel: re.findall(
         rf"%{kernel}(?:\.\d+)? = .*? custom-call\(.*" + MOSAIC
@@ -914,7 +925,8 @@ BANK_PROGRAMS = [(cell, "mixed") for cell in MOE_CELLS] + [
 
 
 @pytest.mark.parametrize("cell,program", BANK_PROGRAMS)
-def test_the_expert_bank_is_read_where_it_lies(v5e, cell, program):
+def test_the_expert_bank_is_read_where_it_lies(compiled_for_v5e, cell,
+                                               program):
     """A MoE cell's forward programs compiled for the chip with the grouped
     form: a layer body is ONE ``moe_grouped_matmul`` over the STACKED bank
     (the layer a prefetched scalar), so the compiled program holds no copy,
@@ -927,12 +939,8 @@ def test_the_expert_bank_is_read_where_it_lies(v5e, cell, program):
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
-    fn, args = (_mixed_program(cell) if program == "mixed"
-                else _pool_program(cell, program, False))
-    sh = SingleDeviceSharding(v5e.devices[0])
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    compiled, args = compiled_for_v5e("mixed", cell) if program == "mixed" \
+        else compiled_for_v5e("pool", cell, program, False)
     text = compiled.as_text()
     moe = args[0]["layers"]["moe"]
     bank = [moe[n] for n in ("w_gate", "w_up", "w_down")]
@@ -1255,6 +1263,9 @@ def compile_train_step_for(devices, cfg, batch, seq, cpu_devices):
         state, tokens, engine._lr_override).compile()
 
 
+# slow: the WHOLE train step at Mistral-7B widths through the TPU compiler
+# (50-70 s); `chip_smoke.py` runs this very step on the chip on every PR, and
+# the kernels' own described-chip compiles above run in tier-1
 @pytest.mark.slow
 def test_train_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
     size = chip_smoke.TrainSize()
@@ -1268,6 +1279,9 @@ def test_train_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
     assert live < 16 * 10 ** 9, mem
 
 
+# slow: two whole-step compiles, one over four described chips (85-140 s);
+# `chip_smoke.py --chips 4` and the `train-zero3-x4` cell run this step on
+# four real chips on every PR
 @pytest.mark.slow
 def test_zero3_step_over_four_chips_keeps_every_kernel(v5e):
     """ZeRO-3 over ``data=4`` as ``chip_smoke.py --chips 4`` runs it: the step
@@ -1281,7 +1295,6 @@ def test_zero3_step_over_four_chips_keeps_every_kernel(v5e):
     assert mosaic[0] == mosaic[1] > 0, mosaic
 
 
-@pytest.mark.slow
 def test_decode_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
     """The server's decode program over the real pool geometry (depth 2: the
     layer scan makes the program the same at any depth)."""
@@ -1293,8 +1306,10 @@ def test_decode_step_compiles_for_v5e_at_mistral_7b_widths(v5e):
     size = dataclasses.replace(chip_smoke.ServeSize(), layers=2)
     eng = chip_smoke.build_server(chip_smoke.mistral_7b(size.layers), size,
                                   seed=0)
-    args = (eng.params, eng.cache, *map(jnp.asarray, eng._slots()),
-            jax.random.PRNGKey(0))
+    # the arguments as the engine's own launch hands them over
+    # (``_launch_decode``: ``_dispatch`` of ``_slots`` with the result
+    # before), so a change of the program's signature changes this call too
+    args = eng._dispatch(lambda *args: args, eng._slots(), seed=0, prev=True)
     sh = SingleDeviceSharding(v5e.devices[0])
     args = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), args)

@@ -50,7 +50,6 @@ def _tiny():
     return chip_smoke, cfg, train, serve
 
 
-@pytest.mark.slow
 def test_chip_smoke_train_phase_tiny(capsys):
     chip_smoke, cfg, train, _ = _tiny()
     chip_smoke.phase_train(train, seed=0, mosaic=False,
@@ -59,7 +58,6 @@ def test_chip_smoke_train_phase_tiny(capsys):
     assert row["phase"] == "train" and row["compiles"] == 1
 
 
-@pytest.mark.slow
 def test_chip_smoke_serve_phase_tiny(capsys):
     chip_smoke, cfg, _, serve = _tiny()
     chip_smoke.phase_serve(serve, seed=0, mosaic=False, cfg=cfg)
@@ -77,7 +75,6 @@ def test_chip_smoke_serve_phase_tiny(capsys):
     assert overlap["overlapped_steps"] > 0
 
 
-@pytest.mark.slow
 def test_chip_smoke_four_chip_phase_on_four_virtual_devices():
     """The ``--chips 4`` phase on four virtual CPU devices (its own process:
     the device count is fixed when JAX starts), the ops resolved as on the

@@ -10,6 +10,7 @@ whole layer, the sigmoid gating by hand, and the configuration's file.
 """
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -77,16 +78,20 @@ def program_logits(path, cfg, params, row):
         return cohere2_moe.apply(cfg, params, jnp.asarray(row[None]),
                                  compute_dtype=f32)[0][0]
     out = []
+    # a jit of this call's own: one compile a shape, not one a piece
     if path == "apply_cached":
+        cached = jax.jit(functools.partial(cohere2_moe.apply_cached, cfg,
+                                           compute_dtype=f32))
         cache = cohere2_moe.init_cache(cfg, 1, 64, dtype=f32)
         for start, piece in pieces(row):
-            logits, cache = cohere2_moe.apply_cached(
-                cfg, params, jnp.asarray(piece[None]), cache,
-                jnp.asarray([start], jnp.int32), compute_dtype=f32)
+            logits, cache = cached(params, jnp.asarray(piece[None]), cache,
+                                   jnp.asarray([start], jnp.int32))
             out.append(logits[0])
         return jnp.concatenate(out)
     # both pools through a manager's tables: the window kind's blocks are
     # given back on the way, its segment short, its lengths shifted
+    paged = jax.jit(functools.partial(cohere2_moe.apply_paged, cfg,
+                                      compute_dtype=f32))
     state = manager(cfg, slots=1)
     kind, = state.window_kinds
     cache = cohere2_moe.init_paged_cache(
@@ -97,11 +102,11 @@ def program_logits(path, cfg, params, row):
         padded = np.zeros((1, width), np.int32)
         padded[0, :len(piece)] = piece
         state.extend(desc, len(piece))
-        logits, cache = cohere2_moe.apply_paged(
-            cfg, params, jnp.asarray(padded), cache,
+        logits, cache = paged(
+            params, jnp.asarray(padded), cache,
             jnp.asarray(state.block_table(desc)[None]),
             jnp.asarray([start], jnp.int32),
-            valid=jnp.arange(width)[None] < len(piece), compute_dtype=f32)
+            valid=jnp.arange(width)[None] < len(piece))
         desc.seen_tokens = start + len(piece)
         out.append(logits[0, :len(piece)])
     assert state.window_blocks_released > 0
@@ -207,6 +212,8 @@ def test_a_mixed_call_is_its_two_segments(f32, one_device):
     others = [rng.integers(0, 256, n) for n in (13, 27)]
     wants = [reference.logits(hf, family.Weights(params), o) for o in others]
     f = jnp.float32
+    paged = jax.jit(functools.partial(cohere2_moe.apply_paged, cfg,
+                                      compute_dtype=f))
     state = manager(cfg, slots=4)
     kind, = state.window_kinds
     cache = cohere2_moe.init_paged_cache(
@@ -223,18 +230,17 @@ def test_a_mixed_call_is_its_two_segments(f32, one_device):
                 piece = o[start:min(start + CHUNK, len(o) - 1)]
                 pad = np.zeros((1, CHUNK), np.int32)
                 pad[0, :len(piece)] = piece
-                _, cache = cohere2_moe.apply_paged(
-                    cfg, params, jnp.asarray(pad), cache,
+                _, cache = paged(
+                    params, jnp.asarray(pad), cache,
                     jnp.asarray(table(d, len(piece))[None]),
                     jnp.asarray([start], jnp.int32),
-                    valid=jnp.arange(CHUNK)[None] < len(piece),
-                    compute_dtype=f)
+                    valid=jnp.arange(CHUNK)[None] < len(piece))
                 d.seen_tokens = start + len(piece)
         for start in (0, 8):                 # the chunk's first 16 tokens
-            _, cache = cohere2_moe.apply_paged(
-                cfg, params, jnp.asarray(row[None, start:start + 8]), cache,
+            _, cache = paged(
+                params, jnp.asarray(row[None, start:start + 8]), cache,
                 jnp.asarray(table(descs[2], 8)[None]),
-                jnp.asarray([start], jnp.int32), compute_dtype=f)
+                jnp.asarray([start], jnp.int32))
             descs[2].seen_tokens = start + 8
         tables = np.zeros((4, state.table_width), np.int32)
         tables[0], tables[1] = table(descs[0], 1), table(descs[1], 1)
@@ -247,9 +253,8 @@ def test_a_mixed_call_is_its_two_segments(f32, one_device):
         tokens = np.zeros((1, 4 + 8), np.int32)
         tokens[0, 0], tokens[0, 1] = others[0][-1], others[1][-1]
         tokens[0, 4:9] = row[16:21]
-        got, _ = cohere2_moe.apply_paged(
-            cfg, params, jnp.asarray(tokens), cache, call, None,
-            valid=call.valid(12), compute_dtype=f)
+        got, _ = paged(params, jnp.asarray(tokens), cache, call, None,
+                       valid=call.valid(12))
     assert gap(got[0, 0], wants[0][-1]) < TOL
     assert gap(got[0, 1], wants[1][-1]) < TOL
     assert gap(got[0, 4:9], want[16:21]) < TOL

@@ -269,13 +269,8 @@ def _family_loss(mod, cfg, params, batch):
     return loss
 
 
-# llama (GQA) and falcon (MQA) ride the fast lane; the other families'
-# execution parity is slow-lane (the jaxpr lint below still traces all
-# five cheaply every run)
-@pytest.mark.parametrize(
-    "name", ["llama", "falcon"]
-    + [pytest.param(n, marks=pytest.mark.slow)
-       for n in ("gpt", "mixtral", "exaone4")])
+@pytest.mark.parametrize("name", ["llama", "falcon", "gpt", "mixtral",
+                                  "exaone4"])
 def test_family_loss_and_grads_match_gate_on(name):
     """Every family's training loss + grads are numerically unchanged by
     the native kernels (the narrow path computes the same attention)."""
@@ -740,7 +735,6 @@ def test_report_renders_gqa_and_spec_sections(tmp_path):
     assert "query/kv head ratio:   4x" in out2.stdout
 
 
-@pytest.mark.slow
 def test_bench_attn_probe_gqa_sweep():
     """detail.attn_probe's GQA sweep runs end-to-end on the CPU lane and
     measures the (nq/nkv)× KV-byte reduction with zero widening calls in

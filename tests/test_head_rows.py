@@ -13,6 +13,8 @@ token. ``rows=`` is part of the ``apply_paged`` contract (ISSUE 46): a
 caller's callable declares it.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,9 +39,13 @@ FAMILIES = {
     "llama": (llama, lambda: llama.LlamaConfig.tiny(max_seq_len=32), {}),
     "mixtral": (mixtral, lambda: mixtral.MixtralConfig.tiny(max_seq_len=32),
                 {}),
+    # the call and the tick, not the ten-layer period
+    # (tests/test_granite_hybrid.py keeps that): a run of each kind, repeated
     "granite_hybrid": (granite_hybrid,
                        lambda: granite_hybrid.GraniteHybridConfig.tiny(
-                           max_seq_len=32), {"slots": SLOTS}),
+                           max_seq_len=32,
+                           layer_types=("mamba", "attention") * 2),
+                       {"slots": SLOTS}),
     # a table of the full kind's width serves both kinds: nothing given back
     "cohere2_moe": (cohere2_moe,
                     lambda: cohere2_moe.Cohere2MoeConfig.tiny(max_seq_len=32),
@@ -60,13 +66,22 @@ def test_gather_rows_picks_each_sequences_own_rows():
     np.testing.assert_array_equal(got[1], x[1, [1, 2, 3]])
 
 
-def _forward(family):
+@functools.cache
+def _family(family):
+    """``(module, config, weights, an empty cache, forward)`` of a family,
+    once a module: ``forward`` is its ``apply_paged`` in float32 under ONE
+    ``jax.jit``, so a family's cases share its compiled shapes (a mixed
+    call's real rows are a value of the program)."""
     module, make, cache_kw = FAMILIES[family]
     cfg = make()
     params = module.init(cfg, jax.random.PRNGKey(0))
     cache = module.init_paged_cache(cfg, BLOCKS, BLOCK, dtype=F32, **cache_kw)
-    fwd = lambda *a, **kw: module.apply_paged(cfg, params, *a,
-                                              compute_dtype=F32, **kw)
+    return module, cfg, params, cache, jax.jit(functools.partial(
+        module.apply_paged, cfg, params, compute_dtype=F32))
+
+
+def _forward(family):
+    module, cfg, _, cache, fwd = _family(family)
     rng = np.random.default_rng(3)
     tok = lambda *shape: jnp.asarray(rng.integers(1, cfg.vocab_size, shape),
                                      jnp.int32)
@@ -158,9 +173,7 @@ def _scores_every_row(module):
 def _engines(family):
     """(the engine as ``build_engine_v2`` makes it, one of the same weights
     around a caller-supplied ``apply_paged`` that scores every row)."""
-    module, make, _ = FAMILIES[family]
-    cfg = make()
-    params = module.init(cfg, jax.random.PRNGKey(0))
+    module, cfg, params, *_ = _family(family)
     mesh_lib.set_mesh(None)
     one = build_engine_v2(module, cfg, params, config=CONFIG)
     two = InferenceEngineV2(

@@ -86,24 +86,27 @@ def program_logits(path, cfg, params, row, dtype=jnp.float32):
         return mixtral.apply(cfg, params, jnp.asarray(row[None]),
                              compute_dtype=dtype)[0][0]
     out = []
+    # a jit of this call's own: one compile a shape, not one a piece
     if path == "apply_cached":
+        cached = jax.jit(functools.partial(mixtral.apply_cached, cfg,
+                                           compute_dtype=dtype))
         cache = mixtral.init_cache(cfg, 1, 32, dtype=dtype)
         for start, piece in pieces(row):
-            logits, cache = mixtral.apply_cached(
-                cfg, params, jnp.asarray(piece[None]), cache,
-                jnp.asarray([start], jnp.int32), compute_dtype=dtype)
+            logits, cache = cached(params, jnp.asarray(piece[None]), cache,
+                                   jnp.asarray([start], jnp.int32))
             out.append(logits[0])
         return jnp.concatenate(out)
+    paged = jax.jit(functools.partial(mixtral.apply_paged, cfg,
+                                      compute_dtype=dtype))
     cache = mixtral.init_paged_cache(cfg, 16, BLOCK, dtype=dtype)
     table = jnp.asarray([[3, 1, 7, 2, 9, 4, 5, 0]], jnp.int32)  # 0: trash
     for start, piece in pieces(row):
         width = CHUNK if start < PROMPT else 1
         padded = np.zeros((1, width), np.int32)
         padded[0, :len(piece)] = piece
-        logits, cache = mixtral.apply_paged(
-            cfg, params, jnp.asarray(padded), cache, table,
-            jnp.asarray([start], jnp.int32),
-            valid=jnp.arange(width)[None] < len(piece), compute_dtype=dtype)
+        logits, cache = paged(params, jnp.asarray(padded), cache, table,
+                              jnp.asarray([start], jnp.int32),
+                              valid=jnp.arange(width)[None] < len(piece))
         out.append(logits[0, :len(piece)])
     return jnp.concatenate(out)
 
@@ -226,6 +229,8 @@ def test_a_mixed_call_is_its_two_segments(f32):
     rng = np.random.default_rng(5)
     others = [rng.integers(0, 256, n) for n in (13, 19)]
     wants = [reference.logits(hf, family.Weights(params), o) for o in others]
+    paged = jax.jit(functools.partial(mixtral.apply_paged, cfg,
+                                      compute_dtype=jnp.float32))
     cache = mixtral.init_paged_cache(cfg, 32, BLOCK, dtype=jnp.float32)
     tables = np.zeros((4, 8), np.int32)
     tables[0, :5], tables[1, :5], tables[2, :7] = (np.arange(1, 6),
@@ -235,15 +240,13 @@ def test_a_mixed_call_is_its_two_segments(f32):
         for i, o in enumerate(others):       # the decode rows' contexts
             pad = np.zeros((1, 24), np.int32)
             pad[0, :len(o) - 1] = o[:-1]
-            _, cache = mixtral.apply_paged(
-                cfg, params, jnp.asarray(pad), cache,
+            _, cache = paged(
+                params, jnp.asarray(pad), cache,
                 jnp.asarray(tables[i:i + 1]), jnp.zeros((1,), jnp.int32),
-                valid=jnp.arange(24)[None] < len(o) - 1,
-                compute_dtype=jnp.float32)
-        _, cache = mixtral.apply_paged(      # the chunk's first 16 tokens
-            cfg, params, jnp.asarray(row[None, :16]), cache,
-            jnp.asarray(tables[2:3]), jnp.zeros((1,), jnp.int32),
-            compute_dtype=jnp.float32)
+                valid=jnp.arange(24)[None] < len(o) - 1)
+        _, cache = paged(                    # the chunk's first 16 tokens
+            params, jnp.asarray(row[None, :16]), cache,
+            jnp.asarray(tables[2:3]), jnp.zeros((1,), jnp.int32))
         call = MixedCall(
             tables=jnp.asarray(tables), lens=jnp.asarray([12, 18, 0, 0]),
             active=jnp.asarray([True, True, False, False]),
@@ -252,9 +255,8 @@ def test_a_mixed_call_is_its_two_segments(f32):
         tokens = np.zeros((1, 4 + 8), np.int32)
         tokens[0, 0], tokens[0, 1] = others[0][-1], others[1][-1]
         tokens[0, 4:9] = row[16:21]
-        got, _ = mixtral.apply_paged(cfg, params, jnp.asarray(tokens), cache,
-                                     call, None, valid=call.valid(12),
-                                     compute_dtype=jnp.float32)
+        got, _ = paged(params, jnp.asarray(tokens), cache, call, None,
+                       valid=call.valid(12))
     assert gap(got[0, 0], wants[0][-1]) < TOL
     assert gap(got[0, 1], wants[1][-1]) < TOL
     assert gap(got[0, 4:9], want[16:21]) < TOL

@@ -12,6 +12,8 @@ the engine's ``step()`` against the UNSPLIT engine of the same weights
 token, and what its one span says.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,14 +35,31 @@ FAMILIES = {
                    {"kv_quant_group": 8}),
     "mixtral": (mixtral, lambda: mixtral.MixtralConfig.tiny(max_seq_len=32),
                 {}),
+    # the call and the tick, not the ten-layer period
+    # (tests/test_granite_hybrid.py keeps that): a run of each kind, repeated
     "granite_hybrid": (granite_hybrid,
                        lambda: granite_hybrid.GraniteHybridConfig.tiny(
-                           max_seq_len=32), {"slots": SLOTS}),
+                           max_seq_len=32,
+                           layer_types=("mamba", "attention") * 2),
+                       {"slots": SLOTS}),
     "gpt": (gpt, lambda: gpt.GPTConfig.tiny(max_seq_len=32), {}),
     "falcon": (falcon, lambda: falcon.FalconConfig.tiny(max_seq_len=32), {}),
     "exaone4": (exaone4, lambda: exaone4.Exaone4Config.tiny(max_seq_len=32),
                 {}),
 }
+
+
+@functools.cache
+def _forward(family):
+    """``(module, config, weights, forward)`` of a family, once a module:
+    ``forward`` is its ``apply_paged`` in float32 under ONE ``jax.jit``, so
+    the cases of a family (their contexts and real rows are values of the
+    program) share its four compiled shapes."""
+    module, make, _ = FAMILIES[family]
+    cfg = make()
+    params = module.init(cfg, jax.random.PRNGKey(0))
+    return module, cfg, params, jax.jit(functools.partial(
+        module.apply_paged, cfg, params, compute_dtype=F32))
 
 
 def _tables():
@@ -68,18 +87,15 @@ def test_a_mixed_call_is_the_chunk_then_the_decode(family, ctx, n_valid):
     chunk that is mostly padding is what a short prompt admitted beside a
     program in flight rides in (ISSUE 37): its padded rows write no block
     but the trash."""
-    module, make, cache_kw = FAMILIES[family]
-    cfg = make()
+    module, cfg, _, fwd = _forward(family)
+    cache_kw = FAMILIES[family][2]
     recurrent = "slots" in cache_kw
-    params = module.init(cfg, jax.random.PRNGKey(0))
     cache = module.init_paged_cache(cfg, BLOCKS, BLOCK, dtype=F32, **cache_kw)
     rng = np.random.default_rng(ctx * 16 + n_valid)
     tok = lambda *shape: jnp.asarray(rng.integers(1, cfg.vocab_size, shape),
                                      jnp.int32)
     tables = jnp.asarray(_tables())
     lens = jnp.asarray([5, 9, ctx, 0], jnp.int32)
-    fwd = lambda *a, **kw: module.apply_paged(cfg, params, *a,
-                                              compute_dtype=F32, **kw)
     # the contexts, by one prefill over the slots in order
     _, cache = fwd(tok(SLOTS, 12), cache, tables, jnp.zeros(SLOTS, jnp.int32),
                    valid=jnp.arange(12)[None] < lens[:, None])
@@ -130,8 +146,8 @@ def test_row_positions_of_both_kinds_of_call():
 def _engine(family, split=CHUNK, trace=False, **extra):
     """``split=0``: the UNSPLIT engine, whose prompts are one-shot
     ``prefill`` calls and whose steps are ``decode`` alone."""
-    module, make, cache_kw = FAMILIES[family]
-    cfg = make()
+    module, cfg, params, _ = _forward(family)
+    cache_kw = FAMILIES[family][2]
     mesh_lib.set_mesh(None)
     config = {"prefill_bucket": CHUNK, "split_prefill_chunk": split,
               "ragged": {"max_tracked_sequences": SLOTS,
@@ -143,9 +159,7 @@ def _engine(family, split=CHUNK, trace=False, **extra):
     if trace:
         config["trace"] = {"enabled": True}
     config.update(extra)
-    return build_engine_v2(module, cfg,
-                           module.init(cfg, jax.random.PRNGKey(0)),
-                           config=config)
+    return build_engine_v2(module, cfg, params, config=config)
 
 
 def _prompts():

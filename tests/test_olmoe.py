@@ -7,6 +7,7 @@ traces to the parent's jaxpr.
 """
 
 import dataclasses
+import functools
 import hashlib
 import re
 
@@ -66,24 +67,27 @@ def program_logits(path, cfg, params, row, dtype=jnp.float32):
         return mixtral.apply(cfg, params, jnp.asarray(row[None]),
                              compute_dtype=dtype)[0][0]
     out = []
+    # a jit of this call's own: one compile a shape, not one a piece
     if path == "apply_cached":
+        cached = jax.jit(functools.partial(mixtral.apply_cached, cfg,
+                                           compute_dtype=dtype))
         cache = mixtral.init_cache(cfg, 1, 32, dtype=dtype)
         for start, piece in pieces(row):
-            logits, cache = mixtral.apply_cached(
-                cfg, params, jnp.asarray(piece[None]), cache,
-                jnp.asarray([start], jnp.int32), compute_dtype=dtype)
+            logits, cache = cached(params, jnp.asarray(piece[None]), cache,
+                                   jnp.asarray([start], jnp.int32))
             out.append(logits[0])
         return jnp.concatenate(out)
+    paged = jax.jit(functools.partial(mixtral.apply_paged, cfg,
+                                      compute_dtype=dtype))
     cache = mixtral.init_paged_cache(cfg, 16, BLOCK, dtype=dtype)
     table = jnp.asarray([[3, 1, 7, 2, 9, 4, 5, 0]], jnp.int32)  # 0: trash
     for start, piece in pieces(row):
         width = CHUNK if start < PROMPT else 1
         padded = np.zeros((1, width), np.int32)
         padded[0, :len(piece)] = piece
-        logits, cache = mixtral.apply_paged(
-            cfg, params, jnp.asarray(padded), cache, table,
-            jnp.asarray([start], jnp.int32),
-            valid=jnp.arange(width)[None] < len(piece), compute_dtype=dtype)
+        logits, cache = paged(params, jnp.asarray(padded), cache, table,
+                              jnp.asarray([start], jnp.int32),
+                              valid=jnp.arange(width)[None] < len(piece))
         out.append(logits[0, :len(piece)])
     return jnp.concatenate(out)
 

@@ -119,7 +119,6 @@ def test_ring_zigzag_schedule_balance():
     assert (inv[perm] == np.arange(64)).all()
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("layout,overlap", [("contiguous", True),
                                             ("zigzag", False),
                                             ("zigzag", True)])
@@ -133,7 +132,6 @@ def test_ring_layouts_match_full(devices8, layout, overlap):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_ring_zigzag_falls_back_to_contiguous_when_inapplicable(devices8):
     """zigzag is a causal-schedule optimization: non-causal requests and
     shapes not divisible by 2P must route through the contiguous core and
@@ -152,7 +150,6 @@ def test_ring_zigzag_falls_back_to_contiguous_when_inapplicable(devices8):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 def test_ring_zigzag_gqa(devices8):
     init_mesh({"data": 2, "seq": 4})
     q, k, v = _qkv(s=32, h=8, kv_heads=2, seed=8)
@@ -163,7 +160,6 @@ def test_ring_zigzag_gqa(devices8):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("layout", ["contiguous", "zigzag"])
 def test_ring_overlap_grads_match_dense(devices8, layout):
     init_mesh({"data": 2, "seq": 4})
@@ -198,7 +194,6 @@ def test_ring_dense_fallback_marker(devices8):
     assert "Comm/ring/dense_fallback" in names
 
 
-@pytest.mark.slow
 def test_measure_ring_overlap_pipelined_vs_serialized(devices8):
     """The measured per-hop overlap fraction: pipelined must hide a nonzero
     share of the KV transfer under compute; serialized must hide none. The

@@ -6,6 +6,7 @@ their cached prefix, plus the park/resume engine seams, headroom
 accounting, the consistent unknown-uid error, and the Serving/sched|router
 telemetry surface."""
 
+import functools
 import math
 import os
 import subprocess
@@ -602,24 +603,35 @@ STOCHASTIC = SamplingParams(temperature=0.7, top_k=5, top_p=0.9)
 OVERLAP_FAMILIES = {
     "llama": lambda: (llama, llama.LlamaConfig.tiny(max_seq_len=64)),
     "mixtral": lambda: _family("mixtral", "MixtralConfig"),
-    "granite_hybrid": lambda: _family("granite_hybrid",
-                                      "GraniteHybridConfig"),
+    # these cases are about the tick, not the ten-layer period
+    # (tests/test_granite_hybrid.py keeps that): a run of each kind, repeated
+    "granite_hybrid": lambda: _family(
+        "granite_hybrid", "GraniteHybridConfig",
+        layer_types=("mamba", "attention") * 2),
 }
 
 
-def _family(name, config):
+def _family(name, config, **kw):
     import importlib
 
     module = importlib.import_module(f"deepspeed_tpu.models.{name}")
-    return module, getattr(module, config).tiny(max_seq_len=64)
+    return module, getattr(module, config).tiny(max_seq_len=64, **kw)
+
+
+@functools.cache
+def _weights(family):
+    """A family's module, configuration and seeded weights, once a module:
+    every engine of it reads them and no program donates them."""
+    module, cfg = OVERLAP_FAMILIES[family]()
+    return module, cfg, module.init(cfg, jax.random.PRNGKey(0))
 
 
 def _overlap_engine(family, **extra):
     """Four slots, chunks of 8: prompts over 8 tokens are split."""
-    module, cfg = OVERLAP_FAMILIES[family]()
+    module, cfg, params = _weights(family)
     mesh_lib.set_mesh(None)
     eng = build_engine_v2(
-        module, cfg, module.init(cfg, jax.random.PRNGKey(0)),
+        module, cfg, params,
         config=dict({"dtype": "float32", "prefill_bucket": 8,
                      "split_prefill_chunk": 8,
                      "ragged": {"max_tracked_sequences": 4,

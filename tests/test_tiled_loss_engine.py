@@ -53,7 +53,6 @@ def _lowered(e):
 # --------------------------------------------------------------------------- #
 # default-OFF pin: the knob must be invisible until asked for
 # --------------------------------------------------------------------------- #
-@pytest.mark.slow
 def test_tiled_loss_default_off_byte_identical(devices8):
     e_def = _mk_engine()                                   # no block at all
     e_off = _mk_engine({"tiled_loss": False})              # explicit off
@@ -87,7 +86,6 @@ def _family_spec(name):
     return mixtral.model_spec(cfg, compute_dtype=jnp.float32)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("family", ["llama", "gptneox", "mixtral"])
 def test_model_tiled_loss_fn_matches_dense(devices8, family):
     spec = _family_spec(family)
@@ -103,7 +101,6 @@ def test_model_tiled_loss_fn_matches_dense(devices8, family):
                                    rtol=1e-4, atol=1e-6)
 
 
-@pytest.mark.slow
 def test_tiled_loss_bias_head_parity():
     """The standalone head with a vocab bias (GPT-J lineage): value+grad
     must match the dense biased CE, including ignore_index masking."""
@@ -139,7 +136,6 @@ def test_tiled_loss_bias_head_parity():
 # --------------------------------------------------------------------------- #
 # memory pin: the tiled head never pays the [B, S, V] fp32 logits cliff
 # --------------------------------------------------------------------------- #
-@pytest.mark.slow
 def test_tiled_loss_compiled_peak_beats_dense(devices8):
     """The FPDT-pin convention on the loss head: compiled peak temp of
     grad(dense CE) carries the S×V fp32 logits (plus its cotangent) while

@@ -81,6 +81,10 @@ def test_to_universal_cli(tmp_path, devices8):
     assert (tmp_path / "t1" / "universal").exists()
 
 
+# slow: two subprocesses that train, checkpoint and serve a toy model from
+# the shipped example scripts (60-80 s of start-up and compiles); no cell and
+# no safety property runs `examples/`, and what they call is held elsewhere
+@pytest.mark.slow
 def test_examples_run(tmp_path):
     """The shipped examples execute end-to-end on CPU (the switching-user
     smoke: train a few steps + checkpoint, then serve)."""

@@ -691,7 +691,6 @@ def _lowered(e):
         return e._train_step.lower(e.state, sb, e._lr_override).as_text()
 
 
-@pytest.mark.slow
 def test_train_default_off_byte_identical(devices8):
     """Default-OFF pin: no ``tuning`` block, an explicitly-disabled block,
     and the pre-tuning build all lower the SAME train step — and no tuner
