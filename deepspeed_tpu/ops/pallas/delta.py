@@ -13,11 +13,11 @@
     leaves (``o``) as rows. Its floor is one read and one write of the live
     rows' state.
 
-``delta_chunk``  many tokens of a row: the state read through the row table
-    (``state_rows_read``), the chunked form of ``ops/delta.py`` in XLA - a
-    batch of ``[tile, tile]`` triangular systems and a ``lax.scan`` of tiles
-    over the state -, and the state written back where it lies
-    (``state_rows_write``): no XLA gather or scatter meets the pool.
+``delta_chunk``  many tokens of a row: ONE kernel (``delta_chunk.py``) reads
+    the row's state through the row table, carries it in VMEM through the
+    row's tiles and hands it back for one ``state_rows_write``; a shape it
+    does not tile takes ``delta_chunk_between_rows``: ``state_rows_read``,
+    the chunked form of ``ops/delta.py`` in XLA, ``state_rows_write``.
 
 Loaded by the family that has such layers (``models/solar_open2.py``), not
 by ``ops/pallas/__init__``: no other program pays for its import.
@@ -132,11 +132,17 @@ def delta_decode_update(pool, layer, rows, fresh, q, k, v, log_a, beta):
     return pool, o.reshape(b, H, dv)
 
 
-def delta_chunk(pool, layer, rows, fresh, q, k, v, log_a, beta,
-                tile: Optional[int] = None):
-    """``t`` tokens of ``b`` rows on their rows of the state pool
-    (``ops/delta.delta_chunk_xla`` is the contract), the pool met by the
-    row-table kernels alone."""
+from . import delta_chunk as _tiles  # noqa: E402 (below the decode kernel:
+#                 a line of it moved is a compile-cache miss of its program)
+
+
+def delta_chunk_between_rows(pool, layer, rows, fresh, q, k, v, log_a, beta,
+                             tile: Optional[int] = None):
+    """:func:`delta_chunk` as the XLA form between the row-table kernels:
+    the state read (``state_rows_read``), ``ops/delta.delta_chunked`` in
+    tiles of ``tile``, the state written back (``state_rows_write``). What
+    a shape the Mosaic kernel does not tile runs, and the twin
+    ``scripts/delta_kernel_bench.py`` times beside it."""
     _float32_state(pool)
     H, dk = k.shape[2:]
     part = (0, dk, pool.shape[3])
@@ -146,6 +152,26 @@ def delta_chunk(pool, layer, rows, fresh, q, k, v, log_a, beta,
         jnp.where(fresh[:, None, None, None], 0.0, S0), tile)
     return state_rows_write(pool, layer, rows, _delta.state_from_heads(S),
                             part), o
+
+
+def delta_chunk(pool, layer, rows, fresh, q, k, v, log_a, beta,
+                tile: Optional[int] = None):
+    """``t`` tokens of ``b`` rows on their rows of the state pool
+    (``ops/delta.delta_chunk_xla`` is the contract): ONE Mosaic kernel over
+    the row table (``ops/pallas/delta_chunk.py``) and one write of the
+    rows' new state, where the kernel tiles the call's shapes
+    (``delta_chunk.takes``: heads of whole 128-lane tiles - the published
+    128 x 128); any other shape takes :func:`delta_chunk_between_rows`,
+    whose tile ``tile`` is (the kernel's is its own)."""
+    _float32_state(pool)
+    H, dk = k.shape[2:]
+    if not _tiles.takes(dk, v.shape[-1], H, k.shape[1], pool.dtype):
+        return delta_chunk_between_rows(pool, layer, rows, fresh, q, k, v,
+                                        log_a, beta, tile)
+    o, new = _tiles.delta_chunk_tiled(pool, layer, rows, fresh, q, k, v,
+                                      log_a, beta)
+    return state_rows_write(pool, layer, rows, new,
+                            (0, dk, pool.shape[3])), o
 
 
 register("delta_decode_update", backend="pallas")(delta_decode_update)

@@ -1672,8 +1672,10 @@ def test_delta_state_kv_blocks_and_experts_stay_where_they_are(v5e, program):
     to-result with no copy of their shape, the expert banks are read where
     they lie (next to no temporaries), the program with its 9.45 GB of
     weights fits the chip with room for a probe's reference, and a KDA layer
-    body is ONE Mosaic call of the state update by its literal name, the
-    chunked form between a read and a write of the row."""
+    body is ONE Mosaic call of the state update by its literal name and ONE
+    of the chunk's delta rule by its own (``delta_chunk``: it reads the
+    row's state where it lies, so no read of the rows stands before it, and
+    one write of them after it)."""
     import re
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
@@ -1701,7 +1703,8 @@ def test_delta_state_kv_blocks_and_experts_stay_where_they_are(v5e, program):
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     decode, chunk = program != "chunk", program != "decode"
     assert calls.count("delta_decode_update") == int(decode)
-    # the tail's rows a segment, and the state's around the chunked form
-    assert calls.count("state_rows_read") == decode + 2 * chunk
+    assert calls.count("delta_chunk") == int(chunk)
+    # the tail's rows a segment, and the chunk's new state after its kernel
+    assert calls.count("state_rows_read") == decode + chunk
     assert calls.count("state_rows_write") == decode + 2 * chunk
     assert calls.count("moe_grouped_matmul") >= 2

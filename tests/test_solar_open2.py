@@ -37,6 +37,7 @@ from deepspeed_tpu.models import solar_open2 as so
 from deepspeed_tpu.models._paged import MixedCall
 from deepspeed_tpu.ops import delta
 from deepspeed_tpu.ops.pallas import delta as kernels
+from deepspeed_tpu.ops.pallas import delta_chunk as tiles
 
 TOL = 2e-4
 ENGINE = {"dtype": "float32", "prefill_bucket": 8, "split_prefill_chunk": 16,
@@ -323,9 +324,17 @@ def test_the_chunk_op_over_the_row_table_is_the_chunked_form(t, tile):
             pool, 0, rows, fresh, *ops, tile=tile)
         got_pool, got = jax.jit(kernels.delta_chunk, static_argnames="tile")(
             pool, 0, rows, fresh, *ops, tile=tile)
+        # heads of 16 lanes are no shape the Mosaic kernel tiles: the op is
+        # the XLA form between the row-table kernels, number for number
+        assert not tiles.takes(16, 16, 4, t, pool.dtype)
+        old_pool, old = jax.jit(kernels.delta_chunk_between_rows,
+                                static_argnames="tile")(
+            pool, 0, rows, fresh, *ops, tile=tile)
         S0 = jnp.where(fresh[:, None, None, None], 0.0,
                        delta.state_to_heads(pool[0, rows, :16], 4))
         o, S1 = delta.delta_recurrence(*ops, S0)
+    np.testing.assert_array_equal(got, old)
+    np.testing.assert_array_equal(got_pool, old_pool)
     assert float(jnp.abs(got - o).max()) < 1e-5
     assert float(jnp.abs(got - want).max()) < 1e-6
     assert float(jnp.abs(delta.state_to_heads(got_pool[0, rows, :16], 4)
@@ -336,19 +345,76 @@ def test_the_chunk_op_over_the_row_table_is_the_chunked_form(t, tile):
     assert float(jnp.abs(want_pool - got_pool).max()) < 1e-6
 
 
-def test_rows_aimed_at_the_trash_row_read_nothing_of_it():
-    pool = _pool().at[:, 3].set(jnp.nan)          # the trash row poisoned
-    ops = _operands(2, 8, H=4, dk=16, dv=16)
+WIDE = dict(H=2, dk=128, dv=128)    # heads the chunk's Mosaic kernel tiles
+
+
+@pytest.mark.parametrize("t", [256, 300, 40, 1])
+def test_the_interpreted_chunk_kernel_is_the_recurrence_under_strong_decay(t):
+    """At heads of whole 128-lane tiles ``delta_chunk`` is ONE Mosaic kernel
+    over the row table (interpreted here) and one write of the rows: whole
+    tiles of ``delta_chunk.TOKENS``, a ragged last tile, less than a tile
+    and one token, under ``log a`` of -3 to -6 a token a channel (the plain
+    ``exp(-g)`` is past float32 by the 30th token), a live row beside a
+    fresh one whose old state must not be read - against the XLA form and
+    the recurrence a token at a time; the other layer, the other rows and
+    the tail's sublanes stay bit-equal."""
+    pool = _pool(**WIDE)
+    ops = _operands(2, t, strength=6.0, seed=t, **WIDE)
+    rows = jnp.asarray([2, 0], jnp.int32)
+    fresh = jnp.asarray([False, True])
+    assert tiles.takes(128, 128, 2, t, pool.dtype)
+    with jax.default_matmul_precision("highest"):
+        want_pool, want = jax.jit(delta.delta_chunk_xla)(
+            pool, 0, rows, fresh, *ops)
+        op = jax.jit(kernels.delta_chunk)
+        got_pool, got = op(pool, 0, rows, fresh, *ops)
+        S0 = jnp.where(fresh[:, None, None, None], 0.0,
+                       delta.state_to_heads(pool[0, rows, :128], 2))
+        o, S1 = delta.delta_recurrence(*ops, S0)
+        program = str(jax.make_jaxpr(kernels.delta_chunk)(
+            pool, 0, rows, fresh, *ops))
+    # the kernel by its literal name, the rows' write after it, and no read
+    # of the rows before it
+    assert program.count("name=delta_chunk") == 1
+    assert program.count("name=state_rows_write") == 1
+    assert "name=state_rows_read" not in program
+    assert got.shape == o.shape == (2, t, 2, 128)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(o).max()) > 0.01
+    assert float(jnp.abs(got - o).max()) < 1e-5
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(delta.state_to_heads(got_pool[0, rows, :128], 2)
+                         - S1).max()) < 1e-5
+    np.testing.assert_array_equal(got_pool[1], pool[1])
+    np.testing.assert_array_equal(got_pool[0, 1], pool[0, 1])
+    np.testing.assert_array_equal(got_pool[0, 3], pool[0, 3])
+    np.testing.assert_array_equal(got_pool[0, :, 128:], pool[0, :, 128:])
+    assert float(jnp.abs(want_pool - got_pool).max()) < 1e-5
+    if t >= 256:
+        g = jnp.cumsum(ops[3], axis=1)
+        assert not bool(jnp.isfinite(jnp.exp(-g)).all())
+
+
+@pytest.mark.parametrize("op", ["decode", "chunk"])
+def test_rows_aimed_at_the_trash_row_read_nothing_of_it(op):
+    if op == "decode":
+        pool, ops = _pool(), _operands(2, 8, H=4, dk=16, dv=16)
+        call = lambda *a: kernels.delta_decode_update(
+            *a, *(x[:, 0] for x in ops))
+    else:       # the Mosaic kernel of a multi-token segment
+        pool, ops = _pool(**WIDE), _operands(2, 40, strength=2.0, **WIDE)
+        call = lambda *a: kernels.delta_chunk(*a, *ops)
+    pool = pool.at[:, 3].set(jnp.nan)          # the trash row poisoned
     rows, fresh = jnp.asarray([3, 3], jnp.int32), jnp.asarray([False, False])
-    got_pool, got = kernels.delta_decode_update(
-        pool, 0, rows, fresh, *(a[:, 0] for a in ops))
+    got_pool, got = call(pool, 0, rows, fresh)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(got_pool[:, :3], pool[:, :3])
 
 
-def test_the_kernels_refuse_a_state_that_is_not_float32_by_name():
-    pool = _pool().astype(jnp.bfloat16)
-    ops = _operands(1, 8, H=4, dk=16, dv=16)
+@pytest.mark.parametrize("d", [16, 128])
+def test_the_kernels_refuse_a_state_that_is_not_float32_by_name(d):
+    pool = _pool(H=4, dk=d, dv=d).astype(jnp.bfloat16)
+    ops = _operands(1, 8, H=4, dk=d, dv=d)
     rows, fresh = jnp.asarray([0], jnp.int32), jnp.asarray([False])
     with pytest.raises(NotImplementedError, match="float32 state"):
         kernels.delta_chunk(pool, 0, rows, fresh, *ops)

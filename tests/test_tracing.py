@@ -1161,13 +1161,20 @@ def _kernel_cases():
                 q[:, :, :2, 0], tile=8)[1],
             [jnp.ones((2, 3, 40, 192), f32), rows,
              jnp.ones((2, 12, 4, 16), f32)]),
-        # a delta-rule layer's one (ops/pallas/delta.py, ISSUE 57; its
-        # chunked form meets the pool through the row-table kernels above):
+        # a delta-rule layer's single-token one (ops/pallas/delta.py, ISSUE
+        # 57):
         # a pool of two slots at four heads of 16 x 16 over a tail
         "delta_decode_update": (
             lambda p, r, q: delta.delta_decode_update(
                 p, 1, r, r == 0, q, q, q, -q, q[:, :, 0])[1],
             [jnp.ones((2, 3, 32, 64), f32), rows, jnp.ones((2, 4, 16), f32)]),
+        # and its multi-token kernel (ops/pallas/delta_chunk.py, ISSUE 60),
+        # at heads it tiles: two of 128 x 128, four tokens a row
+        "delta_chunk": (
+            lambda p, r, q: delta.delta_chunk(
+                p, 1, r, r == 0, q, q, q, -q, q[..., 0])[1],
+            [jnp.ones((2, 3, 144, 256), f32), rows,
+             jnp.ones((2, 4, 2, 128), f32)]),
         "paged_index_write": (
             lambda k, p, bt, n: ps.paged_index_write(k, p, bt, n, n, layer=0),
             [jnp.ones((2, 3, 64), f32), ipool, tables, lens]),
@@ -1207,7 +1214,7 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "paged_sparse_select", "paged_sparse_decode",
                 "paged_sparse_prefill", "moe_grouped_matmul",
                 "retention_decode_update", "retention_chunk",
-                "delta_decode_update"]
+                "delta_decode_update", "delta_chunk"]
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
