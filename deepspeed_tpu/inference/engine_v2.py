@@ -691,6 +691,17 @@ class InferenceEngineV2(InferenceEngine):
                 (ends - np.maximum(firsts - window + 1, 0)).sum())
         return out
 
+    def _past_window_args(self, live) -> Dict[str, int]:
+        """``rows_past_window`` of a ``decode_step`` in a family with window
+        layers: the decode rows whose context is longer than the window -
+        the rows for which a window layer reads less than a full layer, so
+        whether the call really mixes the two regimes."""
+        if not self._window:
+            return {}
+        window = min(self._window.values())
+        return {"rows_past_window":
+                sum(d.seen_tokens + 1 > window for d in live)}
+
     def _table(self, desc, n: int) -> np.ndarray:
         """``desc``'s block table for a call that writes its next ``n``
         tokens: every kind covers them first (a prompt's blocks of the full
@@ -1700,7 +1711,7 @@ class InferenceEngineV2(InferenceEngine):
                 **self._sparse_args([d.seen_tokens + 1 for d in live]),
                 **self._kv_kind_args([d.seen_tokens for d in live],
                                      [1] * len(live)),
-                **chunk_args) as span:
+                **self._past_window_args(live), **chunk_args) as span:
             with self.tracer.span("engine_prep", cat="serving"):
                 self._reserve(live, repeat(1))
                 extra = self._attn_tile_args(live)

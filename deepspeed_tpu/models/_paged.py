@@ -75,6 +75,7 @@ a TPU alone.
 
 from __future__ import annotations
 
+import itertools
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -376,6 +377,177 @@ def scan_nest(layer_types, layers, x, pools, blocks):
     with jax.named_scope("kv_write"):   # as scan_layers names its scan
         return lax.scan(period, (x, pools),
                         jnp.arange(periods, dtype=jnp.int32))[0]
+
+
+# --------------------------------------------------------------------------- #
+# window and full attention layers in ONE stack of one shape of layer
+# (models/cohere2_moe.py, models/mellum.py): what the two families share
+# --------------------------------------------------------------------------- #
+# layer type -> the kind of KV state it keeps (the cache leaves' suffix)
+KINDS = {"full_attention": "full", "sliding_attention": "window"}
+
+
+def stack_layer_types(layer_types, num_layers: int) -> Tuple[str, ...]:
+    """``layer_types`` for every layer of a stack of window and full layers:
+    the tuple as given where it names them all, one period of it repeated
+    otherwise. Refused: a type :data:`KINDS` does not have, and a stack with
+    no full layer (it has no full kind of KV state)."""
+    types = tuple(layer_types)
+    if num_layers % len(types):
+        raise ValueError(f"{num_layers} layers are no whole number "
+                         f"of the {len(types)}-layer pattern")
+    types = types * (num_layers // len(types))
+    unknown = set(types) - set(KINDS)
+    if unknown:
+        raise ValueError(f"layer_types names {sorted(unknown)}; this family "
+                         f"has {sorted(KINDS)}")
+    if "full_attention" not in types:
+        raise ValueError("a stack of window layers alone has no full kind "
+                         "of KV state: this family wants one full layer a "
+                         "period at least")
+    return types
+
+
+def stack_plan(types):
+    """``(periods, period, runs, layers of each type a period)`` of a stack
+    whose layers all have ONE shape (:func:`scan_stack`): the smallest
+    period the pattern repeats with and that period's runs of one type as
+    ``(type, first layer of the run inside the period, first layer of the
+    type inside the period, count)``."""
+    n = len(types)
+    period = next(p for p in range(1, n + 1) if n % p == 0 and all(
+        types[i] == types[i % p] for i in range(n)))
+    runs, seen, at = [], {t: 0 for t in KINDS}, 0
+    for kind, group in itertools.groupby(types[:period]):
+        count = len(list(group))
+        runs.append((kind, at, seen[kind], count))
+        seen[kind] += count
+        at += count
+    return n // period, period, runs, seen
+
+
+def scan_stack(types, x, layers, pools, blocks):
+    """A stack of window and full layers as ``types`` spells it, its layers
+    of one shape and STACKED together (:func:`scan_nest` is the pattern; it
+    takes a stack a kind): an outer scan over the pattern's periods whose
+    body scans each run of one type. A layer takes its weights by its index
+    into the stacked ``layers`` (what a scan's per-step slice of its inputs
+    is), so no period's slab is cut out on the way. ``blocks[type](x,
+    weights, pools, layer index, index among its type) -> (x, pools, aux)``;
+    ``pools`` (None without a cache) is the carry of every scan, beside
+    ``x`` and the summed aux loss."""
+    periods, period, runs, per_period = stack_plan(types)
+
+    def run(kind, carry, p, at, first, count):
+        def step(carry, i):
+            index = p * period + at + i
+            w = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                a, index, 0, keepdims=False), layers)
+            x, pools, aux = carry
+            x, pools, more = blocks[kind](
+                x, w, pools, index, p * per_period[kind] + first + i)
+            return (x, pools, aux + more), None
+
+        return lax.scan(step, carry, jnp.arange(count, dtype=jnp.int32))[0]
+
+    def one_period(carry, p):
+        for kind, at, first, count in runs:
+            carry = run(kind, carry, p, at, first, count)
+        return carry, None
+
+    with jax.named_scope("kv_write"):   # as scan_layers names its scan
+        x, pools, aux = lax.scan(
+            one_period, (x, pools, jnp.zeros((), jnp.float32)),
+            jnp.arange(periods, dtype=jnp.int32))[0]
+    return x, pools, aux
+
+
+def stack_window_kinds(types, sliding_window: int) -> Dict[str, int]:
+    """The kinds of KV state beside the full one, each with its window: what
+    the serving engine sizes a pool and an allocator for
+    (``inference.engine.ModelFamily.window_kinds``)."""
+    return {"window": sliding_window} if "sliding_attention" in types else {}
+
+
+def init_stack_pools(types, sliding_window: int, num_blocks: int,
+                     window_blocks: Optional[Dict[str, int]],
+                     num_kv_heads: int, block_size: int, head_size: int,
+                     dtype=jnp.bfloat16):
+    """The full layers' pools, ``k`` / ``v`` ``[L_full, num_blocks, ...]``,
+    and the window layers', ``k_window`` / ``v_window`` ``[L_window,
+    window_blocks["window"], ...]`` (the engine sizes them:
+    ``inference.ragged.WindowKind.sized``; as many as the full kind's where
+    no one says). No quantized-KV mode."""
+    blocks = {"full": num_blocks,
+              **{kind: (window_blocks or {}).get(kind, num_blocks)
+                 for kind in stack_window_kinds(types, sliding_window)}}
+    layers = {kind: types.count(layer_type)
+              for layer_type, kind in KINDS.items() if kind in blocks}
+    return init_kind_pools(layers, blocks, num_kv_heads, block_size,
+                           head_size, dtype)
+
+
+def paged_kind_attention(cache, block_tables, context_lens, valid,
+                         max_seq_len: int, windows: Dict[str, int]):
+    """``attend(kind, q, k, v, pools, i) -> (mix, pools)``: layer ``i`` of
+    ``kind``'s step over the kind's own pools (``k`` / ``v`` or ``k_<kind>``
+    / ``v_<kind>`` of ``pools``, the scan's carry), through the kind's own
+    table and lengths (:func:`kind_tables`) and, for a window kind, under
+    its window (``windows``: ``stack_window_kinds``), in the scope
+    ``attn_<kind>``."""
+    block_size = cache["k"].shape[-2]
+    # (the engine's width of the full kind's table: ``engine_v2``)
+    full_width = max(2, -(-max_seq_len // block_size))
+    parts = kind_tables(block_tables, context_lens, full_width, block_size)
+    tables = {"full": parts[0], **dict.fromkeys(windows, parts[-1])}
+
+    def attend(kind, q, k, v, pools, i):
+        suffix = "" if kind == "full" else "_" + kind
+        names = ("k" + suffix, "v" + suffix)
+        with jax.named_scope("attn_" + kind):
+            mix, k_c, v_c = paged_attention_step(
+                q, k, v, *(LayerPool(pools[n], None, i) for n in names),
+                *tables[kind], None, valid, window=windows.get(kind))
+        return mix, {**pools, names[0]: k_c.pool, names[1]: v_c.pool}
+
+    return attend
+
+
+def dense_kind_attention(cache, cache_len, positions):
+    """The v1 engine's dense cache under the same stack: every layer keeps
+    the whole context, a window layer masks what lies behind its window.
+    ``of(kind, window)`` makes a kind's mask (once a program) and returns
+    its ``attend(q, k, v, pools, index) -> (mix, pools)``, ``pools`` the
+    ``{"k", "v"}`` ``[L, b, max_len, nkv, hd]`` cache and ``index`` the
+    layer's among ALL layers."""
+    from ..ops.attention import attention
+
+    kv_pos = jnp.arange(cache["k"].shape[2])[None, None, None, :]
+    q_abs = positions[:, None, :, None]
+
+    def write(pool, index, new):
+        def one(c, n, s):
+            return lax.dynamic_update_slice(c, n.astype(c.dtype), (s, 0, 0))
+
+        layer = jax.vmap(one)(lax.dynamic_index_in_dim(
+            pool, index, 0, keepdims=False), new, cache_len)
+        return lax.dynamic_update_index_in_dim(pool, layer, index, 0), layer
+
+    def of(kind, window):
+        mask = kv_pos <= q_abs
+        if window is not None:
+            mask = mask & (q_abs - kv_pos < window)
+
+        def attend(q, k, v, pools, index):
+            k_pool, k_c = write(pools["k"], index, k)
+            v_pool, v_c = write(pools["v"], index, v)
+            with jax.named_scope("attn_" + kind):
+                mix = attention(q, k_c, v_c, causal=False, mask=mask)
+            return mix, {"k": k_pool, "v": v_pool}
+
+        return attend
+
+    return of
 
 
 def paged_attention_step(q, k, v, k_cache, v_cache, block_tables,

@@ -35,29 +35,25 @@ of an expert-parallel deployment, as ``models/mixtral.py`` has it.
 
 from __future__ import annotations
 
-import itertools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..moe.layer import MoELayer, init_moe_ffn, moe_ffn_logical_axes
 from ..ops.attention import attention
 from ..ops.embedding import embedding_lookup
 from ..ops.norms import layer_norm
 from ..ops.rotary import apply_rotary_interleaved, rope_frequencies
-from ._paged import (LayerPool, gather_rows, init_kind_pools, kind_tables,
-                     paged_attention_step, row_positions)
+from ._paged import (KINDS, dense_kind_attention, gather_rows,
+                     init_stack_pools, paged_kind_attention, row_positions,
+                     scan_stack, stack_layer_types, stack_window_kinds)
 from .mixtral import _bank_apart
 from .mixtral import moe_rows  # noqa: F401  (the same shape facts: the
 #                                engine reads them off the family's module)
 
 Params = Dict[str, Any]
-
-# layer type -> the kind of KV state it keeps (the cache leaves' suffix)
-KINDS = {"full_attention": "full", "sliding_attention": "window"}
 
 
 @dataclass(frozen=True)
@@ -94,13 +90,8 @@ class Cohere2MoeConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     def resolved_layer_types(self) -> Tuple[str, ...]:
-        """``layer_types`` for every layer: the tuple as given where it
-        names them all, one period of it repeated otherwise."""
-        types = tuple(self.layer_types)
-        if self.num_layers % len(types):
-            raise ValueError(f"{self.num_layers} layers are no whole number "
-                             f"of the {len(types)}-layer pattern")
-        return types * (self.num_layers // len(types))
+        """``layer_types`` for every layer (``_paged.stack_layer_types``)."""
+        return stack_layer_types(self.layer_types, self.num_layers)
 
     def count(self, layer_type: str) -> int:
         return self.resolved_layer_types().count(layer_type)
@@ -115,49 +106,16 @@ class Cohere2MoeConfig:
         return cls(**base)
 
 
-def _check(cfg: Cohere2MoeConfig) -> None:
-    types = cfg.resolved_layer_types()
-    unknown = set(types) - set(KINDS)
-    if unknown:
-        raise ValueError(f"layer_types names {sorted(unknown)}; this family "
-                         f"has {sorted(KINDS)}")
-    if "full_attention" not in types:
-        raise ValueError("a stack of window layers alone has no full kind "
-                         "of KV state: this family wants one full layer a "
-                         "period at least")
-
-
-def layer_plan(cfg: Cohere2MoeConfig):
-    """``(periods, runs, layers of each type a period)``: the smallest
-    period the pattern repeats with and that period's runs of one type as
-    ``(type, first layer of the run inside the period, first layer of the
-    type inside the period, count)``."""
-    types = cfg.resolved_layer_types()
-    n = len(types)
-    period = next(p for p in range(1, n + 1) if n % p == 0 and all(
-        types[i] == types[i % p] for i in range(n)))
-    runs, seen, at = [], {t: 0 for t in KINDS}, 0
-    for kind, group in itertools.groupby(types[:period]):
-        count = len(list(group))
-        runs.append((kind, at, seen[kind], count))
-        seen[kind] += count
-        at += count
-    return n // period, period, runs, seen
-
-
 def window_kinds(cfg: Cohere2MoeConfig) -> Dict[str, int]:
-    """The kinds of KV state beside the full one, each with its window: what
-    the serving engine sizes a pool and an allocator for
-    (``inference.engine.ModelFamily.window_kinds``)."""
-    return {"window": cfg.sliding_window} \
-        if cfg.count("sliding_attention") else {}
+    """``ModelFamily.window_kinds``: ``{"window": sliding_window}``."""
+    return stack_window_kinds(cfg.resolved_layer_types(), cfg.sliding_window)
 
 
 # --------------------------------------------------------------------------- #
 # parameters
 # --------------------------------------------------------------------------- #
 def init(cfg: Cohere2MoeConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
-    _check(cfg)
+    cfg.resolved_layer_types()      # refuses a pattern the family has not
     h, hd = cfg.hidden_size, cfg.head_size
     L, nh, nkv = cfg.num_layers, cfg.num_heads, cfg.num_kv_heads
     si = cfg.num_shared_experts * cfg.intermediate_size
@@ -261,40 +219,6 @@ def _block(cfg, x, w, bank, index, moe_layer, attend):
     return x + a + m, pools, aux
 
 
-def _scan_nest(cfg, x, layers, pools, blocks):
-    """The stack as ``layer_types`` spells it: an outer scan over the
-    pattern's periods whose body scans each run of one type. A layer takes
-    its weights by its index into the stacked ``layers`` (what a scan's
-    per-step slice of its inputs is), so no period's slab is cut out on the
-    way. ``blocks[type](x, weights, pools, layer index, index among its
-    type) -> (x, pools, aux)``; ``pools`` (None without a cache) is the
-    carry of every scan, beside ``x`` and the summed aux loss."""
-    periods, period, runs, per_period = layer_plan(cfg)
-
-    def run(kind, carry, p, at, first, count):
-        def step(carry, i):
-            index = p * period + at + i
-            w = jax.tree.map(lambda a: lax.dynamic_index_in_dim(
-                a, index, 0, keepdims=False), layers)
-            x, pools, aux = carry
-            x, pools, more = blocks[kind](
-                x, w, pools, index, p * per_period[kind] + first + i)
-            return (x, pools, aux + more), None
-
-        return lax.scan(step, carry, jnp.arange(count, dtype=jnp.int32))[0]
-
-    def one_period(carry, p):
-        for kind, at, first, count in runs:
-            carry = run(kind, carry, p, at, first, count)
-        return carry, None
-
-    with jax.named_scope("kv_write"):   # as _paged.scan_layers names its scan
-        x, pools, aux = lax.scan(
-            one_period, (x, pools, jnp.zeros((), jnp.float32)),
-            jnp.arange(periods, dtype=jnp.int32))[0]
-    return x, pools, aux
-
-
 def _compute_layers(params, compute_dtype, moe_layer=None):
     """``(layers, bank)``: the stacked layers in the compute type and, where
     ``moe_layer``'s calls take the grouped form, the expert banks apart and
@@ -339,7 +263,6 @@ def apply(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray, *,
     """Whole sequences with no cache → (logits [b, s, vocab] fp32, total
     aux loss); with ``return_hidden`` → (scaled normed hidden, unembed
     matrix, total aux loss)."""
-    _check(cfg)
     cos, sin = _rope(cfg)
     moe_layer = _moe(cfg, cfg.drop_tokens)
     layers, _ = _compute_layers(params, compute_dtype)
@@ -353,8 +276,9 @@ def apply(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray, *,
         return lambda x, w, _pools, index, _i: _block(
             cfg, x, w, {}, index, moe_layer, lambda h: attend(h, w))
 
-    x, _, aux = _scan_nest(
-        cfg, _embed(params, tokens, compute_dtype), layers, None,
+    x, _, aux = scan_stack(
+        cfg.resolved_layer_types(), _embed(params, tokens, compute_dtype),
+        layers, None,
         {"sliding_attention": block("sliding_attention", cfg.sliding_window),
          "full_attention": block("full_attention", None)})
     if return_hidden:
@@ -407,7 +331,6 @@ def cache_logical_axes(cfg: Cohere2MoeConfig) -> Params:
 def apply_cached(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
                  cache: Params, cache_len: jnp.ndarray, *,
                  compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Params]:
-    _check(cfg)
     if cache_len.ndim == 0:
         cache_len = jnp.broadcast_to(cache_len, (tokens.shape[0],))
     b, t = tokens.shape
@@ -415,36 +338,18 @@ def apply_cached(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
     positions = cache_len[:, None] + jnp.arange(t)[None, :]
     moe_layer = _moe(cfg, False)
     layers, bank = _compute_layers(params, compute_dtype, moe_layer)
-    kv_pos = jnp.arange(cache["k"].shape[2])[None, None, None, :]
-    q_abs = positions[:, None, :, None]
-
-    def write(pool, index, new):
-        def one(c, n, s):
-            return lax.dynamic_update_slice(c, n.astype(c.dtype), (s, 0, 0))
-
-        layer = jax.vmap(one)(lax.dynamic_index_in_dim(
-            pool, index, 0, keepdims=False), new, cache_len)
-        return lax.dynamic_update_index_in_dim(pool, layer, index, 0), layer
+    dense = dense_kind_attention(cache, cache_len, positions)
 
     def block(layer_type, window):
-        mask = kv_pos <= q_abs
-        if window is not None:
-            mask = mask & (q_abs - kv_pos < window)
-
-        def attend(h, w, pools, index):
-            q, k, v = _qkv(cfg, w, h, layer_type, cos, sin, positions)
-            k_pool, k_c = write(pools["k"], index, k)
-            v_pool, v_c = write(pools["v"], index, v)
-            with jax.named_scope("attn_" + KINDS[layer_type]):
-                mix = attention(q, k_c, v_c, causal=False, mask=mask)
-            return mix, {"k": k_pool, "v": v_pool}
-
+        attend = dense(KINDS[layer_type], window)
         return lambda x, w, pools, index, _i: _block(
-            cfg, x, w, bank, index, moe_layer,
-            lambda h: attend(h, w, pools, index))
+            cfg, x, w, bank, index, moe_layer, lambda h: attend(
+                *_qkv(cfg, w, h, layer_type, cos, sin, positions), pools,
+                index))
 
-    x, cache, _ = _scan_nest(
-        cfg, _embed(params, tokens, compute_dtype), layers, dict(cache),
+    x, cache, _ = scan_stack(
+        cfg.resolved_layer_types(), _embed(params, tokens, compute_dtype),
+        layers, dict(cache),
         {"sliding_attention": block("sliding_attention", cfg.sliding_window),
          "full_attention": block("full_attention", None)})
     return _head(cfg, params, x, compute_dtype), cache
@@ -463,14 +368,9 @@ def init_paged_cache(cfg: Cohere2MoeConfig, num_blocks: int,
     window_blocks["window"], ...]`` (the engine sizes them:
     ``inference.ragged.WindowKind.sized``; as many as the full kind's where
     no one says). No quantized-KV mode."""
-    _check(cfg)
-    blocks = {"full": num_blocks,
-              **{kind: (window_blocks or {}).get(kind, num_blocks)
-                 for kind in window_kinds(cfg)}}
-    layers = {kind: cfg.count(layer_type)
-              for layer_type, kind in KINDS.items() if kind in blocks}
-    return init_kind_pools(layers, blocks, cfg.num_kv_heads, block_size,
-                           cfg.head_size, dtype)
+    return init_stack_pools(cfg.resolved_layer_types(), cfg.sliding_window,
+                            num_blocks, window_blocks, cfg.num_kv_heads,
+                            block_size, cfg.head_size, dtype)
 
 
 def apply_paged(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
@@ -488,7 +388,6 @@ def apply_paged(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
     from it - a cache that gives nothing back). A layer writes and reads its
     own kind's pool through its own kind's table, a window layer at context
     lengths counted from its sequence's first live block."""
-    _check(cfg)
     b, t = tokens.shape
     if valid is None:
         valid = jnp.ones((b, t), bool)
@@ -496,31 +395,17 @@ def apply_paged(cfg: Cohere2MoeConfig, params: Params, tokens: jnp.ndarray,
     positions = row_positions(block_tables, context_lens, t)
     moe_layer = _moe(cfg, False)
     layers, bank = _compute_layers(params, compute_dtype, moe_layer)
-    kinds = window_kinds(cfg)
-    block_size = cache["k"].shape[-2]
-    # (the engine's width of the full kind's table: ``engine_v2``)
-    full_width = max(2, -(-cfg.max_seq_len // block_size))
-    parts = kind_tables(block_tables, context_lens, full_width, block_size)
-    tables = {"full": parts[0], **dict.fromkeys(kinds, parts[-1])}
+    attend = paged_kind_attention(cache, block_tables, context_lens, valid,
+                                  cfg.max_seq_len, window_kinds(cfg))
 
     def block(layer_type):
-        kind = KINDS[layer_type]
-        suffix = "" if kind == "full" else "_" + kind
-        names = ("k" + suffix, "v" + suffix)
-
-        def attend(h, w, pools, i):
-            q, k, v = _qkv(cfg, w, h, layer_type, cos, sin, positions)
-            with jax.named_scope("attn_" + kind):
-                mix, k_c, v_c = paged_attention_step(
-                    q, k, v, *(LayerPool(pools[n], None, i) for n in names),
-                    *tables[kind], positions, valid, window=kinds.get(kind))
-            return mix, {**pools, names[0]: k_c.pool, names[1]: v_c.pool}
-
         return lambda x, w, pools, index, i: _block(
-            cfg, x, w, bank, index, moe_layer,
-            lambda h: attend(h, w, pools, i))
+            cfg, x, w, bank, index, moe_layer, lambda h: attend(
+                KINDS[layer_type], *_qkv(cfg, w, h, layer_type, cos, sin,
+                                         positions), pools, i))
 
-    x, cache, _ = _scan_nest(
-        cfg, _embed(params, tokens, compute_dtype), layers, dict(cache),
+    x, cache, _ = scan_stack(
+        cfg.resolved_layer_types(), _embed(params, tokens, compute_dtype),
+        layers, dict(cache),
         {layer_type: block(layer_type) for layer_type in KINDS})
     return _head(cfg, params, gather_rows(x, rows), compute_dtype), cache

@@ -60,9 +60,12 @@ def yarn_frequencies(head_dim: int, max_len: int, theta: float,
                      beta_fast: float = 32.0, beta_slow: float = 1.0,
                      table_scale: float = 1.0, dtype=jnp.float32):
     """cos/sin tables ``[max_len, head_dim / 2]`` at YaRN's frequencies
-    (:func:`yarn_inv_frequencies`), times ``table_scale`` (the published
-    ``mscale / mscale_all_dim`` quotient). Made once, as
-    :func:`rope_frequencies`' are."""
+    (:func:`yarn_inv_frequencies`), BOTH times ``table_scale``: YaRN's
+    attention temperature in either of the forms it is published in - the
+    ``mscale / mscale_all_dim`` quotient of two :func:`yarn_mscale` (A.X-K1's
+    latent heads) or an ``attention_factor`` given outright for a plain
+    head (Mellum 2's full layers: 0.1 ln(factor) + 1, so the scores carry
+    its square). Made once, as :func:`rope_frequencies`' are."""
     inv_freq = jnp.asarray(yarn_inv_frequencies(
         head_dim, theta, factor, original_max_len, beta_fast, beta_slow))
     freqs = jnp.outer(jnp.arange(max_len, dtype=jnp.float32), inv_freq)

@@ -388,7 +388,12 @@ TRACER_SPANS = frozenset((
     # state-space families (models/granite_hybrid.py ``state_rows``) say
     # ``ssm_chunk_rows`` on ``decode_step`` and ``prefill_chunk``: the
     # chunk's token rows ONE Mamba layer took through the Mosaic scan
-    # ``ssm_chunk_scan``, 0 where the op ran its XLA form; no metric reads it
+    # ``ssm_chunk_scan``, 0 where the op ran its XLA form; no metric reads it.
+    # A family with window KINDS of KV state (models/cohere2_moe.py,
+    # models/mellum.py) says ``kv_tokens_full`` / ``kv_tokens_window`` on
+    # ``decode_step`` and ``prefill_chunk`` and, on ``decode_step`` alone,
+    # ``rows_past_window``: the decode rows whose context is longer than the
+    # window (the benchmark's ``decode_rows_past_window`` reads it)
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

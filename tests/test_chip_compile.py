@@ -625,13 +625,13 @@ def _cell_program(kind, cell_name, *how):
     """``(function, arguments)`` of one of a serve cell's programs, the cache
     its second argument: ``"pool"`` (``_pool_program``: then the program's
     name and whether the pools are int8), ``"mixed"`` (``_mixed_program``;
-    Command A+'s own) or ``"greedy_mixed"`` (the mixed call inside the
+    a two-kind cell's own) or ``"greedy_mixed"`` (the mixed call inside the
     engine's sampling, ``_greedy_mixed_step``: then whether it reads its
     rows)."""
     if kind == "pool":
         return _pool_program(cell_name, *how)
-    forward, args = _command_a_mixed_program() \
-        if cell_name == COMMAND_A_CELL else _mixed_program(cell_name)
+    forward, args = _two_kind_mixed_program(cell_name) \
+        if cell_name in TWO_KIND_CELLS else _mixed_program(cell_name)
     if kind == "greedy_mixed":
         forward = _greedy_mixed_step(forward, *how)
     return forward, args
@@ -696,10 +696,17 @@ def test_the_learned_selections_mixed_program_keeps_three_pools_in_place(
 
 
 COMMAND_A_CELL = "command-a-plus-05-2026.serve-longctx"
+MELLUM_CELL = "mellum2-12b-a2.5b-instruct.serve-mixedlen-32"
+# a cell of window AND full layers: (the window kind's blocks a sequence and
+# in all; each kind's (layers, blocks))
+TWO_KIND_CELLS = {
+    COMMAND_A_CELL: ((145, 2321), {"": (1, 12544), "_window": (3, 2321)}),
+    MELLUM_CELL: ((49, 1569), {"": (2, 10240), "_window": (6, 1569)}),
+}
 
 
-def _command_a_mixed_program():
-    """The Command A+ cell's forward of a mixed call (16 decode rows + a
+def _two_kind_mixed_program(cell_name):
+    """A two-kind cell's forward of a mixed call (its slots' decode rows + a
     512-row chunk) on shapes, at its real configuration and both kinds'
     pool geometry: ``(forward(params, cache, tokens, call, valid,
     rows=None), arguments)`` with the cache second."""
@@ -707,24 +714,25 @@ def _command_a_mixed_program():
     from deepspeed_tpu.inference.ragged import WindowKind
     from deepspeed_tpu.models._paged import MixedCall
 
-    cell = Cell(COMMAND_A_CELL)
+    cell = Cell(cell_name)
     engine = cell.role["engine"]
     ragged = engine["ragged"]
     slots, bs = ragged["max_tracked_sequences"], ragged["block_size"]
     chunk = engine["split_prefill_chunk"]
     cfg = cell.family.build_cfg(cell.model, **cell.role["program_options"])
     module = cell.family.module()
-    params = jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16),
-        jax.eval_shape(lambda k: module.init(cfg, k), jax.random.PRNGKey(0)))
+    params = jax.eval_shape(    # as served: bf16, a float32 router apart
+        lambda k: module.init(cfg, k, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
     kind = WindowKind.sized("window", cfg.sliding_window, slots, chunk, bs)
-    assert (kind.blocks_per_seq, kind.num_blocks) == (145, 2321)
+    per_seq, pools = TWO_KIND_CELLS[cell_name]
+    assert (kind.blocks_per_seq, kind.num_blocks) == per_seq
     cache = jax.eval_shape(lambda: module.init_paged_cache(
         cfg, ragged["memory_config_blocks"], bs,
         window_blocks={"window": kind.num_blocks}))
     assert {k: v.shape[:2] for k, v in cache.items()} == {
-        "k": (1, 12544), "v": (1, 12544),
-        "k_window": (3, 2321), "v_window": (3, 2321)}
+        name + suffix: shape for suffix, shape in pools.items()
+        for name in "kv"}
     table = cfg.max_seq_len // bs + 1 + kind.blocks_per_seq
     i32, s = jnp.int32, jax.ShapeDtypeStruct
     call = MixedCall(s((slots, table), i32), s((slots,), i32),
@@ -740,26 +748,32 @@ def _command_a_mixed_program():
                      s((1, rows), bool))
 
 
+@pytest.mark.parametrize("cell", sorted(TWO_KIND_CELLS))
 def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(
-        compiled_for_v5e):
-    """The Command A+ cell's mixed call (16 decode rows + a 512-row chunk) at
-    its real configuration, compiled for the chip: the full kind's pools AND
-    the window kind's stay where they are (no pool-shaped copy, every pool
-    aliased argument-to-result), a period's body is two writes and two walks
-    a kind - the window layers' scan and the full layer's -, and the whole
-    program with its 11.35 GB of weights and 2.56 GB of pools fits the
-    chip."""
+        compiled_for_v5e, cell):
+    """A two-kind cell's mixed call (its decode rows + a 512-row chunk) at
+    its real configuration, compiled for the chip - Command A+'s (a parallel
+    block, group 16, a window of 4096, 16 of 128 experts held) and
+    Mellum 2's (a sequential block, group 8, a window of 1024 = two chunks,
+    a rope table a kind, all 64 experts of width 896): the full kind's pools
+    AND the window kind's stay where they are (no pool-shaped copy, every
+    pool aliased argument-to-result), a period's body is two writes and two
+    walks a kind - the window layers' scan and the full layer's -, and the
+    whole program with its weights and pools fits the chip."""
     import math
     import re
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
 
-    compiled, args = compiled_for_v5e("mixed", COMMAND_A_CELL)
+    compiled, args = compiled_for_v5e("mixed", cell)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     pools = jax.tree.leaves(args[1])
     assert pool_copy_bytes(text, pools) == 0
     pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
-    assert pool_bytes == (12544 + 3 * 2321) * 32 * 4096
+    kv_heads = pools[0].shape[2]
+    assert pool_bytes == sum(
+        layers * blocks for layers, blocks in TWO_KIND_CELLS[cell][1].values()
+    ) * 32 * 2 * kv_heads * 128 * 2
     assert mem.alias_size_in_bytes >= pool_bytes
     assert 0 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(

@@ -27,6 +27,7 @@ from deepspeed_tpu.inference.engine_v2 import build_engine_v2
 from deepspeed_tpu.inference.serving import (Request, SchedulerConfig,
                                              ServingScheduler)
 from deepspeed_tpu.models import cohere2_moe
+from deepspeed_tpu.models import _paged
 from deepspeed_tpu.models._paged import MixedCall
 from deepspeed_tpu.moe.sharded_moe import top_k_gating, top_k_gating_compact
 
@@ -404,4 +405,4 @@ def test_the_configuration_file_is_the_catalogs_with_the_cut_laid_over():
     assert cfg.resolved_layer_types() == ("sliding_attention",) * 3 \
         + ("full_attention",)
     assert cohere2_moe.window_kinds(cfg) == {"window": 4096}
-    assert cohere2_moe.layer_plan(cfg)[:2] == (1, 4)
+    assert _paged.stack_plan(cfg.resolved_layer_types())[:2] == (1, 4)
