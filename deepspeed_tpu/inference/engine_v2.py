@@ -1017,14 +1017,14 @@ class InferenceEngineV2(InferenceEngine):
 
     def _chunk_tile_args(self, ch: _Chunk) -> Dict[str, int]:
         """Span arguments of a chunk's ``paged_prefill`` walk, host integers
-        the call already holds: the grid steps that hold context its rows
-        attend, the steps the grid takes and the steps a grid as wide as the
-        block table would take (``chunk_attn_tiles_live`` / ``_grid`` /
-        ``_table``; ``ops/pallas/paged_attention.py prefill_tile_counts``) -
-        of ONE layer's call; in a family with window kinds of one call a
-        kind, each times the kind's layers, summed - and the KV tokens of a
-        grid step of the walk, ``chunk_attn_kv_tile`` (the widest of the
-        kinds': 256 where no walk of the launch took the wide tile). None
+        the call already holds: the KV tiles that hold context its rows
+        attend, the tiles the walk takes (the same ones where it fetches its
+        own pages) and the steps a grid as wide as the block table would
+        take (``chunk_attn_tiles_live`` / ``_grid`` / ``_table``;
+        ``ops/pallas/paged_attention.py prefill_tile_counts``) - of ONE
+        layer's call; in a family with window kinds of one call a kind, each
+        times the kind's layers, summed - and the KV tokens of a step of the
+        walk, ``chunk_attn_kv_tile`` (the widest of the kinds'). None
         for a family whose chunk takes another walk (a learned selection) or
         whose paged cache is not ``init_paged_pools``' (a latent pool is:
         one KV head, every query head in its group)."""

@@ -820,7 +820,7 @@ class StateManager:
         position ``seen_tokens`` can read: that row's window starts at
         ``seen_tokens - window + 1``, and every later row's starts later
         (the kernels' own bound: ``ops/pallas/paged_attention.py``
-        ``_table_walk`` ``lo_pg``)."""
+        ``_live_page`` / ``_prefill_kernel`` ``lo_pg``)."""
         return max(0, seen_tokens - kind.window + 1) // self.block_size
 
     @staticmethod

@@ -16,9 +16,9 @@ Reference parity: the inference v2 ragged kernels
 gather-based XLA path (``models/llama.py apply_paged``) that materializes a
 dense [B, max_blocks*bs, ...] KV view per layer; the kernels read KV blocks
 straight out of the shared pool via the block table (scalar-prefetch:
-page DMAs the decode walk issues itself, table-indexed ``BlockSpec``s in the
-multi-token walk), online-softmax accumulating — no dense copy, HBM traffic
-= exactly the live context. The gathered expressions survive as the ops' XLA
+page DMAs both walks issue themselves, table-indexed ``BlockSpec``s where
+Mosaic cannot slice a page), online-softmax accumulating — no dense copy,
+HBM traffic = exactly the live context. The gathered expressions survive as the ops' XLA
 references (``*_xla``), which the registry resolves to off a TPU.
 
 Decode layout: one query token per sequence.
@@ -62,28 +62,44 @@ row's threshold, two more prefetched scalars, and the tile's slice of its
 index scores, one more DMA a tile - exist in the call only where a caller
 hands them (:func:`_page_walk`).
 ``paged_prefill`` shares the flash body (:func:`_flash_update`) at ``tq``
-query tokens a tile and one KV head a step (:func:`_paged_kernel`), on a grid
-``(B, KV heads, query tiles, KV tiles)`` of table-indexed ``BlockSpec``
-pages, joined in VMEM, whose last dimension is DYNAMIC: the tiles up to the
-longest sequence's last REAL row, ``clip(ceil(
-max(context_lens + lengths) / KV tile), 1, table tiles)``, computed in the
-program from the call's own operands. The bound cuts the END of the walk
+query tokens a tile and one KV head a step, and where the pools' rows are
+whole lane tiles (:func:`_fetches_pages`, the decode walk's rule) it
+FETCHES ITS OWN PAGES too (:func:`_prefill_kernel`): grid ``(B, KV heads,
+query tiles)``, sequential, the pools where they lie, and a grid step is one
+(sequence, KV head, query tile)'s WHOLE walk - an in-kernel loop with a
+dynamic trip count from the tile of its first row's window (tile 0 without
+one) to the tile of its last REAL row, one DMA a live page a pool into a
+double-buffered ``[2, tile tokens, hd]`` scratch, the next tile (this
+walk's, or the next grid step's first) in flight while this one is
+computed. No step is dead and none is folded; a query tile of padding
+fetches and computes nothing and writes zeros. Its KV tile has ONE width,
+the wide one (up to 1024 tokens: :func:`_wide_pages`, from the shapes and a
+VMEM budget), whatever the walk's length: what a step pays whatever its
+width - the flash rescale and the row reductions of its ``[rows, 128]``
+scratch, 3.5 us at 1 024 rows - is most of a 256-key step, and a page that
+is not fetched costs nothing (PERF.md section 6, PR 62). int8 pools and
+heads under 128 lanes keep the walk that went before (:func:`_paged_kernel`,
+:func:`_table_walk`; ``paged_sparse_attention.py``'s masked prefill walk is
+built from the same parts): a grid ``(B, KV heads, query tiles, KV tiles)``
+of table-indexed ``BlockSpec`` pages, joined in VMEM, whose last dimension
+is DYNAMIC: the tiles up to the longest sequence's last REAL row, ``clip(
+ceil(max(context_lens + lengths) / KV tile), 1, table tiles)``, computed in
+the program from the call's own operands. The bound cuts the END of the walk
 alone: inside it an early query tile's steps above its last row, a window's
-steps below its first and a shorter sequence's fold as before, and a call
-of zero-length dummies still takes the one step that initialises and writes
-it. The multi-token walk's KV tile has TWO widths, and the program picks:
-~256 tokens where the walk is short, up to 1024 (:func:`_wide_pages`, from
-the shapes and a VMEM budget) where that same bound reaches two wide tiles
-(:func:`_takes_wide`; a ``lax.cond`` over two calls of the one walk, both
-named ``paged_prefill``) - a step's fixed work, the flash rescale of its
-``[rows, 128]`` scratch, is most of a 256-key step and a third of a
-1024-key one, and at few query rows one mostly dead wide step costs a short
-walk more than its one or two narrow ones. A context that is a constant of
-the program bounds the walk statically, and a walk that cannot be long is
-built narrow alone. :func:`decode_tile_counts` and
-:func:`prefill_tile_counts` say on the host, from the same tile sizes and
-the same rule (:func:`prefill_kv_pages`), how many steps a call takes and
-how many hold context.
+steps below its first and a shorter sequence's fold onto a live page, and a
+call of zero-length dummies still takes the one step that initialises and
+writes it. That grid has TWO tile widths, and the program picks: ~256 tokens
+where the walk is short, the wide tile where the same bound reaches two of
+them (:func:`_takes_wide`; a ``lax.cond`` over two calls of the one walk,
+both named ``paged_prefill``) - every page of a step is an operand the
+pipeline pays for (~57 ns), dead or live, so at few query rows one mostly
+dead wide step costs a short walk more than its one or two narrow ones. A
+context that is a constant of the program bounds the walk statically, and a
+walk that cannot be long is built narrow alone. :func:`decode_tile_counts`
+and :func:`prefill_tile_counts` say on the host, from the same tile sizes
+and the same rules (:func:`prefill_kv_pages`), how many tiles a call takes
+and how many hold context - the same tiles where a walk fetches its own
+pages.
 
 Quantized KV mode (``inference.kv_quant``, docs/serving.md "Quantized KV
 cache"): ``k_pool``/``v_pool`` hold int8 codes and ``k_scale``/``v_scale``
@@ -223,11 +239,12 @@ def _latent_values(keys, value_width):
 
 # --------------------------------------------------------------------------- #
 # the flash walks over the block table: ``paged_prefill`` at ``tq`` query
-# tokens a tile and one KV head a grid step over ``BlockSpec`` pages
-# (:func:`_table_walk`), ``paged_decode`` at one query token and every KV
-# head of a sequence a grid step over pages it fetches itself
-# (:func:`_decode_kernel`). Tile sizes come from the shapes alone
-# (:func:`_prefill_tiles`, :func:`_decode_tiles`).
+# tokens a tile and one KV head a grid step, ``paged_decode`` at one query
+# token and every KV head of a sequence a grid step, each over pages it
+# fetches itself (:func:`_prefill_kernel`, :func:`_decode_kernel`) or, where
+# Mosaic cannot slice a page, over ``BlockSpec`` pages (:func:`_table_walk`).
+# Tile sizes come from the shapes alone (:func:`_prefill_tiles`,
+# :func:`_wide_pages`, :func:`_decode_tiles`).
 # --------------------------------------------------------------------------- #
 _Q_ROWS = 1024      # query rows (GQA group x tokens) of one tile at hd <= 128
 _KV_TOKENS = 256    # KV tokens of one grid step: the matmul N, the softmax lanes
@@ -256,39 +273,43 @@ def _prefill_tiles(t: int, g: int, hd: int, bs: int,
 
 def _wide_pages(rows: int, hd: int, bs: int, max_blocks: int, narrow: int,
                 itemsize: int, quant: bool, pools: int = 2) -> int:
-    """Pages of the KV tile a LONG multi-token walk takes, ``narrow`` (the
-    ~256 tokens of :func:`_prefill_tiles`) where there is no wider one. A
-    grid step's FIXED work does not depend on its tile's width - the flash
-    rescale of the ``[rows, 128]`` m / l scratch and the ``[rows, hd]`` f32
-    accumulator, the pages' DMA issue - and at 256 keys it is most of a live
-    step (PERF.md section 6, PRs 38 and 48), so a walk of
-    ``_WIDE_WALK_TILES`` wide tiles or more takes up to ``_WIDE_KV_TOKENS``
-    keys a step: the widest doubling of ``narrow`` whose step fits
-    ``_WIDE_VMEM`` - the K and V (or one latent) tiles double-buffered, the
-    ``[rows, KV]`` f32 scores and probabilities, the q and output blocks
-    double-buffered and the m / l / accumulator scratch - and of which the
-    table holds a long walk. A table that holds none keeps the narrow tile
-    alone (and the program it had), and so do int8 pools: a layer's f32
-    scale pools reach each kernel lane-padded (PERF.md section 7), and a
-    second walk would keep a second padded copy of both. From shapes alone:
-    WHICH tile a call takes is the program's to decide, from its walk's
-    bound (:func:`_takes_wide`)."""
+    """Pages of the WIDE KV tile of a multi-token walk, ``narrow`` (the ~256
+    tokens of :func:`_prefill_tiles`) where there is no wider one. What a
+    step pays whatever its tile's width - the flash rescale of the ``[rows,
+    128]`` m / l scratch and the ``[rows, hd]`` f32 accumulator, the row
+    reductions - is most of a 256-key step at 1 024 rows (PERF.md section 6,
+    PRs 38, 48 and 62), so a walk takes up to ``_WIDE_KV_TOKENS`` keys a
+    step: the widest doubling of ``narrow`` whose step fits ``_WIDE_VMEM`` -
+    the K and V (or one latent) tiles double-buffered, the ``[rows, KV]``
+    f32 scores and probabilities, the q and output blocks double-buffered
+    and the m / l / accumulator scratch - and that the table holds: ONE of,
+    where the walk fetches its own pages (:func:`_fetches_pages`; it takes
+    the wide tile whatever its length - a page it does not fetch costs it
+    nothing), a LONG walk of (``_WIDE_WALK_TILES``) on the grid of
+    ``BlockSpec`` pages, where which tile a call takes is the program's to
+    decide from its walk's bound (:func:`_takes_wide`) and a table that
+    holds no long walk keeps the narrow tile alone (and the program it
+    had). So do int8 pools: a layer's f32 scale pools reach each kernel
+    lane-padded (PERF.md section 7), and a second walk would keep a second
+    padded copy of both. From shapes alone."""
     def step(kv):
         return (2 * pools * kv * hd * itemsize + 2 * rows * kv * 4
                 + 4 * rows * hd * 2 + rows * (256 + hd) * 4)
 
+    tiles = 1 if _fetches_pages(hd, quant) else _WIDE_WALK_TILES
     pages = narrow
     while not quant and 2 * pages * bs <= _WIDE_KV_TOKENS \
-            and _WIDE_WALK_TILES * 2 * pages <= max_blocks \
+            and tiles * 2 * pages <= max_blocks \
             and step(2 * pages * bs) <= _WIDE_VMEM:
         pages *= 2
     return pages
 
 
 def _takes_wide(bound, wide: int, bs: int):
-    """Whether a multi-token walk whose longest sequence's last real row sits
-    at ``bound`` (``max(context_lens + lengths)``: the program's value, or
-    the host's integer) takes the wide tile of ``wide`` pages."""
+    """Whether a walk over the grid of ``BlockSpec`` pages whose longest
+    sequence's last real row sits at ``bound`` (``max(context_lens +
+    lengths)``: the program's value, or the host's integer) takes the wide
+    tile of ``wide`` pages."""
     return bound >= _WIDE_WALK_TILES * wide * bs
 
 
@@ -298,12 +319,12 @@ def _group_rows(g: int) -> int:
 
 
 def _fetches_pages(hd: int, quant: bool) -> bool:
-    """Whether the decode walk fetches its pools' pages itself. Mosaic
-    slices a page out of a pool for a DMA only where the pool's rows are
-    whole 128-lane tiles: not out of an int8 pool's ``[.., bs, ngroups]``
-    f32 scales, nor out of plain pools of heads narrower than a tile (both
-    reach a kernel with their rows padded to 128 lanes). Those keep the
-    grid of ``BlockSpec`` pages."""
+    """Whether a walk (``paged_decode``'s, ``paged_prefill``'s) fetches its
+    pools' pages itself. Mosaic slices a page out of a pool for a DMA only
+    where the pool's rows are whole 128-lane tiles: not out of an int8
+    pool's ``[.., bs, ngroups]`` f32 scales, nor out of plain pools of heads
+    narrower than a tile (both reach a kernel with their rows padded to 128
+    lanes). Those keep the grid of ``BlockSpec`` pages."""
     return not quant and hd % 128 == 0
 
 
@@ -365,29 +386,33 @@ def prefill_kv_pages(context_lens, lengths, t: int, nh: int, pool_shape,
                      max_blocks: int, itemsize: int = 2, quant: bool = False,
                      pools: int = 2) -> int:
     """Pages of the KV tile ONE ``paged_prefill`` call takes (host integers,
-    the rule of the program: the wide tile where the longest sequence's last
-    real row reaches :func:`_takes_wide`'s bound). Times the pool's block
-    size it is the ``chunk_attn_kv_tile`` of a chunk's span."""
+    the rule of the program: the wide tile where the walk fetches its own
+    pages, and on the grid of ``BlockSpec`` pages where the longest
+    sequence's last real row reaches :func:`_takes_wide`'s bound). Times the
+    pool's block size it is the ``chunk_attn_kv_tile`` of a chunk's span."""
     nkv, bs, hd = pool_shape[-3:]
     tq, _, narrow = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
     wide = _wide_pages(nh // nkv * tq, hd, bs, max_blocks, narrow, itemsize,
                        quant, pools)
     bound = int((np.asarray(context_lens) + np.asarray(lengths)).max())
-    return wide if wide > narrow and _takes_wide(bound, wide, bs) else narrow
+    return wide if _fetches_pages(hd, quant) or (
+        wide > narrow and _takes_wide(bound, wide, bs)) else narrow
 
 
 def prefill_tile_counts(context_lens, lengths, t: int, nh: int, pool_shape,
                         max_blocks: int, window=None, itemsize: int = 2,
                         quant: bool = False,
                         pools: int = 2) -> Tuple[int, int, int]:
-    """(live, taken, table-wide) grid steps of ONE ``paged_prefill`` call of
+    """(live, taken, table-wide) KV tiles of ONE ``paged_prefill`` call of
     ``t`` rows a sequence, ``lengths`` of them real, at ``context_lens``
     (host integers; ``window``: the layer's, an int or None), at the KV tile
-    the call takes (:func:`prefill_kv_pages`): the steps whose KV tile a
-    real row of their query tile attends, the steps the grid takes - every
-    (sequence, KV head, query tile) walks as far as the longest sequence's
-    last real row - and the steps of a grid as wide as the table. What the
-    serving engine puts on a chunk's span."""
+    the call takes (:func:`prefill_kv_pages`): the tiles a real row of
+    their query tile attends, the tiles the walk takes - the SAME tiles
+    where it fetches its own pages (:func:`_fetches_pages`: each (sequence,
+    KV head, query tile) walks from its own first tile to its own last); on
+    the grid of ``BlockSpec`` pages every one of them walks as far as the
+    longest sequence's last real row - and the steps of a grid as wide as
+    the table. What the serving engine puts on a chunk's span."""
     nkv, bs, hd = pool_shape[-3:]
     tq, n_qt, _ = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
     pages = prefill_kv_pages(context_lens, lengths, t, nh, pool_shape,
@@ -402,7 +427,9 @@ def prefill_tile_counts(context_lens, lengths, t: int, nh: int, pool_shape,
         live &= j * kv + kv - 1 > ctx + q_lo - window
     n_live = min(max(-(-int((ctx + n).max()) // kv), 1), n_kv)
     walks = ctx.size * nkv * n_qt
-    return int(live.sum()) * nkv, walks * n_live, walks * n_kv
+    live = int(live.sum()) * nkv
+    return (live, live if _fetches_pages(hd, quant) else walks * n_live,
+            walks * n_kv)
 
 
 def _kv_tile(page_refs, scale_refs, dtype):
@@ -411,6 +438,25 @@ def _kv_tile(page_refs, scale_refs, dtype):
     tiles = [r[...] if s is None else _dequant_tile(r, s, dtype)
              for r, s in zip(page_refs, scale_refs)]
     return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=-2)
+
+
+def _chunk_scores(q, k, j, kv, ctx, n, q_lo, tq, wnd_ref, scale):
+    """Masked f32 scores ``[.., rows, kv]`` of a query tile against KV tile
+    ``j``. Rows are g-major/t-minor inside the tile: row r is query token
+    ``q_lo + r % tq`` at absolute position ``ctx + q_lo + r % tq``; it
+    attends itself, nothing past the sequence's ``n`` real rows, and under
+    a window (``wnd_ref``, None: none) its last ``wnd_ref[0]`` positions."""
+    s = _mxu_dot(q, k, _contract(q.ndim, -1),
+                 preferred_element_type=jnp.float32) * scale
+    rows = s.shape[-2]
+    pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
+    q_abs = ctx + q_lo + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tq)
+    valid = jnp.logical_and(pos <= q_abs,   # row attends itself too
+                            pos < ctx + n)
+    if wnd_ref is not None:
+        valid = jnp.logical_and(valid, pos > q_abs - wnd_ref[0])
+    return jnp.where(valid, s, NEG_INF)
 
 
 def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant,
@@ -433,10 +479,9 @@ def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant,
 
     _flash_init(j, m_scr, l_scr, acc_scr)
 
-    # rows are g-major/t-minor inside the tile: row r is query token
-    # q_lo + r % tq at absolute position ctx + q_lo + r % tq. The tile's
-    # live range ends at its last REAL row (padded rows and zero-length
-    # dummy sequences extend nothing) and starts at its first row's window.
+    # the tile's live range ends at its last REAL row (padded rows and
+    # zero-length dummy sequences extend nothing) and starts at its first
+    # row's window
     ctx, n = ctx_ref[b], len_ref[b]
     q_lo = qi * tq
     live = jnp.logical_and(q_lo < n,
@@ -451,17 +496,7 @@ def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant,
         k = _kv_tile(k_refs, ks_refs, q.dtype)   # [.., kv, hd]
         v = _kv_tile(v_refs, vs_refs, q.dtype) if vd is None \
             else k[..., :vd]
-        s = _mxu_dot(q, k, _contract(q.ndim, -1),
-                     preferred_element_type=jnp.float32) * scale
-        rows = s.shape[-2]
-        pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
-        q_abs = ctx + q_lo + jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), tq)
-        valid = jnp.logical_and(pos <= q_abs,   # row attends itself too
-                                pos < ctx + n)
-        if has_window:
-            valid = jnp.logical_and(valid, pos > q_abs - wnd_ref[0])
-        s = jnp.where(valid, s, NEG_INF)
+        s = _chunk_scores(q, k, j, kv, ctx, n, q_lo, tq, wnd_ref, scale)
         _flash_update(s, v, m_scr, l_scr, acc_scr)
 
     _flash_finish(j == (pl.num_programs(3) if n_kv is None else n_kv) - 1,
@@ -894,8 +929,10 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     ``window``/``k_scale``/``v_scale``/``layer``/``value_width`` as in
     :func:`paged_decode_attention`.
     Returns ``[B, t, nh, hd]`` (``value_width`` for ``hd`` over a latent
-    pool). The walk: :func:`_table_walk`, one KV head a
-    grid step, query tiles of ``g * tq`` rows."""
+    pool). The walk: one KV head a grid step, query tiles of ``g * tq``
+    rows, over pages it fetches itself (:func:`_own_pages_walk`) or, for
+    int8 pools and heads under 128 lanes, over ``BlockSpec`` pages
+    (:func:`_table_walk`)."""
     B, t, nh, hd = q.shape
     assert (k_scale is None) == (v_scale is None), \
         "k_scale and v_scale must be given together"
@@ -919,7 +956,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     bound = jnp.max(context_lens + lengths)
 
     def walk(pages):
-        # the walk ends with the last tile any sequence's real rows reach (at
+        # the grid ends with the last tile any sequence's real rows reach (at
         # least one step: a call of dummies alone still initialises and
         # writes)
         n_live = jnp.clip(-(-bound // (pages * bs)), 1,
@@ -938,19 +975,31 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             name="paged_prefill",
         )(*args)
 
-    # one walk, two tile widths: which a call takes is its own bound's to
-    # say (a short walk's one or two narrow steps cost less than a wide one).
-    # Under a context that is a constant of the program (a one-shot
-    # prefill's zeros) the most the bound can be is one too, and a walk that
-    # cannot be long has no wide form to trace, lower and compile
     wide = _wide_pages(rows, hd, bs, max_blocks, pages,
                        k_pool.dtype.itemsize, k_scale is not None,
                        1 if v_pool is None else 2)
     reach = int(context_lens.max()) + t \
         if isinstance(context_lens, np.ndarray) else max_blocks * bs
-    out = walk(pages) if wide == pages or not _takes_wide(reach, wide, bs) \
-        else jax.lax.cond(_takes_wide(bound, wide, bs), lambda: walk(wide),
-                          lambda: walk(pages))
+    if _fetches_pages(hd, k_scale is not None):
+        # the walk that fetches its own pages takes the wide tile whatever
+        # its length: what a step pays whatever its width is its rows'
+        # flash rescale, and a page it does not fetch costs it nothing
+        out = _own_pages_walk(
+            qg, (k_pool,) + (() if v_pool is None else (v_pool,)),
+            block_tables, context_lens, lengths, layer, window,
+            scale=float(hd ** -0.5 if scale is None else scale), rows=rows,
+            tq=tq, pages=wide, vd=value_width, interpret=_interpret())
+    elif wide == pages or not _takes_wide(reach, wide, bs):
+        out = walk(pages)
+    else:
+        # the grid of BlockSpec pages, two tile widths: which a call takes is
+        # its own bound's to say (a short walk's one or two narrow steps cost
+        # less than a wide one). Under a context that is a constant of the
+        # program (a one-shot prefill's zeros) the most the bound can be is
+        # one too, and a walk that cannot be long has no wide form to trace,
+        # lower and compile
+        out = jax.lax.cond(_takes_wide(bound, wide, bs), lambda: walk(wide),
+                           lambda: walk(pages))
     return out.reshape(B, nkv, n_qt, g, tq, od).transpose(0, 2, 4, 1, 3, 5) \
         .reshape(B, n_qt * tq, nh, od)[:, :t]
 
@@ -1139,6 +1188,191 @@ def paged_kv_write_xla(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
                          if p is not None),
                         _stored_rows(k, v, k_pool, k_scale)))
     return _four(outs, v_pool)
+
+
+# --------------------------------------------------------------------------- #
+# the multi-token walk that fetches its own pages
+# --------------------------------------------------------------------------- #
+def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
+                    has_window, vd, layered):
+    """``paged_prefill`` where the walk fetches its own pages
+    (:func:`_fetches_pages`): grid step ``(b, h, qi)`` is the WHOLE walk of
+    query tile ``qi`` of sequence ``b`` over KV head ``h`` - an in-kernel
+    loop from the tile of its first row's window (tile 0 without one) to the
+    tile of its last REAL row, the tiles :func:`_paged_kernel` computes on
+    the grid of ``BlockSpec`` pages and at the same boundaries (tile ``j`` is
+    table entries ``[j * pages, (j + 1) * pages)``), so the flash sums are
+    the same sums in the same order. Each live page of a tile is one DMA a
+    pool, ``pool[layer, tables[b, pg], h]`` into the page's ``bs`` rows of a
+    double-buffered ``[2, pages * bs, hd]`` scratch - the matmul's layout,
+    no join - and pages below the window's first or past the last real
+    row's are not fetched: their rows keep an earlier tile's and are masked.
+    The NEXT tile - this walk's, or the first of the next grid step's - is
+    started before this one is waited for; which half of the scratch holds
+    the tile in flight is carried in SMEM (the grid is sequential). A query
+    tile with no real row (padding, a zero-length dummy) takes one tile of
+    NO page: it fetches and computes nothing, and writes zeros. ``vd``: one
+    pool, and a token's values are the first ``vd`` lanes of its key row."""
+    n_pools = 1 if vd else 2
+    tables_ref, ctx_ref, len_ref, layer_ref = refs[:4]
+    wnd_ref = refs[4] if has_window else None
+    refs = refs[4 + int(has_window):]
+    q_ref, hbm, o_ref = refs[0], refs[1:1 + n_pools], refs[1 + n_pools]
+    bufs = refs[2 + n_pools:2 + 2 * n_pools]
+    sems, slot_ref, m_scr, l_scr, acc_scr = refs[2 + 2 * n_pools:]
+    b, h, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    kv = pages * bs
+    add, mul, div = jax.lax.add, jax.lax.mul, jax.lax.div
+    lo, hi = jax.lax.max, jax.lax.min
+
+    def span(b, qi):
+        """(first tile, tiles, first live page, last live page) of query
+        tile ``qi`` of sequence ``b`` - :func:`_live_page`'s bounds; a tile
+        with no real row: one tile, and a last page below its first."""
+        ctx, n = ctx_ref[b], len_ref[b]
+        q_lo = mul(qi, tq)
+        last = add(add(ctx, hi(add(q_lo, tq), n)), -1)
+        hi_pg = hi(lo(div(last, bs), 0), max_blocks - 1)
+        lo_pg = hi(div(lo(add(add(ctx, q_lo), add(1, -wnd_ref[0])), 0), bs),
+                   hi_pg) if has_window else 0
+        j0 = div(lo_pg, pages)
+        real = q_lo < n
+        return (j0, jnp.where(real, add(add(div(hi_pg, pages), -j0), 1), 1),
+                lo_pg, jnp.where(real, hi_pg, add(lo_pg, -1)))
+
+    def tile_copies(b, h, j, lo_pg, hi_pg, slot, fetch):
+        """The page copies of tile ``j`` of (sequence, KV head) - of its
+        pages in ``[lo_pg, hi_pg]`` alone - started (``fetch``) or waited
+        for; a wait takes the copy's shape and semaphore, and no source."""
+        pg0 = mul(j, pages)
+        first = lo(pg0, lo_pg)
+        n = add(add(hi(add(pg0, pages - 1), hi_pg), 1), -first)
+
+        def page(p, _, skip):
+            blk = hi(lo(tables_ref[b, add(first, p)], 0), nblocks - 1) \
+                if fetch else 0
+            rows = pl.ds(pl.multiple_of(mul(add(skip, p), bs), bs), bs)
+            for i, (pool, buf) in enumerate(zip(hbm, bufs)):
+                src = pool.at[layer_ref[0], blk] if layered else pool.at[blk]
+                copy = pltpu.make_async_copy(src.at[h], buf.at[slot, rows],
+                                             sems.at[i, slot])
+                copy.start() if fetch else copy.wait()
+            return _
+
+        # a whole tile's copies in straight-line code at static rows, the
+        # last (or a window's first) tile's in a counted loop. Unrolled
+        # where the loop is LOWERED, not in Python: every traced copy costs
+        # a TPU host ~18 ms (PERF.md section 6, PR 54)
+        @pl.when(n == pages)
+        def _whole_tile():
+            jax.lax.fori_loop(0, pages, functools.partial(page, skip=0), 0,
+                              unroll=True)
+
+        @pl.when(n < pages)
+        def _part_tile():
+            jax.lax.fori_loop(
+                0, n, functools.partial(page, skip=add(first, -pg0)), 0)
+
+    j0, tiles, lo_pg, hi_pg = span(b, qi)
+
+    @pl.when(jnp.logical_and(jnp.logical_and(b == 0, h == 0), qi == 0))
+    def _prime():
+        # rows of a tile that are not fetched are always masked, so what the
+        # values' scratch holds there has to be finite: every earlier tile's
+        # rows are, fresh VMEM need not be
+        bufs[-1][...] = jnp.zeros_like(bufs[-1])
+        slot_ref[0] = 0
+        tile_copies(b, h, j0, lo_pg, hi_pg, 0, True)
+
+    _flash_init(0, m_scr, l_scr, acc_scr)
+    ctx, n = ctx_ref[b], len_ref[b]
+    q_lo = mul(qi, tq)
+    n_h, n_qt = pl.num_programs(1), pl.num_programs(2)
+
+    def tile(i, _):
+        slot = slot_ref[0]
+        j = add(j0, i)
+        more = i + 1 < tiles
+        # the grid step after this one: query tiles innermost, then KV heads
+        next_q = jnp.logical_not(more)
+        next_h = jnp.logical_and(next_q, qi + 1 == n_qt)
+        next_b = jnp.logical_and(next_h, h + 1 == n_h)
+        qi_n = jnp.where(next_q, jnp.where(next_h, 0, qi + 1), qi)
+        h_n = jnp.where(next_h, jnp.where(next_b, 0, h + 1), h)
+        b_n = jnp.where(next_b, b + 1, b)
+
+        @pl.when(b_n < pl.num_programs(0))
+        def _fetch_next():
+            j0_n, _, lo_n, hi_n = span(b_n, qi_n)
+            tile_copies(b_n, h_n, jnp.where(more, add(j, 1), j0_n), lo_n,
+                        hi_n, 1 - slot, True)
+
+        tile_copies(b, h, j, lo_pg, hi_pg, slot, False)
+
+        @pl.when(hi_pg >= lo_pg)
+        def _compute():
+            k = bufs[0][slot]                           # [kv, hd]
+            v = bufs[1][slot] if vd is None else k[..., :vd]
+            s = _chunk_scores(q_ref[...], k, j, kv, ctx, n, q_lo, tq,
+                              wnd_ref, scale)
+            _flash_update(s, v, m_scr, l_scr, acc_scr)
+
+        slot_ref[0] = 1 - slot
+        return _
+
+    jax.lax.fori_loop(0, tiles, tile, 0)
+    _flash_finish(True, o_ref, l_scr, acc_scr)
+
+
+# in order: the tile in flight belongs to the NEXT grid step
+_OWN_PAGES_GRID = _dim_semantics("arbitrary", "arbitrary", "arbitrary")
+
+
+# jitted: one trace a process and a shape, whatever holds the call (every
+# layer body of every program of that shape) - set-up time otherwise
+@functools.partial(jax.jit, static_argnames=("scale", "rows", "tq", "pages",
+                                             "vd", "interpret"))
+def _own_pages_walk(qg, pools, block_tables, context_lens, lengths, layer,
+                    window, *, scale, rows, tq, pages, vd, interpret):
+    """One ``paged_prefill`` call whose walk fetches its own pages
+    (:func:`_prefill_kernel`) over layer ``layer`` of ``pools`` (K and V, or
+    one latent pool with ``vd``), which reach it where they lie. ``qg``
+    ``[B, nkv, query tiles * rows, hd]`` as :func:`_table_walk` takes it."""
+    B, nkv, _, hd = qg.shape
+    nblocks, bs = pools[0].shape[-4], pools[0].shape[-2]
+    od = vd or hd                       # the output's (values') width
+    kernel = functools.partial(
+        _prefill_kernel, bs=bs, pages=pages, scale=scale, tq=tq,
+        max_blocks=block_tables.shape[1], nblocks=nblocks,
+        has_window=window is not None, vd=vd, layered=pools[0].ndim == 5)
+
+    def qmap(b, h, qi, *_):
+        return (b, h, qi, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4 + int(window is not None),
+        grid=(B, nkv, qg.shape[2] // rows),
+        in_specs=[pl.BlockSpec((None, None, rows, hd), qmap)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=pl.BlockSpec((None, None, rows, od), qmap),
+        scratch_shapes=[pltpu.VMEM((2, pages * bs, hd), p.dtype)
+                        for p in pools] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, od), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), qg.dtype),
+        compiler_params=_OWN_PAGES_GRID,
+        interpret=interpret,
+        name="paged_prefill",
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      lengths.astype(jnp.int32), layer,
+      *(() if window is None else (window.reshape(1),)), qg, *pools)
 
 
 # speculative verification is the same computation at t = 1 + draft tokens:

@@ -362,8 +362,10 @@ TRACER_SPANS = frozenset((
     # ``attn_tiles_{live, grid}`` (and, under a learned selection, the
     # decode rows' index scores' ``index_tiles_{live, grid}`` and
     # ``index_live_tile_share``), and the KV tokens a grid step of that
-    # walk took as ``chunk_attn_kv_tile`` (256, or the wide tile of a long
-    # walk; plain numbers; docs/observability.md). A family with recurrent
+    # walk took as ``chunk_attn_kv_tile`` (the wide tile where the walk
+    # fetches its own pages - there ``_grid == _live`` - else 256, or the
+    # wide tile of a long walk; plain numbers; docs/observability.md). A
+    # family with recurrent
     # state AND experts (models/nemotron_h.py, the first with both) carries
     # ``ssm_rows`` / ``ssm_tokens`` and ``moe_rows_routed`` /
     # ``moe_rows_computed`` / ``moe_row_tile`` on the SAME ``decode_step``,

@@ -11,7 +11,13 @@ window); one more JSON line follows, ``phase: "scope_ops"``: the traced
 ticks, the scope's device seconds in the window (each operation its own
 time only, as ``program_spans.scope_seconds`` counts) and the operations
 under it, dearest first, as ``[label, seconds, calls]`` - the list a kernel
-PR is written from (PERF.md section 5, item 9, was).
+PR is written from (PERF.md section 5, item 9, was). With ``--span NAME
+--args a,b,..`` one more line, ``phase: "span_args"``: those arguments of
+every ``NAME`` span in the window that carries the first of them, a list an
+argument (``--span decode_step --args
+chunk_attn_tiles_live,chunk_attn_tiles_grid,chunk_attn_kv_tile``: whether
+the chunk's walk fetched its own pages - the two counts are then equal -
+and at which tile).
 """
 
 import json
@@ -33,6 +39,8 @@ def main(argv) -> int:
 
     ap = argparse.ArgumentParser(add_help=False)
     ap.add_argument("--scope", required=True)
+    ap.add_argument("--span")
+    ap.add_argument("--args", default="")
     mine, argv = ap.parse_known_args(argv)
     report = span_report.report
 
@@ -47,6 +55,14 @@ def main(argv) -> int:
             return
         window = trace.window()
         ticks = len(ps.named(program.spans, "sched_tick", window))
+        keys = [k for k in mine.args.split(",") if k]
+        if mine.span and keys:
+            spans = [s for s in ps.named(program.spans, mine.span, window)
+                     if s.arg(keys[0]) is not None]
+            print(json.dumps({"phase": "span_args", "span": mine.span,
+                              "spans": len(spans),
+                              **{k: [s.arg(k) for s in spans] for k in keys}}),
+                  flush=True)
         for plane, ops in program.ops.items():
             under = [dataclasses.replace(op, label=op.label or op.name)
                      if f"/{mine.scope}/" in f"/{op_name}/"

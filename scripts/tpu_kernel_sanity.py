@@ -37,7 +37,11 @@ def main():
     rows = {}
     RESULT["detail"]["kernels"] = rows
 
+    only = set(sys.argv[1:])        # check names; none: every check
+
     def check(name, fn):
+        if only and name not in only:
+            return
         rows[name] = "RUNNING"  # visible in the artifact if killed mid-check
         try:
             fn()
@@ -198,6 +202,59 @@ def main():
                     diff_ok(walk(jnp.asarray(w, jnp.int32)), want, 0.05)
 
     check("paged_decode_own_pages", paged_decode_own_pages)
+
+    # the multi-token walk that fetches its own pages (ISSUE 62) against its
+    # XLA twin and, bit for bit, against the grid of ``BlockSpec`` pages it
+    # replaced (same tiles, same flash sums): command-a's group of 16 over
+    # its window kind's 145-entry table, chat's chunk, the verify window, a
+    # latent pool; a zero-length dummy, a padded chunk, a context that ends
+    # on a wide tile's edge and one key past it; static and traced windows
+    # that begin past page 0
+    def paged_prefill_own_pages():
+        from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+        L, nb = 2, 600
+        fetches = pa._fetches_pages
+        for nkv, g, hd, vd, t, bs, mb in (
+                (8, 16, 128, None, 512, 32, 145),
+                (8, 4, 128, None, 256, 32, 256), (8, 4, 128, None, 5, 32, 256),
+                (1, 64, 640, 512, 512, 128, 64),
+                (16, 1, 128, None, 256, 32, 128)):
+            room = mb * bs - t
+            ctx = np.minimum(np.asarray([0, 700, 2048 - t, 2049 - t, room,
+                                         33], np.int32), room)
+            lens = np.asarray([0, t // 2 + 3, t, t, t, 1], np.int32)
+            B = len(ctx)
+            pools = [randn(L, nb, nkv, bs, hd).astype(jnp.bfloat16)
+                     .at[:, nb - 1].set(jnp.nan)
+                     for _ in range(1 if vd else 2)] + [None] * bool(vd)
+            bad, bt = garbage_past_the_end(
+                np.where(lens > 0, ctx + lens - 1, -bs), bs, mb, nb)
+            q = randn(B, t, nkv * g, hd).astype(jnp.bfloat16)
+            ctx, lens = jnp.asarray(ctx), jnp.asarray(lens)
+            kw = dict(layer=1, value_width=vd)
+
+            def walk(w):
+                return pa.paged_prefill_attention(q, *pools, bad, ctx, lens,
+                                                  window=w, **kw)
+
+            for w in (None, 40, 1000, 4096):
+                got = jax.jit(walk)(w and jnp.asarray(w, jnp.int32))
+                pa._fetches_pages = lambda *a: False
+                try:
+                    grid = walk(w)
+                finally:
+                    pa._fetches_pages = fetches
+                assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+                for b, n in enumerate(np.asarray(lens)):
+                    if n:   # a sequence at a time: the scores are [nh, t, S]
+                        want = pa.paged_prefill_attention_xla(
+                            q[b:b + 1], *pools, bt[b:b + 1], ctx[b:b + 1],
+                            lens[b:b + 1], window=w, **kw)
+                        diff_ok(got[b, :n], want[0, :n], 0.05)
+                        diff_ok(got[b, :n], grid[b, :n], 1e-9)
+
+    check("paged_prefill_own_pages", paged_prefill_own_pages)
 
     # the same walk under the learned selection's mask (Keye's decode rows,
     # ISSUE 51): the tile's index scores one more DMA, the row's threshold

@@ -502,11 +502,14 @@ def latent_case(seed, t, ctx, lengths, bs=8, width=256, vd=128, nh=8):
 
 @pytest.mark.parametrize("t, ctx, lengths", [
     (1, [0, 17, 39], [1, 1, 1]), (8, [0, 9, 24], [8, 5, 8]),
-    (20, [3, 0, 11], [20, 1, 13])])
+    (20, [3, 0, 11], [20, 1, 13]), (16, [24, 0, 5], [16, 1, 9]),
+    (5, [35, 0, 8], [5, 5, 5])])
 def test_the_latent_walk_in_interpret_mode_is_its_xla_twin(t, ctx, lengths):
     """Keys the whole 256-lane row, values its first 128 lanes, one KV head
-    and a group of 8: decode and prefill, Pallas (interpreted) against the
-    gathered reference, on every real row."""
+    and a group of 8: decode and prefill - both walks fetch the latent
+    pool's pages themselves -, Pallas (interpreted) against the gathered
+    reference, on every real row: a context that fills the table, a
+    one-row sequence beside a padded chunk, a verify window."""
     q, pool, tables, ctx, lengths, vd = latent_case(t, t, ctx, lengths)
     kw = dict(scale=0.11, layer=1, value_width=vd)
     if t == 1:

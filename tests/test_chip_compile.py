@@ -467,12 +467,12 @@ def test_the_pools_stay_where_they_are(compiled_for_v5e, cell, program, pool):
         assert mem.temp_size_in_bytes < padded + layer_pool
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
-    # a chunk's walk over bf16 pools is two Mosaic calls under one ``cond``
-    # (ISSUE 48: the narrow KV tile and the wide one a long walk takes);
-    # int8 pools keep the one narrow walk, and ONE padded copy of the scales
-    attn, walks = ("paged_prefill", 2 if pool == "bf16" else 1) \
-        if program == "chunk" else ("paged_decode", 1)
-    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == walks
+    # a chunk's walk is ONE Mosaic call: over bf16 pools the walk that
+    # fetches its own pages, at one tile width (ISSUE 62: no ``cond`` over a
+    # narrow walk and a wide one, ISSUE 48's); int8 pools keep the grid's
+    # one narrow walk, and ONE padded copy of the scales
+    attn = "paged_prefill" if program == "chunk" else "paged_decode"
+    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
 
 
 def test_pool_copy_bytes_counts_what_the_scanned_pools_cost(v5e):
@@ -587,10 +587,10 @@ def test_the_state_pool_stays_where_it_is(v5e, program):
                                                 else 0)
     # a chunk's scan is ONE kernel a Mamba layer body (ISSUE 58)
     assert calls.count("ssm_chunk_scan") == (0 if program == "decode" else 2)
-    # (a chunk's walk: the narrow tile's and the wide one's, ISSUE 48)
-    attn, walks = ("paged_decode", 1) if program == "decode" \
-        else ("paged_prefill", 2)
-    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == walks
+    # (lane-packed pools, 128 lanes a row: both walks fetch their own pages,
+    # and a chunk's has one tile width - ISSUE 62)
+    attn = "paged_decode" if program == "decode" else "paged_prefill"
+    assert calls.count("paged_kv_write") == 1 and calls.count(attn) == 1
 
 
 # --- a step's chunk rides in its decode program (ISSUE 32) ----------------- #
@@ -757,9 +757,11 @@ def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(
     Mellum 2's (a sequential block, group 8, a window of 1024 = two chunks,
     a rope table a kind, all 64 experts of width 896): the full kind's pools
     AND the window kind's stay where they are (no pool-shaped copy, every
-    pool aliased argument-to-result), a period's body is two writes and two
-    walks a kind - the window layers' scan and the full layer's -, and the
-    whole program with its weights and pools fits the chip."""
+    pool aliased argument-to-result), a period's body is two writes, one
+    decode walk and ONE chunk walk a kind (ISSUE 62: the walk that fetches
+    its own pages has one tile width) - the window layers' scan and the
+    full layer's -, and the whole program with its weights and pools fits
+    the chip."""
     import math
     import re
 
@@ -779,7 +781,7 @@ def test_two_kinds_of_kv_state_stay_where_they_are_in_the_mixed_program(
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     for name, count in (("paged_kv_write", 4), ("paged_decode", 2),
-                        ("paged_prefill", 4), ("moe_grouped_matmul", 2)):
+                        ("paged_prefill", 2), ("moe_grouped_matmul", 2)):
         assert calls.count(name) == count, (name, calls.count(name))
 
 
@@ -789,8 +791,8 @@ def test_the_mixed_program_reads_a_layers_weights_once(compiled_for_v5e, cell):
     configurations, compiled for the chip: the pools stay where they are
     (no pool-shaped copy, every pool - Granite's state pool too - aliased
     argument-to-result), a layer body that attends is one ``paged_decode``,
-    one ``paged_prefill`` a KV tile its walk may take (two under one
-    ``cond``, ISSUE 48) and a ``paged_kv_write`` a segment, a Mamba layer
+    one ``paged_prefill`` (ISSUE 62: the walk that fetches its own pages has
+    one tile width) and a ``paged_kv_write`` a segment, a Mamba layer
     body one ``ssm_decode_update`` beside the chunk's state rows, the whole
     program fits the chip, and each FFN / expert-bank weight meets ONE
     matmul a layer body: ``slots + 256`` rows wide, where the two programs
@@ -810,7 +812,7 @@ def test_the_mixed_program_reads_a_layers_weights_once(compiled_for_v5e, cell):
     calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
         r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
     assert (calls.count("paged_decode"), calls.count("paged_prefill"),
-            calls.count("paged_kv_write")) == (1, 2, 2)    # narrow and wide
+            calls.count("paged_kv_write")) == (1, 1, 2)
     assert calls.count("ssm_decode_update") == calls.count(
         "ssm_chunk_scan") == (2 if cell == GRANITE_CELL else 0)
     # every matmul against a weight (bf16; the blocked scan's own are f32)
@@ -900,15 +902,15 @@ PARENT_MIXED_PEAK = {COMMAND_A_CELL: 14_182_393_856,
 def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(
         compiled_for_v5e, cell):
     """Command A+'s and chat's mixed program as the engine runs it, compiled
-    for the chip: every ``paged_prefill`` call (one a table kind) takes its
-    last grid dimension as an operand - a Mosaic call's dynamic grid bound
-    is its FIRST operand, an ``s32[]`` ahead of the prefetched block table;
-    ``paged_decode``'s grid is static since ISSUE 49 (a sequence a step: the
-    walk's length is a loop's trip count inside the kernel) and its first
-    operand is the table -, the pools still stay where they are, also those
-    the decode walk takes whole (``memory_space=pl.ANY``), and the program's
-    peak is the parent's (the bound's own scalars are a few KB: nothing the
-    size of a row, a tile or a table is added)."""
+    for the chip: both walks' grids are static - ``paged_decode``'s since
+    ISSUE 49, ``paged_prefill``'s (one call a table kind) since ISSUE 62: a
+    grid step is a whole walk, whose length is a loop's trip count inside
+    the kernel, so a call's FIRST operand is the prefetched block table (a
+    Mosaic call's dynamic grid bound, which the grid of ``BlockSpec`` pages
+    took, would be an ``s32[]`` ahead of it: the name the test keeps) -,
+    the pools stay where they are, also those the walks take whole
+    (``memory_space=pl.ANY``), and the program's peak is the parent's
+    (nothing the size of a row, a tile or a table is added)."""
     import re
 
     from deepspeed_tpu.telemetry.compile import pool_copy_bytes
@@ -921,8 +923,8 @@ def test_the_mixed_programs_prefill_walk_takes_a_traced_grid(
         for kernel in ("paged_prefill", "paged_decode", "paged_kv_write")}
     kinds = 2 if cell == COMMAND_A_CELL else 1
     assert first["paged_decode"] == [f"s32[{args[3].slots}"] * kinds
-    # two walks a kind since ISSUE 48, the narrow tile's and the wide one's
-    assert first["paged_prefill"] == ["s32[]"] * 2 * kinds
+    # one walk a kind, one tile width (ISSUE 62): the chunk's one table row
+    assert first["paged_prefill"] == ["s32[1"] * kinds
     assert len(first["paged_kv_write"]) == 2 * kinds \
         and "s32[]" not in first["paged_kv_write"]      # a static grid's
     assert pool_copy_bytes(text, jax.tree.leaves(args[1])) == 0
@@ -982,10 +984,12 @@ def test_the_expert_bank_is_read_where_it_lies(compiled_for_v5e, cell,
 # the slots' ``paged_decode`` fetches its own pages (958bdb530c8e0656 and
 # a7f9637cb43eb148). Granite's alone on ISSUE 58's, which means to change
 # it: the chunk's scan is one ``ssm_chunk_scan`` kernel (it was
-# 9d8372bd5608f6e3); chat's stands.
+# 9d8372bd5608f6e3); chat's stands. Both again on ISSUE 62's, which means to
+# change them: the chunk's ``paged_prefill`` fetches its own pages, one jitted
+# call at one tile width (1a7c864441030658 and 0a3ea1df291b5e31).
 NO_BANK_PROGRAMS = {
-    "mistral-7b.serve-chat": "1a7c864441030658",
-    GRANITE_CELL: "0a3ea1df291b5e31",
+    "mistral-7b.serve-chat": "758c4cbf0144abce",
+    GRANITE_CELL: "a4066403284661a2",
 }
 
 
@@ -1035,10 +1039,12 @@ def test_paged_kernels_compile_at_head_size_64(v5e, op, geometry):
     assert text.count(MOSAIC) >= 2
 
 
-# --- a long walk takes a wide KV tile (ISSUE 48) ---------------------------- #
+# --- the multi-token walk takes a wide KV tile (ISSUE 48) and fetches its
+# own pages (ISSUE 62) ------------------------------------------------------- #
 # query heads, KV heads, key width, value width (None: a V pool), block,
-# table width, pool blocks, window -> pages of the wide tile (a.x-k1's 2: the
-# VMEM budget leaves its 640-lane rows the 256 keys they had, and one walk)
+# table width, pool blocks, window -> pages of the tile (a.x-k1's 2: the VMEM
+# budget leaves its 640-lane rows the 256 keys they had; Mellum 2's window
+# kind has a 49-block table, which holds ONE 32-page tile)
 WIDE_WALKS = {
     "command_a_full": (128, 8, 128, None, 32, 1024, 12544, None, 32),
     "command_a_full_under_a_window": (128, 8, 128, None, 32, 1024, 12544,
@@ -1047,28 +1053,47 @@ WIDE_WALKS = {
     "command_a_window_kind_no_window": (128, 8, 128, None, 32, 145, 2321,
                                         None, 32),
     "axk1_latent": (64, 1, 640, 512, 128, 256, 3152, None, 2),
+    "mellum_full": (32, 4, 128, None, 32, 1024, 10240, None, 32),
+    "mellum_window_kind": (32, 4, 128, None, 32, 49, 1569, 1024, 32),
 }
+
+
+def _count_equations(jaxpr, counts):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold (a
+    kernel's body, a loop's, a branch's), by primitive."""
+    for e in jaxpr.eqns:
+        counts[e.primitive.name] = counts.get(e.primitive.name, 0) + 1
+        for v in e.params.values():
+            for j in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    _count_equations(inner, counts)
+    return counts
 
 
 @pytest.mark.parametrize("t", [512, 5])     # a chunk; a verify window (tq 16)
 @pytest.mark.parametrize("geometry", sorted(WIDE_WALKS))
 def test_the_wide_prefill_walk_lowers_at_the_long_context_cells(v5e,
                                                                 geometry, t):
-    """The multi-token walk at the two long-context cells' geometries -
+    """The multi-token walk at the long-context cells' geometries -
     command-a's group of 16 over both table widths, with and without a
-    window, and a.x-k1's one latent pool (keys 640 lanes, values the first
-    512, 128-token blocks) - compiles for the chip with BOTH walks in the
-    program, each a Mosaic call named ``paged_prefill`` with a traced grid
-    bound: the narrow tile's and the wide one's, which reads ``pages`` pool
-    pages a step (a.x-k1's has no wider tile in the budget: the one walk it
-    had)."""
+    window, a.x-k1's one latent pool (keys 640 lanes, values the first 512,
+    128-token blocks), Mellum 2's group of 8 over its two kinds - compiles
+    for the chip as ONE Mosaic call named ``paged_prefill`` that fetches its
+    own pages: its operands are the prefetched scalars, q and the POOLS,
+    whole - no page of them -, its tile is the wide one, and its page copies
+    are unrolled where the loop is lowered, not in Python: a start a pool at
+    four sites (a whole tile and a part of one, this walk's first and the
+    next tile), a wait at two, whatever the tile's 32 pages - and the whole
+    call a few hundred traced equations (a traced copy costs a TPU host
+    ~18 ms a program: PERF.md section 6, PR 54)."""
     import re
 
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     nh, nkv, hd, vd, bs, table, blocks, window, pages = WIDE_WALKS[geometry]
     bf, i32 = jnp.bfloat16, jnp.int32
-    narrow = pa._prefill_tiles(t, nh // nkv, hd, bs, table)[2]
+    n_pools = 1 if vd else 2
 
     def step(q, pool, *rest):
         v_pool = None if vd else rest[0]
@@ -1078,15 +1103,21 @@ def test_the_wide_prefill_walk_lowers_at_the_long_context_cells(v5e,
             window=window, value_width=vd)
 
     pool = ((4, blocks, nkv, bs, hd), bf)
-    text = _compile(step, ((1, t, nh, hd), bf), *((pool,) * (1 if vd else 2)),
-                    ((1, table), i32), ((1,), i32), ((1,), i32),
-                    device=v5e.devices[0]).as_text()
+    shapes = (((1, t, nh, hd), bf), *((pool,) * n_pools), ((1, table), i32),
+              ((1,), i32), ((1,), i32))
+    text = _compile(step, *shapes, device=v5e.devices[0]).as_text()
     calls = re.findall(r"%paged_prefill(?:\.\d+)? = .*? custom-call\((.*?)\), "
                        r"custom_call_target=\"" + MOSAIC, text)
-    # operands: the grid bound, 4 scalars (5 under a window), q, the pages
-    fixed = 6 + (window is not None)
-    assert sorted(c.count("%") - fixed for c in calls) \
-        == sorted(p * (1 if vd else 2) for p in {narrow, pages})
+    # operands: 4 scalars (5 under a window), q, the pools
+    assert [c.count("%") for c in calls] \
+        == [5 + (window is not None) + n_pools]
+    assert pa.prefill_kv_pages([0], [t], t, nh, pool[0][1:], table,
+                               pools=n_pools) == pages
+    counts = _count_equations(jax.make_jaxpr(step)(
+        *(jax.ShapeDtypeStruct(*s) for s in shapes)).jaxpr, {})
+    assert (counts["pallas_call"], counts["dma_start"],
+            counts["dma_wait"]) == (1, 4 * n_pools, 2 * n_pools)
+    assert sum(counts.values()) < 400
 
 
 # --- the ``t > 1`` programs are the parent's ------------------------------- #
@@ -1108,11 +1139,14 @@ MULTI_TOKEN_PROGRAMS = {
 # not has changed it by accident. ISSUE 48 replaced the two whose tables hold
 # a long walk over bf16 pools (a ``cond`` over two tile widths; they were
 # 65274280e7fb1e5c and 54cb8a57fa9599f8): int8 pools and a 20-entry table
-# keep the one walk they had, to the letter.
+# keep the one walk they had, to the letter. ISSUE 62 replaced the same two
+# (the walk fetches its own pages: one jitted call, no ``cond``; they were
+# d63d7041f4528c75 and e89f3ddd0d3a8bda): int8 pools and heads of 64 keep the
+# grid of ``BlockSpec`` pages, to the letter.
 PARENT_HASHES = {
-    "mistral_chunk256_bf16": "d63d7041f4528c75",
+    "mistral_chunk256_bf16": "09c8c67e928666f8",
     "mistral_prompt2816_int8": "adc273e57d65238f",
-    "olmoe_chunk256_window": "e89f3ddd0d3a8bda",
+    "olmoe_chunk256_window": "c97010867091a38a",
     "verify_t5_traced_window_int8_ng2": "a87eb11e2b12777f",
     "batched_prefill_mqa": "a5d8074491835204",
 }
@@ -1549,7 +1583,7 @@ def test_state_and_experts_stay_where_they_are_in_nemotrons_mixed_program(
     for name, count in (("ssm_decode_update", 6), ("ssm_chunk_scan", 6),
                         ("moe_grouped_matmul", 6),
                         ("paged_kv_write", 4), ("paged_decode", 2),
-                        ("paged_prefill", 4)):
+                        ("paged_prefill", 2)):      # one tile width: ISSUE 62
         assert calls.count(name) == count, (name, calls.count(name))
 
 

@@ -88,14 +88,18 @@ def test_blocks_go_back_exactly_when_wholly_behind_the_window(window, bs,
     assert st.window_blocks_live("window") == 0
 
 
+@pytest.mark.parametrize("hd", [32, 128])
 @pytest.mark.parametrize("op", ["decode", "prefill"])
-def test_the_kernels_never_read_what_was_given_back(op):
+def test_the_kernels_never_read_what_was_given_back(op, hd):
     """The Mosaic kernels, interpreted, over a window pool in which every
     block the manager has given back or never claimed - the trash block
     among them - is NaN: their output is the gathered reference's over a
     pool nothing was ever taken from. (A NaN read under a mask would still
-    be a NaN: the walk must not fetch the page at all.)"""
-    window, bs, call, nkv, g, hd = 16, 4, 8, 2, 2, 32
+    be a NaN: the walk must not fetch the page at all.) Heads of 32 walk the
+    grid of ``BlockSpec`` pages, heads of 128 fetch their own pages - the
+    multi-token walk's begin past page 0 once blocks are given back."""
+    window, bs, call, nkv, g = 16, 4, 8, 2, 2
+    assert pa._fetches_pages(hd, False) == (hd == 128)
     st, kind = manager(window, bs, call, slots=1, width=24, blocks=40)
     rng = np.random.default_rng(0)
     total = 61

@@ -548,18 +548,21 @@ def _prefill_walk(case):
 
 
 def _walk_tile(case):
-    """Pages of the KV tile the case's walk takes, by hand: the wide tile
-    where the longest ``context + real rows`` reaches two of them and the
-    table holds them, else the 8 (256 keys) every walk took before ISSUE 48.
-    The wide tile is 32 pages (1 024 keys), and 16 where the VMEM budget
-    says so: these cases' pools are float32, and under command-a's 1 024
-    query rows a 1 024-key step of float32 pages is past it. int8 pools
-    have no wide tile."""
+    """Pages of the KV tile the case's walk takes, by hand. These cases'
+    pools are float32 at 128 lanes, so the walk fetches its own pages and
+    takes, whatever its length, the widest tile the table holds ONE of: 32
+    pages (1 024 keys), and 16 where the VMEM budget says so - under 1 024
+    query rows (command-a's group of 16, chat's 256 tokens at a group of 4)
+    a 1 024-key step of float32 pages is past it - or the table is 16
+    blocks. int8 pools keep the grid of BlockSpec
+    pages and have no wide tile: the 8 (256 keys) every walk took before
+    ISSUE 48."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
     t, nh, nkv, table, ctx, lens, _, int8 = PREFILL_WALKS[case]
-    longest = max(c + n for c, n in zip(ctx, lens))
-    wide = 16 if nh // nkv * min(t, 64) == 1024 else 32
-    return wide if longest >= 2 * 32 * wide and table >= 2 * wide \
-        and not int8 else 8
+    rows = nh // nkv * pa._prefill_tiles(t, nh // nkv, 128, 32, table)[0]
+    wide = 16 if rows == 1024 else 32
+    return 8 if int8 else min(wide, 1 << (table.bit_length() - 1))
 
 
 def _walk_bound(case):
@@ -572,12 +575,14 @@ def _walk_bound(case):
     return min(max(-(-longest // (32 * pages)), 1), -(-table // pages))
 
 
-def _taken_walk(walk, ctx, lens, pools=2):
+def _taken_walk(walk, ctx, lens, pools=2, bs=32):
     """``(the pallas_call equation, its grid bound's value, pages a KV tile,
     whether the program chose between two)`` of the walk the program TAKES
     at these operands: where the call is a ``cond`` over two walks, the
     branch its own predicate picks, read out of the jaxpr. ``pools``: the
-    pools a step reads a page of (int8 pools' scale pools too)."""
+    pools a step reads a page of (int8 pools' scale pools too). The walk
+    that fetches its own pages (one jitted call; ISSUE 62) has no bound on
+    its grid - None - and its tile is its scratch's, in blocks of ``bs``."""
     from jax.extend import core as jex_core
 
     def upto(jaxpr, consts, at, outvars, *args):
@@ -585,10 +590,14 @@ def _taken_walk(walk, ctx, lens, pools=2):
                               jaxpr.eqns[:at], debug_info=jaxpr.debug_info)
         return jax.core.eval_jaxpr(head, consts, *args)
 
+    def a_walk(e):
+        return e.primitive.name in ("pallas_call", "cond") or (
+            e.primitive.name == "jit"
+            and e.params["name"] == "_own_pages_walk")
+
     closed = jax.make_jaxpr(walk)(ctx, lens)
     jaxpr, consts, args = closed.jaxpr, closed.consts, (ctx, lens)
-    (at, eqn), = [(i, e) for i, e in enumerate(jaxpr.eqns)
-                  if e.primitive.name in ("pallas_call", "cond")]
+    (at, eqn), = [(i, e) for i, e in enumerate(jaxpr.eqns) if a_walk(e)]
     chose = eqn.primitive.name == "cond"
     if chose:
         index, *args = upto(jaxpr, consts, at, list(eqn.invars), *args)
@@ -596,7 +605,16 @@ def _taken_walk(walk, ctx, lens, pools=2):
         branch = eqn.params["branches"][int(index)]
         jaxpr, consts = branch.jaxpr, branch.consts
         (at, eqn), = [(i, e) for i, e in enumerate(jaxpr.eqns)
-                      if e.primitive.name == "pallas_call"]
+                      if a_walk(e)]
+    if eqn.primitive.name == "jit":     # the walk that fetches its own pages
+        eqn, = [e for e in eqn.params["jaxpr"].jaxpr.eqns
+                if e.primitive.name == "pallas_call"]
+        mapping = eqn.params["grid_mapping"]
+        assert mapping.num_inputs == 1 + pools      # q and the pools, whole
+        tile = eqn.params["jaxpr"].invars[      # [2, pages * bs, hd]
+            mapping.num_index_operands + mapping.num_inputs
+            + mapping.num_outputs].aval.shape
+        return eqn, None, tile[1] // bs, chose
     (n_live,) = upto(jaxpr, consts, at, [eqn.invars[0]], *args)
     pages = (eqn.params["grid_mapping"].num_inputs - 1) // pools   # less q
     return eqn, n_live, pages, chose
@@ -626,12 +644,15 @@ def test_prefill_walk_that_ends_with_the_context_agrees_with_xla(case):
 def test_prefill_walk_is_bit_for_bit_the_table_wide_grids(case, monkeypatch):
     """The steps the bound takes out computed nothing: real rows are the
     table-wide grid's to the bit (``_table_walk`` handed the table's static
-    tile count, as the parent handed it)."""
+    tile count, as the parent handed it) - and where the walk fetches its
+    own pages (ISSUE 62: every case but the int8 one) it is that grid of
+    ``BlockSpec`` pages' to the bit too: the same tiles, the same sums."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     walk, _, ctx, lens = _prefill_walk(case)
     out = np.asarray(jax.jit(walk)(ctx, lens))
     table_walk = pa._table_walk
+    _grid_at_the_own_walks_tile(monkeypatch)
     monkeypatch.setattr(
         pa, "_table_walk", lambda *a, n_kv, pages, **kw: table_walk(
             *a, n_kv=-(-a[3].shape[1] // pages), pages=pages, **kw))
@@ -642,24 +663,24 @@ def test_prefill_walk_is_bit_for_bit_the_table_wide_grids(case, monkeypatch):
 
 @pytest.mark.parametrize("case", sorted(PREFILL_WALKS))
 def test_prefill_grids_last_dimension_is_traced(case):
-    """The ``pallas_call``'s last grid dimension is a value of the program,
+    """On the grid of ``BlockSpec`` pages (the int8 case) the
+    ``pallas_call``'s last grid dimension is a value of the program,
     computed from the call's own ``context_lens`` and ``lengths`` (no new
     argument, one compilation for every context), and it is the tiles of the
     longest context - at the KV tile the program takes for that context
-    (ISSUE 48: 1 024 keys from two such tiles on, chosen by a ``cond`` on
-    the same bound); ``prefill_tile_counts`` says the same on the host."""
+    (``tests`` of ISSUE 48 below: 1 024 keys from two such tiles on, chosen
+    by a ``cond`` on the same bound). Where the walk fetches its own pages
+    there is no such dimension: each (sequence, KV head, query tile) walks
+    to its own end at the wide tile. ``prefill_tile_counts`` says the same
+    on the host."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     walk, _, ctx, lens = _prefill_walk(case)
     t, nh, nkv, table, _, _, window, int8 = PREFILL_WALKS[case]
     call, n_live, pages, chose = _taken_walk(walk, ctx, lens,
                                              4 if int8 else 2)
-    assert pages == _walk_tile(case) and chose == (table >= 64 and not int8)
+    assert pages == _walk_tile(case) and not chose
     mapping = call.params["grid_mapping"]
-    assert mapping.num_dynamic_grid_bounds == 1
-    assert all(isinstance(n, int) for n in mapping.grid[:3]) \
-        and not isinstance(mapping.grid[3], int)
-    assert n_live.dtype == jnp.int32 and int(n_live) == _walk_bound(case)
     how = dict(itemsize=1 if int8 else 4, quant=int8)
     live, taken, wide = pa.prefill_tile_counts(
         np.asarray(ctx), np.asarray(lens), t, nh, (nkv, 32, 128), table,
@@ -667,8 +688,19 @@ def test_prefill_grids_last_dimension_is_traced(case):
     assert pa.prefill_kv_pages(np.asarray(ctx), np.asarray(lens), t, nh,
                                (nkv, 32, 128), table, **how) == pages
     walks = math.prod(mapping.grid[:3])
-    assert taken == walks * _walk_bound(case) \
-        and wide == walks * -(-table // pages) and 0 <= live <= taken <= wide
+    assert wide == walks * -(-table // pages) and 0 <= live <= taken <= wide
+    if pa._fetches_pages(128, int8):
+        # ISSUE 62: a grid step is one (sequence, KV head, query tile)'s
+        # whole walk, to its OWN last tile - no dimension of KV tiles, no
+        # bound on one, and no step taken that holds no context
+        assert not int8 and n_live is None and len(mapping.grid) == 3 \
+            and mapping.num_dynamic_grid_bounds == 0 and taken == live
+        return
+    assert mapping.num_dynamic_grid_bounds == 1
+    assert all(isinstance(n, int) for n in mapping.grid[:3]) \
+        and not isinstance(mapping.grid[3], int)
+    assert n_live.dtype == jnp.int32 and int(n_live) == _walk_bound(case)
+    assert taken == walks * _walk_bound(case)
 
 
 # --- a long walk takes a wide KV tile (ISSUE 48) ---------------------------- #
@@ -724,12 +756,14 @@ def _tile_walk(c):
     from deepspeed_tpu.ops.quantization import kv_quantize_int8
 
     c = dict(dict(t=16, nh=4, nkv=2, window=None, traced=False, ngroups=0,
-                  value_width=None, room=0), **c)
-    t, nh, nkv, bs, hd, mb, nb = c["t"], c["nh"], c["nkv"], 8, 32, 24, 64
+                  value_width=None, room=0, hd=32, table=24), **c)
+    t, nh, nkv, bs, hd, mb, nb = (c["t"], c["nh"], c["nkv"], 8, c["hd"],
+                                  c["table"], 64)
     rs = np.random.RandomState(7)
     b, poison = len(c["ctx"]), nb - 1
     q = jnp.asarray(rs.randn(b, t, nh, hd).astype(np.float32))
-    pools = [jnp.asarray(rs.randn(nb, nkv, bs, hd).astype(np.float32))
+    lead = (c["layers"],) if c.get("layers") else ()
+    pools = [jnp.asarray(rs.randn(*lead, nb, nkv, bs, hd).astype(np.float32))
              for _ in range(1 if c["value_width"] else 2)]
     tables = np.zeros((b, mb), np.int32)
     poisoned = np.full((b, mb), poison, np.int32)
@@ -743,11 +777,13 @@ def _tile_walk(c):
         scales = [ks, vs]
         bad, bad_scales = pools, [s.at[poison].set(jnp.nan) for s in scales]
     else:
-        bad = [p.at[poison].set(jnp.nan) for p in pools]
+        bad = [p.at[..., poison, :, :, :].set(jnp.nan) for p in pools]
     if c["value_width"]:
         pools, bad = pools + [None], bad + [None]
     window = c["window"]
     kw = {} if c["value_width"] is None else {"value_width": c["value_width"]}
+    if lead:
+        kw["layer"] = lead[0] - 1
     ctx = jnp.asarray(c["ctx"], jnp.int32)
     lens = jnp.asarray(c["lens"], jnp.int32)
 
@@ -874,9 +910,11 @@ def test_wide_tile_comes_from_the_shapes_and_leaves_the_query_tiles():
     """``_wide_pages`` at the cells' geometries: 1 024 keys a step where
     1 024 rows of head size 128 walk bf16 pools (command-a both table kinds,
     chat, OLMoE), less where the budget says (a.x-k1's 640-lane latent rows
-    keep their 256, float32 pools under 1 024 rows get 512), the narrow tile
-    for int8 pools and where the table holds no long walk; the query tiles - which Keye's kernels size their scores by - are
-    what they were."""
+    keep their 256, float32 pools under 1 024 rows get 512) or the table
+    holds no such tile (ISSUE 62: ONE of it where the walk fetches its own
+    pages, a long walk of them on the grid of BlockSpec pages - heads of
+    64), the narrow tile for int8 pools; the query tiles - which Keye's
+    kernels size their scores by - are what they were."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
 
@@ -886,8 +924,12 @@ def test_wide_tile_comes_from_the_shapes_and_leaves_the_query_tiles():
     assert wide(1024, 128, 32, 256, 8, 2, False) == 32      # chat, Mixtral
     assert wide(256, 128, 32, 128, 8, 2, False) == 32       # OLMoE (group 1)
     assert wide(64, 128, 32, 256, 8, 2, False) == 32        # a verify window
-    assert wide(1024, 128, 32, 31, 8, 2, False) == 8        # a 992-key table
-    assert wide(1024, 128, 32, 48, 8, 2, False) == 16       # 1 536 keys
+    assert wide(1024, 128, 32, 31, 8, 2, False) == 16       # a 992-key table
+    assert wide(1024, 128, 32, 48, 8, 2, False) == 32       # 1 536 keys
+    assert wide(1024, 128, 32, 49, 8, 2, False) == 32       # Mellum's window
+    assert wide(1024, 64, 32, 31, 8, 2, False) == 8         # ... heads of 64:
+    assert wide(1024, 64, 32, 48, 8, 2, False) == 16        # the grid's rule
+    assert wide(1024, 64, 32, 64, 8, 2, False) == 32
     assert wide(1024, 128, 512, 16, 1, 2, False) == 2       # 512-key pages
     assert wide(1024, 128, 32, 3, 3, 2, False) == 3         # a short table
     assert wide(1024, 640, 128, 256, 2, 2, False, 1) == 2   # a.x-k1's latent
@@ -900,6 +942,169 @@ def test_wide_tile_comes_from_the_shapes_and_leaves_the_query_tiles():
     assert sparse.prefill_rows(512, 32, 4, 128, 32, 1024) == 512
     assert (pa._MAX_PAGES, pa._KV_TOKENS, sparse._PREFILL_PAGES) \
         == (8, 256, 32)
+
+
+# --- the multi-token walk fetches its own pages (ISSUE 62) ------------------ #
+# ``_tile_walk``'s small tiles at head size 128 (whole lane tiles, so the
+# walk fetches its own pages): blocks of 8, a table of 24, query tiles of 16
+# tokens, and ONE KV tile of 8 pages (64 keys) whatever the walk's length.
+OWN_PAGES_WALKS = {
+    "plain": dict(ctx=[150], lens=[16]),
+    # either side of the bound the grid of BlockSpec pages chooses its tile
+    # by: this walk takes the wide tile on both
+    "one_key_short_of_two_wide_tiles": dict(ctx=[111], lens=[16]),
+    "two_wide_tiles": dict(ctx=[112], lens=[16]),
+    "a_walk_of_one_page": dict(ctx=[3], lens=[5]),
+    "context_zero": dict(ctx=[0], lens=[16]),
+    "context_fills_the_table": dict(ctx=[176], lens=[16]),
+    "three_query_tiles": dict(t=40, ctx=[140], lens=[40]),
+    # B = 1 and fewer real rows than the call's: query tiles 1 and 2 hold
+    # none, fetch nothing and write zeros
+    "padded_last_chunk": dict(t=40, ctx=[100], lens=[7]),
+    "zero_length_dummies": dict(ctx=[140, 0, 30, 0], lens=[9, 0, 16, 0]),
+    "every_sequence_a_dummy": dict(ctx=[0, 0], lens=[0, 0]),
+    "window_static": dict(ctx=[150], lens=[16], window=40),
+    "window_traced": dict(ctx=[150], lens=[16], window=40, traced=True),
+    "window_wider_than_a_tile": dict(ctx=[170], lens=[16], window=100),
+    # position 171 - 20: the walk begins in tile 2 of 3
+    "window_walk_begins_past_tile_0": dict(ctx=[170, 20], lens=[16, 16],
+                                           window=20),
+    # a window kind's table: 21 blocks, not whole tiles - the last tile is
+    # five pages, and the walk begins past page 0
+    "window_kinds_short_table": dict(ctx=[150], lens=[16], window=100,
+                                     table=21),
+    "verify_window_t5_b16": dict(
+        t=5, ctx=[0, 3, 59, 60, 63, 64, 100, 127, 128, 150, 187, 1, 64, 9,
+                  120, 31], lens=[5] * 16),
+    "group_16": dict(ctx=[150], lens=[16], nh=32, nkv=2),
+    "group_8": dict(ctx=[150], lens=[16], nh=8, nkv=1),
+    "group_4": dict(ctx=[150, 70], lens=[16, 16], nh=8, nkv=2),
+    "group_1": dict(ctx=[150], lens=[16], nh=2, nkv=2),
+    "layer_of_a_5d_pool": dict(ctx=[150], lens=[16], layers=3),
+    "latent_pool": dict(ctx=[150, 10], lens=[16, 16], nh=4, nkv=1, hd=256,
+                        value_width=128),
+    "latent_pool_640_lanes": dict(ctx=[150], lens=[16], nh=8, nkv=1, hd=640,
+                                  value_width=512),
+    "latent_pool_windowed": dict(ctx=[150], lens=[16], nh=4, nkv=1, hd=256,
+                                 value_width=128, window=20),
+}
+# int8 pools and plain pools of heads under a lane tile: the grid stays
+GRID_WALKS = {
+    "int8_pools": dict(ctx=[150], lens=[16], hd=128, ngroups=1),
+    "head_64": dict(ctx=[150, 20], lens=[16, 9], hd=64),
+    "head_64_windowed": dict(ctx=[150], lens=[16], hd=64, window=40),
+}
+
+
+def _grid_at_the_own_walks_tile(monkeypatch):
+    """The grid of ``BlockSpec`` pages, forced, at the tile the walk that
+    fetches its own pages takes (the widest the table holds ONE of,
+    whatever the walk's length): what that walk must be to the bit."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_fetches_pages", lambda *a: False)
+    monkeypatch.setattr(pa, "_WIDE_WALK_TILES", 1)
+    monkeypatch.setattr(pa, "_takes_wide", lambda *a: True)
+
+
+def _tiles_by_hand(c, tq, kv):
+    """KV tiles a KV head that hold context a real row attends, a loop a
+    (sequence, query tile): from its first row's window (tile 0 without
+    one) to its last REAL row."""
+    n_tiles = 0
+    for ctx, n in zip(c["ctx"], c["lens"]):
+        for q_lo in range(0, -(-c.get("t", 16) // tq) * tq, tq):
+            if q_lo < n:
+                first = max(ctx + q_lo + 1 - c["window"], 0) // kv \
+                    if c.get("window") else 0
+                n_tiles += (ctx + min(q_lo + tq, n) - 1) // kv - first + 1
+    return n_tiles
+
+
+@pytest.mark.parametrize("case", sorted(OWN_PAGES_WALKS))
+def test_prefill_walk_that_fetches_its_own_pages(case, monkeypatch):
+    """``paged_prefill`` where ``_fetches_pages`` holds (interpreted: the
+    interpreter runs its DMAs, its semaphores and its SMEM carry): ONE
+    jitted ``pallas_call`` on a grid (sequences, KV heads, query tiles) with
+    no dimension of KV tiles, the pools whole operands, at the wide tile on
+    both sides of the bound the grid of ``BlockSpec`` pages chooses by.
+    Every real row is the XLA reference's and - TO THE BIT - that grid's at
+    the same tile (the same tiles, the same flash sums), every row is
+    finite, and a query tile with no real row is zeros: every query group,
+    a latent pool (640 lanes too), a layer of a 5-D pool, the verify window
+    at 16 sequences, a padded last chunk, zero-length dummies, static and
+    traced windows, walks that begin past tile 0 and a table that is not
+    whole tiles; table entries past a sequence's blocks are poisoned, and a
+    walk that fetched one - even under its mask - would read NaN. The host's
+    mirror counts the tiles the walk takes: the ones that hold context."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    _small_tiles(monkeypatch)
+    c = dict(dict(hd=128, t=16, nh=4, nkv=2), **OWN_PAGES_WALKS[case])
+    walk, reference, ctx, lens = _tile_walk(c)
+    pools = 1 if c.get("value_width") else 2
+    call, n_live, pages, chose = _taken_walk(walk, ctx, lens, pools, bs=8)
+    mapping = call.params["grid_mapping"]
+    n_qt = -(-c["t"] // 16)
+    assert (n_live, pages, chose) == (None, 8, False) \
+        and mapping.grid == (len(c["ctx"]), c["nkv"], n_qt) \
+        and mapping.num_dynamic_grid_bounds == 0
+    out = np.asarray(jax.jit(walk)(ctx, lens))
+    want = np.asarray(reference())
+    assert out.shape == want.shape and np.isfinite(out).all()
+    how = dict(itemsize=4, pools=pools)
+    shape = (c["nkv"], 8, c["hd"])
+    table = c.get("table", 24)
+    live, taken, wide = pa.prefill_tile_counts(
+        c["ctx"], c["lens"], c["t"], c["nh"], shape, table,
+        c.get("window"), **how)
+    assert live == taken == c["nkv"] * _tiles_by_hand(c, 16, 64) \
+        and wide == len(c["ctx"]) * c["nkv"] * n_qt * -(-table // 8)
+    assert pa.prefill_kv_pages(c["ctx"], c["lens"], c["t"], c["nh"], shape,
+                               table, **how) == 8
+    _grid_at_the_own_walks_tile(monkeypatch)
+    walk, *_ = _tile_walk(c)        # a new function: nothing traced is kept
+    assert _taken_walk(walk, ctx, lens, pools)[1:3] == (
+        max(-(-int(np.max(np.add(c["ctx"], c["lens"]))) // 64), 1), 8)
+    grid = np.asarray(jax.jit(walk)(ctx, lens))
+    for b, n in enumerate(c["lens"]):
+        np.testing.assert_allclose(out[b, :n], want[b, :n], rtol=2e-5,
+                                   atol=2e-5)
+        np.testing.assert_array_equal(out[b, :n], grid[b, :n])
+        # whole query tiles of padding: nothing fetched, zeros written
+        assert not out[b, -(-n // 16) * 16:].any()
+
+
+@pytest.mark.parametrize("case", sorted(GRID_WALKS))
+def test_int8_pools_and_narrow_heads_keep_the_grid_of_blockspec_pages(
+        case, monkeypatch):
+    """Mosaic slices a page out of a pool for a DMA only where the pool's
+    rows are whole 128-lane tiles: int8 pools (their f32 scale pages) and
+    heads of 64 keep the grid ``(sequences, KV heads, query tiles, KV
+    tiles)`` with its traced bound, its two tile widths and its counts -
+    every (sequence, KV head, query tile) as far as the longest."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    _small_tiles(monkeypatch)
+    c = dict(dict(t=16, nh=4, nkv=2), **GRID_WALKS[case])
+    quant = bool(c.get("ngroups"))
+    assert not pa._fetches_pages(c["hd"], quant)
+    walk, reference, ctx, lens = _tile_walk(c)
+    call, n_live, pages, chose = _taken_walk(walk, ctx, lens,
+                                             4 if quant else 2)
+    mapping = call.params["grid_mapping"]
+    assert (int(n_live), pages, chose) == ((11, 2, False) if quant
+                                           else (3, 8, True)) \
+        and len(mapping.grid) == 4 and mapping.num_dynamic_grid_bounds == 1
+    live, taken, _ = pa.prefill_tile_counts(
+        c["ctx"], c["lens"], 16, 4, (2, 8, c["hd"]), 24, c.get("window"),
+        itemsize=1 if quant else 4, quant=quant)
+    assert taken == len(c["ctx"]) * 2 * int(n_live) and 0 < live <= taken \
+        and (live < taken) == (case != "int8_pools")
+    out, want = np.asarray(jax.jit(walk)(ctx, lens)), np.asarray(reference())
+    for b, n in enumerate(c["lens"]):
+        np.testing.assert_allclose(out[b, :n], want[b, :n], rtol=2e-5,
+                                   atol=2e-5)
 
 
 # --- the decode walk fetches its own pages (ISSUE 49) ----------------------- #

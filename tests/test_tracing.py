@@ -939,8 +939,27 @@ HAND_COUNTED_WALKS = {
 }
 
 
+# ISSUE 62, the same walks where the walk fetches its own pages (heads of
+# 128): ONE tile width whatever the length - 64 keys where the table holds
+# one, 32 where it is 4 blocks - and each (sequence, query tile) walks from
+# its own first live tile to its own last, so taken == live: (live, table-wide)
+HAND_COUNTED_OWN_PAGES = {
+    "one_chunk": (1, 2),            # 53 keys: one 64-key tile of the table's 2
+    "batched_with_a_dummy": (1, 4),     # the dummy walks nothing
+    "three_query_tiles": (5, 12),       # rows end at 56, 72, 80: 1 + 2 + 2
+    "window_starts_in_tile_1": (2, 2),  # 51..86: tiles 0 and 1
+    "a_key_short_of_two_wide_tiles": (2, 2),
+    "context_fills_the_table": (2, 2),
+    "two_wide_tiles_of_the_tables_four": (2, 4),
+    "the_longest_decides_for_the_batch": (3 + 1, 12),   # no longer: 3, 1, 0
+    "wide_tiles_under_a_window": (2, 4),    # 181..216: tiles 2 and 3
+    "wide_tiles_three_query_tiles": (9, 12),
+}
+
+
+@pytest.mark.parametrize("hd", [64, 128])
 @pytest.mark.parametrize("case", sorted(HAND_COUNTED_WALKS))
-def test_prefill_tile_counts_are_the_hand_count(case, monkeypatch):
+def test_prefill_tile_counts_are_the_hand_count(case, hd, monkeypatch):
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
     monkeypatch.setattr(pa, "_KV_TOKENS", 32)   # two 16-token pages a tile
@@ -948,12 +967,17 @@ def test_prefill_tile_counts_are_the_hand_count(case, monkeypatch):
     monkeypatch.setattr(pa, "_Q_ROWS", 32)      # 16 tokens x a group of 2
     (ctx, lens), t, table, window, want = HAND_COUNTED_WALKS[case]
     nkv = 3
-    assert pa.prefill_tile_counts(ctx, lens, t, 2 * nkv, (nkv, 16, 128),
-                                  table, window) \
-        == tuple(nkv * n for n in want)
+    counts = pa.prefill_tile_counts(ctx, lens, t, 2 * nkv, (nkv, 16, hd),
+                                    table, window)
+    pages = pa.prefill_kv_pages(ctx, lens, t, 2 * nkv, (nkv, 16, hd), table)
+    if pa._fetches_pages(hd, False):
+        live, wide = HAND_COUNTED_OWN_PAGES[case]
+        assert counts == (nkv * live, nkv * live, nkv * wide)
+        assert pages == 4
+        return
+    assert counts == tuple(nkv * n for n in want)
     longest = max(c + n for c, n in zip(ctx, lens))
-    assert pa.prefill_kv_pages(ctx, lens, t, 2 * nkv, (nkv, 16, 128), table) \
-        == (4 if longest >= 128 else 2)
+    assert pages == (4 if longest >= 128 else 2)
 
 
 def _chunk_tile_spans(eng, prompt_tokens):
@@ -1121,6 +1145,12 @@ def _kernel_cases():
         "paged_prefill": (pa.paged_prefill_attention,
                           [jnp.ones((2, 3, 4, 32), f32), pool, pool,
                            tables, lens]),
+        # the same name's second call site: heads of 128 fetch their own
+        # pages (ISSUE 62; heads of 32 walk the grid of BlockSpec pages)
+        "paged_prefill:own_pages": (
+            pa.paged_prefill_attention,
+            [jnp.ones((2, 3, 4, 128), f32), jnp.ones((8, 2, 16, 128), f32),
+             jnp.ones((8, 2, 16, 128), f32), tables, lens]),
         "paged_kv_write": (
             lambda k, kp, vp, bt, n: pa.paged_kv_write(k, k, kp, vp, bt, n,
                                                        n)[:2],
@@ -1217,16 +1247,23 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "delta_decode_update", "delta_chunk"]
 
 
-@pytest.mark.parametrize("kernel", KERNEL_NAMES)
-def test_every_pallas_call_site_names_its_kernel(kernel):
+# a name with two call sites names the second ``<name>:<which>``
+CALL_SITES = KERNEL_NAMES + ["paged_prefill:own_pages"]
+
+
+@pytest.mark.parametrize("site", CALL_SITES)
+def test_every_pallas_call_site_names_its_kernel(site):
     """The name is what the device trace shows for the kernel's events (the
     HLO instruction is named after it), whatever the program around it;
-    under autodiff it arrives wrapped, ``transpose(jvp(<name>))``."""
+    under autodiff it arrives wrapped, ``transpose(jvp(<name>))``. The two
+    multi-token walks share ONE name: the benchmark's rooflines and every
+    trace reader match ``paged_prefill``, whichever walk a pool takes."""
     import re
 
     import jax
 
-    fn, args = _kernel_cases()[kernel]
+    fn, args = _kernel_cases()[site]
+    kernel = site.partition(":")[0]
     text = jax.jit(fn).lower(*args).as_text(debug_info=True)
     # (a call site inside a jitted wrapper - ``ssm_chunk_scan`` - opens the
     # wrapper's own name stack: the name then starts the location)
@@ -1245,7 +1282,7 @@ def test_every_pallas_call_site_is_in_the_list():
             names = re.findall(r"^\s+name=\"([a-z0-9_]+)\",$", text, re.M)
             assert calls == len(names), f
             found += names
-    assert sorted(found) == sorted(KERNEL_NAMES)
+    assert sorted(found) == sorted(s.partition(":")[0] for s in CALL_SITES)
 
 
 def _scopes_of(fn, *args):
