@@ -1024,16 +1024,19 @@ class InferenceEngineV2(InferenceEngine):
         ``ops/pallas/paged_attention.py prefill_tile_counts``) - of ONE
         layer's call; in a family with window kinds of one call a kind, each
         times the kind's layers, summed - and the KV tokens of a step of the
-        walk, ``chunk_attn_kv_tile`` (the widest of the kinds'). None
-        for a family whose chunk takes another walk (a learned selection) or
-        whose paged cache is not ``init_paged_pools``' (a latent pool is:
-        one KV head, every query head in its group)."""
+        walk, ``chunk_attn_kv_tile`` (the widest of the kinds'). Under a
+        learned selection the walk is ``paged_sparse_prefill``, the same
+        form at a tile of its own (``paged_sparse_attention.prefill_pages``).
+        None for a family whose paged cache is not ``init_paged_pools``' (a
+        latent pool is: one KV head, every query head in its group)."""
         from ..ops.pallas.paged_attention import (prefill_kv_pages,
                                                   prefill_tile_counts)
 
         pool = self._walked_pool()
-        if pool is None or self._indexed:
+        if pool is None:
             return {}
+        if self._indexed:
+            from ..ops.pallas.paged_sparse_attention import prefill_pages
         state = self.state
         walks = [(pool.shape, state.max_blocks_per_seq, 0, None)]
         walks += [(self.cache["k_" + kind.name].shape, kind.blocks_per_seq,
@@ -1046,9 +1049,11 @@ class InferenceEngineV2(InferenceEngine):
             layers = shape[0] if len(walks) > 1 else 1
             call = ([ch.ctx - given], [len(ch.tokens)], ch.width,
                     self.family.cfg.num_heads, shape, width)
-            counts = prefill_tile_counts(*call, window, *how)
+            pages = prefill_pages(*call[2:], how[0]) if self._indexed \
+                else prefill_kv_pages(*call, *how)
+            counts = prefill_tile_counts(*call, window, *how, pages=pages)
             total = tuple(a + layers * n for a, n in zip(total, counts))
-            tile = max(tile, prefill_kv_pages(*call, *how) * shape[-2])
+            tile = max(tile, pages * shape[-2])
         return dict(zip(("chunk_attn_tiles_live", "chunk_attn_tiles_grid",
                          "chunk_attn_tiles_table"), total),
                     chunk_attn_kv_tile=tile)

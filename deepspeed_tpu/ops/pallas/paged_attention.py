@@ -77,9 +77,14 @@ the wide one (up to 1024 tokens: :func:`_wide_pages`, from the shapes and a
 VMEM budget), whatever the walk's length: what a step pays whatever its
 width - the flash rescale and the row reductions of its ``[rows, 128]``
 scratch, 3.5 us at 1 024 rows - is most of a 256-key step, and a page that
-is not fetched costs nothing (PERF.md section 6, PR 62). int8 pools and
+is not fetched costs nothing (PERF.md section 6, PR 62). The same walk
+serves ``paged_sparse_prefill`` (``paged_sparse_attention.py``: a learned
+selection's chunk rows) with one more mask, whose operands - the rows'
+thresholds, two ``[tq, 1]`` blocks, and the query tile's slice of the index
+scores, one more DMA a tile - exist in the call only where a caller hands
+them (:func:`_own_pages_parts`). int8 pools and
 heads under 128 lanes keep the walk that went before (:func:`_paged_kernel`,
-:func:`_table_walk`; ``paged_sparse_attention.py``'s masked prefill walk is
+:func:`_table_walk`; ``paged_sparse_attention.py``'s masked grid walk is
 built from the same parts): a grid ``(B, KV heads, query tiles, KV tiles)``
 of table-indexed ``BlockSpec`` pages, joined in VMEM, whose last dimension
 is DYNAMIC: the tiles up to the longest sequence's last REAL row, ``clip(
@@ -271,6 +276,19 @@ def _prefill_tiles(t: int, g: int, hd: int, bs: int,
     return tq, n_qt, pages
 
 
+def _walk_vmem(rows: int, hd: int, kv: int, itemsize: int, pools: int = 2,
+               mask_rows: int = 0) -> int:
+    """Bytes of VMEM a step of a multi-token walk keeps at ``kv`` keys: the K
+    and V (or one latent) tiles double-buffered, the ``[rows, KV]`` f32
+    scores and probabilities, the q and output blocks double-buffered and
+    the m / l / accumulator scratch - and under a learned selection
+    (``mask_rows`` query tokens a tile) their index scores' ``[mask_rows,
+    KV]`` f32 tile, double-buffered."""
+    return (2 * pools * kv * hd * itemsize + 2 * rows * kv * 4
+            + 4 * rows * hd * 2 + rows * (256 + hd) * 4
+            + 2 * mask_rows * kv * 4)
+
+
 def _wide_pages(rows: int, hd: int, bs: int, max_blocks: int, narrow: int,
                 itemsize: int, quant: bool, pools: int = 2) -> int:
     """Pages of the WIDE KV tile of a multi-token walk, ``narrow`` (the ~256
@@ -279,10 +297,8 @@ def _wide_pages(rows: int, hd: int, bs: int, max_blocks: int, narrow: int,
     128]`` m / l scratch and the ``[rows, hd]`` f32 accumulator, the row
     reductions - is most of a 256-key step at 1 024 rows (PERF.md section 6,
     PRs 38, 48 and 62), so a walk takes up to ``_WIDE_KV_TOKENS`` keys a
-    step: the widest doubling of ``narrow`` whose step fits ``_WIDE_VMEM`` -
-    the K and V (or one latent) tiles double-buffered, the ``[rows, KV]``
-    f32 scores and probabilities, the q and output blocks double-buffered
-    and the m / l / accumulator scratch - and that the table holds: ONE of,
+    step: the widest doubling of ``narrow`` whose step (:func:`_walk_vmem`)
+    fits ``_WIDE_VMEM`` and that the table holds: ONE of,
     where the walk fetches its own pages (:func:`_fetches_pages`; it takes
     the wide tile whatever its length - a page it does not fetch costs it
     nothing), a LONG walk of (``_WIDE_WALK_TILES``) on the grid of
@@ -292,15 +308,12 @@ def _wide_pages(rows: int, hd: int, bs: int, max_blocks: int, narrow: int,
     had). So do int8 pools: a layer's f32 scale pools reach each kernel
     lane-padded (PERF.md section 7), and a second walk would keep a second
     padded copy of both. From shapes alone."""
-    def step(kv):
-        return (2 * pools * kv * hd * itemsize + 2 * rows * kv * 4
-                + 4 * rows * hd * 2 + rows * (256 + hd) * 4)
-
     tiles = 1 if _fetches_pages(hd, quant) else _WIDE_WALK_TILES
     pages = narrow
     while not quant and 2 * pages * bs <= _WIDE_KV_TOKENS \
             and tiles * 2 * pages <= max_blocks \
-            and step(2 * pages * bs) <= _WIDE_VMEM:
+            and _walk_vmem(rows, hd, 2 * pages * bs, itemsize,
+                           pools) <= _WIDE_VMEM:
         pages *= 2
     return pages
 
@@ -401,12 +414,13 @@ def prefill_kv_pages(context_lens, lengths, t: int, nh: int, pool_shape,
 
 def prefill_tile_counts(context_lens, lengths, t: int, nh: int, pool_shape,
                         max_blocks: int, window=None, itemsize: int = 2,
-                        quant: bool = False,
-                        pools: int = 2) -> Tuple[int, int, int]:
+                        quant: bool = False, pools: int = 2,
+                        pages: int = None) -> Tuple[int, int, int]:
     """(live, taken, table-wide) KV tiles of ONE ``paged_prefill`` call of
     ``t`` rows a sequence, ``lengths`` of them real, at ``context_lens``
     (host integers; ``window``: the layer's, an int or None), at the KV tile
-    the call takes (:func:`prefill_kv_pages`): the tiles a real row of
+    the call takes (:func:`prefill_kv_pages`; ``pages``: the tile of another
+    walk of the same form, ``paged_sparse_prefill``'s): the tiles a real row of
     their query tile attends, the tiles the walk takes - the SAME tiles
     where it fetches its own pages (:func:`_fetches_pages`: each (sequence,
     KV head, query tile) walks from its own first tile to its own last); on
@@ -415,8 +429,9 @@ def prefill_tile_counts(context_lens, lengths, t: int, nh: int, pool_shape,
     the table. What the serving engine puts on a chunk's span."""
     nkv, bs, hd = pool_shape[-3:]
     tq, n_qt, _ = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)
-    pages = prefill_kv_pages(context_lens, lengths, t, nh, pool_shape,
-                             max_blocks, itemsize, quant, pools)
+    pages = pages or prefill_kv_pages(context_lens, lengths, t, nh,
+                                      pool_shape, max_blocks, itemsize, quant,
+                                      pools)
     kv, n_kv = pages * bs, -(-max_blocks // pages)
     ctx = np.asarray(context_lens)[:, None, None]
     n = np.asarray(lengths)[:, None, None]
@@ -457,6 +472,31 @@ def _chunk_scores(q, k, j, kv, ctx, n, q_lo, tq, wnd_ref, scale):
     if wnd_ref is not None:
         valid = jnp.logical_and(valid, pos > q_abs - wnd_ref[0])
     return jnp.where(valid, s, NEG_INF)
+
+
+def _selected_chunk_scores(q, k, idx, tau, cut, selected, j, kv, ctx, n,
+                           q_lo, tq, scale):
+    """:func:`_chunk_scores` (no window) under one more mask, a learned
+    selection's (``paged_sparse_attention.py``): ``selected(idx, positions,
+    tau, cut)`` over the tile's index scores ``idx [tq, kv]`` and the rows'
+    thresholds ``[tq, 1]``. One token's row of the mask - its own positions,
+    of them the selected - serves its whole query group: computed once a
+    token, repeated g-major as the rows are. The position mask is AND-ed
+    over whatever ``selected`` says: scores past a row's position are never
+    written."""
+    s = _mxu_dot(q, k, _contract(q.ndim, -1),
+                 preferred_element_type=jnp.float32) * scale
+    g = s.shape[-2] // tq
+    pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
+    q_abs = ctx + q_lo + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    keep = jnp.logical_and(
+        selected(idx, pos, tau, cut),
+        jnp.logical_and(pos <= q_abs, pos < ctx + n))       # [tq, kv]
+    # NEG_INF absorbs any (finite) score: a dropped token's is exactly NEG_INF
+    drop = jnp.where(keep, 0.0, NEG_INF)
+    if g > 1:       # rows are g-major: one mask row a token
+        return (s.reshape(g, tq, kv) + drop[None]).reshape(g * tq, kv)
+    return s + drop
 
 
 def _paged_kernel(*refs, bs, pages, scale, n_kv, tq, has_window, quant,
@@ -1194,7 +1234,7 @@ def paged_kv_write_xla(k: jnp.ndarray, v: jnp.ndarray, k_pool: jnp.ndarray,
 # the multi-token walk that fetches its own pages
 # --------------------------------------------------------------------------- #
 def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
-                    has_window, vd, layered):
+                    has_window, vd, layered, selected=None):
     """``paged_prefill`` where the walk fetches its own pages
     (:func:`_fetches_pages`): grid step ``(b, h, qi)`` is the WHOLE walk of
     query tile ``qi`` of sequence ``b`` over KV head ``h`` - an in-kernel
@@ -1212,14 +1252,23 @@ def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
     the tile in flight is carried in SMEM (the grid is sequential). A query
     tile with no real row (padding, a zero-length dummy) takes one tile of
     NO page: it fetches and computes nothing, and writes zeros. ``vd``: one
-    pool, and a token's values are the first ``vd`` lanes of its key row."""
-    n_pools = 1 if vd else 2
+    pool, and a token's values are the first ``vd`` lanes of its key row.
+    ``selected``: the walk of ``paged_sparse_prefill`` - one more mask,
+    ``selected(scores, positions, tau, cut)``
+    (``paged_sparse_attention.selected``; :func:`_selected_chunk_scores`):
+    the rows' thresholds are two ``[tq, 1]`` blocks of the query tile after
+    q, and a tile's ``[tq, pages * bs]`` slice of the call's index scores
+    ``[B, query tiles * tq, S]`` one more DMA a tile that has a live page,
+    after the pools' in the operands, the scratch and the semaphores."""
+    n_pools, selects = 1 if vd else 2, selected is not None
+    n_src = n_pools + int(selects)
     tables_ref, ctx_ref, len_ref, layer_ref = refs[:4]
     wnd_ref = refs[4] if has_window else None
-    refs = refs[4 + int(has_window):]
-    q_ref, hbm, o_ref = refs[0], refs[1:1 + n_pools], refs[1 + n_pools]
-    bufs = refs[2 + n_pools:2 + 2 * n_pools]
-    sems, slot_ref, m_scr, l_scr, acc_scr = refs[2 + 2 * n_pools:]
+    q_ref, refs = refs[4 + int(has_window)], refs[5 + int(has_window):]
+    if selects:
+        (tau_ref, cut_ref), refs = refs[:2], refs[2:]
+    hbm, o_ref, bufs = refs[:n_src], refs[n_src], refs[1 + n_src:1 + 2 * n_src]
+    sems, slot_ref, m_scr, l_scr, acc_scr = refs[1 + 2 * n_src:]
     b, h, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     kv = pages * bs
     add, mul, div = jax.lax.add, jax.lax.mul, jax.lax.div
@@ -1240,10 +1289,12 @@ def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
         return (j0, jnp.where(real, add(add(div(hi_pg, pages), -j0), 1), 1),
                 lo_pg, jnp.where(real, hi_pg, add(lo_pg, -1)))
 
-    def tile_copies(b, h, j, lo_pg, hi_pg, slot, fetch):
+    def tile_copies(b, h, qi, j, lo_pg, hi_pg, slot, fetch):
         """The page copies of tile ``j`` of (sequence, KV head) - of its
         pages in ``[lo_pg, hi_pg]`` alone - started (``fetch``) or waited
-        for; a wait takes the copy's shape and semaphore, and no source."""
+        for; a wait takes the copy's shape and semaphore, and no source.
+        Under a selection the query tile's index scores over the tile's
+        positions too: whole, whatever is live of them."""
         pg0 = mul(j, pages)
         first = lo(pg0, lo_pg)
         n = add(add(hi(add(pg0, pages - 1), hi_pg), 1), -first)
@@ -1252,7 +1303,7 @@ def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
             blk = hi(lo(tables_ref[b, add(first, p)], 0), nblocks - 1) \
                 if fetch else 0
             rows = pl.ds(pl.multiple_of(mul(add(skip, p), bs), bs), bs)
-            for i, (pool, buf) in enumerate(zip(hbm, bufs)):
+            for i, (pool, buf) in enumerate(zip(hbm[:n_pools], bufs)):
                 src = pool.at[layer_ref[0], blk] if layered else pool.at[blk]
                 copy = pltpu.make_async_copy(src.at[h], buf.at[slot, rows],
                                              sems.at[i, slot])
@@ -1273,16 +1324,28 @@ def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
             jax.lax.fori_loop(
                 0, n, functools.partial(page, skip=add(first, -pg0)), 0)
 
+        if selects:
+            @pl.when(n > 0)
+            def _index_scores():
+                copy = pltpu.make_async_copy(
+                    hbm[n_pools].at[
+                        b, pl.ds(pl.multiple_of(mul(qi, tq), tq), tq),
+                        pl.ds(pl.multiple_of(mul(j, kv), kv), kv)],
+                    bufs[n_pools].at[slot], sems.at[n_pools, slot])
+                copy.start() if fetch else copy.wait()
+
     j0, tiles, lo_pg, hi_pg = span(b, qi)
 
     @pl.when(jnp.logical_and(jnp.logical_and(b == 0, h == 0), qi == 0))
     def _prime():
         # rows of a tile that are not fetched are always masked, so what the
         # values' scratch holds there has to be finite: every earlier tile's
-        # rows are, fresh VMEM need not be
-        bufs[-1][...] = jnp.zeros_like(bufs[-1])
+        # rows are, fresh VMEM need not be (the keys' too where a selection's
+        # mask is ADDED to the scores)
+        for buf in bufs[:n_pools] if selects else bufs[-1:]:
+            buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
-        tile_copies(b, h, j0, lo_pg, hi_pg, 0, True)
+        tile_copies(b, h, qi, j0, lo_pg, hi_pg, 0, True)
 
     _flash_init(0, m_scr, l_scr, acc_scr)
     ctx, n = ctx_ref[b], len_ref[b]
@@ -1304,17 +1367,22 @@ def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
         @pl.when(b_n < pl.num_programs(0))
         def _fetch_next():
             j0_n, _, lo_n, hi_n = span(b_n, qi_n)
-            tile_copies(b_n, h_n, jnp.where(more, add(j, 1), j0_n), lo_n,
-                        hi_n, 1 - slot, True)
+            tile_copies(b_n, h_n, qi_n, jnp.where(more, add(j, 1), j0_n),
+                        lo_n, hi_n, 1 - slot, True)
 
-        tile_copies(b, h, j, lo_pg, hi_pg, slot, False)
+        tile_copies(b, h, qi, j, lo_pg, hi_pg, slot, False)
 
         @pl.when(hi_pg >= lo_pg)
         def _compute():
             k = bufs[0][slot]                           # [kv, hd]
             v = bufs[1][slot] if vd is None else k[..., :vd]
-            s = _chunk_scores(q_ref[...], k, j, kv, ctx, n, q_lo, tq,
-                              wnd_ref, scale)
+            if selects:
+                s = _selected_chunk_scores(
+                    q_ref[...], k, bufs[n_pools][slot], tau_ref[...],
+                    cut_ref[...], selected, j, kv, ctx, n, q_lo, tq, scale)
+            else:
+                s = _chunk_scores(q_ref[...], k, j, kv, ctx, n, q_lo, tq,
+                                  wnd_ref, scale)
             _flash_update(s, v, m_scr, l_scr, acc_scr)
 
         slot_ref[0] = 1 - slot
@@ -1328,6 +1396,56 @@ def _prefill_kernel(*refs, bs, pages, scale, tq, max_blocks, nblocks,
 _OWN_PAGES_GRID = _dim_semantics("arbitrary", "arbitrary", "arbitrary")
 
 
+def _own_pages_parts(qg, pools, block_tables, context_lens, lengths, layer,
+                     window, *, scale, rows, tq, pages, vd, selected=None,
+                     selection=()):
+    """The kernel, grid, result and arguments of one multi-token walk that
+    fetches its own pages (:func:`_prefill_kernel`) over layer ``layer`` of
+    ``pools`` (K and V, or one latent pool with ``vd``), which reach it where
+    they lie. ``qg`` ``[B, nkv, query tiles * rows, hd]`` as
+    :func:`_table_walk` takes it. ``selected`` with ``selection``: one more
+    mask (``selected(scores, positions, tau, cut)``) and its ``(idx, tau,
+    cut)`` - the call's index scores ``[B, query tiles * tq, S]`` (``S``
+    whole KV tiles; left in HBM like the pools) and each row's threshold
+    ``[B, query tiles * tq, 1]``."""
+    B, nkv, _, hd = qg.shape
+    nblocks, bs = pools[0].shape[-4], pools[0].shape[-2]
+    od = vd or hd                       # the output's (values') width
+    scores, taus = tuple(selection[:1]), tuple(selection[1:])
+    hbm = tuple(pools) + scores
+    kernel = functools.partial(
+        _prefill_kernel, bs=bs, pages=pages, scale=scale, tq=tq,
+        max_blocks=block_tables.shape[1], nblocks=nblocks,
+        has_window=window is not None, vd=vd, layered=pools[0].ndim == 5,
+        selected=selected)
+
+    def qmap(b, h, qi, *_):
+        return (b, h, qi, 0)
+
+    tiles = [(pages * bs, hd)] * len(pools) + [(tq, pages * bs)] * len(scores)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4 + int(window is not None),
+        grid=(B, nkv, qg.shape[2] // rows),
+        in_specs=[pl.BlockSpec((None, None, rows, hd), qmap)]
+        + [pl.BlockSpec((None, tq, 1), lambda b, h, qi, *_: (b, qi, 0))]
+        * len(taus) + [pl.BlockSpec(memory_space=pl.ANY)] * len(hbm),
+        out_specs=pl.BlockSpec((None, None, rows, od), qmap),
+        scratch_shapes=[pltpu.VMEM((2,) + tile, p.dtype)
+                        for tile, p in zip(tiles, hbm)] + [
+            pltpu.SemaphoreType.DMA((len(hbm), 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, od), jnp.float32),
+        ],
+    )
+    args = [block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+            lengths.astype(jnp.int32), layer] \
+        + ([] if window is None else [window.reshape(1)]) + [qg, *taus, *hbm]
+    return (kernel, grid_spec,
+            jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), qg.dtype), args)
+
+
 # jitted: one trace a process and a shape, whatever holds the call (every
 # layer body of every program of that shape) - set-up time otherwise
 @functools.partial(jax.jit, static_argnames=("scale", "rows", "tq", "pages",
@@ -1335,44 +1453,16 @@ _OWN_PAGES_GRID = _dim_semantics("arbitrary", "arbitrary", "arbitrary")
 def _own_pages_walk(qg, pools, block_tables, context_lens, lengths, layer,
                     window, *, scale, rows, tq, pages, vd, interpret):
     """One ``paged_prefill`` call whose walk fetches its own pages
-    (:func:`_prefill_kernel`) over layer ``layer`` of ``pools`` (K and V, or
-    one latent pool with ``vd``), which reach it where they lie. ``qg``
-    ``[B, nkv, query tiles * rows, hd]`` as :func:`_table_walk` takes it."""
-    B, nkv, _, hd = qg.shape
-    nblocks, bs = pools[0].shape[-4], pools[0].shape[-2]
-    od = vd or hd                       # the output's (values') width
-    kernel = functools.partial(
-        _prefill_kernel, bs=bs, pages=pages, scale=scale, tq=tq,
-        max_blocks=block_tables.shape[1], nblocks=nblocks,
-        has_window=window is not None, vd=vd, layered=pools[0].ndim == 5)
-
-    def qmap(b, h, qi, *_):
-        return (b, h, qi, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 + int(window is not None),
-        grid=(B, nkv, qg.shape[2] // rows),
-        in_specs=[pl.BlockSpec((None, None, rows, hd), qmap)]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=pl.BlockSpec((None, None, rows, od), qmap),
-        scratch_shapes=[pltpu.VMEM((2, pages * bs, hd), p.dtype)
-                        for p in pools] + [
-            pltpu.SemaphoreType.DMA((len(pools), 2)),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, od), jnp.float32),
-        ],
-    )
+    (:func:`_own_pages_parts`)."""
+    kernel, grid_spec, out_shape, args = _own_pages_parts(
+        qg, pools, block_tables, context_lens, lengths, layer, window,
+        scale=scale, rows=rows, tq=tq, pages=pages, vd=vd)
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape[:-1] + (od,), qg.dtype),
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
         compiler_params=_OWN_PAGES_GRID,
         interpret=interpret,
         name="paged_prefill",
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      lengths.astype(jnp.int32), layer,
-      *(() if window is None else (window.reshape(1),)), qg, *pools)
+    )(*args)
 
 
 # speculative verification is the same computation at t = 1 + draft tokens:

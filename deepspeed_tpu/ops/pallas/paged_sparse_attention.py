@@ -39,10 +39,17 @@ Five calls, each on the layer's index into ``[L, ...]`` pools, like
                         own end, page DMAs issued from the block table, a
                         tile and a sequence ahead) and, a tile, one more DMA:
                         its slice of the row's index scores. The chunk's
-                        rows take ``_paged_kernel``'s grid of table-indexed
-                        ``BlockSpec`` pages - as a decode call does where
-                        Mosaic cannot slice a page out of the pool (heads
-                        narrower than a lane tile), at one token a sequence.
+                        rows take ``paged_prefill``'s walk, which fetches
+                        its own pages too (``_prefill_kernel``: each
+                        (sequence, KV head, query tile) to its last real
+                        row, a tile and a grid step ahead) and, a tile, one
+                        more DMA: the query tile's ``[tq, KV]`` slice of the
+                        scores - the same tiles, the same flash sums as the
+                        grid of table-indexed ``BlockSpec`` pages
+                        (``_sparse_walk``) that both keep where Mosaic
+                        cannot slice a page out of the pool (heads narrower
+                        than a lane tile; the decode call at one token a
+                        sequence).
                         (Both still visit the whole live context - the mask
                         form; a gather over the selected pages is what the
                         ``sparse_attn_roofline`` leaves room for.)
@@ -76,12 +83,14 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import dim_semantics as _dim_semantics
 from ._common import interpret as _interpret
 from ._common import mxu_dot as _mxu_dot
-from .paged_attention import (NEG_INF, _MAX_PAGES, _PAGE_WALK_GRID,
+from .paged_attention import (_MAX_PAGES, _OWN_PAGES_GRID, _PAGE_WALK_GRID,
                               _WALK_GRID, _contract, _decode_tiles,
                               _fetches_pages, _flash_finish, _flash_init,
                               _flash_update, _group_rows, _kv_tile,
-                              _layer_scalar, _page_spec, _page_walk,
-                              _prefill_tiles, _write_pages)
+                              _layer_scalar, _own_pages_parts, _page_spec,
+                              _page_walk,
+                              _prefill_tiles, _selected_chunk_scores,
+                              _walk_vmem, _write_pages)
 
 KEY_MIN = -2 ** 31          # the sort key of a position that is not the row's
 _SELECT_ROWS = 8            # rows of one selection tile: a sublane tile
@@ -112,6 +121,9 @@ _PREFILL_PAGES = 32         # K / V pages of one step of the multi-token
                             # pages (scripts/sparse_kernel_bench.py on the
                             # chip, PR 38). The decode rows' tile is
                             # ``paged_attention._decode_tiles``'.
+_PREFILL_VMEM = 14 << 20    # what a step of that walk may keep of Mosaic's
+                            # 16 MiB of VMEM: the plain walk's tiles and the
+                            # scores' (12.5 MiB at 1 024 rows x 1 024 keys)
 
 
 def index_pack(d: int, block_size: int) -> int:
@@ -794,23 +806,9 @@ def _sparse_kernel(*refs, bs, pages, scale, tq, g):
         none = (None,) * pages
         k = _kv_tile(k_refs, none, q.dtype)
         v = _kv_tile(v_refs, none, q.dtype)
-        s = _mxu_dot(q, k, _contract(q.ndim, -1),
-                     preferred_element_type=jnp.float32) * scale
-        pos = j * kv + jax.lax.broadcasted_iota(jnp.int32, (1, kv), 1)
-        # one token's row of the mask - its own positions, of them the
-        # selected - serves its whole query group: computed once a token,
-        # repeated g-major as the rows are
-        q_abs = ctx + q_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, 1), 0)
-        keep = jnp.logical_and(
-            selected(idx_ref[...], pos, tau_ref[...], cut_ref[...]),
-            jnp.logical_and(pos <= q_abs, pos < ctx + n))  # [tq, kv]
-        drop = jnp.where(keep, 0.0, NEG_INF)
-        # NEG_INF absorbs any score: a dropped token's is exactly NEG_INF
-        if g > 1:       # rows are g-major: one mask row a token
-            s = (s.reshape(g, tq, kv) + drop[None]).reshape(g * tq, kv)
-        else:
-            s = s + drop
+        s = _selected_chunk_scores(q, k, idx_ref[...], tau_ref[...],
+                                   cut_ref[...], selected, j, kv, ctx, n,
+                                   q_lo, tq, scale)
         _flash_update(s, v, m_scr, l_scr, acc_scr)
 
     _flash_finish(j == pl.num_programs(3) - 1, o_ref, l_scr, acc_scr)
@@ -881,32 +879,70 @@ def prefill_rows(t: int, nh: int, nkv: int, hd: int, bs: int,
     return tq * n_qt
 
 
-def _selected_table_walk(q, k_pool, v_pool, idx, tau, cut, block_tables,
-                         context_lens, lengths, scale, layer):
-    """The multi-token masked walk of ``q [B, t, nh, hd]`` as ``(kernel, grid,
-    arguments, result's shape, the result as [B, t, nh, hd])``: query tiles
-    of ``g * tq`` rows, one KV head a step (:func:`_sparse_walk`)."""
+def prefill_pages(t: int, nh: int, pool_shape, max_blocks: int,
+                  itemsize: int = 2) -> int:
+    """Pages of the KV tile of the multi-token masked walk of ``t`` rows
+    over pools of ``pool_shape`` (``[.., nkv, bs, hd]``), whichever way it
+    reaches them: ``_PREFILL_PAGES``, a power of two the table holds, halved
+    until a step - the plain walk's tiles and the query tile's index scores,
+    double-buffered (``paged_attention._walk_vmem``) - fits
+    ``_PREFILL_VMEM``. From the shapes alone
+    (``prefill_tile_counts(.., pages=)`` counts its tiles on the host)."""
+    nkv, bs, hd = pool_shape[-3:]
+    tq = _prefill_tiles(t, nh // nkv, hd, bs, max_blocks)[0]
+    pages = _pow2_pages(_PREFILL_PAGES, max_blocks)
+    while pages > 1 and _walk_vmem(nh // nkv * tq, hd, pages * bs, itemsize,
+                                   mask_rows=tq) > _PREFILL_VMEM:
+        pages //= 2
+    return pages
+
+
+def _masked_tiles(q, k_pool, idx, max_blocks):
+    """``q [B, t, nh, hd]`` as the multi-token walks take it - ``[B, nkv,
+    query tiles * g * tq, hd]``, tile-major then g-major - with ``(g, tq,
+    pages a KV tile)`` of the masked walk over scores ``idx`` and the
+    inverse, a result as ``[B, t, nh, hd]``."""
     B, t, nh, hd = q.shape
-    layer = _layer_scalar(layer, k_pool, v_pool)
     nkv, bs = k_pool.shape[-3:-1]
     g = nh // nkv
-    tq, n_qt, _ = _prefill_tiles(t, g, hd, bs, block_tables.shape[1])
-    pages = _pow2_pages(_PREFILL_PAGES, block_tables.shape[1])
-    rows = g * tq
+    tq, n_qt, _ = _prefill_tiles(t, g, hd, bs, max_blocks)
     assert idx.shape[1] == n_qt * tq, (idx.shape, n_qt, tq)
+    pages = prefill_pages(t, nh, k_pool.shape, max_blocks,
+                          k_pool.dtype.itemsize)
     qg = jnp.pad(q, ((0, 0), (0, n_qt * tq - t), (0, 0), (0, 0)))
     qg = qg.reshape(B, n_qt, tq, nkv, g, hd).transpose(0, 3, 1, 4, 2, 5) \
-        .reshape(B, nkv, n_qt * rows, hd)
+        .reshape(B, nkv, n_qt * g * tq, hd)
 
     def token_major(out):
         return out.reshape(B, nkv, n_qt, g, tq, hd) \
             .transpose(0, 2, 4, 1, 3, 5).reshape(B, n_qt * tq, nh, hd)[:, :t]
 
+    return qg, (g, tq, pages), token_major
+
+
+def _selected_table_walk(q, k_pool, v_pool, idx, tau, cut, block_tables,
+                         context_lens, lengths, scale, layer):
+    """The multi-token masked walk of ``q [B, t, nh, hd]`` over the grid of
+    ``BlockSpec`` pages as ``(kernel, grid, arguments, result's shape, the
+    result as [B, t, nh, hd])``: query tiles of ``g * tq`` rows, one KV head
+    a step (:func:`_sparse_walk`)."""
+    hd = q.shape[-1]
+    qg, (g, tq, pages), token_major = _masked_tiles(q, k_pool, idx,
+                                                    block_tables.shape[1])
     return _sparse_walk(
         qg, k_pool, v_pool, idx, tau[..., None], cut[..., None],
-        block_tables, context_lens, lengths, layer,
-        scale=hd ** -0.5 if scale is None else scale, rows=rows, tq=tq, g=g,
-        pages=pages) + (qg.shape, token_major)
+        block_tables, context_lens, lengths,
+        _layer_scalar(layer, k_pool, v_pool),
+        scale=hd ** -0.5 if scale is None else scale, rows=g * tq, tq=tq,
+        g=g, pages=pages) + (qg.shape, token_major)
+
+
+def _whole_tiles(idx, width: int):
+    """The scores ``[B, rows, S]`` at least ``width`` wide - the KV tiles a
+    walk that fetches its own pages takes of them: where the table is no
+    multiple of the walk's tile and the scores no wider than it, the last
+    tile's DMA stays inside them."""
+    return jnp.pad(idx, ((0, 0), (0, 0), (0, max(0, width - idx.shape[2]))))
 
 
 def paged_sparse_decode_attention(q, k_pool, v_pool, idx, tau, cut,
@@ -927,10 +963,7 @@ def paged_sparse_decode_attention(q, k_pool, v_pool, idx, tau, cut,
         gpad = _group_rows(g)
         pages, heads, n_kv = _decode_tiles(nkv, g, hd, bs, max_blocks,
                                            k_pool.dtype.itemsize, False)
-        short = n_kv * pages * bs - idx.shape[2]
-        if short > 0:   # a table that is no multiple of the walk's tile and
-            # scores no wider than it: the last tile's DMA stays inside them
-            idx = jnp.pad(idx, ((0, 0), (0, 0), (0, short)))
+        idx = _whole_tiles(idx, n_kv * pages * bs)
         qg = jnp.pad(q.reshape(B, nkv, g, hd),
                      ((0, 0), (0, 0), (0, gpad - g), (0, 0)))
         kernel, grid_spec, args = _page_walk(
@@ -967,12 +1000,49 @@ def paged_sparse_decode_attention(q, k_pool, v_pool, idx, tau, cut,
     )(*args))
 
 
+# jitted: one trace a process and a shape, as ``_own_pages_walk`` is
+@functools.partial(jax.jit, static_argnames=("scale", "rows", "tq", "pages",
+                                             "interpret"))
+def _own_pages_masked_walk(qg, pools, selection, block_tables, context_lens,
+                           lengths, layer, *, scale, rows, tq, pages,
+                           interpret):
+    """One ``paged_sparse_prefill`` call whose walk fetches its own pages:
+    ``paged_attention._own_pages_walk`` with ``selection`` (the scores and
+    the rows' thresholds) as three more operands."""
+    kernel, grid_spec, out_shape, args = _own_pages_parts(
+        qg, pools, block_tables, context_lens, lengths, layer, None,
+        scale=scale, rows=rows, tq=tq, pages=pages, vd=None,
+        selected=selected, selection=selection)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=_OWN_PAGES_GRID,
+        interpret=interpret,
+        name="paged_sparse_prefill",
+    )(*args)
+
+
 def paged_sparse_prefill_attention(q, k_pool, v_pool, idx, tau, cut,
                                    block_tables, context_lens, lengths, *,
                                    scale: float = None, layer=None):
     """``paged_prefill_attention`` over the selected tokens alone. ``idx
     [B, rows, S]`` with ``rows`` = :func:`prefill_rows`; ``tau``, ``cut``
-    ``[B, rows]``. Returns ``[B, t, nh, hd]``."""
+    ``[B, rows]``. Returns ``[B, t, nh, hd]``. The walk is
+    ``paged_prefill``'s that fetches its own pages
+    (``paged_attention._own_pages_parts``) with the selection as one more
+    operand, at the same tiles as the grid of ``BlockSpec`` pages
+    (:func:`_sparse_walk`) that serves where it cannot (``_fetches_pages``:
+    heads narrower than a lane tile) - the same sums in the same order."""
+    hd, bs, max_blocks = q.shape[-1], k_pool.shape[-2], block_tables.shape[1]
+    if _fetches_pages(hd, False):
+        qg, (g, tq, pages), token_major = _masked_tiles(q, k_pool, idx,
+                                                        max_blocks)
+        idx = _whole_tiles(idx, -(-max_blocks // pages) * pages * bs)
+        return token_major(_own_pages_masked_walk(
+            qg, (k_pool, v_pool), (idx, tau[..., None], cut[..., None]),
+            block_tables, context_lens, lengths,
+            _layer_scalar(layer, k_pool, v_pool),
+            scale=float(hd ** -0.5 if scale is None else scale),
+            rows=g * tq, tq=tq, pages=pages, interpret=_interpret()))
     kernel, grid_spec, args, out_shape, token_major = _selected_table_walk(
         q, k_pool, v_pool, idx, tau, cut, block_tables, context_lens,
         lengths, scale, layer)

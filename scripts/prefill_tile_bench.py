@@ -57,10 +57,10 @@ SHAPES = {
 FORMS = ("own", "grid", "folded", "one_page")
 
 
-def kernel_us(run, where, calls):
-    """Microseconds of each of the ``calls`` ``paged_prefill`` events of one
-    traced ``run()``, in the device's order (None off a chip: no device
-    plane)."""
+def kernel_us(run, where, calls, name="paged_prefill"):
+    """Microseconds of each of the ``calls`` events of the kernel ``name``
+    (``paged_prefill``) of one traced ``run()``, in the device's order (None
+    off a chip: no device plane)."""
     import jax
     from jax.profiler import ProfileData
 
@@ -74,7 +74,7 @@ def kernel_us(run, where, calls):
               if plane.name.startswith("/device:TPU:0")
               for line in plane.lines if line.name == "XLA Ops"
               for e in line.events
-              if re.match(r"%?paged_prefill", e.name)]
+              if re.match("%?" + name, e.name)]
     if not events:
         return None
     events.sort(key=lambda e: e.start_ns)

@@ -18,7 +18,14 @@ block ids scattered over the pool. One JSON line a (side, seed):
 microseconds a call (``--layers`` calls in one program, median of
 ``--reps``), the share of the dense-read floor (the decoding rows' whole live
 context - K and V, or the index keys - at the chip's published HBM rate) and
-how far the result lies from the gathered XLA op's. The parent is
+how far the result lies from the gathered XLA op's. Then the chunk's masked
+walk (``paged_sparse_prefill``, 512 rows, ``--only prefill``) as the tree
+builds it (``own``: the kernel that fetches its own pages, ISSUE 63) against
+the grid of ``BlockSpec`` pages it replaced (``grid``: ``_fetches_pages``
+forced off, the parent's walk) at ``--contexts``, by the DEVICE's time of the
+kernel's own events in one traced run of a program of ``--layers`` calls (a
+call of ~1 ms is of the order of the host's dispatch), and whether the two
+results are one to the bit. The parent is
 ``--parent DIR`` (an unpacked ``git archive``: what a chip machine, which has
 no ``.git``, needs) or else ``git archive --parent-rev`` unpacked under
 ``/tmp``. A number from here is a kernel's, never a cell's."""
@@ -26,6 +33,7 @@ no ``.git``, needs) or else ``git archive --parent-rev`` unpacked under
 import argparse
 import json
 import os
+import shutil
 import statistics
 import subprocess
 import sys
@@ -190,6 +198,84 @@ def decode_walk(args) -> int:
     return 0
 
 
+def prefill_walk(args) -> int:
+    """The chunk's masked walk, ``own`` against ``grid``: a JSON line a
+    (form, context) - microseconds a call by the kernel's own device events
+    (median of ``--layers`` calls; None off a chip), the KV tiles a call
+    takes - and a ``pair`` line a context: the change and whether the results
+    are bit-equal."""
+    sys.path.insert(0, ROOT)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as S
+    from scripts.prefill_tile_bench import kernel_us
+
+    nb, mb, _ = table_of(args)
+    t, bf, i32 = (16 if args.tiny else 512), jnp.bfloat16, jnp.int32
+    topk = 64 if args.tiny else TOPK
+    layers = 2 if args.tiny else args.layers
+    contexts = [c for c in args.contexts if c + t <= mb * BS] \
+        or [mb * BS - t]
+    key = jax.random.PRNGKey(0)
+    k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                              (L, nb, NKV, BS, HD), bf) for i in range(2))
+    q = jax.random.normal(key, (1, t, NH, HD), bf)
+    rows = S.prefill_rows(t, NH, NKV, HD, BS, mb)
+    idx = jax.random.normal(key, (1, rows, mb * BS), jnp.float32)
+    tables = jnp.asarray(np.random.default_rng(0).integers(1, nb, (1, mb)),
+                         i32)
+    lens = jnp.full((1,), t, i32)
+    fetches, where = pa._fetches_pages, tempfile.mkdtemp(dir="/tmp")
+    read = {}
+    for form in ("own", "grid"):    # the walk's choice, and the host mirror's
+        S._fetches_pages = pa._fetches_pages = \
+            fetches if form == "own" else lambda *a: False
+
+        def walk(ctx_, tau_, cut_, layer):      # a new one a form: jit
+            return S.paged_sparse_prefill_attention(    # keeps its trace
+                q, k, v, idx, tau_, cut_, tables, ctx_, lens, layer=layer)
+
+        def program(ctx_, tau_, cut_):
+            def layer(i, acc):
+                return acc + walk(ctx_, tau_, cut_, i % L).astype(jnp.float32)
+            return jax.lax.fori_loop(0, layers, layer,
+                                     jnp.zeros((1, t, NH, HD), jnp.float32))
+
+        fn, one = jax.jit(program), jax.jit(walk)
+        ops = []
+        for c in contexts:
+            ctx = jnp.full((1,), c, i32)
+            tau, cut = S.paged_sparse_select(
+                idx[0], c + jnp.arange(rows), topk=topk)
+            ops.append((ctx, tau[None], cut[None]))
+        jax.block_until_ready(fn(*ops[-1]))
+        us = kernel_us(lambda: jax.block_until_ready([fn(*o) for o in ops]),
+                       where, layers * len(contexts), "paged_sparse_prefill")
+        for i, (c, o) in enumerate(zip(contexts, ops)):
+            pages = S.prefill_pages(t, NH, (NKV, BS, HD), mb)
+            live, taken, _ = pa.prefill_tile_counts(
+                [c], [t], t, NH, (NKV, BS, HD), mb, pages=pages)
+            mine = us and float(np.median(us[i * layers:(i + 1) * layers]))
+            read.setdefault(c, {})[form] = (mine, np.asarray(one(*o, 1)))
+            print(json.dumps({
+                "case": "sparse_prefill 512 rows", "form": form, "ctx": c,
+                "us": mine, "kv_tile": pages * BS, "steps_live": live,
+                "steps_taken": taken,
+                "device": jax.devices()[0].device_kind}), flush=True)
+    S._fetches_pages = pa._fetches_pages = fetches
+    shutil.rmtree(where, ignore_errors=True)
+    for c, forms in read.items():
+        (own, a), (grid, b) = forms["own"], forms["grid"]
+        print(json.dumps({
+            "pair": "sparse_prefill 512 rows", "ctx": c, "own_us": own,
+            "grid_us": grid, "change": own and grid and own / grid - 1,
+            "bit_equal": bool(np.array_equal(a, b))}), flush=True)
+    return 0
+
+
 def both_sides(args, case: str) -> int:
     """The parent's kernel of ``case``, then this tree's, each in its own
     process."""
@@ -222,10 +308,14 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--ctx", type=int, default=15000)
     ap.add_argument("--reps", type=int, default=5)
-    ap.add_argument("--only", choices=["walk", "index", "tiles"],
+    ap.add_argument("--only", choices=["walk", "index", "prefill", "tiles"],
                     help="the decode rows' masked walk or their index "
-                    "scores, parent against tree, or the other kernels' "
-                    "tile sizes; all three where not given")
+                    "scores, parent against tree, the chunk's masked walk, "
+                    "own pages against the grid, or the other kernels' tile "
+                    "sizes; all four where not given")
+    ap.add_argument("--contexts", type=int, nargs="*",
+                    default=[2048, 8192, 15000, 30000],
+                    help="--only prefill: cached tokens under the chunk")
     ap.add_argument("--parent", help="the parent commit, unpacked")
     ap.add_argument("--parent-rev", default="HEAD")
     ap.add_argument("--layers", type=int, default=24)
@@ -241,12 +331,20 @@ def main() -> int:
                     "of this many pages (a power of two)")
     args = ap.parse_args()
     if args.walk_of:
-        return {"walk": decode_walk, "index": index_scores}[args.case](args)
+        return {"walk": decode_walk, "index": index_scores,
+                "prefill": prefill_walk}[args.case](args)
     for case in ("walk", "index"):
         if args.only in (None, case):
             rc = both_sides(args, case)
             if rc:
                 return rc
+    if args.only in (None, "prefill"):
+        rc = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--case", "prefill",
+             "--walk-of", ROOT, "--layers", str(args.layers), "--contexts",
+             *map(str, args.contexts)] + ["--tiny"] * args.tiny).returncode
+        if rc:
+            return rc
     if args.only not in (None, "tiles"):
         return 0
     sys.path.insert(0, ROOT)

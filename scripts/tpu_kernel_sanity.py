@@ -288,6 +288,72 @@ def main():
 
     check("paged_sparse_decode_own_pages", paged_sparse_decode_own_pages)
 
+    # the chunk's masked walk fetches its own pages too (ISSUE 63): the plain
+    # multi-token walk's kernel with the query tile's index scores one more
+    # DMA a KV tile and its rows' thresholds two more blocks. Keye's group of
+    # 8 at its 1 024-key tile (and a group of 4 over a table that is no
+    # multiple of it), a zero-length dummy, a padded chunk, contexts that
+    # end on a tile's edge and one key past it; garbage table entries past a
+    # sequence's end and garbage SCORES past each row's own position (NaN
+    # and +inf: the chunk's scores call never writes them). Against the XLA
+    # twin and - bit for bit - against the grid of ``BlockSpec`` pages
+    def paged_sparse_prefill_own_pages():
+        from deepspeed_tpu.ops.pallas import paged_attention as pa
+        from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+        L, bs, nb, topk, hd = 2, 32, 600, 256, 128
+        fetches = pa._fetches_pages
+        for nkv, g, t, mb in ((4, 8, 512, 128), (8, 4, 256, 72)):
+            tile = sparse.prefill_pages(t, nkv * g, (nkv, bs, hd), mb) * bs
+            room = mb * bs - t
+            ctx = np.minimum(np.asarray([0, 700, tile - t, tile + 1 - t, room,
+                                         33, 0], np.int32), room)
+            lens = np.asarray([0, t // 2 + 3, t, t, t, 1, t], np.int32)
+            B = len(ctx)
+            k, v = (randn(L, nb, nkv, bs, hd).astype(jnp.bfloat16)
+                    .at[:, nb - 1].set(jnp.nan) for _ in range(2))
+            bad, bt = garbage_past_the_end(
+                np.where(lens > 0, ctx + lens - 1, -bs), bs, mb, nb)
+            q = randn(B, t, nkv * g, hd).astype(jnp.bfloat16)
+            rows = sparse.prefill_rows(t, nkv * g, nkv, hd, bs, mb)
+            tq = pa._prefill_tiles(t, g, hd, bs, mb)[0]
+            idx = randn(B, rows, mb * bs)
+            q_abs = np.where(np.arange(rows)[None] < lens[:, None],
+                             ctx[:, None] + np.arange(rows)[None], -1)
+            tau, cut = sparse.paged_sparse_select(
+                idx.reshape(B * rows, -1), jnp.asarray(q_abs.reshape(-1)),
+                topk=topk)
+            tau, cut = tau.reshape(B, rows), cut.reshape(B, rows)
+            past = np.arange(mb * bs)[None, None] > q_abs[..., None]
+            junk = jnp.where(past, jnp.where(np.arange(mb * bs) % 2 == 0,
+                                             jnp.nan, jnp.inf), idx)
+            ctx, lens = jnp.asarray(ctx), jnp.asarray(lens)
+
+            def walk():
+                return sparse.paged_sparse_prefill_attention(
+                    q, k, v, junk, tau, cut, bad, ctx, lens, layer=1)
+
+            assert sparse._fetches_pages(hd, False)
+            got = jax.jit(walk)()
+            sparse._fetches_pages = lambda *a: False
+            try:
+                grid = walk()
+            finally:
+                sparse._fetches_pages = fetches
+            assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+            for b, n in enumerate(np.asarray(lens)):
+                if n:       # a sequence at a time: the scores are [nh, t, S]
+                    want = sparse.paged_sparse_prefill_attention_xla(
+                        q[b:b + 1], k, v, idx[b:b + 1], tau[b:b + 1],
+                        cut[b:b + 1], bt[b:b + 1], ctx[b:b + 1],
+                        lens[b:b + 1], layer=1)
+                    diff_ok(got[b, :n], want[0, :n], 0.05)
+                    diff_ok(got[b, :n], grid[b, :n], 1e-9)
+                # whole query tiles of padding: nothing fetched, zeros
+                assert not bool(got[b, -(-int(n) // tq) * tq:].any())
+
+    check("paged_sparse_prefill_own_pages", paged_sparse_prefill_own_pages)
+
     # the decode rows' index scores walk their own pages too (ISSUE 54): one
     # DMA a packed index page. Garbage table entries past a sequence's end,
     # idle slots (no row: their whole table row is garbage) first, between

@@ -339,6 +339,53 @@ def test_the_decode_rows_scores_fetch_their_own_index_pages(v5e):
     assert operands.count("%") == 7, operands
 
 
+def test_the_masked_prefill_walk_fetches_its_own_pages(v5e):
+    """``paged_sparse_prefill`` at the Keye cell's geometry (a 512-row chunk
+    of 32 query heads over 4 KV heads of 128, 32-token blocks, a 1 024-block
+    table, the ``[12, 7808, 4, 32, 128]`` pools) compiles for the chip -
+    inside Mosaic's VMEM, the scores' double-buffered ``[128, 1024]`` float32
+    tile beside the plain walk's - as ONE Mosaic call, still the instruction
+    ``paged_sparse_prefill.N`` that ``sparse_attn_roofline`` looks for, and
+    the K and V pools reach it ONCE each, where they lie - not once a page
+    of a grid step's tile (the grid of ``BlockSpec`` pages had 64 page
+    operands). Its page copies are unrolled where the loop is lowered: the
+    plain walk's starts and waits a pool and the scores' one a site."""
+    import re
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+    _, nh, nkv, hd, _, bs, table, blocks, _, _ = CELL_DECODES[KEYE_CELL]
+    t, bf, i32 = 512, jnp.bfloat16, jnp.int32
+    assert pa._fetches_pages(hd, False)
+    assert sparse.prefill_rows(t, nh, nkv, hd, bs, table) == t
+    assert sparse.prefill_pages(t, nh, (nkv, bs, hd), table) * bs == 1024
+
+    def fn(q, k, v, idx, tau, cut, tables, ctx, lens, layer):
+        return sparse.paged_sparse_prefill_attention(
+            q, k, v, idx, tau, cut, tables, ctx, lens, layer=layer)
+
+    pool = ((12, blocks, nkv, bs, hd), bf)
+    shapes = (((1, t, nh, hd), bf), pool, pool,
+              ((1, t, table * bs), jnp.float32), ((1, t), i32), ((1, t), i32),
+              ((1, table), i32), ((1,), i32), ((1,), i32), ((), i32))
+    text = _compile(fn, *shapes, device=v5e.devices[0]).as_text()
+    calls = re.findall(r"%(\S+) = (\S+) custom-call\((.*?)\), .*" + MOSAIC,
+                       text)
+    assert len(calls) == 1, calls
+    name, result, operands = calls[0]
+    assert re.fullmatch(r"paged_sparse_prefill(\.\d+)?", name)
+    assert result.startswith(f"bf16[1,{nkv},{t * nh // nkv},{hd}]")
+    # the tables, contexts, lengths and layer, q, tau and cut, K, V and the
+    # index scores: 10 operands
+    assert operands.count("%") == 10, operands
+    counts = _count_equations(jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(*s) for s in shapes)).jaxpr, {})
+    assert (counts["pallas_call"], counts["dma_start"],
+            counts["dma_wait"]) == (1, 4 * 2 + 2, 2 * 2 + 1)
+    assert sum(counts.values()) < 500
+
+
 @pytest.mark.parametrize("cell", sorted(CELL_DECODES))
 def test_decode_grid_is_sized_by_the_shapes(cell):
     """The walk's tile comes from the cell's shapes and the VMEM budget

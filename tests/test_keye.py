@@ -565,6 +565,179 @@ def test_sparse_attention_interpreted_is_the_gathered_softmax(pools, t,
     assert float(np.abs(np.where(real, got - want, 0)).max()) < 1e-5
 
 
+# --- the masked prefill walk fetches its own pages (ISSUE 63) -------------- #
+# heads of 128 lanes (whole lane tiles, so the walk fetches its own pages),
+# blocks of 8 tokens, a table 24 wide, query tiles of 16 tokens and a KV tile
+# held to 8 pages = 64 keys: ``ctx`` cached tokens, ``lens`` real rows of ``t``
+MASKED_WALKS = {
+    "many_tiles": dict(ctx=[150], lens=[16]),
+    "context_zero": dict(ctx=[0], lens=[16]),
+    "under_one_tile": dict(ctx=[30], lens=[16]),
+    "ends_on_a_tiles_edge": dict(ctx=[48], lens=[16]),
+    "one_key_past_a_tiles_edge": dict(ctx=[49], lens=[16]),
+    "context_fills_the_table": dict(ctx=[176], lens=[16]),
+    "three_query_tiles": dict(t=40, ctx=[140], lens=[40]),
+    # fewer real rows than the call's: query tile 1 is cut short, tile 2
+    # holds none, fetches nothing and writes zeros
+    "chunk_shorter_than_its_last_query_tile": dict(t=40, ctx=[100],
+                                                   lens=[20]),
+    "padded_last_chunk": dict(t=40, ctx=[100], lens=[7]),
+    "zero_length_dummies": dict(ctx=[140, 0, 30, 0], lens=[9, 0, 16, 0]),
+    "every_sequence_a_dummy": dict(ctx=[0, 0], lens=[0, 0]),
+    "group_1": dict(ctx=[150, 70], lens=[16, 16], nh=2, nkv=2),
+    "group_8": dict(ctx=[150], lens=[16], nh=8, nkv=1),
+    # 21 blocks: the last tile is five pages, and the scores are padded to
+    # whole tiles
+    "table_no_multiple_of_the_tile": dict(ctx=[150], lens=[16], table=21),
+    "one_layers_4d_pools": dict(ctx=[150, 3], lens=[16, 5], layers=0),
+    "fewer_keys_than_topk": dict(ctx=[2], lens=[3]),
+}
+
+
+def _masked_walk(c):
+    """``(the walk's operands, the reference's)`` of one small case: the
+    kernel's copy is POISONED wherever it must not look - table entries past
+    a sequence's blocks (every entry of a zero-length dummy) hold a block of
+    NaN rows, an index past the pool and a negative one, in turn, and the
+    index scores past each row's own position, which the chunk's
+    ``paged_index_scores`` never writes, are NaN and +inf."""
+    c = dict(dict(t=16, nh=4, nkv=2, table=24, layers=2), **c)
+    t, nh, nkv, table, bs, hd, nb = (c["t"], c["nh"], c["nkv"], c["table"],
+                                     8, 128, 64)
+    rng = np.random.default_rng(7)
+    B, poison = len(c["ctx"]), nb - 1
+    lead = (c["layers"],) if c["layers"] else ()
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    q, k, v = f(B, t, nh, hd), f(*lead, nb, nkv, bs, hd), \
+        f(*lead, nb, nkv, bs, hd)
+    tables = np.zeros((B, table), np.int32)
+    garbage = np.resize(np.asarray([poison, 10 ** 6, -3], np.int32),
+                        (B, table)).copy()
+    for b, (x, n) in enumerate(zip(c["ctx"], c["lens"])):
+        need = -(-(x + n) // bs) if n else 0
+        tables[b, :need] = garbage[b, :need] = rng.integers(1, poison, need)
+    rows = sparse.prefill_rows(t, nh, nkv, hd, bs, table)
+    width = table * bs
+    idx = f(B, rows, width)
+    q_abs = np.where(np.arange(rows)[None] < np.asarray(c["lens"])[:, None],
+                     np.asarray(c["ctx"])[:, None] + np.arange(rows)[None], -1)
+    tau, cut = sparse.paged_sparse_select_xla(
+        idx.reshape(B * rows, -1), jnp.asarray(q_abs.reshape(-1)), topk=TOPK)
+    tau, cut = tau.reshape(B, rows), cut.reshape(B, rows)
+    past = np.arange(width)[None, None] > q_abs[..., None]
+    bad_idx = jnp.where(past, jnp.where(np.arange(width) % 2 == 0, jnp.nan,
+                                        jnp.inf), idx)
+    ctx, lens = (jnp.asarray(c[n], jnp.int32) for n in ("ctx", "lens"))
+    kw = {"layer": lead[0] - 1} if lead else {}
+    bad = [p.at[..., poison, :, :, :].set(jnp.nan) for p in (k, v)]
+    return ((q, *bad, bad_idx, tau, cut, jnp.asarray(garbage), ctx, lens),
+            (q, k, v, idx, tau, cut, jnp.asarray(tables), ctx, lens), kw)
+
+
+def _small_masked_tiles(monkeypatch):
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_Q_ROWS", 32)
+    monkeypatch.setattr(pa, "_KV_TOKENS", 16)
+    monkeypatch.setattr(pa, "_WIDE_KV_TOKENS", 64)  # the plain walk's tile
+    monkeypatch.setattr(sparse, "_PREFILL_PAGES", 8)
+
+
+def _pallas_calls(fn, *args):
+    """Every ``pallas_call`` equation of ``fn``'s jaxpr, jitted calls' too."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for v in e.params.values():
+                if hasattr(getattr(v, "jaxpr", None), "eqns"):
+                    yield from walk(v.jaxpr)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("case", sorted(MASKED_WALKS))
+def test_masked_prefill_walk_that_fetches_its_own_pages(case, monkeypatch):
+    """``paged_sparse_prefill`` where ``_fetches_pages`` holds (interpreted:
+    the interpreter runs its DMAs, its semaphores and its SMEM carry): ONE
+    ``pallas_call`` on a grid (sequences, KV heads, query tiles) with no
+    dimension of KV tiles, the pools and the index scores whole operands.
+    Every real row is the gathered softmax's and - TO THE BIT - the grid of
+    ``BlockSpec`` pages' at the same tile (the same tiles, the same flash
+    sums in the same order), every row is finite over poisoned table entries
+    and poisoned scores, and a query tile with no real row is zeros. The
+    host's mirror counts the tiles the walk takes: the ones that hold
+    context."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    _small_masked_tiles(monkeypatch)
+    c = dict(dict(t=16, nh=4, nkv=2, table=24), **MASKED_WALKS[case])
+    bad, clean, kw = _masked_walk(c)
+    walk = functools.partial(sparse.paged_sparse_prefill_attention, **kw)
+    assert pa._fetches_pages(128, False)
+    shape = (c["nkv"], 8, 128)
+    assert sparse.prefill_pages(c["t"], c["nh"], shape, c["table"], 4) == 8
+    call, = _pallas_calls(walk, *bad)
+    mapping, n_qt = call.params["grid_mapping"], -(-c["t"] // 16)
+    assert mapping.grid == (len(c["ctx"]), c["nkv"], n_qt) \
+        and mapping.num_dynamic_grid_bounds == 0
+    out = np.asarray(walk(*bad))
+    want = np.asarray(sparse.paged_sparse_prefill_attention_xla(*clean, **kw))
+    assert out.shape == want.shape and np.isfinite(out).all()
+    tiles = sum((x + min(q_lo + 16, n) - 1) // 64 + 1
+                for x, n in zip(c["ctx"], c["lens"])
+                for q_lo in range(0, n_qt * 16, 16) if q_lo < n)
+    live, taken, wide = pa.prefill_tile_counts(
+        c["ctx"], c["lens"], c["t"], c["nh"], shape, c["table"], itemsize=4,
+        pages=8)
+    assert live == taken == c["nkv"] * tiles \
+        and wide == len(c["ctx"]) * c["nkv"] * n_qt * -(-c["table"] // 8)
+    monkeypatch.setattr(sparse, "_fetches_pages", lambda *a: False)
+    # a new function: nothing traced is kept
+    call, = _pallas_calls(lambda *a: walk(*a), *bad)
+    assert len(call.params["grid_mapping"].grid) == 4
+    grid = np.asarray(walk(*bad))
+    for b, n in enumerate(c["lens"]):
+        assert gap(out[b, :n], want[b, :n]) < 1e-5 if n else True
+        np.testing.assert_array_equal(out[b, :n], grid[b, :n])
+        # whole query tiles of padding: nothing fetched, zeros written
+        assert not out[b, -(-n // 16) * 16:].any()
+
+
+def test_the_selection_is_an_operand_of_the_masked_walk_alone(monkeypatch):
+    """The walk that fetches its own pages is ONE kernel body for both ops
+    (``paged_attention._prefill_kernel``), and the selection exists in a
+    call only where a caller hands it: ``paged_prefill`` keeps q and the two
+    pools, two tile scratches and two rows of semaphores - the parent's
+    program, which ``tests/test_chip_compile.py`` holds to its hash -, and
+    ``paged_sparse_prefill`` has the rows' thresholds, the index scores, one
+    more scratch (the scores' double-buffered ``[2, tq, KV]`` float32 tile)
+    and one more row of semaphores."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    _small_masked_tiles(monkeypatch)
+    (q, k, v, idx, tau, cut, tables, ctx, lens), _, kw = _masked_walk(
+        MASKED_WALKS["many_tiles"])
+
+    def operands(call):
+        mapping = call.params["grid_mapping"]
+        at = mapping.num_index_operands + mapping.num_inputs \
+            + mapping.num_outputs
+        scratch = [v.aval for v in call.params["jaxpr"].invars[at:]]
+        return (call.params["name"], mapping.num_index_operands,
+                mapping.num_inputs, [tuple(a.shape) for a in scratch])
+
+    plain, = _pallas_calls(lambda *a: pa.paged_prefill_attention(*a, **kw),
+                           q, k, v, tables, ctx, lens)
+    flash = [(32, 128), (32, 128), (32, 128)]       # m, l, the accumulator
+    assert operands(plain) == ("paged_prefill", 4, 3, [
+        (2, 64, 128), (2, 64, 128), (2, 2), (1,)] + flash)
+    masked, = _pallas_calls(
+        lambda *a: sparse.paged_sparse_prefill_attention(*a, **kw),
+        q, k, v, idx, tau, cut, tables, ctx, lens)
+    assert operands(masked) == ("paged_sparse_prefill", 4, 6, [
+        (2, 64, 128), (2, 64, 128), (2, 16, 64), (3, 2), (1,)] + flash)
+
+
 # --- one chip's share of the expert bank ----------------------------------- #
 def test_the_shares_of_the_bank_add_up_to_the_whole_layer():
     """Four shares of two experts each, attention counted once: the parts of

@@ -1099,6 +1099,39 @@ def test_chunk_spans_sum_both_kinds_walks_over_their_layers(monkeypatch):
                                2 * (-(-table // 2) + 3 * 4))
 
 
+def test_chunk_spans_count_a_learned_selections_masked_walk():
+    """Under a learned selection (Keye's family at heads of 128 lanes and
+    32-token blocks) the chunk's walk is ``paged_sparse_prefill``, which
+    fetches its own pages: every mixed ``decode_step`` carries the counts
+    of ONE layer's call at that walk's own tile - 1 024 keys where the
+    table holds one -, and ``chunk_attn_tiles_grid ==
+    chunk_attn_tiles_live``: the reading that says the mechanism engaged."""
+    from test_keye import TINY, family
+
+    from deepspeed_tpu.inference.engine_v2 import build_engine_v2
+    from deepspeed_tpu.ops.pallas import paged_sparse_attention as sparse
+
+    hf = {**TINY, "head_dim": 128, "max_position_embeddings": 1024}
+    cfg = family.build_cfg(hf, drop_tokens=False)
+    eng = build_engine_v2(
+        family.module(), cfg, family.init(cfg, jax.random.PRNGKey(0)),
+        config={"dtype": "float32", "prefill_bucket": 16,
+                "split_prefill_chunk": 16,
+                "trace": {"enabled": True, "ring_size": 4096,
+                          "dump_on_crash": False},
+                "ragged": {"max_tracked_sequences": 2,
+                           "max_ragged_batch_size": 2,
+                           "memory_config_blocks": 40, "block_size": 32}})
+    nkv, table = cfg.num_kv_heads, eng.state.max_blocks_per_seq
+    assert (nkv, table, eng.cache["k"].shape[-1]) == (2, 32, 128)
+    assert sparse.prefill_pages(16, cfg.num_heads, eng.cache["k"].shape,
+                                table, 4) * 32 == 1024
+    mixed, chunks = _chunk_tile_spans(eng, 40)
+    # one query tile, one 1 024-key tile of the table's one, two KV heads
+    assert mixed == chunks == [(0, 16, 2, 2, 2, 1024), (16, 16, 2, 2, 2, 1024),
+                               (32, 8, 2, 2, 2, 1024)]
+
+
 def _kernel_cases():
     """One tiny call into each ``pallas_call`` site -> the kernel's name."""
     import jax
@@ -1225,6 +1258,13 @@ def _kernel_cases():
             lambda q, i, t_, bt, n: ps.paged_sparse_prefill_attention(
                 q, pool, pool, i, t_, t_, bt, n, n),
             [jnp.ones((2, 3, 4, 32), f32), idx, thr, tables, lens]),
+        # the same name's second call site: heads of 128 fetch their own
+        # pages (ISSUE 63; heads of 32 walk the grid of BlockSpec pages)
+        "paged_sparse_prefill:own_pages": (
+            lambda q, k, i, t_, bt, n: ps.paged_sparse_prefill_attention(
+                q, k, k, i, t_, t_, bt, n, n),
+            [jnp.ones((2, 3, 4, 128), f32), jnp.ones((8, 2, 16, 128), f32),
+             idx, thr, tables, lens]),
         "moe_grouped_matmul": (
             lambda x, wg, wd, e, n: gm.moe_grouped_matmul(
                 x, wg, wg, wd, e, e + 16, n, 1, tile=16),
@@ -1248,7 +1288,8 @@ KERNEL_NAMES = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
 
 
 # a name with two call sites names the second ``<name>:<which>``
-CALL_SITES = KERNEL_NAMES + ["paged_prefill:own_pages"]
+CALL_SITES = KERNEL_NAMES + ["paged_prefill:own_pages",
+                             "paged_sparse_prefill:own_pages"]
 
 
 @pytest.mark.parametrize("site", CALL_SITES)
