@@ -270,7 +270,11 @@ def scan_layers(body, x, layers, cache, *extras):
     :func:`paged_attention_step`. A cache with a THIRD pool, ``kI`` (the
     index keys of a learned token selection, :func:`sparse_attention_step`),
     hands its entry over after the other two and takes it back the same
-    way. Returns ``(x, cache)``.
+    way. ``x`` is whatever the family streams through its layers - the
+    hidden state, or a pytree with more beside it (``models/zaya.py``: the
+    residual stream, a router state layer ``l - 1`` hands layer ``l``, and a
+    per-slot pool of tails every layer reads and writes by the state pool's
+    row ops). Returns ``(x, cache)``.
 
     Nothing with a layout preference of its own may touch the pools on the
     way (an XLA scatter on the carry copies the whole ``[L, ...]`` pool a

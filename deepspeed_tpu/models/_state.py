@@ -8,7 +8,9 @@ previous ``K - 1`` rows - in a slot's row under the recurrent state
 convolution over ``[x | B | C]``, with a bias) when a second kind of layer
 took them (``models/solar_open2.py``: three convolutions without one): the
 same operations in the same order, so the programs that had them are what
-they were.
+they were. ``models/zaya.py`` takes them a third time, for a tail that lies
+BESIDE paged keys and values in every layer (a pool of tails alone) ahead
+of attention, its convolution without the silu.
 """
 
 from __future__ import annotations
@@ -44,10 +46,11 @@ def state_call(pool, block_tables, context_lens, valid, slots):
     return rows, (call.lens == 0, (call.chunk_ctx == 0)[None]), call
 
 
-def short_conv(x, tail, taps, bias=None):
+def short_conv(x, tail, taps, bias=None, silu: bool = True):
     """The causal depthwise convolution of ``x [b, t, C]`` after the
     sequence's previous ``K - 1`` rows ``tail [b, K - 1, C]``, ``taps [K,
-    C]`` (``bias [C]`` or None), in float32, and its silu: ``(silu(conv +
+    C]`` (``bias [C]`` or None), in float32, and its silu (not ``silu``: the
+    plain form, ``models/zaya.py``'s first convolution): ``(silu(conv +
     bias) [b, t, C] in x's type, the rows it ran over [b, K - 1 + t, C])``."""
     t = x.shape[1]
     ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
@@ -56,7 +59,7 @@ def short_conv(x, tail, taps, bias=None):
               for k in range(taps.shape[0]))
     if bias is not None:
         out = out + bias.astype(F32)
-    return jax.nn.silu(out).astype(x.dtype), ext
+    return (jax.nn.silu(out) if silu else out).astype(x.dtype), ext
 
 
 def tail_part(first: int, flat: int, width: int) -> Tuple[int, int, int]:

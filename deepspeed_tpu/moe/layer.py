@@ -183,9 +183,12 @@ class MoELayer:
                 and get_mesh().world_size == 1
                 and all(n == 1 for n in traced.shape.values()))
 
-    def __call__(self, params: Params, x: jnp.ndarray,
-                 layer=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def __call__(self, params: Params, x: jnp.ndarray, layer=None,
+                 logits=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """x: [batch, seq, hidden] → ([batch, seq, hidden], aux_loss).
+        ``logits [batch * seq, n_experts]``: the router's logits where the
+        family computed them itself (a router that is not one matrix:
+        ``models/zaya.py``); ``params`` then needs no ``router``.
         ``layer``: ``params["w_gate" / "w_up" / "w_down"]`` are the STACKED
         ``[L, E, ...]`` banks of every layer and this is the one to read
         (int or traced scalar: the layer scan's index); None: they are one
@@ -198,15 +201,8 @@ class MoELayer:
                 for n in BANK]
         # moe_router: router logits, gating and the dispatch of tokens to
         # expert rows; moe_experts: the expert bank and the combine
-        with jax.named_scope("moe_router"):
-            router = params["router"]
-            if router.dtype == jnp.float32 != tokens.dtype:
-                # a router kept in float32 beside narrower weights is
-                # applied to float32 rows, as its family publishes it
-                logits = jnp.dot(tokens.astype(jnp.float32), router,
-                                 precision=lax.Precision.HIGHEST)
-            else:
-                logits = tokens @ router.astype(tokens.dtype)
+        if logits is None:
+            logits = self._router_logits(params["router"], tokens)
         bias = params.get("router_bias")     # enters the CHOICE alone
         if layer is not None and self.grouped():
             out, aux_loss = self._grouped(tokens, logits, bank, layer, bias)
@@ -236,6 +232,16 @@ class MoELayer:
                                                   shared.dtype)
                 out = out + shared
         return out.reshape(b, s, h), aux_loss
+
+    @staticmethod
+    def _router_logits(router, tokens):
+        with jax.named_scope("moe_router"):
+            if router.dtype == jnp.float32 != tokens.dtype:
+                # a router kept in float32 beside narrower weights is
+                # applied to float32 rows, as its family publishes it
+                return jnp.dot(tokens.astype(jnp.float32), router,
+                               precision=lax.Precision.HIGHEST)
+            return tokens @ router.astype(tokens.dtype)
 
     def _gate_kw(self):
         return dict(capacity_factor=self.capacity_factor,

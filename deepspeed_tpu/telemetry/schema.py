@@ -395,7 +395,17 @@ TRACER_SPANS = frozenset((
     # models/mellum.py) says ``kv_tokens_full`` / ``kv_tokens_window`` on
     # ``decode_step`` and ``prefill_chunk`` and, on ``decode_step`` alone,
     # ``rows_past_window``: the decode rows whose context is longer than the
-    # window (the benchmark's ``decode_rows_past_window`` reads it)
+    # window (the benchmark's ``decode_rows_past_window`` reads it).
+    # models/zaya.py (a tail on the slot pool BESIDE paged keys and values in
+    # every layer, under a top-1 bank with a skip output) says ``cca_rows`` -
+    # the rows ONE layer's CCA mixing took: the live single-token rows and
+    # the chunk's tokens - and ``cca_tail_rows`` - the pool rows their tails
+    # came from and went back to - on ``decode_step`` and ``prefill_chunk``
+    # (``cca_mix_roofline`` reads both), and ``moe_rows_skipped`` beside
+    # ``moe_rows_routed`` / ``moe_rows_computed`` on those and on
+    # ``prefill_batch``: the rows ONE router sends to its skip output, in
+    # expectation under a uniform router (a shape fact, no count: no
+    # counter and no benchmark metric reads it)
     "prefill_batch", "prefill_chunk", "decode_step", "decode_quantum",
     "spec_verify", "engine_prep", "engine_dispatch", "engine_wait",
     "engine_emit",

@@ -1803,3 +1803,99 @@ def test_delta_state_kv_blocks_and_experts_stay_where_they_are(v5e, program):
     assert calls.count("state_rows_read") == decode + chunk
     assert calls.count("state_rows_write") == decode + 2 * chunk
     assert calls.count("moe_grouped_matmul") >= 2
+
+
+# --- a tail on the slot pool BESIDE paged K/V in every layer, under a top-1
+# bank with a skip output: ZAYA1-8B (ISSUE 64) ------------------------------- #
+ZAYA_CELL = "zaya1-8b.serve-reason-64"
+
+
+def _zaya_program(program):
+    """The ZAYA1 cell's paged forward on shapes at its published widths, the
+    cell's 20 layers and all 16 experts, as the engine calls it: ``decode``
+    (64 rows of one token), ``chunk`` (one row of 512) or ``mixed`` (both as
+    ``slots + chunk`` rows, the head on 65). ``(forward, arguments)`` with
+    the cache second."""
+    from benchmark.harness.manifest import Cell
+    from deepspeed_tpu.models._paged import MixedCall
+
+    cell = Cell(ZAYA_CELL)
+    engine = cell.role["engine"]
+    ragged = engine["ragged"]
+    cfg = cell.family.build_cfg(cell.model, **cell.role["program_options"])
+    module = cell.family.module()
+    params = jax.eval_shape(
+        lambda k: module.init(cfg, k, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    slots, bs = ragged["max_tracked_sequences"], ragged["block_size"]
+    chunk = engine["split_prefill_chunk"]
+    cache = jax.eval_shape(lambda: module.init_paged_cache(
+        cfg, ragged["memory_config_blocks"], bs, slots=slots))
+    table = cfg.max_seq_len // bs
+
+    def forward(params, cache, tokens, tables, ctx, valid, rows, read):
+        return module.apply_paged(cfg, params, tokens, cache, tables, ctx,
+                                  valid=valid, slots=rows, rows=read)
+
+    i32, s = jnp.int32, jax.ShapeDtypeStruct
+    if program == "mixed":
+        call = MixedCall(s((slots, table), i32), s((slots,), i32),
+                         s((slots,), bool), s((table,), i32), s((), i32),
+                         s((), i32), s((), i32))
+        rows = slots + chunk
+        return forward, (params, cache, s((1, rows), i32), call, None,
+                         s((1, rows), bool), None, s((1, slots + 1), i32))
+    b, t = (slots, 1) if program == "decode" else (1, chunk)
+    return forward, (params, cache, s((b, t), i32), s((b, table), i32),
+                     s((b,), i32), s((b, t), bool), s((b,), i32),
+                     s((b, 1), i32))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
+def test_a_tail_beside_kv_blocks_in_every_layer_stays_where_it_is(v5e,
+                                                                  program):
+    """The ZAYA1 cell's ``decode`` (64 rows), one ``chunk`` (512 tokens) and
+    the ``mixed`` call of both at the published widths - 20 layers, each
+    with paged K and V AND a row of the tail pool, 16 experts a layer, 2 560
+    blocks of 64 tokens, 65 rows of tails - compiled for the chip: the
+    three pools and the router's carried state ride ONE layer scan, the
+    pools aliased argument-to-result with no copy of their shape, the expert
+    banks read where they lie, the program with its 9.38 GB of weights and
+    3.36 GB of pools fits the chip with room for a probe's own pools, and a
+    layer body reads and writes its segment's tails by the state pool's two
+    row ops beside the paged write and walk."""
+    import re
+
+    from deepspeed_tpu.telemetry.compile import pool_copy_bytes
+
+    fn, args = _zaya_program(program)
+    sh = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    params, cache = args[0], args[1]
+    moe = params["layers"]["moe"]
+    assert moe["router_out"].dtype == jnp.float32
+    assert moe["w_up"].shape == (20, 16, 2048, 2048)
+    assert {k: (tuple(v.shape), v.dtype.name) for k, v in cache.items()} == {
+        "k": ((20, 2560, 2, 64, 128), "bfloat16"),
+        "v": ((20, 2560, 2, 64, 128), "bfloat16"),
+        "tail": ((20, 65, 16, 256), "bfloat16")}
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pools = jax.tree.leaves(cache)
+    assert pool_copy_bytes(text, pools) == 0
+    bank = [moe[n] for n in ("w_gate", "w_up", "w_down")]
+    assert pool_copy_bytes(text, bank) == 0
+    pool_bytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes > 3.36e9
+    assert mem.temp_size_in_bytes < 0.6e9
+    # ... beside a probe's own pools (families/mixed_program.py: 2.73 GB)
+    assert 12.7e9 < mem.peak_memory_in_bytes < V5E_BYTES_LIMIT - 2.9e9
+    calls = [re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%(\S+) = .*? custom-call\(.*" + MOSAIC, text)]
+    decode, chunk = program != "chunk", program != "decode"
+    # ONE layer body: a read and a write of the tails a segment
+    assert calls.count("state_rows_read") == decode + chunk
+    assert calls.count("state_rows_write") == decode + chunk
+    assert calls.count("paged_decode") == int(decode)
+    assert calls.count("moe_grouped_matmul") >= 1
